@@ -15,7 +15,7 @@ correctness contract: active-slot masking and the additive ``-1e9``
 attention bias make retired slots and stale cache positions contribute
 exactly 0.0, so batchmates can never perturb each other.
 
-Run: PADDLE_TPU_FORCE_CPU=1 python examples/serve_generation.py
+Run: JAX_PLATFORMS=cpu python examples/serve_generation.py
 """
 
 import os
@@ -52,10 +52,9 @@ def build_programs():
 
 
 def main():
-    from paddle_tpu.core.places import ensure_backend_or_cpu
+    import jax
 
-    on_acc, diag = ensure_backend_or_cpu(timeout=20, retries=1)
-    print(f"backend: {'accelerator' if on_acc else 'cpu'} ({diag})")
+    print(f"backend: {jax.devices()[0].platform}")
 
     from paddle_tpu.serving import Priority
     from paddle_tpu.serving.decode import GenerationEngine

@@ -14,7 +14,7 @@ padding, a zero mask position contributes exactly 0 to the softmax —
 which is why the padded batched outputs below match the single-request
 predictor bit-for-bit.
 
-Run: PADDLE_TPU_FORCE_CPU=1 python examples/serve_transformer.py
+Run: JAX_PLATFORMS=cpu python examples/serve_transformer.py
 """
 
 import os
@@ -71,10 +71,9 @@ def _make_request(rng, max_len):
 
 
 def main():
-    from paddle_tpu.core.places import ensure_backend_or_cpu
+    import jax
 
-    on_acc, diag = ensure_backend_or_cpu(timeout=20, retries=1)
-    print(f"backend: {'accelerator' if on_acc else 'cpu'} ({diag})")
+    print(f"backend: {jax.devices()[0].platform}")
 
     import paddle_tpu as fluid
     from paddle_tpu import inference
@@ -99,8 +98,6 @@ def main():
 
         # -- engine start: warm the whole lattice up front ----------------
         config = inference.Config(model_dir)
-        if not on_acc:
-            config.disable_tpu()
         lattice = BucketLattice(batch_sizes=(1, 2, 4, 8), seq_lens=(4, 8, 16))
         config.set_serving_buckets(lattice.batch_sizes, lattice.seq_lens)
         engine = ServingEngine(config, lattice=lattice, num_replicas=2,

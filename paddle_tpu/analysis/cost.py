@@ -90,12 +90,12 @@ class MachineModel:
 
 
 #: machine catalog — peak bf16 FLOP/s and HBM BW per chip match
-#: bench.py's `_chip_peak_flops` table; ICI is the per-chip injection
+#: bench.py's `_PEAK_BF16` table; ICI is the per-chip injection
 #: bandwidth of one ring direction-pair, DCN a 100 Gb/s NIC share.
 MACHINES = {
     "tpu-v4-8": MachineModel("tpu-v4-8", 275e12, 1.2e12,
                              9e10, 1e-6, 12.5e9, 1e-5),
-    "tpu-v5e-8": MachineModel("tpu-v5e-8", 394e12, 8.1e11,
+    "tpu-v5e-8": MachineModel("tpu-v5e-8", 197e12, 8.1e11,
                               4.5e10, 1e-6, 12.5e9, 1e-5),
     "tpu-v5p-8": MachineModel("tpu-v5p-8", 459e12, 2.765e12,
                               9e10, 1e-6, 12.5e9, 1e-5),

@@ -18,6 +18,7 @@ import warnings
 import numpy as np
 
 import jax
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core.executor import (
@@ -28,7 +29,7 @@ from paddle_tpu.core.executor import (
 )
 from paddle_tpu.core.scope import global_scope
 from paddle_tpu.observability.tracer import trace_scope
-from paddle_tpu.parallel.env import make_mesh, shard_map as _shard_map
+from paddle_tpu.parallel.env import make_mesh
 from paddle_tpu.utils.enforce import EnforceError, enforce
 from paddle_tpu.utils.flags import flags
 

@@ -10,14 +10,21 @@ process re-enters its step without a retrace:
 
 - tier 1: a process-wide in-memory map fingerprint -> LoweredStep, shared
   by every Executor/Predictor/CompiledProgram in the process;
-- tier 2: an on-disk persistent cache (``PADDLE_TPU_CACHE_DIR``) holding
+- tier 2: an on-disk persistent cache (``<cache_dir()>/ptcc``) holding
   ``jax.export``-serialized StableHLO, written atomically with a CRC32
   like incubate/checkpoint.py — a corrupt or truncated entry is
   quarantined and silently falls back to a fresh trace, never a crash or
   a wrong answer;
-- tier 3: XLA's own persistent compilation cache (enabled under the same
-  directory) so even the StableHLO->executable compile is reused across
-  processes.
+- tier 3: XLA's own persistent compilation cache (``cache_dir()`` itself)
+  so even the StableHLO->executable compile is reused across processes.
+
+Placement is decided from outside, by the standard variable:
+``JAX_COMPILATION_CACHE_DIR`` set -> that directory holds everything (jax
+reads it natively; this module never sets ``jax_compilation_cache_dir``
+on that path); unset -> ``<checkout>/.jax_cache``, a fixed path computed
+from ``__file__`` (the directory is part of XLA's cache key, so one that
+moves never hits). On the CPU backend the default directory is not
+used: see ``enabled()``.
 
 The fingerprint covers everything that can change the compiled artifact:
 the serialized block desc, feed/fetch signature, scope-input
@@ -44,6 +51,7 @@ from paddle_tpu.observability import lockdep as _lockdep
 __all__ = [
     "program_fingerprint",
     "cache_dir",
+    "enabled",
     "get_or_build",
     "load_persistent",
     "store_persistent",
@@ -81,36 +89,59 @@ def _counter(name, help_):
     return c
 
 
+_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def _placed_dir():
+    """Where the environment placed the cache ('' when it did not). Read
+    per call (not latched at import) so the answer is the environment's,
+    not this module's."""
+    return os.environ.get(_DIR_ENV, "").strip()
+
+
 def cache_dir():
-    """The persistent cache directory, or None when disabled. Read per
-    call (not latched at import) so tests and launchers can flip
-    ``PADDLE_TPU_CACHE_DIR`` per process without re-importing."""
-    d = os.environ.get("PADDLE_TPU_CACHE_DIR", "").strip()
-    return d or None
+    """The persistent cache directory: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else ``<checkout>/.jax_cache``."""
+    return _placed_dir() or _DEFAULT_DIR
 
 
-_xla_cache_wired = set()
-
-
-def _wire_xla_cache(d):
-    """Point jax's own persistent compilation cache at our directory so a
-    disk hit skips the XLA compile too, not just the Python trace. Best
-    effort: unsupported knobs on an older/newer jax just leave tier 3
-    off."""
-    if d in _xla_cache_wired:
-        return
-    _xla_cache_wired.add(d)
+def enabled():
+    """Whether anything is persisted. jax's own switch
+    (``JAX_ENABLE_COMPILATION_CACHE``) governs tiers 2 and 3 alike. Past
+    it, an accelerator backend always persists (compiles there take
+    minutes), while the CPU backend persists only where
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache: CPU compiles are
+    short, this jaxlib's XLA:CPU loader logs kilobytes on every load, and
+    tier-1's trace-count assertions must not meet a warm checkout."""
     import jax
 
-    for knob, val in (
-        ("jax_compilation_cache_dir", os.path.join(d, "xla")),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
+    if not jax.config.jax_enable_compilation_cache:
+        return False
+    return bool(_placed_dir()) or jax.default_backend() != "cpu"
+
+
+_xla_cache_wired = False
+
+
+def _wire_xla_cache():
+    """Let XLA's persistent cache keep every executable (its defaults skip
+    sub-second compiles), and — only when the environment did not place
+    it — point it at the default directory."""
+    global _xla_cache_wired
+    if _xla_cache_wired:
+        return
+    _xla_cache_wired = True
+    import jax
+
+    if not _placed_dir():
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 # ---------------------------------------------------------------------------
@@ -125,8 +156,6 @@ _LOWERING_FLAGS = (
     "amp_dtype",
     "rng_impl",
     "sparse_embedding_update",
-    "pallas_sparse_update",
-    "pallas_dgc_topk",
     "dgc_sparse_exchange",
 )
 
@@ -190,7 +219,7 @@ def program_fingerprint(
         # added only when a registry drives placement, so fingerprints of
         # layout-less lowerings (everything the persistent tier holds)
         # are byte-identical to pre-registry revisions — a deploy of this
-        # code does not cold-miss an existing PADDLE_TPU_CACHE_DIR
+        # code does not cold-miss an existing cache directory
         payload["layout"] = layout_sig
     if kernel_sig is not None:
         # same discipline for the Pallas kernel registry
@@ -211,8 +240,8 @@ def program_fingerprint(
 # ---------------------------------------------------------------------------
 
 
-def _entry_path(d, fingerprint):
-    return os.path.join(d, fingerprint + _ENTRY_SUFFIX)
+def _entry_path(fingerprint):
+    return os.path.join(cache_dir(), "ptcc", fingerprint + _ENTRY_SUFFIX)
 
 
 def store_persistent(fingerprint, header, payload):
@@ -222,18 +251,15 @@ def store_persistent(fingerprint, header, payload):
     the payload CRC32 + length recorded in the header so truncation and
     bit-rot are detected before deserialization. Best effort: any IO
     failure leaves the cache cold, never breaks the step."""
-    d = cache_dir()
-    if d is None:
-        return False
     try:
-        os.makedirs(d, exist_ok=True)
+        final = _entry_path(fingerprint)
+        os.makedirs(os.path.dirname(final), exist_ok=True)
         header = dict(header)
         header["fingerprint"] = fingerprint
         header["payload_crc32"] = zlib.crc32(payload) & 0xFFFFFFFF
         header["payload_len"] = len(payload)
         header["created"] = time.time()
         hbytes = json.dumps(header, sort_keys=True).encode("utf-8")
-        final = _entry_path(d, fingerprint)
         tmp = final + f".tmp.{os.getpid()}"
         with open(tmp, "wb") as f:
             f.write(_MAGIC)
@@ -265,10 +291,7 @@ def load_persistent(fingerprint):
     """Load one entry; returns (header, payload) or None. A missing file
     is a plain miss; a corrupt/truncated/mismatched one is quarantined
     and reported as a miss — the caller falls back to a fresh trace."""
-    d = cache_dir()
-    if d is None:
-        return None
-    path = _entry_path(d, fingerprint)
+    path = _entry_path(fingerprint)
     if not os.path.exists(path):
         return None
     try:
@@ -326,9 +349,8 @@ def get_or_build(fingerprint, build):
     fingerprint share ONE ``build()``; distinct fingerprints build in
     parallel. A failed build propagates its exception to every waiter and
     leaves the cache cold (the next call retries)."""
-    d = cache_dir()
-    if d is not None:
-        _wire_xla_cache(d)
+    if enabled():
+        _wire_xla_cache()
     while True:
         with _lock:
             entry = _mem.pop(fingerprint, None)

@@ -459,11 +459,9 @@ def lower_step(
 
     if persist is None:
         persist = mesh is None and in_shardings is None
-    if persist and compile_cache.cache_dir() is None:
-        # no cache dir configured: skip the export/serialize work and
-        # trace straight into a plain jit (the graceful fallback — and
-        # the zero-overhead path when persistence is off)
-        persist = False
+    # with persistence switched off, skip the export/serialize work and
+    # trace straight into a plain jit
+    persist = persist and compile_cache.enabled()
     step_factory = make_step if make_step is not None else _default_step
 
     def build():

@@ -506,7 +506,7 @@ class Predictor:
         compile_s, persistent_hits}. A warmed serving fleet holds misses
         constant while hits grow — the hit-rate metric
         ServingEngine.stats() reports; persistent_hits counts buckets a
-        cold replica loaded from PADDLE_TPU_CACHE_DIR instead of
+        cold replica loaded from the compile-cache directory instead of
         compiling."""
         with self._cache_lock:
             return dict(self._cache_stats)

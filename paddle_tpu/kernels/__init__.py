@@ -9,39 +9,41 @@ kernel               serves                          parity    activation
 ==================== ============================== ========= =========
 flash_attention      scaled_dot_product_attention    tolerance mode
 cached_attention     cached_attention (decode [S,1]) bit       mode
-paged_attention      paged_attention (block arena)   bit       mode
-embedding_admission  hot-slab miss admission         bit       mode
-dgc_topk             dgc gradient compaction         tolerance FLAGS_pallas_dgc_topk
-sparse_row_update    sgd_sparse row scatter          tolerance FLAGS_pallas_sparse_update
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
 ==================== ============================== ========= =========
 
 Every entry registers a ``parity_check`` — tests/test_kernels.py
 parametrizes over ``all_specs()`` and runs them all, so this table IS
-the CI gate (a kernel without a parity test cannot register).
+the CI gate (a kernel without a parity test cannot register) — and every
+``kind="kernel"`` entry must AOT-compile for the v5e
+(tests/test_kernels_tpu_aot.py): a kernel that interprets but does not
+lower for the chip cannot stay registered.
 """
 
 import numpy as np
 
 from paddle_tpu.kernels import registry as _r
 from paddle_tpu.kernels.registry import (  # noqa: F401
-    MODE_ENV, KernelSpec, all_specs, get, has, kernel_sig, mode, probe,
-    register, registry_fingerprint, resolved_mode, scoped_mode, selected,
+    MODE_ENV, KernelSpec, all_specs, fallback_counter, get, has, kernel_sig,
+    mode, probe, register, registry_fingerprint, resolved_mode, scoped_mode,
+    selected,
 )
 
 __all__ = [
     "MODE_ENV", "KernelSpec", "all_specs", "get", "has", "kernel_sig",
     "mode", "probe", "register", "registry_fingerprint", "resolved_mode",
-    "scoped_mode", "selected", "fallback_internal_bytes",
+    "scoped_mode", "selected", "fallback_counter",
+    "fallback_internal_bytes",
 ]
 
 
 def fallback_internal_bytes(op_type, attrs, shape_of, itemsize=4):
-    """HBM bytes the COMPOSITE fallback of a fused attention op
-    materializes that the kernel keeps in VMEM — what
-    ``analysis/memory.py`` adds back to the peak estimate when the
-    kernel is not selected. ``shape_of(slot)`` resolves an input slot's
-    static shape (None when unknown)."""
+    """HBM bytes the COMPOSITE lowering of a fused attention op
+    materializes that a kernel would keep in VMEM — what
+    ``analysis/memory.py`` adds to the peak estimate when no kernel
+    serves the op (always, for ``paged_attention``: it has none yet).
+    ``shape_of(slot)`` resolves an input slot's static shape (None when
+    unknown)."""
     if op_type == "paged_attention":
         q = shape_of("Q")
         if q is None:
@@ -101,6 +103,52 @@ def _parity_flash(rng):
     _assert_close_both_ways(got, ref, "flash_attention", 1e-5, 1e-5)
 
 
+def _tpu_cases_flash():
+    """BERT-base heads at s128 in bf16 with the padding bias (the train
+    leg of chip_smoke.py; batch cut — the grid scales with it, the kernel
+    body does not), and a causal multi-block length (models/gpt_ir.py)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def attend(causal):
+        def fwd(q, k, v, bias):
+            return flash_attention(q, k, v, bias=bias, causal=causal,
+                                   interpret=False)
+        return fwd
+
+    def grads(fwd):
+        return jax.grad(
+            lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+            argnums=(0, 1, 2, 3))
+
+    cases = []
+    for label, causal, (b, h, s, d) in (
+            ("bert_s128", False, (8, 12, 128, 64)),
+            ("causal_s512", True, (2, 8, 512, 128))):
+        args = [((b, h, s, d), "bfloat16")] * 3 + [((b, s), "float32")]
+        cases.append((label + "_fwd", attend(causal), args))
+        cases.append((label + "_grad", grads(attend(causal)), args))
+    return cases
+
+
+def _tpu_cases_cached():
+    """Serving width (hidden 1024, 8 slots) at the longest cache the
+    VMEM gate admits there: 2 x 8 x 128 x 1024 f32 = 8 MiB of K/V."""
+    from paddle_tpu.kernels import attention as A
+
+    S, L, H = 8, 128, 1024
+
+    def fwd(q, k, v, bias):
+        return A.decode_attention(q, k, v, bias, 1.0 / 32.0,
+                                  interpret=False)
+
+    return [("s8_l128_h1024", fwd, [
+        ((S, H), "float32"), ((S, L, H), "float32"),
+        ((S, L, H), "float32"), ((S, 1, L), "float32")])]
+
+
 def _parity_cached(rng):
     """Kernel-interpret vs composite UNDER JIT on both sides: every real
     execution path lowers through one jit (core/lowering.py), and the
@@ -124,76 +172,6 @@ def _parity_cached(rng):
     ref = jax.jit(lambda *a: A.cached_attention_composite(*a, sm))(
         q, k, v, bias)
     _assert_bytes_equal(got, ref, "cached_attention")
-
-
-def _parity_paged(rng):
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.kernels import attention as A
-
-    S, L, H, R = 3, 8, 8, 64
-    q = jnp.asarray(rng.randn(S, H).astype("float32"))
-    ka = jnp.asarray(rng.randn(R, H).astype("float32"))
-    va = jnp.asarray(rng.randn(R, H).astype("float32"))
-    rows = jnp.asarray(rng.randint(0, R, S * L).astype("int64"))
-    cur = rng.randint(1, L, S)
-    bias = np.where(np.arange(L)[None, :] < cur[:, None], 0.0, -1e9)
-    bias = jnp.asarray(bias.astype("float32").reshape(S, 1, L))
-    sm = 1.0 / float(np.sqrt(H))
-    got = jax.jit(lambda *a: A.paged_attention(
-        *a, S, L, sm, interpret=True))(q, ka, va, rows, bias)
-    ref = jax.jit(lambda *a: A.paged_attention_composite(
-        *a, S, L, sm))(q, ka, va, rows, bias)
-    _assert_bytes_equal(got, ref, "paged_attention")
-
-
-def _parity_admission(rng):
-    import jax.numpy as jnp
-
-    from paddle_tpu.kernels import embedding as E
-
-    C, D, M = 32, 8, 5
-    slab = rng.randn(C, D).astype("float32")
-    slots = rng.choice(C, M, replace=False).astype("int32")
-    rows = rng.randn(M, D).astype("float32")
-    got = E.admit_rows(slab, slots, rows, interpret=True)
-    s, r = E.pad_slots(slots, rows, C, D, np.float32)
-    ref = jnp.asarray(slab).at[jnp.asarray(s)].set(jnp.asarray(r),
-                                                   mode="drop")
-    _assert_bytes_equal(got, ref, "embedding_admission")
-    untouched = np.setdiff1d(np.arange(C), slots)
-    _assert_bytes_equal(np.asarray(got)[untouched], slab[untouched],
-                        "embedding_admission untouched rows")
-
-
-def _parity_dgc_topk(rng):
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas.topk import blocked_topk_abs
-
-    x = jnp.asarray(rng.randn(1000).astype("float32"))
-    vals, idx = blocked_topk_abs(x, 16, block=128, interpret=True)
-    ref_v, _ref_i = jax.lax.top_k(jnp.abs(x), 16)
-    _assert_close_both_ways(vals, ref_v, "dgc_topk values", 1e-6, 0)
-    np.testing.assert_allclose(
-        np.abs(np.asarray(x))[np.asarray(idx)], np.asarray(vals),
-        rtol=1e-6)
-
-
-def _parity_sparse_update(rng):
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas.sparse_update import sparse_row_update
-
-    V, D, N = 50, 8, 6
-    p = jnp.asarray(rng.randn(V, D).astype("float32"))
-    ids = jnp.asarray(rng.choice(V, N, replace=False).astype("int32"))
-    rows = jnp.asarray(rng.randn(N, D).astype("float32"))
-    got = sparse_row_update(p, ids, rows, interpret=True)
-    ref = p.at[ids].add(rows)
-    _assert_close_both_ways(got, ref, "sparse_row_update", 1e-6, 1e-6)
 
 
 def _parity_remat(rng):
@@ -226,34 +204,15 @@ def _parity_remat(rng):
 
 register(KernelSpec(
     "flash_attention", ("scaled_dot_product_attention",), "tolerance",
-    _parity_flash,
+    _parity_flash, tpu_cases=_tpu_cases_flash,
     doc="tiled online-softmax attention, training fwd+bwd "
         "(ops/pallas/flash_attention.py)",
 ))
 register(KernelSpec(
     "cached_attention", ("cached_attention",), "bit", _parity_cached,
+    tpu_cases=_tpu_cases_cached,
     doc="fused [S,1] decode attention over a dense slotted cache "
         "(kernels/attention.py)",
-))
-register(KernelSpec(
-    "paged_attention", ("paged_attention",), "bit", _parity_paged,
-    doc="fused paged attention over the flat [R,H] block arenas; the "
-        "[S,L,H] gather view never reaches HBM (kernels/attention.py)",
-))
-register(KernelSpec(
-    "embedding_admission", ("__host_admission__",), "bit",
-    _parity_admission,
-    doc="on-device hot-slab miss admission scatter (kernels/embedding.py)",
-))
-register(KernelSpec(
-    "dgc_topk", ("dgc_momentum",), "tolerance", _parity_dgc_topk,
-    gated_by="pallas_dgc_topk",
-    doc="blocked top-|x| for DGC compaction (ops/pallas/topk.py)",
-))
-register(KernelSpec(
-    "sparse_row_update", ("sgd_sparse",), "tolerance",
-    _parity_sparse_update, gated_by="pallas_sparse_update",
-    doc="row-scatter sparse SGD update (ops/pallas/sparse_update.py)",
 ))
 register(KernelSpec(
     "remat_policy", ("recompute_segment", "recompute_segment_grad"),

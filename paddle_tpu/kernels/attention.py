@@ -1,35 +1,26 @@
-"""Fused decode attention: cached (slotted) and paged (block-arena) forms.
+"""Decode attention: the cached (slotted) and paged (block-arena) forms.
 
-Two Pallas TPU kernels serving the ``[S, 1]`` decode step (the hot path of
-``serving/decode/``), both written as ONE fused body so the per-layer
-attention never round-trips HBM between its stages:
+``cached_attention_composite`` / ``paged_attention_composite`` are THE
+definition of both ops' math — ops/nn.py's lowerings call them.
 
-* ``decode_attention`` — single-position attention of ``q`` ``[S, H]``
-  over a dense slotted cache ``[S, L, H]`` under the additive ``-1e9``
-  bias (the ``cached_attention`` composite, fused).
-* ``paged_attention`` — the PR-13 block-arena form: the kernel takes the
-  flat ``[R, H]`` row arenas and the ``[S * L]`` block row-index feed
-  DIRECTLY and gathers inside the kernel, so the dense ``[S, L, H]``
-  gather view (the composite's HBM intermediate — the gap between the
-  12.8x arena win and the 6.9x peak-HBM win in DECODE_EVIDENCE_r13) only
-  ever exists in VMEM. This is vLLM's PagedAttention read pattern
-  (Kwon et al., 2023) on the Mosaic pipeline.
+One Pallas TPU kernel serves the ``[S, 1]`` decode step of slotted models:
+``decode_attention`` — single-position attention of ``q`` ``[S, H]`` over a
+dense slotted cache ``[S, L, H]`` under the additive ``-1e9`` bias, written
+as ONE fused body (the composite verbatim) so the per-layer attention never
+round-trips HBM between its stages, and so interpret mode is BIT-identical
+to the composite (tests/test_kernels.py, tests/test_decode.py).
 
-Bit-exactness contract: each kernel body is the EXACT composite primitive
-sequence (``*_composite`` below — shared verbatim with the op registry's
-fallback lowering in ops/nn.py), so in interpret mode the Pallas call
-traces to the same jax primitives on the same shapes and the outputs are
-BIT-identical to the fallback — which is what keeps kernel-on decode
-byte-equal to kernel-off decode for every request in every mode
-(tests/test_kernels.py, tests/test_decode.py). Blocked/streamed variants
-(online softmax over KV blocks) would break that bit contract; they stay
-out until on-chip numbers arbitrate, the ops/pallas/ precedent.
+``paged_attention`` has NO kernel: the PR-15 body was the composite verbatim
+with an in-kernel ``jnp.take``, which Pallas cannot lower for TPU. The
+blocked kernel (grid over slot x kv block, block table in scalar prefetch,
+online softmax) is ROADMAP 1.5; until it lands the composite is the one
+path for paged models.
 
-Eligibility: the fused body wants its whole workset resident in VMEM
-(~16 MB/core). ``fits_vmem`` gates the compiled-TPU path per static
-shape; an oversized geometry (e.g. 32k-context arenas) falls back to the
-composite — the mandatory-fallback rule doing its job, counted in
-``kernel_fallbacks_total``.
+Eligibility: the fused body wants its whole workset resident in VMEM.
+``fits_vmem`` gates the compiled-TPU path per static shape on the INPUT
+bytes only (scores and the output are not counted) against ``VMEM_BUDGET``
+= 12 MiB, under Mosaic's 16 MiB default scoped-VMEM limit; an oversized
+geometry runs the composite, counted in ``kernel_fallbacks_total``.
 """
 
 import numpy as np
@@ -37,24 +28,17 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.kernels.registry import fallback_counter
 from paddle_tpu.ops.common import vma_names
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 __all__ = [
     "cached_attention_composite", "paged_attention_composite",
-    "decode_attention", "paged_attention", "fits_vmem",
+    "decode_attention", "fits_vmem",
 ]
 
-#: conservative per-kernel VMEM budget (bytes): ~16 MB/core minus
-#: double-buffering headroom
+#: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
 VMEM_BUDGET = 12 * 1024 * 1024
 
 
@@ -65,19 +49,9 @@ def fits_vmem(*arrays):
     return total <= VMEM_BUDGET
 
 
-def _fallback_counter():
-    from paddle_tpu.observability import metrics as obs_metrics
-
-    return obs_metrics.registry().counter(
-        "kernel_fallbacks_total",
-        "kernel-eligible ops that ran the composite fallback "
-        "(VMEM-oversized geometry or manual-mesh region)",
-    )
-
-
 # ---------------------------------------------------------------------------
 # the composite primitive sequences — THE definition of both ops' math.
-# ops/nn.py's fallback lowerings call these; the kernel bodies call these;
+# ops/nn.py's lowerings call these; the cached kernel body calls its one;
 # bit-identity between the two paths is by construction, not by test luck
 # (the tests then pin it).
 # ---------------------------------------------------------------------------
@@ -109,33 +83,20 @@ def paged_attention_composite(q, k_arena, v_arena, rows, bias, seqs,
 
 
 # ---------------------------------------------------------------------------
-# fused kernels
+# fused kernel
 # ---------------------------------------------------------------------------
 
 
-def _pallas_full_block(body, out_shape, args, interpret):
-    """One-program pallas_call over full-array blocks: the whole workset
-    is VMEM-resident (the eligibility gate guarantees it fits), the body
-    is the fused composite. No grid: decode worksets are small; the win
-    is fusion (no HBM between stages), not tiling."""
-    kw = {} if (interpret or _VMEM is None) else {"memory_space": _VMEM}
-    return pl.pallas_call(
-        body,
-        in_specs=[pl.BlockSpec(**kw) for _ in args],
-        out_specs=pl.BlockSpec(**kw),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-
-
 def decode_attention(q, k_cache, v_cache, bias, sm_scale, interpret=False):
-    """Fused ``[S, 1]`` cached attention. Falls back to the composite
-    when the workset cannot be VMEM-resident on the compiled path or the
-    call sits inside a manual (shard_map) region."""
+    """Fused ``[S, 1]`` cached attention: one program over full-array
+    VMEM blocks (no grid — decode worksets are small; the win is fusion,
+    not tiling). Falls back to the composite when the workset cannot be
+    VMEM-resident on the compiled path or the call sits inside a manual
+    (shard_map) region."""
     if vma_names(q) or (
         not interpret and not fits_vmem(q, k_cache, v_cache, bias)
     ):
-        _fallback_counter().inc()
+        fallback_counter().inc()
         return cached_attention_composite(q, k_cache, v_cache, bias,
                                           sm_scale)
 
@@ -144,39 +105,12 @@ def decode_attention(q, k_cache, v_cache, bias, sm_scale, interpret=False):
             q_ref[...], k_ref[...], v_ref[...], b_ref[...], sm_scale
         ).astype(o_ref.dtype)
 
-    return _pallas_full_block(
-        body, jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [q, k_cache, v_cache, bias], interpret,
-    )
-
-
-def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
-                    sm_scale, interpret=False):
-    """Fused paged attention over the flat ``[R, H]`` block arenas. The
-    row-index feed enters the kernel; the ``[S, L, H]`` gathered views
-    exist only inside it (VMEM), never as an HBM intermediate."""
-    seqs, length = int(seqs), int(length)
-    H = q.shape[-1]
-    if vma_names(q):
-        _fallback_counter().inc()
-        return paged_attention_composite(q, k_arena, v_arena, rows, bias,
-                                         seqs, length, sm_scale)
-    if not interpret:
-        # compiled path: arenas + both gathered views + scores in VMEM
-        gathered = 2 * seqs * length * H * jnp.dtype(q.dtype).itemsize
-        if not fits_vmem(q, k_arena, v_arena, bias) or \
-                gathered > VMEM_BUDGET // 2:
-            _fallback_counter().inc()
-            return paged_attention_composite(
-                q, k_arena, v_arena, rows, bias, seqs, length, sm_scale)
-
-    def body(q_ref, k_ref, v_ref, rows_ref, b_ref, o_ref):
-        o_ref[...] = paged_attention_composite(
-            q_ref[...], k_ref[...], v_ref[...], rows_ref[...], b_ref[...],
-            seqs, length, sm_scale,
-        ).astype(o_ref.dtype)
-
-    return _pallas_full_block(
-        body, jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [q, k_arena, v_arena, rows, bias], interpret,
-    )
+    kw = {} if interpret else {"memory_space": pltpu.VMEM}
+    return pl.pallas_call(
+        body,
+        in_specs=[pl.BlockSpec(**kw) for _ in range(4)],
+        out_specs=pl.BlockSpec(**kw),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="cached_attention",
+    )(q, k_cache, v_cache, bias)

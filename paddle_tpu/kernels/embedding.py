@@ -20,33 +20,28 @@ update retraces O(log capacity) times, not per batch shape. Both jits go
 through the ``core/lowering.py`` ``jit_compile`` chokepoint (compile
 counts stay observable) and are cached here under a lockdep-named lock.
 
-Kernel selection follows the registry: the composite scatter is
-``slab.at[slots].set(rows, mode="drop")``; under Pallas modes the same
-write runs as a row-loop kernel aliasing the slab buffer
-(``input_output_aliases``), which is the true in-place dynamic scatter on
-TPU. Rows move byte-for-byte on every path — admission is bit-identical
-across modes, capacities and ep counts (tools/bench_embedding.py
+The scatter is ``slab.at[slots].set(rows, mode="drop")`` under a donating
+jit — XLA updates the slab in place. Rows move byte-for-byte — admission is
+bit-identical across capacities and ep counts (tools/bench_embedding.py
 --smoke asserts it end to end).
 """
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from paddle_tpu.observability import lockdep
 
 __all__ = ["read_rows", "admit_rows", "admit_bucket", "pad_slots",
            "admission_roundtrip_counter"]
 
-_jit_cache = {}   # (kind, capacity, dim, bucket, dtype, interpret) -> fn
+_jit_cache = {}   # (kind, capacity, dim, bucket, dtype) -> fn
 _jit_lock = lockdep.named_lock("kernels.cache")
 
 
 def admission_roundtrip_counter():
-    """Host capacity-slab round-trips (the legacy admission path). The
-    KERNEL_EVIDENCE gate asserts this stays ZERO under device
+    """Host capacity-slab round-trips (the legacy admission path);
+    tests/test_kernels.py asserts this stays ZERO under device
     admission."""
     from paddle_tpu.observability import metrics as obs_metrics
 
@@ -84,34 +79,8 @@ def _scatter_composite(slab, slots, rows):
     return slab.at[slots].set(rows, mode="drop")
 
 
-def _scatter_pallas(slab, slots, rows, interpret):
-    """Row-loop scatter aliasing the slab buffer: only the admitted rows
-    are written; everything else IS the input buffer (in-place on TPU)."""
-    cap = slab.shape[0]
-    m = slots.shape[0]
-
-    def body(slab_ref, slots_ref, rows_ref, out_ref):
-        def write(i, _):
-            s = slots_ref[i]
-
-            @pl.when(s < cap)
-            def _():
-                out_ref[pl.ds(s, 1), :] = rows_ref[pl.ds(i, 1), :]
-
-            return 0
-
-        jax.lax.fori_loop(0, m, write, 0)
-
-    return pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct(slab.shape, slab.dtype),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(slab, slots, rows)
-
-
-def _get_jit(kind, capacity, dim, bucket, dtype, interpret):
-    key = (kind, capacity, dim, bucket, str(dtype), interpret)
+def _get_jit(kind, capacity, dim, bucket, dtype):
+    key = (kind, capacity, dim, bucket, str(dtype))
     with _jit_lock:
         fn = _jit_cache.get(key)
         if fn is not None:
@@ -120,14 +89,8 @@ def _get_jit(kind, capacity, dim, bucket, dtype, interpret):
 
     if kind == "gather":
         fn = jit_compile(lambda slab, slots: jnp.take(slab, slots, axis=0))
-    elif kind == "admit_composite":
-        fn = jit_compile(_scatter_composite, donate_argnums=(0,))
     else:
-        fn = jit_compile(
-            lambda slab, slots, rows: _scatter_pallas(
-                slab, slots, rows, interpret),
-            donate_argnums=(0,),
-        )
+        fn = jit_compile(_scatter_composite, donate_argnums=(0,))
     with _jit_lock:
         return _jit_cache.setdefault(key, fn)
 
@@ -140,27 +103,14 @@ def read_rows(slab, slots):
     # pad with slot 0 (sliced off below) so the gather shape is bucketed
     s = np.zeros((b,), dtype=np.int32)
     s[:n] = np.asarray(slots, dtype=np.int32)
-    fn = _get_jit("gather", slab.shape[0], slab.shape[1], b,
-                  slab.dtype, False)
+    fn = _get_jit("gather", slab.shape[0], slab.shape[1], b, slab.dtype)
     return np.asarray(fn(jnp.asarray(slab), jnp.asarray(s)))[:n]
 
 
-def admit_rows(slab, slots, rows, *, interpret=None):
+def admit_rows(slab, slots, rows):
     """Scatter the admitted rows into the slab ON DEVICE (donated).
-    ``interpret=None`` consults the kernel registry: composite scatter
-    unless the Pallas kernel is selected. Returns the updated device
-    slab."""
-    from paddle_tpu.kernels import registry
-
-    if interpret is None:
-        sel = registry.selected("embedding_admission")
-        kind = "admit_composite" if sel is None else "admit_pallas"
-        interp = bool(sel.interpret) if sel is not None else False
-    else:
-        kind = "admit_pallas"
-        interp = bool(interpret)
+    Returns the updated device slab."""
     slab = jnp.asarray(slab)   # device-commit so donation is real
     s, r = pad_slots(slots, rows, slab.shape[0], slab.shape[1], slab.dtype)
-    fn = _get_jit(kind, slab.shape[0], slab.shape[1], len(s), slab.dtype,
-                  interp)
+    fn = _get_jit("admit", slab.shape[0], slab.shape[1], len(s), slab.dtype)
     return fn(slab, jnp.asarray(s), jnp.asarray(r))

@@ -29,7 +29,10 @@ Registration is the CI contract: every ``KernelSpec`` MUST carry a
 ``parity_check`` callable — ``register()`` refuses one without it, and
 ``tests/test_kernels.py`` parametrizes over ``all_specs()``, so a new
 kernel cannot land without an interpret-mode parity test (the gate is
-enumerated from the registry, not from a hand-maintained list).
+enumerated from the registry, not from a hand-maintained list). A Pallas
+kernel must also carry ``tpu_cases``: interpret mode accepts programs
+Mosaic refuses, so ``tests/test_kernels_tpu_aot.py`` AOT-compiles each
+one's compiled path for the v5e, chip-free.
 
 The mode is PROCESS-global (it mirrors an environment variable);
 ``scoped_mode()`` swaps it for a ``with`` block — tests that lower under
@@ -44,7 +47,7 @@ from collections import namedtuple
 __all__ = [
     "KernelSpec", "register", "get", "all_specs", "has",
     "mode", "resolved_mode", "selected", "probe", "scoped_mode",
-    "kernel_sig", "registry_fingerprint", "MODE_ENV",
+    "kernel_sig", "registry_fingerprint", "fallback_counter", "MODE_ENV",
 ]
 
 MODE_ENV = "PADDLE_TPU_KERNELS"
@@ -69,20 +72,24 @@ class KernelSpec:
     ``kind``         — "kernel" (a Pallas lowering) or "policy" (an
                        IR-keyed remat policy: no Pallas body, still
                        enumerated so its bit-identity test is mandatory).
-    ``gated_by``     — legacy FLAGS name for kernels whose activation
-                       predates this registry (pallas_sparse_update,
-                       pallas_dgc_topk): the flag selects them, the
-                       registry only enumerates them for the parity gate.
+    ``tpu_cases``    — zero-arg callable returning ``[(label, fn,
+                       [(shape, dtype), ...]), ...]``: the COMPILED
+                       (``interpret=False``) path at realistic shapes.
+                       REQUIRED for kind "kernel":
+                       tests/test_kernels_tpu_aot.py AOT-compiles every
+                       case for a device-less v5e topology, so a kernel
+                       that interprets but does not lower for the chip
+                       cannot register.
     ``version``      — content version mixed into ``kernel_sig()``:
                        bump when the kernel's numerics change so cached
                        executables retrace.
     """
 
     __slots__ = ("name", "op_types", "doc", "parity", "parity_check",
-                 "kind", "gated_by", "version")
+                 "kind", "tpu_cases", "version")
 
     def __init__(self, name, op_types, parity, parity_check, doc="",
-                 kind="kernel", gated_by=None, version=1):
+                 kind="kernel", tpu_cases=None, version=1):
         if parity not in ("bit", "tolerance"):
             raise ValueError(f"kernel {name}: parity must be 'bit' or "
                              f"'tolerance', got {parity!r}")
@@ -92,13 +99,19 @@ class KernelSpec:
                 "every registered kernel must have an interpret-mode "
                 "parity test (the CI gate enumerates the registry)"
             )
+        if kind == "kernel" and not callable(tpu_cases):
+            raise ValueError(
+                f"kernel {name}: a tpu_cases callable is required — every "
+                "registered Pallas kernel must AOT-compile for the chip "
+                "(tests/test_kernels_tpu_aot.py enumerates the registry)"
+            )
         self.name = name
         self.op_types = tuple(op_types)
         self.doc = doc
         self.parity = parity
         self.parity_check = parity_check
         self.kind = kind
-        self.gated_by = gated_by
+        self.tpu_cases = tpu_cases
         self.version = int(version)
 
 
@@ -165,10 +178,9 @@ def resolved_mode():
 
 def selected(name):
     """Selection for one registered kernel under the current mode, or
-    None when its composite fallback should run. Flag-gated legacy
-    kernels are never selected here — their own FLAGS drive them."""
+    None when its composite fallback should run."""
     spec = _specs.get(name)
-    if spec is None or spec.gated_by is not None or spec.kind != "kernel":
+    if spec is None or spec.kind != "kernel":
         return None
     rm = resolved_mode()
     if rm == "off":
@@ -176,9 +188,24 @@ def selected(name):
     return Selection(name, rm == "interpret")
 
 
+def fallback_counter():
+    """``kernel_fallbacks_total``: selected kernels that gave way to their
+    composite by geometry (a workset Mosaic cannot tile or VMEM cannot
+    hold) or inside a manual-mesh region. chip_smoke.py prints it next to
+    the custom-call census so a run cannot pass on composites while
+    claiming kernels."""
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    return obs_metrics.registry().counter(
+        "kernel_fallbacks_total",
+        "kernel-eligible ops that ran the composite fallback "
+        "(untileable/VMEM-oversized geometry or manual-mesh region)",
+    )
+
+
 def probe(name):
-    """Would this kernel serve its op right now? (bench.py's live
-    ``extra.flash_attention`` probe.)"""
+    """Would this kernel serve its op right now? (bench.py turns flash
+    on by this.)"""
     return selected(name) is not None
 
 
@@ -206,12 +233,9 @@ class scoped_mode:
 
 def registry_fingerprint():
     """Pure content hash of the mode-selectable kernel set — which
-    kernels exist and their numeric versions (flag-gated legacy kernels
-    are covered by ``_LOWERING_FLAGS`` in the compile-cache fingerprint
-    already)."""
+    kernels exist and their numeric versions."""
     return sorted(
-        (s.name, s.version) for s in _specs.values()
-        if s.kind == "kernel" and s.gated_by is None
+        (s.name, s.version) for s in _specs.values() if s.kind == "kernel"
     )
 
 
@@ -220,8 +244,8 @@ def kernel_sig():
     fingerprint. None whenever every mode-selectable kernel resolves to
     its composite fallback ("off", or "auto" off-TPU) — so fingerprints
     of kernel-less lowerings stay byte-identical to pre-registry
-    revisions and an existing PADDLE_TPU_CACHE_DIR does not cold-miss on
-    deploy (the layout_sig discipline)."""
+    revisions and an existing compile-cache directory does not cold-miss
+    on deploy (the layout_sig discipline)."""
     rm = resolved_mode()
     if rm == "off":
         return None

@@ -703,11 +703,9 @@ def paged_attention(q, k_arena, v_arena, rows, attn_bias, seqs, length,
     """Fused paged attention: ``q`` ``[S, H]`` attends over rows of the
     flat ``[R, H]`` block arenas addressed by the ``[S * L]`` row feed —
     ``block_gather(k) ; block_gather(v) ; cached_attention`` as ONE op.
-    The reference lowering is that exact composite (bit-identical for
-    any block size); under ``PADDLE_TPU_KERNELS`` the registry serves it
-    with the fused Pallas kernel, where the dense ``[S, L, H]`` gather
-    views live only in VMEM instead of materializing in HBM (the
-    analysis/memory.py accounting difference KERNEL_EVIDENCE commits)."""
+    The lowering is that exact composite (bit-identical for any block
+    size); the dense ``[S, L, H]`` gather views materialize in HBM until
+    the blocked kernel of ROADMAP 1.5 serves this op."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     helper.append_op(

@@ -29,11 +29,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.parallel.ring import ring_attention_local
-
-from paddle_tpu.parallel.env import shard_map as _shard_map
 from paddle_tpu.parallel.moe import moe_ffn_local
 from paddle_tpu.parallel.pipeline import pipeline_apply, split_microbatches
 

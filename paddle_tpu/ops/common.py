@@ -8,13 +8,9 @@ from paddle_tpu.core.dtypes import to_numpy_dtype
 
 def vma_names(x):
     """Manual-mesh-axis (shard_map 'vma') names of x's abstract value, as
-    a frozenset. jax.typeof only exists on newer jax releases and pre-vma
-    avals have no .vma attribute — on those, the empty set is correct
-    (nothing can be inside a manual region whose machinery doesn't
-    exist). One compat seam instead of per-site getattr chains."""
-    typeof = getattr(jax, "typeof", None)
-    aval = typeof(x) if typeof is not None else jax.core.get_aval(x)
-    return getattr(aval, "vma", None) or frozenset()
+    a frozenset: non-empty exactly when x is traced inside a shard_map
+    region."""
+    return jax.typeof(x).vma
 
 
 def first(ins, slot):

@@ -18,10 +18,9 @@ to the objective.
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 
 from paddle_tpu.core.registry import register_op
-
-from paddle_tpu.parallel.env import shard_map as _shard_map
 from paddle_tpu.ops.common import first, vma_names
 from paddle_tpu.utils.enforce import EnforceError
 

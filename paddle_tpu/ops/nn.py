@@ -7,6 +7,8 @@ convs hit the MXU via lax.conv_general_dilated, norms/activations fuse into
 their neighbors under whole-block XLA compilation.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -448,14 +450,12 @@ def _sdpa_seq_parallel(ins, attrs):
 def _sdpa_reference(ins, attrs):
     """Unfused attention (XLA-fused path): q,k,v [B,H,S,D], optional additive
     key bias [B,S]."""
-    import math as _math
-
     sp = _sdpa_seq_parallel(ins, attrs)
     if sp is not None:
         return sp
     q, k, v = first(ins, "Q"), first(ins, "K"), first(ins, "V")
     bias = first(ins, "Bias") if ins.get("Bias") else None
-    scale = attrs.get("sm_scale") or 1.0 / _math.sqrt(q.shape[-1])
+    scale = attrs.get("sm_scale") or 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if bias is not None:
         s = s + bias[:, None, None, :]
@@ -467,9 +467,44 @@ def _sdpa_reference(ins, attrs):
     return {"Out": [jnp.einsum("bhqk,bhkd->bhqd", p, v)]}
 
 
+def _flash_per_shard(mesh, q, k, v, bias, **kw):
+    """GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so under a multi-device mesh the compiled kernel runs
+    per shard: batch over the data axes ('dcn' x 'data') and heads over
+    the tensor-parallel axis (the Megatron split) where they divide,
+    every other axis replicated; GSPMD reshards around the region if the
+    operands arrive laid out otherwise."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.parallel.spec_layout import TP_AXIS_NAMES
+
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+
+    def axes_for(names, dim):
+        """The named axes the mesh has, as long as they divide `dim`."""
+        got = [a for a in names if sizes.get(a, 1) > 1]
+        while got and dim % math.prod(sizes[a] for a in got):
+            got.pop(0)
+        return tuple(got) or None
+
+    batch = axes_for(("dcn", "data"), q.shape[0])
+    spec = P(batch, axes_for(TP_AXIS_NAMES, q.shape[1]), None, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if bias is not None:
+        args, in_specs = args + (bias,), in_specs + (P(batch, None),)
+
+    def local(q, k, v, *b):
+        return flash_attention(q, k, v, bias=b[0] if b else None, **kw)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec)(*args)
+
+
 def _sdpa_pallas(ins, attrs):
     from paddle_tpu.kernels import registry as kernel_registry
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.parallel import env as penv
 
     sp = _sdpa_seq_parallel(ins, attrs)
     if sp is not None:
@@ -481,16 +516,13 @@ def _sdpa_pallas(ins, attrs):
         return _sdpa_reference(ins, attrs)
     q, k, v = first(ins, "Q"), first(ins, "K"), first(ins, "V")
     bias = first(ins, "Bias") if ins.get("Bias") else None
-    return {
-        "Out": [
-            flash_attention(
-                q, k, v, bias=bias,
-                causal=attrs.get("causal", False),
-                sm_scale=attrs.get("sm_scale"),
-                interpret=sel.interpret,
-            )
-        ]
-    }
+    kw = dict(causal=attrs.get("causal", False),
+              sm_scale=attrs.get("sm_scale"), interpret=sel.interpret)
+    mesh = penv.current_mesh()
+    if (mesh is not None and mesh.devices.size > 1 and not sel.interpret
+            and not vma_names(q)):
+        return {"Out": [_flash_per_shard(mesh, q, k, v, bias, **kw)]}
+    return {"Out": [flash_attention(q, k, v, bias=bias, **kw)]}
 
 
 OpRegistry.register(
@@ -556,26 +588,10 @@ def _paged_attention_reference(ins, attrs):
         attrs.get("sm_scale", 1.0))]}
 
 
-def _paged_attention_pallas(ins, attrs):
-    from paddle_tpu.kernels import attention as fused
-    from paddle_tpu.kernels import registry as kernel_registry
-
-    sel = kernel_registry.selected("paged_attention")
-    if sel is None:
-        return _paged_attention_reference(ins, attrs)
-    q = first(ins, "Q")
-    ka, va = first(ins, "KArena"), first(ins, "VArena")
-    rows, bias = first(ins, "Rows"), first(ins, "Bias")
-    return {"Out": [fused.paged_attention(
-        q, ka, va, rows, bias, attrs["seqs"], attrs["length"],
-        attrs.get("sm_scale", 1.0), interpret=sel.interpret)]}
-
-
 OpRegistry.register(
     OpDef(
         "paged_attention",
         _paged_attention_reference,
-        pallas=_paged_attention_pallas,
         nondiff_inputs=("Rows", "Bias"),
     )
 )

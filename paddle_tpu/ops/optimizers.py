@@ -46,31 +46,6 @@ def _sgd_sparse(ins, attrs):
         # the forward zeroed padding rows, so their grads must not land
         rows2 = jnp.where((ids == pi)[:, None], 0.0, rows2)
     scaled = -(lr.astype(p.dtype)) * rows2
-    from paddle_tpu.utils.flags import flags as _flags
-
-    if _flags.pallas_sparse_update:
-        # duplicate-merge in XLA (a tiny [n_tokens, D] scatter), then the
-        # Pallas one-row-per-step kernel writes the touched param rows —
-        # flag-gated until on-chip numbers arbitrate (SURVEY §7)
-        from paddle_tpu.ops.pallas.sparse_update import sparse_row_update
-
-        n = ids.shape[0]
-        uniq, inv, counts = jnp.unique(
-            ids, return_inverse=True, return_counts=True, size=n,
-            fill_value=0,
-        )
-        merged = jnp.zeros((n, d), p.dtype).at[inv.reshape(-1)].add(scaled)
-        # fill slots duplicate id 0 with a zero row. They must run BEFORE
-        # the real id-0 slot in the kernel's sequential grid: a zero-add
-        # step writes the row's CURRENT value back, so a pad step ordered
-        # after the real update could, under pipelined prefetch, clobber
-        # it with the stale pre-update row. Pads-first ordering makes
-        # every pad write the untouched original value — race-free.
-        is_fill = counts == 0
-        perm = jnp.argsort(jnp.where(is_fill, -1, jnp.arange(n)))
-        return {
-            "ParamOut": [sparse_row_update(p, uniq[perm], merged[perm])]
-        }
     return {
         "ParamOut": [p.at[ids].add(scaled)],
     }
@@ -466,16 +441,7 @@ def _dgc_momentum(ins, attrs):
         ))))))
         v_acc = (v + contrib).reshape(-1)
         mag = jnp.abs(v_acc)
-        from paddle_tpu.utils.flags import flags as _flags
-
-        if _flags.pallas_dgc_topk:
-            # blocked VMEM-streaming top-k (ops/pallas/topk.py); falls
-            # back to lax.top_k off-TPU inside shard_map
-            from paddle_tpu.ops.pallas.topk import blocked_topk_abs
-
-            _, top_idx = blocked_topk_abs(v_acc, k_max)
-        else:
-            _, top_idx = lax.top_k(mag, k_max)                # [k]
+        _, top_idx = lax.top_k(mag, k_max)                # [k]
         k_dyn = jnp.round(size * (1.0 - ratio)).astype(jnp.int32)
         keep = (jnp.arange(k_max) < jnp.maximum(k_dyn, 1)).astype(v_acc.dtype)
         vals = v_acc[top_idx] * keep

@@ -15,6 +15,15 @@ normalizer, accumulator) in registers — FLOPs land on the MXU, the running
 state on the VPU.
 
 On non-TPU backends the same kernel runs in Pallas interpret mode (tests).
+
+Mosaic constraints this file is written against (checked chip-free by
+tests/test_kernels_tpu_aot.py): the per-row statistics (lse, delta) and the
+key bias live lane-major in HBM as ``(bh, 1, S)`` rows and are sliced along
+lanes in 128-multiples, so the compiled path wants ``S % 128 == 0`` (other
+lengths run ``_jnp_attention``, counted in ``kernel_fallbacks_total``); a row
+becomes a column as an f32 ``x[:, None]`` — Mosaic relayouts 32-bit vectors
+but refuses the same reshape on an i1 mask, so masks are compared AFTER the
+reshape.
 """
 
 import functools
@@ -23,20 +32,26 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.common import vma_names
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 __all__ = ["flash_attention"]
 
 _NEG = -1e30
+
+
+def _dot(a, b, a_dim, b_dim):
+    """MXU contraction in the operand dtype with f32 accumulation. bf16
+    operands are exact on the MXU, and Mosaic rejects the fp32 contract
+    precision a process-wide ``jax_default_matmul_precision`` would
+    otherwise attach to them — so they pin DEFAULT."""
+    return jax.lax.dot_general(
+        a, b, (((a_dim,), (b_dim,)), ((), ())),
+        precision=(jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16
+                   else None),
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
@@ -52,10 +67,7 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         m, l, acc = carry
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # (BQ, BK) f32
+        s = _dot(q, k, 1, 1) * sm_scale  # (BQ, BK) f32
         if bias_ref is not None:
             s = s + bias_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
         if causal:
@@ -70,10 +82,7 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_new = acc * alpha[:, None] + _dot(p.astype(v.dtype), v, 1, 0)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((block_q,), _NEG, jnp.float32)
@@ -117,10 +126,7 @@ def _sds(shape, dtype, *refs):
     for r in refs:
         vma |= vma_names(r)
     if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        except TypeError:  # pragma: no cover - older jax without vma kwarg
-            pass
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
@@ -133,7 +139,7 @@ def _fwd_impl(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
     k3 = k.reshape(bh, S, D)
     v3 = v.reshape(bh, S, D)
     grid = (bh, S // block_q)
-    kw = dict(memory_space=_VMEM) if (_VMEM is not None and not interpret) else {}
+    kw = {} if interpret else dict(memory_space=pltpu.VMEM)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0), **kw),
         pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0), **kw),
@@ -177,6 +183,7 @@ def _fwd_impl(q, k, v, bias, sm_scale, causal, block_q, block_k, interpret):
             _sds((bh, 1, S), jnp.float32, q3, k3, v3),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     return out.reshape(B, H, S, D), lse.reshape(B, H, S)
 
@@ -214,10 +221,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref, delta_ref,
         g = g_ref[0, pl.ds(i * block_q, block_q), :]
         lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)]
         delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # (BQ, BK) f32
+        s = _dot(q, k, 1, 1) * sm_scale  # (BQ, BK) f32
         if bias_ref is not None:
             s = s + bias_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
         if causal:
@@ -227,22 +231,12 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref, delta_ref,
             s = jnp.where(cols <= rows, s, _NEG)
         # fully-masked rows have lse == _NEG: their fwd output was 0, so
         # their gradient contribution must be 0, not exp(s - _NEG)
-        p = jnp.where(
-            (lse <= _NEG / 2)[:, None], 0.0, jnp.exp(s - lse[:, None])
-        )  # (BQ, BK)
-        dv_new = dv + jax.lax.dot_general(
-            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        lse_col = lse[:, None]
+        p = jnp.where(lse_col <= _NEG / 2, 0.0, jnp.exp(s - lse_col))
+        dv_new = dv + _dot(p.astype(g.dtype), g, 0, 0)
+        dp = _dot(g, v, 1, 1)
         ds = p * (dp - delta[:, None])
-        dk_new = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        dk_new = dk + _dot(ds.astype(q.dtype), q, 0, 0) * sm_scale
         dbias_new = dbias + ds.sum(axis=0)
         return dk_new, dv_new, dbias_new
 
@@ -269,8 +263,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref, delta_ref,
     # dots in input dtype, f32 accumulation (see _attention_kernel)
     q = q_ref[0]
     g = g_ref[0]
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse_col = lse_ref[0, 0][:, None]
+    delta_col = delta_ref[0, 0][:, None]
     rows = i * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -278,10 +272,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref, delta_ref,
     def body(j, dq):
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        s = _dot(q, k, 1, 1) * sm_scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
         if causal:
@@ -289,18 +280,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 1
             )
             s = jnp.where(cols <= rows, s, _NEG)
-        p = jnp.where(
-            (lse <= _NEG / 2)[:, None], 0.0, jnp.exp(s - lse[:, None])
-        )
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        p = jnp.where(lse_col <= _NEG / 2, 0.0, jnp.exp(s - lse_col))
+        dp = _dot(g, v, 1, 1)
+        ds = p * (dp - delta_col)
+        return dq + _dot(ds.astype(k.dtype), k, 1, 0) * sm_scale
 
     dq0 = jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
     nk = seq_len // block_k
@@ -331,7 +314,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
     g3 = g.reshape(bh, S, D)
     lse3 = lse.reshape(bh, 1, S)
     delta3 = delta.reshape(bh, 1, S)
-    kw = dict(memory_space=_VMEM) if (_VMEM is not None and not interpret) else {}
+    kw = {} if interpret else dict(memory_space=pltpu.VMEM)
     full = lambda: pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0), **kw)
     row = lambda: pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0), **kw)
     has_bias = bias is not None
@@ -382,11 +365,18 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
         out_specs=kv_out_specs,
         out_shape=kv_out_shapes,
         interpret=interpret,
+        name="flash_attention_bwd_dkdv",
     )(*args)
     dk3, dv3 = outs[0], outs[1]
-    dbias = (
-        outs[2].reshape(B, H, S).sum(axis=1) if has_bias else None
-    )
+    dbias = None
+    if has_bias:
+        dbias = outs[2].reshape(B, H, S).sum(axis=1)
+        # inside a shard_map that splits heads the bias is shared by the
+        # head shards, so its cotangent sums over the axes q varies on
+        # and the bias does not
+        head_axes = tuple(vma_names(q) - vma_names(bias))
+        if head_axes:
+            dbias = jax.lax.psum(dbias, head_axes)
 
     # ---- dq ----------------------------------------------------------
     dq_in_specs = [
@@ -423,6 +413,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0), **kw),
         out_shape=_sds((bh, S, D), q.dtype, q3, k3, v3, g3),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_args)
 
     return (
@@ -456,6 +447,12 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     if interpret and vma_names(q):
         return _jnp_attention(q, k, v, bias, float(sm_scale), bool(causal))
     S = q.shape[2]
+    if not interpret and S % 128:
+        # Mosaic slices the lane-major bias/lse rows in 128-multiples only
+        from paddle_tpu.kernels.registry import fallback_counter
+
+        fallback_counter().inc()
+        return _jnp_attention(q, k, v, bias, float(sm_scale), bool(causal))
     bq = min(block_q, S)
     bk = min(block_k, S)
     while S % bq:

@@ -20,11 +20,10 @@ pipeline, which is what makes single-device parity tests possible.
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.core.registry import register_op
-
-from paddle_tpu.parallel.env import shard_map as _shard_map
 from paddle_tpu.utils.enforce import EnforceError
 
 
@@ -185,6 +184,5 @@ def _pipeline_stack(ins, attrs):
         mesh=mesh,
         in_specs=(x_spec, P(stage_axis), in_param_specs, ex_specs),
         out_specs=x_spec,
-        body_has_pallas=True,  # stage bodies may lower sdpa through Pallas
     )(x, layer_ids, tuple(stacked), tuple(ex.values()))
     return {"Out": [out]}

@@ -23,9 +23,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-from paddle_tpu.parallel.env import shard_map as _shard_map
 
 
 def dgc_exchange_local(grad, residual, k, axis_name):

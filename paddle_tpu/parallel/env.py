@@ -20,32 +20,6 @@ _bindings = {}
 _current_mesh = None
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None,
-              body_has_pallas=False):
-    """jax.shard_map across jax releases: newer jax exposes it at the top
-    level (with `check_vma`), older releases only under jax.experimental
-    (where the same switch is spelled `check_rep`). Every shard_map in
-    this codebase routes through here so the compat seam is one line per
-    release change.
-
-    `body_has_pallas=True` marks bodies that run Pallas kernels: the new
-    vma checker handles them via annotated out_shapes (_sds), but the
-    legacy replication checker has no pallas_call rule at all — on old
-    jax such bodies must run with check_rep=False."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return native(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    if body_has_pallas and check_vma is None:
-        check_vma = False
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-
-
 @contextlib.contextmanager
 def mesh_context(mesh):
     """Install the mesh a Program is being compiled against, so op

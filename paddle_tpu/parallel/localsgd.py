@@ -20,9 +20,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-from paddle_tpu.parallel.env import shard_map as _shard_map
 
 
 def replicate_for_localsgd(params, n_replicas):
@@ -55,15 +54,14 @@ def localsgd_step_fn(grad_fn, optimizer_update, axis_name="data",
         # collective every step, erasing the 1/k bandwidth saving that is
         # the whole point; the predicate is replicated (derived from the
         # shared step counter) so all shards take the same branch
-        # pvary re-marks the (replicated) mean as axis-varying so both
-        # branches carry the same device-variance type under shard_map;
-        # older jax has no pvary (and no vma types to reconcile) — the
-        # mean is used as-is there
-        pvary = getattr(lax, "pvary", lambda x, _axes: x)
+        # pcast re-marks the (replicated) mean as axis-varying so both
+        # branches carry the same device-variance type under shard_map
         synced = lax.cond(
             do_sync,
             lambda ps: jax.tree.map(
-                lambda p: pvary(lax.pmean(p, axis_name), axis_name), ps
+                lambda p: lax.pcast(
+                    lax.pmean(p, axis_name), axis_name, to="varying"),
+                ps,
             ),
             lambda ps: ps,
             new_p,

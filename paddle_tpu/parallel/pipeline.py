@@ -18,18 +18,10 @@ from jax import lax
 
 
 def _vary(x, axis):
-    """pvary x over `axis` unless it already varies over it."""
-    # inline typeof/get_aval compat (ops.common.vma_names would pull the
-    # whole op library into this low-level module)
-    typeof = getattr(jax, "typeof", None)
-    aval = typeof(x) if typeof is not None else jax.core.get_aval(x)
-    if axis in (getattr(aval, "vma", None) or frozenset()):
+    """Mark x as varying over `axis` unless it already varies over it."""
+    if axis in jax.typeof(x).vma:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis,))
-    return x  # pre-vma jax: nothing to re-mark
+    return lax.pcast(x, axis, to="varying")
 
 
 def pipeline_apply(block_fn, stacked_params, x_mb, stage_axis,

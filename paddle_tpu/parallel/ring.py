@@ -15,9 +15,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-from paddle_tpu.parallel.env import shard_map as _shard_map
 
 
 def _online_step(q, k_blk, v_blk, acc, m, l, scale, mask):

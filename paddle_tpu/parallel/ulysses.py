@@ -13,9 +13,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-from paddle_tpu.parallel.env import shard_map as _shard_map
 
 
 def _full_attention(q, k, v, scale, causal):

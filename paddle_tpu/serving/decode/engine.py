@@ -48,11 +48,11 @@ the picker skip, not reject) and WEIGHTED-FAIR selection layered over the
 queue's strict priority lanes (stride scheduling).
 
 Cold start: the executables per entry lower through ``core/lowering.py``
-into the content-addressed compile cache. With ``PADDLE_TPU_CACHE_DIR``
-set, a fresh replica (or the circuit breaker's relaunched replacement)
-restores them from the ``jax.export`` disk tier with ZERO traces —
-subprocess-asserted in tests/test_decode.py. Before anything compiles,
-the paged arena is sized against the peak-HBM budget via
+into the content-addressed compile cache. With a populated cache
+directory, a fresh replica (or the circuit breaker's relaunched
+replacement) restores them from the ``jax.export`` disk tier with ZERO
+traces — subprocess-asserted in tests/test_decode.py. Before anything
+compiles, the paged arena is sized against the peak-HBM budget via
 ``analysis/memory.py`` — an oversized block pool fails with sizing
 advice, not an XLA OOM.
 """
@@ -2120,10 +2120,7 @@ class GenerationEngine:
         import paddle_tpu as fluid
 
         if place is None:
-            import jax
-
-            place = (fluid.TPUPlace(0) if jax.default_backend() == "tpu"
-                     else fluid.CPUPlace())
+            place = fluid.TPUPlace(0)
         self.place = place
         self.device = place.jax_device()
         GenerationEngine._SEQ += 1

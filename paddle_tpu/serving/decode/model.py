@@ -240,13 +240,11 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
 
     ``fused_attention`` (default True) routes the decode step's
     attention through ONE ``paged_attention`` op — the row-index feeds
-    enter the op directly, so the kernel registry
-    (paddle_tpu/kernels/) can serve it with a fused Pallas kernel that
-    never materializes the dense ``[S, L, H]`` gather view in HBM. The
-    op's reference lowering is the exact gather+attention composite, so
-    tokens are BIT-identical to ``fused_attention=False`` (the pre-r15
-    op sequence, kept for the DECODE_EVIDENCE_r13 static recompute)
-    with kernels on or off.
+    enter the op directly, which is the seam the blocked paged kernel
+    of ROADMAP 1.5 will serve. Today the op lowers to the exact
+    gather+attention composite, so tokens are BIT-identical to
+    ``fused_attention=False`` (the pre-r15 op sequence, kept for the
+    DECODE_EVIDENCE_r13 static recompute).
     """
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard

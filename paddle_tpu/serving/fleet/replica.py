@@ -278,7 +278,8 @@ class SubprocessReplica:
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (repo, env.get("PYTHONPATH")) if p)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the worker runs on the platform its spawner states: the
+        # inherited JAX_PLATFORMS or ``extra_env``; nothing defaults it
         env.update(extra_env or {})
         specs = (list(model_args) if isinstance(model_args, (list, tuple))
                  else [model_args])

@@ -3,8 +3,8 @@
 Run as ``python -m paddle_tpu.serving.fleet.worker --index N ...``: the
 worker builds the canonical cached-attention decoder from its CLI
 geometry, registers it with a GenerationEngine (compile cache dir from
-``PADDLE_TPU_CACHE_DIR`` — a warm disk tier means the worker is
-serving-ready with ZERO traces), prints one ``FLEET_WORKER_READY``
+``core/compile_cache.py cache_dir()`` — a warm disk tier means the
+worker is serving-ready with ZERO traces), prints one ``FLEET_WORKER_READY``
 JSON line naming its port and compile sources, and serves the router's
 length-prefixed JSON RPC on a single connection.
 
@@ -35,8 +35,6 @@ import os
 import socket
 import sys
 import time
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _send(conn, obj):

@@ -94,12 +94,6 @@ define_flag(
     "analog): the [V, D] dense embedding gradient never materializes",
 )
 define_flag(
-    "pallas_sparse_update", False,
-    "serve sgd_sparse row-scatter through the Pallas kernel "
-    "(ops/pallas/sparse_update.py) instead of the XLA scatter; "
-    "interpret-tested, flag-gated until on-chip numbers arbitrate",
-)
-define_flag(
     "static_diagnostics", "",
     "opt-in static-analysis stages run ahead of the mandatory verifier "
     "in core/lowering.py: comma list of 'shapes', 'sharding', 'memory', "
@@ -120,10 +114,4 @@ define_flag(
     "machine model for the 'cost' static diagnostic stage "
     "(analysis/cost.py MACHINES: tpu-v4-8, tpu-v5e-8, tpu-v5p-8, "
     "tpu-v6e-8, cpu-host)",
-)
-define_flag(
-    "pallas_dgc_topk", False,
-    "use the blocked Pallas top-k (ops/pallas/topk.py) for DGC gradient "
-    "compaction instead of lax.top_k; interpret-tested, flag-gated until "
-    "on-chip numbers arbitrate",
 )

@@ -90,6 +90,30 @@ def lower_parallel_step(exe, compiled_program, feed, fetch_list, scope):
     return lowered, compiled_program._mesh
 
 
+_PALLAS_NAME = re.compile(r'op_name="[^"]*?(\w+)\)*/pallas_call"')
+_FIRST_SHAPE = re.compile(r"\b\w+\[[\d,]*\]")
+
+
+def pallas_custom_calls(hlo_text):
+    """Census of the Pallas (Mosaic) kernels in OPTIMIZED HLO text:
+    ``{kernel name: {"count": n, "result": first result shape}}``, the
+    name being the ``pallas_call(name=...)`` each kernel in this repo
+    sets. Read from the executable that ran, this is what tells "the
+    flash kernel served forward and backward" apart from "a composite
+    ran while the registry said tpu" (chip_smoke.py)."""
+    out = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _PALLAS_NAME.search(line)
+        name = m.group(1) if m else "unnamed"
+        shape = _FIRST_SHAPE.search(line.split("custom-call(")[0])
+        rec = out.setdefault(
+            name, {"count": 0, "result": shape.group(0) if shape else None})
+        rec["count"] += 1
+    return out
+
+
 # ---------------------------------------------------------------------------
 # shared step builders — tests/test_hlo.py AND tools/hlo_report.py lower
 # through these, so the committed evidence is generated by the exact

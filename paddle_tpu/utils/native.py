@@ -9,6 +9,7 @@ is present — every native component keeps a pure-Python fallback.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,8 +27,8 @@ class NativeBuildError(RuntimeError):
 
 
 def load_native(component, source=None, extra_flags=()):
-    """Build (if stale) and dlopen csrc/<component>/<component>.so. Returns a
-    ctypes.CDLL, or raises NativeBuildError."""
+    """Build (if stale) and dlopen csrc/<component>/lib<component>.so.
+    Returns a ctypes.CDLL, or raises NativeBuildError."""
     with _lock:
         if component in _cache:
             return _cache[component]
@@ -35,10 +36,19 @@ def load_native(component, source=None, extra_flags=()):
         out = os.path.join(_CSRC, component, f"lib{component}.so")
         if not os.path.exists(src):
             raise NativeBuildError(f"no source for native component {component}")
-        if (
-            not os.path.exists(out)
-            or os.path.getmtime(out) < os.path.getmtime(src)
-        ):
+        # staleness is keyed on a hash of the source and flags stored
+        # beside the .so, not on mtimes: a copy of the tree (the chip
+        # tool's, a fresh checkout over old binaries) resets mtimes and
+        # would make a stale binary look fresh
+        with open(src, "rb") as f:
+            want = hashlib.sha256(
+                f.read() + "\0".join(extra_flags).encode()).hexdigest()
+        stamp = out + ".srchash"
+        have = None
+        if os.path.exists(out) and os.path.exists(stamp):
+            with open(stamp) as f:
+                have = f.read().strip()
+        if have != want:
             # compile to a per-process temp and rename atomically: concurrent
             # launch_procs workers may race to build the same component, and
             # dlopen of a half-written .so is a crash
@@ -60,6 +70,9 @@ def load_native(component, source=None, extra_flags=()):
                     f"native build of {component} failed:\n{proc.stderr[-2000:]}"
                 )
             os.replace(tmp, out)
+            with open(tmp, "w") as f:
+                f.write(want)
+            os.replace(tmp, stamp)
         lib = ctypes.CDLL(out)
         _cache[component] = lib
         return lib

@@ -3,7 +3,7 @@
 Builds a deterministic small train program, runs a few steps, and prints
 one JSON line with the fetched losses (exact reprs, for bit-identity
 comparison across processes) and the compile counters — the parent test
-asserts a second process with a populated ``PADDLE_TPU_CACHE_DIR``
+asserts a second process with a populated ``JAX_COMPILATION_CACHE_DIR``
 reports ZERO traces (``executor_cache_misses_total`` and the
 ``executor_compile_seconds`` observation count both 0), and that
 poisoned/truncated cache entries silently fall back to a retrace with
@@ -19,6 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+import jax
 import numpy as np
 
 import paddle_tpu as fluid
@@ -69,6 +70,7 @@ def main():
         "persistent_errors": val("compile_cache_persistent_errors_total"),
         "compile_observations":
             compile_hist.count if compile_hist is not None else 0,
+        "jax_cache_dir": jax.config.jax_compilation_cache_dir,
     }))
     return 0
 

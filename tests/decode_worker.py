@@ -1,7 +1,7 @@
 """Subprocess worker for the decode engine's AOT warm-start tests.
 
 Builds the canonical cached-attention decoder, registers it with a
-GenerationEngine (compile cache dir from ``PADDLE_TPU_CACHE_DIR``),
+GenerationEngine (compile cache dir from ``JAX_COMPILATION_CACHE_DIR``),
 serves a fixed prompt set, and prints one JSON line: where each of the
 three executables came from (``compile_sources``), the process-wide
 trace/compile counters, and the generated tokens (exact ints, for

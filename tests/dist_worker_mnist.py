@@ -26,7 +26,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "float32")
 
 import paddle_tpu as fluid

@@ -17,7 +17,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "float32")
 
 import paddle_tpu as fluid

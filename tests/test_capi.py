@@ -57,7 +57,7 @@ def test_capi_smoke_from_c_host(tmp_path, rng, capi_lib):
 
     batch, feat = 3, 6
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"  # embedded interpreter must not probe TPU
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [exe_path, model_dir, str(batch), str(feat)],
         capture_output=True, text=True, timeout=600, env=env,
@@ -100,7 +100,7 @@ def test_go_binding_compiles(tmp_path, rng, capi_lib):
     model_dir, _ = _save_model(tmp_path, rng)
     godir = os.path.join(REPO, "go", "paddle")
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["CGO_CFLAGS"] = f"-I{os.path.dirname(capi_lib)}"
     env["CGO_LDFLAGS"] = (
         f"-L{os.path.dirname(capi_lib)} -lcapi "
@@ -143,7 +143,7 @@ def test_capi_train_from_c_host(tmp_path, capi_lib):
     assert build.returncode == 0, build.stderr
     save_dir = os.path.join(str(tmp_path), "saved")
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [exe_path, model_dir, "20", save_dir],
         capture_output=True, text=True, timeout=300, env=env,

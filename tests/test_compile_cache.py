@@ -28,10 +28,10 @@ WORKER = os.path.join(REPO, "tests", "compile_cache_worker.py")
 
 def _run_worker(cache_dir=None, hidden=16):
     env = dict(os.environ)
-    env.pop("PADDLE_TPU_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["JAX_PLATFORMS"] = "cpu"
     if cache_dir is not None:
-        env["PADDLE_TPU_CACHE_DIR"] = str(cache_dir)
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     proc = subprocess.run(
         [sys.executable, WORKER, "--hidden", str(hidden)],
         env=env, capture_output=True, text=True, timeout=300,
@@ -42,7 +42,7 @@ def _run_worker(cache_dir=None, hidden=16):
 
 def _entries(cache_dir):
     return sorted(
-        f for f in os.listdir(cache_dir) if f.endswith(".ptcc")
+        f for f in os.listdir(cache_dir / "ptcc") if f.endswith(".ptcc")
     )
 
 
@@ -62,6 +62,14 @@ def test_cross_process_warm_start(tmp_path):
     cold = _run_worker(cache_dir=cache)
     assert cold["traces"] == baseline["traces"]
     assert _entries(cache), "populate run wrote no cache entries"
+    # placement: jax read JAX_COMPILATION_CACHE_DIR natively and nothing
+    # re-pointed it — XLA's own tier sits in the directory itself, the
+    # jax.export tier in ptcc/ under it, and an unplaced CPU process
+    # (the baseline) persisted nowhere
+    assert cold["jax_cache_dir"] == str(cache)
+    assert baseline["jax_cache_dir"] is None
+    assert any(f.endswith("-cache") for f in os.listdir(cache))
+    assert sorted(os.listdir(tmp_path)) == ["cache"]
     # cache enabled vs disabled must not change a single bit
     assert cold["losses"] == baseline["losses"]
 
@@ -82,19 +90,20 @@ def test_poisoned_cache_entries_fall_back_to_retrace(tmp_path):
     assert len(entries) >= 2  # startup + main step
 
     # bit-rot in the payload of the first entry
-    p0 = cache / entries[0]
+    p0 = cache / "ptcc" / entries[0]
     raw = bytearray(p0.read_bytes())
     raw[-8] ^= 0xFF
     p0.write_bytes(bytes(raw))
     # torn write on the second
-    p1 = cache / entries[1]
+    p1 = cache / "ptcc" / entries[1]
     p1.write_bytes(p1.read_bytes()[: max(8, len(p1.read_bytes()) // 3)])
 
     poisoned = _run_worker(cache_dir=cache)
     assert poisoned["losses"] == baseline["losses"]
     assert poisoned["traces"] == baseline["traces"]  # full retrace
     assert poisoned["persistent_errors"] >= 2
-    corrupt = [f for f in os.listdir(cache) if f.endswith(".corrupt")]
+    corrupt = [f for f in os.listdir(cache / "ptcc")
+               if f.endswith(".corrupt")]
     assert len(corrupt) >= 2, "bad entries were not quarantined"
 
     # the retrace re-populated the cache: a fourth process is warm again
@@ -107,7 +116,7 @@ def test_garbage_file_in_cache_dir_is_ignored(tmp_path):
     cache = tmp_path / "cache"
     _run_worker(cache_dir=cache)
     for name in _entries(cache):
-        (cache / name).write_bytes(b"not a cache entry at all")
+        (cache / "ptcc" / name).write_bytes(b"not a cache entry at all")
     out = _run_worker(cache_dir=cache)
     assert out["traces"] > 0  # fell back
     assert out["persistent_errors"] >= 1

@@ -162,8 +162,6 @@ def test_cost_flops_match_xla(example):
         lowered = hlo.lower_program_step(main, feed, fetch_names,
                                          scope=scope)
     ca = lowered.compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     xla = int(ca.get("flops", 0))
     assert xla > 0
     ratio = max(rep.total_flops, xla) / max(min(rep.total_flops, xla), 1)

@@ -656,10 +656,10 @@ def test_breaker_relaunches_warm_replica_with_zero_recompiles():
 
 def _run_worker(cache_dir):
     env = dict(os.environ)
-    env.pop("PADDLE_TPU_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["JAX_PLATFORMS"] = "cpu"
     if cache_dir is not None:
-        env["PADDLE_TPU_CACHE_DIR"] = str(cache_dir)
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     proc = subprocess.run(
         [sys.executable, WORKER], env=env, capture_output=True,
         text=True, timeout=300,
@@ -699,7 +699,6 @@ def test_bench_decode_smoke_cli():
     paged leg, speculative steps-per-token < 1, and block-pool
     conservation across beam fork/prune."""
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_serving.py"),

@@ -36,7 +36,7 @@ def _clean_env(extra=None):
         if not k.startswith(("PADDLE_", "TRAINING_", "XLA_", "JAX_"))
     }
     env["PYTHONPATH"] = REPO + os.pathsep + os.environ.get("PYTHONPATH", "")
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(extra or {})
     return env
 
@@ -121,7 +121,7 @@ def test_launcher_module_entrypoint():
     from paddle_tpu.distributed.launch import launch_procs
 
     old = dict(os.environ)
-    os.environ["PADDLE_TPU_FORCE_CPU"] = "1"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         codes = launch_procs(
             [WORKER], nproc=2, extra_env={"DIST_STEPS": "2"}

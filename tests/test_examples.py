@@ -17,7 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
                                   "wide_deep"])
 def test_example_runs(name):
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", f"{name}.py")],
         env=env, capture_output=True, text=True, timeout=560,

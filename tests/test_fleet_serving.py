@@ -647,7 +647,7 @@ def test_subprocess_rolling_deploy_by_replacement(tmp_path):
     cache = str(tmp_path / "cache")
     margs = {**GEOM, "name": "flt_roll", "version": "1"}
     r0 = SubprocessReplica.spawn(
-        "r0", 0, margs, extra_env={"PADDLE_TPU_CACHE_DIR": cache})
+        "r0", 0, margs, extra_env={"JAX_COMPILATION_CACHE_DIR": cache})
     old_pid = r0.proc.pid
 
     # in-process references: deterministic init = byte-identical weights
@@ -846,7 +846,7 @@ def test_subprocess_kill_a_replica_bit_identical(tmp_path):
     }])
 
     def spawn(index, fault=False):
-        env = {"PADDLE_TPU_CACHE_DIR": cache}
+        env = {"JAX_COMPILATION_CACHE_DIR": cache}
         if fault:
             env["PADDLE_TPU_FAULTS"] = kill_sched
         return SubprocessReplica.spawn(f"r{index}", index, margs,

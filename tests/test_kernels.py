@@ -1,15 +1,12 @@
 """Pallas kernel registry (paddle_tpu/kernels/): the registry-enumerated
-parity gate, mode/fingerprint wiring, fused-op memory accounting, and the
-KERNEL_EVIDENCE_r15 drift gate.
+parity gate, mode/fingerprint wiring and fused-op memory accounting
+(the chip-free compile gate is tests/test_kernels_tpu_aot.py).
 
 The parity gate is the CI contract of the subsystem: it parametrizes
 over ``kernels.all_specs()``, so a kernel registered without a parity
 check cannot even register, and one whose interpret-mode output drifts
 from its composite fallback fails here by name.
 """
-
-import json
-import os
 
 import numpy as np
 import pytest
@@ -20,8 +17,6 @@ import jax.numpy as jnp
 import paddle_tpu as fluid
 from paddle_tpu import kernels
 from paddle_tpu.kernels import registry as kreg
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -41,17 +36,16 @@ def test_kernel_parity(name, rng):
 
 def test_registration_requires_parity_check():
     with pytest.raises(ValueError, match="parity_check"):
-        kernels.KernelSpec("bogus", ("x",), "bit", None)
+        kernels.KernelSpec("bogus", ("x",), "bit", None, tpu_cases=list)
     with pytest.raises(ValueError, match="parity"):
-        kernels.KernelSpec("bogus", ("x",), "sorta", lambda rng: None)
+        kernels.KernelSpec("bogus", ("x",), "sorta", lambda rng: None,
+                           tpu_cases=list)
 
 
 def test_every_kernel_spec_is_complete():
     specs = kernels.all_specs()
     assert {s.name for s in specs} >= {
-        "flash_attention", "cached_attention", "paged_attention",
-        "embedding_admission", "remat_policy", "dgc_topk",
-        "sparse_row_update",
+        "flash_attention", "cached_attention", "remat_policy",
     }
     for s in specs:
         assert s.op_types, s.name
@@ -69,14 +63,14 @@ def test_mode_env_and_scoped(monkeypatch):
     assert kernels.mode() == "auto"
     # on this CPU rig auto resolves to composites everywhere
     assert kernels.resolved_mode() == "off"
-    assert kernels.selected("paged_attention") is None
+    assert kernels.selected("cached_attention") is None
     with kernels.scoped_mode("interpret"):
         assert kernels.resolved_mode() == "interpret"
-        sel = kernels.selected("paged_attention")
+        sel = kernels.selected("cached_attention")
         assert sel is not None and sel.interpret
         with kernels.scoped_mode("off"):          # nesting: innermost wins
-            assert kernels.selected("paged_attention") is None
-        assert kernels.selected("paged_attention") is not None
+            assert kernels.selected("cached_attention") is None
+        assert kernels.selected("cached_attention") is not None
     monkeypatch.setenv(kernels.MODE_ENV, "off")
     assert kernels.mode() == "off"
     monkeypatch.setenv(kernels.MODE_ENV, "bogus")
@@ -86,14 +80,11 @@ def test_mode_env_and_scoped(monkeypatch):
         kernels.mode()
 
 
-def test_flag_gated_kernels_not_mode_selected():
-    """Legacy FLAGS-gated kernels enumerate in the parity gate but are
-    never selected by the mode (their own flags drive them, and the
-    compile-cache fingerprint already covers those flags)."""
+def test_policy_kind_not_mode_selected():
+    """The remat policy enumerates in the parity gate but is never
+    selected by the mode (an IR attr drives it)."""
     with kernels.scoped_mode("interpret"):
-        assert kernels.selected("dgc_topk") is None
-        assert kernels.selected("sparse_row_update") is None
-        assert kernels.selected("remat_policy") is None  # policy kind
+        assert kernels.selected("remat_policy") is None
 
 
 def test_probe():
@@ -118,7 +109,7 @@ def test_kernel_sig_modes():
     with kernels.scoped_mode("interpret"):
         sig = kernels.kernel_sig()
         assert sig is not None and sig[0] == "interpret"
-        assert ("paged_attention", 1) in sig[1]
+        assert ("cached_attention", 1) in sig[1]
 
 
 def _tiny_cached_attention_program():
@@ -177,8 +168,10 @@ def test_mode_flip_retraces_and_stays_bit_identical(rng):
 
 
 def test_paged_memory_accounting_orders():
-    """kernel-path < composite-path < slotted-dense, and the
-    composite-vs-kernel gap is (at least ~) the dense gather views."""
+    """kernel-path < composite-path, the gap is (at least ~) the dense
+    gather views — and the LIVE estimate counts them under every mode:
+    paged_attention has no kernel (ROADMAP 1.5), its composite is the
+    one path."""
     from paddle_tpu.analysis.memory import estimate_peak_hbm
     from paddle_tpu.serving.decode import build_decoder_model
 
@@ -204,7 +197,7 @@ def test_paged_memory_accounting_orders():
     with kernels.scoped_mode("interpret"):
         live_k = estimate_peak_hbm(m.decode_program, feed_shapes=fs,
                                    fetch_names=[m.logits_fetch])
-    assert live_k.peak_total_bytes == kern.peak_total_bytes
+    assert live_k.peak_total_bytes == comp.peak_total_bytes
 
 
 def test_fused_program_tokens_match_composite_program(rng):
@@ -270,34 +263,4 @@ def test_embedding_device_admission_bit_identical_and_no_roundtrips():
     assert c1 - c0 > 0, "legacy path stopped counting round-trips"
     device = drive("auto")
     assert c.value == c1, "device admission round-tripped the slab"
-    pallas = drive("interpret")
-    assert c.value == c1
-    assert legacy == device == pallas
-
-
-# ---------------------------------------------------------------------------
-# KERNEL_EVIDENCE_r15 drift gate (live recompute, r08/r09/r13 style)
-# ---------------------------------------------------------------------------
-
-
-def test_kernel_evidence_r15_committed():
-    """The committed KERNEL_EVIDENCE_r15.json must be exactly what
-    tools/kernel_report.py derives TODAY — evidence that drifts from the
-    code is worse than no evidence."""
-    sys_path_hack = os.path.join(REPO, "tools")
-    import sys
-
-    if sys_path_hack not in sys.path:
-        sys.path.insert(0, sys_path_hack)
-    import kernel_report
-
-    with open(os.path.join(REPO, "KERNEL_EVIDENCE_r15.json")) as f:
-        committed = json.load(f)
-    live = kernel_report.build_evidence()
-    kernel_report.check(live)
-    kernel_report.check(committed)
-    assert json.dumps(live, sort_keys=True) == \
-        json.dumps(committed, sort_keys=True), (
-            "KERNEL_EVIDENCE_r15.json drifted from the live recompute — "
-            "regenerate with `python tools/kernel_report.py --out "
-            "KERNEL_EVIDENCE_r15.json`")
+    assert legacy == device

@@ -10,8 +10,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from paddle_tpu.parallel.env import make_mesh, shard_map
+from paddle_tpu.parallel.env import make_mesh
 from paddle_tpu.parallel.dgc import dgc_allreduce
 from paddle_tpu.parallel.localsgd import localsgd_train
 

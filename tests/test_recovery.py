@@ -173,7 +173,6 @@ print("WORKER_DONE", start)
     REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     if fault_spec is not None:
         import json
@@ -248,7 +247,6 @@ def test_chaos_train_full_acceptance():
     run resumed from that same checkpoint."""
     REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("PADDLE_TPU_FAULTS", None)
     proc = subprocess.run(
@@ -278,7 +276,7 @@ def test_kill_a_worker_job_survives():
         env_base["PYTHONPATH"] = (
             REPO + os.pathsep + os.environ.get("PYTHONPATH", "")
         )
-        env_base["PADDLE_TPU_FORCE_CPU"] = "1"
+        env_base["JAX_PLATFORMS"] = "cpu"
         env_base["PADDLE_PSERVERS_IP_PORT_LIST"] = srv.endpoint
         trainers = []
         for rank, steps in ((0, 25), (1, 25)):

@@ -868,7 +868,6 @@ def test_chaos_train_smoke_cli():
     newest checkpoint -> supervised auto-restart, quarantine, resume,
     and bit-identical final parameters vs the uninterrupted reference."""
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("PADDLE_TPU_FAULTS", None)
     proc = subprocess.run(

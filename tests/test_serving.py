@@ -586,7 +586,7 @@ def test_serving_capi_from_c_host(tmp_path, rng, capi_lib):
     )
     assert build.returncode == 0, build.stderr
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [exe_path, model_dir, "12", "8"],
         capture_output=True, text=True, timeout=560, env=env,
@@ -605,7 +605,6 @@ def test_bench_serving_smoke_cli():
     """tools/bench_serving.py --smoke is the tier-1 CI hook: runs the
     closed loop end to end and asserts the zero-retrace invariant."""
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_serving.py"),

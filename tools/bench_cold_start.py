@@ -6,7 +6,7 @@ cross-process by definition):
 
 - **train**: process start -> first optimized step of a small MLP train
   program. Cold pays trace + XLA compile; warm loads the serialized step
-  from ``PADDLE_TPU_CACHE_DIR`` (zero traces).
+  from ``JAX_COMPILATION_CACHE_DIR`` (zero traces).
 - **predictor**: Predictor.warmup() over a (batch x seq-like) bucket
   lattice — the serving cold-replica story (ROADMAP item 2's compile
   storm). Cold compiles every lattice point; warm loads each bucket from
@@ -125,9 +125,9 @@ def _worker_predictor(model_dir, buckets):
 
 def _run_child(mode, cache_dir, extra_args):
     env = dict(os.environ)
-    env.pop("PADDLE_TPU_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     if cache_dir:
-        env["PADDLE_TPU_CACHE_DIR"] = cache_dir
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     t0 = time.perf_counter()
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--worker", mode]

@@ -799,12 +799,10 @@ def main(argv=None):
         args.rates = [float(r) for r in
                       (args.rates or str(args.rate)).split(",")]
 
-    from paddle_tpu.core.places import ensure_backend_or_cpu
-
-    on_tpu, diag = ensure_backend_or_cpu()
-
     if args.decode:
         return run_decode(args, np.random.RandomState(0))
+
+    import jax
 
     from paddle_tpu import inference
     from paddle_tpu.serving import BucketLattice, ServingEngine
@@ -812,8 +810,6 @@ def main(argv=None):
     with tempfile.TemporaryDirectory() as tmp:
         model_dir = _save_model(tmp, feat=args.feat, seq=args.seq)
         config = inference.Config(model_dir)
-        if not on_tpu:
-            config.disable_tpu()
         lattice = BucketLattice.pow2(args.max_batch, args.seq or None,
                                      min_seq=2)
         config.set_serving_buckets(lattice.batch_sizes, lattice.seq_lens)
@@ -836,8 +832,7 @@ def main(argv=None):
         "value": round(served / max(wall, 1e-9), 1),
         "unit": "req/s",
         "extra": {
-            "device": "tpu" if on_tpu else "cpu",
-            "backend_diag": diag,
+            "device": jax.devices()[0].platform,
             "served": served,
             "rejected": stats["rejected"],
             "deadline_missed": stats["deadline_missed"],

@@ -18,9 +18,10 @@ import numpy as np
 
 
 def main():
-    from paddle_tpu.core.places import ensure_backend_or_cpu
+    import jax
 
-    on_tpu, diag = ensure_backend_or_cpu()
+    platform = jax.devices()[0].platform
+    on_tpu = platform == "tpu"
 
     import paddle_tpu as fluid
     from paddle_tpu.models import transformer as tfm
@@ -67,8 +68,7 @@ def main():
         "value": round(tr.tokens_per_sec(), 1),
         "unit": "tokens/s",
         "extra": {
-            "device": "tpu" if on_tpu else "cpu",
-            "backend_diag": diag,
+            "device": platform,
             "vocab": cfg.vocab_size,
             "beam": beam,
             "batch": batch,

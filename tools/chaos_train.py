@@ -34,7 +34,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("PADDLE_TPU_FORCE_CPU", "1")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

@@ -234,8 +234,6 @@ def live_sections():
 
     def _xla_flops(lowered):
         ca = lowered.compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         return int(ca.get("flops", 0)), int(ca.get("transcendentals", 0))
 
     out = {}

@@ -79,8 +79,7 @@ def static_hbm_report():
     ):
         # fused_attention=False pins the r13 program structure (gather +
         # attention composite) so the committed r13 numbers stay
-        # byte-reproducible; the kernel-path story is KERNEL_EVIDENCE_r15
-        # (tools/kernel_report.py)
+        # byte-reproducible
         m = build_decoder_model(name=f"hbm_{tag}", version="1", **geom,
                                 fused_attention=False, **kw)
         report = estimate_peak_hbm(
