@@ -31,6 +31,7 @@ from paddle_tpu.observability.tracer import (
     export_chrome_trace,
     get_tracer,
     instant,
+    span,
     trace_scope,
     tracing,
     tracing_enabled,
@@ -68,6 +69,7 @@ from paddle_tpu.observability.lockdep import (
 __all__ = [
     "Tracer",
     "trace_scope",
+    "span",
     "instant",
     "tracing",
     "tracing_enabled",

@@ -12,7 +12,15 @@ the device timeline.
 Zero-overhead-when-disabled contract: ``trace_scope.__enter__`` performs a
 single module-global attribute check and returns; no clock is read, no
 allocation happens. The hot execute path stays within the <=2% budget
-(tools/trace_view.py --smoke measures it).
+(tools/trace_view.py --smoke measures it). ``span(name)`` goes one further
+for hot loops: disabled, it hands back ONE shared no-op (nothing is built,
+no argument is evaluated) whose ``__enter__`` gives None; enabled, the live
+span takes its arguments through ``set()``.
+
+One clock with the device trace: while a ``jax.profiler`` trace is running,
+an enabled span also opens a ``jax.profiler.TraceAnnotation`` of the same
+name and arguments, so xprof / Perfetto show the host's phases beside the
+device operations they launched.
 
     with tracing("/tmp/run.trace.json"):
         with trace_scope("step"):
@@ -26,12 +34,14 @@ allocation happens. The hot execute path stays within the <=2% budget
 import functools
 import json
 import os
+import sys
 import threading
 import time
 
 __all__ = [
     "Tracer",
     "trace_scope",
+    "span",
     "instant",
     "tracing",
     "tracing_enabled",
@@ -271,12 +281,24 @@ class tracing:
         return False
 
 
+def _profiler_annotation(name, args):
+    """An entered ``jax.profiler.TraceAnnotation`` while a jax.profiler
+    trace is running, else None. jax is never imported from here: a
+    running trace means it already is."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    ann = jax.profiler.TraceAnnotation(name, **(args or {}))
+    ann.__enter__()
+    return ann
+
+
 class trace_scope:
     """RAII span: context manager or decorator; nests freely across
     threads (each thread is its own track). Disabled cost is one global
     attribute check."""
 
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, name, cat="host", **args):
         self.name = name
@@ -290,6 +312,9 @@ class trace_scope:
             self._t0 = None
             return self
         tr._stack().append(self.name)
+        # the annotation first, as the benchmark's anchor does: the two
+        # clocks are then read in the same order at every span
+        self._ann = _profiler_annotation(self.name, self.args)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -297,6 +322,8 @@ class trace_scope:
         if self._t0 is None:
             return False
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = _TRACER
         stack = tr._stack()
         if stack:
@@ -305,6 +332,20 @@ class trace_scope:
                        self.args)
         self._t0 = None
         return False
+
+    def set(self, **args):
+        """Arguments known only once the span is open (a byte count, a
+        request id). For a live span only (``_ann`` exists from its
+        ``__enter__`` on): ``span()``'s ``as`` target is None when tracing
+        is off, so the values are never computed then."""
+        self.args = {**self.args, **args} if self.args else args
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def elapsed_ns(self):
+        """Nanoseconds since the live span opened: one clock read, for a
+        phase boundary inside a span that must not hold a child span."""
+        return time.perf_counter_ns() - self._t0
 
     def __call__(self, fn):
         name, cat, args = self.name, self.cat, self.args or {}
@@ -315,6 +356,34 @@ class trace_scope:
                 return fn(*a, **kw)
 
         return wrapped
+
+
+class _NullSpan:
+    """What ``span()`` hands out while tracing is off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def span(name, cat="host"):
+    """``trace_scope(name, cat)`` while tracing is on, else a shared no-op:
+
+        with span("decode::feeds") as sp:
+            ...
+            if sp is not None:
+                sp.set(active=len(active))
+    """
+    if not _TRACER.enabled:
+        return _NULL_SPAN
+    return trace_scope(name, cat)
 
 
 def instant(name, cat="event", **args):

@@ -66,29 +66,36 @@ class RecordEvent:
     """RAII host span (reference: profiler.h:205). Usable as context manager
     or decorator; nests freely. Emits to the observability tracer whenever
     tracing is enabled (independent of the profiler gate) and aggregates
-    into the sorted report when the profiler is enabled."""
+    into the sorted report when the profiler is enabled. With both gates
+    off, entering and leaving is two attribute checks: no clock is read.
 
-    __slots__ = ("name", "_t0", "_span")
+    ``span`` is the live tracer span while tracing is on, else None —
+    the handle for arguments known only inside the event
+    (``ev.span.set(bytes=n)``)."""
+
+    __slots__ = ("name", "_t0", "span")
 
     def __init__(self, name):
         self.name = name
         self._t0 = None
-        self._span = None
+        self.span = None
 
     def __enter__(self):
         if _obs_tracer._TRACER.enabled:
-            self._span = _obs_tracer.trace_scope(self.name, cat="event")
-            self._span.__enter__()
-        self._t0 = time.perf_counter()
+            self.span = _obs_tracer.trace_scope(self.name, cat="event")
+            self.span.__enter__()
+        if _enabled:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self._span is not None:
-            self._span.__exit__(*exc)
-            self._span = None
-        if not _enabled:
+        if self.span is not None:
+            self.span.__exit__(*exc)
+            self.span = None
+        t0, self._t0 = self._t0, None
+        if t0 is None or not _enabled:
             return False
-        dt = time.perf_counter() - self._t0
+        dt = time.perf_counter() - t0
         rec = _events[self.name]
         rec[0] += 1
         rec[1] += dt

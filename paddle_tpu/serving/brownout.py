@@ -29,7 +29,8 @@ threshold. Every transition is recorded with the trigger signal and its
 value (the OVERLOAD_EVIDENCE witness).
 
 The controller is a pure hand-steppable object: no threads, no clocks —
-callers feed signals, it returns a level.
+callers feed signals, it returns a level. A caller that has a clock
+writes it onto the transitions it witnessed (``stamp``).
 """
 
 __all__ = ["BrownoutController", "SEVERITY_NAMES"]
@@ -64,7 +65,9 @@ class BrownoutController:
         self.beam_cap = int(beam_cap)
         self.level = 0
         self.steps = 0
-        self.transitions = []    # {"step", "from", "to", "trigger", "value"}
+        # {"step", "from", "to", "trigger", "value"} and, once stamped,
+        # "time"
+        self.transitions = []
         self._clear_streak = 0
 
     def _pressure(self, occupancy, queue_seconds, deadline):
@@ -110,6 +113,14 @@ class BrownoutController:
         else:
             self._clear_streak = 0
         return self.level
+
+    def stamp(self, since, now):
+        """Writes the caller's clock onto the transitions from index
+        ``since`` on and returns them (the controller reads no clock)."""
+        fresh = self.transitions[since:]
+        for t in fresh:
+            t["time"] = now
+        return fresh
 
     @property
     def name(self):

@@ -55,6 +55,16 @@ traces — subprocess-asserted in tests/test_decode.py. Before anything
 compiles, the paged arena is sized against the peak-HBM budget via
 ``analysis/memory.py`` — an oversized block pool fails with sizing
 advice, not an XLA OOM.
+
+Measured from inside (all of it nothing while tracing is off): one
+``decode::iterate`` span per scheduler iteration holds a span per phase —
+``decode::admit`` > ``decode::prefill`` / ``prefill_fetch`` / ``inject``,
+``decode::chunk`` / ``chunk_fetch``, ``decode::feeds`` / ``step`` /
+``step_fetch`` / ``sample`` — each carrying its request's id where it has
+one; a launch span also says how long the host spent inside
+``jax.device_put`` and inside the executable's call. Always on: a time
+stamp per token on the ``Response``, and the bytes that cross the device
+boundary (``DecodeMetrics``).
 """
 
 import threading
@@ -64,6 +74,8 @@ import numpy as np
 
 from paddle_tpu import profiler
 from paddle_tpu.observability import lockdep
+from paddle_tpu.observability.tracer import instant as _instant
+from paddle_tpu.observability.tracer import span as _span
 from paddle_tpu.resilience import faults
 from paddle_tpu.serving.decode.generate import (
     BeamParams,
@@ -291,6 +303,7 @@ class _ModelEntry:
         self._pending = []      # [GenerationRequest] deferred admissions
         self._brownout = BrownoutController()
         self._bt_seen = 0       # brownout transitions already counted
+        self._iteration = 0     # decode::iterate spans opened (traced only)
         self._admit_seq = 0
         self._chunk_throttle = False
         self.victim_policy = None   # callable([slot ids]) -> slot id
@@ -368,25 +381,45 @@ class _ModelEntry:
         # this on the loop thread while stats() dict-copies concurrently
         self.compile_sources = sources
 
-    def _run(self, kind, feeds):
+    def _run(self, kind, feeds, span=None):
         """Execute one lowered program against the entry scope; written
         persistables (the arenas — donated, updated in place on device)
-        re-enter the scope for the next call."""
+        re-enter the scope for the next call. ``span`` is the caller's
+        live launch span, or None while tracing is off: it is told the
+        bytes fed and the nanoseconds the host spent inside
+        ``jax.device_put`` (from the span's opening) and inside the
+        executable's call — two clock reads, no child span, so the device
+        module still belongs to the caller's span."""
         import jax
 
         entry, executable = self._entries[kind]
         dev = self._engine.device
-        feed_vals = tuple(
-            jax.device_put(np.ascontiguousarray(feeds[n]), dev)
-            for n in entry.feed_names
-        )
+        fed = 0
+        feed_vals = []
+        for n in entry.feed_names:
+            a = np.ascontiguousarray(feeds[n])
+            fed += a.nbytes
+            feed_vals.append(jax.device_put(a, dev))
+        if span is not None:
+            put_ns = span.elapsed_ns()
         donated = tuple(self._scope.find_var(n) for n in entry.donated)
         readonly = tuple(self._scope.find_var(n) for n in entry.readonly)
-        fetches, updates = executable(feed_vals, donated, readonly,
+        fetches, updates = executable(tuple(feed_vals), donated, readonly,
                                       self._rng0)
+        if span is not None:
+            span.set(bytes=fed, put_ns=put_ns,
+                     call_ns=span.elapsed_ns() - put_ns)
+        self._metrics.count_launch(kind, fed)
         for n, u in zip(entry.written, updates):
             self._scope.set(n, u)
         return fetches
+
+    def _fetch(self, value):
+        """One fetch brought to the host (here the host waits for the
+        device), counted in ``serving_fetched_bytes_total``."""
+        a = np.asarray(value)
+        self._metrics.incr("fetched_bytes", a.nbytes)
+        return a
 
     def _reset_arenas(self):
         """Zero the KV pool and drop all slot/block state (relaunch
@@ -452,6 +485,15 @@ class _ModelEntry:
         cycle per speculative slot, then one decode step. Extracted so
         tests can hand-step the interleaving deterministically. Returns
         True when the loop should exit."""
+        with _span("decode::iterate") as sp:
+            if sp is not None:
+                self._iteration += 1
+                sp.set(iteration=self._iteration,
+                       active=self._pool.active_count,
+                       queued=self._queue.depth())
+            return self._iterate_phases()
+
+    def _iterate_phases(self):
         with self._cond:
             for r in self._queue.expire():
                 self._reject_expired(r)
@@ -552,6 +594,13 @@ class _ModelEntry:
         every terminal outcome and KEPT on "deferred" (the request is
         still committed to this entry — it just waits for arena
         capacity). Returns "admitted" | "deferred" | "done"."""
+        with _span("decode::admit") as sp:
+            outcome = self._admit_into_slot(req)
+            if sp is not None:
+                sp.set(request=req.id, outcome=outcome)
+            return outcome
+
+    def _admit_into_slot(self, req):
         if req.expired():
             # picked but dead: release the pick-time in-flight
             # reservation; no slot to free
@@ -916,7 +965,7 @@ class _ModelEntry:
         else:
             toks = (list(st.request.prompt) + list(st.generated))[:n]
             fetches = self._run("prefill", self._prefill_feeds(toks))
-            kvr = [np.asarray(f) for f in fetches[1:]]
+            kvr = [self._fetch(f) for f in fetches[1:]]
             kv = [(kvr[2 * i][0, :n], kvr[2 * i + 1][0, :n])
                   for i in range(len(m.state_names))]
             self._metrics.incr("resume_replays")
@@ -976,8 +1025,10 @@ class _ModelEntry:
             inj[kn] = karr
             inj[vn] = varr
         try:
-            with profiler.RecordEvent("decode::inject"):
-                self._run("inject", inj)
+            with profiler.RecordEvent("decode::inject") as ev:
+                if ev.span is not None:
+                    ev.span.set(request=st.request.id)
+                self._run("inject", inj, ev.span)
         except Exception as e:
             raise _ArenaInvalidError(str(e)) from e
         self._metrics.incr("tier_hits", len(ents))
@@ -998,6 +1049,10 @@ class _ModelEntry:
         n = len(self._brownout.transitions)
         if n > self._bt_seen:
             self._metrics.incr("brownout_transitions", n - self._bt_seen)
+            for t in self._brownout.stamp(self._bt_seen,
+                                          time.perf_counter()):
+                _instant("brownout::transition", **{
+                    k: t[k] for k in ("from", "to", "trigger", "value")})
             self._bt_seen = n
 
     def _shed_confirmed(self):
@@ -1075,15 +1130,23 @@ class _ModelEntry:
             self._metrics.tenant_incr("prefix_hits", req.tenant)
         else:
             t0 = time.perf_counter()
-            with profiler.RecordEvent("decode::prefill"):
+            with profiler.RecordEvent("decode::prefill") as ev:
                 faults.fire("decode.prefill")
-                fetches = self._run("prefill", self._prefill_feeds(prompt))
-            logits = np.asarray(fetches[0])          # [1, L, V]
-            kv_rows = [np.asarray(f) for f in fetches[1:]]
-            # copy: a view would pin the whole [1, L, V] prefill logits
-            # buffer in the prefix cache for the life of the entry
-            logits_row = np.array(logits[0, len(prompt) - 1])
-            self._prefix.put(key, kv_rows, logits_row)
+                if ev.span is not None:
+                    ev.span.set(request=req.id, prompt_len=plen)
+                fetches = self._run("prefill", self._prefill_feeds(prompt),
+                                    ev.span)
+            with _span("decode::prefill_fetch") as sp:
+                logits = self._fetch(fetches[0])         # [1, L, V]
+                kv_rows = [self._fetch(f) for f in fetches[1:]]
+                # copy: a view would pin the whole [1, L, V] prefill
+                # logits buffer in the prefix cache for the life of the
+                # entry
+                logits_row = np.array(logits[0, len(prompt) - 1])
+                self._prefix.put(key, kv_rows, logits_row)
+                if sp is not None:
+                    sp.set(request=req.id, bytes=logits.nbytes
+                           + sum(a.nbytes for a in kv_rows))
             self._metrics.observe_prefill(time.perf_counter() - t0)
         blocks, shared_len = self._acquire_blocks(req)
         st = _Slot(req, mode="decode")
@@ -1102,9 +1165,11 @@ class _ModelEntry:
                 inj[kn] = kv_rows[2 * i]
                 inj[vn] = kv_rows[2 * i + 1]
             try:
-                with profiler.RecordEvent("decode::inject"):
+                with profiler.RecordEvent("decode::inject") as ev:
                     faults.fire("decode.inject")
-                    self._run("inject", inj)
+                    if ev.span is not None:
+                        ev.span.set(request=req.id)
+                    self._run("inject", inj, ev.span)
             except Exception as e:
                 raise _ArenaInvalidError(str(e)) from e
 
@@ -1129,6 +1194,7 @@ class _ModelEntry:
         first = self._choose_token(st, logits_row, device_masked=False)
         st.last_token = first
         st.generated = [first]
+        req.response.token_times.append(time.perf_counter())
         # the prefill's first token: counted apart from generated_tokens
         # so tokens_per_step stays a decode-step quantity (<= S)
         self._metrics.incr("prefill_tokens")
@@ -1195,15 +1261,17 @@ class _ModelEntry:
                 wrows[c] = st.row_map[p]
         t0 = time.perf_counter()
         try:
-            with profiler.RecordEvent("decode::chunk"):
+            with profiler.RecordEvent("decode::chunk") as ev:
                 faults.fire("decode.chunk")
+                if ev.span is not None:
+                    ev.span.set(request=req.id, tokens=real)
                 fetches = self._run("chunk", {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
                     DecodeModel.CHU_BIAS: bias,
                     DecodeModel.CHU_ROWS: st.row_map,
                     DecodeModel.CHU_WRITE_ROWS: wrows,
-                })
+                }, ev.span)
         except Exception as e:
             self._arena_lost(f"chunk-prefill failure: {e}")
             return 1
@@ -1211,7 +1279,10 @@ class _ModelEntry:
         st.done = stop
         if st.done < st.plen:
             return 1
-        logits = np.asarray(fetches[0])              # [1, C, V]
+        with _span("decode::chunk_fetch") as sp:
+            logits = self._fetch(fetches[0])         # [1, C, V]
+            if sp is not None:
+                sp.set(request=req.id, bytes=logits.nbytes)
         self._blocks.register_prompt_blocks(st.blocks, req.prompt)
         st.cursor = st.plen
         if req.beam is not None:
@@ -1229,6 +1300,7 @@ class _ModelEntry:
                                    device_masked=False)
         st.last_token = first
         st.generated = [first]
+        req.response.token_times.append(time.perf_counter())
         self._metrics.incr("prefill_tokens")
         self._metrics.tenant_incr("tokens", req.tenant)
         if self._finished(st):
@@ -1288,7 +1360,7 @@ class _ModelEntry:
                             fetches = draft._run(
                                 "prefill", draft._prefill_feeds(dtoks))
                         nxt = int(np.argmax(
-                            np.asarray(fetches[0])[0, len(dtoks) - 1]))
+                            draft._fetch(fetches[0])[0, len(dtoks) - 1]))
                         props.append(nxt)
                         dtoks.append(nxt)
                     self._metrics.incr("spec_draft_steps", k)
@@ -1307,7 +1379,8 @@ class _ModelEntry:
                 continue
             self._metrics.incr("spec_target_steps")
             self._metrics.observe_prefill(time.perf_counter() - t0)
-            logits = np.asarray(fetches[0])          # [1, L, V]
+            logits = self._fetch(fetches[0])         # [1, L, V]
+            now = time.perf_counter()
             finished = False
             accepted_n = 0
             for j in range(k + 1):
@@ -1322,6 +1395,7 @@ class _ModelEntry:
                 st.generated.append(t)
                 st.toks.append(t)
                 st.last_token = t
+                req.response.token_times.append(now)
                 self._metrics.incr("spec_emitted_tokens")
                 self._metrics.tenant_incr("tokens", req.tenant)
                 if j < k and props[j] == t:
@@ -1378,7 +1452,7 @@ class _ModelEntry:
                 with profiler.RecordEvent("decode::spec_draft_prefill"):
                     fetches = draft._run("prefill",
                                          draft._prefill_feeds(prompt))
-                kv_rows = [np.asarray(f) for f in fetches[1:]]
+                kv_rows = [draft._fetch(f) for f in fetches[1:]]
                 st.d_entry = draft
                 st.d_slot = d_slot
                 st.d_blocks = blocks
@@ -1533,7 +1607,7 @@ class _ModelEntry:
         if write:
             draft._blocks.note_append(st.d_blocks[p // dm.block_size])
         self._metrics.incr("spec_draft_kv_steps")
-        return np.asarray(fetches[0])[s, 0]
+        return draft._fetch(fetches[0])[s, 0]
 
     # -- the decode iteration ---------------------------------------------
     def _arena_lost(self, why):
@@ -1631,23 +1705,26 @@ class _ModelEntry:
             row = np.asarray(logits_row, dtype=np.float32).reshape(-1)
             if st.grammar is not None:
                 row = row + st.grammar.mask()
-            self._commit_beam_selection(group, [row])
+            self._commit_beam_selection(group, [row], time.perf_counter())
         except _ArenaInvalidError:
             raise               # admission's arena handler owns cleanup
         except Exception as e:
             self._reject_beam_group(group, RequestError(
                 f"request {req.id} failed in first beam selection: {e}"))
 
-    def _commit_beam_selection(self, group, rows):
+    def _commit_beam_selection(self, group, rows, now):
         """ONE beam step's bookkeeping: run the committed selection rule
         over the live hypotheses' (masked) logits rows, divert EOS and
         length-exhausted continuations to ``finished``, release pruned
         parents, keep each parent's top continuation in its slot, fork
         the rest (refcount++ + private tail copy), and re-assert block
         row conservation. Returns False when the group retired or
-        failed (its slots are gone)."""
+        failed (its slots are gone). ``now`` stamps the selection: token
+        ``j`` of EVERY hypothesis is chosen by the group's ``j``-th
+        selection."""
         m = self._model
         req = group.request
+        req.response.token_times.append(now)
         live_ids = list(group.order)
         live = [self._slots[s] for s in live_ids]
         room = group.width - len(group.finished)
@@ -1780,6 +1857,9 @@ class _ModelEntry:
             self._metrics.incr("failed")
             self._metrics.observe_request(req)
             return
+        # the best hypothesis may have finished selections ago: its
+        # tokens' stamps are the first len(tokens) selections'
+        del req.response.token_times[len(ranked[0][0]):]
         req.response._complete(outputs={
             "tokens": np.asarray(ranked[0][0], dtype="int64"),
             "beams": [{"tokens": np.asarray(t, dtype="int64"),
@@ -1790,6 +1870,7 @@ class _ModelEntry:
         self._metrics.incr("beam_finished", len(ranked))
         self._metrics.tenant_incr("completed", req.tenant)
         self._metrics.observe_request(req)
+        self._metrics.observe_tokens(req)
 
     def _reject_beam_group(self, group, error):
         """Fail one beam request as a UNIT: release every slot the group
@@ -1809,6 +1890,44 @@ class _ModelEntry:
         self._metrics.observe_request(req)
 
     def _step(self):
+        with _span("decode::feeds") as sp:
+            built = self._step_feeds()
+            if sp is not None and built is not None:
+                sp.set(active=len(built[1]), beam_groups=len(built[2]))
+        if built is None:
+            return
+        feeds, active, groups = built
+        t0 = time.perf_counter()
+        try:
+            with profiler.RecordEvent("decode::step") as ev:
+                faults.fire("decode.step")
+                fetches = self._run("step", feeds, ev.span)
+        except Exception as e:
+            # a failed donated call leaves the arena undefined: every
+            # in-flight sequence is lost (failed loudly), the batch-level
+            # outcome drives the breaker, and the arena resets
+            self._arena_lost(f"decode-step failure: {e}")
+            return
+        if self._breaker is not None:
+            self._breaker_event(self._breaker.record_success())
+        with _span("decode::step_fetch") as sp:
+            logits = self._fetch(fetches[0])             # [S, 1, V]
+            if sp is not None:
+                sp.set(bytes=logits.nbytes)
+        now = time.perf_counter()
+        with _span("decode::sample") as sp:
+            stepped = self._sample(logits, active, groups, now)
+            if sp is not None:
+                sp.set(tokens=stepped)
+        if stepped is not None:
+            self._metrics.observe_step(stepped, stepped,
+                                       time.perf_counter() - t0)
+
+    def _step_feeds(self):
+        """The decode step's feeds from the live slots: ``(feeds, active
+        slot ids, beam groups with a live slot)``, or None when there is
+        nothing to step (or the arena was lost making a cursor
+        writable)."""
         m = self._model
         S, L, R = m.slots, m.max_len, m.rows
         tok = np.zeros((S, 1), "int64")
@@ -1870,7 +1989,7 @@ class _ModelEntry:
                     # the COW re-inject is a DONATED call: its failure
                     # invalidates the whole arena, not one request
                     self._arena_lost(f"copy-on-write inject failure: {e}")
-                    return
+                    return None
             elif _nb is not None:
                 self._rebuild_row_map(st)
             if st.mode == "beam":
@@ -1888,28 +2007,22 @@ class _ModelEntry:
                 # same compiled program for every request, zero retraces
                 dmask[s, 0] = st.grammar.mask()
         if not active and not groups:
-            return
+            return None
         feeds = {DecodeModel.DEC_TOKEN: tok, DecodeModel.DEC_POSITION: pos,
                  DecodeModel.DEC_BIAS: bias,
                  DecodeModel.DEC_ROWS: rows.reshape(-1),
                  DecodeModel.DEC_WRITE_ROWS: wrows}
         if dmask is not None:
             feeds[DecodeModel.DEC_MASK] = dmask
-        t0 = time.perf_counter()
-        try:
-            with profiler.RecordEvent("decode::step"):
-                faults.fire("decode.step")
-                fetches = self._run("step", feeds)
-        except Exception as e:
-            # a failed donated call leaves the arena undefined: every
-            # in-flight sequence is lost (failed loudly), the batch-level
-            # outcome drives the breaker, and the arena resets
-            self._arena_lost(f"decode-step failure: {e}")
-            return
-        if self._breaker is not None:
-            self._breaker_event(self._breaker.record_success())
-        logits = np.asarray(fetches[0])              # [S, 1, V]
-        now = time.perf_counter()
+        return feeds, active, groups
+
+    def _sample(self, logits, active, groups, now):
+        """The host half of a decode step over its fetched ``[S, 1, V]``
+        logits: choose each active slot's token (stamped ``now``), commit
+        its KV append, retire or expire it; then one selection per beam
+        group. Returns the slot-steps done, or None when a beam fork lost
+        the arena."""
+        m = self._model
         stepped = len(active)
         for s in active:
             st = self._slots[s]
@@ -1920,6 +2033,7 @@ class _ModelEntry:
             st.generated.append(nxt)
             st.cursor += 1
             st.last_token = nxt
+            st.request.response.token_times.append(now)
             self._metrics.tenant_incr("tokens", st.request.tenant)
             # finished wins over expired: the device already paid for a
             # COMPLETE generation, deliver it (the prefill fast path
@@ -1946,21 +2060,20 @@ class _ModelEntry:
                 bst.cursor += 1
                 row = np.asarray(logits[sid, 0],
                                  dtype=np.float32).reshape(-1)
-                if bst.grammar is not None and dmask is None:
+                if bst.grammar is not None and not m.logits_mask:
                     row = row + bst.grammar.mask()
                 rows_l.append(row)
             stepped += len(rows_l)
             try:
-                alive = self._commit_beam_selection(group, rows_l)
+                alive = self._commit_beam_selection(group, rows_l, now)
             except _ArenaInvalidError as e:
                 self._arena_lost(f"beam fork inject failure: {e}")
-                return
+                return None
             if alive and group.request.expired(now):
                 self._reject_beam_group(group, DeadlineExceededError(
                     "deadline expired mid-generation after "
                     f"{len(group.finished)} finished hypotheses"))
-        self._metrics.observe_step(stepped, stepped,
-                                   time.perf_counter() - t0)
+        return stepped
 
     def _finished(self, st):
         m = self._model
@@ -1984,6 +2097,7 @@ class _ModelEntry:
         self._metrics.incr("retired")
         self._metrics.tenant_incr("completed", req.tenant)
         self._metrics.observe_request(req)
+        self._metrics.observe_tokens(req)
 
     def _reject_in_flight(self, req, error, slot=None):
         if slot is not None:
@@ -2017,7 +2131,7 @@ class _ModelEntry:
         for _ in range(int(max_new)):
             t = len(toks) - 1
             fetches = self._run("prefill", self._prefill_feeds(toks))
-            row = np.asarray(fetches[0])[0, t].astype(np.float32)
+            row = self._fetch(fetches[0])[0, t].astype(np.float32)
             if g is not None:
                 row = row + g.mask()
             if sampling is not None and not sampling.greedy:
@@ -2043,7 +2157,7 @@ class _ModelEntry:
 
         def logits_fn(tokens):
             fetches = self._run("prefill", self._prefill_feeds(tokens))
-            return np.asarray(fetches[0])[0, len(tokens) - 1]
+            return self._fetch(fetches[0])[0, len(tokens) - 1]
 
         g = GrammarConstraint(grammar) if grammar is not None else None
         return offline_beam_decode(logits_fn, prompt, int(max_new), params,
@@ -2413,6 +2527,8 @@ class GenerationEngine:
             entry.metrics.incr("rejected")
             entry.metrics.incr("brownout_shed")
             entry.metrics.tenant_incr("rejected", tenant)
+            _instant("brownout::shed", level=severity, priority=priority,
+                     tenant=tenant, why="l4_non_high")
             raise RejectedError(
                 f"brownout {entry._brownout.name}: shedding non-HIGH "
                 "traffic under overload",
@@ -2442,6 +2558,9 @@ class GenerationEngine:
                 entry.metrics.incr("rejected")
                 entry.metrics.incr("brownout_shed")
                 entry.metrics.tenant_incr("rejected", tenant)
+                _instant("brownout::shed", level=severity,
+                         priority=priority, tenant=tenant,
+                         why="l3_beam_cap")
                 raise RejectedError(
                     f"brownout {entry._brownout.name}: beam width capped "
                     f"at {entry._brownout.beam_cap} under pressure",

@@ -8,11 +8,27 @@ prefix-cache hits, retirements, and step/prefill latency histograms.
 ``occupancy()`` is the headline number: mean fraction of the S-slot batch
 doing real work per iteration — what continuous batching buys over
 request-at-a-time bucketing.
+
+What a caller waits for, from the per-token time stamps on ``Response``:
+``serving_decode_first_token_seconds`` (submit to first token) and
+``serving_decode_inter_token_seconds`` (the mean gap between a request's
+tokens), observed at retirement. What crosses the device boundary:
+``serving_fed_bytes_total`` / ``serving_fetched_bytes_total``, with
+``serving_step_launches_total`` to put them per decode step.
 """
 
 from paddle_tpu.serving.metrics import ServingMetrics
 
-__all__ = ["DecodeMetrics"]
+__all__ = ["DecodeMetrics", "TOKEN_BUCKETS"]
+
+# 10 ms wide from 50 ms to 500 ms, where a token's wait falls on the chip
+# (a decode step is ~110 ms, a first token a few of them), so a quantile
+# read off the buckets is good to 10 ms there; the usual ladder outside
+TOKEN_BUCKETS = (
+    (0.001, 0.0025, 0.005, 0.01, 0.025)
+    + tuple(round(0.05 + 0.01 * i, 2) for i in range(46))
+    + (0.6, 0.75, 1.0, 1.5, 2.5, 5.0, 10.0, 25.0, 50.0)
+)
 
 
 class DecodeMetrics(ServingMetrics):
@@ -54,6 +70,10 @@ class DecodeMetrics(ServingMetrics):
         # brownout ladder (serving/brownout.py): witnessed transitions
         # and L4 sheds
         "brownout_transitions", "brownout_shed",
+        # the device boundary: bytes of the feeds every launch puts,
+        # bytes of the fetches brought back to the host, and the launches
+        # of the decode-step program (the denominator for "per step")
+        "fed_bytes", "fetched_bytes", "step_launches",
     )
 
     def __init__(self, engine_label=None, registry=None):
@@ -71,7 +91,17 @@ class DecodeMetrics(ServingMetrics):
             "serving_chunk_prefill_seconds",
             "one budgeted chunk-prefill forward", labels=labels,
         )
-        for h in (self._step, self._prefill, self._chunk):
+        self._first_token = self._registry.histogram(
+            "serving_decode_first_token_seconds",
+            "submit to first token", labels=labels, buckets=TOKEN_BUCKETS,
+        )
+        self._inter_token = self._registry.histogram(
+            "serving_decode_inter_token_seconds",
+            "mean gap between one request's tokens", labels=labels,
+            buckets=TOKEN_BUCKETS,
+        )
+        for h in (self._step, self._prefill, self._chunk,
+                  self._first_token, self._inter_token):
             h.reset()
 
     def observe_step(self, active_slots, new_tokens, seconds):
@@ -88,6 +118,25 @@ class DecodeMetrics(ServingMetrics):
         self.incr("chunk_runs")
         self.incr("chunk_tokens", tokens)
         self._chunk.observe(seconds)
+
+    def count_launch(self, kind, fed_bytes):
+        """One launch of the ``kind`` program that put ``fed_bytes`` of
+        feeds on the device."""
+        self.incr("fed_bytes", fed_bytes)
+        if kind == "step":
+            self.incr("step_launches")
+
+    def observe_tokens(self, request):
+        """At retirement: the request's time to first token and the mean
+        gap between its tokens, from ``Response.token_times``."""
+        resp = request.response
+        times = resp.token_times
+        if not times:
+            return
+        self._first_token.observe(times[0] - request.submit_time)
+        if len(times) > 1:
+            self._inter_token.observe(
+                (resp.finish_time - times[0]) / (len(times) - 1))
 
     def occupancy(self, slots):
         steps = self.count("decode_steps")
@@ -106,6 +155,8 @@ class DecodeMetrics(ServingMetrics):
         out.update(self._step.snapshot("decode_step"))
         out.update(self._prefill.snapshot("prefill"))
         out.update(self._chunk.snapshot("chunk_prefill"))
+        out.update(self._first_token.snapshot("first_token"))
+        out.update(self._inter_token.snapshot("inter_token"))
         if extra:
             out.update(extra)
         return out

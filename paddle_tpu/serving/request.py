@@ -94,15 +94,28 @@ class Response:
     The engine thread completes it exactly once with either a
     {fetch_name: np.ndarray} dict or a ServingError; callers block in
     `result()` or poll with `done()` (the C ABI's poll entry maps onto
-    exactly this surface)."""
+    exactly this surface).
 
-    __slots__ = ("_event", "_outputs", "_error", "finish_time")
+    ``token_times`` holds one ``perf_counter()`` value per returned
+    token of a generation, written by the decode engine's thread as each
+    token is chosen (empty for anything else); with the request's
+    ``submit_time`` and ``finish_time`` it gives time to first token and
+    the gaps between tokens."""
+
+    __slots__ = ("_event", "_outputs", "_error", "finish_time",
+                 "token_times")
 
     def __init__(self):
         self._event = threading.Event()
         self._outputs = None
         self._error = None
         self.finish_time = None
+        self.token_times = []
+
+    @property
+    def first_token_time(self):
+        """When the first token was chosen, or None before that."""
+        return self.token_times[0] if self.token_times else None
 
     def _complete(self, outputs=None, error=None):
         if self._event.is_set():  # write-once; late completions are bugs

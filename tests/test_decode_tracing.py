@@ -1,0 +1,465 @@
+"""The decode scheduler measured from inside (ISSUE 25).
+
+Hand-stepped ``_iterate`` over a toy decoder: tracing off records nothing,
+reads the tracer's clock never and serves the same bytes; tracing on, every
+phase of an iteration is a span inside one ``decode::iterate``, a request's
+spans carry its id, every returned token has a time stamp, the bytes that
+cross the device boundary are counted, and the overload decisions (brownout
+transitions, refusals at the door) are events with a time. ``RecordEvent``
+and ``span()`` cost a check and nothing else while their gates are off.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+from paddle_tpu import observability as obs
+from paddle_tpu import profiler
+from paddle_tpu.observability import tracer as tracer_mod
+from paddle_tpu.serving.brownout import BrownoutController
+from paddle_tpu.serving.decode import GenerationEngine, build_decoder_model
+from paddle_tpu.serving.decode import engine as engine_mod
+from paddle_tpu.serving.decode.metrics import TOKEN_BUCKETS
+from paddle_tpu.serving.decode.model import DecodeModel
+from paddle_tpu.serving.request import Priority, RejectedError, Response
+
+PROMPT_LENS = (3, 9, 2, 12)     # two one-shot prefills, two chunked (C=4)
+MAX_NEW = 5
+
+# the spans of one iteration, by what they may lie in
+PHASES = {"decode::admit", "decode::prefill", "decode::prefill_fetch",
+          "decode::inject", "decode::chunk", "decode::chunk_fetch",
+          "decode::feeds", "decode::step", "decode::step_fetch",
+          "decode::sample"}
+LAUNCHES = ("decode::step", "decode::prefill", "decode::chunk",
+            "decode::inject")
+WITH_REQUEST = {"decode::admit", "decode::prefill", "decode::prefill_fetch",
+                "decode::inject", "decode::chunk", "decode::chunk_fetch"}
+
+
+def _model(name):
+    # 24 blocks of 4 rows for 4 slots: occupancy stays under the brownout
+    # ladder's first rung, so no transition reads the clock
+    return build_decoder_model(
+        vocab_size=32, hidden=8, num_layers=2, slots=4, max_len=24,
+        block_size=4, chunk_tokens=4, name=name, version="1")
+
+
+def _prompts():
+    rng = np.random.RandomState(3)
+    return [[int(t) for t in rng.randint(0, 32, size=n)]
+            for n in PROMPT_LENS]
+
+
+def _serve(name, traced, **submit):
+    """Four requests hand-stepped to the end; returns (entry, requests'
+    responses). The engine thread is never started."""
+    engine = GenerationEngine(queue_depth=32, breaker_threshold=0)
+    entry = engine.register_model(lambda: _model(name))
+    if traced:
+        obs.enable_tracing()
+    try:
+        resps = [engine.submit(p, max_new_tokens=MAX_NEW, **submit)
+                 for p in _prompts()]
+        for _ in range(400):
+            if all(r.done() for r in resps):
+                break
+            entry._iterate()
+    finally:
+        if traced:
+            obs.disable_tracing()
+    assert all(r.done() for r in resps)
+    return entry, resps
+
+
+@pytest.fixture
+def clean_tracer():
+    obs.get_tracer().clear()
+    yield obs.get_tracer()
+    obs.disable_tracing()
+    obs.get_tracer().clear()
+
+
+@pytest.fixture(scope="module")
+def traced_run():
+    obs.get_tracer().clear()
+    entry, resps = _serve("trc_on", traced=True)
+    spans = obs.get_tracer().spans()
+    obs.get_tracer().clear()
+    return entry, resps, spans
+
+
+class _CountingClock:
+    """Stands in for the ``time`` module of one module and counts the
+    reads of its clocks."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def perf_counter(self):
+        self.reads += 1
+        return time.perf_counter()
+
+    def perf_counter_ns(self):
+        self.reads += 1
+        return time.perf_counter_ns()
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+# -- tracing off ---------------------------------------------------------------
+
+def test_tracing_off_records_nothing_and_serves_the_same_bytes(
+        clean_tracer, traced_run):
+    entry, resps = _serve("trc_off", traced=False)
+    assert clean_tracer.spans() == [] and clean_tracer.instants() == []
+    assert entry._iteration == 0
+    _entry, traced_resps, _spans = traced_run
+    off = [r.result()["tokens"].tobytes() for r in resps]
+    on = [r.result()["tokens"].tobytes() for r in traced_resps]
+    assert off == on
+    for p, r in zip(_prompts(), resps):
+        assert [int(t) for t in r.result()["tokens"]] == \
+            entry.offline_decode(p, MAX_NEW)
+
+
+def test_tracing_off_an_iteration_reads_the_clock_as_the_parent_did_plus_one_per_request(
+        clean_tracer, monkeypatch):
+    """What the engine reads off ``time`` with tracing off: per decode step
+    three (start, the tokens' ``now``, end), per one-shot prefill and per
+    chunk two (start, end), per admitted request its dispatch time and —
+    the one read this instrumentation adds — its first token's stamp. The
+    tracer's own clock is never read."""
+    engine_clock, tracer_clock = _CountingClock(), _CountingClock()
+    monkeypatch.setattr(engine_mod, "time", engine_clock)
+    monkeypatch.setattr(tracer_mod, "time", tracer_clock)
+    entry, resps = _serve("trc_clock", traced=False)
+    m = entry.metrics
+    assert m.count("brownout_transitions") == 0
+    admitted = len(resps)
+    # one more per request: GenerationRequest's submit_time
+    assert engine_clock.reads == (
+        3 * m.count("decode_steps") + 2 * m.count("prefills")
+        + 2 * m.count("chunk_runs") + 2 * admitted + admitted)
+    assert tracer_clock.reads == 0
+
+
+def test_span_off_is_one_shared_noop_and_builds_nothing(clean_tracer):
+    a, b = tracer_mod.span("decode::feeds"), tracer_mod.span("decode::step")
+    assert a is b is tracer_mod._NULL_SPAN
+    with a as sp:
+        assert sp is None
+    assert clean_tracer.spans() == []
+
+
+def test_record_event_reads_no_clock_when_both_gates_are_off(
+        clean_tracer, monkeypatch):
+    clock = _CountingClock()
+    monkeypatch.setattr(profiler, "time", clock)
+    assert not profiler._enabled and not obs.tracing_enabled()
+    with profiler.RecordEvent("decode::step") as ev:
+        assert ev.span is None
+    assert clock.reads == 0
+    # the report, a documented use, still times the event when asked to
+    profiler.reset_profiler()
+    profiler.start_profiler()
+    try:
+        with profiler.RecordEvent("decode::step"):
+            pass
+    finally:
+        report = profiler.stop_profiler()
+    assert clock.reads == 2
+    assert [r["calls"] for r in report if r["name"] == "decode::step"] == [1]
+    profiler.reset_profiler()
+
+
+def test_record_event_hands_out_the_live_span_for_late_arguments(
+        clean_tracer):
+    obs.enable_tracing()
+    with profiler.RecordEvent("decode::inject") as ev:
+        assert ev.span is not None
+        ev.span.set(request=7)
+        ev.span.set(bytes=64)
+    obs.disable_tracing()
+    assert ev.span is None
+    (s,) = clean_tracer.spans()
+    assert s["name"] == "decode::inject" and s["cat"] == "event"
+    assert s["args"] == {"request": 7, "bytes": 64}
+
+
+# -- tracing on: the spans of an iteration ---------------------------------------
+
+def _inside(inner, outer):
+    return (outer["start_ns"] <= inner["start_ns"] and
+            inner["start_ns"] + inner["dur_ns"]
+            <= outer["start_ns"] + outer["dur_ns"])
+
+
+def test_every_phase_lies_inside_one_iteration(traced_run):
+    _entry, _resps, spans = traced_run
+    iterations = [s for s in spans if s["name"] == "decode::iterate"]
+    numbers = [s["args"]["iteration"] for s in iterations]
+    assert numbers == list(range(1, len(iterations) + 1))
+    assert iterations[0]["args"]["queued"] == len(PROMPT_LENS)
+    assert iterations[0]["args"]["active"] == 0
+    assert iterations[1]["args"]["active"] == len(PROMPT_LENS)
+    phases = [s for s in spans if s["name"] != "decode::iterate"]
+    assert {s["name"] for s in phases} == PHASES
+    for s in phases:
+        holders = [it for it in iterations if _inside(s, it)]
+        assert len(holders) == 1, s
+
+
+def test_the_spans_of_a_request_carry_its_id(traced_run):
+    _entry, resps, spans = traced_run
+    by_request = {}
+    for s in spans:
+        if s["name"] in WITH_REQUEST:
+            by_request.setdefault(s["args"]["request"], []).append(s["name"])
+    assert len(by_request) == len(resps)
+    ids = sorted(by_request)            # ids follow the order of submission
+    for rid, n in zip(ids, PROMPT_LENS):
+        names = by_request[rid]
+        assert names.count("decode::admit") == 1
+        if n <= 4:
+            assert names.count("decode::prefill") == 1
+            assert names.count("decode::prefill_fetch") == 1
+            assert names.count("decode::inject") == 1
+            assert "decode::chunk" not in names
+        else:
+            assert names.count("decode::chunk") == -(-n // 4)
+            assert names.count("decode::chunk_fetch") == 1   # the last only
+            assert "decode::prefill" not in names
+    # a launch span's children of the admission lie inside decode::admit
+    admits = [s for s in spans if s["name"] == "decode::admit"]
+    for s in spans:
+        if s["name"] in ("decode::prefill", "decode::prefill_fetch",
+                         "decode::inject"):
+            (holder,) = [a for a in admits if _inside(s, a)]
+            assert holder["args"]["request"] == s["args"]["request"]
+
+
+def test_a_launch_span_says_where_the_host_spent_its_time(traced_run):
+    _entry, _resps, spans = traced_run
+    launches = [s for s in spans if s["name"] in LAUNCHES]
+    assert launches
+    for s in launches:
+        a = s["args"]
+        assert a["bytes"] > 0
+        assert 0 < a["put_ns"] and 0 < a["call_ns"]
+        assert a["put_ns"] + a["call_ns"] <= s["dur_ns"]
+    # no span opens inside a launch span: the device module of a launch
+    # goes to the program span that started last before it
+    for s in launches:
+        assert not [c for c in spans if c is not s and _inside(c, s)
+                    and c["depth"] > s["depth"]]
+    # and none is named like the executor's, which host_step_ms.train sums
+    assert not [s for s in spans
+                if s["name"].startswith(("executor::", "compiled_program::"))]
+
+
+def test_step_spans_carry_their_sizes(traced_run):
+    entry, _resps, spans = traced_run
+    m = entry.model
+    steps = [s for s in spans if s["name"] == "decode::step"]
+    fetches = [s for s in spans if s["name"] == "decode::step_fetch"]
+    samples = [s for s in spans if s["name"] == "decode::sample"]
+    feeds = [s for s in spans if s["name"] == "decode::feeds"]
+    assert len(steps) == len(fetches) == len(samples) \
+        == entry.metrics.count("decode_steps")
+    assert len(feeds) >= len(steps)
+    assert {s["args"]["bytes"] for s in fetches} == {
+        m.slots * 1 * m.vocab_size * 4}
+    assert sum(s["args"]["tokens"] for s in samples) == \
+        entry.metrics.count("generated_tokens")
+    assert all(1 <= s["args"]["active"] <= m.slots for s in feeds
+               if s["args"])
+
+
+# -- a time stamp for every token ---------------------------------------------------
+
+@pytest.mark.parametrize("mode", ["greedy", "beam", "speculative"])
+def test_one_monotone_time_per_returned_token(mode):
+    engine = GenerationEngine(queue_depth=32, breaker_threshold=0)
+    entry = engine.register_model(lambda: _model("tt_" + mode))
+    submit = {}
+    if mode == "beam":
+        submit = {"beam_width": 2}
+    elif mode == "speculative":
+        engine.register_model(lambda: build_decoder_model(
+            vocab_size=32, hidden=8, num_layers=1, slots=4, max_len=24,
+            block_size=4, chunk_tokens=4, name="tt_draft", version="1"))
+        submit = {"draft_model": "tt_draft", "spec_k": 3, "draft_kv": False}
+    before = time.perf_counter()
+    resps = [engine.submit(p, model="tt_" + mode, max_new_tokens=MAX_NEW,
+                           **submit) for p in _prompts()[:3]]
+    with entry._cond:
+        reqs = list(entry._queue.iter_requests())
+    for _ in range(400):
+        if all(r.done() for r in resps):
+            break
+        entry._iterate()
+    for req, r in zip(reqs, resps):
+        assert req.response is r
+        tokens = r.result()["tokens"]
+        assert len(r.token_times) == len(tokens) >= 1
+        assert r.token_times == sorted(r.token_times)
+        assert r.first_token_time == r.token_times[0]
+        assert before <= req.submit_time <= req.dispatch_time \
+            <= r.first_token_time
+        assert r.token_times[-1] <= r.finish_time
+    # observed at retirement, always on
+    st = entry.stats()
+    assert st["first_token_count"] == len(resps)
+    assert st["inter_token_count"] == len(resps)
+    assert st["first_token_avg_s"] > 0
+
+
+def test_a_response_that_generated_nothing_has_no_token_times():
+    r = Response()
+    assert r.token_times == [] and r.first_token_time is None
+    r._complete(outputs={})
+    assert r.first_token_time is None
+
+
+def test_token_histograms_resolve_ten_milliseconds_where_tokens_fall():
+    inner = [b for b in TOKEN_BUCKETS if 0.05 <= b <= 0.5]
+    assert len(inner) == 46
+    assert max(b - a for a, b in zip(inner, inner[1:])) < 0.0101
+    assert list(TOKEN_BUCKETS) == sorted(set(TOKEN_BUCKETS))
+    from paddle_tpu.observability.metrics import Histogram
+
+    h = Histogram("t", buckets=TOKEN_BUCKETS)
+    for v in (0.131, 0.132, 0.133, 0.134, 0.139):
+        h.observe(v)
+    assert 0.13 <= h.quantile(0.5) <= 0.14      # a median, not a mean
+
+
+# -- bytes at the device boundary ---------------------------------------------------
+
+def test_byte_counters_equal_the_nbytes_of_a_hand_built_step():
+    engine = GenerationEngine(queue_depth=8, breaker_threshold=0)
+    entry = engine.register_model(lambda: _model("bytes"))
+    m = entry.model
+    metrics = entry.metrics
+    S, L = m.slots, m.max_len
+    feeds = {
+        DecodeModel.DEC_TOKEN: np.zeros((S, 1), "int64"),
+        DecodeModel.DEC_POSITION: np.zeros((S, 1), "int64"),
+        DecodeModel.DEC_BIAS: np.zeros((S, 1, L), "float32"),
+        DecodeModel.DEC_ROWS: np.zeros((S * L,), "int64"),
+        DecodeModel.DEC_WRITE_ROWS: np.full((S,), m.rows, dtype="int64"),
+    }
+    fed = sum(a.nbytes for a in feeds.values())
+    assert fed == 8 * S + 8 * S + 4 * S * L + 8 * S * L + 8 * S
+    fetches = entry._run("step", feeds)
+    assert metrics.count("fed_bytes") == fed
+    assert metrics.count("step_launches") == 1
+    assert metrics.count("fetched_bytes") == 0      # nothing fetched yet
+    logits = entry._fetch(fetches[0])
+    assert logits.shape == (S, 1, m.vocab_size)
+    assert metrics.count("fetched_bytes") == logits.nbytes \
+        == 4 * S * m.vocab_size
+    # a prefill puts its own feeds and is not a step
+    pre = entry._prefill_feeds([1, 2, 3])
+    entry._run("prefill", pre)
+    assert metrics.count("fed_bytes") == fed + sum(
+        a.nbytes for a in pre.values())
+    assert metrics.count("step_launches") == 1
+
+
+def test_byte_counters_add_up_over_a_served_run(traced_run):
+    entry, _resps, spans = traced_run
+    m = entry.metrics
+    launches = [s for s in spans if s["name"] in LAUNCHES]
+    assert m.count("fed_bytes") == sum(s["args"]["bytes"] for s in launches)
+    fetched = [s for s in spans if s["name"] in (
+        "decode::step_fetch", "decode::prefill_fetch", "decode::chunk_fetch")]
+    assert m.count("fetched_bytes") == sum(
+        s["args"]["bytes"] for s in fetched)
+    assert m.count("step_launches") == m.count("decode_steps")
+
+
+# -- overload decisions on the timeline ----------------------------------------------
+
+def test_the_controller_reads_no_clock_and_takes_the_callers():
+    ctl = BrownoutController()
+    ctl.step(occupancy=0.97)
+    (t,) = ctl.transitions
+    assert "time" not in t and (t["from"], t["to"]) == (0, 4)
+    assert ctl.stamp(0, 12.5) == [t] and t["time"] == 12.5
+    assert ctl.stamp(1, 99.0) == [] and t["time"] == 12.5
+    assert ctl.snapshot()["transitions"][0]["time"] == 12.5
+
+
+def test_a_forced_l4_is_a_timed_transition_and_a_shed_instant(clean_tracer):
+    engine = GenerationEngine(queue_depth=16, breaker_threshold=0)
+    entry = engine.register_model(lambda: _model("shed"))
+    obs.enable_tracing()
+    before = time.perf_counter()
+    # a deferred admission saturates the occupancy signal: straight to L4
+    entry._pending.append(object())
+    try:
+        entry._brownout_tick()
+        assert entry._brownout.level == 4
+        with pytest.raises(RejectedError, match="shedding non-HIGH"):
+            engine.submit([1, 2], max_new_tokens=2, tenant="t1")
+        high = engine.submit([1, 2], max_new_tokens=2,
+                             priority=Priority.HIGH)
+    finally:
+        entry._pending.pop()
+    obs.disable_tracing()
+    after = time.perf_counter()
+    (t,) = entry.stats()["brownout"]["transitions"]
+    assert (t["from"], t["to"], t["trigger"]) == (0, 4, "occupancy")
+    assert before <= t["time"] <= after
+    assert entry.metrics.count("brownout_transitions") == 1
+    assert entry.metrics.count("brownout_shed") == 1
+    events = {e["name"]: e for e in clean_tracer.instants()}
+    assert events["brownout::transition"]["args"] == {
+        "from": 0, "to": 4, "trigger": "occupancy", "value": 1.0}
+    assert events["brownout::shed"]["args"] == {
+        "level": 4, "priority": Priority.NORMAL, "tenant": "t1",
+        "why": "l4_non_high"}
+    assert events["brownout::transition"]["ts_ns"] \
+        <= events["brownout::shed"]["ts_ns"]
+    assert not high.done()
+    engine.shutdown()
+
+
+def test_the_ladder_itself_did_not_change():
+    """Thresholds, hysteresis and who is shed are the parent's."""
+    ctl = BrownoutController()
+    assert ctl.enter == (0.60, 0.75, 0.85, 0.95)
+    assert ctl.exit == (0.45, 0.60, 0.70, 0.80) and ctl.hold == 3
+    levels = [ctl.step(queue_seconds=v)
+              for v in (0.2, 0.96, 0.5, 0.5, 0.5, 0.5, 0.5, 0.5)]
+    assert levels == [0, 4, 4, 4, 3, 3, 3, 2]
+    assert [set(t) for t in ctl.transitions] == [
+        {"step", "from", "to", "trigger", "value"}] * 3
+
+
+# -- the tracer ---------------------------------------------------------------------
+
+def test_span_arguments_set_late_and_elapsed_time(clean_tracer):
+    obs.enable_tracing()
+    with tracer_mod.span("decode::feeds") as sp:
+        assert isinstance(sp, obs.trace_scope)
+        first = sp.elapsed_ns()
+        sp.set(active=3)
+        sp.set(beam_groups=0)
+        assert sp.elapsed_ns() >= first >= 0
+    obs.disable_tracing()
+    (s,) = clean_tracer.spans()
+    assert s["args"] == {"active": 3, "beam_groups": 0}
+    assert s["dur_ns"] >= first
+
+
+def test_no_annotation_is_opened_without_a_running_profiler(clean_tracer):
+    obs.enable_tracing()
+    with obs.trace_scope("decode::step", request=1) as sp:
+        assert sp._ann is None
+    obs.disable_tracing()
+    assert [s["name"] for s in clean_tracer.spans()] == ["decode::step"]
