@@ -297,7 +297,10 @@ def expand(x, expand_times, name=None):
 
 
 def batched_gather(x, index, name=None):
-    """X [B, S, ...] + Index [B, P] -> [B, P, ...] (rows per batch)."""
+    """X [B, S, ...] + Index [B, P] -> [B, P, ...]: for each sample, the P
+    rows of X that Index names (BERT's masked positions). Whole rows move:
+    B*P contiguous copies forward and B*P row adds into X's gradient, with
+    positions named twice summed in float32; Index gets no gradient."""
     helper = LayerHelper("batched_gather", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(

@@ -449,10 +449,23 @@ def _print(ins, attrs):
 def _batched_gather(ins, attrs):
     """Per-row gather along axis 1: X [B, S, ...] + Index [B, P] ->
     [B, P, ...] (the masked-position gather BERT-style pretraining needs;
-    the reference reaches the same result with LoD + sequence ops)."""
+    the reference reaches the same result with LoD + sequence ops).
+
+    ROWS move, not elements: the index stays [B, P, 1, ...], so the
+    gather's slice covers every trailing dimension of X (B*P contiguous
+    copies) and its vjp is a row scatter-add. Broadcasting the index to
+    the output's shape instead makes every element its own fetch and the
+    gradient an element scatter behind a sort over B*P*H indices (a fifth
+    of BERT-base's step on a v5e, PERF.md PR 26). A float X narrower than
+    float32 goes through float32, which is exact forward and keeps
+    positions named twice in one row from summing their gradients in
+    bfloat16."""
     x = first(ins, "X")
     idx = first(ins, "Index").astype(jnp.int32)
     idx_e = idx.reshape(idx.shape + (1,) * (x.ndim - 2))
-    return {"Out": [jnp.take_along_axis(
-        x, jnp.broadcast_to(idx_e, idx.shape + x.shape[2:]), axis=1
-    )]}
+    wide = x
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        wide = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    return {"Out": [
+        jnp.take_along_axis(wide, idx_e, axis=1).astype(x.dtype)
+    ]}

@@ -331,6 +331,40 @@ def tensors_containing_dims(tensors, dims):
     return out
 
 
+_OP_SIGNATURE = re.compile(r":\s*\((tensor<[^)]*)\)\s*->")
+_WINDOW_DIMS = re.compile(r"(?:offset_dims|update_window_dims) = \[[0-9]")
+_MOVER = re.compile(r"stablehlo\.(gather|scatter|sort)\b")
+
+
+def element_granular_movers(stablehlo_text, hidden, sort_elements):
+    """(kind, operand dims) of every ``stablehlo.gather`` /
+    ``stablehlo.scatter`` that moves ONE element per index (no offset /
+    update-window dimension) on an operand that carries the ``hidden``
+    size, and of every ``stablehlo.sort`` over ``sort_elements`` elements
+    or more. A row gather of X [B, S, H] has ``offset_dims = [2]`` and its
+    transpose ``update_window_dims = [2]``; a full-shape index (one index
+    per OUTPUT element) has neither, and B*P*H single fetches and
+    single-element adds follow (tests/test_hlo.py)."""
+    out = []
+    lines = stablehlo_text.splitlines()
+    for i, line in enumerate(lines):
+        m = _MOVER.search(line)
+        if not m:
+            continue
+        typed = line
+        if "({" in line:    # scatter, sort: the types close the region
+            typed = next(ln for ln in lines[i:]
+                         if ln.lstrip().startswith("})"))
+        operand = stablehlo_tensors(
+            _OP_SIGNATURE.search(typed).group(1))[0][0]
+        if m.group(1) == "sort":
+            if int(np.prod(operand)) >= sort_elements:
+                out.append(("sort", operand))
+        elif hidden in operand and not _WINDOW_DIMS.search(line):
+            out.append((m.group(1), operand))
+    return out
+
+
 def stablehlo_dots(text):
     """(lhs, rhs, out) tensor types for every dot_general in StableHLO."""
     dots = []

@@ -31,6 +31,8 @@ from paddle_tpu.utils import hlo
 S = 512  # long enough that S x S is unambiguous against model dims
 VOCAB = 30522
 P_PRED = 77
+HIDDEN = 768   # bert_train_step_text's defaults
+BATCH = 4
 
 
 def _lower_bert(flash):
@@ -71,6 +73,39 @@ def test_masked_head_no_s_by_vocab(bert_flash_stablehlo):
     tensors = hlo.stablehlo_tensors(bert_flash_stablehlo)
     sxv = hlo.tensors_containing_dims(tensors, (S, VOCAB))
     assert not sxv, f"[S, V]-sized tensors present: {set(sxv)}"
+
+
+def test_masked_gather_moves_rows(bert_flash_stablehlo):
+    """The masked-position gather and its gradient move ROWS of H: no
+    gather / scatter of the step fetches (adds) one element per index on a
+    tensor that carries the hidden size, and nothing sorts B*P*H element
+    indices. On a v5e the element form was 88.6 ms of BERT-base's 404 ms
+    step (PERF.md, PR 26)."""
+    found = hlo.element_granular_movers(
+        bert_flash_stablehlo, hidden=HIDDEN,
+        sort_elements=BATCH * P_PRED * HIDDEN,
+    )
+    assert not found, f"element-at-a-time movers over H: {found}"
+
+
+def test_element_mover_detector_fires():
+    """Positive control: an index broadcast to the output's shape DOES
+    lower to the element gather and the element scatter-add (and a sort
+    that long is seen) — if this stops firing, the gate above proves
+    nothing."""
+    import jax.numpy as jnp
+
+    def broadcast_index_form(x, idx):
+        full = jnp.broadcast_to(idx[:, :, None], idx.shape + x.shape[2:])
+        picked = jnp.take_along_axis(x, full, axis=1)
+        return jnp.sum(jnp.sort(picked.reshape(-1)))
+
+    txt = jax.jit(jax.grad(broadcast_index_form)).lower(
+        jnp.zeros((BATCH, S, HIDDEN)), jnp.zeros((BATCH, P_PRED), "int32")
+    ).as_text()
+    kinds = {k for k, _ in hlo.element_granular_movers(
+        txt, hidden=HIDDEN, sort_elements=BATCH * P_PRED * HIDDEN)}
+    assert kinds == {"gather", "scatter", "sort"}, kinds
 
 
 def test_amp_all_dots_bf16(bert_flash_stablehlo):
@@ -130,6 +165,25 @@ def test_dp_mesh_collectives():
     c = hlo.count_collectives(txt)
     assert c["all-reduce"] >= 1, f"no gradient all-reduce in DP step: {c}"
     assert c["all-to-all"] == 0, f"unexpected all-to-all in DP step: {c}"
+
+
+def test_dp_mesh_masked_gather_stays_local():
+    """The masked-position gather has its batching dimension on the
+    sharded axis, so GSPMD partitions it and its scatter-add gradient with
+    no collective: nothing of [B, S, H] or [B, P, H] size (per shard or
+    global, flattened or not) rides a collective of the dp step."""
+    assert jax.device_count() >= 8
+    b, s, p, h = 16, 24, 5, 64          # tiny BERT: hidden 64
+    txt = hlo.tiny_bert_parallel_text(
+        (8,), ("data",), seq_len=s, batch=b, max_pred=p)
+    assert hlo.count_collectives(txt)["all-reduce"] >= 1
+    activations = set()
+    for rows in (b, b // 8):
+        for n in (s, p):
+            activations |= {(rows, n, h), (rows * n, h)}
+    # the helper matches any given full shape on a collective's line
+    moved = hlo.weight_shaped_collectives(txt, activations)
+    assert not moved, f"activation-sized collectives in the DP step: {moved}"
 
 
 @pytest.fixture(scope="module")

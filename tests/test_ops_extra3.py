@@ -1146,3 +1146,81 @@ def test_unique_layers(rng):
     np.testing.assert_array_equal(np.asarray(ov)[iv], arr)
     # counts for the 3 real uniques (front-compacted, sorted: 1, 2, 3)
     assert cv[:3].tolist() == [2, 1, 3]
+
+
+# ---------------------------------------------------------------------------
+# batched_gather: rows move, forward and backward (PR 26)
+# ---------------------------------------------------------------------------
+
+
+def _batched_gather_case(rng, trailing, x_dtype, idx_dtype):
+    B, S, P = 3, 16, 24
+    x = jnp.asarray(rng.randn(B, S, *trailing), x_dtype)
+    idx = rng.randint(0, S, (B, P))
+    idx[0, :] = 5             # one row names a single position P times
+    idx[1, 4:20] = 0          # padding slots that repeat position 0
+    # a cotangent of one sign, so that a sum over duplicates grows and a
+    # low-precision accumulator has to round at every step
+    g = jnp.asarray(rng.uniform(1.0, 2.0, (B, P) + trailing), x_dtype)
+    return x, idx.astype(idx_dtype), g
+
+
+def _batched_gather_grad(x, idx, g):
+    """The synthesized grad op's outputs, as the executor would call it."""
+    from paddle_tpu.core.backward import resolve_op_def
+
+    return resolve_op_def("batched_gather_grad").lower(
+        {"X": [jnp.asarray(x)], "Index": [jnp.asarray(idx)],
+         "Out@GRAD": [g]},
+        {"__fwd_inputs__": ["X", "Index"], "__fwd_outputs__": ["Out"]},
+    )
+
+
+@pytest.mark.parametrize("idx_dtype", ["int64", "int32"])
+@pytest.mark.parametrize("x_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("trailing", [(), (8,), (3, 4)],
+                         ids=["rank2", "rank3", "rank4"])
+def test_batched_gather_moves_rows(rng, trailing, x_dtype, idx_dtype):
+    """Forward equals np.take_along_axis bit for bit; the synthesized
+    batched_gather_grad equals the dense one-hot contraction (float64),
+    duplicates included: to float32 tolerance for float32 X, to one bf16
+    ulp of the exact sum for bfloat16 X (so duplicates are NOT summed in
+    bfloat16); Index gets no gradient."""
+    x, idx, g = _batched_gather_case(rng, trailing, x_dtype, idx_dtype)
+    out = lower("batched_gather", {"X": [x], "Index": [idx]})["Out"][0]
+    assert out.dtype == x.dtype and out.shape == idx.shape + trailing
+    idx_e = idx.reshape(idx.shape + (1,) * len(trailing))
+    np.testing.assert_array_equal(
+        np.asarray(out.astype(jnp.float32)),
+        np.take_along_axis(np.asarray(x.astype(jnp.float32)), idx_e, axis=1),
+    )
+
+    grads = _batched_gather_grad(x, idx, g)
+    assert set(grads) == {"X@GRAD"}
+    (dx,) = grads["X@GRAD"]
+    assert dx.dtype == x.dtype and dx.shape == x.shape
+    onehot = np.eye(x.shape[1], dtype="float64")[idx]          # [B, P, S]
+    want = np.einsum("bps,bp...->bs...", onehot,
+                     np.asarray(g.astype(jnp.float32), "float64"))
+    got = np.asarray(dx.astype(jnp.float32), "float64")
+    if x_dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+    else:
+        # one bf16 ulp of v > 0 is 2**floor(log2 v) * eps; where nothing
+        # was gathered both sides are exactly 0
+        ulp = (2.0 ** np.floor(np.log2(np.maximum(want, 1e-30)))
+               * float(jnp.finfo(jnp.bfloat16).eps))
+        assert np.all(np.abs(got - want) <= ulp), (
+            np.abs(got - want).max(), ulp.max())
+
+
+@pytest.mark.parametrize("x_dtype", ["int32", "int64"])
+def test_batched_gather_integer_x(rng, x_dtype):
+    """An integer X is gathered exactly and has no grad op outputs."""
+    x = rng.randint(-9, 9, (2, 7, 5)).astype(x_dtype)
+    idx = rng.randint(0, 7, (2, 4)).astype("int64")
+    out = lower("batched_gather", {"X": [x], "Index": [idx]})["Out"][0]
+    np.testing.assert_array_equal(
+        np.asarray(out), np.take_along_axis(x, idx[:, :, None], axis=1))
+    assert out.dtype == jnp.asarray(x).dtype
+    assert _batched_gather_grad(x, idx, out) == {}
