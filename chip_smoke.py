@@ -5,7 +5,8 @@ their public entry points at full width, then — when the host has four
 chips — the same training program over a mesh:
 
   kernels    each registered Pallas kernel against its reference at the
-             shape the legs use (flash fwd + three grads; cached decode)
+             shape the legs use (flash fwd + three grads; cached decode;
+             paged decode at ragged lengths)
   train      models.bert.build_bert_pretrain(BertConfig.base(), s128, bf16
              AMP, gathered MLM head) -> fluid.Executor(TPUPlace(0)) at
              batch 256: flash attention through the registry's default
@@ -241,6 +242,34 @@ def leg_kernels(sizes, interpret):
         "shape": {"slots": Sl, "length": L, "hidden": Hd},
         "max_err_rel_to_max": err,
         "bit_identical_to_composite": got.tobytes() == ref.tobytes(),
+    }
+
+    # the blocked paged kernel at the serve leg's geometry, ragged lengths
+    # (a free slot, a full one), against its composite at full precision
+    from paddle_tpu import kernels
+
+    p = sizes["serve"]
+    Sp, Lp, bs, Hp = p["slots"], p["max_len"], p["block_size"], p["hidden"]
+    lengths = [0, 1, bs - 1, bs, bs + 1, Lp // 3, Lp - 1, Lp][:Sp]
+    lengths += [Lp // 2] * (Sp - len(lengths))
+    args = kernels._paged_case(rng, Sp, Lp, bs, Hp, lengths)
+    sm = 1.0 / float(np.sqrt(Hp))
+    before = _kernel_fallbacks()
+    got = np.asarray(jax.jit(lambda *a: attention.paged_attention(
+        *a, Sp, Lp, bs, sm, interpret=interpret))(*args))
+    if _kernel_fallbacks() != before:
+        raise AssertionError("paged_attention gave way to its composite")
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(jax.jit(
+            lambda *a: attention.paged_attention_composite(
+                *a, Sp, Lp, sm))(*args))
+    live = np.asarray(lengths) > 0
+    err = float(np.abs(got[live] - ref[live]).max() / np.abs(ref[live]).max())
+    if not np.isfinite(got).all() or got[~live].any() or err > 1e-4:
+        raise AssertionError(f"paged_attention: rel err {err}")
+    out["paged_attention"] = {
+        "shape": {"slots": Sp, "length": Lp, "block": bs, "hidden": Hp},
+        "lengths": lengths, "max_err_rel_to_max": err,
     }
     return out
 

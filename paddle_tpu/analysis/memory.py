@@ -225,9 +225,9 @@ def estimate_peak_hbm(program, *, feed_shapes=None, fetch_names=(),
             return 0
         use_kernel = kernel_path
         if use_kernel is None:
-            from paddle_tpu.kernels import registry as _kr
+            from paddle_tpu import kernels as _k
 
-            use_kernel = _kr.selected(op.type) is not None
+            use_kernel = _k.selected_for(op.type, op.attrs) is not None
         if use_kernel:
             return 0
         from paddle_tpu.kernels import fallback_internal_bytes

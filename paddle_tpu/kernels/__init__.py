@@ -9,6 +9,7 @@ kernel               serves                          parity    activation
 ==================== ============================== ========= =========
 flash_attention      scaled_dot_product_attention    tolerance mode
 cached_attention     cached_attention (decode [S,1]) bit       mode
+paged_attention      paged_attention (decode [S,1])  tolerance mode
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
 ==================== ============================== ========= =========
 
@@ -32,18 +33,28 @@ from paddle_tpu.kernels.registry import (  # noqa: F401
 __all__ = [
     "MODE_ENV", "KernelSpec", "all_specs", "get", "has", "kernel_sig",
     "mode", "probe", "register", "registry_fingerprint", "resolved_mode",
-    "scoped_mode", "selected", "fallback_counter",
+    "scoped_mode", "selected", "selected_for", "fallback_counter",
     "fallback_internal_bytes",
 ]
 
 
+def selected_for(op_type, attrs):
+    """``selected(op_type)`` for one op as it is written: the paged kernel
+    walks a block table it derives from ``Rows``, so it serves only an op
+    that names its ``block_size`` (``[R, H]`` does not carry it; a program
+    serialized before the attribute existed runs the composite). The
+    lowering and ``analysis/memory.py`` both ask here."""
+    if op_type == "paged_attention" and not attrs.get("block_size"):
+        return None
+    return selected(op_type)
+
+
 def fallback_internal_bytes(op_type, attrs, shape_of, itemsize=4):
     """HBM bytes the COMPOSITE lowering of a fused attention op
-    materializes that a kernel would keep in VMEM — what
+    materializes that a kernel keeps in VMEM — what
     ``analysis/memory.py`` adds to the peak estimate when no kernel
-    serves the op (always, for ``paged_attention``: it has none yet).
-    ``shape_of(slot)`` resolves an input slot's static shape (None when
-    unknown)."""
+    serves the op. ``shape_of(slot)`` resolves an input slot's static
+    shape (None when unknown)."""
     if op_type == "paged_attention":
         q = shape_of("Q")
         if q is None:
@@ -174,6 +185,78 @@ def _parity_cached(rng):
     _assert_bytes_equal(got, ref, "cached_attention")
 
 
+def _paged_case(rng, seqs, length, block, hidden, lengths, share=(),
+                shuffle=True):
+    """A paged-attention problem as the engine feeds it: a pool of
+    ``seqs * ceil(length / block)`` blocks, block-aligned row maps (zero
+    past a slot's live blocks), prefix-opening biases. ``share`` lists
+    ``(slot, other, blocks)``: ``slot`` reads ``other``'s first blocks."""
+    per = -(-length // block)
+    pool = seqs * per
+    ids = (rng.permutation(pool) if shuffle else np.arange(pool))
+    ids = ids.reshape(seqs, per)
+    for slot, other, n in share:
+        ids[slot, :n] = ids[other, :n]
+    rows = np.zeros((seqs, length), "int64")
+    bias = np.full((seqs, 1, length), -1e9, "float32")
+    for s, n in enumerate(lengths):
+        for i in range(-(-n // block)):
+            lo, hi = i * block, min((i + 1) * block, length)
+            rows[s, lo:hi] = ids[s, i] * block + np.arange(hi - lo)
+        bias[s, 0, :n] = 0.0
+    draw = lambda *shape: rng.randn(*shape).astype("float32")
+    return (draw(seqs, hidden), draw(pool * block, hidden),
+            draw(pool * block, hidden), rows.reshape(-1), bias)
+
+
+def _paged_both(args, seqs, length, block, hidden):
+    """(kernel through the interpreter, composite) on one ``_paged_case``,
+    under jit on both sides, as numpy."""
+    import jax
+
+    from paddle_tpu.kernels import attention as A
+
+    sm = 1.0 / float(np.sqrt(hidden))
+    got = jax.jit(lambda *a: A.paged_attention(
+        *a, seqs, length, block, sm, interpret=True))(*args)
+    ref = jax.jit(lambda *a: A.paged_attention_composite(
+        *a, seqs, length, sm))(*args)
+    return np.asarray(got), np.asarray(ref)
+
+
+def _parity_paged(rng):
+    """Ragged lengths in one batch (a free slot among them), two slots
+    sharing prefix blocks, the block table out of order;
+    tests/test_kernels.py holds more geometries."""
+    S, L, bs, H = 6, 64, 16, 128
+    lengths = [1, 15, 16, 17, 64, 0]
+    args = _paged_case(rng, S, L, bs, H, lengths, share=[(3, 4, 1)])
+    got, ref = _paged_both(args, S, L, bs, H)
+    live = np.asarray(lengths) > 0
+    _assert_close_both_ways(got[live], ref[live], "paged_attention",
+                            1e-5, 1e-5)
+    # a free slot's bias is all -1e9: the composite averages garbage rows
+    # there (not compared); the kernel reads nothing and writes zeros
+    assert not got[~live].any()
+
+
+def _tpu_cases_paged():
+    """The serving cell's geometry (decoder_1024x24: 48 slots x 1,024
+    positions, block 16, 49,152 arena rows, hidden 1,024, float32)."""
+    from paddle_tpu.kernels import attention as A
+
+    S, L, bs, H = 48, 1024, 16, 1024
+    R = S * L
+
+    def fwd(q, k, v, rows, bias):
+        return A.paged_attention(q, k, v, rows, bias, S, L, bs, 1.0 / 32.0,
+                                 interpret=False)
+
+    return [("s48_l1024_b16_h1024", fwd, [
+        ((S, H), "float32"), ((R, H), "float32"), ((R, H), "float32"),
+        ((S * L,), "int32"), ((S, 1, L), "float32")])]
+
+
 def _parity_remat(rng):
     import jax
     import jax.numpy as jnp
@@ -213,6 +296,12 @@ register(KernelSpec(
     tpu_cases=_tpu_cases_cached,
     doc="fused [S,1] decode attention over a dense slotted cache "
         "(kernels/attention.py)",
+))
+register(KernelSpec(
+    "paged_attention", ("paged_attention",), "tolerance", _parity_paged,
+    tpu_cases=_tpu_cases_paged,
+    doc="blocked [S,1] decode attention over the live blocks of a paged "
+        "arena, online softmax (kernels/attention.py)",
 ))
 register(KernelSpec(
     "remat_policy", ("recompute_segment", "recompute_segment_grad"),

@@ -699,22 +699,28 @@ def cached_attention(q, k_cache, v_cache, attn_bias, sm_scale=1.0,
 
 
 def paged_attention(q, k_arena, v_arena, rows, attn_bias, seqs, length,
-                    sm_scale=1.0, name=None):
+                    sm_scale=1.0, block_size=None, name=None):
     """Fused paged attention: ``q`` ``[S, H]`` attends over rows of the
     flat ``[R, H]`` block arenas addressed by the ``[S * L]`` row feed —
     ``block_gather(k) ; block_gather(v) ; cached_attention`` as ONE op.
-    The lowering is that exact composite (bit-identical for any block
-    size); the dense ``[S, L, H]`` gather views materialize in HBM until
-    the blocked kernel of ROADMAP 1.5 serves this op."""
+    The reference lowering is that exact composite. ``block_size`` says
+    that ``rows`` is block-aligned (every ``block_size`` positions of a
+    slot name consecutive arena rows from a multiple of ``block_size``):
+    with it the kernel registry may serve the op by the blocked kernel of
+    kernels/attention.py, which reads each slot's live blocks in place
+    and never writes the dense ``[S, L, H]`` views."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {"sm_scale": float(sm_scale), "seqs": int(seqs),
+             "length": int(length)}
+    if block_size:
+        attrs["block_size"] = int(block_size)
     helper.append_op(
         "paged_attention",
         {"Q": [q.name], "KArena": [k_arena.name], "VArena": [v_arena.name],
          "Rows": [rows.name], "Bias": [attn_bias.name]},
         {"Out": [out.name]},
-        {"sm_scale": float(sm_scale), "seqs": int(seqs),
-         "length": int(length)},
+        attrs,
     )
     return out
 

@@ -538,9 +538,10 @@ OpRegistry.register(
 # ---------------------------------------------------------------------------
 # fused decode attention (paddle_tpu/kernels/): cached (dense slotted) and
 # paged (block-arena row feeds). The reference lowerings ARE the composite
-# primitive sequences the old layer composites emitted — bit-identity
-# between kernel-on and kernel-off paths is by shared definition
-# (kernels/attention.py), not by test luck.
+# primitive sequences the old layer composites emitted. The cached kernel
+# is that sequence verbatim (bit-identical by shared definition); the
+# paged kernel is an online softmax over the live blocks, held to the
+# composite within a tolerance (kernels/attention.py).
 # ---------------------------------------------------------------------------
 
 
@@ -588,10 +589,27 @@ def _paged_attention_reference(ins, attrs):
         attrs.get("sm_scale", 1.0))]}
 
 
+def _paged_attention_pallas(ins, attrs):
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import attention as fused
+
+    sel = kernels.selected_for("paged_attention", attrs)
+    if sel is None:
+        return _paged_attention_reference(ins, attrs)
+    q = first(ins, "Q")
+    ka, va = first(ins, "KArena"), first(ins, "VArena")
+    rows, bias = first(ins, "Rows"), first(ins, "Bias")
+    return {"Out": [fused.paged_attention(
+        q, ka, va, rows, bias, attrs["seqs"], attrs["length"],
+        attrs["block_size"], attrs.get("sm_scale", 1.0),
+        interpret=sel.interpret)]}
+
+
 OpRegistry.register(
     OpDef(
         "paged_attention",
         _paged_attention_reference,
+        pallas=_paged_attention_pallas,
         nondiff_inputs=("Rows", "Bias"),
     )
 )

@@ -1939,6 +1939,7 @@ class _ModelEntry:
                  if m.logits_mask else None)
         active = []
         groups = []     # beam groups with a live slot this step
+        live_blocks = 0
         for s in range(S):
             st = self._slots[s]
             if st is None or st.mode not in ("decode", "beam"):
@@ -2002,12 +2003,15 @@ class _ModelEntry:
             bias[s, 0, :st.cursor + 1] = 0.0
             rows[s] = st.row_map
             wrows[s] = self._row_of(st, st.cursor)
+            live_blocks += st.cursor // m.block_size + 1
             if dmask is not None and st.grammar is not None:
                 # the grammar's next-token constraint rides in as DATA —
                 # same compiled program for every request, zero retraces
                 dmask[s, 0] = st.grammar.mask()
         if not active and not groups:
             return None
+        self._metrics.observe_blocks(
+            live_blocks, S * -(-L // m.block_size))
         feeds = {DecodeModel.DEC_TOKEN: tok, DecodeModel.DEC_POSITION: pos,
                  DecodeModel.DEC_BIAS: bias,
                  DecodeModel.DEC_ROWS: rows.reshape(-1),

@@ -14,7 +14,9 @@ What a caller waits for, from the per-token time stamps on ``Response``:
 ``serving_decode_inter_token_seconds`` (the mean gap between a request's
 tokens), observed at retirement. What crosses the device boundary:
 ``serving_fed_bytes_total`` / ``serving_fetched_bytes_total``, with
-``serving_step_launches_total`` to put them per decode step.
+``serving_step_launches_total`` to put them per decode step. What a step's
+attention has to read: ``serving_decode_live_blocks_total`` over
+``serving_decode_block_slots_total``.
 """
 
 from paddle_tpu.serving.metrics import ServingMetrics
@@ -74,6 +76,10 @@ class DecodeMetrics(ServingMetrics):
         # bytes of the fetches brought back to the host, and the launches
         # of the decode-step program (the denominator for "per step")
         "fed_bytes", "fetched_bytes", "step_launches",
+        # what the paged-attention kernel has to read: blocks that hold
+        # a stepping slot's positions up to its cursor, over every block
+        # of every slot (what the whole-arena gather read)
+        "decode_live_blocks", "decode_block_slots",
     )
 
     def __init__(self, engine_label=None, registry=None):
@@ -125,6 +131,13 @@ class DecodeMetrics(ServingMetrics):
         self.incr("fed_bytes", fed_bytes)
         if kind == "step":
             self.incr("step_launches")
+
+    def observe_blocks(self, live, slots):
+        """One decode step's feeds: ``live`` blocks hold its stepping
+        slots' positions up to their cursors, of ``slots`` block slots
+        (S x blocks per slot) in the step's row map."""
+        self.incr("decode_live_blocks", live)
+        self.incr("decode_block_slots", slots)
 
     def observe_tokens(self, request):
         """At retirement: the request's time to first token and the mean

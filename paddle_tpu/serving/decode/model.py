@@ -240,11 +240,12 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
 
     ``fused_attention`` (default True) routes the decode step's
     attention through ONE ``paged_attention`` op — the row-index feeds
-    enter the op directly, which is the seam the blocked paged kernel
-    of ROADMAP 1.5 will serve. Today the op lowers to the exact
-    gather+attention composite, so tokens are BIT-identical to
-    ``fused_attention=False`` (the pre-r15 op sequence, kept for the
-    DECODE_EVIDENCE_r13 static recompute).
+    and the block size enter the op directly. Its reference lowering is
+    the exact gather+attention composite (the CPU path and the ``off``
+    path: BIT-identical to ``fused_attention=False``, the pre-r15 op
+    sequence kept for the DECODE_EVIDENCE_r13 static recompute); on a
+    TPU the blocked kernel of kernels/attention.py serves it, reading
+    each slot's live blocks alone, within 1e-5 of the composite.
     """
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
@@ -346,7 +347,7 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
             if fused_attention:
                 ctx = fluid.layers.paged_attention(
                     fluid.layers.squeeze(q, [1]), nk, nv, rows, bias,
-                    S, L, sm_scale=sm_scale)
+                    S, L, sm_scale=sm_scale, block_size=BS)
             else:
                 gk = fluid.layers.block_gather(nk, rows, S, L)
                 gv = fluid.layers.block_gather(nv, rows, S, L)

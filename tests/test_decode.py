@@ -96,14 +96,15 @@ def test_continuous_decode_matches_offline_any_admission_order(served):
                 f"offline {refs[i]}")
 
 
-def test_decode_modes_bit_identical_kernels_on_vs_off():
+def test_decode_modes_tokens_equal_kernels_on_vs_off():
     """Paged, chunked, and speculative decode under the kernel registry's
-    "interpret" mode (Pallas kernels through the interpreter) vs "off"
-    (composite fallbacks), with SHUFFLED admission orders: every
-    request's tokens equal the offline reference, and the two modes are
-    byte-identical to each other — the fused paged-attention kernel is
-    the exact composite primitive sequence, held here against the real
-    engine."""
+    "interpret" mode (the blocked paged-attention kernel through the
+    Pallas interpreter) vs "off" (the composite), with SHUFFLED admission
+    orders: every request's tokens equal the offline reference in both
+    modes, so the two modes' tokens equal each other. The kernel is an
+    online softmax, within 1e-5 of the composite and not its bytes
+    (tests/test_kernels.py; the logits are held to that tolerance, and
+    replay to bytes, in tests/test_paged_kernel_engine.py)."""
     from paddle_tpu import kernels
 
     rng = np.random.RandomState(11)
@@ -146,8 +147,8 @@ def test_decode_modes_bit_identical_kernels_on_vs_off():
             engine.shutdown()
             return outs
 
-    # different admission orders per mode pair: bit-identity must hold
-    # regardless of slot assignment/batchmates (the PR-13 property)
+    # different admission orders per mode pair: the tokens must not depend
+    # on slot assignment or batchmates (the PR-13 property)
     assert drive("off", 0) == drive("interpret", 1)
     assert drive("interpret", 2) == drive("off", 3)
 

@@ -88,6 +88,37 @@ def test_masked_gather_moves_rows(bert_flash_stablehlo):
     assert not found, f"element-at-a-time movers over H: {found}"
 
 
+@pytest.mark.parametrize("mode, views", [("interpret", False),
+                                         ("off", True)])
+def test_paged_decode_step_never_writes_the_gathered_arena(mode, views):
+    """The decode step lowered with the paged kernel selected holds no
+    value of ``[S * L, H]`` or ``[S, L, H]``: a slot's live blocks are
+    read in place, block by block. The composite ("off": the positive
+    control, so the detector is known to fire) gathers both views in
+    every layer. On a v5e those gathers were ~100 of a 104 ms step
+    (PERF.md, PR 29)."""
+    from paddle_tpu import kernels
+    from paddle_tpu.serving.decode import build_decoder_model
+
+    S, L, H = 4, 96, 24
+    # 30 blocks of 8 rows: the arena itself is [240, H], so [S * L, H]
+    # can only be a gathered view
+    m = build_decoder_model(vocab_size=40, hidden=H, num_layers=2, slots=S,
+                            max_len=L, block_size=8, num_blocks=30,
+                            name=f"hlo_{mode}", version="1")
+    feed = {n: np.zeros(shape, dtype)
+            for n, shape, dtype in m.decode_feed_sig()}
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope), kernels.scoped_mode(mode):
+        fluid.Executor(fluid.CPUPlace()).run(m.startup_program)
+        txt = hlo.lower_program_step(
+            m.decode_program, feed, [m.logits_fetch], scope=scope).as_text()
+    tensors = hlo.stablehlo_tensors(txt)
+    dense = (hlo.tensors_with_trailing(tensors, (S * L, H))
+             + hlo.tensors_with_trailing(tensors, (S, L, H)))
+    assert bool(dense) == views, (mode, set(dense))
+
+
 def test_element_mover_detector_fires():
     """Positive control: an index broadcast to the output's shape DOES
     lower to the element gather and the element scatter-add (and a sort

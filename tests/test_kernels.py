@@ -45,7 +45,8 @@ def test_registration_requires_parity_check():
 def test_every_kernel_spec_is_complete():
     specs = kernels.all_specs()
     assert {s.name for s in specs} >= {
-        "flash_attention", "cached_attention", "remat_policy",
+        "flash_attention", "cached_attention", "paged_attention",
+        "remat_policy",
     }
     for s in specs:
         assert s.op_types, s.name
@@ -169,9 +170,10 @@ def test_mode_flip_retraces_and_stays_bit_identical(rng):
 
 def test_paged_memory_accounting_orders():
     """kernel-path < composite-path, the gap is (at least ~) the dense
-    gather views — and the LIVE estimate counts them under every mode:
-    paged_attention has no kernel (ROADMAP 1.5), its composite is the
-    one path."""
+    gather views — and the LIVE estimate follows the registry: the
+    composite's views are charged where the composite runs ("off", and a
+    paged op that names no block size), not where the blocked kernel
+    serves the op."""
     from paddle_tpu.analysis.memory import estimate_peak_hbm
     from paddle_tpu.serving.decode import build_decoder_model
 
@@ -197,7 +199,116 @@ def test_paged_memory_accounting_orders():
     with kernels.scoped_mode("interpret"):
         live_k = estimate_peak_hbm(m.decode_program, feed_shapes=fs,
                                    fetch_names=[m.logits_fetch])
-    assert live_k.peak_total_bytes == comp.peak_total_bytes
+        assert live_k.peak_total_bytes == kern.peak_total_bytes
+        # an op without the attribute (a program serialized before the
+        # kernel) lowers to the composite under every mode
+        for op in m.decode_program.global_block().ops:
+            if op.type == "paged_attention":
+                del op.attrs["block_size"]
+        old = estimate_peak_hbm(m.decode_program, feed_shapes=fs,
+                                fetch_names=[m.logits_fetch])
+    assert old.peak_total_bytes == comp.peak_total_bytes
+
+
+# ---------------------------------------------------------------------------
+# the blocked paged kernel against its composite
+# ---------------------------------------------------------------------------
+
+#: (seqs, length, block, hidden, lengths, share): ragged lengths around a
+#: block's and a group's edges, a full slot, free slots, shared prefix
+#: blocks, a length that is no multiple of the block, one block a group
+PAGED_CASES = {
+    "ragged": (6, 64, 16, 128, [1, 15, 16, 17, 64, 0], ()),
+    "shared_prefix": (4, 64, 16, 128, [40, 33, 64, 48], [(1, 0, 2),
+                                                        (3, 2, 3)]),
+    "group_edges": (4, 300, 16, 128, [300, 129, 128, 127], ()),
+    "rehearsal_geometry": (4, 24, 4, 8, [1, 5, 24, 0], ()),
+    "length_not_a_block_multiple": (3, 30, 4, 8, [30, 7, 0], ()),
+    "one_block_a_group": (3, 512, 256, 128, [512, 257, 3], ()),
+}
+
+
+_paged_both = kernels._paged_both
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+@pytest.mark.parametrize("shuffle", [False, True],
+                         ids=["table_in_order", "table_out_of_order"])
+def test_paged_kernel_matches_composite(case, shuffle, rng):
+    """Both ways at 1e-5 (an online softmax regroups float32 sums: a
+    tolerance, not bytes). A free slot's row is finite (zeros) and is not
+    compared: the composite averages whatever rows the table names."""
+    S, L, bs, H, lengths, share = PAGED_CASES[case]
+    args = kernels._paged_case(rng, S, L, bs, H, lengths, share=share,
+                               shuffle=shuffle)
+    got, ref = _paged_both(args, S, L, bs, H)
+    live = np.asarray(lengths) > 0
+    kernels._assert_close_both_ways(got[live], ref[live], case, 1e-5, 1e-5)
+    assert not got[~live].any()
+
+
+def test_paged_kernel_free_slot_moves_no_other_slot(rng):
+    """Freeing a slot (an all-masked bias row, its rows zeroed as the
+    engine's feeds do) changes no byte of any other slot's output, and
+    dead blocks are never read: NaNs planted in every row past the live
+    lengths do not reach the result."""
+    S, L, bs, H = 4, 64, 16, 128
+    lengths = [20, 64, 33, 7]
+    q, k, v, rows, bias = kernels._paged_case(rng, S, L, bs, H, lengths)
+    full, _ = _paged_both((q, k, v, rows, bias), S, L, bs, H)
+    rows2, bias2 = rows.reshape(S, L).copy(), bias.copy()
+    rows2[2], bias2[2] = 0, -1e9
+    freed, _ = _paged_both((q, k, v, rows2.reshape(-1), bias2), S, L, bs, H)
+    keep = [0, 1, 3]
+    assert freed[keep].tobytes() == full[keep].tobytes()
+    assert not freed[2].any()
+    live_rows = set()
+    for s, n in enumerate(lengths):
+        for blk in range(-(-n // bs)):
+            r0 = rows.reshape(S, L)[s, blk * bs]
+            live_rows.update(range(r0, r0 + bs))
+    dead = np.array(sorted(set(range(k.shape[0])) - live_rows))
+    k2, v2 = k.copy(), v.copy()
+    k2[dead], v2[dead] = np.nan, np.nan
+    poisoned, _ = _paged_both((q, k2, v2, rows, bias), S, L, bs, H)
+    assert poisoned.tobytes() == full.tobytes()
+
+
+def test_paged_kernel_any_bias(rng):
+    """The bias tile is added inside the kernel, so a bias that is not a
+    prefix (holes, finite penalties) gives the composite's answer too:
+    the lengths only bound which blocks are read."""
+    S, L, bs, H = 3, 64, 16, 128
+    q, k, v, rows, bias = kernels._paged_case(rng, S, L, bs, H, [64, 50, 30])
+    bias[0, 0, 5:40] = -1e9          # a hole over two whole blocks
+    bias[1, 0, :50] = rng.randn(50)  # finite penalties
+    bias[2, 0, ::3] = -1e9
+    got, ref = _paged_both((q, k, v, rows, bias), S, L, bs, H)
+    kernels._assert_close_both_ways(got, ref, "any bias", 1e-5, 1e-5)
+
+
+def test_paged_kernel_geometry_fallbacks_are_counted(rng):
+    """A block Mosaic cannot tile runs the composite on the compiled
+    path and counts in kernel_fallbacks_total; the interpreter has no
+    such limit."""
+    from paddle_tpu.kernels import attention as A
+
+    S, L, bs, H = 2, 8, 4, 8
+    args = kernels._paged_case(rng, S, L, bs, H, [8, 3])
+    c = kernels.fallback_counter()
+    c0 = c.value
+    out = jax.jit(lambda *a: A.paged_attention(
+        *a, S, L, bs, 0.5, interpret=False))(*args)
+    ref = jax.jit(lambda *a: A.paged_attention_composite(
+        *a, S, L, 0.5))(*args)
+    assert c.value == c0 + 1
+    assert np.asarray(out).tobytes() == np.asarray(ref).tobytes()
+    assert A._mosaic_tiles(16, 1024, "float32")
+    assert not A._mosaic_tiles(8, 1024, "bfloat16")
+    # the group shrinks to fit the VMEM budget before it gives up
+    assert A._paged_group(16, 64, 1024, "float32") == 8
+    assert A._paged_group(16, 64, 8192, "float32") == 6
+    assert A._paged_group(256, 4, 8192, "float32") == 0
 
 
 def test_fused_program_tokens_match_composite_program(rng):

@@ -39,7 +39,7 @@ def v5e_devices():
 
 def test_every_kernel_declares_tpu_cases():
     assert {s.name for s in KERNEL_SPECS} >= {
-        "flash_attention", "cached_attention"}
+        "flash_attention", "cached_attention", "paged_attention"}
     with pytest.raises(ValueError, match="tpu_cases"):
         kernels.KernelSpec("bogus", ("x",), "bit", lambda rng: None)
 

@@ -32,7 +32,7 @@ while asserting block-pool conservation across fork/prune.
 (all served, zero retrace after warmup; for --decode also continuous-
 vs-offline bit-identity, occupancy gain > 1.5x, and the KERNEL parity
 leg: the same paged+chunked+speculative workload under
-PADDLE_TPU_KERNELS=off vs =interpret must produce byte-identical
+PADDLE_TPU_KERNELS=off vs =interpret must produce the same
 tokens; for --sample/--beam also replay bit-identity, zero retraces
 after warmup, and beam block-conservation) — wired into tier-1 CI by
 tests/test_serving.py and tests/test_decode.py.
@@ -394,12 +394,12 @@ def run_decode(args, rng):
 
 
 def _kernel_modes_leg(args):
-    """Kernel on/off bit-identity gate (PADDLE_TPU_KERNELS): the same
-    paged + chunked + speculative workload decoded hand-stepped under
-    the registry's "off" (composite fallbacks) and "interpret" (Pallas
-    kernels through the interpreter) modes must produce BYTE-identical
-    tokens for every request — the fused paged-attention kernel is the
-    exact composite primitive sequence, and this is where that contract
+    """Kernel on/off gate (PADDLE_TPU_KERNELS): the same paged + chunked
+    + speculative workload decoded hand-stepped under the registry's
+    "off" (composite fallbacks) and "interpret" (Pallas kernels through
+    the interpreter) modes must produce the SAME tokens for every
+    request — the blocked paged-attention kernel is within 1e-5 of its
+    composite (an online softmax, not its bytes), and this is where that
     is held against the real engine, not a unit harness."""
     from paddle_tpu import kernels
     from paddle_tpu.serving.decode import GenerationEngine, build_decoder_model
