@@ -282,7 +282,7 @@ def _bert(sizes, flash):
     cfg = bert.BertConfig(**t["config"])
     if flash:
         # the fused kernel applies no attention-prob dropout
-        # (models/bert.py enforces it), exactly as bench.py configures it
+        # (models/bert.py enforces it)
         cfg.use_flash_attention = True
         cfg.attention_probs_dropout_prob = 0.0
     flags.rng_impl = "rbg"

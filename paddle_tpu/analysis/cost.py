@@ -60,8 +60,7 @@ __all__ = [
 
 class MachineModel:
     """Nominal per-chip peaks + two-level link model. The numbers are
-    catalog peaks (the same book values bench.py's `_chip_peak_flops`
-    compares MFU against), not measured — the roofline's job is RANKING
+    catalog peaks, not measured — the roofline's job is RANKING
     programs and catching order-of-magnitude regressions pre-compile;
     absolute wall-clock calibration is on-chip work (ROADMAP item 1)."""
 
@@ -89,8 +88,8 @@ class MachineModel:
         }
 
 
-#: machine catalog — peak bf16 FLOP/s and HBM BW per chip match
-#: bench.py's `_PEAK_BF16` table; ICI is the per-chip injection
+#: machine catalog — peak bf16 FLOP/s and HBM BW per chip are the
+#: published ones; ICI is the per-chip injection
 #: bandwidth of one ring direction-pair, DCN a 100 Gb/s NIC share.
 MACHINES = {
     "tpu-v4-8": MachineModel("tpu-v4-8", 275e12, 1.2e12,
@@ -1071,9 +1070,9 @@ def pipeline_bubble_report(program, *, shape_report=None, axis_sizes=None,
                 if info is not None and info.shape and \
                         not is_sym(info.shape[0]):
                     layers = int(info.shape[0])
-            # schedule-aware (PipelinedStack(schedule=...)); programs
-            # with the default gpipe attr keep the exact committed
-            # COST_EVIDENCE_r16 entry, byte for byte
+            # schedule-aware (PipelinedStack(schedule=...)); the default
+            # is gpipe's (s-1)/(m+s-1), tests/test_cost_analysis.py::
+            # test_pipeline_bubble_gpipe_fraction
             kind = op.attrs.get("schedule") or "gpipe"
             if kind != "gpipe" and s > 1:
                 from paddle_tpu.parallel.pipeline_runtime.schedule import (

@@ -27,9 +27,9 @@ and emits a **resharding report**:
 ``collective_budget_diagnostics`` turns the report into a linter with a
 configurable byte budget (tools/lint_program.py ``collectives
 --budget-kb``); ``weight_sized_events`` is the static twin of
-utils/hlo.py ``weight_shaped_collectives``. STATIC_EVIDENCE_r09.json
-cross-validates the predictions against the live HLO recompute on the
-r07 evidence programs.
+utils/hlo.py ``weight_shaped_collectives``. tests/test_hlo.py::
+test_static_sharding_analysis_predicts_the_live_collectives holds the
+predictions against the lowered HLO on the registry and Megatron arms.
 """
 
 
@@ -65,8 +65,6 @@ class ReshardEvent:
         self.block_idx = block_idx
         # mesh axes the collective's ring spans — what the cost model
         # (analysis/cost.py) prices through the ici/dcn link tiers.
-        # Deliberately NOT in to_json(): STATIC_EVIDENCE_r09.json embeds
-        # to_json() output and must not drift.
         self.axes = tuple(axes or ())
 
     def to_json(self):

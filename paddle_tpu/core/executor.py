@@ -579,8 +579,8 @@ class Executor:
         produced (written back via _set_verified, already on `dev`), so the
         common path is ONE dict lookup — not a device_put (the round-2
         profile's biggest host-side line item) and not even a per-step
-        `.devices()` call (~5 us x ~600 scope entries on BERT,
-        tools/bench_host_overhead.py). User-facing scope.set invalidates
+        `.devices()` call (~5 us x ~600 scope entries on BERT).
+        User-facing scope.set invalidates
         the verification.
 
         `store=False` for DONATED inputs: their buffer is consumed by the

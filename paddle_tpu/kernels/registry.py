@@ -204,8 +204,7 @@ def fallback_counter():
 
 
 def probe(name):
-    """Would this kernel serve its op right now? (bench.py turns flash
-    on by this.)"""
+    """Would this kernel serve its op right now?"""
     return selected(name) is not None
 
 

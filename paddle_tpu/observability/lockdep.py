@@ -23,10 +23,10 @@ declared rule, not just the observed inversion)::
 The witness is env-gated: inert unless ``PADDLE_TPU_LOCKDEP=1`` (or
 ``enable()`` is called). Disabled cost is one module-flag check per
 acquire/release on top of the raw ``threading`` primitive — named locks
-stay safe for hot paths. The discovered hierarchy (``snapshot()``) is
-committed as CONCURRENCY_EVIDENCE_r11.json by
-``tools/stress_concurrency.py --evidence`` and drift-gated by
-tests/test_concurrency.py.
+stay safe for hot paths. The hierarchy a deterministic pass over the
+threaded subsystems discovers (``snapshot()``) must have no cycle and
+run with every declared chain:
+tests/test_concurrency.py::test_witnessed_lock_hierarchy_has_no_cycle_and_obeys_declared_chains.
 
 Notes on semantics:
 
@@ -364,7 +364,7 @@ def declared_orders():
 
 def enable(on=True):
     """Flip the witness at runtime (tests / the stress harness). Call
-    ``reset()`` too when starting a fresh evidence pass."""
+    ``reset()`` too when starting a fresh witnessing pass."""
     _S.enabled = bool(on)
     return _S.enabled
 
@@ -404,8 +404,7 @@ def snapshot():
     """The witnessed state: registered lock classes, the observed
     may-acquire-while-holding edges (with first-witness attribution),
     declared hierarchies, and any cycles still present in the graph
-    (always [] unless violations were swallowed by the caller) — the
-    CONCURRENCY_EVIDENCE payload."""
+    (always [] unless violations were swallowed by the caller)."""
     with _S.mu:
         edges = sorted((a, b) for (a, b) in _S.edges)
         attributed = [

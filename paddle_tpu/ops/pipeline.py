@@ -98,7 +98,7 @@ def _pipeline_stack(ins, attrs):
         # microbatch, scan the stacked layers. Looping microbatches (not
         # scanning the full batch) keeps the per-gemm shapes identical to
         # the pipelined arms, so single-device parity is BITWISE, not
-        # just allclose (the evidence gate's no-pipeline reference).
+        # just allclose (the no-pipeline reference of tests/test_pipeline_runtime.py).
         body = _body_runner(
             sub, inner_x, inner_out, param_inner, ex, bindings, rng
         )

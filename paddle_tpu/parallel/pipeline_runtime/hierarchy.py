@@ -12,9 +12,9 @@ the diagnostic goes quiet.
 
 `dcn_crossing_collective_bytes` is the trust-but-verify half: it parses
 `replica_groups` out of OPTIMIZED HLO and prices the bytes that actually
-cross the dcn boundary, so the evidence gate can assert the realized DCN
-traffic matches the linter's predicted post-decomposition number instead
-of taking the sharding annotations on faith.
+cross the dcn boundary, so a test can hold the realized DCN traffic
+against the linter's post-decomposition story instead of taking the
+sharding annotations on faith.
 """
 
 import re

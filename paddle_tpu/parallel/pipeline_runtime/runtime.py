@@ -12,8 +12,8 @@ only a per-tick chunk selector. Reverse-mode AD transposes the ring into
 the mirrored backward wave (the bwd half of the slot table).
 
 The fill/drain edge of each lap is one CHUNK (1/v of a stage) deep, which
-is where the bubble win comes from: 3/11 vs gpipe's 3/7 at the
-COST_EVIDENCE_r16 s=4/m=4 operating point.
+is where the bubble win comes from: 3/11 vs gpipe's 3/7 at s=4, m=4
+(tests/test_pipeline_runtime.py::test_predicted_bubble_closed_forms).
 
 The schedule override context here is how a RUN-time choice (
 ``with_parallel(pipeline_schedule=...)``) reaches the `pipeline_stack`

@@ -13,7 +13,7 @@ Two kinds:
 * ``gpipe`` — the classic fill/drain schedule (Huang et al.): microbatch m
   runs forward on stage d at tick m+d; backwards mirror after the flush.
   Per-stage busy time is 2m of a 2(m+s-1)-tick makespan, so the bubble is
-  the committed ``(s-1)/(m+s-1)`` (COST_EVIDENCE_r16: 3/7 at s=4, m=4).
+  ``(s-1)/(m+s-1)``: 3/7 at s=4, m=4.
 
 * ``1f1b`` — the interleaved schedule (Narayanan et al. / Megatron's
   virtual stages): every device hosts ``interleave`` model CHUNKS, so the
@@ -21,14 +21,15 @@ Two kinds:
   times (the circular collective_permute ring in runtime.py). Fill/drain
   edges shrink by the chunk size: the table realizes
   ``((v-1)(s-m) + s-1) / (m + s*v - 1)`` — 3/11 at s=4, m=4, v=2, beating
-  the committed GPipe 3/7. The backward is the reverse-mode transpose of
+  GPipe's 3/7. The backward is the reverse-mode transpose of
   the forward wave (generic vjp path), so bwd slots mirror fwd slots; the
   interleaving buys bubble, not stash — every chunk residual stays live
   across the fwd->bwd span and is priced that way (memory.py).
 
 A slot table is exact, not aspirational: runtime.py derives its tick loop
-from the same (stage, chunk, microbatch, tick) arithmetic, and the
-evidence gate (tools/pipeline_report.py) recomputes the table walk live.
+from the same (stage, chunk, microbatch, tick) arithmetic, and
+tests/test_pipeline_runtime.py::test_schedule_table_is_a_valid_schedule
+walks the table (forward before backward, peak live residuals).
 """
 
 from collections import namedtuple
@@ -48,7 +49,7 @@ Slot = namedtuple("Slot", ("tick", "stage", "chunk", "microbatch", "phase"))
 
 def predicted_bubble(kind, num_stages, num_microbatches, interleave=1):
     """Closed-form bubble fraction for the circular-wave schedules this
-    package executes. ``gpipe`` is the committed (s-1)/(m+s-1); ``1f1b``
+    package executes. ``gpipe`` is (s-1)/(m+s-1); ``1f1b``
     with v chunks/device is ((v-1)(s-m) + s-1)/(m + s*v - 1) — equal to
     Megatron's (s-1)/(m*v + s-1) at the m == s operating point."""
     s, m = int(num_stages), int(num_microbatches)
@@ -104,7 +105,7 @@ class Schedule:
 
     def stage_timeline(self, stage):
         """Per-tick occupancy of one stage: list of None (idle) or
-        (phase, chunk, microbatch) — the PROFILE.md timeline view."""
+        (phase, chunk, microbatch)."""
         line = [None] * self.num_ticks
         for s in self.slots_for_stage(stage):
             assert line[s.tick] is None, ("slot collision", s)
@@ -127,21 +128,6 @@ class Schedule:
                 d_peak = max(d_peak, live)
             peak = max(peak, d_peak)
         return peak
-
-    def to_table(self):
-        """JSON-stable form for the committed evidence."""
-        return {
-            "kind": self.kind,
-            "stages": self.num_stages,
-            "microbatches": self.num_microbatches,
-            "interleave": self.interleave,
-            "ticks": self.num_ticks,
-            "busy_slots": len(self.slots),
-            "realized_bubble": round(self.realized_bubble(), 6),
-            "predicted_bubble": round(self.predicted(), 6),
-            "peak_stash_slots": self.peak_stash_slots(),
-            "slots": [list(s) for s in self.slots],
-        }
 
 
 def _gpipe_slots(s, m):

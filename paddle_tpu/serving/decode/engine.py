@@ -2155,8 +2155,9 @@ class _ModelEntry:
     def offline_beam(self, prompt, max_new, params, grammar=None):
         """Offline beam reference: ``generate.offline_beam_decode`` with
         this entry's prefill forward as the whole-sequence logits
-        oracle. The engine's slot-based incremental beam is bit-compared
-        against this by tests and GEN_EVIDENCE_r17."""
+        oracle. The engine's slot-based incremental beam must give these
+        hypotheses token for token
+        (tests/test_generate.py::test_beam_matches_offline_reference_and_conserves_blocks)."""
         m = self._model
 
         def logits_fn(tokens):

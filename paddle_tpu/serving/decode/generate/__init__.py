@@ -21,8 +21,9 @@ and the block tables:
   ``DEC_MASK`` feed: structured output with zero retraces.
 
 Each mode (and each composition) is bit-identical to its offline
-whole-sequence reference — the GEN_EVIDENCE_r17 property, drift-gated
-by tools/decode_report.py.
+whole-sequence reference, and all of them back to back on one warm
+engine compile nothing: tests/test_generate.py::
+test_every_generation_mode_on_one_warm_engine_compiles_nothing.
 """
 
 from paddle_tpu.serving.decode.generate.beam import (

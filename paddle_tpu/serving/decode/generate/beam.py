@@ -120,9 +120,9 @@ def offline_beam_decode(logits_fn, prompt, max_new, params, eos_id,
     """The whole-sequence beam reference: ``logits_fn(tokens)`` returns
     the float32 ``[V]`` next-token logits of a full forward over
     ``tokens`` (the engine wires the prefill program in). The loop here
-    IS the committed selection semantics — the engine's slot-based
-    incremental beam must reproduce its output byte-for-byte, which the
-    GEN_EVIDENCE_r17 drift gate asserts.
+    IS the selection semantics — the engine's slot-based incremental
+    beam must reproduce its hypotheses token for token
+    (tests/test_generate.py::test_beam_matches_offline_reference_and_conserves_blocks).
 
     Returns finished hypotheses ``[(tokens, score), ...]`` best-first
     (``finished_ranking``); tokens include the EOS when one fired."""

@@ -10,8 +10,9 @@ a function of ``(R.seed, t)`` and NOTHING else: not the batchmates, not
 the slot index, not the admission order, not whether the token was
 emitted by a plain decode step or inside a speculative verify cycle.
 Replaying a request with the same seed reproduces the byte-identical
-token stream in any of those configurations (the GEN_EVIDENCE_r17
-property), because
+token stream in any of those configurations
+(tests/test_generate.py::test_sampled_decode_bit_identical_any_admission_order),
+because
 
 * threefry is counter-based and bit-exact across backends/platforms (a
   jax guarantee the compile-cache work already leans on), and

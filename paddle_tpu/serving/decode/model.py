@@ -210,8 +210,7 @@ def _state_var(main_program, startup_program, name, shape):
 def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
                         slots=4, max_len=32, eos_id=None, name="decoder",
                         version="1", block_size=None, num_blocks=None,
-                        chunk_tokens=None, fused_attention=True,
-                        logits_mask=False):
+                        chunk_tokens=None, logits_mask=False):
     """Build the canonical cached-attention decoder as a paged
     DecodeModel.
 
@@ -229,23 +228,22 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
     the analysis/memory.py gate) to get the paged memory win.
     ``chunk_tokens`` >= 2 additionally builds the chunk-prefill program.
 
-    ``logits_mask`` (default False — opt-in so pre-r17 program
-    structures and their committed evidence stay byte-reproducible)
-    adds a fixed-shape ``[S, 1, V]`` additive mask feed applied to the
-    decode step's logits (``layers.logits_mask_add``): the
+    ``logits_mask`` (default False: at a real vocabulary the mask is a
+    ``[S, 1, V]`` float32 feed every step, so only models that serve
+    grammars pay for it) adds a fixed-shape additive mask feed applied
+    to the decode step's logits (``layers.logits_mask_add``): the
     grammar-constrained decode contract. Per-step masks enter as data —
     the compiled shape never changes, so constrained decode cannot
     retrace; an all-zeros mask is a bit-exact no-op for every
     unconstrained slot.
 
-    ``fused_attention`` (default True) routes the decode step's
-    attention through ONE ``paged_attention`` op — the row-index feeds
-    and the block size enter the op directly. Its reference lowering is
-    the exact gather+attention composite (the CPU path and the ``off``
-    path: BIT-identical to ``fused_attention=False``, the pre-r15 op
-    sequence kept for the DECODE_EVIDENCE_r13 static recompute); on a
-    TPU the blocked kernel of kernels/attention.py serves it, reading
-    each slot's live blocks alone, within 1e-5 of the composite.
+    The decode step's attention is ONE ``paged_attention`` op — the
+    row-index feeds and the block size enter the op directly. Its
+    reference lowering is the gather+attention composite
+    (``paged_attention_composite``: the CPU path and the ``off`` path);
+    on a TPU the blocked kernel of kernels/attention.py serves it,
+    reading each slot's live blocks alone, within 1e-5 of the composite
+    (tests/test_kernels.py, tests/test_paged_kernel_engine.py).
     """
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
@@ -344,16 +342,9 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
             # in-place device update, not a copy
             fluid.layers.assign(nk, output=kc)
             fluid.layers.assign(nv, output=vc)
-            if fused_attention:
-                ctx = fluid.layers.paged_attention(
-                    fluid.layers.squeeze(q, [1]), nk, nv, rows, bias,
-                    S, L, sm_scale=sm_scale, block_size=BS)
-            else:
-                gk = fluid.layers.block_gather(nk, rows, S, L)
-                gv = fluid.layers.block_gather(nv, rows, S, L)
-                ctx = fluid.layers.cached_attention(
-                    fluid.layers.squeeze(q, [1]), gk, gv, bias,
-                    sm_scale=sm_scale)
+            ctx = fluid.layers.paged_attention(
+                fluid.layers.squeeze(q, [1]), nk, nv, rows, bias,
+                S, L, sm_scale=sm_scale, block_size=BS)
             ctx = fluid.layers.unsqueeze(ctx, [1])
             h = fluid.layers.elementwise_add(h, proj(ctx, H, f"l{i}.out"))
             h = ffn_block(h, i)
@@ -426,8 +417,7 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
     kwargs = dict(vocab_size=V, hidden=H, num_layers=NL, ffn_dim=FFN,
                   slots=S, max_len=L, eos_id=eos_id, name=name,
                   version=version, block_size=BS, num_blocks=NB,
-                  chunk_tokens=C, fused_attention=fused_attention,
-                  logits_mask=logits_mask)
+                  chunk_tokens=C, logits_mask=logits_mask)
     return DecodeModel(
         decode_program=decode, prefill_program=prefill,
         inject_program=inject, chunk_program=chunk,

@@ -27,7 +27,8 @@ into a re-dispatch:
 
 Locking adopts ``lockdep.named_lock`` from day one; the declared
 hierarchy is ``fleet.router -> serving.queue -> decode.tenant``
-(witnessed in CONCURRENCY_EVIDENCE_r11.json).
+(witnessed live by tests/test_concurrency.py::
+test_witnessed_lock_hierarchy_has_no_cycle_and_obeys_declared_chains).
 """
 
 from paddle_tpu.serving.fleet.health import ReplicaHealth

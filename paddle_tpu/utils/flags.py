@@ -69,11 +69,7 @@ def get_flags(names=None):
 # Core flags, mirroring the categories in the reference's flags.cc.
 define_flag("check_nan_inf", False, "check every op output for NaN/Inf")
 define_flag("benchmark", False, "block after each op for timing")
-define_flag("eager_delete_tensor_gb", 0.0, "GC threshold (donation-based on TPU)")
 define_flag("use_donation", True, "donate parameter buffers into compiled steps")
-define_flag("executor_log_level", 0, "VLOG level for executor tracing")
-define_flag("rpc_deadline", 180000, "PS RPC deadline ms")
-define_flag("rpc_retry_times", 3, "PS RPC retry count")
 define_flag("amp_dtype", "bfloat16", "low-precision dtype for AMP on TPU")
 define_flag(
     "rng_impl", "threefry",
@@ -81,7 +77,6 @@ define_flag(
     "jax's default splittable generator; 'rbg' uses the TPU's hardware RNG "
     "path - much cheaper bits, same distribution, different stream",
 )
-define_flag("allocator_strategy", "auto_growth", "host allocator strategy label")
 define_flag(
     "dgc_sparse_exchange", True,
     "DGCMomentumOptimizer + data-parallel CompiledProgram: run the block "

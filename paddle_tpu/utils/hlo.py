@@ -115,9 +115,7 @@ def pallas_custom_calls(hlo_text):
 
 
 # ---------------------------------------------------------------------------
-# shared step builders — tests/test_hlo.py AND tools/hlo_report.py lower
-# through these, so the committed evidence is generated by the exact
-# computation the gates assert on
+# shared step builders — tests/test_hlo.py's gates lower through these
 # ---------------------------------------------------------------------------
 
 
@@ -240,8 +238,7 @@ def resnet_train_step_text(depth=50, class_dim=1000, image_shape=None,
 def adam_mlp_step_lowered(hidden=16, batch=4):
     """Small-but-real Adam train step (two fc layers) lowered through the
     production path — the donation/aliasing and fused-optimizer gates
-    (tests/test_hlo.py) and the committed evidence (tools/hlo_report.py)
-    both read THIS computation. Returns (lowered, donated_names, program).
+    (tests/test_hlo.py) read THIS computation. Returns (lowered, donated_names, program).
     """
     import paddle_tpu as fluid
     from paddle_tpu.core.executor import plan_step
@@ -433,9 +430,9 @@ def count_collectives(opt_text):
 
 
 # ---------------------------------------------------------------------------
-# collective byte accounting (the HLO_EVIDENCE_r07 'collective bytes stay
-# activation-sized' section) — what each collective MOVES, not just that it
-# exists
+# collective byte accounting ('collective bytes stay activation-sized',
+# tests/test_hlo.py::test_tp_registry_collective_bytes_activation_sized) —
+# what each collective MOVES, not just that it exists
 # ---------------------------------------------------------------------------
 
 _DTYPE_BYTES = {

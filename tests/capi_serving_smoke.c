@@ -3,6 +3,7 @@
  * compare each against the single-request predictor, print stats.
  * Compiled + executed by tests/test_serving.py.
  * usage: capi_serving_smoke <model_dir> <n_requests> <feat> */
+#include <math.h>
 #include <stdio.h>
 #include <stdlib.h>
 #include <string.h>
@@ -92,10 +93,14 @@ int main(int argc, char** argv) {
     void* rdata;
     size_t rnbytes;
     PD_GetOutput(pred, out_name, &rdt, &rshape, &rndim, &rdata, &rnbytes);
-    if (nbytes == rnbytes && memcmp(data, rdata, nbytes) == 0 &&
-        ndim == rndim) {
-      ++matched;  /* bit-for-bit: batched+padded == single-request */
+    /* batched+padded vs single-request are two executables: equal within
+       |a - b| <= 1e-6 + 1e-6 * |b| per element, not bit for bit */
+    int same = nbytes == rnbytes && ndim == rndim && dt == rdt;
+    for (size_t k = 0; same && k < nbytes / sizeof(float); ++k) {
+      float a = ((const float*)data)[k], b = ((const float*)rdata)[k];
+      if (!(fabsf(a - b) <= 1e-6f + 1e-6f * fabsf(b))) same = 0;
     }
+    if (same) ++matched;
     PD_Free(oshape);
     PD_Free(data);
     PD_Free(rshape);

@@ -865,7 +865,7 @@ def test_elastic_resume_4_to_2_exactly_once(tmp_path, num_workers,
 
 
 # ---------------------------------------------------------------------------
-# bench CLI smoke (tier-1 wiring, like bench_serving/trace_view)
+# bench CLI smoke (tier-1 wiring, like trace_view)
 # ---------------------------------------------------------------------------
 
 

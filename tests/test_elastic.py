@@ -1,7 +1,7 @@
 """Elastic gang training (r14): global-cursor data re-sharding, pinned
 sync-step resume, gang-generation stamping, the ElasticGangSupervisor
 shrink/grow loop, the new fault sites, and the chaos_elastic property
-gate (smoke CLI + ELASTIC_EVIDENCE_r14.json drift gate in one run).
+gate (the smoke CLI's own invariants, read from its --json report).
 """
 
 import json
@@ -527,39 +527,37 @@ def test_launch_cli_elastic_flags(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the property gate: chaos smoke CLI + evidence drift gate (ONE run)
+# the property gate: the chaos smoke CLI, its report read back
 # ---------------------------------------------------------------------------
 
 
-def test_elastic_evidence_r14_committed(tmp_path):
-    """Runs `tools/chaos_elastic.py --smoke --evidence` LIVE (kill a
-    rank mid-step -> shrink 4->2 -> grow 2->4, replay-determinism +
-    exactly-once + monotone generations asserted inside the CLI) and
-    drift-gates the committed ELASTIC_EVIDENCE_r14.json against the
-    recompute: committed claims must re-derive byte-for-byte."""
-    out = tmp_path / "ev.json"
+def test_elastic_shrink_grow_replays_bit_identically():
+    """`tools/chaos_elastic.py --smoke --json` LIVE: a rank is killed
+    mid-step, the gang shrinks 4->2 and grows 2->4; the committed stream
+    (batches and rank 0's losses) equals a reference run driven over
+    the same schedule with no kill, every sample is consumed exactly
+    once, generations rise 0, 1, 2 and both resumes restored shards."""
     env = dict(os.environ)
     env.pop("PADDLE_TPU_FAULTS", None)
     env.pop("PADDLE_TPU_FAULT_STATE", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "chaos_elastic.py"),
-         "--smoke", "--evidence", str(out)],
+         "--smoke", "--json"],
         capture_output=True, text=True, timeout=540, env=env,
     )
     assert proc.returncode == 0, \
         proc.stdout[-4000:] + proc.stderr[-4000:]
     assert "CHAOS_ELASTIC_OK" in proc.stdout
-    with open(out) as f:
-        live = json.load(f)
-    with open(os.path.join(REPO, "ELASTIC_EVIDENCE_r14.json")) as f:
-        committed = json.load(f)
-    assert committed["scenario"] == live["scenario"], (
-        "scenario drift: regenerate ELASTIC_EVIDENCE_r14.json")
-    assert committed["invariants"] == live["invariants"], {
-        k: (committed["invariants"].get(k), live["invariants"].get(k))
-        for k in set(committed["invariants"]) | set(live["invariants"])
-        if committed["invariants"].get(k) != live["invariants"].get(k)
-    }
-    inv = live["invariants"]
+    report = json.loads(next(
+        ln for ln in proc.stdout.splitlines() if ln.startswith("{")))
+    assert report["pass"] and report["failures"] == []
+    inv = report["invariants"]
     assert inv["bit_identical"] and inv["lost_or_duplicated"] == 0
+    assert [4, 2, "shrink"] in inv["resizes"]
+    assert [2, 4, "grow"] in inv["resizes"]
+    assert [ph["world"] for ph in inv["schedule"]] == [4, 2, 4]
     assert inv["generations"] == [0, 1, 2]
+    assert inv["committed_batches"] > 0
+    assert inv["shrink_sharded_restored"] > 0
+    assert inv["grow_sharded_restored"] > 0
+    assert inv["grown_ranks_from_chief"] >= 1

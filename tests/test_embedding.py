@@ -546,41 +546,44 @@ def test_ep_mesh_no_slab_shaped_collectives():
 
 
 # ---------------------------------------------------------------------------
-# bench smoke + committed evidence gate
+# bench smoke: one live run, its report held two ways
 # ---------------------------------------------------------------------------
 
 
-def test_bench_embedding_smoke_cli(tmp_path):
-    """tools/bench_embedding.py --smoke: bit-identical lookups across
-    cache configs, a non-trivial hit rate, and dedup HLO evidence."""
-    out = str(tmp_path / "bench.json")
+@pytest.fixture(scope="module")
+def bench_smoke_report():
+    """tools/bench_embedding.py --smoke, run once; its JSON report."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_embedding.py"),
-         "--smoke", "--out", out],
+         "--smoke"],
         capture_output=True, text=True, timeout=560,
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    rep = json.load(open(out))
-    assert rep["smoke"]["bit_identical_across_configs"] is True
-    assert rep["smoke"]["hit_rate"] > 0.3
-    assert rep["dedup_evidence"]["dedup_saves"] is True
+    return json.loads(proc.stdout[proc.stdout.index("{"):])
 
 
-def test_embedding_evidence_r08_committed():
-    """The committed EMBEDDING_EVIDENCE_r08.json must claim exactly what
-    this suite proves live: one slab gather moving fewer rows than ids,
-    a firing dedup-off control, and a non-trivial measured hit rate."""
-    path = os.path.join(REPO, "EMBEDDING_EVIDENCE_r08.json")
-    with open(path) as f:
-        sec = json.load(f)
-    ev = sec["dedup_evidence"]
+def test_bench_embedding_smoke_cli(bench_smoke_report):
+    """The smoke run's own asserts passed: bit-identical lookups across
+    cache configs (eviction traffic included), device admission with no
+    host round trip, the legacy path bit-identical."""
+    smoke = bench_smoke_report["smoke"]
+    assert smoke["asserts"] == "passed"
+    assert smoke["bit_identical_across_configs"] is True
+    assert smoke["device_admission_roundtrips"] == 0
+    assert smoke["legacy_path_bit_identical"] is True
+
+
+def test_dedup_gather_moves_fewer_rows_than_ids_and_control_fires(
+        bench_smoke_report):
+    """Read off the lowered computation of the live run: ONE slab gather
+    moving fewer rows than ids, a dedup-off control that moves at least
+    one row per id (or the claim proves nothing), a non-trivial measured
+    hit rate and a hit counter that moved."""
+    rep = bench_smoke_report
+    ev = rep["dedup_evidence"]
     assert ev["gathers"] == 1
     assert ev["rows_moved"] < ev["n_ids"]
-    assert sec["dedup_off_control"]["rows_moved"] >= ev["n_ids"], (
-        "the dedup-off control stopped firing — the dedup claim above "
-        "proves nothing"
-    )
-    assert sec["smoke"]["bit_identical_across_configs"] is True
-    assert sec["smoke"]["hit_rate"] > 0.3
-    assert sec["cache_hit_gauges"]["embedding_cache_hits_total"] > 0
+    assert rep["dedup_off_control"]["rows_moved"] >= ev["n_ids"]
+    assert rep["smoke"]["hit_rate"] > 0.3
+    assert rep["cache_hit_gauges"]["embedding_cache_hits_total"] > 0

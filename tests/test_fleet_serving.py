@@ -5,11 +5,10 @@ The core property, asserted every way this file can reach it: once the
 fleet ACCEPTS a request, exactly one answer is delivered and — because
 decode is bit-deterministic — it is byte-identical to the single-replica
 offline reference, no matter which replicas died, quarantined, or
-drained along the way. The r12 evidence file commits that claim
-(FLEET_EVIDENCE_r12.json) and `test_fleet_evidence_r12_committed`
-re-derives it live, the same drift-gate discipline as r08–r11.
+drained along the way.
 """
 
+import argparse
 import json
 import os
 import subprocess
@@ -895,7 +894,7 @@ def test_subprocess_kill_a_replica_bit_identical(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# evidence drift gate + CLI smoke (tier-1 wiring)
+# the chaos scenario in-process + CLI smoke (tier-1 wiring)
 # ---------------------------------------------------------------------------
 
 
@@ -909,32 +908,28 @@ def _load_tool(name):
     return mod
 
 
-def test_fleet_evidence_r12_committed():
-    """The committed chaos claims must re-derive LIVE: the scenario in
-    FLEET_EVIDENCE_r12.json is re-run in-process and every
-    deterministic field (config, zero-loss ledger, bit-identity, the
-    sha256 over all generated tokens, zero-trace scale-up) must match
-    byte-for-byte. Drift means failover behavior changed without
-    regenerating evidence: run
-    `python tools/chaos_serve.py --evidence FLEET_EVIDENCE_r12.json`."""
-    path = os.path.join(REPO, "FLEET_EVIDENCE_r12.json")
-    assert os.path.exists(path), "FLEET_EVIDENCE_r12.json missing"
-    with open(path) as f:
-        committed = json.load(f)
-    cs = _load_tool("chaos_serve")
+def test_replica_kill_loses_nothing_and_changes_no_token():
+    """tools/chaos_serve.py's scenario in-process (3 replicas, 18
+    requests, 13 unique prompts, replica 1 killed while holding work):
+    accepted == completed, nothing lost, the kill fired once and took
+    one replica, the replacement served with zero traces, and every
+    token equals both the offline reference's and the unkilled leg's."""
     import logging
 
+    cs = _load_tool("chaos_serve")
     logging.getLogger("paddle_tpu.resilience.faults").setLevel(
         logging.ERROR)
-    report = cs.run_scenario(dict(committed["scenario"]))
+    cfg = cs.default_cfg(argparse.Namespace(
+        replicas=3, requests=18, max_new=6, kill_replica=1, seed=2,
+        arrival_s=0.002))
+    report = cs.run_scenario(cfg)
     assert report["failures"] == [], report["failures"]
-    assert report["scenario"] == committed["scenario"], "scenario drift"
-    assert report["invariants"] == committed["invariants"], (
-        "fleet evidence drift:\n"
-        f"fresh    {report['invariants']}\n"
-        f"committed {committed['invariants']}")
-    assert committed["invariants"]["lost"] == 0
-    assert committed["invariants"]["scaleup_traces"] == 0
+    inv = report["invariants"]
+    assert inv["accepted"] == inv["completed"] == 18 and inv["lost"] == 0
+    assert inv["kill_fired"] and inv["replica_deaths"] == 1
+    assert inv["bit_identical"] and inv["tokens_equal_unkilled"]
+    assert inv["scaleup_traces"] == 0
+    assert inv["unique_prompts"] < 18            # repeats: affinity fires
     assert report["measured"]["rerouted"] >= 1
 
 

@@ -6,17 +6,16 @@ grammar-constrained masks — is bit-identical to its offline
 whole-sequence reference REGARDLESS of admission order, slot assignment,
 or batchmates; none of them widens the compiled program set (grammar
 masks ride the DEC_MASK data feed: zero retraces after warmup); beam
-fork/prune conserves the block pool exactly; and the committed
-GEN_EVIDENCE_r17.json re-derives live byte-for-byte.
+fork/prune conserves the block pool exactly.
 """
 
 import json
-import os
 import re
 import time
 
 import numpy as np
 import pytest
+from decode_testing import SPEC_PROMPTS, jits, spec_leg
 
 from paddle_tpu.serving.decode import (
     BeamParams,
@@ -34,15 +33,7 @@ from paddle_tpu.serving.decode.generate.beam import (
 )
 from paddle_tpu.serving.request import RejectedError
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 VOCAB = ["<eos>"] + list("abcdefghijklmnopqrstuvwxyz") + list("01234")
-
-
-def _jits():
-    from paddle_tpu.observability import metrics as obs_metrics
-    m = obs_metrics.registry().get("lowering_jit_total")
-    return int(m.value) if m is not None else 0
 
 
 def _gen_model(name, version="1", slots=4, max_len=32, hidden=8,
@@ -295,10 +286,10 @@ def test_grammar_decode_conforms_zero_retraces(gen_served):
     g = CompiledGrammar.from_json_schema({"type": "boolean"}, VOCAB,
                                          eos_id=0)
     ref = entry.offline_decode([9, 1, 4], 10, grammar=g)
-    j0 = _jits()
+    j0 = jits()
     got = engine.submit([9, 1, 4], model="gens", max_new_tokens=10,
                         grammar=g).result(timeout=120)
-    assert _jits() == j0
+    assert jits() == j0
     toks = [int(t) for t in got["tokens"]]
     assert toks == ref
     text = "".join(VOCAB[t] for t in toks if t != 0)
@@ -392,55 +383,84 @@ def test_draft_kv_pins_entry_and_falls_back_when_busy():
         engine.shutdown()
 
 
-def test_draft_kv_steps_per_token_meets_r13_baseline():
-    """The r13 speculative scenario with draft-KV slots: target-side
-    steps-per-token reproduces the committed baseline EXACTLY (the
-    proposals are bit-identical; only the draft's cost model changed),
-    and the draft does ~one slot-step per emitted token instead of a
-    whole-prompt replay per cycle."""
-    dr = _load_tool("decode_report")
-    rep = dr.draft_kv_report()
-    assert rep["steps_per_token"] <= dr.R13_STEPS_PER_TOKEN, rep
-    assert rep["bit_identical"], rep
-    assert rep["draft_kv_fallbacks"] == 0, rep
-    assert rep["retraces_after_warmup"] == 0, rep
+def test_draft_kv_target_steps_equal_replay_baseline():
+    """Draft-KV slots change WHO computes the proposals, not what they
+    are: the target's verify steps per emitted token do not exceed the
+    replay-proposal leg's (run here, same scenario), the draft does
+    O(1) slot steps per token after one prefill per request, nothing
+    falls back, nothing compiles, tokens equal target-only decode."""
+    replay, _, replay_same = spec_leg("kvbase_r", draft_kv=False)
+    st, retraces, same = spec_leg("kvbase")
+    assert replay_same and same
+    assert replay["spec_draft_kv_steps"] == 0, replay
+    assert st["spec_emitted_tokens"] == replay["spec_emitted_tokens"]
+    assert st["spec_target_steps"] <= replay["spec_target_steps"], st
+    assert st["spec_steps_per_token"] <= 0.7, st
+    assert st["spec_draft_kv_prefills"] == len(SPEC_PROMPTS), st
+    assert st["spec_draft_kv_steps"] > 0, st
+    assert st["spec_draft_kv_fallbacks"] == 0, st
+    assert retraces == 0
 
 
-# ---------------------------------------------------------------------------
-# the committed evidence re-derives live
-# ---------------------------------------------------------------------------
+def test_every_generation_mode_on_one_warm_engine_compiles_nothing():
+    """Sampled (two admission orders), beam, grammar (regex and JSON
+    schema) and sampled speculation run back to back on ONE warmed
+    engine under ONE jit counter: each equals its offline reference and
+    the whole sequence compiles nothing."""
+    prompts = ([5, 9, 2, 4, 7], [11, 3, 8], [6, 1, 12, 2, 9, 4, 3], [14, 2])
+    engine = GenerationEngine(queue_depth=32, breaker_threshold=0)
+    tgt = engine.register_model(lambda: _gen_model(
+        "modes", eos_id=0, logits_mask=True))
+    engine.register_model(lambda: _gen_model("modes_d", eos_id=0))
+    sp = SamplingParams(temperature=0.9, top_k=8, top_p=0.95, seed=42)
+    sampled_refs = [tgt.offline_decode(p, 6, sampling=sp) for p in prompts]
+    beam_refs = [tgt.offline_beam(p, 6, BeamParams(3)) for p in prompts[:2]]
+    grammars = (CompiledGrammar.from_regex("ab*c", VOCAB, eos_id=0),
+                CompiledGrammar.from_json_schema({"type": "boolean"}, VOCAB,
+                                                 eos_id=0))
+    grammar_refs = [tgt.offline_decode(prompts[0], 10, grammar=g)
+                    for g in grammars]
 
+    def tokens(resp):
+        return [int(t) for t in resp.result(timeout=120)["tokens"]]
 
-def _load_tool(name):
-    import importlib.util
+    engine.start()
+    j0 = jits()
+    try:
+        for order_seed in (0, 1):
+            order = np.random.RandomState(order_seed).permutation(len(prompts))
+            resps = {int(i): engine.submit(prompts[i], model="modes",
+                                           max_new_tokens=6, sampling=sp)
+                     for i in order}
+            assert [tokens(resps[i]) for i in range(len(prompts))] \
+                == sampled_refs, order_seed
+        assert tgt.stats()["sampled_tokens"] > 0
 
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "tools", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+        for p, ref in zip(prompts[:2], beam_refs):
+            got = engine.submit(p, model="modes", beam_width=3,
+                                max_new_tokens=6).result(timeout=120)
+            assert ([[int(t) for t in h["tokens"]] for h in got["beams"]]
+                    == [list(rt) for rt, _rs in ref])
+            for h, (_rt, rs) in zip(got["beams"], ref):
+                assert abs(h["score"] - rs) <= 1e-5 * max(1.0, abs(rs))
+        assert tgt.stats()["beam_forks"] > 0
+        tgt.block_pool.check_conservation()
 
+        got_re, got_js = [tokens(engine.submit(
+            prompts[0], model="modes", max_new_tokens=10, grammar=g))
+            for g in grammars]
+        assert [got_re, got_js] == grammar_refs
+        assert re.fullmatch(
+            "ab*c", "".join(VOCAB[t] for t in got_re if t != 0))
+        assert isinstance(json.loads(
+            "".join(VOCAB[t] for t in got_js if t != 0)), bool)
+        assert tgt.stats()["grammar_steps"] > 0
 
-def test_gen_evidence_r17_committed():
-    """GEN_EVIDENCE_r17.json must re-derive LIVE: sampled / beam /
-    grammar / spec_sampled legs plus the draft-KV baseline are recomputed
-    in-process and every deterministic field compared byte-for-byte.
-    Drift means generation behavior changed without regenerating
-    evidence: run `python tools/decode_report.py --gen --out
-    GEN_EVIDENCE_r17.json`."""
-    path = os.path.join(REPO, "GEN_EVIDENCE_r17.json")
-    assert os.path.exists(path), "GEN_EVIDENCE_r17.json missing"
-    with open(path) as f:
-        committed = json.load(f)
-    dr = _load_tool("decode_report")
-    fresh = dr.build_gen_evidence()
-    dr.check_gen(fresh)                # live acceptance gates
-    dr.check_gen(committed)            # committed claims still qualify
-    assert fresh["modes"] == committed["modes"], (
-        "generation-modes evidence drift:\n"
-        f"fresh     {fresh['modes']}\n"
-        f"committed {committed['modes']}")
-    assert fresh["draft_kv"] == committed["draft_kv"], (
-        "draft-KV evidence drift:\n"
-        f"fresh     {fresh['draft_kv']}\n"
-        f"committed {committed['draft_kv']}")
+        got = tokens(engine.submit(
+            prompts[2], model="modes", max_new_tokens=6, sampling=sp,
+            draft_model="modes_d", spec_k=3))
+        assert got == sampled_refs[2]
+        assert tgt.stats()["spec_draft_kv_fallbacks"] == 0
+    finally:
+        engine.shutdown()
+    assert jits() == j0, "a generation mode compiled after warm-up"

@@ -36,8 +36,6 @@ BATCH = 4
 
 
 def _lower_bert(flash):
-    # shared with tools/hlo_report.py so the committed evidence is
-    # generated by the exact computation these gates assert on
     return hlo.bert_train_step_text(
         flash, seq_len=S, vocab=VOCAB, max_pred=P_PRED
     )
@@ -69,7 +67,7 @@ def test_unfused_path_detector_fires():
 def test_masked_head_no_s_by_vocab(bert_flash_stablehlo):
     """The MLM head must project only gathered masked positions: a tensor
     carrying both S and VOCAB dims means the full [*, S, V] logits came
-    back (4 GB at bench shapes, PROFILE.md item 1)."""
+    back (4 GB at bench shapes)."""
     tensors = hlo.stablehlo_tensors(bert_flash_stablehlo)
     sxv = hlo.tensors_containing_dims(tensors, (S, VOCAB))
     assert not sxv, f"[S, V]-sized tensors present: {set(sxv)}"
@@ -217,19 +215,48 @@ def test_dp_mesh_masked_gather_stays_local():
     assert not moved, f"activation-sized collectives in the DP step: {moved}"
 
 
-@pytest.fixture(scope="module")
-def tp_registry_lowering():
-    """dp2 x tp4 tiny-BERT step with parameter placement from the
-    canonical SpecLayout registry (parallel/spec_layout.py), plus the
-    full rank>=2 parameter-shape set. seq_len=24 keeps activation shapes
-    disjoint from every parameter shape (at 16, [B_local*S, H] == the
-    qkv weight shape and the scan could not tell them apart)."""
+GEO = dict(seq_len=24, max_pred=20, with_param_shapes=True)
+
+
+def _arm(tag):
+    """mesh shape, axis names and placement of one sharding arm."""
+    from paddle_tpu.parallel.sharding import MEGATRON_RULES
     from paddle_tpu.parallel.spec_layout import SpecLayout
 
-    return hlo.tiny_bert_parallel_text(
-        (2, 4), ("data", "model"), spec_layout=SpecLayout(),
-        seq_len=24, max_pred=20, with_param_shapes=True,
-    )
+    return {
+        "tp_registry": ((2, 4), ("data", "model"),
+                        dict(spec_layout=SpecLayout())),
+        "dp_fsdp_tp_registry": ((2, 2, 2), ("data", "fsdp", "model"),
+                                dict(spec_layout=SpecLayout())),
+        # the PR-4-era rule table leaves pos/type embeddings, pooler and
+        # MLM head replicated and pays weight-sized collectives for
+        # them: the positive control of every scan below
+        "megatron_control": ((2, 4), ("data", "model"),
+                             dict(param_rules=MEGATRON_RULES)),
+    }[tag]
+
+
+@pytest.fixture(scope="module")
+def lowered_arm():
+    """tag -> (optimized HLO text, rank>=2 parameter shapes) of the
+    tiny-BERT step on that arm's 8-device mesh, lowered once a module.
+    seq_len=24 keeps activation shapes disjoint from every parameter
+    shape (at 16, [B_local*S, H] == the qkv weight shape and the scan
+    could not tell them apart)."""
+    cache = {}
+
+    def get(tag):
+        if tag not in cache:
+            shape, axes, placement = _arm(tag)
+            cache[tag] = hlo.tiny_bert_parallel_text(
+                shape, axes, **placement, **GEO)
+        return cache[tag]
+    return get
+
+
+@pytest.fixture(scope="module")
+def tp_registry_lowering(lowered_arm):
+    return lowered_arm("tp_registry")
 
 
 def test_tp_mesh_no_weight_sized_collectives(tp_registry_lowering):
@@ -273,8 +300,7 @@ def test_tp_registry_collective_bytes_activation_sized(
     """Byte accounting on the same step: the single largest value any
     collective materializes must be activation-class (within 2x of the
     flattened residual activation), nowhere near the largest full
-    parameter — the quantitative form of the shape scan above, recorded
-    in HLO_EVIDENCE_r07.json by tools/hlo_report.py."""
+    parameter — the quantitative form of the shape scan above."""
     txt, param_shapes = tp_registry_lowering
     report = hlo.collective_byte_report(txt)
     assert report["by_kind"], "no collectives parsed"
@@ -287,18 +313,13 @@ def test_tp_registry_collective_bytes_activation_sized(
     assert report["max_bytes"] <= 2 * act_bytes, report
 
 
-def test_dp_fsdp_tp_registry_no_weight_sized_collectives():
+def test_dp_fsdp_tp_registry_no_weight_sized_collectives(lowered_arm):
     """The full dp x fsdp x tp factorization (2x2x2) through the
     registry: parameters and optimizer state ZeRO-sliced over fsdp and
     tensor-sharded over tp, still zero full-weight collectives and
     byte-bounded wire traffic."""
-    from paddle_tpu.parallel.spec_layout import SpecLayout
-
     assert jax.device_count() >= 8
-    txt, param_shapes = hlo.tiny_bert_parallel_text(
-        (2, 2, 2), ("data", "fsdp", "model"), spec_layout=SpecLayout(),
-        seq_len=24, max_pred=20, with_param_shapes=True,
-    )
+    txt, param_shapes = lowered_arm("dp_fsdp_tp_registry")
     c = hlo.count_collectives(txt)
     assert sum(c.values()) >= 1, f"no collectives in dp2xfsdp2xtp2: {c}"
     offenders = hlo.weight_shaped_collectives(txt, param_shapes)
@@ -311,114 +332,75 @@ def test_dp_fsdp_tp_registry_no_weight_sized_collectives():
     assert report["max_bytes"] <= 2 * act_bytes, report
 
 
-def test_hlo_evidence_r07_committed(tp_registry_lowering):
-    """The committed HLO_EVIDENCE_r07.json (tools/hlo_report.py) must
-    claim exactly what this suite proves live: zero weight-shaped
-    collectives on the registry steps, a firing detector on the
-    MEGATRON_RULES control, and byte accounting present — so the
-    evidence cannot drift from the asserted computation."""
-    import json
-    import os
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "HLO_EVIDENCE_r07.json",
-    )
-    with open(path) as f:
-        sec = json.load(f)["spec_layout_r07"]
-    assert sec["weight_shaped_collectives_tp_registry"] == 0
-    assert sec["weight_shaped_collectives_dp_fsdp_tp_registry"] == 0
-    assert sec["weight_shaped_collectives_megatron_control"] > 0, (
-        "the positive control stopped firing — the zero-claims above "
-        "prove nothing"
-    )
+def test_weight_shaped_scan_is_zero_on_registry_and_fires_on_megatron(
+        lowered_arm):
+    """Both registry arms move ZERO full-parameter-shaped operands and
+    no collective of theirs materializes anything near the largest
+    parameter; the same scan over the MEGATRON_RULES lowering finds
+    weight-shaped collectives — if that control stops firing, the zeros
+    prove nothing."""
+    ctl_txt, ctl_shapes = lowered_arm("megatron_control")
+    assert len(hlo.weight_shaped_collectives(ctl_txt, ctl_shapes)) > 0
     for tag in ("tp_registry", "dp_fsdp_tp_registry"):
-        rep = sec[f"collective_bytes_{tag}"]
-        assert rep["by_kind"] and rep["max_bytes"] > 0
-        assert rep["max_bytes"] < sec["param_full_bytes"]["largest"] * 2
-    # the committed zero must match THIS process's lowering of the same
-    # builder at the same geometry
-    txt, shapes = tp_registry_lowering
-    assert len(hlo.weight_shaped_collectives(txt, shapes)) == \
-        sec["weight_shaped_collectives_tp_registry"]
+        txt, shapes = lowered_arm(tag)
+        assert hlo.weight_shaped_collectives(txt, shapes) == [], tag
+        largest = max(4 * int(np.prod(shp)) for shp in shapes)
+        rep = hlo.collective_byte_report(txt)
+        assert rep["by_kind"] and 0 < rep["max_bytes"] < 2 * largest, rep
 
 
-def test_static_evidence_r09_committed(tp_registry_lowering):
-    """STATIC_EVIDENCE_r09.json (tools/static_report.py) claims the static
-    sharding analyzer predicts the live collective story: zero
-    weight-sized collectives on the registry arms, every one of the
-    control's live weight gathers predicted with bytes within 2x, and the
-    budget linter separating the two. The live half is recomputed HERE
-    (the static half is re-derived by tools/lint_program.py smoke), so
-    neither side of the committed comparison can drift silently."""
-    import json
-    import os
-    from collections import Counter
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "STATIC_EVIDENCE_r09.json",
+def test_static_sharding_analysis_predicts_the_live_collectives(lowered_arm):
+    """analysis/sharding.py, with no XLA in the loop, tells the same
+    collective story the lowered HLO does, arm by arm: no weight-sized
+    event and a passing 192 KB budget on the registry arms, both firing
+    on the Megatron control; every live weight-shaped collective has a
+    static prediction of that shape within 2x of its bytes; and the
+    predicted all-reduce bytes are within 2x of the live all-reduce
+    bytes SUMMED (a sum does not move when XLA combines instructions;
+    their count does, and is not held)."""
+    from paddle_tpu.analysis.sharding import (
+        analyze_sharding,
+        collective_budget_diagnostics,
+        weight_param_shapes,
+        weight_sized_events,
     )
-    with open(path) as f:
-        ev = json.load(f)
-    arms = ev["arms"]
-    # committed static claims: registry clean + under budget, control
-    # fires both detectors
-    for tag in ("tp_registry", "dp_fsdp_tp_registry"):
-        assert arms[tag]["static"]["weight_sized_count"] == 0
-        assert arms[tag]["static"]["budget_verdict"] == "pass"
-        assert arms[tag]["live"]["weight_shaped_count"] == 0
-    control = arms["megatron_control"]
-    assert control["static"]["weight_sized_count"] > 0
-    assert control["static"]["budget_verdict"] == "fail"
-    assert control["live"]["weight_shaped_count"] > 0, (
-        "the positive control stopped firing — the zero-claims above "
-        "prove nothing"
-    )
-    # every live weight-shaped collective matched a static prediction,
-    # bytes within the 2x acceptance bound
-    for tag, arm in arms.items():
-        assert arm["match"]["live_collectives_unmatched"] == 0, arm["match"]
-        assert arm["match"]["max_byte_ratio"] <= 2.0, arm["match"]
+    from paddle_tpu.models import bert
+    from paddle_tpu.parallel.env import make_mesh
 
-    # live recompute, registry arm: the fixture's lowering of the same
-    # builder at the same geometry must still show ZERO weight-shaped
-    # collectives (matches the committed live claim)
-    txt, shapes = tp_registry_lowering
-    assert len(hlo.weight_shaped_collectives(txt, shapes)) == \
-        arms["tp_registry"]["live"]["weight_shaped_count"]
-
-    # live recompute, control arm: offender shapes/counts must equal the
-    # committed live section AND be covered by the committed static
-    # predictions within 2x bytes
-    from paddle_tpu.parallel.sharding import MEGATRON_RULES
-
-    ctl_txt, ctl_shapes = hlo.tiny_bert_parallel_text(
-        (2, 4), ("data", "model"), param_rules=MEGATRON_RULES,
-        seq_len=24, max_pred=20, with_param_shapes=True,
-    )
-    offenders = hlo.weight_shaped_collectives(ctl_txt, ctl_shapes)
-    assert len(offenders) == control["live"]["weight_shaped_count"]
-    live_counts = Counter(
-        (k, "x".join(map(str, s))) for k, s, _l in offenders
-    )
-    committed_counts = {
-        (e["kind"], e["shape"]): e["count"]
-        for e in control["live"]["weight_shaped"]
-    }
-    assert dict(live_counts) == committed_counts
-    static_preds = control["static"]["weight_sized"]
-    for (_kind, shape_s), _n in live_counts.items():
-        shape = tuple(int(d) for d in shape_s.split("x"))
-        nbytes = 4
-        for d in shape:
-            nbytes *= d
-        preds = [e for e in static_preds
-                 if tuple(e["shape"]) == shape and e["bytes"]]
-        assert preds, f"live {shape} gather has no static prediction"
-        ratio = max(preds[0]["bytes"], nbytes) / min(preds[0]["bytes"],
-                                                    nbytes)
-        assert ratio <= 2.0, (shape, preds[0]["bytes"], nbytes)
+    cfg = bert.BertConfig.tiny()
+    cfg.hidden_dropout_prob = cfg.attention_probs_dropout_prob = 0.0
+    main, _startup, _feeds, _fetches = bert.build_bert_pretrain(
+        cfg, seq_len=GEO["seq_len"], lr=1e-3,
+        max_predictions_per_seq=GEO["max_pred"])
+    data = bert.synthetic_batch(
+        np.random.RandomState(0), 8, GEO["seq_len"], cfg,
+        max_predictions_per_seq=GEO["max_pred"])
+    feed_shapes = {k: tuple(np.asarray(v).shape) for k, v in data.items()}
+    param_shapes = weight_param_shapes(main)
+    for tag in ("tp_registry", "dp_fsdp_tp_registry", "megatron_control"):
+        shape, axes, placement = _arm(tag)
+        rep = analyze_sharding(main, make_mesh(shape, axes),
+                               feed_shapes=feed_shapes, **placement)
+        predicted = weight_sized_events(rep, param_shapes)
+        over = collective_budget_diagnostics(rep, 192 * 1024)
+        txt, shapes = lowered_arm(tag)
+        offenders = hlo.weight_shaped_collectives(txt, shapes)
+        if tag == "megatron_control":
+            assert predicted and over and offenders, tag
+        else:
+            assert not predicted and not over and not offenders, tag
+        for kind, shp, _line in offenders:
+            nbytes = 4 * int(np.prod(shp))
+            preds = [e.bytes for e in predicted
+                     if tuple(e.shape or ()) == tuple(shp) and e.bytes]
+            assert preds, f"{tag}: live {kind} {shp} was not predicted"
+            ratio = min(max(b, nbytes) / min(b, nbytes) for b in preds)
+            assert ratio <= 2.0, (tag, shp, preds, nbytes)
+        static_ar = rep.by_kind()["all-reduce"]["total_bytes"]
+        live_ar = hlo.collective_byte_report(txt)["by_kind"][
+            "all-reduce"]["total_bytes"]
+        assert max(static_ar, live_ar) <= 2.0 * min(static_ar, live_ar), (
+            tag, static_ar, live_ar)
 
 
 def test_flash_long_context_no_s2():
@@ -496,9 +478,7 @@ def test_resnet_dp_mesh_collectives():
 @pytest.fixture(scope="module")
 def adam_step_lowered():
     """A small-but-real Adam train step (two fc layers), lowered through
-    the production path (core/lowering.py) — shared with
-    tools/hlo_report.py so the committed evidence asserts this exact
-    computation."""
+    the production path (core/lowering.py)."""
     return hlo.adam_mlp_step_lowered()
 
 

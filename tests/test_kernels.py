@@ -311,34 +311,6 @@ def test_paged_kernel_geometry_fallbacks_are_counted(rng):
     assert A._paged_group(256, 4, 8192, "float32") == 0
 
 
-def test_fused_program_tokens_match_composite_program(rng):
-    """fused_attention=True (one paged_attention op) vs False (the r13
-    gather+attention op sequence): same weights by deterministic init,
-    BIT-identical decode."""
-    from paddle_tpu.serving.decode import GenerationEngine, build_decoder_model
-
-    geom = dict(vocab_size=32, hidden=8, num_layers=2, slots=4, max_len=24)
-
-    def drive(fused, tag):
-        engine = GenerationEngine(queue_depth=8, breaker_threshold=0)
-        entry = engine.register_model(lambda: build_decoder_model(
-            block_size=4, name=f"fusedcmp_{tag}", version="1",
-            fused_attention=fused, **geom))
-        prompts = [[3, 1, 4, 1, 5], [3, 1, 4], [9, 2]]
-        resps = [engine.submit(p, max_new_tokens=6) for p in prompts]
-        entry._admit_free_slots()
-        for _ in range(60):
-            if all(r.done() for r in resps):
-                break
-            entry._step()
-        outs = [[int(t) for t in r.result(timeout=60)["tokens"]]
-                for r in resps]
-        engine.shutdown()
-        return outs
-
-    assert drive(True, "on") == drive(False, "off")
-
-
 # ---------------------------------------------------------------------------
 # on-device embedding admission
 # ---------------------------------------------------------------------------

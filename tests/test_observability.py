@@ -508,7 +508,7 @@ def test_executor_spans_cover_compile_and_execute(tracer, rng):
 
 
 # ---------------------------------------------------------------------------
-# CLI smoke (fast-tier wiring, like bench_serving/chaos_train)
+# CLI smoke (fast-tier wiring, like chaos_train)
 # ---------------------------------------------------------------------------
 
 def test_trace_view_smoke_cli(tmp_path):

@@ -691,8 +691,7 @@ def test_lint_collectives_budget_exit_code(tmp_path, capsys):
 
 @pytest.mark.slow
 def test_lint_smoke_subprocess():
-    """The fast-tier CI gate end to end: all examples lint clean and the
-    committed static evidence matches a fresh recompute."""
+    """The fast-tier CI gate end to end: all examples lint clean."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "lint_program.py"),
@@ -700,7 +699,7 @@ def test_lint_smoke_subprocess():
         capture_output=True, text=True, env=env, timeout=540,
     )
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-1000:]
-    assert "static evidence matches" in proc.stdout
+    assert "all examples clean" in proc.stdout
 
 
 def test_smoke_gate_in_process():

@@ -16,10 +16,8 @@ and ASSERTS the engine's promises instead of trusting them:
   * HLO dedup evidence: one slab gather moving U_pad < n_ids rows, and
     a firing dedup-off control.
 
-Prints one JSON report (also written to --out); tools/ convention of
-bench_input.py / bench_checkpoint.py. EMBEDDING_EVIDENCE_r08.json is
-this report at the pinned smoke config, gated by
-test_embedding_evidence_r08_committed.
+Prints one JSON report; tests/test_embedding.py reads the ``--smoke``
+run's report from stdout and holds its claims.
 """
 
 import argparse
@@ -154,7 +152,6 @@ def main():
     ap = argparse.ArgumentParser("sharded embedding engine bench")
     ap.add_argument("--smoke", action="store_true",
                     help="small workload + hard asserts (fast tier)")
-    ap.add_argument("--out", default=None, help="write the JSON here too")
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--batch", type=int, default=None)
     args = ap.parse_args()
@@ -293,11 +290,7 @@ def main():
         assert ev_off["rows_moved"] >= ev_on["n_ids"], ev_off
         report["smoke"]["asserts"] = "passed"
 
-    txt = json.dumps(report, indent=1, sort_keys=True)
-    print(txt)
-    if args.out:
-        with open(args.out, "w") as f:
-            f.write(txt + "\n")
+    print(json.dumps(report, indent=1, sort_keys=True))
     return 0
 
 

@@ -101,7 +101,7 @@ def run_loader(n_samples, batch_size, num_workers, work, digest=False):
 
 def capture_trace(out_path, n_samples, batch_size, work):
     """Short traced pass: returns the span-name aggregate from the
-    exported Chrome trace (PROFILE.md's input-pipeline timeline)."""
+    exported Chrome trace."""
     from paddle_tpu import observability as obs
 
     obs.enable_tracing()

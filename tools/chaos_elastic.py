@@ -32,14 +32,13 @@ The property gate — replay determinism:
   resizes.
 
 ``--smoke`` runs the seconds-scale configuration and asserts all of it
-— wired into the fast tier (tests/test_elastic.py, which also uses
-``--evidence`` output as the ELASTIC_EVIDENCE_r14.json drift gate: one
-scenario run serves both, the chaos_serve/chaos_train pattern).
+— wired into the fast tier (tests/test_elastic.py reads the ``--json``
+report of the same run and asserts its invariants).
 
 Usage:
   python tools/chaos_elastic.py [--nproc 4] [--min-nproc 2]
       [--steps 16] [--interval 2] [--kill-step 5] [--kill-rank 3]
-      [--preempt-step 12] [--smoke] [--json] [--evidence OUT.json]
+      [--preempt-step 12] [--smoke] [--json]
 """
 
 import argparse
@@ -528,8 +527,6 @@ def run_scenario(args, work):
     check(el_losses == ref_losses,
           f"rank-0 loss sequence diverged at steps "
           f"{sorted(s for s in el_losses if el_losses.get(s) != ref_losses.get(s))[:5]}")
-    loss_digest = hashlib.sha256(json.dumps(
-        sorted(el_losses.items())).encode()).hexdigest()
 
     # -- exactly-once ------------------------------------------------------
     problems, per_epoch = check_exactly_once(el_committed)
@@ -596,7 +593,6 @@ def run_scenario(args, work):
             "lost_or_duplicated": len(problems),
             "bit_identical": bit_identical,
             "stream_digest": el_digest,
-            "rank0_loss_digest": loss_digest,
             "shrink_sharded_restored": shrink_r0.get("sharded_restored"),
             "grow_sharded_restored": grow_r0.get("sharded_restored"),
             "grown_ranks_from_chief": len(grown),
@@ -611,30 +607,6 @@ def run_scenario(args, work):
         "failures": failures,
     }
     return report
-
-
-def _write_evidence(path, report):
-    payload = {
-        "issue": 14,
-        "generated_by": ("python tools/chaos_elastic.py --smoke "
-                         "--evidence ELASTIC_EVIDENCE_r14.json"),
-        "drift_gates": [
-            "tests/test_elastic.py::test_elastic_evidence_r14_committed "
-            "(live recompute via --smoke --evidence)",
-        ],
-        "scenario": report["scenario"],
-        "invariants": report["invariants"],
-        # informational: timing-dependent, NOT drift-gated
-        "measured": report["measured"],
-    }
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=1, sort_keys=True)
-        f.write("\n")
-    inv = payload["invariants"]
-    print(f"wrote {path}: schedule="
-          f"{[(p['world'], p['start'], p['stop']) for p in inv['schedule']]} "
-          f"bit_identical={inv['bit_identical']} "
-          f"lost_or_duplicated={inv['lost_or_duplicated']}")
 
 
 def main(argv=None):
@@ -665,8 +637,6 @@ def main(argv=None):
                     help="keep artifacts here instead of a tmpdir")
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-scale run + invariant asserts (CI)")
-    ap.add_argument("--evidence", metavar="OUT.json",
-                    help="write the elastic evidence file")
     ap.add_argument("--json", action="store_true", dest="as_json")
     args = ap.parse_args(argv)
 
@@ -685,8 +655,6 @@ def main(argv=None):
         if not args.workdir:
             shutil.rmtree(work, ignore_errors=True)
     wall = time.perf_counter() - t0
-    if args.evidence:
-        _write_evidence(args.evidence, report)
     if args.as_json:
         print(json.dumps({"pass": not report["failures"], **report,
                           "wall_s": round(wall, 1)}))

@@ -15,7 +15,7 @@ corruption) checkpoint state.
 
 `--smoke` runs the seconds-scale configuration and asserts all of it —
 wired into the fast test tier by tests/test_resilience.py, the same
-pattern as tools/bench_serving.py.
+pattern as tools/chaos_serve.py.
 
 Usage:
   python tools/chaos_train.py [--nproc 2] [--steps 30] [--interval 5]

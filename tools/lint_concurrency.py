@@ -18,13 +18,7 @@ one machine-readable report line.
      mutation; a blocking-under-lock control rides along) — the gate is
      proven live, not vacuously green;
   3. the runtime lockdep witness raises on a live ABBA inversion and on
-     a declared-hierarchy violation (observability/lockdep.py);
-  4. the static half of CONCURRENCY_EVIDENCE_r11.json matches a fresh
-     recompute (drift = the analyzer or the sources changed without
-     regenerating evidence — run
-     ``python tools/stress_concurrency.py --evidence
-     CONCURRENCY_EVIDENCE_r11.json``). The runtime (lockdep) half is
-     drift-gated by tests/test_concurrency.py.
+     a declared-hierarchy violation (observability/lockdep.py).
 """
 
 import argparse
@@ -137,29 +131,6 @@ def _print_report(rep, as_json, out=sys.stdout):
 # ---------------------------------------------------------------------------
 
 
-def static_section(rep):
-    """The static half of CONCURRENCY_EVIDENCE_r11.json, derived from a
-    Report — ONE definition shared by the evidence generator
-    (tools/stress_concurrency.py) and the drift checks here/in tests.
-    Suppression entries carry (file, reason) — not line numbers, which
-    would drift on every unrelated edit."""
-    return {
-        "files": rep.files,
-        "lock_ids": sorted(l.id for l in rep.locks),
-        "unsuppressed_findings": len(rep.findings),
-        "cycles": rep.cycles,
-        "hold_edges": sorted({(e.a, e.b) for e in rep.edges}),
-        "suppressions": sorted(
-            {(f.file, f.suppress_reason) for f in rep.suppressed}
-        ),
-    }
-
-
-def _norm(section):
-    """Committed JSON turns tuples into lists; normalize both sides."""
-    return json.loads(json.dumps(section))
-
-
 def _smoke(as_json):
     from paddle_tpu.analysis.concurrency import scan_sources
 
@@ -249,28 +220,11 @@ def _smoke(as_json):
         lockdep.reset()
         lockdep.enable(was)
 
-    # 4. static evidence drift gate
-    path = os.path.join(REPO, "CONCURRENCY_EVIDENCE_r11.json")
-    if not os.path.exists(path):
-        check(False,
-              "CONCURRENCY_EVIDENCE_r11.json missing (run "
-              "tools/stress_concurrency.py --evidence "
-              "CONCURRENCY_EVIDENCE_r11.json)")
-    else:
-        with open(path) as f:
-            committed = json.load(f)
-        fresh = _norm(static_section(rep))
-        want = committed.get("static", {})
-        for key in sorted(set(fresh) | set(want)):
-            check(want.get(key) == fresh.get(key),
-                  f"static evidence drift in '{key}': committed "
-                  f"{want.get(key)!r} != fresh {fresh.get(key)!r}")
-
     if not failures:
         print(f"smoke: concurrency lint clean over {rep.files} files "
               f"({len(rep.locks)} locks, {len(rep.suppressed)} attributed "
               f"suppressions), all 3 static controls + 2 runtime witness "
-              f"controls fired, static evidence matches")
+              f"controls fired")
     if as_json:
         print(json.dumps({"pass": not failures, "failures": failures,
                           "files": rep.files, "locks": len(rep.locks),
@@ -288,7 +242,7 @@ def main(argv=None):
                     help="one JSON report line")
     ap.add_argument("--smoke", action="store_true",
                     help="fast-tier CI gate: repo clean + positive "
-                    "controls fire + static evidence matches")
+                    "controls fire")
     try:
         args = ap.parse_args(argv)
         if args.smoke:

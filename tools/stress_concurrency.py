@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Deterministic-interleaving concurrency stress harness + evidence.
+"""Deterministic-interleaving concurrency stress harness.
 
 Drives the repo's hottest threaded paths — GenerationEngine
 admission/retire, RequestQueue admission/expiry, EmbeddingEngine
@@ -27,13 +27,11 @@ CI contract: exit 0 = clean, 1 = failures, 2 = internal error;
 ``--smoke`` runs every scenario once on the default seed (wired into
 tier-1 by tests/test_concurrency.py); ``--json`` machine summary.
 
-``--evidence OUT.json`` regenerates CONCURRENCY_EVIDENCE_r11.json: a
-DETERMINISTIC single-threaded lockdep pass over the decode + serving +
-embedding + checkpoint + dataio drivers records the discovered
-lock-order hierarchy (e.g. ``serving.queue -> decode.tenant``), merged
-with the static lint inventory — drift-gated by
-tests/test_concurrency.py (runtime half) and
-``tools/lint_concurrency.py --smoke`` (static half).
+``lockdep_pass()`` is a DETERMINISTIC single-threaded lockdep pass over
+the decode + serving + embedding + checkpoint + dataio + fleet drivers;
+it returns the witnessed lock-order hierarchy (e.g. ``serving.queue ->
+decode.tenant``), which tests/test_concurrency.py holds against the
+chains declared in code.
 """
 
 import argparse
@@ -105,7 +103,7 @@ def scenario_queue(seed, n_per_thread=60, threads=4):
     stop = threading.Event()
 
     def submitter(k):
-        rng = random.Random((seed, "submit", k))
+        rng = random.Random(f"{seed}:submit:{k}")
         try:
             for i in range(n_per_thread):
                 deadline = (time.perf_counter() + 0.005
@@ -193,7 +191,7 @@ def scenario_decode(seed, n_requests=6):
         SamplingParams,
     )
 
-    rng = random.Random((seed, "decode"))
+    rng = random.Random(f"{seed}:decode")
     prompts = [[rng.randrange(16) for _ in range(rng.randrange(1, 5))]
                for _ in range(n_requests)]
     max_news = [rng.randrange(1, 5) for _ in range(n_requests)]
@@ -277,7 +275,7 @@ def _decode_overload_leg(seed):
     from paddle_tpu.resilience import faults
     from paddle_tpu.serving.decode import GenerationEngine
 
-    rng = random.Random((seed, "overload"))
+    rng = random.Random(f"{seed}:overload")
     prompts = [[rng.randrange(16) for _ in range(4)] for _ in range(2)]
     engine = GenerationEngine(queue_depth=8, breaker_threshold=0)
     entry = engine.register_model(lambda: _small_decode_model(
@@ -315,7 +313,7 @@ def _decode_overload_leg(seed):
 
 
 def _embedding_stream(seed, steps=30, batch=6, id_space=40):
-    rng = random.Random((seed, "embedding"))
+    rng = random.Random(f"{seed}:embedding")
     return [[rng.randrange(id_space) for _ in range(batch)]
             for _ in range(steps)]
 
@@ -442,11 +440,11 @@ _SCENARIO_FNS = {
 
 
 # ---------------------------------------------------------------------------
-# deterministic evidence drivers (single-threaded lockdep pass)
+# deterministic drivers (single-threaded lockdep pass)
 # ---------------------------------------------------------------------------
 
 
-def _drive_decode_evidence():
+def _drive_decode():
     """Decode + serving-queue exercise with NO scheduler thread: submit,
     expire, admit (prefill+inject), step, retire — every acquisition on
     this thread, so the discovered edge set is a pure function of the
@@ -456,7 +454,7 @@ def _drive_decode_evidence():
     engine = GenerationEngine(queue_depth=16, breaker_threshold=0)
     engine.set_tenant("a", weight=2.0)
     entry = engine.register_model(
-        lambda: _small_decode_model("evidence", slots=2, max_len=8))
+        lambda: _small_decode_model("lockpass", slots=2, max_len=8))
     r1 = engine.submit([1, 2], max_new_tokens=2, tenant="a")
     r2 = engine.submit([3], max_new_tokens=2, tenant="b")
     dead = engine.submit([4], max_new_tokens=2, tenant="a",
@@ -474,11 +472,11 @@ def _drive_decode_evidence():
     # blocks-under-slot chains, draft-KV walks decode.draft ->
     # decode.blocks (the declared proposal-slot chain)
     engine.register_model(
-        lambda: _small_decode_model("evidence_d", slots=2, max_len=8))
-    b = engine.submit([1, 2], max_new_tokens=2, model="evidence",
+        lambda: _small_decode_model("lockpass_d", slots=2, max_len=8))
+    b = engine.submit([1, 2], max_new_tokens=2, model="lockpass",
                       beam_width=2)
-    s = engine.submit([3, 1], max_new_tokens=2, model="evidence",
-                      draft_model="evidence_d", spec_k=2)
+    s = engine.submit([3, 1], max_new_tokens=2, model="lockpass",
+                      draft_model="lockpass_d", spec_k=2)
     for _ in range(12):
         if b.done() and s.done():
             break
@@ -490,12 +488,12 @@ def _drive_decode_evidence():
     # decode.blocks, witnessing the declared decode.blocks ->
     # decode.tier edge; the resume walks it again via the host tier
     ov = engine.register_model(
-        lambda: _small_decode_model("evidence_ov", slots=2, max_len=16,
+        lambda: _small_decode_model("lockpass_ov", slots=2, max_len=16,
                                     block_size=2, num_blocks=6))
     o1 = engine.submit([1, 2, 3, 4], max_new_tokens=6,
-                       model="evidence_ov")
+                       model="lockpass_ov")
     o2 = engine.submit([5, 6, 7, 8], max_new_tokens=6,
-                       model="evidence_ov")
+                       model="lockpass_ov")
     for _ in range(40):
         if o1.done() and o2.done():
             break
@@ -508,7 +506,7 @@ def _drive_decode_evidence():
     engine.stats()
 
 
-def _drive_queue_evidence():
+def _drive_queue():
     from paddle_tpu.serving.decode.engine import GenerationRequest
     from paddle_tpu.serving.queue import RequestQueue
     from paddle_tpu.serving.request import Priority
@@ -524,7 +522,7 @@ def _drive_queue_evidence():
     q.note_drained()
 
 
-def _drive_embedding_evidence(tmpdir):
+def _drive_embedding(tmpdir):
     """Embedding write-back + a checkpoint save through extra_state: the
     manifest/table/pending hierarchy in one deterministic pass."""
     import numpy as np
@@ -536,7 +534,7 @@ def _drive_embedding_evidence(tmpdir):
 
     scope = fluid.Scope()
     engine = EmbeddingEngine(scope=scope, push_workers=1)
-    rt = engine.register(TableConfig("evidence", 4, capacity=16, ep=2))
+    rt = engine.register(TableConfig("lockpass", 4, capacity=16, ep=2))
     for step in range(6):
         ids = np.asarray([(step * 5 + j) % 24 for j in range(6)], np.int64)
         rt.lookup(ids, train=True)
@@ -549,21 +547,21 @@ def _drive_embedding_evidence(tmpdir):
     engine.close()
 
 
-def _drive_metrics_evidence():
+def _drive_metrics():
     from paddle_tpu.observability import metrics as obs_metrics
     from paddle_tpu.serving.metrics import ServingMetrics
 
-    m = ServingMetrics(engine_label="lockdep-evidence")
+    m = ServingMetrics(engine_label="lockdep-pass")
     m.tenant_incr("tokens", "a")
     m.tenant_counts("tokens")
     obs_metrics.scrape_text()
 
 
-def _drive_dataio_evidence():
+def _drive_dataio():
     _dataio_digest(0, num_workers=2, prefetch=True)
 
 
-def _drive_fleet_evidence():
+def _drive_fleet():
     """Fleet router + local replicas with NO pump or scheduler threads:
     submit (routing reads the replica queue depth under fleet.router —
     the hierarchy's top edge), a replica death, the parked re-dispatch,
@@ -577,73 +575,52 @@ def _drive_fleet_evidence():
         engine = GenerationEngine(queue_depth=8, breaker_threshold=0,
                                   label=f"lockdep-fleet-{i}")
         engine.register_model(
-            lambda: _small_decode_model("evidence", slots=2, max_len=8))
+            lambda: _small_decode_model("lockpass", slots=2, max_len=8))
         router.add_replica(LocalReplica(f"r{i}", i, engine))
     resp = router.submit([1, 2], max_new_tokens=1)
     (rr,) = router._inflight.values()
     victim = rr.replica
     router._replicas[victim].kill()
-    router._mark_dead(victim, "evidence")
+    router._mark_dead(victim, "lockpass")
     router._tick()          # health pass + re-dispatch of the parked rr
     assert rr.replica is not None and rr.replica != victim
-    entry = router._replicas[rr.replica].engine.entry("evidence", "1")
+    entry = router._replicas[rr.replica].engine.entry("lockpass", "1")
     entry._admit_free_slots()   # prefill fast path finishes max_new=1
     router._tick()              # poll + deliver
     assert resp.done() and resp.error() is None
     router.stats()
 
 
-def evidence_sections(tmpdir=None):
+def lockdep_pass(tmpdir=None):
     """Run every deterministic driver under an armed, reset lockdep and
-    return the evidence payload {lockdep, static}. The SAME function
-    backs ``--evidence`` and the drift gate in tests/test_concurrency.py
-    — committed claims must re-derive, byte-for-byte."""
-    import importlib.util
+    return its snapshot (edges, declared chains, cycles, violations)."""
     import tempfile
 
-    from paddle_tpu.analysis.concurrency import scan_paths
     from paddle_tpu.observability import lockdep
-
-    spec = importlib.util.spec_from_file_location(
-        "lint_concurrency", os.path.join(REPO, "tools",
-                                         "lint_concurrency.py"))
-    lint_mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(lint_mod)
 
     was = lockdep.enabled()
     hook = lockdep.get_stall_hook()
     own_tmp = None
     if tmpdir is None:
-        own_tmp = tempfile.TemporaryDirectory(prefix="lockdep_evidence_")
+        own_tmp = tempfile.TemporaryDirectory(prefix="lockdep_pass_")
         tmpdir = own_tmp.name
     try:
         lockdep.set_stall_hook(None)
         lockdep.enable()
         lockdep.reset()
-        _drive_queue_evidence()
-        _drive_decode_evidence()
-        _drive_embedding_evidence(tmpdir)
-        _drive_metrics_evidence()
-        _drive_dataio_evidence()
-        _drive_fleet_evidence()
-        snap = lockdep.snapshot()
+        _drive_queue()
+        _drive_decode()
+        _drive_embedding(tmpdir)
+        _drive_metrics()
+        _drive_dataio()
+        _drive_fleet()
+        return lockdep.snapshot()
     finally:
         lockdep.reset()
         lockdep.enable(was)
         lockdep.set_stall_hook(hook)
         if own_tmp is not None:
             own_tmp.cleanup()
-    static = lint_mod.static_section(scan_paths([os.path.join(
-        REPO, "paddle_tpu")]))
-    return {
-        "lockdep": {
-            "edges": snap["edges"],
-            "declared": sorted(snap["declared"]),
-            "cycles": snap["cycles"],
-            "violations": snap["violations"],
-        },
-        "static": static,
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -712,28 +689,6 @@ def _run_scenarios(names, seed, as_json):
     return EXIT_FINDINGS if failures else EXIT_CLEAN
 
 
-def _write_evidence(path):
-    payload = {
-        "issue": 11,
-        "generated_by": ("python tools/stress_concurrency.py --evidence "
-                         "CONCURRENCY_EVIDENCE_r11.json"),
-        "drift_gates": [
-            "tests/test_concurrency.py::"
-            "test_concurrency_evidence_r11_committed",
-            "tools/lint_concurrency.py --smoke (static half)",
-        ],
-    }
-    payload.update(evidence_sections())
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=1, sort_keys=True)
-        f.write("\n")
-    lk = payload["lockdep"]
-    print(f"wrote {path}: {len(lk['edges'])} witnessed edges, "
-          f"{len(lk['declared'])} declared chains, cycles={lk['cycles']}, "
-          f"{payload['static']['unsuppressed_findings']} static findings")
-    return EXIT_CLEAN if not lk["cycles"] else EXIT_FINDINGS
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="deterministic concurrency stress harness")
@@ -742,13 +697,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--smoke", action="store_true",
                     help="tier-1 gate: all scenarios once on the seed")
-    ap.add_argument("--evidence", metavar="OUT.json",
-                    help="regenerate the concurrency evidence file")
     ap.add_argument("--json", action="store_true", dest="as_json")
     try:
         args = ap.parse_args(argv)
-        if args.evidence:
-            return _write_evidence(args.evidence)
         if args.smoke and args.scenario:
             print("--smoke is the ALL-scenarios tier-1 gate; drop "
                   "--scenario (use --scenario/--seed alone to replay)",
