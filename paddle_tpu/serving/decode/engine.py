@@ -20,7 +20,13 @@ Scheduling modes, all bit-identical to the offline whole-sequence
 reference for any admission order (tested, not asserted by construction
 alone):
 
-* **decode** — the ``[S, 1]`` hot path, as in PR 10.
+* **decode** — the ``[S, 1]`` hot path, as in PR 10. The step program
+  chooses each slot's greedy token itself (``arg_max`` over its own
+  float32 logits); a step whose slots are all greedy brings those S
+  integers to the host, any other step (a sampled slot, a beam group, a
+  grammar masked on the host, a hand-built model without
+  ``token_fetch``) the whole ``[S, 1, V]`` logits. Decided per step
+  from the slots' own policies; both give the same tokens.
 * **chunked prefill** — a prompt longer than the chunk budget streams
   through the ``[1, C]`` chunk program ONE chunk per engine iteration,
   interleaved with decode steps, so a 32k-token admission never stalls
@@ -63,8 +69,8 @@ Measured from inside (all of it nothing while tracing is off): one
 ``step_fetch`` / ``sample`` — each carrying its request's id where it has
 one; a launch span also says how long the host spent inside
 ``jax.device_put`` and inside the executable's call. Always on: a time
-stamp per token on the ``Response``, and the bytes that cross the device
-boundary (``DecodeMetrics``).
+stamp per token on the ``Response``, the bytes that cross the device
+boundary and the steps that fetched the whole logits (``DecodeMetrics``).
 """
 
 import threading
@@ -354,9 +360,14 @@ class _ModelEntry:
         from paddle_tpu.core import lowering
 
         m = self._model
+        # the logits stay at index 0; the device-chosen tokens follow
+        # where the program has them (a hand-built model may not)
+        step_fetches = [m.logits_fetch]
+        if m.token_fetch is not None:
+            step_fetches.append(m.token_fetch)
         plans = [
             ("step", m.decode_program, m.decode_feed_sig(),
-             [m.logits_fetch], True),
+             step_fetches, True),
             ("prefill", m.prefill_program, m.prefill_feed_sig(),
              [m.prefill_logits_fetch] + [n for kv in m.prefill_kv_fetches
                                          for n in kv], False),
@@ -416,7 +427,9 @@ class _ModelEntry:
 
     def _fetch(self, value):
         """One fetch brought to the host (here the host waits for the
-        device), counted in ``serving_fetched_bytes_total``."""
+        device), counted in ``serving_fetched_bytes_total``. An output
+        nobody passes here stays on the device: a greedy decode step
+        fetches its ``[S, 1]`` tokens and leaves the logits there."""
         a = np.asarray(value)
         self._metrics.incr("fetched_bytes", a.nbytes)
         return a
@@ -1658,13 +1671,17 @@ class _ModelEntry:
 
     # -- generation policy (host-side selection over fetched logits) ------
     def _choose_token(self, st, logits_row, device_masked):
-        """The ONE token-selection point for non-beam paths: grammar
-        mask (host-applied unless the decode program already added the
+        """The ONE token-selection point for non-beam paths, wherever a
+        logits ROW becomes a token on the host: grammar mask
+        (host-applied unless the decode program already added the
         DEC_MASK feed — bit-identical either way, float32 add on both
         sides), then the committed-stream sampler or plain argmax. The
         step index is the absolute emitted-token index, so the sampled
         stream replays bit-exactly for ANY admission order, batchmates,
-        or slot assignment."""
+        or slot assignment. A decode step whose slots are all greedy has
+        no row to pass: its tokens are the step program's own argmax
+        over the same float32 rows (`_tokens_suffice`, `_sample`), the
+        first index of the maximum on both sides."""
         row = np.asarray(logits_row, dtype=np.float32).reshape(-1)
         if st.grammar is not None and not device_masked:
             row = row + st.grammar.mask()
@@ -1889,7 +1906,35 @@ class _ModelEntry:
         req.response._complete(error=error)
         self._metrics.observe_request(req)
 
+    def _tokens_suffice(self, active, groups):
+        """Whether this step's host half needs nothing but the
+        device-chosen tokens: every stepping slot is greedy and its
+        grammar mask, if it has one, was added on the device. A beam
+        group ranks whole rows, a sampler draws from one and a
+        host-applied grammar masks one, so any of them in the step
+        brings the whole ``[S, 1, V]`` logits over, as does a model with
+        no ``token_fetch``. Decided per step from the slots themselves:
+        no option selects it."""
+        m = self._model
+        if m.token_fetch is None or groups:
+            return False
+        for s in active:
+            st = self._slots[s]
+            if st.sampling is not None and not st.sampling.greedy:
+                return False
+            if st.grammar is not None and not m.logits_mask:
+                return False
+        return True
+
     def _step(self):
+        """One decode iteration: feeds, launch, ONE fetch, the host
+        half. The fetch (``decode::step_fetch``, where the host waits
+        for the device) brings the ``[S, 1]`` tokens when
+        `_tokens_suffice`, else the ``[S, 1, V]`` float32 logits, counted
+        in ``serving_decode_logits_fetch_steps_total``; the span says
+        which (``rows="tokens"|"logits"``). Nothing is sliced out of the
+        device array: that would dispatch, and could compile, inside a
+        serving window."""
         with _span("decode::feeds") as sp:
             built = self._step_feeds()
             if sp is not None and built is not None:
@@ -1910,13 +1955,20 @@ class _ModelEntry:
             return
         if self._breaker is not None:
             self._breaker_event(self._breaker.record_success())
+        tokens_only = self._tokens_suffice(active, groups)
         with _span("decode::step_fetch") as sp:
-            logits = self._fetch(fetches[0])             # [S, 1, V]
+            if tokens_only:
+                fetched = self._fetch(fetches[1])        # [S, 1] int
+            else:
+                fetched = self._fetch(fetches[0])        # [S, 1, V]
+                self._metrics.incr("decode_logits_fetch_steps")
             if sp is not None:
-                sp.set(bytes=logits.nbytes)
+                sp.set(bytes=fetched.nbytes,
+                       rows="tokens" if tokens_only else "logits")
         now = time.perf_counter()
         with _span("decode::sample") as sp:
-            stepped = self._sample(logits, active, groups, now)
+            stepped = self._sample(fetched, active, groups, now,
+                                   tokens_only)
             if sp is not None:
                 sp.set(tokens=stepped)
         if stepped is not None:
@@ -2020,20 +2072,30 @@ class _ModelEntry:
             feeds[DecodeModel.DEC_MASK] = dmask
         return feeds, active, groups
 
-    def _sample(self, logits, active, groups, now):
-        """The host half of a decode step over its fetched ``[S, 1, V]``
-        logits: choose each active slot's token (stamped ``now``), commit
-        its KV append, retire or expire it; then one selection per beam
-        group. Returns the slot-steps done, or None when a beam fork lost
-        the arena."""
+    def _sample(self, fetched, active, groups, now, tokens_only):
+        """The host half of a decode step over what it fetched: each
+        active slot's token (stamped ``now``), its KV append committed,
+        the slot retired or expired; then one selection per beam group.
+        ``fetched`` is the ``[S, 1, V]`` logits, from which
+        `_choose_token` takes every token, or with ``tokens_only`` the
+        ``[S, 1]`` tokens the step program chose itself (no beam group
+        steps then): a slot takes its integer, and a device-masked
+        grammar still advances on it. Returns the slot-steps done, or
+        None when a beam fork lost the arena."""
         m = self._model
         stepped = len(active)
         for s in active:
             st = self._slots[s]
             self._blocks.note_append(
                 st.blocks[st.cursor // m.block_size])
-            nxt = self._choose_token(st, logits[s, 0],
-                                     device_masked=m.logits_mask)
+            if tokens_only:
+                nxt = int(fetched[s, 0])
+                if st.grammar is not None:
+                    st.grammar.advance(nxt)
+                    self._metrics.incr("grammar_steps")
+            else:
+                nxt = self._choose_token(st, fetched[s, 0],
+                                         device_masked=m.logits_mask)
             st.generated.append(nxt)
             st.cursor += 1
             st.last_token = nxt
@@ -2062,7 +2124,7 @@ class _ModelEntry:
                 self._blocks.note_append(
                     bst.blocks[bst.cursor // m.block_size])
                 bst.cursor += 1
-                row = np.asarray(logits[sid, 0],
+                row = np.asarray(fetched[sid, 0],
                                  dtype=np.float32).reshape(-1)
                 if bst.grammar is not None and not m.logits_mask:
                     row = row + bst.grammar.mask()

@@ -14,8 +14,12 @@ What a caller waits for, from the per-token time stamps on ``Response``:
 ``serving_decode_inter_token_seconds`` (the mean gap between a request's
 tokens), observed at retirement. What crosses the device boundary:
 ``serving_fed_bytes_total`` / ``serving_fetched_bytes_total``, with
-``serving_step_launches_total`` to put them per decode step. What a step's
-attention has to read: ``serving_decode_live_blocks_total`` over
+``serving_step_launches_total`` to put them per decode step. A greedy
+decode step fetches its ``[S, 1]`` tokens, chosen by the step program;
+``serving_decode_logits_fetch_steps_total`` counts the steps that brought
+the whole ``[S, 1, V]`` float32 logits to the host instead, because a
+slot sampled, searched beams or masked a grammar on the host. What a
+step's attention has to read: ``serving_decode_live_blocks_total`` over
 ``serving_decode_block_slots_total``.
 """
 
@@ -74,8 +78,11 @@ class DecodeMetrics(ServingMetrics):
         "brownout_transitions", "brownout_shed",
         # the device boundary: bytes of the feeds every launch puts,
         # bytes of the fetches brought back to the host, and the launches
-        # of the decode-step program (the denominator for "per step")
+        # of the decode-step program (the denominator for "per step");
+        # and of the engine's own decode steps, those whose fetch was
+        # the whole logits and not the device-chosen tokens
         "fed_bytes", "fetched_bytes", "step_launches",
+        "decode_logits_fetch_steps",
         # what the paged-attention kernel has to read: blocks that hold
         # a stepping slot's positions up to its cursor, over every block
         # of every slot (what the whole-arena gather read)

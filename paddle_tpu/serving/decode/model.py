@@ -16,6 +16,10 @@ static:
   slots are bit-invisible, admitted slots join mid-flight, and the
   compiled executable never sees the batch change). Arenas are DONATED
   through core/lowering.py: the scatter is an in-place device update.
+  Two outputs: the float32 logits ``[S, 1, V]`` and, chosen on the
+  device from those same logits, ``next_token [S, 1]`` (their argmax
+  over the vocabulary). The engine brings the tokens alone to the host
+  on a step whose slots are all greedy, the logits on every other.
 * **prefill** — whole-prompt forward at ``[1, L]`` with a causal
   additive bias, fetching per-layer K/V rows ``[1, L, H]`` and logits
   ``[1, L, V]``. Stateless (donation off): its outputs are
@@ -63,7 +67,15 @@ class DecodeModel:
     ``(k_rows, v_rows)`` fetch names of the prefill program. ``builder``
     (optional) is a zero-arg callable that re-creates a content-identical
     DecodeModel — the circuit breaker's relaunch path uses it to rebuild
-    a replica that warms entirely from the compile cache."""
+    a replica that warms entirely from the compile cache.
+
+    The decode step fetches ``[logits_fetch, token_fetch]``:
+    ``logits_fetch`` names the float32 ``[S, 1, V]`` logits (mask added
+    when ``logits_mask``), ``token_fetch`` the ``[S, 1]`` integers that
+    the program's own ``arg_max`` takes from them: the first index of
+    each row's maximum, what ``np.argmax`` gives the host over the same
+    row. A hand-built model without such a var leaves it None, and the
+    engine then fetches the logits on every step."""
 
     # feed-name contract (fixed; the engine builds these arrays)
     DEC_TOKEN = "dec_token"
@@ -88,7 +100,8 @@ class DecodeModel:
                  prefill_kv_fetches, inject_kv_feeds, block_size,
                  num_blocks, chunk_program=None, chunk_tokens=None,
                  chunk_logits_fetch=None, eos_id=None, name="model",
-                 version="1", builder=None, logits_mask=False):
+                 version="1", builder=None, logits_mask=False,
+                 token_fetch=None):
         self.decode_program = decode_program
         self.prefill_program = prefill_program
         self.inject_program = inject_program
@@ -103,6 +116,7 @@ class DecodeModel:
         self.chunk_tokens = int(chunk_tokens) if chunk_tokens else None
         self.state_names = list(state_names)
         self.logits_fetch = logits_fetch
+        self.token_fetch = token_fetch
         self.prefill_logits_fetch = prefill_logits_fetch
         self.chunk_logits_fetch = chunk_logits_fetch
         self.prefill_kv_fetches = list(prefill_kv_fetches)
@@ -237,6 +251,15 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
     retrace; an all-zeros mask is a bit-exact no-op for every
     unconstrained slot.
 
+    The decode program ENDS in one ``arg_max`` over the vocabulary axis
+    of those logits (after the mask, where there is one):
+    ``next_token [S, 1]``, the model's ``token_fetch``. Greedy token
+    choice happens there, so a step of greedy slots hands the host S
+    integers and not ``S * V`` floats; the logits stay an output (index
+    0 of the step's fetches) for the steps whose slots sample, search
+    beams or mask on the host. Prefill, inject and chunk programs have
+    no such op.
+
     The decode step's attention is ONE ``paged_attention`` op — the
     row-index feeds and the block size enter the op directly. Its
     reference lowering is the gather+attention composite
@@ -351,6 +374,8 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
         dec_logits = proj(h, V, "head")
         if lmask is not None:
             dec_logits = fluid.layers.logits_mask_add(dec_logits, lmask)
+        # greedy choice on the device: first index of each row's maximum
+        next_token = fluid.layers.argmax(dec_logits, axis=-1)
 
     # -- inject: scatter prefill rows into arbitrary arena rows ----------
     inject = Program()
@@ -425,6 +450,7 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
         slots=S, max_len=L, vocab_size=V, hidden=H,
         block_size=BS, num_blocks=NB, chunk_tokens=C,
         state_names=state_names, logits_fetch=dec_logits.name,
+        token_fetch=next_token.name,
         prefill_logits_fetch=pre_logits.name,
         chunk_logits_fetch=chu_logits_name,
         prefill_kv_fetches=kv_fetches, inject_kv_feeds=inj_feeds,

@@ -11,6 +11,7 @@ and ``span()`` cost a check and nothing else while their gates are off.
 
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -270,8 +271,12 @@ def test_step_spans_carry_their_sizes(traced_run):
     assert len(steps) == len(fetches) == len(samples) \
         == entry.metrics.count("decode_steps")
     assert len(feeds) >= len(steps)
+    # four greedy requests: every step brings its [S, 1] tokens, chosen
+    # by the step program, and leaves the logits on the device
+    assert {s["args"]["rows"] for s in fetches} == {"tokens"}
     assert {s["args"]["bytes"] for s in fetches} == {
-        m.slots * 1 * m.vocab_size * 4}
+        m.slots * jax.dtypes.canonicalize_dtype(np.int64).itemsize}
+    assert entry.metrics.count("decode_logits_fetch_steps") == 0
     assert sum(s["args"]["tokens"] for s in samples) == \
         entry.metrics.count("generated_tokens")
     assert all(1 <= s["args"]["active"] <= m.slots for s in feeds

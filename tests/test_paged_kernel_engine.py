@@ -22,17 +22,26 @@ MAX_NEW = [6, 7, 5, 6]
 
 
 def _record_logits(entry, into):
-    """Keep every stepping slot's logits row, by response, as the decode
-    step's sampling sees it."""
-    sample = entry._sample
+    """Keep every stepping slot's logits row, by response: the decode
+    step's first output, read here whichever of its outputs the engine
+    brings to the host (a greedy step fetches its tokens alone)."""
+    run, sample = entry._run, entry._sample
+    step = {}
 
-    def recording(logits, active, groups, now):
+    def running(kind, feeds, span=None):
+        fetches = run(kind, feeds, span)
+        if kind == "step":
+            step["logits"] = np.asarray(fetches[0])
+        return fetches
+
+    def recording(fetched, active, groups, now, tokens_only):
         slots = list(active) + [s for g in groups for s in g.order]
         for s in slots:
             into.setdefault(id(entry._slots[s].request.response), []).append(
-                np.array(logits[s, 0]))
-        return sample(logits, active, groups, now)
+                np.array(step["logits"][s, 0]))
+        return sample(fetched, active, groups, now, tokens_only)
 
+    entry._run = running
     entry._sample = recording
 
 
