@@ -26,7 +26,13 @@ alone):
   integers to the host, any other step (a sampled slot, a beam group, a
   grammar masked on the host, a hand-built model without
   ``token_fetch``) the whole ``[S, 1, V]`` logits. Decided per step
-  from the slots' own policies; both give the same tokens.
+  from the slots' own policies; both give the same tokens. A step that
+  needs its tokens alone (and steps no grammar, whose next mask follows
+  from the token's value) is left on the device, and the next
+  iteration launches its successor, fed those tokens as the device
+  array they are, BEFORE it fetches them: depth one, decided per
+  iteration from the scheduler's own state (``_iterate``), the same
+  tokens in either order.
 * **chunked prefill** — a prompt longer than the chunk budget streams
   through the ``[1, C]`` chunk program ONE chunk per engine iteration,
   interleaved with decode steps, so a 32k-token admission never stalls
@@ -68,9 +74,13 @@ Measured from inside (all of it nothing while tracing is off): one
 ``decode::chunk`` / ``chunk_fetch``, ``decode::feeds`` / ``step`` /
 ``step_fetch`` / ``sample`` — each carrying its request's id where it has
 one; a launch span also says how long the host spent inside
-``jax.device_put`` and inside the executable's call. Always on: a time
-stamp per token on the ``Response``, the bytes that cross the device
-boundary and the steps that fetched the whole logits (``DecodeMetrics``).
+``jax.device_put`` and inside the executable's call, ``decode::step``
+whether it was launched ahead of the previous step's fetch (``ahead``),
+and a ``decode::step_fetch`` made with nothing launched over it, why
+(``drain``). Always on: a time stamp per token on the ``Response``, taken
+when the host has the token, the bytes that cross the device boundary,
+the steps that fetched the whole logits and the steps launched ahead
+(``DecodeMetrics``).
 """
 
 import threading
@@ -210,11 +220,14 @@ class _Slot:
     physical arena row of position ``p`` (the device half of the
     table). ``d_*`` is the draft-KV footprint of a speculative slot:
     its slot/blocks/row-map ON THE DRAFT ENTRY plus ``d_cursor``, the
-    next draft arena position without a committed KV row."""
+    next draft arena position without a committed KV row. ``ahead``
+    counts the slot's tokens that a launched decode step has produced on
+    the device and the host has not read yet: ``cursor`` already counts
+    their rows, ``generated`` and ``last_token`` do not hold them."""
 
     __slots__ = ("request", "mode", "cursor", "last_token", "generated",
                  "blocks", "row_map", "plen", "done", "shared_len", "toks",
-                 "sampling", "grammar", "beam", "score", "seq",
+                 "sampling", "grammar", "beam", "score", "seq", "ahead",
                  "d_entry", "d_slot", "d_blocks", "d_row_map", "d_cursor")
 
     def __init__(self, request, mode="decode"):
@@ -226,6 +239,7 @@ class _Slot:
         self.blocks = []
         self.row_map = None
         self.seq = 0            # admission order (default victim policy)
+        self.ahead = 0          # tokens launched, not yet on the host
         self.plen = len(request.prompt)
         self.done = 0           # chunked prefill: prompt positions landed
         self.shared_len = 0     # positions served by radix-shared blocks
@@ -260,6 +274,27 @@ class _BeamGroup:
         # slot here for later forks instead of returning it to the pool,
         # so a fork can never lose its slot to a concurrent admission
         self.spare = []
+
+
+class _LaunchedStep:
+    """One decode step on the device whose fetch the host has not made
+    yet. ``states`` are the ``_Slot`` objects of ``active`` at launch: a
+    slot that was retired or rejected since (its id may serve another
+    request by now) gets no token. ``host_s`` is the wall time of the
+    ``_step`` body that launched it, when that body delivered nothing:
+    the step's share of ``serving_decode_step_seconds`` that its
+    delivery still owes."""
+
+    __slots__ = ("fetches", "active", "states", "groups", "tokens_only",
+                 "host_s")
+
+    def __init__(self, fetches, active, states, groups, tokens_only):
+        self.fetches = fetches
+        self.active = active
+        self.states = states
+        self.groups = groups
+        self.tokens_only = tokens_only
+        self.host_s = 0.0
 
 
 class _ParkedSession:
@@ -310,6 +345,7 @@ class _ModelEntry:
         self._brownout = BrownoutController()
         self._bt_seen = 0       # brownout transitions already counted
         self._iteration = 0     # decode::iterate spans opened (traced only)
+        self._launched = None   # the _LaunchedStep in flight, if any
         self._admit_seq = 0
         self._chunk_throttle = False
         self.victim_policy = None   # callable([slot ids]) -> slot id
@@ -400,7 +436,10 @@ class _ModelEntry:
         bytes fed and the nanoseconds the host spent inside
         ``jax.device_put`` (from the span's opening) and inside the
         executable's call — two clock reads, no child span, so the device
-        module still belongs to the caller's span."""
+        module still belongs to the caller's span. A feed that is a
+        device array already (a launched-ahead step's tokens: the
+        previous step's own output) is handed over as it is: nothing is
+        put, nothing counted as fed, and the host does not wait for it."""
         import jax
 
         entry, executable = self._entries[kind]
@@ -408,9 +447,12 @@ class _ModelEntry:
         fed = 0
         feed_vals = []
         for n in entry.feed_names:
-            a = np.ascontiguousarray(feeds[n])
-            fed += a.nbytes
-            feed_vals.append(jax.device_put(a, dev))
+            a = feeds[n]
+            if not isinstance(a, jax.Array):
+                a = np.ascontiguousarray(a)
+                fed += a.nbytes
+                a = jax.device_put(a, dev)
+            feed_vals.append(a)
         if span is not None:
             put_ns = span.elapsed_ns()
         donated = tuple(self._scope.find_var(n) for n in entry.donated)
@@ -450,6 +492,8 @@ class _ModelEntry:
         self._pool.reset()
         self._blocks.reset()
         self._slots = [None] * m.slots
+        # a step in flight read the lost arena: it is never delivered
+        self._launched = None
 
     def relaunch(self):
         """The circuit breaker's replacement replica: rebuild programs
@@ -493,11 +537,32 @@ class _ModelEntry:
             pass
 
     def _iterate(self):
-        """ONE scheduler iteration: expire, (breaker), admit up to the
-        free slots, advance AT MOST ONE prefill chunk, run one verify
-        cycle per speculative slot, then one decode step. Extracted so
-        tests can hand-step the interleaving deterministically. Returns
-        True when the loop should exit."""
+        """ONE scheduler iteration. Extracted so tests can hand-step the
+        interleaving deterministically. Returns True when the loop
+        should exit.
+
+        With no decode step in flight the phases run as they always
+        did: expire, (breaker), admit up to the free slots, advance AT
+        MOST ONE prefill chunk, run one verify cycle per speculative
+        slot, then `_step`: feeds, launch, and — when a slot's policy
+        needs the logits — the fetch and the host half at once. A step
+        whose slots need only their tokens is left IN FLIGHT instead,
+        its cursors already advanced, and the next iteration decides at
+        its top (`_drain_reason`) between two orders:
+
+        * **ahead**: nothing of this iteration would wait on the device
+          or change who steps. No admission runs, a prefilling slot
+          gets a chunk that is not its prompt's last (a launch, no
+          fetch), and `_step` launches step N+1, fed step N's tokens as
+          the device array they are, BEFORE it fetches and delivers
+          step N: the host's wait for N's tokens hides under N+1.
+        * **drain**: anything else. Step N is fetched and delivered
+          first, then the iteration runs as above. A produced token
+          never waits behind an admission's prefill, and no slot is
+          retired in the middle of one.
+
+        Depth one: at most one step is on the device unread, and the
+        two orders share every line but the place of the fetch."""
         with _span("decode::iterate") as sp:
             if sp is not None:
                 self._iteration += 1
@@ -517,7 +582,13 @@ class _ModelEntry:
                     and self._pool.active_count == 0
                     and not self._parked and not self._pending):
                 return True
-        self._brownout_tick()
+            queued = not self._queue.empty()
+        moved = self._brownout_tick()
+        if self._launched is not None:
+            why = "brownout" if moved else self._drain_reason(queued)
+            if why is not None:
+                self._drain(why)
+        ahead = self._launched is not None
         if self._breaker is not None and not self._stop:
             verdict, wait_s = self._breaker.gate()
             if verdict == "wait":
@@ -541,8 +612,11 @@ class _ModelEntry:
                     self._breaker_event(self._breaker.record_failure())
                     return False
         # parked sessions and deferred admissions get first claim on
-        # freed capacity — FIFO, before any new pick from the queue
-        admitted = self._service_parked() + self._admit_free_slots()
+        # freed capacity — FIFO, before any new pick from the queue. With
+        # a step in flight there is neither, and a request that arrived
+        # since the decision waits one iteration for the drain.
+        admitted = (0 if ahead else
+                    self._service_parked() + self._admit_free_slots())
         progressed = self._advance_prefills() + self._advance_spec()
         if not any(st is not None and st.mode in ("decode", "beam")
                    for st in self._slots):
@@ -557,6 +631,45 @@ class _ModelEntry:
             return False
         self._step()
         return False
+
+    def _steps_again(self, st):
+        """Whether a decode slot is fed to the next step, from what the
+        cursor shows: its launched tokens, read or not, leave room under
+        ``max_new`` and ``max_len``. Only a slot with a token in flight
+        can say no (any other was retired when its last token landed);
+        an ``eos_id`` is not known yet and costs one wasted row."""
+        return (len(st.generated) + st.ahead < st.request.max_new
+                and st.cursor < self._model.max_len)
+
+    def _drain_reason(self, queued):
+        """Why the step in flight has to be fetched and delivered before
+        this iteration goes on, or None when the next step may launch
+        ahead of that fetch. From state that is there: a phase of this
+        iteration would wait on the device or change who steps
+        (``queued`` work and a free slot, a parked or deferred session,
+        a speculative slot's verify, a prompt's last chunk), the engine
+        is stopping or its breaker is not closed, or no slot of the step
+        in flight steps again."""
+        if self._stop:
+            return "shutdown"
+        if self._breaker is not None and self._breaker.state != "closed":
+            return "breaker"
+        if self._parked or self._pending:
+            return "parked"
+        if queued and self._pool.free_count > 0:
+            return "admission"
+        steps = False
+        for st in self._slots:
+            if st is None:
+                continue
+            if st.mode == "spec":
+                return "spec"
+            if st.mode == "prefill":
+                if st.plen - st.done <= self._model.chunk_tokens:
+                    return "prefill"
+            elif self._steps_again(st):
+                steps = True
+        return None if steps else "idle"
 
     def _reject_expired(self, request):
         self._metrics.incr("deadline_missed")
@@ -763,7 +876,9 @@ class _ModelEntry:
         it can never be resumed because its lifetime footprint exceeds
         the whole pool)."""
         st = self._slots[s]
-        if st is None or st.mode not in ("decode", "spec"):
+        if st is None or st.mode not in ("decode", "spec") or st.ahead:
+            # a session whose last token is still on the device cannot
+            # be spilled: its rows are known, its tokens are not
             return False
         req = st.request
         m = self._model
@@ -1051,7 +1166,8 @@ class _ModelEntry:
     def _brownout_tick(self):
         """One severity evaluation per scheduler iteration. Occupancy
         saturates while anything is parked or deferred — the arena is
-        over-subscribed even if the instantaneous row count dipped."""
+        over-subscribed even if the instantaneous row count dipped.
+        Returns whether the ladder moved."""
         occ = self._blocks.stats()["occupancy"]
         if self._parked or self._pending:
             occ = 1.0
@@ -1060,13 +1176,15 @@ class _ModelEntry:
                             queue_seconds=qp["queue_seconds"],
                             deadline=qp["deadline"])
         n = len(self._brownout.transitions)
-        if n > self._bt_seen:
+        moved = n > self._bt_seen
+        if moved:
             self._metrics.incr("brownout_transitions", n - self._bt_seen)
             for t in self._brownout.stamp(self._bt_seen,
                                           time.perf_counter()):
                 _instant("brownout::transition", **{
                     k: t[k] for k in ("from", "to", "trigger", "value")})
             self._bt_seen = n
+        return moved
 
     def _shed_confirmed(self):
         """Live pressure re-check guarding the two REJECT gates (L4
@@ -1927,59 +2045,145 @@ class _ModelEntry:
         return True
 
     def _step(self):
-        """One decode iteration: feeds, launch, ONE fetch, the host
-        half. The fetch (``decode::step_fetch``, where the host waits
-        for the device) brings the ``[S, 1]`` tokens when
-        `_tokens_suffice`, else the ``[S, 1, V]`` float32 logits, counted
-        in ``serving_decode_logits_fetch_steps_total``; the span says
-        which (``rows="tokens"|"logits"``). Nothing is sliced out of the
-        device array: that would dispatch, and could compile, inside a
-        serving window."""
-        with _span("decode::feeds") as sp:
-            built = self._step_feeds()
-            if sp is not None and built is not None:
-                sp.set(active=len(built[1]), beam_groups=len(built[2]))
+        """One decode iteration: feeds and launch, then the ONE fetch of
+        a step and its host half, in one of two orders.
+
+        The cursor half of the host's work (`_advance_cursors`) runs as
+        soon as the launch has returned. A step that `_tokens_suffice`
+        and that steps no grammar is then left IN FLIGHT. If one was in
+        flight already, this is the launch AHEAD of its fetch: the feeds
+        were built from cursors that count it, its ``[S, 1]`` tokens are
+        this step's token feed without leaving the device, and it is
+        fetched and delivered (`_land`) only now, under the step just
+        launched. Any other step (a sampled slot, a beam group, a
+        grammar, a model without ``token_fetch``) lands in this same
+        body, as every step once did. A step in flight that this one
+        cannot follow (`_step_feeds` names why), or that nothing
+        follows, is drained first.
+
+        The fetch (``decode::step_fetch``, where the host waits for the
+        device unless the step has finished under its successor) brings
+        the ``[S, 1]`` tokens, or the ``[S, 1, V]`` float32 logits,
+        counted in ``serving_decode_logits_fetch_steps_total``; the span
+        says which (``rows="tokens"|"logits"``). Nothing is sliced out
+        of the device array: that would dispatch, and could compile,
+        inside a serving window."""
+        built = self._traced_feeds()
+        if isinstance(built, str):
+            self._drain(built)
+            built = self._traced_feeds()
+        prev = self._launched
         if built is None:
+            if prev is not None:
+                self._drain("idle")
             return
         feeds, active, groups = built
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::step") as ev:
                 faults.fire("decode.step")
+                if ev.span is not None:
+                    ev.span.set(ahead=prev is not None)
                 fetches = self._run("step", feeds, ev.span)
         except Exception as e:
             # a failed donated call leaves the arena undefined: every
-            # in-flight sequence is lost (failed loudly), the batch-level
-            # outcome drives the breaker, and the arena resets
+            # in-flight sequence is lost (failed loudly; with a step in
+            # flight, the slots of both, and that step is not delivered),
+            # the batch-level outcome drives the breaker, and the arena
+            # resets
             self._arena_lost(f"decode-step failure: {e}")
             return
         if self._breaker is not None:
             self._breaker_event(self._breaker.record_success())
-        tokens_only = self._tokens_suffice(active, groups)
+        step = _LaunchedStep(fetches, active,
+                             [self._slots[s] for s in active], groups,
+                             self._tokens_suffice(active, groups))
+        self._advance_cursors(step.states)
+        if prev is not None:
+            self._metrics.incr("decode_steps_ahead")
+            self._launched = step
+            self._land(prev, t0)
+        elif step.tokens_only and not any(
+                st.grammar is not None for st in step.states):
+            step.host_s = time.perf_counter() - t0
+            self._launched = step
+        else:
+            # a grammar's next mask follows from the token's VALUE, even
+            # where the device adds it: such a step lands here too
+            self._land(step, t0)
+
+    def _traced_feeds(self):
+        with _span("decode::feeds") as sp:
+            built = self._step_feeds()
+            if sp is not None and isinstance(built, tuple):
+                sp.set(active=len(built[1]), beam_groups=len(built[2]))
+        return built
+
+    def _advance_cursors(self, states):
+        """The half of a step's host work that follows from the cursor
+        alone, done once the step is launched: the K/V append committed
+        and the cursor moved, so the next `_step_feeds` builds from
+        positions that count this step whether or not its tokens have
+        been read. Who steps again follows (`_steps_again`). A beam
+        group's cursors move with its selection, in `_sample`."""
+        bs = self._model.block_size
+        for st in states:
+            self._blocks.note_append(st.blocks[st.cursor // bs])
+            st.cursor += 1
+            st.ahead += 1
+
+    def _drain(self, why):
+        """Fetch and deliver the step in flight with nothing launched
+        over it: the host waits for the device here, and
+        ``decode::step_fetch`` says why (``drain=``)."""
+        step, self._launched = self._launched, None
+        self._land(step, time.perf_counter(), drain=why)
+
+    def _land(self, step, t0, drain=None):
+        """The ONE fetch of a launched step and the half of the host's
+        work that needs the tokens' values (`_sample`). Observes the
+        step in ``serving_decode_step_seconds``: the wall time since
+        ``t0`` (the `_step` body, or the drain) plus what the step's
+        launch cost in a body that delivered nothing."""
         with _span("decode::step_fetch") as sp:
-            if tokens_only:
-                fetched = self._fetch(fetches[1])        # [S, 1] int
+            if step.tokens_only:
+                fetched = self._fetch(step.fetches[1])       # [S, 1] int
             else:
-                fetched = self._fetch(fetches[0])        # [S, 1, V]
+                fetched = self._fetch(step.fetches[0])       # [S, 1, V]
                 self._metrics.incr("decode_logits_fetch_steps")
             if sp is not None:
                 sp.set(bytes=fetched.nbytes,
-                       rows="tokens" if tokens_only else "logits")
+                       rows="tokens" if step.tokens_only else "logits")
+                if drain is not None:
+                    sp.set(drain=drain)
+        # a slot retired or rejected since the launch (an ``eos_id``, a
+        # deadline: seen only when the step before this one landed) was
+        # stepped for nothing; its token is dropped
+        active = [s for s, st in zip(step.active, step.states)
+                  if self._slots[s] is st]
         now = time.perf_counter()
         with _span("decode::sample") as sp:
-            stepped = self._sample(fetched, active, groups, now,
-                                   tokens_only)
+            stepped = self._sample(fetched, active, step.groups, now,
+                                   step.tokens_only)
             if sp is not None:
                 sp.set(tokens=stepped)
         if stepped is not None:
-            self._metrics.observe_step(stepped, stepped,
-                                       time.perf_counter() - t0)
+            self._metrics.observe_step(
+                stepped, stepped, time.perf_counter() - t0 + step.host_s)
 
     def _step_feeds(self):
         """The decode step's feeds from the live slots: ``(feeds, active
         slot ids, beam groups with a live slot)``, or None when there is
         nothing to step (or the arena was lost making a cursor
-        writable)."""
+        writable). With a step in flight the cursors count it already, a
+        slot it finishes is left out, and the token feed is its
+        ``[S, 1]`` device output itself: rows of slots that do not step
+        are ignored as ever (their ``wrows`` is the sentinel, their
+        ``bias`` all ``NEG_INF``). That holds only if every stepping
+        slot has its token in that step and none has to be parked; else
+        nothing is built and the reason to drain comes back as a string.
+        What the loop has done before is done again unchanged after the
+        drain: a block opened stays opened."""
         m = self._model
         S, L, R = m.slots, m.max_len, m.rows
         tok = np.zeros((S, 1), "int64")
@@ -1992,10 +2196,16 @@ class _ModelEntry:
         active = []
         groups = []     # beam groups with a live slot this step
         live_blocks = 0
+        launched = self._launched
         for s in range(S):
             st = self._slots[s]
             if st is None or st.mode not in ("decode", "beam"):
                 continue
+            if launched is not None:
+                if not st.ahead:
+                    return "slots"      # its last token is on the host
+                if not self._steps_again(st):
+                    continue
             # make the cursor position writable: allocate a fresh block
             # when it opens a new chunk, COW when it lands in a SHARED
             # partial tail (divergence), unregister an exclusively-owned
@@ -2018,6 +2228,8 @@ class _ModelEntry:
                 # the host tier, resume byte-identically later) instead
                 # of failing; loud only when the host tier cannot absorb
                 # it or the session can never be resumed
+                if launched is not None:
+                    return "park"   # spill what the session has produced
                 self._metrics.incr("blocks_exhausted")
                 parked = (self._park_group(st.beam) if st.mode == "beam"
                           else self._park_slot(s))
@@ -2070,12 +2282,16 @@ class _ModelEntry:
                  DecodeModel.DEC_WRITE_ROWS: wrows}
         if dmask is not None:
             feeds[DecodeModel.DEC_MASK] = dmask
+        if launched is not None:
+            feeds[DecodeModel.DEC_TOKEN] = launched.fetches[1]
         return feeds, active, groups
 
     def _sample(self, fetched, active, groups, now, tokens_only):
-        """The host half of a decode step over what it fetched: each
-        active slot's token (stamped ``now``), its KV append committed,
-        the slot retired or expired; then one selection per beam group.
+        """The half of a decode step's host work that needs what it
+        fetched (`_advance_cursors` did the other at launch): each
+        active slot's token, stamped ``now`` — when the host HAS it,
+        however long ago the device chose it — then the slot retired or
+        expired; then one selection per beam group.
         ``fetched`` is the ``[S, 1, V]`` logits, from which
         `_choose_token` takes every token, or with ``tokens_only`` the
         ``[S, 1]`` tokens the step program chose itself (no beam group
@@ -2086,8 +2302,7 @@ class _ModelEntry:
         stepped = len(active)
         for s in active:
             st = self._slots[s]
-            self._blocks.note_append(
-                st.blocks[st.cursor // m.block_size])
+            st.ahead -= 1
             if tokens_only:
                 nxt = int(fetched[s, 0])
                 if st.grammar is not None:
@@ -2097,7 +2312,6 @@ class _ModelEntry:
                 nxt = self._choose_token(st, fetched[s, 0],
                                          device_masked=m.logits_mask)
             st.generated.append(nxt)
-            st.cursor += 1
             st.last_token = nxt
             st.request.response.token_times.append(now)
             self._metrics.tenant_incr("tokens", st.request.tenant)
@@ -2143,9 +2357,11 @@ class _ModelEntry:
 
     def _finished(self, st):
         m = self._model
+        # the cursor as of the slot's last token on the host: a step
+        # launched over it has moved the cursor once more
         return (len(st.generated) >= st.request.max_new
                 or (m.eos_id is not None and st.last_token == m.eos_id)
-                or st.cursor >= m.max_len)
+                or st.cursor - st.ahead >= m.max_len)
 
     def _retire(self, slot):
         st = self._slots[slot]

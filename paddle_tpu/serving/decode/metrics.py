@@ -21,6 +21,25 @@ the whole ``[S, 1, V]`` float32 logits to the host instead, because a
 slot sampled, searched beams or masked a grammar on the host. What a
 step's attention has to read: ``serving_decode_live_blocks_total`` over
 ``serving_decode_block_slots_total``.
+
+The order of a decode step's phases. A step is feeds, launch
+(``decode::step``), ONE fetch (``decode::step_fetch``) and the host half
+that needs the fetched values (``decode::sample``). A step that needs
+only its tokens stays on the device after its launch, and the scheduler
+launches the next one, fed those tokens as a device array, AHEAD of the
+fetch: ``serving_decode_steps_ahead_total`` counts such launches,
+beside ``serving_step_launches_total`` that counts all, and the
+``decode::step`` span says ``ahead=True|False``. A DRAIN is the other
+order: the step in flight is fetched and delivered with nothing launched
+over it, because the iteration is about to admit, resume, verify, end a
+prompt's prefill, park, stop, or has nothing more to step; its
+``decode::step_fetch`` span says why (``drain=``). Every step is observed
+once in ``serving_decode_step_seconds``, when it is delivered: the wall
+time of the ``_step`` body that delivered it (with a step in flight that
+is the launch of step N+1 and the fetch and host half of step N), or of
+the drain, plus the time of the body that launched it if that body
+delivered nothing. The sum over a window is the host time spent on
+decode steps; an iteration's other phases are not in it.
 """
 
 from paddle_tpu.serving.metrics import ServingMetrics
@@ -83,6 +102,8 @@ class DecodeMetrics(ServingMetrics):
         # the whole logits and not the device-chosen tokens
         "fed_bytes", "fetched_bytes", "step_launches",
         "decode_logits_fetch_steps",
+        # step launches made before the previous step was fetched
+        "decode_steps_ahead",
         # what the paged-attention kernel has to read: blocks that hold
         # a stepping slot's positions up to its cursor, over every block
         # of every slot (what the whole-arena gather read)

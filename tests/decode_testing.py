@@ -1,9 +1,15 @@
 """Shared by the decode test modules: weights under which the served
-tokens depend on the prompt and on the K/V rows, the jit counter, and
-the three-request speculative scenario."""
+tokens depend on the prompt and on the K/V rows, a model whose every
+step is the serial one, a recorder of each delivered logits row, the jit
+counter, and the three-request speculative scenario."""
+
+import collections
+
+import numpy as np
 
 from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.serving.decode import GenerationEngine, build_decoder_model
+from paddle_tpu.serving.decode.model import DecodeModel
 
 SPEC_PROMPTS = ([3, 1, 4, 1, 5], [9, 2, 6], [3, 1, 4, 1, 5, 9])
 SPEC_MAX_NEW = (12, 10, 12)
@@ -21,6 +27,54 @@ def sharpen(entry, factor=8.0):
         if name.endswith(".tok_emb"):
             scope.set(name, scope.find_var(name) * factor)
     return entry
+
+
+def without_token_fetch(m):
+    """The same programs under the same names, built by hand with no
+    ``token_fetch``: every step fetches its logits alone and lands
+    before the next is launched, as every step of the engine once
+    did."""
+    return DecodeModel(
+        decode_program=m.decode_program, prefill_program=m.prefill_program,
+        inject_program=m.inject_program, startup_program=m.startup_program,
+        chunk_program=m.chunk_program, chunk_tokens=m.chunk_tokens,
+        chunk_logits_fetch=m.chunk_logits_fetch,
+        slots=m.slots, max_len=m.max_len, vocab_size=m.vocab_size,
+        hidden=m.hidden, state_names=m.state_names,
+        logits_fetch=m.logits_fetch,
+        prefill_logits_fetch=m.prefill_logits_fetch,
+        prefill_kv_fetches=m.prefill_kv_fetches,
+        inject_kv_feeds=m.inject_kv_feeds, block_size=m.block_size,
+        num_blocks=m.num_blocks, eos_id=m.eos_id, name=m.name,
+        version=m.version, logits_mask=m.logits_mask)
+
+
+def record_step_logits(entry, into):
+    """Keep every delivered slot's logits row in ``into``, by response:
+    the decode step's first output, read here whichever of its outputs
+    the engine brings to the host (a greedy step fetches its tokens
+    alone). Steps are delivered in the order of their launches, one of
+    them perhaps after the next one's launch: the rows wait in that
+    order."""
+    run, sample = entry._run, entry._sample
+    launched = collections.deque()
+
+    def running(kind, feeds, span=None):
+        fetches = run(kind, feeds, span)
+        if kind == "step":
+            launched.append(np.asarray(fetches[0]))
+        return fetches
+
+    def recording(fetched, active, groups, now, tokens_only):
+        logits = launched.popleft()
+        slots = list(active) + [s for g in groups for s in g.order]
+        for s in slots:
+            into.setdefault(id(entry._slots[s].request.response), []).append(
+                np.array(logits[s, 0]))
+        return sample(fetched, active, groups, now, tokens_only)
+
+    entry._run = running
+    entry._sample = recording
 
 
 def jits():

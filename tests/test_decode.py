@@ -24,7 +24,13 @@ import time
 
 import numpy as np
 import pytest
-from decode_testing import SPEC_MAX_NEW, jits, sharpen, spec_leg
+from decode_testing import (
+    SPEC_MAX_NEW,
+    jits,
+    sharpen,
+    spec_leg,
+    without_token_fetch,
+)
 
 from paddle_tpu.resilience import faults
 from paddle_tpu.serving.decode import (
@@ -828,17 +834,26 @@ def test_block_pool_exhaustion_fails_loudly_and_recovers():
 # ---------------------------------------------------------------------------
 
 
-def test_chunked_prefill_interleaves_and_matches_unchunked():
+@pytest.mark.parametrize("order", ["ahead", "serial"])
+def test_chunked_prefill_interleaves_and_matches_unchunked(order):
     """A long prompt admits through the [1, C] chunk program ONE chunk
     per engine iteration: the in-flight decode slot gains a token EVERY
     iteration of the admission window (never stalls longer than the
     chunk budget), and the chunked generation is bit-identical to the
     offline (unchunked, whole-sequence) reference. Hand-stepped through
-    entry._iterate() for a deterministic interleaving record."""
+    entry._iterate() for a deterministic interleaving record. Two orders
+    of a step's fetch give the same record: ``serial`` (a model without
+    ``token_fetch``: every step lands in the iteration that launched
+    it) and ``ahead`` (the token an iteration delivers is the step's
+    that the iteration BEFORE launched; the chunks between the first and
+    the last run under a step in flight, the admission and the last
+    chunk drain it first)."""
     engine = GenerationEngine(queue_depth=16, breaker_threshold=0)
-    entry = engine.register_model(lambda: build_decoder_model(
+    model = build_decoder_model(
         vocab_size=32, hidden=8, num_layers=2, slots=2, max_len=32,
-        block_size=4, chunk_tokens=5, name="chunkfair", version="1"))
+        block_size=4, chunk_tokens=5, name="chunkfair", version="1")
+    entry = engine.register_model(
+        model if order == "ahead" else without_token_fetch(model))
     rng = np.random.RandomState(11)
     long_prompt = [int(t) for t in rng.randint(0, 32, size=17)]
     ref_long = entry.offline_decode(long_prompt, 5)
@@ -846,8 +861,12 @@ def test_chunked_prefill_interleaves_and_matches_unchunked():
     short = engine.submit([1, 2], max_new_tokens=20)
     assert entry._admit_free_slots() == 1
     entry._step()                              # short is mid-generation
+    # ahead: that step's token is still on the device
+    assert len(entry._slots[0].generated) == (1 if order == "ahead" else 2)
+    assert (entry._launched is not None) == (order == "ahead")
     lng = engine.submit(long_prompt, max_new_tokens=5)
     progress = []
+    in_flight = []
     for _ in range(40):
         before = len(entry._slots[0].generated)
         if entry._iterate():
@@ -857,6 +876,7 @@ def test_chunked_prefill_interleaves_and_matches_unchunked():
         prefilling = any(
             st is not None and st.mode == "prefill" for st in entry._slots)
         progress.append((prefilling, after - before))
+        in_flight.append(entry._launched is not None)
         if short.done() and lng.done():
             break
     # fairness: during EVERY iteration the long admission was chunking,
@@ -868,6 +888,16 @@ def test_chunked_prefill_interleaves_and_matches_unchunked():
     assert [int(t) for t in short.result(timeout=5)["tokens"]] == ref_short
     assert entry.metrics.count("chunk_runs") >= 3
     assert entry.metrics.count("chunk_tokens") >= 16
+    m = entry.metrics
+    assert m.count("step_launches") == m.count("decode_steps")
+    if order == "serial":
+        assert m.count("decode_steps_ahead") == 0 and not any(in_flight)
+    else:
+        # every iteration but the last leaves a step in flight, and all
+        # launches but three were ahead of a fetch: the hand-made first,
+        # the one after the admission, the one after the last chunk
+        assert all(in_flight[:-1]) and not in_flight[-1]
+        assert m.count("decode_steps") - m.count("decode_steps_ahead") == 3
 
 
 def test_chunked_prefill_skips_radix_shared_chunks():

@@ -13,7 +13,7 @@ the logits path of the parent. The tokens must be the same.
 import jax
 import numpy as np
 import pytest
-from decode_testing import sharpen
+from decode_testing import sharpen, without_token_fetch
 
 from paddle_tpu import observability as obs
 from paddle_tpu.serving.decode import (
@@ -62,23 +62,6 @@ def _build(name, **opts):
         block_size=4, name=name, version="1", **opts)
 
 
-def _without_token_fetch(m):
-    """The same programs under the same names, built by hand with no
-    ``token_fetch``: the step fetches its logits alone, as every step of
-    the parent did."""
-    return DecodeModel(
-        decode_program=m.decode_program, prefill_program=m.prefill_program,
-        inject_program=m.inject_program, startup_program=m.startup_program,
-        slots=m.slots, max_len=m.max_len, vocab_size=m.vocab_size,
-        hidden=m.hidden, state_names=m.state_names,
-        logits_fetch=m.logits_fetch,
-        prefill_logits_fetch=m.prefill_logits_fetch,
-        prefill_kv_fetches=m.prefill_kv_fetches,
-        inject_kv_feeds=m.inject_kv_feeds, block_size=m.block_size,
-        num_blocks=m.num_blocks, eos_id=m.eos_id, name=m.name,
-        version=m.version, logits_mask=m.logits_mask)
-
-
 def _serve(model, submits, host_grammar):
     """Every request queued before the engine starts, so all are admitted
     in one round and step together. Returns (entry, answers, the
@@ -122,7 +105,7 @@ def test_served_tokens_equal_the_logits_path_and_the_fetch_follows_the_slots(
     assert built.token_fetch is not None
     entry, got, spans = _serve(built, submits, host_grammar)
     ref_entry, want, ref_spans = _serve(
-        _without_token_fetch(_build(f"tf_{case}", **opts)), submits,
+        without_token_fetch(_build(f"tf_{case}", **opts)), submits,
         host_grammar)
     assert got == want
     assert any(len(set(a["tokens"])) > 2 for a in got), got
@@ -141,8 +124,17 @@ def test_served_tokens_equal_the_logits_path_and_the_fetch_follows_the_slots(
         assert logits_steps == 0
         assert {(s["args"]["rows"], s["args"]["bytes"]) for s in spans} \
             == {("tokens", m.slots * TOKEN_BYTES)}
-    # the hand-built model has no tokens to fetch: every step, the logits
+    # a step launches ahead of the previous one's fetch only where that
+    # one needed its tokens alone and stepped no grammar (ISSUE 34)
+    ahead = entry.metrics.count("decode_steps_ahead")
+    if every_step:
+        assert ahead == 0
+    elif case == "greedy":
+        assert 0 < ahead < steps
+    # the hand-built model has no tokens to fetch: every step, the logits,
+    # and every step lands before the next is launched
     ref_m = ref_entry.metrics
+    assert ref_m.count("decode_steps_ahead") == 0
     assert ref_m.count("decode_logits_fetch_steps") \
         == ref_m.count("decode_steps") == len(ref_spans)
     assert {s["args"]["rows"] for s in ref_spans} == {"logits"}
@@ -169,6 +161,11 @@ def test_a_greedy_batch_takes_the_short_path_once_its_sampled_mate_retires():
     # the first token of each request is the prefill's: 3 steps sampled
     assert rows == ["logits"] * 3 + ["tokens"] * 6
     assert entry.metrics.count("decode_logits_fetch_steps") == 3
+    # and only the greedy rest launches ahead of a fetch: its first step
+    # follows a step that landed in its own body, the other five a step
+    # in flight; the last fetch has nothing launched over it
+    assert entry.metrics.count("decode_steps_ahead") == 5
+    assert [s["args"].get("drain") for s in spans] == [None] * 8 + ["idle"]
     for prompt, kw, a in zip(PROMPTS, submits, got):
         assert a["tokens"] == entry.offline_decode(
             prompt, kw["max_new_tokens"], sampling=kw.get("sampling"))
@@ -181,7 +178,7 @@ def test_two_equal_maxima_give_the_lower_index_on_both_paths():
     largest."""
     tokens = {}
     for path, shape in (("tokens", lambda m: m),
-                        ("logits", _without_token_fetch)):
+                        ("logits", without_token_fetch)):
         engine = GenerationEngine(queue_depth=8, breaker_threshold=0)
         entry = engine.register_model(shape(_build("tf_tie")))
         scope = entry._scope
@@ -277,5 +274,5 @@ def test_the_step_executable_fetches_logits_then_tokens():
     # nothing was counted as fetched: `_fetch` is the one counting door
     assert entry.metrics.count("fetched_bytes") == 0
     hand = GenerationEngine(queue_depth=8, breaker_threshold=0) \
-        .register_model(_without_token_fetch(_build("tf_exec")))
+        .register_model(without_token_fetch(_build("tf_exec")))
     assert len(hand._run("step", feeds)) == 1

@@ -7,6 +7,12 @@ spans carry its id, every returned token has a time stamp, the bytes that
 cross the device boundary are counted, and the overload decisions (brownout
 transitions, refusals at the door) are events with a time. ``RecordEvent``
 and ``span()`` cost a check and nothing else while their gates are off.
+
+Since ISSUE 34 a greedy step's fetch comes after the NEXT step's launch.
+What is pinned here about the order of an iteration is pinned in both
+orders, as cases: ``ahead`` is the built model, ``serial`` the same
+programs without ``token_fetch``, whose every step fetches its logits and
+lands before anything else is launched.
 """
 
 import time
@@ -14,6 +20,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from decode_testing import without_token_fetch
 
 from paddle_tpu import observability as obs
 from paddle_tpu import profiler
@@ -53,11 +60,16 @@ def _prompts():
             for n in PROMPT_LENS]
 
 
-def _serve(name, traced, **submit):
+ORDERS = ("ahead", "serial")
+
+
+def _serve(name, traced, order="ahead", **submit):
     """Four requests hand-stepped to the end; returns (entry, requests'
     responses). The engine thread is never started."""
     engine = GenerationEngine(queue_depth=32, breaker_threshold=0)
-    entry = engine.register_model(lambda: _model(name))
+    model = _model(name)
+    entry = engine.register_model(
+        model if order == "ahead" else without_token_fetch(model))
     if traced:
         obs.enable_tracing()
     try:
@@ -82,12 +94,13 @@ def clean_tracer():
     obs.get_tracer().clear()
 
 
-@pytest.fixture(scope="module")
-def traced_run():
+@pytest.fixture(scope="module", params=ORDERS)
+def traced_run(request):
     obs.get_tracer().clear()
-    entry, resps = _serve("trc_on", traced=True)
+    entry, resps = _serve("trc_on", traced=True, order=request.param)
     spans = obs.get_tracer().spans()
     obs.get_tracer().clear()
+    entry.order = request.param
     return entry, resps, spans
 
 
@@ -126,23 +139,30 @@ def test_tracing_off_records_nothing_and_serves_the_same_bytes(
             entry.offline_decode(p, MAX_NEW)
 
 
+@pytest.mark.parametrize("order", ORDERS)
 def test_tracing_off_an_iteration_reads_the_clock_as_the_parent_did_plus_one_per_request(
-        clean_tracer, monkeypatch):
+        clean_tracer, monkeypatch, order):
     """What the engine reads off ``time`` with tracing off: per decode step
     three (start, the tokens' ``now``, end), per one-shot prefill and per
     chunk two (start, end), per admitted request its dispatch time and —
-    the one read this instrumentation adds — its first token's stamp. The
-    tracer's own clock is never read."""
+    the one read this instrumentation adds — its first token's stamp. A
+    step that is left in flight and then DRAINED is timed in two pieces,
+    the body that launched it (start, end) and the drain (start, ``now``,
+    end): two reads more for every launch that was not ahead of a fetch.
+    The tracer's own clock is never read."""
     engine_clock, tracer_clock = _CountingClock(), _CountingClock()
     monkeypatch.setattr(engine_mod, "time", engine_clock)
     monkeypatch.setattr(tracer_mod, "time", tracer_clock)
-    entry, resps = _serve("trc_clock", traced=False)
+    entry, resps = _serve("trc_clock", traced=False, order=order)
     m = entry.metrics
     assert m.count("brownout_transitions") == 0
     admitted = len(resps)
+    steps, ahead = m.count("decode_steps"), m.count("decode_steps_ahead")
+    assert (ahead > 0) == (order == "ahead")
+    drained = steps - ahead if order == "ahead" else 0
     # one more per request: GenerationRequest's submit_time
     assert engine_clock.reads == (
-        3 * m.count("decode_steps") + 2 * m.count("prefills")
+        3 * steps + 2 * drained + 2 * m.count("prefills")
         + 2 * m.count("chunk_runs") + 2 * admitted + admitted)
     assert tracer_clock.reads == 0
 
@@ -199,7 +219,7 @@ def _inside(inner, outer):
 
 
 def test_every_phase_lies_inside_one_iteration(traced_run):
-    _entry, _resps, spans = traced_run
+    entry, _resps, spans = traced_run
     iterations = [s for s in spans if s["name"] == "decode::iterate"]
     numbers = [s["args"]["iteration"] for s in iterations]
     assert numbers == list(range(1, len(iterations) + 1))
@@ -211,6 +231,41 @@ def test_every_phase_lies_inside_one_iteration(traced_run):
     for s in phases:
         holders = [it for it in iterations if _inside(s, it)]
         assert len(holders) == 1, s
+    # the order of a step's phases inside their iteration
+    for it in iterations:
+        names = [s["name"][8:] for s in sorted(
+            (s for s in phases if _inside(s, it)),
+            key=lambda s: s["start_ns"])
+            if s["name"] in ("decode::feeds", "decode::step",
+                             "decode::step_fetch", "decode::sample")]
+        if entry.order == "serial":
+            # feeds, launch, the step's own fetch, its host half
+            assert names in ([], ["feeds"],
+                             ["feeds", "step", "step_fetch", "sample"]), names
+        else:
+            # a fetch and its host half go together, after the launch of
+            # the next step (ahead) or before everything else (a drain,
+            # at the iteration's top or when nothing steps again); a
+            # launch may leave its step in flight
+            assert names in (
+                [], ["feeds"], ["feeds", "step"],
+                ["feeds", "step", "step_fetch", "sample"],
+                ["step_fetch", "sample"],
+                ["step_fetch", "sample", "feeds"],
+                ["step_fetch", "sample", "feeds", "step"],
+                ["feeds", "step_fetch", "sample"]), names
+    steps = [s for s in spans if s["name"] == "decode::step"]
+    fetches = [s for s in spans if s["name"] == "decode::step_fetch"]
+    ahead = [s["args"]["ahead"] for s in steps]
+    drains = [s["args"].get("drain") for s in fetches]
+    assert sum(ahead) == entry.metrics.count("decode_steps_ahead")
+    if entry.order == "serial":
+        assert not any(ahead) and set(drains) == {None}
+    else:
+        # every launch is ahead of a fetch or follows a drain, one for one
+        assert any(ahead) and ahead[0] is False
+        assert len(ahead) - sum(ahead) == len(drains) - drains.count(None)
+        assert set(drains) - {None} <= {"admission", "prefill", "idle"}
 
 
 def test_the_spans_of_a_request_carry_its_id(traced_run):
@@ -272,11 +327,19 @@ def test_step_spans_carry_their_sizes(traced_run):
         == entry.metrics.count("decode_steps")
     assert len(feeds) >= len(steps)
     # four greedy requests: every step brings its [S, 1] tokens, chosen
-    # by the step program, and leaves the logits on the device
-    assert {s["args"]["rows"] for s in fetches} == {"tokens"}
-    assert {s["args"]["bytes"] for s in fetches} == {
-        m.slots * jax.dtypes.canonicalize_dtype(np.int64).itemsize}
-    assert entry.metrics.count("decode_logits_fetch_steps") == 0
+    # by the step program, and leaves the logits on the device; the model
+    # without them brings the logits, every step
+    if entry.order == "ahead":
+        assert {s["args"]["rows"] for s in fetches} == {"tokens"}
+        assert {s["args"]["bytes"] for s in fetches} == {
+            m.slots * jax.dtypes.canonicalize_dtype(np.int64).itemsize}
+        assert entry.metrics.count("decode_logits_fetch_steps") == 0
+    else:
+        assert {s["args"]["rows"] for s in fetches} == {"logits"}
+        assert {s["args"]["bytes"] for s in fetches} == {
+            4 * m.slots * m.vocab_size}
+        assert entry.metrics.count("decode_logits_fetch_steps") \
+            == len(steps)
     assert sum(s["args"]["tokens"] for s in samples) == \
         entry.metrics.count("generated_tokens")
     assert all(1 <= s["args"]["active"] <= m.slots for s in feeds
@@ -344,9 +407,10 @@ def test_token_histograms_resolve_ten_milliseconds_where_tokens_fall():
 
 # -- bytes at the device boundary ---------------------------------------------------
 
-def test_byte_counters_equal_the_nbytes_of_a_hand_built_step():
+@pytest.mark.parametrize("token_feed", ["host", "device"])
+def test_byte_counters_equal_the_nbytes_of_a_hand_built_step(token_feed):
     engine = GenerationEngine(queue_depth=8, breaker_threshold=0)
-    entry = engine.register_model(lambda: _model("bytes"))
+    entry = engine.register_model(lambda: _model("bytes_" + token_feed))
     m = entry.model
     metrics = entry.metrics
     S, L = m.slots, m.max_len
@@ -359,6 +423,13 @@ def test_byte_counters_equal_the_nbytes_of_a_hand_built_step():
     }
     fed = sum(a.nbytes for a in feeds.values())
     assert fed == 8 * S + 8 * S + 4 * S * L + 8 * S * L + 8 * S
+    if token_feed == "device":
+        # a launched-ahead step's tokens are the previous step's output:
+        # on the device already, so nothing of them is fed
+        feeds[DecodeModel.DEC_TOKEN] = jax.device_put(
+            np.zeros((S, 1), jax.dtypes.canonicalize_dtype(np.int64)),
+            engine.device)
+        fed -= 8 * S
     fetches = entry._run("step", feeds)
     assert metrics.count("fed_bytes") == fed
     assert metrics.count("step_launches") == 1

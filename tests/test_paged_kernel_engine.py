@@ -11,6 +11,7 @@ the arena a step's attention has to read is held to the script.
 
 import numpy as np
 import pytest
+from decode_testing import record_step_logits
 
 from paddle_tpu import kernels
 from paddle_tpu.serving.decode import GenerationEngine, build_decoder_model
@@ -19,30 +20,6 @@ GEOM = dict(vocab_size=32, hidden=8, num_layers=2, slots=4, max_len=24,
             block_size=4)
 PROMPTS = [[3, 1, 4, 1, 5, 9, 2, 6, 5], [3, 1, 4], [9, 2], [7, 7, 1, 8, 2]]
 MAX_NEW = [6, 7, 5, 6]
-
-
-def _record_logits(entry, into):
-    """Keep every stepping slot's logits row, by response: the decode
-    step's first output, read here whichever of its outputs the engine
-    brings to the host (a greedy step fetches its tokens alone)."""
-    run, sample = entry._run, entry._sample
-    step = {}
-
-    def running(kind, feeds, span=None):
-        fetches = run(kind, feeds, span)
-        if kind == "step":
-            step["logits"] = np.asarray(fetches[0])
-        return fetches
-
-    def recording(fetched, active, groups, now, tokens_only):
-        slots = list(active) + [s for g in groups for s in g.order]
-        for s in slots:
-            into.setdefault(id(entry._slots[s].request.response), []).append(
-                np.array(step["logits"][s, 0]))
-        return sample(fetched, active, groups, now, tokens_only)
-
-    entry._run = running
-    entry._sample = recording
 
 
 def _serve(mode, name, submit, order=None, geom=GEOM):
@@ -56,7 +33,7 @@ def _serve(mode, name, submit, order=None, geom=GEOM):
         engine.register_model(lambda: build_decoder_model(
             name=name + "_d", version="1", **geom))
         rows = {}
-        _record_logits(entry, rows)
+        record_step_logits(entry, rows)
         order = list(range(len(PROMPTS))) if order is None else order
         resps = {i: submit(engine, i) for i in order}
         for _ in range(800):
