@@ -7,7 +7,7 @@ import importlib
 import time
 
 from benchmark.builders._program import SEED_MODULUS
-from benchmark.manifest import sizes
+from benchmark.manifest import model_sizes
 
 
 class DecoderServer:
@@ -77,7 +77,7 @@ def _rescale(scope, weights):
 def build(config, traffic, seed, rehearse):
     from paddle_tpu.serving import GenerationEngine, build_decoder_model
 
-    model = sizes(config["model"], rehearse)
+    model = model_sizes(config, rehearse)
 
     def make():
         m = build_decoder_model(name=config["name"], version="1", **model)

@@ -4,8 +4,14 @@ One file per configuration (``configs/<name>.json``), per traffic mix
 (``traffic/<name>.json``) and per per-layer metric (``metrics/<name>.json``),
 each found by the name in BENCHMARK.json. Nothing here, or anywhere in the
 harness, branches on the name of a cell, a configuration or a metric.
+
+A configuration taken from a published ``config.json`` keeps its source's
+keys under their published names at the TOP LEVEL of its file, named in
+``source_keys``: that is where the driver's check reads them
+(``check_against_source`` is that check, as far as three refusals show it).
 """
 
+import importlib
 import json
 import os
 import re
@@ -25,9 +31,18 @@ PER_LAYER_KEYS = {"name", "unit", "better", "source", "layer", "moves",
                   "workloads"}
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
-CONFIG_FILE_KEYS = {"name", "builder", "source", "source_values", "reduced",
-                    "assumed", "deployment", "why", "model", "settings",
-                    "flops", "reference", "rehearsal"}
+CONFIG_FILE_KEYS = {"name", "builder", "source", "source_keys",
+                    "source_values", "reduced", "assumed", "deployment",
+                    "why", "model", "settings", "flops", "reference",
+                    "rehearsal"}
+PUBLISHED = "published."
+# what section 4 of the model-configs guide lets a depth cut or a chip's
+# share change: how many layers, routed experts, heads and rows of the
+# vocabulary are held here, and the layer pattern that shrinks with the
+# depth. Whatever else differs from the source is a width.
+COUNTS = re.compile(
+    r"(layers?|heads?|vocab(_size)?|routed_experts|(num|n)(_local)?_experts"
+    r"|pattern|layer_types|block_types?)$")
 TRAFFIC_KINDS = {"train_steps", "open_loop", "closed_loop"}
 TRAFFIC_FILE_KEYS = {
     "train_steps": {"kind", "why", "batch", "seq_len", "image_shape",
@@ -127,11 +142,136 @@ def metrics_of(manifest, group, workload_name):
 
 def load_config(manifest, name):
     (entry,) = [c for c in manifest["configs"] if c["name"] == name]
-    cfg = _load(os.path.join(ROOT, entry["file"]))
-    _only(cfg, CONFIG_FILE_KEYS, entry["file"])
+    cfg = load_config_file(os.path.join(ROOT, entry["file"]),
+                           entry["reduced"])
     if cfg["name"] != name:
         raise ManifestError(f"{entry['file']}: name is {cfg['name']!r}")
     return cfg
+
+
+def load_config_file(path, entry_reduced=None):
+    """A configuration's file. Beside the harness's own keys it may hold
+    its source's published keys at the top level, each named in
+    ``source_keys``, with the value that is run here; where it states its
+    source's values (``source_values``) it is held to them as the driver
+    holds it to the catalog's row. ``entry_reduced`` is ``reduced`` of the
+    file's entry in BENCHMARK.json, for a file that has one."""
+    cfg = _load(path)
+    published = cfg.get("source_keys", [])
+    for key in published:
+        _name(key, f"{path}: source key")
+    clash = sorted(CONFIG_FILE_KEYS & set(published))
+    if clash:
+        raise ManifestError(f"{path}: source_keys names the harness's own "
+                            f"{clash}")
+    _only(cfg, CONFIG_FILE_KEYS | set(published), path)
+    missing = [k for k in published if k not in cfg]
+    if missing:
+        raise ManifestError(f"{path}: source_keys names {missing}, which "
+                            "the file does not give at its top level")
+    _only(cfg.get("rehearsal", {}), published, f"{path}: rehearsal")
+    try:
+        model = model_sizes(cfg, False)
+    except KeyError as e:
+        raise ManifestError(f"{path}: model names published.{e.args[0]}, "
+                            "which source_keys does not") from None
+    for key in published:
+        if not _same(model.get(key, cfg[key]), cfg[key]):
+            raise ManifestError(
+                f"{path}: {key} is {cfg[key]!r} at the top level and "
+                f"{model[key]!r} under model")
+    if published and "source_values" in cfg:
+        complaint = check_against_source(
+            cfg, cfg["reduced"] if entry_reduced is None else entry_reduced,
+            cfg["source_values"])
+        if complaint:
+            raise ManifestError(f"{path}: {complaint}")
+    if "flops" in cfg:
+        count_function(cfg["flops"]["function"])
+    return cfg
+
+
+def _is_number(value):
+    return isinstance(value, (int, float)) and not isinstance(value, bool)
+
+
+def _same(ours, theirs):
+    return ours == theirs and isinstance(ours, bool) == isinstance(
+        theirs, bool)
+
+
+def _widths_changed(key, ours, theirs):
+    """The keys, by dotted path, that part ``ours`` from ``theirs`` and are
+    no count: a group is compared leaf by leaf, each leaf under its own
+    name."""
+    if isinstance(ours, dict) and isinstance(theirs, dict):
+        return [f"{key}.{path}" for k in sorted(set(ours) | set(theirs))
+                for path in _widths_changed(k, ours.get(k), theirs.get(k))]
+    return [] if _same(ours, theirs) or COUNTS.search(key) else [key]
+
+
+def check_against_source(cfg, entry_reduced, row_config):
+    """The driver's rule for a configuration of the catalog, as three
+    refusals state it; returns the first complaint, or None. ``cfg`` is the
+    configuration's file, ``entry_reduced`` the ``reduced`` of its entry in
+    BENCHMARK.json, ``row_config`` the ``config`` of its source's row.
+
+    Every number and every nested group of the row stands at the TOP LEVEL
+    of the file under the same key (a published null is not asked for).
+    A key whose value differs is listed in ``reduced``, in the file and in
+    the entry alike, and is a count (``COUNTS``); anything else that
+    differs is a width, and is refused whether or not ``reduced`` lists it,
+    inside a group too. ``source`` has at most 200 characters."""
+    if len(cfg["source"]) > 200:
+        return (f"source has {len(cfg['source'])} characters, and may have "
+                "200")
+    if sorted(cfg["reduced"]) != sorted(entry_reduced):
+        return (f"reduced is {sorted(cfg['reduced'])} in the file and "
+                f"{sorted(entry_reduced)} in BENCHMARK.json's entry")
+    for key, theirs in row_config.items():
+        demanded = _is_number(theirs) or isinstance(theirs, dict)
+        ours = cfg.get(key)
+        if ours is None and not demanded:
+            continue
+        if ours is None:
+            where = ("only under model" if key in cfg.get("model", {})
+                     else "as null" if key in cfg else "nowhere")
+            return (f"the file gives {key} {where} and its source gives "
+                    f"{theirs!r}: every number and every nested group of "
+                    "the source stands at the top level of the file, under "
+                    "the same key and named in source_keys")
+        if _same(ours, theirs):
+            continue
+        widths = _widths_changed(key, ours, theirs)
+        if widths:
+            return (f"{widths[0]} is {ours!r} and its source gives "
+                    f"{theirs!r}: only a count may differ (layers, routed "
+                    "experts, heads, rows of the vocabulary, the layer "
+                    "pattern); a width may not change, whether or not "
+                    "reduced lists it or the group that holds it")
+        if key not in cfg["reduced"]:
+            return (f"{key} is {ours!r} and its source gives {theirs!r}, "
+                    "and reduced does not list it")
+    return None
+
+
+def count_function(name):
+    """The operations-and-bytes function a metric's kernel or a
+    configuration's ``flops`` names: ``<function>`` of flops.py, or
+    ``<module>.<function>`` of ``counts/<module>.py``, a file of its
+    own."""
+    module, _, function = name.rpartition(".")
+    for part in (module or "flops", function):
+        _name(part, f"count function {name!r}")
+    try:
+        found = importlib.import_module(
+            f"benchmark.counts.{module}" if module else "benchmark.flops")
+    except ModuleNotFoundError as e:
+        raise ManifestError(f"count function {name!r}: {e}") from None
+    if not callable(getattr(found, function, None)):
+        raise ManifestError(f"count function {name!r}: {found.__name__} "
+                            f"has no function {function!r}")
+    return getattr(found, function)
 
 
 def load_traffic(name):
@@ -150,10 +290,19 @@ def load_traffic(name):
 def load_metric(name):
     _name(name, "metric name")
     path = os.path.join(HERE, "metrics", name + ".json")
-    m = _load(path)
-    _only(m, METRIC_FILE_KEYS, path)
+    m = load_metric_file(path)
     if m["name"] != name:
         raise ManifestError(f"{path}: name is {m['name']!r}")
+    return m
+
+
+def load_metric_file(path):
+    m = _load(path)
+    _only(m, METRIC_FILE_KEYS, path)
+    args = m.get("args", {})
+    for named in [args] + args.get("kernels", []):
+        if "function" in named:
+            count_function(named["function"])
     return m
 
 
@@ -164,3 +313,29 @@ def sizes(section, rehearse):
     if rehearse:
         out.update(section.get("rehearsal", {}))
     return out
+
+
+def published(cfg, rehearse):
+    """The source's keys as they are run here, one group: the published
+    value, the cut one for a key in ``reduced``, the file's top-level
+    ``rehearsal`` laid over them in the CPU rehearsal. Empty for a
+    configuration that names no ``source_keys``."""
+    return sizes({k: cfg[k] for k in cfg.get("source_keys", ())}
+                 | {"rehearsal": cfg.get("rehearsal", {})}, rehearse)
+
+
+def model_sizes(cfg, rehearse):
+    """``model`` as the builder gets it: a size given as
+    ``"published.<key>"`` is that key of ``published``."""
+    group = published(cfg, rehearse)
+    return {k: group[v[len(PUBLISHED):]]
+            if isinstance(v, str) and v.startswith(PUBLISHED) else v
+            for k, v in sizes(cfg["model"], rehearse).items()}
+
+
+def run_sizes(cfg, traffic, chips, rehearse):
+    """What a metric file's ``call`` and ``denominator_times`` may name by
+    dotted path."""
+    return {"model": model_sizes(cfg, rehearse),
+            "published": published(cfg, rehearse),
+            "traffic": sizes(traffic, rehearse), "chips": chips}

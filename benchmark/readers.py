@@ -10,15 +10,17 @@ out of the line.
 
 ``run`` is the dict a driver fills: ``facts`` (numbers the driver measured
 itself), ``registry`` (before/after snapshots of the program's metrics
-registry around the window), ``spans`` and ``trace`` with ``trace_window``
-(traced runs on the chip only), ``config``, ``traffic``, ``chips`` and
-``peaks``.
+registry around the window), ``stretch_registry`` (the same at the edges of
+the traced stretch, where the driver takes them), ``spans`` and ``trace``
+with ``trace_window`` (traced runs on the chip only), ``config``,
+``sizes``, ``chips`` and ``peaks``.
 """
 
 import re
 import statistics
 
-from benchmark import flops, trace as tr
+from benchmark import trace as tr
+from benchmark.manifest import count_function
 
 
 def _lookup(tree, dotted):
@@ -27,27 +29,47 @@ def _lookup(tree, dotted):
     return tree
 
 
-def _value(expr, sizes):
+def _value(expr, run):
     """A size for a kernel call, from the metric file: a number, a dotted
-    path into the run's sizes (``model.hidden_size``, ``traffic.batch``,
-    ``chips``), or {"mul": [...]} / {"div": [a, b]} of such."""
+    path into the run's sizes (``model.hidden_size``,
+    ``published.num_hidden_layers``, ``traffic.batch``, ``chips``),
+    {"counter": family} (how far that counter of the program's registry
+    moved over the traced stretch: a size that follows the traffic), or
+    {"mul": [...]} / {"div": [a, b]} of such. Two whole numbers divide to a
+    whole number (a batch over the chips), a counter's movement exactly.
+    None where a counter named is not there to read."""
     if isinstance(expr, (int, float)):
         return expr
     if isinstance(expr, str):
-        return _lookup(sizes, expr)
+        return _lookup(run["sizes"], expr)
+    if "counter" in expr:
+        if run.get("stretch_registry") is None:
+            return None
+        return _family_delta(run, expr["counter"], key="stretch_registry")
+    parts = [_value(part, run) for part in expr.get("mul") or expr["div"]]
+    if None in parts:
+        return None
     if "mul" in expr:
         out = 1
-        for part in expr["mul"]:
-            out *= _value(part, sizes)
+        for part in parts:
+            out *= part
         return out
-    a, b = (_value(part, sizes) for part in expr["div"])
-    return a // b
+    a, b = parts
+    return a // b if isinstance(a, int) and isinstance(b, int) else a / b
 
 
-def _family_delta(run, family, field=None):
-    """How far all series of a family moved over the window; for a
-    histogram ``field`` is ``sum`` or ``count``."""
-    before, after = run["registry"]
+def _call_sizes(call, run):
+    """The sizes a count function is called with, or None where one of
+    them is not there to read."""
+    sizes = {name: _value(expr, run) for name, expr in call.items()}
+    return None if None in sizes.values() else sizes
+
+
+def _family_delta(run, family, field=None, key="registry"):
+    """How far all series of a family moved over the window (or, with
+    ``key``, between another pair of snapshots); for a histogram ``field``
+    is ``sum`` or ``count``."""
+    before, after = run[key]
     if family not in after:
         return None
     moved = 0.0
@@ -153,9 +175,13 @@ def device_share(args, run):
 def device_roofline(args, run):
     """100 * the least time the chip could take over the time it took, for
     the kernels in ``kernels``: each gives a ``pattern`` of event names and
-    a function of flops.py with the sizes of one call (``_value``
-    expressions). The least time of a call is max(operations / peak FLOP/s,
-    bytes / peak bytes/s)."""
+    a count function (``manifest.count_function``) with the sizes of one
+    call (``_value`` expressions). The least time of a call is
+    max(operations / peak FLOP/s, bytes / peak bytes/s). A kernel whose
+    work follows the traffic says ``"summed": true``: its ``call`` then
+    gives the sizes of ALL its calls in the traced stretch together (a
+    counter's movement), and the least time is taken once, not per
+    event."""
     devs = _devices(run)
     if not devs:
         return None
@@ -168,11 +194,12 @@ def device_roofline(args, run):
                          window)
         if not events:
             continue
-        call = {name: _value(expr, run["sizes"])
-                for name, expr in k["call"].items()}
-        ops, moved = getattr(flops, k["function"])(**call)
-        least += len(events) * max(ops / peaks["bf16_flops"],
-                                   moved / peaks["hbm_bytes_per_s"])
+        call = _call_sizes(k["call"], run)
+        if call is None:
+            continue
+        ops, moved = count_function(k["function"])(**call)
+        least += (1 if k.get("summed") else len(events)) * max(
+            ops / peaks["bf16_flops"], moved / peaks["hbm_bytes_per_s"])
         took += tr.total(events)
     return 100.0 * least / took if took else None
 
@@ -204,14 +231,32 @@ def device_seconds_per_span(args, run):
     return args.get("scale", 1.0) * seconds / count
 
 
+def span_mfu(args, run):
+    """100 * the operations the traced stretch's work required (a count
+    function over ``call`` sizes, counters' movements among them) / (device
+    seconds of the executables launched under the program's span ``span``
+    in that stretch * peak FLOP/s): a whole step's share of the chip's
+    peak where the work of a step follows the traffic."""
+    devs = _devices(run)
+    if not devs or run.get("spans") is None:
+        return None
+    seconds = tr.module_seconds_by_span(
+        devs[0], run["spans"], run["trace_window"]).get(args["span"])
+    call = _call_sizes(args["call"], run)
+    if not seconds or call is None:
+        return None
+    ops, _moved = count_function(args["function"])(**call)
+    return 100.0 * ops / (seconds * run["peaks"]["bf16_flops"])
+
+
 def mfu(args, run):
-    """100 * required operations per step (the configuration's function in
-    flops.py) * steps per second / (chips * peak FLOP/s)."""
+    """100 * required operations per step (the configuration's count
+    function) * steps per second / (chips * peak FLOP/s)."""
     rate = run["facts"].get("steps_per_s")
     if rate is None or run.get("peaks") is None:
         return None
     cfg = run["config"]
-    ops = getattr(flops, cfg["flops"]["function"])(
+    ops = count_function(cfg["flops"]["function"])(
         run["sizes"]["model"], cfg["settings"], run["sizes"]["traffic"])
     return 100.0 * ops * rate / (run["chips"] * run["peaks"]["bf16_flops"])
 
@@ -219,4 +264,4 @@ def mfu(args, run):
 READERS = {f.__name__: f for f in (
     fact, counter_delta, counter_ratio, histogram_mean, histogram_share,
     span_stat, device_idle, device_share, device_roofline, device_exposed,
-    device_seconds_per_span, mfu)}
+    device_seconds_per_span, span_mfu, mfu)}

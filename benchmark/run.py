@@ -6,15 +6,19 @@ Builds the cell's system from its configuration file, warms up the shapes
 its traffic uses (all of that is ``setup_s``), measures for ``--seconds``
 and prints, last on stdout, one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace 0``,
-its per-layer metrics with ``--trace 1``), ``device`` and, traced,
-``breakdown``. It measures on a TPU or not at all: with no TPU, or fewer
-chips than the cell asks for, it exits non-zero and prints no result.
+its per-layer metrics with ``--trace 1``), ``device``, traced
+``breakdown``, and last ``compared``: each number that decided ``correct``
+beside its limit, and whether it holds (the last lines of stderr say the
+same). It measures on a TPU or not at all: with no TPU, or fewer chips than
+the cell asks for, it exits non-zero and prints no result.
 
 ``--rehearse-cpu`` is the explicit mode the test file uses: the sizes under
 ``rehearsal`` in the data files, the CPU platform REQUIRED, Pallas kernels
 interpreted, and every metric's value null: a CPU run never supplies a
 number under the name of a device metric. With it, ``--config`` and
-``--traffic`` may name files that no cell registers yet.
+``--traffic`` may name files that no cell registers yet: ``--config`` a
+configuration of BENCHMARK.json by name or a configuration's FILE by its
+path from the checkout's root, ``--traffic`` a file of ``traffic/``.
 """
 
 import time
@@ -49,7 +53,8 @@ def _args(argv):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse-cpu", action="store_true")
     ap.add_argument("--config", help="with --rehearse-cpu, in place of "
-                    "--workload: a configuration of BENCHMARK.json")
+                    "--workload: a configuration of BENCHMARK.json, or "
+                    "the path of a configuration's file")
     ap.add_argument("--traffic", help="with --rehearse-cpu: a traffic file")
     args = ap.parse_args(argv)
     if not args.rehearse_cpu and (args.config or args.traffic
@@ -103,10 +108,13 @@ def main(argv=None):
     bench = manifest.load_manifest()
     if args.workload:
         cell = manifest.workload(bench, args.workload)
+        config = manifest.load_config(bench, cell["config"])
     else:
-        cell = {"name": f"{args.config}.{args.traffic}", "chips": 1,
-                "config": args.config, "traffic": args.traffic}
-    config = manifest.load_config(bench, cell["config"])
+        config = (manifest.load_config_file(os.path.join(ROOT, args.config))
+                  if args.config.endswith(".json")
+                  else manifest.load_config(bench, args.config))
+        cell = {"name": f"{config['name']}.{args.traffic}", "chips": 1,
+                "traffic": args.traffic}
     traffic = manifest.load_traffic(cell["traffic"])
 
     if not args.rehearse_cpu:
@@ -158,10 +166,10 @@ def main(argv=None):
 
     if args.trace:
         run = {"facts": result["facts"], "registry": result["registry"],
+               "stretch_registry": result["stretch_registry"],
                "config": config, "chips": cell["chips"], "peaks": peaks,
-               "sizes": {"model": manifest.sizes(config["model"], args.rehearse_cpu),
-                         "traffic": manifest.sizes(traffic, args.rehearse_cpu),
-                         "chips": cell["chips"]},
+               "sizes": manifest.run_sizes(config, traffic, cell["chips"],
+                                           args.rehearse_cpu),
                **reduced}
         metrics = {}
         for entry in manifest.metrics_of(bench, "per_layer", cell["name"]):
@@ -191,7 +199,13 @@ def main(argv=None):
             "metrics": metrics, "device": device}
     if reduced.get("trace"):
         line["breakdown"] = _breakdown(reduced)
+    line["compared"] = {
+        name: {"value": value, "limit": limit, "holds": bool(holds)}
+        for name, (value, limit, holds) in result["compared"].items()}
     print(json.dumps(line), flush=True)
+    for name, (value, limit, holds) in result["compared"].items():
+        print(f"compared {name}: {value!r}, limit {limit!r}: "
+              f"{'holds' if holds else 'FAILS'}", file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":

@@ -21,8 +21,12 @@ def _timed(system, n):
 def run(system, traffic, args, clock0, compiles, tracer):
     """Runs the cell's window. Returns a dict: ``end_to_end`` values by
     metric name, ``facts`` the driver measured itself, ``registry`` (the
-    program's metrics registry before and after the window), ``correct``, ``attempted`` and ``failed``. ``tracer`` is None, or a
-    function that gives the context manager of a traced window."""
+    program's metrics registry before and after the window),
+    ``stretch_registry`` (the same at the edges of the traced stretch, or
+    None), ``correct``, ``compared`` (each number that decided it, as
+    (value, its limit, whether it holds)), ``attempted`` and ``failed``.
+    ``tracer`` is None, or a function that gives the context manager of a
+    traced window."""
     from paddle_tpu.observability import metrics as obs_metrics
 
     registry = obs_metrics.registry()
@@ -32,6 +36,7 @@ def run(system, traffic, args, clock0, compiles, tracer):
     step_s = warm_s / warm_n
     n = max(1, round(args.seconds / step_s))
     traced_steps = 0
+    stretch = None
 
     before = compiles.snapshot()
     registry_before = registry.snapshot()
@@ -42,7 +47,9 @@ def run(system, traffic, args, clock0, compiles, tracer):
         traced_steps = max(2, min(n - 1, round(
             traffic["trace_seconds"] / step_s)))
         with tracer():
+            stretch = [registry.snapshot()]
             _timed(system, traced_steps)
+            stretch.append(registry.snapshot())
     rest = max(1, n - traced_steps)
     rest_s, losses = _timed(system, rest)
     t_close = time.perf_counter()
@@ -50,8 +57,14 @@ def run(system, traffic, args, clock0, compiles, tracer):
 
     values = [float(np.asarray(x).reshape(-1)[0])
               for x in first + warm + losses[-1:]]
-    correct = (all(math.isfinite(v) for v in values)
-               and values[-1] < values[0] and moved == 0)
+    not_finite = sum(not math.isfinite(v) for v in values)
+    compared = {
+        "losses_not_finite": (not_finite, 0, not_finite == 0),
+        "last_loss_below_first": (values[-1], values[0],
+                                  values[-1] < values[0]),
+        "compiles_in_window": (moved, 0, moved == 0),
+    }
+    correct = all(holds for _value, _limit, holds in compared.values())
     print(f"# set-up: first step (compile or load) {first_s:.2f} s, "
           f"{warm_n} warm-up steps {warm_s:.2f} s", flush=True)
     print(f"# losses: first {values[0]:.4f}, warm-up "
@@ -71,5 +84,8 @@ def run(system, traffic, args, clock0, compiles, tracer):
         "compiles_in_window": moved,
         "load_s": first_s - step_s,
     }
-    return {"end_to_end": end_to_end, "facts": facts, "registry": (registry_before, registry.snapshot()),
-            "correct": correct, "attempted": steps, "failed": 0}
+    return {"end_to_end": end_to_end, "facts": facts,
+            "registry": (registry_before, registry.snapshot()),
+            "stretch_registry": stretch,
+            "correct": correct, "compared": compared,
+            "attempted": steps, "failed": 0}

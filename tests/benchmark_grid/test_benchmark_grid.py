@@ -25,7 +25,8 @@ from benchmark import trace as tr  # noqa: E402
 
 BENCH = manifest.load_manifest()
 CELLS = [w["name"] for w in BENCH["workloads"]]
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -99,8 +100,8 @@ def test_the_harness_never_branches_on_a_name():
     names = {x["name"] for key in ("configs", "workloads", "end_to_end",
                                    "per_layer") for x in BENCH[key]}
     names |= {w["traffic"] for w in BENCH["workloads"]}
-    names -= {"setup_s", "train_throughput", "serve_token_latency_p50",
-              "serve_token_latency_p90"}   # what the drivers measure
+    # what the drivers measure
+    names -= {"setup_s", "train_throughput", "serve_token_latency_p50"}
     bench_dir = os.path.join(ROOT, "benchmark")
     for dirpath, _dirs, files in os.walk(bench_dir):
         for f in files:
@@ -486,7 +487,11 @@ def test_counter_and_histogram_readers_read_the_window_only():
 
 REHEARSALS = [("--workload", name) for name in CELLS] + [
     # a traffic file that no cell registers: a later PR adds it as data
-    ("--config", "decoder_1024x24", "--traffic", "chat_saturated")]
+    ("--config", "decoder_1024x24", "--traffic", "chat_saturated"),
+    # a configuration FILE that BENCHMARK.json does not register, in the
+    # format a published configuration takes (test_published_configs.py)
+    ("--config", "tests/benchmark_grid/fixtures/configs/published_tiny.json",
+     "--traffic", "chat_steady")]
 
 
 @pytest.fixture(scope="module")
@@ -513,13 +518,21 @@ def rehearsed():
     return out
 
 
-@pytest.mark.parametrize("cell", REHEARSALS, ids=lambda c: c[-1])
+@pytest.mark.parametrize("cell", REHEARSALS,
+                         ids=lambda c: c[-1] if len(c) == 2 else
+                         os.path.basename(c[1]) + "." + c[-1])
 def test_cell_rehearses_on_the_cpu_with_the_contracts_last_line(
         rehearsed, cell):
     code, stdout, stderr = rehearsed[cell]
     assert code == 0, stderr[-2000:]
     line = json.loads(stdout.strip().splitlines()[-1])
     assert set(line) == LINE_KEYS          # no breakdown without a device
+    assert list(line)[-1] == "compared"    # last, each number by its limit
+    for name, number in line["compared"].items():
+        assert set(number) == {"value", "limit", "holds"}
+        assert number["holds"] is True, name
+        assert f"compared {name}: " in stderr.strip().splitlines()[
+            -len(line["compared"]):][list(line["compared"]).index(name)]
     assert set(line["device"]) == DEVICE_KEYS
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True and line["failed"] == 0

@@ -58,16 +58,19 @@ def test_metric_file_passes_the_manifest_and_names_what_it_reads(name):
 
 
 def test_the_two_entries_come_last_and_nothing_before_them_moved():
+    """Found by name, so that a later PR can append after them: the two
+    follow PR 25's ten, which follow ``hbm_compiled_gb``."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-2:] == ["decode_live_block_share",
-                          "paged_attention_device_share"]
+    first = names.index("decode_live_block_share")
+    assert names[first:first + 2] == ["decode_live_block_share",
+                                      "paged_attention_device_share"]
     # PR 25's ten, in their order, directly before
-    assert names[-12:-2] == [
+    assert names[first - 10:first] == [
         "serve_queue_wait_ms", "serve_first_token_ms", "serve_inter_token_ms",
         "prefill_kv_fetch_ms", "decode_logits_fetch_ms", "decode_feeds_ms",
         "decode_sample_ms", "serve_fed_mb_per_step",
         "serve_fetched_mb_per_step", "serve_shed"]
-    assert names[-13] == "hbm_compiled_gb"
+    assert names[first - 11] == "hbm_compiled_gb"
 
 
 def test_the_kernel_is_named_as_the_pattern_reads_it():

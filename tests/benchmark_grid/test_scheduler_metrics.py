@@ -73,12 +73,21 @@ def test_metric_file_passes_the_manifest_and_names_what_it_reads(name):
 
 
 def test_the_new_entries_come_last_and_nothing_before_them_moved():
+    """PR 25's ten, found by name: they follow what was there before them
+    in the order they were added, and what was there kept its order. Later
+    PRs append after them (PR 29 two, PR 35 two)."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert set(names[-len(NEW):]) == set(NEW)
-    assert names[len(names) - len(NEW) - 1] == "hbm_compiled_gb"
-    # the metrics that time these layers from outside stay
-    assert {"decode_step_ms", "prefill_share", "decode_step_device_ms",
-            "generator_lateness_ms"} <= set(names)
+    first = names.index(next(iter(NEW)))
+    assert names[first:first + len(NEW)] == list(NEW)
+    assert names[:first] == [
+        "cache_load_s", "window_compiles.train", "window_compiles.serve",
+        "host_step_ms.train", "train_mfu", "flash_device_share",
+        "flash_attention_roofline", "collective_exposed_ms",
+        "decode_step_ms", "decode_occupancy", "prefill_share",
+        "serve_output_rate", "serve_requests_finished",
+        "generator_lateness_ms", "decode_step_device_ms",
+        "device_idle.train", "device_idle.serve", "hbm_compiled_gb"]
+    assert len(set(names)) == len(names)
 
 
 # -- a rehearsal of the serving cell and of a training cell -------------------
@@ -112,6 +121,24 @@ def test_the_serving_rehearsal_reads_every_new_metric(rehearsed):
         # present: its reader found its histogram, counter or span; null,
         # as every value of a CPU run is
         assert line["metrics"][name] == {"value": None, "unit": units[name]}
+
+
+def test_the_tail_stands_beside_the_median_as_a_per_layer_reading(rehearsed):
+    """PR 35: a window finishes a hundred-odd requests and the 90th
+    percentile is a dozen of them, so it carries no bound; the driver still
+    measures it over every request that finished, as it does the median."""
+    assert "serve_token_latency_p90" not in [
+        m["name"] for m in BENCH["end_to_end"]]
+    (entry,) = [m for m in BENCH["per_layer"]
+                if m["name"] == "serve_token_latency_p90"]
+    assert (entry["unit"], entry["moves"], entry["source"]) == (
+        "ms/token", "serve_token_latency_p50", "host_clock")
+    assert rehearsed[SERVING]["metrics"]["serve_token_latency_p90"] == {
+        "value": None, "unit": "ms/token"}
+    assert "serve_token_latency_p90" not in rehearsed[TRAINING]["metrics"]
+    assert _read({"facts": {"token_latency_p90_ms": 6.9}},
+                 "serve_token_latency_p90") == 6.9
+    assert _read({"facts": {}}, "serve_token_latency_p90") is None
 
 
 def test_a_training_cell_leaves_them_out(rehearsed):
