@@ -73,10 +73,27 @@ _SIGNATURES = {
         ranks={"Q": 2, "KCache": 3, "VCache": 3, "Bias": 3},
         dtype_family={"Q": "float"},
     ),
+    # the bias is the host's float32 feed whatever the arenas' dtype
     "paged_attention": OpSignature(
-        same_dtype=[("Q", "KArena", "VArena", "Bias")],
+        same_dtype=[("Q", "KArena", "VArena")],
         ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Bias": 3},
-        dtype_family={"Q": "float", "Rows": "int"},
+        dtype_family={"Q": "float", "Bias": "float", "Rows": "int"},
+    ),
+    "chunk_paged_attention": OpSignature(
+        same_dtype=[("Q", "KArena", "VArena")],
+        ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Bias": 3},
+        dtype_family={"Q": "float", "Bias": "float", "Rows": "int"},
+    ),
+    "rms_norm": OpSignature(ranks={"Scale": 1}, dtype_family={"X": "float"}),
+    "relu2": OpSignature(dtype_family={"X": "float"}),
+    "moe_routed_experts": OpSignature(
+        same_dtype=[("WUp", "WDown")],
+        ranks={"GateW": 2, "SelectBias": 1, "WUp": 3, "WDown": 3},
+        dtype_family={"X": "float", "WriteRows": "int"},
+    ),
+    "mamba2_mixer": OpSignature(
+        ranks={"ConvState": 3, "SsmState": 4, "ConvW": 2},
+        dtype_family={"X": "float", "WriteRows": "int"},
     ),
     "lookup_table": OpSignature(
         dtype_family={"Ids": "int", "W": "float"}, ranks={"W": 2}

@@ -158,7 +158,9 @@ def _check_signature(block, op, op_index, diags):
     for slot, family in sig.dtype_family.items():
         for n in op.inputs.get(slot, []) + op.outputs.get(slot, []):
             dt = _declared_dtype(block, n)
-            if dt is not None and not dt.startswith(family):
+            # bfloat16 is a float dtype whose name does not start so
+            if dt is not None and not dt.startswith(family) and not (
+                    family == "float" and dt == "bfloat16"):
                 _diag(diags, "error", "dtype-mismatch",
                       f"op '{op.type}' slot {slot} expects a {family} dtype, "
                       f"var '{n}' is {dt}",

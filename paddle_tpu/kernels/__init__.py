@@ -10,6 +10,8 @@ kernel               serves                          parity    activation
 flash_attention      scaled_dot_product_attention    tolerance mode
 cached_attention     cached_attention (decode [S,1]) bit       mode
 paged_attention      paged_attention (decode [S,1])  tolerance mode
+moe_experts          moe_routed_experts (decode)     tolerance mode
+ssm_update           mamba2_mixer (decode [S,1])     tolerance mode
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
 ==================== ============================== ========= =========
 
@@ -227,7 +229,7 @@ def _paged_both(args, seqs, length, block, hidden):
 def _parity_paged(rng):
     """Ragged lengths in one batch (a free slot among them), two slots
     sharing prefix blocks, the block table out of order;
-    tests/test_kernels.py holds more geometries."""
+    tests/test_kernels.py holds more geometries. Then the head axis."""
     S, L, bs, H = 6, 64, 16, 128
     lengths = [1, 15, 16, 17, 64, 0]
     args = _paged_case(rng, S, L, bs, H, lengths, share=[(3, 4, 1)])
@@ -238,6 +240,7 @@ def _parity_paged(rng):
     # a free slot's bias is all -1e9: the composite averages garbage rows
     # there (not compared); the kernel reads nothing and writes zeros
     assert not got[~live].any()
+    _parity_paged_grouped(rng)
 
 
 def _tpu_cases_paged():
@@ -254,7 +257,126 @@ def _tpu_cases_paged():
 
     return [("s48_l1024_b16_h1024", fwd, [
         ((S, H), "float32"), ((R, H), "float32"), ((R, H), "float32"),
-        ((S * L,), "int32"), ((S, 1, L), "float32")])]
+        ((S * L,), "int32"), ((S, 1, L), "float32")])
+            ] + _tpu_cases_paged_grouped()
+
+
+def _parity_paged_grouped(rng):
+    """The head axis: 2 K/V heads of 128 a row, 4 query heads to each."""
+    import jax
+
+    from paddle_tpu.kernels import attention as A
+
+    S, L, bs, G, per, D = 5, 64, 16, 2, 4, 128
+    lengths = [1, 15, 17, 64, 0]
+    _q, k, v, rows, bias = _paged_case(rng, S, L, bs, G * D, lengths)
+    q = rng.randn(S, G * per * D).astype("float32")
+    sm = 1.0 / float(np.sqrt(D))
+    got = jax.jit(lambda *a: A.paged_attention(
+        *a, S, L, bs, sm, interpret=True, kv_heads=G))(q, k, v, rows, bias)
+    ref = jax.jit(lambda *a: A.paged_attention_composite(
+        *a, S, L, sm, kv_heads=G))(q, k, v, rows, bias)
+    live = np.asarray(lengths) > 0
+    _assert_close_both_ways(np.asarray(got)[live], np.asarray(ref)[live],
+                            "paged_attention (grouped)", 1e-5, 1e-5)
+    assert not np.asarray(got)[~live].any()
+
+
+def _tpu_cases_paged_grouped():
+    """The hybrid serving cell's geometry (nemotron3_nano_30b_a3b: 32 slots
+    x 2,048 positions, block 16, rows of 2 K/V heads of 128 in bfloat16, 16
+    query heads to each)."""
+    from paddle_tpu.kernels import attention as A
+
+    S, L, bs, G, per, D = 32, 2048, 16, 2, 16, 128
+    R = S * L
+
+    def fwd(q, k, v, rows, bias):
+        return A.paged_attention(q, k, v, rows, bias, S, L, bs,
+                                 1.0 / float(np.sqrt(D)), kv_heads=G)
+
+    return [("s32_l2048_b16_g2x16x128_bf16", fwd, [
+        ((S, G * per * D), "bfloat16"), ((R, G * D), "bfloat16"),
+        ((R, G * D), "bfloat16"), ((S * L,), "int32"),
+        ((S, 1, L), "float32")])]
+
+
+def _parity_moe_experts(rng):
+    """Held experts some of which no token chose, a masked token, and the
+    step in which none is touched."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import moe
+
+    T, H, F, E, EA, k = 16, 24, 20, 4, 16, 3
+    x = jnp.asarray(rng.randn(T, H).astype("float32"))
+    gate = jnp.asarray(rng.randn(EA, H).astype("float32"))
+    sel = jnp.asarray(0.1 * rng.randn(EA).astype("float32"))
+    w_up = jnp.asarray(0.2 * rng.randn(E, F, H).astype("float32"))
+    w_down = jnp.asarray(0.2 * rng.randn(E, F, H).astype("float32"))
+    mask = jnp.asarray(rng.rand(T) > 0.3)
+    idx, w = moe.route(x, gate, sel, k, 2.5, True)
+    run = jax.jit(lambda *a: moe.moe_experts(*a, interpret=True))
+    untouched = 0
+    for offset in (0, 4, 12):
+        c = moe.held_weights(idx, w, mask, offset, E)
+        untouched += int((~np.asarray(c != 0).any(0)).sum())
+        _assert_close_both_ways(
+            run(x, c, w_up, w_down),
+            moe.experts_composite(x, c, w_up, w_down), "moe_experts",
+            1e-5, 1e-5)
+    assert untouched, "no case left a held expert untouched"
+    assert not np.asarray(run(x, jnp.zeros((T, E)), w_up, w_down)).any()
+
+
+def _tpu_cases_moe_experts():
+    """The hybrid serving cell's expert layer: a step's 32 tokens, 16 held
+    experts of width 1,856 at hidden 2,688, bfloat16."""
+    from paddle_tpu.kernels import moe
+
+    T, H, F, E = 32, 2688, 1856, 16
+    return [("t32_h2688_f1856_e16_bf16", moe.moe_experts, [
+        ((T, H), "bfloat16"), ((T, E), "float32"),
+        ((E, F, H), "bfloat16"), ((E, F, H), "bfloat16")])]
+
+
+def _parity_ssm_update(rng):
+    """Some slots step, none does, all do, the last alone: a slot that does
+    not step keeps its state bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import mamba
+
+    S, H, P, N = 6, 32, 8, 128
+    draw = lambda *shape: jnp.asarray(rng.randn(*shape).astype("float32"))
+    state, xdt, bh, ch = (draw(S, H, P, N), draw(S, H, P), draw(S, H, N),
+                          draw(S, H, N))
+    decay = jnp.asarray(rng.rand(S, H).astype("float32"))
+    run = jax.jit(lambda *a: mamba.ssm_update(*a, interpret=True))
+    for steps in ([1, 0, 1, 1, 0, 0], [0] * 6, [1] * 6, [0, 0, 0, 0, 0, 1]):
+        mask = jnp.asarray(steps, bool)
+        new, y = run(state, xdt, decay, bh, ch, mask)
+        ref_new, ref_y = mamba.ssm_update_composite(state, xdt, decay, bh,
+                                                    ch, mask)
+        _assert_close_both_ways(new, ref_new, "ssm_update state", 1e-5, 1e-5)
+        _assert_close_both_ways(y, ref_y, "ssm_update y", 1e-5, 1e-4)
+        idle = ~np.asarray(mask)
+        _assert_bytes_equal(np.asarray(new)[idle], np.asarray(state)[idle],
+                            "ssm_update idle slots")
+
+
+def _tpu_cases_ssm_update():
+    """The hybrid serving cell's Mamba layer: 32 slots of 64 heads x 64 x
+    128 float32 state."""
+    from paddle_tpu.kernels import mamba
+
+    S, H, P, N = 32, 64, 64, 128
+    return [("s32_h64_p64_n128", mamba.ssm_update, [
+        ((S, H, P, N), "float32"), ((S, H, P), "float32"),
+        ((S, H), "float32"), ((S, H, N), "float32"), ((S, H, N), "float32"),
+        ((S,), "bool")])]
 
 
 def _parity_remat(rng):
@@ -302,6 +424,18 @@ register(KernelSpec(
     tpu_cases=_tpu_cases_paged,
     doc="blocked [S,1] decode attention over the live blocks of a paged "
         "arena, online softmax (kernels/attention.py)",
+))
+register(KernelSpec(
+    "moe_experts", ("moe_routed_experts",), "tolerance", _parity_moe_experts,
+    tpu_cases=_tpu_cases_moe_experts,
+    doc="a decode step's held experts: the touched ones' weights streamed "
+        "once, the others never read (kernels/moe.py)",
+))
+register(KernelSpec(
+    "ssm_update", ("mamba2_mixer",), "tolerance", _parity_ssm_update,
+    tpu_cases=_tpu_cases_ssm_update,
+    doc="one-token Mamba-2 state update of the stepping slots, in place "
+        "(kernels/mamba.py)",
 ))
 register(KernelSpec(
     "remat_policy", ("recompute_segment", "recompute_segment_grad"),

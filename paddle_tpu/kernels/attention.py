@@ -29,7 +29,11 @@ it is close to the composite and not bit-identical with it. What stays
 bit-exact is one compiled program replayed under any admission order, and
 resume after park (tests/test_decode.py). The body takes dtype and widths
 from its operands and reduces in float32 on the VPU (a one-row GEMV wastes
-the MXU), which is never a lower precision than the composite's.
+the MXU), which is never a lower precision than the composite's. With
+``kv_heads`` (grouped-query attention: rows of ``kv_heads`` K/V heads side
+by side, a whole number of query heads to each) a group's query heads are
+the rows of one MXU product per K/V head and block group, scores and
+softmax in float32.
 
 Eligibility: ``decode_attention`` wants its whole workset resident in VMEM;
 ``fits_vmem`` gates the compiled-TPU path per static shape on the INPUT
@@ -41,6 +45,8 @@ Mosaic cannot tile (rows not a multiple of the dtype's sublane tile, hidden
 not a multiple of 128) runs the composite. Every such fallback is counted
 in ``kernel_fallbacks_total``.
 """
+
+import functools
 
 import numpy as np
 
@@ -59,7 +65,8 @@ _CLOSED = -5e8
 
 __all__ = [
     "cached_attention_composite", "paged_attention_composite",
-    "decode_attention", "paged_attention", "fits_vmem",
+    "chunk_attention_composite", "decode_attention", "paged_attention",
+    "fits_vmem",
 ]
 
 #: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
@@ -96,14 +103,64 @@ def cached_attention_composite(q, k_cache, v_cache, bias, sm_scale):
 
 
 def paged_attention_composite(q, k_arena, v_arena, rows, bias, seqs,
-                              length, sm_scale):
+                              length, sm_scale, kv_heads=0):
     """``block_gather(k) ; block_gather(v) ; cached_attention`` as one
     function: gather rows byte-for-byte out of the flat arenas, then the
-    cached-attention sequence over the gathered views."""
+    cached-attention sequence over the gathered views. With ``kv_heads``
+    the rows hold that many K (V) heads side by side and ``q`` a whole
+    number of query heads to each (grouped-query attention): every query
+    head attends over its group's K/V head, under the one bias row."""
+    if kv_heads:
+        return _grouped_composite(q, k_arena, v_arena, rows, bias, seqs,
+                                  length, sm_scale, kv_heads)
     flat = rows.reshape(-1)
     gk = jnp.take(k_arena, flat, axis=0).reshape(int(seqs), int(length), -1)
     gv = jnp.take(v_arena, flat, axis=0).reshape(int(seqs), int(length), -1)
     return cached_attention_composite(q, gk, gv, bias, sm_scale)
+
+
+def _grouped_composite(q, k_arena, v_arena, rows, bias, seqs, length,
+                       sm_scale, kv_heads):
+    """``q`` ``[S, heads * D]`` against arenas ``[R, kv_heads * D]``;
+    scores and softmax in float32, the two products in the arenas' dtype
+    accumulated in float32."""
+    s, l, g = int(seqs), int(length), int(kv_heads)
+    d = k_arena.shape[-1] // g
+    f32 = jnp.float32
+    prec = jax.lax.Precision.HIGHEST if k_arena.dtype == f32 else None
+    flat = rows.reshape(-1)
+    gk = jnp.take(k_arena, flat, axis=0).reshape(s, l, g, d)
+    gv = jnp.take(v_arena, flat, axis=0).reshape(s, l, g, d)
+    q4 = q.reshape(s, g, -1, d).astype(k_arena.dtype)
+    scores = jnp.einsum("sgqd,slgd->sgql", q4, gk,
+                        preferred_element_type=f32, precision=prec)
+    att = jax.nn.softmax(scores * sm_scale
+                         + bias.reshape(s, 1, 1, l).astype(f32), axis=-1)
+    ctx = jnp.einsum("sgql,slgd->sgqd", att.astype(v_arena.dtype), gv,
+                     preferred_element_type=f32, precision=prec)
+    return ctx.reshape(s, -1).astype(q.dtype)
+
+
+def chunk_attention_composite(q, k_arena, v_arena, rows, bias, sm_scale,
+                              kv_heads):
+    """A prompt chunk's ``C`` queries ``[C, heads * D]`` over ONE
+    sequence's ``L`` arena rows (``rows`` ``[L]``) under the host's causal
+    bias ``[1, C, L]``, grouped as ``_grouped_composite`` groups a step's
+    heads."""
+    c, l, g = q.shape[0], rows.shape[0], int(kv_heads)
+    d = k_arena.shape[-1] // g
+    f32 = jnp.float32
+    prec = jax.lax.Precision.HIGHEST if k_arena.dtype == f32 else None
+    gk = jnp.take(k_arena, rows, axis=0).reshape(l, g, d)
+    gv = jnp.take(v_arena, rows, axis=0).reshape(l, g, d)
+    q4 = q.reshape(c, g, -1, d).astype(k_arena.dtype)
+    scores = jnp.einsum("cgqd,lgd->cgql", q4, gk, preferred_element_type=f32,
+                        precision=prec)
+    att = jax.nn.softmax(scores * sm_scale
+                         + bias.reshape(c, 1, 1, l).astype(f32), axis=-1)
+    ctx = jnp.einsum("cgql,lgd->cgqd", att.astype(v_arena.dtype), gv,
+                     preferred_element_type=f32, precision=prec)
+    return ctx.reshape(c, -1).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -165,6 +222,25 @@ def _mosaic_tiles(block_size, hidden, dtype):
     return block_size % sublanes == 0 and hidden % 128 == 0
 
 
+def _block_copies(bt_ref, arenas, bufs, sem, s, live, grp, half, act, *,
+                  block, group, per_slot):
+    """``start`` or ``wait`` (``act``) for the copies of group ``grp``'s
+    live blocks of slot ``s``, K and V, into ``half`` of the scratch."""
+    for j in range(group):
+        blk = grp * group + j
+        row0 = pl.multiple_of(
+            bt_ref[s * per_slot + jnp.minimum(blk, per_slot - 1)]
+            * block, block)
+        dst = (half, pl.ds(j * block, block))
+
+        @pl.when(blk < live)
+        def _():
+            for n, (arena, buf) in enumerate(zip(arenas, bufs)):
+                getattr(pltpu.make_async_copy(
+                    arena.at[pl.ds(row0, block)], buf.at[dst],
+                    sem.at[n, half]), act)()
+
+
 def _paged_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, sem, *, sm_scale, block, group, per_slot):
     s = pl.program_id(0)
@@ -179,22 +255,9 @@ def _paged_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     def copies(grp, half, act):
-        """``start`` or ``wait`` for the copies of group ``grp``'s live
-        blocks, K and V, into ``half`` of the scratch."""
-        for j in range(group):
-            blk = grp * group + j
-            row0 = pl.multiple_of(
-                bt_ref[s * per_slot + jnp.minimum(blk, per_slot - 1)]
-                * block, block)
-            dst = (half, pl.ds(j * block, block))
-
-            @pl.when(blk < live)
-            def _():
-                for n, (arena, buf) in enumerate(((k_hbm, kbuf),
-                                                  (v_hbm, vbuf))):
-                    getattr(pltpu.make_async_copy(
-                        arena.at[pl.ds(row0, block)], buf.at[dst],
-                        sem.at[n, half]), act)()
+        _block_copies(bt_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, s, live,
+                      grp, half, act, block=block, group=group,
+                      per_slot=per_slot)
 
     @pl.when(ngroups > 0)
     def _():
@@ -231,8 +294,77 @@ def _paged_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
+def _paged_grouped_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
+                        kbuf, vbuf, sem, *, sm_scale, block, group, per_slot,
+                        kv_heads):
+    """``_paged_body`` with a head axis: the rows hold ``kv_heads`` K (V)
+    heads of ``D`` side by side, ``q_ref`` is ``[kv_heads, per, D]``, and a
+    group of blocks is reduced once per K/V head on the MXU, ``per`` query
+    rows at a time (scores ``[per, rows]``, so the bias tile is a row)."""
+    s = pl.program_id(0)
+    live = pl.cdiv(len_ref[s], block)
+    ngroups = pl.cdiv(live, group)
+    d = q_ref.shape[-1]
+    f32 = jnp.float32
+    # a process-wide matmul precision reaches inside the body, and Mosaic
+    # refuses a float32 one on bfloat16 operands: pin the operands' own
+    prec = (jax.lax.Precision.HIGHEST if kbuf.dtype == f32
+            else jax.lax.Precision.DEFAULT)
+
+    @pl.when(s == 0)
+    def _():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def copies(grp, half, act):
+        _block_copies(bt_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, s, live,
+                      grp, half, act, block=block, group=group,
+                      per_slot=per_slot)
+
+    @pl.when(ngroups > 0)
+    def _():
+        copies(0, 0, "start")
+
+    def reduce_group(grp, carry):
+        half = grp % 2
+
+        @pl.when(grp + 1 < ngroups)
+        def _():
+            copies(grp + 1, 1 - half, "start")
+
+        copies(grp, half, "wait")
+        tile = b_ref[pl.ds(grp, 1), :].astype(f32)            # [1, rows]
+        out = []
+        for g in range(kv_heads):
+            m, l, acc = carry[g]
+            k = kbuf[half, :, g * d:(g + 1) * d]              # [rows, D]
+            v = vbuf[half, :, g * d:(g + 1) * d]
+            sc = jax.lax.dot_general(
+                q_ref[g], k, (((1,), (1,)), ((), ())), precision=prec,
+                preferred_element_type=f32)                   # [per, rows]
+            if sm_scale != 1.0:
+                sc = sc * sm_scale
+            sc = sc + tile
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                        precision=prec,
+                                        preferred_element_type=f32)
+            out.append((m_new, l, acc))
+        return tuple(out)
+
+    per = q_ref.shape[1]
+    carry = jax.lax.fori_loop(0, ngroups, reduce_group, tuple(
+        (jnp.full((per, 1), -jnp.inf, f32), jnp.zeros((per, 1), f32),
+         jnp.zeros((per, d), f32)) for _ in range(kv_heads)))
+    for g, (_m, l, acc) in enumerate(carry):
+        o_ref[g] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
+
+
 def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
-                    block_size, sm_scale, interpret=False):
+                    block_size, sm_scale, interpret=False, kv_heads=0):
     """Blocked paged attention: ``paged_attention_composite`` computed
     from the live blocks alone (see the module docstring). ``rows`` must
     be block-aligned, as the engine's row maps are: every ``block_size``
@@ -240,15 +372,20 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     ``block_size``. Falls back to the composite when Mosaic cannot tile
     the geometry or the call sits inside a manual (shard_map) region."""
     S, L, bs = int(seqs), int(length), int(block_size)
-    H = q.shape[-1]
+    H = k_arena.shape[-1]
+    G = int(kv_heads)
     per_slot = -(-L // bs)
     group = _paged_group(bs, per_slot, H, k_arena.dtype)
-    if vma_names(q) or group == 0 or (
-        not interpret and not _mosaic_tiles(bs, H, k_arena.dtype)
-    ):
+    # a grouped body slices a head's D lanes out of a row and stacks `per`
+    # query rows: both in whole tiles of the dtype
+    sublanes = 8 * (4 // jnp.dtype(k_arena.dtype).itemsize)
+    heads_tile = not G or ((H // G) % 128 == 0
+                           and (q.shape[-1] // H) % sublanes == 0)
+    if vma_names(q) or group == 0 or (not interpret and not (
+            _mosaic_tiles(bs, H, k_arena.dtype) and heads_tile)):
         fallback_counter().inc()
         return paged_attention_composite(q, k_arena, v_arena, rows, bias,
-                                         S, L, sm_scale)
+                                         S, L, sm_scale, kv_heads=G)
     grows = group * bs
     ngroups = -(-per_slot // group)
     table = (rows.reshape(S, L)[:, ::bs] // bs).astype(jnp.int32)
@@ -258,9 +395,16 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
         axis=-1)
     tiles = jnp.pad(bias2, ((0, 0), (0, ngroups * grows - L)),
                     constant_values=-1e9).reshape(S, ngroups, grows)
-    row = pl.BlockSpec((None, 1, H), lambda s, *_: (s, 0, 0))
+    if G:
+        per, d = q.shape[-1] // H, H // G
+        row = pl.BlockSpec((None, G, per, d), lambda s, *_: (s, 0, 0, 0))
+        body = functools.partial(_paged_grouped_body, kv_heads=G)
+        q_in = q.reshape(S, G, per, d).astype(k_arena.dtype)
+    else:
+        row = pl.BlockSpec((None, 1, H), lambda s, *_: (s, 0, 0))
+        body, q_in = _paged_body, q.reshape(S, 1, H)
     out = pl.pallas_call(
-        lambda *refs: _paged_body(
+        lambda *refs: body(
             *refs, sm_scale=sm_scale, block=bs, group=group,
             per_slot=per_slot),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -280,11 +424,10 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, 1, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(table.reshape(-1), lengths, q.reshape(S, 1, H), tiles,
-      k_arena, v_arena)
-    return out.reshape(S, H)
+    )(table.reshape(-1), lengths, q_in, tiles, k_arena, v_arena)
+    return out.reshape(q.shape)

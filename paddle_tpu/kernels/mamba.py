@@ -1,0 +1,293 @@
+"""Mamba-2 (state-space duality) mixer: the recurrence's three evaluations.
+
+Per head, with a state ``h`` of ``[P, N]`` (head dim x state size)::
+
+    h_t = exp(dt_t * A) * h_{t-1} + dt_t * x_t (outer) B_t
+    y_t = h_t . C_t + D * x_t
+
+``ssm_scan_sequential`` is that recurrence one token at a time: THE
+definition. ``ssm_scan_chunked`` evaluates it exactly by chunks of ``Q``
+tokens (decay-masked ``C B^T`` inside a chunk, the carried state between
+chunks): the prefill's form, all einsums, no kernel. ``ssm_update`` is the
+decode step's one-token update over a ``[S, H, P, N]`` batch of per-slot
+states, a Pallas kernel that reads and writes the states of the STEPPING
+slots alone, in place; ``ssm_update_composite`` is its reference lowering,
+its CPU path and its ``off`` path.
+
+``mixer_chunk`` / ``mixer_step`` are the whole mixer between its two
+projections (convolution, activations, dt, the scan, the gated grouped
+RMSNorm) for a ``[T, ...]`` prompt chunk of ONE slot and for a ``[S, ...]``
+decode step, over the per-slot state arrays:
+
+* SSM state ``[S, H, P, N]`` float32.
+* convolution tail ``[S, K - 1, D]``: the last ``K - 1`` inputs of the
+  causal depthwise convolution, oldest first (time before channels: a
+  ``[.., D, K - 1]`` array pads its minor dimension of 3 to a lane tile of
+  128 on the TPU).
+
+A position whose ``mask`` is false moves neither state: its ``dt`` is 0
+(decay 1, no input) and the tail is taken at the last real token.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.kernels.registry import fallback_counter
+from paddle_tpu.ops.common import vma_names
+
+__all__ = [
+    "ssm_scan_sequential", "ssm_scan_chunked", "ssm_update_composite",
+    "ssm_update", "mixer_chunk", "mixer_step", "gated_group_norm",
+]
+
+_HI = jax.lax.Precision.HIGHEST
+#: heads of one slot's state that one grid step of the kernel holds
+_HEAD_BLOCK = 16
+
+
+def _per_head(g, heads):
+    """``[.., G, N]`` group values as ``[.., H, N]``: head ``h`` reads
+    group ``h // (H / G)``."""
+    return jnp.repeat(g, heads // g.shape[-2], axis=-2)
+
+
+def ssm_scan_sequential(x, dt, a, b, c, h0):
+    """The recurrence token by token. ``x`` ``[T, H, P]``, ``dt`` ``[T, H]``
+    (after softplus; 0 at a masked position), ``a`` ``[H]`` (negative),
+    ``b``, ``c`` ``[T, G, N]``, ``h0`` ``[H, P, N]``; returns ``y``
+    ``[T, H, P]`` (without the ``D x`` term) and the last state."""
+    heads = x.shape[1]
+
+    def step(h, inp):
+        xt, dtt, bt, ct = inp
+        h = (jnp.exp(dtt * a)[:, None, None] * h
+             + (dtt[:, None] * xt)[:, :, None] * _per_head(bt, heads)[:, None])
+        return h, jnp.sum(h * _per_head(ct, heads)[:, None], axis=-1)
+
+    h, y = jax.lax.scan(step, h0, (x, dt, b, c))
+    return y, h
+
+
+def ssm_scan_chunked(x, dt, a, b, c, h0, chunk):
+    """The same recurrence, exactly, by chunks of ``chunk`` tokens: inside
+    a chunk ``y_t = sum_{s<=t} exp(cs_t - cs_s) (C_t . B_s) dt_s x_s`` with
+    ``cs`` the running sum of ``dt * A``, plus what the carried state
+    gives, ``exp(cs_t) C_t . h``; between chunks the state moves by the
+    whole chunk at once. Any length: the tail is padded with ``dt = 0``."""
+    t_real, heads = x.shape[0], x.shape[1]
+    q = min(int(chunk), t_real)
+    pad = -t_real % q
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                       for v in (x, dt, b, c))
+    n = x.shape[0] // q
+    x, dt, b, c = (v.reshape((n, q) + v.shape[1:]) for v in (x, dt, b, c))
+    tril = jnp.tril(jnp.ones((q, q), bool))[:, :, None]
+
+    def step(h, inp):
+        xq, dtq, bq, cq = inp
+        cs = jnp.cumsum(dtq * a, axis=0)                       # [Q, H]
+        decay = jnp.where(tril, jnp.exp(cs[:, None] - cs[None]), 0.0)
+        cb = jnp.einsum("tgn,sgn->tsg", cq, bq, precision=_HI)
+        w = decay * jnp.repeat(cb, heads // cb.shape[-1], axis=-1)
+        xdt = xq * dtq[:, :, None]                             # [Q, H, P]
+        y = jnp.einsum("tsh,shp->thp", w, xdt, precision=_HI)
+        y = y + jnp.exp(cs)[:, :, None] * jnp.einsum(
+            "thn,hpn->thp", _per_head(cq, heads), h, precision=_HI)
+        to_end = jnp.exp(cs[-1][None] - cs)                    # [Q, H]
+        h = jnp.exp(cs[-1])[:, None, None] * h + jnp.einsum(
+            "shp,shn->hpn", xdt * to_end[:, :, None], _per_head(bq, heads),
+            precision=_HI)
+        return h, y
+
+    h, y = jax.lax.scan(step, h0, (x, dt, b, c))
+    return y.reshape((n * q,) + y.shape[2:])[:t_real], h
+
+
+def ssm_update_composite(state, xdt, decay, bh, ch, mask):
+    """One token per slot. ``state`` ``[S, H, P, N]``, ``xdt`` ``[S, H, P]``
+    (``dt * x``), ``decay`` ``[S, H]`` (``exp(dt * A)``), ``bh``, ``ch``
+    ``[S, H, N]``, ``mask`` ``[S]``: a slot that does not step keeps its
+    state bit for bit and reads ``y`` 0."""
+    new = (decay[:, :, None, None] * state
+           + xdt[:, :, :, None] * bh[:, :, None, :])
+    y = jnp.sum(new * ch[:, :, None, :], axis=-1)
+    keep = mask[:, None, None]
+    return jnp.where(keep[..., None], new, state), jnp.where(keep, y, 0.0)
+
+
+def _ssm_body(sid_ref, n_ref, st_ref, x_ref, da_ref, b_ref, c_ref,
+              out_ref, y_ref, *, block):
+    g = pl.program_id(0)
+    n = n_ref[0]
+
+    @pl.when(g < n)
+    def _():
+        for i in range(block):
+            new = (da_ref[:, i:i + 1] * st_ref[i]
+                   + x_ref[:, i:i + 1] * b_ref[i:i + 1, :])
+            out_ref[i] = new
+            y_ref[:, i:i + 1] = jnp.sum(new * c_ref[i:i + 1, :], axis=-1,
+                                        keepdims=True)
+
+    @pl.when(n == 0)
+    def _():
+        # no slot steps: every grid step names one block, which goes back
+        # as it came
+        out_ref[...] = st_ref[...]
+
+
+def ssm_update(state, xdt, decay, bh, ch, mask, interpret=False):
+    """``ssm_update_composite`` with the states of the stepping slots read
+    and written in place and no others touched: the grid runs over the
+    slots in an order that puts the stepping ones first (scalar prefetch),
+    a head block at a time; past the last of them every grid step names the
+    block of the step before, so nothing is copied. Inside, a head's state
+    is one ``[P, N]`` tile: ``x`` comes transposed (``[P, heads]``) so that
+    a head's column broadcasts along the lanes."""
+    s, heads, p, n_state = state.shape
+    block = min(_HEAD_BLOCK, heads)
+    if vma_names(state) or heads % block or (not interpret and (
+            p % 8 or n_state % 128 or state.dtype != jnp.float32)):
+        fallback_counter().inc()
+        return ssm_update_composite(state, xdt, decay, bh, ch, mask)
+    nb = heads // block
+    order = jnp.argsort(jnp.logical_not(mask), stable=True).astype(jnp.int32)
+    count = jnp.sum(mask.astype(jnp.int32))
+    last = jnp.maximum(count - 1, 0)
+    sid = jnp.where(jnp.arange(s) < count, order, order[last])
+    f32 = jnp.float32
+    # [S, nb, P, block]: a head block's x as columns; decay as one row
+    xt = jnp.swapaxes(xdt.astype(f32).reshape(s, nb, block, p), 2, 3)
+    da = decay.astype(f32).reshape(s, nb, 1, block)
+    bh = bh.astype(f32).reshape(s, nb, block, n_state)
+    ch = ch.astype(f32).reshape(s, nb, block, n_state)
+
+    def where(g, j, sid_ref, n_ref):
+        live = g < n_ref[0]
+        return sid_ref[g], jnp.where(live, j, nb - 1)
+
+    def small(shape):
+        return pl.BlockSpec(
+            (None, None) + shape,
+            lambda g, j, sid_ref, n_ref: where(g, j, sid_ref, n_ref)
+            + (0, 0))
+
+    st_spec = pl.BlockSpec(
+        (None, block, p, n_state),
+        lambda g, j, sid_ref, n_ref: where(g, j, sid_ref, n_ref) + (0, 0))
+    new, yt = pl.pallas_call(
+        functools.partial(_ssm_body, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s, nb),
+            in_specs=[st_spec, small((p, block)), small((1, block)),
+                      small((block, n_state)), small((block, n_state))],
+            out_specs=[st_spec, small((p, block))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((s, nb, p, block), f32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ssm_update",
+    )(sid, count.reshape(1), state.reshape(s, heads, p, n_state), xt, da,
+      bh, ch)
+    y = jnp.swapaxes(yt, 2, 3).reshape(s, heads, p)
+    return new, jnp.where(mask[:, None, None], y, 0.0)
+
+
+def gated_group_norm(y, z, weight, groups, eps):
+    """``RMSNorm(y * silu(z))`` over ``groups`` equal groups of the last
+    dimension, times ``weight``; float32 inside."""
+    f32 = jnp.float32
+    g = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    shape = g.shape
+    g = g.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(shape) * weight.astype(f32)
+
+
+def _float32(params):
+    """The mixer's small parameters, in the order the two forms unpack."""
+    return (params[k].astype(jnp.float32) for k in
+            ("conv_w", "conv_b", "dt_bias", "a_log", "d", "norm_w"))
+
+
+def _split(zxbcdt, heads, head_dim, groups, n_state):
+    d_inner = heads * head_dim
+    conv_dim = d_inner + 2 * groups * n_state
+    return (zxbcdt[..., :d_inner], zxbcdt[..., d_inner:d_inner + conv_dim],
+            zxbcdt[..., d_inner + conv_dim:])
+
+
+def _split_xbc(xbc, heads, head_dim, groups, n_state):
+    d_inner, gn = heads * head_dim, groups * n_state
+    lead = xbc.shape[:-1]
+    return (xbc[..., :d_inner].reshape(lead + (heads, head_dim)),
+            xbc[..., d_inner:d_inner + gn].reshape(lead + (groups, n_state)),
+            xbc[..., d_inner + gn:].reshape(lead + (groups, n_state)))
+
+
+def mixer_chunk(zxbcdt, params, conv_state, ssm_state, slot, mask, reset, *,
+                heads, head_dim, groups, n_state, chunk, eps, out_dtype):
+    """A prompt chunk ``[T, in_proj width]`` of ONE slot against that slot's
+    rows of the state arrays. ``mask`` ``[T]`` marks the real positions (a
+    prefix), ``reset`` says the chunk opens the prompt: the slot's states
+    start from zero, whatever a retired request left there. Returns the
+    gated, normed ``y`` ``[T, d_inner]`` and both state arrays with the
+    slot's rows replaced."""
+    f32 = jnp.float32
+    conv_w, conv_b, dt_bias, a_log, d_skip, norm_w = _float32(params)
+    z, xbc, dt = _split(zxbcdt.astype(f32), heads, head_dim, groups, n_state)
+    taps = conv_w.shape[0]
+    keep = jnp.where(reset, 0.0, 1.0).astype(f32)
+    tail = jax.lax.dynamic_index_in_dim(conv_state, slot, 0, False)
+    h0 = jax.lax.dynamic_index_in_dim(ssm_state, slot, 0, False)
+    tail, h0 = tail.astype(f32) * keep, h0.astype(f32) * keep
+    ext = jnp.concatenate([tail, xbc], axis=0)              # [T + K-1, D]
+    t = xbc.shape[0]
+    conv = sum(conv_w[k] * ext[k:k + t] for k in range(taps)) + conv_b
+    x, b, c = _split_xbc(jax.nn.silu(conv), heads, head_dim, groups, n_state)
+    dt = jnp.where(mask[:, None], jax.nn.softplus(dt + dt_bias), 0.0)
+    y, h = ssm_scan_chunked(x, dt, -jnp.exp(a_log), b, c, h0, chunk)
+    y = (y + d_skip[:, None] * x).reshape(t, heads * head_dim)
+    out = gated_group_norm(y, z, norm_w, groups, eps).astype(out_dtype)
+    real = jnp.sum(mask.astype(jnp.int32))
+    new_tail = jax.lax.dynamic_slice_in_dim(ext, real, taps - 1, axis=0)
+    conv_state = jax.lax.dynamic_update_index_in_dim(
+        conv_state, new_tail.astype(conv_state.dtype), slot, 0)
+    ssm_state = jax.lax.dynamic_update_index_in_dim(
+        ssm_state, h.astype(ssm_state.dtype), slot, 0)
+    return out, conv_state, ssm_state
+
+
+def mixer_step(zxbcdt, params, conv_state, ssm_state, mask, *, heads,
+               head_dim, groups, n_state, eps, out_dtype, kernel=None):
+    """One token per slot ``[S, in_proj width]``; ``mask`` ``[S]`` marks the
+    slots that step. ``kernel`` is None (the composite) or the interpret
+    flag of the ``ssm_update`` kernel."""
+    f32 = jnp.float32
+    conv_w, conv_b, dt_bias, a_log, d_skip, norm_w = _float32(params)
+    z, xbc, dt = _split(zxbcdt.astype(f32), heads, head_dim, groups, n_state)
+    ext = jnp.concatenate([conv_state.astype(f32), xbc[:, None]], axis=1)
+    conv = jnp.sum(conv_w[None] * ext, axis=1) + conv_b
+    x, b, c = _split_xbc(jax.nn.silu(conv), heads, head_dim, groups, n_state)
+    dt = jax.nn.softplus(dt + dt_bias)                        # [S, H]
+    args = (dt[:, :, None] * x, jnp.exp(-dt * jnp.exp(a_log)),
+            _per_head(b, heads), _per_head(c, heads), mask)
+    if kernel is None or ssm_state.dtype != f32:
+        new, y = ssm_update_composite(ssm_state.astype(f32), *args)
+        new = new.astype(ssm_state.dtype)
+    else:
+        new, y = ssm_update(ssm_state, *args, interpret=kernel)
+    y = (y + d_skip[:, None] * x).reshape(x.shape[0], heads * head_dim)
+    out = gated_group_norm(y, z, norm_w, groups, eps).astype(out_dtype)
+    conv_state = jnp.where(mask[:, None, None],
+                           ext[:, 1:].astype(conv_state.dtype), conv_state)
+    return out, conv_state, new

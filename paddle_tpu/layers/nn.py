@@ -30,6 +30,11 @@ __all__ = [
     "logits_mask_add",
     "cached_attention",
     "paged_attention",
+    "chunk_paged_attention",
+    "rms_norm",
+    "relu2",
+    "moe_routed_experts",
+    "mamba2_mixer",
     "block_gather",
     "block_scatter_write",
     "moe_ffn",
@@ -117,8 +122,11 @@ def fc(
     bias_attr=None,
     act=None,
     name=None,
+    out_dtype=None,
 ):
-    """reference: python/paddle/fluid/layers/nn.py:205."""
+    """reference: python/paddle/fluid/layers/nn.py:205. ``out_dtype``
+    (e.g. "float32" over bfloat16 operands) is the dtype the product is
+    accumulated and handed on in."""
     helper = LayerHelper(
         "fc", param_attr=param_attr, bias_attr=bias_attr, act=act, name=name
     )
@@ -143,12 +151,15 @@ def fc(
     w = helper.create_parameter(
         helper.param_attr, shape=[in_features, size], dtype=dtype
     )
-    out = helper.create_variable_for_type_inference(dtype)
+    out = helper.create_variable_for_type_inference(out_dtype or dtype)
+    attrs = {"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
     helper.append_op(
         "mul",
         {"X": [input.name], "Y": [w.name]},
         {"Out": [out.name]},
-        {"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1},
+        attrs,
     )
     if helper.bias_attr is not False:
         b = helper.create_parameter(
@@ -699,7 +710,7 @@ def cached_attention(q, k_cache, v_cache, attn_bias, sm_scale=1.0,
 
 
 def paged_attention(q, k_arena, v_arena, rows, attn_bias, seqs, length,
-                    sm_scale=1.0, block_size=None, name=None):
+                    sm_scale=1.0, block_size=None, kv_heads=0, name=None):
     """Fused paged attention: ``q`` ``[S, H]`` attends over rows of the
     flat ``[R, H]`` block arenas addressed by the ``[S * L]`` row feed —
     ``block_gather(k) ; block_gather(v) ; cached_attention`` as ONE op.
@@ -708,13 +719,17 @@ def paged_attention(q, k_arena, v_arena, rows, attn_bias, seqs, length,
     slot name consecutive arena rows from a multiple of ``block_size``):
     with it the kernel registry may serve the op by the blocked kernel of
     kernels/attention.py, which reads each slot's live blocks in place
-    and never writes the dense ``[S, L, H]`` views."""
+    and never writes the dense ``[S, L, H]`` views. ``kv_heads`` > 0 is
+    grouped-query attention: the arenas' rows hold that many K (V) heads
+    side by side and ``q`` a whole number of query heads to each."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {"sm_scale": float(sm_scale), "seqs": int(seqs),
              "length": int(length)}
     if block_size:
         attrs["block_size"] = int(block_size)
+    if kv_heads:
+        attrs["kv_heads"] = int(kv_heads)
     helper.append_op(
         "paged_attention",
         {"Q": [q.name], "KArena": [k_arena.name], "VArena": [v_arena.name],
@@ -722,6 +737,147 @@ def paged_attention(q, k_arena, v_arena, rows, attn_bias, seqs, length,
         {"Out": [out.name]},
         attrs,
     )
+    return out
+
+
+def chunk_paged_attention(q, k_arena, v_arena, rows, attn_bias, kv_heads,
+                          sm_scale=1.0, name=None):
+    """A prompt chunk's queries ``[C, heads * D]`` over ONE sequence's
+    ``[L]`` rows of the paged arenas under the host's causal bias
+    ``[1, C, L]``, grouped-query (``kv_heads`` K/V heads a row): the chunk
+    program's form of ``paged_attention``."""
+    helper = LayerHelper("chunk_paged_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        "chunk_paged_attention",
+        {"Q": [q.name], "KArena": [k_arena.name], "VArena": [v_arena.name],
+         "Rows": [rows.name], "Bias": [attn_bias.name]},
+        {"Out": [out.name]},
+        {"sm_scale": float(sm_scale), "kv_heads": int(kv_heads)},
+    )
+    return out
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, out_dtype=None,
+             name=None):
+    """RMSNorm over the last dimension with a learned scale (ones at
+    start), computed in float32; ``out_dtype`` names the result's dtype
+    (a float32 residual normed into a bfloat16 mixer input)."""
+    from paddle_tpu.initializer import ConstantInitializer
+
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    scale = helper.create_parameter(
+        helper.param_attr, shape=[int(input.shape[-1])], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(out_dtype or input.dtype)
+    attrs = {"epsilon": float(epsilon)}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
+    helper.append_op("rms_norm", {"X": [input.name], "Scale": [scale.name]},
+                     {"Out": [out.name]}, attrs)
+    return out
+
+
+def relu2(x, name=None):
+    """Squared relu, ``max(x, 0)^2`` (also ``fc(..., act="relu2")``)."""
+    helper = LayerHelper("relu2", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("relu2", {"X": [x.name]}, {"Out": [out.name]})
+    return out
+
+
+def moe_routed_experts(input, write_rows, num_rows, router_experts,
+                       held_experts, ffn_dim, k, param_attrs, expert_offset=0,
+                       score_scale=1.0, normalize=True, kernel=False,
+                       name=None):
+    """This chip's share of a routed-experts layer (ops/moe.py
+    ``moe_routed_experts``): the router scores ``input`` ``[..., H]``
+    against all ``router_experts`` (sigmoid scores, a selection bias, top
+    ``k``, normalised over the k, times ``score_scale``) and the
+    ``held_experts`` that live here (ids from ``expert_offset``) add their
+    relu-squared FFNs' part; no capacity, no dropped token. ``write_rows``
+    marks the real tokens (a row ``>= num_rows`` is routed nowhere).
+    ``param_attrs``: ``gate`` ``[E_all, H]`` and ``select_bias`` ``[E_all]``
+    (float32), ``w_up``, ``w_down`` ``[held, F, H]`` (``input``'s dtype).
+    ``kernel`` lets the ``moe_experts`` kernel serve the op (the decode
+    step). Returns ``(out float32, counts int32 [3])``."""
+    helper = LayerHelper("moe_routed_experts", name=name)
+    hidden = int(input.shape[-1])
+    gate = helper.create_parameter(
+        param_attrs["gate"], shape=[router_experts, hidden], dtype="float32")
+    select_bias = helper.create_parameter(
+        param_attrs["select_bias"], shape=[router_experts], dtype="float32")
+    w_up = helper.create_parameter(
+        param_attrs["w_up"], shape=[held_experts, ffn_dim, hidden],
+        dtype=input.dtype)
+    w_down = helper.create_parameter(
+        param_attrs["w_down"], shape=[held_experts, ffn_dim, hidden],
+        dtype=input.dtype)
+    out = helper.create_variable_for_type_inference("float32")
+    counts = helper.create_variable_for_type_inference("int32")
+    helper.append_op(
+        "moe_routed_experts",
+        {"X": [input.name], "GateW": [gate.name],
+         "SelectBias": [select_bias.name], "WUp": [w_up.name],
+         "WDown": [w_down.name], "WriteRows": [write_rows.name]},
+        {"Out": [out.name], "Counts": [counts.name]},
+        {"k": int(k), "score_scale": float(score_scale),
+         "normalize": bool(normalize), "expert_offset": int(expert_offset),
+         "num_rows": int(num_rows), "kernel": bool(kernel)},
+    )
+    return out, counts
+
+
+def mamba2_mixer(input, conv_state, ssm_state, write_rows, num_rows, mode,
+                 heads, head_dim, groups, state_size, conv_kernel,
+                 param_attrs, slot=None, positions=None, chunk_size=128,
+                 epsilon=1e-5, out_dtype=None, name=None):
+    """The Mamba-2 mixer between its projections (ops/mamba.py): ``input``
+    is the input projection's ``z | xBC | dt``, ``[1, C, width]`` of the one
+    slot ``slot`` names (``mode="chunk"``; ``positions`` tells a prompt's
+    first chunk, which starts from zero states) or ``[S, 1, width]``
+    (``mode="step"``). Advances the per-slot ``conv_state``
+    ``[S, K - 1, D]`` and ``ssm_state`` ``[S, H, P, N]`` in place (assigned
+    back, so the lowering donates them) for the tokens ``write_rows`` marks
+    as real, and returns the gated, normed ``y``. ``param_attrs``:
+    ``conv_w`` ``[K, D]``, ``conv_b`` ``[D]``, ``dt_bias``, ``a_log``, ``d``
+    ``[H]``, ``norm_w`` ``[H * P]``, all float32."""
+    from paddle_tpu.layers.tensor import assign
+
+    helper = LayerHelper("mamba2_mixer", name=name)
+    d_inner = heads * head_dim
+    conv_dim = d_inner + 2 * groups * state_size
+    shapes = {"conv_w": [conv_kernel, conv_dim], "conv_b": [conv_dim],
+              "dt_bias": [heads], "a_log": [heads], "d": [heads],
+              "norm_w": [d_inner]}
+    params = {k: helper.create_parameter(param_attrs[k], shape=shape,
+                                         dtype="float32")
+              for k, shape in shapes.items()}
+    ins = {"X": [input.name], "ConvW": [params["conv_w"].name],
+           "ConvB": [params["conv_b"].name],
+           "DtBias": [params["dt_bias"].name],
+           "ALog": [params["a_log"].name], "D": [params["d"].name],
+           "NormW": [params["norm_w"].name],
+           "ConvState": [conv_state.name], "SsmState": [ssm_state.name],
+           "WriteRows": [write_rows.name]}
+    if mode == "chunk":
+        ins["Slot"] = [slot.name]
+        ins["Positions"] = [positions.name]
+    out = helper.create_variable_for_type_inference(out_dtype or input.dtype)
+    new_conv = helper.create_variable_for_type_inference(conv_state.dtype)
+    new_ssm = helper.create_variable_for_type_inference(ssm_state.dtype)
+    attrs = {"mode": mode, "heads": int(heads), "head_dim": int(head_dim),
+             "groups": int(groups), "state_size": int(state_size),
+             "chunk_size": int(chunk_size), "epsilon": float(epsilon),
+             "num_rows": int(num_rows)}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
+    helper.append_op(
+        "mamba2_mixer", ins,
+        {"Out": [out.name], "ConvStateOut": [new_conv.name],
+         "SsmStateOut": [new_ssm.name]}, attrs)
+    assign(new_conv, output=conv_state)
+    assign(new_ssm, output=ssm_state)
     return out
 
 

@@ -59,7 +59,11 @@ def _mul(ins, attrs):
     xs, ys = x.shape, y.shape
     x2 = x.reshape((math.prod(xs[:xnc]), -1))
     y2 = y.reshape((math.prod(ys[:ync]), -1))
-    out = x2 @ y2
+    if attrs.get("out_dtype"):
+        # a narrow product accumulated, and handed on, in a wider dtype
+        out = jnp.matmul(x2, y2, preferred_element_type=attrs["out_dtype"])
+    else:
+        out = x2 @ y2
     out_shape = tuple(xs[:xnc]) + tuple(ys[ync:])
     return {"Out": [out.reshape(out_shape)]}
 

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map as _shard_map
 
-from paddle_tpu.core.registry import register_op
+from paddle_tpu.core.registry import OpDef, OpRegistry, register_op
 from paddle_tpu.ops.common import first, vma_names
 from paddle_tpu.utils.enforce import EnforceError
 
@@ -119,3 +119,52 @@ def _moe_ffn(ins, attrs):
         "Out": [y.reshape(orig_shape).astype(x.dtype)],
         "AuxLoss": [aux.astype(jnp.float32)],
     }
+
+
+def _moe_routed_experts_reference(ins, attrs):
+    return _moe_routed_experts(ins, attrs, None)
+
+
+def _moe_routed_experts_pallas(ins, attrs):
+    """The ``moe_experts`` kernel serves the op where the builder asks for
+    it (``kernel``: the decode step); a prompt chunk runs the composite."""
+    from paddle_tpu import kernels
+
+    sel = kernels.selected("moe_experts") if attrs.get("kernel") else None
+    return _moe_routed_experts(ins, attrs,
+                               None if sel is None else sel.interpret)
+
+
+def _moe_routed_experts(ins, attrs, kernel):
+    """The held experts' part of a routed-experts layer (kernels/moe.py):
+    ``X`` ``[T, H]``, the router ``GateW`` ``[E_all, H]`` and its selection
+    bias ``SelectBias`` ``[E_all]`` over ALL the experts, ``WUp`` and
+    ``WDown`` ``[held, F, H]`` of the experts ``expert_offset ..
+    expert_offset + held - 1`` that live here, and ``WriteRows`` ``[T]``: a
+    token whose row is ``>= num_rows`` (one that writes nowhere: a slot
+    that does not step, a chunk's padding) is routed nowhere and counted
+    nowhere. ``Out`` float32 ``[T, H]``; ``Counts`` int32 ``[3]``
+    (assignments, held assignments, held experts touched)."""
+    from paddle_tpu.kernels import moe
+
+    x = first(ins, "X")
+    lead = x.shape[:-1]
+    xt = x.reshape(-1, x.shape[-1])
+    w_up, w_down = first(ins, "WUp"), first(ins, "WDown")
+    held, offset = w_up.shape[0], attrs.get("expert_offset", 0)
+    mask = first(ins, "WriteRows").reshape(-1) < attrs["num_rows"]
+    idx, w = moe.route(xt, first(ins, "GateW"), first(ins, "SelectBias"),
+                       attrs["k"], attrs.get("score_scale", 1.0),
+                       attrs.get("normalize", True))
+    c = moe.held_weights(idx, w, mask, offset, held)
+    if kernel is None:
+        out = moe.experts_composite(xt, c, w_up, w_down)
+    else:
+        out = moe.moe_experts(xt, c, w_up, w_down, interpret=kernel)
+    return {"Out": [out.reshape(lead + (x.shape[-1],))],
+            "Counts": [moe.routing_counts(idx, c, mask)]}
+
+
+OpRegistry.register(OpDef(
+    "moe_routed_experts", _moe_routed_experts_reference,
+    pallas=_moe_routed_experts_pallas, nondiff_inputs=("WriteRows",)))

@@ -586,7 +586,7 @@ def _paged_attention_reference(ins, attrs):
     rows, bias = first(ins, "Rows"), first(ins, "Bias")
     return {"Out": [fused.paged_attention_composite(
         q, ka, va, rows, bias, attrs["seqs"], attrs["length"],
-        attrs.get("sm_scale", 1.0))]}
+        attrs.get("sm_scale", 1.0), kv_heads=attrs.get("kv_heads", 0))]}
 
 
 def _paged_attention_pallas(ins, attrs):
@@ -602,7 +602,7 @@ def _paged_attention_pallas(ins, attrs):
     return {"Out": [fused.paged_attention(
         q, ka, va, rows, bias, attrs["seqs"], attrs["length"],
         attrs["block_size"], attrs.get("sm_scale", 1.0),
-        interpret=sel.interpret)]}
+        interpret=sel.interpret, kv_heads=attrs.get("kv_heads", 0))]}
 
 
 OpRegistry.register(
@@ -613,6 +613,37 @@ OpRegistry.register(
         nondiff_inputs=("Rows", "Bias"),
     )
 )
+
+
+@register_op("chunk_paged_attention", nondiff_inputs=("Rows", "Bias"))
+def _chunk_paged_attention(ins, attrs):
+    """A prompt chunk's queries ``[C, heads * D]`` over one sequence's
+    rows of the paged arenas (grouped-query; kernels/attention.py
+    ``chunk_attention_composite``)."""
+    from paddle_tpu.kernels import attention as fused
+
+    return {"Out": [fused.chunk_attention_composite(
+        first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
+        first(ins, "Rows"), first(ins, "Bias"), attrs.get("sm_scale", 1.0),
+        attrs["kv_heads"])]}
+
+
+@register_op("rms_norm")
+def _rms_norm(ins, attrs):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last dimension, in
+    float32; ``out_dtype`` names the result's dtype (default: ``x``'s)."""
+    x, w = first(ins, "X"), first(ins, "Scale")
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(
+        jnp.mean(xf * xf, axis=-1, keepdims=True) + attrs.get("epsilon", 1e-5))
+    y = y * w.astype(jnp.float32)
+    return {"Out": [y.astype(attrs.get("out_dtype") or x.dtype)]}
+
+
+@register_op("relu2")
+def _relu2(ins, attrs):
+    """Squared relu: ``max(x, 0)^2``."""
+    return {"Out": [jnp.square(jnp.maximum(first(ins, "X"), 0))]}
 
 
 @register_op("one_hot", nondiff_inputs=("X",))

@@ -42,6 +42,7 @@ from paddle_tpu.serving.decode import (
     DecodeModel,
     GenerationEngine,
     build_decoder_model,
+    build_nemotron_h_model,
 )
 from paddle_tpu.serving.engine import ServingEngine
 from paddle_tpu.serving.fleet import (
@@ -72,6 +73,7 @@ __all__ = [
     "LocalReplica",
     "SubprocessReplica",
     "build_decoder_model",
+    "build_nemotron_h_model",
     "Priority",
     "RejectedError",
     "ReplicaLostError",

@@ -30,6 +30,8 @@ Modules:
 * `model`  — `DecodeModel`: the fixed-shape paged-program contract
   (decode step / prefill / inject / optional chunk prefill) +
   `build_decoder_model`, the canonical cached-attention decoder builder.
+* `hybrid` — `build_nemotron_h_model`: a Mamba-2 / grouped-query attention
+  / routed-experts decoder with per-slot recurrent state beside the arena.
 * `pool`   — host-side slot allocator, block allocator + radix prefix
   index (storage dedup), and the content-hash prefill cache (compute
   dedup).
@@ -55,6 +57,7 @@ from paddle_tpu.serving.decode.generate import (
     SamplingParams,
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
+from paddle_tpu.serving.decode.hybrid import build_nemotron_h_model
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -78,5 +81,6 @@ __all__ = [
     "SlotPool",
     "block_hashes",
     "build_decoder_model",
+    "build_nemotron_h_model",
     "prompt_key",
 ]

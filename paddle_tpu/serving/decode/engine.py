@@ -46,6 +46,20 @@ alone):
   output BIT-IDENTICAL to target-only decode; the win is target
   steps-per-emitted-token < 1.
 
+State of a second kind: a model may keep **per-slot recurrent state**
+(``DecodeModel.slot_states``: a state-space layer's, beside the paged
+rows). Its programs advance it in place for the tokens whose write row is
+real and leave every other slot's bytes alone; the engine names the slot
+to the chunk program, sends EVERY prompt of such a model through chunked
+prefill from its first token (the chunk at position 0 starts the slot
+from zero: ``decode::state_reset``), registers no block of it for
+sharing, parks no session of it, and refuses what would need a snapshot
+of a state: a prefix cache or a host tier at ``register_model``, beam
+search and speculation at ``submit``. Launch-ahead stays safe: the one
+step an ``eos_id`` wastes dirties only a state the next admission resets.
+A step's integer counts (``counts_fetch``: how a step's tokens were
+routed) come back in the same fetch as its tokens.
+
 Correctness contract: (a) retired/foreign slots touch the arena only
 through dropped or disjoint row scatters (exact no-ops), and (b) the
 additive ``-1e9`` attention bias makes positions beyond a slot's cursor
@@ -401,14 +415,20 @@ class _ModelEntry:
         step_fetches = [m.logits_fetch]
         if m.token_fetch is not None:
             step_fetches.append(m.token_fetch)
-        plans = [
-            ("step", m.decode_program, m.decode_feed_sig(),
-             step_fetches, True),
-            ("prefill", m.prefill_program, m.prefill_feed_sig(),
-             [m.prefill_logits_fetch] + [n for kv in m.prefill_kv_fetches
-                                         for n in kv], False),
-            ("inject", m.inject_program, m.inject_feed_sig(), [], True),
-        ]
+            if m.counts_fetch is not None:
+                step_fetches.append(m.counts_fetch)
+        plans = [("step", m.decode_program, m.decode_feed_sig(),
+                  step_fetches, True)]
+        # a model with per-slot recurrent state has neither: every prompt
+        # streams through the chunk program
+        if m.prefill_program is not None:
+            plans.append(
+                ("prefill", m.prefill_program, m.prefill_feed_sig(),
+                 [m.prefill_logits_fetch] + [n for kv in m.prefill_kv_fetches
+                                             for n in kv], False))
+        if m.inject_program is not None:
+            plans.append(
+                ("inject", m.inject_program, m.inject_feed_sig(), [], True))
         if m.chunk_program is not None:
             plans.append(("chunk", m.chunk_program, m.chunk_feed_sig(),
                           [m.chunk_logits_fetch], True))
@@ -484,11 +504,11 @@ class _ModelEntry:
         import jax.numpy as jnp
 
         m = self._model
-        for kn, vn in m.state_names:
-            for n in (kn, vn):
-                self._scope.set(n, jax.device_put(
-                    jnp.zeros((m.rows, m.hidden), jnp.float32),
-                    self._engine.device))
+        states = [(n, (m.rows, m.kv_width), m.kv_dtype)
+                  for kv in m.state_names for n in kv] + m.slot_states
+        for n, shape, dtype in states:
+            self._scope.set(n, jax.device_put(
+                jnp.zeros(shape, dtype), self._engine.device))
         self._pool.reset()
         self._blocks.reset()
         self._slots = [None] * m.slots
@@ -876,9 +896,12 @@ class _ModelEntry:
         it can never be resumed because its lifetime footprint exceeds
         the whole pool)."""
         st = self._slots[s]
-        if st is None or st.mode not in ("decode", "spec") or st.ahead:
+        if (st is None or st.mode not in ("decode", "spec") or st.ahead
+                or self._model.recurrent):
             # a session whose last token is still on the device cannot
-            # be spilled: its rows are known, its tokens are not
+            # be spilled: its rows are known, its tokens are not. Nor can
+            # one whose slot holds recurrent state: the tier keeps K/V
+            # rows, and the state is no function of them
             return False
         req = st.request
         m = self._model
@@ -1235,7 +1258,10 @@ class _ModelEntry:
         prompt = req.prompt
         plen = len(prompt)
         if (m.chunk_tokens and "chunk" in self._entries
-                and plen > m.chunk_tokens):
+                and (plen > m.chunk_tokens or m.recurrent)):
+            # a recurrent model's every prompt takes this path, from its
+            # first token: the chunks build the slot's state as they go
+            # (no block of it was ever registered, so nothing is shared)
             blocks, shared_len = self._acquire_blocks(req)
             st = _Slot(req, mode="prefill")
             st.seq = self._admit_seq
@@ -1396,13 +1422,22 @@ class _ModelEntry:
                 faults.fire("decode.chunk")
                 if ev.span is not None:
                     ev.span.set(request=req.id, tokens=real)
-                fetches = self._run("chunk", {
+                feeds = {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
                     DecodeModel.CHU_BIAS: bias,
                     DecodeModel.CHU_ROWS: st.row_map,
                     DecodeModel.CHU_WRITE_ROWS: wrows,
-                }, ev.span)
+                }
+                if m.recurrent:
+                    # the chunk at position 0 resets the slot's state
+                    # (``decode::state_reset``: whatever a retired or
+                    # wasted step left there), every chunk advances it
+                    feeds[DecodeModel.CHU_SLOT] = np.array([s], "int64")
+                    if start == 0:
+                        _instant("decode::state_reset", request=req.id,
+                                 slot=s)
+                fetches = self._run("chunk", feeds, ev.span)
         except Exception as e:
             self._arena_lost(f"chunk-prefill failure: {e}")
             return 1
@@ -1414,7 +1449,8 @@ class _ModelEntry:
             logits = self._fetch(fetches[0])         # [1, C, V]
             if sp is not None:
                 sp.set(request=req.id, bytes=logits.nbytes)
-        self._blocks.register_prompt_blocks(st.blocks, req.prompt)
+        if not m.recurrent:
+            self._blocks.register_prompt_blocks(st.blocks, req.prompt)
         st.cursor = st.plen
         if req.beam is not None:
             st.mode = "beam"
@@ -2145,17 +2181,30 @@ class _ModelEntry:
         step in ``serving_decode_step_seconds``: the wall time since
         ``t0`` (the `_step` body, or the drain) plus what the step's
         launch cost in a body that delivered nothing."""
+        m = self._model
         with _span("decode::step_fetch") as sp:
-            if step.tokens_only:
-                fetched = self._fetch(step.fetches[1])       # [S, 1] int
-            else:
+            tokens = counts = None
+            if m.counts_fetch is not None:
+                # the S tokens and the step's counts in ONE vector
+                both = self._fetch(step.fetches[2])
+                tokens, counts = (both[:m.slots].reshape(m.slots, 1),
+                                  both[m.slots:])
+            if not step.tokens_only:
                 fetched = self._fetch(step.fetches[0])       # [S, 1, V]
                 self._metrics.incr("decode_logits_fetch_steps")
+            elif tokens is None:
+                fetched = self._fetch(step.fetches[1])       # [S, 1] int
+            else:
+                fetched = tokens
             if sp is not None:
                 sp.set(bytes=fetched.nbytes,
                        rows="tokens" if step.tokens_only else "logits")
                 if drain is not None:
                     sp.set(drain=drain)
+        # what the device did in this step, wasted slots included
+        if counts is not None:
+            for name, n in zip(m.count_names, counts):
+                self._metrics.incr(name, int(n))
         # a slot retired or rejected since the launch (an ``eos_id``, a
         # deadline: seen only when the step before this one landed) was
         # stepped for nothing; its token is dropped
@@ -2407,6 +2456,11 @@ class _ModelEntry:
         (paged decode, chunked prefill, speculative, sampled,
         constrained) — against THIS."""
         m = self._model
+        if m.prefill_program is None:
+            raise RuntimeError(
+                f"model {m.label} has no stateless prefill program to "
+                "re-run (per-slot recurrent state): its reference is a "
+                "plain forward pass outside the engine")
         toks = list(prompt)
         out = []
         g = GrammarConstraint(grammar) if grammar is not None else None
@@ -2549,6 +2603,18 @@ class GenerationEngine:
             model = model()        # zero-arg builder
         if model.key in self._entries:
             raise ValueError(f"model {model.label} already registered")
+        if model.recurrent and (self._prefix_cache_size
+                                or self._host_tier_bytes):
+            from paddle_tpu.utils.enforce import EnforceError
+
+            raise EnforceError(
+                f"model {model.label} keeps per-slot recurrent state, "
+                "which the prefix cache and the host KV tier cannot carry: "
+                "both key on K/V rows, a function of the token prefix "
+                "alone, and hold no snapshot of a state. Host it on an "
+                "engine with prefix_cache_size=0 and host_tier_mb=0 (got "
+                f"prefix_cache_size={self._prefix_cache_size}, "
+                f"host_tier_mb={self._host_tier_bytes >> 20})")
         self._check_hbm(model)
         entry = _ModelEntry(
             self, model, self._queue_depth, self._breaker_threshold,
@@ -2821,6 +2887,13 @@ class GenerationEngine:
             sampling = SamplingParams(**sampling)
         if sampling is not None and not isinstance(sampling, SamplingParams):
             self._bad(entry, "sampling must be a SamplingParams or dict")
+        if m.recurrent and (beam_width is not None
+                            or draft_model is not None):
+            # a fork copies K/V rows and a verify re-derives them from
+            # the tokens; neither carries a slot's recurrent state
+            self._bad(entry, f"model {m.label} keeps per-slot recurrent "
+                             "state: beam search and speculative decoding "
+                             "are not served for it")
         beam = None
         if beam_width is not None:
             beam = BeamParams(beam_width)

@@ -108,6 +108,14 @@ class DecodeMetrics(ServingMetrics):
         # a stepping slot's positions up to its cursor, over every block
         # of every slot (what the whole-arena gather read)
         "decode_live_blocks", "decode_block_slots",
+        # a model with routed experts of which this chip holds a share,
+        # per decode step as the device ran it (wasted slots included):
+        # tokens x k over the expert layers, those that landed on a held
+        # expert, held experts with at least one token (of held experts x
+        # expert layers a step: a constant of the model, like the
+        # state-space layers a stepping slot updates, so neither is
+        # counted here)
+        "moe_assignments", "moe_held_assignments", "moe_touched_experts",
     )
 
     def __init__(self, engine_label=None, registry=None):

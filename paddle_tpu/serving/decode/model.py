@@ -69,6 +69,21 @@ class DecodeModel:
     DecodeModel — the circuit breaker's relaunch path uses it to rebuild
     a replica that warms entirely from the compile cache.
 
+    A model may keep state of a second kind (``slot_states``: ``(name,
+    shape, dtype)`` of arrays whose first axis is the SLOT, not the arena
+    row): the recurrent state of a state-space layer, advanced in place by
+    the chunk and step programs and reset by a prompt's first chunk. Such
+    a model has no stateless prefill and no inject program (both None):
+    every prompt streams through the chunk program, which is also fed the
+    slot's index (``CHU_SLOT``), and K/V rows alone can neither be shared
+    by prefix nor parked (``GenerationEngine.register_model`` refuses a
+    prefix cache or a host tier for it). ``kv_width`` / ``kv_dtype`` are
+    the arenas' row width and dtype where they are not ``hidden`` /
+    float32 (grouped-query attention in bfloat16). ``counts_fetch`` names
+    an int32 vector, the step's ``[S]`` tokens then one integer per
+    ``count_names``: what a greedy step hands the host in its ONE fetch,
+    the integers added to the counters of those names.
+
     The decode step fetches ``[logits_fetch, token_fetch]``:
     ``logits_fetch`` names the float32 ``[S, 1, V]`` logits (mask added
     when ``logits_mask``), ``token_fetch`` the ``[S, 1]`` integers that
@@ -93,6 +108,7 @@ class DecodeModel:
     CHU_BIAS = "chu_bias"
     CHU_ROWS = "chu_rows"
     CHU_WRITE_ROWS = "chu_write_rows"
+    CHU_SLOT = "chu_slot"
 
     def __init__(self, *, decode_program, prefill_program, inject_program,
                  startup_program, slots, max_len, vocab_size, hidden,
@@ -101,7 +117,8 @@ class DecodeModel:
                  num_blocks, chunk_program=None, chunk_tokens=None,
                  chunk_logits_fetch=None, eos_id=None, name="model",
                  version="1", builder=None, logits_mask=False,
-                 token_fetch=None):
+                 token_fetch=None, kv_width=None, kv_dtype="float32",
+                 slot_states=(), counts_fetch=None, count_names=()):
         self.decode_program = decode_program
         self.prefill_program = prefill_program
         self.inject_program = inject_program
@@ -126,6 +143,17 @@ class DecodeModel:
         self.version = str(version)
         self.builder = builder
         self.logits_mask = bool(logits_mask)
+        self.kv_width = int(kv_width) if kv_width else self.hidden
+        self.kv_dtype = str(kv_dtype)
+        self.slot_states = [(n, tuple(int(d) for d in shape), str(dt))
+                            for n, shape, dt in slot_states]
+        self.counts_fetch = counts_fetch
+        self.count_names = tuple(count_names)
+
+    @property
+    def recurrent(self):
+        """Whether the model keeps per-slot recurrent state."""
+        return bool(self.slot_states)
 
     @property
     def key(self):
@@ -141,19 +169,23 @@ class DecodeModel:
         return self.num_blocks * self.block_size
 
     def arena_bytes(self):
-        """Exact bytes of the paged KV pool: 2 arenas x layers x
-        ``[R, H]`` float32 — what `analysis/memory.py` sees as
+        """Exact bytes of the model's state on the device: the paged KV
+        pool (2 arenas x layers x ``[R, kv_width]`` of ``kv_dtype``) and
+        every per-slot state array — what `analysis/memory.py` sees as
         persistent state and what the HBM budget gate reasons about.
         The slotted design's ``S * max_len`` rows become
         ``num_blocks * block_size``, sized to USED tokens."""
-        per = self.rows * self.hidden * 4
-        return per * 2 * len(self.state_names)
+        per = self.rows * self.kv_width * _itemsize(self.kv_dtype)
+        slot = sum(int(np.prod(shape)) * _itemsize(dt)
+                   for _n, shape, dt in self.slot_states)
+        return per * 2 * len(self.state_names) + slot
 
     def slotted_equivalent_bytes(self):
         """What the PR 10 dense design would reserve for the same
         ``(slots, max_len)`` grid — the paged-vs-slotted comparison
         baseline in DECODE_EVIDENCE."""
-        per = self.slots * self.max_len * self.hidden * 4
+        per = (self.slots * self.max_len * self.kv_width
+               * _itemsize(self.kv_dtype))
         return per * 2 * len(self.state_names)
 
     # -- feed signatures (ordered like each program's feed list) ---------
@@ -191,32 +223,40 @@ class DecodeModel:
 
     def chunk_feed_sig(self):
         c, l = self.chunk_tokens, self.max_len
-        return (
+        sig = [
             (self.CHU_TOKENS, (1, c), "int64"),
             (self.CHU_POSITIONS, (1, c), "int64"),
             (self.CHU_BIAS, (1, c, l), "float32"),
             (self.CHU_ROWS, (l,), "int64"),
             (self.CHU_WRITE_ROWS, (c,), "int64"),
-        )
+        ]
+        if self.recurrent:
+            # whose rows of the per-slot state arrays the chunk advances
+            sig.append((self.CHU_SLOT, (1,), "int64"))
+        return tuple(sig)
 
 
-def _state_var(main_program, startup_program, name, shape):
-    """A persistable float32 state var declared in ``main_program`` and
+def _itemsize(dtype):
+    return 2 if dtype in ("bfloat16", "float16") else np.dtype(dtype).itemsize
+
+
+def _state_var(main_program, startup_program, name, shape, dtype="float32"):
+    """A persistable state var declared in ``main_program`` and
     zero-initialized ONCE in the shared startup (create_global_var would
     append a duplicate fill per program that declares the arena)."""
     mblock = main_program.global_block()
     var = mblock.vars.get(name)
     if var is None:
         var = mblock.create_var(name=name, shape=list(shape),
-                                dtype="float32", persistable=True)
+                                dtype=dtype, persistable=True)
         var.stop_gradient = True
     sblock = startup_program.global_block()
     if name not in sblock.vars:
-        sblock.create_var(name=name, shape=list(shape), dtype="float32",
+        sblock.create_var(name=name, shape=list(shape), dtype=dtype,
                           persistable=True)
         sblock.append_op(
             "fill_constant", {}, {"Out": [name]},
-            {"shape": list(shape), "dtype": "float32", "value": 0.0},
+            {"shape": list(shape), "dtype": dtype, "value": 0.0},
         )
     return var
 

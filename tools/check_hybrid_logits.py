@@ -1,0 +1,240 @@
+"""The served ``nemotron_h`` configuration against its plain reference,
+outside any timed window, and the readings the cell's limits are set from
+(``benchmark/traffic/reasoning_steady.json``; PERF.md section 2).
+
+    python3 tools/check_hybrid_logits.py --seed <n> [--requests 32]
+        [--steps 192] [--faults ssm,conv,kv,kv_all]
+        [--references float8_e4m3fn,operands:bfloat16]
+        [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
+        [--dump chiprun_out/rows.npz] [--rehearse-cpu]
+
+Requests of the cell's own length distribution go through the engine,
+chunked prefill then decode steps with the logits fetched and the top token
+taken (what a greedy request is served, with its row); the reference reads
+the prompt and the served tokens in one pass. Per served token:
+``row_sigma``, max |served row - reference row| over the reference row's
+standard deviation, and ``behind``, how far the served token's logit lies
+behind the reference row's top in those units: the quantity
+``benchmark/serve.py`` holds to ``check_tolerance``. ``answers_wrong``
+replays the cell's own comparison on these rows (a request is wrong when
+one of its first ``check_tokens`` tokens is behind by more than
+``check_tolerance``; ``--requests`` of them where the cell takes
+``check_requests``).
+
+The served tokens are read once as served and once for each of
+``--faults``: one layer's SSM state (``ssm``), convolution tail (``conv``)
+or K arena (``kv``; ``kv_all``: every attention layer's) put back to what it
+was after every decode step (a stale row). ``--references``
+reads the sound tokens again with the reference computed otherwise:
+``<dtype>`` rounds its weights through that dtype (the precision below the
+served one), ``operands:<dtype>`` the left operand of its products (the
+served program's own arithmetic, as near as a plain pass comes), and for
+the latter the expert layers' choices are compared with the float32 pass's
+(``flips``): the tokens whose chosen sets differ, a layer, and those of
+them where a held expert comes or goes.
+"""
+
+import argparse
+import json
+import os
+import sys
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+OVER = (0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 1.0)
+
+
+def _stale(entry, fault):
+    """``entry._run`` wrapped so that after every decode step one layer's
+    state of kind ``fault`` is what it was before the step; returns the
+    call that takes the wrap off."""
+    import jax.numpy as jnp
+
+    m = entry.model
+    names = ([kv[0] for kv in m.state_names] if fault == "kv_all" else
+             [m.state_names[0][0]] if fault == "kv" else
+             [n for n, _s, _d in m.slot_states if "." + fault in n][:1])
+    launch = entry._run
+
+    def run(kind, feeds, span=None):
+        if kind != "step":
+            return launch(kind, feeds, span)
+        kept = [jnp.array(entry._scope.find_var(n), copy=True)
+                for n in names]
+        out = launch(kind, feeds, span)
+        for n, was in zip(names, kept):
+            entry._scope.set(n, was)
+        return out
+
+    entry._run = run
+    return lambda: setattr(entry, "_run", launch)
+
+
+def _serve(system, prompts, steps):
+    """The greedy answers to ``prompts`` with the row each token was the
+    top of: ``[(tokens, rows [steps, V])]``."""
+    from paddle_tpu.serving.decode import SamplingParams
+
+    rows = {}
+
+    def top(st, row, device_masked):
+        row = np.array(row, np.float32)
+        rows.setdefault(id(st.request.response), []).append(row)
+        return int(row.argmax())
+
+    entry = system.entry
+    choose, entry._choose_token = entry._choose_token, top
+    try:
+        # a sampled policy brings every step's rows to the host
+        responses = [system.engine.submit(
+            p, max_new_tokens=steps, sampling=SamplingParams(seed=i))
+            for i, p in enumerate(prompts)]
+        return [([int(t) for t in r.result(timeout=1800)["tokens"]],
+                 np.stack(rows[id(r)])) for r in responses]
+    finally:
+        entry._choose_token = choose
+
+
+def _against(system, prompts, served, **how):
+    """(row_sigma, behind), each ``[requests, steps]``, and what
+    ``routing`` adds."""
+    sigma, behind, extra = [], [], []
+    for prompt, (out, got) in zip(prompts, served):
+        first = len(prompt) - 1
+        want = system.reference_logits(
+            list(prompt) + out[:-1], range(first, first + len(out)), **how)
+        if how.get("routing"):
+            want, *more = want
+            extra.append(more)
+        std = want.std(1)
+        sigma.append(np.abs(got - want).max(1) / std)
+        behind.append((want.max(1) - want[np.arange(len(out)), out]) / std)
+    return np.stack(sigma), np.stack(behind), extra
+
+
+def _summary(sigma, behind, traffic):
+    flat = behind.reshape(-1)
+    tokens = traffic["check_tokens"]
+    worst = behind[:, :tokens].max(1)
+    return {
+        "row_sigma": {"median": float(np.median(sigma)),
+                      "p90": float(np.percentile(sigma, 90)),
+                      "worst": float(sigma.max())},
+        "behind": {"worst": float(flat.max()),
+                   "share_of_tokens_over": {
+                       str(t): float((flat > t).mean()) for t in OVER}},
+        "answers_wrong": int((worst > traffic["check_tolerance"]).sum()),
+        "answers": int(worst.size),
+        "answers_worst_behind": [round(float(w), 4) for w in worst]}
+
+
+def _flips(routing, other, held, k):
+    """Per expert layer: tokens whose chosen sets differ between two
+    passes, and those of them where a held expert comes or goes."""
+    differ = held_differ = 0
+    for (a, _sa), (b, _sb) in zip(routing, other):
+        a, b = np.sort(a[..., :k], -1), np.sort(b[..., :k], -1)
+        moved = (a != b).any(-1)                      # [layers, tokens]
+        mine = lambda x: np.where(  # noqa: E731
+            (x >= held[0]) & (x < held[1]), x, -1)
+        held_moved = (np.sort(mine(a), -1) != np.sort(mine(b), -1)).any(-1)
+        differ = differ + moved.sum(1)
+        held_differ = held_differ + held_moved.sum(1)
+    return {"tokens_differing_by_layer": [int(n) for n in differ],
+            "with_a_held_expert_by_layer": [int(n) for n in held_differ]}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--requests", type=int, default=32)
+    ap.add_argument("--steps", type=int, default=192)
+    ap.add_argument("--faults", default="")
+    ap.add_argument("--references", default="")
+    ap.add_argument("--state-dtype", default=None)
+    ap.add_argument("--kernels", default=None, choices=("off", "interpret"))
+    ap.add_argument("--pattern", default=None, help="another layer pattern "
+                    "(a diagnosis by kind of layer; widths as configured)")
+    ap.add_argument("--dump", default=None, help="an .npz of every "
+                    "reading's row_sigma and behind, [requests, steps]")
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    args = ap.parse_args(argv)
+
+    from benchmark import manifest, workgen
+    from benchmark.builders import nemotron_h_engine
+    from paddle_tpu import kernels
+
+    bench = manifest.load_manifest()
+    config = manifest.load_config(bench, "nemotron3_nano_30b_a3b")
+    traffic = manifest.sizes(manifest.load_traffic("reasoning_steady"),
+                             args.rehearse_cpu)
+    if not args.rehearse_cpu:
+        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                              os.path.join(ROOT, ".jax_cache"))
+    if args.state_dtype:
+        config = dict(config, settings=dict(config["settings"],
+                                            state_dtype=args.state_dtype))
+    if args.pattern:
+        config = dict(config, hybrid_override_pattern=args.pattern,
+                      num_hidden_layers=len(args.pattern))
+    mode = args.kernels or ("interpret" if args.rehearse_cpu else None)
+    rng = np.random.default_rng(args.seed)
+    with kernels.scoped_mode(mode or kernels.mode()):
+        system = nemotron_h_engine.build(config, traffic, args.seed,
+                                         args.rehearse_cpu)
+        lengths = workgen.stratified_lengths(traffic["prompt_len"],
+                                             args.requests)
+        steps = min(args.steps, traffic["max_total_len"] - max(lengths))
+        prompts = [workgen.prompt_tokens(rng, n, system.vocab_size)
+                   for n in lengths]
+        system.engine.start()
+        served = {"sound": _serve(system, prompts, steps)}
+        for fault in filter(None, args.faults.split(",")):
+            undo = _stale(system.entry, fault)
+            served["stale_" + fault] = _serve(system, prompts, steps)
+            undo()
+        system.engine.shutdown()
+    keys = system.config
+    held = (system.expert_offset,
+            system.expert_offset + keys["n_routed_experts"])
+    report = {"seed": args.seed, "requests": len(prompts), "steps": steps,
+              "prompt_lengths": lengths,
+              "state_dtype": config["settings"]["state_dtype"],
+              "kernels": mode or "auto",
+              "check_tokens": traffic["check_tokens"],
+              "check_tolerance": traffic["check_tolerance"]}
+    dump = {}
+    for name, answers in served.items():
+        sigma, behind, routing = _against(
+            system, prompts, answers, routing=name == "sound")
+        report[name] = _summary(sigma, behind, traffic)
+        dump[name + ".row_sigma"], dump[name + ".behind"] = sigma, behind
+        if name != "sound":
+            continue
+        gap = np.concatenate([(s[..., -2] - s[..., -1]).reshape(-1)
+                              for _ids, s in routing])
+        report["reference_margin_share_under"] = {
+            str(t): float((gap < t).mean()) for t in (1e-3, 3e-3, 1e-2)}
+        dump["sound.margin"] = np.stack(
+            [s[..., -2] - s[..., -1] for _ids, s in routing])
+        for ref in filter(None, args.references.split(",")):
+            how = ({"round_operands": ref.split(":")[1], "routing": True}
+                   if ref.startswith("operands:") else {"round_to": ref})
+            _sigma, behind, other = _against(system, prompts, answers, **how)
+            report["reference_" + ref] = _summary(_sigma, behind, traffic)
+            dump[f"reference_{ref}.behind"] = behind
+            if other:
+                report["reference_" + ref]["flips"] = _flips(
+                    routing, other, held, keys["num_experts_per_tok"])
+    if args.dump:
+        os.makedirs(os.path.dirname(os.path.abspath(args.dump)),
+                    exist_ok=True)
+        np.savez_compressed(args.dump, **dump)
+    print(json.dumps(report), flush=True)
+
+
+if __name__ == "__main__":
+    main()
