@@ -33,6 +33,13 @@ alone):
   array they are, BEFORE it fetches them: depth one, decided per
   iteration from the scheduler's own state (``_iterate``), the same
   tokens in either order.
+* **one-shot prefill** — a prompt the chunk budget covers (every prompt
+  of a model without a chunk program) runs the whole-prompt prefill
+  program once, and its admission moves no bulk bytes across the host
+  link: the program's K/V outputs are the inject program's feeds, the
+  host takes the one logits row at the prompt's last position and, in
+  one fetch, the rows such a prompt can fill (what the prefix cache and
+  copy-on-write keep), and all of it is launched before the host waits.
 * **chunked prefill** — a prompt longer than the chunk budget streams
   through the ``[1, C]`` chunk program ONE chunk per engine iteration,
   interleaved with decode steps, so a 32k-token admission never stalls
@@ -331,6 +338,20 @@ class _ParkedSession:
         self.parked_at = time.perf_counter()
 
 
+def _pick_row(logits, index):
+    """Row ``index`` of ``[1, N, V]`` logits, on the device."""
+    import jax
+
+    return jax.lax.dynamic_index_in_dim(logits[0], index, axis=0,
+                                        keepdims=False)
+
+
+def _by_layer(live):
+    """The ``(k, v)`` rows of each layer in a ``[2 * layers, P, H]`` host
+    copy of a one-shot prefill (``_ModelEntry._prefill_to_host``)."""
+    return [(live[i], live[i + 1]) for i in range(0, len(live), 2)]
+
+
 class _ModelEntry:
     """One hosted (model, version): programs + executables + slot batch +
     block pool + its scheduler thread. All slot/arena/block mutation
@@ -404,6 +425,7 @@ class _ModelEntry:
             exe.run(self._model.startup_program)
         self._rng0 = zero_rng_key(self._engine.device)
         self._lower_all()
+        self._compile_admission_helpers()
         return self
 
     def _lower_all(self):
@@ -448,6 +470,49 @@ class _ModelEntry:
         # this on the loop thread while stats() dict-copies concurrently
         self.compile_sources = sources
 
+    def _compile_admission_helpers(self):
+        """What an admission needs on the device besides the programs,
+        made once here so that nothing compiles and nothing constant is
+        put after registration. ``_pickers[kind]`` takes the float32
+        ``[1, N, V]`` logits of the prefill or chunk program and a
+        position (an OPERAND: one executable for every prompt length)
+        and gives that one ``[V]`` row. ``_stack_live`` takes the prefill
+        program's K/V outputs (``[1, L, H]`` float32 each, what the inject
+        program is fed) and gives their first P rows as ONE
+        ``[2 * layers, P, H]`` array, the host copy the prefix cache and
+        copy-on-write keep; P is ``chunk_tokens`` where a chunk program
+        takes every longer prompt, else ``max_len``. ``_causal_bias`` is
+        the prefill program's ``[1, L, L]`` bias, the same for every
+        prompt. Shapes are the model's contract, not a relaunch's: the
+        rebuilt programs are content-identical, so these outlive it."""
+        import jax
+
+        m = self._model
+        L, V = m.max_len, m.vocab_size
+        chunked = bool(m.chunk_tokens) and m.chunk_program is not None
+
+        def sds(*shape, dtype=np.float32):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        def picker(n):
+            return jax.jit(_pick_row).lower(
+                sds(1, n, V), sds(dtype=np.int32)).compile()
+
+        self._pickers = {}
+        if chunked:
+            self._pickers["chunk"] = picker(m.chunk_tokens)
+        if m.prefill_program is None:
+            return
+        self._pickers["prefill"] = picker(L)
+        rows = m.chunk_tokens if chunked else L
+        self._stack_live = jax.jit(
+            lambda *kv: jax.numpy.stack([a[0, :rows] for a in kv])
+        ).lower(*[sds(1, L, m.hidden)] * (2 * len(m.prefill_kv_fetches))
+                ).compile()
+        self._causal_bias = jax.device_put(
+            np.triu(np.full((L, L), NEG_INF, "float32"), k=1)[None],
+            self._engine.device)
+
     def _run(self, kind, feeds, span=None):
         """Execute one lowered program against the entry scope; written
         persistables (the arenas — donated, updated in place on device)
@@ -459,7 +524,9 @@ class _ModelEntry:
         module still belongs to the caller's span. A feed that is a
         device array already (a launched-ahead step's tokens: the
         previous step's own output) is handed over as it is: nothing is
-        put, nothing counted as fed, and the host does not wait for it."""
+        put, nothing counted as fed, and the host does not wait for it;
+        so are a one-shot prefill's K/V outputs fed to the inject program,
+        and the prefill program's constant causal bias."""
         import jax
 
         entry, executable = self._entries[kind]
@@ -1099,6 +1166,23 @@ class _ModelEntry:
         self._metrics.incr("sessions_resumed")
         return True
 
+    def _inject_feeds(self, inj_rows, pieces):
+        """The inject program's feeds from HOST rows: ``pieces`` lists
+        ``(position, kv)``, ``kv[i]`` the ``(k, v)`` rows of layer ``i``
+        that belong at that position onward; each layer's rows are padded
+        to the program's ``[1, L, H]`` (``inj_rows`` says which of them
+        land anywhere)."""
+        m = self._model
+        inj = {DecodeModel.INJ_ROWS: inj_rows}
+        for i, names in enumerate(m.inject_kv_feeds):
+            for j, name in enumerate(names):
+                arr = np.zeros((1, m.max_len, m.hidden), "float32")
+                for p, kv in pieces:
+                    rows = kv[i][j]
+                    arr[0, p:p + len(rows)] = rows
+                inj[name] = arr
+        return inj
+
     def _inject_rows(self, st, key):
         """Re-inject a resumed session's KV rows ``[0:cursor)``. The tier
         entry is consumed if present and CRC-clean; otherwise (evicted or
@@ -1122,16 +1206,8 @@ class _ModelEntry:
             self._metrics.incr("resume_replays")
         inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
         inj_rows[:n] = st.row_map[:n]
-        inj = {DecodeModel.INJ_ROWS: inj_rows}
-        for i, (kn, vn) in enumerate(m.inject_kv_feeds):
-            karr = np.zeros((1, m.max_len, m.hidden), "float32")
-            varr = np.zeros((1, m.max_len, m.hidden), "float32")
-            karr[0, :n] = kv[i][0]
-            varr[0, :n] = kv[i][1]
-            inj[kn] = karr
-            inj[vn] = varr
         try:
-            self._run("inject", inj)
+            self._run("inject", self._inject_feeds(inj_rows, [(0, kv)]))
         except Exception as e:
             self._arena_lost(f"resume inject failure: {e}")
             return False
@@ -1165,16 +1241,8 @@ class _ModelEntry:
         lo, hi = start * bs, idx * bs
         inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
         inj_rows[lo:hi] = st.row_map[lo:hi]
-        inj = {DecodeModel.INJ_ROWS: inj_rows}
-        for i, (kn, vn) in enumerate(m.inject_kv_feeds):
-            karr = np.zeros((1, m.max_len, m.hidden), "float32")
-            varr = np.zeros((1, m.max_len, m.hidden), "float32")
-            for j, ent in enumerate(ents):
-                p = lo + j * bs
-                karr[0, p:p + bs] = ent.kv_rows[i][0]
-                varr[0, p:p + bs] = ent.kv_rows[i][1]
-            inj[kn] = karr
-            inj[vn] = varr
+        inj = self._inject_feeds(inj_rows, [
+            (lo + j * bs, ent.kv_rows) for j, ent in enumerate(ents)])
         try:
             with profiler.RecordEvent("decode::inject") as ev:
                 if ev.span is not None:
@@ -1280,12 +1348,16 @@ class _ModelEntry:
             return
         key = prompt_key(prompt)
         cached = self._prefix.get(key)
+        fetches = None
         if cached is not None:
-            kv_rows, logits_row = cached
             # hit/miss totals live on PrefixCache (one source, surfaced
             # by stats()); only the per-tenant series is a counter here
             self._metrics.tenant_incr("prefix_hits", req.tenant)
         else:
+            # a miss moves no bulk bytes across the host link: the
+            # prefill program's outputs stay on the device, where the
+            # inject program, the row picker and the stack-and-trim read
+            # them, all launched before the host waits for anything
             t0 = time.perf_counter()
             with profiler.RecordEvent("decode::prefill") as ev:
                 faults.fire("decode.prefill")
@@ -1293,19 +1365,13 @@ class _ModelEntry:
                     ev.span.set(request=req.id, prompt_len=plen)
                 fetches = self._run("prefill", self._prefill_feeds(prompt),
                                     ev.span)
-            with _span("decode::prefill_fetch") as sp:
-                logits = self._fetch(fetches[0])         # [1, L, V]
-                kv_rows = [self._fetch(f) for f in fetches[1:]]
-                # copy: a view would pin the whole [1, L, V] prefill
-                # logits buffer in the prefix cache for the life of the
-                # entry
-                logits_row = np.array(logits[0, len(prompt) - 1])
-                self._prefix.put(key, kv_rows, logits_row)
-                if sp is not None:
-                    sp.set(request=req.id, bytes=logits.nbytes
-                           + sum(a.nbytes for a in kv_rows))
-            self._metrics.observe_prefill(time.perf_counter() - t0)
-        blocks, shared_len = self._acquire_blocks(req)
+        try:
+            blocks, shared_len = self._acquire_blocks(req)
+        except _DeferAdmission:
+            if fetches is not None:
+                # the retry finds the prompt in the prefix cache
+                self._prefill_to_host(req, key, fetches)
+            raise
         st = _Slot(req, mode="decode")
         st.seq = self._admit_seq
         st.blocks = blocks
@@ -1317,10 +1383,14 @@ class _ModelEntry:
             # same KV bytes)
             inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
             inj_rows[shared_len:plen] = st.row_map[shared_len:plen]
-            inj = {DecodeModel.INJ_ROWS: inj_rows}
-            for i, (kn, vn) in enumerate(m.inject_kv_feeds):
-                inj[kn] = kv_rows[2 * i]
-                inj[vn] = kv_rows[2 * i + 1]
+            if fetches is not None:
+                inj = {DecodeModel.INJ_ROWS: inj_rows}
+                for i, (kn, vn) in enumerate(m.inject_kv_feeds):
+                    inj[kn] = fetches[1 + 2 * i]
+                    inj[vn] = fetches[2 + 2 * i]
+            else:
+                inj = self._inject_feeds(inj_rows,
+                                         [(0, _by_layer(cached[0]))])
             try:
                 with profiler.RecordEvent("decode::inject") as ev:
                     faults.fire("decode.inject")
@@ -1329,11 +1399,16 @@ class _ModelEntry:
                     self._run("inject", inj, ev.span)
             except Exception as e:
                 raise _ArenaInvalidError(str(e)) from e
+            if fetches is not None:
+                self._metrics.incr("prefill_device_injects")
+        if fetches is not None:
+            cached = self._prefill_to_host(req, key, fetches)
+            self._metrics.observe_prefill(time.perf_counter() - t0)
+        live, logits_row = cached
 
         def host_rows(start, stop):
-            return [(np.array(kv_rows[2 * i][0, start:stop]),
-                     np.array(kv_rows[2 * i + 1][0, start:stop]))
-                    for i in range(len(m.state_names))]
+            return [(np.array(k[start:stop]), np.array(v[start:stop]))
+                    for k, v in _by_layer(live)]
 
         self._blocks.register_prompt_blocks(blocks, prompt,
                                             host_rows=host_rows)
@@ -1364,11 +1439,29 @@ class _ModelEntry:
         toks = np.zeros((1, m.max_len), "int64")
         toks[0, :len(prompt)] = prompt
         pos = np.arange(m.max_len, dtype="int64")[None]
-        bias = np.triu(np.full((m.max_len, m.max_len), NEG_INF, "float32"),
-                       k=1)[None]
         return {DecodeModel.PRE_TOKENS: toks,
                 DecodeModel.PRE_POSITIONS: pos,
-                DecodeModel.PRE_BIAS: bias}
+                DecodeModel.PRE_BIAS: self._causal_bias}
+
+    def _prefill_to_host(self, req, key, fetches):
+        """The host's copy of a one-shot prefill, in two fetches: the
+        ``[V]`` logits row at the prompt's last position and the
+        ``[2 * layers, P, H]`` live K/V rows (``_compile_admission_helpers``),
+        both cut on the device from the prefill program's outputs, which
+        stay there. Both launches precede both fetches. The pair is the
+        prefix cache's entry, and the rows back the copy-on-write of a
+        shared partial block."""
+        with _span("decode::prefill_fetch") as sp:
+            row = self._pickers["prefill"](
+                fetches[0], np.int32(len(req.prompt) - 1))
+            live = self._stack_live(*fetches[1:])
+            logits_row = self._fetch(row)
+            live = self._fetch(live)
+            self._prefix.put(key, live, logits_row)
+            if sp is not None:
+                sp.set(request=req.id,
+                       bytes=logits_row.nbytes + live.nbytes)
+        return live, logits_row
 
     # -- chunked prefill ---------------------------------------------------
     def _advance_prefills(self):
@@ -1446,16 +1539,19 @@ class _ModelEntry:
         if st.done < st.plen:
             return 1
         with _span("decode::chunk_fetch") as sp:
-            logits = self._fetch(fetches[0])         # [1, C, V]
+            # the one row a prompt's last chunk is run for, [V] of the
+            # [1, C, V] that stay on the device
+            logits_row = self._fetch(self._pickers["chunk"](
+                fetches[0], np.int32(real - 1)))
             if sp is not None:
-                sp.set(request=req.id, bytes=logits.nbytes)
+                sp.set(request=req.id, bytes=logits_row.nbytes)
         if not m.recurrent:
             self._blocks.register_prompt_blocks(st.blocks, req.prompt)
         st.cursor = st.plen
         if req.beam is not None:
             st.mode = "beam"
             try:
-                self._begin_beam(s, np.array(logits[0, real - 1]))
+                self._begin_beam(s, logits_row)
             except _ArenaInvalidError as e:
                 self._arena_lost(f"beam fork inject failure: {e}")
             return 1
@@ -1463,8 +1559,7 @@ class _ModelEntry:
         st.sampling = req.sampling
         if req.grammar is not None:
             st.grammar = GrammarConstraint(req.grammar)
-        first = self._choose_token(st, logits[0, real - 1],
-                                   device_masked=False)
+        first = self._choose_token(st, logits_row, device_masked=False)
         st.last_token = first
         st.generated = [first]
         req.response.token_times.append(time.perf_counter())
@@ -1811,16 +1906,9 @@ class _ModelEntry:
         u = cow.size_used
         inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
         inj_rows[:u] = cow.block.row0 + np.arange(u)
-        inj = {DecodeModel.INJ_ROWS: inj_rows}
-        for i, (kn, vn) in enumerate(m.inject_kv_feeds):
-            karr = np.zeros((1, m.max_len, m.hidden), "float32")
-            varr = np.zeros((1, m.max_len, m.hidden), "float32")
-            karr[0, :u] = cow.host_rows[i][0]
-            varr[0, :u] = cow.host_rows[i][1]
-            inj[kn] = karr
-            inj[vn] = varr
         with profiler.RecordEvent("decode::cow_inject"):
-            self._run("inject", inj)
+            self._run("inject",
+                      self._inject_feeds(inj_rows, [(0, cow.host_rows)]))
         self._rebuild_row_map(st)
 
     # -- generation policy (host-side selection over fetched logits) ------

@@ -67,6 +67,9 @@ class DecodeMetrics(ServingMetrics):
         # PrefixCache itself — stats() reports them from that one
         # source; only the per-tenant prefix_hits series is a counter)
         "prefills", "rejected_quota", "blocks_exhausted",
+        # one-shot admissions whose K/V rows went from the prefill
+        # program's outputs into the arena without visiting the host
+        "prefill_device_injects",
         # chunked prefill (one budgeted chunk per engine iteration)
         "chunk_runs", "chunk_tokens",
         # speculative decoding: target verify forwards vs emitted tokens

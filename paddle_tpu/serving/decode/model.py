@@ -21,14 +21,21 @@ static:
   over the vocabulary). The engine brings the tokens alone to the host
   on a step whose slots are all greedy, the logits on every other.
 * **prefill** — whole-prompt forward at ``[1, L]`` with a causal
-  additive bias, fetching per-layer K/V rows ``[1, L, H]`` and logits
-  ``[1, L, V]``. Stateless (donation off): its outputs are
-  host-cacheable, which is what feeds both the prefill cache and the
-  copy-on-write bytes of shared partial blocks.
+  additive bias (a constant the engine puts on the device once), giving
+  per-layer K/V rows ``[1, L, H]`` and logits ``[1, L, V]``. Stateless
+  (donation off), and its outputs STAY on the device: an admission
+  feeds the K/V outputs to the inject program as they are and brings to
+  the host the one ``[V]`` logits row at the prompt's last position and
+  the rows a one-shot prompt can fill, ``[2 * layers, P, H]`` in one
+  fetch (P = ``chunk_tokens`` where a chunk program takes every longer
+  prompt, else ``L``). That host copy is what the prefill cache keeps
+  and what backs the copy-on-write bytes of shared partial blocks.
 * **inject** — scatters up to ``L`` prefill K/V rows into arbitrary
-  arena rows by a row map ``[L]`` (rows >= ``R`` dropped). Shared-prefix
-  admissions inject ONLY their non-shared suffix — shared blocks
-  already hold byte-identical rows.
+  arena rows by a row map ``[L]`` (rows >= ``R`` dropped); its K/V feeds
+  are the prefill program's outputs on a prefill-cache miss, host rows
+  padded to ``[1, L, H]`` on a hit, a resume or a copy-on-write.
+  Shared-prefix admissions inject ONLY their non-shared suffix — shared
+  blocks already hold byte-identical rows.
 * **chunk prefill** (built when ``chunk_tokens`` is set) — ``[1, C]``
   prompt chunk against the paged arena: scatters the chunk's own K/V
   rows, gathers the full ``[L]`` context view back, and attends under a

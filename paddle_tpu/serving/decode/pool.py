@@ -123,9 +123,14 @@ class PrefixCache:
     content hash (prefill COMPUTE dedup; the BlockPool radix below is
     the storage dedup that rides on top of it).
 
-    Values are host numpy tuples ``(kv_rows, logits_row)`` where
-    ``kv_rows`` is the per-layer ``[1, L, H]`` K/V list and
-    ``logits_row`` the ``[V]`` logits at the prompt's last position.
+    Values are host numpy tuples ``(live_rows, logits_row)``:
+    ``live_rows`` is ONE ``[2 * layers, P, H]`` array, layer ``i``'s K
+    rows at ``2 * i`` and its V rows at ``2 * i + 1``, cut on the device
+    to the P rows a one-shot prompt can fill (the model's
+    ``chunk_tokens`` where longer prompts stream through the chunk
+    program, else ``max_len``) and brought to the host in one fetch;
+    ``logits_row`` is the ``[V]`` logits at the prompt's last position.
+    A hit pads the rows to the inject program's ``[1, L, H]`` feeds.
     Thread-safe (submissions from many clients race admission)."""
 
     def __init__(self, capacity=64):
@@ -145,13 +150,11 @@ class PrefixCache:
             self.hits += 1
             return val
 
-    def put(self, key, kv_rows, logits_row):
+    def put(self, key, live_rows, logits_row):
         if self.capacity <= 0:
             return
         with self._lock:
-            self._map[key] = (
-                [np.asarray(r) for r in kv_rows], np.asarray(logits_row),
-            )
+            self._map[key] = (np.asarray(live_rows), np.asarray(logits_row))
             self._map.move_to_end(key)
             while len(self._map) > self.capacity:
                 self._map.popitem(last=False)

@@ -217,6 +217,110 @@ def test_prefix_cache_dedups_prefill_bit_exactly(served):
 
 
 # ---------------------------------------------------------------------------
+# a one-shot admission moves no bulk bytes across the host link (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+ONE_SHOT = dict(vocab_size=32, hidden=8, num_layers=2, slots=4, max_len=16,
+                block_size=4)
+
+
+@pytest.fixture(scope="module", params=["chunk_program", "no_chunk_program"])
+def one_shot(request):
+    """A sharpened entry, never started, with a chunk program (prompts of
+    up to ``chunk_tokens`` = 8 are one-shot: P = 8) or without (every
+    prompt is: P = ``max_len`` = 16)."""
+    chunked = request.param == "chunk_program"
+    engine = GenerationEngine(queue_depth=16, breaker_threshold=0)
+    entry = sharpen(engine.register_model(lambda: build_decoder_model(
+        name="oneshot_" + request.param, version="1",
+        chunk_tokens=8 if chunked else None, **ONE_SHOT)))
+    return engine, entry, 8 if chunked else ONE_SHOT["max_len"]
+
+
+@pytest.mark.parametrize("plen", [1, 4, 5, 8], ids=[
+    "one_token", "block_multiple", "block_multiple_plus_1", "chunk_tokens"])
+def test_one_shot_admission_fetches_one_row_and_the_live_rows(one_shot, plen):
+    """On a prefix-cache miss the host takes the ``[V]`` logits row and
+    ONE ``[2 * layers, P, H]`` array of K/V rows, and feeds the prefill
+    program's tokens and positions and the inject program's row map:
+    neither the causal bias (a constant on the device since
+    registration) nor any K/V (the prefill program's outputs are the
+    inject program's feeds). The prefix cache keeps those P rows a layer,
+    and the served tokens equal the offline reference's."""
+    engine, entry, P = one_shot
+    V, H, L = (ONE_SHOT[k] for k in ("vocab_size", "hidden", "max_len"))
+    layers = ONE_SHOT["num_layers"]
+    prompt = [20 + plen] + [int(t) for t in range(3, 2 + plen)]
+    ref = entry.offline_decode(prompt, 4)
+    count = entry.metrics.count
+    fed0, fetched0 = count("fed_bytes"), count("fetched_bytes")
+    injects0, entries0 = count("prefill_device_injects"), len(
+        entry.prefix_cache)
+    resp = engine.submit(prompt, max_new_tokens=4)
+    assert entry._admit_free_slots() == 1
+    assert count("fetched_bytes") - fetched0 \
+        == V * 4 + 2 * layers * P * H * 4
+    assert count("fed_bytes") - fed0 == 3 * L * 8
+    assert count("prefill_device_injects") - injects0 == 1
+    assert len(entry.prefix_cache) == entries0 + 1
+    live, row = entry.prefix_cache.get(next(reversed(
+        entry.prefix_cache._map)))
+    assert live.shape == (2 * layers, P, H)
+    assert live.nbytes == 2 * layers * P * H * 4 and row.shape == (V,)
+    for _ in range(8):
+        entry._iterate()
+    assert [int(t) for t in resp.result(timeout=60)["tokens"]] == ref
+    # the same prompt again is a hit: nothing prefilled, nothing fetched
+    # but its steps' tokens, and the same answer from the trimmed rows
+    fetched0, prefills0 = count("fetched_bytes"), count("prefills")
+    resp = engine.submit(prompt, max_new_tokens=4)
+    assert entry._admit_free_slots() == 1
+    assert count("fetched_bytes") == fetched0
+    assert count("prefills") == prefills0
+    assert count("prefill_device_injects") - injects0 == 1
+    for _ in range(8):
+        entry._iterate()
+    assert [int(t) for t in resp.result(timeout=60)["tokens"]] == ref
+
+
+def test_nothing_compiles_after_register_model():
+    """Twenty admissions of twenty distinct prompt lengths, one-shot
+    (1..8) and chunked (9..20), served to the end: jax's backend-compile
+    events and the repo's own counters, counted by the benchmark's own
+    ``Compiles`` (``chip_smoke.py``'s arithmetic), stand still, as
+    ``compiles_in_window`` must on the chip. The position of the logits
+    row is an operand of the picker, not a slice baked into a program."""
+    import jax
+
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark.compiles import Compiles
+
+    engine = GenerationEngine(queue_depth=32, breaker_threshold=0)
+    entry = engine.register_model(lambda: build_decoder_model(
+        vocab_size=32, hidden=8, num_layers=2, slots=4, max_len=32,
+        block_size=4, chunk_tokens=8, name="nocompile", version="1"))
+    rng = np.random.RandomState(37)
+    prompts = [[int(t) for t in rng.randint(0, 32, size=n)]
+               for n in range(1, 21)]
+    compiles = Compiles()
+    before = compiles.snapshot()
+    resps = [engine.submit(p, max_new_tokens=3) for p in prompts]
+    for _ in range(400):
+        if all(r.done() for r in resps):
+            break
+        entry._iterate()
+    assert all(len(r.result(timeout=60)["tokens"]) == 3 for r in resps)
+    assert entry.metrics.count("prefills") == 8
+    assert entry.metrics.count("prefill_device_injects") == 8
+    assert Compiles.moved(before, compiles.snapshot()) == 0, \
+        "an admission compiled after registration"
+    # the count is live: a computation jax has not seen moves it
+    jax.jit(lambda x: x * 37 + 1)(np.arange(37))
+    assert Compiles.moved(before, compiles.snapshot()) > 0
+
+
+# ---------------------------------------------------------------------------
 # multi-tenant registry + weighted-fair scheduling
 # ---------------------------------------------------------------------------
 

@@ -438,11 +438,14 @@ def test_byte_counters_equal_the_nbytes_of_a_hand_built_step(token_feed):
     assert logits.shape == (S, 1, m.vocab_size)
     assert metrics.count("fetched_bytes") == logits.nbytes \
         == 4 * S * m.vocab_size
-    # a prefill puts its own feeds and is not a step
+    # a prefill puts its own feeds and is not a step; its causal bias is
+    # a constant put at registration, so nothing of it is fed
     pre = entry._prefill_feeds([1, 2, 3])
+    assert isinstance(pre[DecodeModel.PRE_BIAS], jax.Array)
     entry._run("prefill", pre)
     assert metrics.count("fed_bytes") == fed + sum(
-        a.nbytes for a in pre.values())
+        a.nbytes for a in pre.values() if not isinstance(a, jax.Array)) \
+        == fed + 2 * 8 * L
     assert metrics.count("step_launches") == 1
 
 
@@ -456,6 +459,33 @@ def test_byte_counters_add_up_over_a_served_run(traced_run):
     assert m.count("fetched_bytes") == sum(
         s["args"]["bytes"] for s in fetched)
     assert m.count("step_launches") == m.count("decode_steps")
+
+
+def test_a_one_shot_admission_moves_one_row_and_the_live_rows(traced_run):
+    """Since ISSUE 37: ``decode::prefill_fetch``, once per one-shot miss,
+    brings the ``[V]`` logits row and the ``[2 * layers, P, H]`` live K/V
+    rows (P = ``chunk_tokens`` here) and no more; the prefill launch puts
+    tokens and positions, the inject launch its row map alone, its K/V
+    feeds being the prefill program's outputs; a last chunk's fetch is
+    the one row too."""
+    entry, _resps, spans = traced_run
+    m = entry.model
+    V, H, L, P = m.vocab_size, m.hidden, m.max_len, m.chunk_tokens
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s["args"])
+    one_shot = sum(n <= P for n in PROMPT_LENS)
+    assert len(by_name["decode::prefill_fetch"]) == one_shot \
+        == entry.metrics.count("prefills") \
+        == entry.metrics.count("prefill_device_injects")
+    assert {a["bytes"] for a in by_name["decode::prefill_fetch"]} \
+        == {4 * V + 2 * len(m.state_names) * P * H * 4}
+    assert [a["bytes"] for a in by_name["decode::prefill"]] \
+        == [2 * 8 * L] * one_shot
+    assert [a["bytes"] for a in by_name["decode::inject"]] \
+        == [8 * L] * one_shot
+    assert [a["bytes"] for a in by_name["decode::chunk_fetch"]] \
+        == [4 * V] * (len(PROMPT_LENS) - one_shot)
 
 
 # -- overload decisions on the timeline ----------------------------------------------
