@@ -10,8 +10,7 @@ FetchHandler stack, rebuilt TPU-native):
   registry with Prometheus-style text exposition (``scrape_text``).
 * ``sanitizer`` — the FLAGS_check_nan_inf interpreter mode: every op
   output checked, violations named with the op and its user callstack.
-* ``logger``    — rate-limited structured logging + ``log_event`` (one
-  call fans out to the log, an instant trace event, and a counter).
+* ``logger``    — namespaced loggers and rate-limited logging.
 * ``fetcher``   — background periodic fetchers for long training loops
   (FetchHandlerMonitor) and registry scrapes (PeriodicMetricsDump).
 * ``lockdep``   — runtime lock-order witness: named lock classes, one
@@ -47,7 +46,6 @@ from paddle_tpu.observability.metrics import (
 from paddle_tpu.observability.logger import (
     RateLimitedLogger,
     get_logger,
-    log_event,
 )
 from paddle_tpu.observability.sanitizer import (
     NanInfError,
@@ -85,7 +83,6 @@ __all__ = [
     "scrape_text",
     "RateLimitedLogger",
     "get_logger",
-    "log_event",
     "NanInfError",
     "check_output",
     "sanitize_nan_inf",

@@ -1,9 +1,4 @@
-"""Structured + rate-limited logging glued to the tracer and registry.
-
-``log_event`` is the one-call structured event: a stdlib log record, an
-instant trace event (visible in the chrome timeline next to the spans it
-explains), and a counter in the metrics registry — so a gang restart or a
-skipped record is simultaneously grep-able, plottable, and scrape-able.
+"""Rate-limited logging glued to the registry.
 
 ``RateLimitedLogger`` caps repetitive per-record messages (reader skips,
 retry storms) at N pass-throughs, then stays silent until ``summarize()``
@@ -15,9 +10,8 @@ import logging
 import threading
 
 from paddle_tpu.observability import metrics as _metrics
-from paddle_tpu.observability import tracer as _tracer
 
-__all__ = ["get_logger", "log_event", "RateLimitedLogger"]
+__all__ = ["get_logger", "RateLimitedLogger"]
 
 _ROOT = "paddle_tpu"
 
@@ -29,20 +23,6 @@ def get_logger(name=None):
     if name.startswith(_ROOT):
         return logging.getLogger(name)
     return logging.getLogger(f"{_ROOT}.{name}")
-
-
-def log_event(kind, _level=logging.INFO, _logger=None, **fields):
-    """Record one structured event everywhere at once: instant trace
-    event, ``events_total{kind=...}`` counter, and (optionally) a log
-    line. Returns the event dict."""
-    _tracer.instant(kind, cat="event", **fields)
-    _metrics.registry().counter(
-        "events_total", "structured events by kind",
-        labels={"kind": kind},
-    ).inc()
-    if _logger is not None:
-        _logger.log(_level, "%s %s", kind, fields)
-    return dict(kind=kind, **fields)
 
 
 class RateLimitedLogger:

@@ -304,18 +304,22 @@ class _LaunchedStep:
     request by now) gets no token. ``host_s`` is the wall time of the
     ``_step`` body that launched it, when that body delivered nothing:
     the step's share of ``serving_decode_step_seconds`` that its
-    delivery still owes."""
+    delivery still owes. ``launch`` is the number its ``decode::step``
+    span carries, for the ``decode::step_fetch`` that lands it (None
+    when the launch was not traced)."""
 
     __slots__ = ("fetches", "active", "states", "groups", "tokens_only",
-                 "host_s")
+                 "host_s", "launch")
 
-    def __init__(self, fetches, active, states, groups, tokens_only):
+    def __init__(self, fetches, active, states, groups, tokens_only,
+                 launch=None):
         self.fetches = fetches
         self.active = active
         self.states = states
         self.groups = groups
         self.tokens_only = tokens_only
         self.host_s = 0.0
+        self.launch = launch
 
 
 class _ParkedSession:
@@ -336,6 +340,14 @@ class _ParkedSession:
         self.keys = keys
         self.group = group
         self.parked_at = time.perf_counter()
+
+
+def _stopwatch_ns():
+    """Nanoseconds since this call, at each call of what it returns: what
+    ``trace_scope.elapsed_ns`` is to a live span, for a launch that is
+    timed while tracing is off."""
+    t0 = time.perf_counter_ns()
+    return lambda: time.perf_counter_ns() - t0
 
 
 def _pick_row(logits, index):
@@ -366,7 +378,10 @@ class _ModelEntry:
         self._cond = threading.Condition(self._queue.lock)
         self._pool = SlotPool(model.slots)
         self._slots = [None] * model.slots
-        self._blocks = BlockPool(model.num_blocks, model.block_size)
+        self._metrics = DecodeMetrics(
+            engine_label=f"{engine.label}:{model.label}")
+        self._blocks = BlockPool(model.num_blocks, model.block_size,
+                                 count=self._metrics.incr)
         self._prefix = PrefixCache(prefix_cache_size)
         # graceful degradation (r18): host-RAM KV tier, parked sessions,
         # deferred admissions, and the brownout severity ladder. The
@@ -388,8 +403,6 @@ class _ModelEntry:
             _ReplicaBreaker(breaker_threshold, breaker_cooldown_s)
             if breaker_threshold and breaker_threshold > 0 else None
         )
-        self._metrics = DecodeMetrics(
-            engine_label=f"{engine.label}:{model.label}")
         self.compile_sources = {"trace": 0, "disk": 0, "memory": 0}
         self._entries = {}      # kind -> (LoweredStep, executable)
         self._thread = None
@@ -521,7 +534,11 @@ class _ModelEntry:
         bytes fed and the nanoseconds the host spent inside
         ``jax.device_put`` (from the span's opening) and inside the
         executable's call — two clock reads, no child span, so the device
-        module still belongs to the caller's span. A feed that is a
+        module still belongs to the caller's span. The step program's two
+        halves are also observed, tracing on or off, in
+        ``serving_decode_step_put_seconds`` / ``..._call_seconds``: from
+        the span's pair of reads where there is a span, else from this
+        call's start. A feed that is a
         device array already (a launched-ahead step's tokens: the
         previous step's own output) is handed over as it is: nothing is
         put, nothing counted as fed, and the host does not wait for it;
@@ -531,6 +548,8 @@ class _ModelEntry:
 
         entry, executable = self._entries[kind]
         dev = self._engine.device
+        elapsed_ns = (span.elapsed_ns if span is not None
+                      else _stopwatch_ns() if kind == "step" else None)
         fed = 0
         feed_vals = []
         for n in entry.feed_names:
@@ -540,15 +559,19 @@ class _ModelEntry:
                 fed += a.nbytes
                 a = jax.device_put(a, dev)
             feed_vals.append(a)
-        if span is not None:
-            put_ns = span.elapsed_ns()
+        if elapsed_ns is not None:
+            put_ns = elapsed_ns()
         donated = tuple(self._scope.find_var(n) for n in entry.donated)
         readonly = tuple(self._scope.find_var(n) for n in entry.readonly)
         fetches, updates = executable(tuple(feed_vals), donated, readonly,
                                       self._rng0)
-        if span is not None:
-            span.set(bytes=fed, put_ns=put_ns,
-                     call_ns=span.elapsed_ns() - put_ns)
+        if elapsed_ns is not None:
+            call_ns = elapsed_ns() - put_ns
+            if span is not None:
+                span.set(bytes=fed, put_ns=put_ns, call_ns=call_ns)
+            if kind == "step":
+                self._metrics.observe_step_launch(put_ns * 1e-9,
+                                                  call_ns * 1e-9)
         self._metrics.count_launch(kind, fed)
         for n, u in zip(entry.written, updates):
             self._scope.set(n, u)
@@ -683,7 +706,7 @@ class _ModelEntry:
                     for r in self._queue.expire():
                         self._reject_expired(r)
                     if not self._stop:
-                        self._cond.wait(timeout=min(wait_s, 0.1))
+                        self._wait("breaker", min(wait_s, 0.1))
                 return False
             if verdict == "probe" and not self._probe_relaunched:
                 # re-admission probe IS a relaunch: fresh programs,
@@ -714,10 +737,28 @@ class _ModelEntry:
             if not admitted and not progressed:
                 with self._cond:
                     if not self._stop:
-                        self._cond.wait(timeout=0.02)
+                        self._wait("idle", 0.02)
             return False
         self._step()
         return False
+
+    def _wait(self, why, timeout):
+        """Sleep on the condition (held by the caller) until a submit or
+        a shutdown notifies it, or ``timeout`` runs out: the one place the
+        loop is asleep. Every sleep is observed in
+        ``serving_decode_wait_seconds`` and is a ``decode::wait`` span
+        that says why (``"idle"``: nothing decodable and this round moved
+        nothing; ``"breaker"``: the open breaker's cooldown) and what the
+        loop left waiting when it chose to sleep (``queued`` rows,
+        ``parked`` sessions, ``pending`` deferred admissions): a wait
+        taken with work queued is visible as such."""
+        with _span("decode::wait") as sp:
+            if sp is not None:
+                sp.set(why=why, queued=self._queue.depth(),
+                       parked=len(self._parked), pending=len(self._pending))
+            t0 = time.perf_counter()
+            self._cond.wait(timeout=timeout)
+            self._metrics.observe_wait(time.perf_counter() - t0)
 
     def _steps_again(self, st):
         """Whether a decode slot is fed to the next step, from what the
@@ -914,27 +955,37 @@ class _ModelEntry:
         return blocks, shared_len
 
     # -- preemption / host-tier spill / resume ----------------------------
+    def _read_arenas(self, pick):
+        """``pick(arena)`` of every K and V arena, per layer, and the
+        bytes brought to the host for it: each arena WHOLE, whatever is
+        picked. They are fetches (``serving_fetched_bytes_total``) and
+        are counted in ``serving_arena_read_bytes_total`` besides."""
+        out, nbytes = [], 0
+        for kn, vn in self._model.state_names:
+            k = self._fetch(self._scope.find_var(kn))
+            v = self._fetch(self._scope.find_var(vn))
+            nbytes += k.nbytes + v.nbytes
+            out.append((np.array(pick(k)), np.array(pick(v))))
+        self._metrics.incr("arena_read_bytes", nbytes)
+        return out, nbytes
+
     def _read_block_rows(self, b):
         """Tier write-back reader: one registered block's live arena rows
         (called by the pool inside ``decode.blocks`` at LRU eviction —
-        before the evictee's rows can be overwritten by its successor)."""
-        out = []
-        for kn, vn in self._model.state_names:
-            k = np.asarray(self._scope.find_var(kn))
-            v = np.asarray(self._scope.find_var(vn))
-            out.append((np.array(k[b.row0:b.row0 + b.size_used]),
-                        np.array(v[b.row0:b.row0 + b.size_used])))
-        return out
+        before the evictee's rows can be overwritten by its successor).
+        A ``decode::writeback`` span, with the bytes it brought over."""
+        with _span("decode::writeback") as sp:
+            rows, nbytes = self._read_arenas(
+                lambda a: a[b.row0:b.row0 + b.size_used])
+            if sp is not None:
+                sp.set(block=b.id, rows=b.size_used, bytes=nbytes)
+        return rows
 
     def _read_rows(self, row_map, n):
-        """One slot's KV rows ``[0:n)`` off the live arena, per layer."""
+        """One slot's KV rows ``[0:n)`` off the live arena, per layer,
+        and the bytes of arena brought to the host for them."""
         idx = np.asarray(row_map[:n], dtype=np.int64)
-        out = []
-        for kn, vn in self._model.state_names:
-            k = np.asarray(self._scope.find_var(kn))
-            v = np.asarray(self._scope.find_var(vn))
-            out.append((np.array(k[idx]), np.array(v[idx])))
-        return out
+        return self._read_arenas(lambda a: a[idx])
 
     def _park_victim(self, req):
         """Pick and park one decode-mode victim to free blocks for
@@ -989,9 +1040,11 @@ class _ModelEntry:
         if need > m.num_blocks:
             return False
         key = f"park:{req.id}:0"
-        with profiler.RecordEvent("decode::spill"):
+        with profiler.RecordEvent("decode::spill") as ev:
             faults.fire("decode.spill")
-            rows = self._read_rows(st.row_map, st.cursor)
+            rows, nbytes = self._read_rows(st.row_map, st.cursor)
+            if ev.span is not None:
+                ev.span.set(bytes=nbytes)
             toks = (list(req.prompt) + list(st.generated))[:st.cursor]
             if not self._tier.put(key, rows, st.cursor, tokens=toks):
                 return False
@@ -1018,11 +1071,15 @@ class _ModelEntry:
         if need > m.num_blocks:
             return False
         keys = []
-        with profiler.RecordEvent("decode::spill"):
+        spilled = 0
+        with profiler.RecordEvent("decode::spill") as ev:
             faults.fire("decode.spill")
             for rank, (sid, st) in enumerate(live):
                 key = f"park:{req.id}:{rank}"
-                rows = self._read_rows(st.row_map, st.cursor)
+                rows, nbytes = self._read_rows(st.row_map, st.cursor)
+                spilled += nbytes
+                if ev.span is not None:
+                    ev.span.set(bytes=spilled)
                 toks = (list(req.prompt) + list(st.generated))[:st.cursor]
                 if not self._tier.put(key, rows, st.cursor, tokens=toks):
                     for k in keys:
@@ -2203,12 +2260,14 @@ class _ModelEntry:
             return
         feeds, active, groups = built
         t0 = time.perf_counter()
+        launch = None
         try:
             with profiler.RecordEvent("decode::step") as ev:
                 faults.fire("decode.step")
-                if ev.span is not None:
-                    ev.span.set(ahead=prev is not None)
                 fetches = self._run("step", feeds, ev.span)
+                if ev.span is not None:
+                    launch = self._metrics.count("step_launches")
+                    ev.span.set(ahead=prev is not None, launch=launch)
         except Exception as e:
             # a failed donated call leaves the arena undefined: every
             # in-flight sequence is lost (failed loudly; with a step in
@@ -2221,7 +2280,7 @@ class _ModelEntry:
             self._breaker_event(self._breaker.record_success())
         step = _LaunchedStep(fetches, active,
                              [self._slots[s] for s in active], groups,
-                             self._tokens_suffice(active, groups))
+                             self._tokens_suffice(active, groups), launch)
         self._advance_cursors(step.states)
         if prev is not None:
             self._metrics.incr("decode_steps_ahead")
@@ -2289,6 +2348,8 @@ class _ModelEntry:
                        rows="tokens" if step.tokens_only else "logits")
                 if drain is not None:
                     sp.set(drain=drain)
+                if step.launch is not None:
+                    sp.set(launch=step.launch)
         # what the device did in this step, wasted slots included
         if counts is not None:
             for name, n in zip(m.count_names, counts):

@@ -40,11 +40,33 @@ is the launch of step N+1 and the fetch and host half of step N), or of
 the drain, plus the time of the body that launched it if that body
 delivered nothing. The sum over a window is the host time spent on
 decode steps; an iteration's other phases are not in it.
+
+Where the loop's time goes when it is not in a phase, and where a
+launch's goes. ``serving_decode_wait_seconds`` observes every sleep of the
+scheduler on its condition (the 20 ms idle poll, the breaker's wait; a
+``decode::wait`` span each): its sum over a window is the share of it the
+loop was asleep. ``serving_decode_step_put_seconds`` and
+``serving_decode_step_call_seconds`` observe the two halves of every
+launch of the step program, from its start to the end of the feeds'
+``jax.device_put``s and from there to the executable's return: the
+``put_ns`` / ``call_ns`` of the ``decode::step`` span, which carries
+``launch=<n>`` (the value of ``serving_step_launches_total``), as does the
+``decode::step_fetch`` that lands that step.
+
+The KV block pool and the host tier, counted where it happens
+(``pool.py``): ``serving_pool_block_allocs_total`` blocks handed out,
+``serving_pool_evictions_total`` of them recycled a cached block,
+``serving_tier_writebacks_total`` evicted blocks the tier took;
+``serving_arena_read_bytes_total`` bytes of K/V arena brought to the host
+to spill rows (an eviction's write-back, a ``decode::writeback`` span; a
+parked session's ``decode::spill``), which are fetches like any other and
+so are in ``serving_fetched_bytes_total`` too.
 """
 
 from paddle_tpu.serving.metrics import ServingMetrics
 
-__all__ = ["DecodeMetrics", "TOKEN_BUCKETS"]
+__all__ = ["DecodeMetrics", "TOKEN_BUCKETS", "WAIT_BUCKETS",
+           "LAUNCH_BUCKETS"]
 
 # 10 ms wide from 50 ms to 500 ms, where a token's wait falls on the chip
 # (a decode step is ~110 ms, a first token a few of them), so a quantile
@@ -53,6 +75,20 @@ TOKEN_BUCKETS = (
     (0.001, 0.0025, 0.005, 0.01, 0.025)
     + tuple(round(0.05 + 0.01 * i, 2) for i in range(46))
     + (0.6, 0.75, 1.0, 1.5, 2.5, 5.0, 10.0, 25.0, 50.0)
+)
+
+# the scheduler's sleeps, 0.1 ms to 0.1 s: a poll that runs out takes just
+# over its 20 ms and has a bucket of its own, one cut short by a submit
+# falls anywhere under it
+WAIT_BUCKETS = (1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2, 1.5e-2, 2e-2,
+                2.5e-2, 5e-2, 0.1)
+
+# each half of a step's launch, 50 us to 50 ms, 0.1 ms wide from 0.5 to
+# 1.5 ms where both fall on the chip (0.9-1.2 ms)
+LAUNCH_BUCKETS = (
+    (5e-5, 1e-4, 2.5e-4)
+    + tuple(round(5e-4 + 1e-4 * i, 4) for i in range(11))
+    + (2e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2, 5e-2)
 )
 
 
@@ -119,6 +155,12 @@ class DecodeMetrics(ServingMetrics):
         # state-space layers a stepping slot updates, so neither is
         # counted here)
         "moe_assignments", "moe_held_assignments", "moe_touched_experts",
+        # the KV block pool and the host tier (counted by pool.py through
+        # the sink the engine hands it): blocks handed out, those of them
+        # that recycled a cached block, evicted blocks the tier took; and
+        # the bytes of arena brought to the host to spill rows
+        "pool_block_allocs", "pool_evictions", "tier_writebacks",
+        "arena_read_bytes",
     )
 
     def __init__(self, engine_label=None, registry=None):
@@ -145,8 +187,24 @@ class DecodeMetrics(ServingMetrics):
             "mean gap between one request's tokens", labels=labels,
             buckets=TOKEN_BUCKETS,
         )
+        self._wait = self._registry.histogram(
+            "serving_decode_wait_seconds",
+            "one sleep of the scheduler on its condition", labels=labels,
+            buckets=WAIT_BUCKETS,
+        )
+        self._step_put = self._registry.histogram(
+            "serving_decode_step_put_seconds",
+            "a step launch up to the end of its feeds' device_puts",
+            labels=labels, buckets=LAUNCH_BUCKETS,
+        )
+        self._step_call = self._registry.histogram(
+            "serving_decode_step_call_seconds",
+            "a step launch inside the executable's call", labels=labels,
+            buckets=LAUNCH_BUCKETS,
+        )
         for h in (self._step, self._prefill, self._chunk,
-                  self._first_token, self._inter_token):
+                  self._first_token, self._inter_token, self._wait,
+                  self._step_put, self._step_call):
             h.reset()
 
     def observe_step(self, active_slots, new_tokens, seconds):
@@ -163,6 +221,13 @@ class DecodeMetrics(ServingMetrics):
         self.incr("chunk_runs")
         self.incr("chunk_tokens", tokens)
         self._chunk.observe(seconds)
+
+    def observe_wait(self, seconds):
+        self._wait.observe(seconds)
+
+    def observe_step_launch(self, put_seconds, call_seconds):
+        self._step_put.observe(put_seconds)
+        self._step_call.observe(call_seconds)
 
     def count_launch(self, kind, fed_bytes):
         """One launch of the ``kind`` program that put ``fed_bytes`` of
@@ -209,6 +274,9 @@ class DecodeMetrics(ServingMetrics):
         out.update(self._chunk.snapshot("chunk_prefill"))
         out.update(self._first_token.snapshot("first_token"))
         out.update(self._inter_token.snapshot("inter_token"))
+        out.update(self._wait.snapshot("decode_wait"))
+        out.update(self._step_put.snapshot("step_put"))
+        out.update(self._step_call.snapshot("step_call"))
         if extra:
             out.update(extra)
         return out

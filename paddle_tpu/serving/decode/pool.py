@@ -310,9 +310,13 @@ class BlockPool:
     """Block-granular allocator over the flat row arena + the radix
     prefix index. All allocation calls happen on the entry's scheduler
     thread; ``stats()`` may be read from any thread (the lock makes the
-    counters coherent)."""
+    counters coherent). ``count(name, n=1)`` is the owner's sink for what
+    the pool alone sees happen (``DecodeMetrics.incr``: a block handed
+    out, an eviction, a write-back the tier took), called at the line
+    where it happens, under ``decode.blocks``; the attributes below move
+    with it and ``stats()`` reads them."""
 
-    def __init__(self, num_blocks, block_size):
+    def __init__(self, num_blocks, block_size, count=None):
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self._blocks = [Block(i, i * self.block_size)
@@ -323,8 +327,10 @@ class BlockPool:
         self._lock = lockdep.named_lock("decode.blocks")
         self._tier = None              # HostKVTier (attach_tier)
         self._tier_read = None         # block -> per-layer [(k, v)] rows
+        self._count = count or (lambda name, n=1: None)
         self.cow_copies = 0
-        self.evictions = 0
+        self.allocs = 0                # blocks handed out
+        self.evictions = 0             # of those, recycled a cached block
         self.radix_hits = 0            # shared-block references served
         self.forks = 0                 # beam forks served (refcount++ paths)
         self.tier_writebacks = 0       # evicted blocks spilled to host
@@ -358,10 +364,13 @@ class BlockPool:
             self._blocks[bid].reset()
             self._free.append(bid)
             self.evictions += 1
+            self._count("pool_evictions")
         bid = self._free.pop()
         b = self._blocks[bid]
         b.reset()
         b.refcount = 1
+        self.allocs += 1
+        self._count("pool_block_allocs")
         return b
 
     def _writeback_locked(self, b):
@@ -380,6 +389,7 @@ class BlockPool:
         if self._tier.put("blk:" + b.chain_hash, rows, b.size_used,
                           tokens=b.tokens):
             self.tier_writebacks += 1
+            self._count("tier_writebacks")
 
     def acquire_rows(self, n_rows):
         """Fresh PRIVATE blocks covering ``n_rows`` positions with
@@ -634,6 +644,7 @@ class BlockPool:
                 "dedup_ratio": logical / float(max(physical, 1)),
                 "cow_copies": self.cow_copies,
                 "forks": self.forks,
+                "allocs": self.allocs,
                 "evictions": self.evictions,
                 "radix_hits": self.radix_hits,
                 "radix_entries": len(self._radix),

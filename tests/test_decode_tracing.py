@@ -13,6 +13,12 @@ What is pinned here about the order of an iteration is pinned in both
 orders, as cases: ``ahead`` is the built model, ``serial`` the same
 programs without ``token_fetch``, whose every step fetches its logits and
 lands before anything else is launched.
+
+Since ISSUE 38 the loop's sleeps are ``decode::wait`` spans observed in
+``serving_decode_wait_seconds``, the two halves of a step's launch are
+observed in ``serving_decode_step_put_seconds`` / ``..._call_seconds``
+whether tracing is on or off, and a step's launch and its landing carry
+one ``launch`` number.
 """
 
 import time
@@ -40,6 +46,9 @@ PHASES = {"decode::admit", "decode::prefill", "decode::prefill_fetch",
           "decode::inject", "decode::chunk", "decode::chunk_fetch",
           "decode::feeds", "decode::step", "decode::step_fetch",
           "decode::sample"}
+# the loop's sleep: in the ``ahead`` order the drain that delivers the last
+# tokens is followed, in the same iteration, by the idle poll
+WAIT = "decode::wait"
 LAUNCHES = ("decode::step", "decode::prefill", "decode::chunk",
             "decode::inject")
 WITH_REQUEST = {"decode::admit", "decode::prefill", "decode::prefill_fetch",
@@ -145,25 +154,37 @@ def test_tracing_off_an_iteration_reads_the_clock_as_the_parent_did_plus_one_per
     """What the engine reads off ``time`` with tracing off: per decode step
     three (start, the tokens' ``now``, end), per one-shot prefill and per
     chunk two (start, end), per admitted request its dispatch time and —
-    the one read this instrumentation adds — its first token's stamp. A
-    step that is left in flight and then DRAINED is timed in two pieces,
-    the body that launched it (start, end) and the drain (start, ``now``,
-    end): two reads more for every launch that was not ahead of a fetch.
+    the one read ISSUE 25's instrumentation added — its first token's
+    stamp. A step that is left in flight and then DRAINED is timed in two
+    pieces, the body that launched it (start, end) and the drain (start,
+    ``now``, end): two reads more for every launch that was not ahead of
+    a fetch. Since ISSUE 38 the two always-on measurements add theirs:
+    three per launch of the step program (its start, the end of the
+    feeds' puts, the call's return: with tracing ON the span's own pair
+    serves and the engine reads none) and two per sleep of the loop.
+    Two idle iterations at the end make sure there are sleeps to count.
     The tracer's own clock is never read."""
     engine_clock, tracer_clock = _CountingClock(), _CountingClock()
     monkeypatch.setattr(engine_mod, "time", engine_clock)
     monkeypatch.setattr(tracer_mod, "time", tracer_clock)
     entry, resps = _serve("trc_clock", traced=False, order=order)
+    for _ in range(2):
+        entry._iterate()
     m = entry.metrics
     assert m.count("brownout_transitions") == 0
     admitted = len(resps)
     steps, ahead = m.count("decode_steps"), m.count("decode_steps_ahead")
     assert (ahead > 0) == (order == "ahead")
     drained = steps - ahead if order == "ahead" else 0
+    st = entry.stats()
+    launches, waits = m.count("step_launches"), st["decode_wait_count"]
+    assert launches == steps == st["step_put_count"] == st["step_call_count"]
+    assert waits == (3 if order == "ahead" else 2)
     # one more per request: GenerationRequest's submit_time
     assert engine_clock.reads == (
         3 * steps + 2 * drained + 2 * m.count("prefills")
-        + 2 * m.count("chunk_runs") + 2 * admitted + admitted)
+        + 2 * m.count("chunk_runs") + 2 * admitted + admitted
+        + 3 * launches + 2 * waits)
     assert tracer_clock.reads == 0
 
 
@@ -227,7 +248,8 @@ def test_every_phase_lies_inside_one_iteration(traced_run):
     assert iterations[0]["args"]["active"] == 0
     assert iterations[1]["args"]["active"] == len(PROMPT_LENS)
     phases = [s for s in spans if s["name"] != "decode::iterate"]
-    assert {s["name"] for s in phases} == PHASES
+    assert {s["name"] for s in phases} == PHASES | (
+        {WAIT} if entry.order == "ahead" else set())
     for s in phases:
         holders = [it for it in iterations if _inside(s, it)]
         assert len(holders) == 1, s
@@ -344,6 +366,136 @@ def test_step_spans_carry_their_sizes(traced_run):
         entry.metrics.count("generated_tokens")
     assert all(1 <= s["args"]["active"] <= m.slots for s in feeds
                if s["args"])
+
+
+def test_the_launch_histograms_hold_what_the_step_spans_say(traced_run):
+    """Every launch of the step program is observed once in each of the
+    two histograms, and with tracing on by the span's own pair of clock
+    reads: the sums agree to within a microsecond a launch."""
+    entry, _resps, spans = traced_run
+    steps = [s for s in spans if s["name"] == "decode::step"]
+    st = entry.stats()
+    assert st["step_put_count"] == st["step_call_count"] == len(steps) \
+        == entry.metrics.count("step_launches")
+    for half in ("put", "call"):
+        in_spans = sum(s["args"][half + "_ns"] for s in steps) * 1e-9
+        observed = st[f"step_{half}_count"] * st[f"step_{half}_avg_s"]
+        assert abs(observed - in_spans) <= 1e-6 * len(steps), half
+    # the other programs' halves stay arguments of their spans
+    assert all("put_ns" in s["args"] for s in spans
+               if s["name"] in LAUNCHES)
+
+
+def test_a_step_fetch_carries_the_launch_of_one_earlier_step(traced_run):
+    """``launch`` is the running number of step launches on the
+    ``decode::step`` span and on the ``decode::step_fetch`` that lands
+    that step: one fetch a launch, after it, whether the fetch comes under
+    the next launch (``ahead``) or in the launching body or a drain."""
+    entry, _resps, spans = traced_run
+    steps = [s for s in spans if s["name"] == "decode::step"]
+    fetches = [s for s in spans if s["name"] == "decode::step_fetch"]
+    numbers = [s["args"]["launch"] for s in steps]
+    assert numbers == list(range(1, len(steps) + 1))
+    assert numbers[-1] == entry.metrics.count("step_launches")
+    assert sorted(f["args"]["launch"] for f in fetches) == numbers
+    by_number = {s["args"]["launch"]: s for s in steps}
+    later = 0
+    for f in fetches:
+        step = by_number[f["args"]["launch"]]
+        assert step["start_ns"] + step["dur_ns"] <= f["start_ns"]
+        # launches that started between this step's and its landing
+        between = [s for s in steps
+                   if step["start_ns"] < s["start_ns"] < f["start_ns"]]
+        assert len(between) <= 1            # depth one
+        later += len(between)
+    assert later == entry.metrics.count("decode_steps_ahead")
+    assert (later > 0) == (entry.order == "ahead")
+
+
+# -- the loop's sleeps ------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def idle_run():
+    """Three iterations with nothing to do (the idle poll), two under an
+    open breaker with two requests queued (the breaker's wait), then the
+    requests served once the breaker is closed again."""
+    obs.get_tracer().clear()
+    engine = GenerationEngine(queue_depth=32, breaker_threshold=1,
+                              breaker_cooldown_s=0.15)
+    entry = engine.register_model(_model("trc_idle"))
+    obs.enable_tracing()
+    try:
+        for _ in range(3):
+            entry._iterate()
+        resps = [engine.submit(p, max_new_tokens=MAX_NEW)
+                 for p in _prompts()[:2]]
+        entry._breaker.record_failure()
+        assert entry._breaker.state == "open"
+        for _ in range(2):
+            entry._iterate()
+        for _ in range(400):
+            if all(r.done() for r in resps):
+                break
+            entry._iterate()
+    finally:
+        obs.disable_tracing()
+    spans = obs.get_tracer().spans()
+    obs.get_tracer().clear()
+    assert all(r.done() and r.error() is None for r in resps)
+    return entry, spans
+
+
+def test_every_wait_lies_inside_one_iteration_and_says_why(idle_run):
+    _entry, spans = idle_run
+    iterations = [s for s in spans if s["name"] == "decode::iterate"]
+    waits = [s for s in spans if s["name"] == WAIT]
+    assert len(waits) >= 5
+    for w in waits:
+        assert len([it for it in iterations if _inside(w, it)]) == 1
+        # nothing opens inside a wait: the loop is asleep
+        assert not [c for c in spans if c is not w and _inside(c, w)
+                    and c["depth"] > w["depth"]]
+        assert set(w["args"]) == {"why", "queued", "parked", "pending"}
+    assert [w["args"]["why"] for w in waits[:5]] == \
+        ["idle"] * 3 + ["breaker"] * 2
+    # a wait taken with requests queued says so
+    assert [w["args"]["queued"] for w in waits[:5]] == [0, 0, 0, 2, 2]
+    assert {(w["args"]["parked"], w["args"]["pending"])
+            for w in waits} == {(0, 0)}
+    # the idle poll is 20 ms; the breaker's waits are 0.1 s at most each
+    # and together the 0.15 s of its cooldown
+    assert all(15e6 < w["dur_ns"] < 500e6 for w in waits[:3])
+    assert 0.1e9 < sum(w["dur_ns"] for w in waits[3:5]) < 1e9
+
+
+def test_the_wait_histogram_sums_the_wait_spans(idle_run):
+    """``serving_decode_wait_seconds`` observes each sleep from inside its
+    span: the same sleeps, the sum a few clock reads and one ``set`` a
+    wait under the spans' total."""
+    entry, spans = idle_run
+    waits = [s for s in spans if s["name"] == WAIT]
+    st = entry.stats()
+    assert st["decode_wait_count"] == len(waits)
+    observed = st["decode_wait_count"] * st["decode_wait_avg_s"]
+    in_spans = sum(w["dur_ns"] for w in waits) * 1e-9
+    assert observed <= in_spans
+    assert in_spans - observed < 2e-3 * len(waits)
+    assert observed > 0.9 * in_spans
+
+
+def test_the_new_histograms_are_cut_where_their_values_fall():
+    from paddle_tpu.serving.decode.metrics import (LAUNCH_BUCKETS,
+                                                   WAIT_BUCKETS)
+
+    assert (WAIT_BUCKETS[0], WAIT_BUCKETS[-1]) == (1e-4, 0.1)
+    assert (LAUNCH_BUCKETS[0], LAUNCH_BUCKETS[-1]) == (5e-5, 5e-2)
+    for buckets in (WAIT_BUCKETS, LAUNCH_BUCKETS):
+        assert list(buckets) == sorted(set(buckets))
+    # a poll that runs out (20 ms and a little) has a bucket of its own
+    assert 0.02 in WAIT_BUCKETS and 0.025 in WAIT_BUCKETS
+    # both halves of a launch are 0.9-1.2 ms on the chip: 0.1 ms apart there
+    inner = [b for b in LAUNCH_BUCKETS if 5e-4 <= b <= 1.5e-3]
+    assert max(b - a for a, b in zip(inner, inner[1:])) < 1.01e-4
 
 
 # -- a time stamp for every token ---------------------------------------------------
