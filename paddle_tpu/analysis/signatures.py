@@ -79,6 +79,10 @@ _SIGNATURES = {
         ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Bias": 3},
         dtype_family={"Q": "float", "Bias": "float", "Rows": "int"},
     ),
+    "paged_step_feeds": OpSignature(
+        ranks={"Packed": 2, "Token": 2},
+        dtype_family={"Packed": "int", "Token": "int"},
+    ),
     "chunk_paged_attention": OpSignature(
         same_dtype=[("Q", "KArena", "VArena")],
         ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Bias": 3},

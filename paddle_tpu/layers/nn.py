@@ -31,6 +31,7 @@ __all__ = [
     "cached_attention",
     "paged_attention",
     "chunk_paged_attention",
+    "paged_step_feeds",
     "rms_norm",
     "relu2",
     "moe_routed_experts",
@@ -738,6 +739,28 @@ def paged_attention(q, k_arena, v_arena, rows, attn_bias, seqs, length,
         attrs,
     )
     return out
+
+
+def paged_step_feeds(packed, token, length, block_size, name=None):
+    """A paged decode step's per-slot inputs, built on the device from the
+    ONE ``[S, 4 + ceil(length / block_size)]`` int32 array the host puts a
+    step (ops/nn.py ``paged_step_feeds``: a slot's token or -1, position,
+    attention length, write row, then its block table) and ``token``, the
+    ``[S, 1]`` tokens a slot with -1 takes (the step before's own output,
+    still on the device). Returns ``(token [S, 1], position [S, 1], bias
+    [S, 1, L] float32, rows [S * L], write_rows [S])``: what
+    ``embedding``, ``paged_attention`` and ``block_scatter_write`` take."""
+    helper = LayerHelper("paged_step_feeds", name=name)
+    outs = {slot: helper.create_variable_for_type_inference(
+        "float32" if slot == "Bias" else packed.dtype, stop_gradient=True)
+        for slot in ("TokenOut", "Position", "Bias", "Rows", "WriteRows")}
+    helper.append_op(
+        "paged_step_feeds",
+        {"Packed": [packed.name], "Token": [token.name]},
+        {slot: [v.name] for slot, v in outs.items()},
+        {"length": int(length), "block_size": int(block_size)},
+    )
+    return tuple(outs.values())
 
 
 def chunk_paged_attention(q, k_arena, v_arena, rows, attn_bias, kv_heads,

@@ -615,6 +615,36 @@ OpRegistry.register(
 )
 
 
+@register_op("paged_step_feeds", nondiff_inputs=("Packed", "Token"))
+def _paged_step_feeds(ins, attrs):
+    """What a paged decode step reads of its slots, from the ONE integer
+    array the host puts a step: ``Packed`` ``[S, 4 + T]`` holds a slot's
+    token (negative: take ``Token``'s, the device array of the step
+    before), its position, its attention length (0: the slot does not
+    step), its write row, then its block table (block ids, T =
+    ``ceil(length / block_size)``). Gives the ``[S, 1]`` tokens and
+    positions, the additive ``[S, 1, L]`` bias (0.0 below a slot's length,
+    -1e9 from it on), the ``[S * L]`` row map (position ``p`` reads row
+    ``table[p // block_size] * block_size + p % block_size``) and the
+    ``[S]`` write rows."""
+    packed, token = first(ins, "Packed"), first(ins, "Token")
+    L, bs = int(attrs["length"]), int(attrs["block_size"])
+    S = packed.shape[0]
+    head = packed[:, :4, None]                                  # [S, 4, 1]
+    table = packed[:, 4:]
+    own = head[:, 0]
+    within = jnp.arange(bs, dtype=packed.dtype)
+    rows = (table[:, :, None] * bs + within).reshape(S, -1)[:, :L]
+    open_ = jnp.arange(L, dtype=packed.dtype) < head[:, 2]      # [S, L]
+    return {
+        "TokenOut": [jnp.where(own < 0, token, own)],
+        "Position": [head[:, 1]],
+        "Bias": [jnp.where(open_, 0.0, -1e9).astype(jnp.float32)[:, None]],
+        "Rows": [rows.reshape(-1)],
+        "WriteRows": [packed[:, 3]],
+    }
+
+
 @register_op("chunk_paged_attention", nondiff_inputs=("Rows", "Bias"))
 def _chunk_paged_attention(ins, attrs):
     """A prompt chunk's queries ``[C, heads * D]`` over one sequence's

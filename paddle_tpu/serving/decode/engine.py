@@ -10,8 +10,9 @@ occupancy tracks offered load instead of the slowest batchmate.
 
 Storage is a **block-granular paged arena** (vLLM's PagedAttention,
 SOSP'23): KV rows live in fixed-size blocks handed out by
-``pool.BlockPool``; the compiled programs see only flat row-index feeds,
-so HBM scales with USED tokens, prompts sharing a prefix share PHYSICAL
+``pool.BlockPool``; the compiled programs see only flat row-index feeds
+(the decode step: one packed integer array, below), so HBM scales with
+USED tokens, prompts sharing a prefix share PHYSICAL
 blocks through the radix index (copy-on-write at divergence), and the
 arena is sized against ``analysis/memory.py``'s pre-compile HBM gate
 instead of reserving a dense ``slots x max_len`` grid.
@@ -32,7 +33,13 @@ alone):
   iteration launches its successor, fed those tokens as the device
   array they are, BEFORE it fetches them: depth one, decided per
   iteration from the scheduler's own state (``_iterate``), the same
-  tokens in either order.
+  tokens in either order. From the host a step takes ONE array, put
+  once (``dec_step``, int32 ``[S, 4 + blocks per slot]``: each stepping
+  slot's token or -1, cursor, attention length, write row and block
+  table); the step program makes its positions, causal bias, row map
+  and write rows of it on the device (``paged_step_feeds``). A put costs
+  this host ~0.25 ms whatever its size (PERF.md §6, PR 39), so the
+  count of puts, not their bytes, is what a step's launch pays.
 * **one-shot prefill** — a prompt the chunk budget covers (every prompt
   of a model without a chunk program) runs the whole-prompt prefill
   program once, and its admission moves no bulk bytes across the host
@@ -94,7 +101,8 @@ Measured from inside (all of it nothing while tracing is off): one
 ``decode::admit`` > ``decode::prefill`` / ``prefill_fetch`` / ``inject``,
 ``decode::chunk`` / ``chunk_fetch``, ``decode::feeds`` / ``step`` /
 ``step_fetch`` / ``sample`` — each carrying its request's id where it has
-one; a launch span also says how long the host spent inside
+one; a launch span also says how many host arrays it put (``puts``: one
+a decode step), their ``bytes``, how long the host spent inside
 ``jax.device_put`` and inside the executable's call, ``decode::step``
 whether it was launched ahead of the previous step's fetch (``ahead``),
 and a ``decode::step_fetch`` made with nothing launched over it, why
@@ -238,18 +246,21 @@ class _Slot:
     "spec" (speculative verify cycles — holds no TARGET arena blocks),
     or "beam" (one live beam hypothesis; its group coordinates via
     ``beam``). ``blocks`` is the slot's block table; ``row_map[p]`` the
-    physical arena row of position ``p`` (the device half of the
-    table). ``d_*`` is the draft-KV footprint of a speculative slot:
-    its slot/blocks/row-map ON THE DRAFT ENTRY plus ``d_cursor``, the
+    physical arena row of position ``p`` (what the chunk and inject
+    programs are fed) and ``table`` the blocks' ids (the slot's row of
+    the decode step's one feed, which makes the row map of it on the
+    device). ``d_*`` is the draft-KV footprint of a speculative slot:
+    its slot/blocks/row-map/table ON THE DRAFT ENTRY plus ``d_cursor``, the
     next draft arena position without a committed KV row. ``ahead``
     counts the slot's tokens that a launched decode step has produced on
     the device and the host has not read yet: ``cursor`` already counts
     their rows, ``generated`` and ``last_token`` do not hold them."""
 
     __slots__ = ("request", "mode", "cursor", "last_token", "generated",
-                 "blocks", "row_map", "plen", "done", "shared_len", "toks",
-                 "sampling", "grammar", "beam", "score", "seq", "ahead",
-                 "d_entry", "d_slot", "d_blocks", "d_row_map", "d_cursor")
+                 "blocks", "row_map", "table", "plen", "done", "shared_len",
+                 "toks", "sampling", "grammar", "beam", "score", "seq",
+                 "ahead", "d_entry", "d_slot", "d_blocks", "d_row_map",
+                 "d_table", "d_cursor")
 
     def __init__(self, request, mode="decode"):
         self.request = request
@@ -259,6 +270,7 @@ class _Slot:
         self.generated = []
         self.blocks = []
         self.row_map = None
+        self.table = None
         self.seq = 0            # admission order (default victim policy)
         self.ahead = 0          # tokens launched, not yet on the host
         self.plen = len(request.prompt)
@@ -273,6 +285,7 @@ class _Slot:
         self.d_slot = None
         self.d_blocks = None
         self.d_row_map = None
+        self.d_table = None
         self.d_cursor = 0
 
 
@@ -356,6 +369,22 @@ def _pick_row(logits, index):
 
     return jax.lax.dynamic_index_in_dim(logits[0], index, axis=0,
                                         keepdims=False)
+
+
+def _map_blocks(m, blocks, row_map):
+    """``(row_map, table)`` of a slot of model ``m`` that holds ``blocks``:
+    the ``[max_len]`` int64 arena row of every position the blocks cover,
+    written into ``row_map`` (made when None; what lies past the blocks
+    is left as it was, and is never read), and the blocks' ids
+    (`DecodeModel.block_table`)."""
+    bs = m.block_size
+    if row_map is None:
+        row_map = np.zeros(m.max_len, dtype="int64")
+    for i, b in enumerate(blocks):
+        lo = i * bs
+        hi = min(lo + bs, m.max_len)
+        row_map[lo:hi] = b.row0 + np.arange(hi - lo)
+    return row_map, m.block_table(blocks)
 
 
 def _by_layer(live):
@@ -496,12 +525,18 @@ class _ModelEntry:
         copy-on-write keep; P is ``chunk_tokens`` where a chunk program
         takes every longer prompt, else ``max_len``. ``_causal_bias`` is
         the prefill program's ``[1, L, L]`` bias, the same for every
-        prompt. Shapes are the model's contract, not a relaunch's: the
-        rebuilt programs are content-identical, so these outlive it."""
+        prompt. ``_no_tokens`` is the step program's ``dec_token`` on a
+        step whose tokens come from the host (they ride in ``dec_step``;
+        a launched-ahead step's is the output of the step before).
+        Shapes are the model's contract, not a relaunch's: the rebuilt
+        programs are content-identical, so these outlive it."""
         import jax
 
         m = self._model
         L, V = m.max_len, m.vocab_size
+        self._no_tokens = jax.device_put(
+            np.zeros((m.slots, 1), jax.dtypes.canonicalize_dtype(np.int64)),
+            self._engine.device)
         chunked = bool(m.chunk_tokens) and m.chunk_program is not None
 
         def sds(*shape, dtype=np.float32):
@@ -531,7 +566,8 @@ class _ModelEntry:
         persistables (the arenas — donated, updated in place on device)
         re-enter the scope for the next call. ``span`` is the caller's
         live launch span, or None while tracing is off: it is told the
-        bytes fed and the nanoseconds the host spent inside
+        bytes fed, in how many host arrays (``puts``: one a decode step,
+        its ``dec_step``), and the nanoseconds the host spent inside
         ``jax.device_put`` (from the span's opening) and inside the
         executable's call — two clock reads, no child span, so the device
         module still belongs to the caller's span. The step program's two
@@ -550,13 +586,14 @@ class _ModelEntry:
         dev = self._engine.device
         elapsed_ns = (span.elapsed_ns if span is not None
                       else _stopwatch_ns() if kind == "step" else None)
-        fed = 0
+        fed = puts = 0
         feed_vals = []
         for n in entry.feed_names:
             a = feeds[n]
             if not isinstance(a, jax.Array):
                 a = np.ascontiguousarray(a)
                 fed += a.nbytes
+                puts += 1
                 a = jax.device_put(a, dev)
             feed_vals.append(a)
         if elapsed_ns is not None:
@@ -568,7 +605,8 @@ class _ModelEntry:
         if elapsed_ns is not None:
             call_ns = elapsed_ns() - put_ns
             if span is not None:
-                span.set(bytes=fed, put_ns=put_ns, call_ns=call_ns)
+                span.set(bytes=fed, puts=puts, put_ns=put_ns,
+                         call_ns=call_ns)
             if kind == "step":
                 self._metrics.observe_step_launch(put_ns * 1e-9,
                                                   call_ns * 1e-9)
@@ -913,14 +951,8 @@ class _ModelEntry:
         return b.row0 + p % self._model.block_size
 
     def _rebuild_row_map(self, st):
-        m = self._model
-        bs = m.block_size
-        if st.row_map is None:
-            st.row_map = np.zeros(m.max_len, dtype="int64")
-        for i, b in enumerate(st.blocks):
-            lo = i * bs
-            hi = min(lo + bs, m.max_len)
-            st.row_map[lo:hi] = b.row0 + np.arange(hi - lo)
+        st.row_map, st.table = _map_blocks(self._model, st.blocks,
+                                           st.row_map)
 
     def _acquire_blocks(self, req):
         """Acquire the prompt's block chain, parking victims instead of
@@ -1807,14 +1839,8 @@ class _ModelEntry:
             self._metrics.incr("spec_draft_kv_fallbacks")
 
     def _rebuild_draft_row_map(self, draft, st):
-        dm = draft.model
-        bs = dm.block_size
-        if st.d_row_map is None:
-            st.d_row_map = np.zeros(dm.max_len, dtype="int64")
-        for i, b in enumerate(st.d_blocks):
-            lo = i * bs
-            hi = min(lo + bs, dm.max_len)
-            st.d_row_map[lo:hi] = b.row0 + np.arange(hi - lo)
+        st.d_row_map, st.d_table = _map_blocks(draft.model, st.d_blocks,
+                                               st.d_row_map)
 
     def _release_draft(self, st):
         """Return a spec slot's draft-side footprint (caller holds the
@@ -1892,27 +1918,17 @@ class _ModelEntry:
             st.d_blocks = blocks
             if _nb is not None:
                 self._rebuild_draft_row_map(draft, st)
-        S, L, R = dm.slots, dm.max_len, dm.rows
-        tok = np.zeros((S, 1), "int64")
-        pos = np.zeros((S, 1), "int64")
-        bias = np.full((S, 1, L), NEG_INF, "float32")
-        rows = np.zeros((S, L), "int64")
-        wrows = np.full((S,), R, dtype="int64")
-        s = st.d_slot
-        tok[s, 0] = int(token)
-        pos[s, 0] = p
-        bias[s, 0, :p + 1] = 0.0
-        rows[s] = st.d_row_map
+        step = dm.step_feed()
+        row = dm.rows       # write=False: the row is right already
         if write:
             b = st.d_blocks[p // dm.block_size]
-            wrows[s] = b.row0 + p % dm.block_size
-        feeds = {DecodeModel.DEC_TOKEN: tok, DecodeModel.DEC_POSITION: pos,
-                 DecodeModel.DEC_BIAS: bias,
-                 DecodeModel.DEC_ROWS: rows.reshape(-1),
-                 DecodeModel.DEC_WRITE_ROWS: wrows}
+            row = b.row0 + p % dm.block_size
+        dm.fill_step(step, st.d_slot, p, st.d_table, row, int(token))
+        feeds = {DecodeModel.DEC_STEP: step,
+                 DecodeModel.DEC_TOKEN: draft._no_tokens}
         if dm.logits_mask:
             feeds[DecodeModel.DEC_MASK] = np.zeros(
-                (S, 1, dm.vocab_size), "float32")
+                (dm.slots, 1, dm.vocab_size), "float32")
         try:
             with profiler.RecordEvent("decode::spec_draft_kv"):
                 fetches = draft._run("step", feeds)
@@ -1926,7 +1942,7 @@ class _ModelEntry:
         if write:
             draft._blocks.note_append(st.d_blocks[p // dm.block_size])
         self._metrics.incr("spec_draft_kv_steps")
-        return draft._fetch(fetches[0])[s, 0]
+        return draft._fetch(fetches[0])[st.d_slot, 0]
 
     # -- the decode iteration ---------------------------------------------
     def _arena_lost(self, why):
@@ -2373,22 +2389,24 @@ class _ModelEntry:
         """The decode step's feeds from the live slots: ``(feeds, active
         slot ids, beam groups with a live slot)``, or None when there is
         nothing to step (or the arena was lost making a cursor
-        writable). With a step in flight the cursors count it already, a
-        slot it finishes is left out, and the token feed is its
-        ``[S, 1]`` device output itself: rows of slots that do not step
-        are ignored as ever (their ``wrows`` is the sentinel, their
-        ``bias`` all ``NEG_INF``). That holds only if every stepping
+        writable). ONE host array, ``dec_step`` (`DecodeModel.step_feed`
+        / `fill_step`): a stepping slot's token, cursor, length, write
+        row and block table, ~70 integers; the step program makes the
+        bias and the row map of them on the device, and no ``[S, L]``
+        array is built here. ``dec_token`` is a device array either
+        way: zeros that nobody reads, or with a step in flight (the
+        cursors count it already, a slot it finishes is left out) that
+        step's ``[S, 1]`` output itself, every stepping slot's token -1:
+        rows of slots that do not step are ignored as ever (their write
+        row is the sentinel, their length 0, so their bias all
+        ``NEG_INF``). That holds only if every stepping
         slot has its token in that step and none has to be parked; else
         nothing is built and the reason to drain comes back as a string.
         What the loop has done before is done again unchanged after the
         drain: a block opened stays opened."""
         m = self._model
-        S, L, R = m.slots, m.max_len, m.rows
-        tok = np.zeros((S, 1), "int64")
-        pos = np.zeros((S, 1), "int64")
-        bias = np.full((S, 1, L), NEG_INF, "float32")
-        rows = np.zeros((S, L), "int64")
-        wrows = np.full((S,), R, dtype="int64")
+        S = m.slots
+        step = m.step_feed()
         dmask = (np.zeros((S, 1, m.vocab_size), "float32")
                  if m.logits_mask else None)
         active = []
@@ -2460,11 +2478,9 @@ class _ModelEntry:
                     groups.append(st.beam)
             else:
                 active.append(s)
-            tok[s, 0] = st.last_token
-            pos[s, 0] = st.cursor
-            bias[s, 0, :st.cursor + 1] = 0.0
-            rows[s] = st.row_map
-            wrows[s] = self._row_of(st, st.cursor)
+            m.fill_step(step, s, st.cursor, st.table,
+                        self._row_of(st, st.cursor),
+                        -1 if launched is not None else st.last_token)
             live_blocks += st.cursor // m.block_size + 1
             if dmask is not None and st.grammar is not None:
                 # the grammar's next-token constraint rides in as DATA —
@@ -2472,16 +2488,12 @@ class _ModelEntry:
                 dmask[s, 0] = st.grammar.mask()
         if not active and not groups:
             return None
-        self._metrics.observe_blocks(
-            live_blocks, S * -(-L // m.block_size))
-        feeds = {DecodeModel.DEC_TOKEN: tok, DecodeModel.DEC_POSITION: pos,
-                 DecodeModel.DEC_BIAS: bias,
-                 DecodeModel.DEC_ROWS: rows.reshape(-1),
-                 DecodeModel.DEC_WRITE_ROWS: wrows}
+        self._metrics.observe_blocks(live_blocks, S * m.blocks_per_slot)
+        feeds = {DecodeModel.DEC_STEP: step,
+                 DecodeModel.DEC_TOKEN: (self._no_tokens if launched is None
+                                         else launched.fetches[1])}
         if dmask is not None:
             feeds[DecodeModel.DEC_MASK] = dmask
-        if launched is not None:
-            feeds[DecodeModel.DEC_TOKEN] = launched.fetches[1]
         return feeds, active, groups
 
     def _sample(self, fetched, active, groups, now, tokens_only):

@@ -17,7 +17,9 @@ no inject program because nothing re-injects: a model with per-slot
 recurrent state is hosted without a prefix cache and without a host tier
 (``GenerationEngine.register_model`` refuses either), since a K/V row is a
 function of its token prefix alone and a recurrent state is not a thing
-the radix index or the tier can key.
+the radix index or the tier can key. The decode step takes the contract's
+one host feed (``dec_step``: model.py) and expands it on the device like
+every model's; its positions go unread.
 
 State of two kinds: per attention layer the paged ``[R, kv_heads * D]`` K
 and V arenas (``state_names``), per Mamba layer the per-SLOT convolution
@@ -277,11 +279,12 @@ def build_nemotron_h_model(
     # -- decode step: one token per slot at [S, 1] -----------------------
     decode = Program()
     with unique_name.guard(), program_guard(decode, startup):
-        tok = fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64")
-        fluid.data(DecodeModel.DEC_POSITION, [S, 1], dtype="int64")
-        bias = fluid.data(DecodeModel.DEC_BIAS, [S, 1, L], dtype="float32")
-        rows = fluid.data(DecodeModel.DEC_ROWS, [S * L], dtype="int64")
-        wrows = fluid.data(DecodeModel.DEC_WRITE_ROWS, [S], dtype="int64")
+        # no position encoding: the step's positions go unread
+        tok, _pos, bias, rows, wrows = fluid.layers.paged_step_feeds(
+            fluid.data(DecodeModel.DEC_STEP,
+                       [S, DecodeModel.STEP_TABLE + -(-L // BS)],
+                       dtype="int32"),
+            fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64"), L, BS)
 
         def attend_step(i, q, k, v):
             nk, nv = write(decode, i, wrows, k, v, 1)

@@ -4,18 +4,28 @@ A generation model is served through THREE (optionally FOUR) fixed-shape
 programs that share one scope (weights by name) and one **paged KV
 arena**: per layer, one flat persistable ``[R, H]`` row matrix per K and
 per V, where ``R = num_blocks * block_size``. Block tables live on the
-host (serving/decode/pool.py); programs see only **row-index feeds**, so
+host (serving/decode/pool.py); programs see only **row-index feeds** (the
+decode step: a block table it expands itself), so
 memory scales with *used* tokens while every compiled shape stays
 static:
 
-* **decode step** — the per-iteration hot path. ONE static shape: token
-  ``[S, 1]`` + position ``[S, 1]`` + attention bias ``[S, 1, L]`` + a
-  gather row map ``[S * L]`` (position ``p`` of slot ``s`` reads arena
-  row ``rows[s * L + p]``) + a scatter row ``[S]`` naming where each
-  slot's new K/V row lands (``R`` = "write nowhere", dropped — retired
-  slots are bit-invisible, admitted slots join mid-flight, and the
-  compiled executable never sees the batch change). Arenas are DONATED
-  through core/lowering.py: the scatter is an in-place device update.
+* **decode step** — the per-iteration hot path. ONE static shape and,
+  from the host, ONE small feed: ``dec_step``, int32
+  ``[S, 4 + ceil(L / block_size)]`` — a slot's token (-1: the one in
+  ``dec_token``), its position, its attention length (position + 1; 0 for
+  a slot that does not step), its write row (``R`` = "write nowhere",
+  dropped — retired slots are bit-invisible, admitted slots join
+  mid-flight, and the compiled executable never sees the batch change),
+  then its block table (block ids, 0 past the last block).
+  ``dec_token`` ``[S, 1]`` is the tokens of the slots that carry -1: the
+  step before's own output, handed over on the device. The program's
+  first op (``paged_step_feeds``) makes of the two what the layers read:
+  token and position ``[S, 1]``, the attention bias ``[S, 1, L]`` (0.0
+  below a slot's length, ``-1e9`` from it on), the gather row map
+  ``[S * L]`` (position ``p`` of slot ``s`` reads arena row
+  ``rows[s * L + p]`` = ``table[s, p // bs] * bs + p % bs``) and the
+  scatter rows ``[S]``. Arenas are DONATED through core/lowering.py: the
+  scatter is an in-place device update.
   Two outputs: the float32 logits ``[S, 1, V]`` and, chosen on the
   device from those same logits, ``next_token [S, 1]`` (their argmax
   over the vocabulary). The engine brings the tokens alone to the host
@@ -97,14 +107,18 @@ class DecodeModel:
     the program's own ``arg_max`` takes from them: the first index of
     each row's maximum, what ``np.argmax`` gives the host over the same
     row. A hand-built model without such a var leaves it None, and the
-    engine then fetches the logits on every step."""
+    engine then fetches the logits on every step.
+
+    The decode step's feeds: ``DEC_STEP`` (`step_feed`, `fill_step`,
+    `block_table`: the one array the host builds and puts a step),
+    ``DEC_TOKEN`` (``[S, 1]``, a device array: the step before's tokens
+    where ``DEC_STEP`` says -1) and, with ``logits_mask``, ``DEC_MASK``.
+    The program's ``paged_step_feeds`` op turns the first two into the
+    tokens, positions, bias, row map and write rows its layers read."""
 
     # feed-name contract (fixed; the engine builds these arrays)
     DEC_TOKEN = "dec_token"
-    DEC_POSITION = "dec_position"
-    DEC_BIAS = "dec_bias"
-    DEC_ROWS = "dec_rows"
-    DEC_WRITE_ROWS = "dec_write_rows"
+    DEC_STEP = "dec_step"
     DEC_MASK = "dec_mask"
     PRE_TOKENS = "pre_tokens"
     PRE_POSITIONS = "pre_positions"
@@ -116,6 +130,9 @@ class DecodeModel:
     CHU_ROWS = "chu_rows"
     CHU_WRITE_ROWS = "chu_write_rows"
     CHU_SLOT = "chu_slot"
+    # columns of a slot's row of ``dec_step``; its block table follows
+    STEP_TOKEN, STEP_POSITION, STEP_LENGTH, STEP_WRITE_ROW, STEP_TABLE = (
+        range(5))
 
     def __init__(self, *, decode_program, prefill_program, inject_program,
                  startup_program, slots, max_len, vocab_size, hidden,
@@ -175,6 +192,38 @@ class DecodeModel:
         """Physical arena rows: the paged pool's capacity in tokens."""
         return self.num_blocks * self.block_size
 
+    @property
+    def blocks_per_slot(self):
+        """Blocks a slot at ``max_len`` holds: its block table's width."""
+        return -(-self.max_len // self.block_size)
+
+    def step_feed(self):
+        """``dec_step`` of a step that no slot takes (every length 0,
+        every write row ``R``, every token left to ``dec_token``): the
+        engine fills the rows of the slots that step (`fill_step`)."""
+        feed = np.zeros((self.slots, self.STEP_TABLE + self.blocks_per_slot),
+                        "int32")
+        feed[:, self.STEP_TOKEN] = -1
+        feed[:, self.STEP_WRITE_ROW] = self.rows
+        return feed
+
+    def fill_step(self, feed, slot, position, table, write_row, token=-1):
+        """Slot ``slot`` steps at ``position`` over the blocks ``table``
+        names (an int array of `blocks_per_slot` block ids), attending to
+        positions ``<= position``. Its new K/V row lands at ``write_row``
+        (``rows``: nowhere); ``token`` is fed from the host, or -1 where
+        ``dec_token`` holds it on the device."""
+        feed[slot, :self.STEP_TABLE] = (token, position, position + 1,
+                                        write_row)
+        feed[slot, self.STEP_TABLE:] = table
+
+    def block_table(self, blocks):
+        """The ``[blocks_per_slot]`` block ids of a slot's block list (its
+        row of ``dec_step``'s table; 0 past the last block)."""
+        table = np.zeros(self.blocks_per_slot, "int32")
+        table[:len(blocks)] = [b.row0 // self.block_size for b in blocks]
+        return table
+
     def arena_bytes(self):
         """Exact bytes of the model's state on the device: the paged KV
         pool (2 arenas x layers x ``[R, kv_width]`` of ``kv_dtype``) and
@@ -197,13 +246,11 @@ class DecodeModel:
 
     # -- feed signatures (ordered like each program's feed list) ---------
     def decode_feed_sig(self):
-        s, l = self.slots, self.max_len
+        s = self.slots
         sig = [
             (self.DEC_TOKEN, (s, 1), "int64"),
-            (self.DEC_POSITION, (s, 1), "int64"),
-            (self.DEC_BIAS, (s, 1, l), "float32"),
-            (self.DEC_ROWS, (s * l,), "int64"),
-            (self.DEC_WRITE_ROWS, (s,), "int64"),
+            (self.DEC_STEP, (s, self.STEP_TABLE + self.blocks_per_slot),
+             "int32"),
         ]
         if self.logits_mask:
             # grammar-constrained decode: per-step [S, 1, V] additive
@@ -307,8 +354,9 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
     beams or mask on the host. Prefill, inject and chunk programs have
     no such op.
 
-    The decode step's attention is ONE ``paged_attention`` op — the
-    row-index feeds and the block size enter the op directly. Its
+    The decode step's attention is ONE ``paged_attention`` op — the row
+    map and bias that ``paged_step_feeds`` makes of the step's one host
+    feed, and the block size, enter the op directly. Its
     reference lowering is the gather+attention composite
     (``paged_attention_composite``: the CPU path and the ``off`` path);
     on a TPU the blocked kernel of kernels/attention.py serves it,
@@ -390,11 +438,10 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
     # -- decode step: one token per slot at [S, 1], paged arena ----------
     decode = Program()
     with unique_name.guard(), program_guard(decode, startup):
-        tok = fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64")
-        pos = fluid.data(DecodeModel.DEC_POSITION, [S, 1], dtype="int64")
-        bias = fluid.data(DecodeModel.DEC_BIAS, [S, 1, L], dtype="float32")
-        rows = fluid.data(DecodeModel.DEC_ROWS, [S * L], dtype="int64")
-        wrows = fluid.data(DecodeModel.DEC_WRITE_ROWS, [S], dtype="int64")
+        tok, pos, bias, rows, wrows = fluid.layers.paged_step_feeds(
+            fluid.data(DecodeModel.DEC_STEP,
+                       [S, DecodeModel.STEP_TABLE + per_slot], dtype="int32"),
+            fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64"), L, BS)
         lmask = (fluid.data(DecodeModel.DEC_MASK, [S, 1, V],
                             dtype="float32") if logits_mask else None)
         h = embed(tok, pos)

@@ -260,7 +260,7 @@ def test_the_step_executable_fetches_logits_then_tokens():
     m = entry.model
     feeds = {n: np.zeros(shape, dtype)
              for n, shape, dtype in m.decode_feed_sig()}
-    feeds[DecodeModel.DEC_WRITE_ROWS][:] = m.rows
+    feeds[DecodeModel.DEC_STEP] = m.step_feed()      # nobody steps
     assert list(entry._entries["step"][0].fetch_names) \
         == [m.logits_fetch, m.token_fetch]
     fetches = entry._run("step", feeds)

@@ -566,15 +566,13 @@ def test_byte_counters_equal_the_nbytes_of_a_hand_built_step(token_feed):
     m = entry.model
     metrics = entry.metrics
     S, L = m.slots, m.max_len
+    # the step's one host array: a row of 4 + ceil(L / block) int32 a slot
     feeds = {
         DecodeModel.DEC_TOKEN: np.zeros((S, 1), "int64"),
-        DecodeModel.DEC_POSITION: np.zeros((S, 1), "int64"),
-        DecodeModel.DEC_BIAS: np.zeros((S, 1, L), "float32"),
-        DecodeModel.DEC_ROWS: np.zeros((S * L,), "int64"),
-        DecodeModel.DEC_WRITE_ROWS: np.full((S,), m.rows, dtype="int64"),
+        DecodeModel.DEC_STEP: m.step_feed(),
     }
     fed = sum(a.nbytes for a in feeds.values())
-    assert fed == 8 * S + 8 * S + 4 * S * L + 8 * S * L + 8 * S
+    assert fed == 8 * S + 4 * S * (4 + -(-L // m.block_size))
     if token_feed == "device":
         # a launched-ahead step's tokens are the previous step's output:
         # on the device already, so nothing of them is fed
