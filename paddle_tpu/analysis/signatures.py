@@ -91,8 +91,15 @@ _SIGNATURES = {
     "rms_norm": OpSignature(ranks={"Scale": 1}, dtype_family={"X": "float"}),
     "relu2": OpSignature(dtype_family={"X": "float"}),
     "moe_routed_experts": OpSignature(
-        same_dtype=[("WUp", "WDown")],
-        ranks={"GateW": 2, "SelectBias": 1, "WUp": 3, "WDown": 3},
+        same_dtype=[("WUp", "WDown", "WGate")],
+        ranks={"GateW": 2, "SelectBias": 1, "WUp": 3, "WDown": 3,
+               "WGate": 3},
+        dtype_family={"X": "float", "WriteRows": "int"},
+    ),
+    "rotary_embedding": OpSignature(
+        dtype_family={"X": "float", "Positions": "int"}),
+    "gated_short_conv": OpSignature(
+        ranks={"X": 3, "ConvState": 3, "ConvW": 2},
         dtype_family={"X": "float", "WriteRows": "int"},
     ),
     "mamba2_mixer": OpSignature(

@@ -262,12 +262,18 @@ def _tpu_cases_paged():
 
 
 def _parity_paged_grouped(rng):
-    """The head axis: 2 K/V heads of 128 a row, 4 query heads to each."""
+    """The head axis: 2 K/V heads of 128 a row, 4 query heads to each; then
+    8 heads of 64, two to a lane tile."""
+    for G, D in ((2, 128), (8, 64)):
+        _parity_paged_heads(rng, G, 4, D)
+
+
+def _parity_paged_heads(rng, G, per, D):
     import jax
 
     from paddle_tpu.kernels import attention as A
 
-    S, L, bs, G, per, D = 5, 64, 16, 2, 4, 128
+    S, L, bs = 5, 64, 16
     lengths = [1, 15, 17, 64, 0]
     _q, k, v, rows, bias = _paged_case(rng, S, L, bs, G * D, lengths)
     q = rng.randn(S, G * per * D).astype("float32")
@@ -283,22 +289,25 @@ def _parity_paged_grouped(rng):
 
 
 def _tpu_cases_paged_grouped():
-    """The hybrid serving cell's geometry (nemotron3_nano_30b_a3b: 32 slots
-    x 2,048 positions, block 16, rows of 2 K/V heads of 128 in bfloat16, 16
-    query heads to each)."""
+    """The hybrid serving cells' geometries in bfloat16 at block 16 and
+    2,048 positions: nemotron3_nano_30b_a3b (32 slots, rows of 2 K/V heads
+    of 128, 16 query heads to each) and lfm2_24b_a2b (128 slots, rows of 8
+    K/V heads of 64, 4 query heads to each: two heads a lane tile)."""
     from paddle_tpu.kernels import attention as A
 
-    S, L, bs, G, per, D = 32, 2048, 16, 2, 16, 128
-    R = S * L
+    def case(S, L, bs, G, per, D):
+        R = S * L
 
-    def fwd(q, k, v, rows, bias):
-        return A.paged_attention(q, k, v, rows, bias, S, L, bs,
-                                 1.0 / float(np.sqrt(D)), kv_heads=G)
+        def fwd(q, k, v, rows, bias):
+            return A.paged_attention(q, k, v, rows, bias, S, L, bs,
+                                     1.0 / float(np.sqrt(D)), kv_heads=G)
 
-    return [("s32_l2048_b16_g2x16x128_bf16", fwd, [
-        ((S, G * per * D), "bfloat16"), ((R, G * D), "bfloat16"),
-        ((R, G * D), "bfloat16"), ((S * L,), "int32"),
-        ((S, 1, L), "float32")])]
+        return (f"s{S}_l{L}_b{bs}_g{G}x{per}x{D}_bf16", fwd, [
+            ((S, G * per * D), "bfloat16"), ((R, G * D), "bfloat16"),
+            ((R, G * D), "bfloat16"), ((S * L,), "int32"),
+            ((S, 1, L), "float32")])
+
+    return [case(32, 2048, 16, 2, 16, 128), case(128, 2048, 16, 8, 4, 64)]
 
 
 def _parity_moe_experts(rng):
@@ -328,17 +337,30 @@ def _parity_moe_experts(rng):
             1e-5, 1e-5)
     assert untouched, "no case left a held expert untouched"
     assert not np.asarray(run(x, jnp.zeros((T, E)), w_up, w_down)).any()
+    # gated experts: a third matrix and its own accumulator
+    H2 = 256
+    x2 = jnp.asarray(rng.randn(T, H2).astype("float32"))
+    three = [jnp.asarray(0.1 * rng.randn(E, F, H2).astype("float32"))
+             for _ in range(3)]
+    c = moe.held_weights(idx, w, mask, 0, E)
+    _assert_close_both_ways(
+        run(x2, c, *three), moe.experts_composite(x2, c, *three),
+        "moe_experts (gated)", 1e-5, 1e-5)
 
 
 def _tpu_cases_moe_experts():
-    """The hybrid serving cell's expert layer: a step's 32 tokens, 16 held
-    experts of width 1,856 at hidden 2,688, bfloat16."""
+    """The hybrid serving cells' expert layers in bfloat16:
+    nemotron3_nano_30b_a3b (a step's 32 tokens, 16 held relu2 experts of
+    width 1,856 at hidden 2,688) and lfm2_24b_a2b (128 tokens, 8 held gated
+    experts of width 1,536 at hidden 2,048)."""
     from paddle_tpu.kernels import moe
 
-    T, H, F, E = 32, 2688, 1856, 16
-    return [("t32_h2688_f1856_e16_bf16", moe.moe_experts, [
-        ((T, H), "bfloat16"), ((T, E), "float32"),
-        ((E, F, H), "bfloat16"), ((E, F, H), "bfloat16")])]
+    def case(T, H, F, E, matrices):
+        return (f"t{T}_h{H}_f{F}_e{E}_m{matrices}_bf16", moe.moe_experts,
+                [((T, H), "bfloat16"), ((T, E), "float32")]
+                + [((E, F, H), "bfloat16")] * matrices)
+
+    return [case(32, 2688, 1856, 16, 2), case(128, 2048, 1536, 8, 3)]
 
 
 def _parity_ssm_update(rng):

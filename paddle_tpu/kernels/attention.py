@@ -33,7 +33,8 @@ the MXU), which is never a lower precision than the composite's. With
 ``kv_heads`` (grouped-query attention: rows of ``kv_heads`` K/V heads side
 by side, a whole number of query heads to each) a group's query heads are
 the rows of one MXU product per K/V head and block group, scores and
-softmax in float32.
+softmax in float32; heads narrower than the 128 lanes go two (or more) to
+a product (``grouped_layout``).
 
 Eligibility: ``decode_attention`` wants its whole workset resident in VMEM;
 ``fits_vmem`` gates the compiled-TPU path per static shape on the INPUT
@@ -66,7 +67,7 @@ _CLOSED = -5e8
 __all__ = [
     "cached_attention_composite", "paged_attention_composite",
     "chunk_attention_composite", "decode_attention", "paged_attention",
-    "fits_vmem",
+    "fits_vmem", "grouped_layout",
 ]
 
 #: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
@@ -363,6 +364,49 @@ def _paged_grouped_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
         o_ref[g] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
+def grouped_layout(width, kv_heads, q_width, dtype, interpret=False):
+    """How the grouped body sees rows of ``kv_heads`` K/V heads (``width``
+    lanes in all) and ``q_width // width`` query heads to each: ``(pack,
+    rows)``. The body slices whole 128-lane tiles out of a row, so heads
+    narrower than a tile go ``pack`` to a slice (two heads of 64), their
+    query rows stacked in one block, each head's in its own lanes and zeros
+    in its neighbours' (the neighbours' products vanish); ``rows`` is the
+    block's query rows, padded to whole sublane tiles of ``dtype``. ``(0,
+    0)`` says that no packing gives whole tiles and the composite runs: THE
+    eligibility test of the grouped form, by geometry. ``interpret`` has no
+    Mosaic to please and takes any head as it is."""
+    d, per = width // kv_heads, q_width // width
+    pack = 128 // d if d < 128 and 128 % d == 0 else 1
+    if kv_heads % pack or (pack * d) % 128:
+        return (1, per) if interpret else (0, 0)
+    sublanes = 1 if interpret else 8 * (4 // jnp.dtype(dtype).itemsize)
+    return pack, -(-pack * per // sublanes) * sublanes
+
+
+def _pack_heads(q, pack, rows):
+    """``q`` ``[S, G, per, D]`` as ``[S, G / pack, rows, pack * D]``: head
+    ``j`` of a pack in rows ``j * per ..`` and lanes ``j * D ..``, zeros
+    elsewhere."""
+    s, g, per, d = q.shape
+    if pack == 1:
+        return q if rows == per else jnp.pad(
+            q, ((0, 0), (0, 0), (0, rows - per), (0, 0)))
+    eye = jnp.eye(pack, dtype=q.dtype)[None, None, :, None, :, None]
+    wide = (q.reshape(s, g // pack, pack, per, 1, d) * eye).reshape(
+        s, g // pack, pack * per, pack * d)
+    return jnp.pad(wide, ((0, 0), (0, 0), (0, rows - pack * per), (0, 0)))
+
+
+def _unpack_heads(out, pack, per):
+    """``_pack_heads``'s inverse on the body's output: each head's rows, its
+    own lanes of them; ``[S, G, per, D]``."""
+    s, pairs, _rows, lanes = out.shape
+    d = lanes // pack
+    blocks = out[:, :, :pack * per].reshape(s, pairs, pack, per, pack, d)
+    return jnp.stack([blocks[:, :, j, :, j] for j in range(pack)],
+                     axis=2).reshape(s, pairs * pack, per, d)
+
+
 def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
                     block_size, sm_scale, interpret=False, kv_heads=0):
     """Blocked paged attention: ``paged_attention_composite`` computed
@@ -376,13 +420,10 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     G = int(kv_heads)
     per_slot = -(-L // bs)
     group = _paged_group(bs, per_slot, H, k_arena.dtype)
-    # a grouped body slices a head's D lanes out of a row and stacks `per`
-    # query rows: both in whole tiles of the dtype
-    sublanes = 8 * (4 // jnp.dtype(k_arena.dtype).itemsize)
-    heads_tile = not G or ((H // G) % 128 == 0
-                           and (q.shape[-1] // H) % sublanes == 0)
-    if vma_names(q) or group == 0 or (not interpret and not (
-            _mosaic_tiles(bs, H, k_arena.dtype) and heads_tile)):
+    pack, qrows = grouped_layout(H, G, q.shape[-1], k_arena.dtype,
+                                 interpret) if G else (1, 1)
+    if vma_names(q) or group == 0 or not pack or (
+            not interpret and not _mosaic_tiles(bs, H, k_arena.dtype)):
         fallback_counter().inc()
         return paged_attention_composite(q, k_arena, v_arena, rows, bias,
                                          S, L, sm_scale, kv_heads=G)
@@ -396,10 +437,12 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     tiles = jnp.pad(bias2, ((0, 0), (0, ngroups * grows - L)),
                     constant_values=-1e9).reshape(S, ngroups, grows)
     if G:
-        per, d = q.shape[-1] // H, H // G
-        row = pl.BlockSpec((None, G, per, d), lambda s, *_: (s, 0, 0, 0))
-        body = functools.partial(_paged_grouped_body, kv_heads=G)
-        q_in = q.reshape(S, G, per, d).astype(k_arena.dtype)
+        lanes = pack * (H // G)
+        row = pl.BlockSpec((None, G // pack, qrows, lanes),
+                           lambda s, *_: (s, 0, 0, 0))
+        body = functools.partial(_paged_grouped_body, kv_heads=G // pack)
+        q_in = _pack_heads(q.reshape(S, G, -1, H // G), pack,
+                           qrows).astype(k_arena.dtype)
     else:
         row = pl.BlockSpec((None, 1, H), lambda s, *_: (s, 0, 0))
         body, q_in = _paged_body, q.reshape(S, 1, H)
@@ -430,4 +473,6 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
         interpret=interpret,
         name="paged_attention",
     )(table.reshape(-1), lengths, q_in, tiles, k_arena, v_arena)
+    if G:
+        out = _unpack_heads(out, pack, q.shape[-1] // H)
     return out.reshape(q.shape)

@@ -27,6 +27,12 @@ decode step, over the per-slot state arrays:
 
 A position whose ``mask`` is false moves neither state: its ``dt`` is 0
 (decay 1, no input) and the tail is taken at the last real token.
+
+``conv_tail_chunk`` / ``conv_tail_step`` are that convolution over its
+per-slot tail alone, and ``short_conv_chunk`` / ``short_conv_step`` the
+gated short convolution (the ``lfm2`` family's operator) that is nothing
+else: ``C * conv(B * u)`` for ``B | C | u`` the input projection's thirds,
+no bias, no activation, no other state.
 """
 
 import functools
@@ -42,6 +48,8 @@ from paddle_tpu.ops.common import vma_names
 __all__ = [
     "ssm_scan_sequential", "ssm_scan_chunked", "ssm_update_composite",
     "ssm_update", "mixer_chunk", "mixer_step", "gated_group_norm",
+    "conv_tail_chunk", "conv_tail_step", "short_conv_chunk",
+    "short_conv_step",
 ]
 
 _HI = jax.lax.Precision.HIGHEST
@@ -234,6 +242,58 @@ def _split_xbc(xbc, heads, head_dim, groups, n_state):
             xbc[..., d_inner + gn:].reshape(lead + (groups, n_state)))
 
 
+def conv_tail_chunk(x, conv_w, conv_state, slot, mask, keep):
+    """The causal depthwise convolution of a prompt chunk ``x`` ``[T, D]``
+    (float32) of ONE slot over ``conv_w`` ``[K, D]`` (tap ``K - 1`` on the
+    current token), continued from that slot's tail times ``keep`` (0.0
+    where the chunk opens the prompt). Returns the ``[T, D]`` sums (no
+    bias, no activation) and the tail array with the slot's rows replaced
+    by the last ``K - 1`` inputs up to the last real position of ``mask``
+    (a prefix)."""
+    taps, t = conv_w.shape[0], x.shape[0]
+    tail = jax.lax.dynamic_index_in_dim(conv_state, slot, 0, False)
+    ext = jnp.concatenate([tail.astype(jnp.float32) * keep, x], axis=0)
+    conv = sum(conv_w[k] * ext[k:k + t] for k in range(taps))
+    real = jnp.sum(mask.astype(jnp.int32))
+    new_tail = jax.lax.dynamic_slice_in_dim(ext, real, taps - 1, axis=0)
+    return conv, jax.lax.dynamic_update_index_in_dim(
+        conv_state, new_tail.astype(conv_state.dtype), slot, 0)
+
+
+def conv_tail_step(x, conv_w, conv_state, mask):
+    """One token per slot ``x`` ``[S, D]`` (float32) against every slot's
+    tail; the slots of ``mask`` ``[S]`` move theirs on by it."""
+    ext = jnp.concatenate([conv_state.astype(jnp.float32), x[:, None]],
+                          axis=1)
+    conv = jnp.sum(conv_w[None] * ext, axis=1)
+    return conv, jnp.where(mask[:, None, None],
+                           ext[:, 1:].astype(conv_state.dtype), conv_state)
+
+
+def _gate_thirds(bcu):
+    """``B * u`` and ``C`` of the input projection's ``B | C | u``."""
+    b, c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
+    return b * u, c
+
+
+def short_conv_chunk(bcu, conv_w, conv_state, slot, mask, reset, out_dtype):
+    """The gated short convolution over a prompt chunk ``[T, 3 D]`` of ONE
+    slot: ``C * conv(B * u)``; ``reset`` says the chunk opens the prompt."""
+    bu, c = _gate_thirds(bcu)
+    conv, conv_state = conv_tail_chunk(
+        bu, conv_w.astype(jnp.float32), conv_state, slot, mask,
+        jnp.where(reset, 0.0, 1.0).astype(jnp.float32))
+    return (c * conv).astype(out_dtype), conv_state
+
+
+def short_conv_step(bcu, conv_w, conv_state, mask, out_dtype):
+    """The same for one token of every slot ``[S, 3 D]``."""
+    bu, c = _gate_thirds(bcu)
+    conv, conv_state = conv_tail_step(bu, conv_w.astype(jnp.float32),
+                                      conv_state, mask)
+    return (c * conv).astype(out_dtype), conv_state
+
+
 def mixer_chunk(zxbcdt, params, conv_state, ssm_state, slot, mask, reset, *,
                 heads, head_dim, groups, n_state, chunk, eps, out_dtype):
     """A prompt chunk ``[T, in_proj width]`` of ONE slot against that slot's
@@ -245,23 +305,18 @@ def mixer_chunk(zxbcdt, params, conv_state, ssm_state, slot, mask, reset, *,
     f32 = jnp.float32
     conv_w, conv_b, dt_bias, a_log, d_skip, norm_w = _float32(params)
     z, xbc, dt = _split(zxbcdt.astype(f32), heads, head_dim, groups, n_state)
-    taps = conv_w.shape[0]
     keep = jnp.where(reset, 0.0, 1.0).astype(f32)
-    tail = jax.lax.dynamic_index_in_dim(conv_state, slot, 0, False)
     h0 = jax.lax.dynamic_index_in_dim(ssm_state, slot, 0, False)
-    tail, h0 = tail.astype(f32) * keep, h0.astype(f32) * keep
-    ext = jnp.concatenate([tail, xbc], axis=0)              # [T + K-1, D]
+    h0 = h0.astype(f32) * keep
     t = xbc.shape[0]
-    conv = sum(conv_w[k] * ext[k:k + t] for k in range(taps)) + conv_b
-    x, b, c = _split_xbc(jax.nn.silu(conv), heads, head_dim, groups, n_state)
+    conv, conv_state = conv_tail_chunk(xbc, conv_w, conv_state, slot, mask,
+                                       keep)
+    x, b, c = _split_xbc(jax.nn.silu(conv + conv_b), heads, head_dim, groups,
+                         n_state)
     dt = jnp.where(mask[:, None], jax.nn.softplus(dt + dt_bias), 0.0)
     y, h = ssm_scan_chunked(x, dt, -jnp.exp(a_log), b, c, h0, chunk)
     y = (y + d_skip[:, None] * x).reshape(t, heads * head_dim)
     out = gated_group_norm(y, z, norm_w, groups, eps).astype(out_dtype)
-    real = jnp.sum(mask.astype(jnp.int32))
-    new_tail = jax.lax.dynamic_slice_in_dim(ext, real, taps - 1, axis=0)
-    conv_state = jax.lax.dynamic_update_index_in_dim(
-        conv_state, new_tail.astype(conv_state.dtype), slot, 0)
     ssm_state = jax.lax.dynamic_update_index_in_dim(
         ssm_state, h.astype(ssm_state.dtype), slot, 0)
     return out, conv_state, ssm_state
@@ -275,9 +330,9 @@ def mixer_step(zxbcdt, params, conv_state, ssm_state, mask, *, heads,
     f32 = jnp.float32
     conv_w, conv_b, dt_bias, a_log, d_skip, norm_w = _float32(params)
     z, xbc, dt = _split(zxbcdt.astype(f32), heads, head_dim, groups, n_state)
-    ext = jnp.concatenate([conv_state.astype(f32), xbc[:, None]], axis=1)
-    conv = jnp.sum(conv_w[None] * ext, axis=1) + conv_b
-    x, b, c = _split_xbc(jax.nn.silu(conv), heads, head_dim, groups, n_state)
+    conv, conv_state = conv_tail_step(xbc, conv_w, conv_state, mask)
+    x, b, c = _split_xbc(jax.nn.silu(conv + conv_b), heads, head_dim, groups,
+                         n_state)
     dt = jax.nn.softplus(dt + dt_bias)                        # [S, H]
     args = (dt[:, :, None] * x, jnp.exp(-dt * jnp.exp(a_log)),
             _per_head(b, heads), _per_head(c, heads), mask)
@@ -288,6 +343,4 @@ def mixer_step(zxbcdt, params, conv_state, ssm_state, mask, *, heads,
         new, y = ssm_update(ssm_state, *args, interpret=kernel)
     y = (y + d_skip[:, None] * x).reshape(x.shape[0], heads * head_dim)
     out = gated_group_norm(y, z, norm_w, groups, eps).astype(out_dtype)
-    conv_state = jnp.where(mask[:, None, None],
-                           ext[:, 1:].astype(conv_state.dtype), conv_state)
     return out, conv_state, new

@@ -33,6 +33,8 @@ __all__ = [
     "chunk_paged_attention",
     "paged_step_feeds",
     "rms_norm",
+    "rotary_embedding",
+    "gated_short_conv",
     "relu2",
     "moe_routed_experts",
     "mamba2_mixer",
@@ -801,6 +803,22 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, out_dtype=None,
     return out
 
 
+def rotary_embedding(x, positions, theta=10000.0, out_dtype=None, name=None):
+    """Rotary positions (ops/nn.py ``rotary_embedding``): ``x`` ``[...,
+    heads, D]`` turned, whole head and rotate-half, by ``positions``
+    ``[...]`` (what ``paged_step_feeds`` gives a step, the chunk program's
+    position feed) at base ``theta``; float32 inside."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+    attrs = {"theta": float(theta)}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
+    helper.append_op("rotary_embedding",
+                     {"X": [x.name], "Positions": [positions.name]},
+                     {"Out": [out.name]}, attrs)
+    return out
+
+
 def relu2(x, name=None):
     """Squared relu, ``max(x, 0)^2`` (also ``fc(..., act="relu2")``)."""
     helper = LayerHelper("relu2", name=name)
@@ -811,19 +829,21 @@ def relu2(x, name=None):
 
 def moe_routed_experts(input, write_rows, num_rows, router_experts,
                        held_experts, ffn_dim, k, param_attrs, expert_offset=0,
-                       score_scale=1.0, normalize=True, kernel=False,
-                       name=None):
+                       score_scale=1.0, normalize=True, norm_epsilon=1e-20,
+                       kernel=False, name=None):
     """This chip's share of a routed-experts layer (ops/moe.py
     ``moe_routed_experts``): the router scores ``input`` ``[..., H]``
     against all ``router_experts`` (sigmoid scores, a selection bias, top
-    ``k``, normalised over the k, times ``score_scale``) and the
-    ``held_experts`` that live here (ids from ``expert_offset``) add their
-    relu-squared FFNs' part; no capacity, no dropped token. ``write_rows``
-    marks the real tokens (a row ``>= num_rows`` is routed nowhere).
-    ``param_attrs``: ``gate`` ``[E_all, H]`` and ``select_bias`` ``[E_all]``
-    (float32), ``w_up``, ``w_down`` ``[held, F, H]`` (``input``'s dtype).
-    ``kernel`` lets the ``moe_experts`` kernel serve the op (the decode
-    step). Returns ``(out float32, counts int32 [3])``."""
+    ``k``, normalised over the k's sum + ``norm_epsilon``, times
+    ``score_scale``) and the ``held_experts`` that live here (ids from
+    ``expert_offset``) add their FFNs' part; no capacity, no dropped token.
+    ``write_rows`` marks the real tokens (a row ``>= num_rows`` is routed
+    nowhere). ``param_attrs``: ``gate`` ``[E_all, H]`` and ``select_bias``
+    ``[E_all]`` (float32), ``w_up``, ``w_down`` ``[held, F, H]``
+    (``input``'s dtype) and, for gated experts (``silu(gate) * up`` in
+    place of ``relu(up)^2``), ``w_gate`` likewise. ``kernel`` lets the
+    ``moe_experts`` kernel serve the op (the decode step). Returns ``(out
+    float32, counts int32 [4])``."""
     helper = LayerHelper("moe_routed_experts", name=name)
     hidden = int(input.shape[-1])
     gate = helper.create_parameter(
@@ -836,16 +856,21 @@ def moe_routed_experts(input, write_rows, num_rows, router_experts,
     w_down = helper.create_parameter(
         param_attrs["w_down"], shape=[held_experts, ffn_dim, hidden],
         dtype=input.dtype)
+    ins = {"X": [input.name], "GateW": [gate.name],
+           "SelectBias": [select_bias.name], "WUp": [w_up.name],
+           "WDown": [w_down.name], "WriteRows": [write_rows.name]}
+    if "w_gate" in param_attrs:
+        ins["WGate"] = [helper.create_parameter(
+            param_attrs["w_gate"], shape=[held_experts, ffn_dim, hidden],
+            dtype=input.dtype).name]
     out = helper.create_variable_for_type_inference("float32")
     counts = helper.create_variable_for_type_inference("int32")
     helper.append_op(
-        "moe_routed_experts",
-        {"X": [input.name], "GateW": [gate.name],
-         "SelectBias": [select_bias.name], "WUp": [w_up.name],
-         "WDown": [w_down.name], "WriteRows": [write_rows.name]},
+        "moe_routed_experts", ins,
         {"Out": [out.name], "Counts": [counts.name]},
         {"k": int(k), "score_scale": float(score_scale),
-         "normalize": bool(normalize), "expert_offset": int(expert_offset),
+         "normalize": bool(normalize), "norm_epsilon": float(norm_epsilon),
+         "expert_offset": int(expert_offset),
          "num_rows": int(num_rows), "kernel": bool(kernel)},
     )
     return out, counts
@@ -901,6 +926,41 @@ def mamba2_mixer(input, conv_state, ssm_state, write_rows, num_rows, mode,
          "SsmStateOut": [new_ssm.name]}, attrs)
     assign(new_conv, output=conv_state)
     assign(new_ssm, output=ssm_state)
+    return out
+
+
+def gated_short_conv(input, conv_state, write_rows, num_rows, mode, taps,
+                     param_attr, slot=None, positions=None, out_dtype=None,
+                     name=None):
+    """The gated short convolution between its projections
+    (ops/short_conv.py): ``input`` is the input projection's ``B | C | u``,
+    ``[1, C, 3 D]`` of the one slot ``slot`` names (``mode="chunk"``;
+    ``positions`` tells a prompt's first chunk, whose tail starts from
+    zeros) or ``[S, 1, 3 D]`` (``mode="step"``). Advances the per-slot
+    ``conv_state`` ``[S, taps - 1, D]`` in place (assigned back, so the
+    lowering donates it) for the tokens ``write_rows`` marks as real, and
+    returns ``C * conv(B * u)``. ``param_attr``: the convolution's weight
+    ``[taps, D]``, float32."""
+    from paddle_tpu.layers.tensor import assign
+
+    helper = LayerHelper("gated_short_conv", name=name)
+    width = int(input.shape[-1]) // 3
+    conv_w = helper.create_parameter(param_attr, shape=[int(taps), width],
+                                     dtype="float32")
+    ins = {"X": [input.name], "ConvW": [conv_w.name],
+           "ConvState": [conv_state.name], "WriteRows": [write_rows.name]}
+    if mode == "chunk":
+        ins["Slot"] = [slot.name]
+        ins["Positions"] = [positions.name]
+    out = helper.create_variable_for_type_inference(out_dtype or input.dtype)
+    new_conv = helper.create_variable_for_type_inference(conv_state.dtype)
+    attrs = {"mode": mode, "num_rows": int(num_rows)}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
+    helper.append_op("gated_short_conv", ins,
+                     {"Out": [out.name], "ConvStateOut": [new_conv.name]},
+                     attrs)
+    assign(new_conv, output=conv_state)
     return out
 
 
@@ -1320,14 +1380,19 @@ def elementwise_pow(x, y, axis=-1, act=None, name=None):
     return elementwise_op("elementwise_pow", x, y, axis, act, name)
 
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           out_dtype=None):
     helper = LayerHelper("matmul", name=name)
-    out = helper.create_variable_for_type_inference(x.dtype)
+    out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y,
+             "alpha": alpha}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
     helper.append_op(
         "matmul",
         {"X": [x.name], "Y": [y.name]},
         {"Out": [out.name]},
-        {"transpose_X": transpose_x, "transpose_Y": transpose_y, "alpha": alpha},
+        attrs,
     )
     return out
 

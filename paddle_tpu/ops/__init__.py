@@ -22,6 +22,7 @@ from paddle_tpu.ops import py_func  # noqa: F401
 from paddle_tpu.ops import vision  # noqa: F401
 from paddle_tpu.ops import moe  # noqa: F401
 from paddle_tpu.ops import mamba  # noqa: F401
+from paddle_tpu.ops import short_conv  # noqa: F401
 from paddle_tpu.ops import misc_extra  # noqa: F401
 from paddle_tpu.ops import vision_extra  # noqa: F401
 from paddle_tpu.ops import fused  # noqa: F401

@@ -40,7 +40,9 @@ def _matmul(ins, attrs):
         x = jnp.swapaxes(x, -1, -2) if x.ndim > 1 else x
     if attrs.get("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim > 1 else y
-    out = jnp.matmul(x, y)
+    # ``out_dtype``: a narrow product accumulated, and handed on, in a
+    # wider dtype (``mul`` has the same)
+    out = jnp.matmul(x, y, preferred_element_type=attrs.get("out_dtype"))
     alpha = attrs.get("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

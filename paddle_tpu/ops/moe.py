@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax import shard_map as _shard_map
 
 from paddle_tpu.core.registry import OpDef, OpRegistry, register_op
-from paddle_tpu.ops.common import first, vma_names
+from paddle_tpu.ops.common import first, maybe, vma_names
 from paddle_tpu.utils.enforce import EnforceError
 
 _ACTS = {
@@ -138,29 +138,34 @@ def _moe_routed_experts_pallas(ins, attrs):
 def _moe_routed_experts(ins, attrs, kernel):
     """The held experts' part of a routed-experts layer (kernels/moe.py):
     ``X`` ``[T, H]``, the router ``GateW`` ``[E_all, H]`` and its selection
-    bias ``SelectBias`` ``[E_all]`` over ALL the experts, ``WUp`` and
-    ``WDown`` ``[held, F, H]`` of the experts ``expert_offset ..
-    expert_offset + held - 1`` that live here, and ``WriteRows`` ``[T]``: a
+    bias ``SelectBias`` ``[E_all]`` over ALL the experts, ``WUp``,
+    ``WDown`` and, for gated experts, ``WGate`` ``[held, F, H]`` of the
+    experts ``expert_offset .. expert_offset + held - 1`` that live here
+    (with ``WGate`` an expert is ``silu(gate) * up``, without it
+    ``relu(up)^2``), and ``WriteRows`` ``[T]``: a
     token whose row is ``>= num_rows`` (one that writes nowhere: a slot
     that does not step, a chunk's padding) is routed nowhere and counted
-    nowhere. ``Out`` float32 ``[T, H]``; ``Counts`` int32 ``[3]``
-    (assignments, held assignments, held experts touched)."""
+    nowhere. ``Out`` float32 ``[T, H]``; ``Counts`` int32 ``[4]``
+    (assignments, held assignments, held experts touched, the busiest held
+    expert's tokens)."""
     from paddle_tpu.kernels import moe
 
     x = first(ins, "X")
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
     w_up, w_down = first(ins, "WUp"), first(ins, "WDown")
+    w_gate = maybe(ins, "WGate")
     held, offset = w_up.shape[0], attrs.get("expert_offset", 0)
     mask = first(ins, "WriteRows").reshape(-1) < attrs["num_rows"]
     idx, w = moe.route(xt, first(ins, "GateW"), first(ins, "SelectBias"),
                        attrs["k"], attrs.get("score_scale", 1.0),
-                       attrs.get("normalize", True))
+                       attrs.get("normalize", True),
+                       attrs.get("norm_epsilon", 1e-20))
     c = moe.held_weights(idx, w, mask, offset, held)
     if kernel is None:
-        out = moe.experts_composite(xt, c, w_up, w_down)
+        out = moe.experts_composite(xt, c, w_up, w_down, w_gate)
     else:
-        out = moe.moe_experts(xt, c, w_up, w_down, interpret=kernel)
+        out = moe.moe_experts(xt, c, w_up, w_down, w_gate, interpret=kernel)
     return {"Out": [out.reshape(lead + (x.shape[-1],))],
             "Counts": [moe.routing_counts(idx, c, mask)]}
 

@@ -670,6 +670,25 @@ def _rms_norm(ins, attrs):
     return {"Out": [y.astype(attrs.get("out_dtype") or x.dtype)]}
 
 
+@register_op("rotary_embedding", nondiff_inputs=("Positions",))
+def _rotary_embedding(ins, attrs):
+    """Rotary positions over the WHOLE head, rotate-half pairing: ``X``
+    ``[..., heads, D]`` at ``Positions`` ``[...]`` (one a token); lane ``i <
+    D / 2`` of a head is paired with lane ``i + D / 2`` and the pair turned
+    by ``position * theta^(-2 i / D)``. Angles, sines and the rotation in
+    float32; ``out_dtype`` names the result's dtype (default: ``X``'s)."""
+    x, pos = first(ins, "X"), first(ins, "Positions")
+    half = x.shape[-1] // 2
+    freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                   * (-jnp.log(jnp.float32(attrs["theta"])) / half))
+    angle = pos.astype(jnp.float32)[..., None, None] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return {"Out": [y.astype(attrs.get("out_dtype") or x.dtype)]}
+
+
 @register_op("relu2")
 def _relu2(ins, attrs):
     """Squared relu: ``max(x, 0)^2``."""

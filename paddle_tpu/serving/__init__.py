@@ -42,6 +42,7 @@ from paddle_tpu.serving.decode import (
     DecodeModel,
     GenerationEngine,
     build_decoder_model,
+    build_lfm2_model,
     build_nemotron_h_model,
 )
 from paddle_tpu.serving.engine import ServingEngine
@@ -74,6 +75,7 @@ __all__ = [
     "SubprocessReplica",
     "build_decoder_model",
     "build_nemotron_h_model",
+    "build_lfm2_model",
     "Priority",
     "RejectedError",
     "ReplicaLostError",

@@ -31,7 +31,9 @@ Modules:
   (decode step / prefill / inject / optional chunk prefill) +
   `build_decoder_model`, the canonical cached-attention decoder builder.
 * `hybrid` — `build_nemotron_h_model`: a Mamba-2 / grouped-query attention
-  / routed-experts decoder with per-slot recurrent state beside the arena.
+  / routed-experts decoder with per-slot recurrent state beside the arena;
+  `build_lfm2_model`: gated short convolutions beside grouped-query
+  attention with QK-norm and rotary positions, gated routed experts.
 * `pool`   — host-side slot allocator, block allocator + radix prefix
   index (storage dedup), and the content-hash prefill cache (compute
   dedup).
@@ -57,7 +59,8 @@ from paddle_tpu.serving.decode.generate import (
     SamplingParams,
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
-from paddle_tpu.serving.decode.hybrid import build_nemotron_h_model
+from paddle_tpu.serving.decode.hybrid import (
+    build_lfm2_model, build_nemotron_h_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -82,5 +85,6 @@ __all__ = [
     "block_hashes",
     "build_decoder_model",
     "build_nemotron_h_model",
+    "build_lfm2_model",
     "prompt_key",
 ]

@@ -1,5 +1,11 @@
-"""A hybrid state-space / attention / routed-experts decoder as a paged
-DecodeModel: the ``nemotron_h`` family (NVIDIA Nemotron-H / Nemotron 3).
+"""Hybrid decoders as paged DecodeModels: per-slot recurrent state beside
+paged grouped-query K/V rows, routed experts of which this chip holds a
+share. Two families, one set of parts (``_Parts``: the named seeded
+parameters, projections, norms, arenas and their write; ``_hybrid_model``:
+the two programs around a family's ``stack`` and the DecodeModel).
+
+**``nemotron_h``** (NVIDIA Nemotron-H / Nemotron 3:
+``build_nemotron_h_model``).
 
 ``hybrid_override_pattern`` names one mixer a block: ``M`` a Mamba-2 mixer,
 ``*`` grouped-query attention (no position encoding: the Mamba layers carry
@@ -31,18 +37,33 @@ Expert parallelism seen from one rank: the router scores all
 ``router_experts``; the ``n_routed_experts`` held here are ids
 ``expert_rank * n_routed_experts ..``; what the absent experts would add is
 left out. The vocabulary may be a slice likewise (``vocab_size`` rows).
+
+**``lfm2_moe``** (Liquid LFM2 with routed experts, ``build_lfm2_model``).
+``layer_types`` names one OPERATOR a layer, ``conv`` a gated short
+convolution (``C * conv(B * u)`` over ``conv_L_cache`` taps: its per-slot
+state is the tail of the last ``conv_L_cache - 1`` inputs and nothing
+else) or ``full_attention`` grouped-query attention with an RMSNorm over
+each head of q and k (QK-norm) and rotary positions (whole head,
+rotate-half) read from the positions every program already carries; K rows
+are stored after both. Every layer is ``x <- x + operator(RMSNorm(x))`` and
+then ``x <- x + ffn(RMSNorm(x))``: a dense SwiGLU in the
+``num_dense_layers`` leading layers, sigmoid top-k routed SwiGLU experts
+(a bias on the choice, the chosen scores normalised over their sum + 1e-6,
+no shared expert) in the others. A final RMSNorm and a head TIED to the
+embedding; no bias anywhere. The same two programs, the same one-feed
+decode step, the same share of the experts.
 """
 
 import math
 
 from paddle_tpu.serving.decode.model import DecodeModel, _state_var
 
-__all__ = ["build_nemotron_h_model", "MOE_COUNTS"]
+__all__ = ["build_nemotron_h_model", "build_lfm2_model", "MOE_COUNTS"]
 
 #: what the decode step's ``Counts`` hold, in order: the engine adds them
 #: to the counters of these names when the step's tokens come back
 MOE_COUNTS = ("moe_assignments", "moe_held_assignments",
-              "moe_touched_experts")
+              "moe_touched_experts", "moe_peak_expert_tokens")
 
 
 def _then(block, shape, start, ops, out):
@@ -107,167 +128,69 @@ def _dt_bias(dt_min, dt_max, floor):
     return DtBias()
 
 
-def build_nemotron_h_model(
-        vocab_size, hidden_size, hybrid_override_pattern, *,
-        mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size,
-        conv_kernel, chunk_size, num_attention_heads, num_key_value_heads,
-        head_dim, n_routed_experts, router_experts, num_experts_per_tok,
-        moe_intermediate_size, moe_shared_expert_intermediate_size,
-        routed_scaling_factor, norm_topk_prob=True, layer_norm_epsilon=1e-5,
-        time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4,
-        expert_rank=0, dtype="bfloat16", state_dtype="float32", slots=4,
-        max_len=64, block_size=16, num_blocks=None, chunk_tokens=16, eos_id=None, name="nemotron_h",
-        version="1"):
-    """Build the hybrid decoder as a paged DecodeModel (module docstring).
-    The sizes are the published ``config.json``'s keys under their own
-    names; ``n_routed_experts`` is how many experts are HELD here and
-    ``router_experts`` how many the router scores (the published count);
-    ``vocab_size`` the rows of the vocabulary held here. ``state_dtype`` is
-    the SSM state's and the convolution tail's (float32 as served; a
-    narrower one runs the composite and is what the comparison with the
-    reference has to catch)."""
-    import paddle_tpu as fluid
-    from paddle_tpu.core.ir import Program, program_guard
-    from paddle_tpu.initializer import (
-        ConstantInitializer, NormalInitializer, UniformInitializer)
-    from paddle_tpu.utils import unique_name
+class _Parts:
+    """What a hybrid family's layers are built from, under the model's
+    name: seeded parameters (matrices normal ``std``, those that write into
+    the residual ``back``), bias-free projections, RMSNorms, the paged K/V
+    arenas of the attention layers ``a_layers`` with their scatter write,
+    and the per-slot states."""
 
-    V, H, S, L = int(vocab_size), int(hidden_size), int(slots), int(max_len)
-    pattern = str(hybrid_override_pattern)
-    if set(pattern) - set("ME*"):
-        raise ValueError(f"hybrid_override_pattern {pattern!r}: a block is "
-                         "M (Mamba-2), E (experts) or * (attention)")
-    NL = len(pattern)
-    BS = int(block_size)
-    NB = int(num_blocks) if num_blocks else S * -(-L // BS)
-    R = NB * BS
-    C = int(chunk_tokens)
-    if not 2 <= C <= L:
-        raise ValueError(f"chunk_tokens must be in [2, {L}], got {C}")
-    MH, MP, MG, MN = (int(mamba_num_heads), int(mamba_head_dim),
-                      int(n_groups), int(ssm_state_size))
-    d_inner = MH * MP
-    conv_dim = d_inner + 2 * MG * MN
-    in_width = 2 * d_inner + 2 * MG * MN + MH
-    NQ, NKV, D = (int(num_attention_heads), int(num_key_value_heads),
-                  int(head_dim))
-    kv_width = NKV * D
-    held, router = int(n_routed_experts), int(router_experts)
-    offset = int(expert_rank) * held
-    if offset + held > router:
-        raise ValueError(f"expert_rank {expert_rank} x {held} held experts "
-                         f"passes the router's {router}")
-    eps = float(layer_norm_epsilon)
-    sm_scale = 1.0 / math.sqrt(D)
-    prefix = f"{name}_v{version}"
-    std = 0.02
-    back = std / math.sqrt(NL)      # rescale_prenorm_residual
+    def __init__(self, prefix, dtype, eps, std, back, rows, kv_width,
+                 a_layers, slot_states):
+        import paddle_tpu as fluid
+        from paddle_tpu.core.ir import Program
 
-    def attr(suffix, init):
-        return fluid.ParamAttr(name=f"{prefix}.{suffix}", initializer=init)
+        self.fluid = fluid
+        self.prefix, self.dtype, self.eps = prefix, dtype, eps
+        self.std, self.back = std, back
+        self.rows, self.kv_width = rows, kv_width
+        self.a_layers = a_layers
+        self.state_names = [(f"{prefix}.kcache{i}", f"{prefix}.vcache{i}")
+                            for i in a_layers]
+        self.slot_states = slot_states
+        self.startup = Program()
 
-    def matrix(suffix, residual=False):
-        return attr(suffix, NormalInitializer(0.0, back if residual else std))
+    def attr(self, suffix, init):
+        return self.fluid.ParamAttr(name=f"{self.prefix}.{suffix}",
+                                    initializer=init)
 
-    def proj(h, size, suffix, act=None, residual=False, out_dtype=None):
-        return fluid.layers.fc(
-            h, size, num_flatten_dims=2, act=act, bias_attr=False,
-            param_attr=matrix(suffix + ".w", residual), out_dtype=out_dtype)
+    def matrix(self, suffix, residual=False):
+        from paddle_tpu.initializer import NormalInitializer
 
-    def norm(h, suffix):
-        return fluid.layers.rms_norm(
-            h, epsilon=eps, out_dtype=dtype,
-            param_attr=attr(suffix, ConstantInitializer(1.0)))
+        return self.attr(suffix, NormalInitializer(
+            0.0, self.back if residual else self.std))
 
-    m_layers = [i for i, kind in enumerate(pattern) if kind == "M"]
-    a_layers = [i for i, kind in enumerate(pattern) if kind == "*"]
-    state_names = [(f"{prefix}.kcache{i}", f"{prefix}.vcache{i}")
-                   for i in a_layers]
-    slot_states = []
-    for i in m_layers:
-        slot_states.append((f"{prefix}.conv{i}",
-                            (S, int(conv_kernel) - 1, conv_dim),
-                            state_dtype))
-        slot_states.append((f"{prefix}.ssm{i}", (S, MH, MP, MN),
-                            state_dtype))
-    startup = Program()
+    def proj(self, h, size, suffix, act=None, residual=False,
+             out_dtype=None):
+        return self.fluid.layers.fc(
+            h, size, num_flatten_dims=len(h.shape) - 1, act=act,
+            bias_attr=False, param_attr=self.matrix(suffix + ".w", residual),
+            out_dtype=out_dtype)
 
-    def mamba_attrs(i):
-        return {
-            "conv_w": attr(f"l{i}.conv_w", UniformInitializer(-0.5, 0.5)),
-            "conv_b": attr(f"l{i}.conv_b", UniformInitializer(-0.5, 0.5)),
-            "dt_bias": attr(f"l{i}.dt_bias", _dt_bias(
-                time_step_min, time_step_max, time_step_floor)),
-            "a_log": attr(f"l{i}.a_log", _log_uniform(1.0, 16.0)),
-            "d": attr(f"l{i}.d", ConstantInitializer(1.0)),
-            "norm_w": attr(f"l{i}.mixer_norm", ConstantInitializer(1.0)),
-        }
+    def norm(self, h, suffix, out_dtype=None):
+        from paddle_tpu.initializer import ConstantInitializer
 
-    def expert_attrs(i):
-        return {
-            "gate": matrix(f"l{i}.gate"),
-            "select_bias": attr(f"l{i}.select_bias",
-                                NormalInitializer(0.0, 0.05)),
-            "w_up": matrix(f"l{i}.w_up"),
-            "w_down": matrix(f"l{i}.w_down", residual=True),
-        }
+        return self.fluid.layers.rms_norm(
+            h, epsilon=self.eps, out_dtype=out_dtype or self.dtype,
+            param_attr=self.attr(suffix, ConstantInitializer(1.0)))
 
-    def stack(program, toks, positions, wrows, mode, attend, slot=None):
-        """The 52 blocks over ``toks`` ([S, 1] or [1, C]); ``attend(i, q, k,
-        v)`` is the program's own attention over the paged arenas. Returns
-        (logits, the expert layers' routing counts)."""
-        h = fluid.layers.cast(fluid.layers.embedding(
-            toks, size=(V, H), dtype=dtype,
-            param_attr=matrix("embed")), "float32")
-        counts = []
-        for i, kind in enumerate(pattern):
-            x = norm(h, f"l{i}.norm")
-            if kind == "M":
-                conv = _state_var(program, startup, *slot_states[
-                    2 * m_layers.index(i)][:2], dtype=state_dtype)
-                ssm = _state_var(program, startup, *slot_states[
-                    2 * m_layers.index(i) + 1][:2], dtype=state_dtype)
-                y = fluid.layers.mamba2_mixer(
-                    proj(x, in_width, f"l{i}.in_proj", out_dtype="float32"),
-                    conv, ssm, wrows, R, mode, MH, MP, MG, MN,
-                    int(conv_kernel), mamba_attrs(i), slot=slot,
-                    positions=positions, chunk_size=int(chunk_size),
-                    epsilon=eps, out_dtype=dtype)
-                out = proj(y, H, f"l{i}.out_proj", residual=True,
-                           out_dtype="float32")
-            elif kind == "*":
-                ctx = attend(i, proj(x, NQ * D, f"l{i}.q"),
-                             proj(x, kv_width, f"l{i}.k"),
-                             proj(x, kv_width, f"l{i}.v"))
-                out = proj(ctx, H, f"l{i}.o", residual=True,
-                           out_dtype="float32")
-            else:
-                routed, n = fluid.layers.moe_routed_experts(
-                    x, wrows, R, router, held, int(moe_intermediate_size),
-                    int(num_experts_per_tok), expert_attrs(i),
-                    expert_offset=offset,
-                    score_scale=float(routed_scaling_factor),
-                    normalize=bool(norm_topk_prob), kernel=mode == "step")
-                counts.append(n)
-                shared = proj(
-                    proj(x, int(moe_shared_expert_intermediate_size),
-                         f"l{i}.shared_up", act="relu2"),
-                    H, f"l{i}.shared_down", residual=True,
-                    out_dtype="float32")
-                out = fluid.layers.elementwise_add(routed, shared)
-            h = fluid.layers.elementwise_add(h, out)
-        logits = proj(norm(h, "final_norm"), V, "head", out_dtype="float32")
-        return logits, counts
+    def slot_state(self, program, index):
+        name, shape, dtype = self.slot_states[index]
+        return _state_var(program, self.startup, name, shape, dtype=dtype)
 
-    def arenas(program, i):
-        kn, vn = state_names[a_layers.index(i)]
-        return (_state_var(program, startup, kn, [R, kv_width], dtype=dtype),
-                _state_var(program, startup, vn, [R, kv_width], dtype=dtype))
+    def arenas(self, program, i):
+        kn, vn = self.state_names[self.a_layers.index(i)]
+        shape = [self.rows, self.kv_width]
+        return (_state_var(program, self.startup, kn, shape,
+                           dtype=self.dtype),
+                _state_var(program, self.startup, vn, shape,
+                           dtype=self.dtype))
 
-    def write(program, i, wrows, k, v, axis):
+    def write(self, program, i, wrows, k, v, axis):
         """Scatter the new K/V rows and persist (the lowering donates the
         arenas); attention reads the written views."""
-        kc, vc = arenas(program, i)
+        fluid = self.fluid
+        kc, vc = self.arenas(program, i)
         nk = fluid.layers.block_scatter_write(
             kc, wrows, fluid.layers.squeeze(k, [axis]))
         nv = fluid.layers.block_scatter_write(
@@ -276,24 +199,39 @@ def build_nemotron_h_model(
         fluid.layers.assign(nv, output=vc)
         return nk, nv
 
+
+def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
+                  block_size, num_blocks, chunk_tokens, kv_heads, sm_scale,
+                  eos_id, name, version):
+    """The hybrid family's two programs around ``stack(program, toks,
+    positions, wrows, mode, attend, slot)`` (the layers over ``toks`` ``[S,
+    1]`` or ``[1, C]``; ``attend(i, q, k, v)`` is the program's own
+    attention over the paged arenas; returns the logits and the expert
+    layers' routing counts), and their DecodeModel."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core.ir import Program, program_guard
+    from paddle_tpu.utils import unique_name
+
+    S, L, BS, C = slots, max_len, block_size, chunk_tokens
+    startup = parts.startup
+
     # -- decode step: one token per slot at [S, 1] -----------------------
     decode = Program()
     with unique_name.guard(), program_guard(decode, startup):
-        # no position encoding: the step's positions go unread
-        tok, _pos, bias, rows, wrows = fluid.layers.paged_step_feeds(
+        tok, pos, bias, rows, wrows = fluid.layers.paged_step_feeds(
             fluid.data(DecodeModel.DEC_STEP,
                        [S, DecodeModel.STEP_TABLE + -(-L // BS)],
                        dtype="int32"),
             fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64"), L, BS)
 
         def attend_step(i, q, k, v):
-            nk, nv = write(decode, i, wrows, k, v, 1)
+            nk, nv = parts.write(decode, i, wrows, k, v, 1)
             ctx = fluid.layers.paged_attention(
                 fluid.layers.squeeze(q, [1]), nk, nv, rows, bias, S, L,
-                sm_scale=sm_scale, block_size=BS, kv_heads=NKV)
+                sm_scale=sm_scale, block_size=BS, kv_heads=kv_heads)
             return fluid.layers.unsqueeze(ctx, [1])
 
-        dec_logits, counts = stack(decode, tok, None, wrows, "step",
+        dec_logits, counts = stack(decode, tok, pos, wrows, "step",
                                    attend_step)
         next_token = fluid.layers.argmax(dec_logits, axis=-1)
         # what a greedy step hands the host in ONE fetch: the S tokens,
@@ -316,41 +254,302 @@ def build_nemotron_h_model(
         cslot = fluid.data(DecodeModel.CHU_SLOT, [1], dtype="int64")
 
         def attend_chunk(i, q, k, v):
-            nk, nv = write(chunk, i, cwrows, k, v, 0)
+            nk, nv = parts.write(chunk, i, cwrows, k, v, 0)
             ctx = fluid.layers.chunk_paged_attention(
-                fluid.layers.squeeze(q, [0]), nk, nv, crows, cbias, NKV,
+                fluid.layers.squeeze(q, [0]), nk, nv, crows, cbias, kv_heads,
                 sm_scale=sm_scale)
             return fluid.layers.unsqueeze(ctx, [0])
 
         chu_logits, _ = stack(chunk, toks, pos, cwrows, "chunk",
                               attend_chunk, slot=cslot)
 
-    kwargs = dict(
-        vocab_size=V, hidden_size=H, hybrid_override_pattern=pattern,
-        mamba_num_heads=MH, mamba_head_dim=MP, n_groups=MG,
-        ssm_state_size=MN, conv_kernel=conv_kernel, chunk_size=chunk_size,
-        num_attention_heads=NQ, num_key_value_heads=NKV, head_dim=D,
-        n_routed_experts=held, router_experts=router,
-        num_experts_per_tok=num_experts_per_tok,
-        moe_intermediate_size=moe_intermediate_size,
-        moe_shared_expert_intermediate_size=(
-            moe_shared_expert_intermediate_size),
-        routed_scaling_factor=routed_scaling_factor,
-        norm_topk_prob=norm_topk_prob, layer_norm_epsilon=eps,
-        time_step_min=time_step_min, time_step_max=time_step_max,
-        time_step_floor=time_step_floor, expert_rank=expert_rank,
-        dtype=dtype, state_dtype=state_dtype, slots=S, max_len=L,
-        block_size=BS, num_blocks=NB, chunk_tokens=C, eos_id=eos_id, name=name, version=version)
     return DecodeModel(
         decode_program=decode, prefill_program=None, inject_program=None,
         chunk_program=chunk, startup_program=startup,
-        slots=S, max_len=L, vocab_size=V, hidden=H, block_size=BS,
-        num_blocks=NB, chunk_tokens=C, state_names=state_names,
-        kv_width=kv_width, kv_dtype=dtype, slot_states=slot_states,
+        slots=S, max_len=L, vocab_size=vocab, hidden=hidden, block_size=BS,
+        num_blocks=num_blocks, chunk_tokens=C,
+        state_names=parts.state_names, kv_width=parts.kv_width,
+        kv_dtype=parts.dtype, slot_states=parts.slot_states,
         logits_fetch=dec_logits.name, token_fetch=next_token.name,
         counts_fetch=token_counts.name if counts else None,
         count_names=MOE_COUNTS if counts else (),
         prefill_logits_fetch=None, chunk_logits_fetch=chu_logits.name,
         prefill_kv_fetches=[], inject_kv_feeds=[],
-        eos_id=eos_id, name=name, version=version,
-        builder=lambda: build_nemotron_h_model(**kwargs))
+        eos_id=eos_id, name=name, version=version, builder=rebuild)
+
+
+def _geometry(slots, max_len, block_size, num_blocks, chunk_tokens):
+    """``(S, L, BS, NB, C)`` as whole numbers, the arena sized for every
+    slot's full length where ``num_blocks`` is not given."""
+    S, L, BS, C = int(slots), int(max_len), int(block_size), int(chunk_tokens)
+    if not 2 <= C <= L:
+        raise ValueError(f"chunk_tokens must be in [2, {L}], got {C}")
+    return S, L, BS, int(num_blocks) if num_blocks else S * -(-L // BS), C
+
+
+def _held(expert_rank, held, router):
+    """The id of the first expert held here."""
+    offset = int(expert_rank) * held
+    if offset + held > router:
+        raise ValueError(f"expert_rank {expert_rank} x {held} held experts "
+                         f"passes the router's {router}")
+    return offset
+
+
+def build_nemotron_h_model(
+        vocab_size, hidden_size, hybrid_override_pattern, *,
+        mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size,
+        conv_kernel, chunk_size, num_attention_heads, num_key_value_heads,
+        head_dim, n_routed_experts, router_experts, num_experts_per_tok,
+        moe_intermediate_size, moe_shared_expert_intermediate_size,
+        routed_scaling_factor, norm_topk_prob=True, layer_norm_epsilon=1e-5,
+        time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4,
+        expert_rank=0, dtype="bfloat16", state_dtype="float32", slots=4,
+        max_len=64, block_size=16, num_blocks=None, chunk_tokens=16, eos_id=None, name="nemotron_h",
+        version="1"):
+    """Build the ``nemotron_h`` decoder as a paged DecodeModel (module
+    docstring). The sizes are the published ``config.json``'s keys under
+    their own names; ``n_routed_experts`` is how many experts are HELD here
+    and ``router_experts`` how many the router scores (the published
+    count); ``vocab_size`` the rows of the vocabulary held here.
+    ``state_dtype`` is the SSM state's and the convolution tail's (float32
+    as served; a narrower one runs the composite and is what the comparison
+    with the reference has to catch)."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import (
+        ConstantInitializer, NormalInitializer, UniformInitializer)
+
+    V, H = int(vocab_size), int(hidden_size)
+    pattern = str(hybrid_override_pattern)
+    if set(pattern) - set("ME*"):
+        raise ValueError(f"hybrid_override_pattern {pattern!r}: a block is "
+                         "M (Mamba-2), E (experts) or * (attention)")
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    MH, MP, MG, MN = (int(mamba_num_heads), int(mamba_head_dim),
+                      int(n_groups), int(ssm_state_size))
+    d_inner = MH * MP
+    conv_dim = d_inner + 2 * MG * MN
+    in_width = 2 * d_inner + 2 * MG * MN + MH
+    NQ, NKV, D = (int(num_attention_heads), int(num_key_value_heads),
+                  int(head_dim))
+    held, router = int(n_routed_experts), int(router_experts)
+    offset = _held(expert_rank, held, router)
+    eps = float(layer_norm_epsilon)
+    prefix = f"{name}_v{version}"
+    m_layers = [i for i, kind in enumerate(pattern) if kind == "M"]
+    slot_states = []
+    for i in m_layers:
+        slot_states.append((f"{prefix}.conv{i}",
+                            (S, int(conv_kernel) - 1, conv_dim),
+                            state_dtype))
+        slot_states.append((f"{prefix}.ssm{i}", (S, MH, MP, MN),
+                            state_dtype))
+    # rescale_prenorm_residual: one mixer a block
+    parts = _Parts(prefix, dtype, eps, 0.02, 0.02 / math.sqrt(len(pattern)),
+                   R, NKV * D,
+                   [i for i, kind in enumerate(pattern) if kind == "*"],
+                   slot_states)
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+
+    def mamba_attrs(i):
+        return {
+            "conv_w": attr(f"l{i}.conv_w", UniformInitializer(-0.5, 0.5)),
+            "conv_b": attr(f"l{i}.conv_b", UniformInitializer(-0.5, 0.5)),
+            "dt_bias": attr(f"l{i}.dt_bias", _dt_bias(
+                time_step_min, time_step_max, time_step_floor)),
+            "a_log": attr(f"l{i}.a_log", _log_uniform(1.0, 16.0)),
+            "d": attr(f"l{i}.d", ConstantInitializer(1.0)),
+            "norm_w": attr(f"l{i}.mixer_norm", ConstantInitializer(1.0)),
+        }
+
+    def expert_attrs(i):
+        return {
+            "gate": matrix(f"l{i}.gate"),
+            "select_bias": attr(f"l{i}.select_bias",
+                                NormalInitializer(0.0, 0.05)),
+            "w_up": matrix(f"l{i}.w_up"),
+            "w_down": matrix(f"l{i}.w_down", residual=True),
+        }
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The 52 blocks over ``toks``; no position encoding: a step's
+        positions go unread."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        counts = []
+        for i, kind in enumerate(pattern):
+            x = norm(h, f"l{i}.norm")
+            if kind == "M":
+                at = 2 * m_layers.index(i)
+                conv, ssm = (parts.slot_state(program, at),
+                             parts.slot_state(program, at + 1))
+                y = fluid.layers.mamba2_mixer(
+                    proj(x, in_width, f"l{i}.in_proj", out_dtype="float32"),
+                    conv, ssm, wrows, R, mode, MH, MP, MG, MN,
+                    int(conv_kernel), mamba_attrs(i), slot=slot,
+                    positions=positions, chunk_size=int(chunk_size),
+                    epsilon=eps, out_dtype=dtype)
+                out = proj(y, H, f"l{i}.out_proj", residual=True,
+                           out_dtype="float32")
+            elif kind == "*":
+                ctx = attend(i, proj(x, NQ * D, f"l{i}.q"),
+                             proj(x, NKV * D, f"l{i}.k"),
+                             proj(x, NKV * D, f"l{i}.v"))
+                out = proj(ctx, H, f"l{i}.o", residual=True,
+                           out_dtype="float32")
+            else:
+                routed, n = fluid.layers.moe_routed_experts(
+                    x, wrows, R, router, held, int(moe_intermediate_size),
+                    int(num_experts_per_tok), expert_attrs(i),
+                    expert_offset=offset,
+                    score_scale=float(routed_scaling_factor),
+                    normalize=bool(norm_topk_prob), kernel=mode == "step")
+                counts.append(n)
+                shared = proj(
+                    proj(x, int(moe_shared_expert_intermediate_size),
+                         f"l{i}.shared_up", act="relu2"),
+                    H, f"l{i}.shared_down", residual=True,
+                    out_dtype="float32")
+                out = fluid.layers.elementwise_add(routed, shared)
+            h = fluid.layers.elementwise_add(h, out)
+        logits = proj(norm(h, "final_norm"), V, "head", out_dtype="float32")
+        return logits, counts
+
+    return _hybrid_model(
+        parts, stack, lambda: build_nemotron_h_model(**kwargs), vocab=V,
+        hidden=H, slots=S, max_len=L, block_size=BS, num_blocks=NB,
+        chunk_tokens=C, kv_heads=NKV, sm_scale=1.0 / math.sqrt(D),
+        eos_id=eos_id, name=name, version=version)
+
+
+def build_lfm2_model(
+        vocab_size, hidden_size, layer_types, *, num_attention_heads,
+        num_key_value_heads, intermediate_size, num_dense_layers,
+        num_experts, router_experts, num_experts_per_tok,
+        moe_intermediate_size, conv_L_cache=3, rope_theta=1000000.0,
+        routed_scaling_factor=1.0, norm_topk_prob=True, norm_eps=1e-5,
+        initializer_range=0.02, expert_rank=0, dtype="bfloat16",
+        state_dtype="float32", slots=4,
+        max_len=64, block_size=16, num_blocks=None, chunk_tokens=16,
+        eos_id=None, name="lfm2", version="1"):
+    """Build the ``lfm2_moe`` decoder as a paged DecodeModel (module
+    docstring). The sizes are the published ``config.json``'s keys under
+    their own names (``rope_theta`` is ``rope_parameters``'s); a head is
+    ``hidden_size / num_attention_heads`` wide; ``num_experts`` is how many
+    experts are HELD here and ``router_experts`` how many the router scores
+    (the published count). ``state_dtype`` is the convolution tails';
+    ``initializer_range`` the matrices' standard deviation (a tiny preset
+    takes a wider one: at 0.02 a hidden size of 64 leaves the layers
+    nothing to say beside the embedding)."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import NormalInitializer, UniformInitializer
+
+    V, H = int(vocab_size), int(hidden_size)
+    kinds = [str(kind) for kind in layer_types]
+    if set(kinds) - {"conv", "full_attention"}:
+        raise ValueError(f"layer_types {kinds}: an operator is conv or "
+                         "full_attention")
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    NQ, NKV = int(num_attention_heads), int(num_key_value_heads)
+    D = H // NQ
+    taps, dense = int(conv_L_cache), int(num_dense_layers)
+    held, router = int(num_experts), int(router_experts)
+    offset = _held(expert_rank, held, router)
+    prefix = f"{name}_v{version}"
+    c_layers = [i for i, kind in enumerate(kinds) if kind == "conv"]
+    # two sub-layers a layer write into the residual
+    std = float(initializer_range)
+    parts = _Parts(
+        prefix, dtype, float(norm_eps), std,
+        std / math.sqrt(2 * len(kinds)), R, NKV * D,
+        [i for i, kind in enumerate(kinds) if kind == "full_attention"],
+        [(f"{prefix}.conv{i}", (S, taps - 1, H), state_dtype)
+         for i in c_layers])
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+    bound = 1.0 / math.sqrt(taps)       # conv1d's default draw
+
+    def expert_attrs(i):
+        return {
+            "gate": matrix(f"l{i}.gate"),
+            "select_bias": attr(f"l{i}.expert_bias",
+                                NormalInitializer(0.0, 0.05)),
+            "w_gate": matrix(f"l{i}.w1"),
+            "w_up": matrix(f"l{i}.w3"),
+            "w_down": matrix(f"l{i}.w2", residual=True),
+        }
+
+    def heads(t, n, positions, suffix):
+        """QK-norm, then the rotation, over each of ``t``'s ``n`` heads."""
+        lead = [int(d) for d in t.shape[:2]]
+        t = norm(fluid.layers.reshape(t, lead + [n, D]), suffix,
+                 out_dtype="float32")
+        return fluid.layers.reshape(fluid.layers.rotary_embedding(
+            t, positions, theta=float(rope_theta), out_dtype=dtype),
+            lead + [n * D])
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The 40 layers over ``toks``: operator, then feed-forward."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        embed = program.global_block().var(f"{prefix}.embed")
+        counts = []
+        for i, kind in enumerate(kinds):
+            x = norm(h, f"l{i}.operator_norm")
+            if kind == "conv":
+                y = fluid.layers.gated_short_conv(
+                    proj(x, 3 * H, f"l{i}.in_proj", out_dtype="float32"),
+                    parts.slot_state(program, c_layers.index(i)), wrows, R,
+                    mode, taps,
+                    attr(f"l{i}.conv_w", UniformInitializer(-bound, bound)),
+                    slot=slot, positions=positions, out_dtype=dtype)
+                out = proj(y, H, f"l{i}.out_proj", residual=True,
+                           out_dtype="float32")
+            else:
+                ctx = attend(
+                    i,
+                    heads(proj(x, NQ * D, f"l{i}.q", out_dtype="float32"),
+                          NQ, positions, f"l{i}.q_layernorm"),
+                    heads(proj(x, NKV * D, f"l{i}.k", out_dtype="float32"),
+                          NKV, positions, f"l{i}.k_layernorm"),
+                    proj(x, NKV * D, f"l{i}.v"))
+                out = proj(ctx, H, f"l{i}.out_proj", residual=True,
+                           out_dtype="float32")
+            h = fluid.layers.elementwise_add(h, out)
+            x = norm(h, f"l{i}.ffn_norm")
+            if i < dense:
+                gated = fluid.layers.elementwise_mul(
+                    proj(x, int(intermediate_size), f"l{i}.w1", act="silu",
+                         out_dtype="float32"),
+                    proj(x, int(intermediate_size), f"l{i}.w3",
+                         out_dtype="float32"))
+                out = proj(fluid.layers.cast(gated, dtype), H, f"l{i}.w2",
+                           residual=True, out_dtype="float32")
+            else:
+                out, n = fluid.layers.moe_routed_experts(
+                    x, wrows, R, router, held, int(moe_intermediate_size),
+                    int(num_experts_per_tok), expert_attrs(i),
+                    expert_offset=offset,
+                    score_scale=float(routed_scaling_factor),
+                    normalize=bool(norm_topk_prob), norm_epsilon=1e-6,
+                    kernel=mode == "step")
+                counts.append(n)
+            h = fluid.layers.elementwise_add(h, out)
+        logits = fluid.layers.matmul(norm(h, "embedding_norm"), embed,
+                                     transpose_y=True, out_dtype="float32")
+        return logits, counts
+
+    return _hybrid_model(
+        parts, stack, lambda: build_lfm2_model(**kwargs), vocab=V, hidden=H,
+        slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
+        kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
+        version=version)

@@ -153,8 +153,10 @@ class DecodeMetrics(ServingMetrics):
         # expert, held experts with at least one token (of held experts x
         # expert layers a step: a constant of the model, like the
         # state-space layers a stepping slot updates, so neither is
-        # counted here)
+        # counted here), and the busiest held expert's tokens summed over
+        # the expert layers (the straggler a grouped product waits for)
         "moe_assignments", "moe_held_assignments", "moe_touched_experts",
+        "moe_peak_expert_tokens",
         # the KV block pool and the host tier (counted by pool.py through
         # the sink the engine hands it): blocks handed out, those of them
         # that recycled a cached block, evicted blocks the tier took; and

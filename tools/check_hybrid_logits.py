@@ -1,9 +1,11 @@
-"""The served ``nemotron_h`` configuration against its plain reference,
-outside any timed window, and the readings the cell's limits are set from
-(``benchmark/traffic/reasoning_steady.json``; PERF.md section 2).
+"""A served hybrid configuration (``nemotron3_nano_30b_a3b``,
+``lfm2_24b_a2b``: any whose file names a ``builder`` and a ``reference``)
+against its plain reference, outside any timed window, and the readings the
+cell's limits are set from (its traffic file; PERF.md section 2).
 
-    python3 tools/check_hybrid_logits.py --seed <n> [--requests 32]
-        [--steps 192] [--faults ssm,conv,kv,kv_all]
+    python3 tools/check_hybrid_logits.py --seed <n>
+        [--config nemotron3_nano_30b_a3b] [--traffic reasoning_steady]
+        [--requests 32] [--steps 192] [--faults ssm,conv,kv,kv_all,positions]
         [--references float8_e4m3fn,operands:bfloat16]
         [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
         [--dump chiprun_out/rows.npz] [--rehearse-cpu]
@@ -24,7 +26,9 @@ one of its first ``check_tokens`` tokens is behind by more than
 The served tokens are read once as served and once for each of
 ``--faults``: one layer's SSM state (``ssm``), convolution tail (``conv``)
 or K arena (``kv``; ``kv_all``: every attention layer's) put back to what it
-was after every decode step (a stale row). ``--references``
+was after every decode step (a stale row), or every decode step's
+positions one too far (``positions``: a rotation is relative, so the fault
+is the step's rows turned against the prompt's). ``--references``
 reads the sound tokens again with the reference computed otherwise:
 ``<dtype>`` rounds its weights through that dtype (the precision below the
 served one), ``operands:<dtype>`` the left operand of its products (the
@@ -62,6 +66,10 @@ def _stale(entry, fault):
     def run(kind, feeds, span=None):
         if kind != "step":
             return launch(kind, feeds, span)
+        if fault == "positions":
+            step = np.array(feeds[m.DEC_STEP])
+            step[:, 1] += 1
+            feeds = dict(feeds, **{m.DEC_STEP: step})
         kept = [jnp.array(entry._scope.find_var(n), copy=True)
                 for n in names]
         out = launch(kind, feeds, span)
@@ -150,6 +158,8 @@ def _flips(routing, other, held, k):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--config", default="nemotron3_nano_30b_a3b")
+    ap.add_argument("--traffic", default="reasoning_steady")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--steps", type=int, default=192)
     ap.add_argument("--faults", default="")
@@ -163,13 +173,16 @@ def main(argv=None):
     ap.add_argument("--rehearse-cpu", action="store_true")
     args = ap.parse_args(argv)
 
+    import importlib
+
     from benchmark import manifest, workgen
-    from benchmark.builders import nemotron_h_engine
     from paddle_tpu import kernels
 
     bench = manifest.load_manifest()
-    config = manifest.load_config(bench, "nemotron3_nano_30b_a3b")
-    traffic = manifest.sizes(manifest.load_traffic("reasoning_steady"),
+    config = manifest.load_config(bench, args.config)
+    builder = importlib.import_module(
+        "benchmark.builders." + config["builder"])
+    traffic = manifest.sizes(manifest.load_traffic(args.traffic),
                              args.rehearse_cpu)
     if not args.rehearse_cpu:
         os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
@@ -183,8 +196,8 @@ def main(argv=None):
     mode = args.kernels or ("interpret" if args.rehearse_cpu else None)
     rng = np.random.default_rng(args.seed)
     with kernels.scoped_mode(mode or kernels.mode()):
-        system = nemotron_h_engine.build(config, traffic, args.seed,
-                                         args.rehearse_cpu)
+        system = builder.build(config, traffic, args.seed,
+                               args.rehearse_cpu)
         lengths = workgen.stratified_lengths(traffic["prompt_len"],
                                              args.requests)
         steps = min(args.steps, traffic["max_total_len"] - max(lengths))
@@ -198,9 +211,11 @@ def main(argv=None):
             undo()
         system.engine.shutdown()
     keys = system.config
-    held = (system.expert_offset,
-            system.expert_offset + keys["n_routed_experts"])
-    report = {"seed": args.seed, "requests": len(prompts), "steps": steps,
+    # how many experts are held here: the count the configuration cut
+    (cut,) = [k for k in config["reduced"] if k.endswith("experts")]
+    held = (system.expert_offset, system.expert_offset + keys[cut])
+    report = {"seed": args.seed, "config": args.config,
+              "requests": len(prompts), "steps": steps,
               "prompt_lengths": lengths,
               "state_dtype": config["settings"]["state_dtype"],
               "kernels": mode or "auto",
