@@ -33,10 +33,13 @@ alone):
   iteration launches its successor, fed those tokens as the device
   array they are, BEFORE it fetches them: depth one, decided per
   iteration from the scheduler's own state (``_iterate``), the same
-  tokens in either order. From the host a step takes ONE array, put
-  once (``dec_step``, int32 ``[S, 4 + blocks per slot]``: each stepping
-  slot's token or -1, cursor, attention length, write row and block
-  table); the step program makes its positions, causal bias, row map
+  tokens in either order. A slot that was not in the step in flight
+  (new: its first token came off its prompt's last chunk) joins the next
+  one with its token from the host, beside the others' on the device:
+  the step program chooses slot by slot. From the host a step takes ONE
+  array, put once (``dec_step``, int32 ``[S, 4 + blocks per slot]``: each
+  stepping slot's token or -1, cursor, attention length, write row and
+  block table); the step program makes its positions, causal bias, row map
   and write rows of it on the device (``paged_step_feeds``). A put costs
   this host ~0.25 ms whatever its size (PERF.md §6, PR 39), so the
   count of puts, not their bytes, is what a step's launch pays.
@@ -52,7 +55,16 @@ alone):
   interleaved with decode steps, so a 32k-token admission never stalls
   in-flight generations for more than one chunk's compute. Chunks fully
   covered by radix-shared blocks are skipped (shared prefixes share
-  prefill work AND storage).
+  prefill work AND storage). The whole of such an admission runs in the
+  launch-ahead order: the arrival is admitted (blocks from the free
+  list, a slot in mode ``"prefill"``) and every chunk launched, the
+  last one too, with the decode step in flight untouched; the last
+  chunk's one logits row is fetched behind the NEXT step's launch.
+  What still DRAINS the step in flight first, and says why
+  (``serving_decode_drains_total{why=}``): a one-shot prompt, a
+  speculative or beam request, a parked or deferred session, blocks the
+  free list cannot cover, a new slot that samples or masks a grammar, a
+  brownout move, a stop, an open breaker, a step that nothing follows.
 * **speculative** — a draft model (just another ``(model, version)``
   registry entry) greedily proposes k tokens; the target verifies all
   of them in ONE batch-prefill forward and emits the longest matching
@@ -105,11 +117,14 @@ one; a launch span also says how many host arrays it put (``puts``: one
 a decode step), their ``bytes``, how long the host spent inside
 ``jax.device_put`` and inside the executable's call, ``decode::step``
 whether it was launched ahead of the previous step's fetch (``ahead``),
-and a ``decode::step_fetch`` made with nothing launched over it, why
+``decode::chunk`` whether a step was in flight (``ahead``) and whether it
+is its prompt's last (``last``), that chunk's ``decode::chunk_fetch``
+whether a launch was made over it first (``deferred``), and a
+``decode::step_fetch`` made with nothing launched over it, why
 (``drain``). Always on: a time stamp per token on the ``Response``, taken
 when the host has the token, the bytes that cross the device boundary,
-the steps that fetched the whole logits and the steps launched ahead
-(``DecodeMetrics``).
+the steps that fetched the whole logits, the steps and the chunks
+launched ahead and the drains by reason (``DecodeMetrics``).
 """
 
 import threading
@@ -335,6 +350,21 @@ class _LaunchedStep:
         self.launch = launch
 
 
+class _LaunchedChunk:
+    """A prompt's LAST chunk on the device, with the row picker behind
+    it, whose one ``[V]`` logits row (``row``, a device array) the host
+    has not fetched yet. ``state`` is the ``_Slot`` of ``slot`` at the
+    launch, still in mode ``"prefill"`` with every position done: a slot
+    rejected since (a deadline, a lost arena) gets no token."""
+
+    __slots__ = ("slot", "state", "row")
+
+    def __init__(self, slot, state, row):
+        self.slot = slot
+        self.state = state
+        self.row = row
+
+
 class _ParkedSession:
     """One preempted in-flight session waiting off-device. ``states``
     holds the live ``_Slot`` objects (host state — sampling stream,
@@ -425,6 +455,7 @@ class _ModelEntry:
         self._bt_seen = 0       # brownout transitions already counted
         self._iteration = 0     # decode::iterate spans opened (traced only)
         self._launched = None   # the _LaunchedStep in flight, if any
+        self._chunk_rows = []   # [_LaunchedChunk] last chunks not landed
         self._admit_seq = 0
         self._chunk_throttle = False
         self.victim_policy = None   # callable([slot ids]) -> slot id
@@ -640,8 +671,10 @@ class _ModelEntry:
         self._pool.reset()
         self._blocks.reset()
         self._slots = [None] * m.slots
-        # a step in flight read the lost arena: it is never delivered
+        # a step in flight read the lost arena: it is never delivered,
+        # nor is a last chunk's row
         self._launched = None
+        self._chunk_rows = []
 
     def relaunch(self):
         """The circuit breaker's replacement replica: rebuild programs
@@ -689,28 +722,44 @@ class _ModelEntry:
         interleaving deterministically. Returns True when the loop
         should exit.
 
-        With no decode step in flight the phases run as they always
-        did: expire, (breaker), admit up to the free slots, advance AT
-        MOST ONE prefill chunk, run one verify cycle per speculative
-        slot, then `_step`: feeds, launch, and — when a slot's policy
-        needs the logits — the fetch and the host half at once. A step
-        whose slots need only their tokens is left IN FLIGHT instead,
-        its cursors already advanced, and the next iteration decides at
-        its top (`_drain_reason`) between two orders:
+        The phases, in this order: expire, (breaker), resume parked
+        sessions, admit up to the free slots, advance AT MOST ONE
+        prefill chunk, run one verify cycle per speculative slot, then
+        `_step`: feeds, launch, and — when a slot's policy needs the
+        logits — the fetch and the host half at once. A step whose slots
+        need only their tokens is left IN FLIGHT instead, its cursors
+        already advanced, and the next iteration runs in one of two
+        orders:
 
-        * **ahead**: nothing of this iteration would wait on the device
-          or change who steps. No admission runs, a prefilling slot
-          gets a chunk that is not its prompt's last (a launch, no
-          fetch), and `_step` launches step N+1, fed step N's tokens as
-          the device array they are, BEFORE it fetches and delivers
-          step N: the host's wait for N's tokens hides under N+1.
-        * **drain**: anything else. Step N is fetched and delivered
-          first, then the iteration runs as above. A produced token
-          never waits behind an admission's prefill, and no slot is
-          retired in the middle of one.
+        * **ahead**: nothing of this iteration has to see the device or
+          change who steps. An arrival whose admission is a block
+          acquisition and a new prefilling slot is admitted, a
+          prefilling slot gets its chunk, the prompt's LAST one too (a
+          launch and the row picker's, no fetch), and `_step` launches
+          step N+1 for the slots that already step, fed step N's tokens
+          as the device array they are, BEFORE it fetches and delivers
+          step N and then the last chunk's one logits row, which the
+          device finished before N+1 began: the host's waits hide under
+          N+1. The slot that row starts joins step N+2 with its token
+          from the host beside the others' on the device.
+        * **drain**: step N is fetched and delivered first, with nothing
+          launched over it (`_drain`, which says why and counts it), and
+          the iteration then runs as if no step had been in flight. Kept
+          for what has to see the device or changes who steps
+          (`_drain_reason`; for a picked request, `_admission_waits`):
+          a one-shot prompt, whose ``decode::prefill_fetch`` waits — a
+          produced token never waits behind an admission's prefill —, a
+          speculative or beam request, a parked or deferred session, a
+          block acquisition the free list cannot cover, a brownout move,
+          a stop, an open breaker, and a step that nothing follows.
 
-        Depth one: at most one step is on the device unread, and the
-        two orders share every line but the place of the fetch."""
+        The device runs what it is given in launch order, which is what
+        keeps the first order sound: a step launched before a slot
+        retired writes its wasted row before the next owner's chunk,
+        launched later, overwrites it, and a recurrent slot's chunk at
+        position 0 resets its state after the wasted step. Depth one: at
+        most one step is on the device unread, and the two orders share
+        every line but the place of the fetches."""
         with _span("decode::iterate") as sp:
             if sp is not None:
                 self._iteration += 1
@@ -730,13 +779,11 @@ class _ModelEntry:
                     and self._pool.active_count == 0
                     and not self._parked and not self._pending):
                 return True
-            queued = not self._queue.empty()
         moved = self._brownout_tick()
         if self._launched is not None:
-            why = "brownout" if moved else self._drain_reason(queued)
+            why = "brownout" if moved else self._drain_reason()
             if why is not None:
                 self._drain(why)
-        ahead = self._launched is not None
         if self._breaker is not None and not self._stop:
             verdict, wait_s = self._breaker.gate()
             if verdict == "wait":
@@ -760,14 +807,17 @@ class _ModelEntry:
                     self._breaker_event(self._breaker.record_failure())
                     return False
         # parked sessions and deferred admissions get first claim on
-        # freed capacity — FIFO, before any new pick from the queue. With
-        # a step in flight there is neither, and a request that arrived
-        # since the decision waits one iteration for the drain.
-        admitted = (0 if ahead else
-                    self._service_parked() + self._admit_free_slots())
+        # freed capacity — FIFO, before any new pick from the queue (with
+        # a step in flight there is neither: `_drain_reason`). Pick
+        # first, then decide: the picked requests say whether their
+        # admission may run under the step in flight.
+        admitted = self._service_parked()
+        picked = self._pick_free_slots()
+        if self._launched is not None and self._admission_waits(picked):
+            self._drain("admission")
+        admitted += self._admit_picked(picked)
         progressed = self._advance_prefills() + self._advance_spec()
-        if not any(st is not None and st.mode in ("decode", "beam")
-                   for st in self._slots):
+        if not self._any_stepping():
             # nothing decodable AND this round moved nothing — either
             # the queue is empty, or everything queued is blocked on a
             # tenant cap held by another entry's in-flight work; poll,
@@ -779,6 +829,11 @@ class _ModelEntry:
             return False
         self._step()
         return False
+
+    def _any_stepping(self):
+        """Whether some slot is fed to decode steps."""
+        return any(st is not None and st.mode in ("decode", "beam")
+                   for st in self._slots)
 
     def _wait(self, why, timeout):
         """Sleep on the condition (held by the caller) until a submit or
@@ -807,23 +862,25 @@ class _ModelEntry:
         return (len(st.generated) + st.ahead < st.request.max_new
                 and st.cursor < self._model.max_len)
 
-    def _drain_reason(self, queued):
+    def _drain_reason(self):
         """Why the step in flight has to be fetched and delivered before
-        this iteration goes on, or None when the next step may launch
-        ahead of that fetch. From state that is there: a phase of this
-        iteration would wait on the device or change who steps
-        (``queued`` work and a free slot, a parked or deferred session,
-        a speculative slot's verify, a prompt's last chunk), the engine
-        is stopping or its breaker is not closed, or no slot of the step
-        in flight steps again."""
+        this iteration goes on, or None when the iteration may run under
+        it and launch the next step ahead of its fetch. From state that
+        is there: a phase of this iteration has to see the device or
+        changes who steps beyond one slot (a parked or deferred session,
+        a speculative slot's verify, the last chunk of a BEAM request's
+        prompt, whose first selection forks slots and copies arena rows),
+        the engine is stopping or its breaker is not closed, or no slot
+        of the step in flight steps again. An arrival and the last chunk
+        of any other prompt are no reason: `_admission_waits` decides for
+        the requests picked, and a last chunk is a launch whose row
+        lands behind the next step's (`_advance_prefills`, `_step`)."""
         if self._stop:
             return "shutdown"
         if self._breaker is not None and self._breaker.state != "closed":
             return "breaker"
         if self._parked or self._pending:
             return "parked"
-        if queued and self._pool.free_count > 0:
-            return "admission"
         steps = False
         for st in self._slots:
             if st is None:
@@ -831,7 +888,8 @@ class _ModelEntry:
             if st.mode == "spec":
                 return "spec"
             if st.mode == "prefill":
-                if st.plen - st.done <= self._model.chunk_tokens:
+                if (st.request.beam is not None and 0 < st.plen - st.done
+                        <= self._model.chunk_tokens):
                     return "prefill"
             elif self._steps_again(st):
                 steps = True
@@ -851,6 +909,12 @@ class _ModelEntry:
 
     # -- admission (blocks + prefill/inject into a free slot) -------------
     def _admit_free_slots(self):
+        return self._admit_picked(self._pick_free_slots())
+
+    def _pick_free_slots(self):
+        """Take from the queue what the free slots can hold. A picked
+        request is committed to this entry (its tenant's in-flight
+        reservation is held): `_admit_picked` has to follow."""
         picked = []
         # brownout L3+: LOW-lane dispatch quota drops to zero — queued
         # LOW requests wait out the pressure episode instead of landing
@@ -872,6 +936,39 @@ class _ModelEntry:
                 rows += req.rows
             # the round's picks are ONE drain event for the rate EWMA
             self._queue.note_drained()
+        return picked
+
+    def _admission_waits(self, picked):
+        """Whether admitting ``picked`` has to see the device or changes
+        who steps, so that a step in flight is drained first. It does
+        not when every one of them is a block acquisition from the free
+        list and a new slot in mode ``"prefill"`` (`_takes_chunks`):
+        host work, under which the step in flight goes on. It does for a
+        one-shot prompt (its ``decode::prefill_fetch`` waits for the
+        device: a produced token never waits behind an admission's
+        prefill), for a speculative or beam request, and for blocks the
+        free list cannot cover (an eviction's write-back reads the
+        arenas, an exhausted pool parks a victim)."""
+        bs = self._model.block_size
+        blocks = 0
+        for req in picked:
+            if (req.draft_key is not None or req.beam is not None
+                    or not self._takes_chunks(req)):
+                return True
+            blocks += (len(req.prompt) + bs - 1) // bs
+        return blocks > self._blocks.free_count
+
+    def _takes_chunks(self, req):
+        """Whether a prompt streams through the chunk program: one the
+        chunk budget does not cover, and a recurrent model's every
+        prompt, from its first token (the chunks build the slot's state
+        as they go; no block of it was ever registered, so nothing is
+        shared)."""
+        m = self._model
+        return bool(m.chunk_tokens and "chunk" in self._entries
+                    and (len(req.prompt) > m.chunk_tokens or m.recurrent))
+
+    def _admit_picked(self, picked):
         for req in picked:
             self._engine._tenant_unqueue(req.tenant)
             if self._admit_one(req) == "deferred":
@@ -1414,11 +1511,7 @@ class _ModelEntry:
             return
         prompt = req.prompt
         plen = len(prompt)
-        if (m.chunk_tokens and "chunk" in self._entries
-                and (plen > m.chunk_tokens or m.recurrent)):
-            # a recurrent model's every prompt takes this path, from its
-            # first token: the chunks build the slot's state as they go
-            # (no block of it was ever registered, so nothing is shared)
+        if self._takes_chunks(req):
             blocks, shared_len = self._acquire_blocks(req)
             st = _Slot(req, mode="prefill")
             st.seq = self._admit_seq
@@ -1558,11 +1651,23 @@ class _ModelEntry:
         (round-robin): the per-iteration prompt work is bounded by
         ``chunk_tokens``, which is the fairness contract — in-flight
         decode slots stall for at most one chunk's compute per admitted
-        long prompt."""
+        long prompt.
+
+        A chunk is a launch and no fetch, the prompt's LAST one too: the
+        row picker is launched behind it and the ``[V]`` row it cuts
+        stays on the device in ``self._chunk_rows`` until `_step` has
+        launched the next step over it (`_land_chunks`); the slot stays
+        in mode ``"prefill"`` till then. With a step in flight the chunk
+        is a launch AHEAD (``ahead=`` on ``decode::chunk``,
+        ``serving_chunk_launches_ahead_total``). Where no step is in
+        flight and no slot steps there is nothing to launch over it, and
+        the row is fetched at once; so is a beam request's, whose first
+        selection forks slots and copies arena rows (`_drain_reason`
+        drained for it)."""
         m = self._model
-        pref = [s for s in range(m.slots)
-                if self._slots[s] is not None
-                and self._slots[s].mode == "prefill"]
+        pref = [s for s, st in enumerate(self._slots)
+                if st is not None and st.mode == "prefill"
+                and st.done < st.plen]
         if not pref:
             return 0
         # brownout L2+: halve the chunk budget (one chunk every OTHER
@@ -1585,6 +1690,8 @@ class _ModelEntry:
         start = st.done
         stop = min(start + C, st.plen)
         real = stop - start
+        last = stop == st.plen
+        ahead = self._launched is not None
         toks = np.zeros((1, C), "int64")
         toks[0, :real] = req.prompt[start:stop]
         pos = np.zeros((1, C), "int64")
@@ -1603,7 +1710,8 @@ class _ModelEntry:
             with profiler.RecordEvent("decode::chunk") as ev:
                 faults.fire("decode.chunk")
                 if ev.span is not None:
-                    ev.span.set(request=req.id, tokens=real)
+                    ev.span.set(request=req.id, tokens=real, ahead=ahead,
+                                last=last)
                 feeds = {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
@@ -1621,42 +1729,74 @@ class _ModelEntry:
                                  slot=s)
                 fetches = self._run("chunk", feeds, ev.span)
         except Exception as e:
+            # with a step in flight the slots of both are lost, and that
+            # step is not delivered
             self._arena_lost(f"chunk-prefill failure: {e}")
             return 1
-        self._metrics.observe_chunk(real, time.perf_counter() - t0)
+        self._metrics.observe_chunk(real, time.perf_counter() - t0, ahead)
         st.done = stop
-        if st.done < st.plen:
+        if not last:
             return 1
-        with _span("decode::chunk_fetch") as sp:
-            # the one row a prompt's last chunk is run for, [V] of the
-            # [1, C, V] that stay on the device
-            logits_row = self._fetch(self._pickers["chunk"](
-                fetches[0], np.int32(real - 1)))
-            if sp is not None:
-                sp.set(request=req.id, bytes=logits_row.nbytes)
-        if not m.recurrent:
-            self._blocks.register_prompt_blocks(st.blocks, req.prompt)
-        st.cursor = st.plen
-        if req.beam is not None:
-            st.mode = "beam"
-            try:
-                self._begin_beam(s, logits_row)
-            except _ArenaInvalidError as e:
-                self._arena_lost(f"beam fork inject failure: {e}")
-            return 1
-        st.mode = "decode"
-        st.sampling = req.sampling
-        if req.grammar is not None:
-            st.grammar = GrammarConstraint(req.grammar)
-        first = self._choose_token(st, logits_row, device_masked=False)
-        st.last_token = first
-        st.generated = [first]
-        req.response.token_times.append(time.perf_counter())
-        self._metrics.incr("prefill_tokens")
-        self._metrics.tenant_incr("tokens", req.tenant)
-        if self._finished(st):
-            self._retire(s)
+        # the one row a prompt's last chunk is run for, [V] of the
+        # [1, C, V] that stay on the device
+        self._chunk_rows.append(_LaunchedChunk(s, st, self._pickers["chunk"](
+            fetches[0], np.int32(real - 1))))
+        if req.beam is not None or not (ahead or self._any_stepping()):
+            self._land_chunks(deferred=False)
         return 1
+
+    def _land_chunks(self, deferred):
+        """Fetch every last chunk's logits row that is still on the
+        device and start its slot: the prompt's blocks registered, the
+        cursor at the prompt's end, the first token chosen and stamped
+        (a beam request's first selection), the slot in mode
+        ``"decode"`` with its token on the HOST, or retired where that
+        token ends the request, or rejected where its deadline ran out
+        meanwhile (finished wins over expired, as in `_sample`).
+        ``deferred`` says whether a launch was made over the row since
+        its chunk's (``decode::chunk_fetch`` carries it): the fetch is
+        then of a buffer that is ready, or nearly, since the chunk ran
+        BEFORE that launch."""
+        if not self._chunk_rows:
+            return
+        landing, self._chunk_rows = self._chunk_rows, []
+        for rec in landing:
+            s, st = rec.slot, rec.state
+            if self._slots[s] is not st:
+                continue    # rejected since the launch: the row is dropped
+            req = st.request
+            with _span("decode::chunk_fetch") as sp:
+                logits_row = self._fetch(rec.row)
+                if sp is not None:
+                    sp.set(request=req.id, bytes=logits_row.nbytes,
+                           deferred=deferred)
+            if not self._model.recurrent:
+                self._blocks.register_prompt_blocks(st.blocks, req.prompt)
+            st.cursor = st.plen
+            if req.beam is not None:
+                st.mode = "beam"
+                try:
+                    self._begin_beam(s, logits_row)
+                except _ArenaInvalidError as e:
+                    self._arena_lost(f"beam fork inject failure: {e}")
+                continue
+            st.mode = "decode"
+            st.sampling = req.sampling
+            if req.grammar is not None:
+                st.grammar = GrammarConstraint(req.grammar)
+            first = self._choose_token(st, logits_row, device_masked=False)
+            st.last_token = first
+            st.generated = [first]
+            now = time.perf_counter()
+            req.response.token_times.append(now)
+            self._metrics.incr("prefill_tokens")
+            self._metrics.tenant_incr("tokens", req.tenant)
+            if self._finished(st):
+                self._retire(s)
+            elif req.expired(now):
+                self._reject_in_flight(req, DeadlineExceededError(
+                    "deadline expired mid-generation after 1 tokens"),
+                    slot=s)
 
     # -- speculative decoding ----------------------------------------------
     def _advance_spec(self):
@@ -2241,22 +2381,37 @@ class _ModelEntry:
                 return False
         return True
 
+    def _rides_ahead(self, st):
+        """Whether a stepping slot needs nothing of a step but its
+        token, so that the step may stay in flight: greedy, no grammar
+        (its next mask follows from the token's VALUE), no beam
+        hypothesis. `_tokens_suffice` asks the same of a built step."""
+        return (st.mode == "decode" and st.grammar is None
+                and (st.sampling is None or st.sampling.greedy))
+
     def _step(self):
         """One decode iteration: feeds and launch, then the ONE fetch of
-        a step and its host half, in one of two orders.
+        a step and its host half, in one of two orders, then the logits
+        row of a prompt's last chunk that was launched before it.
 
         The cursor half of the host's work (`_advance_cursors`) runs as
         soon as the launch has returned. A step that `_tokens_suffice`
         and that steps no grammar is then left IN FLIGHT. If one was in
         flight already, this is the launch AHEAD of its fetch: the feeds
         were built from cursors that count it, its ``[S, 1]`` tokens are
-        this step's token feed without leaving the device, and it is
-        fetched and delivered (`_land`) only now, under the step just
+        this step's token feed without leaving the device (a slot that
+        was not in it, new since, is fed its own from the host), and it
+        is fetched and delivered (`_land`) only now, under the step just
         launched. Any other step (a sampled slot, a beam group, a
         grammar, a model without ``token_fetch``) lands in this same
         body, as every step once did. A step in flight that this one
         cannot follow (`_step_feeds` names why), or that nothing
         follows, is drained first.
+
+        Last, what lies behind the launch: every last chunk's row
+        (`_land_chunks`). The device ran that chunk BEFORE the step just
+        launched, so the fetch finds it ready or nearly, and the slot it
+        starts steps with the NEXT launch, its token from the host.
 
         The fetch (``decode::step_fetch``, where the host waits for the
         device unless the step has finished under its successor) brings
@@ -2273,6 +2428,8 @@ class _ModelEntry:
         if built is None:
             if prev is not None:
                 self._drain("idle")
+            else:
+                self._land_chunks(deferred=False)
             return
         feeds, active, groups = built
         t0 = time.perf_counter()
@@ -2302,14 +2459,14 @@ class _ModelEntry:
             self._metrics.incr("decode_steps_ahead")
             self._launched = step
             self._land(prev, t0)
-        elif step.tokens_only and not any(
-                st.grammar is not None for st in step.states):
+        elif step.tokens_only and all(map(self._rides_ahead, step.states)):
             step.host_s = time.perf_counter() - t0
             self._launched = step
         else:
             # a grammar's next mask follows from the token's VALUE, even
             # where the device adds it: such a step lands here too
             self._land(step, t0)
+        self._land_chunks(deferred=True)
 
     def _traced_feeds(self):
         with _span("decode::feeds") as sp:
@@ -2333,10 +2490,14 @@ class _ModelEntry:
 
     def _drain(self, why):
         """Fetch and deliver the step in flight with nothing launched
-        over it: the host waits for the device here, and
-        ``decode::step_fetch`` says why (``drain=``)."""
+        over it, then every last chunk's row: the host waits for the
+        device here, ``decode::step_fetch`` says why (``drain=``), and
+        ``serving_decode_drains_total{why=}`` counts it, tracing on or
+        off."""
         step, self._launched = self._launched, None
+        self._metrics.count_drain(why)
         self._land(step, time.perf_counter(), drain=why)
+        self._land_chunks(deferred=False)
 
     def _land(self, step, t0, drain=None):
         """The ONE fetch of a launched step and the half of the host's
@@ -2399,11 +2560,17 @@ class _ModelEntry:
         step's ``[S, 1]`` output itself, every stepping slot's token -1:
         rows of slots that do not step are ignored as ever (their write
         row is the sentinel, their length 0, so their bias all
-        ``NEG_INF``). That holds only if every stepping
-        slot has its token in that step and none has to be parked; else
-        nothing is built and the reason to drain comes back as a string.
-        What the loop has done before is done again unchanged after the
-        drain: a block opened stays opened."""
+        ``NEG_INF``). A slot that was NOT in that step (new since: its
+        first token came off a prompt's last chunk, ``ahead`` 0) is fed
+        its token from the host in the same array, beside the others'
+        -1. That holds only if such a slot needs nothing but its token
+        (`_rides_ahead`) and no slot has to be parked; else nothing is
+        built and the reason to drain comes back as a string: ``"slots"``
+        for a new slot that samples, masks a grammar or is a beam
+        hypothesis (its step brings the logits over and lands in the body
+        that launched it), ``"park"``. What the loop has done before is
+        done again unchanged after the drain: a block opened stays
+        opened."""
         m = self._model
         S = m.slots
         step = m.step_feed()
@@ -2419,8 +2586,10 @@ class _ModelEntry:
                 continue
             if launched is not None:
                 if not st.ahead:
-                    return "slots"      # its last token is on the host
-                if not self._steps_again(st):
+                    # new since that launch: its last token is on the host
+                    if not self._rides_ahead(st):
+                        return "slots"
+                elif not self._steps_again(st):
                     continue
             # make the cursor position writable: allocate a fresh block
             # when it opens a new chunk, COW when it lands in a SHARED
@@ -2480,7 +2649,7 @@ class _ModelEntry:
                 active.append(s)
             m.fill_step(step, s, st.cursor, st.table,
                         self._row_of(st, st.cursor),
-                        -1 if launched is not None else st.last_token)
+                        -1 if st.ahead else st.last_token)
             live_blocks += st.cursor // m.block_size + 1
             if dmask is not None and st.grammar is not None:
                 # the grammar's next-token constraint rides in as DATA —

@@ -29,11 +29,26 @@ only its tokens stays on the device after its launch, and the scheduler
 launches the next one, fed those tokens as a device array, AHEAD of the
 fetch: ``serving_decode_steps_ahead_total`` counts such launches,
 beside ``serving_step_launches_total`` that counts all, and the
-``decode::step`` span says ``ahead=True|False``. A DRAIN is the other
-order: the step in flight is fetched and delivered with nothing launched
-over it, because the iteration is about to admit, resume, verify, end a
-prompt's prefill, park, stop, or has nothing more to step; its
-``decode::step_fetch`` span says why (``drain=``). Every step is observed
+``decode::step`` span says ``ahead=True|False``. A chunked admission rides
+in the same order: an arrival whose admission is a block acquisition and
+a slot in mode ``"prefill"`` is admitted under the step in flight, and
+every chunk of its prompt, the last one too, is a launch and no fetch
+(``serving_chunk_launches_ahead_total`` counts the chunk launches made with
+a step in flight, beside ``serving_chunk_runs_total`` that counts all;
+``decode::chunk`` says ``ahead=`` and ``last=``). The last chunk's one
+logits row is fetched behind the NEXT step's launch (``decode::chunk_fetch``
+says ``deferred=True``), and the slot it starts joins the step after with
+its token from the host. A DRAIN is the other order: the step in flight
+is fetched and delivered with nothing launched over it, because the
+iteration has to see the device or changes who steps. Its
+``decode::step_fetch`` span says why (``drain=``), and
+``serving_decode_drains_total{why=}`` counts it, tracing on or off
+(``DRAIN_REASONS``): ``admission`` (a picked request takes the one-shot
+prefill, speculates, searches beams, or needs blocks the free list lacks),
+``prefill`` (a beam request's last chunk), ``slots`` (a new slot samples
+or masks a grammar: its step lands in its own body), ``park``, ``parked``
+(a parked or deferred session), ``spec``, ``idle`` (nothing follows the
+step), ``brownout``, ``breaker``, ``shutdown``. Every step is observed
 once in ``serving_decode_step_seconds``, when it is delivered: the wall
 time of the ``_step`` body that delivered it (with a step in flight that
 is the launch of step N+1 and the fetch and host half of step N), or of
@@ -66,7 +81,14 @@ so are in ``serving_fetched_bytes_total`` too.
 from paddle_tpu.serving.metrics import ServingMetrics
 
 __all__ = ["DecodeMetrics", "TOKEN_BUCKETS", "WAIT_BUCKETS",
-           "LAUNCH_BUCKETS"]
+           "LAUNCH_BUCKETS", "DRAIN_REASONS"]
+
+# why a step in flight was fetched with nothing launched over it: every
+# reason `engine.py _drain_reason`, `_iterate_phases` and `_step_feeds`
+# name, each a series of serving_decode_drains_total from the start (a
+# reader finds the family in a window without a drain)
+DRAIN_REASONS = ("admission", "prefill", "slots", "park", "parked", "spec",
+                 "idle", "brownout", "breaker", "shutdown")
 
 # 10 ms wide from 50 ms to 500 ms, where a token's wait falls on the chip
 # (a decode step is ~110 ms, a first token a few of them), so a quantile
@@ -108,6 +130,8 @@ class DecodeMetrics(ServingMetrics):
         "prefill_device_injects",
         # chunked prefill (one budgeted chunk per engine iteration)
         "chunk_runs", "chunk_tokens",
+        # chunk launches, last or not, made with a decode step in flight
+        "chunk_launches_ahead",
         # speculative decoding: target verify forwards vs emitted tokens
         # is the headline ratio; accepted/proposed is the acceptance rate
         "spec_target_steps", "spec_draft_steps", "spec_proposed_tokens",
@@ -204,9 +228,17 @@ class DecodeMetrics(ServingMetrics):
             "a step launch inside the executable's call", labels=labels,
             buckets=LAUNCH_BUCKETS,
         )
+        self._drains = {
+            why: self._registry.counter(
+                "serving_decode_drains_total",
+                "steps in flight fetched with nothing launched over them",
+                labels={**labels, "why": why},
+            )
+            for why in DRAIN_REASONS
+        }
         for h in (self._step, self._prefill, self._chunk,
                   self._first_token, self._inter_token, self._wait,
-                  self._step_put, self._step_call):
+                  self._step_put, self._step_call, *self._drains.values()):
             h.reset()
 
     def observe_step(self, active_slots, new_tokens, seconds):
@@ -219,10 +251,20 @@ class DecodeMetrics(ServingMetrics):
         self.incr("prefills")
         self._prefill.observe(seconds)
 
-    def observe_chunk(self, tokens, seconds):
+    def observe_chunk(self, tokens, seconds, ahead=False):
         self.incr("chunk_runs")
         self.incr("chunk_tokens", tokens)
+        if ahead:
+            self.incr("chunk_launches_ahead")
         self._chunk.observe(seconds)
+
+    def count_drain(self, why):
+        """One step in flight drained, for the reason ``why``."""
+        self._drains[why].inc()
+
+    def drains(self):
+        """{why: count} of the drains so far."""
+        return {why: int(c.value) for why, c in self._drains.items()}
 
     def observe_wait(self, seconds):
         self._wait.observe(seconds)
@@ -279,6 +321,7 @@ class DecodeMetrics(ServingMetrics):
         out.update(self._wait.snapshot("decode_wait"))
         out.update(self._step_put.snapshot("step_put"))
         out.update(self._step_call.snapshot("step_call"))
+        out["decode_drains"] = self.drains()
         if extra:
             out.update(extra)
         return out

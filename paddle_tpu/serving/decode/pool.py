@@ -349,6 +349,12 @@ class BlockPool:
     def rows(self):
         return self.num_blocks * self.block_size
 
+    @property
+    def free_count(self):
+        """Blocks on the free list: what can be handed out without
+        evicting a cached block (and so without a write-back)."""
+        return len(self._free)
+
     def block(self, bid):
         return self._blocks[bid]
 

@@ -46,7 +46,9 @@ def without_token_fetch(m):
         prefill_kv_fetches=m.prefill_kv_fetches,
         inject_kv_feeds=m.inject_kv_feeds, block_size=m.block_size,
         num_blocks=m.num_blocks, eos_id=m.eos_id, name=m.name,
-        version=m.version, logits_mask=m.logits_mask)
+        version=m.version, logits_mask=m.logits_mask,
+        kv_width=m.kv_width, kv_dtype=m.kv_dtype,
+        slot_states=m.slot_states)
 
 
 def record_step_logits(entry, into):

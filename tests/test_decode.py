@@ -949,9 +949,8 @@ def test_chunked_prefill_interleaves_and_matches_unchunked(order):
     of a step's fetch give the same record: ``serial`` (a model without
     ``token_fetch``: every step lands in the iteration that launched
     it) and ``ahead`` (the token an iteration delivers is the step's
-    that the iteration BEFORE launched; the chunks between the first and
-    the last run under a step in flight, the admission and the last
-    chunk drain it first)."""
+    that the iteration BEFORE launched; the admission and every chunk,
+    the last too, run under a step in flight)."""
     engine = GenerationEngine(queue_depth=16, breaker_threshold=0)
     model = build_decoder_model(
         vocab_size=32, hidden=8, num_layers=2, slots=2, max_len=32,
@@ -998,10 +997,13 @@ def test_chunked_prefill_interleaves_and_matches_unchunked(order):
         assert m.count("decode_steps_ahead") == 0 and not any(in_flight)
     else:
         # every iteration but the last leaves a step in flight, and all
-        # launches but three were ahead of a fetch: the hand-made first,
-        # the one after the admission, the one after the last chunk
+        # launches but the hand-made first were ahead of a fetch: since
+        # ISSUE 42 neither the chunked admission nor its last chunk
+        # drains (three launches were not ahead before)
         assert all(in_flight[:-1]) and not in_flight[-1]
-        assert m.count("decode_steps") - m.count("decode_steps_ahead") == 3
+        assert m.count("decode_steps") - m.count("decode_steps_ahead") == 1
+        assert m.drains() == dict.fromkeys(m.drains(), 0) | {"idle": 1}
+        assert m.count("chunk_launches_ahead") == m.count("chunk_runs")
 
 
 def test_chunked_prefill_skips_radix_shared_chunks():

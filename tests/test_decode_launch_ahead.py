@@ -1,10 +1,13 @@
-"""Launching decode step N+1 ahead of step N's fetch (ISSUE 34).
+"""Launching decode step N+1 ahead of step N's fetch (ISSUE 34), and a
+chunked admission in the same order (ISSUE 42, the file's second half).
 
 A step whose slots need only their tokens stays on the device after its
 launch; the next iteration launches the following step, fed those tokens
 as the device array they are, and fetches and delivers the first one only
-then. Anything that would wait on the device or change who steps drains
-the step in flight first. Hand-stepped through ``entry._iterate()`` over
+then. Anything that has to see the device or changes who steps drains
+the step in flight first; since ISSUE 42 an arrival whose admission is a
+block acquisition and a prefilling slot, a prompt's last chunk and the new
+slot's first step do not. Hand-stepped through ``entry._iterate()`` over
 the toy decoder, sharpened so that a wrong K/V row or a wrong token feed
 moves the served tokens. The serial engine of every comparison is the same
 model built without ``token_fetch``: each of its steps fetches the logits
@@ -243,8 +246,10 @@ def test_greedy_tokens_equal_the_serial_engines(scenario):
 def test_a_chunk_that_is_not_its_prompts_last_runs_under_a_step_in_flight(
         tracer):
     """A prefilling slot's chunk is a launch and no fetch, so it does not
-    drain the step in flight; the prompt's LAST chunk fetches its logits
-    and turns the slot into a stepping one, so it does."""
+    drain the step in flight; since ISSUE 42 neither does the arrival
+    (its admission is a block acquisition and a slot in mode "prefill")
+    nor the prompt's LAST chunk (its row is fetched behind the next
+    step's launch)."""
     engine, entry = _engine("la_chunks", chunk_tokens=4)
     rng = np.random.RandomState(7)
     long_prompt = [int(t) for t in rng.randint(0, 32, size=14)]
@@ -269,11 +274,21 @@ def test_a_chunk_that_is_not_its_prompts_last_runs_under_a_step_in_flight(
     drains = [[s["args"].get("drain")
                for s in within(it, "decode::step_fetch")]
               for it in chunk_its]
-    # the admission's own iteration drained for it, the middle chunks ran
-    # under a step in flight, the last chunk drained as "prefill"
-    assert ahead == [[False], [True], [True], [False]]
-    assert drains == [["admission"], [None], [None], ["prefill"]]
-    assert len(within(chunk_its[-1], "decode::chunk_fetch")) == 1
+    # every chunk ran under a step in flight, the admission's own and the
+    # prompt's last too: the last chunk does not drain
+    assert ahead == [[True], [True], [True], [True]]
+    assert drains == [[None], [None], [None], [None]]
+    chunks = [s for it in chunk_its for s in within(it, "decode::chunk")]
+    assert [(s["args"]["ahead"], s["args"]["last"]) for s in chunks] == [
+        (True, False), (True, False), (True, False), (True, True)]
+    (row,) = within(chunk_its[-1], "decode::chunk_fetch")
+    assert row["args"]["deferred"] is True
+    (step,) = within(chunk_its[-1], "decode::step")
+    assert step["start_ns"] < row["start_ns"]
+    assert entry.metrics.drains()["prefill"] == 0
+    assert entry.metrics.drains()["admission"] == 0
+    assert entry.metrics.count("chunk_launches_ahead") \
+        == entry.metrics.count("chunk_runs") == 4
 
 
 # -- a deadline that expires with a step in flight ----------------------------------------
@@ -502,11 +517,13 @@ def test_a_fault_in_a_launched_ahead_step_fails_both_steps_slots_and_recovers():
 
 # -- a step handed its tokens by hand -----------------------------------------------------
 
-def test_step_called_by_hand_drains_for_a_slot_the_step_in_flight_lacks(
+def test_step_called_by_hand_steps_a_slot_the_step_in_flight_lacks(
         tracer):
-    """`_step` itself holds the rule its token feed rests on: every
-    stepping slot has its token in the step in flight. A caller that
-    admits by hand between two steps gets a drain, not a stale feed."""
+    """`_step` itself holds the rule its token feed rests on: a stepping
+    slot has its token in the step in flight, or on the host. A caller
+    that admits by hand between two steps gets the new slot stepped from
+    its host token beside the others' -1 (a drain until ISSUE 42), not a
+    stale feed."""
     engine, entry = _engine("la_byhand")
     refs = [entry.offline_decode([3, 1, 4], 5),
             entry.offline_decode([9, 2, 6, 5], 4)]
@@ -516,9 +533,481 @@ def test_step_called_by_hand_drains_for_a_slot_the_step_in_flight_lacks(
     entry._step()
     b = engine.submit([9, 2, 6, 5], max_new_tokens=4)
     assert entry._admit_free_slots() == 1
+    fed = []
+    _watch_step_tokens(entry, fed)
+    sb = entry._slots[1]
+    first = sb.last_token
     for _ in range(10):
         entry._step()
+    assert fed[0][:2] == [-1, first] and fed[1][:2] == [-1, -1]
     assert _tokens(a) == refs[0] and _tokens(b) == refs[1]
     drains = [s["args"].get("drain") for s in tracer.spans()
               if s["name"] == "decode::step_fetch"]
-    assert drains[:2] == [None, "slots"] and drains[-1] == "idle"
+    assert drains[:2] == [None, None] and drains[-1] == "idle"
+    assert entry.metrics.drains()["slots"] == 0
+
+
+# == ISSUE 42: a chunked admission joins the launch-ahead order ===========================
+#
+# An arrival whose admission is a block acquisition and a slot in mode
+# "prefill", every chunk of its prompt (the last one too) and the new slot's
+# first step run under the step in flight. Toy decoder with chunks of 4 (a
+# prompt of up to 4 tokens is one-shot and still drains) and a small recurrent
+# hybrid (every prompt chunked), each against its serial engine.
+
+HYBRID = dict(
+    vocab_size=96, hidden_size=64, hybrid_override_pattern="MEM*E",
+    mamba_num_heads=8, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+    conv_kernel=4, chunk_size=8, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=16, n_routed_experts=4,
+    router_experts=8, num_experts_per_tok=2, moe_intermediate_size=24,
+    moe_shared_expert_intermediate_size=48, routed_scaling_factor=2.5,
+    norm_topk_prob=True, layer_norm_epsilon=1e-5, dtype="float32",
+    expert_rank=1, slots=4, max_len=48, block_size=4, chunk_tokens=4)
+
+
+def _hybrid(name, serial=False, **opts):
+    """A small recurrent hybrid, as tests/test_nemotron_h_serving.py
+    ``_model`` builds its own: no prefix cache, no host tier."""
+    from paddle_tpu.serving.decode import build_nemotron_h_model
+
+    model = build_nemotron_h_model(name=name, **dict(HYBRID, **opts))
+    model.startup_program.random_seed = 7
+    if serial:
+        model = without_token_fetch(model)
+    engine = GenerationEngine(prefix_cache_size=0, host_tier_mb=0,
+                              breaker_threshold=0)
+    return engine, engine.register_model(model)
+
+
+def _make(kind, name, serial=False, **opts):
+    if kind == "hybrid":
+        return _hybrid(name, serial=serial, **opts)
+    return _engine(name, serial=serial, chunk_tokens=4, **opts)
+
+
+def _prompt(n, seed, vocab=32):
+    rng = np.random.RandomState(seed)
+    return [int(t) for t in rng.randint(1, vocab, size=n)]
+
+
+def _watch_step_tokens(entry, into):
+    """Keep the token column of every step's ``dec_step`` feed."""
+    run = entry._run
+
+    def running(kind, feeds, span=None):
+        if kind == "step":
+            into.append(feeds["dec_step"][:, 0].tolist())
+        return run(kind, feeds, span)
+
+    entry._run = running
+
+
+def _spans(tracer, name):
+    return sorted((s for s in tracer.spans() if s["name"] == name),
+                  key=lambda s: s["start_ns"])
+
+
+def test_an_arrival_its_chunks_and_its_first_step_run_under_steps_in_flight(
+        tracer):
+    """The schedule by hand: ``a`` steps alone; ``b`` (10 tokens: chunks of
+    4, 4 and 2) arrives with a step in flight. No fetch of a step is a
+    drain until the end; every chunk is a launch ahead; the last chunk's
+    row lands behind the next step's launch; ``b`` joins the step after
+    with its token from the host beside ``a``'s -1."""
+    engine, entry = _engine("la42_sched", chunk_tokens=4)
+    pb = _prompt(10, 3)
+    ref_a = entry.offline_decode([3, 1, 4], 12)
+    ref_b = entry.offline_decode(pb, 4)
+    fed = []
+    _watch_step_tokens(entry, fed)
+    a = engine.submit([3, 1, 4], max_new_tokens=12)
+    entry._iterate()            # 1: a admitted (one-shot), step 1 in flight
+    entry._iterate()            # 2: step 2 ahead, step 1 lands
+    sa = entry._slots[0]
+    b = engine.submit(pb, max_new_tokens=4)
+    # 3: b admitted under step 2, its first chunk launched under it, step 3
+    #    ahead for a alone
+    entry._iterate()
+    sb = entry._slots[1]
+    assert (sb.mode, sb.done) == ("prefill", 4) and sa.ahead == 1
+    assert entry.metrics.drains()["admission"] == 0
+    entry._iterate()            # 4: chunk 2 ahead, step 4 ahead
+    assert (sb.mode, sb.done) == ("prefill", 8)
+    # 5: the last chunk is a launch too; step 5 (a alone) goes out BEFORE
+    #    its row is fetched; the row lands and b has its first token on the
+    #    host, in no step yet
+    entry._iterate()
+    assert (sb.mode, sb.done, sb.cursor, sb.ahead) == ("decode", 10, 10, 0)
+    assert sb.generated == ref_b[:1] and len(b.token_times) == 1
+    assert sb not in entry._launched.states
+    assert fed[-1][:2] == [-1, -1]              # b's row: not stepping
+    # 6: step 6 ahead of step 5's fetch: a rides on the device, b is fed
+    #    its token from the host
+    entry._iterate()
+    assert fed[-1][:2] == [-1, ref_b[0]]
+    assert sb in entry._launched.states and sb.ahead == 1
+    entry._iterate()            # 7: both ride on the device
+    assert fed[-1][:2] == [-1, -1]
+    for _ in range(20):
+        if a.done() and b.done():
+            break
+        entry._iterate()
+    assert _tokens(a) == ref_a and _tokens(b) == ref_b
+    for r in (a, b):
+        _assert_stamps(r)
+
+    chunks = _spans(tracer, "decode::chunk")
+    assert [(s["args"]["tokens"], s["args"]["ahead"], s["args"]["last"])
+            for s in chunks] == [(4, True, False), (4, True, False),
+                                 (2, True, True)]
+    (row,) = _spans(tracer, "decode::chunk_fetch")
+    assert row["args"]["deferred"] is True
+    steps = _spans(tracer, "decode::step")
+    # the row's fetch follows the launch of the step after its chunk
+    assert [s["start_ns"] < row["start_ns"] for s in steps[:6]] == [
+        True, True, True, True, True, False]
+    assert chunks[-1]["start_ns"] < steps[4]["start_ns"]
+    fetches = _spans(tracer, "decode::step_fetch")
+    assert [s["args"].get("drain") for s in fetches] \
+        == [None] * (len(fetches) - 1) + ["idle"]
+    assert [s["args"]["ahead"] for s in steps] \
+        == [False] + [True] * (len(steps) - 1)
+    m = entry.metrics
+    assert m.drains() == dict.fromkeys(m.drains(), 0) | {"idle": 1}
+    assert m.count("chunk_runs") == m.count("chunk_launches_ahead") == 3
+    assert m.count("decode_steps_ahead") == m.count("step_launches") - 1
+    assert "serving_decode_drains_total" in obs.scrape_text()
+    assert "serving_chunk_launches_ahead_total" in obs.scrape_text()
+    entry.block_pool.check_conservation()
+    assert entry.block_pool.stats()["blocks_live"] == 0
+
+
+@pytest.mark.parametrize("plen", [1, 4, 5])
+@pytest.mark.parametrize("kind", ["decoder", "hybrid"])
+def test_prompts_of_1_c_and_c_plus_1_tokens_equal_the_serial_engines(
+        kind, plen):
+    """A prompt of 1, C and C + 1 tokens arriving with a step in flight:
+    one chunk that is the last, a full last chunk, a chunk and a last one
+    of one token (one-shot for the first two on the decoder). The tokens
+    are the serial engine's and, where there is one, the offline
+    reference's."""
+    vocab = 96 if kind == "hybrid" else 32
+    script = {0: [dict(prompt=_prompt(6, 1, vocab), max_new_tokens=14)],
+              3: [dict(prompt=_prompt(plen, 2, vocab), max_new_tokens=6)],
+              5: [dict(prompt=_prompt(9, 4, vocab), max_new_tokens=5)]}
+    served = {}
+    for serial in (False, True):
+        engine, entry = _make(kind, f"la42_{kind}_{plen}", serial=serial)
+        resps = _play(entry, engine, script)
+        served[serial] = [_tokens(r) for r in resps]
+        for r in resps:
+            _assert_stamps(r)
+        m = entry.metrics
+        assert m.count("failed") == 0
+        assert m.count("step_launches") == m.count("decode_steps")
+        assert m.count("generated_tokens") + m.count("prefill_tokens") \
+            == sum(len(t) for t in served[serial])
+        entry.block_pool.check_conservation()
+        assert entry.block_pool.stats()["blocks_live"] == 0
+        if serial:
+            assert m.count("decode_steps_ahead") == 0
+            assert m.count("chunk_launches_ahead") == 0
+            assert sum(m.drains().values()) == 0
+            continue
+        chunked = kind == "hybrid" or plen > 4
+        assert m.drains()["admission"] == (0 if chunked else 1)
+        assert m.drains()["prefill"] == m.drains()["slots"] == 0
+        # the first request's two chunks found no step to run under,
+        # every later chunk did
+        assert m.count("chunk_launches_ahead") == m.count("chunk_runs") - 2
+        if kind == "decoder":
+            kws = [kw for i in sorted(script) for kw in script[i]]
+            for kw, got in zip(kws, served[serial]):
+                assert got == entry.offline_decode(kw["prompt"],
+                                                   kw["max_new_tokens"])
+    assert served[False] == served[True]
+    assert any(len(set(t)) > 2 for t in served[False]), served
+
+
+# -- a first token that ends the request --------------------------------------------------
+
+@pytest.mark.parametrize("ending", ["max_new_of_1", "eos_id"])
+def test_a_first_token_that_ends_the_request_retires_at_the_landing(ending):
+    """The step launched over the last chunk did not include the new slot
+    (its row had not landed), so a request that its first token ends is
+    retired at the landing and no step wastes a row on it."""
+    pb = _prompt(7, 9)
+    _probe_engine, probe = _engine("la42_end", chunk_tokens=4)
+    first = probe.offline_decode(pb, 1)[0]
+    long_run = probe.offline_decode([3, 1, 4], 12)
+    opts, max_new = {}, 1
+    if ending == "eos_id":
+        assert first not in long_run
+        opts, max_new = {"eos_id": first}, 6
+    engine, entry = _engine("la42_end", chunk_tokens=4, **opts)
+    fed = []
+    _watch_step_tokens(entry, fed)
+    a = engine.submit([3, 1, 4], max_new_tokens=12)
+    entry._iterate()
+    entry._iterate()
+    b = engine.submit(pb, max_new_tokens=max_new)
+    entry._iterate()                        # admitted, first chunk
+    sb = entry._slots[1]
+    assert sb.mode == "prefill" and not b.done()
+    steps = entry.metrics.count("step_launches")
+    entry._iterate()                        # last chunk, its row landed
+    assert b.done() and _tokens(b) == [first]
+    assert entry._slots[1] is None and sb not in entry._launched.states
+    assert entry.metrics.count("step_launches") == steps + 1
+    for _ in range(20):
+        if a.done():
+            break
+        entry._iterate()
+    assert _tokens(a) == long_run
+    # b's slot never carried a token or stepped: its column stayed -1
+    assert {row[1] for row in fed} == {-1}
+    m = entry.metrics
+    assert m.count("active_slot_steps") == m.count("generated_tokens") == 11
+    assert m.count("prefill_tokens") == 2 and m.count("retired") == 2
+    assert m.drains()["admission"] == m.drains()["prefill"] == 0
+    entry.block_pool.check_conservation()
+    assert entry.block_pool.stats()["blocks_live"] == 0
+
+
+# -- a wasted row against a next owner admitted under that step ---------------------------
+
+@pytest.mark.parametrize("kind", ["decoder", "hybrid"])
+def test_a_next_owner_admitted_under_the_step_that_wastes_a_row(kind, tracer):
+    """``first`` ends at an ``eos_id`` that the landing of step N shows,
+    after step N+1 was launched with it. The follower arrives next, is
+    ADMITTED UNDER step N+1 (no drain) into the slot and the blocks that
+    step still writes, and its chunks, launched later, overwrite the wasted
+    row; a recurrent slot's chunk at position 0 resets the state that step
+    dirtied. Its logits rows are, byte for byte, those of an engine that
+    never saw ``first``."""
+    vocab = 96 if kind == "hybrid" else 32
+    name = f"la42_waste_{kind}"
+    prompt, keeper_prompt = _prompt(6, 21, vocab), _prompt(5, 22, vocab)
+    follower = _prompt(9, 23, vocab)
+    # the serial engine's free run of the first request names the token
+    # that will end it in mid-stream
+    engine0, probe = _make(kind, name, serial=True)
+    (free,) = _play(probe, engine0,
+                    {0: [dict(prompt=prompt, max_new_tokens=12)]})
+    free_run = _tokens(free)
+    at = next(i for i in range(3, 10) if free_run[i] not in free_run[:i])
+    eos = free_run[at]
+
+    def serve(with_first):
+        engine, entry = _make(kind, name, eos_id=eos)
+        rows, out = {}, {}
+        record_step_logits(entry, rows)
+        keeper = engine.submit(keeper_prompt, max_new_tokens=26)
+        if with_first:
+            first = engine.submit(prompt, max_new_tokens=12)
+            for _ in range(60):
+                st = entry._slots[1]
+                entry._iterate()
+                if first.done():
+                    break
+            # the step over the one that brought the eos holds the slot
+            assert st in entry._launched.states
+            assert entry._slots[1] is None
+            out["first"] = _tokens(first)
+            # where that step writes the row nobody will read
+            out["tail"] = st.blocks[(st.cursor - 1) // 4].id
+            tracer.clear()
+        else:
+            for _ in range(6):
+                entry._iterate()
+        wasting = entry._launched
+        second = engine.submit(follower, max_new_tokens=10)
+        entry._iterate()
+        sf = entry._slots[1]
+        assert sf.request.response is second and sf.mode == "prefill"
+        out["blocks"] = [b.id for b in sf.blocks]
+        # admitted and given its first chunk with that step untouched
+        assert entry.metrics.drains()["admission"] == 0
+        chunk = _spans(tracer, "decode::chunk")[0] if with_first else None
+        out["ahead"] = chunk and chunk["args"]["ahead"]
+        out["resets"] = [i["args"]["slot"] for i in tracer.instants()
+                         if i["name"] == "decode::state_reset"]
+        out["wasting"] = wasting
+        for _ in range(80):
+            if second.done() and keeper.done():
+                break
+            entry._iterate()
+        out["second"] = _tokens(second)
+        out["second_rows"] = rows[id(second)]
+        out["keeper"] = _tokens(keeper)
+        assert eos not in out["keeper"][:-1]
+        out["drains"] = entry.metrics.drains()
+        entry.block_pool.check_conservation()
+        assert entry.block_pool.stats()["blocks_live"] == 0
+        return out
+
+    used, clean = serve(True), serve(False)
+    assert used["first"] == free_run[:at + 1]
+    # the follower holds the block the wasted row was written to
+    assert used["tail"] in used["blocks"] and used["ahead"] is True
+    if kind == "hybrid":
+        assert used["resets"] == [1]
+    assert used["second"] == clean["second"]
+    assert used["keeper"] == clean["keeper"]
+    assert len(used["second_rows"]) == len(clean["second_rows"]) > 0
+    for x, y in zip(used["second_rows"], clean["second_rows"]):
+        assert x.tobytes() == y.tobytes()
+    assert used["drains"]["admission"] == used["drains"]["prefill"] == 0
+
+
+# -- a deadline that expires with the last chunk unlanded ---------------------------------
+
+def test_a_deadline_that_expires_with_the_last_chunk_unlanded():
+    """The row is on the device, the deadline runs out, the next step is
+    launched without the slot, and the landing delivers the one token the
+    device paid for and then fails the request: no step ever holds it."""
+    engine, entry = _engine("la42_deadline", chunk_tokens=4)
+    ref = entry.offline_decode([3, 1, 4], 10)
+    pb = _prompt(7, 5)
+    first = entry.offline_decode(pb, 1)
+    a = engine.submit([3, 1, 4], max_new_tokens=10)
+    entry._iterate()
+    entry._iterate()
+    doomed = engine.submit(pb, max_new_tokens=8, deadline_ms=600000)
+    entry._iterate()                                # admitted, chunk 1
+    sd = entry._slots[1]
+    assert entry._launched is not None
+    assert entry._advance_prefills() == 1           # the last chunk
+    assert len(entry._chunk_rows) == 1 and sd.mode == "prefill"
+    assert entry._advance_prefills() == 0           # nothing left to chunk
+    sd.request.deadline = 0.0
+    entry._step()
+    assert entry._chunk_rows == [] and entry._slots[1] is None
+    assert sd not in entry._launched.states
+    with pytest.raises(DeadlineExceededError,
+                       match="mid-generation after 1 tokens"):
+        doomed.result()
+    assert len(doomed.token_times) == 1 and sd.generated == first
+    for _ in range(20):
+        if a.done():
+            break
+        entry._iterate()
+    assert _tokens(a) == ref
+    m = entry.metrics
+    assert m.count("deadline_missed") == 1 and m.count("failed") == 0
+    assert m.count("generated_tokens") + m.count("prefill_tokens") == 10 + 1
+    entry.block_pool.check_conservation()
+    assert entry.block_pool.stats()["blocks_live"] == 0
+
+
+# -- a fault in a chunk launched under a step ---------------------------------------------
+
+def test_a_fault_at_the_chunk_with_a_step_in_flight_fails_both_and_recovers():
+    """The launch of the prompt's second chunk raises with a step in
+    flight: the arena is undefined, so the prefilling slot and the slots of
+    that step fail loudly, the step is not delivered, and the engine
+    serves the next request."""
+    engine, entry = _engine("la42_fault", chunk_tokens=4)
+    pb = _prompt(10, 6)
+    ref = entry.offline_decode(pb, 4)
+    faults.configure([{"site": "decode.chunk", "action": "raise",
+                       "at_call": 2}])
+    try:
+        a = engine.submit([3, 1, 4], max_new_tokens=12)
+        entry._iterate()
+        entry._iterate()
+        b = engine.submit(pb, max_new_tokens=4)
+        entry._iterate()                    # chunk 1 under a step in flight
+        assert entry._launched is not None and not a.done()
+        delivered = entry.metrics.count("decode_steps")
+        entry._iterate()                    # chunk 2 raises
+        assert entry._launched is None and entry._chunk_rows == []
+        assert [st for st in entry._slots if st is not None] == []
+        for r in (a, b):
+            with pytest.raises(RequestError, match="chunk-prefill failure"):
+                r.result(timeout=1)
+        m = entry.metrics
+        assert m.count("step_failures") == 1
+        assert m.count("decode_steps") == delivered     # never landed
+        again = engine.submit(pb, max_new_tokens=4)
+        for _ in range(20):
+            if again.done():
+                break
+            entry._iterate()
+        assert _tokens(again) == ref
+        entry.block_pool.check_conservation()
+        assert entry.block_pool.stats()["blocks_live"] == 0
+    finally:
+        faults.reset()
+
+
+# -- what still drains, and why -----------------------------------------------------------
+
+def _still_drains_one_shot():
+    # a prompt the chunk budget covers: its prefill_fetch waits
+    return {}, {0: [dict(prompt=[3, 1, 4], max_new_tokens=12)],
+                3: [dict(prompt=[9, 2, 6], max_new_tokens=5)]}, \
+        {"admission": 1}
+
+
+def _still_drains_beam():
+    # a beam request drains at its admission, and again for its last
+    # chunk: the first selection forks slots and copies arena rows
+    return {}, {0: [dict(prompt=[3, 1, 4], max_new_tokens=12)],
+                3: [dict(prompt=_prompt(7, 8), max_new_tokens=5,
+                         beam_width=2)]}, {"admission": 1, "prefill": 1}
+
+
+def _still_drains_sampled():
+    # admitted and chunked under steps in flight; the new slot's first
+    # step brings the logits over, so the step in flight is drained for it
+    return {}, {0: [dict(prompt=[3, 1, 4], max_new_tokens=12)],
+                3: [dict(prompt=_prompt(7, 8), max_new_tokens=5,
+                         sampling=SAMPLED)]}, \
+        {"admission": 0, "prefill": 0, "slots": 1}
+
+
+def _still_drains_parked():
+    # three sessions outgrow eight blocks: sessions are parked and, while
+    # one waits to be resumed, every iteration drains ("parked"); the
+    # ladder's moves drain too ("brownout")
+    return {"num_blocks": 8, "slots": 3}, {
+        0: [dict(prompt=[3, 1, 4], max_new_tokens=14),
+            dict(prompt=[9, 2, 6], max_new_tokens=14),
+            dict(prompt=[7, 7, 1], max_new_tokens=14)]}, \
+        {"parked": 1, "brownout": 1}
+
+
+STILL_DRAINS = {"one_shot": _still_drains_one_shot,
+                "beam": _still_drains_beam,
+                "sampled": _still_drains_sampled,
+                "parked": _still_drains_parked}
+
+
+@pytest.mark.parametrize("case", sorted(STILL_DRAINS))
+def test_what_has_to_see_the_device_still_drains_and_says_why(case, tracer):
+    opts, script, reasons = STILL_DRAINS[case]()
+    served = {}
+    for serial in (False, True):
+        engine, entry = _engine(f"la42_{case}", serial=serial,
+                                chunk_tokens=4, **opts)
+        tracer.clear()
+        resps = _play(entry, engine, script, iterations=200)
+        outs = [r.result() for r in resps]
+        served[serial] = [
+            (o["tokens"].tolist(),
+             [(b["tokens"].tolist(), b["score"])
+              for b in o.get("beams", ())]) for o in outs]
+        entry.block_pool.check_conservation()
+        assert entry.block_pool.stats()["blocks_live"] == 0
+        if serial:
+            continue
+        drains = entry.metrics.drains()
+        for why, n in reasons.items():
+            assert (drains[why] >= n) if n else (drains[why] == 0), drains
+        spans = [s["args"]["drain"] for s in tracer.spans()
+                 if s["name"] == "decode::step_fetch"
+                 and "drain" in s["args"]]
+        assert {why: spans.count(why) for why in drains} == drains
+    assert served[False] == served[True]

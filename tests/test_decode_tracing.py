@@ -19,6 +19,16 @@ Since ISSUE 38 the loop's sleeps are ``decode::wait`` spans observed in
 observed in ``serving_decode_step_put_seconds`` / ``..._call_seconds``
 whether tracing is on or off, and a step's launch and its landing carry
 one ``launch`` number.
+
+Since ISSUE 42 a chunked admission runs in the launch-ahead order too:
+``decode::chunk`` says whether it was launched with a step in flight
+(``ahead=``) and whether it is its prompt's last (``last=``), the
+``decode::chunk_fetch`` of that last chunk's one row whether a launch was
+made over it first (``deferred=``), and two counters stand beside the
+spans, tracing on or off: ``serving_decode_drains_total{why=}`` (one per
+``decode::step_fetch`` that carries ``drain=``) and
+``serving_chunk_launches_ahead_total`` (one per ``decode::chunk`` with
+``ahead=True``).
 """
 
 import time
@@ -34,6 +44,7 @@ from paddle_tpu.observability import tracer as tracer_mod
 from paddle_tpu.serving.brownout import BrownoutController
 from paddle_tpu.serving.decode import GenerationEngine, build_decoder_model
 from paddle_tpu.serving.decode import engine as engine_mod
+from paddle_tpu.serving.decode import metrics as metrics_mod
 from paddle_tpu.serving.decode.metrics import TOKEN_BUCKETS
 from paddle_tpu.serving.decode.model import DecodeModel
 from paddle_tpu.serving.request import Priority, RejectedError, Response
@@ -410,6 +421,92 @@ def test_a_step_fetch_carries_the_launch_of_one_earlier_step(traced_run):
         later += len(between)
     assert later == entry.metrics.count("decode_steps_ahead")
     assert (later > 0) == (entry.order == "ahead")
+
+
+# -- a chunked admission in the launch-ahead order (ISSUE 42) --------------------------
+
+def test_a_chunk_says_ahead_and_last_and_its_rows_fetch_says_deferred(
+        traced_run):
+    entry, _resps, spans = traced_run
+    spans = sorted(spans, key=lambda s: s["start_ns"])
+    chunks = [s for s in spans if s["name"] == "decode::chunk"]
+    rows = [s for s in spans if s["name"] == "decode::chunk_fetch"]
+    steps = [s for s in spans if s["name"] == "decode::step"]
+    assert len(chunks) == sum(-(-n // 4) for n in PROMPT_LENS if n > 4)
+    by_request = {}
+    for s in chunks:
+        assert type(s["args"]["ahead"]) is type(s["args"]["last"]) is bool
+        by_request.setdefault(s["args"]["request"], []).append(s)
+    assert len(by_request) == len(rows) == 2
+    for row in rows:
+        mine = by_request[row["args"]["request"]]
+        # the prompt's last chunk, and that one alone, says so
+        assert [c["args"]["last"] for c in mine] \
+            == [False] * (len(mine) - 1) + [True]
+        assert mine[-1]["start_ns"] < row["start_ns"]
+        # deferred: a step was launched between the chunk and its row's
+        # fetch (every stepping slot lands in its own body when serial,
+        # and the row still waits for that launch)
+        over = [st for st in steps
+                if mine[-1]["start_ns"] < st["start_ns"] < row["start_ns"]]
+        assert row["args"]["deferred"] is bool(over)
+        assert row["args"]["bytes"] == 4 * entry.model.vocab_size
+    if entry.order == "serial":
+        assert not any(c["args"]["ahead"] for c in chunks)
+    else:
+        # one prompt's last chunk found nothing stepping (its row was
+        # fetched at once), the other's ran under a step in flight
+        assert any(c["args"]["ahead"] for c in chunks)
+        assert sorted(r["args"]["deferred"] for r in rows) == [False, True]
+
+
+def test_the_drain_counter_equals_the_fetches_that_say_drain(traced_run):
+    entry, _resps, spans = traced_run
+    said = [s["args"]["drain"] for s in spans
+            if s["name"] == "decode::step_fetch" and "drain" in s["args"]]
+    drains = entry.metrics.drains()
+    assert sorted(drains) == sorted(metrics_mod.DRAIN_REASONS)
+    assert {why: said.count(why) for why in drains} == drains
+    assert sum(drains.values()) == len(said)
+    assert entry.stats()["decode_drains"] == drains
+    if entry.order == "serial":
+        assert said == []
+    else:
+        # all four were admitted with nothing in flight; no prompt's
+        # last chunk drained; a step that nothing followed did
+        assert drains["idle"] >= 1
+        assert drains["admission"] == drains["prefill"] == 0
+        assert drains["slots"] == 0
+    # every reason is a series of the family from the start
+    family = obs.registry().snapshot()["serving_decode_drains_total"]
+    mine = {k: v for k, v in family.items()
+            if f'engine="{entry.metrics.engine_label}"' in k}
+    assert len(mine) == len(metrics_mod.DRAIN_REASONS)
+    assert sum(mine.values()) == len(said)
+
+
+def test_chunk_launches_ahead_equals_the_chunk_spans_that_say_ahead(
+        traced_run):
+    entry, _resps, spans = traced_run
+    chunks = [s for s in spans if s["name"] == "decode::chunk"]
+    m = entry.metrics
+    assert m.count("chunk_runs") == len(chunks)
+    assert m.count("chunk_launches_ahead") \
+        == sum(s["args"]["ahead"] for s in chunks)
+    assert entry.stats()["chunk_launches_ahead"] \
+        == m.count("chunk_launches_ahead")
+
+
+def test_the_two_counters_count_with_tracing_off(clean_tracer, traced_run):
+    traced_entry, _resps, _spans = traced_run
+    entry, _ = _serve("trc_off_drains", traced=False,
+                      order=traced_entry.order)
+    assert clean_tracer.spans() == []
+    assert entry.metrics.drains() == traced_entry.metrics.drains()
+    assert entry.metrics.count("chunk_launches_ahead") \
+        == traced_entry.metrics.count("chunk_launches_ahead")
+    assert "serving_decode_drains_total" in obs.scrape_text()
+    assert "serving_chunk_launches_ahead_total" in obs.scrape_text()
 
 
 # -- the loop's sleeps ------------------------------------------------------------------
