@@ -263,9 +263,10 @@ def _tpu_cases_paged():
 
 def _parity_paged_grouped(rng):
     """The head axis: 2 K/V heads of 128 a row, 4 query heads to each; then
-    8 heads of 64, two to a lane tile."""
-    for G, D in ((2, 128), (8, 64)):
-        _parity_paged_heads(rng, G, 4, D)
+    8 heads of 64, two to a lane tile; then 16 heads of 128 with ONE query
+    head each (one real query row in a tile)."""
+    for G, per, D in ((2, 4, 128), (8, 4, 64), (16, 1, 128)):
+        _parity_paged_heads(rng, G, per, D)
 
 
 def _parity_paged_heads(rng, G, per, D):
@@ -292,7 +293,9 @@ def _tpu_cases_paged_grouped():
     """The hybrid serving cells' geometries in bfloat16 at block 16 and
     2,048 positions: nemotron3_nano_30b_a3b (32 slots, rows of 2 K/V heads
     of 128, 16 query heads to each) and lfm2_24b_a2b (128 slots, rows of 8
-    K/V heads of 64, 4 query heads to each: two heads a lane tile)."""
+    K/V heads of 64, 4 query heads to each: two heads a lane tile); and
+    ouro_2_6b at 1,024 positions (16 slots, rows of 16 K/V heads of 128
+    with ONE query head each: one real query row in a 16-row tile)."""
     from paddle_tpu.kernels import attention as A
 
     def case(S, L, bs, G, per, D):
@@ -307,7 +310,8 @@ def _tpu_cases_paged_grouped():
             ((R, G * D), "bfloat16"), ((S * L,), "int32"),
             ((S, 1, L), "float32")])
 
-    return [case(32, 2048, 16, 2, 16, 128), case(128, 2048, 16, 8, 4, 64)]
+    return [case(32, 2048, 16, 2, 16, 128), case(128, 2048, 16, 8, 4, 64),
+            case(16, 1024, 16, 16, 1, 128)]
 
 
 def _parity_moe_experts(rng):

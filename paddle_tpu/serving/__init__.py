@@ -44,6 +44,7 @@ from paddle_tpu.serving.decode import (
     build_decoder_model,
     build_lfm2_model,
     build_nemotron_h_model,
+    build_ouro_model,
 )
 from paddle_tpu.serving.engine import ServingEngine
 from paddle_tpu.serving.fleet import (
@@ -76,6 +77,7 @@ __all__ = [
     "build_decoder_model",
     "build_nemotron_h_model",
     "build_lfm2_model",
+    "build_ouro_model",
     "Priority",
     "RejectedError",
     "ReplicaLostError",

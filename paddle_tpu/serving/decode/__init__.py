@@ -33,7 +33,9 @@ Modules:
 * `hybrid` — `build_nemotron_h_model`: a Mamba-2 / grouped-query attention
   / routed-experts decoder with per-slot recurrent state beside the arena;
   `build_lfm2_model`: gated short convolutions beside grouped-query
-  attention with QK-norm and rotary positions, gated routed experts.
+  attention with QK-norm and rotary positions, gated routed experts;
+  `build_ouro_model`: one stack of layers run several times a token with
+  shared parameters, paged K/V rows per (pass, layer), an exit gate.
 * `pool`   — host-side slot allocator, block allocator + radix prefix
   index (storage dedup), and the content-hash prefill cache (compute
   dedup).
@@ -60,7 +62,7 @@ from paddle_tpu.serving.decode.generate import (
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.hybrid import (
-    build_lfm2_model, build_nemotron_h_model)
+    build_lfm2_model, build_nemotron_h_model, build_ouro_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -86,5 +88,6 @@ __all__ = [
     "build_decoder_model",
     "build_nemotron_h_model",
     "build_lfm2_model",
+    "build_ouro_model",
     "prompt_key",
 ]

@@ -84,7 +84,29 @@ of a state: a prefix cache or a host tier at ``register_model``, beam
 search and speculation at ``submit``. Launch-ahead stays safe: the one
 step an ``eos_id`` wastes dirties only a state the next admission resets.
 A step's integer counts (``counts_fetch``: how a step's tokens were
-routed) come back in the same fetch as its tokens.
+routed, how many passes of a looped stack they took) come back in the same
+fetch as its tokens. A model without prefill and inject programs
+(``DecodeModel.chunks_only``) need not be recurrent: one whose stack runs
+several times a token keeps K/V rows per (pass, layer), ``state_names`` is
+one pair per such state, and it is refused a host tier (nothing could put
+its rows back), beams and speculation likewise.
+
+**Admission by reservation.** An arena may be smaller than ``slots x
+ceil(max_len / block_size)`` (a token's rows can cost too much to give
+every slot its full length). With a host tier a session that finds the
+pool empty mid-generation parks; WITHOUT one (``host_tier_mb=0``) it could
+only fail, so such an entry (``_reserves``) admits a greedy or sampled
+request against its WHOLE block chain, ``ceil((len(prompt) +
+max_new_tokens) / block_size)``, both known at ``submit``: the pool
+promises the chain (``BlockPool.reserve``; promised and unopened blocks
+count against ``free_count``), the slot opens its blocks out of the
+promise as its cursor moves and hands back the rest when it retires. A
+tenant's head request whose chain the pool cannot cover stays in the
+QUEUE (``_pick(fits=)``; ``admissions_deferred`` counts it once) and takes
+no slot; one whose chain no pool could hold fails at its admission.
+Nothing parks, nothing fails mid-generation, and an arrival whose chain is
+covered joins the launch-ahead order without a drain, as before. Selected
+by the pool's size and the tier's absence alone.
 
 Correctness contract: (a) retired/foreign slots touch the arena only
 through dropped or disjoint row scatters (exact no-ops), and (b) the
@@ -198,7 +220,7 @@ class GenerationRequest:
     __slots__ = ("id", "prompt", "max_new", "tenant", "priority", "deadline",
                  "submit_time", "dispatch_time", "response", "rows",
                  "draft_key", "spec_k", "sampling", "beam", "grammar",
-                 "draft_kv")
+                 "draft_kv", "held_back")
 
     def __init__(self, rid, prompt, max_new, tenant, priority, deadline,
                  draft_key=None, spec_k=0, sampling=None, beam=None,
@@ -219,6 +241,7 @@ class GenerationRequest:
         self.rows = beam.width if beam is not None else 1
         self.draft_key = draft_key
         self.spec_k = int(spec_k)
+        self.held_back = False  # the block pool made it wait (counted once)
 
     def expired(self, now=None):
         if self.deadline is None:
@@ -269,13 +292,15 @@ class _Slot:
     next draft arena position without a committed KV row. ``ahead``
     counts the slot's tokens that a launched decode step has produced on
     the device and the host has not read yet: ``cursor`` already counts
-    their rows, ``generated`` and ``last_token`` do not hold them."""
+    their rows, ``generated`` and ``last_token`` do not hold them.
+    ``reserve`` is what is left of the slot's reservation (admission by
+    reservation): the blocks of its whole chain that it has not opened."""
 
     __slots__ = ("request", "mode", "cursor", "last_token", "generated",
                  "blocks", "row_map", "table", "plen", "done", "shared_len",
                  "toks", "sampling", "grammar", "beam", "score", "seq",
-                 "ahead", "d_entry", "d_slot", "d_blocks", "d_row_map",
-                 "d_table", "d_cursor")
+                 "ahead", "reserve", "d_entry", "d_slot", "d_blocks",
+                 "d_row_map", "d_table", "d_cursor")
 
     def __init__(self, request, mode="decode"):
         self.request = request
@@ -288,6 +313,7 @@ class _Slot:
         self.table = None
         self.seq = 0            # admission order (default victim policy)
         self.ahead = 0          # tokens launched, not yet on the host
+        self.reserve = 0        # blocks promised by the pool, not yet opened
         self.plen = len(request.prompt)
         self.done = 0           # chunked prefill: prompt positions landed
         self.shared_len = 0     # positions served by radix-shared blocks
@@ -448,7 +474,16 @@ class _ModelEntry:
         # (decode.blocks -> decode.tier); reads go through the engine so
         # the device rows come off the live arena.
         self._tier = HostKVTier(capacity_bytes=engine._host_tier_bytes)
-        self._blocks.attach_tier(self._tier, read_rows=self._read_block_rows)
+        if engine._host_tier_bytes:
+            self._blocks.attach_tier(self._tier,
+                                     read_rows=self._read_block_rows)
+        # admission by reservation: an arena that cannot give every slot
+        # its full length, with no tier to park a session on, admits a
+        # request against its WHOLE block chain, so that no admitted
+        # request can find the pool empty mid-generation
+        self._reserves = (not engine._host_tier_bytes
+                          and model.num_blocks
+                          < model.slots * model.blocks_per_slot)
         self._parked = []       # [_ParkedSession] FIFO
         self._pending = []      # [GenerationRequest] deferred admissions
         self._brownout = BrownoutController()
@@ -922,18 +957,35 @@ class _ModelEntry:
         lanes = (Priority.LANES if self._brownout.level < 3
                  else tuple(p for p in Priority.LANES if p != Priority.LOW))
         with self._cond:
-            rows = 0
+            rows = blocks = 0
+            room = self._blocks.free_count
+
+            def fits(req):
+                # admission by reservation: a tenant's head request whose
+                # chain the pool cannot cover now waits in the queue (its
+                # turn comes back; FIFO within the tenant), and no slot is
+                # spent on it. One that can NEVER fit goes on, to fail
+                # loudly at its admission
+                need = self._chain(req)
+                if need <= room - blocks or need > self._model.num_blocks:
+                    return True
+                self._hold_back(req)
+                return False
+
             while self._pool.free_count - rows > 0:
                 # budget in ROWS, not requests: a beam admission claims
                 # width slots (seed + first-selection forks) before the
                 # next pick runs
                 req = self._engine._pick(
                     self._queue, max_rows=self._pool.free_count - rows,
-                    lanes=lanes)
+                    lanes=lanes, fits=fits if self._reserves else None)
                 if req is None:
                     break
                 picked.append(req)
                 rows += req.rows
+                need = self._chain(req)
+                if need <= self._model.num_blocks:
+                    blocks += need
             # the round's picks are ONE drain event for the rate EWMA
             self._queue.note_drained()
         return picked
@@ -948,25 +1000,48 @@ class _ModelEntry:
         device: a produced token never waits behind an admission's
         prefill), for a speculative or beam request, and for blocks the
         free list cannot cover (an eviction's write-back reads the
-        arenas, an exhausted pool parks a victim)."""
+        arenas, an exhausted pool parks a victim). Under admission by
+        reservation the blocks are a request's whole chain, and the pool's
+        count leaves out what it has promised already."""
         bs = self._model.block_size
         blocks = 0
         for req in picked:
             if (req.draft_key is not None or req.beam is not None
                     or not self._takes_chunks(req)):
                 return True
-            blocks += (len(req.prompt) + bs - 1) // bs
+            blocks += self._chain(req) or (len(req.prompt) + bs - 1) // bs
         return blocks > self._blocks.free_count
+
+    def _chain(self, req):
+        """The blocks a request's whole sequence takes, prompt and answer
+        (both known at ``submit``), where its admission reserves them: 0
+        for an engine that does not reserve, and for a beam or speculative
+        request (a fork copies and shares blocks, a verify holds none:
+        neither's footprint is a sum known here; they are served from what
+        is promised to nobody)."""
+        if (not self._reserves or req.beam is not None
+                or req.draft_key is not None):
+            return 0
+        m = self._model
+        return -(-min(len(req.prompt) + req.max_new, m.max_len)
+                 // m.block_size)
+
+    def _hold_back(self, req):
+        """The pool cannot cover ``req``'s chain yet: counted once a
+        request, however many rounds it waits."""
+        if not req.held_back:
+            req.held_back = True
+            self._metrics.incr("admissions_deferred")
 
     def _takes_chunks(self, req):
         """Whether a prompt streams through the chunk program: one the
-        chunk budget does not cover, and a recurrent model's every
-        prompt, from its first token (the chunks build the slot's state
-        as they go; no block of it was ever registered, so nothing is
-        shared)."""
+        chunk budget does not cover, and every prompt, from its first
+        token, of a model that has no one-shot prefill (a recurrent
+        model's chunks build the slot's state as they go, and no block of
+        it was ever registered, so nothing is shared)."""
         m = self._model
         return bool(m.chunk_tokens and "chunk" in self._entries
-                    and (len(req.prompt) > m.chunk_tokens or m.recurrent))
+                    and (len(req.prompt) > m.chunk_tokens or m.chunks_only))
 
     def _admit_picked(self, picked):
         for req in picked:
@@ -986,7 +1061,9 @@ class _ModelEntry:
         with _span("decode::admit") as sp:
             outcome = self._admit_into_slot(req)
             if sp is not None:
-                sp.set(request=req.id, outcome=outcome)
+                sp.set(request=req.id, outcome=outcome,
+                       reserved=self._chain(req) if outcome == "admitted"
+                       else 0, free=self._blocks.free_count)
             return outcome
 
     def _admit_into_slot(self, req):
@@ -1058,11 +1135,37 @@ class _ModelEntry:
         Otherwise victims are preempted (spilled to the host tier, to
         resume byte-identically) until the prompt fits; if that is not
         possible right now, ``_DeferAdmission`` sends the request to
-        ``_pending`` with its tenant reservation intact."""
+        ``_pending`` with its tenant reservation intact.
+
+        Under admission by reservation (`_chain`) nobody is parked: the
+        request's whole chain is promised by the pool or the request
+        waits, and what comes back third is the part of the chain not
+        opened yet (``_Slot.reserve``)."""
+        m = self._model
+        chain = self._chain(req)
+        if chain > m.num_blocks:
+            self._metrics.incr("blocks_exhausted")
+            self._metrics.incr("blocks_failed_total")
+            raise RuntimeError(
+                f"the request's chain of {chain} blocks (prompt and answer)"
+                f" can never fit a pool of {m.num_blocks}; shorten it or "
+                "host the model with more blocks")
+        if chain:
+            if not self._blocks.reserve(chain):
+                self._hold_back(req)
+                raise _DeferAdmission()
+            held = self._blocks.reserved
+            blocks, shared_len = self._blocks.acquire_for_prompt(
+                req.prompt, promised=chain)
+            self._metrics.incr("reserved_admissions")
+            self._metrics.incr("blocks_reserved", chain)
+            # what the prompt's blocks used up of the promise (reserved
+            # moves on this thread alone)
+            return (blocks, shared_len,
+                    chain - (held - self._blocks.reserved))
         blocks, shared_len = self._blocks.acquire_for_prompt(req.prompt)
         if blocks is not None:
-            return blocks, shared_len
-        m = self._model
+            return blocks, shared_len, 0
         self._metrics.incr("blocks_exhausted")
         if (len(req.prompt) + m.block_size - 1) // m.block_size \
                 > m.num_blocks:
@@ -1081,14 +1184,15 @@ class _ModelEntry:
         if blocks is None:
             self._metrics.incr("admissions_deferred")
             raise _DeferAdmission()
-        return blocks, shared_len
+        return blocks, shared_len, 0
 
     # -- preemption / host-tier spill / resume ----------------------------
     def _read_arenas(self, pick):
-        """``pick(arena)`` of every K and V arena, per layer, and the
-        bytes brought to the host for it: each arena WHOLE, whatever is
-        picked. They are fetches (``serving_fetched_bytes_total``) and
-        are counted in ``serving_arena_read_bytes_total`` besides."""
+        """``pick(arena)`` of every K and V arena, per state pair (a
+        layer's, or a (pass, layer)'s), and the bytes brought to the host
+        for it: each arena WHOLE, whatever is picked. They are fetches
+        (``serving_fetched_bytes_total``) and are counted in
+        ``serving_arena_read_bytes_total`` besides."""
         out, nbytes = [], 0
         for kn, vn in self._model.state_names:
             k = self._fetch(self._scope.find_var(kn))
@@ -1144,11 +1248,11 @@ class _ModelEntry:
         the whole pool)."""
         st = self._slots[s]
         if (st is None or st.mode not in ("decode", "spec") or st.ahead
-                or self._model.recurrent):
+                or self._model.chunks_only):
             # a session whose last token is still on the device cannot
             # be spilled: its rows are known, its tokens are not. Nor can
-            # one whose slot holds recurrent state: the tier keeps K/V
-            # rows, and the state is no function of them
+            # one of a model that nothing re-injects into: the tier keeps
+            # K/V rows, and a recurrent state is no function of them
             return False
         req = st.request
         m = self._model
@@ -1512,10 +1616,11 @@ class _ModelEntry:
         prompt = req.prompt
         plen = len(prompt)
         if self._takes_chunks(req):
-            blocks, shared_len = self._acquire_blocks(req)
+            blocks, shared_len, reserve = self._acquire_blocks(req)
             st = _Slot(req, mode="prefill")
             st.seq = self._admit_seq
             st.blocks = blocks
+            st.reserve = reserve
             st.shared_len = shared_len
             # the FINAL chunk always runs (it produces the last-position
             # logits), even when the radix served every block
@@ -1548,7 +1653,7 @@ class _ModelEntry:
                 fetches = self._run("prefill", self._prefill_feeds(prompt),
                                     ev.span)
         try:
-            blocks, shared_len = self._acquire_blocks(req)
+            blocks, shared_len, reserve = self._acquire_blocks(req)
         except _DeferAdmission:
             if fetches is not None:
                 # the retry finds the prompt in the prefix cache
@@ -1557,6 +1662,7 @@ class _ModelEntry:
         st = _Slot(req, mode="decode")
         st.seq = self._admit_seq
         st.blocks = blocks
+        st.reserve = reserve
         st.shared_len = shared_len
         self._rebuild_row_map(st)
         if shared_len < plen:
@@ -1711,7 +1817,7 @@ class _ModelEntry:
                 faults.fire("decode.chunk")
                 if ev.span is not None:
                     ev.span.set(request=req.id, tokens=real, ahead=ahead,
-                                last=last)
+                                last=last, passes=m.passes)
                 feeds = {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
@@ -2440,7 +2546,8 @@ class _ModelEntry:
                 fetches = self._run("step", feeds, ev.span)
                 if ev.span is not None:
                     launch = self._metrics.count("step_launches")
-                    ev.span.set(ahead=prev is not None, launch=launch)
+                    ev.span.set(ahead=prev is not None, launch=launch,
+                                passes=self._model.passes)
         except Exception as e:
             # a failed donated call leaves the arena undefined: every
             # in-flight sequence is lost (failed loudly; with a step in
@@ -2597,7 +2704,7 @@ class _ModelEntry:
             # partial before mutating it
             try:
                 blocks, _nb, cow = self._blocks.ensure_appendable(
-                    st.blocks, st.cursor)
+                    st.blocks, st.cursor, promised=st.reserve > 0)
             except RuntimeError as e:
                 # pool invariant violation: loud per-request failure,
                 # never a dead scheduler thread
@@ -2632,6 +2739,8 @@ class _ModelEntry:
                     self._reject_in_flight(st.request, err, slot=s)
                 continue
             st.blocks = blocks
+            if _nb is not None and st.reserve:
+                st.reserve -= 1
             if cow is not None:
                 try:
                     self._apply_cow(st, cow)
@@ -2747,7 +2856,7 @@ class _ModelEntry:
         self._slots[slot] = None
         self._pool.release(slot)
         if st.blocks:
-            self._blocks.release(st.blocks)
+            self._blocks.release(st.blocks, st.reserve)
         self._release_draft_locked(st)
         req = st.request
         self._engine._tenant_unflight(req.tenant)
@@ -2766,7 +2875,7 @@ class _ModelEntry:
             self._slots[slot] = None
             self._pool.release(slot)
             if st is not None and st.blocks:
-                self._blocks.release(st.blocks)
+                self._blocks.release(st.blocks, st.reserve)
             if st is not None:
                 self._release_draft_locked(st)
         self._engine._tenant_unflight(req.tenant)
@@ -2945,6 +3054,14 @@ class GenerationEngine:
                 "engine with prefix_cache_size=0 and host_tier_mb=0 (got "
                 f"prefix_cache_size={self._prefix_cache_size}, "
                 f"host_tier_mb={self._host_tier_bytes >> 20})")
+        if model.chunks_only and self._host_tier_bytes:
+            from paddle_tpu.utils.enforce import EnforceError
+
+            raise EnforceError(
+                f"model {model.label} has no inject program: what the host "
+                "KV tier keeps (an evicted block's rows, a parked "
+                "session's) could never be put back. Host it on an engine "
+                f"with host_tier_mb=0 (got {self._host_tier_bytes >> 20})")
         self._check_hbm(model)
         entry = _ModelEntry(
             self, model, self._queue_depth, self._breaker_threshold,
@@ -3079,7 +3196,7 @@ class GenerationEngine:
             st = self._tenant(tenant)
             st.in_flight = max(st.in_flight - 1, 0)
 
-    def _pick(self, queue, max_rows=None, lanes=None):
+    def _pick(self, queue, max_rows=None, lanes=None, fits=None):
         """Weighted-fair pick (caller holds queue.lock): first non-empty
         priority lane wins (strict priority), then the lane's queued
         tenant with the smallest virtual time, skipping tenants at their
@@ -3090,7 +3207,10 @@ class GenerationEngine:
         for the round — head-of-line within the tenant is deliberate,
         per-tenant FIFO is the ordering contract. ``lanes`` restricts the
         eligible priority lanes (brownout L3 zeroes the LOW-lane
-        dispatch quota this way — queued LOW waits, it is not lost)."""
+        dispatch quota this way — queued LOW waits, it is not lost).
+        ``fits(request)`` is the entry's own say on a tenant's head
+        request (its block pool cannot cover it yet): skipped for the
+        round like one that needs more rows."""
         with self._tenant_lock:
             for lane in (lanes if lanes is not None else Priority.LANES):
                 requests = queue.lane(lane)
@@ -3105,9 +3225,10 @@ class GenerationEngine:
                     if (st.max_in_flight is not None
                             and st.in_flight >= st.max_in_flight):
                         continue
-                    if max_rows is not None and r.rows > max_rows:
-                        # not enough free slots THIS round for the
-                        # tenant's head request; its turn comes back
+                    if (max_rows is not None and r.rows > max_rows
+                            or fits is not None and not fits(r)):
+                        # not enough free slots (or blocks) THIS round for
+                        # the tenant's head request; its turn comes back
                         candidates[r.tenant] = None
                         continue
                     candidates[r.tenant] = (st, r)
@@ -3217,12 +3338,14 @@ class GenerationEngine:
             sampling = SamplingParams(**sampling)
         if sampling is not None and not isinstance(sampling, SamplingParams):
             self._bad(entry, "sampling must be a SamplingParams or dict")
-        if m.recurrent and (beam_width is not None
-                            or draft_model is not None):
-            # a fork copies K/V rows and a verify re-derives them from
-            # the tokens; neither carries a slot's recurrent state
+        if m.chunks_only and (beam_width is not None
+                              or draft_model is not None):
+            # a fork re-injects copied K/V rows and a verify re-derives
+            # them in a one-shot prefill; a model served by chunks alone
+            # has neither program (and a fork carries no recurrent state)
             self._bad(entry, f"model {m.label} keeps per-slot recurrent "
-                             "state: beam search and speculative decoding "
+                             "state or has no prefill and inject programs: "
+                             "beam search and speculative decoding "
                              "are not served for it")
         beam = None
         if beam_width is not None:

@@ -1,8 +1,9 @@
 """Hybrid decoders as paged DecodeModels: per-slot recurrent state beside
 paged grouped-query K/V rows, routed experts of which this chip holds a
-share. Two families, one set of parts (``_Parts``: the named seeded
-parameters, projections, norms, arenas and their write; ``_hybrid_model``:
-the two programs around a family's ``stack`` and the DecodeModel).
+share, a stack of layers run several times a token. Three families, one
+set of parts (``_Parts``: the named seeded parameters, projections, norms,
+arenas and their write; ``_hybrid_model``: the two programs around a
+family's ``stack`` and the DecodeModel).
 
 **``nemotron_h``** (NVIDIA Nemotron-H / Nemotron 3:
 ``build_nemotron_h_model``).
@@ -52,18 +53,42 @@ then ``x <- x + ffn(RMSNorm(x))``: a dense SwiGLU in the
 no shared expert) in the others. A final RMSNorm and a head TIED to the
 embedding; no bias anywhere. The same two programs, the same one-feed
 decode step, the same share of the experts.
+
+**``ouro``** (ByteDance Ouro, a looped language model:
+``build_ouro_model``). ONE stack of ``num_hidden_layers`` layers, applied
+``total_ut_steps`` times to every token with the SAME parameters (made once
+by name: a layer's seven matrices and four norms exist once, whatever the
+number of passes). A layer is sandwich-normed: ``h <- h + RMSNorm(attn(
+RMSNorm(h)))`` then ``h <- h + RMSNorm(ffn(RMSNorm(h)))``, attention
+multi-head with rotary positions (whole head, rotate-half) and a SwiGLU
+feed-forward, no bias anywhere. After a pass the final RMSNorm; the normed
+stream is what enters the next pass, and after the last the untied head.
+The K/V rows of layer ``l`` differ from pass to pass, so the paged state is
+one arena pair per (pass, layer): ``total_ut_steps x num_hidden_layers``
+pairs in ``state_names``, no per-slot state. An exit gate reads every
+pass's normed stream (``sigmoid(w . h + b)``); at the published
+``early_exit_threshold`` of 1 every token takes every pass, and the gate's
+distribution rides to the host as two counts beside the step's tokens
+(``LOOP_COUNTS``). A threshold under 1 is refused: leaving early is another
+result, and the scheduler steps every slot at one depth.
 """
 
 import math
 
 from paddle_tpu.serving.decode.model import DecodeModel, _state_var
 
-__all__ = ["build_nemotron_h_model", "build_lfm2_model", "MOE_COUNTS"]
+__all__ = ["build_nemotron_h_model", "build_lfm2_model", "build_ouro_model",
+           "MOE_COUNTS", "LOOP_COUNTS"]
 
 #: what the decode step's ``Counts`` hold, in order: the engine adds them
 #: to the counters of these names when the step's tokens come back
 MOE_COUNTS = ("moe_assignments", "moe_held_assignments",
               "moe_touched_experts", "moe_peak_expert_tokens")
+
+#: a looped stack's: the passes run over the step's stepping tokens, and
+#: the sum over them of the pass at which the exit gate expects to leave,
+#: in thousandths
+LOOP_COUNTS = ("loop_pass_tokens", "loop_exit_pass_milli")
 
 
 def _then(block, shape, start, ops, out):
@@ -133,7 +158,9 @@ class _Parts:
     name: seeded parameters (matrices normal ``std``, those that write into
     the residual ``back``), bias-free projections, RMSNorms, the paged K/V
     arenas of the attention layers ``a_layers`` with their scatter write,
-    and the per-slot states."""
+    and the per-slot states. An attention layer is named by its index, or
+    by ``(pass, layer)`` where a stack runs several times and every pass
+    keeps rows of its own."""
 
     def __init__(self, prefix, dtype, eps, std, back, rows, kv_width,
                  a_layers, slot_states):
@@ -145,8 +172,10 @@ class _Parts:
         self.std, self.back = std, back
         self.rows, self.kv_width = rows, kv_width
         self.a_layers = a_layers
-        self.state_names = [(f"{prefix}.kcache{i}", f"{prefix}.vcache{i}")
-                            for i in a_layers]
+        tags = [i if isinstance(i, int) else ".p%d.l%d" % i
+                for i in a_layers]
+        self.state_names = [(f"{prefix}.kcache{tag}", f"{prefix}.vcache{tag}")
+                            for tag in tags]
         self.slot_states = slot_states
         self.startup = Program()
 
@@ -202,12 +231,13 @@ class _Parts:
 
 def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                   block_size, num_blocks, chunk_tokens, kv_heads, sm_scale,
-                  eos_id, name, version):
+                  eos_id, name, version, count_names=MOE_COUNTS, passes=1):
     """The hybrid family's two programs around ``stack(program, toks,
     positions, wrows, mode, attend, slot)`` (the layers over ``toks`` ``[S,
     1]`` or ``[1, C]``; ``attend(i, q, k, v)`` is the program's own
-    attention over the paged arenas; returns the logits and the expert
-    layers' routing counts), and their DecodeModel."""
+    attention over the paged arenas; returns the logits and the int32
+    vectors of ``count_names`` that its layers counted, which are summed),
+    and their DecodeModel."""
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
     from paddle_tpu.utils import unique_name
@@ -251,7 +281,9 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         cbias = fluid.data(DecodeModel.CHU_BIAS, [1, C, L], dtype="float32")
         crows = fluid.data(DecodeModel.CHU_ROWS, [L], dtype="int64")
         cwrows = fluid.data(DecodeModel.CHU_WRITE_ROWS, [C], dtype="int64")
-        cslot = fluid.data(DecodeModel.CHU_SLOT, [1], dtype="int64")
+        # whose rows of the per-slot states the chunk advances
+        cslot = (fluid.data(DecodeModel.CHU_SLOT, [1], dtype="int64")
+                 if parts.slot_states else None)
 
         def attend_chunk(i, q, k, v):
             nk, nv = parts.write(chunk, i, cwrows, k, v, 0)
@@ -272,7 +304,7 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         kv_dtype=parts.dtype, slot_states=parts.slot_states,
         logits_fetch=dec_logits.name, token_fetch=next_token.name,
         counts_fetch=token_counts.name if counts else None,
-        count_names=MOE_COUNTS if counts else (),
+        count_names=count_names if counts else (), passes=passes,
         prefill_logits_fetch=None, chunk_logits_fetch=chu_logits.name,
         prefill_kv_fetches=[], inject_kv_feeds=[],
         eos_id=eos_id, name=name, version=version, builder=rebuild)
@@ -553,3 +585,133 @@ def build_lfm2_model(
         slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
         kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
         version=version)
+
+
+def build_ouro_model(
+        vocab_size, hidden_size, num_hidden_layers, *, num_attention_heads,
+        num_key_value_heads, head_dim, intermediate_size, total_ut_steps=4,
+        early_exit_threshold=1.0, rms_norm_eps=1e-6, rope_theta=1000000.0,
+        initializer_range=0.02, dtype="bfloat16", slots=4, max_len=64,
+        block_size=16, num_blocks=None, chunk_tokens=16, eos_id=None,
+        name="ouro", version="1"):
+    """Build the ``ouro`` looped decoder as a paged DecodeModel (module
+    docstring). The sizes are the published ``config.json``'s keys under
+    their own names. ``num_blocks`` may be fewer than ``slots`` sequences
+    of ``max_len`` need (a token's rows are ``total_ut_steps`` times a
+    plain stack's): the engine then admits a request against its whole
+    block chain (engine.py, admission by reservation).
+    ``initializer_range`` is the matrices' standard deviation (a tiny
+    preset takes a wider one, as ``build_lfm2_model``'s)."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import ConstantInitializer
+    from paddle_tpu.layer_helper import LayerHelper
+
+    if float(early_exit_threshold) < 1.0:
+        raise ValueError(
+            f"early_exit_threshold {early_exit_threshold}: under 1 a token "
+            "leaves before its last pass, which is another result and "
+            "would step slots at different depths; only 1 is served")
+    V, H, NL = int(vocab_size), int(hidden_size), int(num_hidden_layers)
+    T = int(total_ut_steps)
+    if T < 1:
+        raise ValueError(f"total_ut_steps must be at least 1, got {T}")
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    NQ, NKV, D = (int(num_attention_heads), int(num_key_value_heads),
+                  int(head_dim))
+    F = int(intermediate_size)
+    prefix = f"{name}_v{version}"
+    # two sub-layers a layer application write into the residual
+    std = float(initializer_range)
+    parts = _Parts(prefix, dtype, float(rms_norm_eps), std,
+                   std / math.sqrt(2 * NL * T), R, NKV * D,
+                   [(t, i) for t in range(T) for i in range(NL)], [])
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+
+    def heads(x, n, suffix, positions):
+        """``x`` projected to ``n`` heads and the rotation over each. The
+        matrix is stored ``[n * D, H]``, a head's rows together: the
+        product that feeds a rotation wants it so, and a stack run more
+        than once otherwise keeps a transposed copy of it in HBM from its
+        first pass to its last (16.8 MB a layer: PERF.md section 6,
+        PR 43)."""
+        lead = [int(d) for d in x.shape[:2]]
+        w = LayerHelper("fc").create_parameter(
+            matrix(suffix + ".w"), shape=[n * D, H], dtype=dtype)
+        t = fluid.layers.matmul(x, w, transpose_y=True, out_dtype="float32")
+        return fluid.layers.reshape(fluid.layers.rotary_embedding(
+            fluid.layers.reshape(t, lead + [n, D]), positions,
+            theta=float(rope_theta), out_dtype=dtype), lead + [n * D])
+
+    def layer(h, t, i, positions, attend):
+        x = norm(h, f"l{i}.input_layernorm")
+        ctx = attend((t, i), heads(x, NQ, f"l{i}.q", positions),
+                     heads(x, NKV, f"l{i}.k", positions),
+                     proj(x, NKV * D, f"l{i}.v"))
+        out = proj(ctx, H, f"l{i}.o", residual=True, out_dtype="float32")
+        h = fluid.layers.elementwise_add(
+            h, norm(out, f"l{i}.post_attention_layernorm",
+                    out_dtype="float32"))
+        x = norm(h, f"l{i}.pre_feedforward_layernorm")
+        gated = fluid.layers.elementwise_mul(
+            proj(x, F, f"l{i}.gate", act="silu", out_dtype="float32"),
+            proj(x, F, f"l{i}.up", out_dtype="float32"))
+        out = proj(fluid.layers.cast(gated, dtype), H, f"l{i}.down",
+                   residual=True, out_dtype="float32")
+        return fluid.layers.elementwise_add(
+            h, norm(out, f"l{i}.post_feedforward_layernorm",
+                    out_dtype="float32"))
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The ``NL`` layers ``T`` times over ``toks``, the final norm
+        after every pass; the exit gate's two counts over the tokens whose
+        write row is real."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        # E[exit pass] = sum over t of P(not left before pass t + 1):
+        # 1 + (1 - l_1) + (1 - l_1)(1 - l_2) + ..; the last pass takes
+        # what is left, so its own gate decides nothing
+        stays, expected = None, []
+        for t in range(T):
+            for i in range(NL):
+                h = layer(h, t, i, positions, attend)
+            h = norm(h, "final_norm", out_dtype="float32")
+            leave = fluid.layers.sigmoid(fluid.layers.fc(
+                h, 1, num_flatten_dims=2, param_attr=matrix("exit_gate.w"),
+                bias_attr=attr("exit_gate.b", ConstantInitializer(0.0))))
+            stay = fluid.layers.scale(leave, scale=-1.0, bias=1.0)
+            stays = stay if stays is None else fluid.layers.elementwise_mul(
+                stays, stay)
+            if t < T - 1:
+                expected.append(stays)
+        logits = proj(fluid.layers.cast(h, dtype), V, "head",
+                      out_dtype="float32")
+        n = int(wrows.shape[0])
+        # 1.0 for a token whose write row is real, 0.0 at the sentinel R
+        steps = fluid.layers.clip(fluid.layers.scale(
+            fluid.layers.cast(wrows, "float32"), scale=-1.0, bias=float(R)),
+            0.0, 1.0)
+        later = (fluid.layers.sums(expected) if len(expected) > 1
+                 else expected[0] if expected
+                 else fluid.layers.scale(steps, scale=0.0))
+        milli = fluid.layers.scale(fluid.layers.reshape(later, [n]),
+                                   scale=1000.0, bias=1000.5)
+        # truncation rounds a positive number to nearest
+        milli = fluid.layers.cast(fluid.layers.cast(milli, "int32"),
+                                  "float32")
+        counts = fluid.layers.cast(fluid.layers.concat([
+            fluid.layers.scale(fluid.layers.reduce_sum(steps), scale=float(T)),
+            fluid.layers.reduce_sum(
+                fluid.layers.elementwise_mul(milli, steps))], axis=0),
+            "int32")
+        return logits, [counts]
+
+    return _hybrid_model(
+        parts, stack, lambda: build_ouro_model(**kwargs), vocab=V, hidden=H,
+        slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
+        kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
+        version=version, count_names=LOOP_COUNTS, passes=T)

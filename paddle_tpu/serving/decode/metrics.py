@@ -181,6 +181,16 @@ class DecodeMetrics(ServingMetrics):
         # the expert layers (the straggler a grouped product waits for)
         "moe_assignments", "moe_held_assignments", "moe_touched_experts",
         "moe_peak_expert_tokens",
+        # a model whose stack runs several times a token, per decode step
+        # as the device ran it: stepping tokens x the passes they took,
+        # and the sum over them of the pass at which the exit gate expects
+        # to leave, in thousandths
+        "loop_pass_tokens", "loop_exit_pass_milli",
+        # admission by reservation (an arena smaller than slots x length,
+        # no tier): requests admitted against their whole block chain, and
+        # the blocks promised to them; "admissions_deferred" counts, once
+        # a request, those the pool and not the slots made wait
+        "reserved_admissions", "blocks_reserved",
         # the KV block pool and the host tier (counted by pool.py through
         # the sink the engine hands it): blocks handed out, those of them
         # that recycled a cached block, evicted blocks the tier took; and

@@ -99,7 +99,10 @@ class DecodeModel:
     float32 (grouped-query attention in bfloat16). ``counts_fetch`` names
     an int32 vector, the step's ``[S]`` tokens then one integer per
     ``count_names``: what a greedy step hands the host in its ONE fetch,
-    the integers added to the counters of those names.
+    the integers added to the counters of those names. ``state_names``
+    is one pair per state the attention layers keep, a layer's or, where a
+    stack runs ``passes`` times a token with rows of its own each time, a
+    (pass, layer)'s; ``passes`` is what the launch spans say of it.
 
     The decode step fetches ``[logits_fetch, token_fetch]``:
     ``logits_fetch`` names the float32 ``[S, 1, V]`` logits (mask added
@@ -142,7 +145,8 @@ class DecodeModel:
                  chunk_logits_fetch=None, eos_id=None, name="model",
                  version="1", builder=None, logits_mask=False,
                  token_fetch=None, kv_width=None, kv_dtype="float32",
-                 slot_states=(), counts_fetch=None, count_names=()):
+                 slot_states=(), counts_fetch=None, count_names=(),
+                 passes=1):
         self.decode_program = decode_program
         self.prefill_program = prefill_program
         self.inject_program = inject_program
@@ -173,11 +177,20 @@ class DecodeModel:
                             for n, shape, dt in slot_states]
         self.counts_fetch = counts_fetch
         self.count_names = tuple(count_names)
+        self.passes = int(passes)
 
     @property
     def recurrent(self):
         """Whether the model keeps per-slot recurrent state."""
         return bool(self.slot_states)
+
+    @property
+    def chunks_only(self):
+        """Whether every prompt streams through the chunk program and
+        nothing is ever re-injected: the model has no one-shot prefill
+        and no inject program (per-slot recurrent state, or K/V rows of
+        several passes a layer, which no host copy carries)."""
+        return self.prefill_program is None
 
     @property
     def key(self):

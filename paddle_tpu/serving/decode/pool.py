@@ -232,7 +232,10 @@ class _RadixTree:
             for i in range(n_full):
                 chunk = tuple(toks[i * bs:(i + 1) * bs])
                 child = node.children.get(chunk)
-                if child is None:
+                # a node whose own block was evicted before a descendant's
+                # (a chain is released, so cached, head first) names no
+                # block: the chain ends before it
+                if child is None or child.block_id is None:
                     break
                 node = child
                 ids.append(node.block_id)
@@ -334,6 +337,7 @@ class BlockPool:
         self.radix_hits = 0            # shared-block references served
         self.forks = 0                 # beam forks served (refcount++ paths)
         self.tier_writebacks = 0       # evicted blocks spilled to host
+        self.reserved = 0              # promised to admitted owners, unopened
 
     def attach_tier(self, tier, read_rows=None):
         """Adopt a host-RAM tier (tier.py): LRU eviction write-backs a
@@ -351,15 +355,41 @@ class BlockPool:
 
     @property
     def free_count(self):
-        """Blocks on the free list: what can be handed out without
-        evicting a cached block (and so without a write-back)."""
-        return len(self._free)
+        """Blocks that can be handed out at no cost and are promised to
+        nobody: the free list, beside it the cached blocks where no tier
+        takes an evicted block's rows (the eviction then moves no bytes),
+        less the blocks reserved for admitted owners and not yet
+        opened."""
+        return self._room_locked() if self._tier is None else (
+            len(self._free) - self.reserved)
+
+    def _room_locked(self):
+        """Blocks an owner without a reservation may still be given."""
+        return len(self._free) + len(self._cached) - self.reserved
+
+    # -- reservation -------------------------------------------------------
+    def reserve(self, n):
+        """Promise ``n`` blocks to one owner, who opens them later (its
+        allocations pass ``promised``) and hands back what it never opened
+        (`release`). False, and nothing promised, when the pool cannot
+        cover them beside what it has promised already."""
+        with self._lock:
+            if n > self._room_locked():
+                return False
+            self.reserved += int(n)
+            return True
 
     def block(self, bid):
         return self._blocks[bid]
 
     # -- allocation --------------------------------------------------------
-    def _alloc_locked(self):
+    def _alloc_locked(self, promised=False):
+        """One block: off the free list, else the LRU cached one's.
+        ``promised`` says the caller holds a reservation, which this
+        allocation uses up; any other caller gets None rather than a block
+        that is promised to someone."""
+        if not promised and self._room_locked() <= 0:
+            return None
         if not self._free:
             # evict the LRU cached (refcount-0, registered) block
             if not self._cached:
@@ -375,6 +405,8 @@ class BlockPool:
         b = self._blocks[bid]
         b.reset()
         b.refcount = 1
+        if promised:
+            self.reserved -= 1
         self.allocs += 1
         self._count("pool_block_allocs")
         return b
@@ -406,7 +438,7 @@ class BlockPool:
         bs = self.block_size
         n = (int(n_rows) + bs - 1) // bs
         with self._lock:
-            if n > len(self._free) + len(self._cached):
+            if n > self._room_locked():
                 return None
             out = []
             for i in range(n):
@@ -415,13 +447,17 @@ class BlockPool:
                 out.append(b)
             return out
 
-    def acquire_for_prompt(self, tokens):
+    def acquire_for_prompt(self, tokens, promised=0):
         """Map a prompt onto blocks: longest shared full-block chain
         from the radix tree (+ a shared partial tail when one matches),
         fresh private blocks for the rest. Returns
         ``(blocks, shared_len)`` — ``shared_len`` positions already hold
         the right rows on device and must NOT be re-injected — or
-        ``(None, 0)`` when the pool cannot cover the prompt."""
+        ``(None, 0)`` when the pool cannot cover the prompt. ``promised``
+        is how many blocks the caller has reserved (`reserve`): the fresh
+        ones come out of those, and so does a shared block that was
+        cached (it leaves what the pool can hand out); ``reserved`` falls
+        by what was used."""
         toks = [int(t) for t in tokens]
         bs = self.block_size
         ids, node, tail_bid = self._radix.lookup_chain(toks, bs)
@@ -445,17 +481,22 @@ class BlockPool:
             shared_ids = {b.id for b in sharing}
             evictable = sum(1 for bid in self._cached
                             if bid not in shared_ids)
-            if n_new > (len(self._free) + evictable):
+            promised = int(promised)
+            if n_new > (len(self._free) + evictable - self.reserved
+                        + promised):
                 return None, 0
             # commit: reference shared, allocate private
             for b in sharing:
                 if b.refcount == 0:
                     self._cached.pop(b.id, None)
+                    if promised:
+                        promised -= 1
+                        self.reserved -= 1
                 b.refcount += 1
                 self.radix_hits += 1
             blocks = list(sharing)
             for i in range(n_new):
-                nb = self._alloc_locked()
+                nb = self._alloc_locked(promised=i < promised)
                 start = shared_len + i * bs
                 nb.tokens = tuple(toks[start:start + bs])
                 nb.size_used = min(bs, len(toks) - start)
@@ -493,9 +534,10 @@ class BlockPool:
                         b.partial_of = node.chain_hash
                     break
 
-    def ensure_appendable(self, blocks, cursor):
-        """Make position ``cursor`` writable for ONE owner. Returns
-        ``(blocks, new_block, cow)``:
+    def ensure_appendable(self, blocks, cursor, promised=False):
+        """Make position ``cursor`` writable for ONE owner (``promised``:
+        one that holds a reservation, which a fresh block uses up).
+        Returns ``(blocks, new_block, cow)``:
 
         * cursor opens a new chunk -> allocate a fresh private block
           (``new_block`` set);
@@ -511,7 +553,7 @@ class BlockPool:
         idx = cursor // bs
         if idx >= len(blocks):
             with self._lock:
-                nb = self._alloc_locked()
+                nb = self._alloc_locked(promised)
             if nb is None:
                 return None, None, None
             return blocks + [nb], nb, None
@@ -521,7 +563,7 @@ class BlockPool:
                 if b.host_rows is None:
                     raise RuntimeError(
                         f"shared block {b.id} has no host rows to COW")
-                nb = self._alloc_locked()
+                nb = self._alloc_locked(promised)
                 if nb is None:
                     return None, None, None
                 nb.size_used = b.size_used
@@ -576,10 +618,12 @@ class BlockPool:
                 child.append(nb)
             return child, nb, src
 
-    def release(self, blocks):
-        """Drop one owner's references. Registered refcount-0 blocks
-        stay cached (LRU) for future prefix hits; private ones free."""
+    def release(self, blocks, reserved=0):
+        """Drop one owner's references, and the ``reserved`` blocks it was
+        promised and never opened. Registered refcount-0 blocks stay
+        cached (LRU) for future prefix hits; private ones free."""
         with self._lock:
+            self.reserved -= int(reserved)
             for b in blocks:
                 b.refcount -= 1
                 if b.refcount > 0:
@@ -601,6 +645,7 @@ class BlockPool:
                 b.reset()
             self._free = list(range(self.num_blocks - 1, -1, -1))
             self._cached.clear()
+            self.reserved = 0
 
     def check_conservation(self):
         """The row-conservation invariant, assertable after every beam
@@ -642,6 +687,7 @@ class BlockPool:
                 "blocks_free": len(self._free),
                 "blocks_cached": len(self._cached),
                 "blocks_live": len(live),
+                "blocks_reserved": self.reserved,
                 "rows_total": self.rows,
                 "rows_live": physical,
                 "rows_cached": cached_rows,

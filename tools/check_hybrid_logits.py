@@ -1,13 +1,16 @@
 """A served hybrid configuration (``nemotron3_nano_30b_a3b``,
-``lfm2_24b_a2b``: any whose file names a ``builder`` and a ``reference``)
-against its plain reference, outside any timed window, and the readings the
-cell's limits are set from (its traffic file; PERF.md section 2).
+``lfm2_24b_a2b``, ``ouro_2_6b``: the three families of
+``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
+``reference``) against its plain reference, outside any timed window, and
+the readings the cell's limits are set from (its traffic file; PERF.md
+section 2).
 
     python3 tools/check_hybrid_logits.py --seed <n>
         [--config nemotron3_nano_30b_a3b] [--traffic reasoning_steady]
         [--requests 32] [--steps 192] [--faults ssm,conv,kv,kv_all,positions]
         [--references float8_e4m3fn,operands:bfloat16]
         [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
+        [--passes 3] [--share-passes] [--stale-arena 1,7]
         [--dump chiprun_out/rows.npz] [--rehearse-cpu]
 
 Requests of the cell's own length distribution go through the engine,
@@ -35,7 +38,20 @@ served one), ``operands:<dtype>`` the left operand of its products (the
 served program's own arithmetic, as near as a plain pass comes), and for
 the latter the expert layers' choices are compared with the float32 pass's
 (``flips``): the tokens whose chosen sets differ, a layer, and those of
-them where a held expert comes or goes.
+them where a held expert comes or goes; its ``rows_from_float32`` is that
+reference's rows against the float32 reference's (how far rounding alone
+carries a row: the floor under every ``row_sigma``), and every fault's
+tokens are read against it as well (``against_operands:<dtype>``).
+
+A looped stack's controls (``--config ouro_2_6b``): ``--passes N`` reads the
+sound tokens against the reference run N times through its stack and not
+``total_ut_steps``; ``--stale-arena t,l`` puts the K arena of pass ``t``,
+layer ``l`` alone (one of 192) back after every decode step;
+``--share-passes`` builds the SERVED model with every pass of a layer on
+pass 0's arena pair, so that a token's older rows are its last pass's for
+every pass (the decode-time sharing the family's paper offers as an
+approximation, which nothing serves): the whole run is then the control,
+and its ``sound`` reading is what the comparison has to refuse.
 """
 
 import argparse
@@ -58,9 +74,19 @@ def _stale(entry, fault):
     import jax.numpy as jnp
 
     m = entry.model
-    names = ([kv[0] for kv in m.state_names] if fault == "kv_all" else
-             [m.state_names[0][0]] if fault == "kv" else
-             [n for n, _s, _d in m.slot_states if "." + fault in n][:1])
+    k_arenas = [kv[0] for kv in m.state_names]
+    if fault == "kv_all":
+        names = k_arenas
+    elif fault == "kv":
+        names = k_arenas[:1]
+    elif fault.startswith("arena_"):
+        tag = ".kcache.p%d.l%d" % tuple(
+            int(x) for x in fault[len("arena_"):].split("_"))
+        names = [n for n in k_arenas if n.endswith(tag)]
+    else:
+        names = [n for n, _s, _d in m.slot_states if "." + fault in n][:1]
+    if fault != "positions" and not names:
+        raise ValueError(f"no state of the model answers to {fault!r}")
     launch = entry._run
 
     def run(kind, feeds, span=None):
@@ -123,6 +149,26 @@ def _against(system, prompts, served, **how):
     return np.stack(sigma), np.stack(behind), extra
 
 
+def _between(system, prompts, served, how):
+    """The quantiles of max |row - float32 reference's row| over the
+    latter's standard deviation, a served token, for the reference computed
+    as ``how`` says: the ``row_sigma`` of one reference against the
+    other."""
+    sigma = []
+    for prompt, (out, _got) in zip(prompts, served):
+        first = len(prompt) - 1
+        at = list(prompt) + out[:-1], range(first, first + len(out))
+        want = system.reference_logits(*at)
+        other = system.reference_logits(*at, **how)
+        if isinstance(other, tuple):
+            other = other[0]
+        sigma.append(np.abs(other - want).max(1) / want.std(1))
+    sigma = np.stack(sigma)
+    return {"median": float(np.median(sigma)),
+            "p90": float(np.percentile(sigma, 90)),
+            "worst": float(sigma.max())}
+
+
 def _summary(sigma, behind, traffic):
     flat = behind.reshape(-1)
     tokens = traffic["check_tokens"]
@@ -168,6 +214,12 @@ def main(argv=None):
     ap.add_argument("--kernels", default=None, choices=("off", "interpret"))
     ap.add_argument("--pattern", default=None, help="another layer pattern "
                     "(a diagnosis by kind of layer; widths as configured)")
+    ap.add_argument("--passes", type=int, default=None, help="a looped "
+                    "stack: the reference run this many times through it")
+    ap.add_argument("--share-passes", action="store_true", help="a looped "
+                    "stack: SERVE every pass of a layer from one arena pair")
+    ap.add_argument("--stale-arena", default=None, metavar="PASS,LAYER",
+                    help="a looped stack: that one K arena a step stale")
     ap.add_argument("--dump", default=None, help="an .npz of every "
                     "reading's row_sigma and behind, [requests, steps]")
     ap.add_argument("--rehearse-cpu", action="store_true")
@@ -193,6 +245,15 @@ def main(argv=None):
     if args.pattern:
         config = dict(config, hybrid_override_pattern=args.pattern,
                       num_hidden_layers=len(args.pattern))
+    if args.share_passes:
+        from paddle_tpu.serving.decode import hybrid
+
+        own = hybrid._Parts.arenas
+        hybrid._Parts.arenas = lambda parts, program, key: own(
+            parts, program, (0, key[1]))
+    faults = [f for f in args.faults.split(",") if f]
+    if args.stale_arena:
+        faults.append("arena_" + args.stale_arena.replace(",", "_"))
     mode = args.kernels or ("interpret" if args.rehearse_cpu else None)
     rng = np.random.default_rng(args.seed)
     with kernels.scoped_mode(mode or kernels.mode()):
@@ -205,42 +266,64 @@ def main(argv=None):
                    for n in lengths]
         system.engine.start()
         served = {"sound": _serve(system, prompts, steps)}
-        for fault in filter(None, args.faults.split(",")):
+        for fault in faults:
             undo = _stale(system.entry, fault)
             served["stale_" + fault] = _serve(system, prompts, steps)
             undo()
         system.engine.shutdown()
     keys = system.config
     # how many experts are held here: the count the configuration cut
-    (cut,) = [k for k in config["reduced"] if k.endswith("experts")]
-    held = (system.expert_offset, system.expert_offset + keys[cut])
+    # (none for a configuration without routed experts)
+    cut = [k for k in config["reduced"] if k.endswith("experts")]
+    held = (system.expert_offset,
+            system.expert_offset + keys[cut[0]]) if cut else None
     report = {"seed": args.seed, "config": args.config,
               "requests": len(prompts), "steps": steps,
               "prompt_lengths": lengths,
-              "state_dtype": config["settings"]["state_dtype"],
+              "state_dtype": config["settings"].get("state_dtype"),
+              "passes_share_arenas": bool(args.share_passes),
               "kernels": mode or "auto",
               "check_tokens": traffic["check_tokens"],
               "check_tolerance": traffic["check_tolerance"]}
     dump = {}
+    rounded = [ref for ref in args.references.split(",")
+               if ref.startswith("operands:")]
     for name, answers in served.items():
         sigma, behind, routing = _against(
-            system, prompts, answers, routing=name == "sound")
+            system, prompts, answers,
+            **({"routing": True} if held and name == "sound" else {}))
         report[name] = _summary(sigma, behind, traffic)
         dump[name + ".row_sigma"], dump[name + ".behind"] = sigma, behind
         if name != "sound":
+            # a fault's tokens against the rounded reference too: whether
+            # a comparison with rounding's share taken out would tell it
+            for ref in rounded:
+                report[name]["against_" + ref] = _summary(*_against(
+                    system, prompts, answers,
+                    round_operands=ref.split(":")[1])[:2], traffic)
             continue
-        gap = np.concatenate([(s[..., -2] - s[..., -1]).reshape(-1)
-                              for _ids, s in routing])
-        report["reference_margin_share_under"] = {
-            str(t): float((gap < t).mean()) for t in (1e-3, 3e-3, 1e-2)}
-        dump["sound.margin"] = np.stack(
-            [s[..., -2] - s[..., -1] for _ids, s in routing])
-        for ref in filter(None, args.references.split(",")):
-            how = ({"round_operands": ref.split(":")[1], "routing": True}
-                   if ref.startswith("operands:") else {"round_to": ref})
+        if held:
+            gap = np.concatenate([(s[..., -2] - s[..., -1]).reshape(-1)
+                                  for _ids, s in routing])
+            report["reference_margin_share_under"] = {
+                str(t): float((gap < t).mean()) for t in (1e-3, 3e-3, 1e-2)}
+            dump["sound.margin"] = np.stack(
+                [s[..., -2] - s[..., -1] for _ids, s in routing])
+        refs = [(ref, dict({"round_operands": ref.split(":")[1]},
+                           **({"routing": True} if held else {}))
+                 if ref.startswith("operands:") else {"round_to": ref})
+                for ref in filter(None, args.references.split(","))]
+        if args.passes is not None:
+            refs.append((f"passes_{args.passes}", {"passes": args.passes}))
+        for ref, how in refs:
             _sigma, behind, other = _against(system, prompts, answers, **how)
             report["reference_" + ref] = _summary(_sigma, behind, traffic)
             dump[f"reference_{ref}.behind"] = behind
+            if ref.startswith("operands:"):
+                # how far rounding alone carries a row: this reference's
+                # rows against the float32 reference's, in its units
+                report["reference_" + ref]["rows_from_float32"] = _between(
+                    system, prompts, answers, how)
             if other:
                 report["reference_" + ref]["flips"] = _flips(
                     routing, other, held, keys["num_experts_per_tok"])
