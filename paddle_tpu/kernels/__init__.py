@@ -211,35 +211,48 @@ def _paged_case(rng, seqs, length, block, hidden, lengths, share=(),
             draw(pool * block, hidden), rows.reshape(-1), bias)
 
 
-def _paged_both(args, seqs, length, block, hidden):
+def _paged_both(args, seqs, length, block, hidden, kv_heads=0):
     """(kernel through the interpreter, composite) on one ``_paged_case``,
-    under jit on both sides, as numpy."""
+    under jit on both sides, as numpy. With ``kv_heads`` the rows hold that
+    many heads side by side and ``args``' query is ``[S, heads * D]``."""
     import jax
 
     from paddle_tpu.kernels import attention as A
 
-    sm = 1.0 / float(np.sqrt(hidden))
+    sm = 1.0 / float(np.sqrt(hidden // (kv_heads or 1)))
     got = jax.jit(lambda *a: A.paged_attention(
-        *a, seqs, length, block, sm, interpret=True))(*args)
+        *a, seqs, length, block, sm, interpret=True,
+        kv_heads=kv_heads))(*args)
     ref = jax.jit(lambda *a: A.paged_attention_composite(
-        *a, seqs, length, sm))(*args)
+        *a, seqs, length, sm, kv_heads=kv_heads))(*args)
     return np.asarray(got), np.asarray(ref)
+
+
+def _assert_paged_parity(args, lengths, what, *geometry, **kw):
+    """Live slots within 1e-5 both ways of the composite; a free slot's
+    bias is all -1e9, so the composite averages garbage rows there (not
+    compared) and the kernel reads nothing and writes zeros."""
+    got, ref = _paged_both(args, *geometry, **kw)
+    live = np.asarray(lengths) > 0
+    _assert_close_both_ways(got[live], ref[live], what, 1e-5, 1e-5)
+    assert not got[~live].any()
 
 
 def _parity_paged(rng):
     """Ragged lengths in one batch (a free slot among them), two slots
-    sharing prefix blocks, the block table out of order;
+    sharing prefix blocks, the block table out of order; then what the
+    copy pipeline hands from slot to slot: the first slot free, live and
+    free slots alternating, the last slot the only live one, all free;
     tests/test_kernels.py holds more geometries. Then the head axis."""
     S, L, bs, H = 6, 64, 16, 128
     lengths = [1, 15, 16, 17, 64, 0]
     args = _paged_case(rng, S, L, bs, H, lengths, share=[(3, 4, 1)])
-    got, ref = _paged_both(args, S, L, bs, H)
-    live = np.asarray(lengths) > 0
-    _assert_close_both_ways(got[live], ref[live], "paged_attention",
-                            1e-5, 1e-5)
-    # a free slot's bias is all -1e9: the composite averages garbage rows
-    # there (not compared); the kernel reads nothing and writes zeros
-    assert not got[~live].any()
+    _assert_paged_parity(args, lengths, "paged_attention", S, L, bs, H)
+    for lengths in ([0, 33, 64, 1, 0, 20], [7, 0, 64, 0, 17, 0],
+                    [0, 0, 0, 0, 0, 40], [0] * 6):
+        args = _paged_case(rng, S, L, bs, H, lengths)
+        _assert_paged_parity(args, lengths, f"paged_attention {lengths}",
+                             S, L, bs, H)
     _parity_paged_grouped(rng)
 
 
@@ -264,29 +277,36 @@ def _tpu_cases_paged():
 def _parity_paged_grouped(rng):
     """The head axis: 2 K/V heads of 128 a row, 4 query heads to each; then
     8 heads of 64, two to a lane tile; then 16 heads of 128 with ONE query
-    head each (one real query row in a tile)."""
-    for G, per, D in ((2, 4, 128), (8, 4, 64), (16, 1, 128)):
-        _parity_paged_heads(rng, G, per, D)
+    head each (one real query row in a tile). Each at lengths a block, a
+    tile and a slot long and one off them, then with slots as long as the
+    geometry's copy unit (512, 256 and 128 rows in float32) and one off
+    it, a free slot between them."""
+    for G, per, D, unit_rows in ((2, 4, 128, 512), (8, 4, 64, 256),
+                                 (16, 1, 128, 128)):
+        _parity_paged_heads(rng, G, per, D, 64, [1, 15, 17, 64, 0])
+        _parity_paged_unit_edges(rng, G, per, D, unit_rows)
 
 
-def _parity_paged_heads(rng, G, per, D):
-    import jax
-
-    from paddle_tpu.kernels import attention as A
-
-    S, L, bs = 5, 64, 16
-    lengths = [1, 15, 17, 64, 0]
+def _paged_heads_case(rng, G, per, D, L, lengths, bs=16):
+    """``_paged_case`` with rows of ``G`` heads of ``D`` and ``per`` query
+    heads to each."""
+    S = len(lengths)
     _q, k, v, rows, bias = _paged_case(rng, S, L, bs, G * D, lengths)
     q = rng.randn(S, G * per * D).astype("float32")
-    sm = 1.0 / float(np.sqrt(D))
-    got = jax.jit(lambda *a: A.paged_attention(
-        *a, S, L, bs, sm, interpret=True, kv_heads=G))(q, k, v, rows, bias)
-    ref = jax.jit(lambda *a: A.paged_attention_composite(
-        *a, S, L, sm, kv_heads=G))(q, k, v, rows, bias)
-    live = np.asarray(lengths) > 0
-    _assert_close_both_ways(np.asarray(got)[live], np.asarray(ref)[live],
-                            "paged_attention (grouped)", 1e-5, 1e-5)
-    assert not np.asarray(got)[~live].any()
+    return (q, k, v, rows, bias), (S, L, bs, G * D)
+
+
+def _parity_paged_heads(rng, G, per, D, L, lengths):
+    args, geometry = _paged_heads_case(rng, G, per, D, L, lengths)
+    _assert_paged_parity(args, lengths, "paged_attention (grouped)",
+                         *geometry, kv_heads=G)
+
+
+def _parity_paged_unit_edges(rng, G, per, D, unit_rows):
+    """Slots one under, at and one over a copy unit of ``unit_rows`` rows,
+    a free slot between them, then two units."""
+    _parity_paged_heads(rng, G, per, D, 2 * unit_rows, [
+        unit_rows - 1, 0, unit_rows, unit_rows + 1, 2 * unit_rows])
 
 
 def _tpu_cases_paged_grouped():
@@ -447,9 +467,10 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "paged_attention", ("paged_attention",), "tolerance", _parity_paged,
-    tpu_cases=_tpu_cases_paged,
+    tpu_cases=_tpu_cases_paged, version=2,
     doc="blocked [S,1] decode attention over the live blocks of a paged "
-        "arena, online softmax (kernels/attention.py)",
+        "arena, online softmax, the next live copy unit always in flight "
+        "(kernels/attention.py)",
 ))
 register(KernelSpec(
     "moe_experts", ("moe_routed_experts",), "tolerance", _parity_moe_experts,

@@ -13,38 +13,65 @@ round-trips HBM between its stages, and so interpret mode is BIT-identical
 to the composite (tests/test_kernels.py).
 
 ``paged_attention`` — the same attention over the flat ``[R, H]`` block
-arenas, which stay in HBM: the grid runs over slots, a slot's block table
-and length ride in scalar prefetch, and the body brings the slot's LIVE
-blocks into a double-buffered VMEM scratch by ``make_async_copy``, a group
-of blocks at a time, the next group's copies in flight while this one is
-reduced by an online softmax. The trip count comes from the slot's length:
-a dead block is never read, no ``[S * L, H]`` view is ever written, and a
-free slot (length 0) reads nothing and writes zeros. The bias tile of every
-visited group is added to the scores, so the kernel computes exactly
-``softmax(q K^T scale + bias) V`` for ANY bias; the length only bounds which
-blocks are touched, and every position skipped is one whose weight is
-``exp(-1e9 - m) = 0`` in float32. Its parity contract is a TOLERANCE
-(1e-5 both ways, as for flash): an online softmax regroups float32 sums, so
-it is close to the composite and not bit-identical with it. What stays
-bit-exact is one compiled program replayed under any admission order, and
-resume after park (tests/test_decode.py). The body takes dtype and widths
-from its operands and reduces in float32 on the VPU (a one-row GEMV wastes
-the MXU), which is never a lower precision than the composite's. With
-``kv_heads`` (grouped-query attention: rows of ``kv_heads`` K/V heads side
-by side, a whole number of query heads to each) a group's query heads are
-the rows of one MXU product per K/V head and block group, scores and
-softmax in float32; heads narrower than the 128 lanes go two (or more) to
-a product (``grouped_layout``).
+arenas, which stay in HBM. What it computes is exactly ``softmax(q K^T scale
++ bias) V`` for ANY bias, from a slot's LIVE blocks alone: the bias tile of
+every visited row tile is added to the scores, the slot's length (1 + the
+last position its bias row opens) only bounds which blocks are touched, and
+every position skipped is one whose weight is ``exp(-1e9 - m) = 0`` in
+float32. A dead block is never read, no ``[S * L, H]`` view is ever written,
+and a free slot (length 0) reads nothing and writes zeros.
+
+The copy pipeline (``_paged_pipeline``, one implementation for both bodies).
+The unit of a copy is a COPY UNIT (``_paged_group``): whole reduce tiles of
+blocks, about a megabyte of K plus V from the block size, the row width and
+the dtype (one 128-row tile at 2,048 bf16 or 1,024 float32 lanes, four at
+512 bf16, eight at 256), never more than a slot holds, and small enough that
+two halves of K and of V (the double-buffered VMEM scratch) fit the budget
+below. A unit's live blocks are started with one ``make_async_copy`` a block
+and arena, all on its half's semaphore, and waited for together: a DMA
+semaphore counts bytes, so ONE wait an arena of the unit's size serves a
+full unit (a short one takes a wait per power of two). The grid runs over
+the slots, ``_STEP_SLOTS`` to a grid step (a grid step costs the same
+whether its slots are live or free); the block table, the lengths and each
+slot's NEXT LIVE slot ride in scalar prefetch. What is in flight when: the
+next live unit, always. The first grid step zeroes the scratch and starts the
+first live slot's first unit; from then on, when a unit's reduce begins, the
+unit after it is started into the other half — the slot's own next unit or,
+under the slot's LAST unit, the first unit of the next live slot, which may
+stand any number of free slots (and grid steps) later and finds its copy
+started. Which half that is goes from slot to slot through one SMEM word.
+So only a call's first unit is waited for with nothing to overlap it. The
+reduce walks a unit in tiles of ``_TILE_ROWS`` rows (the bias tile stays a
+``(1, 128)`` row) by an online softmax, and walks only tiles that hold a
+live position, so a short slot in a large unit does no more arithmetic than
+its tiles. Rows of the scratch that a short unit leaves unwritten are stale:
+they hold zeros or an EARLIER unit's live rows (this slot's or another's,
+never a dead block's), their weight is 0 and they are finite, so a slot's
+output bytes do not depend on which slots precede it nor on the half its
+first unit landed in.
+
+Its parity contract is a TOLERANCE (1e-5 both ways, as for flash): an online
+softmax regroups float32 sums, so it is close to the composite and not
+bit-identical with it. What stays bit-exact is one compiled program
+replayed under any admission order, and resume after park
+(tests/test_decode.py). The body takes dtype and widths from its operands
+and reduces in float32 on the VPU (a one-row GEMV wastes the MXU), which is
+never a lower precision than the composite's. With ``kv_heads``
+(grouped-query attention: rows of ``kv_heads`` K/V heads side by side, a
+whole number of query heads to each) a group's query heads are the rows of
+one MXU product per K/V head and row tile, scores and softmax in float32;
+heads narrower than the 128 lanes go two (or more) to a product
+(``grouped_layout``).
 
 Eligibility: ``decode_attention`` wants its whole workset resident in VMEM;
 ``fits_vmem`` gates the compiled-TPU path per static shape on the INPUT
 bytes only (scores and the output are not counted) against ``VMEM_BUDGET``
 = 12 MiB, under Mosaic's 16 MiB default scoped-VMEM limit. The paged
-kernel's scratch is two groups of K and of V (2 MiB at hidden 1024, block
-16, float32), held to the same budget by shrinking the group; a block
-Mosaic cannot tile (rows not a multiple of the dtype's sublane tile, hidden
-not a multiple of 128) runs the composite. Every such fallback is counted
-in ``kernel_fallbacks_total``.
+kernel's scratch is two units of K and of V (2 MiB at every serving cell's
+geometry), held to the same budget by shrinking the unit, then the tile; a
+block Mosaic cannot tile (rows not a multiple of the dtype's sublane tile,
+hidden not a multiple of 128) runs the composite. Every such fallback is
+counted in ``kernel_fallbacks_total``.
 """
 
 import functools
@@ -72,6 +99,10 @@ __all__ = [
 
 #: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
 VMEM_BUDGET = 12 * 1024 * 1024
+
+#: the ``jax.named_scope`` of what ``paged_attention`` derives from ``rows``
+#: and ``bias`` alone, the same for every call of a step program
+TABLES_SCOPE = "paged_attention_tables"
 
 
 def fits_vmem(*arrays):
@@ -203,17 +234,43 @@ def decode_attention(q, k_cache, v_cache, bias, sm_scale, interpret=False):
 # ---------------------------------------------------------------------------
 
 #: rows of K (and of V) one reduction of the paged kernel covers: a lane
-#: tile, so the group's bias tile is one (1, 128) row
-_GROUP_ROWS = 128
+#: tile, so a tile's bias is one (1, 128) row
+_TILE_ROWS = 128
+
+#: slots one grid step serves (a divisor of the slot count up to this): a
+#: grid step costs ~0.2 us whether its slot is live or free, 128 of them
+#: were a third of a call at 24 live slots
+_STEP_SLOTS = 8
+
+#: bytes of K plus V a copy unit aims at: what one wait brings in, large
+#: enough that the descriptors' issue and the DMA's latency are a small
+#: part of it whatever the row width
+_UNIT_BYTES = 1 << 20
+
+
+def _paged_tile(block_size, blocks_per_slot, hidden, dtype):
+    """Blocks per reduce tile of the paged kernel: a lane tile's worth of
+    rows, fewer when two halves of K and of V would not fit ``VMEM_BUDGET``;
+    0 when not even single blocks do."""
+    t = max(1, min(int(blocks_per_slot), _TILE_ROWS // int(block_size)))
+    per_block = 4 * int(block_size) * int(hidden) * jnp.dtype(dtype).itemsize
+    return min(t, VMEM_BUDGET // per_block)
 
 
 def _paged_group(block_size, blocks_per_slot, hidden, dtype):
-    """Blocks per group of the paged kernel: a lane tile's worth of rows,
-    fewer when two groups of K and of V would not fit ``VMEM_BUDGET``;
-    0 when not even single blocks do."""
-    g = max(1, min(int(blocks_per_slot), _GROUP_ROWS // int(block_size)))
-    per_block = 4 * int(block_size) * int(hidden) * jnp.dtype(dtype).itemsize
-    return min(g, VMEM_BUDGET // per_block)
+    """Blocks per COPY UNIT of the paged kernel (what is started, and
+    waited for, together): whole reduce tiles, about ``_UNIT_BYTES`` of K
+    plus V from the row width and dtype, no more than a slot has and than
+    two halves of K and of V fit ``VMEM_BUDGET``; 0 when no block does.
+    The engine counts a step's units with the same function."""
+    tile = _paged_tile(block_size, blocks_per_slot, hidden, dtype)
+    if not tile:
+        return 0
+    tile_bytes = (2 * tile * int(block_size) * int(hidden)
+                  * jnp.dtype(dtype).itemsize)
+    tiles = min(_UNIT_BYTES // tile_bytes, -(-int(blocks_per_slot) // tile),
+                VMEM_BUDGET // (2 * tile_bytes))
+    return max(1, tiles) * tile
 
 
 def _mosaic_tiles(block_size, hidden, dtype):
@@ -223,64 +280,138 @@ def _mosaic_tiles(block_size, hidden, dtype):
     return block_size % sublanes == 0 and hidden % 128 == 0
 
 
-def _block_copies(bt_ref, arenas, bufs, sem, s, live, grp, half, act, *,
-                  block, group, per_slot):
-    """``start`` or ``wait`` (``act``) for the copies of group ``grp``'s
-    live blocks of slot ``s``, K and V, into ``half`` of the scratch."""
-    for j in range(group):
-        blk = grp * group + j
+def _start_copies(bt_ref, len_ref, arenas, bufs, sem, slot, unit_no, half, *,
+                  block, unit, per_slot):
+    """Start the copies of the live blocks of ``slot``'s copy unit
+    ``unit_no``, K and V, into ``half`` of the scratch: one descriptor a
+    block and arena, all of a half's on one semaphore an arena."""
+    live = pl.cdiv(len_ref[slot], block)
+    first = unit_no * unit
+
+    def one(j, c):
         row0 = pl.multiple_of(
-            bt_ref[s * per_slot + jnp.minimum(blk, per_slot - 1)]
-            * block, block)
-        dst = (half, pl.ds(j * block, block))
+            bt_ref[slot * per_slot + first + j] * block, block)
+        dst = (half, pl.ds(pl.multiple_of(j * block, block), block))
+        for n, (arena, buf) in enumerate(zip(arenas, bufs)):
+            pltpu.make_async_copy(arena.at[pl.ds(row0, block)], buf.at[dst],
+                                  sem.at[n, half]).start()
+        return c
 
-        @pl.when(blk < live)
+    jax.lax.fori_loop(0, jnp.minimum(live - first, unit), one, 0)
+
+
+def _wait_copies(len_ref, bufs, sem, slot, unit_no, half, *, block, unit):
+    """Wait for what ``_start_copies`` started for that unit. A DMA
+    semaphore counts bytes, and a wait needs only a descriptor of the size
+    it waits for (here the scratch rows onto themselves: an arena may hold
+    fewer rows than a unit): the unit's live blocks are waited for in
+    power-of-two runs, ONE wait an arena for a full unit, not block by
+    block."""
+    count = jnp.minimum(pl.cdiv(len_ref[slot], block) - unit_no * unit, unit)
+    run = 1 << (unit.bit_length() - 1)
+    while run:
+        @pl.when(count & run != 0)
+        def _(run=run):
+            for n, buf in enumerate(bufs):
+                rows = buf.at[half, pl.ds(0, run * block)]
+                pltpu.make_async_copy(rows, rows, sem.at[n, half]).wait()
+        run >>= 1
+
+
+def _paged_pipeline(bt_ref, len_ref, nxt_ref, arenas, bufs, sem, half_ref,
+                    init, reduce_tile, finish, *, block, tile, unit,
+                    per_slot, step_slots):
+    """The copy pipeline both bodies share. A grid step serves
+    ``step_slots`` slots, one after the other: ``carry = init(i)``, then
+    the slot's live reduce tiles (``tile`` blocks each) go through
+    ``reduce_tile(i, half, row0, tile_no, carry)``, then ``finish(i,
+    carry)`` writes the slot's output. While a copy unit (``unit`` blocks)
+    is reduced, the NEXT live unit is in flight into the other half of the
+    scratch: the slot's own next unit or, under its last, the first unit of
+    the next live slot (``nxt_ref``: the next slot with a position, the
+    slot count when there is none), in this grid step or a later one, which
+    then finds it started. ``half_ref`` (SMEM) carries from slot to slot
+    which half the next live slot's first unit is in; the first grid step
+    zeroes the scratch and starts the first live slot's."""
+    # (the interpreter resolves grid primitives at the body's top level only)
+    step0 = pl.program_id(0) * step_slots
+    seqs = pl.num_programs(0) * step_slots
+    trows = tile * block
+    per_unit = unit // tile
+
+    def start(slot, unit_no, half):
+        _start_copies(bt_ref, len_ref, arenas, bufs, sem, slot, unit_no,
+                      half, block=block, unit=unit, per_slot=per_slot)
+
+    @pl.when(step0 == 0)
+    def _():
+        # rows of the scratch that a short unit leaves unwritten carry
+        # weight exp(-1e9 - m) = 0, so they only have to be finite: zeros
+        # here, and later some earlier unit's live rows
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        half_ref[0] = 0
+        first = jnp.where(len_ref[0] > 0, 0, nxt_ref[0])
+
+        @pl.when(first < seqs)
         def _():
-            for n, (arena, buf) in enumerate(zip(arenas, bufs)):
-                getattr(pltpu.make_async_copy(
-                    arena.at[pl.ds(row0, block)], buf.at[dst],
-                    sem.at[n, half]), act)()
+            start(first, 0, 0)
+
+    def one_slot(i, c):
+        s = step0 + i
+        ntiles = pl.cdiv(len_ref[s], trows)
+        nunits = pl.cdiv(ntiles, per_unit)
+        half0 = half_ref[0]
+
+        def step(t, carry):
+            u = t // per_unit
+            half = (half0 + u) % 2
+
+            @pl.when(t % per_unit == 0)
+            def _():
+                last = u + 1 == nunits
+                slot = jnp.where(last, nxt_ref[s], s)
+
+                @pl.when(slot < seqs)
+                def _():
+                    start(jnp.minimum(slot, seqs - 1),
+                          jnp.where(last, 0, u + 1), 1 - half)
+
+                _wait_copies(len_ref, bufs, sem, s, u, half, block=block,
+                             unit=unit)
+
+            row0 = pl.multiple_of((t % per_unit) * trows, trows)
+            return reduce_tile(i, half, row0, t, carry)
+
+        finish(i, jax.lax.fori_loop(0, ntiles, step, init(i)))
+
+        @pl.when(nunits > 0)
+        def _():
+            half_ref[0] = (half0 + nunits) % 2
+
+        return c
+
+    jax.lax.fori_loop(0, step_slots, one_slot, 0)
 
 
-def _paged_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
-                kbuf, vbuf, sem, *, sm_scale, block, group, per_slot):
-    s = pl.program_id(0)
-    live = pl.cdiv(len_ref[s], block)             # blocks to read
-    ngroups = pl.cdiv(live, group)
+def _paged_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
+                kbuf, vbuf, sem, half_ref, *, sm_scale, **geometry):
+    f32 = jnp.float32
+    trows = geometry["tile"] * geometry["block"]
 
-    @pl.when(s == 0)
-    def _():
-        # a short last group leaves rows of the scratch unwritten; their
-        # weight is exp(-1e9 - m) = 0, so they only have to be finite
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+    def init(i):
+        return (jnp.full((1, 1), -jnp.inf, f32), jnp.zeros((1, 1), f32),
+                jnp.zeros(q_ref.shape[1:], f32))
 
-    def copies(grp, half, act):
-        _block_copies(bt_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, s, live,
-                      grp, half, act, block=block, group=group,
-                      per_slot=per_slot)
-
-    @pl.when(ngroups > 0)
-    def _():
-        copies(0, 0, "start")
-
-    q = q_ref[...].astype(jnp.float32)            # [1, H]
-
-    def reduce_group(grp, carry):
+    def reduce_tile(i, half, row0, t, carry):
         m, l, acc = carry
-        half = grp % 2
-
-        @pl.when(grp + 1 < ngroups)
-        def _():
-            copies(grp + 1, 1 - half, "start")
-
-        copies(grp, half, "wait")
-        k = kbuf[half].astype(jnp.float32)        # [rows, H]
-        v = vbuf[half].astype(jnp.float32)
+        q = q_ref[i].astype(f32)                               # [1, H]
+        k = kbuf[half, pl.ds(row0, trows)].astype(f32)         # [rows, H]
+        v = vbuf[half, pl.ds(row0, trows)].astype(f32)
         sc = jnp.sum(k * q, axis=-1, keepdims=True)            # [rows, 1]
         if sm_scale != 1.0:
             sc = sc * sm_scale
-        sc = sc + b_ref[pl.ds(grp, 1), :].astype(jnp.float32).reshape(-1, 1)
+        sc = sc + b_ref[i, pl.ds(t, 1), :].astype(f32).reshape(-1, 1)
         m_new = jnp.maximum(m, jnp.max(sc, axis=0, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(sc - m_new)
@@ -288,64 +419,48 @@ def _paged_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
         acc = acc * alpha + jnp.sum(p * v, axis=0, keepdims=True)
         return m_new, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, ngroups, reduce_group, (
-        jnp.full((1, 1), -jnp.inf, jnp.float32),
-        jnp.zeros((1, 1), jnp.float32),
-        jnp.zeros(q.shape, jnp.float32)))
-    o_ref[...] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
+    def finish(i, carry):
+        _m, l, acc = carry
+        o_ref[i] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
+
+    _paged_pipeline(bt_ref, len_ref, nxt_ref, (k_hbm, v_hbm), (kbuf, vbuf),
+                    sem, half_ref, init, reduce_tile, finish, **geometry)
 
 
-def _paged_grouped_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
-                        kbuf, vbuf, sem, *, sm_scale, block, group, per_slot,
-                        kv_heads):
+def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm,
+                        o_ref, kbuf, vbuf, sem, half_ref, *, sm_scale,
+                        kv_heads, **geometry):
     """``_paged_body`` with a head axis: the rows hold ``kv_heads`` K (V)
-    heads of ``D`` side by side, ``q_ref`` is ``[kv_heads, per, D]``, and a
-    group of blocks is reduced once per K/V head on the MXU, ``per`` query
-    rows at a time (scores ``[per, rows]``, so the bias tile is a row)."""
-    s = pl.program_id(0)
-    live = pl.cdiv(len_ref[s], block)
-    ngroups = pl.cdiv(live, group)
-    d = q_ref.shape[-1]
+    heads of ``D`` side by side, a slot's ``q_ref[i]`` is ``[kv_heads, per,
+    D]``, and a tile of rows is reduced once per K/V head on the MXU,
+    ``per`` query rows at a time (scores ``[per, rows]``, so the bias tile
+    is a row)."""
+    per, d = q_ref.shape[2:]
+    trows = geometry["tile"] * geometry["block"]
     f32 = jnp.float32
     # a process-wide matmul precision reaches inside the body, and Mosaic
     # refuses a float32 one on bfloat16 operands: pin the operands' own
     prec = (jax.lax.Precision.HIGHEST if kbuf.dtype == f32
             else jax.lax.Precision.DEFAULT)
 
-    @pl.when(s == 0)
-    def _():
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+    def init(i):
+        return tuple(
+            (jnp.full((per, 1), -jnp.inf, f32), jnp.zeros((per, 1), f32),
+             jnp.zeros((per, d), f32)) for _ in range(kv_heads))
 
-    def copies(grp, half, act):
-        _block_copies(bt_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, s, live,
-                      grp, half, act, block=block, group=group,
-                      per_slot=per_slot)
-
-    @pl.when(ngroups > 0)
-    def _():
-        copies(0, 0, "start")
-
-    def reduce_group(grp, carry):
-        half = grp % 2
-
-        @pl.when(grp + 1 < ngroups)
-        def _():
-            copies(grp + 1, 1 - half, "start")
-
-        copies(grp, half, "wait")
-        tile = b_ref[pl.ds(grp, 1), :].astype(f32)            # [1, rows]
+    def reduce_tile(i, half, row0, t, carry):
+        bias = b_ref[i, pl.ds(t, 1), :].astype(f32)           # [1, rows]
         out = []
         for g in range(kv_heads):
             m, l, acc = carry[g]
-            k = kbuf[half, :, g * d:(g + 1) * d]              # [rows, D]
-            v = vbuf[half, :, g * d:(g + 1) * d]
+            k = kbuf[half, pl.ds(row0, trows), g * d:(g + 1) * d]  # [rows, D]
+            v = vbuf[half, pl.ds(row0, trows), g * d:(g + 1) * d]
             sc = jax.lax.dot_general(
-                q_ref[g], k, (((1,), (1,)), ((), ())), precision=prec,
+                q_ref[i, g], k, (((1,), (1,)), ((), ())), precision=prec,
                 preferred_element_type=f32)                   # [per, rows]
             if sm_scale != 1.0:
                 sc = sc * sm_scale
-            sc = sc + tile
+            sc = sc + bias
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(sc - m_new)
@@ -356,12 +471,12 @@ def _paged_grouped_body(bt_ref, len_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
             out.append((m_new, l, acc))
         return tuple(out)
 
-    per = q_ref.shape[1]
-    carry = jax.lax.fori_loop(0, ngroups, reduce_group, tuple(
-        (jnp.full((per, 1), -jnp.inf, f32), jnp.zeros((per, 1), f32),
-         jnp.zeros((per, d), f32)) for _ in range(kv_heads)))
-    for g, (_m, l, acc) in enumerate(carry):
-        o_ref[g] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
+    def finish(i, carry):
+        for g, (_m, l, acc) in enumerate(carry):
+            o_ref[i, g] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
+
+    _paged_pipeline(bt_ref, len_ref, nxt_ref, (k_hbm, v_hbm), (kbuf, vbuf),
+                    sem, half_ref, init, reduce_tile, finish, **geometry)
 
 
 def grouped_layout(width, kv_heads, q_width, dtype, interpret=False):
@@ -419,52 +534,63 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     H = k_arena.shape[-1]
     G = int(kv_heads)
     per_slot = -(-L // bs)
-    group = _paged_group(bs, per_slot, H, k_arena.dtype)
+    tile = _paged_tile(bs, per_slot, H, k_arena.dtype)
+    unit = _paged_group(bs, per_slot, H, k_arena.dtype)
     pack, qrows = grouped_layout(H, G, q.shape[-1], k_arena.dtype,
                                  interpret) if G else (1, 1)
-    if vma_names(q) or group == 0 or not pack or (
+    if vma_names(q) or unit == 0 or not pack or (
             not interpret and not _mosaic_tiles(bs, H, k_arena.dtype)):
         fallback_counter().inc()
         return paged_attention_composite(q, k_arena, v_arena, rows, bias,
                                          S, L, sm_scale, kv_heads=G)
-    grows = group * bs
-    ngroups = -(-per_slot // group)
-    table = (rows.reshape(S, L)[:, ::bs] // bs).astype(jnp.int32)
-    bias2 = bias.reshape(S, L)
-    lengths = jnp.max(
-        jnp.where(bias2 > _CLOSED, jnp.arange(1, L + 1, dtype=jnp.int32), 0),
-        axis=-1)
-    tiles = jnp.pad(bias2, ((0, 0), (0, ngroups * grows - L)),
-                    constant_values=-1e9).reshape(S, ngroups, grows)
+    trows = tile * bs
+    ntiles = -(-per_slot // tile)
+    step_slots = max(n for n in range(1, _STEP_SLOTS + 1) if S % n == 0)
+    # what every call over one ``rows`` and ``bias`` derives alike (XLA
+    # computes it once a program: tests/test_hlo.py): the block table, the
+    # lengths, each slot's next live slot and the bias in tiles
+    with jax.named_scope(TABLES_SCOPE):
+        table = (rows.reshape(S, L)[:, ::bs] // bs).astype(jnp.int32)
+        bias2 = bias.reshape(S, L)
+        lengths = jnp.max(jnp.where(
+            bias2 > _CLOSED, jnp.arange(1, L + 1, dtype=jnp.int32), 0),
+            axis=-1)
+        slot = jnp.arange(S, dtype=jnp.int32)
+        nxt = jnp.min(jnp.where(
+            (lengths > 0)[None, :] & (slot[None, :] > slot[:, None]),
+            slot[None, :], S), axis=-1)
+        tiles = jnp.pad(bias2, ((0, 0), (0, ntiles * trows - L)),
+                        constant_values=-1e9).reshape(S, ntiles, trows)
     if G:
         lanes = pack * (H // G)
-        row = pl.BlockSpec((None, G // pack, qrows, lanes),
+        row = pl.BlockSpec((step_slots, G // pack, qrows, lanes),
                            lambda s, *_: (s, 0, 0, 0))
         body = functools.partial(_paged_grouped_body, kv_heads=G // pack)
         q_in = _pack_heads(q.reshape(S, G, -1, H // G), pack,
                            qrows).astype(k_arena.dtype)
     else:
-        row = pl.BlockSpec((None, 1, H), lambda s, *_: (s, 0, 0))
+        row = pl.BlockSpec((step_slots, 1, H), lambda s, *_: (s, 0, 0))
         body, q_in = _paged_body, q.reshape(S, 1, H)
     out = pl.pallas_call(
-        lambda *refs: body(
-            *refs, sm_scale=sm_scale, block=bs, group=group,
-            per_slot=per_slot),
+        functools.partial(body, sm_scale=sm_scale, block=bs, tile=tile,
+                          unit=unit, per_slot=per_slot,
+                          step_slots=step_slots),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(S,),
+            num_scalar_prefetch=3,
+            grid=(S // step_slots,),
             in_specs=[
                 row,
-                pl.BlockSpec((None, ngroups, grows),
+                pl.BlockSpec((step_slots, ntiles, trows),
                              lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=row,
             scratch_shapes=[
-                pltpu.VMEM((2, grows, H), k_arena.dtype),
-                pltpu.VMEM((2, grows, H), v_arena.dtype),
+                pltpu.VMEM((2, unit * bs, H), k_arena.dtype),
+                pltpu.VMEM((2, unit * bs, H), v_arena.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
@@ -472,7 +598,7 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(table.reshape(-1), lengths, q_in, tiles, k_arena, v_arena)
+    )(table.reshape(-1), lengths, nxt, q_in, tiles, k_arena, v_arena)
     if G:
         out = _unpack_heads(out, pack, q.shape[-1] // H)
     return out.reshape(q.shape)

@@ -467,6 +467,12 @@ class _ModelEntry:
             engine_label=f"{engine.label}:{model.label}")
         self._blocks = BlockPool(model.num_blocks, model.block_size,
                                  count=self._metrics.incr)
+        # blocks the paged-attention kernel copies as one unit at this
+        # geometry (0: no kernel serves it), to count a step's units
+        from paddle_tpu.kernels.attention import _paged_group
+        self._copy_unit = _paged_group(
+            model.block_size, model.blocks_per_slot, model.kv_width,
+            model.kv_dtype)
         self._prefix = PrefixCache(prefix_cache_size)
         # graceful degradation (r18): host-RAM KV tier, parked sessions,
         # deferred admissions, and the brownout severity ladder. The
@@ -2685,7 +2691,7 @@ class _ModelEntry:
                  if m.logits_mask else None)
         active = []
         groups = []     # beam groups with a live slot this step
-        live_blocks = 0
+        live_blocks = copy_units = 0
         launched = self._launched
         for s in range(S):
             st = self._slots[s]
@@ -2759,14 +2765,18 @@ class _ModelEntry:
             m.fill_step(step, s, st.cursor, st.table,
                         self._row_of(st, st.cursor),
                         -1 if st.ahead else st.last_token)
-            live_blocks += st.cursor // m.block_size + 1
+            reads = st.cursor // m.block_size + 1
+            live_blocks += reads
+            if self._copy_unit:
+                copy_units += -(-reads // self._copy_unit)
             if dmask is not None and st.grammar is not None:
                 # the grammar's next-token constraint rides in as DATA —
                 # same compiled program for every request, zero retraces
                 dmask[s, 0] = st.grammar.mask()
         if not active and not groups:
             return None
-        self._metrics.observe_blocks(live_blocks, S * m.blocks_per_slot)
+        self._metrics.observe_blocks(live_blocks, S * m.blocks_per_slot,
+                                     copy_units)
         feeds = {DecodeModel.DEC_STEP: step,
                  DecodeModel.DEC_TOKEN: (self._no_tokens if launched is None
                                          else launched.fetches[1])}

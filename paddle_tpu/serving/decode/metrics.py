@@ -20,7 +20,10 @@ decode step fetches its ``[S, 1]`` tokens, chosen by the step program;
 the whole ``[S, 1, V]`` float32 logits to the host instead, because a
 slot sampled, searched beams or masked a grammar on the host. What a
 step's attention has to read: ``serving_decode_live_blocks_total`` over
-``serving_decode_block_slots_total``.
+``serving_decode_block_slots_total``; and in how many copy units the
+kernel brings it in, ``serving_paged_copy_units_total``, of which
+``serving_paged_copy_units_ahead_total`` are in flight before their reduce
+is due.
 
 The order of a decode step's phases. A step is feeds, launch
 (``decode::step``), ONE fetch (``decode::step_fetch``) and the host half
@@ -171,6 +174,12 @@ class DecodeMetrics(ServingMetrics):
         # a stepping slot's positions up to its cursor, over every block
         # of every slot (what the whole-arena gather read)
         "decode_live_blocks", "decode_block_slots",
+        # how the kernel brings those blocks in: copy units (what it
+        # starts and waits for together: kernels/attention.py
+        # _paged_group) over the stepping slots, and those of them whose
+        # copy is started before their reduce is due (all but a step's
+        # first: a slot's first unit rides under the slot before it)
+        "paged_copy_units", "paged_copy_units_ahead",
         # a model with routed experts of which this chip holds a share,
         # per decode step as the device ran it (wasted slots included):
         # tokens x k over the expert layers, those that landed on a held
@@ -290,12 +299,16 @@ class DecodeMetrics(ServingMetrics):
         if kind == "step":
             self.incr("step_launches")
 
-    def observe_blocks(self, live, slots):
+    def observe_blocks(self, live, slots, copy_units):
         """One decode step's feeds: ``live`` blocks hold its stepping
         slots' positions up to their cursors, of ``slots`` block slots
-        (S x blocks per slot) in the step's row map."""
+        (S x blocks per slot) in the step's row map; the kernel brings
+        them in as ``copy_units`` units, all but the first in flight
+        before their reduce is due."""
         self.incr("decode_live_blocks", live)
         self.incr("decode_block_slots", slots)
+        self.incr("paged_copy_units", copy_units)
+        self.incr("paged_copy_units_ahead", max(copy_units - 1, 0))
 
     def observe_tokens(self, request):
         """At retirement: the request's time to first token and the mean

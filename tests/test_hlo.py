@@ -117,6 +117,47 @@ def test_paged_decode_step_never_writes_the_gathered_arena(mode, views):
     assert bool(dense) == views, (mode, set(dense))
 
 
+def test_paged_attention_tables_are_derived_once_a_step():
+    """Every ``paged_attention`` call of a step program reads the same
+    ``rows`` and ``bias`` (the one ``paged_step_feeds`` op's outputs), and
+    the kernel's wrapper derives its scalar-prefetch operands from them at
+    every call: the block table, the lengths (a max over each bias row),
+    each slot's next live slot (a min), the bias in tiles. Four layers
+    trace four of each (the control: the unoptimized HLO holds 2 x 4
+    reductions to an ``s32[S]``); XLA's CSE leaves ONE, under the wrapper's
+    named scope. On a v5e the derivation is ~2 us against 192 calls of
+    ~33 us in ouro_2_6b's step (PERF.md, PR 44)."""
+    import re
+
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import attention
+    from paddle_tpu.serving.decode import build_decoder_model
+
+    S, L, H, N = 5, 96, 24, 4
+    m = build_decoder_model(vocab_size=40, hidden=H, num_layers=N, slots=S,
+                            max_len=L, block_size=8, num_blocks=36,
+                            name="hlo_tables", version="1")
+    feed = {n: np.zeros(shape, dtype)
+            for n, shape, dtype in m.decode_feed_sig()}
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope), kernels.scoped_mode("interpret"):
+        fluid.Executor(fluid.CPUPlace()).run(m.startup_program)
+        lowered = hlo.lower_program_step(
+            m.decode_program, feed, [m.logits_fetch], scope=scope)
+    to_slots = r"= s32\[%d\]\{0\} reduce\(" % S
+    traced = lowered.compiler_ir(dialect="hlo").as_hlo_text()
+    assert len(re.findall(to_slots, traced)) == 2 * N
+    compiled = lowered.compile().as_text()
+    kept = [line for line in compiled.splitlines()
+            if re.search(to_slots, line)]
+    assert sorted(re.findall(r"/(reduce_\w+)\"", " ".join(kept))) == [
+        "reduce_max", "reduce_min"], kept
+    assert all(attention.TABLES_SCOPE in line for line in kept), kept
+    gathers = [line for line in compiled.splitlines()
+               if " gather(" in line and attention.TABLES_SCOPE in line]
+    assert len(gathers) <= 1, gathers
+
+
 def test_element_mover_detector_fires():
     """Positive control: an index broadcast to the output's shape DOES
     lower to the element gather and the element scatter-add (and a sort

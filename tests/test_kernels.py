@@ -225,6 +225,17 @@ PAGED_CASES = {
     "rehearsal_geometry": (4, 24, 4, 8, [1, 5, 24, 0], ()),
     "length_not_a_block_multiple": (3, 30, 4, 8, [30, 7, 0], ()),
     "one_block_a_group": (3, 512, 256, 128, [512, 257, 3], ()),
+    # what the copy pipeline hands from slot to slot (PR 44)
+    "first_slot_free": (4, 64, 16, 128, [0, 20, 64, 7], ()),
+    "last_slot_the_only_live_one": (4, 64, 16, 128, [0, 0, 0, 33], ()),
+    "live_and_free_alternating": (6, 64, 16, 128, [17, 0, 64, 0, 5, 0], ()),
+    "all_free": (3, 64, 16, 128, [0, 0, 0], ()),
+    # hidden 1,024 in float32: a copy unit is one 128-row tile
+    "one_unit_then_eight": (2, 1024, 16, 1024, [100, 1024], ()),
+    "eight_units_then_one": (2, 1024, 16, 1024, [1024, 100], ()),
+    # hidden 128 in float32: a copy unit is eight tiles, 1,024 rows
+    "unit_edges": (4, 2048, 16, 128, [1023, 1024, 0, 1025], ()),
+    "unit_larger_than_the_slot": (3, 48, 16, 128, [48, 17, 0], ()),
 }
 
 
@@ -241,10 +252,71 @@ def test_paged_kernel_matches_composite(case, shuffle, rng):
     S, L, bs, H, lengths, share = PAGED_CASES[case]
     args = kernels._paged_case(rng, S, L, bs, H, lengths, share=share,
                                shuffle=shuffle)
-    got, ref = _paged_both(args, S, L, bs, H)
-    live = np.asarray(lengths) > 0
-    kernels._assert_close_both_ways(got[live], ref[live], case, 1e-5, 1e-5)
-    assert not got[~live].any()
+    kernels._assert_paged_parity(args, lengths, case, S, L, bs, H)
+
+
+#: (K/V heads a row, query heads to each, head size, rows of a copy unit in
+#: float32 at block 16): the serving cells' three grouped layouts
+GROUPED = {"gqa128": (2, 4, 128, 512), "gqa64_packed": (8, 4, 64, 256),
+           "mha128": (16, 1, 128, 128)}
+
+
+@pytest.mark.parametrize("layout", sorted(GROUPED))
+def test_paged_grouped_kernel_at_a_copy_units_edges(layout, rng):
+    """The grouped body at lengths one under, at and one over its
+    geometry's copy unit, a free slot between them, then two units."""
+    from paddle_tpu.kernels import attention as A
+
+    G, per, D, unit_rows = GROUPED[layout]
+    assert A._paged_group(16, 2 * unit_rows // 16, G * D,
+                          "float32") * 16 == unit_rows
+    kernels._parity_paged_unit_edges(rng, G, per, D, unit_rows)
+
+
+def _poison_dead_rows(k, v, rows, lengths, L, bs):
+    """NaNs in every arena row that no live block of any slot names."""
+    live_rows = set()
+    for s, n in enumerate(lengths):
+        for blk in range(-(-n // bs)):
+            r0 = rows.reshape(len(lengths), L)[s, blk * bs]
+            live_rows.update(range(r0, r0 + bs))
+    dead = np.array(sorted(set(range(k.shape[0])) - live_rows))
+    k2, v2 = k.copy(), v.copy()
+    k2[dead], v2[dead] = np.nan, np.nan
+    return k2, v2
+
+
+PERMUTATIONS = {"reversed": [5, 4, 3, 2, 1, 0], "rotated": [2, 3, 4, 5, 0, 1],
+                "interleaved": [3, 0, 4, 1, 5, 2]}
+
+
+@pytest.mark.parametrize("order", sorted(PERMUTATIONS))
+@pytest.mark.parametrize("body", ["plain", "grouped"])
+def test_paged_kernel_slot_output_is_the_same_wherever_it_stands(
+        body, order, rng):
+    """A slot's output bytes depend on its own rows, bias and query alone:
+    not on which slots stand before it (whose last unit its first copy
+    rode under), not on which half of the scratch that copy landed in, not
+    on what a short unit left stale there. One case with its slots
+    permuted, NaNs planted in every dead arena row. Rows wide enough that a
+    copy unit is ONE 128-row tile: slots of one to four units."""
+    L, bs = 512, 16
+    lengths = [130, 0, 512, 17, 0, 300]
+    if body == "plain":
+        G, geometry = 0, (len(lengths), L, bs, 1024)
+        q, k, v, rows, bias = kernels._paged_case(rng, *geometry, lengths)
+    else:
+        G = 16
+        (q, k, v, rows, bias), geometry = kernels._paged_heads_case(
+            rng, G, 1, 128, L, lengths)
+    k, v = _poison_dead_rows(k, v, rows, lengths, L, bs)
+    base, _ = _paged_both((q, k, v, rows, bias), *geometry, kv_heads=G)
+    assert np.isfinite(base).all()
+    perm = PERMUTATIONS[order]
+    moved, _ = _paged_both(
+        (q[perm], k, v, rows.reshape(len(lengths), L)[perm].reshape(-1),
+         bias[perm]), *geometry, kv_heads=G)
+    assert moved.tobytes() == base[perm].tobytes()
 
 
 def test_paged_kernel_free_slot_moves_no_other_slot(rng):
@@ -262,14 +334,7 @@ def test_paged_kernel_free_slot_moves_no_other_slot(rng):
     keep = [0, 1, 3]
     assert freed[keep].tobytes() == full[keep].tobytes()
     assert not freed[2].any()
-    live_rows = set()
-    for s, n in enumerate(lengths):
-        for blk in range(-(-n // bs)):
-            r0 = rows.reshape(S, L)[s, blk * bs]
-            live_rows.update(range(r0, r0 + bs))
-    dead = np.array(sorted(set(range(k.shape[0])) - live_rows))
-    k2, v2 = k.copy(), v.copy()
-    k2[dead], v2[dead] = np.nan, np.nan
+    k2, v2 = _poison_dead_rows(k, v, rows, lengths, L, bs)
     poisoned, _ = _paged_both((q, k2, v2, rows, bias), S, L, bs, H)
     assert poisoned.tobytes() == full.tobytes()
 
@@ -305,10 +370,17 @@ def test_paged_kernel_geometry_fallbacks_are_counted(rng):
     assert np.asarray(out).tobytes() == np.asarray(ref).tobytes()
     assert A._mosaic_tiles(16, 1024, "float32")
     assert not A._mosaic_tiles(8, 1024, "bfloat16")
-    # the group shrinks to fit the VMEM budget before it gives up
+    # a copy unit is about a megabyte of K plus V in whole reduce tiles
+    # (decoder, ouro: one tile; lfm2 four; nemotron eight), never more
+    # than the slot, and shrinks to fit the VMEM budget before it gives up
     assert A._paged_group(16, 64, 1024, "float32") == 8
+    assert A._paged_group(16, 64, 2048, "bfloat16") == 8
+    assert A._paged_group(16, 128, 512, "bfloat16") == 32
+    assert A._paged_group(16, 128, 256, "bfloat16") == 64
+    assert A._paged_group(16, 4, 256, "bfloat16") == 4
     assert A._paged_group(16, 64, 8192, "float32") == 6
     assert A._paged_group(256, 4, 8192, "float32") == 0
+    assert A._paged_tile(16, 128, 256, "bfloat16") == 8
 
 
 # ---------------------------------------------------------------------------

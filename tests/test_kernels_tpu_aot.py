@@ -56,6 +56,46 @@ def test_kernel_compiles_for_v5e(name, v5e_devices):
             "case ran a composite, so it guards nothing")
 
 
+#: the four serving cells' paged-attention geometries (``_tpu_cases_paged``'s
+#: labels) and the blocks of a copy unit at each: about a megabyte of K
+#: plus V whatever the row width
+PAGED_UNITS = {
+    "s48_l1024_b16_h1024": 8,                   # decoder_1024x24, float32
+    "s32_l2048_b16_g2x16x128_bf16": 64,         # nemotron3_nano_30b_a3b
+    "s128_l2048_b16_g8x4x64_bf16": 32,          # lfm2_24b_a2b
+    "s16_l1024_b16_g16x1x128_bf16": 8,          # ouro_2_6b
+}
+
+
+@pytest.mark.parametrize("label", sorted(PAGED_UNITS))
+def test_paged_kernel_serves_every_cells_geometry(label, v5e_devices):
+    """Mosaic takes the paged kernel's body (the copy pipeline carried
+    from slot to slot through SMEM, a unit waited for in one descriptor an
+    arena, several slots a grid step) at each serving cell's geometry with
+    no fallback to the composite, and two halves of K and of V at the
+    cell's copy unit stay under the kernel's VMEM budget."""
+    from paddle_tpu.kernels import attention as A
+
+    cases = {c[0]: c for c in kernels.get("paged_attention").tpu_cases()}
+    assert set(cases) == set(PAGED_UNITS)
+    _label, fn, arg_specs = cases[label]
+    (rows, hidden), dtype = arg_specs[1]
+    seqs = arg_specs[4][0][0]
+    per_slot = rows // seqs // 16
+    unit = A._paged_group(16, per_slot, hidden, dtype)
+    assert unit == PAGED_UNITS[label]
+    assert unit % A._paged_tile(16, per_slot, hidden, dtype) == 0
+    scratch = 4 * unit * 16 * hidden * np.dtype(dtype).itemsize
+    assert scratch == 2 * 2 ** 20 <= A.VMEM_BUDGET
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.fallback_counter().value
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("shape,names", [
     ((4,), ("data",)), ((2, 2), ("data", "model")), ((2, 2), ("dcn", "data")),
 ])

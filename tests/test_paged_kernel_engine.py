@@ -158,3 +158,42 @@ def test_live_block_counters_follow_the_script():
     for family in ("serving_decode_live_blocks_total",
                    "serving_decode_block_slots_total"):
         assert family in families, family
+
+
+def test_copy_unit_counters_follow_the_script(monkeypatch):
+    """``serving_paged_copy_units_total`` is the sum over stepping slots of
+    ceil(live blocks / unit), the unit from the ``_paged_group`` the kernel
+    calls (taken once, when the model is registered);
+    ``serving_paged_copy_units_ahead_total`` all of a step's units but its
+    first (the next live unit, the next live SLOT's first one too, is in
+    flight while the one before it is reduced). 512 positions in blocks of
+    4 with a unit held to one 128-row tile: 32 blocks."""
+    from paddle_tpu.kernels import attention as A
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    geom = dict(GEOM, max_len=512)
+    bs, per_slot = geom["block_size"], 512 // geom["block_size"]
+    monkeypatch.setattr(A, "_UNIT_BYTES", 2 * 128 * geom["hidden"] * 4)
+    unit = A._paged_group(bs, per_slot, geom["hidden"], "float32")
+    assert unit == 32 < per_slot
+    prompts = [[1 + i % 9 for i in range(n)] for n in (126, 3, 250, 5)]
+    max_new = [6, 5, 3, 4]
+    name = "pk_units"
+    _tokens, _logits, st = _serve(
+        "off", name, lambda engine, i: engine.submit(
+            prompts[i], max_new_tokens=max_new[i], model=name), geom=geom)
+    # request i steps max_new[i] - 1 times, at cursors len(prompt) + t:
+    # the first crosses from one unit to two at its third step
+    per_step = [[-(-(-(-(len(p) + t + 1) // bs)) // unit)
+                 for p, n in zip(prompts, max_new) if t < n - 1]
+                for t in range(max(max_new) - 1)]
+    assert per_step == [[1, 1, 2, 1], [1, 1, 2, 1], [2, 1, 1], [2, 1], [2]]
+    assert st["decode_steps"] == len(per_step)
+    assert st["paged_copy_units"] == sum(map(sum, per_step)) == 19
+    assert st["paged_copy_units_ahead"] == 19 - len(per_step)
+    # (the parent's schedule started all but one unit a live SLOT ahead:
+    # 19 - 14 = 5 of them)
+    families = obs_metrics.registry().snapshot()
+    for family in ("serving_paged_copy_units_total",
+                   "serving_paged_copy_units_ahead_total"):
+        assert family in families, family
