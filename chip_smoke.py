@@ -603,6 +603,7 @@ def main(argv=None):
             report["four_chip"] = {"ran": False,
                                    "devices": jax.local_device_count()}
     report["kernel_fallbacks_total"] = _kernel_fallbacks()
+    report["flash_grid"] = kernels.flash_grid_snapshot()
     report["compiles_total"] = compiles.snapshot()
     report["smoke_seconds_total"] = round(time.perf_counter() - t0, 1)
     report["claim"] = None

@@ -27,16 +27,16 @@ import numpy as np
 
 from paddle_tpu.kernels import registry as _r
 from paddle_tpu.kernels.registry import (  # noqa: F401
-    MODE_ENV, KernelSpec, all_specs, fallback_counter, get, has, kernel_sig,
-    mode, probe, register, registry_fingerprint, resolved_mode, scoped_mode,
-    selected,
+    FLASH_KERNELS, MODE_ENV, KernelSpec, all_specs, fallback_counter,
+    flash_grid_snapshot, get, has, kernel_sig, mode, probe, register,
+    registry_fingerprint, resolved_mode, scoped_mode, selected,
 )
 
 __all__ = [
     "MODE_ENV", "KernelSpec", "all_specs", "get", "has", "kernel_sig",
     "mode", "probe", "register", "registry_fingerprint", "resolved_mode",
     "scoped_mode", "selected", "selected_for", "fallback_counter",
-    "fallback_internal_bytes",
+    "fallback_internal_bytes", "flash_grid_snapshot", "FLASH_KERNELS",
 ]
 
 
@@ -117,9 +117,11 @@ def _parity_flash(rng):
 
 
 def _tpu_cases_flash():
-    """BERT-base heads at s128 in bf16 with the padding bias (the train
-    leg of chip_smoke.py; batch cut — the grid scales with it, the kernel
-    body does not), and a causal multi-block length (models/gpt_ir.py)."""
+    """BERT-base heads at s128 in bf16 with the padding bias: the training
+    cells' FULL per-chip geometry (batch 256: the heads a grid step serves,
+    and so the kernel body, come from B*H), a cut batch (the train leg of
+    chip_smoke.py), an odd B*H (each kernel takes another divisor of 21),
+    and a causal multi-block length (models/gpt_ir.py)."""
     import jax
     import jax.numpy as jnp
 
@@ -139,6 +141,8 @@ def _tpu_cases_flash():
     cases = []
     for label, causal, (b, h, s, d) in (
             ("bert_s128", False, (8, 12, 128, 64)),
+            ("bert_s128_b256", False, (256, 12, 128, 64)),
+            ("bert_s128_odd", False, (7, 3, 128, 64)),
             ("causal_s512", True, (2, 8, 512, 128))):
         args = [((b, h, s, d), "bfloat16")] * 3 + [((b, s), "float32")]
         cases.append((label + "_fwd", attend(causal), args))
@@ -455,8 +459,9 @@ def _parity_remat(rng):
 
 register(KernelSpec(
     "flash_attention", ("scaled_dot_product_attention",), "tolerance",
-    _parity_flash, tpu_cases=_tpu_cases_flash,
-    doc="tiled online-softmax attention, training fwd+bwd "
+    _parity_flash, tpu_cases=_tpu_cases_flash, version=2,
+    doc="tiled online-softmax attention, training fwd+bwd, as many "
+        "(batch, head) pairs a grid step as a VMEM budget holds "
         "(ops/pallas/flash_attention.py)",
 ))
 register(KernelSpec(

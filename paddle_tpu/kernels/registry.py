@@ -47,7 +47,8 @@ from collections import namedtuple
 __all__ = [
     "KernelSpec", "register", "get", "all_specs", "has",
     "mode", "resolved_mode", "selected", "probe", "scoped_mode",
-    "kernel_sig", "registry_fingerprint", "fallback_counter", "MODE_ENV",
+    "kernel_sig", "registry_fingerprint", "fallback_counter",
+    "flash_grid_metrics", "flash_grid_snapshot", "FLASH_KERNELS", "MODE_ENV",
 ]
 
 MODE_ENV = "PADDLE_TPU_KERNELS"
@@ -201,6 +202,52 @@ def fallback_counter():
         "kernel-eligible ops that ran the composite fallback "
         "(untileable/VMEM-oversized geometry or manual-mesh region)",
     )
+
+
+#: the flash kernels by the ``kernel`` label of their two series
+FLASH_KERNELS = ("fwd", "bwd_dkdv", "bwd_dq")
+
+
+def flash_grid_metrics(kernel, grid_steps, heads):
+    """Counted at every lowered call of a flash kernel (``kernel`` is one
+    of ``FLASH_KERNELS``), beside the fallbacks:
+    ``flash_grid_steps_total{kernel=}`` adds the call's grid size and
+    ``flash_heads_per_step{kernel=}`` holds the (batch, head) pairs a grid
+    step of the last such call serves (``flash_attention._heads_per_step``)
+    — a step whose heads-per-step reads 1 at short sequences is paying for
+    its grid, not for its work."""
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    reg = obs_metrics.registry()
+    labels = {"kernel": kernel}
+    reg.counter(
+        "flash_grid_steps_total",
+        "grid steps of the flash-attention kernels' lowered calls",
+        labels=labels,
+    ).inc(grid_steps)
+    reg.gauge(
+        "flash_heads_per_step",
+        "(batch, head) pairs one grid step of the last lowered "
+        "flash-attention call serves",
+        labels=labels,
+    ).set(heads)
+
+
+def flash_grid_snapshot():
+    """``{kernel: {"grid_steps", "heads_per_step"}}`` as
+    ``flash_grid_metrics`` left them (zeros for a kernel never lowered)."""
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    reg = obs_metrics.registry()
+    out = {}
+    for kernel in FLASH_KERNELS:
+        steps = reg.get("flash_grid_steps_total", {"kernel": kernel})
+        heads = reg.get("flash_heads_per_step", {"kernel": kernel})
+        out[kernel] = {
+            "grid_steps": int(steps.value) if steps is not None else 0,
+            "heads_per_step": int(heads.value) if heads is not None else 0,
+        }
+    return out
 
 
 def probe(name):

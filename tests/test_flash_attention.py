@@ -154,3 +154,112 @@ def test_flash_block_logic_at_kernel_scale(rng, S, D):
     g2 = jax.grad(lambda q: jnp.sum(ref(q, k, v)))(q)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                rtol=5e-3, atol=5e-4)
+
+
+# ---- several (batch, head) pairs a grid step (_heads_per_step) -----------
+
+_SPLIT = {1: (1, 1), 6: (2, 3), 7: (7, 1), 10: (5, 2), 24: (2, 12),
+          96: (8, 12)}
+_D = 64
+
+
+def _group(bh, S, dtype):
+    """(forward, dk/dv, dq) heads a grid step at the test's geometry."""
+    from paddle_tpu.ops.pallas.flash_attention import _heads_per_step
+
+    return tuple(_heads_per_step(bh, S, 128, _D, dtype, blocked=n)
+                 for n in (2, 4, 3))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("S", [128, 256])
+@pytest.mark.parametrize("bh", sorted(_SPLIT))
+def test_grouped_heads_match_the_composite(bh, S, with_bias, causal, dtype):
+    """The interpreter's forward, dq, dk, dv and dbias with G heads a grid
+    step against ``_jnp_attention`` and its ``jax.grad``: every divisor
+    pattern of B*H (a prime takes 1 or itself), one and two blocks, the
+    padding bias. Where there is a bias, a second batch element and no
+    causal mask, the first one's rows are FULLY masked (-inf on every key):
+    they read 0 and hand back no gradient, whichever place they have among
+    a grid step's heads (the composite has no such rows: a softmax over
+    nothing)."""
+    from paddle_tpu.ops.pallas.flash_attention import _jnp_attention
+
+    B, H = _SPLIT[bh]
+    groups = _group(bh, S, dtype)
+    assert all(bh % g == 0 for g in groups)
+    if bh == 7:
+        assert set(groups) <= {1, 7}
+    elif bh > 1:
+        assert min(groups) > 1, groups
+    rng = np.random.RandomState(bh * 1000 + S)
+    q, k, v, w = (jnp.asarray(rng.randn(B, H, S, _D).astype("float32") * 0.5,
+                              dtype) for _ in range(4))
+    bias, live = None, slice(None)
+    if with_bias:
+        keep = rng.rand(B, S) > 0.3
+        keep[:, 0] = True
+        bias = np.where(keep, 0, -1e9).astype("float32")
+        if B > 1 and not causal:
+            bias[0], live = -np.inf, slice(1, None)
+        bias = jnp.asarray(bias)
+    sm = 1.0 / math.sqrt(_D)
+    wf = w.astype(jnp.float32)
+
+    def flash(q, k, v, bias):
+        out = flash_attention(q, k, v, bias=bias, causal=causal,
+                              interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * wf), out
+
+    def composite(q, k, v, bias):
+        out = _jnp_attention(q, k, v, bias, sm, causal)
+        return jnp.sum(out.astype(jnp.float32) * wf[live]), out
+
+    argnums = (0, 1, 2, 3) if with_bias else (0, 1, 2)
+    (_, out), grads = jax.value_and_grad(
+        flash, argnums=argnums, has_aux=True)(q, k, v, bias)
+    (_, ref), ref_grads = jax.value_and_grad(
+        composite, argnums=argnums, has_aux=True)(
+            q[live], k[live], v[live], None if bias is None else bias[live])
+    # bfloat16: the kernel rounds p and dS to the operands' dtype before
+    # their products (the MXU's inputs), the composite works in float32
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for name, a, b in zip(("o", "dq", "dk", "dv", "dbias"),
+                          (out,) + tuple(grads), (ref,) + tuple(ref_grads)):
+        a = np.asarray(a.astype(jnp.float32))
+        b = np.asarray(b.astype(jnp.float32))
+        assert np.isfinite(a).all(), name
+        if live != slice(None):
+            assert not a[0].any(), f"{name}: a fully-masked row is not 0"
+        assert a[live].shape == b.shape
+        scale = max(float(np.abs(b).max()), 1.0)
+        np.testing.assert_allclose(a[live], b, rtol=10 * tol,
+                                   atol=tol * scale,
+                                   err_msg=f"{name} groups={groups}")
+
+
+def test_heads_per_step_follows_the_shapes():
+    """G alone: it divides B*H, is at least 16 at the BERT cell's shapes,
+    never rises with S, is 1 where one head's K and V fill the budget, and
+    its blocks stay inside the budget wherever it is over 1."""
+    from paddle_tpu.ops.pallas import flash_attention as F
+
+    assert F._heads_per_step(3072, 128, 128, 64, "bfloat16") >= 16
+    assert F._heads_per_step(3072, 8192, 128, 64, "bfloat16") == 1
+    for dtype in ("bfloat16", "float32"):
+        size = np.dtype(jnp.dtype(dtype)).itemsize
+        for d in (64, 128):
+            for bh in (1, 7, 10, 96, 768, 3072, 3 * 7 * 11):
+                for blocked in (2, 3, 4):
+                    last = bh
+                    for s in (128, 256, 512, 1024, 2048, 4096, 8192, 16384):
+                        g = F._heads_per_step(bh, s, 128, d, dtype,
+                                              blocked=blocked)
+                        assert 1 <= g <= last and bh % g == 0
+                        last = g
+                        held = g * ((2 * s + blocked * 128) * 128 * size
+                                    + 4 * 8 * s * 4)
+                        assert g == 1 or held <= F._STEP_BYTES
+                    assert last == 1

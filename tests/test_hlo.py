@@ -41,9 +41,22 @@ def _lower_bert(flash):
     )
 
 
+LAYERS = 2     # bert_train_step_text's defaults
+HEADS = 12
 @pytest.fixture(scope="module")
-def bert_flash_stablehlo():
-    return _lower_bert(flash=True)
+def bert_flash_lowering():
+    """(StableHLO text, ``kernels.flash_grid_snapshot()`` before and after)
+    of the module's flash lowering."""
+    from paddle_tpu import kernels
+
+    before = kernels.flash_grid_snapshot()
+    text = _lower_bert(flash=True)
+    return text, before, kernels.flash_grid_snapshot()
+
+
+@pytest.fixture(scope="module")
+def bert_flash_stablehlo(bert_flash_lowering):
+    return bert_flash_lowering[0]
 
 
 def test_flash_train_step_no_s2_buffers(bert_flash_stablehlo):
@@ -53,6 +66,26 @@ def test_flash_train_step_no_s2_buffers(bert_flash_stablehlo):
     tensors = hlo.stablehlo_tensors(bert_flash_stablehlo)
     s2 = hlo.tensors_with_trailing(tensors, (S, S))
     assert not s2, f"S^2 buffers on the flash path: {set(s2)}"
+
+
+def test_flash_grid_serves_several_heads_a_step(bert_flash_lowering):
+    """``flash_grid_steps_total`` adds calls x B*H / G x S / block at
+    lowering, with G from the shapes: here 48 (batch, head) pairs of
+    [512, 64] bf16 go 8 to a grid step in all three kernels, and a layer
+    lowers the forward twice (the op, and the grad op's re-run: PERF.md)."""
+    from paddle_tpu.ops.pallas.flash_attention import _heads_per_step
+
+    _text, before, after = bert_flash_lowering
+    bh, blocks = BATCH * HEADS, S // 128
+    calls = {"fwd": 2 * LAYERS, "bwd_dkdv": LAYERS, "bwd_dq": LAYERS}
+    blocked = {"fwd": 2, "bwd_dkdv": 4, "bwd_dq": 3}
+    assert set(after) == set(calls)
+    for kernel, n in calls.items():
+        g = _heads_per_step(bh, S, 128, HIDDEN // HEADS, "bfloat16",
+                            blocked=blocked[kernel])
+        assert g == after[kernel]["heads_per_step"] == 8
+        steps = after[kernel]["grid_steps"] - before[kernel]["grid_steps"]
+        assert steps == n * (bh // g) * blocks, kernel
 
 
 def test_unfused_path_detector_fires():

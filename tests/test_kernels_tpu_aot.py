@@ -96,6 +96,44 @@ def test_paged_kernel_serves_every_cells_geometry(label, v5e_devices):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+#: the flash cases' geometries (``_tpu_cases_flash``'s labels): B*H, S and
+#: the (batch, head) pairs a grid step of the forward, dk/dv and dq kernels
+#: serves there — the largest divisor of B*H a 4 MiB set of blocks holds
+FLASH_GROUPS = {
+    "bert_s128": (96, 128, (24, 16, 16)),
+    "bert_s128_b256": (3072, 128, (24, 16, 16)),   # the training cells'
+    "bert_s128_odd": (21, 128, (21, 7, 21)),
+    "causal_s512": (16, 512, (8, 8, 8)),
+}
+
+
+@pytest.mark.parametrize("label", sorted(FLASH_GROUPS))
+def test_flash_kernels_group_heads_at_every_geometry(label, v5e_devices):
+    """Mosaic takes all three flash kernels with several (batch, head)
+    pairs a grid step (the loop over a block's leading index) at the
+    training cells' full per-chip geometry, at an odd B*H and at a causal
+    multi-block length, without a fallback; the grids the lowering counts
+    are B*H / G x S / 128 with the G the shapes give."""
+    cases = {c[0]: c for c in kernels.get("flash_attention").tpu_cases()}
+    assert {c[:-5] for c in cases if c.endswith("_grad")} == set(FLASH_GROUPS)
+    bh, seq, groups = FLASH_GROUPS[label]
+    _label, fn, arg_specs = cases[label + "_grad"]
+    assert arg_specs[0][0][0] * arg_specs[0][0][1] == bh
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.flash_grid_snapshot()
+    fallbacks = kernels.fallback_counter().value
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernels.fallback_counter().value == fallbacks
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    after = kernels.flash_grid_snapshot()
+    for kernel, g in zip(kernels.FLASH_KERNELS, groups):
+        assert after[kernel]["heads_per_step"] == g
+        assert (after[kernel]["grid_steps"] - before[kernel]["grid_steps"]
+                == bh // g * (seq // 128))
+
+
 @pytest.mark.parametrize("shape,names", [
     ((4,), ("data",)), ((2, 2), ("data", "model")), ((2, 2), ("dcn", "data")),
 ])
