@@ -8,112 +8,63 @@ of S slots is stepped once per model iteration through ONE compiled
 iterations and admitted prompts prefill into free slots mid-flight, so
 occupancy tracks offered load instead of the slowest batchmate.
 
-Storage is a **block-granular paged arena** (vLLM's PagedAttention,
-SOSP'23): KV rows live in fixed-size blocks handed out by
-``pool.BlockPool``; the compiled programs see only flat row-index feeds
-(the decode step: one packed integer array, below), so HBM scales with
-USED tokens, prompts sharing a prefix share PHYSICAL
-blocks through the radix index (copy-on-write at divergence), and the
-arena is sized against ``analysis/memory.py``'s pre-compile HBM gate
-instead of reserving a dense ``slots x max_len`` grid.
+Storage is a block-granular paged arena, and it is `kvstate.py`'s: where a
+sequence's K/V rows are, how they are written, read, spilled, shared and
+given back (`KVStore`, one per entry; `SeqKV`, one per sequence). This
+module decides who runs; it sees block COUNTS and never a row.
 
 Scheduling modes, all bit-identical to the offline whole-sequence
 reference for any admission order (tested, not asserted by construction
 alone):
 
-* **decode** — the ``[S, 1]`` hot path, as in PR 10. The step program
-  chooses each slot's greedy token itself (``arg_max`` over its own
-  float32 logits); a step whose slots are all greedy brings those S
-  integers to the host, any other step (a sampled slot, a beam group, a
-  grammar masked on the host, a hand-built model without
-  ``token_fetch``) the whole ``[S, 1, V]`` logits. Decided per step
-  from the slots' own policies; both give the same tokens. A step that
-  needs its tokens alone (and steps no grammar, whose next mask follows
-  from the token's value) is left on the device, and the next
-  iteration launches its successor, fed those tokens as the device
-  array they are, BEFORE it fetches them: depth one, decided per
-  iteration from the scheduler's own state (``_iterate``), the same
-  tokens in either order. A slot that was not in the step in flight
-  (new: its first token came off its prompt's last chunk) joins the next
-  one with its token from the host, beside the others' on the device:
-  the step program chooses slot by slot. From the host a step takes ONE
-  array, put once (``dec_step``, int32 ``[S, 4 + blocks per slot]``: each
-  stepping slot's token or -1, cursor, attention length, write row and
-  block table); the step program makes its positions, causal bias, row map
-  and write rows of it on the device (``paged_step_feeds``). A put costs
-  this host ~0.25 ms whatever its size (PERF.md §6, PR 39), so the
-  count of puts, not their bytes, is what a step's launch pays.
+* **decode** — the ``[S, 1]`` hot path. The step program chooses each
+  slot's greedy token itself; a step whose slots are all greedy brings
+  those S integers to the host, any other (a sampled slot, a beam group, a
+  grammar masked on the host, a model without ``token_fetch``) the whole
+  ``[S, 1, V]`` logits: decided per step from the slots' own policies
+  (`_tokens_suffice`). A step that needs its tokens alone is left on the
+  device and the next iteration launches its successor, fed those tokens
+  as the device array they are, BEFORE it fetches them: depth one, the
+  same tokens in either order (`_iterate` has the two orders and what
+  drains, ``serving_decode_drains_total{why=}``). From the host a step
+  takes ONE array, put once (``dec_step``: `_step_feeds`); a put costs
+  this host ~0.25 ms whatever its size (PERF.md §6, PR 39).
 * **one-shot prefill** — a prompt the chunk budget covers (every prompt
   of a model without a chunk program) runs the whole-prompt prefill
   program once, and its admission moves no bulk bytes across the host
   link: the program's K/V outputs are the inject program's feeds, the
-  host takes the one logits row at the prompt's last position and, in
-  one fetch, the rows such a prompt can fill (what the prefix cache and
-  copy-on-write keep), and all of it is launched before the host waits.
-* **chunked prefill** — a prompt longer than the chunk budget streams
-  through the ``[1, C]`` chunk program ONE chunk per engine iteration,
-  interleaved with decode steps, so a 32k-token admission never stalls
-  in-flight generations for more than one chunk's compute. Chunks fully
-  covered by radix-shared blocks are skipped (shared prefixes share
-  prefill work AND storage). The whole of such an admission runs in the
-  launch-ahead order: the arrival is admitted (blocks from the free
-  list, a slot in mode ``"prefill"``) and every chunk launched, the
-  last one too, with the decode step in flight untouched; the last
-  chunk's one logits row is fetched behind the NEXT step's launch.
-  What still DRAINS the step in flight first, and says why
-  (``serving_decode_drains_total{why=}``): a one-shot prompt, a
-  speculative or beam request, a parked or deferred session, blocks the
-  free list cannot cover, a new slot that samples or masks a grammar, a
-  brownout move, a stop, an open breaker, a step that nothing follows.
+  host takes one logits row and, in one fetch, the rows such a prompt can
+  fill, and all of it is launched before the host waits.
+* **chunked prefill** — a longer prompt streams through the ``[1, C]``
+  chunk program ONE chunk per engine iteration, interleaved with decode
+  steps, so a 32k-token admission never stalls in-flight generations for
+  more than one chunk's compute; chunks that radix-shared blocks cover
+  are skipped. The whole admission runs in the launch-ahead order: every
+  chunk is a launch under the step in flight, and the last chunk's one
+  logits row is fetched behind the NEXT step's launch.
 * **speculative** — a draft model (just another ``(model, version)``
   registry entry) greedily proposes k tokens; the target verifies all
   of them in ONE batch-prefill forward and emits the longest matching
-  prefix plus its own correction token. Greedy acceptance makes the
-  output BIT-IDENTICAL to target-only decode; the win is target
-  steps-per-emitted-token < 1.
+  prefix plus its own correction token: BIT-IDENTICAL to target-only
+  decode, at fewer target steps per emitted token.
 
 State of a second kind: a model may keep **per-slot recurrent state**
-(``DecodeModel.slot_states``: a state-space layer's, beside the paged
-rows). Its programs advance it in place for the tokens whose write row is
-real and leave every other slot's bytes alone; the engine names the slot
-to the chunk program, sends EVERY prompt of such a model through chunked
-prefill from its first token (the chunk at position 0 starts the slot
-from zero: ``decode::state_reset``), registers no block of it for
-sharing, parks no session of it, and refuses what would need a snapshot
-of a state: a prefix cache or a host tier at ``register_model``, beam
-search and speculation at ``submit``. Launch-ahead stays safe: the one
-step an ``eos_id`` wastes dirties only a state the next admission resets.
-A step's integer counts (``counts_fetch``: how a step's tokens were
-routed, how many passes of a looped stack they took) come back in the same
-fetch as its tokens. A model without prefill and inject programs
-(``DecodeModel.chunks_only``) need not be recurrent: one whose stack runs
-several times a token keeps K/V rows per (pass, layer), ``state_names`` is
-one pair per such state, and it is refused a host tier (nothing could put
-its rows back), beams and speculation likewise.
+(``DecodeModel.slot_states``), or K/V rows per (pass, layer) of a looped
+stack; either has no prefill and inject programs (``chunks_only``). The
+scheduler names the slot to the chunk program, sends EVERY prompt of such
+a model through chunked prefill from its first token (the chunk at
+position 0 starts the slot from zero: ``decode::state_reset``), parks no
+session of it, and refuses beam search and speculation at ``submit``
+(what the store refuses it: `kvstate.py`). Launch-ahead stays safe: the
+one step an ``eos_id`` wastes dirties only a state the next admission
+resets. A step's integer counts (``counts_fetch``) come back in the same
+fetch as its tokens.
 
-**Admission by reservation.** An arena may be smaller than ``slots x
-ceil(max_len / block_size)`` (a token's rows can cost too much to give
-every slot its full length). With a host tier a session that finds the
-pool empty mid-generation parks; WITHOUT one (``host_tier_mb=0``) it could
-only fail, so such an entry (``_reserves``) admits a greedy or sampled
-request against its WHOLE block chain, ``ceil((len(prompt) +
-max_new_tokens) / block_size)``, both known at ``submit``: the pool
-promises the chain (``BlockPool.reserve``; promised and unopened blocks
-count against ``free_count``), the slot opens its blocks out of the
-promise as its cursor moves and hands back the rest when it retires. A
-tenant's head request whose chain the pool cannot cover stays in the
-QUEUE (``_pick(fits=)``; ``admissions_deferred`` counts it once) and takes
-no slot; one whose chain no pool could hold fails at its admission.
-Nothing parks, nothing fails mid-generation, and an arrival whose chain is
-covered joins the launch-ahead order without a drain, as before. Selected
-by the pool's size and the tier's absence alone.
-
-Correctness contract: (a) retired/foreign slots touch the arena only
-through dropped or disjoint row scatters (exact no-ops), and (b) the
-additive ``-1e9`` attention bias makes positions beyond a slot's cursor
-contribute exactly 0.0 (the repo-wide padding contract); gather/scatter
-relocate rows byte-for-byte, so the paged rebuild preserves PR 10's
-bit-exactness property for every block size.
+**Admission by reservation** (`KVStore.acquire`): where the store promises
+a request its whole block chain or nothing, a tenant's head request whose
+chain the pool cannot cover stays in the QUEUE (``_pick(fits=)``) and
+takes no slot; nothing parks, nothing fails mid-generation, and an arrival
+whose chain is covered joins the launch-ahead order without a drain.
 
 Multi-tenancy: one engine hosts N ``(model, version)`` entries, each with
 its own slot batch, queue, and scheduler thread. Admission applies
@@ -125,28 +76,17 @@ Cold start: the executables per entry lower through ``core/lowering.py``
 into the content-addressed compile cache. With a populated cache
 directory, a fresh replica (or the circuit breaker's relaunched
 replacement) restores them from the ``jax.export`` disk tier with ZERO
-traces — subprocess-asserted in tests/test_decode.py. Before anything
-compiles, the paged arena is sized against the peak-HBM budget via
-``analysis/memory.py`` — an oversized block pool fails with sizing
-advice, not an XLA OOM.
+traces — subprocess-asserted in tests/test_decode.py. An arena that does
+not fit the HBM budget fails before anything compiles (`_check_hbm`).
 
-Measured from inside (all of it nothing while tracing is off): one
-``decode::iterate`` span per scheduler iteration holds a span per phase —
-``decode::admit`` > ``decode::prefill`` / ``prefill_fetch`` / ``inject``,
-``decode::chunk`` / ``chunk_fetch``, ``decode::feeds`` / ``step`` /
-``step_fetch`` / ``sample`` — each carrying its request's id where it has
-one; a launch span also says how many host arrays it put (``puts``: one
-a decode step), their ``bytes``, how long the host spent inside
-``jax.device_put`` and inside the executable's call, ``decode::step``
-whether it was launched ahead of the previous step's fetch (``ahead``),
-``decode::chunk`` whether a step was in flight (``ahead``) and whether it
-is its prompt's last (``last``), that chunk's ``decode::chunk_fetch``
-whether a launch was made over it first (``deferred``), and a
-``decode::step_fetch`` made with nothing launched over it, why
-(``drain``). Always on: a time stamp per token on the ``Response``, taken
-when the host has the token, the bytes that cross the device boundary,
-the steps that fetched the whole logits, the steps and the chunks
-launched ahead and the drains by reason (``DecodeMetrics``).
+Measured from inside (nothing while tracing is off): one
+``decode::iterate`` span per scheduler iteration holds a span per phase,
+each carrying its request's id where it has one; a launch span says what it
+put (`_run`), ``decode::step`` and ``decode::chunk`` whether they were
+launched ahead, a ``decode::step_fetch`` with nothing launched over it why
+(``drain``). Always on: a time stamp per token on the ``Response``, the
+bytes that cross the device boundary, the steps launched ahead and the
+drains by reason (``DecodeMetrics``).
 """
 
 import threading
@@ -172,16 +112,13 @@ from paddle_tpu.serving.decode.generate.beam import (
 )
 from paddle_tpu.serving.decode.generate.beam import select as beam_select
 from paddle_tpu.serving.brownout import BrownoutController
+from paddle_tpu.serving.decode.kvstate import (
+    ArenaInvalidError,
+    KVStore,
+    SlotPool,
+)
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.model import NEG_INF, DecodeModel
-from paddle_tpu.serving.decode.pool import (
-    BlockPool,
-    PrefixCache,
-    SlotPool,
-    block_hashes,
-    prompt_key,
-)
-from paddle_tpu.serving.decode.tier import HostKVTier
 from paddle_tpu.serving.engine import _ReplicaBreaker
 from paddle_tpu.serving.queue import RequestQueue
 from paddle_tpu.serving.request import (
@@ -249,12 +186,6 @@ class GenerationRequest:
         return (now if now is not None else time.perf_counter()) > self.deadline
 
 
-class _ArenaInvalidError(RuntimeError):
-    """A DONATED arena update (inject/chunk) failed mid-execution: the
-    old buffers were consumed and the new ones never materialized, so the
-    whole KV pool — not just the admitting request — is undefined."""
-
-
 class _DeferAdmission(Exception):
     """Raised out of ``_acquire_blocks`` when the arena is exhausted and
     the request cannot be admitted right now, but WILL fit later (parked
@@ -283,40 +214,32 @@ class _Slot:
     "prefill" (a long prompt streaming through the chunk program),
     "spec" (speculative verify cycles — holds no TARGET arena blocks),
     or "beam" (one live beam hypothesis; its group coordinates via
-    ``beam``). ``blocks`` is the slot's block table; ``row_map[p]`` the
-    physical arena row of position ``p`` (what the chunk and inject
-    programs are fed) and ``table`` the blocks' ids (the slot's row of
-    the decode step's one feed, which makes the row map of it on the
-    device). ``d_*`` is the draft-KV footprint of a speculative slot:
-    its slot/blocks/row-map/table ON THE DRAFT ENTRY plus ``d_cursor``, the
-    next draft arena position without a committed KV row. ``ahead``
-    counts the slot's tokens that a launched decode step has produced on
-    the device and the host has not read yet: ``cursor`` already counts
-    their rows, ``generated`` and ``last_token`` do not hold them.
-    ``reserve`` is what is left of the slot's reservation (admission by
-    reservation): the blocks of its whole chain that it has not opened."""
+    ``beam``). ``kv`` is where the sequence's K/V rows are on this entry
+    (a `kvstate.SeqKV`; None for a speculative slot, which holds none).
+    A speculative slot with a draft-KV footprint has ``draft_kv``, the
+    same ON THE DRAFT ENTRY ``d_entry``, in its batch slot ``d_slot``,
+    and ``d_cursor``, the next draft arena position without a committed
+    KV row. ``ahead`` counts the slot's tokens that a launched decode step
+    has produced on the device and the host has not read yet: ``cursor``
+    already counts their rows, ``generated`` and ``last_token`` do not
+    hold them."""
 
     __slots__ = ("request", "mode", "cursor", "last_token", "generated",
-                 "blocks", "row_map", "table", "plen", "done", "shared_len",
-                 "toks", "sampling", "grammar", "beam", "score", "seq",
-                 "ahead", "reserve", "d_entry", "d_slot", "d_blocks",
-                 "d_row_map", "d_table", "d_cursor")
+                 "kv", "plen", "done", "toks", "sampling", "grammar", "beam",
+                 "score", "seq", "ahead", "d_entry", "d_slot", "draft_kv",
+                 "d_cursor")
 
-    def __init__(self, request, mode="decode"):
+    def __init__(self, request, mode="decode", seq=0):
         self.request = request
         self.mode = mode
         self.cursor = 0
         self.last_token = None
         self.generated = []
-        self.blocks = []
-        self.row_map = None
-        self.table = None
-        self.seq = 0            # admission order (default victim policy)
+        self.kv = None          # SeqKV on this entry
+        self.seq = seq          # admission order (default victim policy)
         self.ahead = 0          # tokens launched, not yet on the host
-        self.reserve = 0        # blocks promised by the pool, not yet opened
         self.plen = len(request.prompt)
         self.done = 0           # chunked prefill: prompt positions landed
-        self.shared_len = 0     # positions served by radix-shared blocks
         self.toks = None        # spec mode: prompt + emitted so far
         self.sampling = None    # SamplingParams (committed-stream sampling)
         self.grammar = None     # per-hypothesis GrammarConstraint
@@ -324,9 +247,7 @@ class _Slot:
         self.score = 0.0        # beam: cumulative float64 log-prob
         self.d_entry = None     # draft-KV: the draft _ModelEntry
         self.d_slot = None
-        self.d_blocks = None
-        self.d_row_map = None
-        self.d_table = None
+        self.draft_kv = None    # SeqKV on the draft entry
         self.d_cursor = 0
 
 
@@ -427,28 +348,6 @@ def _pick_row(logits, index):
                                         keepdims=False)
 
 
-def _map_blocks(m, blocks, row_map):
-    """``(row_map, table)`` of a slot of model ``m`` that holds ``blocks``:
-    the ``[max_len]`` int64 arena row of every position the blocks cover,
-    written into ``row_map`` (made when None; what lies past the blocks
-    is left as it was, and is never read), and the blocks' ids
-    (`DecodeModel.block_table`)."""
-    bs = m.block_size
-    if row_map is None:
-        row_map = np.zeros(m.max_len, dtype="int64")
-    for i, b in enumerate(blocks):
-        lo = i * bs
-        hi = min(lo + bs, m.max_len)
-        row_map[lo:hi] = b.row0 + np.arange(hi - lo)
-    return row_map, m.block_table(blocks)
-
-
-def _by_layer(live):
-    """The ``(k, v)`` rows of each layer in a ``[2 * layers, P, H]`` host
-    copy of a one-shot prefill (``_ModelEntry._prefill_to_host``)."""
-    return [(live[i], live[i + 1]) for i in range(0, len(live), 2)]
-
-
 class _ModelEntry:
     """One hosted (model, version): programs + executables + slot batch +
     block pool + its scheduler thread. All slot/arena/block mutation
@@ -465,31 +364,15 @@ class _ModelEntry:
         self._slots = [None] * model.slots
         self._metrics = DecodeMetrics(
             engine_label=f"{engine.label}:{model.label}")
-        self._blocks = BlockPool(model.num_blocks, model.block_size,
-                                 count=self._metrics.incr)
-        # blocks the paged-attention kernel copies as one unit at this
-        # geometry (0: no kernel serves it), to count a step's units
-        from paddle_tpu.kernels.attention import _paged_group
-        self._copy_unit = _paged_group(
-            model.block_size, model.blocks_per_slot, model.kv_width,
-            model.kv_dtype)
-        self._prefix = PrefixCache(prefix_cache_size)
-        # graceful degradation (r18): host-RAM KV tier, parked sessions,
-        # deferred admissions, and the brownout severity ladder. The
-        # pool writes registered blocks back to the tier at LRU eviction
-        # (decode.blocks -> decode.tier); reads go through the engine so
-        # the device rows come off the live arena.
-        self._tier = HostKVTier(capacity_bytes=engine._host_tier_bytes)
-        if engine._host_tier_bytes:
-            self._blocks.attach_tier(self._tier,
-                                     read_rows=self._read_block_rows)
-        # admission by reservation: an arena that cannot give every slot
-        # its full length, with no tier to park a session on, admits a
-        # request against its WHOLE block chain, so that no admitted
-        # request can find the pool empty mid-generation
-        self._reserves = (not engine._host_tier_bytes
-                          and model.num_blocks
-                          < model.slots * model.blocks_per_slot)
+        # where every sequence's K/V rows are kept: block pool, host tier,
+        # prefix cache. It launches and fetches through this entry (tests
+        # wrap `_run`), and finds the arenas in its scope
+        self.kv = KVStore(
+            model, engine._host_tier_bytes, prefix_cache_size, self._metrics,
+            run=lambda *a: self._run(*a), fetch=lambda v: self._fetch(v),
+            scope=lambda: self._scope, device=engine.device)
+        # graceful degradation (r18): parked sessions, deferred
+        # admissions, and the brownout severity ladder
         self._parked = []       # [_ParkedSession] FIFO
         self._pending = []      # [GenerationRequest] deferred admissions
         self._brownout = BrownoutController()
@@ -521,7 +404,7 @@ class _ModelEntry:
         # _draft_ok poisons the entry after a failed donated draft call —
         # users fall back to replay proposals instead of reading an
         # undefined arena
-        self._draft_lock = lockdep.named_lock("decode.draft")
+        self._draft_lock = lockdep.named_lock("decode.draft", rlock=True)
         self._draft_pinned = False
         self._draft_ok = True
 
@@ -704,13 +587,11 @@ class _ModelEntry:
         import jax.numpy as jnp
 
         m = self._model
-        states = [(n, (m.rows, m.kv_width), m.kv_dtype)
-                  for kv in m.state_names for n in kv] + m.slot_states
-        for n, shape, dtype in states:
+        for n, shape, dtype in m.slot_states:
             self._scope.set(n, jax.device_put(
                 jnp.zeros(shape, dtype), self._engine.device))
+        self.kv.reset()
         self._pool.reset()
-        self._blocks.reset()
         self._slots = [None] * m.slots
         # a step in flight read the lost arena: it is never delivered,
         # nor is a last chunk's row
@@ -786,13 +667,9 @@ class _ModelEntry:
         * **drain**: step N is fetched and delivered first, with nothing
           launched over it (`_drain`, which says why and counts it), and
           the iteration then runs as if no step had been in flight. Kept
-          for what has to see the device or changes who steps
-          (`_drain_reason`; for a picked request, `_admission_waits`):
-          a one-shot prompt, whose ``decode::prefill_fetch`` waits — a
-          produced token never waits behind an admission's prefill —, a
-          speculative or beam request, a parked or deferred session, a
-          block acquisition the free list cannot cover, a brownout move,
-          a stop, an open breaker, and a step that nothing follows.
+          for what has to see the device or changes who steps: a brownout
+          move, and what `_drain_reason` and, for a picked request,
+          `_admission_waits` list.
 
         The device runs what it is given in launch order, which is what
         keeps the first order sound: a step launched before a slot
@@ -912,10 +789,9 @@ class _ModelEntry:
         a speculative slot's verify, the last chunk of a BEAM request's
         prompt, whose first selection forks slots and copies arena rows),
         the engine is stopping or its breaker is not closed, or no slot
-        of the step in flight steps again. An arrival and the last chunk
-        of any other prompt are no reason: `_admission_waits` decides for
-        the requests picked, and a last chunk is a launch whose row
-        lands behind the next step's (`_advance_prefills`, `_step`)."""
+        of the step in flight steps again. An arrival is no reason
+        (`_admission_waits` decides for the requests picked), nor is the
+        last chunk of any other prompt (`_advance_prefills`)."""
         if self._stop:
             return "shutdown"
         if self._breaker is not None and self._breaker.state != "closed":
@@ -964,32 +840,23 @@ class _ModelEntry:
                  else tuple(p for p in Priority.LANES if p != Priority.LOW))
         with self._cond:
             rows = blocks = 0
-            room = self._blocks.free_count
-
-            def fits(req):
-                # admission by reservation: a tenant's head request whose
-                # chain the pool cannot cover now waits in the queue (its
-                # turn comes back; FIFO within the tenant), and no slot is
-                # spent on it. One that can NEVER fit goes on, to fail
-                # loudly at its admission
-                need = self._chain(req)
-                if need <= room - blocks or need > self._model.num_blocks:
-                    return True
-                self._hold_back(req)
-                return False
-
+            # admission by reservation: a tenant's head request whose chain
+            # the pool cannot cover now waits in the queue (its turn comes
+            # back; FIFO within the tenant), and no slot is spent on it
+            fits = ((lambda req: self.kv.covers(req, blocks))
+                    if self.kv.reserves else None)
             while self._pool.free_count - rows > 0:
                 # budget in ROWS, not requests: a beam admission claims
                 # width slots (seed + first-selection forks) before the
                 # next pick runs
                 req = self._engine._pick(
                     self._queue, max_rows=self._pool.free_count - rows,
-                    lanes=lanes, fits=fits if self._reserves else None)
+                    lanes=lanes, fits=fits)
                 if req is None:
                     break
                 picked.append(req)
                 rows += req.rows
-                need = self._chain(req)
+                need = self.kv.chain(req)
                 if need <= self._model.num_blocks:
                     blocks += need
             # the round's picks are ONE drain event for the rate EWMA
@@ -1006,38 +873,15 @@ class _ModelEntry:
         device: a produced token never waits behind an admission's
         prefill), for a speculative or beam request, and for blocks the
         free list cannot cover (an eviction's write-back reads the
-        arenas, an exhausted pool parks a victim). Under admission by
-        reservation the blocks are a request's whole chain, and the pool's
-        count leaves out what it has promised already."""
-        bs = self._model.block_size
+        arenas, an exhausted pool parks a victim; under reservation they
+        are a request's whole chain)."""
         blocks = 0
         for req in picked:
             if (req.draft_key is not None or req.beam is not None
                     or not self._takes_chunks(req)):
                 return True
-            blocks += self._chain(req) or (len(req.prompt) + bs - 1) // bs
-        return blocks > self._blocks.free_count
-
-    def _chain(self, req):
-        """The blocks a request's whole sequence takes, prompt and answer
-        (both known at ``submit``), where its admission reserves them: 0
-        for an engine that does not reserve, and for a beam or speculative
-        request (a fork copies and shares blocks, a verify holds none:
-        neither's footprint is a sum known here; they are served from what
-        is promised to nobody)."""
-        if (not self._reserves or req.beam is not None
-                or req.draft_key is not None):
-            return 0
-        m = self._model
-        return -(-min(len(req.prompt) + req.max_new, m.max_len)
-                 // m.block_size)
-
-    def _hold_back(self, req):
-        """The pool cannot cover ``req``'s chain yet: counted once a
-        request, however many rounds it waits."""
-        if not req.held_back:
-            req.held_back = True
-            self._metrics.incr("admissions_deferred")
+            blocks += self.kv.admission_blocks(req)
+        return blocks > self.kv.free_blocks
 
     def _takes_chunks(self, req):
         """Whether a prompt streams through the chunk program: one the
@@ -1068,19 +912,16 @@ class _ModelEntry:
             outcome = self._admit_into_slot(req)
             if sp is not None:
                 sp.set(request=req.id, outcome=outcome,
-                       reserved=self._chain(req) if outcome == "admitted"
-                       else 0, free=self._blocks.free_count)
+                       reserved=self.kv.chain(req) if outcome == "admitted"
+                       else 0, free=self.kv.free_blocks)
             return outcome
 
     def _admit_into_slot(self, req):
         if req.expired():
             # picked but dead: release the pick-time in-flight
             # reservation; no slot to free
-            self._engine._tenant_unflight(req.tenant)
-            self._metrics.incr("deadline_missed")
-            req.response._complete(error=DeadlineExceededError(
+            self._reject_in_flight(req, DeadlineExceededError(
                 "deadline expired before prefill"))
-            self._metrics.observe_request(req)
             return "done"
         slot = self._pool.acquire()
         if slot is None:
@@ -1093,24 +934,14 @@ class _ModelEntry:
             self._pool.release(slot)
             self._slots[slot] = None
             return "deferred"
-        except _ArenaInvalidError as e:
+        except ArenaInvalidError as e:
             # donated inject failed: like a step failure, every
             # in-flight sequence is lost (failed loudly), the
             # outcome drives the breaker, and the arena resets
             self._slots[slot] = None
-            self._engine._tenant_unflight(req.tenant)
-            self._metrics.incr("failed")
-            req.response._complete(error=RequestError(
+            self._reject_in_flight(req, RequestError(
                 f"request {req.id} failed in inject: {e}"))
-            self._metrics.observe_request(req)
-            self._metrics.incr("step_failures")
-            self._probe_relaunched = False
-            if self._breaker is not None:
-                self._breaker_event(self._breaker.record_failure())
-            self._reject_all_slots(lambda r: ReplicaLostError(
-                f"request {r.id} lost to arena "
-                f"failure during admission: {e}"))
-            self._reset_arenas()
+            self._arena_lost(f"arena failure during admission: {e}")
             # the reset arena is valid (zeroed): the REMAINING picked
             # requests still admit — dropping them would abandon
             # their futures and leak their tenants' queued counters
@@ -1118,114 +949,36 @@ class _ModelEntry:
         except Exception as e:  # request-attributed, not replica health
             self._pool.release(slot)
             self._slots[slot] = None
-            self._engine._tenant_unflight(req.tenant)
-            self._metrics.incr("failed")
-            req.response._complete(error=RequestError(
+            self._reject_in_flight(req, RequestError(
                 f"request {req.id} failed in prefill: {e}"))
-            self._metrics.observe_request(req)
             return "done"
         return "admitted"
 
-    def _row_of(self, st, p):
-        b = st.blocks[p // self._model.block_size]
-        return b.row0 + p % self._model.block_size
-
-    def _rebuild_row_map(self, st):
-        st.row_map, st.table = _map_blocks(self._model, st.blocks,
-                                           st.row_map)
-
     def _acquire_blocks(self, req):
-        """Acquire the prompt's block chain, parking victims instead of
-        hard-failing under exhaustion. Loud failure is reserved for the
-        one unfixable case — the prompt alone can never fit the pool.
+        """The prompt's K/V footing (`KVStore.acquire`), parking victims
+        instead of hard-failing under exhaustion. Loud failure is the
+        store's, for the one unfixable case: a chain no pool could hold.
         Otherwise victims are preempted (spilled to the host tier, to
         resume byte-identically) until the prompt fits; if that is not
         possible right now, ``_DeferAdmission`` sends the request to
-        ``_pending`` with its tenant reservation intact.
-
-        Under admission by reservation (`_chain`) nobody is parked: the
-        request's whole chain is promised by the pool or the request
-        waits, and what comes back third is the part of the chain not
-        opened yet (``_Slot.reserve``)."""
-        m = self._model
-        chain = self._chain(req)
-        if chain > m.num_blocks:
+        ``_pending`` with its tenant reservation intact. Under admission
+        by reservation nobody is parked: the request's whole chain is
+        promised by the pool or the request waits."""
+        kv = self.kv.acquire(req)
+        if kv is None and not self.kv.reserves:
             self._metrics.incr("blocks_exhausted")
-            self._metrics.incr("blocks_failed_total")
-            raise RuntimeError(
-                f"the request's chain of {chain} blocks (prompt and answer)"
-                f" can never fit a pool of {m.num_blocks}; shorten it or "
-                "host the model with more blocks")
-        if chain:
-            if not self._blocks.reserve(chain):
-                self._hold_back(req)
-                raise _DeferAdmission()
-            held = self._blocks.reserved
-            blocks, shared_len = self._blocks.acquire_for_prompt(
-                req.prompt, promised=chain)
-            self._metrics.incr("reserved_admissions")
-            self._metrics.incr("blocks_reserved", chain)
-            # what the prompt's blocks used up of the promise (reserved
-            # moves on this thread alone)
-            return (blocks, shared_len,
-                    chain - (held - self._blocks.reserved))
-        blocks, shared_len = self._blocks.acquire_for_prompt(req.prompt)
-        if blocks is not None:
-            return blocks, shared_len, 0
-        self._metrics.incr("blocks_exhausted")
-        if (len(req.prompt) + m.block_size - 1) // m.block_size \
-                > m.num_blocks:
-            self._metrics.incr("blocks_failed_total")
-            raise RuntimeError(
-                f"block pool exhausted ({self._blocks.stats()['blocks_free']}"
-                f" free of {m.num_blocks}) and the prompt alone can never "
-                "fit; shorten the prompt or host the model with more blocks")
-        # don't preempt on behalf of NEW work while earlier preempted
-        # sessions are still waiting — they have first claim on capacity
-        while blocks is None and not self._parked:
-            if not self._park_victim(req):
-                break
-            blocks, shared_len = self._blocks.acquire_for_prompt(req.prompt)
-        self._metrics.incr("blocks_parked_total")
-        if blocks is None:
-            self._metrics.incr("admissions_deferred")
+            # don't preempt on behalf of NEW work while earlier preempted
+            # sessions are still waiting — they have first claim on capacity
+            while kv is None and not self._parked and self._park_victim(req):
+                kv = self.kv.acquire(req)
+            self._metrics.incr("blocks_parked_total")
+            if kv is None:
+                self._metrics.incr("admissions_deferred")
+        if kv is None:
             raise _DeferAdmission()
-        return blocks, shared_len, 0
+        return kv
 
     # -- preemption / host-tier spill / resume ----------------------------
-    def _read_arenas(self, pick):
-        """``pick(arena)`` of every K and V arena, per state pair (a
-        layer's, or a (pass, layer)'s), and the bytes brought to the host
-        for it: each arena WHOLE, whatever is picked. They are fetches
-        (``serving_fetched_bytes_total``) and are counted in
-        ``serving_arena_read_bytes_total`` besides."""
-        out, nbytes = [], 0
-        for kn, vn in self._model.state_names:
-            k = self._fetch(self._scope.find_var(kn))
-            v = self._fetch(self._scope.find_var(vn))
-            nbytes += k.nbytes + v.nbytes
-            out.append((np.array(pick(k)), np.array(pick(v))))
-        self._metrics.incr("arena_read_bytes", nbytes)
-        return out, nbytes
-
-    def _read_block_rows(self, b):
-        """Tier write-back reader: one registered block's live arena rows
-        (called by the pool inside ``decode.blocks`` at LRU eviction —
-        before the evictee's rows can be overwritten by its successor).
-        A ``decode::writeback`` span, with the bytes it brought over."""
-        with _span("decode::writeback") as sp:
-            rows, nbytes = self._read_arenas(
-                lambda a: a[b.row0:b.row0 + b.size_used])
-            if sp is not None:
-                sp.set(block=b.id, rows=b.size_used, bytes=nbytes)
-        return rows
-
-    def _read_rows(self, row_map, n):
-        """One slot's KV rows ``[0:n)`` off the live arena, per layer,
-        and the bytes of arena brought to the host for them."""
-        idx = np.asarray(row_map[:n], dtype=np.int64)
-        return self._read_arenas(lambda a: a[idx])
-
     def _park_victim(self, req):
         """Pick and park one decode-mode victim to free blocks for
         ``req``. Policy is a seam (tests shuffle it); the default preempts
@@ -1262,84 +1015,73 @@ class _ModelEntry:
             return False
         req = st.request
         m = self._model
-        if st.mode == "spec":
-            # no target arena rows: the park is pure host state. The
-            # draft-KV footprint (if any) is released; resume falls back
-            # to replay proposals — same committed tokens either way.
-            with profiler.RecordEvent("decode::spill"):
-                faults.fire("decode.spill")
-                self._release_draft_locked(st)
-            self._slots[s] = None
-            self._pool.release(s)
-            # lockdep: ok(single writer: the scheduler thread; submit-side readers only probe emptiness (GIL-atomic) and tolerate staleness)
-            self._parked.append(_ParkedSession(req, "spec", [st], []))
-            self._metrics.incr("sessions_parked")
-            return True
-        need = (st.plen + req.max_new + m.block_size - 1) // m.block_size
-        if need > m.num_blocks:
+        # a spec session holds no target arena rows: its park is pure
+        # host state. Its draft-KV footprint (if any) is released; resume
+        # falls back to replay proposals — same committed tokens either way
+        if (st.mode == "decode" and -(-(st.plen + req.max_new)
+                                      // m.block_size) > m.num_blocks):
             return False
-        key = f"park:{req.id}:0"
-        with profiler.RecordEvent("decode::spill") as ev:
-            faults.fire("decode.spill")
-            rows, nbytes = self._read_rows(st.row_map, st.cursor)
-            if ev.span is not None:
-                ev.span.set(bytes=nbytes)
-            toks = (list(req.prompt) + list(st.generated))[:st.cursor]
-            if not self._tier.put(key, rows, st.cursor, tokens=toks):
-                return False
-        self._slots[s] = None
-        self._pool.release(s)
-        self._blocks.release(st.blocks)
-        st.blocks = []
-        self._release_draft_locked(st)
+        keys = self._spill(req, [st])
+        if keys is None:
+            return False
+        self._vacate(s)
         # lockdep: ok(single writer: the scheduler thread; submit-side readers only probe emptiness (GIL-atomic) and tolerate staleness)
-        self._parked.append(_ParkedSession(req, "decode", [st], [key]))
+        self._parked.append(_ParkedSession(req, st.mode, [st], keys))
         self._metrics.incr("sessions_parked")
         return True
+
+    def _spill(self, req, states):
+        """Every state's rows ``[0:cursor)`` to the host tier, rank-keyed,
+        under one ``decode::spill`` span: the keys, or None (and nothing
+        kept) where the tier cannot take them."""
+        keys, spilled = [], 0
+        with profiler.RecordEvent("decode::spill") as ev:
+            faults.fire("decode.spill")
+            for rank, st in enumerate(states):
+                if st.kv is None:
+                    continue
+                key, nbytes = self.kv.spill(st.kv, st.cursor, req.id, rank,
+                                            self._committed(st))
+                spilled += nbytes
+                if ev.span is not None:
+                    ev.span.set(bytes=spilled)
+                if key is None:
+                    self.kv.drop_spilled(keys)
+                    return None
+                keys.append(key)
+        return keys
+
+    def _vacate(self, slot):
+        """Empty a batch slot: the slot, its sequence's blocks (with what
+        is left of its reservation) and its draft footprint given back.
+        Returns the state that held it."""
+        st = self._slots[slot]
+        self._slots[slot] = None
+        self._pool.release(slot)
+        if st is not None:
+            self.kv.release(st.kv)
+            self._release_draft(st)
+        return st
 
     def _park_group(self, group):
         """Preempt a whole beam group: every live hypothesis spills its
         rows (rank-keyed), the group releases ALL its slots (spares
         included), and resume rebuilds ``order`` in the same rank order —
         selection tie-breaking stays bit-identical."""
-        req = group.request
         m = self._model
-        live = [(sid, self._slots[sid]) for sid in group.order]
-        need = sum((st.cursor + m.block_size - 1) // m.block_size
-                   for _, st in live)
-        if need > m.num_blocks:
+        states = [self._slots[sid] for sid in group.order]
+        if sum(-(-st.cursor // m.block_size) for st in states) > m.num_blocks:
             return False
-        keys = []
-        spilled = 0
-        with profiler.RecordEvent("decode::spill") as ev:
-            faults.fire("decode.spill")
-            for rank, (sid, st) in enumerate(live):
-                key = f"park:{req.id}:{rank}"
-                rows, nbytes = self._read_rows(st.row_map, st.cursor)
-                spilled += nbytes
-                if ev.span is not None:
-                    ev.span.set(bytes=spilled)
-                toks = (list(req.prompt) + list(st.generated))[:st.cursor]
-                if not self._tier.put(key, rows, st.cursor, tokens=toks):
-                    for k in keys:
-                        # lockdep: ok(HostKVTier is internally locked — decode.tier, a leaf under decode.blocks)
-                        self._tier.discard(k)
-                    return False
-                keys.append(key)
-        states = []
-        for sid, st in live:
-            self._slots[sid] = None
-            self._pool.release(sid)
-            self._blocks.release(st.blocks)
-            st.blocks = []
-            states.append(st)
-        for sid in group.spare:
-            self._pool.release(sid)
+        keys = self._spill(group.request, states)
+        if keys is None:
+            return False
+        for sid in group.order + group.spare:
+            self._vacate(sid)
         group.spare = []
         group.order = []
         # lockdep: ok(single writer: the scheduler thread; submit-side readers only probe emptiness (GIL-atomic) and tolerate staleness)
         self._parked.append(
-            _ParkedSession(req, "beam", states, keys, group=group))
+            _ParkedSession(group.request, "beam", states, keys, group=group))
         self._metrics.incr("sessions_parked")
         return True
 
@@ -1373,22 +1115,14 @@ class _ModelEntry:
         return progressed
 
     def _drop_parked(self, ps, error):
-        for key in ps.keys:
-            # lockdep: ok(HostKVTier is internally locked — decode.tier, a leaf under decode.blocks)
-            self._tier.discard(key)
-        self._engine._tenant_unflight(ps.request.tenant)
-        self._metrics.incr("deadline_missed"
-                           if isinstance(error, DeadlineExceededError)
-                           else "failed")
-        ps.request.response._complete(error=error)
-        self._metrics.observe_request(ps.request)
+        self.kv.drop_spilled(ps.keys)
+        self._reject_in_flight(ps.request, error)
 
     def _resume_session(self, ps):
         """Re-admit one parked session. Returns False when capacity is
         still insufficient (caller retries next iteration); True when the
         session left the parked list — resumed, or terminally failed via
         an arena loss during re-injection."""
-        m = self._model
         if ps.mode == "spec":
             s = self._pool.acquire()
             if s is None:
@@ -1398,156 +1132,46 @@ class _ModelEntry:
                 self._slots[s] = ps.states[0]
             self._metrics.incr("sessions_resumed")
             return True
-        if ps.mode == "decode":
-            st = ps.states[0]
-            s = self._pool.acquire()
-            if s is None:
-                return False
-            blocks = self._blocks.acquire_rows(st.cursor)
-            if blocks is None:
-                self._pool.release(s)
-                return False
-            st.blocks = blocks
-            st.shared_len = 0
-            self._rebuild_row_map(st)
-            self._slots[s] = st
-            with profiler.RecordEvent("decode::resume"):
-                faults.fire("decode.resume")
-                ok = self._inject_rows(st, ps.keys[0])
-            if not ok:
-                return True     # arena lost; session rejected with the rest
-            self._metrics.incr("sessions_resumed")
-            return True
-        # beam: all live hypotheses come back together, in rank order
-        group = ps.group
+        # every live hypothesis comes back together, in rank order (a
+        # decode session is one): slots and fresh chains for all, or none
         got = []
-        ok = True
         for st in ps.states:
             s = self._pool.acquire()
-            blocks = (self._blocks.acquire_rows(st.cursor)
-                      if s is not None else None)
-            if s is None or blocks is None:
+            kv = self.kv.acquire_rows(st.cursor) if s is not None else None
+            if kv is None:
                 if s is not None:
                     self._pool.release(s)
-                ok = False
-                break
-            got.append((s, st, blocks))
-        if not ok:
-            for s, st, blocks in got:
-                self._pool.release(s)
-                self._blocks.release(blocks)
-            return False
-        group.order = []
-        for s, st, blocks in got:
-            st.blocks = blocks
-            st.shared_len = 0
-            self._rebuild_row_map(st)
+                for s, st in got:
+                    self._pool.release(s)
+                    self.kv.release(st.kv)
+                return False
+            st.kv = kv
+            got.append((s, st))
+        for s, st in got:
             self._slots[s] = st
-            group.order.append(s)
-        # re-establish the group's width reservation, best-effort: forks
-        # need spares, and admission must not steal them back first
-        while len(group.order) + len(group.spare) < group.width:
-            sid = self._pool.acquire()
-            if sid is None:
-                break
-            group.spare.append(sid)
+        group = ps.group
+        if group is not None:
+            group.order = [s for s, _st in got]
+            self._claim_spares(group)
         with profiler.RecordEvent("decode::resume"):
             faults.fire("decode.resume")
-            for rank, (s, st, blocks) in enumerate(got):
-                if not self._inject_rows(st, ps.keys[rank]):
-                    for key in ps.keys:
-                        # lockdep: ok(HostKVTier is internally locked — decode.tier, a leaf under decode.blocks)
-                        self._tier.discard(key)
-                    return True     # arena lost; group rejected with the rest
+            for (_s, st), key in zip(got, ps.keys):
+                try:
+                    self.kv.restore(
+                        st.kv, key, st.cursor,
+                        lambda: self.run_prefill(self._committed(st))[1:])
+                except ArenaInvalidError as e:
+                    # the session was rejected with every other slot
+                    self._arena_lost(f"resume inject failure: {e}")
+                    self.kv.drop_spilled(ps.keys)
+                    return True
         self._metrics.incr("sessions_resumed")
         return True
 
-    def _inject_feeds(self, inj_rows, pieces):
-        """The inject program's feeds from HOST rows: ``pieces`` lists
-        ``(position, kv)``, ``kv[i]`` the ``(k, v)`` rows of layer ``i``
-        that belong at that position onward; each layer's rows are padded
-        to the program's ``[1, L, H]`` (``inj_rows`` says which of them
-        land anywhere)."""
-        m = self._model
-        inj = {DecodeModel.INJ_ROWS: inj_rows}
-        for i, names in enumerate(m.inject_kv_feeds):
-            for j, name in enumerate(names):
-                arr = np.zeros((1, m.max_len, m.hidden), "float32")
-                for p, kv in pieces:
-                    rows = kv[i][j]
-                    arr[0, p:p + len(rows)] = rows
-                inj[name] = arr
-        return inj
-
-    def _inject_rows(self, st, key):
-        """Re-inject a resumed session's KV rows ``[0:cursor)``. The tier
-        entry is consumed if present and CRC-clean; otherwise (evicted or
-        quarantined) the rows are RECOMPUTED from the committed tokens —
-        byte-identical, because a causal KV row is a pure function of its
-        token prefix. Returns False on arena loss (the donated inject
-        failed; ``_arena_lost`` already rejected every slot, this session
-        included)."""
-        m = self._model
-        n = st.cursor
-        # lockdep: ok(HostKVTier is internally locked — decode.tier, a leaf under decode.blocks)
-        ent = self._tier.pop(key)
-        if ent is not None and ent.size_used == n:
-            kv = ent.kv_rows
-        else:
-            toks = (list(st.request.prompt) + list(st.generated))[:n]
-            fetches = self._run("prefill", self._prefill_feeds(toks))
-            kvr = [self._fetch(f) for f in fetches[1:]]
-            kv = [(kvr[2 * i][0, :n], kvr[2 * i + 1][0, :n])
-                  for i in range(len(m.state_names))]
-            self._metrics.incr("resume_replays")
-        inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
-        inj_rows[:n] = st.row_map[:n]
-        try:
-            self._run("inject", self._inject_feeds(inj_rows, [(0, kv)]))
-        except Exception as e:
-            self._arena_lost(f"resume inject failure: {e}")
-            return False
-        return True
-
-    def _restore_from_tier(self, st):
-        """Chunked admission's host-tier fast path: contiguous full
-        prompt blocks just past the radix-shared prefix whose rows were
-        written back at eviction re-INJECT instead of re-running chunk
-        prefill — prefix-cache reach is bounded by host RAM, not HBM.
-        Returns the prompt position covered through (0 = no extension);
-        only applies from a block boundary, since a shared partial tail
-        already occupies the next block index."""
-        m = self._model
-        bs = m.block_size
-        if st.shared_len % bs != 0:
-            return 0
-        prompt = st.request.prompt
-        hashes = block_hashes(prompt, bs)
-        start = st.shared_len // bs
-        ents = []
-        idx = start
-        while idx < len(hashes) and (idx + 1) * bs <= st.plen:
-            ent = self._tier.get("blk:" + hashes[idx])
-            if ent is None or ent.size_used != bs:
-                break
-            ents.append(ent)
-            idx += 1
-        if not ents:
-            return 0
-        lo, hi = start * bs, idx * bs
-        inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
-        inj_rows[lo:hi] = st.row_map[lo:hi]
-        inj = self._inject_feeds(inj_rows, [
-            (lo + j * bs, ent.kv_rows) for j, ent in enumerate(ents)])
-        try:
-            with profiler.RecordEvent("decode::inject") as ev:
-                if ev.span is not None:
-                    ev.span.set(request=st.request.id)
-                self._run("inject", inj, ev.span)
-        except Exception as e:
-            raise _ArenaInvalidError(str(e)) from e
-        self._metrics.incr("tier_hits", len(ents))
-        return hi
+    @staticmethod
+    def _committed(st):
+        """The tokens whose K/V rows a session holds: ``[0:cursor)``."""
+        return (list(st.request.prompt) + list(st.generated))[:st.cursor]
 
     # -- brownout ----------------------------------------------------------
     def _brownout_tick(self):
@@ -1555,7 +1179,7 @@ class _ModelEntry:
         saturates while anything is parked or deferred — the arena is
         over-subscribed even if the instantaneous row count dipped.
         Returns whether the ladder moved."""
-        occ = self._blocks.stats()["occupancy"]
+        occ = self.kv.occupancy()
         if self._parked or self._pending:
             occ = 1.0
         qp = self._queue.pressure()
@@ -1584,7 +1208,7 @@ class _ModelEntry:
         if self._parked or self._pending:
             return True
         try:
-            occ = self._blocks.stats()["occupancy"]
+            occ = self.kv.occupancy()
         except Exception:
             occ = 0.0
         qp = self._queue.pressure()
@@ -1592,7 +1216,6 @@ class _ModelEntry:
         return live >= self._brownout.exit[self._brownout.level - 1]
 
     def _prefill_into(self, req, slot):
-        m = self._model
         req.dispatch_time = time.perf_counter()
         self._admit_seq += 1
         # brownout L1/L2: shed OUTPUT-INVISIBLE quality first — committed
@@ -1605,8 +1228,7 @@ class _ModelEntry:
             # prefill. With draft_kv the proposals get their own slot +
             # blocks on the DRAFT entry (O(1) per proposed token);
             # admission failure there degrades to replay proposals.
-            st = _Slot(req, mode="spec")
-            st.seq = self._admit_seq
+            st = _Slot(req, "spec", self._admit_seq)
             st.toks = list(req.prompt)
             st.sampling = req.sampling
             if req.grammar is not None:
@@ -1622,25 +1244,18 @@ class _ModelEntry:
         prompt = req.prompt
         plen = len(prompt)
         if self._takes_chunks(req):
-            blocks, shared_len, reserve = self._acquire_blocks(req)
-            st = _Slot(req, mode="prefill")
-            st.seq = self._admit_seq
-            st.blocks = blocks
-            st.reserve = reserve
-            st.shared_len = shared_len
+            st = _Slot(req, "prefill", self._admit_seq)
+            st.kv = self._acquire_blocks(req)
             # the FINAL chunk always runs (it produces the last-position
-            # logits), even when the radix served every block
-            st.done = min(shared_len, plen - 1)
-            self._rebuild_row_map(st)
-            restored = self._restore_from_tier(st)
-            if restored > st.done:
-                st.done = min(restored, plen - 1)
+            # logits), even when the radix served every block, or the
+            # host tier the blocks past them
+            st.done = min(max(st.kv.shared_len, self.kv.restore_prefix(
+                st.kv, prompt, req.id)), plen - 1)
             self._slots[slot] = st
             self._metrics.incr("admitted")
             self._metrics.tenant_incr("admitted", req.tenant)
             return
-        key = prompt_key(prompt)
-        cached = self._prefix.get(key)
+        key, cached = self.kv.prefix_get(prompt)
         fetches = None
         if cached is not None:
             # hit/miss totals live on PrefixCache (one source, surfaced
@@ -1656,56 +1271,31 @@ class _ModelEntry:
                 faults.fire("decode.prefill")
                 if ev.span is not None:
                     ev.span.set(request=req.id, prompt_len=plen)
-                fetches = self._run("prefill", self._prefill_feeds(prompt),
-                                    ev.span)
+                fetches = self.run_prefill(prompt, ev.span)
         try:
-            blocks, shared_len, reserve = self._acquire_blocks(req)
+            kv = self._acquire_blocks(req)
         except _DeferAdmission:
             if fetches is not None:
                 # the retry finds the prompt in the prefix cache
                 self._prefill_to_host(req, key, fetches)
             raise
-        st = _Slot(req, mode="decode")
-        st.seq = self._admit_seq
-        st.blocks = blocks
-        st.reserve = reserve
-        st.shared_len = shared_len
-        self._rebuild_row_map(st)
-        if shared_len < plen:
+        st = _Slot(req, "decode", self._admit_seq)
+        st.kv = kv
+        if kv.shared_len < plen:
             # inject ONLY the non-shared suffix: shared blocks already
             # hold byte-identical rows (same tokens -> same prefix ->
-            # same KV bytes)
-            inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
-            inj_rows[shared_len:plen] = st.row_map[shared_len:plen]
-            if fetches is not None:
-                inj = {DecodeModel.INJ_ROWS: inj_rows}
-                for i, (kn, vn) in enumerate(m.inject_kv_feeds):
-                    inj[kn] = fetches[1 + 2 * i]
-                    inj[vn] = fetches[2 + 2 * i]
-            else:
-                inj = self._inject_feeds(inj_rows,
-                                         [(0, _by_layer(cached[0]))])
-            try:
-                with profiler.RecordEvent("decode::inject") as ev:
-                    faults.fire("decode.inject")
-                    if ev.span is not None:
-                        ev.span.set(request=req.id)
-                    self._run("inject", inj, ev.span)
-            except Exception as e:
-                raise _ArenaInvalidError(str(e)) from e
+            # same KV bytes). A miss's rows never leave the device
+            self.kv.write_rows(
+                kv, kv.shared_len, plen,
+                fetches[1:] if fetches is not None else cached[0],
+                "decode::inject", fault="decode.inject", request=req.id)
             if fetches is not None:
                 self._metrics.incr("prefill_device_injects")
         if fetches is not None:
             cached = self._prefill_to_host(req, key, fetches)
             self._metrics.observe_prefill(time.perf_counter() - t0)
         live, logits_row = cached
-
-        def host_rows(start, stop):
-            return [(np.array(k[start:stop]), np.array(v[start:stop]))
-                    for k, v in _by_layer(live)]
-
-        self._blocks.register_prompt_blocks(blocks, prompt,
-                                            host_rows=host_rows)
+        self.kv.register(kv, prompt, live)
         st.cursor = plen
         self._slots[slot] = st
         self._metrics.incr("admitted")
@@ -1714,19 +1304,29 @@ class _ModelEntry:
             st.mode = "beam"
             self._begin_beam(slot, logits_row)
             return
+        self._first_token(slot, st, logits_row)
+
+    def _first_token(self, slot, st, logits_row):
+        """A decode slot's first token, off its prompt's last logits row:
+        chosen, stamped and counted, and the slot retired where that token
+        ends the request. Returns the stamp, or None when it retired."""
+        req = st.request
         st.sampling = req.sampling
         if req.grammar is not None:
             st.grammar = GrammarConstraint(req.grammar)
         first = self._choose_token(st, logits_row, device_masked=False)
         st.last_token = first
         st.generated = [first]
-        req.response.token_times.append(time.perf_counter())
+        now = time.perf_counter()
+        req.response.token_times.append(now)
         # the prefill's first token: counted apart from generated_tokens
         # so tokens_per_step stays a decode-step quantity (<= S)
         self._metrics.incr("prefill_tokens")
         self._metrics.tenant_incr("tokens", req.tenant)
         if self._finished(st):
             self._retire(slot)
+            return None
+        return now
 
     def _prefill_feeds(self, prompt):
         m = self._model
@@ -1736,6 +1336,23 @@ class _ModelEntry:
         return {DecodeModel.PRE_TOKENS: toks,
                 DecodeModel.PRE_POSITIONS: pos,
                 DecodeModel.PRE_BIAS: self._causal_bias}
+
+    def prefill_logits(self, tokens, event=None):
+        """The ``[L, V]`` logits of `run_prefill` over ``tokens``, on the
+        host; the launch alone under a span named ``event``."""
+        if event is None:
+            fetches = self.run_prefill(tokens)
+        else:
+            with profiler.RecordEvent(event):
+                fetches = self.run_prefill(tokens)
+        return self._fetch(fetches[0])[0]
+
+    def run_prefill(self, tokens, span=None):
+        """The stateless whole-sequence forward over ``tokens``, launched:
+        its device outputs, the ``[1, L, V]`` logits first, then a ``[1,
+        L, H]`` K and V per state pair. Another entry's scheduler calls it
+        too, on its draft (replay proposals, a draft-KV admission)."""
+        return self._run("prefill", self._prefill_feeds(tokens), span)
 
     def _prefill_to_host(self, req, key, fetches):
         """The host's copy of a one-shot prefill, in two fetches: the
@@ -1751,7 +1368,7 @@ class _ModelEntry:
             live = self._stack_live(*fetches[1:])
             logits_row = self._fetch(row)
             live = self._fetch(live)
-            self._prefix.put(key, live, logits_row)
+            self.kv.prefix_put(key, live, logits_row)
             if sp is not None:
                 sp.set(request=req.id,
                        bytes=logits_row.nbytes + live.nbytes)
@@ -1770,12 +1387,10 @@ class _ModelEntry:
         stays on the device in ``self._chunk_rows`` until `_step` has
         launched the next step over it (`_land_chunks`); the slot stays
         in mode ``"prefill"`` till then. With a step in flight the chunk
-        is a launch AHEAD (``ahead=`` on ``decode::chunk``,
-        ``serving_chunk_launches_ahead_total``). Where no step is in
-        flight and no slot steps there is nothing to launch over it, and
-        the row is fetched at once; so is a beam request's, whose first
-        selection forks slots and copies arena rows (`_drain_reason`
-        drained for it)."""
+        is a launch AHEAD. Where no step is in flight and no slot steps
+        there is nothing to launch over it, and the row is fetched at
+        once; so is a beam request's, whose first selection forks slots
+        and copies arena rows (`_drain_reason` drained for it)."""
         m = self._model
         pref = [s for s, st in enumerate(self._slots)
                 if st is not None and st.mode == "prefill"
@@ -1798,7 +1413,7 @@ class _ModelEntry:
                 f"deadline expired during chunked prefill after "
                 f"{st.done}/{st.plen} tokens"), slot=s)
             return 1
-        C, L, R = m.chunk_tokens, m.max_len, m.rows
+        C, L = m.chunk_tokens, m.max_len
         start = st.done
         stop = min(start + C, st.plen)
         real = stop - start
@@ -1812,11 +1427,6 @@ class _ModelEntry:
         bias[0, :real] = np.where(
             np.arange(L)[None, :] <= (start + np.arange(real))[:, None],
             np.float32(0.0), np.float32(NEG_INF))
-        wrows = np.full((C,), R, dtype="int64")
-        for c in range(real):
-            p = start + c
-            if p >= st.shared_len:   # never rewrite radix-shared rows
-                wrows[c] = st.row_map[p]
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::chunk") as ev:
@@ -1828,8 +1438,10 @@ class _ModelEntry:
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
                     DecodeModel.CHU_BIAS: bias,
-                    DecodeModel.CHU_ROWS: st.row_map,
-                    DecodeModel.CHU_WRITE_ROWS: wrows,
+                    DecodeModel.CHU_ROWS: st.kv.row_map,
+                    # never rewrite radix-shared rows
+                    DecodeModel.CHU_WRITE_ROWS: st.kv.chunk_write_rows(
+                        start, stop, C),
                 }
                 if m.recurrent:
                     # the chunk at position 0 resets the slot's state
@@ -1866,9 +1478,8 @@ class _ModelEntry:
         token ends the request, or rejected where its deadline ran out
         meanwhile (finished wins over expired, as in `_sample`).
         ``deferred`` says whether a launch was made over the row since
-        its chunk's (``decode::chunk_fetch`` carries it): the fetch is
-        then of a buffer that is ready, or nearly, since the chunk ran
-        BEFORE that launch."""
+        its chunk's (``decode::chunk_fetch`` carries it): the buffer is
+        then ready, or nearly."""
         if not self._chunk_rows:
             return
         landing, self._chunk_rows = self._chunk_rows, []
@@ -1882,30 +1493,18 @@ class _ModelEntry:
                 if sp is not None:
                     sp.set(request=req.id, bytes=logits_row.nbytes,
                            deferred=deferred)
-            if not self._model.recurrent:
-                self._blocks.register_prompt_blocks(st.blocks, req.prompt)
+            self.kv.register(st.kv, req.prompt)
             st.cursor = st.plen
             if req.beam is not None:
                 st.mode = "beam"
                 try:
                     self._begin_beam(s, logits_row)
-                except _ArenaInvalidError as e:
+                except ArenaInvalidError as e:
                     self._arena_lost(f"beam fork inject failure: {e}")
                 continue
             st.mode = "decode"
-            st.sampling = req.sampling
-            if req.grammar is not None:
-                st.grammar = GrammarConstraint(req.grammar)
-            first = self._choose_token(st, logits_row, device_masked=False)
-            st.last_token = first
-            st.generated = [first]
-            now = time.perf_counter()
-            req.response.token_times.append(now)
-            self._metrics.incr("prefill_tokens")
-            self._metrics.tenant_incr("tokens", req.tenant)
-            if self._finished(st):
-                self._retire(s)
-            elif req.expired(now):
+            now = self._first_token(s, st, logits_row)
+            if now is not None and req.expired(now):
                 self._reject_in_flight(req, DeadlineExceededError(
                     "deadline expired mid-generation after 1 tokens"),
                     slot=s)
@@ -1947,8 +1546,8 @@ class _ModelEntry:
             # failure loses nothing but this cycle, so it is a
             # request-attributed failure — never a dead scheduler
             # thread, never an arena loss. (This also contains the
-            # cross-entry read: draft._run from this thread may race a
-            # draft-side breaker relaunch, whose builder contract makes
+            # cross-entry read: draft.run_prefill from this thread may race
+            # a draft-side breaker relaunch, whose builder contract makes
             # any observed executable content-identical — and any torn
             # state it could still surface lands here, on one request.)
             try:
@@ -1959,11 +1558,8 @@ class _ModelEntry:
                     props = []
                     dtoks = list(st.toks)
                     for _ in range(k):
-                        with profiler.RecordEvent("decode::spec_draft"):
-                            fetches = draft._run(
-                                "prefill", draft._prefill_feeds(dtoks))
-                        nxt = int(np.argmax(
-                            draft._fetch(fetches[0])[0, len(dtoks) - 1]))
+                        nxt = int(np.argmax(draft.prefill_logits(
+                            dtoks, "decode::spec_draft")[len(dtoks) - 1]))
                         props.append(nxt)
                         dtoks.append(nxt)
                     self._metrics.incr("spec_draft_steps", k)
@@ -1973,8 +1569,7 @@ class _ModelEntry:
                 t0 = time.perf_counter()
                 with profiler.RecordEvent("decode::spec_verify"):
                     faults.fire("decode.verify")
-                    fetches = self._run("prefill",
-                                        self._prefill_feeds(dtoks))
+                    fetches = self.run_prefill(dtoks)
             except Exception as e:
                 self._reject_in_flight(req, RequestError(
                     f"request {req.id} failed in speculative cycle: "
@@ -2031,91 +1626,46 @@ class _ModelEntry:
         """Give a speculative slot its own KV slot + blocks on the DRAFT
         entry and prefill the prompt into them ONCE; every later
         proposal is then one [S,1] draft decode step instead of a
-        whole-prompt replay. Draft blocks are deliberately never
-        radix-registered: the draft arena shares no partial tails, so
-        the proposal hot path can never trigger a COW there. Any
-        failure falls back to replay proposals (counted), never fails
-        the request."""
+        whole-prompt replay. Draft blocks are never radix-registered.
+        Any failure falls back to replay proposals (counted), never
+        fails the request."""
         if not draft._draft_ok or not draft._draft_pinned:
             return
         prompt = st.request.prompt
-        d_slot = None
-        blocks = None
+        seat = None
         try:
             with draft._draft_lock:
-                d_slot = draft._pool.acquire()
-                if d_slot is None:
-                    self._metrics.incr("spec_draft_kv_fallbacks")
-                    return
-                blocks, _shared = draft._blocks.acquire_for_prompt(prompt)
-                if blocks is None:
-                    draft._pool.release(d_slot)
+                seat = draft.draft_seat(st.request)
+                if seat is None:
                     self._metrics.incr("spec_draft_kv_fallbacks")
                     return
                 with profiler.RecordEvent("decode::spec_draft_prefill"):
-                    fetches = draft._run("prefill",
-                                         draft._prefill_feeds(prompt))
-                kv_rows = [draft._fetch(f) for f in fetches[1:]]
-                st.d_entry = draft
-                st.d_slot = d_slot
-                st.d_blocks = blocks
-                st.d_row_map = None
-                self._rebuild_draft_row_map(draft, st)
-                dm = draft.model
-                plen = len(prompt)
-                inj_rows = np.full((dm.max_len,), dm.rows, dtype="int64")
-                inj_rows[:plen] = st.d_row_map[:plen]
-                inj = {DecodeModel.INJ_ROWS: inj_rows}
-                for i, (kn, vn) in enumerate(dm.inject_kv_feeds):
-                    inj[kn] = kv_rows[2 * i]
-                    inj[vn] = kv_rows[2 * i + 1]
-                with profiler.RecordEvent("decode::spec_draft_inject"):
-                    draft._run("inject", inj)
-                st.d_cursor = plen
+                    outs = draft.run_prefill(prompt)
+                draft.kv.write_rows(
+                    seat[1], 0, len(prompt),
+                    draft.kv.host_rows(outs[1:], len(prompt)),
+                    "decode::spec_draft_inject")
+                st.d_entry, (st.d_slot, st.draft_kv) = draft, seat
+                st.d_cursor = len(prompt)
                 self._metrics.incr("spec_draft_kv_prefills")
         except Exception:
             # the inject is DONATED on the draft arena: poison the entry
             # (all draft-KV users revert to replay) rather than trusting
             # an undefined arena
             draft._draft_ok = False
-            if st.d_entry is draft:
-                st.d_entry = None
-                st.d_slot = None
-                st.d_blocks = None
-                st.d_row_map = None
-                st.d_cursor = 0
-            if blocks is not None:
-                draft._blocks.release(blocks)
-            if d_slot is not None:
-                draft._pool.release(d_slot)
+            if seat is not None:
+                draft.draft_unseat(*seat)
             self._metrics.incr("spec_draft_kv_fallbacks")
 
-    def _rebuild_draft_row_map(self, draft, st):
-        st.d_row_map, st.d_table = _map_blocks(draft.model, st.d_blocks,
-                                               st.d_row_map)
-
     def _release_draft(self, st):
-        """Return a spec slot's draft-side footprint (caller holds the
-        draft lock, or knows no other thread can touch this state)."""
+        """Return a spec slot's draft-side footprint (the draft lock is
+        re-entrant: a proposal cycle that holds it may call this)."""
         draft = st.d_entry
-        if draft is None:
-            return
-        if st.d_blocks:
-            draft._blocks.release(st.d_blocks)
-        if st.d_slot is not None:
-            draft._pool.release(st.d_slot)
-        st.d_entry = None
-        st.d_slot = None
-        st.d_blocks = None
-        st.d_row_map = None
-        st.d_cursor = 0
-
-    def _release_draft_locked(self, st):
-        draft = st.d_entry
-        if draft is None:
-            return
-        with draft._draft_lock:
-            self._release_draft(st)
+        if draft is not None:
+            with draft._draft_lock:
+                draft.draft_unseat(st.d_slot, st.draft_kv)
+            st.d_entry = st.d_slot = st.draft_kv = None
+            st.d_cursor = 0
 
     def _draft_propose_kv(self, st, draft, k):
         """Greedy draft proposals in O(1) decode steps per token from
@@ -2128,7 +1678,7 @@ class _ModelEntry:
         prefill invariant applied to the draft entry), or None to make
         the caller fall back to replay."""
         if not draft._draft_ok:
-            self._release_draft_locked(st)
+            self._release_draft(st)
             self._metrics.incr("spec_draft_kv_fallbacks")
             return None
         n = len(st.toks)
@@ -2158,43 +1708,62 @@ class _ModelEntry:
         return the [V] logits row. Returns None after releasing the
         draft footprint when the draft pool is exhausted or the draft
         arena died — the caller reverts to replay proposals."""
-        dm = draft.model
-        if write:
-            blocks, _nb, cow = draft._blocks.ensure_appendable(
-                st.d_blocks, p)
-            if blocks is None:
-                self._release_draft(st)
-                self._metrics.incr("spec_draft_kv_fallbacks")
-                return None
-            assert cow is None, "draft blocks are never radix-shared"
-            st.d_blocks = blocks
-            if _nb is not None:
-                self._rebuild_draft_row_map(draft, st)
-        step = dm.step_feed()
-        row = dm.rows       # write=False: the row is right already
-        if write:
-            b = st.d_blocks[p // dm.block_size]
-            row = b.row0 + p % dm.block_size
-        dm.fill_step(step, st.d_slot, p, st.d_table, row, int(token))
-        feeds = {DecodeModel.DEC_STEP: step,
-                 DecodeModel.DEC_TOKEN: draft._no_tokens}
-        if dm.logits_mask:
-            feeds[DecodeModel.DEC_MASK] = np.zeros(
-                (dm.slots, 1, dm.vocab_size), "float32")
         try:
-            with profiler.RecordEvent("decode::spec_draft_kv"):
-                fetches = draft._run("step", feeds)
+            opened = not write or draft.kv.open_block(st.draft_kv, p)
+            if opened:
+                row = draft.draft_step(st.d_slot, st.draft_kv, p, token,
+                                       write)
         except Exception:
             # donated call on the DRAFT arena failed: poison the draft
             # for every user; this request reverts to replay proposals
             draft._draft_ok = False
+            opened = False
+        if not opened:
             self._release_draft(st)
             self._metrics.incr("spec_draft_kv_fallbacks")
             return None
-        if write:
-            draft._blocks.note_append(st.d_blocks[p // dm.block_size])
         self._metrics.incr("spec_draft_kv_steps")
-        return draft._fetch(fetches[0])[st.d_slot, 0]
+        return row
+
+    # -- as a draft: called from a target's scheduler thread, under
+    # `_draft_lock`, while this entry is pinned (its own loop is idle) ------
+    def draft_seat(self, req):
+        """A batch slot and the prompt's blocks for a speculative request
+        of another entry: ``(slot, SeqKV)``, or None when either is out."""
+        slot = self._pool.acquire()
+        if slot is None:
+            return None
+        try:
+            kv = self.kv.acquire(req)
+        except RuntimeError:    # a prompt this pool can never hold
+            kv = None
+        if kv is None:
+            self._pool.release(slot)
+            return None
+        return slot, kv
+
+    def draft_unseat(self, slot, kv):
+        self.kv.release(kv)
+        self._pool.release(slot)
+
+    def draft_step(self, slot, kv, p, token, write):
+        """ONE decode step of this entry for ``slot`` alone: ``token`` at
+        position ``p``, its K/V row written where ``write`` (else the
+        row is right already). Returns the ``[V]`` logits row."""
+        m = self._model
+        step = m.step_feed()
+        m.fill_step(step, slot, p, kv.table,
+                    kv.row_of(p) if write else m.rows, int(token))
+        feeds = {DecodeModel.DEC_STEP: step,
+                 DecodeModel.DEC_TOKEN: self._no_tokens}
+        if m.logits_mask:
+            feeds[DecodeModel.DEC_MASK] = np.zeros(
+                (m.slots, 1, m.vocab_size), "float32")
+        with profiler.RecordEvent("decode::spec_draft_kv"):
+            fetches = self._run("step", feeds)
+        if write:
+            self.kv.note_append(kv, p)
+        return self._fetch(fetches[0])[slot, 0]
 
     # -- the decode iteration ---------------------------------------------
     def _arena_lost(self, why):
@@ -2223,18 +1792,6 @@ class _ModelEntry:
                                    slot=s)
         for g in groups:
             self._reject_beam_group(g, make_error(g.request))
-
-    def _apply_cow(self, st, cow):
-        """Copy-on-write landed a fresh block: re-inject the shared
-        partial's retained host rows into it, then remap the slot."""
-        m = self._model
-        u = cow.size_used
-        inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
-        inj_rows[:u] = cow.block.row0 + np.arange(u)
-        with profiler.RecordEvent("decode::cow_inject"):
-            self._run("inject",
-                      self._inject_feeds(inj_rows, [(0, cow.host_rows)]))
-        self._rebuild_row_map(st)
 
     # -- generation policy (host-side selection over fetched logits) ------
     def _choose_token(self, st, logits_row, device_masked):
@@ -2277,24 +1834,28 @@ class _ModelEntry:
         if req.grammar is not None:
             st.grammar = GrammarConstraint(req.grammar)
         group.order = [s]
-        # claim the rest of the group's row reservation up front (the
-        # admission round budgeted width rows for this pick)
-        for _ in range(group.width - 1):
-            sid = self._pool.acquire()
-            if sid is None:
-                break
-            group.spare.append(sid)
+        # the admission round budgeted width rows for this pick
+        self._claim_spares(group)
         self._metrics.incr("beam_requests")
         try:
             row = np.asarray(logits_row, dtype=np.float32).reshape(-1)
             if st.grammar is not None:
                 row = row + st.grammar.mask()
             self._commit_beam_selection(group, [row], time.perf_counter())
-        except _ArenaInvalidError:
+        except ArenaInvalidError:
             raise               # admission's arena handler owns cleanup
         except Exception as e:
             self._reject_beam_group(group, RequestError(
                 f"request {req.id} failed in first beam selection: {e}"))
+
+    def _claim_spares(self, group):
+        """The rest of the group's width reservation, best-effort: forks
+        need spares, and admission must not take them first."""
+        while len(group.order) + len(group.spare) < group.width:
+            sid = self._pool.acquire()
+            if sid is None:
+                break
+            group.spare.append(sid)
 
     def _commit_beam_selection(self, group, rows, now):
         """ONE beam step's bookkeeping: run the committed selection rule
@@ -2342,7 +1903,7 @@ class _ModelEntry:
             else:
                 try:
                     child = self._fork_beam(group, live[p], t, sc)
-                except _ArenaInvalidError:
+                except ArenaInvalidError:
                     raise
                 except Exception as e:
                     self._reject_beam_group(group, RequestError(
@@ -2357,58 +1918,33 @@ class _ModelEntry:
             if st.grammar is not None:
                 st.grammar.advance(t)
         group.order = new_order
-        self._blocks.check_conservation()
+        self.kv.pool.check_conservation()
         if len(group.finished) >= group.width or not new_order:
             self._retire_beam(group)
             return False
         return True
 
     def _fork_beam(self, group, parent, token, score):
-        """COW-fork one live hypothesis: second owner on the parent's
-        full blocks, a private tail block filled by a device row copy
-        (arena scope read -> inject), and a fresh slot carrying the
-        forked host state."""
-        m = self._model
-        child_blocks, nb, src = self._blocks.fork_blocks(
-            parent.blocks, parent.cursor)
-        if child_blocks is None:
-            raise RuntimeError("block pool exhausted forking a beam")
+        """COW-fork one live hypothesis: a fresh slot carrying the forked
+        host state, over a second owner of the parent's rows
+        (`KVStore.fork`)."""
         slot = group.spare.pop() if group.spare else self._pool.acquire()
         if slot is None:
-            self._blocks.release(child_blocks)
             raise RuntimeError("slot pool exhausted forking a beam")
-        if nb is not None:
-            u = nb.size_used
-            inj_rows = np.full((m.max_len,), m.rows, dtype="int64")
-            inj_rows[:u] = nb.row0 + np.arange(u)
-            inj = {DecodeModel.INJ_ROWS: inj_rows}
-            for i, (kn_s, vn_s) in enumerate(m.state_names):
-                kn, vn = m.inject_kv_feeds[i]
-                karr = np.zeros((1, m.max_len, m.hidden), "float32")
-                varr = np.zeros((1, m.max_len, m.hidden), "float32")
-                karr[0, :u] = np.asarray(
-                    self._scope.find_var(kn_s))[src.row0:src.row0 + u]
-                varr[0, :u] = np.asarray(
-                    self._scope.find_var(vn_s))[src.row0:src.row0 + u]
-                inj[kn] = karr
-                inj[vn] = varr
-            try:
-                with profiler.RecordEvent("decode::beam_fork_inject"):
-                    self._run("inject", inj)
-            except Exception as e:
-                raise _ArenaInvalidError(str(e)) from e
         st = _Slot(group.request, mode="beam")
+        try:
+            st.kv = self.kv.fork(parent.kv, parent.cursor)
+        except Exception:
+            group.spare.append(slot)    # the group's, till it is rejected
+            raise
         st.beam = group
-        st.blocks = child_blocks
         st.plen = parent.plen
-        st.shared_len = parent.shared_len
         st.cursor = parent.cursor
         st.last_token = int(token)
         st.generated = parent.generated + [int(token)]
         st.score = score
         if parent.grammar is not None:
             st.grammar = parent.grammar.fork().advance(token)
-        self._rebuild_row_map(st)
         self._slots[slot] = st
         return slot
 
@@ -2419,8 +1955,7 @@ class _ModelEntry:
             st.beam.spare.append(sid)   # keep the group's reservation
         else:
             self._pool.release(sid)
-        if st.blocks:
-            self._blocks.release(st.blocks)
+        self.kv.release(st.kv)
 
     def _release_group_slots(self, group):
         for sid, st in enumerate(self._slots):
@@ -2433,28 +1968,20 @@ class _ModelEntry:
     def _retire_beam(self, group):
         self._release_group_slots(group)
         req = group.request
-        self._engine._tenant_unflight(req.tenant)
         ranked = beam_finished_ranking(group.finished)
         if not ranked:
-            req.response._complete(error=RequestError(
+            self._reject_in_flight(req, RequestError(
                 f"request {req.id}: beam search finished no hypothesis"))
-            self._metrics.incr("failed")
-            self._metrics.observe_request(req)
             return
         # the best hypothesis may have finished selections ago: its
         # tokens' stamps are the first len(tokens) selections'
         del req.response.token_times[len(ranked[0][0]):]
-        req.response._complete(outputs={
+        self._metrics.incr("beam_finished", len(ranked))
+        self._complete(req, {
             "tokens": np.asarray(ranked[0][0], dtype="int64"),
             "beams": [{"tokens": np.asarray(t, dtype="int64"),
                        "score": float(sc)} for t, sc in ranked],
         })
-        self._metrics.incr("completed")
-        self._metrics.incr("retired")
-        self._metrics.incr("beam_finished", len(ranked))
-        self._metrics.tenant_incr("completed", req.tenant)
-        self._metrics.observe_request(req)
-        self._metrics.observe_tokens(req)
 
     def _reject_beam_group(self, group, error):
         """Fail one beam request as a UNIT: release every slot the group
@@ -2463,15 +1990,8 @@ class _ModelEntry:
         admitting request's handler — the done() guard keeps the
         write-once future honest.)"""
         self._release_group_slots(group)
-        req = group.request
-        if req.response.done():
-            return
-        self._engine._tenant_unflight(req.tenant)
-        self._metrics.incr(
-            "deadline_missed" if isinstance(error, DeadlineExceededError)
-            else "failed")
-        req.response._complete(error=error)
-        self._metrics.observe_request(req)
+        if not group.request.response.done():
+            self._reject_in_flight(group.request, error)
 
     def _tokens_suffice(self, active, groups):
         """Whether this step's host half needs nothing but the
@@ -2503,35 +2023,22 @@ class _ModelEntry:
 
     def _step(self):
         """One decode iteration: feeds and launch, then the ONE fetch of
-        a step and its host half, in one of two orders, then the logits
-        row of a prompt's last chunk that was launched before it.
+        a step and its host half, in one of `_iterate`'s two orders, then
+        the logits row of a prompt's last chunk that was launched before
+        it (`_land_chunks`: the device ran that chunk BEFORE the step just
+        launched, so the fetch finds it ready or nearly, and the slot it
+        starts steps with the NEXT launch, its token from the host).
 
         The cursor half of the host's work (`_advance_cursors`) runs as
         soon as the launch has returned. A step that `_tokens_suffice`
-        and that steps no grammar is then left IN FLIGHT. If one was in
-        flight already, this is the launch AHEAD of its fetch: the feeds
-        were built from cursors that count it, its ``[S, 1]`` tokens are
-        this step's token feed without leaving the device (a slot that
-        was not in it, new since, is fed its own from the host), and it
-        is fetched and delivered (`_land`) only now, under the step just
-        launched. Any other step (a sampled slot, a beam group, a
-        grammar, a model without ``token_fetch``) lands in this same
-        body, as every step once did. A step in flight that this one
-        cannot follow (`_step_feeds` names why), or that nothing
-        follows, is drained first.
-
-        Last, what lies behind the launch: every last chunk's row
-        (`_land_chunks`). The device ran that chunk BEFORE the step just
-        launched, so the fetch finds it ready or nearly, and the slot it
-        starts steps with the NEXT launch, its token from the host.
-
-        The fetch (``decode::step_fetch``, where the host waits for the
-        device unless the step has finished under its successor) brings
-        the ``[S, 1]`` tokens, or the ``[S, 1, V]`` float32 logits,
-        counted in ``serving_decode_logits_fetch_steps_total``; the span
-        says which (``rows="tokens"|"logits"``). Nothing is sliced out
-        of the device array: that would dispatch, and could compile,
-        inside a serving window."""
+        and that steps no grammar is then left IN FLIGHT; if one was in
+        flight already, it is fetched and delivered (`_land`) only now,
+        under the step just launched, whose token feed is its ``[S, 1]``
+        output without leaving the device. Any other step lands in this
+        same body, as every step once did. A step in flight that this one
+        cannot follow (`_step_feeds` names why), or that nothing follows,
+        is drained first. Nothing is sliced out of a device array: that
+        would dispatch, and could compile, inside a serving window."""
         built = self._traced_feeds()
         if isinstance(built, str):
             self._drain(built)
@@ -2595,9 +2102,9 @@ class _ModelEntry:
         positions that count this step whether or not its tokens have
         been read. Who steps again follows (`_steps_again`). A beam
         group's cursors move with its selection, in `_sample`."""
-        bs = self._model.block_size
+        note_append = self.kv.note_append
         for st in states:
-            self._blocks.note_append(st.blocks[st.cursor // bs])
+            note_append(st.kv, st.cursor)
             st.cursor += 1
             st.ahead += 1
 
@@ -2665,25 +2172,20 @@ class _ModelEntry:
         nothing to step (or the arena was lost making a cursor
         writable). ONE host array, ``dec_step`` (`DecodeModel.step_feed`
         / `fill_step`): a stepping slot's token, cursor, length, write
-        row and block table, ~70 integers; the step program makes the
-        bias and the row map of them on the device, and no ``[S, L]``
-        array is built here. ``dec_token`` is a device array either
+        row and block table; the step program makes the bias and the row
+        map of them on the device. ``dec_token`` is a device array either
         way: zeros that nobody reads, or with a step in flight (the
         cursors count it already, a slot it finishes is left out) that
-        step's ``[S, 1]`` output itself, every stepping slot's token -1:
-        rows of slots that do not step are ignored as ever (their write
-        row is the sentinel, their length 0, so their bias all
-        ``NEG_INF``). A slot that was NOT in that step (new since: its
-        first token came off a prompt's last chunk, ``ahead`` 0) is fed
-        its token from the host in the same array, beside the others'
-        -1. That holds only if such a slot needs nothing but its token
-        (`_rides_ahead`) and no slot has to be parked; else nothing is
-        built and the reason to drain comes back as a string: ``"slots"``
-        for a new slot that samples, masks a grammar or is a beam
-        hypothesis (its step brings the logits over and lands in the body
-        that launched it), ``"park"``. What the loop has done before is
-        done again unchanged after the drain: a block opened stays
-        opened."""
+        step's ``[S, 1]`` output itself, every stepping slot's token -1;
+        rows of slots that do not step are ignored as ever. A slot that
+        was NOT in that step (new since, ``ahead`` 0) is fed its token
+        from the host in the same array. That holds only if such a slot
+        needs nothing but its token (`_rides_ahead`) and no slot has to be
+        parked; else nothing is built and the reason to drain comes back
+        as a string: ``"slots"`` for a new slot that samples, masks a
+        grammar or is a beam hypothesis, ``"park"``. What the loop has
+        done before is done again unchanged after the drain: a block
+        opened stays opened."""
         m = self._model
         S = m.slots
         step = m.step_feed()
@@ -2704,13 +2206,14 @@ class _ModelEntry:
                         return "slots"
                 elif not self._steps_again(st):
                     continue
-            # make the cursor position writable: allocate a fresh block
-            # when it opens a new chunk, COW when it lands in a SHARED
-            # partial tail (divergence), unregister an exclusively-owned
-            # partial before mutating it
+            # make the cursor position writable
             try:
-                blocks, _nb, cow = self._blocks.ensure_appendable(
-                    st.blocks, st.cursor, promised=st.reserve > 0)
+                opened = self.kv.open_block(st.kv, st.cursor)
+            except ArenaInvalidError as e:
+                # the COW re-inject is a DONATED call: its failure
+                # invalidates the whole arena, not one request
+                self._arena_lost(f"copy-on-write inject failure: {e}")
+                return None
             except RuntimeError as e:
                 # pool invariant violation: loud per-request failure,
                 # never a dead scheduler thread
@@ -2721,7 +2224,7 @@ class _ModelEntry:
                     self._reject_in_flight(st.request, RequestError(
                         f"request {st.request.id} failed: {e}"), slot=s)
                 continue
-            if blocks is None:
+            if not opened:
                 # mid-generation exhaustion: park the session (spill to
                 # the host tier, resume byte-identically later) instead
                 # of failing; loud only when the host tier cannot absorb
@@ -2744,31 +2247,18 @@ class _ModelEntry:
                 else:
                     self._reject_in_flight(st.request, err, slot=s)
                 continue
-            st.blocks = blocks
-            if _nb is not None and st.reserve:
-                st.reserve -= 1
-            if cow is not None:
-                try:
-                    self._apply_cow(st, cow)
-                except Exception as e:
-                    # the COW re-inject is a DONATED call: its failure
-                    # invalidates the whole arena, not one request
-                    self._arena_lost(f"copy-on-write inject failure: {e}")
-                    return None
-            elif _nb is not None:
-                self._rebuild_row_map(st)
             if st.mode == "beam":
                 if st.beam not in groups:
                     groups.append(st.beam)
             else:
                 active.append(s)
-            m.fill_step(step, s, st.cursor, st.table,
-                        self._row_of(st, st.cursor),
+            m.fill_step(step, s, st.cursor, st.kv.table,
+                        st.kv.row_of(st.cursor),
                         -1 if st.ahead else st.last_token)
             reads = st.cursor // m.block_size + 1
             live_blocks += reads
-            if self._copy_unit:
-                copy_units += -(-reads // self._copy_unit)
+            if self.kv.copy_unit:
+                copy_units += -(-reads // self.kv.copy_unit)
             if dmask is not None and st.grammar is not None:
                 # the grammar's next-token constraint rides in as DATA —
                 # same compiled program for every request, zero retraces
@@ -2833,8 +2323,7 @@ class _ModelEntry:
             rows_l = []
             for sid in group.order:
                 bst = self._slots[sid]
-                self._blocks.note_append(
-                    bst.blocks[bst.cursor // m.block_size])
+                self.kv.note_append(bst.kv, bst.cursor)
                 bst.cursor += 1
                 row = np.asarray(fetched[sid, 0],
                                  dtype=np.float32).reshape(-1)
@@ -2844,7 +2333,7 @@ class _ModelEntry:
             stepped += len(rows_l)
             try:
                 alive = self._commit_beam_selection(group, rows_l, now)
-            except _ArenaInvalidError as e:
+            except ArenaInvalidError as e:
                 self._arena_lost(f"beam fork inject failure: {e}")
                 return None
             if alive and group.request.expired(now):
@@ -2862,17 +2351,13 @@ class _ModelEntry:
                 or st.cursor - st.ahead >= m.max_len)
 
     def _retire(self, slot):
-        st = self._slots[slot]
-        self._slots[slot] = None
-        self._pool.release(slot)
-        if st.blocks:
-            self._blocks.release(st.blocks, st.reserve)
-        self._release_draft_locked(st)
-        req = st.request
+        st = self._vacate(slot)
+        self._complete(st.request, {
+            "tokens": np.asarray(st.generated, dtype="int64")})
+
+    def _complete(self, req, outputs):
         self._engine._tenant_unflight(req.tenant)
-        req.response._complete(outputs={
-            "tokens": np.asarray(st.generated, dtype="int64"),
-        })
+        req.response._complete(outputs=outputs)
         self._metrics.incr("completed")
         self._metrics.incr("retired")
         self._metrics.tenant_incr("completed", req.tenant)
@@ -2881,13 +2366,7 @@ class _ModelEntry:
 
     def _reject_in_flight(self, req, error, slot=None):
         if slot is not None:
-            st = self._slots[slot]
-            self._slots[slot] = None
-            self._pool.release(slot)
-            if st is not None and st.blocks:
-                self._blocks.release(st.blocks, st.reserve)
-            if st is not None:
-                self._release_draft_locked(st)
+            self._vacate(slot)
         self._engine._tenant_unflight(req.tenant)
         self._metrics.incr(
             "deadline_missed" if isinstance(error, DeadlineExceededError)
@@ -2914,9 +2393,7 @@ class _ModelEntry:
         out = []
         g = GrammarConstraint(grammar) if grammar is not None else None
         for _ in range(int(max_new)):
-            t = len(toks) - 1
-            fetches = self._run("prefill", self._prefill_feeds(toks))
-            row = self._fetch(fetches[0])[0, t].astype(np.float32)
+            row = self.prefill_logits(toks)[len(toks) - 1].astype(np.float32)
             if g is not None:
                 row = row + g.mask()
             if sampling is not None and not sampling.greedy:
@@ -2942,8 +2419,7 @@ class _ModelEntry:
         m = self._model
 
         def logits_fn(tokens):
-            fetches = self._run("prefill", self._prefill_feeds(tokens))
-            return self._fetch(fetches[0])[0, len(tokens) - 1]
+            return self.prefill_logits(tokens)[len(tokens) - 1]
 
         g = GrammarConstraint(grammar) if grammar is not None else None
         return offline_beam_decode(logits_fn, prompt, int(max_new), params,
@@ -2952,7 +2428,6 @@ class _ModelEntry:
     # -- observability ----------------------------------------------------
     def stats(self):
         m = self._model
-        pool = self._blocks.stats()
         spec_t = self._metrics.count("spec_target_steps")
         spec_e = self._metrics.count("spec_emitted_tokens")
         spec_p = self._metrics.count("spec_proposed_tokens")
@@ -2967,8 +2442,7 @@ class _ModelEntry:
             "arena_mib": m.arena_bytes() / 2**20,
             "slotted_equivalent_mib":
                 m.slotted_equivalent_bytes() / 2**20,
-            "block_pool": pool,
-            "block_dedup_ratio": pool["dedup_ratio"],
+            **self.kv.stats(),
             "spec_steps_per_token": (spec_t / spec_e) if spec_e else None,
             "spec_acceptance_rate": (
                 self._metrics.count("spec_accepted_tokens") / spec_p
@@ -2977,15 +2451,11 @@ class _ModelEntry:
                 self._metrics.count("spec_draft_kv_steps") / spec_e
                 if spec_e else None),
             "draft_pinned": self._draft_pinned,
-            "prefix_cache_entries": len(self._prefix),
-            "prefix_hits": self._prefix.hits,
-            "prefix_misses": self._prefix.misses,
             "compile_sources": dict(self.compile_sources),
             "breaker_state": (self._breaker.state if self._breaker
                               else None),
             "tenant_tokens": self._metrics.tenant_counts("tokens"),
             "tenant_completed": self._metrics.tenant_counts("completed"),
-            "host_tier": self._tier.stats(),
             "brownout_severity": self._brownout.level,
             "brownout": self._brownout.snapshot(),
             "parked_sessions": len(self._parked),
@@ -3002,11 +2472,11 @@ class _ModelEntry:
 
     @property
     def prefix_cache(self):
-        return self._prefix
+        return self.kv.prefix
 
     @property
     def block_pool(self):
-        return self._blocks
+        return self.kv.pool
 
 
 class GenerationEngine:
@@ -3052,26 +2522,8 @@ class GenerationEngine:
             model = model()        # zero-arg builder
         if model.key in self._entries:
             raise ValueError(f"model {model.label} already registered")
-        if model.recurrent and (self._prefix_cache_size
-                                or self._host_tier_bytes):
-            from paddle_tpu.utils.enforce import EnforceError
-
-            raise EnforceError(
-                f"model {model.label} keeps per-slot recurrent state, "
-                "which the prefix cache and the host KV tier cannot carry: "
-                "both key on K/V rows, a function of the token prefix "
-                "alone, and hold no snapshot of a state. Host it on an "
-                "engine with prefix_cache_size=0 and host_tier_mb=0 (got "
-                f"prefix_cache_size={self._prefix_cache_size}, "
-                f"host_tier_mb={self._host_tier_bytes >> 20})")
-        if model.chunks_only and self._host_tier_bytes:
-            from paddle_tpu.utils.enforce import EnforceError
-
-            raise EnforceError(
-                f"model {model.label} has no inject program: what the host "
-                "KV tier keeps (an evicted block's rows, a parked "
-                "session's) could never be put back. Host it on an engine "
-                f"with host_tier_mb=0 (got {self._host_tier_bytes >> 20})")
+        KVStore.check_carries(model, self._host_tier_bytes,
+                              self._prefix_cache_size)
         self._check_hbm(model)
         entry = _ModelEntry(
             self, model, self._queue_depth, self._breaker_threshold,

@@ -454,23 +454,37 @@ def test_heartbeat_monitor_start_stop_idempotent():
 
 def test_heartbeat_monitor_restarts_after_loop_death():
     """A loop that self-terminated (heartbeat RPC failure) leaves a dead
-    _thread behind; start() must spawn a replacement, not no-op."""
+    _thread behind; start() must spawn a replacement, not no-op. The dying
+    thread is JOINED, and the server is back before the restart: a
+    replacement that met the same dead server died as fast as the first,
+    and whether the assertion below saw it alive was a race that six
+    loaded workers lost."""
     from paddle_tpu.incubate.checkpoint import HeartBeatMonitor
 
-    class Dying:
-        def heartbeat(self, wid):
-            raise ConnectionError("server gone")
+    class Flaky:
+        gone = True
+        answered = threading.Event()
 
-    mon = HeartBeatMonitor(Dying(), worker_id=0, worker_num=1,
+        def heartbeat(self, wid):
+            if self.gone:
+                raise ConnectionError("server gone")
+            self.answered.set()
+            return {}
+
+    client = Flaky()
+    mon = HeartBeatMonitor(client, worker_id=0, worker_num=1,
                            timeout=10, period=0.01)
     mon.start()
-    deadline = time.time() + 5
-    while mon._thread.is_alive() and time.time() < deadline:
-        time.sleep(0.01)
-    assert mon._thread is not None and not mon._thread.is_alive()
+    first = mon._thread
+    first.join(timeout=60)
+    assert not first.is_alive()
+    client.gone = False
     mon.start()
+    assert mon._thread is not first
+    assert client.answered.wait(timeout=60)
     assert mon._thread.is_alive()
     mon.stop()
+    assert mon._thread is None
 
 
 # ---------------------------------------------------------------------------

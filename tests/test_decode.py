@@ -462,7 +462,9 @@ def test_inject_failure_invalidates_arena_and_recovers():
     try:
         victim = engine.submit([1, 2], max_new_tokens=24)  # holds slot 0
         deadline = time.time() + 30
-        while entry.stats()["active_slots"] < 1:
+        # ``admitted`` moves after the victim's own inject; its slot is
+        # active BEFORE it, and a fault armed in between hit the victim
+        while entry.stats()["admitted"] < 1:
             assert time.time() < deadline
             time.sleep(0.002)
         faults.configure([{"site": "decode.inject", "action": "raise",

@@ -368,11 +368,11 @@ def test_an_eos_models_wasted_row_is_never_read_by_the_blocks_next_owner():
                     break
             assert wasted
             out["first"] = _tokens(first)
-            out["first_blocks"] = {b.id for b in st.blocks} or None
+            out["first_blocks"] = {b.id for b in st.kv.blocks} or None
             out["first_rows"] = rows[id(first)]
         second = engine.submit(follower, max_new_tokens=10)
         entry._iterate()
-        out["second_blocks"] = {b.id for b in entry._slots[0].blocks}
+        out["second_blocks"] = {b.id for b in entry._slots[0].kv.blocks}
         for _ in range(40):
             if second.done():
                 break
@@ -406,13 +406,13 @@ def test_the_next_owner_gets_the_block_the_wasted_row_landed_in():
     engine, entry = _engine("la_eos_blocks")
     first = engine.submit([3, 1, 4, 1, 5], max_new_tokens=4)
     entry._iterate()
-    tail = entry._slots[0].blocks[-1].id
+    tail = entry._slots[0].kv.blocks[-1].id
     for _ in range(10):
         entry._iterate()
     assert first.done()
     engine.submit([9, 2, 6], max_new_tokens=2)
     entry._iterate()
-    assert entry._slots[0].blocks[0].id == tail
+    assert entry._slots[0].kv.blocks[0].id == tail
 
 
 # -- policies that need more than the token never launch ahead ----------------------------
@@ -816,7 +816,7 @@ def test_a_next_owner_admitted_under_the_step_that_wastes_a_row(kind, tracer):
             assert entry._slots[1] is None
             out["first"] = _tokens(first)
             # where that step writes the row nobody will read
-            out["tail"] = st.blocks[(st.cursor - 1) // 4].id
+            out["tail"] = st.kv.blocks[(st.cursor - 1) // 4].id
             tracer.clear()
         else:
             for _ in range(6):
@@ -826,7 +826,7 @@ def test_a_next_owner_admitted_under_the_step_that_wastes_a_row(kind, tracer):
         entry._iterate()
         sf = entry._slots[1]
         assert sf.request.response is second and sf.mode == "prefill"
-        out["blocks"] = [b.id for b in sf.blocks]
+        out["blocks"] = [b.id for b in sf.kv.blocks]
         # admitted and given its first chunk with that step untouched
         assert entry.metrics.drains()["admission"] == 0
         chunk = _spans(tracer, "decode::chunk")[0] if with_first else None

@@ -274,9 +274,9 @@ def _watch_steps(entry, seen):
         stepping, covered = {}, {}
         for s in slots:
             st = entry._slots[s]
-            stepping[s] = (st.last_token, st.cursor, st.row_map,
-                           entry._row_of(st, st.cursor))
-            covered[s] = min(len(st.blocks) * m.block_size, m.max_len)
+            stepping[s] = (st.last_token, st.cursor, st.kv.row_map,
+                           st.kv.row_of(st.cursor))
+            covered[s] = min(len(st.kv.blocks) * m.block_size, m.max_len)
         assert set(feeds) == {DecodeModel.DEC_STEP, DecodeModel.DEC_TOKEN}
         assert isinstance(feeds[DecodeModel.DEC_STEP], np.ndarray)
         assert not isinstance(feeds[DecodeModel.DEC_TOKEN], np.ndarray)
@@ -284,7 +284,7 @@ def _watch_steps(entry, seen):
             m, _expand(m, feeds), _parent_arrays(m, stepping), covered,
             device_tokens=None if launched is None else launched.fetches[1],
             host=[s for s in slots if not entry._slots[s].ahead])
-        seen.append(tuple(tuple(b.id for b in entry._slots[s].blocks)
+        seen.append(tuple(tuple(b.id for b in entry._slots[s].kv.blocks)
                           for s in slots))
         return built
 
@@ -378,14 +378,14 @@ def _engine_draft_no_write():
     def checking(kind, feeds, span=None):
         if kind == "step":
             st, token, p, write = calls.pop()
-            b = st.d_blocks[p // dm.block_size]
+            b = st.draft_kv.blocks[p // dm.block_size]
             row = b.row0 + p % dm.block_size if write else dm.rows
             assert feeds[DecodeModel.DEC_STEP][st.d_slot, 3] == row
             _assert_equal(
                 dm, _expand(dm, feeds),
-                _parent_arrays(dm, {st.d_slot: (token, p, st.d_row_map,
+                _parent_arrays(dm, {st.d_slot: (token, p, st.draft_kv.row_map,
                                                 row)}),
-                {st.d_slot: min(len(st.d_blocks) * dm.block_size,
+                {st.d_slot: min(len(st.draft_kv.blocks) * dm.block_size,
                                 dm.max_len)})
             seen.append(write)
         return run(kind, feeds, span)

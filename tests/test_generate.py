@@ -266,6 +266,25 @@ def test_beam_matches_offline_reference_and_conserves_blocks(gen_served):
     assert st["active_slots"] == 0      # width-reserved slots all returned
 
 
+def test_a_beam_forks_tail_copy_is_counted_as_an_arena_read(gen_served):
+    """A fork copies its parent's partial tail block arena to arena
+    through the host, by the store's one read (`KVStore.read_block`):
+    every arena comes over whole and is counted, as a spill's and a
+    write-back's are (it was read uncounted before PR 46)."""
+    engine, entry = gen_served
+    before = entry.stats()
+    engine.submit([7, 2, 9, 4, 1], model="gens", max_new_tokens=6,
+                  beam_width=3).result(timeout=120)
+    st = entry.stats()
+    forks = st["beam_forks"] - before["beam_forks"]
+    read = st["arena_read_bytes"] - before["arena_read_bytes"]
+    arena = entry.model.arena_bytes()
+    assert st["sessions_parked"] == before["sessions_parked"]
+    assert forks > 0 and read > 0 and read % arena == 0
+    assert read // arena <= forks       # an aligned fork copies nothing
+    assert st["fetched_bytes"] - before["fetched_bytes"] >= read
+
+
 def test_beam_with_grammar_matches_offline(gen_served):
     engine, entry = gen_served
     g = CompiledGrammar.from_regex("a(b|c)*d", VOCAB, eos_id=0)

@@ -311,7 +311,7 @@ def test_corruption_walkback_recomputes_not_garbage():
             break
         if entry._parked and not corrupted:
             for key in entry._parked[0].keys:
-                entry._tier.corrupt_entry(key)
+                entry.kv.tier.corrupt_entry(key)
             corrupted = True
         entry._iterate()
     outs = [[int(t) for t in r.result(timeout=60)["tokens"]]
