@@ -58,8 +58,11 @@ TRAFFIC_FILE_KEYS = {
                     "check_tolerance", "check_min_equal", "trace_seconds",
                     "rehearsal"},
 }
+# which cells report a metric is BENCHMARK.json's entry's to say, and no
+# one else's: a file that carried the list could not take a new cell's
+# name without an edit, so each cell brought copies of the files
 METRIC_FILE_KEYS = {"name", "layer", "unit", "better", "source", "moves",
-                    "workloads", "reader", "args", "what"}
+                    "reader", "args", "what"}
 
 
 class ManifestError(ValueError):

@@ -32,30 +32,75 @@ DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 # -- the manifest and its data files ---------------------------------------
 
-def test_manifest_and_every_data_file_parse():
-    assert set(BENCH) == manifest.MANIFEST_KEYS
-    assert len(json.dumps(BENCH)) < 64 * 1024
-    for path in BENCH["paths"]:
+# an eighth cell, added the way benchmark/README.md's table says a cell is
+# added: entries appended to BENCHMARK.json and no file touched. The
+# configuration is the fixture in the format a published one takes, run
+# through the decoder's builder, so the cell reports what the decoder's does
+EIGHTH = {"name": "published_tiny.chat_steady", "config": "published_tiny",
+          "traffic": "chat_steady", "chips": 1,
+          "why": "a test's cell: the fixture configuration under the "
+                 "decoder cell's traffic, added by appends alone"}
+EIGHTH_FILE = "tests/benchmark_grid/fixtures/configs/published_tiny.json"
+LIKE = "decoder_1024x24.chat_steady"     # the cell whose lists it joins
+
+
+@pytest.fixture(scope="module")
+def eighth(tmp_path_factory):
+    """A root that differs from the checkout in BENCHMARK.json alone (every
+    other entry of the checkout is linked into it), and its manifest."""
+    root = tmp_path_factory.mktemp("an_eighth_cell")
+    bench = json.loads(json.dumps(BENCH))
+    cfg = manifest.load_config_file(os.path.join(ROOT, EIGHTH_FILE))
+    bench["configs"].append({
+        "name": cfg["name"], "source": cfg["source"], "file": EIGHTH_FILE,
+        "reduced": cfg["reduced"], "why": cfg["why"]})
+    bench["workloads"].append(EIGHTH)
+    for group in ("end_to_end", "per_layer"):
+        for m in bench[group]:
+            if LIKE in m.get("workloads", ()):
+                m["workloads"].append(EIGHTH["name"])
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f, indent=1)
+    for entry in os.listdir(ROOT):
+        if entry != "BENCHMARK.json" and not entry.startswith("."):
+            os.symlink(os.path.join(ROOT, entry), root / entry)
+    return str(root), manifest.load_manifest(root=str(root))
+
+
+@pytest.fixture(params=["as_accepted", "with_an_eighth_cell"])
+def bench(request):
+    """Every invariant of the grid holds on the manifest as it is and on
+    the one a later PR's appends would leave."""
+    if request.param == "as_accepted":
+        return BENCH
+    return request.getfixturevalue("eighth")[1]
+
+
+def test_manifest_and_every_data_file_parse(bench):
+    assert set(bench) == manifest.MANIFEST_KEYS
+    assert len(json.dumps(bench)) < 64 * 1024
+    assert 1 <= len(bench["per_layer"]) <= 128
+    for path in bench["paths"]:
         assert os.path.isdir(os.path.join(ROOT, path))
     assert not any(part.startswith("/") or ".." in part
-                   for part in BENCH["command"])
+                   for part in bench["command"])
     files = set()
-    for c in BENCH["configs"]:
-        cfg = manifest.load_config(BENCH, c["name"])
-        assert c["file"].startswith(BENCH["paths"][0] + "/")
+    for c in bench["configs"]:
+        cfg = manifest.load_config(bench, c["name"])
+        assert any(c["file"].startswith(path + "/")
+                   for path in bench["paths"])
         assert c["reduced"] == cfg["reduced"]
-        assert any(w["config"] == c["name"] for w in BENCH["workloads"])
+        assert any(w["config"] == c["name"] for w in bench["workloads"])
         files.add(c["file"])
-    assert len(files) == len(BENCH["configs"])
-    for w in BENCH["workloads"]:
+    assert len(files) == len(bench["configs"])
+    for w in bench["workloads"]:
         manifest.load_traffic(w["traffic"])
         assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         spec = manifest.load_metric(m["name"])
         assert spec["reader"] in readers.READERS
         for key in ("layer", "unit", "better", "source", "moves"):
             assert spec[key] == m[key], (m["name"], key)
-        assert spec.get("workloads") == m.get("workloads")
 
 
 def test_unknown_keys_are_refused():
@@ -65,41 +110,42 @@ def test_unknown_keys_are_refused():
         manifest._name("two words", "a name")
 
 
-def test_names_units_and_bounds_hold_to_the_contract():
+def test_names_units_and_bounds_hold_to_the_contract(bench):
     for group in ("end_to_end", "per_layer"):
-        for m in BENCH[group]:
+        for m in bench[group]:
             assert manifest.NAME.match(m["name"])
             assert manifest.UNIT.match(m["unit"])
             assert m["better"] in ("lower", "higher")
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1
         assert m["source"] in ("host_clock", "device_trace")
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert "setup_s" in [m["name"] for m in BENCH["end_to_end"]]
+    assert 1 <= bench["run_seconds"] <= 51
+    assert "setup_s" in [m["name"] for m in bench["end_to_end"]]
 
 
-def test_every_moves_names_a_metric_that_all_its_cells_report():
-    for m in BENCH["per_layer"]:
-        (target,) = [e for e in BENCH["end_to_end"]
+def test_every_moves_names_a_metric_that_all_its_cells_report(bench):
+    cells = [w["name"] for w in bench["workloads"]]
+    for m in bench["per_layer"]:
+        (target,) = [e for e in bench["end_to_end"]
                      if e["name"] == m["moves"]]
-        for cell in m.get("workloads", CELLS):
-            assert cell in CELLS
-            assert cell in target.get("workloads", CELLS), (m["name"], cell)
-    for cell in CELLS:
-        e2e = manifest.metrics_of(BENCH, "end_to_end", cell)
+        for cell in m.get("workloads", cells):
+            assert cell in cells
+            assert cell in target.get("workloads", cells), (m["name"], cell)
+    for cell in cells:
+        e2e = manifest.metrics_of(bench, "end_to_end", cell)
         assert {"setup_s"} < {m["name"] for m in e2e}
-        assert manifest.metrics_of(BENCH, "per_layer", cell)
+        assert manifest.metrics_of(bench, "per_layer", cell)
 
 
-def test_at_most_a_quarter_of_the_cells_ask_for_four_chips():
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+def test_at_most_a_quarter_of_the_cells_ask_for_four_chips(bench):
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(bench["workloads"]) // 4)
 
 
-def test_the_harness_never_branches_on_a_name():
+def test_the_harness_never_branches_on_a_name(bench):
     names = {x["name"] for key in ("configs", "workloads", "end_to_end",
-                                   "per_layer") for x in BENCH[key]}
-    names |= {w["traffic"] for w in BENCH["workloads"]}
+                                   "per_layer") for x in bench[key]}
+    names |= {w["traffic"] for w in bench["workloads"]}
     # what the drivers measure
     names -= {"setup_s", "train_throughput", "serve_token_latency_p50"}
     bench_dir = os.path.join(ROOT, "benchmark")
@@ -109,6 +155,124 @@ def test_the_harness_never_branches_on_a_name():
                 text = open(os.path.join(dirpath, f)).read()
                 for name in names:
                     assert f'"{name}"' not in text, (f, name)
+
+
+# -- one entry a quantity (PR 47) ------------------------------------------------
+
+METRICS_DIR = os.path.join(ROOT, "benchmark", "metrics")
+# what the fold of PR 47 left of 128 entries, in their order: a reading that
+# every serving cell takes is ONE entry whose list names the cells
+FOLDED = [
+    "cache_load_s", "window_compiles.train", "window_compiles.serve",
+    "host_step_ms.train", "train_mfu", "flash_device_share",
+    "flash_attention_roofline", "collective_exposed_ms", "decode_step_ms",
+    "decode_occupancy", "prefill_share", "serve_output_rate",
+    "serve_requests_finished", "generator_lateness_ms",
+    "decode_step_device_ms", "device_idle.train", "device_idle.serve",
+    "hbm_compiled_gb", "serve_queue_wait_ms", "serve_first_token_ms",
+    "serve_inter_token_ms", "prefill_kv_fetch_ms", "decode_logits_fetch_ms",
+    "decode_feeds_ms", "decode_sample_ms", "serve_fed_mb_per_step",
+    "serve_fetched_mb_per_step", "serve_shed", "decode_live_block_share",
+    "paged_attention_device_share", "paged_attention_roofline",
+    "decode_step_mfu", "serve_token_latency_p90", "moe_experts_roofline",
+    "ssm_update_roofline", "paged_attention_roofline.gqa",
+    "moe_experts_device_share", "ssm_update_device_share",
+    "serve_device_mfu", "moe_held_share", "moe_touched_share",
+    "decode_wait_share", "decode_put_ms", "decode_call_ms",
+    "pool_evicted_alloc_share", "kv_arena_read_bytes",
+    "moe_experts_roofline.gated", "paged_attention_roofline.gqa64",
+    "serve_device_mfu.lfm2", "moe_touched_share.lfm2",
+    "moe_tokens_per_touched_expert", "moe_peak_expert_tokens",
+    "chunk_tokens_per_launch", "decode_drains",
+    "paged_attention_roofline.mha128", "serve_device_mfu.ouro",
+    "loop_passes_per_token", "loop_expected_exit_pass", "kv_live_gb_per_step",
+    "reserved_blocks_per_admission", "admissions_deferred"]
+
+
+def test_no_two_metric_files_are_copies_and_none_lists_cells(tmp_path):
+    """The fold as a test: two files that differ in ``name`` and ``what``
+    alone are one reading under two names, which is how ``per_layer`` came
+    to hold 128 entries for 61 readings; a new cell appends its name to
+    the one entry's list in BENCHMARK.json, which alone says which cells
+    report what."""
+    specs = {f[:-len(".json")]: manifest.load_metric_file(
+                 os.path.join(METRICS_DIR, f))
+             for f in sorted(os.listdir(METRICS_DIR)) if f.endswith(".json")}
+    assert set(specs) == {m["name"] for m in BENCH["per_layer"]}
+    readings = {}
+    for name, spec in specs.items():
+        assert spec["name"] == name
+        assert "workloads" not in spec, name
+        reading = json.dumps({k: v for k, v in spec.items()
+                              if k not in ("name", "what")}, sort_keys=True)
+        assert reading not in readings, (name, readings[reading])
+        readings[reading] = name
+    # and the loader refuses a file that carries the list
+    carried = dict(specs["decode_step_ms"], workloads=[LIKE])
+    path = tmp_path / "decode_step_ms.json"
+    path.write_text(json.dumps(carried))
+    with pytest.raises(manifest.ManifestError, match="unknown keys"):
+        manifest.load_metric_file(str(path))
+
+
+def test_the_fold_left_sixty_one_entries_and_they_keep_their_order():
+    """128 -> 61 (under the 64 the issue asked for); the contract's cap is
+    128 and later cells add entries behind these, so what is held is that
+    the fold's survivors are there, once each, in their order."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert len(FOLDED) == len(set(FOLDED)) == 61 <= 64
+    assert len(set(names)) == len(names) <= 128
+    assert [n for n in names if n in set(FOLDED)] == FOLDED
+
+
+# -- an eighth cell takes no edit (PR 47) ------------------------------------------
+
+def test_an_eighth_cell_gets_the_folded_readings_from_the_manifest_alone(
+        eighth):
+    _root, bench = eighth
+    cell = manifest.workload(bench, EIGHTH["name"])
+    assert cell == EIGHTH
+    for group in ("end_to_end", "per_layer"):
+        mine = [m["name"] for m in manifest.metrics_of(bench, group,
+                                                       EIGHTH["name"])]
+        assert mine == [m["name"] for m in manifest.metrics_of(
+            BENCH, group, LIKE)]
+    # nothing else moved: every other cell reports what it did
+    for w in BENCH["workloads"]:
+        for group in ("end_to_end", "per_layer"):
+            assert [m["name"] for m in manifest.metrics_of(
+                bench, group, w["name"])] == [m["name"] for m in
+                manifest.metrics_of(BENCH, group, w["name"])]
+
+
+def test_an_eighth_cell_rehearses_with_no_file_touched(eighth):
+    root, bench = eighth
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    procs = {trace: subprocess.Popen(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"),
+         "--workload", EIGHTH["name"], "--seed", "4700000008",
+         "--seconds", "1", "--trace", trace, "--rehearse-cpu"],
+        cwd=root, env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        text=True) for trace in ("0", "1")}
+    lines = {}
+    for trace, p in procs.items():
+        stdout, stderr = p.communicate(timeout=300)
+        assert p.returncode == 0, stderr[-2000:]
+        lines[trace] = json.loads(stdout.strip().splitlines()[-1])
+        assert lines[trace]["correct"] is True
+        assert lines[trace]["failed"] == 0
+    assert set(lines["0"]["metrics"]) == {"serve_token_latency_p50",
+                                          "setup_s"}
+    # every reading of the cell that a CPU run can name, under its ONE name
+    want = {m["name"] for m in manifest.metrics_of(
+        bench, "per_layer", EIGHTH["name"]) if m["source"] != "device_trace"}
+    assert set(lines["1"]["metrics"]) == want
+    assert {"decode_step_ms", "decode_drains", "serve_first_token_ms",
+            "pool_evicted_alloc_share"} <= want
+    # the checkout was not written to
+    assert not os.path.exists(os.path.join(ROOT, ".bench_trace",
+                                           EIGHTH["name"]))
 
 
 # -- traffic ------------------------------------------------------------------

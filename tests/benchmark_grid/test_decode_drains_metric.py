@@ -58,8 +58,9 @@ def test_the_file_passes_the_manifest_and_names_the_family():
     assert spec["reader"] == "counter_delta" in readers.READERS
     assert spec["args"] == {"family": FAMILY}
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    for key in ("layer", "unit", "better", "source", "moves", "workloads"):
+    for key in ("layer", "unit", "better", "source", "moves"):
         assert spec[key] == entry[key], key
+    assert "workloads" not in spec          # the entry alone lists the cells
     assert (entry["layer"], entry["unit"], entry["better"],
             entry["source"], entry["moves"]) == (
         "decode scheduler", "count", "lower", "program_counter",
@@ -69,25 +70,26 @@ def test_the_file_passes_the_manifest_and_names_the_family():
         assert why in spec["what"]
 
 
-def test_it_lists_all_three_serving_cells_from_the_start():
-    """An accepted file's list cannot grow, so the three are there at once:
-    every cell that reports the metric it moves."""
+def test_it_lists_every_cell_of_the_latency_metric_whatever_their_number():
+    """Every engine's loop drains, so every cell that reports the metric it
+    moves reports it: the entry's list is the latency metric's, and a cell
+    that joins the one joins the other."""
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    assert entry["workloads"] == SERVING
+    assert set(SERVING) <= set(entry["workloads"])
     (latency,) = [m for m in BENCH["end_to_end"]
                   if m["name"] == "serve_token_latency_p50"]
     assert entry["workloads"] == latency["workloads"]
 
 
-def test_the_entry_follows_what_was_there_and_nothing_before_it_moved():
+def test_the_entry_follows_what_was_there_and_no_list_names_a_stranger():
     names = [m["name"] for m in BENCH["per_layer"]]
-    at = names.index(NAME)
-    assert at == 100 and names[at - 1] == "chunk_tokens_per_launch"
+    assert names.index("chunk_tokens_per_launch") < names.index(NAME)
     assert len(set(names)) == len(names)
     cells = {w["name"] for w in BENCH["workloads"]}
-    for m in BENCH["per_layer"][:at]:
+    for m in BENCH["per_layer"]:
         assert set(m.get("workloads", ())) <= cells
-    assert len(BENCH["workloads"]) == 6 and len(BENCH["configs"]) == 5
+    configs = {c["name"] for c in BENCH["configs"]}
+    assert {w["config"] for w in BENCH["workloads"]} == configs
 
 
 def test_the_program_registers_every_reason_from_the_start():

@@ -141,11 +141,15 @@ def test_stepped_tokens_by_hand():
 # -- the metric files through their readers ----------------------------------------
 
 LABEL = '{engine="e"}'
-NEW = ["moe_experts_roofline.gated", "moe_experts_device_share.lfm2",
-       "paged_attention_roofline.gqa64", "paged_attention_device_share.lfm2",
-       "serve_device_mfu.lfm2", "moe_held_share.lfm2",
+NEW = ["moe_experts_roofline.gated", "moe_experts_device_share",
+       "paged_attention_roofline.gqa64", "paged_attention_device_share",
+       "serve_device_mfu.lfm2", "moe_held_share",
        "moe_touched_share.lfm2", "moe_tokens_per_touched_expert",
        "moe_peak_expert_tokens", "chunk_tokens_per_launch"]
+# the two readings whose file differs by configuration (a count function, a
+# scale): the cell reports its own and not the one it parts from
+OWN = {"serve_device_mfu.lfm2": "serve_device_mfu",
+       "moe_touched_share.lfm2": "moe_touched_share"}
 
 
 def _run(moved):
@@ -187,9 +191,9 @@ def test_the_rooflines_follow_the_counters_of_the_stretch():
     assert _read("paged_attention_roofline.gqa64", run) == pytest.approx(
         100 * 50_000 * 16 * 512 * 2 * 2 * 10 / 819e9 / 0.2)
     busy = 0.2 * 2 + 1.0
-    assert _read("moe_experts_device_share.lfm2", run) == pytest.approx(
+    assert _read("moe_experts_device_share", run) == pytest.approx(
         100 * 0.2 / busy)
-    assert _read("paged_attention_device_share.lfm2", run) == pytest.approx(
+    assert _read("paged_attention_device_share", run) == pytest.approx(
         100 * 0.2 / busy)
 
 
@@ -214,7 +218,7 @@ def test_the_routing_readings_are_ratios_of_counters():
                 "serving_decode_steps_total": 10,
                 "serving_chunk_tokens_total": 900,
                 "serving_chunk_runs_total": 10})
-    assert _read("moe_held_share.lfm2", run) == pytest.approx(12.5)
+    assert _read("moe_held_share", run) == pytest.approx(12.5)
     assert _read("moe_touched_share.lfm2", run) == pytest.approx(
         100 * 2_400 / 3_040)
     assert _read("moe_tokens_per_touched_expert", run) == pytest.approx(2.5)
@@ -233,35 +237,35 @@ def test_a_program_without_the_counters_reads_nothing_and_does_not_raise():
         assert _read(name, run) is None, name
 
 
-def test_every_new_metric_is_the_cells_alone_and_is_registered():
-    mine = {m["name"]: m for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]}
-    assert set(NEW) <= set(mine) and len(mine) == 34
+def test_every_new_metric_lists_the_cell_and_is_registered():
+    mine = {m["name"]: m for m in manifest.metrics_of(BENCH, "per_layer", CELL)}
+    # the ten, the readings every serving cell shares, and the two that
+    # every cell reports; a later entry that lists the cell adds to them
+    assert set(NEW) <= set(mine) and len(mine) >= 37
     for name, entry in mine.items():
         spec = manifest.load_metric(name)
-        for key in ("unit", "better", "source", "layer", "moves",
-                    "workloads"):
+        for key in ("unit", "better", "source", "layer", "moves"):
             assert spec[key] == entry[key], (name, key)
+        assert "workloads" not in spec      # the entry alone lists the cells
+    for name in NEW:
+        assert CELL in mine[name]["workloads"]
     (latency,) = [m for m in BENCH["end_to_end"]
                   if m["name"] == "serve_token_latency_p50"]
-    assert latency["workloads"][-1] == CELL
+    assert CELL in latency["workloads"]
 
 
-def test_the_cells_own_copies_read_as_the_accepted_cells_metrics_do():
-    suffix = ".lfm2"
-    own = {"serve_device_mfu.lfm2", "moe_held_share.lfm2",
-           "moe_touched_share.lfm2"}      # their counts and scales differ
-    copies = [m["name"] for m in BENCH["per_layer"]
-              if m["name"].endswith(suffix) and m["name"] not in own]
-    # the twenty that the Nemotron cell copied, PR 38's five, and the
-    # expert kernel's share of the device
-    assert len(copies) == 26
-    for name in copies:
-        mine = manifest.load_metric(name)
-        theirs = manifest.load_metric(name[:-len(suffix)])
-        for key in ("reader", "args", "unit", "better", "source", "layer",
-                    "moves"):
-            assert mine.get(key) == theirs.get(key), (name, key)
+@pytest.mark.parametrize("name", sorted(OWN))
+def test_a_file_of_the_cells_own_differs_from_the_one_it_parts_from(name):
+    """One entry a quantity: a second file under a cell's suffix is there
+    only where the reading itself differs by configuration, and then a
+    cell reports one of the two."""
+    mine, theirs = manifest.load_metric(name), manifest.load_metric(OWN[name])
+    for key in ("reader", "unit", "better", "source", "layer", "moves"):
+        assert mine[key] == theirs[key], (name, key)
+    assert mine["args"] != theirs["args"]
+    entries = {m["name"]: m for m in BENCH["per_layer"]}
+    assert CELL in entries[name]["workloads"]
+    assert CELL not in entries[OWN[name]]["workloads"]
 
 
 def test_the_traffic_is_the_issues_letter_for_letter():
@@ -316,7 +320,7 @@ def test_the_cell_rehearses_and_prints_every_metric_a_cpu_run_can_name():
     # other metric of the cell is in the line, each value null
     want = {m["name"] for m in entries if m["source"] != "device_trace"}
     assert set(line["metrics"]) == want
-    assert {"cache_load_s", "hbm_compiled_gb", "moe_held_share.lfm2",
+    assert {"cache_load_s", "hbm_compiled_gb", "moe_held_share",
             "moe_touched_share.lfm2", "moe_tokens_per_touched_expert",
             "moe_peak_expert_tokens", "chunk_tokens_per_launch"} <= want
     assert all(m["value"] is None for m in line["metrics"].values())

@@ -125,10 +125,14 @@ def test_stepped_tokens_by_hand():
 # -- the metric files through their readers ----------------------------------------
 
 LABEL = '{engine="e"}'
-NEW = ["paged_attention_roofline.mha128", "paged_attention_device_share.ouro",
+NEW = ["paged_attention_roofline.mha128", "paged_attention_device_share",
        "serve_device_mfu.ouro", "loop_passes_per_token",
        "loop_expected_exit_pass", "kv_live_gb_per_step",
-       "reserved_blocks_per_admission", "admissions_deferred.ouro"]
+       "reserved_blocks_per_admission", "admissions_deferred"]
+# what PR 43 left off this cell for room and PR 47's fold brought back: the
+# host's per-step halves, each an entry the other serving cells report too
+BACK = ["decode_feeds_ms", "decode_sample_ms", "decode_put_ms",
+        "serve_fed_mb_per_step", "serve_fetched_mb_per_step"]
 
 
 def _run(moved):
@@ -162,7 +166,7 @@ def test_the_roofline_follows_the_live_blocks_of_the_stretch():
     # a block across the 192 pairs' K and V arenas is 25,165,824 bytes
     assert _read("paged_attention_roofline.mha128", run) == pytest.approx(
         100 * 40_000 * 25_165_824 / 819e9 / 0.2)
-    assert _read("paged_attention_device_share.ouro", run) == pytest.approx(
+    assert _read("paged_attention_device_share", run) == pytest.approx(
         100 * 0.2 / 1.2)
 
 
@@ -192,8 +196,8 @@ def test_the_loops_and_the_pools_readings_are_ratios_of_counters():
     assert _read("kv_live_gb_per_step", run) == pytest.approx(
         200 * 25_165_824 / 1e9)
     assert _read("reserved_blocks_per_admission", run) == pytest.approx(24.0)
-    assert _read("admissions_deferred.ouro", run) == 17
-    assert _read("chunk_tokens_per_launch.ouro", run) == pytest.approx(90.0)
+    assert _read("admissions_deferred", run) == 17
+    assert _read("chunk_tokens_per_launch", run) == pytest.approx(90.0)
 
 
 def test_a_program_without_the_counters_reads_nothing_and_does_not_raise():
@@ -226,50 +230,44 @@ def test_the_program_registers_what_the_files_read():
                 DecodeMetrics.COUNTERS, family
 
 
-def _files_of_the_cell_alone():
-    folder = os.path.join(ROOT, "benchmark", "metrics")
-    names = (f[:-len(".json")] for f in os.listdir(folder)
-             if f.endswith(".json"))
-    return {n for n in names
-            if manifest.load_metric(n).get("workloads") == [CELL]}
-
-
-def test_every_new_metric_is_the_cells_alone_and_is_registered():
-    """The cell's own entries, by membership: a later cell appends after
-    them and a later fold may move them, so nothing here counts the lists or
-    looks at their last place."""
-    mine = {m["name"]: m for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]}
-    # every file that lists the cell alone is registered, and the reverse
-    assert set(NEW) <= set(mine) and set(mine) == _files_of_the_cell_alone()
+def test_every_new_metric_lists_the_cell_and_is_registered():
+    """The cell's entries, by membership: a later cell appends itself to
+    their lists and a later entry follows them, so nothing here counts the
+    lists or looks at their last place."""
+    mine = {m["name"]: m for m in manifest.metrics_of(BENCH, "per_layer", CELL)}
+    assert set(NEW) <= set(mine)
     for name, entry in mine.items():
         spec = manifest.load_metric(name)
-        for key in ("unit", "better", "source", "layer", "moves",
-                    "workloads"):
+        for key in ("unit", "better", "source", "layer", "moves"):
             assert spec[key] == entry[key], (name, key)
+        assert "workloads" not in spec      # the entry alone lists the cells
+    for name in NEW:
+        assert CELL in mine[name]["workloads"]
     (latency,) = [m for m in BENCH["end_to_end"]
                   if m["name"] == "serve_token_latency_p50"]
     assert CELL in latency["workloads"]
     (cell,) = [w for w in BENCH["workloads"] if w["name"] == CELL]
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 4)
 
 
-def test_the_cells_own_copies_read_as_the_accepted_cells_metrics_do():
-    suffix = ".ouro"
-    own = {"serve_device_mfu.ouro",     # its count function differs
-           "admissions_deferred.ouro"}  # new: no accepted file reads it
-    copies = [m["name"] for m in BENCH["per_layer"]
-              if m["name"].endswith(suffix) and m["name"] not in own]
-    assert {"decode_step_ms.ouro", "decode_logits_fetch_ms.ouro",
-            "decode_call_ms.ouro", "chunk_tokens_per_launch.ouro",
-            "decode_drains.ouro"} <= set(copies)
-    for name in copies:
-        mine = manifest.load_metric(name)
-        theirs = manifest.load_metric(name[:-len(suffix)])
-        for key in ("reader", "args", "unit", "better", "source", "layer",
-                    "moves"):
-            assert mine.get(key) == theirs.get(key), (name, key)
+def test_the_cell_reports_the_shared_readings_and_what_was_left_out_for_room():
+    """No file under the cell's suffix is left but the one whose count
+    function is its own; the five host halves PR 43 dropped at 128 of 128
+    are the cell's again, by an append to their entries' lists; the two
+    that read 0 here by construction stay off."""
+    entries = {m["name"]: m for m in BENCH["per_layer"]}
+    assert [n for n in entries if n.endswith(".ouro")] == [
+        "serve_device_mfu.ouro"]
+    mine, theirs = (manifest.load_metric("serve_device_mfu.ouro"),
+                    manifest.load_metric("serve_device_mfu"))
+    assert mine["reader"] == theirs["reader"] and mine["args"] != theirs["args"]
+    for name in ["decode_step_ms", "decode_logits_fetch_ms", "decode_call_ms",
+                 "chunk_tokens_per_launch", "decode_drains"] + BACK:
+        assert CELL in entries[name]["workloads"], name
+    for name in ("kv_arena_read_bytes", "serve_shed"):
+        assert CELL not in entries[name]["workloads"], name
 
 
 def test_the_traffic_is_the_issues():
@@ -335,8 +333,8 @@ def test_the_cell_rehearses_and_prints_every_metric_a_cpu_run_can_name():
     assert set(line["metrics"]) == want
     assert {"cache_load_s", "hbm_compiled_gb", "loop_passes_per_token",
             "loop_expected_exit_pass", "kv_live_gb_per_step",
-            "reserved_blocks_per_admission", "admissions_deferred.ouro",
-            "decode_drains.ouro"} <= want
+            "reserved_blocks_per_admission", "admissions_deferred",
+            "decode_drains"} | set(BACK) <= want
     assert all(m["value"] is None for m in line["metrics"].values())
 
 
