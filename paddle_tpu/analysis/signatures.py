@@ -83,6 +83,14 @@ _SIGNATURES = {
         ranks={"Packed": 2, "Token": 2},
         dtype_family={"Packed": "int", "Token": "int"},
     ),
+    "paged_block_feeds": OpSignature(
+        ranks={"Packed": 2, "State": 2},
+        dtype_family={"Packed": "int", "State": "int"},
+    ),
+    "block_fill_decide": OpSignature(
+        ranks={"Logits": 3, "Held": 2, "Decided": 2},
+        dtype_family={"Logits": "float", "Held": "int", "Decided": "int"},
+    ),
     "chunk_paged_attention": OpSignature(
         same_dtype=[("Q", "KArena", "VArena")],
         ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Bias": 3},

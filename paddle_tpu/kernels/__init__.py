@@ -281,12 +281,14 @@ def _tpu_cases_paged():
 def _parity_paged_grouped(rng):
     """The head axis: 2 K/V heads of 128 a row, 4 query heads to each; then
     8 heads of 64, two to a lane tile; then 16 heads of 128 with ONE query
-    head each (one real query row in a tile). Each at lengths a block, a
-    tile and a slot long and one off them, then with slots as long as the
-    geometry's copy unit (512, 256 and 128 rows in float32) and one off
-    it, a free slot between them."""
+    head each (one real query row in a tile); then 4 heads of 128 with 32
+    query rows each (a block pass: the 8 query heads of a K/V head at each
+    of a block's 4 positions, which all see the same rows). Each at lengths
+    a block, a tile and a slot long and one off them, then with slots as
+    long as the geometry's copy unit (512, 256, 128 and 256 rows in
+    float32) and one off it, a free slot between them."""
     for G, per, D, unit_rows in ((2, 4, 128, 512), (8, 4, 64, 256),
-                                 (16, 1, 128, 128)):
+                                 (16, 1, 128, 128), (4, 32, 128, 256)):
         _parity_paged_heads(rng, G, per, D, 64, [1, 15, 17, 64, 0])
         _parity_paged_unit_edges(rng, G, per, D, unit_rows)
 
@@ -319,7 +321,9 @@ def _tpu_cases_paged_grouped():
     of 128, 16 query heads to each) and lfm2_24b_a2b (128 slots, rows of 8
     K/V heads of 64, 4 query heads to each: two heads a lane tile); and
     ouro_2_6b at 1,024 positions (16 slots, rows of 16 K/V heads of 128
-    with ONE query head each: one real query row in a 16-row tile)."""
+    with ONE query head each: one real query row in a 16-row tile) and
+    sdar_30b_a3b (32 slots, rows of 4 K/V heads of 128 with 32 query rows
+    each: a block of 4 positions x 8 query heads)."""
     from paddle_tpu.kernels import attention as A
 
     def case(S, L, bs, G, per, D):
@@ -335,7 +339,7 @@ def _tpu_cases_paged_grouped():
             ((S, 1, L), "float32")])
 
     return [case(32, 2048, 16, 2, 16, 128), case(128, 2048, 16, 8, 4, 64),
-            case(16, 1024, 16, 16, 1, 128)]
+            case(16, 1024, 16, 16, 1, 128), case(32, 1024, 16, 4, 32, 128)]
 
 
 def _parity_moe_experts(rng):
@@ -379,8 +383,10 @@ def _parity_moe_experts(rng):
 def _tpu_cases_moe_experts():
     """The hybrid serving cells' expert layers in bfloat16:
     nemotron3_nano_30b_a3b (a step's 32 tokens, 16 held relu2 experts of
-    width 1,856 at hidden 2,688) and lfm2_24b_a2b (128 tokens, 8 held gated
-    experts of width 1,536 at hidden 2,048)."""
+    width 1,856 at hidden 2,688), lfm2_24b_a2b (128 tokens, 8 held gated
+    experts of width 1,536 at hidden 2,048) and sdar_30b_a3b (a block
+    pass's 32 x 4 tokens, 16 held gated experts of width 768 at hidden
+    2,048)."""
     from paddle_tpu.kernels import moe
 
     def case(T, H, F, E, matrices):
@@ -388,7 +394,8 @@ def _tpu_cases_moe_experts():
                 [((T, H), "bfloat16"), ((T, E), "float32")]
                 + [((E, F, H), "bfloat16")] * matrices)
 
-    return [case(32, 2688, 1856, 16, 2), case(128, 2048, 1536, 8, 3)]
+    return [case(32, 2688, 1856, 16, 2), case(128, 2048, 1536, 8, 3),
+            case(128, 2048, 768, 16, 3)]
 
 
 def _parity_ssm_update(rng):

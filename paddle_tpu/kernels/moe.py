@@ -2,7 +2,8 @@
 seen from one rank).
 
 ``route`` scores every token against ALL the router's experts (sigmoid
-scores, a selection bias that moves the choice and not the weight, top-k,
+scores, or a softmax over them all; a selection bias that moves the choice
+and not the weight, top-k,
 the chosen scores normalised over all k and scaled). ``held_weights`` keeps
 of each token's k weights those of the ``held`` experts that live here
 (ids ``offset .. offset + held - 1``): a ``[T, held]`` matrix, mostly zeros.
@@ -42,12 +43,19 @@ _HI = jax.lax.Precision.HIGHEST
 _WEIGHT_BLOCKS = 8 * 1024 * 1024
 
 
-def route(x, gate_w, select_bias, k, scale, normalize, eps=1e-20):
+#: how a router turns its products into an expert's score
+_SCORES = {"sigmoid": jax.nn.sigmoid,
+           "softmax": lambda z: jax.nn.softmax(z, axis=-1)}
+
+
+def route(x, gate_w, select_bias, k, scale, normalize, eps=1e-20,
+          score="sigmoid"):
     """``(expert ids [T, k], weights [T, k])`` over all of ``gate_w``'s
     experts (``gate_w`` ``[E, H]`` and ``select_bias`` ``[E]`` float32):
-    scores and weights in float32; ``eps`` stands under the sum that the
-    chosen scores are normalised by."""
-    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), gate_w.T,
+    scores (``score``: each expert's ``sigmoid``, or a ``softmax`` over all
+    the experts) and weights in float32; ``eps`` stands under the sum that
+    the chosen scores are normalised by."""
+    s = _SCORES[score](jnp.matmul(x.astype(jnp.float32), gate_w.T,
                                   precision=_HI))
     _, idx = jax.lax.top_k(s + select_bias, k)
     w = jnp.take_along_axis(s, idx, axis=-1)

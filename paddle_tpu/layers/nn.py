@@ -32,6 +32,8 @@ __all__ = [
     "paged_attention",
     "chunk_paged_attention",
     "paged_step_feeds",
+    "paged_block_feeds",
+    "block_fill_decide",
     "rms_norm",
     "rotary_embedding",
     "gated_short_conv",
@@ -765,6 +767,51 @@ def paged_step_feeds(packed, token, length, block_size, name=None):
     return tuple(outs.values())
 
 
+def paged_block_feeds(packed, state, length, block_size, block_len,
+                      mask_token, name=None):
+    """``paged_step_feeds`` for a step that runs ``block_len`` positions a
+    slot which see one another (ops/nn.py ``paged_block_feeds``):
+    ``packed`` ``[S, 4 + block_len + ceil(length / block_size)]`` int32 from
+    the host, ``state`` ``[S, 2 * block_len]`` the pass before's block
+    (tokens, decided bits), still on the device. Returns ``(token [S, B],
+    position [S, B], bias [S, 1, L] float32, rows [S * L], write_rows
+    [S * B], held [S, B], decided [S, B])``."""
+    helper = LayerHelper("paged_block_feeds", name=name)
+    outs = {slot: helper.create_variable_for_type_inference(
+        "float32" if slot == "Bias" else packed.dtype, stop_gradient=True)
+        for slot in ("TokenOut", "Position", "Bias", "Rows", "WriteRows",
+                     "Held", "Decided")}
+    helper.append_op(
+        "paged_block_feeds",
+        {"Packed": [packed.name], "State": [state.name]},
+        {slot: [v.name] for slot, v in outs.items()},
+        {"length": int(length), "block_size": int(block_size),
+         "block_len": int(block_len), "mask_token": int(mask_token)},
+    )
+    return tuple(outs.values())
+
+
+def block_fill_decide(logits, held, decided, mask_token, name=None):
+    """One pass's decision over every slot's block (ops/nn.py
+    ``block_fill_decide``): ``logits`` ``[S, B, V]`` float32, ``held`` and
+    ``decided`` ``[S, B]`` as ``paged_block_feeds`` gave them. Returns
+    ``(state [S, 2 B]``, the next pass's block; ``host`` int32 ``[2 S]``,
+    each slot's decided position (-1: a commit pass) then its token)."""
+    helper = LayerHelper("block_fill_decide", name=name)
+    state = helper.create_variable_for_type_inference(held.dtype,
+                                                      stop_gradient=True)
+    host = helper.create_variable_for_type_inference("int32",
+                                                     stop_gradient=True)
+    helper.append_op(
+        "block_fill_decide",
+        {"Logits": [logits.name], "Held": [held.name],
+         "Decided": [decided.name]},
+        {"State": [state.name], "Host": [host.name]},
+        {"mask_token": int(mask_token)},
+    )
+    return state, host
+
+
 def chunk_paged_attention(q, k_arena, v_arena, rows, attn_bias, kv_heads,
                           sm_scale=1.0, name=None):
     """A prompt chunk's queries ``[C, heads * D]`` over ONE sequence's
@@ -830,12 +877,12 @@ def relu2(x, name=None):
 def moe_routed_experts(input, write_rows, num_rows, router_experts,
                        held_experts, ffn_dim, k, param_attrs, expert_offset=0,
                        score_scale=1.0, normalize=True, norm_epsilon=1e-20,
-                       kernel=False, name=None):
+                       kernel=False, score="sigmoid", name=None):
     """This chip's share of a routed-experts layer (ops/moe.py
     ``moe_routed_experts``): the router scores ``input`` ``[..., H]``
-    against all ``router_experts`` (sigmoid scores, a selection bias, top
-    ``k``, normalised over the k's sum + ``norm_epsilon``, times
-    ``score_scale``) and the ``held_experts`` that live here (ids from
+    against all ``router_experts`` (``score``: ``sigmoid`` scores, or a
+    ``softmax`` over all the experts; a selection bias, top ``k``,
+    normalised over the k's sum + ``norm_epsilon``, times ``score_scale``) and the ``held_experts`` that live here (ids from
     ``expert_offset``) add their FFNs' part; no capacity, no dropped token.
     ``write_rows`` marks the real tokens (a row ``>= num_rows`` is routed
     nowhere). ``param_attrs``: ``gate`` ``[E_all, H]`` and ``select_bias``
@@ -871,7 +918,11 @@ def moe_routed_experts(input, write_rows, num_rows, router_experts,
         {"k": int(k), "score_scale": float(score_scale),
          "normalize": bool(normalize), "norm_epsilon": float(norm_epsilon),
          "expert_offset": int(expert_offset),
-         "num_rows": int(num_rows), "kernel": bool(kernel)},
+         "num_rows": int(num_rows), "kernel": bool(kernel),
+         # written only where it is not the default: a program that
+         # scores by sigmoid keeps the bytes it had (the compile cache's
+         # key: ROADMAP 3.13)
+         **({"score": str(score)} if score != "sigmoid" else {})},
     )
     return out, counts
 

@@ -137,8 +137,9 @@ def _moe_routed_experts_pallas(ins, attrs):
 
 def _moe_routed_experts(ins, attrs, kernel):
     """The held experts' part of a routed-experts layer (kernels/moe.py):
-    ``X`` ``[T, H]``, the router ``GateW`` ``[E_all, H]`` and its selection
-    bias ``SelectBias`` ``[E_all]`` over ALL the experts, ``WUp``,
+    ``X`` ``[T, H]``, the router ``GateW`` ``[E_all, H]`` (attribute
+    ``score``: ``sigmoid``, or ``softmax`` over all of them) and its
+    selection bias ``SelectBias`` ``[E_all]`` over ALL the experts, ``WUp``,
     ``WDown`` and, for gated experts, ``WGate`` ``[held, F, H]`` of the
     experts ``expert_offset .. expert_offset + held - 1`` that live here
     (with ``WGate`` an expert is ``silu(gate) * up``, without it
@@ -160,7 +161,8 @@ def _moe_routed_experts(ins, attrs, kernel):
     idx, w = moe.route(xt, first(ins, "GateW"), first(ins, "SelectBias"),
                        attrs["k"], attrs.get("score_scale", 1.0),
                        attrs.get("normalize", True),
-                       attrs.get("norm_epsilon", 1e-20))
+                       attrs.get("norm_epsilon", 1e-20),
+                       attrs.get("score", "sigmoid"))
     c = moe.held_weights(idx, w, mask, offset, held)
     if kernel is None:
         out = moe.experts_composite(xt, c, w_up, w_down, w_gate)

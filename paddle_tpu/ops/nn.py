@@ -645,6 +645,77 @@ def _paged_step_feeds(ins, attrs):
     }
 
 
+@register_op("paged_block_feeds", nondiff_inputs=("Packed", "State"))
+def _paged_block_feeds(ins, attrs):
+    """``paged_step_feeds`` for a step that runs a BLOCK of ``B`` positions
+    a slot, all of which see every earlier block and the whole of their
+    own. ``Packed`` ``[S, 4 + B + T]`` holds a slot's flag (negative: its
+    block is ``State``'s, the device array of the pass before), the block's
+    first position, its attention length (first position + B; 0: the slot
+    does not step), its first write row (the block's B rows are
+    consecutive), then the block's B tokens as the host knows them (-1: not
+    decided), then its block table. ``State`` ``[S, 2 B]``: the block's
+    tokens, then a 0/1 per position that is decided. Gives the ``[S, B]``
+    tokens the layers read (``mask_token`` where a position is not
+    decided) and positions, the ``[S, 1, L]`` bias, the ``[S * L]`` row
+    map, the ``[S * B]`` write rows, and the block's ``[S, B]`` tokens and
+    decided bits for ``block_fill_decide``."""
+    packed, state = first(ins, "Packed"), first(ins, "State")
+    L, bs = int(attrs["length"]), int(attrs["block_size"])
+    B = int(attrs["block_len"])
+    S = packed.shape[0]
+    head, given, table = packed[:, :4], packed[:, 4:4 + B], packed[:, 4 + B:]
+    own = head[:, :1] < 0
+    held = jnp.where(own, state[:, :B].astype(packed.dtype), given)
+    decided = jnp.where(own, state[:, B:].astype(packed.dtype),
+                        (given >= 0).astype(packed.dtype))
+    within = jnp.arange(bs, dtype=packed.dtype)
+    rows = (table[:, :, None] * bs + within).reshape(S, -1)[:, :L]
+    open_ = jnp.arange(L, dtype=packed.dtype) < head[:, 2:3]     # [S, L]
+    at = jnp.arange(B, dtype=packed.dtype)
+    return {
+        "TokenOut": [jnp.where(decided > 0, held, int(attrs["mask_token"]))],
+        "Position": [head[:, 1:2] + at],
+        "Bias": [jnp.where(open_, 0.0, -1e9).astype(jnp.float32)[:, None]],
+        "Rows": [rows.reshape(-1)],
+        "WriteRows": [(head[:, 3:4] + at).reshape(-1)],
+        "Held": [held],
+        "Decided": [decided],
+    }
+
+
+@register_op("block_fill_decide",
+             nondiff_inputs=("Logits", "Held", "Decided"))
+def _block_fill_decide(ins, attrs):
+    """One pass's decision over a block of ``B`` positions a slot
+    (low-confidence-first, one position a pass): among the positions not
+    decided, the one whose top candidate (``mask_token``'s logit left out)
+    has the highest softmax probability, in float32, the lowest position on
+    a tie, takes that candidate. A slot whose block was whole when the pass
+    began (its commit pass) opens the next block: nothing decided.
+    ``State`` ``[S, 2 B]`` is the block after the pass (tokens, then
+    decided bits: the next pass's ``paged_block_feeds`` input); ``Host``
+    int32 ``[2 S]`` the position each slot decided (-1: a commit pass), then
+    its token."""
+    logits = first(ins, "Logits").astype(jnp.float32)          # [S, B, V]
+    held, decided = first(ins, "Held"), first(ins, "Decided") > 0
+    B = held.shape[1]
+    vocab = jnp.arange(logits.shape[-1])
+    logits = jnp.where(vocab == int(attrs["mask_token"]), -jnp.inf, logits)
+    cand = jnp.argmax(logits, axis=-1).astype(held.dtype)       # [S, B]
+    conf = jnp.exp(jnp.max(logits, axis=-1)
+                   - jax.nn.logsumexp(logits, axis=-1))
+    pick = jnp.argmax(jnp.where(decided, -1.0, conf), axis=-1)  # [S]
+    commit = jnp.all(decided, axis=-1)                          # [S]
+    chosen = jnp.arange(B)[None, :] == pick[:, None]
+    token = jnp.take_along_axis(cand, pick[:, None], axis=1)[:, 0]
+    tokens = jnp.where(chosen, cand, held)
+    bits = (decided | chosen) & ~commit[:, None]
+    state = jnp.concatenate([tokens, bits.astype(held.dtype)], axis=1)
+    host = jnp.concatenate([jnp.where(commit, -1, pick), token])
+    return {"State": [state], "Host": [host.astype(jnp.int32)]}
+
+
 @register_op("chunk_paged_attention", nondiff_inputs=("Rows", "Bias"))
 def _chunk_paged_attention(ins, attrs):
     """A prompt chunk's queries ``[C, heads * D]`` over one sequence's

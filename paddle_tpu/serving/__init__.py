@@ -45,6 +45,7 @@ from paddle_tpu.serving.decode import (
     build_lfm2_model,
     build_nemotron_h_model,
     build_ouro_model,
+    build_sdar_model,
 )
 from paddle_tpu.serving.engine import ServingEngine
 from paddle_tpu.serving.fleet import (
@@ -78,6 +79,7 @@ __all__ = [
     "build_nemotron_h_model",
     "build_lfm2_model",
     "build_ouro_model",
+    "build_sdar_model",
     "Priority",
     "RejectedError",
     "ReplicaLostError",

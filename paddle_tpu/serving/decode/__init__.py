@@ -62,7 +62,8 @@ from paddle_tpu.serving.decode.generate import (
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.hybrid import (
-    build_lfm2_model, build_nemotron_h_model, build_ouro_model)
+    build_lfm2_model, build_nemotron_h_model, build_ouro_model,
+    build_sdar_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -89,5 +90,6 @@ __all__ = [
     "build_nemotron_h_model",
     "build_lfm2_model",
     "build_ouro_model",
+    "build_sdar_model",
     "prompt_key",
 ]

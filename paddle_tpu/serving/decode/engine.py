@@ -66,6 +66,28 @@ chain the pool cannot cover stays in the QUEUE (``_pick(fits=)``) and
 takes no slot; nothing parks, nothing fails mid-generation, and an arrival
 whose chain is covered joins the launch-ahead order without a drain.
 
+**Answers filled a block at a time** (``DecodeModel.fills_blocks``: a
+block-diffusion decoder). The decode program is a BLOCK PASS over each
+stepping slot's current block of ``block_len`` positions, and a slot's
+block costs one FILL pass a position not yet decided, each deciding one
+more position in the order the model's confidence gives, then a COMMIT
+pass that leaves the block's final K/V rows and opens the next block. The
+host knows without a fetch which pass of which block a slot is in
+(`_Slot.bpass` of `_Slot.fills`), so launch-ahead stands as it is: the
+block's state (tokens, decided bits) is the pass's device output and the
+next launch's feed, and the one fetch brings each slot's decided position
+and token. A delivered pass hands a slot 0 to ``block_len`` tokens: the
+answer grows in POSITION order (`_sample_blocks`), a token decided ahead of
+its turn waits on the host; the cursor moves by ``block_len`` when a block
+is committed; a prompt's first ``len - len % block_len`` tokens go through
+the chunk program under the block mask (`DecodeModel.chunk_bias`) and the
+rest open the first block as positions already decided, so the last
+chunk's logits row is never fetched. ``result()`` carries ``decided_at``
+beside ``tokens``: the pass of its block that decided each token.
+Sampling, beams, grammars, speculation, a prefix cache and a host tier are
+refused for such a model (`submit`, `KVStore.check_carries`): a block's
+rows are not final until it is committed.
+
 Multi-tenancy: one engine hosts N ``(model, version)`` entries, each with
 its own slot batch, queue, and scheduler thread. Admission applies
 per-tenant quotas (queued rows reject at the door; in-flight caps make
@@ -222,12 +244,24 @@ class _Slot:
     KV row. ``ahead`` counts the slot's tokens that a launched decode step
     has produced on the device and the host has not read yet: ``cursor``
     already counts their rows, ``generated`` and ``last_token`` do not
-    hold them."""
+    hold them.
+
+    A slot of a model that fills blocks (module docstring): ``cursor`` is
+    the first position of the block whose next pass is to be LAUNCHED,
+    ``fills`` the fill passes that block needs (the positions not decided
+    when it opened) and ``bpass`` how many of its passes are launched
+    (``bpass == fills``: the next is its commit pass); ``plen`` is the
+    prompt's whole blocks, which the chunk program lands. Of the passes
+    DELIVERED: ``block`` is the current block's tokens (-1: not decided),
+    ``pending`` the answer tokens decided ahead of their turn (answer index
+    -> token, delivery time, pass) and ``decided_at`` the pass of its block
+    that decided each token of ``generated``."""
 
     __slots__ = ("request", "mode", "cursor", "last_token", "generated",
                  "kv", "plen", "done", "toks", "sampling", "grammar", "beam",
                  "score", "seq", "ahead", "d_entry", "d_slot", "draft_kv",
-                 "d_cursor")
+                 "d_cursor", "block", "fills", "bpass", "pending",
+                 "decided_at")
 
     def __init__(self, request, mode="decode", seq=0):
         self.request = request
@@ -249,6 +283,10 @@ class _Slot:
         self.d_slot = None
         self.draft_kv = None    # SeqKV on the draft entry
         self.d_cursor = 0
+        self.block = None       # block filling: set by `_start_blocks`
+        self.fills = self.bpass = 0
+        self.pending = None
+        self.decided_at = None
 
 
 class _BeamGroup:
@@ -281,13 +319,15 @@ class _LaunchedStep:
     the step's share of ``serving_decode_step_seconds`` that its
     delivery still owes. ``launch`` is the number its ``decode::step``
     span carries, for the ``decode::step_fetch`` that lands it (None
-    when the launch was not traced)."""
+    when the launch was not traced). ``blocks`` is, for a model that fills
+    blocks, each of ``states``' ``(block's first position, pass of the
+    block, whether it is the commit pass)`` at the launch."""
 
     __slots__ = ("fetches", "active", "states", "groups", "tokens_only",
-                 "host_s", "launch")
+                 "host_s", "launch", "blocks")
 
     def __init__(self, fetches, active, states, groups, tokens_only,
-                 launch=None):
+                 launch=None, blocks=None):
         self.fetches = fetches
         self.active = active
         self.states = states
@@ -295,6 +335,7 @@ class _LaunchedStep:
         self.tokens_only = tokens_only
         self.host_s = 0.0
         self.launch = launch
+        self.blocks = blocks
 
 
 class _LaunchedChunk:
@@ -490,7 +531,8 @@ class _ModelEntry:
         m = self._model
         L, V = m.max_len, m.vocab_size
         self._no_tokens = jax.device_put(
-            np.zeros((m.slots, 1), jax.dtypes.canonicalize_dtype(np.int64)),
+            np.zeros((m.slots, m.step_state_width),
+                     jax.dtypes.canonicalize_dtype(np.int64)),
             self._engine.device)
         chunked = bool(m.chunk_tokens) and m.chunk_program is not None
 
@@ -776,9 +818,18 @@ class _ModelEntry:
         cursor shows: its launched tokens, read or not, leave room under
         ``max_new`` and ``max_len``. Only a slot with a token in flight
         can say no (any other was retired when its last token landed);
-        an ``eos_id`` is not known yet and costs one wasted row."""
+        an ``eos_id`` is not known yet and costs one wasted row. A slot
+        that fills blocks steps until every fill pass of the block that
+        holds its answer's last position is launched (that block needs no
+        commit pass: nothing is generated after it)."""
+        m = self._model
+        if m.fills_blocks:
+            req = st.request
+            last = len(req.prompt) + req.max_new - 1
+            return (st.cursor + m.block_len <= last
+                    or st.bpass < st.fills)
         return (len(st.generated) + st.ahead < st.request.max_new
-                and st.cursor < self._model.max_len)
+                and st.cursor < m.max_len)
 
     def _drain_reason(self):
         """Why the step in flight has to be fetched and delivered before
@@ -1246,11 +1297,18 @@ class _ModelEntry:
         if self._takes_chunks(req):
             st = _Slot(req, "prefill", self._admit_seq)
             st.kv = self._acquire_blocks(req)
-            # the FINAL chunk always runs (it produces the last-position
-            # logits), even when the radix served every block, or the
-            # host tier the blocks past them
-            st.done = min(max(st.kv.shared_len, self.kv.restore_prefix(
-                st.kv, prompt, req.id)), plen - 1)
+            if self._model.fills_blocks:
+                # the prompt's whole blocks are the chunk program's; what
+                # is left opens the first block, and no row is fetched
+                st.plen -= plen % self._model.block_len
+                if not st.plen:
+                    self._start_blocks(st)
+            else:
+                # the FINAL chunk always runs (it produces the
+                # last-position logits), even when the radix served every
+                # block, or the host tier the blocks past them
+                st.done = min(max(st.kv.shared_len, self.kv.restore_prefix(
+                    st.kv, prompt, req.id)), plen - 1)
             self._slots[slot] = st
             self._metrics.incr("admitted")
             self._metrics.tenant_incr("admitted", req.tenant)
@@ -1327,6 +1385,21 @@ class _ModelEntry:
             self._retire(slot)
             return None
         return now
+
+    def _start_blocks(self, st):
+        """A block-filling slot whose prompt's whole blocks are launched
+        starts stepping: its first block opens at the end of them, the
+        prompt's last ``len % block_len`` tokens in it as positions
+        already decided. Nothing is fetched: its first token comes out of
+        a block pass."""
+        B = self._model.block_len
+        left = [int(t) for t in st.request.prompt[st.plen:]]
+        st.mode = "decode"
+        st.cursor = st.plen
+        st.block = left + [-1] * (B - len(left))
+        st.fills, st.bpass = B - len(left), 0
+        st.pending, st.decided_at = {}, []
+        st.sampling = st.request.sampling
 
     def _prefill_feeds(self, prompt):
         m = self._model
@@ -1413,7 +1486,7 @@ class _ModelEntry:
                 f"deadline expired during chunked prefill after "
                 f"{st.done}/{st.plen} tokens"), slot=s)
             return 1
-        C, L = m.chunk_tokens, m.max_len
+        C = m.chunk_tokens
         start = st.done
         stop = min(start + C, st.plen)
         real = stop - start
@@ -1423,17 +1496,15 @@ class _ModelEntry:
         toks[0, :real] = req.prompt[start:stop]
         pos = np.zeros((1, C), "int64")
         pos[0, :real] = np.arange(start, stop)
-        bias = np.full((1, C, L), NEG_INF, "float32")
-        bias[0, :real] = np.where(
-            np.arange(L)[None, :] <= (start + np.arange(real))[:, None],
-            np.float32(0.0), np.float32(NEG_INF))
+        bias = m.chunk_bias(start, real)
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::chunk") as ev:
                 faults.fire("decode.chunk")
                 if ev.span is not None:
                     ev.span.set(request=req.id, tokens=real, ahead=ahead,
-                                last=last, passes=m.passes)
+                                last=last, passes=m.passes,
+                                block_mask=m.fills_blocks)
                 feeds = {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
@@ -1460,6 +1531,9 @@ class _ModelEntry:
         self._metrics.observe_chunk(real, time.perf_counter() - t0, ahead)
         st.done = stop
         if not last:
+            return 1
+        if m.fills_blocks:
+            self._start_blocks(st)
             return 1
         # the one row a prompt's last chunk is run for, [V] of the
         # [1, C, V] that stay on the device
@@ -2051,6 +2125,9 @@ class _ModelEntry:
                 self._land_chunks(deferred=False)
             return
         feeds, active, groups = built
+        states = [self._slots[s] for s in active]
+        blocks = ([(st.cursor, st.bpass, st.bpass == st.fills)
+                   for st in states] if self._model.fills_blocks else None)
         t0 = time.perf_counter()
         launch = None
         try:
@@ -2060,7 +2137,8 @@ class _ModelEntry:
                 if ev.span is not None:
                     launch = self._metrics.count("step_launches")
                     ev.span.set(ahead=prev is not None, launch=launch,
-                                passes=self._model.passes)
+                                passes=self._model.passes,
+                                **self._block_attrs(blocks))
         except Exception as e:
             # a failed donated call leaves the arena undefined: every
             # in-flight sequence is lost (failed loudly; with a step in
@@ -2071,9 +2149,9 @@ class _ModelEntry:
             return
         if self._breaker is not None:
             self._breaker_event(self._breaker.record_success())
-        step = _LaunchedStep(fetches, active,
-                             [self._slots[s] for s in active], groups,
-                             self._tokens_suffice(active, groups), launch)
+        step = _LaunchedStep(fetches, active, states, groups,
+                             self._tokens_suffice(active, groups), launch,
+                             blocks)
         self._advance_cursors(step.states)
         if prev is not None:
             self._metrics.incr("decode_steps_ahead")
@@ -2101,12 +2179,33 @@ class _ModelEntry:
         and the cursor moved, so the next `_step_feeds` builds from
         positions that count this step whether or not its tokens have
         been read. Who steps again follows (`_steps_again`). A beam
-        group's cursors move with its selection, in `_sample`."""
+        group's cursors move with its selection, in `_sample`. A slot that
+        fills blocks counts the pass; its cursor and its appended rows
+        move by a block when the pass launched was the block's commit."""
         note_append = self.kv.note_append
+        B = self._model.block_len
         for st in states:
-            note_append(st.kv, st.cursor)
-            st.cursor += 1
             st.ahead += 1
+            if B == 1:
+                note_append(st.kv, st.cursor)
+                st.cursor += 1
+                continue
+            st.bpass += 1
+            if st.bpass > st.fills:
+                for p in range(st.cursor, st.cursor + B):
+                    note_append(st.kv, p)
+                st.cursor += B
+                st.fills, st.bpass = B, 0
+
+    def _block_attrs(self, blocks):
+        """What a launch or fetch span says of a block pass: the block's
+        length, the slots whose pass is a commit and those that decide a
+        token; nothing for a model that steps a token a slot."""
+        if blocks is None:
+            return {}
+        commit = sum(1 for _p0, _k, last in blocks if last)
+        return {"block_len": self._model.block_len, "commit": commit,
+                "decided": len(blocks) - commit}
 
     def _drain(self, why):
         """Fetch and deliver the step in flight with nothing launched
@@ -2129,10 +2228,14 @@ class _ModelEntry:
         with _span("decode::step_fetch") as sp:
             tokens = counts = None
             if m.counts_fetch is not None:
-                # the S tokens and the step's counts in ONE vector
+                # the S tokens (a block pass: each slot's decided position,
+                # then its token) and the step's counts in ONE vector
                 both = self._fetch(step.fetches[2])
-                tokens, counts = (both[:m.slots].reshape(m.slots, 1),
-                                  both[m.slots:])
+                if m.fills_blocks:
+                    tokens, counts = both[:2 * m.slots], both[2 * m.slots:]
+                else:
+                    tokens, counts = (both[:m.slots].reshape(m.slots, 1),
+                                      both[m.slots:])
             if not step.tokens_only:
                 fetched = self._fetch(step.fetches[0])       # [S, 1, V]
                 self._metrics.incr("decode_logits_fetch_steps")
@@ -2147,6 +2250,7 @@ class _ModelEntry:
                     sp.set(drain=drain)
                 if step.launch is not None:
                     sp.set(launch=step.launch)
+                sp.set(**self._block_attrs(step.blocks))
         # what the device did in this step, wasted slots included
         if counts is not None:
             for name, n in zip(m.count_names, counts):
@@ -2158,13 +2262,16 @@ class _ModelEntry:
                   if self._slots[s] is st]
         now = time.perf_counter()
         with _span("decode::sample") as sp:
-            stepped = self._sample(fetched, active, step.groups, now,
-                                   step.tokens_only)
+            if m.fills_blocks:
+                stepped, new = self._sample_blocks(fetched, step, now)
+            else:
+                stepped = new = self._sample(fetched, active, step.groups,
+                                             now, step.tokens_only)
             if sp is not None:
-                sp.set(tokens=stepped)
+                sp.set(tokens=new)
         if stepped is not None:
             self._metrics.observe_step(
-                stepped, stepped, time.perf_counter() - t0 + step.host_s)
+                stepped, new, time.perf_counter() - t0 + step.host_s)
 
     def _step_feeds(self):
         """The decode step's feeds from the live slots: ``(feeds, active
@@ -2252,10 +2359,17 @@ class _ModelEntry:
                     groups.append(st.beam)
             else:
                 active.append(s)
-            m.fill_step(step, s, st.cursor, st.kv.table,
-                        st.kv.row_of(st.cursor),
-                        -1 if st.ahead else st.last_token)
-            reads = st.cursor // m.block_size + 1
+            if m.fills_blocks:
+                # the block from the device where a pass of it is in
+                # flight, else as the host knows it
+                m.fill_block(step, s, st.cursor, st.kv.table,
+                             st.kv.row_of(st.cursor),
+                             None if st.ahead else st.block)
+            else:
+                m.fill_step(step, s, st.cursor, st.kv.table,
+                            st.kv.row_of(st.cursor),
+                            -1 if st.ahead else st.last_token)
+            reads = (st.cursor + m.block_len - 1) // m.block_size + 1
             live_blocks += reads
             if self.kv.copy_unit:
                 copy_units += -(-reads // self.kv.copy_unit)
@@ -2342,6 +2456,56 @@ class _ModelEntry:
                     f"{len(group.finished)} finished hypotheses"))
         return stepped
 
+    def _sample_blocks(self, fetched, step, now):
+        """`_sample` for a delivered BLOCK PASS: ``fetched`` is each
+        slot's decided position in its block (-1: the pass was the block's
+        commit) and then each slot's token. A commit opens the next block
+        on the host's copy. A fill puts the token where it was decided and
+        the answer then grows in POSITION order: a token decided ahead of
+        an earlier position waits in ``pending`` (with the time this pass
+        was delivered, which is the stamp it gets) until every position
+        before it is decided, so a pass hands the stream 0 to
+        ``block_len`` tokens. Returns ``(slot passes delivered, tokens
+        decided)``."""
+        m = self._model
+        S, B = m.slots, m.block_len
+        where, token = fetched[:S], fetched[S:]
+        stepped = decided = 0
+        for s, st, (p0, k, commit) in zip(step.active, step.states,
+                                          step.blocks):
+            if self._slots[s] is not st:
+                continue    # retired or rejected since the launch
+            stepped += 1
+            st.ahead -= 1
+            req = st.request
+            self._metrics.count_block_pass("commit" if commit else "fill")
+            if commit:
+                st.block = [-1] * B
+                self._metrics.incr("blocks_committed")
+            else:
+                at = int(where[s])
+                st.block[at] = int(token[s])
+                st.pending[p0 + at - len(req.prompt)] = (
+                    int(token[s]), now, k)
+                decided += 1
+                while (len(st.generated) in st.pending
+                       and not self._finished(st)):
+                    tok, stamp, at_pass = st.pending.pop(len(st.generated))
+                    st.generated.append(tok)
+                    st.last_token = tok
+                    st.decided_at.append(at_pass)
+                    req.response.token_times.append(stamp)
+                    self._metrics.tenant_incr("tokens", req.tenant)
+            # finished wins over expired, as in `_sample`
+            if self._finished(st):
+                self._retire(s)
+            elif req.expired(now):
+                self._reject_in_flight(req, DeadlineExceededError(
+                    "deadline expired mid-generation after "
+                    f"{len(st.generated)} tokens"), slot=s)
+        self._metrics.incr("block_tokens_decided", decided)
+        return stepped, decided
+
     def _finished(self, st):
         m = self._model
         # the cursor as of the slot's last token on the host: a step
@@ -2352,8 +2516,10 @@ class _ModelEntry:
 
     def _retire(self, slot):
         st = self._vacate(slot)
-        self._complete(st.request, {
-            "tokens": np.asarray(st.generated, dtype="int64")})
+        outputs = {"tokens": np.asarray(st.generated, dtype="int64")}
+        if st.decided_at is not None:
+            outputs["decided_at"] = np.asarray(st.decided_at, dtype="int64")
+        self._complete(st.request, outputs)
 
     def _complete(self, req, outputs):
         self._engine._tenant_unflight(req.tenant)
@@ -2750,7 +2916,8 @@ class GenerationEngine:
                draft_version=None, spec_k=4, sampling=None,
                beam_width=None, grammar=None, draft_kv=True):
         """Admit one generation request; returns its Response future
-        (``result()`` -> ``{"tokens": int64 array}``). Raises structured
+        (``result()`` -> ``{"tokens": int64 array}``, and ``"decided_at"``
+        for a model that fills blocks). Raises structured
         RejectedError on invalid prompts, over-quota tenants, or a full
         queue (with a measured retry-after). ``deadline_at`` is an
         ABSOLUTE ``time.perf_counter()`` deadline (it wins over
@@ -2800,6 +2967,17 @@ class GenerationEngine:
             sampling = SamplingParams(**sampling)
         if sampling is not None and not isinstance(sampling, SamplingParams):
             self._bad(entry, "sampling must be a SamplingParams or dict")
+        if m.fills_blocks and (
+                beam_width is not None or draft_model is not None
+                or grammar is not None
+                or (sampling is not None and not sampling.greedy)):
+            self._bad(entry, f"model {m.label} fills its answer a block of "
+                             f"{m.block_len} positions at a time, in the "
+                             "order its own confidence gives, and a "
+                             "block's K/V rows are not final until it is "
+                             "committed: sampling, beam search, grammars "
+                             "and speculative decoding are not served "
+                             "for it")
         if m.chunks_only and (beam_width is not None
                               or draft_model is not None):
             # a fork re-injects copied K/V rows and a verify re-derives

@@ -71,6 +71,22 @@ pass's normed stream (``sigmoid(w . h + b)``); at the published
 distribution rides to the host as two counts beside the step's tokens
 (``LOOP_COUNTS``). A threshold under 1 is refused: leaving early is another
 result, and the scheduler steps every slot at one depth.
+
+**``sdar_moe``** (JetLM SDAR with routed experts, a block-diffusion
+decoder: ``build_sdar_model``). Every layer is grouped-query attention with
+an RMSNorm over each head of q and k and rotary positions (as
+``lfm2_moe``'s attention layers) and then routed SwiGLU experts: a SOFTMAX
+over all the router's experts, top k, the chosen weights over their sum, no
+selection bias, no shared expert, no dense layer. A final RMSNorm and an
+untied head; no bias anywhere. What makes it the family is the mask and the
+generation rule (``DecodeModel``, "a model that generates by filling
+blocks"): a position sees every earlier block of ``block_len`` positions
+and the whole of its own, and the decode program is a block pass over ``[S,
+block_len]`` positions whose queries ride K/V-head-major into the one
+``paged_attention`` op (a K/V head's ``block_len x group`` query rows
+together, all under the slot's one bias row), whose experts see ``S x
+block_len`` tokens, and whose last op decides one position a slot
+(``block_fill_decide``).
 """
 
 import math
@@ -78,7 +94,7 @@ import math
 from paddle_tpu.serving.decode.model import DecodeModel, _state_var
 
 __all__ = ["build_nemotron_h_model", "build_lfm2_model", "build_ouro_model",
-           "MOE_COUNTS", "LOOP_COUNTS"]
+           "build_sdar_model", "MOE_COUNTS", "LOOP_COUNTS"]
 
 #: what the decode step's ``Counts`` hold, in order: the engine adds them
 #: to the counters of these names when the step's tokens come back
@@ -203,6 +219,17 @@ class _Parts:
             h, epsilon=self.eps, out_dtype=out_dtype or self.dtype,
             param_attr=self.attr(suffix, ConstantInitializer(1.0)))
 
+    def normed_rotated_heads(self, t, n, positions, suffix, theta):
+        """QK-norm (an RMSNorm over a head, weight ``suffix``), then the
+        rotation at ``positions``, over each of ``t``'s ``n`` heads."""
+        fluid = self.fluid
+        lead, d = [int(x) for x in t.shape[:2]], int(t.shape[-1]) // n
+        t = self.norm(fluid.layers.reshape(t, lead + [n, d]), suffix,
+                      out_dtype="float32")
+        return fluid.layers.reshape(fluid.layers.rotary_embedding(
+            t, positions, theta=float(theta), out_dtype=self.dtype),
+            lead + [n * d])
+
     def slot_state(self, program, index):
         name, shape, dtype = self.slot_states[index]
         return _state_var(program, self.startup, name, shape, dtype=dtype)
@@ -216,14 +243,16 @@ class _Parts:
                            dtype=self.dtype))
 
     def write(self, program, i, wrows, k, v, axis):
-        """Scatter the new K/V rows and persist (the lowering donates the
-        arenas); attention reads the written views."""
+        """Scatter the new K/V rows (``axis``: the one to squeeze out of
+        ``k`` and ``v``, or None for ``[rows, kv_width]`` as they are) and
+        persist (the lowering donates the arenas); attention reads the
+        written views."""
         fluid = self.fluid
         kc, vc = self.arenas(program, i)
-        nk = fluid.layers.block_scatter_write(
-            kc, wrows, fluid.layers.squeeze(k, [axis]))
-        nv = fluid.layers.block_scatter_write(
-            vc, wrows, fluid.layers.squeeze(v, [axis]))
+        flat = ((lambda t: t) if axis is None
+                else (lambda t: fluid.layers.squeeze(t, [axis])))
+        nk = fluid.layers.block_scatter_write(kc, wrows, flat(k))
+        nv = fluid.layers.block_scatter_write(vc, wrows, flat(v))
         fluid.layers.assign(nk, output=kc)
         fluid.layers.assign(nv, output=vc)
         return nk, nv
@@ -231,13 +260,17 @@ class _Parts:
 
 def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                   block_size, num_blocks, chunk_tokens, kv_heads, sm_scale,
-                  eos_id, name, version, count_names=MOE_COUNTS, passes=1):
+                  eos_id, name, version, count_names=MOE_COUNTS, passes=1,
+                  block_len=1, mask_token=None):
     """The hybrid family's two programs around ``stack(program, toks,
     positions, wrows, mode, attend, slot)`` (the layers over ``toks`` ``[S,
     1]`` or ``[1, C]``; ``attend(i, q, k, v)`` is the program's own
     attention over the paged arenas; returns the logits and the int32
     vectors of ``count_names`` that its layers counted, which are summed),
-    and their DecodeModel."""
+    and their DecodeModel. With ``block_len`` B > 1 the decode program is
+    a block pass (``DecodeModel``): ``toks`` ``[S, B]``, a K/V head's B x
+    group query rows side by side into ``paged_attention``, and one
+    position a slot decided at the end."""
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
     from paddle_tpu.utils import unique_name
@@ -245,14 +278,25 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
     S, L, BS, C = slots, max_len, block_size, chunk_tokens
     startup = parts.startup
 
-    # -- decode step: one token per slot at [S, 1] -----------------------
+    # -- decode step: one token per slot at [S, 1], or a block's B --------
+    B = int(block_len)
     decode = Program()
     with unique_name.guard(), program_guard(decode, startup):
-        tok, pos, bias, rows, wrows = fluid.layers.paged_step_feeds(
-            fluid.data(DecodeModel.DEC_STEP,
-                       [S, DecodeModel.STEP_TABLE + -(-L // BS)],
-                       dtype="int32"),
-            fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64"), L, BS)
+        packed = fluid.data(
+            DecodeModel.DEC_STEP,
+            [S, DecodeModel.STEP_TABLE + (B if B > 1 else 0) + -(-L // BS)],
+            dtype="int32")
+        if B > 1:
+            state = fluid.data(DecodeModel.DEC_TOKEN, [S, 2 * B],
+                               dtype="int64")
+            tok, pos, bias, rows, wrows, held, decided = (
+                fluid.layers.paged_block_feeds(packed, state, L, BS, B,
+                                               mask_token))
+        else:
+            tok, pos, bias, rows, wrows = fluid.layers.paged_step_feeds(
+                packed,
+                fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64"),
+                L, BS)
 
         def attend_step(i, q, k, v):
             nk, nv = parts.write(decode, i, wrows, k, v, 1)
@@ -261,13 +305,40 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                 sm_scale=sm_scale, block_size=BS, kv_heads=kv_heads)
             return fluid.layers.unsqueeze(ctx, [1])
 
+        def attend_block(i, q, k, v):
+            """The block's rows written FIRST, then its B positions as
+            further query rows of their K/V head: all see the same rows."""
+            width = parts.kv_width
+            nk, nv = parts.write(
+                decode, i, wrows, fluid.layers.reshape(k, [S * B, width]),
+                fluid.layers.reshape(v, [S * B, width]), None)
+            group = int(q.shape[-1]) // kv_heads       # a K/V head's lanes
+            by_head = fluid.layers.transpose(
+                fluid.layers.reshape(q, [S, B, kv_heads, group]),
+                [0, 2, 1, 3])
+            ctx = fluid.layers.paged_attention(
+                fluid.layers.reshape(by_head, [S, kv_heads * B * group]),
+                nk, nv, rows, bias, S, L, sm_scale=sm_scale, block_size=BS,
+                kv_heads=kv_heads)
+            return fluid.layers.reshape(fluid.layers.transpose(
+                fluid.layers.reshape(ctx, [S, kv_heads, B, group]),
+                [0, 2, 1, 3]), [S, B, kv_heads * group])
+
         dec_logits, counts = stack(decode, tok, pos, wrows, "step",
-                                   attend_step)
-        next_token = fluid.layers.argmax(dec_logits, axis=-1)
-        # what a greedy step hands the host in ONE fetch: the S tokens,
-        # then the step's routing counts summed over the expert layers
-        host = [fluid.layers.cast(
-            fluid.layers.reshape(next_token, [S]), "int32")]
+                                   attend_block if B > 1 else attend_step)
+        if B > 1:
+            # the block after the pass stays on the device for the next
+            # launch; the host gets each slot's decided position and token
+            next_token, decided_now = fluid.layers.block_fill_decide(
+                dec_logits, held, decided, mask_token)
+            host = [decided_now]
+        else:
+            next_token = fluid.layers.argmax(dec_logits, axis=-1)
+            # what a greedy step hands the host in ONE fetch: the S
+            # tokens, then the step's routing counts summed over the
+            # expert layers
+            host = [fluid.layers.cast(
+                fluid.layers.reshape(next_token, [S]), "int32")]
         if counts:
             host.append(fluid.layers.sums(counts) if len(counts) > 1
                         else counts[0])
@@ -303,8 +374,9 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         state_names=parts.state_names, kv_width=parts.kv_width,
         kv_dtype=parts.dtype, slot_states=parts.slot_states,
         logits_fetch=dec_logits.name, token_fetch=next_token.name,
-        counts_fetch=token_counts.name if counts else None,
+        counts_fetch=token_counts.name if counts or B > 1 else None,
         count_names=count_names if counts else (), passes=passes,
+        block_len=B, mask_token=mask_token,
         prefill_logits_fetch=None, chunk_logits_fetch=chu_logits.name,
         prefill_kv_fetches=[], inject_kv_feeds=[],
         eos_id=eos_id, name=name, version=version, builder=rebuild)
@@ -520,13 +592,8 @@ def build_lfm2_model(
         }
 
     def heads(t, n, positions, suffix):
-        """QK-norm, then the rotation, over each of ``t``'s ``n`` heads."""
-        lead = [int(d) for d in t.shape[:2]]
-        t = norm(fluid.layers.reshape(t, lead + [n, D]), suffix,
-                 out_dtype="float32")
-        return fluid.layers.reshape(fluid.layers.rotary_embedding(
-            t, positions, theta=float(rope_theta), out_dtype=dtype),
-            lead + [n * D])
+        return parts.normed_rotated_heads(t, n, positions, suffix,
+                                          rope_theta)
 
     def stack(program, toks, positions, wrows, mode, attend, slot=None):
         """The 40 layers over ``toks``: operator, then feed-forward."""
@@ -715,3 +782,96 @@ def build_ouro_model(
         slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
         kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
         version=version, count_names=LOOP_COUNTS, passes=T)
+
+
+def build_sdar_model(
+        vocab_size, hidden_size, num_hidden_layers, *, num_attention_heads,
+        num_key_value_heads, head_dim, num_experts, router_experts,
+        num_experts_per_tok, moe_intermediate_size, block_len=4,
+        denoising_steps=4, mask_token_id=None, norm_topk_prob=True,
+        rms_norm_eps=1e-6, rope_theta=1000000.0, initializer_range=0.02,
+        expert_rank=0, dtype="bfloat16", slots=4, max_len=64, block_size=16,
+        num_blocks=None, chunk_tokens=16, eos_id=None, name="sdar",
+        version="1"):
+    """Build the ``sdar_moe`` block-diffusion decoder as a paged
+    DecodeModel (module docstring). The sizes are the published
+    ``config.json``'s keys under their own names; ``num_experts`` is how
+    many experts are HELD here and ``router_experts`` how many the router
+    scores (the published count). ``block_len`` positions make a block,
+    filled over ``denoising_steps`` passes: only one position a pass is
+    served (``denoising_steps == block_len``: with more a pass the host
+    could no longer say, without a fetch, which pass of which block a slot
+    is in once a first block opens part decided). ``mask_token_id`` is what
+    an undecided position holds and what no answer may hold (default: the
+    vocabulary's last id). ``initializer_range`` as ``build_lfm2_model``'s."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import ConstantInitializer
+
+    V, H, NL = int(vocab_size), int(hidden_size), int(num_hidden_layers)
+    B = int(block_len)
+    if B < 2 or int(denoising_steps) != B:
+        raise ValueError(
+            f"block_len {block_len} with denoising_steps {denoising_steps}: "
+            "a block of at least 2 positions is filled one position a pass")
+    mask = V - 1 if mask_token_id is None else int(mask_token_id)
+    if not 0 <= mask < V:
+        raise ValueError(f"mask_token_id {mask} is not in [0, {V})")
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    NQ, NKV, D = (int(num_attention_heads), int(num_key_value_heads),
+                  int(head_dim))
+    held, router = int(num_experts), int(router_experts)
+    offset = _held(expert_rank, held, router)
+    prefix = f"{name}_v{version}"
+    # two sub-layers a layer write into the residual
+    std = float(initializer_range)
+    parts = _Parts(prefix, dtype, float(rms_norm_eps), std,
+                   std / math.sqrt(2 * NL), R, NKV * D, list(range(NL)), [])
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+
+    def heads(t, n, positions, suffix):
+        return parts.normed_rotated_heads(t, n, positions, suffix,
+                                          rope_theta)
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The ``NL`` layers over ``toks``: attention, then the experts."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        counts = []
+        for i in range(NL):
+            x = norm(h, f"l{i}.input_layernorm")
+            ctx = attend(
+                i,
+                heads(proj(x, NQ * D, f"l{i}.q", out_dtype="float32"),
+                      NQ, positions, f"l{i}.q_norm"),
+                heads(proj(x, NKV * D, f"l{i}.k", out_dtype="float32"),
+                      NKV, positions, f"l{i}.k_norm"),
+                proj(x, NKV * D, f"l{i}.v"))
+            h = fluid.layers.elementwise_add(h, proj(
+                ctx, H, f"l{i}.o", residual=True, out_dtype="float32"))
+            out, n = fluid.layers.moe_routed_experts(
+                norm(h, f"l{i}.post_attention_layernorm"), wrows, R, router,
+                held, int(moe_intermediate_size), int(num_experts_per_tok),
+                {"gate": matrix(f"l{i}.gate"),
+                 # this router's choice is its scores' own
+                 "select_bias": attr(f"l{i}.select_bias",
+                                     ConstantInitializer(0.0)),
+                 "w_gate": matrix(f"l{i}.w1"),
+                 "w_up": matrix(f"l{i}.w3"),
+                 "w_down": matrix(f"l{i}.w2", residual=True)},
+                expert_offset=offset, normalize=bool(norm_topk_prob),
+                kernel=mode == "step", score="softmax")
+            counts.append(n)
+            h = fluid.layers.elementwise_add(h, out)
+        logits = proj(norm(h, "norm"), V, "head", out_dtype="float32")
+        return logits, counts
+
+    return _hybrid_model(
+        parts, stack, lambda: build_sdar_model(**kwargs), vocab=V, hidden=H,
+        slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
+        kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
+        version=version, block_len=B, mask_token=mask)

@@ -39,7 +39,8 @@ rows; a per-slot recurrent state is no function of them, and a model
 without an inject program (``chunks_only``) could never take rows back. So
 `GenerationEngine.register_model` refuses such a model a prefix cache or a
 tier, its blocks are never registered for sharing and its sessions never
-spill.
+spill. A model that fills its answer a block at a time is refused both as
+well: a block's rows are rewritten by every pass until it is committed.
 """
 
 import numpy as np
@@ -149,8 +150,19 @@ class KVStore:
     def check_carries(model, tier_bytes, prefix_cache_size):
         """Refuse, before anything is built, a model whose state a store
         of these sizes could not carry."""
+        from paddle_tpu.serving.request import ServingError
         from paddle_tpu.utils.enforce import EnforceError
 
+        if model.fills_blocks and (prefix_cache_size or tier_bytes):
+            raise ServingError(
+                f"model {model.label} fills its answer a block of "
+                f"{model.block_len} positions at a time: a block's K/V "
+                "rows are rewritten by every pass and final only once it "
+                "is committed, so neither the prefix cache nor the host KV "
+                "tier may hold them. Host it on an engine with "
+                "prefix_cache_size=0 and host_tier_mb=0 (got "
+                f"prefix_cache_size={prefix_cache_size}, "
+                f"host_tier_mb={tier_bytes >> 20})")
         if model.recurrent and (prefix_cache_size or tier_bytes):
             raise EnforceError(
                 f"model {model.label} keeps per-slot recurrent state, "

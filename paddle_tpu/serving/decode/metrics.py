@@ -71,6 +71,15 @@ launch of the step program, from its start to the end of the feeds'
 ``launch=<n>`` (the value of ``serving_step_launches_total``), as does the
 ``decode::step_fetch`` that lands that step.
 
+A model that fills its answer a block at a time:
+``serving_block_passes_total{kind=fill|commit}`` counts the slots'
+delivered passes (their sum is what ``serving_active_slot_steps_total``
+moves by), ``serving_block_tokens_decided_total`` the tokens they decided
+and ``serving_blocks_committed_total`` the blocks committed;
+``decode::step`` and ``decode::step_fetch`` carry ``block_len=``,
+``commit=`` (slots whose pass is a commit) and ``decided=``,
+``decode::chunk`` says ``block_mask=``.
+
 The KV block pool and the host tier, counted where it happens
 (``pool.py``): ``serving_pool_block_allocs_total`` blocks handed out,
 ``serving_pool_evictions_total`` of them recycled a cached block,
@@ -84,7 +93,12 @@ so are in ``serving_fetched_bytes_total`` too.
 from paddle_tpu.serving.metrics import ServingMetrics
 
 __all__ = ["DecodeMetrics", "TOKEN_BUCKETS", "WAIT_BUCKETS",
-           "LAUNCH_BUCKETS", "DRAIN_REASONS"]
+           "LAUNCH_BUCKETS", "DRAIN_REASONS", "BLOCK_PASS_KINDS"]
+
+# what a delivered pass of a block-filling model was to a slot: it decided
+# one of the block's positions, or it found the block whole and left the
+# K/V rows that stay (engine.py, "Answers filled a block at a time")
+BLOCK_PASS_KINDS = ("fill", "commit")
 
 # why a step in flight was fetched with nothing launched over it: every
 # reason `engine.py _drain_reason`, `_iterate_phases` and `_step_feeds`
@@ -206,6 +220,11 @@ class DecodeMetrics(ServingMetrics):
         # the bytes of arena brought to the host to spill rows
         "pool_block_allocs", "pool_evictions", "tier_writebacks",
         "arena_read_bytes",
+        # a model that fills its answer a block at a time, per delivered
+        # pass: tokens decided (a fill pass decides one a slot) and blocks
+        # whose commit pass was delivered; the slots' passes by kind are
+        # serving_block_passes_total{kind=fill|commit}
+        "block_tokens_decided", "blocks_committed",
     )
 
     def __init__(self, engine_label=None, registry=None):
@@ -255,9 +274,18 @@ class DecodeMetrics(ServingMetrics):
             )
             for why in DRAIN_REASONS
         }
+        self._block_passes = {
+            kind: self._registry.counter(
+                "serving_block_passes_total",
+                "a slot's delivered passes over its block, by kind",
+                labels={**labels, "kind": kind},
+            )
+            for kind in BLOCK_PASS_KINDS
+        }
         for h in (self._step, self._prefill, self._chunk,
                   self._first_token, self._inter_token, self._wait,
-                  self._step_put, self._step_call, *self._drains.values()):
+                  self._step_put, self._step_call, *self._drains.values(),
+                  *self._block_passes.values()):
             h.reset()
 
     def observe_step(self, active_slots, new_tokens, seconds):
@@ -280,6 +308,16 @@ class DecodeMetrics(ServingMetrics):
     def count_drain(self, why):
         """One step in flight drained, for the reason ``why``."""
         self._drains[why].inc()
+
+    def count_block_pass(self, kind):
+        """One slot's delivered pass over its block: a ``fill`` (it
+        decided a position) or the block's ``commit``."""
+        self._block_passes[kind].inc()
+
+    def block_passes(self):
+        """{kind: count} of the slots' delivered block passes."""
+        return {kind: int(c.value)
+                for kind, c in self._block_passes.items()}
 
     def drains(self):
         """{why: count} of the drains so far."""
@@ -345,6 +383,7 @@ class DecodeMetrics(ServingMetrics):
         out.update(self._step_put.snapshot("step_put"))
         out.update(self._step_call.snapshot("step_call"))
         out["decode_drains"] = self.drains()
+        out["block_passes"] = self.block_passes()
         if extra:
             out.update(extra)
         return out

@@ -117,7 +117,24 @@ class DecodeModel:
     ``DEC_TOKEN`` (``[S, 1]``, a device array: the step before's tokens
     where ``DEC_STEP`` says -1) and, with ``logits_mask``, ``DEC_MASK``.
     The program's ``paged_step_feeds`` op turns the first two into the
-    tokens, positions, bias, row map and write rows its layers read."""
+    tokens, positions, bias, row map and write rows its layers read.
+
+    **A model that generates by filling blocks** (``block_len`` B > 1,
+    ``mask_token``): positions come in blocks of B; a position sees every
+    earlier block and the WHOLE of its own, prompt and answer alike
+    (`chunk_bias`). The decode program is then a BLOCK PASS: it runs the B
+    positions of each stepping slot's current block (a position not yet
+    decided holds ``mask_token``), rewrites their K/V rows, and decides ONE
+    more position a slot, the one its own confidence ranks first; a pass
+    that finds its block whole (the COMMIT pass) leaves the rows that stay
+    and opens the next block. So a block of u undecided positions costs u
+    fill passes and a commit pass. ``DEC_STEP`` is ``[S, 4 + B + blocks]``
+    (`fill_block`: the block's first position, its end as the length, its
+    first write row, the block's tokens as the host knows them) and
+    ``DEC_TOKEN`` ``[S, 2 B]`` the block state the pass before left on the
+    device (``token_fetch``: tokens, decided bits); ``counts_fetch`` opens
+    with ``2 S`` integers, each slot's decided position (-1: a commit
+    pass) and its token."""
 
     # feed-name contract (fixed; the engine builds these arrays)
     DEC_TOKEN = "dec_token"
@@ -146,7 +163,7 @@ class DecodeModel:
                  version="1", builder=None, logits_mask=False,
                  token_fetch=None, kv_width=None, kv_dtype="float32",
                  slot_states=(), counts_fetch=None, count_names=(),
-                 passes=1):
+                 passes=1, block_len=1, mask_token=None):
         self.decode_program = decode_program
         self.prefill_program = prefill_program
         self.inject_program = inject_program
@@ -178,6 +195,16 @@ class DecodeModel:
         self.counts_fetch = counts_fetch
         self.count_names = tuple(count_names)
         self.passes = int(passes)
+        self.block_len = int(block_len)
+        self.mask_token = mask_token
+        if self.block_len > 1 and (self.block_size % self.block_len
+                                   or self.max_len % self.block_len
+                                   or self.chunk_tokens % self.block_len):
+            raise ValueError(
+                f"block_len {self.block_len} has to divide block_size "
+                f"{self.block_size} (a block's rows lie in one page), "
+                f"max_len {self.max_len} and chunk_tokens "
+                f"{self.chunk_tokens}")
 
     @property
     def recurrent(self):
@@ -210,11 +237,29 @@ class DecodeModel:
         """Blocks a slot at ``max_len`` holds: its block table's width."""
         return -(-self.max_len // self.block_size)
 
+    @property
+    def fills_blocks(self):
+        """Whether the decode program is a block pass (class docstring)."""
+        return self.block_len > 1
+
+    @property
+    def step_table(self):
+        """The column of ``dec_step`` at which a slot's block table
+        begins: a block pass carries the block's tokens before it."""
+        return self.STEP_TABLE + (self.block_len if self.fills_blocks else 0)
+
+    @property
+    def step_state_width(self):
+        """Columns of ``dec_token``: a token a slot, or a block's tokens
+        and decided bits."""
+        return 2 * self.block_len if self.fills_blocks else 1
+
     def step_feed(self):
         """``dec_step`` of a step that no slot takes (every length 0,
         every write row ``R``, every token left to ``dec_token``): the
-        engine fills the rows of the slots that step (`fill_step`)."""
-        feed = np.zeros((self.slots, self.STEP_TABLE + self.blocks_per_slot),
+        engine fills the rows of the slots that step (`fill_step`,
+        `fill_block`)."""
+        feed = np.zeros((self.slots, self.step_table + self.blocks_per_slot),
                         "int32")
         feed[:, self.STEP_TOKEN] = -1
         feed[:, self.STEP_WRITE_ROW] = self.rows
@@ -229,6 +274,31 @@ class DecodeModel:
         feed[slot, :self.STEP_TABLE] = (token, position, position + 1,
                                         write_row)
         feed[slot, self.STEP_TABLE:] = table
+
+    def fill_block(self, feed, slot, start, table, write_row, tokens=None):
+        """Slot ``slot`` runs a pass over the block at positions ``[start,
+        start + block_len)``, attending to every position below the
+        block's end. Its K/V rows land at ``write_row`` and the rows after
+        it. ``tokens`` is the block as the host knows it (a token, or -1
+        where none is decided), or None where ``dec_token`` holds it on
+        the device."""
+        own = tokens is None
+        feed[slot, :self.STEP_TABLE] = (-1 if own else 0, start,
+                                        start + self.block_len, write_row)
+        feed[slot, self.STEP_TABLE:self.step_table] = -1 if own else tokens
+        feed[slot, self.step_table:] = table
+
+    def chunk_bias(self, start, real):
+        """The chunk program's ``[1, C, L]`` additive bias for ``real``
+        prompt positions from ``start``: a position sees what lies at or
+        before it, and with ``block_len`` B the whole of its own block."""
+        at, B = start + np.arange(real), self.block_len
+        bias = np.full((1, self.chunk_tokens, self.max_len), NEG_INF,
+                       "float32")
+        bias[0, :real] = np.where(
+            np.arange(self.max_len)[None, :] // B <= (at // B)[:, None],
+            np.float32(0.0), np.float32(NEG_INF))
+        return bias
 
     def block_table(self, blocks):
         """The ``[blocks_per_slot]`` block ids of a slot's block list (its
@@ -261,8 +331,8 @@ class DecodeModel:
     def decode_feed_sig(self):
         s = self.slots
         sig = [
-            (self.DEC_TOKEN, (s, 1), "int64"),
-            (self.DEC_STEP, (s, self.STEP_TABLE + self.blocks_per_slot),
+            (self.DEC_TOKEN, (s, self.step_state_width), "int64"),
+            (self.DEC_STEP, (s, self.step_table + self.blocks_per_slot),
              "int32"),
         ]
         if self.logits_mask:

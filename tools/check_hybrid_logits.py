@@ -1,5 +1,5 @@
 """A served hybrid configuration (``nemotron3_nano_30b_a3b``,
-``lfm2_24b_a2b``, ``ouro_2_6b``: the three families of
+``lfm2_24b_a2b``, ``ouro_2_6b``, ``sdar_30b_a3b``: the four families of
 ``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
 ``reference``) against its plain reference, outside any timed window, and
 the readings the cell's limits are set from (its traffic file; PERF.md
@@ -11,6 +11,7 @@ section 2).
         [--references float8_e4m3fn,operands:bfloat16]
         [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
         [--passes 3] [--share-passes] [--stale-arena 1,7]
+        [--replays causal,left_to_right]
         [--dump chiprun_out/rows.npz] [--rehearse-cpu]
 
 Requests of the cell's own length distribution go through the engine,
@@ -52,6 +53,18 @@ pass 0's arena pair, so that a token's older rows are its last pass's for
 every pass (the decode-time sharing the family's paper offers as an
 approximation, which nothing serves): the whole run is then the control,
 and its ``sound`` reading is what the comparison has to refuse.
+
+A model that fills its answer a block at a time (``--config sdar_30b_a3b
+--traffic chat_blocks``) is served greedy, as it only can be, and no row is
+fetched: ``behind`` alone is read (``row_sigma`` is null), each served token
+against the reference's row from the block state in which it was decided
+(the builder's replay of the response's ``decided_at``), over the tokens
+whose block lies wholly inside the answer. Its controls: ``--faults
+skip_commit`` serves every block WITHOUT its commit pass's write (the
+block's K/V rows stay as its last filling pass wrote them, one position of
+them computed while it still held the mask token); ``--replays causal``
+reads the sound tokens against the reference under a causal mask,
+``--replays left_to_right`` against a replay that ignores ``decided_at``.
 """
 
 import argparse
@@ -85,13 +98,22 @@ def _stale(entry, fault):
         names = [n for n in k_arenas if n.endswith(tag)]
     else:
         names = [n for n, _s, _d in m.slot_states if "." + fault in n][:1]
-    if fault != "positions" and not names:
+    if fault not in ("positions", "skip_commit") and not names:
         raise ValueError(f"no state of the model answers to {fault!r}")
     launch = entry._run
 
     def run(kind, feeds, span=None):
         if kind != "step":
             return launch(kind, feeds, span)
+        if fault == "skip_commit":
+            # a slot whose pass is its block's commit writes nowhere
+            step = np.array(feeds[m.DEC_STEP])
+            for s, st in enumerate(entry._slots):
+                if (st is not None and st.mode == "decode"
+                        and step[s, m.STEP_LENGTH]
+                        and st.bpass == st.fills):
+                    step[s, m.STEP_WRITE_ROW] = m.rows
+            feeds = dict(feeds, **{m.DEC_STEP: step})
         if fault == "positions":
             step = np.array(feeds[m.DEC_STEP])
             step[:, 1] += 1
@@ -120,6 +142,13 @@ def _serve(system, prompts, steps):
         return int(row.argmax())
 
     entry = system.entry
+    if entry.model.fills_blocks:
+        # greedy is all such a model serves, and the comparison replays
+        # the response's own filling order: no row is fetched
+        responses = [system.engine.submit(p, max_new_tokens=steps)
+                     for p in prompts]
+        return [([int(t) for t in r.result(timeout=1800)["tokens"]], r)
+                for r in responses]
     choose, entry._choose_token = entry._choose_token, top
     try:
         # a sampled policy brings every step's rows to the host
@@ -136,7 +165,14 @@ def _against(system, prompts, served, **how):
     """(row_sigma, behind), each ``[requests, steps]``, and what
     ``routing`` adds."""
     sigma, behind, extra = [], [], []
+    tokens = how.pop("tokens", None)
     for prompt, (out, got) in zip(prompts, served):
+        if not isinstance(got, np.ndarray):
+            # a block-filling model's response in place of rows: the
+            # builder's replay reads it under the prompt (a fault's serving
+            # of the same prompts noted its own since)
+            system.engine.noted[tuple(prompt)], got = got, None
+        out = out[:tokens]
         first = len(prompt) - 1
         want = system.reference_logits(
             list(prompt) + out[:-1], range(first, first + len(out)), **how)
@@ -144,7 +180,8 @@ def _against(system, prompts, served, **how):
             want, *more = want
             extra.append(more)
         std = want.std(1)
-        sigma.append(np.abs(got - want).max(1) / std)
+        sigma.append(np.full(len(out), np.nan) if got is None
+                     else np.abs(got[:len(out)] - want).max(1) / std)
         behind.append((want.max(1) - want[np.arange(len(out)), out]) / std)
     return np.stack(sigma), np.stack(behind), extra
 
@@ -174,9 +211,10 @@ def _summary(sigma, behind, traffic):
     tokens = traffic["check_tokens"]
     worst = behind[:, :tokens].max(1)
     return {
-        "row_sigma": {"median": float(np.median(sigma)),
-                      "p90": float(np.percentile(sigma, 90)),
-                      "worst": float(sigma.max())},
+        "row_sigma": None if np.isnan(sigma).all() else {
+            "median": float(np.median(sigma)),
+            "p90": float(np.percentile(sigma, 90)),
+            "worst": float(sigma.max())},
         "behind": {"worst": float(flat.max()),
                    "share_of_tokens_over": {
                        str(t): float((flat > t).mean()) for t in OVER}},
@@ -220,6 +258,10 @@ def main(argv=None):
                     "stack: SERVE every pass of a layer from one arena pair")
     ap.add_argument("--stale-arena", default=None, metavar="PASS,LAYER",
                     help="a looped stack: that one K arena a step stale")
+    ap.add_argument("--replays", default="", help="a model that fills "
+                    "blocks: the sound tokens against the reference under "
+                    "a causal mask (causal), against a replay that ignores "
+                    "the served filling order (left_to_right)")
     ap.add_argument("--dump", default=None, help="an .npz of every "
                     "reading's row_sigma and behind, [requests, steps]")
     ap.add_argument("--rehearse-cpu", action="store_true")
@@ -288,10 +330,15 @@ def main(argv=None):
     dump = {}
     rounded = [ref for ref in args.references.split(",")
                if ref.startswith("operands:")]
+    blocks = system.entry.model.fills_blocks
+    # a block's replay needs every position of the block in the answer
+    whole = ({"tokens": steps - system.entry.model.block_len + 1}
+             if blocks else {})
     for name, answers in served.items():
         sigma, behind, routing = _against(
-            system, prompts, answers,
-            **({"routing": True} if held and name == "sound" else {}))
+            system, prompts, answers, **whole,
+            **({"routing": True} if held and name == "sound" and not blocks
+               else {}))
         report[name] = _summary(sigma, behind, traffic)
         dump[name + ".row_sigma"], dump[name + ".behind"] = sigma, behind
         if name != "sound":
@@ -302,7 +349,7 @@ def main(argv=None):
                     system, prompts, answers,
                     round_operands=ref.split(":")[1])[:2], traffic)
             continue
-        if held:
+        if held and not blocks:
             gap = np.concatenate([(s[..., -2] - s[..., -1]).reshape(-1)
                                   for _ids, s in routing])
             report["reference_margin_share_under"] = {
@@ -315,8 +362,13 @@ def main(argv=None):
                 for ref in filter(None, args.references.split(","))]
         if args.passes is not None:
             refs.append((f"passes_{args.passes}", {"passes": args.passes}))
+        for replay in filter(None, args.replays.split(",")):
+            refs.append(("replay_" + replay,
+                         {"mask": "causal"} if replay == "causal"
+                         else {"order": replay}))
         for ref, how in refs:
-            _sigma, behind, other = _against(system, prompts, answers, **how)
+            _sigma, behind, other = _against(system, prompts, answers,
+                                             **whole, **how)
             report["reference_" + ref] = _summary(_sigma, behind, traffic)
             dump[f"reference_{ref}.behind"] = behind
             if ref.startswith("operands:"):
