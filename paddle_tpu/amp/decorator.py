@@ -131,8 +131,34 @@ def rewrite_program_amp(program=None, amp_lists=None, dest_dtype=None):
                     new_names.append(n)
             op.inputs[slot] = new_names
         i += 1
+    _round_gelu_where_every_reader_casts(program, dest_dtype)
     program._bump_version()
     return program
+
+
+def _round_gelu_where_every_reader_casts(program, dest_dtype):
+    """An exact ``gelu`` whose result is read ONLY by casts to the AMP
+    dtype (a white-list product follows it: BERT's FFN) gets
+    ``round_dtype``: the op rounds its result itself and holds the rounded
+    value as one buffer (ops/common.py gelu). The casts then change no bit,
+    so every number of the program is what it was; what changes is that
+    the compiler can no longer leave the result unwritten and evaluate
+    gelu again inside each product that reads it (PERF.md section 6, PR 49)."""
+    exact = [op for op in program.global_block().ops
+             if op.type == "gelu" and not op.attrs.get("approximate", False)]
+    if not exact:
+        return
+    readers = {}
+    for block in program.blocks:
+        for op in block.ops:
+            for name in op.input_names():
+                readers.setdefault(name, []).append(op)
+    for op in exact:
+        reading = readers.get(op.outputs["Out"][0], [])
+        if reading and all(r.type == "cast"
+                           and r.attrs.get("out_dtype") == dest_dtype
+                           for r in reading):
+            op.attrs["round_dtype"] = dest_dtype
 
 
 class OptimizerWithMixedPrecision:

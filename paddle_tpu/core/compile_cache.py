@@ -195,15 +195,19 @@ def program_fingerprint(
     sharding_sig already covers the RESOLVED specs (the layout also owns
     future placement of vars this step does not touch, and two processes
     with the same layout must agree on the fingerprint without resolving
-    first). The jax version and backend are always mixed in: a version
-    bump or a backend switch invalidates every persisted entry (fall
-    back to retrace — never a wrong answer from a stale module)."""
+    first). The jax version, the backend and ``lowering.LOWERING_VERSION``
+    (the op lowerings' hand-kept version: the Program's bytes do not say
+    what a lowering emits for them) are always mixed in: a version bump
+    or a backend switch invalidates every persisted entry (fall back to
+    retrace — never a wrong answer from a stale module)."""
     import jax
 
+    from paddle_tpu.core import lowering
     from paddle_tpu.utils.flags import flags
 
     payload = {
         "ir": None,  # filled below as raw bytes, hashed separately
+        "lowering": lowering.LOWERING_VERSION,
         "feed_sig": [[n, list(s), str(d)] for n, s, d in feed_sig],
         "fetch": list(fetch_names),
         "scope_sig": [[n, list(s), str(d)] for n, s, d in scope_sig],

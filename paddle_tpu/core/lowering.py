@@ -34,7 +34,14 @@ from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.utils.enforce import EnforceError
 
 __all__ = ["LoweredStep", "lower_step", "jit_compile", "verify_for_lowering",
-           "abstract_signature", "zero_rng_key"]
+           "abstract_signature", "zero_rng_key", "LOWERING_VERSION"]
+
+#: joins every program fingerprint (compile_cache.program_fingerprint).
+#: Bump it in the PR that changes what an op lowering under ops/ or the
+#: step closure here EMITS for an unchanged Program: the persistent tier
+#: would otherwise load the executable of the old lowering. A Pallas
+#: kernel's body has its own ``KernelSpec(version=)``.
+LOWERING_VERSION = 1
 
 _JITS = obs_metrics.registry().counter(
     "lowering_jit_total", "jax.jit computations created via the chokepoint"

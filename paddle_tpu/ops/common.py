@@ -1,7 +1,11 @@
 """Shared helpers for op lowering rules."""
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from paddle_tpu.core.dtypes import to_numpy_dtype
 
@@ -96,3 +100,81 @@ def astype_like(g, ref):
 
 def flat_float(x):
     return jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
+
+
+# ---------------------------------------------------------------------------
+# gelu — the `gelu` op and fc's fused activation lower through this one
+# ---------------------------------------------------------------------------
+
+_SQRT_HALF = math.sqrt(0.5)
+_INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
+
+
+def _gelu_lowerings(form):
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    return obs_metrics.registry().counter(
+        "gelu_lowerings_total",
+        "gelu ops lowered, by the form handed to XLA",
+        labels={"form": form},
+    )
+
+
+def gelu_lowering_counts():
+    """``gelu_lowerings_total`` by form, as ``gelu`` left it."""
+    return {form: int(_gelu_lowerings(form).value)
+            for form in ("erf", "tanh")}
+
+
+def _normal_cdf(x):
+    return 0.5 * (1.0 + lax.erf(x * _SQRT_HALF))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _gelu_erf(x, round_dtype):
+    y = x * _normal_cdf(x)
+    if round_dtype is not None:
+        # the barrier makes the rounded value a buffer of its own: without
+        # it the TPU compiler writes gelu's result nowhere and evaluates it
+        # again on the way into every product that reads it
+        y = lax.optimization_barrier(y.astype(round_dtype)).astype(x.dtype)
+    return y
+
+
+def _gelu_erf_fwd(x, round_dtype):
+    return _gelu_erf(x, round_dtype), x
+
+
+def _gelu_erf_bwd(round_dtype, x, g):
+    pdf = jnp.exp(-0.5 * x * x) * _INV_SQRT_2PI
+    return (g * (_normal_cdf(x) + x * pdf),)
+
+
+_gelu_erf.defvjp(_gelu_erf_fwd, _gelu_erf_bwd)
+
+
+def gelu(x, approximate=False, round_dtype=None):
+    """gelu(x) = x * Phi(x). The exact form goes through ``lax.erf``, which
+    the TPU compiler keeps as ONE instruction, with the derivative
+    ``Phi(x) + x * phi(x)`` written out from the saved x. ``jax.nn.gelu``
+    writes it ``0.5 * x * erfc(-x / sqrt 2)``; XLA has no erfc and expands
+    it into three polynomial branches, ~65 float32 operations an element
+    forward and ~72 backward, which rode as the epilogue of BERT's FFN
+    products and held the MXU back (PERF.md section 6, PR 49). ``1 + erf`` loses
+    RELATIVE precision where gelu itself vanishes (x < -4); the absolute
+    error stays a few float32 ulps of |x|. Operands narrower than float32
+    are evaluated in float32 and rounded once: in bfloat16 ``1 + erf``
+    would cancel to nothing from x = -2 on. The rule is reverse-mode only
+    (core/backward.py takes every gradient through ``jax.vjp``).
+
+    ``round_dtype`` (the exact form's alone; the AMP rewrite sets it where
+    every reader of the result casts it to that dtype anyway,
+    amp/decorator.py) rounds the result to it, still in the operand's
+    dtype, and keeps the rounded value a buffer of its own.
+    ``gelu_lowerings_total{form=}`` counts each lowered call."""
+    _gelu_lowerings("tanh" if approximate else "erf").inc()
+    if approximate:
+        return jax.nn.gelu(x, approximate=True)
+    if jnp.finfo(x.dtype).bits < 32:
+        return _gelu_erf(x.astype(jnp.float32), None).astype(x.dtype)
+    return _gelu_erf(x, round_dtype)

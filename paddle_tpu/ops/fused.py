@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import register_op
-from paddle_tpu.ops.common import first, maybe
+from paddle_tpu.ops.common import first, gelu, maybe
 from paddle_tpu.utils.enforce import EnforceError
 
 
@@ -439,7 +439,7 @@ _FC_ACTS = {
     "relu6": lambda x: jnp.clip(x, 0.0, 6.0),
     # exact (erf) form — matches the standalone gelu op's default
     # approximate=False (fc_fuse refuses to fold an approximate gelu)
-    "gelu": lambda x: jax.nn.gelu(x, approximate=False),
+    "gelu": gelu,
     "tanh": jnp.tanh,
     "sigmoid": jax.nn.sigmoid,
 }

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.registry import OpDef, OpRegistry, register_op, register_grad
 from paddle_tpu.ops.common import (
     first,
+    gelu,
     maybe,
     normalize_padding,
     rng_key,
@@ -37,7 +38,9 @@ _activation("relu", lambda x, a: jax.nn.relu(x))
 _activation("relu6", lambda x, a: jnp.minimum(jax.nn.relu(x), a.get("threshold", 6.0)))
 _activation("sigmoid", lambda x, a: jax.nn.sigmoid(x))
 _activation("tanh", lambda x, a: jnp.tanh(x))
-_activation("gelu", lambda x, a: jax.nn.gelu(x, approximate=a.get("approximate", False)))
+_activation("gelu", lambda x, a: gelu(
+    x, approximate=a.get("approximate", False),
+    round_dtype=a.get("round_dtype")))
 _activation("leaky_relu", lambda x, a: jax.nn.leaky_relu(x, a.get("alpha", 0.02)))
 _activation("elu", lambda x, a: jax.nn.elu(x, a.get("alpha", 1.0)))
 _activation("softplus", lambda x, a: jax.nn.softplus(x))

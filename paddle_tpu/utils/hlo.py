@@ -28,13 +28,17 @@ def _sds_of(value):
     return jax.ShapeDtypeStruct(tuple(arr.shape), np.asarray(value).dtype if not hasattr(value, "dtype") else value.dtype)
 
 
-def lower_program_step(program, feed, fetch_list, scope=None, donate=True):
+def lower_program_step(program, feed, fetch_list, scope=None, donate=True,
+                       sharding=None):
     """Lower the Executor's whole-block step for `program` WITHOUT running it.
 
     `feed` maps name -> array (shape/dtype only). The scope must hold
     initialized persistables (run the startup program first). Returns the
     jax ``Lowered``: ``.as_text()`` is StableHLO, ``.compile().as_text()``
-    the backend-optimized HLO.
+    the backend-optimized HLO. ``sharding`` goes on every argument: a
+    ``SingleDeviceSharding`` on a device of a DESCRIBED topology
+    (``jax.experimental.topologies``) makes ``.compile()`` the TPU
+    compiler's own, chip-free (tests/test_kernels_tpu_aot.py).
 
     Routes through the shared lowering (core/lowering.py, cache bypassed:
     evidence must come from a fresh trace of the CURRENT program), so the
@@ -65,7 +69,12 @@ def lower_program_step(program, feed, fetch_list, scope=None, donate=True):
     donated_sds = tuple(_sds_of(scope.find_var(n)) for n in entry.donated)
     readonly_sds = tuple(_sds_of(scope.find_var(n)) for n in entry.readonly)
     key = jax.random.PRNGKey(0)
-    return entry.lower(feed_sds, donated_sds, readonly_sds, key)
+    args = (feed_sds, donated_sds, readonly_sds, _sds_of(key))
+    if sharding is not None:
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), args)
+    return entry.lower(*args)
 
 
 def lower_parallel_step(exe, compiled_program, feed, fetch_list, scope):
@@ -120,8 +129,11 @@ def pallas_custom_calls(hlo_text):
 
 
 def bert_train_step_text(flash, seq_len=512, layers=2, batch=4,
-                         vocab=30522, max_pred=77, hidden=768, heads=12):
-    """StableHLO text of a BERT train step (bf16 AMP, gathered MLM head).
+                         vocab=30522, max_pred=77, hidden=768, heads=12,
+                         device=None):
+    """StableHLO text of a BERT train step (bf16 AMP, gathered MLM head),
+    or, with ``device`` (one of a described topology's: see
+    ``lower_program_step``), the OPTIMIZED HLO of that step compiled for it.
 
     ``flash=True`` lowers under the kernel registry's INTERPRET mode
     (paddle_tpu/kernels/): on this CPU rig the registry's default
@@ -152,9 +164,12 @@ def bert_train_step_text(flash, seq_len=512, layers=2, batch=4,
             np.random.RandomState(0), batch, seq_len, cfg,
             max_predictions_per_seq=max_pred,
         )
-        return lower_program_step(
-            main, data, [fetches[0]], scope=scope
-        ).as_text()
+        lowered = lower_program_step(
+            main, data, [fetches[0]], scope=scope,
+            sharding=device and jax.sharding.SingleDeviceSharding(device),
+        )
+        return (lowered.as_text() if device is None
+                else lowered.compile().as_text())
 
 
 def tiny_bert_parallel_text(mesh_shape, axis_names, param_rules=None,
@@ -414,6 +429,103 @@ def unfused_adam_chain_ops(opt_text):
                 _UNFUSED_ADAM_OP.search(s):
             out.append(s[:160])
     return out
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_LAYOUT = re.compile(r"\]\{[^{}]*\}")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|[a-z0-9]+\[[0-9,]*\]) ([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+#: instructions that compute nothing an element
+_NOT_ARITHMETIC = frozenset((
+    "parameter", "constant", "broadcast", "bitcast", "copy", "reshape",
+    "transpose", "tuple", "get-tuple-element", "convolution", "fusion",
+    "iota", "slice", "dynamic-slice", "pad", "concatenate",
+))
+
+
+def fusion_census(opt_text):
+    """Every ``fusion`` instruction of OPTIMIZED HLO text with what its
+    computation holds, fusions nested in it included: a list of
+    ``{"name", "kind", "outputs": [(dims, dtype)], "body": [(opcode,
+    dims, dtype, op_name)]}``, ``body`` holding the instructions that
+    produce ONE array. On the TPU a matrix product is a ``convolution``
+    and ``kind=kOutput`` means the fusion is built AROUND it: what else it
+    holds rides on the product's operands (a prologue) or on its result
+    (an epilogue), and the MXU waits for it (PERF.md section 6, PR 49)."""
+    comps, current = {}, None
+    for line in opt_text.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION.match(line)
+            current = comps.setdefault(m.group(1), []) if m else None
+        elif current is not None:
+            current.append(_LAYOUT.sub("]", line))
+
+    def body_of(name):
+        out = []
+        for line in comps.get(name, ()):
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            if m.group(3) == "fusion":
+                out.extend(body_of(_CALLS.search(line).group(1)))
+                continue
+            shapes = opt_hlo_shapes(m.group(2))
+            if len(shapes) == 1 and not m.group(2).startswith("("):
+                op_name = _OP_NAME.search(line)
+                out.append((m.group(3),) + shapes[0]
+                           + (op_name.group(1) if op_name else "",))
+        return out
+
+    found = []
+    for lines in comps.values():
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            if not m or m.group(3) != "fusion":
+                continue
+            found.append({
+                "name": m.group(1),
+                "kind": re.search(r"kind=(\w+)", line).group(1),
+                "outputs": opt_hlo_shapes(m.group(2)),
+                "body": body_of(_CALLS.search(line).group(1)),
+            })
+    return found
+
+
+def erfc_expansions(opt_text):
+    """Instructions of OPTIMIZED HLO text that jax traced under ``erfc``
+    (``op_name=".../erfc"``): XLA has no such instruction and writes ~60
+    float32 operations an element in its place."""
+    return [line.strip()[:160] for line in opt_text.splitlines()
+            if re.search(r'op_name="[^"]*/erfc"', line)]
+
+
+def activation_epilogues(opt_text, width):
+    """What rides on the matrix products that make or read an activation
+    of ``width`` features: for every fusion that holds a ``convolution``
+    and outputs ``bf16[..., width]`` or ``f32[width]`` (BERT's FFN1 forward
+    product and FFN2's data-gradient product with the bias gradient),
+    ``{"name", "kind", "erf": how many, "float32_ops": the float32
+    instructions of the activation's shape that compute something}``."""
+    found = []
+    for fusion in fusion_census(opt_text):
+        body = fusion["body"]
+        outputs = fusion["outputs"]
+        if not any(op == "convolution" for op, *_ in body) or not any(
+                (dtype == "bf16" and len(dims) > 1 and dims[-1] == width)
+                or (dtype, dims) == ("f32", (width,))
+                for dims, dtype in outputs):
+            continue
+        found.append({
+            "name": fusion["name"], "kind": fusion["kind"],
+            "erf": sum(op == "erf" for op, *_ in body),
+            "float32_ops": sum(
+                dtype == "f32" and len(dims) > 1 and dims[-1] == width
+                and op not in _NOT_ARITHMETIC
+                for op, dims, dtype, _name in body),
+        })
+    return found
 
 
 def count_collectives(opt_text):

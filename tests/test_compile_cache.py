@@ -173,6 +173,23 @@ def test_fingerprint_covers_jax_version_and_backend(monkeypatch):
     assert fp != compile_cache.program_fingerprint(p, feed_sig, ["loss"])
 
 
+@pytest.mark.parametrize("bump, same", [(0, True), (1, False)],
+                         ids=["unchanged", "bumped"])
+def test_fingerprint_covers_lowering_version(monkeypatch, bump, same):
+    """The Program's bytes do not say what an op lowering emits for them:
+    a bumped ``lowering.LOWERING_VERSION`` misses every persisted entry,
+    an unchanged one still hits."""
+    from paddle_tpu.core import lowering
+
+    feed_sig = (("x", (2, 4), "float32"),)
+    p = _tiny_program()
+    fp = compile_cache.program_fingerprint(p, feed_sig, ["loss"])
+    monkeypatch.setattr(lowering, "LOWERING_VERSION",
+                        lowering.LOWERING_VERSION + bump)
+    again = compile_cache.program_fingerprint(p, feed_sig, ["loss"])
+    assert (fp == again) is same
+
+
 def test_flag_changes_miss_cleanly():
     from paddle_tpu.utils.flags import flags
 

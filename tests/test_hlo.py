@@ -587,3 +587,93 @@ def test_no_unfused_adam_chains(adam_step_lowered):
     lowered, _donated, _main = adam_step_lowered
     offenders = hlo.unfused_adam_chain_ops(lowered.compile().as_text())
     assert offenders == [], "unfused adam-chain ops:\n" + "\n".join(offenders)
+
+
+# ---------------------------------------------------------------------------
+# the exact gelu as the TPU's own compiler leaves it (PERF.md section 6, PR 49):
+# BERT's step at the training cells' widths, compiled for a described v5e
+# ---------------------------------------------------------------------------
+
+FFN = 3072     # BertConfig's intermediate size, the cells'
+
+
+@pytest.fixture(scope="module")
+def v5e_device():
+    import os
+
+    from jax.experimental import topologies
+
+    # as tests/test_kernels_tpu_aot.py: a compile-only client holds no
+    # device, so parallel test workers may each load libtpu
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        return topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _bert_cell_step_for(device):
+    return hlo.bert_train_step_text(
+        False, seq_len=128, layers=2, batch=256, max_pred=20, device=device)
+
+
+@pytest.fixture(scope="module")
+def bert_step_on_v5e(v5e_device):
+    """(optimized HLO, ``gelu_lowerings_total`` by form before and after)."""
+    from paddle_tpu.ops.common import gelu_lowering_counts
+
+    before = gelu_lowering_counts()
+    return _bert_cell_step_for(v5e_device), before, gelu_lowering_counts()
+
+
+def test_compiled_bert_step_holds_no_erfc_expansion(bert_step_on_v5e):
+    text, _before, _after = bert_step_on_v5e
+    assert hlo.erfc_expansions(text) == []
+
+
+def test_ffn_products_keep_a_light_gelu_epilogue(bert_step_on_v5e):
+    """FFN1's forward product (bias add and gelu on its result) and FFN2's
+    data-gradient product (gelu's derivative and the bias gradient on its
+    result) are each ONE fusion built around the product, with one ``erf``
+    and a few float32 operations an element: the compiler neither split
+    the activation off into a pass of its own over ``[256, 128, 3072]``
+    nor left gelu's result unwritten to evaluate it again inside every
+    product that reads it (it does, without the AMP rewrite's
+    ``round_dtype``: 24 ms of a 256 ms step on the chip)."""
+    text, _before, _after = bert_step_on_v5e
+    fusions = hlo.activation_epilogues(text, FFN)
+    assert len(fusions) == 2 * LAYERS, fusions
+    for f in fusions:
+        assert f["kind"] == "kOutput", f
+        assert f["erf"] == 1, f
+        assert f["float32_ops"] <= 30, f
+    rest = [f["name"] for f in hlo.fusion_census(text)
+            if any(op == "erf" for op, dims, *_ in f["body"]
+                   if dims[-1:] == (FFN,))]
+    assert sorted(rest) == sorted(f["name"] for f in fusions)
+
+
+def test_bert_step_lowers_every_gelu_through_erf(bert_step_on_v5e):
+    """Two layers and the MLM transform: three gelu ops, each lowered at
+    the program's build (shape inference), in the step and in the grad
+    op's ``jax.vjp``."""
+    _text, before, after = bert_step_on_v5e
+    assert after["erf"] - before["erf"] == 3 * (LAYERS + 1)
+    assert after["tanh"] == before["tanh"]
+
+
+def test_erfc_detector_fires(v5e_device, monkeypatch):
+    """Positive control: with jax's own exact gelu in the lowering's place
+    the expansion is in the step and weighs on both products."""
+    from paddle_tpu.ops import nn as nn_ops
+
+    monkeypatch.setattr(
+        nn_ops, "gelu",
+        lambda x, approximate=False, round_dtype=None: jax.nn.gelu(
+            x, approximate=approximate))
+    text = _bert_cell_step_for(v5e_device)
+    assert len(hlo.erfc_expansions(text)) > 100
+    fusions = hlo.activation_epilogues(text, FFN)
+    assert len(fusions) == 2 * LAYERS, fusions
+    for f in fusions:
+        assert f["erf"] == 0 and f["float32_ops"] > 30, f
