@@ -50,8 +50,12 @@ def infer_op_shapes(op_type, block, inputs, attrs):
     clean_attrs = {
         k: v for k, v in attrs.items() if k not in ("op_callstack",)
     }
+    from paddle_tpu.ops.common import shape_inference
+
     try:
-        out = jax.eval_shape(lambda ins: op_def.lower(ins, clean_attrs), specs)
+        with shape_inference():
+            out = jax.eval_shape(
+                lambda ins: op_def.lower(ins, clean_attrs), specs)
     except Exception:
         return None
     result = {}

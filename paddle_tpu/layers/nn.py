@@ -1305,6 +1305,14 @@ def dropout(
     name=None,
     dropout_implementation="downgrade_in_infer",
 ):
+    """Zero each element of `x` with probability `dropout_prob` (reference:
+    fluid.layers.dropout). The mask is saved for the gradient. With `seed`
+    0 it follows the program's `random_seed` and the executor's step count,
+    so equal (seed, step) give equal masks. Under
+    `CompiledProgram.with_parallel` over a mesh whose data axes divide
+    dim 0, each shard draws its own rows' bits (ops/common.py keep_mask):
+    the masks are a function of (seed, step, op, shard), not those the seed
+    gives on one device, and they depend on the data axes' size."""
     helper = LayerHelper("dropout", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     mask = helper.create_variable_for_type_inference(x.dtype, stop_gradient=True)

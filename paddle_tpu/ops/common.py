@@ -1,7 +1,9 @@
 """Shared helpers for op lowering rules."""
 
+import contextlib
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +66,96 @@ def seeded_rng_key(ins, attrs):
         return base
     raw = jnp.asarray(injected[0]).astype(jnp.uint32)
     return jax.random.fold_in(base, raw[0] ^ raw[1])
+
+
+#: the mesh axes a batch is split over (parallel/spec_layout.py names the
+#: tensor-parallel ones)
+DATA_AXIS_NAMES = ("dcn", "data")
+
+
+def mesh_axes_dividing(mesh, names, dim):
+    """Those of the axes `names` that `mesh` has with more than one device,
+    as long as their product divides `dim`; None when none is left."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    got = [a for a in names if sizes.get(a, 1) > 1]
+    while got and dim % math.prod(sizes[a] for a in got):
+        got.pop(0)
+    return tuple(got) or None
+
+
+_lowering_for = threading.local()
+
+
+@contextlib.contextmanager
+def shape_inference():
+    """Entered by ``layer_helper.infer_op_shapes`` around its abstract
+    evaluation: a lowering run for its output shapes puts nothing into a
+    step, so ``rng_draws_total`` stands still under it."""
+    old = getattr(_lowering_for, "shapes", False)
+    _lowering_for.shapes = True
+    try:
+        yield
+    finally:
+        _lowering_for.shapes = old
+
+
+def _rng_draws(placement):
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    return obs_metrics.registry().counter(
+        "rng_draws_total",
+        "dropout masks lowered into a step, by how much of the array a "
+        "device draws",
+        labels={"placement": placement},
+    )
+
+
+def rng_draw_counts():
+    """``rng_draws_total`` by placement, as ``keep_mask`` left it."""
+    return {placement: int(_rng_draws(placement).value)
+            for placement in ("per_shard", "global")}
+
+
+def keep_mask(key, keep_prob, shape):
+    """A Bernoulli(`keep_prob`) mask of `shape`, each device drawing its own
+    rows where the lowering can see that it may. GSPMD does not partition
+    ``rng-bit-generator``: under ``with_parallel`` every device of a data
+    mesh drew the GLOBAL batch's bits and kept its slice, four times the
+    work on four chips (PERF.md section 6, PR 50). When a mesh is current,
+    its data axes ('dcn', 'data') have more than one device between them
+    and divide dim 0, and the draw is not inside a manual region already
+    (the DGC per-shard step, a ``pipeline_stack`` body), the draw runs in
+    a ``shard_map`` that only the key enters: each shard folds its linear
+    index over the data axes into the key and draws `shape` with dim 0
+    divided; every other axis sees the draw replicated, as before. Such
+    masks are a function of (key, shard) and so of the data axes' size,
+    not the masks one device draws from the key; anywhere else this is
+    ``jax.random.bernoulli(key, keep_prob, shape)``.
+    ``rng_draws_total{placement=per_shard|global}`` counts each draw
+    lowered into a step."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel import env as penv
+
+    mesh = penv.current_mesh()
+    axes = None
+    if (mesh is not None and shape
+            and not jax.sharding.get_abstract_mesh().manual_axes):
+        axes = mesh_axes_dividing(mesh, DATA_AXIS_NAMES, shape[0])
+    if not getattr(_lowering_for, "shapes", False):
+        _rng_draws("per_shard" if axes else "global").inc()
+    if not axes:
+        return jax.random.bernoulli(key, keep_prob, shape)
+    shards = math.prod(mesh.shape[a] for a in axes)
+    local_shape = (shape[0] // shards, *shape[1:])
+
+    def local(key):
+        key = jax.random.fold_in(key, lax.axis_index(axes))
+        return jax.random.bernoulli(key, keep_prob, local_shape)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=P(),
+        out_specs=P(axes, *[None] * (len(shape) - 1)))(key)
 
 
 def reduce_axes(attrs, ndim):

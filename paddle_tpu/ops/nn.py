@@ -14,11 +14,15 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.registry import OpDef, OpRegistry, register_op, register_grad
 from paddle_tpu.ops.common import (
+    DATA_AXIS_NAMES,
     first,
     gelu,
+    keep_mask,
     maybe,
+    mesh_axes_dividing,
     normalize_padding,
     rng_key,
+    seeded_rng_key,
     vma_names,
 )
 from paddle_tpu.utils.enforce import EnforceError
@@ -334,10 +338,7 @@ def _dropout(ins, attrs):
     if attrs.get("is_test", False):
         out = x if impl == "upscale_in_train" else x * (1.0 - p)
         return {"Out": [out], "Mask": [jnp.ones_like(x)]}
-    from paddle_tpu.ops.common import seeded_rng_key
-
-    key = seeded_rng_key(ins, attrs)
-    keep = jax.random.bernoulli(key, 1.0 - p, x.shape)
+    keep = keep_mask(seeded_rng_key(ins, attrs), 1.0 - p, x.shape)
     mask = keep.astype(x.dtype)
     if impl == "upscale_in_train":
         out = jnp.where(keep, x / (1.0 - p), 0.0).astype(x.dtype)
@@ -482,17 +483,9 @@ def _flash_per_shard(mesh, q, k, v, bias, **kw):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.parallel.spec_layout import TP_AXIS_NAMES
 
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-
-    def axes_for(names, dim):
-        """The named axes the mesh has, as long as they divide `dim`."""
-        got = [a for a in names if sizes.get(a, 1) > 1]
-        while got and dim % math.prod(sizes[a] for a in got):
-            got.pop(0)
-        return tuple(got) or None
-
-    batch = axes_for(("dcn", "data"), q.shape[0])
-    spec = P(batch, axes_for(TP_AXIS_NAMES, q.shape[1]), None, None)
+    batch = mesh_axes_dividing(mesh, DATA_AXIS_NAMES, q.shape[0])
+    spec = P(batch, mesh_axes_dividing(mesh, TP_AXIS_NAMES, q.shape[1]),
+             None, None)
     args, in_specs = (q, k, v), (spec, spec, spec)
     if bias is not None:
         args, in_specs = args + (bias,), in_specs + (P(batch, None),)

@@ -12,6 +12,7 @@ fit and numerics are settled by ``chip_smoke.py`` on the chip.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -174,3 +175,77 @@ def test_flash_on_a_mesh_runs_per_shard(shape, names, v5e_devices,
                            "flash_attention_bwd_dq"}, census
     # 16 x 12 (batch x heads) rows split four ways, whichever axes do it
     assert census["flash_attention_fwd"]["result"] == "bf16[48,128,64]"
+
+
+_RNG_SHAPE = re.compile(r"= u32\[([\d,]*)\][^ ]* rng-bit-generator\(")
+
+
+def _dropout_step_rng_shapes(devices, names, mesh_shape, dims,
+                             batch_first=True):
+    """The result shapes of every ``rng-bit-generator`` in the optimized
+    HLO of mean(dropout(x)) and its gradient, the Program's own ops run by
+    the block interpreter as a CompiledProgram step runs them, compiled
+    for the described v5e devices under a mesh."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import _interpret_block
+    from paddle_tpu.parallel.env import mesh_context
+
+    B, S, H = dims
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.data("x", shape=[B, S, H], dtype="bfloat16")
+        x.stop_gradient = False
+        h = x if batch_first else fluid.layers.transpose(x, [1, 0, 2])
+        out = fluid.layers.dropout(
+            h, 0.1, dropout_implementation="upscale_in_train")
+        loss = fluid.layers.mean(fluid.layers.cast(out, "float32"))
+        (gx,) = fluid.gradients(loss, x)
+
+    def step(x, key):
+        env = _interpret_block(main.global_block(), {"x": x}, key)
+        return env[loss.name], env[gx.name]
+
+    mesh = Mesh(np.array(devices).reshape(mesh_shape), names)
+    key = jax.random.key(0, impl="rbg")
+    args = (jax.ShapeDtypeStruct((B, S, H), jnp.bfloat16,
+                                 sharding=NamedSharding(mesh, P(names[0]))),
+            jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                 sharding=NamedSharding(mesh, P())))
+    with mesh_context(mesh):
+        text = jax.jit(step).lower(*args).compile().as_text()
+    shapes = [tuple(int(d) for d in found.split(","))
+              for found in _RNG_SHAPE.findall(text)]
+    assert shapes and len(shapes) == text.count(" rng-bit-generator("), (
+        "a rng-bit-generator of the compiled dropout step went unread")
+    return shapes
+
+
+@pytest.mark.parametrize("names, mesh_shape, shards", [
+    (("data",), (4,), 4), (("dcn", "data"), (2, 2), 4),
+    (("data", "model"), (2, 2), 2)])
+def test_dropout_on_a_data_mesh_draws_the_shards_bits(
+        names, mesh_shape, shards, v5e_devices):
+    """GSPMD does not partition ``rng-bit-generator``: left to it, every
+    device of a data mesh draws the GLOBAL batch's bits and keeps its
+    slice (49 draws of ``u32[1024,128,768]`` a step on each chip of the
+    dp4 cell, 32 ms of 269: PERF.md section 6, PR 50). ``ops/common.py keep_mask`` draws
+    inside a ``shard_map``, so every generator of the compiled step has the
+    PER-SHARD leading dimension. Only the TPU compiler can hold this: the
+    CPU backend expands the generator into threefry arithmetic BEFORE it
+    partitions, so a virtual CPU mesh shows neither the fault nor the
+    cure."""
+    shapes = _dropout_step_rng_shapes(v5e_devices, names, mesh_shape,
+                                      (64, 128, 256))
+    assert set(shapes) == {(64 // shards, 128, 256)}, shapes
+
+
+def test_dropout_whose_rows_the_mesh_does_not_divide_draws_globally(
+        v5e_devices):
+    """What the lowering can see is dim 0: a ``[S, B, H]`` operand of 126
+    rows under four shards keeps the one draw of the whole array."""
+    shapes = _dropout_step_rng_shapes(v5e_devices, ("data",), (4,),
+                                      (64, 126, 256), batch_first=False)
+    assert set(shapes) == {(126, 64, 256)}, shapes
