@@ -91,11 +91,14 @@ _SIGNATURES = {
         ranks={"Logits": 3, "Held": 2, "Decided": 2},
         dtype_family={"Logits": "float", "Held": "int", "Decided": "int"},
     ),
+    # the mask is made on the device from the chunk's (start, real)
     "chunk_paged_attention": OpSignature(
         same_dtype=[("Q", "KArena", "VArena")],
-        ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Bias": 3},
-        dtype_family={"Q": "float", "Bias": "float", "Rows": "int"},
+        ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Span": 1},
+        dtype_family={"Q": "float", "Span": "int", "Rows": "int"},
     ),
+    "chunk_mask_bias": OpSignature(
+        ranks={"Span": 1}, dtype_family={"Span": "int"}),
     "rms_norm": OpSignature(ranks={"Scale": 1}, dtype_family={"X": "float"}),
     "relu2": OpSignature(dtype_family={"X": "float"}),
     "moe_routed_experts": OpSignature(

@@ -10,6 +10,7 @@ kernel               serves                          parity    activation
 flash_attention      scaled_dot_product_attention    tolerance mode
 cached_attention     cached_attention (decode [S,1]) bit       mode
 paged_attention      paged_attention (decode [S,1])  tolerance mode
+chunk_paged_attention chunk_paged_attention ([C] of one slot) tolerance mode
 moe_experts          moe_routed_experts (decode)     tolerance mode
 ssm_update           mamba2_mixer (decode [S,1])     tolerance mode
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
@@ -22,6 +23,8 @@ the CI gate (a kernel without a parity test cannot register) — and every
 (tests/test_kernels_tpu_aot.py): a kernel that interprets but does not
 lower for the chip cannot stay registered.
 """
+
+import functools
 
 import numpy as np
 
@@ -46,7 +49,8 @@ def selected_for(op_type, attrs):
     that names its ``block_size`` (``[R, H]`` does not carry it; a program
     serialized before the attribute existed runs the composite). The
     lowering and ``analysis/memory.py`` both ask here."""
-    if op_type == "paged_attention" and not attrs.get("block_size"):
+    if (op_type in ("paged_attention", "chunk_paged_attention")
+            and not attrs.get("block_size")):
         return None
     return selected(op_type)
 
@@ -323,7 +327,8 @@ def _tpu_cases_paged_grouped():
     ouro_2_6b at 1,024 positions (16 slots, rows of 16 K/V heads of 128
     with ONE query head each: one real query row in a 16-row tile) and
     sdar_30b_a3b (32 slots, rows of 4 K/V heads of 128 with 32 query rows
-    each: a block of 4 positions x 8 query heads)."""
+    each: a block of 4 positions x 8 query heads); and granite_4_0_h_micro
+    at 16,896 positions (32 slots of 1,056 blocks, lfm2's heads)."""
     from paddle_tpu.kernels import attention as A
 
     def case(S, L, bs, G, per, D):
@@ -339,7 +344,88 @@ def _tpu_cases_paged_grouped():
             ((S, 1, L), "float32")])
 
     return [case(32, 2048, 16, 2, 16, 128), case(128, 2048, 16, 8, 4, 64),
-            case(16, 1024, 16, 16, 1, 128), case(32, 1024, 16, 4, 32, 128)]
+            case(16, 1024, 16, 16, 1, 128), case(32, 1024, 16, 4, 32, 128),
+            case(32, 16896, 16, 8, 4, 64)]
+
+
+def _chunk_case(rng, C, L, bs, G, per, D, dtype="float32"):
+    """One slot's arenas under a shuffled block table (more blocks in the
+    pool than the slot holds) and a chunk's queries: ``(q, k, v, rows)``."""
+    per_slot = -(-L // bs)
+    pool = per_slot + 3
+    ids = rng.permutation(pool)[:per_slot]
+    rows = (ids[:, None] * bs + np.arange(bs)).reshape(-1)[:L]
+    draw = lambda *shape: rng.randn(*shape).astype(dtype)
+    return (draw(C, G * per * D), draw(pool * bs, G * D),
+            draw(pool * bs, G * D), rows.astype("int64"))
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_both(bs, G, D, block_len):
+    """(kernel through the interpreter, composite under the rule's bias),
+    jitted once a geometry: the chunk's span is an argument."""
+    import jax
+
+    from paddle_tpu.kernels import attention as A
+
+    sm = 1.0 / float(np.sqrt(D))
+    return (jax.jit(lambda *a: A.chunk_attention(
+                *a, bs, sm, G, block_len=block_len, interpret=True)),
+            jax.jit(lambda *a: A.chunk_attention_by_span(
+                *a, sm, G, block_len)))
+
+
+def _assert_chunk_parity(args, start, real, bs, G, D, block_len=1):
+    """The real queries within 2e-5 both ways of the composite under the
+    rule's bias; a query past them reads nothing and gives zeros."""
+    kernel, composite = _chunk_both(bs, G, D, block_len)
+    span = np.array([start, real], "int32")
+    got = np.asarray(kernel(*args, span))
+    ref = np.asarray(composite(*args, span))
+    _assert_close_both_ways(got[:real], ref[:real],
+                            f"chunk_attention {start}+{real}", 2e-5, 2e-5)
+    assert not got[real:].any()
+
+
+def _parity_chunk_attention(rng):
+    """The step kernel's four head geometries, each with the chunk at the
+    prompt's start and behind rows, full and short, ending one under, at
+    and one over a block and a copy tile (256 rows), under a shuffled block
+    table; then the block mask (``block_len`` 4)."""
+    for G, per, D in ((2, 4, 128), (8, 4, 64), (16, 1, 128), (4, 8, 128)):
+        args = _chunk_case(rng, 32, 320, 16, G, per, D)
+        for start, real in ((0, 32), (0, 7), (32, 17), (224, 31), (224, 32),
+                            (240, 17), (288, 32)):
+            _assert_chunk_parity(args, start, real, 16, G, D)
+    args = _chunk_case(rng, 32, 320, 16, 4, 8, 128)
+    for start, real in ((0, 28), (32, 32), (252, 8)):
+        _assert_chunk_parity(args, start, real, 16, 4, 128, block_len=4)
+
+
+def _tpu_cases_chunk_attention():
+    """granite_4_0_h_micro's chunk (512 queries over up to 16,896 rows of
+    8 K/V heads of 64, 4 query heads to each, block 16, bfloat16), and the
+    accepted hybrid cells' head geometries at a length the kernel serves
+    (a chunk of 512 over 4,096 rows): nemotron3_nano_30b_a3b (2 x 16 x
+    128), ouro_2_6b (16 x 1 x 128) and sdar_30b_a3b (4 x 8 x 128 under its
+    block mask)."""
+    from paddle_tpu.kernels import attention as A
+
+    def case(C, L, bs, G, per, D, block_len=1):
+        R = 4 * L
+
+        def fwd(q, k, v, rows, span):
+            return A.chunk_attention(q, k, v, rows, span, bs,
+                                     1.0 / float(np.sqrt(D)), G,
+                                     block_len=block_len)
+
+        return (f"c{C}_l{L}_b{bs}_g{G}x{per}x{D}_m{block_len}_bf16", fwd, [
+            ((C, G * per * D), "bfloat16"), ((R, G * D), "bfloat16"),
+            ((R, G * D), "bfloat16"), ((L,), "int32"), ((2,), "int32")])
+
+    return [case(512, 16896, 16, 8, 4, 64), case(512, 4096, 16, 2, 16, 128),
+            case(512, 4096, 16, 16, 1, 128),
+            case(512, 4096, 16, 4, 8, 128, block_len=4)]
 
 
 def _parity_moe_experts(rng):
@@ -483,6 +569,14 @@ register(KernelSpec(
     doc="blocked [S,1] decode attention over the live blocks of a paged "
         "arena, online softmax, the next live copy unit always in flight "
         "(kernels/attention.py)",
+))
+register(KernelSpec(
+    "chunk_paged_attention", ("chunk_paged_attention",), "tolerance",
+    _parity_chunk_attention, tpu_cases=_tpu_cases_chunk_attention,
+    doc="a prompt chunk's queries over the live blocks of its slot, the "
+        "mask made on the device from the chunk's span, online softmax "
+        "over double-buffered copy tiles (kernels/attention.py "
+        "chunk_attention)",
 ))
 register(KernelSpec(
     "moe_experts", ("moe_routed_experts",), "tolerance", _parity_moe_experts,

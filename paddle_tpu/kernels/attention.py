@@ -94,7 +94,8 @@ _CLOSED = -5e8
 __all__ = [
     "cached_attention_composite", "paged_attention_composite",
     "chunk_attention_composite", "decode_attention", "paged_attention",
-    "fits_vmem", "grouped_layout",
+    "chunk_attention", "chunk_attention_by_span", "chunk_horizon",
+    "chunk_mask_bias", "fits_vmem", "grouped_layout",
 ]
 
 #: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
@@ -173,12 +174,38 @@ def _grouped_composite(q, k_arena, v_arena, rows, bias, seqs, length,
     return ctx.reshape(s, -1).astype(q.dtype)
 
 
+def chunk_horizon(span, chunk, length, block_len=1):
+    """THE rule of the chunk program's mask, on the device: ``span`` is the
+    chunk's ``(start, real)``, its first position and its count of real
+    positions; query ``c < real`` stands at ``start + c`` and sees what
+    lies at or before it and, with ``block_len`` B, the whole of its own
+    block: the positions below ``(at // B + 1) * B``. Returns that bound
+    for each of the ``chunk`` queries, int32 ``[C]``, 0 for a query past
+    the real ones (it sees nothing)."""
+    b = int(block_len)
+    c = jnp.arange(int(chunk), dtype=jnp.int32)
+    start, real = span[0].astype(jnp.int32), span[1].astype(jnp.int32)
+    bound = jnp.minimum(((start + c) // b + 1) * b, int(length))
+    return jnp.where(c < real, bound, 0)
+
+
+def chunk_mask_bias(span, chunk, length, block_len=1):
+    """``chunk_horizon`` as the additive float32 ``[1, C, L]`` bias a
+    composite adds to its scores: 0.0 where a query sees, ``-1e9`` where
+    not."""
+    sees = (jnp.arange(int(length), dtype=jnp.int32)[None, :]
+            < chunk_horizon(span, chunk, length, block_len)[:, None])
+    return jnp.where(sees, 0.0, -1e9).astype(jnp.float32)[None]
+
+
 def chunk_attention_composite(q, k_arena, v_arena, rows, bias, sm_scale,
                               kv_heads):
     """A prompt chunk's ``C`` queries ``[C, heads * D]`` over ONE
-    sequence's ``L`` arena rows (``rows`` ``[L]``) under the host's causal
-    bias ``[1, C, L]``, grouped as ``_grouped_composite`` groups a step's
-    heads."""
+    sequence's ``L`` arena rows (``rows`` ``[L]``) under the additive bias
+    ``[1, C, L]`` (``chunk_mask_bias``), grouped as ``_grouped_composite``
+    groups a step's heads. Work and memory are ``C x L`` whatever the
+    prompt's length so far: the reference lowering, the CPU path and the
+    path of the short geometries (``chunk_attention``)."""
     c, l, g = q.shape[0], rows.shape[0], int(kv_heads)
     d = k_arena.shape[-1] // g
     f32 = jnp.float32
@@ -193,6 +220,16 @@ def chunk_attention_composite(q, k_arena, v_arena, rows, bias, sm_scale,
     ctx = jnp.einsum("cgql,lgd->cgqd", att.astype(v_arena.dtype), gv,
                      preferred_element_type=f32, precision=prec)
     return ctx.reshape(c, -1).astype(q.dtype)
+
+
+def chunk_attention_by_span(q, k_arena, v_arena, rows, span, sm_scale,
+                            kv_heads, block_len=1):
+    """``chunk_attention_composite`` under the bias the rule makes of the
+    chunk's ``span``: THE definition of the ``chunk_paged_attention`` op."""
+    return chunk_attention_composite(
+        q, k_arena, v_arena, rows,
+        chunk_mask_bias(span, q.shape[0], rows.shape[0], block_len),
+        sm_scale, kv_heads)
 
 
 # ---------------------------------------------------------------------------
@@ -602,3 +639,191 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     if G:
         out = _unpack_heads(out, pack, q.shape[-1] // H)
     return out.reshape(q.shape)
+
+
+# ---------------------------------------------------------------------------
+# chunk kernel: a prompt chunk's queries over the live blocks of ONE slot
+# ---------------------------------------------------------------------------
+
+#: query rows (chunk positions x query rows a position) one grid step of the
+#: chunk kernel holds: their running max, sum and accumulator live in VMEM
+_CHUNK_QUERY_ROWS = 1024
+
+#: rows of K (and of V) one reduction of the chunk kernel covers
+_CHUNK_TILE_ROWS = 256
+
+#: the chunk kernel's scoped-VMEM limit: its query tile's running state is
+#: some 10 MiB beside the double-buffered rows, over Mosaic's 16 MiB default
+_CHUNK_VMEM_LIMIT = 48 * 1024 * 1024
+
+#: ``C x L`` from which the chunk kernel serves a geometry. Read on a v5e
+#: (``tools/check_chunk_attention.py``, a call's device time, kernel |
+#: composite, ms; PERF.md section 6, PR 51): at the accepted serving cells'
+#: 128 x 2,048 (8 x 4 x 64) 0.079 | 0.066 behind 1,024 rows and 0.100 |
+#: 0.060 behind 1,920, (2 x 16 x 128) 0.086 | 0.082 and 0.104 | 0.080, at
+#: 128 x 1,024 under a block mask of 4 0.061 | 0.060 and 0.069 | 0.051, at
+#: 256 x 1,024 (16 x 1 x 128) 0.065 | 0.047 and 0.065 | 0.044: the
+#: composite's dense ``[C, heads, L]`` scores are 8-33 MB there and it is
+#: never the slower; at 512 x 16,896 (8 x 4 x 64) 0.12 | 4.75 at the
+#: prompt's start, 0.54 | 4.75 behind 4,096 rows, 1.82 | 4.75 behind 16,384
+CHUNK_KERNEL_MIN_WORK = 1 << 20
+
+
+def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
+                kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, sm_scale, block,
+                tile, per_slot):
+    """One tile of a chunk's queries (``q_ref`` ``[pairs, rows, lanes]``:
+    ``grouped_layout``'s packing, a position's query rows together) against
+    the slot's live rows, a copy tile of ``tile`` blocks at a time, the
+    next one in flight while this one is reduced: an online softmax whose
+    mask is ``key position < hz_ref`` (``chunk_horizon``, a row each)."""
+    i = pl.program_id(0)
+    pairs, rows, lanes = q_ref.shape
+    trows = tile * block
+    f32 = jnp.float32
+    prec = (jax.lax.Precision.HIGHEST if kbuf.dtype == f32
+            else jax.lax.Precision.DEFAULT)
+    nk = nt_ref[i]
+
+    @pl.when(i == 0)
+    def _():
+        # rows a short tile leaves unwritten carry weight 0 and only have
+        # to be finite (``_paged_pipeline``)
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, f32)
+    l_ref[...] = jnp.zeros(l_ref.shape, f32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    def start(tile_no, half):
+        _start_copies(bt_ref, len_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, 0,
+                      tile_no, half, block=block, unit=tile,
+                      per_slot=per_slot)
+
+    @pl.when(nk > 0)
+    def _():
+        start(0, 0)
+
+    def step(t, c):
+        half = t % 2
+
+        @pl.when(t + 1 < nk)
+        def _():
+            start(t + 1, 1 - half)
+
+        _wait_copies(len_ref, (kbuf, vbuf), sem, 0, t, half, block=block,
+                     unit=tile)
+        at = t * trows + jax.lax.broadcasted_iota(jnp.int32, (1, trows), 1)
+        sees = at < hz_ref[...]                              # [rows, trows]
+        for g in range(pairs):
+            k = kbuf[half, :, g * lanes:(g + 1) * lanes]    # [trows, lanes]
+            v = vbuf[half, :, g * lanes:(g + 1) * lanes]
+            sc = jax.lax.dot_general(
+                q_ref[g], k, (((1,), (1,)), ((), ())), precision=prec,
+                preferred_element_type=f32)                  # [rows, trows]
+            if sm_scale != 1.0:
+                sc = sc * sm_scale
+            sc = jnp.where(sees, sc, -1e9)
+            m = m_ref[g]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+                p.astype(v.dtype), v, precision=prec,
+                preferred_element_type=f32)
+            m_ref[g] = m_new
+        return c
+
+    jax.lax.fori_loop(0, nk, step, 0)
+    # a query that sees nothing (past the real ones) gives zeros
+    real = hz_ref[...] > 0
+    for g in range(pairs):
+        l = jnp.where(real, l_ref[g], 1.0)
+        o_ref[g] = jnp.where(real, acc_ref[g] / l, 0.0).astype(o_ref.dtype)
+
+
+def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
+                    kv_heads, block_len=1, interpret=False):
+    """``chunk_attention_composite`` under ``chunk_mask_bias(span)``
+    computed from the slot's LIVE blocks alone: a prompt chunk's ``C``
+    queries ``[C, heads * D]`` over the rows that ``rows`` ``[L]`` (block
+    aligned, as ``paged_attention``'s) names up to the chunk's last
+    horizon, read through the block table in copy tiles of
+    ``_CHUNK_TILE_ROWS`` rows, double-buffered, with running max and sum
+    over the tiles; float32 accumulation, operands in the arenas' dtype;
+    heads grouped and packed as the step kernel's (``grouped_layout``). A
+    tile of queries visits the tiles of rows up to ITS last horizon, so a
+    chunk's cost follows the prompt so far and not ``L``. A query past the
+    real ones reads nothing and gives zeros (the composite averages
+    garbage there). Falls back to the composite, counted, where Mosaic
+    cannot tile the geometry or inside a manual region; a geometry whose
+    ``C x L`` is under ``CHUNK_KERNEL_MIN_WORK`` takes the composite by
+    choice (it is the faster one there, as measured), uncounted."""
+    C, L, bs, G = q.shape[0], rows.shape[0], int(block_size), int(kv_heads)
+    H = k_arena.shape[-1]
+
+    def composite():
+        return chunk_attention_by_span(q, k_arena, v_arena, rows, span,
+                                       sm_scale, G, block_len)
+
+    if not interpret and C * L < CHUNK_KERNEL_MIN_WORK:
+        return composite()
+    pack, _rows = grouped_layout(H, G, q.shape[-1], k_arena.dtype, interpret)
+    per = q.shape[-1] // H
+    rpp = pack * per                       # query rows a position and pair
+    sublanes = 1 if interpret else 8 * (4 // jnp.dtype(k_arena.dtype).itemsize)
+    qt = max(1, min(C, _CHUNK_QUERY_ROWS // rpp))
+    while C % qt:
+        qt -= 1
+    per_slot = -(-L // bs)
+    tile = max(1, min(per_slot, _CHUNK_TILE_ROWS // bs))
+    if vma_names(q) or not pack or (qt * rpp) % sublanes or (
+            not interpret and not _mosaic_tiles(bs, H, k_arena.dtype)):
+        fallback_counter().inc()
+        return composite()
+    trows = tile * bs
+    lanes = pack * (H // G)
+    pairs = G // pack
+    table = (rows[::bs] // bs).astype(jnp.int32)
+    horizon = chunk_horizon(span, C, L, block_len)
+    # rows the copies may touch: up to the chunk's last horizon
+    live = jnp.max(horizon).reshape(1)
+    ntiles = -(-jnp.max(horizon.reshape(C // qt, qt), axis=-1) // trows)
+    hz = jnp.repeat(horizon, rpp).reshape(C * rpp, 1)
+    q_in = _pack_heads(q.reshape(C, G, per, H // G), pack, rpp).astype(
+        k_arena.dtype)                              # [C, pairs, rpp, lanes]
+    q_in = jnp.swapaxes(q_in, 0, 1).reshape(pairs, C * rpp, lanes)
+    spec = pl.BlockSpec((pairs, qt * rpp, lanes), lambda i, *_: (0, i, 0))
+    out = pl.pallas_call(
+        functools.partial(_chunk_body, sm_scale=sm_scale, block=bs,
+                          tile=tile, per_slot=per_slot),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(C // qt,),
+            in_specs=[
+                spec,
+                pl.BlockSpec((qt * rpp, 1), lambda i, *_: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, trows, H), k_arena.dtype),
+                pltpu.VMEM((2, trows, H), v_arena.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((pairs, qt * rpp, 1), jnp.float32),
+                pltpu.VMEM((pairs, qt * rpp, 1), jnp.float32),
+                pltpu.VMEM((pairs, qt * rpp, lanes), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
+        interpret=interpret,
+        name="chunk_attention",
+    )(table, live, ntiles.astype(jnp.int32), q_in, hz, k_arena, v_arena)
+    out = jnp.swapaxes(out.reshape(pairs, C, rpp, lanes), 0, 1)
+    return _unpack_heads(out, pack, per).reshape(q.shape)

@@ -31,6 +31,7 @@ __all__ = [
     "cached_attention",
     "paged_attention",
     "chunk_paged_attention",
+    "chunk_mask_bias",
     "paged_step_feeds",
     "paged_block_feeds",
     "block_fill_decide",
@@ -812,20 +813,36 @@ def block_fill_decide(logits, held, decided, mask_token, name=None):
     return state, host
 
 
-def chunk_paged_attention(q, k_arena, v_arena, rows, attn_bias, kv_heads,
-                          sm_scale=1.0, name=None):
+def chunk_mask_bias(span, chunk, length, block_len=1, name=None):
+    """The chunk program's additive float32 ``[1, chunk, length]`` bias,
+    made on the device from the chunk's ``span`` ``(start, real)`` (ops/nn.py
+    ``chunk_mask_bias``): for a chunk program that attends by plain ops."""
+    helper = LayerHelper("chunk_mask_bias", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "chunk_mask_bias", {"Span": [span.name]}, {"Out": [out.name]},
+        {"chunk": int(chunk), "length": int(length),
+         "block_len": int(block_len)})
+    return out
+
+
+def chunk_paged_attention(q, k_arena, v_arena, rows, span, kv_heads,
+                          block_size, sm_scale=1.0, block_len=1, name=None):
     """A prompt chunk's queries ``[C, heads * D]`` over ONE sequence's
-    ``[L]`` rows of the paged arenas under the host's causal bias
-    ``[1, C, L]``, grouped-query (``kv_heads`` K/V heads a row): the chunk
-    program's form of ``paged_attention``."""
+    ``[L]`` rows of the paged arenas, grouped-query (``kv_heads`` K/V heads
+    a row), under the mask the device makes of ``span`` (the chunk's first
+    position and its count of real positions) and ``block_len``: the chunk
+    program's form of ``paged_attention``, from the live blocks alone where
+    the kernel serves it (kernels/attention.py ``chunk_attention``)."""
     helper = LayerHelper("chunk_paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     helper.append_op(
         "chunk_paged_attention",
         {"Q": [q.name], "KArena": [k_arena.name], "VArena": [v_arena.name],
-         "Rows": [rows.name], "Bias": [attn_bias.name]},
+         "Rows": [rows.name], "Span": [span.name]},
         {"Out": [out.name]},
-        {"sm_scale": float(sm_scale), "kv_heads": int(kv_heads)},
+        {"sm_scale": float(sm_scale), "kv_heads": int(kv_heads),
+         "block_size": int(block_size), "block_len": int(block_len)},
     )
     return out
 

@@ -712,17 +712,53 @@ def _block_fill_decide(ins, attrs):
     return {"State": [state], "Host": [host.astype(jnp.int32)]}
 
 
-@register_op("chunk_paged_attention", nondiff_inputs=("Rows", "Bias"))
-def _chunk_paged_attention(ins, attrs):
-    """A prompt chunk's queries ``[C, heads * D]`` over one sequence's
-    rows of the paged arenas (grouped-query; kernels/attention.py
-    ``chunk_attention_composite``)."""
+@register_op("chunk_mask_bias", nondiff_inputs=("Span",))
+def _chunk_mask_bias(ins, attrs):
+    """The chunk program's additive ``[1, C, L]`` bias from the chunk's
+    ``Span`` ``(start, real)``, on the device (kernels/attention.py
+    ``chunk_horizon``: THE rule), for a chunk program that attends by plain
+    matmul and softmax ops."""
     from paddle_tpu.kernels import attention as fused
 
-    return {"Out": [fused.chunk_attention_composite(
+    return {"Out": [fused.chunk_mask_bias(
+        first(ins, "Span"), attrs["chunk"], attrs["length"],
+        attrs.get("block_len", 1))]}
+
+
+def _chunk_paged_attention_reference(ins, attrs):
+    from paddle_tpu.kernels import attention as fused
+
+    return {"Out": [fused.chunk_attention_by_span(
         first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
-        first(ins, "Rows"), first(ins, "Bias"), attrs.get("sm_scale", 1.0),
-        attrs["kv_heads"])]}
+        first(ins, "Rows"), first(ins, "Span"), attrs.get("sm_scale", 1.0),
+        attrs["kv_heads"], attrs.get("block_len", 1))]}
+
+
+def _chunk_paged_attention_pallas(ins, attrs):
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import attention as fused
+
+    sel = kernels.selected_for("chunk_paged_attention", attrs)
+    if sel is None:
+        return _chunk_paged_attention_reference(ins, attrs)
+    return {"Out": [fused.chunk_attention(
+        first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
+        first(ins, "Rows"), first(ins, "Span"), attrs["block_size"],
+        attrs.get("sm_scale", 1.0), attrs["kv_heads"],
+        block_len=attrs.get("block_len", 1), interpret=sel.interpret)]}
+
+
+# a prompt chunk's queries ``[C, heads * D]`` over one sequence's rows of
+# the paged arenas under the mask of the chunk's ``Span`` (grouped-query;
+# kernels/attention.py ``chunk_attention`` and its composite)
+OpRegistry.register(
+    OpDef(
+        "chunk_paged_attention",
+        _chunk_paged_attention_reference,
+        pallas=_chunk_paged_attention_pallas,
+        nondiff_inputs=("Rows", "Span"),
+    )
+)
 
 
 @register_op("rms_norm")

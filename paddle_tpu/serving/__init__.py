@@ -42,6 +42,7 @@ from paddle_tpu.serving.decode import (
     DecodeModel,
     GenerationEngine,
     build_decoder_model,
+    build_granite_hybrid_model,
     build_lfm2_model,
     build_nemotron_h_model,
     build_ouro_model,
@@ -77,6 +78,7 @@ __all__ = [
     "SubprocessReplica",
     "build_decoder_model",
     "build_nemotron_h_model",
+    "build_granite_hybrid_model",
     "build_lfm2_model",
     "build_ouro_model",
     "build_sdar_model",

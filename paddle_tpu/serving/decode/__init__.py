@@ -35,7 +35,9 @@ Modules:
   `build_lfm2_model`: gated short convolutions beside grouped-query
   attention with QK-norm and rotary positions, gated routed experts;
   `build_ouro_model`: one stack of layers run several times a token with
-  shared parameters, paged K/V rows per (pass, layer), an exit gate.
+  shared parameters, paged K/V rows per (pass, layer), an exit gate;
+  `build_granite_hybrid_model`: Mamba-2 or position-free grouped-query
+  mixers with a dense SwiGLU in every layer and four scalar multipliers.
 * `pool`   — host-side slot allocator, block allocator + radix prefix
   index (storage dedup), and the content-hash prefill cache (compute
   dedup).
@@ -62,8 +64,8 @@ from paddle_tpu.serving.decode.generate import (
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.hybrid import (
-    build_lfm2_model, build_nemotron_h_model, build_ouro_model,
-    build_sdar_model)
+    build_granite_hybrid_model, build_lfm2_model, build_nemotron_h_model,
+    build_ouro_model, build_sdar_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -88,6 +90,7 @@ __all__ = [
     "block_hashes",
     "build_decoder_model",
     "build_nemotron_h_model",
+    "build_granite_hybrid_model",
     "build_lfm2_model",
     "build_ouro_model",
     "build_sdar_model",

@@ -80,7 +80,7 @@ and token. A delivered pass hands a slot 0 to ``block_len`` tokens: the
 answer grows in POSITION order (`_sample_blocks`), a token decided ahead of
 its turn waits on the host; the cursor moves by ``block_len`` when a block
 is committed; a prompt's first ``len - len % block_len`` tokens go through
-the chunk program under the block mask (`DecodeModel.chunk_bias`) and the
+the chunk program under the block mask (`DecodeModel.chunk_bias`'s rule) and the
 rest open the first block as positions already decided, so the last
 chunk's logits row is never fetched. ``result()`` carries ``decided_at``
 beside ``tokens``: the pass of its block that decided each token.
@@ -671,6 +671,19 @@ class _ModelEntry:
         if self._thread is not None:
             self._thread.join(timeout)
             self._thread = None
+
+    def release_states(self):
+        """Once the loop has stopped, drop the K/V arenas and the per-slot
+        states from the scope (nothing reads them again; the weights
+        stay): what a caller does that needs the device's memory for
+        something else after serving. False, and nothing dropped, while
+        the loop runs."""
+        if self._thread is not None:
+            return False
+        self._scope.erase(
+            [n for kv in self._model.state_names for n in kv]
+            + [n for n, _shape, _dtype in self._model.slot_states])
+        return True
 
     def notify(self):
         with self._cond:
@@ -1496,19 +1509,21 @@ class _ModelEntry:
         toks[0, :real] = req.prompt[start:stop]
         pos = np.zeros((1, C), "int64")
         pos[0, :real] = np.arange(start, stop)
-        bias = m.chunk_bias(start, real)
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::chunk") as ev:
                 faults.fire("decode.chunk")
                 if ev.span is not None:
+                    # context: the rows behind the chunk; live_blocks: the
+                    # blocks its attention reads (through its own rows)
                     ev.span.set(request=req.id, tokens=real, ahead=ahead,
                                 last=last, passes=m.passes,
-                                block_mask=m.fills_blocks)
+                                block_mask=m.fills_blocks, context=start,
+                                live_blocks=-(-stop // m.block_size))
                 feeds = {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
-                    DecodeModel.CHU_BIAS: bias,
+                    DecodeModel.CHU_SPAN: m.chunk_span(start, real),
                     DecodeModel.CHU_ROWS: st.kv.row_map,
                     # never rewrite radix-shared rows
                     DecodeModel.CHU_WRITE_ROWS: st.kv.chunk_write_rows(
@@ -1528,7 +1543,8 @@ class _ModelEntry:
             # step is not delivered
             self._arena_lost(f"chunk-prefill failure: {e}")
             return 1
-        self._metrics.observe_chunk(real, time.perf_counter() - t0, ahead)
+        self._metrics.observe_chunk(real, time.perf_counter() - t0, ahead,
+                                    context=start)
         st.done = stop
         if not last:
             return 1

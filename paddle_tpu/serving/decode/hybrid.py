@@ -1,6 +1,6 @@
 """Hybrid decoders as paged DecodeModels: per-slot recurrent state beside
 paged grouped-query K/V rows, routed experts of which this chip holds a
-share, a stack of layers run several times a token. Three families, one
+share, a stack of layers run several times a token. Five families, one
 set of parts (``_Parts``: the named seeded parameters, projections, norms,
 arenas and their write; ``_hybrid_model``: the two programs around a
 family's ``stack`` and the DecodeModel).
@@ -87,6 +87,20 @@ block_len]`` positions whose queries ride K/V-head-major into the one
 together, all under the slot's one bias row), whose experts see ``S x
 block_len`` tokens, and whose last op decides one position a slot
 (``block_fill_decide``).
+
+**``granitemoehybrid``** (IBM Granite 4.0-H, dense: ``build_granite_hybrid_model``).
+``layer_types`` names one MIXER a layer, ``mamba`` (``nemotron_h``'s
+Mamba-2 mixer with ONE group: every head reads the same B and C) or
+``attention`` (grouped-query, no position encoding, scores scaled by
+``attention_multiplier`` and not by ``1 / sqrt(head)``); every layer is ``h
+<- h + residual_multiplier * mixer(RMSNorm(h))`` and then ``h <- h +
+residual_multiplier * mlp(RMSNorm(h))``, a dense SwiGLU of width
+``shared_intermediate_size`` in EVERY layer (no routed term: the family's
+``num_local_experts`` is 0 here, and another count is refused). The
+embedding is scaled by ``embedding_multiplier``, the head is TIED to it and
+the logits are divided by ``logits_scaling``; a final RMSNorm; no bias but
+the convolution's. No expert is counted: the decode step's one fetch is its
+tokens.
 """
 
 import math
@@ -94,7 +108,8 @@ import math
 from paddle_tpu.serving.decode.model import DecodeModel, _state_var
 
 __all__ = ["build_nemotron_h_model", "build_lfm2_model", "build_ouro_model",
-           "build_sdar_model", "MOE_COUNTS", "LOOP_COUNTS"]
+           "build_sdar_model", "build_granite_hybrid_model", "MOE_COUNTS",
+           "LOOP_COUNTS"]
 
 #: what the decode step's ``Counts`` hold, in order: the engine adds them
 #: to the counters of these names when the step's tokens come back
@@ -199,17 +214,18 @@ class _Parts:
         return self.fluid.ParamAttr(name=f"{self.prefix}.{suffix}",
                                     initializer=init)
 
-    def matrix(self, suffix, residual=False):
+    def matrix(self, suffix, residual=False, std=None):
         from paddle_tpu.initializer import NormalInitializer
 
         return self.attr(suffix, NormalInitializer(
-            0.0, self.back if residual else self.std))
+            0.0, std or (self.back if residual else self.std)))
 
     def proj(self, h, size, suffix, act=None, residual=False,
-             out_dtype=None):
+             out_dtype=None, std=None):
         return self.fluid.layers.fc(
             h, size, num_flatten_dims=len(h.shape) - 1, act=act,
-            bias_attr=False, param_attr=self.matrix(suffix + ".w", residual),
+            bias_attr=False,
+            param_attr=self.matrix(suffix + ".w", residual, std),
             out_dtype=out_dtype)
 
     def norm(self, h, suffix, out_dtype=None):
@@ -349,7 +365,9 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
     with unique_name.guard(), program_guard(chunk, startup):
         toks = fluid.data(DecodeModel.CHU_TOKENS, [1, C], dtype="int64")
         pos = fluid.data(DecodeModel.CHU_POSITIONS, [1, C], dtype="int64")
-        cbias = fluid.data(DecodeModel.CHU_BIAS, [1, C, L], dtype="float32")
+        # the chunk's first position and its count of real positions: the
+        # mask is made of them on the device
+        cspan = fluid.data(DecodeModel.CHU_SPAN, [2], dtype="int32")
         crows = fluid.data(DecodeModel.CHU_ROWS, [L], dtype="int64")
         cwrows = fluid.data(DecodeModel.CHU_WRITE_ROWS, [C], dtype="int64")
         # whose rows of the per-slot states the chunk advances
@@ -359,8 +377,8 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         def attend_chunk(i, q, k, v):
             nk, nv = parts.write(chunk, i, cwrows, k, v, 0)
             ctx = fluid.layers.chunk_paged_attention(
-                fluid.layers.squeeze(q, [0]), nk, nv, crows, cbias, kv_heads,
-                sm_scale=sm_scale)
+                fluid.layers.squeeze(q, [0]), nk, nv, crows, cspan, kv_heads,
+                BS, sm_scale=sm_scale, block_len=B)
             return fluid.layers.unsqueeze(ctx, [0])
 
         chu_logits, _ = stack(chunk, toks, pos, cwrows, "chunk",
@@ -400,6 +418,23 @@ def _held(expert_rank, held, router):
     return offset
 
 
+def _mamba_attrs(attr, i, dt_min, dt_max, dt_floor):
+    """Layer ``i``'s small Mamba-2 parameters as the family draws them:
+    ``A_log = log U(1, 16)``, ``dt_bias`` the inverse softplus of a
+    log-uniform step, ``D`` and the mixer norm 1, the convolution's weight
+    and bias ``U(-0.5, 0.5)`` (conv1d's default at 4 taps)."""
+    from paddle_tpu.initializer import ConstantInitializer, UniformInitializer
+
+    return {
+        "conv_w": attr(f"l{i}.conv_w", UniformInitializer(-0.5, 0.5)),
+        "conv_b": attr(f"l{i}.conv_b", UniformInitializer(-0.5, 0.5)),
+        "dt_bias": attr(f"l{i}.dt_bias", _dt_bias(dt_min, dt_max, dt_floor)),
+        "a_log": attr(f"l{i}.a_log", _log_uniform(1.0, 16.0)),
+        "d": attr(f"l{i}.d", ConstantInitializer(1.0)),
+        "norm_w": attr(f"l{i}.mixer_norm", ConstantInitializer(1.0)),
+    }
+
+
 def build_nemotron_h_model(
         vocab_size, hidden_size, hybrid_override_pattern, *,
         mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size,
@@ -421,8 +456,7 @@ def build_nemotron_h_model(
     with the reference has to catch)."""
     kwargs = dict(locals())
     import paddle_tpu as fluid
-    from paddle_tpu.initializer import (
-        ConstantInitializer, NormalInitializer, UniformInitializer)
+    from paddle_tpu.initializer import NormalInitializer
 
     V, H = int(vocab_size), int(hidden_size)
     pattern = str(hybrid_override_pattern)
@@ -460,15 +494,8 @@ def build_nemotron_h_model(
                                 parts.norm)
 
     def mamba_attrs(i):
-        return {
-            "conv_w": attr(f"l{i}.conv_w", UniformInitializer(-0.5, 0.5)),
-            "conv_b": attr(f"l{i}.conv_b", UniformInitializer(-0.5, 0.5)),
-            "dt_bias": attr(f"l{i}.dt_bias", _dt_bias(
-                time_step_min, time_step_max, time_step_floor)),
-            "a_log": attr(f"l{i}.a_log", _log_uniform(1.0, 16.0)),
-            "d": attr(f"l{i}.d", ConstantInitializer(1.0)),
-            "norm_w": attr(f"l{i}.mixer_norm", ConstantInitializer(1.0)),
-        }
+        return _mamba_attrs(attr, i, time_step_min, time_step_max,
+                            time_step_floor)
 
     def expert_attrs(i):
         return {
@@ -875,3 +902,136 @@ def build_sdar_model(
         slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
         kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
         version=version, block_len=B, mask_token=mask)
+
+
+def build_granite_hybrid_model(
+        vocab_size, hidden_size, layer_types, *, num_attention_heads,
+        num_key_value_heads, shared_intermediate_size, mamba_n_heads,
+        mamba_d_head, mamba_n_groups, mamba_d_state, mamba_d_conv,
+        mamba_chunk_size, embedding_multiplier, attention_multiplier,
+        residual_multiplier, logits_scaling, num_local_experts=0,
+        rms_norm_eps=1e-5, initializer_range=0.02, qk_initializer_range=None,
+        time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4,
+        dtype="bfloat16", state_dtype="float32", slots=4, max_len=64,
+        block_size=16, num_blocks=None, chunk_tokens=16, eos_id=None,
+        name="granite_hybrid", version="1"):
+    """Build the dense ``granitemoehybrid`` decoder as a paged DecodeModel
+    (module docstring). The sizes are the published ``config.json``'s keys
+    under their own names; a head is ``hidden_size / num_attention_heads``
+    wide; the Mamba mixer's inner width is ``mamba_n_heads x mamba_d_head``
+    (``mamba_expand`` is not read). ``num_blocks`` may be fewer than
+    ``slots`` sequences of ``max_len`` need: the engine then admits a
+    request against its whole block chain. ``state_dtype`` and
+    ``initializer_range`` as ``build_nemotron_h_model``'s and
+    ``build_lfm2_model``'s, but the embedding, which is also the head, is
+    drawn at ``initializer_range / embedding_multiplier``: the stream the
+    first layer reads then has the other families' scale, and a token does
+    not simply answer itself through the tied head.
+    ``qk_initializer_range`` draws the attention mixers' q and k
+    projections apart from the rest: ``attention_multiplier`` is muP's
+    ``1 / head`` and not ``1 / sqrt(head)``, which presumes a query and its
+    keys CORRELATE as a trained pair does; two independent draws at
+    ``initializer_range`` give scores of standard deviation
+    ``sqrt(head) x hidden x range^2 x attention_multiplier`` (0.1 at the
+    published sizes), a uniform softmax that hands on the mean of V
+    whatever K holds. The time-step draws are Mamba-2's defaults (the
+    family's ``config.json`` does not carry them)."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import NormalInitializer
+
+    if int(num_local_experts):
+        raise ValueError(
+            f"num_local_experts {num_local_experts}: only the dense member "
+            "of the family (no routed term beside the shared MLP) is built")
+    V, H = int(vocab_size), int(hidden_size)
+    kinds = [str(kind) for kind in layer_types]
+    if set(kinds) - {"mamba", "attention"}:
+        raise ValueError(f"layer_types {kinds}: a mixer is mamba or "
+                         "attention")
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    MH, MP, MG, MN = (int(mamba_n_heads), int(mamba_d_head),
+                      int(mamba_n_groups), int(mamba_d_state))
+    taps, F = int(mamba_d_conv), int(shared_intermediate_size)
+    d_inner = MH * MP
+    conv_dim = d_inner + 2 * MG * MN
+    in_width = 2 * d_inner + 2 * MG * MN + MH
+    NQ, NKV = int(num_attention_heads), int(num_key_value_heads)
+    D = H // NQ
+    eps = float(rms_norm_eps)
+    embed_by, by = float(embedding_multiplier), float(residual_multiplier)
+    prefix = f"{name}_v{version}"
+    m_layers = [i for i, kind in enumerate(kinds) if kind == "mamba"]
+    slot_states = []
+    for i in m_layers:
+        slot_states.append((f"{prefix}.conv{i}", (S, taps - 1, conv_dim),
+                            state_dtype))
+        slot_states.append((f"{prefix}.ssm{i}", (S, MH, MP, MN),
+                            state_dtype))
+    # one mixer's output projection a layer is drawn narrower, as
+    # ``nemotron_h``'s (1 / sqrt(layers)); the MLP's takes the same
+    std = float(initializer_range)
+    qk_std = float(qk_initializer_range or std)
+    parts = _Parts(
+        prefix, dtype, eps, std, std / math.sqrt(len(kinds)), R, NKV * D,
+        [i for i, kind in enumerate(kinds) if kind == "attention"],
+        slot_states)
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The layers over ``toks``: mixer, then the dense gated MLP, each
+        into the residual times ``residual_multiplier``; no position
+        encoding: a step's positions go unread."""
+        h = fluid.layers.scale(fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=attr("embed", NormalInitializer(0.0, std / embed_by))),
+            "float32"), scale=embed_by)
+        embed = program.global_block().var(f"{prefix}.embed")
+        for i, kind in enumerate(kinds):
+            x = norm(h, f"l{i}.input_layernorm")
+            if kind == "mamba":
+                at = 2 * m_layers.index(i)
+                y = fluid.layers.mamba2_mixer(
+                    proj(x, in_width, f"l{i}.in_proj", out_dtype="float32"),
+                    parts.slot_state(program, at),
+                    parts.slot_state(program, at + 1), wrows, R, mode, MH,
+                    MP, MG, MN, taps,
+                    _mamba_attrs(attr, i, time_step_min, time_step_max,
+                                 time_step_floor),
+                    slot=slot, positions=positions,
+                    chunk_size=int(mamba_chunk_size), epsilon=eps,
+                    out_dtype=dtype)
+                out = proj(y, H, f"l{i}.out_proj", residual=True,
+                           out_dtype="float32")
+            else:
+                ctx = attend(i, proj(x, NQ * D, f"l{i}.q", std=qk_std),
+                             proj(x, NKV * D, f"l{i}.k", std=qk_std),
+                             proj(x, NKV * D, f"l{i}.v"))
+                out = proj(ctx, H, f"l{i}.o", residual=True,
+                           out_dtype="float32")
+            h = fluid.layers.elementwise_add(
+                h, fluid.layers.scale(out, scale=by))
+            x = norm(h, f"l{i}.post_attention_layernorm")
+            # the input projection's two halves, stored apart
+            gated = fluid.layers.elementwise_mul(
+                proj(x, F, f"l{i}.mlp_gate", act="silu",
+                     out_dtype="float32"),
+                proj(x, F, f"l{i}.mlp_up", out_dtype="float32"))
+            out = proj(fluid.layers.cast(gated, dtype), H, f"l{i}.mlp_down",
+                       residual=True, out_dtype="float32")
+            h = fluid.layers.elementwise_add(
+                h, fluid.layers.scale(out, scale=by))
+        logits = fluid.layers.scale(
+            fluid.layers.matmul(norm(h, "norm"), embed, transpose_y=True,
+                                out_dtype="float32"),
+            scale=1.0 / float(logits_scaling))
+        return logits, []
+
+    return _hybrid_model(
+        parts, stack, lambda: build_granite_hybrid_model(**kwargs), vocab=V,
+        hidden=H, slots=S, max_len=L, block_size=BS, num_blocks=NB,
+        chunk_tokens=C, kv_heads=NKV, sm_scale=float(attention_multiplier),
+        eos_id=eos_id, name=name, version=version, count_names=())

@@ -131,6 +131,10 @@ LAUNCH_BUCKETS = (
 )
 
 
+#: real positions of a chunk launch: powers of two up to any chunk size
+CHUNK_TOKEN_BUCKETS = tuple(float(1 << i) for i in range(1, 14))
+
+
 class DecodeMetrics(ServingMetrics):
     COUNTERS = ServingMetrics.COUNTERS + (
         # iteration-level scheduler ("generated_tokens" counts tokens a
@@ -147,6 +151,10 @@ class DecodeMetrics(ServingMetrics):
         "prefill_device_injects",
         # chunked prefill (one budgeted chunk per engine iteration)
         "chunk_runs", "chunk_tokens",
+        # rows behind the chunks (their first positions, summed) and the
+        # (query, row) pairs their masks opened: what the chunks' attention
+        # is required to do follows the prompts so far
+        "chunk_context_rows", "chunk_attended_rows",
         # chunk launches, last or not, made with a decode step in flight
         "chunk_launches_ahead",
         # speculative decoding: target verify forwards vs emitted tokens
@@ -242,6 +250,12 @@ class DecodeMetrics(ServingMetrics):
             "serving_chunk_prefill_seconds",
             "one budgeted chunk-prefill forward", labels=labels,
         )
+        # its sum over a window is the prompt tokens prefilled there
+        self._chunk_size = self._registry.histogram(
+            "serving_chunk_prefill_tokens",
+            "real prompt positions of one chunk launch", labels=labels,
+            buckets=CHUNK_TOKEN_BUCKETS,
+        )
         self._first_token = self._registry.histogram(
             "serving_decode_first_token_seconds",
             "submit to first token", labels=labels, buckets=TOKEN_BUCKETS,
@@ -298,9 +312,16 @@ class DecodeMetrics(ServingMetrics):
         self.incr("prefills")
         self._prefill.observe(seconds)
 
-    def observe_chunk(self, tokens, seconds, ahead=False):
+    def observe_chunk(self, tokens, seconds, ahead=False, context=0):
+        """One launch of the chunk program over ``tokens`` real prompt
+        positions with ``context`` rows behind them (a causal mask: query
+        c sees ``context + c + 1`` rows)."""
         self.incr("chunk_runs")
         self.incr("chunk_tokens", tokens)
+        self.incr("chunk_context_rows", context)
+        self.incr("chunk_attended_rows",
+                  tokens * context + tokens * (tokens + 1) // 2)
+        self._chunk_size.observe(tokens)
         if ahead:
             self.incr("chunk_launches_ahead")
         self._chunk.observe(seconds)

@@ -49,7 +49,9 @@ static:
 * **chunk prefill** (built when ``chunk_tokens`` is set) — ``[1, C]``
   prompt chunk against the paged arena: scatters the chunk's own K/V
   rows, gathers the full ``[L]`` context view back, and attends under a
-  host-fed bias that opens exactly the causal prefix. Long prompts
+  bias that opens exactly the causal prefix, made on the device from the
+  chunk's two integers (``chu_span``: its first position and its count of
+  real positions; ``chunk_mask_bias``). Long prompts
   stream through it one budgeted chunk per engine iteration instead of
   stalling the decode batch.
 
@@ -122,7 +124,7 @@ class DecodeModel:
     **A model that generates by filling blocks** (``block_len`` B > 1,
     ``mask_token``): positions come in blocks of B; a position sees every
     earlier block and the WHOLE of its own, prompt and answer alike
-    (`chunk_bias`). The decode program is then a BLOCK PASS: it runs the B
+    (`chunk_bias` states the rule). The decode program is then a BLOCK PASS: it runs the B
     positions of each stepping slot's current block (a position not yet
     decided holds ``mask_token``), rewrites their K/V rows, and decides ONE
     more position a slot, the one its own confidence ranks first; a pass
@@ -146,7 +148,7 @@ class DecodeModel:
     INJ_ROWS = "inj_rows"
     CHU_TOKENS = "chu_tokens"
     CHU_POSITIONS = "chu_positions"
-    CHU_BIAS = "chu_bias"
+    CHU_SPAN = "chu_span"
     CHU_ROWS = "chu_rows"
     CHU_WRITE_ROWS = "chu_write_rows"
     CHU_SLOT = "chu_slot"
@@ -288,10 +290,19 @@ class DecodeModel:
         feed[slot, self.STEP_TABLE:self.step_table] = -1 if own else tokens
         feed[slot, self.step_table:] = table
 
+    def chunk_span(self, start, real):
+        """The chunk program's ``chu_span`` feed for ``real`` prompt
+        positions from ``start``: the two integers its mask is made from,
+        on the device."""
+        return np.array([start, real], "int32")
+
     def chunk_bias(self, start, real):
-        """The chunk program's ``[1, C, L]`` additive bias for ``real``
-        prompt positions from ``start``: a position sees what lies at or
-        before it, and with ``block_len`` B the whole of its own block."""
+        """The RULE of the chunk program's mask, stated in numpy for the
+        tests (nothing feeds it: the device makes the same of
+        ``chunk_span``, kernels/attention.py ``chunk_horizon``): the ``[1,
+        C, L]`` additive bias for ``real`` prompt positions from ``start``,
+        under which a position sees what lies at or before it, and with
+        ``block_len`` B the whole of its own block."""
         at, B = start + np.arange(real), self.block_len
         bias = np.full((1, self.chunk_tokens, self.max_len), NEG_INF,
                        "float32")
@@ -363,7 +374,7 @@ class DecodeModel:
         sig = [
             (self.CHU_TOKENS, (1, c), "int64"),
             (self.CHU_POSITIONS, (1, c), "int64"),
-            (self.CHU_BIAS, (1, c, l), "float32"),
+            (self.CHU_SPAN, (2,), "int32"),
             (self.CHU_ROWS, (l,), "int64"),
             (self.CHU_WRITE_ROWS, (c,), "int64"),
         ]
@@ -582,8 +593,8 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
             toks = fluid.data(DecodeModel.CHU_TOKENS, [1, C], dtype="int64")
             pos = fluid.data(DecodeModel.CHU_POSITIONS, [1, C],
                              dtype="int64")
-            bias = fluid.data(DecodeModel.CHU_BIAS, [1, C, L],
-                              dtype="float32")
+            bias = fluid.layers.chunk_mask_bias(
+                fluid.data(DecodeModel.CHU_SPAN, [2], dtype="int32"), C, L)
             crows = fluid.data(DecodeModel.CHU_ROWS, [L], dtype="int64")
             cwrows = fluid.data(DecodeModel.CHU_WRITE_ROWS, [C],
                                 dtype="int64")
@@ -601,8 +612,8 @@ def build_decoder_model(vocab_size, hidden=16, num_layers=2, ffn_dim=None,
                 fluid.layers.assign(nk, output=kc)
                 fluid.layers.assign(nv, output=vc)
                 # gather AFTER the scatter: the context view includes the
-                # chunk's own rows; the host bias opens exactly the
-                # causal prefix per chunk position
+                # chunk's own rows; the bias opens exactly the causal
+                # prefix per chunk position
                 gk = fluid.layers.block_gather(nk, crows, 1, L)
                 gv = fluid.layers.block_gather(nv, crows, 1, L)
                 scores = fluid.layers.matmul(q, gk, transpose_y=True,

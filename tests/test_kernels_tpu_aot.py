@@ -57,7 +57,7 @@ def test_kernel_compiles_for_v5e(name, v5e_devices):
             "case ran a composite, so it guards nothing")
 
 
-#: the five serving cells' paged-attention geometries (``_tpu_cases_paged``'s
+#: the six serving cells' paged-attention geometries (``_tpu_cases_paged``'s
 #: labels) and the blocks of a copy unit at each: about a megabyte of K
 #: plus V whatever the row width
 PAGED_UNITS = {
@@ -66,7 +66,8 @@ PAGED_UNITS = {
     "s128_l2048_b16_g8x4x64_bf16": 32,          # lfm2_24b_a2b
     "s16_l1024_b16_g16x1x128_bf16": 8,          # ouro_2_6b
     "s32_l1024_b16_g4x32x128_bf16": 32,         # sdar_30b_a3b, a block pass
-}
+    "s32_l16896_b16_g8x4x64_bf16": 32,          # granite_4_0_h_micro: 1,056
+}                                               # blocks a slot
 
 
 @pytest.mark.parametrize("label", sorted(PAGED_UNITS))

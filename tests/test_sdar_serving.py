@@ -29,7 +29,7 @@ if ROOT not in sys.path:
 from benchmark.references import plain_sdar as reference  # noqa: E402
 from paddle_tpu import kernels  # noqa: E402
 from paddle_tpu.core.registry import OpRegistry  # noqa: E402
-from paddle_tpu.kernels import moe  # noqa: E402
+from paddle_tpu.kernels import attention, moe  # noqa: E402
 from paddle_tpu.serving import (  # noqa: E402
     GenerationEngine, ServingError, build_sdar_model)
 from paddle_tpu.serving.decode import SamplingParams  # noqa: E402
@@ -230,7 +230,12 @@ def test_every_chunk_gives_the_references_logits_under_the_block_mask(exact):
         toks = tuple(feeds[DecodeModel.CHU_TOKENS][0, :real])
         by_tokens[start, toks] = out[0][0, :real]
         # block-causal: a position sees its whole block and no later one
-        bias = feeds[DecodeModel.CHU_BIAS][0]
+        # (the mask the device makes of the chunk's two integers)
+        assert [start, real] == list(feeds[DecodeModel.CHU_SPAN])
+        bias = np.asarray(attention.chunk_mask_bias(
+            feeds[DecodeModel.CHU_SPAN], GEOMETRY["chunk_tokens"],
+            GEOMETRY["max_len"], B))[0]
+        assert np.array_equal(bias, entry.model.chunk_bias(start, real)[0])
         for i in range(real):
             sees = np.flatnonzero(bias[i] == 0.0)
             assert sees[-1] == (start + i) // B * B + B - 1

@@ -1,14 +1,15 @@
 """A served hybrid configuration (``nemotron3_nano_30b_a3b``,
-``lfm2_24b_a2b``, ``ouro_2_6b``, ``sdar_30b_a3b``: the four families of
-``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
+``lfm2_24b_a2b``, ``ouro_2_6b``, ``sdar_30b_a3b``, ``granite_4_0_h_micro``:
+the five families of ``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
 ``reference``) against its plain reference, outside any timed window, and
 the readings the cell's limits are set from (its traffic file; PERF.md
 section 2).
 
     python3 tools/check_hybrid_logits.py --seed <n>
         [--config nemotron3_nano_30b_a3b] [--traffic reasoning_steady]
-        [--requests 32] [--steps 192] [--faults ssm,conv,kv,kv_all,positions]
-        [--references float8_e4m3fn,operands:bfloat16]
+        [--requests 32] [--steps 192]
+        [--faults ssm,conv,kv,kv_all,positions,chunk_ssm,chunk_kv,chunks_kv]
+        [--references float8_e4m3fn,operands:bfloat16,attention_multiplier=0.125]
         [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
         [--passes 3] [--share-passes] [--stale-arena 1,7]
         [--replays causal,left_to_right]
@@ -32,10 +33,18 @@ The served tokens are read once as served and once for each of
 or K arena (``kv``; ``kv_all``: every attention layer's) put back to what it
 was after every decode step (a stale row), or every decode step's
 positions one too far (``positions``: a rotation is relative, so the fault
-is the step's rows turned against the prompt's). ``--references``
+is the step's rows turned against the prompt's); ``chunk_ssm`` and
+``chunk_kv`` go wrong at ONE chunk boundary of every prompt longer than a
+chunk, the one before its last chunk: the first Mamba layer's SSM state of
+the slot dropped there, or the first attention layer's K arena left a chunk
+stale (``_chunk_fault``); ``chunks_kv`` puts that arena back after EVERY
+chunk launch, as ``kv`` does after every decode step (no prompt's K rows
+land in it: a chunk's queries find their own chunk's keys alone).
+``--references``
 reads the sound tokens again with the reference computed otherwise:
 ``<dtype>`` rounds its weights through that dtype (the precision below the
-served one), ``operands:<dtype>`` the left operand of its products (the
+served one), ``<key>=<value>`` misreads one published key of a reference
+that takes such controls (``attention_multiplier=0.125``), ``operands:<dtype>`` the left operand of its products (the
 served program's own arithmetic, as near as a plain pass comes), and for
 the latter the expert layers' choices are compared with the float32 pass's
 (``flips``): the tokens whose chosen sets differ, a layer, and those of
@@ -123,6 +132,44 @@ def _stale(entry, fault):
         out = launch(kind, feeds, span)
         for n, was in zip(names, kept):
             entry._scope.set(n, was)
+        return out
+
+    entry._run = run
+    return lambda: setattr(entry, "_run", launch)
+
+
+def _chunk_fault(entry, fault):
+    """``entry._run`` wrapped so that ONE chunk boundary of every prompt
+    longer than a chunk goes wrong, the one before its last chunk:
+    ``chunk_ssm`` drops the first Mamba layer's SSM state of the slot there
+    (the last chunk starts from zero in that layer), ``chunk_kv`` leaves
+    the first attention layer's K arena a chunk stale (the last chunk's K
+    rows never land). ``chunks_kv`` is ``chunk_kv`` at EVERY chunk launch.
+    Returns the call that takes the wrap off."""
+    import jax.numpy as jnp
+
+    m = entry.model
+    name = (m.state_names[0][0] if fault.endswith("_kv") else
+            [n for n, _s, _d in m.slot_states if ".ssm" in n][0])
+    launch = entry._run
+
+    def run(kind, feeds, span=None):
+        if kind != "chunk":
+            return launch(kind, feeds, span)
+        start = int(feeds[m.CHU_SPAN][0])
+        slot = int(feeds[m.CHU_SLOT][0])
+        plen = entry._slots[slot].plen
+        if fault != "chunks_kv" and (
+                not start
+                or start != (plen - 1) // m.chunk_tokens * m.chunk_tokens):
+            return launch(kind, feeds, span)
+        was = entry._scope.find_var(name)
+        if fault == "chunk_ssm":
+            entry._scope.set(name, was.at[slot].set(0))
+            return launch(kind, feeds, span)
+        kept = jnp.array(was, copy=True)
+        out = launch(kind, feeds, span)
+        entry._scope.set(name, kept)
         return out
 
     entry._run = run
@@ -309,7 +356,8 @@ def main(argv=None):
         system.engine.start()
         served = {"sound": _serve(system, prompts, steps)}
         for fault in faults:
-            undo = _stale(system.entry, fault)
+            undo = (_chunk_fault if fault.startswith("chunk")
+                    else _stale)(system.entry, fault)
             served["stale_" + fault] = _serve(system, prompts, steps)
             undo()
         system.engine.shutdown()
@@ -358,7 +406,9 @@ def main(argv=None):
                 [s[..., -2] - s[..., -1] for _ids, s in routing])
         refs = [(ref, dict({"round_operands": ref.split(":")[1]},
                            **({"routing": True} if held else {}))
-                 if ref.startswith("operands:") else {"round_to": ref})
+                 if ref.startswith("operands:")
+                 else {ref.split("=")[0]: float(ref.split("=")[1])}
+                 if "=" in ref else {"round_to": ref})
                 for ref in filter(None, args.references.split(","))]
         if args.passes is not None:
             refs.append((f"passes_{args.passes}", {"passes": args.passes}))
