@@ -13,6 +13,7 @@ paged_attention      paged_attention (decode [S,1])  tolerance mode
 chunk_paged_attention chunk_paged_attention ([C] of one slot) tolerance mode
 moe_experts          moe_routed_experts (decode)     tolerance mode
 ssm_update           mamba2_mixer (decode [S,1])     tolerance mode
+ssm_scan             mamba2_mixer (a prompt chunk)   tolerance mode
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
 ==================== ============================== ========= =========
 
@@ -522,6 +523,60 @@ def _tpu_cases_ssm_update():
         ((S,), "bool")])]
 
 
+def _scan_case(rng, T, H, P, N, G, real=None):
+    """A launch's scan operands as ``mixer_chunk`` makes them: ``dt`` after
+    softplus and 0 past the ``real`` tokens, ``a`` negative, ``b`` scaled so
+    that a launch's ``y`` is no larger than its ``x``, a carried ``h0``.
+    ``rng`` is a ``RandomState`` or a ``Generator``."""
+    draw = lambda *shape: rng.standard_normal(shape).astype("float32")
+    dt = np.log1p(np.exp(draw(T, H) - 1.0)).astype("float32")
+    dt[T if real is None else real:] = 0.0
+    return (draw(T, H, P), dt, -np.exp(0.5 * draw(H)), draw(T, G, N) / N,
+            draw(T, G, N), draw(H, P, N))
+
+
+def _parity_ssm_scan(rng):
+    """The scan with the kernel as its loop's body against the einsums:
+    one group and several (a head block of whole groups, and of part of
+    one), two heads side by side in a lane group and one, a launch that is
+    whole scan chunks and a ragged one behind ``dt = 0``."""
+    import jax
+
+    from paddle_tpu.kernels import mamba
+
+    for T, H, P, N, G, Q, real in (
+            (48, 8, 8, 16, 1, 16, None), (40, 32, 8, 16, 2, 16, 29),
+            (32, 8, 8, 16, 4, 16, None), (16, 4, 128, 128, 2, 8, None),
+            (16, 16, 64, 128, 8, 16, 11)):
+        args = _scan_case(rng, T, H, P, N, G, real)
+        got = jax.jit(lambda *a: mamba.ssm_scan_chunked(
+            *a, Q, kernel=True))(*args)
+        ref = jax.jit(lambda *a: mamba.ssm_scan_chunked(*a, Q))(*args)
+        for what, g, r in zip(("y", "state"), got, ref):
+            _assert_close_both_ways(g, r, f"ssm_scan {what}", 1e-5, 1e-5)
+
+
+def _tpu_cases_ssm_scan():
+    """granite_4_0_h_micro's launch (512 tokens in scan chunks of 256, 64
+    heads of 64 over a state of 128, one group), the same launch ragged
+    (301 tokens: padded to two scan chunks inside) and
+    nemotron3_nano_30b_a3b's (128 tokens, one scan chunk, 8 groups)."""
+    from paddle_tpu.kernels import mamba
+
+    def case(label, T, G, Q, H=64, P=64, N=128):
+        def fwd(*a):
+            return mamba.ssm_scan_chunked(*a, Q, kernel=False)
+
+        return (label, fwd, [
+            ((T, H, P), "float32"), ((T, H), "float32"), ((H,), "float32"),
+            ((T, G, N), "float32"), ((T, G, N), "float32"),
+            ((H, P, N), "float32")])
+
+    return [case("t512_q256_h64_p64_n128_g1", 512, 1, 256),
+            case("t301_q256_h64_p64_n128_g1", 301, 1, 256),
+            case("t128_q128_h64_p64_n128_g8", 128, 8, 128)]
+
+
 def _parity_remat(rng):
     import jax
     import jax.numpy as jnp
@@ -589,6 +644,13 @@ register(KernelSpec(
     tpu_cases=_tpu_cases_ssm_update,
     doc="one-token Mamba-2 state update of the stepping slots, in place "
         "(kernels/mamba.py)",
+))
+register(KernelSpec(
+    "ssm_scan", ("mamba2_mixer",), "tolerance", _parity_ssm_scan,
+    tpu_cases=_tpu_cases_ssm_scan,
+    doc="a scan chunk of the Mamba-2 recurrence for all heads, a head "
+        "block a grid step, its [Q, Q] decay-masked products kept in VMEM: "
+        "the body of the chunked scan's loop (kernels/mamba.py)",
 ))
 register(KernelSpec(
     "remat_policy", ("recompute_segment", "recompute_segment_grad"),

@@ -8,11 +8,13 @@ Per head, with a state ``h`` of ``[P, N]`` (head dim x state size)::
 ``ssm_scan_sequential`` is that recurrence one token at a time: THE
 definition. ``ssm_scan_chunked`` evaluates it exactly by chunks of ``Q``
 tokens (decay-masked ``C B^T`` inside a chunk, the carried state between
-chunks): the prefill's form, all einsums, no kernel. ``ssm_update`` is the
-decode step's one-token update over a ``[S, H, P, N]`` batch of per-slot
-states, a Pallas kernel that reads and writes the states of the STEPPING
-slots alone, in place; ``ssm_update_composite`` is its reference lowering,
-its CPU path and its ``off`` path.
+chunks): the prefill's form, a loop over the chunks whose body is einsums
+or, on the chip, the ``ssm_scan`` Pallas kernel (a scan chunk for all heads,
+a head block a grid step, the ``[Q, Q]`` decay-masked products kept in
+VMEM). ``ssm_update`` is the decode step's one-token update over a ``[S,
+H, P, N]`` batch of per-slot states, a Pallas kernel that reads and writes
+the states of the STEPPING slots alone, in place; ``ssm_update_composite``
+is its reference lowering, its CPU path and its ``off`` path.
 
 ``mixer_chunk`` / ``mixer_step`` are the whole mixer between its two
 projections (convolution, activations, dt, the scan, the gated grouped
@@ -80,12 +82,15 @@ def ssm_scan_sequential(x, dt, a, b, c, h0):
     return y, h
 
 
-def ssm_scan_chunked(x, dt, a, b, c, h0, chunk):
+def ssm_scan_chunked(x, dt, a, b, c, h0, chunk, kernel=None):
     """The same recurrence, exactly, by chunks of ``chunk`` tokens: inside
     a chunk ``y_t = sum_{s<=t} exp(cs_t - cs_s) (C_t . B_s) dt_s x_s`` with
     ``cs`` the running sum of ``dt * A``, plus what the carried state
     gives, ``exp(cs_t) C_t . h``; between chunks the state moves by the
-    whole chunk at once. Any length: the tail is padded with ``dt = 0``."""
+    whole chunk at once. Any length: the tail is padded with ``dt = 0``.
+    ``kernel`` is None (the scan's body is the einsums below) or the
+    interpret flag of the ``ssm_scan`` kernel, which is that body with a
+    chunk's ``[Q, Q]`` tiles kept in VMEM."""
     t_real, heads = x.shape[0], x.shape[1]
     q = min(int(chunk), t_real)
     pad = -t_real % q
@@ -112,8 +117,164 @@ def ssm_scan_chunked(x, dt, a, b, c, h0, chunk):
             precision=_HI)
         return h, y
 
-    h, y = jax.lax.scan(step, h0, (x, dt, b, c))
+    if kernel is None or not _scan_kernel_serves(x, b, kernel):
+        h, y = jax.lax.scan(step, h0, (x, dt, b, c))
+    else:
+        y, h = _scan_by_kernel(x, dt, a, b, c, h0, kernel)
     return y.reshape((n * q,) + y.shape[2:])[:t_real], h
+
+
+#: VMEM that the blocks of one ``ssm_scan`` grid step may take, both sets
+#: (the step being computed and the one being copied); the body's own
+#: values (``b c^T``, what the state gives and takes for the block's heads,
+#: a head's ``w`` tiles) come to ~3 MiB more, under Mosaic's default scoped
+#: limit of 16 MiB
+_SCAN_BLOCK_BYTES = 8 * 2 ** 20
+
+
+def _scan_heads(heads, per_group, q, p, n_state):
+    """Heads a grid step of ``ssm_scan`` holds, from the operands' shapes:
+    the largest divisor of ``heads`` up to ``_HEAD_BLOCK`` that is whole
+    groups or a whole part of one and whose ``x``, ``y`` and state blocks
+    fit ``_SCAN_BLOCK_BYTES``."""
+    per_head = 2 * 4 * (2 * q * p + 2 * p * n_state)
+    cap = max(1, min(_HEAD_BLOCK, heads, _SCAN_BLOCK_BYTES // per_head))
+    return max(g for g in range(1, cap + 1) if heads % g == 0
+               and (g % per_group == 0 or per_group % g == 0))
+
+
+def _scan_kernel_serves(x, b, interpret):
+    """Whether the kernel takes these chunks ``x`` ``[n, Q, H, P]``, ``b``
+    ``[n, Q, G, N]``: not inside a manual mesh region, and compiled only
+    where a scan chunk's tokens and the state's columns are whole lane
+    tiles and a head's rows whole sublane tiles."""
+    _n, q, _heads, p = x.shape
+    if vma_names(x) or (not interpret and (
+            q % 128 or b.shape[-1] % 128 or p % 8)):
+        fallback_counter().inc()
+        return False
+    return True
+
+
+def _scan_body(i_ref, x_ref, row_ref, col_ref, end_ref, b_ref, c_ref, h_ref,
+               _y_in, y_ref, ho_ref, *, block, p, per_group):
+    """One head block of one scan chunk, TIME ON THE LANES: ``x_ref``
+    ``[block * P, Q]`` (a head's ``P`` rows one under the other),
+    ``row_ref`` ``[4 * block, Q]`` (per head a row each of ``cs``,
+    ``exp(cs)``, ``dt`` and the decay to the chunk's end), ``col_ref``
+    ``[Q, block]`` (``cs`` as columns), ``end_ref`` ``[block, N]`` (the
+    whole chunk's decay), ``b_ref``, ``c_ref`` ``[groups of the block, Q,
+    N]``, ``h_ref`` ``[block * P, N]``. A head's ``w^T`` ``[Q, Q]`` (``s``
+    down, ``t`` across) stays where it is made and the head's ``P`` rows of
+    ``dt x`` stream past it; what the state gives and what it takes are one
+    product a group each. ``w^T`` is made a lane tile of ``t`` at a time,
+    down to the last ``s`` the mask lets that tile see: the tokens after it
+    are zeros by the mask and are neither made nor multiplied."""
+    del i_ref
+    f32 = jnp.float32
+    q = x_ref.shape[1]
+    dot = functools.partial(jax.lax.dot_general, precision=_HI,
+                            preferred_element_type=f32)
+    live = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+            <= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+    tile = 128 if q % 128 == 0 else q
+    in_group = min(block, per_group)
+    for g in range(block // in_group):
+        b, c = b_ref[g], c_ref[g]                              # [Q, N]
+        bc = dot(b, c, (((1,), (1,)), ((), ())))               # [Q s, Q t]
+        lo = g * in_group * p
+        from_state = dot(h_ref[lo:lo + in_group * p, :], c,
+                         (((1,), (1,)), ((), ())))             # [.. P, Q]
+        to_state = []
+        for k in range(in_group):
+            i, rows = g * in_group + k, slice(lo + k * p, lo + (k + 1) * p)
+            ecs, dt, to_end = (
+                row_ref[m * block + i:m * block + i + 1, :]
+                for m in range(1, 4))                          # [1, Q]
+            xdt = x_ref[rows, :] * dt                          # [P, Q]
+            y = []
+            for t0 in range(0, q, tile):
+                t, s = slice(t0, t0 + tile), slice(0, t0 + tile)
+                w = jnp.where(
+                    live[s, t],
+                    jnp.exp(row_ref[i:i + 1, t] - col_ref[s, i:i + 1]),
+                    0.0) * bc[s, t]
+                y.append(dot(xdt[:, s], w, (((1,), (0,)), ((), ()))))
+            y_ref[rows, :] = (jnp.concatenate(y, axis=1)
+                              + ecs * from_state[k * p:(k + 1) * p])
+            to_state.append(xdt * to_end)
+        new = dot(jnp.concatenate(to_state, axis=0), b,
+                  (((1,), (0,)), ((), ())))                    # [.. P, N]
+        for k in range(in_group):
+            i, rows = g * in_group + k, slice(lo + k * p, lo + (k + 1) * p)
+            ho_ref[rows, :] = (end_ref[i:i + 1, :] * h_ref[rows, :]
+                               + new[k * p:(k + 1) * p])
+
+
+def _scan_by_kernel(x, dt, a, b, c, h0, interpret):
+    """The scan over the chunks ``x`` ``[n, Q, H, P]``, ``dt`` ``[n, Q, H]``,
+    ``b``, ``c`` ``[n, Q, G, N]`` with the ``ssm_scan`` kernel as its body:
+    a trip is one call whose grid runs over head blocks. Everything that is
+    a number a token a head (``cs`` and what is made of it: kilobytes) is
+    made here for all chunks at once, as the rows and columns the body
+    broadcasts from. ``x`` and ``y`` go in and out as ``[H * P, n * Q]``,
+    time last: the layout XLA gives the projections around a mixer at these
+    shapes, so neither is copied to be turned; they stay whole arrays that
+    a trip reads and writes its chunk of in place (the trip's index is a
+    scalar the block maps read), so the loop copies nothing in or out."""
+    f32 = jnp.float32
+    n, q, heads, p = x.shape
+    groups, n_state = b.shape[2], b.shape[3]
+    per_group = heads // groups
+    block = _scan_heads(heads, per_group, q, p, n_state)
+    nb = heads // block
+    cs = jnp.cumsum(dt * a, axis=1)                            # [n, Q, H]
+    rows = jnp.stack([cs, jnp.exp(cs), dt, jnp.exp(cs[:, -1:] - cs)], 1)
+    rows = rows.reshape(n, 4, q, nb, block).transpose(0, 3, 1, 4, 2)
+    rows = rows.reshape(n, nb, 4 * block, q)
+    cols = cs.reshape(n, q, nb, block).swapaxes(1, 2)          # [n,nb,Q,blk]
+    end = jnp.broadcast_to(jnp.exp(cs[:, -1]).reshape(n, nb, block, 1),
+                           (n, nb, block, n_state))
+    b, c = (jnp.swapaxes(v.astype(f32), 1, 2) for v in (b, c))  # [n,G,Q,N]
+    group_block = max(1, block // per_group)
+    blocks_a_group = max(1, per_group // block)
+    xy_spec = pl.BlockSpec((block * p, q), lambda j, i_ref: (j, i_ref[0]))
+    small = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (None, None) + shape, lambda j, i_ref: (i_ref[0], j, 0, 0))
+    bc_spec = pl.BlockSpec(
+        (None, group_block, q, n_state),
+        lambda j, i_ref: (i_ref[0], j // blocks_a_group, 0, 0))
+    h_spec = pl.BlockSpec((block * p, n_state), lambda j, i_ref: (j, 0))
+    call = pl.pallas_call(
+        functools.partial(_scan_body, block=block, p=p,
+                          per_group=per_group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb,),
+            in_specs=[xy_spec, small(4 * block, q), small(q, block),
+                      small(block, n_state), bc_spec, bc_spec, h_spec,
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[xy_spec, h_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((heads * p, n * q), f32),
+                   jax.ShapeDtypeStruct((heads * p, n_state), f32)],
+        input_output_aliases={7: 1, 8: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="ssm_scan",
+    )
+    x = x.astype(f32).reshape(n * q, heads * p).T
+
+    def trip(carry, i):
+        h, y = carry
+        y, h = call(i.reshape(1), x, rows, cols, end, b, c, h, y)
+        return (h, y), None
+
+    (h, y), _ = jax.lax.scan(
+        trip, (h0.astype(f32).reshape(heads * p, n_state),
+               jnp.zeros_like(x)), jnp.arange(n, dtype=jnp.int32))
+    return y.T.reshape(n, q, heads, p), h.reshape(heads, p, n_state)
 
 
 def ssm_update_composite(state, xdt, decay, bh, ch, mask):
@@ -295,13 +456,15 @@ def short_conv_step(bcu, conv_w, conv_state, mask, out_dtype):
 
 
 def mixer_chunk(zxbcdt, params, conv_state, ssm_state, slot, mask, reset, *,
-                heads, head_dim, groups, n_state, chunk, eps, out_dtype):
+                heads, head_dim, groups, n_state, chunk, eps, out_dtype,
+                kernel=None):
     """A prompt chunk ``[T, in_proj width]`` of ONE slot against that slot's
     rows of the state arrays. ``mask`` ``[T]`` marks the real positions (a
     prefix), ``reset`` says the chunk opens the prompt: the slot's states
-    start from zero, whatever a retired request left there. Returns the
-    gated, normed ``y`` ``[T, d_inner]`` and both state arrays with the
-    slot's rows replaced."""
+    start from zero, whatever a retired request left there. ``kernel`` is
+    None (the composite scan) or the interpret flag of the ``ssm_scan``
+    kernel. Returns the gated, normed ``y`` ``[T, d_inner]`` and both state
+    arrays with the slot's rows replaced."""
     f32 = jnp.float32
     conv_w, conv_b, dt_bias, a_log, d_skip, norm_w = _float32(params)
     z, xbc, dt = _split(zxbcdt.astype(f32), heads, head_dim, groups, n_state)
@@ -314,7 +477,8 @@ def mixer_chunk(zxbcdt, params, conv_state, ssm_state, slot, mask, reset, *,
     x, b, c = _split_xbc(jax.nn.silu(conv + conv_b), heads, head_dim, groups,
                          n_state)
     dt = jnp.where(mask[:, None], jax.nn.softplus(dt + dt_bias), 0.0)
-    y, h = ssm_scan_chunked(x, dt, -jnp.exp(a_log), b, c, h0, chunk)
+    y, h = ssm_scan_chunked(x, dt, -jnp.exp(a_log), b, c, h0, chunk,
+                            kernel=kernel)
     y = (y + d_skip[:, None] * x).reshape(t, heads * head_dim)
     out = gated_group_norm(y, z, norm_w, groups, eps).astype(out_dtype)
     ssm_state = jax.lax.dynamic_update_index_in_dim(

@@ -14,7 +14,7 @@ mathematics). Two modes, by ``mode``:
 In both, ``WriteRows`` marks the real tokens: a token whose row is ``>=
 num_rows`` writes no K/V row anywhere (a slot that does not step, a chunk's
 padding) and moves no state here. The decode step's state update may be
-served by the ``ssm_update`` kernel.
+served by the ``ssm_update`` kernel, a prompt chunk's scan by ``ssm_scan``.
 """
 
 from paddle_tpu.core.registry import OpDef, OpRegistry
@@ -39,7 +39,7 @@ def _mixer(ins, attrs, kernel):
         y, conv, ssm = mamba.mixer_chunk(
             x[0], params, conv, ssm, first(ins, "Slot")[0], mask,
             first(ins, "Positions")[0, 0] == 0,
-            chunk=attrs.get("chunk_size", 128), **sizes)
+            chunk=attrs.get("chunk_size", 128), kernel=kernel, **sizes)
         y = y[None]
     else:
         y, conv, ssm = mamba.mixer_step(x[:, 0], params, conv, ssm, mask,
@@ -55,7 +55,8 @@ def _mixer_reference(ins, attrs):
 def _mixer_pallas(ins, attrs):
     from paddle_tpu import kernels
 
-    sel = kernels.selected("ssm_update") if attrs["mode"] == "step" else None
+    sel = kernels.selected(
+        "ssm_update" if attrs["mode"] == "step" else "ssm_scan")
     return _mixer(ins, attrs, None if sel is None else sel.interpret)
 
 

@@ -137,6 +137,61 @@ def test_flash_kernels_group_heads_at_every_geometry(label, v5e_devices):
                 == bh // g * (seq // 128))
 
 
+#: the hybrid serving cells' Mamba layers as a launch of the chunk program
+#: meets them: (tokens a launch, groups, scan chunk) at 64 heads of 64 over
+#: a state of 128, and the loops the compiled mixer keeps (a launch of ONE
+#: scan chunk has none: XLA drops a loop of one trip)
+SCAN_LAYERS = {
+    "granite_4_0_h_micro": (512, 1, 256, 1),
+    "nemotron3_nano_30b_a3b": (128, 8, 128, 0),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SCAN_LAYERS))
+def test_ssm_scan_kernel_is_the_body_of_the_mixers_one_loop(cell,
+                                                            v5e_devices):
+    """``mixer_chunk`` with the ``ssm_scan`` kernel, compiled for the chip
+    at each hybrid cell's geometry: no fallback, one custom call, and the
+    loop over the scan chunks still there around it (what
+    ``ssm_scan_device_share`` matches, ``^%?while``, prices kernel and glue
+    alike), with nothing of a chunk's size copied inside it: the body reads
+    and writes its chunk of the launch's ``x`` and ``y`` in place."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import mamba
+
+    tokens, groups, chunk, loops = SCAN_LAYERS[cell]
+    H, P, N, S = 64, 64, 128, 32
+    conv_dim = H * P + 2 * groups * N
+    width = 2 * H * P + 2 * groups * N + H
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    spec = lambda shape, dtype="float32": jax.ShapeDtypeStruct(  # noqa: E731
+        shape, np.dtype(dtype), sharding=sharding)
+    params = {"conv_w": spec((4, conv_dim)), "conv_b": spec((conv_dim,)),
+              "dt_bias": spec((H,)), "a_log": spec((H,)), "d": spec((H,)),
+              "norm_w": spec((H * P,))}
+
+    def layer(z, params, conv, ssm, slot, mask):
+        return mamba.mixer_chunk(
+            z, params, conv, ssm, slot, mask, False, heads=H, head_dim=P,
+            groups=groups, n_state=N, chunk=chunk, eps=1e-5,
+            out_dtype=jnp.bfloat16, kernel=False)
+
+    before = kernels.fallback_counter().value
+    text = jax.jit(layer).lower(
+        spec((tokens, width), "bfloat16"), params, spec((S, 3, conv_dim)),
+        spec((S, H, P, N)), spec((), "int32"), spec((tokens,), "bool"),
+    ).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r" while\(", text)) == loops
+    if loops:
+        name = re.search(r" while\(.*body=%?([\w.-]+)", text).group(1)
+        body = text.split(f"\n%{name} (", 1)[1].split("\n}", 1)[0]
+        assert "custom-call(" in body
+        assert f"f32[{H * P},{chunk}]" not in body
+
+
 @pytest.mark.parametrize("shape,names", [
     ((4,), ("data",)), ((2, 2), ("data", "model")), ((2, 2), ("dcn", "data")),
 ])
