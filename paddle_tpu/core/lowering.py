@@ -25,6 +25,7 @@ remaining free-function jits (models/, tools/), so compile counts stay
 observable from one place.
 """
 
+import re
 import time
 
 import numpy as np
@@ -346,6 +347,25 @@ def _rng_abstract():
     return jax.ShapeDtypeStruct(key.shape, key.dtype)
 
 
+def _named(fn, label):
+    """``fn`` (a lowered step's four-argument contract) under a name made
+    of ``label``: what ``jax.jit`` is handed, so that the device module is
+    ``jit_decode_<model>_step`` in a profiler's trace and not the
+    ``jit_call`` that every ``exported.call`` would be. The wrapper is
+    outside whatever is exported: the persisted module's bytes and the
+    fingerprint do not carry it. An entry shared through the memory tier
+    (one fingerprint under two labels) keeps its first label's name."""
+    name = re.sub(r"\W", "_", label)
+    if not name or name[0].isdigit():
+        name = "_" + name
+
+    def call(feed_vals, donated_vals, readonly_vals, rng_key):
+        return fn(feed_vals, donated_vals, readonly_vals, rng_key)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 def _default_step(block, plan):
     feed_names, fetch_names, donated, readonly, written, ops = plan
     from paddle_tpu.core.executor import _interpret_block
@@ -488,7 +508,7 @@ def lower_step(
                 header, payload = rec
                 t0 = time.perf_counter()
                 entry = _entry_from_payload(header, payload, plan,
-                                            fingerprint, jit_kwargs)
+                                            fingerprint, jit_kwargs, label)
                 if entry is not None:
                     _PERSIST_HITS.inc()
                     _PERSIST_LOAD_SECONDS.observe(time.perf_counter() - t0)
@@ -502,11 +522,11 @@ def lower_step(
         if persist:
             fn = _trace_and_persist(
                 step, plan, _abstract_args(plan, feed_sig, scope),
-                fingerprint, jit_kwargs,
+                fingerprint, jit_kwargs, label,
             )
         if fn is None:
             _JITS.inc()
-            fn = jax.jit(step, **jit_kwargs)
+            fn = jax.jit(_named(step, label), **jit_kwargs)
         return LoweredStep(fn, plan, fingerprint, "trace",
                            time.perf_counter() - t0)
 
@@ -559,7 +579,8 @@ def _abstract_args(plan, feed_sig, scope):
     return (feed_sds, donated_sds, readonly_sds, _rng_abstract())
 
 
-def _trace_and_persist(step, plan, abstract_sig, fingerprint, jit_kwargs):
+def _trace_and_persist(step, plan, abstract_sig, fingerprint, jit_kwargs,
+                       label):
     """Trace once through ``jax.export``, persist the serialized module,
     and return a jitted wrapper around the exported call — the EXACT
     module a later process will deserialize, so cache-cold and cache-warm
@@ -596,7 +617,8 @@ def _trace_and_persist(step, plan, abstract_sig, fingerprint, jit_kwargs):
         payload,
     )
     _JITS.inc()
-    return jax.jit(exported.call, **_wrapper_jit_kwargs(jit_kwargs))
+    return jax.jit(_named(exported.call, label),
+                   **_wrapper_jit_kwargs(jit_kwargs))
 
 
 def _wrapper_jit_kwargs(jit_kwargs):
@@ -609,7 +631,8 @@ def _wrapper_jit_kwargs(jit_kwargs):
     return out
 
 
-def _entry_from_payload(header, payload, plan, fingerprint, jit_kwargs):
+def _entry_from_payload(header, payload, plan, fingerprint, jit_kwargs,
+                        label):
     """Wrap a persisted module for execution, cross-checking the stored
     I/O plan against the freshly computed one — a mismatch means the
     planner or program changed without changing the fingerprint inputs
@@ -631,7 +654,8 @@ def _entry_from_payload(header, payload, plan, fingerprint, jit_kwargs):
     try:
         exported = jax_export.deserialize(payload)
         _JITS.inc()
-        fn = jax.jit(exported.call, **_wrapper_jit_kwargs(jit_kwargs))
+        fn = jax.jit(_named(exported.call, label),
+                     **_wrapper_jit_kwargs(jit_kwargs))
     except Exception:
         return None
     return LoweredStep(fn, plan, fingerprint, "disk", 0.0)

@@ -22,6 +22,18 @@ an enabled span also opens a ``jax.profiler.TraceAnnotation`` of the same
 name and arguments, so xprof / Perfetto show the host's phases beside the
 device operations they launched.
 
+Lanes: a producer that knows when something OFF the host ran (the decode
+engine's ready watcher, ``serving/decode/lane.py``: when the device had
+finished each launch) records it with ``record_lane`` on a named track of
+its own (``device:0``). A lane event is on the tracer's clock and in the
+Chrome export, beside the host spans, but is NOT a span: ``spans()`` does
+not return it (a consumer that cuts time along whatever span started last
+would cut along the device's events too), ``lanes()`` does, and it has no
+``TraceAnnotation`` twin (xprof has the device itself). Lanes are asked for,
+``tracing(path, lanes=True)``: their producer costs a thread that wakes with
+every launch, which a capture that wants the host's spans alone (the
+benchmark's traced run) does not pay.
+
     with tracing("/tmp/run.trace.json"):
         with trace_scope("step"):
             with trace_scope("fwd"):
@@ -45,6 +57,7 @@ __all__ = [
     "instant",
     "tracing",
     "tracing_enabled",
+    "lanes_enabled",
     "enable_tracing",
     "disable_tracing",
     "export_chrome_trace",
@@ -53,6 +66,7 @@ __all__ = [
 
 # span tuple layout (kept flat — dicts are built once, at export):
 # (name, cat, start_ns, dur_ns, tid, thread_name, depth, args)
+# lane event layout: (track, name, start_ns, dur_ns, args)
 
 
 class Tracer:
@@ -62,34 +76,40 @@ class Tracer:
 
     def __init__(self, max_events=1_000_000):
         self.enabled = False
+        self.lanes_on = False       # while enabled: lanes were asked for
         self._default_max_events = int(max_events)
         self.max_events = int(max_events)
         self._lock = threading.Lock()
         self._spans = []
         self._instants = []
+        self._lanes = []
         self._dropped = 0
         self._epoch_ns = time.perf_counter_ns()
         self._tls = threading.local()
 
     # -- lifecycle ---------------------------------------------------------
-    def start(self, max_events=None):
+    def start(self, max_events=None, lanes=False):
         with self._lock:
             # a cap set for one capture does not leak into the next
             self.max_events = (int(max_events) if max_events is not None
                                else self._default_max_events)
             self._spans = []
             self._instants = []
+            self._lanes = []
             self._dropped = 0
             self._epoch_ns = time.perf_counter_ns()
+            self.lanes_on = bool(lanes)
             self.enabled = True
 
     def stop(self):
         self.enabled = False
+        self.lanes_on = False
 
     def clear(self):
         with self._lock:
             self._spans = []
             self._instants = []
+            self._lanes = []
             self._dropped = 0
 
     # -- per-thread nesting ------------------------------------------------
@@ -131,6 +151,22 @@ class Tracer:
                 return
             self._instants.append(ev)
 
+    def record_lane(self, track, name, start_ns, end_ns, args=None):
+        """One event of a lane (module docstring): what ran on ``track``
+        from ``start_ns`` to ``end_ns`` of ``perf_counter_ns``. Recorded
+        whether or not the tracer is still enabled: the producer learns of
+        an event after it ended, and the last ones of a capture end after
+        its ``stop()``. An event that began before the capture did belongs
+        to the one before and is dropped."""
+        with self._lock:
+            if start_ns < self._epoch_ns:
+                return
+            if len(self._lanes) >= self.max_events:
+                self._dropped += 1
+                return
+            self._lanes.append((track, name, start_ns, end_ns - start_ns,
+                                args))
+
     # -- introspection (tests, summaries) ----------------------------------
     def spans(self):
         """Snapshot of finished spans as dicts (ns-resolution, epoch-
@@ -160,9 +196,28 @@ class Tracer:
             for name, cat, ts, _dur, tid, tname, _d, args in evs
         ]
 
+    def lanes(self):
+        """Snapshot of the lane events as dicts, in the order recorded
+        (ns-resolution, epoch-relative start)."""
+        with self._lock:
+            lanes = list(self._lanes)
+        return [
+            {"track": track, "name": name,
+             "start_ns": start_ns - self._epoch_ns, "dur_ns": dur_ns,
+             "args": args or {}}
+            for track, name, start_ns, dur_ns, args in lanes
+        ]
+
     @property
     def dropped(self):
         return self._dropped
+
+    @property
+    def epoch_ns(self):
+        """When the current capture began (``start()``), on
+        ``perf_counter_ns``: an event stamped before it is another
+        capture's."""
+        return self._epoch_ns
 
     # -- export ------------------------------------------------------------
     def chrome_trace(self):
@@ -173,6 +228,7 @@ class Tracer:
         with self._lock:
             spans = list(self._spans)
             instants = list(self._instants)
+            lanes = list(self._lanes)
             epoch = self._epoch_ns
             dropped = self._dropped
         events = [
@@ -211,6 +267,23 @@ class Tracer:
             if args:
                 ev["args"] = dict(args)
             events.append(ev)
+        # a lane is a track of its own: small tids, which no thread's
+        # ident (an address) takes; 0 carries the process's name
+        lane_tids = {}
+        for track, name, start_ns, dur_ns, args in lanes:
+            ev = {
+                "name": name,
+                "cat": "lane",
+                "ph": "X",
+                "ts": (start_ns - epoch) / 1e3,
+                "dur": dur_ns / 1e3,
+                "pid": pid,
+                "tid": lane_tids.setdefault(track, len(lane_tids) + 1),
+            }
+            if args:
+                ev["args"] = dict(args)
+            events.append(ev)
+        seen_tids.update((tid, track) for track, tid in lane_tids.items())
         for tid, tname in seen_tids.items():
             events.append({
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
@@ -246,8 +319,12 @@ def tracing_enabled():
     return _TRACER.enabled
 
 
-def enable_tracing(max_events=None):
-    _TRACER.start(max_events=max_events)
+def lanes_enabled():
+    return _TRACER.lanes_on
+
+
+def enable_tracing(max_events=None, lanes=False):
+    _TRACER.start(max_events=max_events, lanes=lanes)
     return _TRACER
 
 
@@ -262,17 +339,19 @@ def export_chrome_trace(path):
 
 class tracing:
     """Context manager: enable the default tracer, optionally exporting a
-    Chrome-trace JSON on exit.
+    Chrome-trace JSON on exit; ``lanes=True`` asks the lanes' producers
+    to record too (module docstring).
 
         with tracing("/tmp/step.trace.json") as tr: ...
     """
 
-    def __init__(self, path=None, max_events=None):
+    def __init__(self, path=None, max_events=None, lanes=False):
         self.path = path
         self.max_events = max_events
+        self.lanes = lanes
 
     def __enter__(self):
-        return enable_tracing(max_events=self.max_events)
+        return enable_tracing(max_events=self.max_events, lanes=self.lanes)
 
     def __exit__(self, *exc):
         disable_tracing()
@@ -346,6 +425,11 @@ class trace_scope:
         """Nanoseconds since the live span opened: one clock read, for a
         phase boundary inside a span that must not hold a child span."""
         return time.perf_counter_ns() - self._t0
+
+    def opened_ns(self):
+        """When the live span opened, on ``perf_counter_ns``: what turns an
+        ``elapsed_ns`` into a time on the tracer's clock. No clock read."""
+        return self._t0
 
     def __call__(self, fn):
         name, cat, args = self.name, self.cat, self.args or {}

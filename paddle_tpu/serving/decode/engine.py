@@ -106,8 +106,12 @@ Measured from inside (nothing while tracing is off): one
 each carrying its request's id where it has one; a launch span says what it
 put (`_run`), ``decode::step`` and ``decode::chunk`` whether they were
 launched ahead, a ``decode::step_fetch`` with nothing launched over it why
-(``drain``). Always on: a time stamp per token on the ``Response``, the
-bytes that cross the device boundary, the steps launched ahead and the
+(``drain``); and, where the capture asks (``tracing(path, lanes=True)``),
+the engine keeps a lane of the DEVICE's own (``lane.py``: when the device
+had finished each launch of `_run`, so busy and idle seconds by cause,
+``serving_device_*``, and a ``device::<kind>`` event a launch on the track
+``device:<id>``). Always on: a time stamp per token on the ``Response``,
+the bytes that cross the device boundary, the steps launched ahead and the
 drains by reason (``DecodeMetrics``).
 """
 
@@ -119,6 +123,7 @@ import numpy as np
 from paddle_tpu import profiler
 from paddle_tpu.observability import lockdep
 from paddle_tpu.observability.tracer import instant as _instant
+from paddle_tpu.observability.tracer import lanes_enabled as _lanes
 from paddle_tpu.observability.tracer import span as _span
 from paddle_tpu.resilience import faults
 from paddle_tpu.serving.decode.generate import (
@@ -139,6 +144,7 @@ from paddle_tpu.serving.decode.kvstate import (
     KVStore,
     SlotPool,
 )
+from paddle_tpu.serving.decode.lane import DeviceLane
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.model import NEG_INF, DecodeModel
 from paddle_tpu.serving.engine import _ReplicaBreaker
@@ -421,6 +427,7 @@ class _ModelEntry:
         self._iteration = 0     # decode::iterate spans opened (traced only)
         self._launched = None   # the _LaunchedStep in flight, if any
         self._chunk_rows = []   # [_LaunchedChunk] last chunks not landed
+        self._lane_slept = 0.0  # the loop's sleep at its last stamped launch
         self._admit_seq = 0
         self._chunk_throttle = False
         self.victim_policy = None   # callable([slot ids]) -> slot id
@@ -550,10 +557,13 @@ class _ModelEntry:
             return
         self._pickers["prefill"] = picker(L)
         rows = m.chunk_tokens if chunked else L
-        self._stack_live = jax.jit(
-            lambda *kv: jax.numpy.stack([a[0, :rows] for a in kv])
-        ).lower(*[sds(1, L, m.hidden)] * (2 * len(m.prefill_kv_fetches))
-                ).compile()
+
+        def stack_live(*kv):    # the device module's name, as the lane's
+            return jax.numpy.stack([a[0, :rows] for a in kv])
+
+        self._stack_live = jax.jit(stack_live).lower(
+            *[sds(1, L, m.hidden)] * (2 * len(m.prefill_kv_fetches))
+        ).compile()
         self._causal_bias = jax.device_put(
             np.triu(np.full((L, L), NEG_INF, "float32"), k=1)[None],
             self._engine.device)
@@ -576,7 +586,16 @@ class _ModelEntry:
         previous step's own output) is handed over as it is: nothing is
         put, nothing counted as fed, and the host does not wait for it;
         so are a one-shot prefill's K/V outputs fed to the inject program,
-        and the prefill program's constant causal bias."""
+        and the prefill program's constant causal bias.
+
+        While the tracer's lanes are on (one test, else) the launch also
+        goes to the device's lane (``lane.py``): with a live span and an
+        output that no later launch donates (the last of ``fetches``, never
+        an arena), the ready watcher is handed the span's two reads as times
+        on the tracer's clock, what the loop slept since its launch before
+        and that output; a launch with no span or no output (the inject
+        program) is named to the lane and rides with the next stamped
+        one."""
         import jax
 
         entry, executable = self._entries[kind]
@@ -610,6 +629,17 @@ class _ModelEntry:
         self._metrics.count_launch(kind, fed)
         for n, u in zip(entry.written, updates):
             self._scope.set(n, u)
+        if _lanes():
+            if span is not None and fetches:
+                t_call0 = span.opened_ns() + put_ns
+                slept = self._metrics.slept_seconds()
+                self._engine.lane.launched(
+                    self._metrics, kind, self._metrics.launches(kind),
+                    t_call0, t_call0 + call_ns,
+                    int((slept - self._lane_slept) * 1e9), fetches[-1])
+                self._lane_slept = slept
+            else:
+                self._engine.lane.rode(kind, program=True)
         return fetches
 
     def _fetch(self, value):
@@ -1452,6 +1482,9 @@ class _ModelEntry:
             row = self._pickers["prefill"](
                 fetches[0], np.int32(len(req.prompt) - 1))
             live = self._stack_live(*fetches[1:])
+            if _lanes():
+                self._engine.lane.rode("pick_row")
+                self._engine.lane.rode("stack_live")
             logits_row = self._fetch(row)
             live = self._fetch(live)
             self.kv.prefix_put(key, live, logits_row)
@@ -1555,6 +1588,8 @@ class _ModelEntry:
         # [1, C, V] that stay on the device
         self._chunk_rows.append(_LaunchedChunk(s, st, self._pickers["chunk"](
             fetches[0], np.int32(real - 1))))
+        if _lanes():
+            self._engine.lane.rode("pick_row")
         if req.beam is not None or not (ahead or self._any_stepping()):
             self._land_chunks(deferred=False)
         return 1
@@ -2693,6 +2728,9 @@ class GenerationEngine:
         self._started = False
         self._next_id = 0
         self._id_lock = lockdep.named_lock("decode.ids")
+        # the device's lane: every entry's traced launches, in launch
+        # order, to one ready watcher (its thread starts with the first)
+        self.lane = DeviceLane(self.device)
 
     # -- model registry ---------------------------------------------------
     def register_model(self, model):
@@ -2914,6 +2952,7 @@ class GenerationEngine:
         finish generating before the loops exit."""
         for entry in self._entries.values():
             entry.shutdown(timeout)
+        self.lane.close(timeout)
         self._started = False
 
     drain = shutdown

@@ -71,6 +71,19 @@ launch of the step program, from its start to the end of the feeds'
 ``launch=<n>`` (the value of ``serving_step_launches_total``), as does the
 ``decode::step_fetch`` that lands that step.
 
+What the DEVICE did with each launch, only while the tracer's lanes are on
+(``tracing(path, lanes=True)``; the engine's device lane, ``lane.py``: a
+ready watcher stamps when the device had finished each launch, and the
+account parts the wall time between two stamps). Float seconds, each
+``serving_<name>_total``: a launch handed over while the launch before was
+still running gives the time between the two stamps to
+``device_queued_seconds`` (the device was busy all of it); any other launch
+parts its interval into ``device_idle_empty_seconds`` (nothing dispatched,
+the loop asleep in `_wait`), ``device_idle_host_seconds`` (nothing
+dispatched, the loop at work) and ``device_unqueued_seconds`` (dispatch
+latency and device time together, which the host cannot part). The four add
+up to the wall time the account covers.
+
 A model that fills its answer a block at a time:
 ``serving_block_passes_total{kind=fill|commit}`` counts the slots'
 delivered passes (their sum is what ``serving_active_slot_steps_total``
@@ -233,6 +246,11 @@ class DecodeMetrics(ServingMetrics):
         # whose commit pass was delivered; the slots' passes by kind are
         # serving_block_passes_total{kind=fill|commit}
         "block_tokens_decided", "blocks_committed",
+        # the device lane (lane.py), float seconds, moved only while the
+        # tracer's lanes are on: the four parts of the wall time between the
+        # watcher's stamps
+        "device_queued_seconds", "device_unqueued_seconds",
+        "device_idle_empty_seconds", "device_idle_host_seconds",
     )
 
     def __init__(self, engine_label=None, registry=None):
@@ -357,6 +375,34 @@ class DecodeMetrics(ServingMetrics):
         self.incr("fed_bytes", fed_bytes)
         if kind == "step":
             self.incr("step_launches")
+
+    def launches(self, kind):
+        """The number of the ``kind`` program's launch that has just
+        returned, by the counters that are always on:
+        ``serving_step_launches_total`` (what ``decode::step`` carries as
+        ``launch=``), else one more than ``serving_chunk_runs_total`` or
+        ``serving_prefills_total``, which count a launch once the engine
+        has seen it succeed."""
+        if kind == "step":
+            return int(self.count("step_launches"))
+        return int(self.count("chunk_runs" if kind == "chunk"
+                              else "prefills")) + 1
+
+    def slept_seconds(self):
+        """Seconds the loop has slept in `_wait` so far."""
+        return self._wait.sum
+
+    def observe_device(self, parts):
+        """One stamped launch as the device lane accounts it
+        (``lane.Parts``)."""
+        if parts.queued:
+            self.incr("device_queued_seconds", parts.device_ns * 1e-9)
+            return
+        if parts.idle_empty_ns:
+            self.incr("device_idle_empty_seconds", parts.idle_empty_ns * 1e-9)
+        if parts.idle_host_ns:
+            self.incr("device_idle_host_seconds", parts.idle_host_ns * 1e-9)
+        self.incr("device_unqueued_seconds", parts.unqueued_ns * 1e-9)
 
     def observe_blocks(self, live, slots, copy_units):
         """One decode step's feeds: ``live`` blocks hold its stepping
