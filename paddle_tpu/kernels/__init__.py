@@ -12,7 +12,7 @@ cached_attention     cached_attention (decode [S,1]) bit       mode
 paged_attention      paged_attention (decode [S,1])  tolerance mode
 chunk_paged_attention chunk_paged_attention ([C] of one slot) tolerance mode
 moe_experts          moe_routed_experts (decode)     tolerance mode
-ssm_update           mamba2_mixer (decode [S,1])     tolerance mode
+ssm_update           mamba2_mixer (decode [S,1]; a grid step a stepping slot's heads, as many as VMEM takes) tolerance mode
 ssm_scan             mamba2_mixer (a prompt chunk)   tolerance mode
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
 ==================== ============================== ========= =========
@@ -487,7 +487,9 @@ def _tpu_cases_moe_experts():
 
 def _parity_ssm_update(rng):
     """Some slots step, none does, all do, the last alone: a slot that does
-    not step keeps its state bit for bit."""
+    not step keeps its state bit for bit. At the block the shapes give (a
+    slot's 32 heads a grid step); tests/test_ssm_update_kernel.py holds the
+    smaller blocks."""
     import jax
     import jax.numpy as jnp
 
@@ -512,15 +514,18 @@ def _parity_ssm_update(rng):
 
 
 def _tpu_cases_ssm_update():
-    """The hybrid serving cell's Mamba layer: 32 slots of 64 heads x 64 x
-    128 float32 state."""
+    """The hybrid serving cells' Mamba layer (32 slots of 64 heads x 64 x
+    128 float32 state: a slot's whole state a grid step) and a state so
+    wide that a grid step holds one head of it."""
     from paddle_tpu.kernels import mamba
 
-    S, H, P, N = 32, 64, 64, 128
-    return [("s32_h64_p64_n128", mamba.ssm_update, [
-        ((S, H, P, N), "float32"), ((S, H, P), "float32"),
-        ((S, H), "float32"), ((S, H, N), "float32"), ((S, H, N), "float32"),
-        ((S,), "bool")])]
+    def case(S, H, P, N):
+        return (f"s{S}_h{H}_p{P}_n{N}", mamba.ssm_update, [
+            ((S, H, P, N), "float32"), ((S, H, P), "float32"),
+            ((S, H), "float32"), ((S, H, N), "float32"),
+            ((S, H, N), "float32"), ((S,), "bool")])
+
+    return [case(32, 64, 64, 128), case(4, 3, 512, 1024)]
 
 
 def _scan_case(rng, T, H, P, N, G, real=None):
@@ -641,8 +646,12 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "ssm_update", ("mamba2_mixer",), "tolerance", _parity_ssm_update,
-    tpu_cases=_tpu_cases_ssm_update,
-    doc="one-token Mamba-2 state update of the stepping slots, in place "
+    tpu_cases=_tpu_cases_ssm_update, version=2,
+    doc="one-token Mamba-2 state update of the stepping slots, in place: a "
+        "grid step carries as many heads of ONE stepping slot as an 8 MiB "
+        "VMEM budget takes (at 64 heads x 64 x 128 the slot's whole state, "
+        "2 MB in and 2 MB out) and sums a head's new state times C over "
+        "the state dimension on the MXU, y leaving as whole rows "
         "(kernels/mamba.py)",
 ))
 register(KernelSpec(

@@ -13,8 +13,10 @@ or, on the chip, the ``ssm_scan`` Pallas kernel (a scan chunk for all heads,
 a head block a grid step, the ``[Q, Q]`` decay-masked products kept in
 VMEM). ``ssm_update`` is the decode step's one-token update over a ``[S,
 H, P, N]`` batch of per-slot states, a Pallas kernel that reads and writes
-the states of the STEPPING slots alone, in place; ``ssm_update_composite``
-is its reference lowering, its CPU path and its ``off`` path.
+the states of the STEPPING slots alone, in place, a grid step as many heads
+of one slot as a VMEM budget takes (``_update_heads``: a slot's whole state
+at the published 64 heads x 64 x 128); ``ssm_update_composite`` is its
+reference lowering, its CPU path and its ``off`` path.
 
 ``mixer_chunk`` / ``mixer_step`` are the whole mixer between its two
 projections (convolution, activations, dt, the scan, the gated grouped
@@ -55,8 +57,6 @@ __all__ = [
 ]
 
 _HI = jax.lax.Precision.HIGHEST
-#: heads of one slot's state that one grid step of the kernel holds
-_HEAD_BLOCK = 16
 
 
 def _per_head(g, heads):
@@ -130,15 +130,18 @@ def ssm_scan_chunked(x, dt, a, b, c, h0, chunk, kernel=None):
 #: a head's ``w`` tiles) come to ~3 MiB more, under Mosaic's default scoped
 #: limit of 16 MiB
 _SCAN_BLOCK_BYTES = 8 * 2 ** 20
+#: the most heads a grid step of ``ssm_scan`` holds: the body is unrolled
+#: over them
+_SCAN_HEADS = 16
 
 
 def _scan_heads(heads, per_group, q, p, n_state):
     """Heads a grid step of ``ssm_scan`` holds, from the operands' shapes:
-    the largest divisor of ``heads`` up to ``_HEAD_BLOCK`` that is whole
+    the largest divisor of ``heads`` up to ``_SCAN_HEADS`` that is whole
     groups or a whole part of one and whose ``x``, ``y`` and state blocks
     fit ``_SCAN_BLOCK_BYTES``."""
     per_head = 2 * 4 * (2 * q * p + 2 * p * n_state)
-    cap = max(1, min(_HEAD_BLOCK, heads, _SCAN_BLOCK_BYTES // per_head))
+    cap = max(1, min(_SCAN_HEADS, heads, _SCAN_BLOCK_BYTES // per_head))
     return max(g for g in range(1, cap + 1) if heads % g == 0
                and (g % per_group == 0 or per_group % g == 0))
 
@@ -289,19 +292,47 @@ def ssm_update_composite(state, xdt, decay, bh, ch, mask):
     return jnp.where(keep[..., None], new, state), jnp.where(keep, y, 0.0)
 
 
+#: VMEM that the state blocks of one ``ssm_update`` grid step may take, all
+#: four (the block being updated and the one being copied, in and out); the
+#: five small operands' blocks come to ~0.4 MiB more at 64 heads, under
+#: Mosaic's default scoped limit of 16 MiB
+_UPDATE_BLOCK_BYTES = 8 * 2 ** 20
+
+
+def _update_heads(heads, p, n_state):
+    """Heads of ONE slot that a grid step of ``ssm_update`` holds, from the
+    operands' shapes: the largest divisor of ``heads`` whose float32 state
+    blocks fit ``_UPDATE_BLOCK_BYTES``."""
+    cap = max(1, min(heads, _UPDATE_BLOCK_BYTES // (4 * 4 * p * n_state)))
+    return max(g for g in range(1, cap + 1) if heads % g == 0)
+
+
 def _ssm_body(sid_ref, n_ref, st_ref, x_ref, da_ref, b_ref, c_ref,
-              out_ref, y_ref, *, block):
+              out_ref, y_ref, *, block, group):
     g = pl.program_id(0)
     n = n_ref[0]
 
     @pl.when(g < n)
     def _():
-        for i in range(block):
-            new = (da_ref[:, i:i + 1] * st_ref[i]
-                   + x_ref[:, i:i + 1] * b_ref[i:i + 1, :])
-            out_ref[i] = new
-            y_ref[:, i:i + 1] = jnp.sum(new * c_ref[i:i + 1, :], axis=-1,
-                                        keepdims=True)
+        ones = jnp.ones((8, st_ref.shape[-1]), jnp.float32)
+        for r in range(block // group):
+            prods = []
+            for i in range(r * group, (r + 1) * group):
+                new = (da_ref[:, i:i + 1] * st_ref[i]
+                       + x_ref[:, i:i + 1] * b_ref[i:i + 1, :])
+                out_ref[i] = new
+                prods.append(new * c_ref[i:i + 1, :])
+            # the sums over the state dimension of ``group`` heads' rows as
+            # ONE row of ``y``: ``ones . prods^T`` on the MXU, which has
+            # nothing else to do (float32 at HIGHEST: a product goes in as
+            # its bfloat16 pieces and is summed in float32). A reduction
+            # along the lanes and a ``[P, 1]`` column store a head cost a
+            # ninth of a call; this hides under the state's copies
+            # (tools/check_ssm_update.py)
+            y_ref[r:r + 1, :] = jax.lax.dot_general(
+                ones, jnp.concatenate(prods, axis=0),
+                (((1,), (1,)), ((), ())), precision=_HI,
+                preferred_element_type=jnp.float32)[0:1]
 
     @pl.when(n == 0)
     def _():
@@ -310,21 +341,28 @@ def _ssm_body(sid_ref, n_ref, st_ref, x_ref, da_ref, b_ref, c_ref,
         out_ref[...] = st_ref[...]
 
 
-def ssm_update(state, xdt, decay, bh, ch, mask, interpret=False):
+def ssm_update(state, xdt, decay, bh, ch, mask, interpret=False, block=None):
     """``ssm_update_composite`` with the states of the stepping slots read
     and written in place and no others touched: the grid runs over the
     slots in an order that puts the stepping ones first (scalar prefetch),
-    a head block at a time; past the last of them every grid step names the
-    block of the step before, so nothing is copied. Inside, a head's state
-    is one ``[P, N]`` tile: ``x`` comes transposed (``[P, heads]``) so that
-    a head's column broadcasts along the lanes."""
+    a grid step ``block`` heads of one slot (None: as many as
+    ``_update_heads`` gives from the shapes; at 64 heads x 64 x 128 a
+    slot's whole state, 2 MB in and 2 MB out); past the last stepping slot
+    every grid step names the block of the step before, so nothing is
+    copied. Inside, a head's state is one ``[P, N]`` tile: ``x`` comes
+    transposed (``[P, heads]``) so that a head's column broadcasts along
+    the lanes, and ``y`` leaves as rows of ``128 / P`` heads' ``P`` values
+    side by side (``[S, H, P]`` as it lies in memory)."""
     s, heads, p, n_state = state.shape
-    block = min(_HEAD_BLOCK, heads)
+    if block is None:
+        block = _update_heads(heads, p, n_state)
     if vma_names(state) or heads % block or (not interpret and (
             p % 8 or n_state % 128 or state.dtype != jnp.float32)):
         fallback_counter().inc()
         return ssm_update_composite(state, xdt, decay, bh, ch, mask)
     nb = heads // block
+    # heads whose ``y`` share a row of 128 lanes
+    group = 128 // p if 128 % p == 0 and block % (128 // p) == 0 else 1
     order = jnp.argsort(jnp.logical_not(mask), stable=True).astype(jnp.int32)
     count = jnp.sum(mask.astype(jnp.int32))
     last = jnp.maximum(count - 1, 0)
@@ -349,17 +387,18 @@ def ssm_update(state, xdt, decay, bh, ch, mask, interpret=False):
     st_spec = pl.BlockSpec(
         (None, block, p, n_state),
         lambda g, j, sid_ref, n_ref: where(g, j, sid_ref, n_ref) + (0, 0))
-    new, yt = pl.pallas_call(
-        functools.partial(_ssm_body, block=block),
+    y_block = (block // group, group * p)
+    new, y = pl.pallas_call(
+        functools.partial(_ssm_body, block=block, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s, nb),
             in_specs=[st_spec, small((p, block)), small((1, block)),
                       small((block, n_state)), small((block, n_state))],
-            out_specs=[st_spec, small((p, block))],
+            out_specs=[st_spec, small(y_block)],
         ),
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((s, nb, p, block), f32)],
+                   jax.ShapeDtypeStruct((s, nb) + y_block, f32)],
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
@@ -367,8 +406,7 @@ def ssm_update(state, xdt, decay, bh, ch, mask, interpret=False):
         name="ssm_update",
     )(sid, count.reshape(1), state.reshape(s, heads, p, n_state), xt, da,
       bh, ch)
-    y = jnp.swapaxes(yt, 2, 3).reshape(s, heads, p)
-    return new, jnp.where(mask[:, None, None], y, 0.0)
+    return new, jnp.where(mask[:, None, None], y.reshape(s, heads, p), 0.0)
 
 
 def gated_group_norm(y, z, weight, groups, eps):

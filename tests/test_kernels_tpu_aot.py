@@ -137,6 +137,37 @@ def test_flash_kernels_group_heads_at_every_geometry(label, v5e_devices):
                 == bh // g * (seq // 128))
 
 
+#: ``_tpu_cases_ssm_update``'s labels and the heads of one slot a grid step
+#: of ``ssm_update`` carries there: the hybrid serving cells' layer (both
+#: publish 64 heads x 64 x 128: a slot's whole 2 MB state a grid step) and
+#: the smallest block the chooser can return
+UPDATE_BLOCKS = {
+    "s32_h64_p64_n128": 64,
+    "s4_h3_p512_n1024": 1,
+}
+
+
+@pytest.mark.parametrize("label", sorted(UPDATE_BLOCKS))
+def test_ssm_update_carries_the_block_the_shapes_give(label, v5e_devices):
+    """Mosaic takes ``ssm_update`` at the block ``_update_heads`` gives
+    from the operands' shapes, under the default VMEM limit, as ONE custom
+    call with no fallback to the composite."""
+    from paddle_tpu.kernels import mamba
+
+    cases = {c[0]: c for c in kernels.get("ssm_update").tpu_cases()}
+    assert set(cases) == set(UPDATE_BLOCKS)
+    _label, fn, arg_specs = cases[label]
+    _slots, heads, p, n_state = arg_specs[0][0]
+    assert mamba._update_heads(heads, p, n_state) == UPDATE_BLOCKS[label]
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.fallback_counter().value
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 #: the hybrid serving cells' Mamba layers as a launch of the chunk program
 #: meets them: (tokens a launch, groups, scan chunk) at 64 heads of 64 over
 #: a state of 128, and the loops the compiled mixer keeps (a launch of ONE
