@@ -97,6 +97,19 @@ _SIGNATURES = {
         ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Span": 1},
         dtype_family={"Q": "float", "Span": "int", "Rows": "int"},
     ),
+    # one latent arena: a row is its token's key and, its first lanes, value
+    "paged_latent_attention": OpSignature(
+        same_dtype=[("Q", "Arena", "WUK", "WUV")],
+        ranks={"Q": 2, "Arena": 2, "WUK": 3, "WUV": 3, "Rows": 1, "Bias": 3},
+        dtype_family={"Q": "float", "Bias": "float", "Rows": "int"},
+    ),
+    "chunk_latent_attention": OpSignature(
+        same_dtype=[("Q", "Arena", "WUK", "WUV")],
+        ranks={"Q": 2, "Arena": 2, "WUK": 3, "WUV": 3, "Rows": 1, "Span": 1},
+        dtype_family={"Q": "float", "Span": "int", "Rows": "int"},
+    ),
+    "position_log_scale": OpSignature(
+        dtype_family={"X": "float", "Positions": "int"}),
     "chunk_mask_bias": OpSignature(
         ranks={"Span": 1}, dtype_family={"Span": "int"}),
     "rms_norm": OpSignature(ranks={"Scale": 1}, dtype_family={"X": "float"}),

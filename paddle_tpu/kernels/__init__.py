@@ -9,9 +9,10 @@ kernel               serves                          parity    activation
 ==================== ============================== ========= =========
 flash_attention      scaled_dot_product_attention    tolerance mode
 cached_attention     cached_attention (decode [S,1]) bit       mode
-paged_attention      paged_attention (decode [S,1])  tolerance mode
+paged_attention      paged_attention, paged_latent_attention (decode [S,1]; one latent arena or K and V) tolerance mode
 chunk_paged_attention chunk_paged_attention ([C] of one slot) tolerance mode
 moe_experts          moe_routed_experts (decode)     tolerance mode
+moe_grouped          moe_routed_experts (a prompt chunk's pairs, by expert) tolerance mode
 ssm_update           mamba2_mixer (decode [S,1]; a grid step a stepping slot's heads, as many as VMEM takes) tolerance mode
 ssm_scan             mamba2_mixer (a prompt chunk)   tolerance mode
 remat_policy         recompute_segment[_grad]        bit       IR attr (policy kind)
@@ -263,6 +264,37 @@ def _parity_paged(rng):
         _assert_paged_parity(args, lengths, f"paged_attention {lengths}",
                              S, L, bs, H)
     _parity_paged_grouped(rng)
+    _parity_paged_latent(rng)
+
+
+def _parity_paged_latent(rng):
+    """ONE arena (a latent cache: a row is its token's key and, its first
+    lanes, its value), 8 query heads over rows of 384 lanes with values of
+    256, then 4 over 128 with values of 32: lengths a block, a tile and a
+    slot long and one off them, a free slot, then slots around a copy
+    unit."""
+    import jax
+
+    from paddle_tpu.kernels import attention as A
+
+    for heads, W, VW, L, lengths in (
+            (8, 384, 256, 64, [1, 15, 17, 64, 0]),
+            (4, 128, 32, 64, [16, 0, 33, 64, 1]),
+            (8, 384, 256, 1280, [639, 0, 640, 641, 1280])):
+        S, bs = len(lengths), 16
+        _q, arena, _v, rows, bias = _paged_case(rng, S, L, bs, W, lengths)
+        q = rng.randn(S, heads * W).astype("float32")
+        got = np.asarray(jax.jit(lambda *a: A.paged_attention(
+            a[0], a[1], None, a[2], a[3], S, L, bs, 0.1, interpret=True,
+            v_width=VW))(q, arena, rows, bias))
+        ref = np.asarray(jax.jit(lambda *a: A.paged_attention_composite(
+            a[0], a[1], None, a[2], a[3], S, L, 0.1, v_width=VW))(
+                q, arena, rows, bias))
+        live = np.asarray(lengths) > 0
+        assert got.shape == (S, heads * VW)
+        _assert_close_both_ways(got[live], ref[live],
+                                "paged_attention (latent)", 1e-5, 1e-5)
+        assert not got[~live].any()
 
 
 def _tpu_cases_paged():
@@ -344,9 +376,22 @@ def _tpu_cases_paged_grouped():
             ((R, G * D), "bfloat16"), ((S * L,), "int32"),
             ((S, 1, L), "float32")])
 
+    def latent(S, L, bs, heads, W, VW):
+        """mistral_small_4_119b: 16 slots of 33,280 positions over ONE
+        arena of 384-lane rows, 32 absorbed query heads, values 256."""
+        R = 4 * L
+
+        def fwd(q, arena, rows, bias):
+            return A.paged_attention(q, arena, None, rows, bias, S, L, bs,
+                                     0.195, v_width=VW)
+
+        return (f"s{S}_l{L}_b{bs}_latent{heads}x{W}v{VW}_bf16", fwd, [
+            ((S, heads * W), "bfloat16"), ((R, W), "bfloat16"),
+            ((S * L,), "int32"), ((S, 1, L), "float32")])
+
     return [case(32, 2048, 16, 2, 16, 128), case(128, 2048, 16, 8, 4, 64),
             case(16, 1024, 16, 16, 1, 128), case(32, 1024, 16, 4, 32, 128),
-            case(32, 16896, 16, 8, 4, 64)]
+            case(32, 16896, 16, 8, 4, 64), latent(16, 33280, 16, 32, 384, 256)]
 
 
 def _chunk_case(rng, C, L, bs, G, per, D, dtype="float32"):
@@ -483,6 +528,72 @@ def _tpu_cases_moe_experts():
 
     return [case(32, 2688, 1856, 16, 2), case(128, 2048, 1536, 8, 3),
             case(128, 2048, 768, 16, 3)]
+
+
+def _parity_moe_grouped(rng):
+    """The grouped product against the dense composite over the same
+    routing: a balanced router, every token on ONE held expert, no token on
+    any, a masked token; held experts at the router's start and behind it;
+    two row tiles; gated experts and squared-relu ones."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import moe
+
+    T, H, F, E, EA, k = 64, 256, 40, 4, 16, 3
+    x = jnp.asarray(rng.randn(T, H).astype("float32"))
+    gate = jnp.asarray(rng.randn(EA, H).astype("float32"))
+    gate_w, up_w, down_w = (
+        jnp.asarray(0.1 * rng.randn(E, F, H).astype("float32"))
+        for _ in range(3))
+    mask = jnp.asarray(rng.rand(T) > 0.2)
+    seen = set()
+    for select in (np.zeros(EA), np.where(np.arange(EA) == 5, 100.0, 0.0),
+                   np.where(np.arange(EA) < 8, -100.0, 0.0)):
+        idx, w = moe.route(x, gate, jnp.asarray(select.astype("float32")), k,
+                           1.0, True, score="softmax")
+        for offset in (0, 4):
+            c = moe.held_weights(idx, w, mask, offset, E)
+            per_expert = np.asarray((c != 0).sum(0))
+            seen.add((int(per_expert.max()) == int(np.asarray(mask).sum()),
+                      not per_expert.any()))
+            for tile, wg in ((8, gate_w), (16, gate_w), (16, None)):
+                got = jax.jit(lambda *a: moe.moe_grouped(
+                    *a, offset, up_w, down_w, wg, interpret=True,
+                    row_tile=tile))(x, idx, w, mask)
+                _assert_close_both_ways(
+                    got, moe.experts_composite(x, c, up_w, down_w, wg),
+                    "moe_grouped", 1e-5, 1e-5)
+                pairs, rows, touched = np.asarray(moe.grouped_counts(
+                    idx, mask, offset, E, tile))
+                assert pairs == per_expert.sum()
+                assert rows == (-(-per_expert // tile) * tile).sum()
+                assert touched == (per_expert > 0).sum()
+    assert (True, False) in seen and (False, True) in seen, seen
+
+
+def _tpu_cases_moe_grouped():
+    """mistral_small_4_119b's chunk (512 and 2,048 tokens choosing 4 of
+    128, 16 held gated experts of width 2,048 at hidden 4,096) in
+    bfloat16, and a squared-relu geometry (nemotron3_nano_30b_a3b's
+    experts at a chunk of 1,024)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import moe
+
+    def case(T, H, F, E, k, matrices):
+        def fwd(x, idx, w, mask, *ws):
+            up, down = ws[0], ws[1]
+            return moe.moe_grouped(x, idx, w, mask, 0, up, down,
+                                   ws[2] if matrices == 3 else None)
+
+        return (f"t{T}_h{H}_f{F}_e{E}_k{k}_m{matrices}_bf16", fwd,
+                [((T, H), "bfloat16"), ((T, k), "int32"),
+                 ((T, k), "float32"), ((T,), "bool")]
+                + [((E, F, H), "bfloat16")] * matrices)
+
+    return [case(512, 4096, 2048, 16, 4, 3), case(2048, 4096, 2048, 16, 4, 3),
+            case(1024, 2688, 1856, 16, 6, 2)]
 
 
 def _parity_ssm_update(rng):
@@ -624,8 +735,9 @@ register(KernelSpec(
         "(kernels/attention.py)",
 ))
 register(KernelSpec(
-    "paged_attention", ("paged_attention",), "tolerance", _parity_paged,
-    tpu_cases=_tpu_cases_paged, version=2,
+    "paged_attention", ("paged_attention", "paged_latent_attention"),
+    "tolerance", _parity_paged,
+    tpu_cases=_tpu_cases_paged, version=3,
     doc="blocked [S,1] decode attention over the live blocks of a paged "
         "arena, online softmax, the next live copy unit always in flight "
         "(kernels/attention.py)",
@@ -643,6 +755,15 @@ register(KernelSpec(
     tpu_cases=_tpu_cases_moe_experts,
     doc="a decode step's held experts: the touched ones' weights streamed "
         "once, the others never read (kernels/moe.py)",
+))
+register(KernelSpec(
+    "moe_grouped", ("moe_routed_experts",), "tolerance", _parity_moe_grouped,
+    tpu_cases=_tpu_cases_moe_grouped,
+    doc="a prompt chunk's (token, held expert) pairs sorted by expert, each "
+        "expert's rows in whole row tiles, one product a row tile over that "
+        "expert's matrices; dropless; taken where the rows it multiplies "
+        "are a quarter of the dense product's or fewer (kernels/moe.py "
+        "takes_grouped)",
 ))
 register(KernelSpec(
     "ssm_update", ("mamba2_mixer",), "tolerance", _parity_ssm_update,

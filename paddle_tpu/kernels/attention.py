@@ -95,7 +95,8 @@ __all__ = [
     "cached_attention_composite", "paged_attention_composite",
     "chunk_attention_composite", "decode_attention", "paged_attention",
     "chunk_attention", "chunk_attention_by_span", "chunk_horizon",
-    "chunk_mask_bias", "fits_vmem", "grouped_layout",
+    "chunk_mask_bias", "fits_vmem", "grouped_layout", "paged_copy_unit",
+    "absorb_queries", "project_values", "latent_chunk_expanded",
 ]
 
 #: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
@@ -136,16 +137,21 @@ def cached_attention_composite(q, k_cache, v_cache, bias, sm_scale):
 
 
 def paged_attention_composite(q, k_arena, v_arena, rows, bias, seqs,
-                              length, sm_scale, kv_heads=0):
+                              length, sm_scale, kv_heads=0, v_width=0):
     """``block_gather(k) ; block_gather(v) ; cached_attention`` as one
     function: gather rows byte-for-byte out of the flat arenas, then the
     cached-attention sequence over the gathered views. With ``kv_heads``
     the rows hold that many K (V) heads side by side and ``q`` a whole
     number of query heads to each (grouped-query attention): every query
-    head attends over its group's K/V head, under the one bias row."""
+    head attends over its group's K/V head, under the one bias row.
+    Handed ONE arena (``v_arena`` None: a latent cache, the last section)
+    every query head attends over the one row a token, and a token's value
+    is the first ``v_width`` lanes of that same row."""
+    if v_arena is None:
+        kv_heads = 1
     if kv_heads:
         return _grouped_composite(q, k_arena, v_arena, rows, bias, seqs,
-                                  length, sm_scale, kv_heads)
+                                  length, sm_scale, kv_heads, v_width)
     flat = rows.reshape(-1)
     gk = jnp.take(k_arena, flat, axis=0).reshape(int(seqs), int(length), -1)
     gv = jnp.take(v_arena, flat, axis=0).reshape(int(seqs), int(length), -1)
@@ -153,7 +159,7 @@ def paged_attention_composite(q, k_arena, v_arena, rows, bias, seqs,
 
 
 def _grouped_composite(q, k_arena, v_arena, rows, bias, seqs, length,
-                       sm_scale, kv_heads):
+                       sm_scale, kv_heads, v_width=0):
     """``q`` ``[S, heads * D]`` against arenas ``[R, kv_heads * D]``;
     scores and softmax in float32, the two products in the arenas' dtype
     accumulated in float32."""
@@ -163,7 +169,9 @@ def _grouped_composite(q, k_arena, v_arena, rows, bias, seqs, length,
     prec = jax.lax.Precision.HIGHEST if k_arena.dtype == f32 else None
     flat = rows.reshape(-1)
     gk = jnp.take(k_arena, flat, axis=0).reshape(s, l, g, d)
-    gv = jnp.take(v_arena, flat, axis=0).reshape(s, l, g, d)
+    gv = (gk[..., :int(v_width)] if v_arena is None
+          else jnp.take(v_arena, flat, axis=0).reshape(s, l, g, d))
+    v_arena = k_arena if v_arena is None else v_arena
     q4 = q.reshape(s, g, -1, d).astype(k_arena.dtype)
     scores = jnp.einsum("sgqd,slgd->sgql", q4, gk,
                         preferred_element_type=f32, precision=prec)
@@ -279,28 +287,37 @@ _TILE_ROWS = 128
 #: were a third of a call at 24 live slots
 _STEP_SLOTS = 8
 
+#: rows one reduction covers where the kernel is handed ONE latent arena:
+#: all the heads' queries (32 rows, not a K/V head's few) stand against a
+#: tile, and at a lane tile's 128 rows a slot of 8,192 positions is 64
+#: products of [32, 384] x [384, 128], each waited for alone: 0.73 ms for 16
+#: such slots, 14 % of what their rows' bytes take (my chip run, PR 56)
+_LATENT_TILE_ROWS = 512
+
 #: bytes of K plus V a copy unit aims at: what one wait brings in, large
 #: enough that the descriptors' issue and the DMA's latency are a small
 #: part of it whatever the row width
 _UNIT_BYTES = 1 << 20
 
 
-def _paged_tile(block_size, blocks_per_slot, hidden, dtype):
+def _paged_tile(block_size, blocks_per_slot, hidden, dtype,
+                tile_rows=None):
     """Blocks per reduce tile of the paged kernel: a lane tile's worth of
-    rows, fewer when two halves of K and of V would not fit ``VMEM_BUDGET``;
-    0 when not even single blocks do."""
-    t = max(1, min(int(blocks_per_slot), _TILE_ROWS // int(block_size)))
+    rows (``tile_rows``: another count), fewer when two halves of K and of
+    V would not fit ``VMEM_BUDGET``; 0 when not even single blocks do."""
+    t = max(1, min(int(blocks_per_slot),
+                   (tile_rows or _TILE_ROWS) // int(block_size)))
     per_block = 4 * int(block_size) * int(hidden) * jnp.dtype(dtype).itemsize
     return min(t, VMEM_BUDGET // per_block)
 
 
-def _paged_group(block_size, blocks_per_slot, hidden, dtype):
+def _paged_group(block_size, blocks_per_slot, hidden, dtype, tile_rows=None):
     """Blocks per COPY UNIT of the paged kernel (what is started, and
     waited for, together): whole reduce tiles, about ``_UNIT_BYTES`` of K
     plus V from the row width and dtype, no more than a slot has and than
     two halves of K and of V fit ``VMEM_BUDGET``; 0 when no block does.
     The engine counts a step's units with the same function."""
-    tile = _paged_tile(block_size, blocks_per_slot, hidden, dtype)
+    tile = _paged_tile(block_size, blocks_per_slot, hidden, dtype, tile_rows)
     if not tile:
         return 0
     tile_bytes = (2 * tile * int(block_size) * int(hidden)
@@ -308,6 +325,20 @@ def _paged_group(block_size, blocks_per_slot, hidden, dtype):
     tiles = min(_UNIT_BYTES // tile_bytes, -(-int(blocks_per_slot) // tile),
                 VMEM_BUDGET // (2 * tile_bytes))
     return max(1, tiles) * tile
+
+
+def _tile_rows(arenas):
+    """Rows a reduce tile covers by how many arenas a layer's cache is: ONE
+    is a latent cache's (``_LATENT_TILE_ROWS``)."""
+    return _LATENT_TILE_ROWS if arenas == 1 else _TILE_ROWS
+
+
+def paged_copy_unit(block_size, blocks_per_slot, hidden, dtype, arenas=2):
+    """Blocks per copy unit of ``paged_attention`` over a cache of
+    ``arenas`` arenas a layer (K and V, or a latent cache's one): what the
+    kernel runs and what the engine counts a step's units by."""
+    return _paged_group(block_size, blocks_per_slot, hidden, dtype,
+                        _tile_rows(arenas))
 
 
 def _mosaic_tiles(block_size, hidden, dtype):
@@ -464,15 +495,20 @@ def _paged_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
                     sem, half_ref, init, reduce_tile, finish, **geometry)
 
 
-def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm,
-                        o_ref, kbuf, vbuf, sem, half_ref, *, sm_scale,
-                        kv_heads, **geometry):
+def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, *refs,
+                        sm_scale, kv_heads, arenas, **geometry):
     """``_paged_body`` with a head axis: the rows hold ``kv_heads`` K (V)
     heads of ``D`` side by side, a slot's ``q_ref[i]`` is ``[kv_heads, per,
     D]``, and a tile of rows is reduced once per K/V head on the MXU,
     ``per`` query rows at a time (scores ``[per, rows]``, so the bias tile
-    is a row)."""
+    is a row). ``refs``: the ``arenas`` arenas in HBM, the output, their
+    scratches, the semaphores and the half word. With ONE arena (a latent
+    cache) a row is its token's key AND, in its first lanes (the output's
+    width), its value: the row is copied once and read twice."""
+    hbm, (o_ref, *bufs, sem, half_ref) = refs[:arenas], refs[arenas:]
+    kbuf, vbuf = bufs[0], bufs[-1]
     per, d = q_ref.shape[2:]
+    dv = o_ref.shape[-1]
     trows = geometry["tile"] * geometry["block"]
     f32 = jnp.float32
     # a process-wide matmul precision reaches inside the body, and Mosaic
@@ -483,7 +519,7 @@ def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm,
     def init(i):
         return tuple(
             (jnp.full((per, 1), -jnp.inf, f32), jnp.zeros((per, 1), f32),
-             jnp.zeros((per, d), f32)) for _ in range(kv_heads))
+             jnp.zeros((per, dv), f32)) for _ in range(kv_heads))
 
     def reduce_tile(i, half, row0, t, carry):
         bias = b_ref[i, pl.ds(t, 1), :].astype(f32)           # [1, rows]
@@ -491,7 +527,7 @@ def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm,
         for g in range(kv_heads):
             m, l, acc = carry[g]
             k = kbuf[half, pl.ds(row0, trows), g * d:(g + 1) * d]  # [rows, D]
-            v = vbuf[half, pl.ds(row0, trows), g * d:(g + 1) * d]
+            v = vbuf[half, pl.ds(row0, trows), g * dv:(g + 1) * dv]
             sc = jax.lax.dot_general(
                 q_ref[i, g], k, (((1,), (1,)), ((), ())), precision=prec,
                 preferred_element_type=f32)                   # [per, rows]
@@ -512,8 +548,8 @@ def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm,
         for g, (_m, l, acc) in enumerate(carry):
             o_ref[i, g] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
 
-    _paged_pipeline(bt_ref, len_ref, nxt_ref, (k_hbm, v_hbm), (kbuf, vbuf),
-                    sem, half_ref, init, reduce_tile, finish, **geometry)
+    _paged_pipeline(bt_ref, len_ref, nxt_ref, hbm, bufs, sem, half_ref,
+                    init, reduce_tile, finish, **geometry)
 
 
 def grouped_layout(width, kv_heads, q_width, dtype, interpret=False):
@@ -559,27 +595,45 @@ def _unpack_heads(out, pack, per):
                      axis=2).reshape(s, pairs * pack, per, d)
 
 
+#: the paged kernel's name when it serves ONE latent arena: a device trace
+#: tells it from the two-arena calls by name
+LATENT_STEP_KERNEL = "latent_paged_attention"
+
+#: the ``jax.named_scope`` of the expanded form's loops
+EXPANDED_SCOPE = "latent_chunk_expanded"
+
+
 def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
-                    block_size, sm_scale, interpret=False, kv_heads=0):
+                    block_size, sm_scale, interpret=False, kv_heads=0,
+                    v_width=0):
     """Blocked paged attention: ``paged_attention_composite`` computed
     from the live blocks alone (see the module docstring). ``rows`` must
     be block-aligned, as the engine's row maps are: every ``block_size``
     positions of a slot name consecutive arena rows from a multiple of
-    ``block_size``. Falls back to the composite when Mosaic cannot tile
-    the geometry or the call sits inside a manual (shard_map) region."""
+    ``block_size``. Handed ONE arena (``v_arena`` None) the grouped body
+    copies a token's row once and reads it as the key of every query head
+    and, its first ``v_width`` lanes, as the value. Falls back to the
+    composite when Mosaic cannot tile the geometry or the call sits inside
+    a manual (shard_map) region."""
     S, L, bs = int(seqs), int(length), int(block_size)
     H = k_arena.shape[-1]
-    G = int(kv_heads)
+    latent = v_arena is None
+    G = 1 if latent else int(kv_heads)
+    VW = int(v_width) if latent else H
+    arenas = (k_arena,) if latent else (k_arena, v_arena)
     per_slot = -(-L // bs)
-    tile = _paged_tile(bs, per_slot, H, k_arena.dtype)
-    unit = _paged_group(bs, per_slot, H, k_arena.dtype)
+    tile = _paged_tile(bs, per_slot, H, k_arena.dtype,
+                       _tile_rows(len(arenas)))
+    unit = paged_copy_unit(bs, per_slot, H, k_arena.dtype, len(arenas))
     pack, qrows = grouped_layout(H, G, q.shape[-1], k_arena.dtype,
                                  interpret) if G else (1, 1)
     if vma_names(q) or unit == 0 or not pack or (
-            not interpret and not _mosaic_tiles(bs, H, k_arena.dtype)):
+            not interpret and not (_mosaic_tiles(bs, H, k_arena.dtype)
+                                   and VW % 128 == 0)):
         fallback_counter().inc()
         return paged_attention_composite(q, k_arena, v_arena, rows, bias,
-                                         S, L, sm_scale, kv_heads=G)
+                                         S, L, sm_scale, kv_heads=G,
+                                         v_width=VW)
     trows = tile * bs
     ntiles = -(-per_slot // tile)
     step_slots = max(n for n in range(1, _STEP_SLOTS + 1) if S % n == 0)
@@ -602,12 +656,18 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
         lanes = pack * (H // G)
         row = pl.BlockSpec((step_slots, G // pack, qrows, lanes),
                            lambda s, *_: (s, 0, 0, 0))
-        body = functools.partial(_paged_grouped_body, kv_heads=G // pack)
+        out_row = pl.BlockSpec((step_slots, G // pack, qrows, VW // G * pack),
+                               lambda s, *_: (s, 0, 0, 0))
+        body = functools.partial(_paged_grouped_body, kv_heads=G // pack,
+                                 arenas=len(arenas))
         q_in = _pack_heads(q.reshape(S, G, -1, H // G), pack,
                            qrows).astype(k_arena.dtype)
+        out_shape = q_in.shape[:-1] + (VW // G * pack,)
     else:
-        row = pl.BlockSpec((step_slots, 1, H), lambda s, *_: (s, 0, 0))
+        row = out_row = pl.BlockSpec((step_slots, 1, H),
+                                     lambda s, *_: (s, 0, 0))
         body, q_in = _paged_body, q.reshape(S, 1, H)
+        out_shape = q_in.shape
     out = pl.pallas_call(
         functools.partial(body, sm_scale=sm_scale, block=bs, tile=tile,
                           unit=unit, per_slot=per_slot,
@@ -619,26 +679,23 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
                 row,
                 pl.BlockSpec((step_slots, ntiles, trows),
                              lambda s, *_: (s, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=row,
-            scratch_shapes=[
-                pltpu.VMEM((2, unit * bs, H), k_arena.dtype),
-                pltpu.VMEM((2, unit * bs, H), v_arena.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+            ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in arenas],
+            out_specs=out_row,
+            scratch_shapes=[pltpu.VMEM((2, unit * bs, H), a.dtype)
+                            for a in arenas] + [
+                pltpu.SemaphoreType.DMA((len(arenas), 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_attention",
-    )(table.reshape(-1), lengths, nxt, q_in, tiles, k_arena, v_arena)
+        name="paged_attention" if not latent else LATENT_STEP_KERNEL,
+    )(table.reshape(-1), lengths, nxt, q_in, tiles, *arenas)
     if G:
         out = _unpack_heads(out, pack, q.shape[-1] // H)
-    return out.reshape(q.shape)
+    return out.reshape(S, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -827,3 +884,137 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
     )(table, live, ntiles.astype(jnp.int32), q_in, hz, k_arena, v_arena)
     out = jnp.swapaxes(out.reshape(pairs, C, rpp, lanes), 0, 1)
     return _unpack_heads(out, pack, per).reshape(q.shape)
+
+
+# ---------------------------------------------------------------------------
+# latent attention over ONE arena: a token's row is [c (latent) | k^R (rope)
+# | zeros], c the normalised compressed K/V and k^R the one rotated key all
+# heads share. Head h's key is [c . W_UK,h | k^R] and its value c . W_UV,h.
+# The decode step attends ABSORBED (the up-projections moved onto the query
+# and the output: q~_h = [q^N_h . W_UK,h^T | q^R_h] against the row as it
+# lies, the context (sum_j a_j c_j) . W_UV,h: ``absorb_queries``,
+# ``paged_attention`` handed one arena, ``project_values``); a prompt chunk
+# attends EXPANDED (every context row up-projected to its heads' keys and
+# values first: ``latent_chunk_expanded``).
+# ---------------------------------------------------------------------------
+
+#: context rows the expanded form up-projects and reduces at a time, and
+#: the queries that stand against them at a time. READ ON THE CHIP
+#: (tools/check_latent_attention.py; PERF.md section 6, PR 56), ms a call
+#: behind 8,192 rows at 32 heads of 64 + 64 | 128 over a latent of 256:
+#: 512 queries 1.12, 1,024 queries in ONE tile 4.57, 2,048 in one 19.8; in
+#: tiles of 512 queries 2.23 and 4.69. Up to a [512, heads, 512] tile of
+#: scores XLA keeps the loop's body on the chip, past it the scores and the
+#: running sums go through HBM. So the queries go 512 at a time, each tile
+#: with a loop of its own over the rows ITS last query sees. (The absorbed
+#: form through the chunk kernel read 1.96, 4.09 and 8.58 there: 1.6 x the
+#: operations a pair; it is not kept for chunks.)
+_EXPAND_TILE_ROWS = 512
+_EXPAND_QUERY_TILE = 512
+
+
+def _latent_parts(q, w_uk, rope):
+    """``q`` ``[C, heads * (nope + rope)]`` as ``(q^N [C, heads, nope], q^R
+    [C, heads, rope])``."""
+    heads, nope = w_uk.shape[:2]
+    q3 = q.reshape(q.shape[0], heads, nope + int(rope))
+    return q3[..., :nope], q3[..., nope:]
+
+
+def absorb_queries(q, w_uk, rope, width):
+    """``q~`` ``[C, heads * width]`` in ``q``'s dtype: head h's ``[q^N_h .
+    W_UK,h | q^R_h | zeros]``, what stands against a latent arena's rows of
+    ``width`` lanes (``w_uk`` ``[heads, nope, latent]``)."""
+    f32 = jnp.float32
+    prec = jax.lax.Precision.HIGHEST if q.dtype == f32 else None
+    qn, qr = _latent_parts(q, w_uk, rope)
+    # (the product leaves in ``q``'s dtype: the accumulation is the
+    # chip's own, float32)
+    qa = jnp.einsum("chn,hnl->chl", qn, w_uk.astype(q.dtype), precision=prec)
+    pad = int(width) - qa.shape[-1] - qr.shape[-1]
+    wide = jnp.concatenate(
+        [qa, qr, jnp.zeros(qr.shape[:-1] + (pad,), q.dtype)], axis=-1)
+    return wide.reshape(q.shape[0], -1)
+
+
+def project_values(ctx, w_uv, dtype):
+    """The absorbed form's context ``[C, heads * latent]`` through each
+    head's ``W_UV`` (``[heads, latent, value]``): ``[C, heads * value]``."""
+    f32 = jnp.float32
+    prec = jax.lax.Precision.HIGHEST if ctx.dtype == f32 else None
+    heads, latent, _value = w_uv.shape
+    out = jnp.einsum("chl,hlv->chv", ctx.reshape(-1, heads, latent),
+                     w_uv.astype(ctx.dtype), precision=prec)
+    return out.reshape(ctx.shape[0], -1).astype(dtype)
+
+
+def _expand_rows(g, w_uk, w_uv, rope):
+    """Arena rows ``g`` ``[T, W]`` as every head's ``(k^N [T, heads, nope],
+    k^R [T, rope], v [T, heads, value])``, in ``g``'s dtype."""
+    f32 = jnp.float32
+    prec = jax.lax.Precision.HIGHEST if g.dtype == f32 else None
+    latent = w_uk.shape[-1]
+    c = g[:, :latent]
+    kn = jnp.einsum("tl,hnl->thn", c, w_uk.astype(g.dtype), precision=prec)
+    v = jnp.einsum("tl,hlv->thv", c, w_uv.astype(g.dtype), precision=prec)
+    return kn, g[:, latent:latent + int(rope)], v
+
+
+def latent_chunk_expanded(q, w_uk, w_uv, arena, rows, span, sm_scale, rope,
+                          tile_rows=None):
+    """The EXPANDED form of a prompt chunk's latent attention: the slot's
+    rows up-projected ``tile_rows`` at a time and reduced by an online
+    softmax, ``_EXPAND_QUERY_TILE`` queries at a time, each tile of queries
+    in a loop whose trip count is ITS last horizon, so nothing of a ``[C,
+    L]`` size is made and the work follows the prompt so far.
+    ``tile_rows`` None: every row and every query at once (the dense
+    composite, for the CPU and the tests). Scores and sums float32,
+    products in the arena's dtype."""
+    f32 = jnp.float32
+    C, L = q.shape[0], rows.shape[0]
+    prec = jax.lax.Precision.HIGHEST if arena.dtype == f32 else None
+    qn, qr = _latent_parts(q.astype(arena.dtype), w_uk, rope)
+    horizon = chunk_horizon(span, C, L)                        # [C]
+    T = L if tile_rows is None else int(tile_rows)
+    Q = C if tile_rows is None or C % _EXPAND_QUERY_TILE else min(
+        C, _EXPAND_QUERY_TILE)
+    padded = jnp.pad(rows, (0, -L % T))
+    heads, value = w_uv.shape[0], w_uv.shape[-1]
+
+    def tile_of_queries(qn, qr, horizon):
+        def reduce(t, carry):
+            m, l, acc = carry
+            at = t * T + jnp.arange(T, dtype=jnp.int32)
+            g = jnp.take(arena,
+                         jax.lax.dynamic_slice(padded, (t * T,), (T,)),
+                         axis=0)
+            kn, kr, v = _expand_rows(g, w_uk, w_uv, rope)
+            sc = (jnp.einsum("chn,thn->cht", qn, kn,
+                             preferred_element_type=f32, precision=prec)
+                  + jnp.einsum("chr,tr->cht", qr, kr,
+                               preferred_element_type=f32,
+                               precision=prec)) * sm_scale
+            sc = jnp.where((at[None, :] < horizon[:, None])[:, None, :], sc,
+                           -1e9)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "cht,thv->chv", p.astype(v.dtype), v,
+                preferred_element_type=f32, precision=prec)
+            return m_new, l, acc
+
+        init = (jnp.full((Q, heads, 1), -jnp.inf, f32),
+                jnp.zeros((Q, heads, 1), f32),
+                jnp.zeros((Q, heads, value), f32))
+        trips = -(-jnp.max(horizon) // T)
+        _m, l, acc = jax.lax.fori_loop(0, trips, reduce, init)
+        real = (horizon > 0)[:, None, None]
+        return jnp.where(real, acc / jnp.where(real, l, 1.0), 0.0)
+
+    with jax.named_scope(EXPANDED_SCOPE):
+        out = jnp.concatenate([
+            tile_of_queries(qn[c:c + Q], qr[c:c + Q], horizon[c:c + Q])
+            for c in range(0, C, Q)])
+    return out.reshape(C, -1).astype(q.dtype)

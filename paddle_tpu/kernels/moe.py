@@ -14,6 +14,12 @@ them. An expert's activation follows its operands: handed a gate matrix it
 is gated, ``silu(W_gate,e . x) * (W_up,e . x)`` (SwiGLU, three matrices);
 handed none, ``relu(W_up,e . x)^2`` (two).
 
+``moe_grouped`` is the prompt chunk's GROUPED product (below): the
+(token, held expert) pairs sorted by expert, each expert's rows padded to
+whole row tiles, and one product a row tile over that expert's matrices:
+the operations follow the pairs the routing made, not ``tokens x held``.
+``takes_grouped`` is THE rule of when it runs.
+
 ``experts_composite`` computes that part densely (every held expert over
 every token, weighted by ``c``): the reference lowering, the CPU path, the
 ``off`` path, and the prompt chunk's path. ``moe_experts`` is the decode
@@ -34,7 +40,8 @@ from paddle_tpu.kernels.registry import fallback_counter
 from paddle_tpu.ops.common import vma_names
 
 __all__ = ["route", "held_weights", "routing_counts", "experts_composite",
-           "moe_experts", "hidden_tile"]
+           "moe_experts", "hidden_tile", "grouped_rows", "takes_grouped",
+           "group_pairs", "moe_grouped", "grouped_counts", "ROW_TILE"]
 
 _HI = jax.lax.Precision.HIGHEST
 #: bytes of VMEM the kernel's weight blocks may take, both buffers of every
@@ -144,18 +151,23 @@ def hidden_tile(tokens, hidden, ffn, dtype, matrices, interpret=False):
     return max(fits, default=0)
 
 
-def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated):
+def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated,
+                  summed=True):
     """``refs``: the up (and, ``gated``, the gate) matrix's tile, the down
     matrix's, the output, then the scratches: the up product (and the
-    gate's) ``[T, F]`` float32 and the activation in the weights' dtype."""
+    gate's) ``[T, F]`` float32 and the activation in the weights' dtype.
+    ``summed``: every grid row (an expert) adds into the ONE resident
+    output; without it a grid row (a row tile of one expert) writes the
+    output block of its own."""
     ins, (o_ref, *acc, a_ref) = refs[:2 + gated], refs[2 + gated:]
     firsts, down_ref = ins[:-1], ins[-1]
     g, j = pl.program_id(0), pl.program_id(1)
     prec = _kernel_precision(down_ref.dtype)
 
-    @pl.when((g == 0) & (j == 0))
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    if summed:
+        @pl.when((g == 0) & (j == 0))
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(g < n_ref[0])
     def _():
@@ -181,8 +193,12 @@ def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated):
         @pl.when(j >= tiles)
         def _():
             jj = jnp.maximum(j - tiles, 0)
-            o_ref[jj] += jnp.dot(a_ref[...], down_ref[...], precision=prec,
-                                 preferred_element_type=jnp.float32)
+            out = jnp.dot(a_ref[...], down_ref[...], precision=prec,
+                          preferred_element_type=jnp.float32)
+            if summed:
+                o_ref[jj] += out
+            else:
+                o_ref[jj] = out
 
 
 def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
@@ -244,3 +260,192 @@ def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
         name="moe_experts",
     )(eid, count.reshape(1), xs, cols, *firsts, w_down)
     return jnp.swapaxes(out, 0, 1).reshape(t, hidden)
+
+
+# ---------------------------------------------------------------------------
+# the grouped product: a prompt chunk's (token, held expert) pairs by expert
+# ---------------------------------------------------------------------------
+
+#: rows of one expert a grid step of the grouped kernel multiplies: an
+#: expert's pairs are padded to whole tiles of this many
+ROW_TILE = 128
+
+#: the grouped kernel's scoped-VMEM limit: a row tile's two ``[rows, F]``
+#: float32 products, its activation, the token and output blocks and both
+#: buffers of three weight blocks pass Mosaic's 16 MiB default at an expert
+#: width of 2,048 and a hidden size of 4,096. (Weight tiles of 1,024 lanes
+#: in place of ``hidden_tile``'s 256 read the same time on the chip: 2.11
+#: ms for 2.07 at 512 tokens, PR 56.)
+_GROUPED_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def grouped_rows(tokens, k, held, row_tile=ROW_TILE):
+    """Rows of the grouped product's sorted buffer: every pair the routing
+    can make (``tokens x min(k, held)``: dropless whatever the imbalance)
+    in whole row tiles, and a ragged last tile for each held expert."""
+    pairs = tokens * min(k, held)
+    return (-(-pairs // row_tile) + held) * row_tile
+
+
+#: how many times fewer rows than the dense product the grouped one has to
+#: multiply before it is taken. READ ON THE CHIP (PR 56,
+#: tools/check_latent_attention.py: 16 held gated experts of width 2,048 at
+#: hidden 4,096, bfloat16; ms grouped | dense): a quarter of the dense rows
+#: (512 tokens, 2,048 of 8,192) 2.07 | 2.30, an eighth 2.51 | 4.56, a
+#: sixteenth 4.73 | 9.08, and with every token on one expert (2,432 of
+#: 8,192 rows, 3.4 x fewer) 2.34 | 2.27: the grouped product streams every
+#: touched expert's matrices a row tile at a time and never runs under
+#: ~2 ms there, while XLA's dense einsum runs at 91 % of the chip's peak
+_GROUPED_FEWER_ROWS = 4
+
+
+def takes_grouped(tokens, k, held, router, row_tile=ROW_TILE):
+    """THE rule of whether a routed-experts layer over ``tokens`` tokens
+    runs the grouped product or the dense one, by the rows each multiplies:
+    the dense product ``tokens x held``, the grouped one, under a balanced
+    routing, each held expert's ``tokens x k / router`` pairs in whole row
+    tiles. Grouped from ``1 / _GROUPED_FEWER_ROWS`` of the dense rows down:
+    where the chip read it the faster one."""
+    per_expert = -(-tokens * k // router)
+    expected = held * -(-per_expert // row_tile) * row_tile
+    return _GROUPED_FEWER_ROWS * expected <= tokens * held
+
+
+def _held_pairs(idx, mask, offset, held):
+    """``(valid [T, k], hit [T, k, held])``: which of a token's choices is a
+    pair (a held expert, ``mask[t]`` true), and on which held expert."""
+    local = idx - offset
+    valid = (local >= 0) & (local < held) & mask[:, None]
+    return valid, valid[:, :, None] & (
+        local[:, :, None] == jnp.arange(held)[None, None, :])
+
+
+def group_pairs(idx, w, mask, offset, held, rows, row_tile=ROW_TILE):
+    """The routing's ``[T, k]`` choices as the grouped product's layout.
+    A pair is a token and one of its chosen experts that is held here
+    (``offset .. offset + held - 1``) with ``mask[t]`` true. Pairs are
+    grouped by expert, in token order within one, and expert ``e``'s group
+    starts at a multiple of ``row_tile``. Returns ``(dest [T, k]: a pair's
+    row in the sorted buffer, ``rows`` where there is no pair; token
+    [rows]: the token each row reads (0 for padding); weight [rows]: its
+    routing weight (0.0 for padding); tile_expert [rows / row_tile]: the
+    expert whose matrices a row tile multiplies; tiles: how many row tiles
+    hold a pair)``."""
+    T, k = idx.shape
+    valid, hit = _held_pairs(idx, mask, offset, held)
+    flat = hit.reshape(T * k, held).astype(jnp.int32)
+    before = jnp.cumsum(flat, axis=0) - flat        # pairs of e before this
+    counts = jnp.sum(flat, axis=0)                              # [E]
+    tiles_of = -(-counts // row_tile)
+    ends = jnp.cumsum(tiles_of)
+    start = (ends - tiles_of) * row_tile                        # [E]
+    dest = jnp.sum(flat * (start[None, :] + before), axis=-1)
+    dest = jnp.where(valid.reshape(-1), dest, rows)             # [T * k]
+    token = jnp.zeros((rows,), jnp.int32).at[dest].set(
+        jnp.repeat(jnp.arange(T, dtype=jnp.int32), k), mode="drop")
+    weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
+        w.reshape(-1).astype(jnp.float32), mode="drop")
+    tiles = ends[-1]
+    # a tile past the last used one names the last used tile's expert: its
+    # block index does not move, so nothing is copied for it
+    at = jnp.minimum(jnp.arange(rows // row_tile), jnp.maximum(tiles - 1, 0))
+    # (compared against every end at once: the default method is a loop,
+    # and the chunk program's only loops are its attention's)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, at, side="right", method="compare_all"),
+        held - 1)
+    return (dest.reshape(T, k), token, weight,
+            tile_expert.astype(jnp.int32), tiles.astype(jnp.int32))
+
+
+def grouped_counts(idx, mask, offset, held, row_tile=ROW_TILE):
+    """int32 ``[3]``: the (token, held expert) pairs the routing made, the
+    rows the grouped product multiplies for them (each held expert's pairs
+    in whole row tiles), and the held experts with a pair (whose matrices
+    have to be read)."""
+    _valid, hit = _held_pairs(idx, mask, offset, held)
+    counts = jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)
+    return jnp.stack([jnp.sum(counts),
+                      jnp.sum(-(-counts // row_tile)) * row_tile,
+                      jnp.sum((counts > 0).astype(jnp.int32))])
+
+
+def _gather_pairs(ys, dest):
+    """Token ``t``'s result: the sum of its pairs' rows of ``ys`` (``dest``
+    ``[T, k]``; a ``dest`` past the last row is no pair and adds 0)."""
+    picked = jnp.take(ys, dest.reshape(-1), axis=0, mode="fill",
+                      fill_value=0.0)
+    return jnp.sum(picked.reshape(dest.shape + (-1,)), axis=1)
+
+
+def moe_grouped(x, idx, w, mask, offset, w_up, w_down, w_gate=None,
+                interpret=False, row_tile=ROW_TILE):
+    """The held experts' part of a routed layer as a GROUPED product:
+    ``experts_composite(x, held_weights(idx, w, mask, offset, held), ...)``
+    computed over the pairs the routing made (``group_pairs``). Grid ``(row
+    tiles, 2 * hidden tiles)``: a row tile's ``x . W_up`` (and ``x .
+    W_gate``) accumulate over tiles of the hidden size into ``[rows, F]``
+    scratches, then the down product's columns leave tile by tile; a row
+    tile past the last used one does nothing and copies nothing. Dropless:
+    the sorted buffer holds every pair the routing can make
+    (``grouped_rows``), so all tokens on one expert, or an expert with
+    none, are the same program. Falls back to the dense composite, counted,
+    where Mosaic cannot take the geometry (``hidden_tile``) or inside a
+    manual region."""
+    t, hidden = x.shape
+    held, ffn, _ = w_up.shape
+    firsts = [w_up] if w_gate is None else [w_up, w_gate]
+    tile = hidden_tile(row_tile, hidden, ffn, w_up.dtype, len(firsts) + 1,
+                       interpret)
+    if vma_names(x) or not tile:
+        fallback_counter().inc()
+        return experts_composite(x, held_weights(idx, w, mask, offset, held),
+                                 w_up, w_down, w_gate)
+    rows = grouped_rows(t, idx.shape[1], held, row_tile)
+    dest, token, weight, tile_expert, used = group_pairs(
+        idx, w, mask, offset, held, rows, row_tile)
+    tiles, M = hidden // tile, rows // row_tile
+    xs = jnp.take(x.astype(w_up.dtype), token, axis=0)         # [rows, H]
+    xs = jnp.swapaxes(xs.reshape(rows, tiles, tile), 0, 1)
+    cols = weight.reshape(rows, 1)
+
+    def tile_of(m, n_ref):
+        return jnp.minimum(m, jnp.maximum(n_ref[0] - 1, 0))
+
+    def step(m, j, n_ref):
+        return jnp.where(m < n_ref[0], j, 2 * tiles - 1)
+
+    first = pl.BlockSpec(
+        (None, ffn, tile), lambda m, j, e, n: (
+            e[m], 0, jnp.minimum(step(m, j, n), tiles - 1)))
+    out = pl.pallas_call(
+        functools.partial(_experts_body, tiles=tiles,
+                          gated=w_gate is not None, summed=False),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(M, 2 * tiles),
+            in_specs=[
+                pl.BlockSpec((tiles, row_tile, tile),
+                             lambda m, j, e, n: (0, tile_of(m, n), 0)),
+                pl.BlockSpec((row_tile, 1),
+                             lambda m, j, e, n: (tile_of(m, n), 0)),
+                *[first for _ in firsts],
+                pl.BlockSpec(
+                    (None, ffn, tile), lambda m, j, e, n: (
+                        e[m], 0, jnp.maximum(step(m, j, n) - tiles, 0))),
+            ],
+            out_specs=pl.BlockSpec((tiles, row_tile, tile),
+                                   lambda m, j, e, n: (0, tile_of(m, n), 0)),
+            scratch_shapes=[pltpu.VMEM((row_tile, ffn), jnp.float32)
+                            for _ in firsts]
+            + [pltpu.VMEM((row_tile, ffn), w_down.dtype)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((tiles, rows, tile), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_grouped",
+    )(tile_expert, used.reshape(1), xs, cols, *firsts, w_down)
+    ys = jnp.swapaxes(out, 0, 1).reshape(rows, hidden)
+    return _gather_pairs(ys, dest)

@@ -37,6 +37,9 @@ __all__ = [
     "block_fill_decide",
     "rms_norm",
     "rotary_embedding",
+    "position_log_scale",
+    "paged_latent_attention",
+    "chunk_latent_attention",
     "gated_short_conv",
     "relu2",
     "moe_routed_experts",
@@ -867,19 +870,97 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, out_dtype=None,
     return out
 
 
-def rotary_embedding(x, positions, theta=10000.0, out_dtype=None, name=None):
+def rotary_embedding(x, positions, theta=10000.0, out_dtype=None, name=None,
+                     freqs=None, interleaved=False):
     """Rotary positions (ops/nn.py ``rotary_embedding``): ``x`` ``[...,
     heads, D]`` turned, whole head and rotate-half, by ``positions``
     ``[...]`` (what ``paged_step_feeds`` gives a step, the chunk program's
-    position feed) at base ``theta``; float32 inside."""
+    position feed) at base ``theta``; float32 inside. ``freqs`` (``D / 2``
+    numbers) is a frequency table in ``theta``'s place, ``interleaved``
+    pairs lanes ``(2 i, 2 i + 1)``."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
     attrs = {"theta": float(theta)}
     if out_dtype:
         attrs["out_dtype"] = out_dtype
+    # written only where they are not the default: a program that does not
+    # use them keeps the bytes, and the compile-cache key, it had
+    if freqs is not None:
+        attrs["freqs"] = [float(f) for f in freqs]
+    if interleaved:
+        attrs["interleaved"] = True
     helper.append_op("rotary_embedding",
                      {"X": [x.name], "Positions": [positions.name]},
                      {"Out": [out.name]}, attrs)
+    return out
+
+
+def position_log_scale(x, positions, beta, period, out_dtype=None, name=None):
+    """``x`` times ``1 + beta * ln(1 + floor(position / period))`` (ops/nn.py
+    ``position_log_scale``): a query scaled by how many ``period``s of
+    context lie before it."""
+    helper = LayerHelper("position_log_scale", name=name)
+    out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+    attrs = {"beta": float(beta), "period": int(period)}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
+    helper.append_op("position_log_scale",
+                     {"X": [x.name], "Positions": [positions.name]},
+                     {"Out": [out.name]}, attrs)
+    return out
+
+
+def _latent_weights(helper, heads, nope, value, latent, param_attrs, dtype):
+    """``(w_uk [heads, nope, latent], w_uv [heads, latent, value])``: the
+    compressed K/V's up-projection, a head's key part and value part."""
+    return (helper.create_parameter(param_attrs["w_uk"],
+                                    shape=[heads, nope, latent], dtype=dtype),
+            helper.create_parameter(param_attrs["w_uv"],
+                                    shape=[heads, latent, value], dtype=dtype))
+
+
+def paged_latent_attention(q, arena, rows, attn_bias, seqs, length, heads,
+                           nope, rope, value, latent, param_attrs,
+                           sm_scale=1.0, block_size=None, name=None):
+    """A decode step's latent attention (ops/nn.py
+    ``paged_latent_attention``): ``q`` ``[S, heads * (nope + rope)]`` over
+    the ONE arena of rows ``[c (latent) | k^R (rope) | zeros]``, absorbed;
+    ``param_attrs``: ``w_uk`` and ``w_uv``. Returns ``[S, heads *
+    value]``."""
+    helper = LayerHelper("paged_latent_attention", name=name)
+    w_uk, w_uv = _latent_weights(helper, heads, nope, value, latent,
+                                 param_attrs, q.dtype)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {"sm_scale": float(sm_scale), "seqs": int(seqs),
+             "length": int(length), "rope": int(rope)}
+    if block_size:
+        attrs["block_size"] = int(block_size)
+    helper.append_op(
+        "paged_latent_attention",
+        {"Q": [q.name], "WUK": [w_uk.name], "WUV": [w_uv.name],
+         "Arena": [arena.name], "Rows": [rows.name],
+         "Bias": [attn_bias.name]},
+        {"Out": [out.name]}, attrs)
+    return out
+
+
+def chunk_latent_attention(q, arena, rows, span, heads, nope, rope, value,
+                           latent, param_attrs, sm_scale=1.0, name=None):
+    """A prompt chunk's latent attention (ops/nn.py
+    ``chunk_latent_attention``): ``q`` ``[C, heads * (nope + rope)]`` over
+    ONE sequence's rows of the latent arena under the mask of ``span``,
+    expanded (kernels/attention.py ``latent_chunk_expanded``). Returns
+    ``[C, heads * value]``."""
+    helper = LayerHelper("chunk_latent_attention", name=name)
+    w_uk, w_uv = _latent_weights(helper, heads, nope, value, latent,
+                                 param_attrs, q.dtype)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        "chunk_latent_attention",
+        {"Q": [q.name], "WUK": [w_uk.name], "WUV": [w_uv.name],
+         "Arena": [arena.name], "Rows": [rows.name], "Span": [span.name]},
+        {"Out": [out.name]},
+        {"sm_scale": float(sm_scale), "rope": int(rope)})
     return out
 
 
@@ -894,7 +975,8 @@ def relu2(x, name=None):
 def moe_routed_experts(input, write_rows, num_rows, router_experts,
                        held_experts, ffn_dim, k, param_attrs, expert_offset=0,
                        score_scale=1.0, normalize=True, norm_epsilon=1e-20,
-                       kernel=False, score="sigmoid", name=None):
+                       kernel=False, score="sigmoid", group_counts=False,
+                       name=None):
     """This chip's share of a routed-experts layer (ops/moe.py
     ``moe_routed_experts``): the router scores ``input`` ``[..., H]``
     against all ``router_experts`` (``score``: ``sigmoid`` scores, or a
@@ -906,8 +988,11 @@ def moe_routed_experts(input, write_rows, num_rows, router_experts,
     ``[E_all]`` (float32), ``w_up``, ``w_down`` ``[held, F, H]``
     (``input``'s dtype) and, for gated experts (``silu(gate) * up`` in
     place of ``relu(up)^2``), ``w_gate`` likewise. ``kernel`` lets the
-    ``moe_experts`` kernel serve the op (the decode step). Returns ``(out
-    float32, counts int32 [4])``."""
+    ``moe_experts`` kernel serve the op (the decode step); elsewhere the op
+    takes the grouped product where its rule says so. Returns ``(out
+    float32, counts int32 [4])`` and, with ``group_counts``, int32 ``[3]``
+    besides: the (token, held expert) pairs, the rows multiplied for them
+    and the held experts with a pair."""
     helper = LayerHelper("moe_routed_experts", name=name)
     hidden = int(input.shape[-1])
     gate = helper.create_parameter(
@@ -929,9 +1014,13 @@ def moe_routed_experts(input, write_rows, num_rows, router_experts,
             dtype=input.dtype).name]
     out = helper.create_variable_for_type_inference("float32")
     counts = helper.create_variable_for_type_inference("int32")
+    outs = {"Out": [out.name], "Counts": [counts.name]}
+    pairs = None
+    if group_counts:
+        pairs = helper.create_variable_for_type_inference("int32")
+        outs["GroupCounts"] = [pairs.name]
     helper.append_op(
-        "moe_routed_experts", ins,
-        {"Out": [out.name], "Counts": [counts.name]},
+        "moe_routed_experts", ins, outs,
         {"k": int(k), "score_scale": float(score_scale),
          "normalize": bool(normalize), "norm_epsilon": float(norm_epsilon),
          "expert_offset": int(expert_offset),
@@ -939,9 +1028,10 @@ def moe_routed_experts(input, write_rows, num_rows, router_experts,
          # written only where it is not the default: a program that
          # scores by sigmoid keeps the bytes it had (the compile cache's
          # key: ROADMAP 3.13)
-         **({"score": str(score)} if score != "sigmoid" else {})},
+         **({"score": str(score)} if score != "sigmoid" else {}),
+         **({"group_counts": True} if group_counts else {})},
     )
-    return out, counts
+    return (out, counts, pairs) if group_counts else (out, counts)
 
 
 def mamba2_mixer(input, conv_state, ssm_state, write_rows, num_rows, mode,

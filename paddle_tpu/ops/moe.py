@@ -127,15 +127,22 @@ def _moe_routed_experts_reference(ins, attrs):
 
 def _moe_routed_experts_pallas(ins, attrs):
     """The ``moe_experts`` kernel serves the op where the builder asks for
-    it (``kernel``: the decode step); a prompt chunk runs the composite."""
+    it (``kernel``: the decode step); elsewhere (a prompt chunk) the
+    ``moe_grouped`` kernel where the rows it multiplies are few beside the
+    dense product's (kernels/moe.py ``takes_grouped``), else the
+    composite."""
     from paddle_tpu import kernels
 
-    sel = kernels.selected("moe_experts") if attrs.get("kernel") else None
-    return _moe_routed_experts(ins, attrs,
-                               None if sel is None else sel.interpret)
+    if attrs.get("kernel"):
+        sel = kernels.selected("moe_experts")
+        return _moe_routed_experts(
+            ins, attrs, None if sel is None else sel.interpret)
+    sel = kernels.selected("moe_grouped")
+    return _moe_routed_experts(
+        ins, attrs, None, None if sel is None else sel.interpret)
 
 
-def _moe_routed_experts(ins, attrs, kernel):
+def _moe_routed_experts(ins, attrs, kernel, grouped=None):
     """The held experts' part of a routed-experts layer (kernels/moe.py):
     ``X`` ``[T, H]``, the router ``GateW`` ``[E_all, H]`` (attribute
     ``score``: ``sigmoid``, or ``softmax`` over all of them) and its
@@ -148,7 +155,13 @@ def _moe_routed_experts(ins, attrs, kernel):
     that does not step, a chunk's padding) is routed nowhere and counted
     nowhere. ``Out`` float32 ``[T, H]``; ``Counts`` int32 ``[4]``
     (assignments, held assignments, held experts touched, the busiest held
-    expert's tokens)."""
+    expert's tokens); with ``group_counts``, ``GroupCounts`` int32 ``[3]``:
+    the (token, held expert) pairs, the rows multiplied for them (each
+    held expert's pairs in whole row tiles where the rule takes the grouped
+    product, every token for every held expert where it does not) and the
+    held experts with a pair.
+    ``kernel`` is the step kernel's interpret flag, ``grouped`` the grouped
+    kernel's (None: the composite)."""
     from paddle_tpu.kernels import moe
 
     x = first(ins, "X")
@@ -156,20 +169,31 @@ def _moe_routed_experts(ins, attrs, kernel):
     xt = x.reshape(-1, x.shape[-1])
     w_up, w_down = first(ins, "WUp"), first(ins, "WDown")
     w_gate = maybe(ins, "WGate")
+    gate_w = first(ins, "GateW")
     held, offset = w_up.shape[0], attrs.get("expert_offset", 0)
     mask = first(ins, "WriteRows").reshape(-1) < attrs["num_rows"]
-    idx, w = moe.route(xt, first(ins, "GateW"), first(ins, "SelectBias"),
+    idx, w = moe.route(xt, gate_w, first(ins, "SelectBias"),
                        attrs["k"], attrs.get("score_scale", 1.0),
                        attrs.get("normalize", True),
                        attrs.get("norm_epsilon", 1e-20),
                        attrs.get("score", "sigmoid"))
     c = moe.held_weights(idx, w, mask, offset, held)
-    if kernel is None:
-        out = moe.experts_composite(xt, c, w_up, w_down, w_gate)
-    else:
+    by_pairs = not attrs.get("kernel") and moe.takes_grouped(
+        xt.shape[0], attrs["k"], held, gate_w.shape[0])
+    if kernel is not None:
         out = moe.moe_experts(xt, c, w_up, w_down, w_gate, interpret=kernel)
-    return {"Out": [out.reshape(lead + (x.shape[-1],))],
+    elif grouped is not None and by_pairs:
+        out = moe.moe_grouped(xt, idx, w, mask, offset, w_up, w_down, w_gate,
+                              interpret=grouped)
+    else:
+        out = moe.experts_composite(xt, c, w_up, w_down, w_gate)
+    outs = {"Out": [out.reshape(lead + (x.shape[-1],))],
             "Counts": [moe.routing_counts(idx, c, mask)]}
+    if attrs.get("group_counts"):
+        pairs = moe.grouped_counts(idx, mask, offset, held)
+        outs["GroupCounts"] = [pairs if by_pairs else pairs.at[1].set(
+            jnp.sum(mask.astype(jnp.int32)) * held)]
+    return outs
 
 
 OpRegistry.register(OpDef(

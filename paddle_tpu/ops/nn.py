@@ -775,21 +775,126 @@ def _rms_norm(ins, attrs):
 
 @register_op("rotary_embedding", nondiff_inputs=("Positions",))
 def _rotary_embedding(ins, attrs):
-    """Rotary positions over the WHOLE head, rotate-half pairing: ``X``
-    ``[..., heads, D]`` at ``Positions`` ``[...]`` (one a token); lane ``i <
+    """Rotary positions over the WHOLE head: ``X`` ``[..., heads, D]`` at
+    ``Positions`` ``[...]`` (one a token). Rotate-half pairing: lane ``i <
     D / 2`` of a head is paired with lane ``i + D / 2`` and the pair turned
-    by ``position * theta^(-2 i / D)``. Angles, sines and the rotation in
-    float32; ``out_dtype`` names the result's dtype (default: ``X``'s)."""
+    by ``position * theta^(-2 i / D)``. With ``freqs`` (``D / 2`` numbers: a
+    table, e.g. a base blended frequency by frequency with a stretched one)
+    pair ``i`` turns by ``position * freqs[i]`` instead, and with
+    ``interleaved`` pair ``i`` is lanes ``(2 i, 2 i + 1)``. Angles, sines
+    and the rotation in float32; ``out_dtype`` names the result's dtype
+    (default: ``X``'s)."""
     x, pos = first(ins, "X"), first(ins, "Positions")
     half = x.shape[-1] // 2
-    freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
-                   * (-jnp.log(jnp.float32(attrs["theta"])) / half))
+    if attrs.get("freqs"):
+        freq = jnp.asarray(attrs["freqs"], jnp.float32)
+    else:
+        freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                       * (-jnp.log(jnp.float32(attrs["theta"])) / half))
     angle = pos.astype(jnp.float32)[..., None, None] * freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     xf = x.astype(jnp.float32)
-    a, b = xf[..., :half], xf[..., half:]
-    y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    if attrs.get("interleaved"):
+        pairs = xf.reshape(xf.shape[:-1] + (half, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        y = jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                      axis=-1).reshape(xf.shape)
+    else:
+        a, b = xf[..., :half], xf[..., half:]
+        y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
     return {"Out": [y.astype(attrs.get("out_dtype") or x.dtype)]}
+
+
+@register_op("position_log_scale", nondiff_inputs=("Positions",))
+def _position_log_scale(ins, attrs):
+    """``X`` ``[..., W]`` at ``Positions`` ``[...]`` times ``1 + beta * ln(1
+    + floor(position / period))``: a query's scale that grows with the
+    logarithm of how many ``period``s of context lie before it (1 below the
+    first). In float32; ``out_dtype`` names the result's dtype."""
+    x, pos = first(ins, "X"), first(ins, "Positions")
+    periods = jnp.floor_divide(pos, int(attrs["period"])).astype(jnp.float32)
+    by = 1.0 + float(attrs["beta"]) * jnp.log1p(periods)
+    by = by.reshape(by.shape + (1,) * (x.ndim - by.ndim))
+    y = x.astype(jnp.float32) * by
+    return {"Out": [y.astype(attrs.get("out_dtype") or x.dtype)]}
+
+
+def _paged_latent_attention(ins, attrs, kernel):
+    from paddle_tpu.kernels import attention as fused
+
+    q, arena = first(ins, "Q"), first(ins, "Arena")
+    w_uk, w_uv = first(ins, "WUK"), first(ins, "WUV")
+    rows, bias = first(ins, "Rows"), first(ins, "Bias")
+    latent, sm = w_uk.shape[-1], attrs.get("sm_scale", 1.0)
+    wide = fused.absorb_queries(q.astype(arena.dtype), w_uk, attrs["rope"],
+                                arena.shape[-1])
+    if kernel is None:
+        ctx = fused.paged_attention_composite(
+            wide, arena, None, rows, bias, attrs["seqs"], attrs["length"],
+            sm, v_width=latent)
+    else:
+        ctx = fused.paged_attention(
+            wide, arena, None, rows, bias, attrs["seqs"], attrs["length"],
+            attrs["block_size"], sm, interpret=kernel, v_width=latent)
+    return {"Out": [fused.project_values(ctx, w_uv, q.dtype)]}
+
+
+def _paged_latent_pallas(ins, attrs):
+    from paddle_tpu import kernels
+
+    sel = kernels.selected_for("paged_attention", attrs)
+    return _paged_latent_attention(ins, attrs,
+                                   None if sel is None else sel.interpret)
+
+
+# a decode step's queries ``[S, heads * (nope + rope)]`` over ONE latent
+# arena (a token's row: its normalised compressed K/V, then the one rotated
+# key all heads share), ABSORBED: ``WUK`` moved onto the query, ``WUV`` onto
+# the context (kernels/attention.py ``paged_attention`` handed one arena)
+OpRegistry.register(
+    OpDef(
+        "paged_latent_attention",
+        lambda ins, attrs: _paged_latent_attention(ins, attrs, None),
+        pallas=_paged_latent_pallas,
+        nondiff_inputs=("Rows", "Bias"),
+    )
+)
+
+
+def _chunk_latent_attention(ins, attrs, tile_rows):
+    from paddle_tpu.kernels import attention as fused
+
+    return {"Out": [fused.latent_chunk_expanded(
+        first(ins, "Q"), first(ins, "WUK"), first(ins, "WUV"),
+        first(ins, "Arena"), first(ins, "Rows"), first(ins, "Span"),
+        attrs.get("sm_scale", 1.0), attrs["rope"], tile_rows=tile_rows)]}
+
+
+def _chunk_latent_tiled(ins, attrs):
+    """Where kernels serve the program (any mode but ``off``) the chunk's
+    queries and the rows they see go a tile at a time
+    (kernels/attention.py ``latent_chunk_expanded``: XLA's loops, no kernel
+    of this repo's); ``off`` runs the dense definition."""
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import attention as fused
+
+    tiled = kernels.resolved_mode() != "off"
+    return _chunk_latent_attention(
+        ins, attrs, fused._EXPAND_TILE_ROWS if tiled else None)
+
+
+# a prompt chunk's queries over one sequence's rows of a latent arena,
+# EXPANDED: every row a query sees up-projected to its heads' keys and
+# values (the step attends absorbed, above; the count and the chip both
+# favour expanded for a chunk: kernels/attention.py ``_EXPAND_QUERY_TILE``)
+OpRegistry.register(
+    OpDef(
+        "chunk_latent_attention",
+        lambda ins, attrs: _chunk_latent_attention(ins, attrs, None),
+        pallas=_chunk_latent_tiled,
+        nondiff_inputs=("Rows", "Span"),
+    )
+)
 
 
 @register_op("relu2")

@@ -161,8 +161,9 @@ def _split(ins, attrs):
     num = attrs.get("num", 0)
     sections = attrs.get("sections", [])
     if sections:
-        idx = jnp.cumsum(jnp.array(sections[:-1]))
-        outs = jnp.split(x, [int(i) for i in idx], axis=axis)
+        # the offsets are the program's, not the trace's: plain integers
+        idx = [sum(sections[:i + 1]) for i in range(len(sections) - 1)]
+        outs = jnp.split(x, idx, axis=axis)
     else:
         outs = jnp.split(x, num, axis=axis)
     return {"Out": list(outs)}

@@ -43,6 +43,7 @@ from paddle_tpu.serving.decode import (
     GenerationEngine,
     build_decoder_model,
     build_granite_hybrid_model,
+    build_latent_moe_model,
     build_lfm2_model,
     build_nemotron_h_model,
     build_ouro_model,
@@ -79,6 +80,7 @@ __all__ = [
     "build_decoder_model",
     "build_nemotron_h_model",
     "build_granite_hybrid_model",
+    "build_latent_moe_model",
     "build_lfm2_model",
     "build_ouro_model",
     "build_sdar_model",

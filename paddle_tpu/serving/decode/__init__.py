@@ -37,7 +37,10 @@ Modules:
   `build_ouro_model`: one stack of layers run several times a token with
   shared parameters, paged K/V rows per (pass, layer), an exit gate;
   `build_granite_hybrid_model`: Mamba-2 or position-free grouped-query
-  mixers with a dense SwiGLU in every layer and four scalar multipliers.
+  mixers with a dense SwiGLU in every layer and four scalar multipliers;
+  `build_latent_moe_model`: latent attention over ONE arena a layer (a
+  token's compressed K/V and its shared rotary key), YaRN rotation, softmax
+  routed experts beside a shared one.
 * `pool`   — host-side slot allocator, block allocator + radix prefix
   index (storage dedup), and the content-hash prefill cache (compute
   dedup).
@@ -64,8 +67,8 @@ from paddle_tpu.serving.decode.generate import (
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.hybrid import (
-    build_granite_hybrid_model, build_lfm2_model, build_nemotron_h_model,
-    build_ouro_model, build_sdar_model)
+    build_granite_hybrid_model, build_latent_moe_model, build_lfm2_model,
+    build_nemotron_h_model, build_ouro_model, build_sdar_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -91,6 +94,7 @@ __all__ = [
     "build_decoder_model",
     "build_nemotron_h_model",
     "build_granite_hybrid_model",
+    "build_latent_moe_model",
     "build_lfm2_model",
     "build_ouro_model",
     "build_sdar_model",

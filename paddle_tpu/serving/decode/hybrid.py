@@ -101,6 +101,33 @@ embedding is scaled by ``embedding_multiplier``, the head is TIED to it and
 the logits are divided by ``logits_scaling``; a final RMSNorm; no bias but
 the convolution's. No expert is counted: the decode step's one fetch is its
 tokens.
+
+**``mistral4``** (Mistral Small 4, the DeepSeek-V2/V3 decoder's keys:
+``build_latent_moe_model``). Every layer is LATENT attention and then routed
+experts beside one shared expert. Attention: the query through a low-rank
+pair (``q_a`` to ``q_lora_rank``, an RMSNorm, ``q_b`` to ``heads x (nope +
+rope)``); K and V through ONE compressed vector a token (``kv_a`` to
+``kv_lora_rank + rope``: ``c``, RMSNormed, and ``k^R``, the one rotary key
+all heads share); head h's key is ``[c . W_UK,h | k^R]`` and its value ``c .
+W_UV,h``. What a layer CACHES is the token's ``[c | k^R]`` row alone, in ONE
+arena (``state_names`` of 1-tuples), padded with zeros to whole 128-lane
+tiles (384 lanes for 256 + 64: an array's minor dimension is tiled by 128 on
+the chip whatever its declared width, so a 320-lane row would take the same
+bytes). The step attends ABSORBED (``W_UK`` moved onto the query, ``W_UV``
+onto the context: all heads read the one row), a chunk EXPANDED
+(``kernels/attention.py latent_chunk_expanded``). The rotation is over the
+``rope`` lanes of the query's heads and of ``k^R``, INTERLEAVED pairs ``(2 j,
+2 j + 1)`` as published (``rope_interleave``), by a YaRN frequency table
+(``yarn_frequencies``: the base's frequencies blended with the base's /
+``factor``; sines and cosines unscaled, ``mscale == mscale_all_dim``), and
+the whole query is scaled by ``1 + beta ln(1 + floor(p / original_max))``
+(``llama_4_scaling_beta``). Scores are scaled by ``(nope + rope)^-1/2 m^2``,
+``m = 0.1 mscale_all_dim ln(factor) + 1``. The router is a softmax over all
+experts, top k, renormalised; gated SwiGLU experts and a shared expert of
+the same width; a final RMSNorm and an untied head; no bias anywhere. The
+chunk program sums, on the device, what its routed layers multiplied
+(``GROUPED_COUNTS``), and the next step hands the sums to the host in its
+one fetch.
 """
 
 import math
@@ -108,13 +135,22 @@ import math
 from paddle_tpu.serving.decode.model import DecodeModel, _state_var
 
 __all__ = ["build_nemotron_h_model", "build_lfm2_model", "build_ouro_model",
-           "build_sdar_model", "build_granite_hybrid_model", "MOE_COUNTS",
-           "LOOP_COUNTS"]
+           "build_sdar_model", "build_granite_hybrid_model",
+           "build_latent_moe_model", "yarn_frequencies", "MOE_COUNTS",
+           "GROUPED_COUNTS", "LOOP_COUNTS"]
 
 #: what the decode step's ``Counts`` hold, in order: the engine adds them
 #: to the counters of these names when the step's tokens come back
 MOE_COUNTS = ("moe_assignments", "moe_held_assignments",
               "moe_touched_experts", "moe_peak_expert_tokens")
+
+#: what the prompt chunks' routed layers multiplied since the step before:
+#: the (token, held expert) pairs their routing made, the rows computed for
+#: them, and the held experts with a pair (a layer and launch each); summed
+#: on the device by the chunk program (a chunk is a launch and no fetch)
+#: and handed over, and zeroed, by the next step
+GROUPED_COUNTS = ("moe_grouped_pairs", "moe_grouped_rows",
+                  "moe_grouped_experts")
 
 #: a looped stack's: the passes run over the step's stepping tokens, and
 #: the sum over them of the pass at which the exit gate expects to leave,
@@ -194,7 +230,7 @@ class _Parts:
     keeps rows of its own."""
 
     def __init__(self, prefix, dtype, eps, std, back, rows, kv_width,
-                 a_layers, slot_states):
+                 a_layers, slot_states, latent=False):
         import paddle_tpu as fluid
         from paddle_tpu.core.ir import Program
 
@@ -205,8 +241,12 @@ class _Parts:
         self.a_layers = a_layers
         tags = [i if isinstance(i, int) else ".p%d.l%d" % i
                 for i in a_layers]
-        self.state_names = [(f"{prefix}.kcache{tag}", f"{prefix}.vcache{tag}")
-                            for tag in tags]
+        # a latent cache keeps ONE arena a layer: a token's row is its
+        # key and, in its first lanes, its value
+        self.state_names = [
+            (f"{prefix}.lcache{tag}",) if latent
+            else (f"{prefix}.kcache{tag}", f"{prefix}.vcache{tag}")
+            for tag in tags]
         self.slot_states = slot_states
         self.startup = Program()
 
@@ -251,33 +291,32 @@ class _Parts:
         return _state_var(program, self.startup, name, shape, dtype=dtype)
 
     def arenas(self, program, i):
-        kn, vn = self.state_names[self.a_layers.index(i)]
         shape = [self.rows, self.kv_width]
-        return (_state_var(program, self.startup, kn, shape,
-                           dtype=self.dtype),
-                _state_var(program, self.startup, vn, shape,
-                           dtype=self.dtype))
+        return tuple(
+            _state_var(program, self.startup, n, shape, dtype=self.dtype)
+            for n in self.state_names[self.a_layers.index(i)])
 
-    def write(self, program, i, wrows, k, v, axis):
-        """Scatter the new K/V rows (``axis``: the one to squeeze out of
-        ``k`` and ``v``, or None for ``[rows, kv_width]`` as they are) and
-        persist (the lowering donates the arenas); attention reads the
+    def write(self, program, i, wrows, axis, *rows):
+        """Scatter the new rows, one ``[.., kv_width]`` array an arena of
+        layer ``i`` (K and V, or a latent cache's one; ``axis``: the one to
+        squeeze out of each, or None for ``[rows, kv_width]`` as they are)
+        and persist (the lowering donates the arenas); attention reads the
         written views."""
         fluid = self.fluid
-        kc, vc = self.arenas(program, i)
         flat = ((lambda t: t) if axis is None
                 else (lambda t: fluid.layers.squeeze(t, [axis])))
-        nk = fluid.layers.block_scatter_write(kc, wrows, flat(k))
-        nv = fluid.layers.block_scatter_write(vc, wrows, flat(v))
-        fluid.layers.assign(nk, output=kc)
-        fluid.layers.assign(nv, output=vc)
-        return nk, nv
+        arenas = self.arenas(program, i)
+        written = [fluid.layers.block_scatter_write(arena, wrows, flat(new))
+                   for arena, new in zip(arenas, rows)]
+        for view, arena in zip(written, arenas):
+            fluid.layers.assign(view, output=arena)
+        return written
 
 
 def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                   block_size, num_blocks, chunk_tokens, kv_heads, sm_scale,
                   eos_id, name, version, count_names=MOE_COUNTS, passes=1,
-                  block_len=1, mask_token=None):
+                  block_len=1, mask_token=None, latent=None):
     """The hybrid family's two programs around ``stack(program, toks,
     positions, wrows, mode, attend, slot)`` (the layers over ``toks`` ``[S,
     1]`` or ``[1, C]``; ``attend(i, q, k, v)`` is the program's own
@@ -286,7 +325,11 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
     and their DecodeModel. With ``block_len`` B > 1 the decode program is
     a block pass (``DecodeModel``): ``toks`` ``[S, B]``, a K/V head's B x
     group query rows side by side into ``paged_attention``, and one
-    position a slot decided at the end."""
+    position a slot decided at the end. With ``latent`` (the widths
+    ``heads``, ``nope``, ``rope``, ``value``, ``latent`` of a latent
+    attention) a layer keeps ONE arena and ``attend(i, q, row, weights)``
+    writes the token's ``row`` and attends over the arena as it lies
+    (``weights``: the ``w_uk`` and ``w_uv`` parameter attributes)."""
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
     from paddle_tpu.utils import unique_name
@@ -315,7 +358,7 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                 L, BS)
 
         def attend_step(i, q, k, v):
-            nk, nv = parts.write(decode, i, wrows, k, v, 1)
+            nk, nv = parts.write(decode, i, wrows, 1, k, v)
             ctx = fluid.layers.paged_attention(
                 fluid.layers.squeeze(q, [1]), nk, nv, rows, bias, S, L,
                 sm_scale=sm_scale, block_size=BS, kv_heads=kv_heads)
@@ -326,8 +369,9 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
             further query rows of their K/V head: all see the same rows."""
             width = parts.kv_width
             nk, nv = parts.write(
-                decode, i, wrows, fluid.layers.reshape(k, [S * B, width]),
-                fluid.layers.reshape(v, [S * B, width]), None)
+                decode, i, wrows, None,
+                fluid.layers.reshape(k, [S * B, width]),
+                fluid.layers.reshape(v, [S * B, width]))
             group = int(q.shape[-1]) // kv_heads       # a K/V head's lanes
             by_head = fluid.layers.transpose(
                 fluid.layers.reshape(q, [S, B, kv_heads, group]),
@@ -340,8 +384,18 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                 fluid.layers.reshape(ctx, [S, kv_heads, B, group]),
                 [0, 2, 1, 3]), [S, B, kv_heads * group])
 
-        dec_logits, counts = stack(decode, tok, pos, wrows, "step",
-                                   attend_block if B > 1 else attend_step)
+        def attend_step_latent(i, q, row, weights):
+            arena, = parts.write(decode, i, wrows, 1, row)
+            ctx = fluid.layers.paged_latent_attention(
+                fluid.layers.squeeze(q, [1]), arena, rows, bias, S, L,
+                param_attrs=weights, sm_scale=sm_scale, block_size=BS,
+                **latent)
+            return fluid.layers.unsqueeze(ctx, [1])
+
+        dec_logits, counts = stack(
+            decode, tok, pos, wrows, "step",
+            attend_step_latent if latent else
+            attend_block if B > 1 else attend_step)
         if B > 1:
             # the block after the pass stays on the device for the next
             # launch; the host gets each slot's decided position and token
@@ -375,14 +429,22 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                  if parts.slot_states else None)
 
         def attend_chunk(i, q, k, v):
-            nk, nv = parts.write(chunk, i, cwrows, k, v, 0)
+            nk, nv = parts.write(chunk, i, cwrows, 0, k, v)
             ctx = fluid.layers.chunk_paged_attention(
                 fluid.layers.squeeze(q, [0]), nk, nv, crows, cspan, kv_heads,
                 BS, sm_scale=sm_scale, block_len=B)
             return fluid.layers.unsqueeze(ctx, [0])
 
-        chu_logits, _ = stack(chunk, toks, pos, cwrows, "chunk",
-                              attend_chunk, slot=cslot)
+        def attend_chunk_latent(i, q, row, weights):
+            arena, = parts.write(chunk, i, cwrows, 0, row)
+            ctx = fluid.layers.chunk_latent_attention(
+                fluid.layers.squeeze(q, [0]), arena, crows, cspan,
+                param_attrs=weights, sm_scale=sm_scale, **latent)
+            return fluid.layers.unsqueeze(ctx, [0])
+
+        chu_logits, _ = stack(
+            chunk, toks, pos, cwrows, "chunk",
+            attend_chunk_latent if latent else attend_chunk, slot=cslot)
 
     return DecodeModel(
         decode_program=decode, prefill_program=None, inject_program=None,
@@ -1035,3 +1097,201 @@ def build_granite_hybrid_model(
         hidden=H, slots=S, max_len=L, block_size=BS, num_blocks=NB,
         chunk_tokens=C, kv_heads=NKV, sm_scale=float(attention_multiplier),
         eos_id=eos_id, name=name, version=version, count_names=())
+
+
+def yarn_frequencies(dim, theta, factor, beta_fast, beta_slow,
+                     original_max_position_embeddings):
+    """The ``dim / 2`` rotary frequencies of a YaRN-scaled base: ``theta_j
+    = theta^(-2 j / dim)`` below the correction range, ``theta_j / factor``
+    past it, a linear blend across it. The range is where a frequency
+    turns ``beta_fast`` (its low end, floored) to ``beta_slow`` (its high
+    end, ceiled) times over the original context:
+    ``dim ln(original / (2 pi beta)) / (2 ln theta)``, clipped to the
+    table."""
+    half = int(dim) // 2
+
+    def turns(beta):
+        return (dim * math.log(original_max_position_embeddings
+                               / (2 * math.pi * beta))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    span = max(high - low, 0.001)
+    out = []
+    for j in range(half):
+        base = float(theta) ** (-2.0 * j / dim)
+        ramp = min(max((j - low) / span, 0.0), 1.0)
+        out.append((1.0 - ramp) * base + ramp * base / float(factor))
+    return out
+
+
+def build_latent_moe_model(
+        vocab_size, hidden_size, num_hidden_layers, *, num_attention_heads,
+        q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+        v_head_dim, rope_parameters, n_routed_experts, router_experts,
+        num_experts_per_tok, moe_intermediate_size, n_shared_experts=1,
+        routed_scaling_factor=1.0, norm_topk_prob=True, rope_interleave=True,
+        rms_norm_eps=1e-6, initializer_range=0.02, expert_rank=0,
+        dtype="bfloat16", slots=4, max_len=64, block_size=16,
+        num_blocks=None, chunk_tokens=16, eos_id=None, name="mistral4",
+        version="1"):
+    """Build the ``mistral4`` latent-attention decoder as a paged
+    DecodeModel (module docstring). The sizes are the published
+    ``config.json``'s keys under their own names (``rope_parameters`` the
+    whole group: ``rope_theta``, ``factor``, ``beta_fast``, ``beta_slow``,
+    ``original_max_position_embeddings``, ``mscale``, ``mscale_all_dim``,
+    ``llama_4_scaling_beta``); ``n_routed_experts`` is how many experts are
+    HELD here and ``router_experts`` how many the router scores;
+    ``vocab_size`` the rows of the vocabulary held here. ``num_blocks`` may
+    be fewer than ``slots`` sequences of ``max_len`` need (admission by
+    reservation). ``initializer_range`` as ``build_lfm2_model``'s (the
+    query passes an RMSNorm before ``q_b`` and the rotary key none, so one
+    range gives scores that peak: no range of their own as
+    ``build_granite_hybrid_model``'s)."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import ConstantInitializer
+
+    rp = dict(rope_parameters)
+    if float(rp.get("mscale", 1.0)) != float(rp.get("mscale_all_dim", 1.0)):
+        raise ValueError(
+            f"rope_parameters mscale {rp.get('mscale')} != mscale_all_dim "
+            f"{rp.get('mscale_all_dim')}: the sines and cosines would be "
+            "scaled by their ratio, which is not built")
+    V, H, NL = int(vocab_size), int(hidden_size), int(num_hidden_layers)
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    NH, QR, KVR = (int(num_attention_heads), int(q_lora_rank),
+                   int(kv_lora_rank))
+    DN, DR, DV = (int(qk_nope_head_dim), int(qk_rope_head_dim),
+                  int(v_head_dim))
+    W = -(-(KVR + DR) // 128) * 128             # an arena row, whole tiles
+    F, FS = (int(moe_intermediate_size),
+             int(moe_intermediate_size) * int(n_shared_experts))
+    held, router = int(n_routed_experts), int(router_experts)
+    offset = _held(expert_rank, held, router)
+    freqs = yarn_frequencies(
+        DR, float(rp["rope_theta"]), float(rp["factor"]),
+        float(rp["beta_fast"]), float(rp["beta_slow"]),
+        int(rp["original_max_position_embeddings"]))
+    m = 0.1 * float(rp.get("mscale_all_dim", 1.0)) * math.log(
+        float(rp["factor"])) + 1.0
+    sm_scale = m * m / math.sqrt(DN + DR)
+    beta = float(rp.get("llama_4_scaling_beta", 0.0))
+    period = int(rp["original_max_position_embeddings"])
+    prefix = f"{name}_v{version}"
+    # two sub-layers a layer write into the residual
+    std = float(initializer_range)
+    parts = _Parts(prefix, dtype, float(rms_norm_eps), std,
+                   std / math.sqrt(2 * NL), R, W, list(range(NL)), [],
+                   latent=True)
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+    grouped_name = f"{prefix}.grouped_counts"
+
+    def rotated(t, positions, out_dtype):
+        return fluid.layers.rotary_embedding(
+            t, positions, freqs=freqs, interleaved=bool(rope_interleave),
+            out_dtype=out_dtype)
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The ``NL`` layers over ``toks``: latent attention, then the
+        routed experts beside the shared one."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        lead = [int(d) for d in toks.shape[:2]]
+        counts, pairs = [], []
+        # which tokens the router routes: a write row under ``R``, but for a
+        # CHUNK its span's real positions (``c - real < 0``): a position
+        # whose row a shared block already holds (the last token of a prompt
+        # served before: this model has no per-slot state, so the pool
+        # shares its full blocks) writes nowhere and is still a token
+        routes, under = wrows, R
+        if mode == "chunk":
+            real = fluid.layers.slice(
+                program.global_block().var(DecodeModel.CHU_SPAN), [0], [1],
+                [2])
+            routes, under = fluid.layers.elementwise_sub(
+                fluid.layers.cumsum(fluid.layers.fill_constant(
+                    [lead[1]], "int32", 1), exclusive=True), real), 0
+        for i in range(NL):
+            x = norm(h, f"l{i}.input_layernorm")
+            cq = norm(proj(x, QR, f"l{i}.q_a", out_dtype="float32"),
+                      f"l{i}.q_a_layernorm")
+            q = fluid.layers.position_log_scale(
+                fluid.layers.reshape(
+                    proj(cq, NH * (DN + DR), f"l{i}.q_b",
+                         out_dtype="float32"),
+                    lead + [NH, DN + DR]), positions, beta, period)
+            qn, qr = fluid.layers.split(q, [DN, DR], dim=-1)
+            q = fluid.layers.cast(fluid.layers.reshape(
+                fluid.layers.concat(
+                    [qn, rotated(qr, positions, "float32")], axis=-1),
+                lead + [NH * (DN + DR)]), dtype)
+            c, kr = fluid.layers.split(
+                proj(x, KVR + DR, f"l{i}.kv_a", out_dtype="float32"),
+                [KVR, DR], dim=-1)
+            kr = fluid.layers.reshape(
+                rotated(fluid.layers.reshape(kr, lead + [1, DR]), positions,
+                        dtype), lead + [DR])
+            row = fluid.layers.pad(
+                fluid.layers.concat(
+                    [norm(c, f"l{i}.kv_a_layernorm"), kr], axis=-1),
+                [0, 0, 0, 0, 0, W - KVR - DR])
+            ctx = attend(i, q, row, {"w_uk": matrix(f"l{i}.kv_b_k"),
+                                     "w_uv": matrix(f"l{i}.kv_b_v")})
+            h = fluid.layers.elementwise_add(h, proj(
+                ctx, H, f"l{i}.o", residual=True, out_dtype="float32"))
+            x = norm(h, f"l{i}.post_attention_layernorm")
+            routed, n, g = fluid.layers.moe_routed_experts(
+                x, routes, under, router, held, F, int(num_experts_per_tok),
+                {"gate": matrix(f"l{i}.gate"),
+                 # this router's choice is its scores' own
+                 "select_bias": attr(f"l{i}.select_bias",
+                                     ConstantInitializer(0.0)),
+                 "w_gate": matrix(f"l{i}.w1"),
+                 "w_up": matrix(f"l{i}.w3"),
+                 "w_down": matrix(f"l{i}.w2", residual=True)},
+                expert_offset=offset,
+                score_scale=float(routed_scaling_factor),
+                normalize=bool(norm_topk_prob), kernel=mode == "step",
+                score="softmax", group_counts=True)
+            counts.append(n)
+            pairs.append(g)
+            gated = fluid.layers.elementwise_mul(
+                proj(x, FS, f"l{i}.shared_gate", act="silu",
+                     out_dtype="float32"),
+                proj(x, FS, f"l{i}.shared_up", out_dtype="float32"))
+            shared = proj(fluid.layers.cast(gated, dtype), H,
+                          f"l{i}.shared_down", residual=True,
+                          out_dtype="float32")
+            h = fluid.layers.elementwise_add(
+                h, fluid.layers.elementwise_add(routed, shared))
+        logits = proj(norm(h, "norm"), V, "head", out_dtype="float32")
+        # what the chunks' routed layers multiplied is summed on the device
+        # (a chunk is a launch and no fetch); a step hands the sums over
+        # beside its own counts and zeroes them
+        total = _state_var(program, parts.startup, grouped_name,
+                           [len(GROUPED_COUNTS)], dtype="int32")
+        if mode == "chunk":
+            fluid.layers.assign(fluid.layers.sums([total] + pairs),
+                                output=total)
+            return logits, []
+        both = fluid.layers.concat([fluid.layers.sums(counts), total],
+                                   axis=0)
+        fluid.layers.assign(
+            fluid.layers.fill_constant([len(GROUPED_COUNTS)], "int32", 0),
+            output=total)
+        return logits, [both]
+
+    return _hybrid_model(
+        parts, stack, lambda: build_latent_moe_model(**kwargs), vocab=V,
+        hidden=H, slots=S, max_len=L, block_size=BS, num_blocks=NB,
+        chunk_tokens=C, kv_heads=1, sm_scale=sm_scale, eos_id=eos_id,
+        name=name, version=version,
+        count_names=MOE_COUNTS + GROUPED_COUNTS,
+        latent={"heads": NH, "nope": DN, "rope": DR, "value": DV,
+                "latent": KVR})

@@ -122,7 +122,7 @@ class KVStore:
 
     def __init__(self, model, tier_bytes, prefix_cache_size, metrics, run,
                  fetch, scope, device):
-        from paddle_tpu.kernels.attention import _paged_group
+        from paddle_tpu.kernels.attention import paged_copy_unit
 
         self._model = model
         self._metrics = metrics
@@ -142,9 +142,9 @@ class KVStore:
                          < model.slots * model.blocks_per_slot)
         # blocks the paged-attention kernel copies as one unit at this
         # geometry (0: no kernel serves it), to count a step's units
-        self.copy_unit = _paged_group(
+        self.copy_unit = paged_copy_unit(
             model.block_size, model.blocks_per_slot, model.kv_width,
-            model.kv_dtype)
+            model.kv_dtype, model.arenas // len(model.state_names))
 
     @staticmethod
     def check_carries(model, tier_bytes, prefix_cache_size):
@@ -361,17 +361,17 @@ class KVStore:
             raise ArenaInvalidError(str(e)) from e
 
     def _read(self, pick):
-        """``pick(arena)`` of every K and V arena, per state pair, and the
+        """``pick(arena)`` of every arena, per entry of ``state_names`` (a
+        K and V pair, or a latent cache's one), and the
         bytes brought to the host for it: each arena WHOLE, whatever is
         picked. They are fetches (``serving_fetched_bytes_total``) and are
         counted in ``serving_arena_read_bytes_total`` besides."""
         out, nbytes = [], 0
         scope = self._scope()
-        for kn, vn in self._model.state_names:
-            k = self._fetch(scope.find_var(kn))
-            v = self._fetch(scope.find_var(vn))
-            nbytes += k.nbytes + v.nbytes
-            out.append((np.array(pick(k)), np.array(pick(v))))
+        for names in self._model.state_names:
+            arenas = [self._fetch(scope.find_var(n)) for n in names]
+            nbytes += sum(a.nbytes for a in arenas)
+            out.append(tuple(np.array(pick(a)) for a in arenas))
         self._metrics.incr("arena_read_bytes", nbytes)
         return out, nbytes
 

@@ -225,6 +225,15 @@ class DecodeMetrics(ServingMetrics):
         # the expert layers (the straggler a grouped product waits for)
         "moe_assignments", "moe_held_assignments", "moe_touched_experts",
         "moe_peak_expert_tokens",
+        # the prompt CHUNKS' routed layers (summed on the device by the
+        # chunk program, handed over by the next step: hybrid.py
+        # GROUPED_COUNTS): the (token, held expert) pairs their routing
+        # made, and the rows multiplied for them (each held expert's pairs
+        # in whole row tiles under the grouped product, every token for
+        # every held expert under the dense one) and the held experts with
+        # a pair (whose matrices a layer of a launch has to read), summed
+        # over the layers
+        "moe_grouped_pairs", "moe_grouped_rows", "moe_grouped_experts",
         # a model whose stack runs several times a token, per decode step
         # as the device ran it: stepping tokens x the passes they took,
         # and the sum over them of the pass at which the exit gate expects

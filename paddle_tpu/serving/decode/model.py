@@ -82,7 +82,9 @@ class DecodeModel:
     """The paged programs + their naming contract and geometry.
 
     ``state_names`` lists per-layer ``(k_arena, v_arena)`` var names
-    (each ``[R, H]``); ``prefill_kv_fetches`` the matching per-layer
+    (each ``[R, H]``), or a 1-tuple ``(arena,)`` where a layer's cache is
+    ONE arena (a latent cache: a token's row is its key and, in its first
+    lanes, its value); ``prefill_kv_fetches`` the matching per-layer
     ``(k_rows, v_rows)`` fetch names of the prefill program. ``builder``
     (optional) is a zero-arg callable that re-creates a content-identical
     DecodeModel — the circuit breaker's relaunch path uses it to rebuild
@@ -235,6 +237,12 @@ class DecodeModel:
         return self.num_blocks * self.block_size
 
     @property
+    def arenas(self):
+        """How many ``[R, kv_width]`` arenas hold the paged state: the
+        names of every entry of ``state_names``."""
+        return sum(len(names) for names in self.state_names)
+
+    @property
     def blocks_per_slot(self):
         """Blocks a slot at ``max_len`` holds: its block table's width."""
         return -(-self.max_len // self.block_size)
@@ -320,7 +328,8 @@ class DecodeModel:
 
     def arena_bytes(self):
         """Exact bytes of the model's state on the device: the paged KV
-        pool (2 arenas x layers x ``[R, kv_width]`` of ``kv_dtype``) and
+        pool (every arena ``state_names`` lists, two a layer or a latent
+        cache's one, ``[R, kv_width]`` of ``kv_dtype`` each) and
         every per-slot state array — what `analysis/memory.py` sees as
         persistent state and what the HBM budget gate reasons about.
         The slotted design's ``S * max_len`` rows become
@@ -328,7 +337,7 @@ class DecodeModel:
         per = self.rows * self.kv_width * _itemsize(self.kv_dtype)
         slot = sum(int(np.prod(shape)) * _itemsize(dt)
                    for _n, shape, dt in self.slot_states)
-        return per * 2 * len(self.state_names) + slot
+        return per * self.arenas + slot
 
     def slotted_equivalent_bytes(self):
         """What the PR 10 dense design would reserve for the same
@@ -336,7 +345,7 @@ class DecodeModel:
         baseline in DECODE_EVIDENCE."""
         per = (self.slots * self.max_len * self.kv_width
                * _itemsize(self.kv_dtype))
-        return per * 2 * len(self.state_names)
+        return per * self.arenas
 
     # -- feed signatures (ordered like each program's feed list) ---------
     def decode_feed_sig(self):

@@ -79,7 +79,9 @@ def test_paged_kernel_serves_every_cells_geometry(label, v5e_devices):
     cell's copy unit stay under the kernel's VMEM budget."""
     from paddle_tpu.kernels import attention as A
 
-    cases = {c[0]: c for c in kernels.get("paged_attention").tpu_cases()}
+    # (the two-arena cases: a latent cache's one arena has its own test)
+    cases = {c[0]: c for c in kernels.get("paged_attention").tpu_cases()
+             if len(c[2]) == 5}
     assert set(cases) == set(PAGED_UNITS)
     _label, fn, arg_specs = cases[label]
     (rows, hidden), dtype = arg_specs[1]
@@ -97,6 +99,36 @@ def test_paged_kernel_serves_every_cells_geometry(label, v5e_devices):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert kernels.fallback_counter().value == before
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_paged_kernel_serves_one_latent_arena(v5e_devices):
+    """Handed ONE arena (mistral_small_4_119b: 16 slots of 33,280 positions,
+    rows of 384 lanes, 32 absorbed query heads, values the first 256 lanes)
+    the grouped body compiles with no fallback at the geometry it RUNS:
+    ``paged_copy_unit`` for one arena, a copy unit of one reduce tile of
+    512 rows (384 KB of the ONE arena), two halves of it under the VMEM
+    budget."""
+    from paddle_tpu.kernels import attention as A
+
+    (case,) = [c for c in kernels.get("paged_attention").tpu_cases()
+               if len(c[2]) == 4]
+    _label, fn, arg_specs = case
+    (rows, width), dtype = arg_specs[1]
+    assert (width, dtype) == (384, "bfloat16")
+    per_slot = 33280 // 16
+    unit = A.paged_copy_unit(16, per_slot, width, dtype, arenas=1)
+    assert unit == 32 == A._paged_tile(16, per_slot, width, dtype,
+                                       A._LATENT_TILE_ROWS)
+    assert unit * 16 == A._LATENT_TILE_ROWS
+    assert 2 * unit * 16 * width * 2 <= A.VMEM_BUDGET
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.fallback_counter().value
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "latent_paged_attention" in text
 
 
 #: the flash cases' geometries (``_tpu_cases_flash``'s labels): B*H, S and

@@ -1,6 +1,7 @@
 """A served hybrid configuration (``nemotron3_nano_30b_a3b``,
-``lfm2_24b_a2b``, ``ouro_2_6b``, ``sdar_30b_a3b``, ``granite_4_0_h_micro``:
-the five families of ``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
+``lfm2_24b_a2b``, ``ouro_2_6b``, ``sdar_30b_a3b``, ``granite_4_0_h_micro``,
+``mistral_small_4_119b``:
+the six families of ``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
 ``reference``) against its plain reference, outside any timed window, and
 the readings the cell's limits are set from (its traffic file; PERF.md
 section 2).
@@ -8,7 +9,8 @@ section 2).
     python3 tools/check_hybrid_logits.py --seed <n>
         [--config nemotron3_nano_30b_a3b] [--traffic reasoning_steady]
         [--requests 32] [--steps 192]
-        [--faults ssm,conv,kv,kv_all,positions,chunk_ssm,chunk_kv,chunks_kv]
+        [--faults ssm,conv,kv,kv_all,positions,chunk_ssm,chunk_kv,chunks_kv,
+                  again]
         [--references float8_e4m3fn,operands:bfloat16,attention_multiplier=0.125]
         [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
         [--passes 3] [--share-passes] [--stale-arena 1,7]
@@ -39,7 +41,13 @@ chunk, the one before its last chunk: the first Mamba layer's SSM state of
 the slot dropped there, or the first attention layer's K arena left a chunk
 stale (``_chunk_fault``); ``chunks_kv`` puts that arena back after EVERY
 chunk launch, as ``kv`` does after every decode step (no prompt's K rows
-land in it: a chunk's queries find their own chunk's keys alone).
+land in it: a chunk's queries find their own chunk's keys alone);
+``again`` plants nothing and serves the prompts a second time AS THE POOL
+HOLDS THEM (a model without per-slot state meets its prompts' full blocks
+in place and prefills the rest alone): a served token may not depend on
+what was served before it. Every other fault first zeroes the arenas and
+empties the pool, so that its prompts are prefilled anew and a row that
+does not land reads as zeros.
 ``--references``
 reads the sound tokens again with the reference computed otherwise:
 ``<dtype>`` rounds its weights through that dtype (the precision below the
@@ -87,6 +95,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 OVER = (0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 1.0)
+#: the reference's readings of this run, by (prompt, tokens, how)
+_READ = {}
 
 
 def _stale(entry, fault):
@@ -96,6 +106,10 @@ def _stale(entry, fault):
     import jax.numpy as jnp
 
     m = entry.model
+    if fault == "again":
+        # nothing planted: the same prompts served a second time, what a
+        # fault's reading is held against beside the first serving's
+        return lambda: None
     k_arenas = [kv[0] for kv in m.state_names]
     if fault == "kv_all":
         names = k_arenas
@@ -157,7 +171,11 @@ def _chunk_fault(entry, fault):
         if kind != "chunk":
             return launch(kind, feeds, span)
         start = int(feeds[m.CHU_SPAN][0])
-        slot = int(feeds[m.CHU_SLOT][0])
+        # a model without per-slot state is not fed its slot: the chunk's
+        # row map is its slot's own
+        slot = (int(feeds[m.CHU_SLOT][0]) if m.CHU_SLOT in feeds else next(
+            s for s, st in enumerate(entry._slots)
+            if st is not None and st.kv.row_map is feeds[m.CHU_ROWS]))
         plen = entry._slots[slot].plen
         if fault != "chunks_kv" and (
                 not start
@@ -221,8 +239,14 @@ def _against(system, prompts, served, **how):
             system.engine.noted[tuple(prompt)], got = got, None
         out = out[:tokens]
         first = len(prompt) - 1
-        want = system.reference_logits(
-            list(prompt) + out[:-1], range(first, first + len(out)), **how)
+        # (a pass over 32k positions takes its time: the same tokens read
+        # the same way, a fault that moved no token, are not read twice)
+        key = (tuple(prompt), tuple(out), repr(sorted(how.items())))
+        if key not in _READ:
+            _READ[key] = system.reference_logits(
+                list(prompt) + out[:-1], range(first, first + len(out)),
+                **how)
+        want = _READ[key]
         if how.get("routing"):
             want, *more = want
             extra.append(more)
@@ -356,6 +380,14 @@ def main(argv=None):
         system.engine.start()
         served = {"sound": _serve(system, prompts, steps)}
         for fault in faults:
+            if fault != "again":
+                # every request is over and the loop waits: zero the arenas
+                # and empty the pool. A model whose blocks the pool shares
+                # (one with no per-slot state) would meet its prompts' rows
+                # in place and prefill nothing; and a row that never lands
+                # has to read as zeros, not as the same prompt's row of the
+                # serving before (the pool hands the same blocks out again)
+                system.entry.kv.reset()
             undo = (_chunk_fault if fault.startswith("chunk")
                     else _stale)(system.entry, fault)
             served["stale_" + fault] = _serve(system, prompts, steps)
@@ -379,17 +411,25 @@ def main(argv=None):
     rounded = [ref for ref in args.references.split(",")
                if ref.startswith("operands:")]
     blocks = system.entry.model.fills_blocks
+    # the expert layers' choices, of a reference that hands them over
+    import inspect
+
+    routes = held and not blocks and "routing" in inspect.signature(
+        system.reference.logits).parameters
     # a block's replay needs every position of the block in the answer
     whole = ({"tokens": steps - system.entry.model.block_len + 1}
              if blocks else {})
     for name, answers in served.items():
         sigma, behind, routing = _against(
             system, prompts, answers, **whole,
-            **({"routing": True} if held and name == "sound" and not blocks
-               else {}))
+            **({"routing": True} if routes and name == "sound" else {}))
         report[name] = _summary(sigma, behind, traffic)
         dump[name + ".row_sigma"], dump[name + ".behind"] = sigma, behind
         if name != "sound":
+            # answers whose tokens are the first serving's, token for token
+            report[name]["answers_as_sound"] = sum(
+                out == first for (out, _g), (first, _f) in zip(
+                    answers, served["sound"]))
             # a fault's tokens against the rounded reference too: whether
             # a comparison with rounding's share taken out would tell it
             for ref in rounded:
@@ -397,7 +437,7 @@ def main(argv=None):
                     system, prompts, answers,
                     round_operands=ref.split(":")[1])[:2], traffic)
             continue
-        if held and not blocks:
+        if routes:
             gap = np.concatenate([(s[..., -2] - s[..., -1]).reshape(-1)
                                   for _ids, s in routing])
             report["reference_margin_share_under"] = {
@@ -405,9 +445,9 @@ def main(argv=None):
             dump["sound.margin"] = np.stack(
                 [s[..., -2] - s[..., -1] for _ids, s in routing])
         refs = [(ref, dict({"round_operands": ref.split(":")[1]},
-                           **({"routing": True} if held else {}))
+                           **({"routing": True} if routes else {}))
                  if ref.startswith("operands:")
-                 else {ref.split("=")[0]: float(ref.split("=")[1])}
+                 else {ref.split("=")[0]: json.loads(ref.split("=")[1])}
                  if "=" in ref else {"round_to": ref})
                 for ref in filter(None, args.references.split(","))]
         if args.passes is not None:
