@@ -11,6 +11,7 @@ flash_attention      scaled_dot_product_attention    tolerance mode
 cached_attention     cached_attention (decode [S,1]) bit       mode
 paged_attention      paged_attention, paged_latent_attention (decode [S,1]; one latent arena or K and V) tolerance mode
 chunk_paged_attention chunk_paged_attention ([C] of one slot) tolerance mode
+latent_chunk_attention chunk_latent_attention ([C] of one slot over ONE latent arena, expanded; fallback: the same form by XLA's loops) tolerance mode
 moe_experts          moe_routed_experts (decode)     tolerance mode
 moe_grouped          moe_routed_experts (a prompt chunk's pairs, by expert) tolerance mode
 ssm_update           mamba2_mixer (decode [S,1]; a grid step a stepping slot's heads, as many as VMEM takes) tolerance mode
@@ -474,6 +475,79 @@ def _tpu_cases_chunk_attention():
             case(512, 4096, 16, 4, 8, 128, block_len=4)]
 
 
+def _latent_chunk_case(rng, heads, nope, rope, value, latent, L, bs, C,
+                       dtype="float32"):
+    """One slot's latent arena (rows ``[c | k^R | zeros]`` of 128 lanes)
+    under a shuffled block table, the up-projections and a chunk's
+    queries: ``(q, w_uk, w_uv, arena, rows)``."""
+    per_slot = -(-L // bs)
+    pool = per_slot + 3
+    ids = rng.permutation(pool)[:per_slot]
+    rows = (ids[:, None] * bs + np.arange(bs)).reshape(-1)[:L]
+    arena = np.zeros((pool * bs, 128), dtype)
+    arena[:, :latent + rope] = rng.randn(pool * bs, latent + rope)
+    return (rng.randn(C, heads * (nope + rope)).astype(dtype),
+            (0.3 * rng.randn(heads, nope, latent)).astype(dtype),
+            (0.3 * rng.randn(heads, latent, value)).astype(dtype),
+            arena, rows.astype("int64"))
+
+
+def _parity_latent_chunk(rng):
+    """The kernel (interpreted) against the dense expanded composite: 4
+    heads of 8 + 8 | 16 over a latent of 32, then 12 heads (two groups of
+    6) of 4 + 4 | 8 over 8; a chunk of 32 in sub-tiles of 8 over
+    320 rows in tiles of 64, so the spans cross dead tiles, tiles a
+    sub-tile skips, and tiles it takes unmasked: an empty context, a short
+    last chunk, a ragged horizon, a chunk that ends on a block's edge, one
+    past several whole tiles, and NO real query (zeros)."""
+    import jax
+
+    from paddle_tpu.kernels import attention as A
+
+    sizes = (A._LATENT_CHUNK_QUERY_ROWS, A._LATENT_CHUNK_TILE_ROWS)
+    A._LATENT_CHUNK_QUERY_ROWS, A._LATENT_CHUNK_TILE_ROWS = 8, 64
+    try:
+        for heads, nope, value, latent in ((4, 8, 16, 32), (12, 4, 8, 8)):
+            args = _latent_chunk_case(rng, heads, nope, nope, value, latent,
+                                      320, 16, 32)
+            kernel = jax.jit(lambda *a: A.latent_chunk_attention(
+                *a, 16, 0.2, nope, interpret=True))
+            dense = jax.jit(lambda *a: A.latent_chunk_expanded(
+                *a, 0.2, nope))
+            for start, real in ((0, 32), (0, 7), (224, 31), (288, 32),
+                                (130, 20), (64, 0)):
+                span = np.array([start, real], "int32")
+                got = np.asarray(kernel(*args, span))
+                ref = np.asarray(dense(*args, span))
+                _assert_close_both_ways(
+                    got[:real], ref[:real],
+                    f"latent_chunk_attention {start}+{real}", 2e-5, 2e-5)
+                assert not got[real:].any()
+    finally:
+        A._LATENT_CHUNK_QUERY_ROWS, A._LATENT_CHUNK_TILE_ROWS = sizes
+
+
+def _tpu_cases_latent_chunk():
+    """mistral_small_4_119b's chunk: 1,024 queries, and a prompt's short
+    last launch of 512, of 32 heads of 64 + 64 | 128 over a latent of 256,
+    a slot of 33,280 positions in blocks of 16 rows of 384 lanes,
+    bfloat16."""
+    from paddle_tpu.kernels import attention as A
+
+    heads, nope, rope, value, latent, W, bs, L = 32, 64, 64, 128, 256, 384, \
+        16, 33280
+
+    def fwd(q, w_uk, w_uv, arena, rows, span):
+        return A.latent_chunk_attention(q, w_uk, w_uv, arena, rows, span,
+                                        bs, 0.195, rope)
+
+    return [(f"c{C}_l{L}_b{bs}_h{heads}x{nope}+{rope}x{value}_bf16", fwd, [
+        ((C, heads * (nope + rope)), "bfloat16"),
+        ((heads, nope, latent), "bfloat16"),
+        ((heads, latent, value), "bfloat16"), ((16 * L, W), "bfloat16"),
+        ((L,), "int32"), ((2,), "int32")]) for C in (1024, 512)]
+
+
 def _parity_moe_experts(rng):
     """Held experts some of which no token chose, a masked token, and the
     step in which none is touched."""
@@ -749,6 +823,16 @@ register(KernelSpec(
         "mask made on the device from the chunk's span, online softmax "
         "over double-buffered copy tiles (kernels/attention.py "
         "chunk_attention)",
+))
+register(KernelSpec(
+    "latent_chunk_attention", ("chunk_latent_attention",), "tolerance",
+    _parity_latent_chunk, tpu_cases=_tpu_cases_latent_chunk, version=3,
+    doc="a prompt chunk's latent attention, EXPANDED: a group of heads a "
+        "grid step, the slot's live rows walked once in double-buffered "
+        "copy tiles, each up-projected once for all the chunk's queries; "
+        "keys, values, scores and running sums stay in VMEM, the softmax "
+        "transposed (a query a lane) "
+        "(kernels/attention.py latent_chunk_attention)",
 ))
 register(KernelSpec(
     "moe_experts", ("moe_routed_experts",), "tolerance", _parity_moe_experts,

@@ -83,7 +83,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels.registry import fallback_counter
+from paddle_tpu.kernels.registry import (
+    fallback_counter, latent_chunk_counter,
+)
 from paddle_tpu.ops.common import vma_names
 
 #: a bias at or under this is a closed position: the paged kernel takes a
@@ -97,6 +99,7 @@ __all__ = [
     "chunk_attention", "chunk_attention_by_span", "chunk_horizon",
     "chunk_mask_bias", "fits_vmem", "grouped_layout", "paged_copy_unit",
     "absorb_queries", "project_values", "latent_chunk_expanded",
+    "latent_chunk_attention",
 ]
 
 #: per-kernel budget (bytes) for the INPUT blocks; see the module docstring
@@ -602,6 +605,9 @@ LATENT_STEP_KERNEL = "latent_paged_attention"
 #: the ``jax.named_scope`` of the expanded form's loops
 EXPANDED_SCOPE = "latent_chunk_expanded"
 
+#: the expanded form's kernel, by the name its events carry in a device trace
+LATENT_CHUNK_KERNEL = "latent_chunk_attention"
+
 
 def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
                     block_size, sm_scale, interpret=False, kv_heads=0,
@@ -895,20 +901,24 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
 # lies, the context (sum_j a_j c_j) . W_UV,h: ``absorb_queries``,
 # ``paged_attention`` handed one arena, ``project_values``); a prompt chunk
 # attends EXPANDED (every context row up-projected to its heads' keys and
-# values first: ``latent_chunk_expanded``).
+# values first): through ONE kernel, ``latent_chunk_attention`` (the module's
+# last section), whose fallback is the same form in XLA's loops,
+# ``latent_chunk_expanded``, which is also, dense, the op's definition.
 # ---------------------------------------------------------------------------
 
-#: context rows the expanded form up-projects and reduces at a time, and
-#: the queries that stand against them at a time. READ ON THE CHIP
-#: (tools/check_latent_attention.py; PERF.md section 6, PR 56), ms a call
-#: behind 8,192 rows at 32 heads of 64 + 64 | 128 over a latent of 256:
-#: 512 queries 1.12, 1,024 queries in ONE tile 4.57, 2,048 in one 19.8; in
-#: tiles of 512 queries 2.23 and 4.69. Up to a [512, heads, 512] tile of
-#: scores XLA keeps the loop's body on the chip, past it the scores and the
-#: running sums go through HBM. So the queries go 512 at a time, each tile
-#: with a loop of its own over the rows ITS last query sees. (The absorbed
-#: form through the chunk kernel read 1.96, 4.09 and 8.58 there: 1.6 x the
-#: operations a pair; it is not kept for chunks.)
+#: context rows the LOOPS of the expanded form (the kernel's fallback)
+#: up-project and reduce at a time, and the queries that stand against them
+#: at a time. READ ON THE CHIP (tools/check_latent_attention.py; PERF.md
+#: section 6, PR 56), ms a call behind 8,192 rows at 32 heads of 64 + 64 |
+#: 128 over a latent of 256: 512 queries 1.12, 1,024 queries in ONE tile
+#: 4.57, 2,048 in one 19.8; in tiles of 512 queries 2.23 and 4.69. Up to a
+#: [512, heads, 512] tile of scores XLA keeps the loop's body on the chip,
+#: past it the scores and the running sums go through HBM. So the queries go
+#: 512 at a time, each tile with a loop of its own over the rows ITS last
+#: query sees, and each loop up-projects every row it sees again: what the
+#: kernel does once a chunk. (The absorbed form through the chunk kernel
+#: read 1.96, 4.09 and 8.58 there: 1.6 x the operations a pair; it is not
+#: kept for chunks.)
 _EXPAND_TILE_ROWS = 512
 _EXPAND_QUERY_TILE = 512
 
@@ -962,11 +972,13 @@ def _expand_rows(g, w_uk, w_uv, rope):
 
 def latent_chunk_expanded(q, w_uk, w_uv, arena, rows, span, sm_scale, rope,
                           tile_rows=None):
-    """The EXPANDED form of a prompt chunk's latent attention: the slot's
-    rows up-projected ``tile_rows`` at a time and reduced by an online
-    softmax, ``_EXPAND_QUERY_TILE`` queries at a time, each tile of queries
-    in a loop whose trip count is ITS last horizon, so nothing of a ``[C,
-    L]`` size is made and the work follows the prompt so far.
+    """The EXPANDED form of a prompt chunk's latent attention in XLA's own
+    loops (``latent_chunk_attention``'s fallback and, with ``tile_rows``
+    None, the op's definition): the slot's rows up-projected ``tile_rows``
+    at a time and reduced by an online softmax, ``_EXPAND_QUERY_TILE``
+    queries at a time, each tile of queries in a loop whose trip count is
+    ITS last horizon, so nothing of a ``[C, L]`` size is made and the work
+    follows the prompt so far.
     ``tile_rows`` None: every row and every query at once (the dense
     composite, for the CPU and the tests). Scores and sums float32,
     products in the arena's dtype."""
@@ -1018,3 +1030,261 @@ def latent_chunk_expanded(q, w_uk, w_uv, arena, rows, span, sm_scale, rope,
             tile_of_queries(qn[c:c + Q], qr[c:c + Q], horizon[c:c + Q])
             for c in range(0, C, Q)])
     return out.reshape(C, -1).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the expanded form as a kernel: every row a chunk sees read and up-projected
+# once a chunk and a group of heads; keys, values, scores and sums stay in VMEM
+# ---------------------------------------------------------------------------
+
+#: heads one call of the latent chunk kernel holds: their queries, their
+#: running max, sum and accumulator, and the rows' keys and values for them
+_LATENT_CHUNK_HEADS = 8
+
+#: queries of a head that stand against a tile of rows at a time (a sub-tile
+#: whose last horizon lies before the tile skips it) and the rows a tile is
+_LATENT_CHUNK_QUERY_ROWS = 256
+_LATENT_CHUNK_TILE_ROWS = 512
+
+#: what a call's blocks and scratch may take of ``_CHUNK_VMEM_LIMIT``
+#: (the rest is Mosaic's own): a chunk of 2,048 queries holds 8 heads in 33
+#: MB
+_LATENT_CHUNK_VMEM = 40 * 1024 * 1024
+
+
+def _latent_chunk_body(bt_ref, live_ref, first_ref, last_ref, q_ref, hz_ref,
+                       wk_ref, wv_ref, arena, o_ref, rowbuf, sem, key_ref,
+                       val_ref, m_ref, l_ref, acc_ref, *, sm_scale, block,
+                       tile, per_slot):
+    """One group of heads against the slot's live rows, a copy tile of
+    ``tile`` blocks at a time, the next one in flight while this one is
+    up-projected and reduced. A tile's rows ``[c | k^R | zeros]`` become,
+    ONCE for all the chunk's queries, every head's key ``[k^N_h | k^R]``
+    (``[rows, nope + rope]``: the score is one contraction over those lanes)
+    and value, kept transposed (``[value, rows]``). Then each sub-tile of
+    queries whose last horizon (``last_ref``) reaches the tile takes it head
+    by head through an online softmax that runs TRANSPOSED: the queries are
+    handed over as ``[heads, sub-tiles, nope + rope, queries]``, the scores
+    are ``[rows, queries]``, so a query's running max and sum are lanes of
+    a ``[1, queries]`` row, the reductions over a tile's rows run down the
+    sublanes, and the accumulator is ``[value, queries]``. A tile is masked
+    by ``key position < hz_ref`` only where the sub-tile's first horizon
+    (``first_ref``) does not clear it."""
+    heads, trows, width = key_ref.shape
+    nope, latent = width // 2, wv_ref.shape[2]
+    subtiles = q_ref.shape[1]
+    f32 = jnp.float32
+    dt = rowbuf.dtype
+    prec = (jax.lax.Precision.HIGHEST if dt == f32
+            else jax.lax.Precision.DEFAULT)
+    rowwise = (((1,), (1,)), ((), ()))
+    nk = pl.cdiv(live_ref[0], trows)
+    # rows a short tile leaves unwritten lie past every horizon and only
+    # have to be finite (``_paged_pipeline``)
+    rowbuf[...] = jnp.zeros_like(rowbuf)
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, f32)
+    l_ref[...] = jnp.zeros(l_ref.shape, f32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    def start(tile_no, half):
+        _start_copies(bt_ref, live_ref, (arena,), (rowbuf,), sem, 0, tile_no,
+                      half, block=block, unit=tile, per_slot=per_slot)
+
+    @pl.when(nk > 0)
+    def _():
+        start(0, 0)
+
+    def step(t, c):
+        half = t % 2
+
+        @pl.when(t + 1 < nk)
+        def _():
+            start(t + 1, 1 - half)
+
+        _wait_copies(live_ref, (rowbuf,), sem, 0, t, half, block=block,
+                     unit=tile)
+        c_kv = rowbuf[half, :, :latent]                      # [trows, latent]
+        # two heads' k^N side by side fill the lanes of one key: the even
+        # head's stays where it is, the odd one's is turned into its place,
+        # and the row's k^R, turned past them, fills the rest. (Products
+        # leave in the arena's dtype, accumulated in float32: the loops'
+        # ``_expand_rows``.)
+        shared = pltpu.roll(
+            rowbuf[half, :, latent:latent + width].astype(f32), nope, 1)
+        low = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) < nope
+        for pair in range(heads // 2):
+            both = jnp.dot(c_kv, wk_ref[pair], precision=prec,
+                           preferred_element_type=f32)       # [trows, width]
+            key_ref[2 * pair] = jnp.where(low, both, shared).astype(dt)
+            key_ref[2 * pair + 1] = jnp.where(
+                low, pltpu.roll(both, nope, 1), shared).astype(dt)
+        for h in range(heads):
+            val_ref[h] = jax.lax.dot_general(
+                wv_ref[h], c_kv, rowwise, precision=prec,
+                preferred_element_type=f32).astype(dt)       # [value, trows]
+        row0 = t * trows
+        at = row0 + jax.lax.broadcasted_iota(jnp.int32, (trows, 1), 0)
+
+        def reduce(s, masked):
+            sees = at < hz_ref[s] if masked else None        # [trows, qs]
+            for h in range(heads):
+                sc = jnp.dot(key_ref[h], q_ref[h, s], precision=prec,
+                             preferred_element_type=f32)     # [trows, qs]
+                if sm_scale != 1.0:
+                    sc = sc * sm_scale
+                if masked:
+                    sc = jnp.where(sees, sc, -1e9)
+                m = m_ref[h, s]                              # [1, qs]
+                m_new = jnp.maximum(m, jnp.max(sc, axis=0, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(sc - m_new)
+                l_ref[h, s] = l_ref[h, s] * alpha + jnp.sum(
+                    p, axis=0, keepdims=True)
+                acc_ref[h, s] = acc_ref[h, s] * alpha + jnp.dot(
+                    val_ref[h], p.astype(dt), precision=prec,
+                    preferred_element_type=f32)              # [value, qs]
+                m_ref[h, s] = m_new
+
+        def subtile(s, c):
+            reaches = last_ref[s] > row0
+            clears = first_ref[s] >= row0 + trows
+
+            @pl.when(reaches & clears)
+            def _():
+                reduce(s, False)
+
+            @pl.when(reaches & jnp.logical_not(clears))
+            def _():
+                reduce(s, True)
+
+            return c
+
+        jax.lax.fori_loop(0, subtiles, subtile, 0)
+        return c
+
+    jax.lax.fori_loop(0, nk, step, 0)
+    # a query that sees nothing (past the real ones) gives zeros
+    for s in range(subtiles):
+        real = hz_ref[s] > 0                                 # [1, qs]
+        for h in range(heads):
+            l = jnp.where(real, l_ref[h, s], 1.0)
+            o_ref[h, s] = jnp.where(real, acc_ref[h, s] / l,
+                                    0.0).astype(o_ref.dtype)
+
+
+def latent_chunk_attention(q, w_uk, w_uv, arena, rows, span, block_size,
+                           sm_scale, rope, interpret=False):
+    """``latent_chunk_expanded`` as ONE kernel: a prompt chunk's ``C``
+    queries ``[C, heads * (nope + rope)]`` over the rows that ``rows``
+    ``[L]`` (block aligned, as ``paged_attention``'s) names up to the
+    chunk's last horizon, read through the block table in copy tiles of
+    ``_LATENT_CHUNK_TILE_ROWS`` rows, double-buffered. A call serves a
+    group of ``_LATENT_CHUNK_HEADS`` heads (as many as ``_LATENT_CHUNK_VMEM``
+    takes): it walks the live tiles once, up-projects each ONCE for all the
+    chunk's queries and keeps keys, values, scores and the running max, sum
+    and accumulator in VMEM (``_latent_chunk_body``); the groups go one
+    after the other in a loop of XLA's. The mathematics and the roundings are
+    the loops': rows, keys, values and probabilities in the arena's dtype,
+    scores and sums float32, a query past the real ones gives zeros. Falls
+    back to the loops, counted, where the op names no block size, a head's
+    two key parts differ in width, Mosaic cannot tile the geometry, or
+    inside a manual region."""
+    C, L = q.shape[0], rows.shape[0]
+    heads, nope, latent = w_uk.shape
+    value, rope, bs = w_uv.shape[-1], int(rope), int(block_size or 0)
+    W, dt = arena.shape[-1], arena.dtype
+    width = nope + rope
+    lanes = 1 if interpret else 128
+    qs = max(n for n in range(1, min(C, _LATENT_CHUNK_QUERY_ROWS) + 1)
+             if C % n == 0)
+    per_slot = -(-L // max(bs, 1))
+    tile = max(1, min(per_slot, _LATENT_CHUNK_TILE_ROWS // max(bs, 1)))
+    trows = tile * bs
+    size = jnp.dtype(dt).itemsize
+
+    def scratch(group):
+        """VMEM bytes of a call that holds ``group`` heads: the two
+        buffers of its queries, output and weights, the accumulators, the
+        row tiles, their keys and values, and a sub-tile's scores."""
+        return (2 * C * group * (width + value) * size
+                + group * C * (value + 16) * 4
+                + 2 * group * (nope + value) * latent * size
+                + (2 * W + group * (width + value)) * trows * size
+                + 4 * trows * (3 * qs + 2 * width))
+
+    group = max([n for n in range(2, _LATENT_CHUNK_HEADS + 1, 2)
+                 if heads % n == 0 and scratch(n) <= _LATENT_CHUNK_VMEM],
+                default=0)
+    if vma_names(q) or not bs or not group or nope != rope or (
+            not interpret and not (
+                _mosaic_tiles(bs, W, dt) and qs % lanes == 0
+                and width % lanes == 0 and value % lanes == 0
+                and latent % lanes == 0)):
+        fallback_counter().inc()
+        return latent_chunk_expanded(q, w_uk, w_uv, arena, rows, span,
+                                     sm_scale, rope,
+                                     tile_rows=_EXPAND_TILE_ROWS)
+    latent_chunk_counter().inc()
+    subtiles, groups = C // qs, heads // group
+    table = (rows[::bs] // bs).astype(jnp.int32)
+    horizon = chunk_horizon(span, C, L).reshape(subtiles, qs)
+    scalars = (table, jnp.max(horizon).reshape(1), horizon[:, 0],
+               jnp.max(horizon, axis=-1))
+    whole = lambda *shape: pl.BlockSpec(                     # noqa: E731
+        shape, lambda g, *_: (0,) * len(shape))
+    call = pl.pallas_call(
+        functools.partial(_latent_chunk_body, sm_scale=sm_scale, block=bs,
+                          tile=tile, per_slot=per_slot),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(1,),
+            in_specs=[
+                whole(group, subtiles, width, qs),
+                whole(subtiles, 1, qs),
+                whole(group // 2, latent, width),
+                whole(group, value, latent),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=whole(group, subtiles, value, qs),
+            scratch_shapes=[
+                pltpu.VMEM((2, trows, W), dt),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.VMEM((group, trows, width), dt),
+                pltpu.VMEM((group, value, trows), dt),
+                pltpu.VMEM((group, subtiles, 1, qs), jnp.float32),
+                pltpu.VMEM((group, subtiles, 1, qs), jnp.float32),
+                pltpu.VMEM((group, subtiles, value, qs), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((group, subtiles, value, qs), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
+        interpret=interpret,
+        name=LATENT_CHUNK_KERNEL,
+    )
+    # [groups, group heads, sub-tiles, nope + rope, queries]
+    turned = jnp.transpose(
+        q.astype(dt).reshape(subtiles, qs, groups, group, width),
+        (2, 3, 0, 4, 1))
+    # a pair of heads' W_UK side by side: [latent, 2 * nope] a pair
+    pairs = jnp.swapaxes(w_uk.astype(dt).reshape(
+        groups, group // 2, width, latent), 2, 3)
+    values = jnp.swapaxes(w_uv.astype(dt), 1, 2).reshape(
+        groups, group, value, latent)
+    hz = horizon.reshape(subtiles, 1, qs)
+
+    def of_group(g, out):
+        return out.at[g].set(call(*scalars, turned[g], hz, pairs[g],
+                                  values[g], arena))
+
+    # ONE call a group of heads, in a loop of XLA's and not in the kernel's
+    # grid, for what stands AROUND the call in a chunk program: XLA keeps
+    # nothing in VMEM across a loop, and across a lone custom call it kept
+    # enough there that the next sub-layer's largest intermediates fell out
+    # of it (the chunk launch lost what the kernel won: PERF.md section 6,
+    # PR 57)
+    out = jnp.zeros((groups, group, subtiles, value, qs), q.dtype)
+    out = (of_group(0, out) if groups == 1
+           else jax.lax.fori_loop(0, groups, of_group, out))
+    return jnp.transpose(out, (2, 4, 0, 1, 3)).reshape(C, heads * value)

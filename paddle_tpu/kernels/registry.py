@@ -48,7 +48,7 @@ __all__ = [
     "KernelSpec", "register", "get", "all_specs", "has",
     "mode", "resolved_mode", "selected", "probe", "scoped_mode",
     "kernel_sig", "registry_fingerprint", "fallback_counter",
-    "flash_grid_metrics", "flash_grid_snapshot", "FLASH_KERNELS", "MODE_ENV",
+    "latent_chunk_counter", "flash_grid_metrics", "flash_grid_snapshot", "FLASH_KERNELS", "MODE_ENV",
 ]
 
 MODE_ENV = "PADDLE_TPU_KERNELS"
@@ -201,6 +201,20 @@ def fallback_counter():
         "kernel_fallbacks_total",
         "kernel-eligible ops that ran the composite fallback "
         "(untileable/VMEM-oversized geometry or manual-mesh region)",
+    )
+
+
+def latent_chunk_counter():
+    """``latent_chunk_kernel_lowerings_total``: lowered calls of the latent
+    chunk kernel (kernels/attention.py ``latent_chunk_attention``), beside
+    the fallbacks: one a layer of a compiled chunk program of a latent
+    model, none where the loops serve."""
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    return obs_metrics.registry().counter(
+        "latent_chunk_kernel_lowerings_total",
+        "lowered calls of the latent chunk attention kernel (the expanded "
+        "form of a prompt chunk's latent attention as one Pallas kernel)",
     )
 
 

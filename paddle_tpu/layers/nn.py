@@ -945,22 +945,26 @@ def paged_latent_attention(q, arena, rows, attn_bias, seqs, length, heads,
 
 
 def chunk_latent_attention(q, arena, rows, span, heads, nope, rope, value,
-                           latent, param_attrs, sm_scale=1.0, name=None):
+                           latent, param_attrs, sm_scale=1.0,
+                           block_size=None, name=None):
     """A prompt chunk's latent attention (ops/nn.py
     ``chunk_latent_attention``): ``q`` ``[C, heads * (nope + rope)]`` over
     ONE sequence's rows of the latent arena under the mask of ``span``,
-    expanded (kernels/attention.py ``latent_chunk_expanded``). Returns
-    ``[C, heads * value]``."""
+    expanded (kernels/attention.py ``latent_chunk_expanded``; with
+    ``block_size``, the arena's, the ``latent_chunk_attention`` kernel can
+    walk the slot's block table). Returns ``[C, heads * value]``."""
     helper = LayerHelper("chunk_latent_attention", name=name)
     w_uk, w_uv = _latent_weights(helper, heads, nope, value, latent,
                                  param_attrs, q.dtype)
     out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {"sm_scale": float(sm_scale), "rope": int(rope)}
+    if block_size:
+        attrs["block_size"] = int(block_size)
     helper.append_op(
         "chunk_latent_attention",
         {"Q": [q.name], "WUK": [w_uk.name], "WUV": [w_uv.name],
          "Arena": [arena.name], "Rows": [rows.name], "Span": [span.name]},
-        {"Out": [out.name]},
-        {"sm_scale": float(sm_scale), "rope": int(rope)})
+        {"Out": [out.name]}, attrs)
     return out
 
 

@@ -861,37 +861,49 @@ OpRegistry.register(
 )
 
 
-def _chunk_latent_attention(ins, attrs, tile_rows):
+def _chunk_latent_operands(ins):
+    return [first(ins, slot)
+            for slot in ("Q", "WUK", "WUV", "Arena", "Rows", "Span")]
+
+
+def _chunk_latent_attention(ins, attrs):
+    """THE definition: the dense expanded composite, every row and every
+    query at once."""
     from paddle_tpu.kernels import attention as fused
 
     return {"Out": [fused.latent_chunk_expanded(
-        first(ins, "Q"), first(ins, "WUK"), first(ins, "WUV"),
-        first(ins, "Arena"), first(ins, "Rows"), first(ins, "Span"),
-        attrs.get("sm_scale", 1.0), attrs["rope"], tile_rows=tile_rows)]}
+        *_chunk_latent_operands(ins), attrs.get("sm_scale", 1.0),
+        attrs["rope"])]}
 
 
-def _chunk_latent_tiled(ins, attrs):
-    """Where kernels serve the program (any mode but ``off``) the chunk's
-    queries and the rows they see go a tile at a time
-    (kernels/attention.py ``latent_chunk_expanded``: XLA's loops, no kernel
-    of this repo's); ``off`` runs the dense definition."""
+def _chunk_latent_pallas(ins, attrs):
+    """Where kernels serve the program (any mode but ``off``) the chunk runs
+    kernels/attention.py ``latent_chunk_attention``: a Pallas kernel that
+    up-projects a tile of the slot's live rows once for all the chunk's
+    queries, or, for a shape it refuses (counted), its fallback, the same
+    form by XLA's loops over tiles of queries and of rows; ``off`` runs the
+    dense definition."""
     from paddle_tpu import kernels
     from paddle_tpu.kernels import attention as fused
 
-    tiled = kernels.resolved_mode() != "off"
-    return _chunk_latent_attention(
-        ins, attrs, fused._EXPAND_TILE_ROWS if tiled else None)
+    sel = kernels.selected("latent_chunk_attention")
+    if sel is None:
+        return _chunk_latent_attention(ins, attrs)
+    return {"Out": [fused.latent_chunk_attention(
+        *_chunk_latent_operands(ins), attrs.get("block_size"),
+        attrs.get("sm_scale", 1.0), attrs["rope"],
+        interpret=sel.interpret)]}
 
 
 # a prompt chunk's queries over one sequence's rows of a latent arena,
 # EXPANDED: every row a query sees up-projected to its heads' keys and
 # values (the step attends absorbed, above; the count and the chip both
-# favour expanded for a chunk: kernels/attention.py ``_EXPAND_QUERY_TILE``)
+# favour expanded for a chunk: PERF.md section 6, PR 56)
 OpRegistry.register(
     OpDef(
         "chunk_latent_attention",
-        lambda ins, attrs: _chunk_latent_attention(ins, attrs, None),
-        pallas=_chunk_latent_tiled,
+        _chunk_latent_attention,
+        pallas=_chunk_latent_pallas,
         nondiff_inputs=("Rows", "Span"),
     )
 )

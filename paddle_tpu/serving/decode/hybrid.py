@@ -115,7 +115,8 @@ tiles (384 lanes for 256 + 64: an array's minor dimension is tiled by 128 on
 the chip whatever its declared width, so a 320-lane row would take the same
 bytes). The step attends ABSORBED (``W_UK`` moved onto the query, ``W_UV``
 onto the context: all heads read the one row), a chunk EXPANDED
-(``kernels/attention.py latent_chunk_expanded``). The rotation is over the
+(``kernels/attention.py latent_chunk_attention``, one kernel; its fallback
+the same form by XLA's loops, ``latent_chunk_expanded``). The rotation is over the
 ``rope`` lanes of the query's heads and of ``k^R``, INTERLEAVED pairs ``(2 j,
 2 j + 1)`` as published (``rope_interleave``), by a YaRN frequency table
 (``yarn_frequencies``: the base's frequencies blended with the base's /
@@ -439,7 +440,8 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
             arena, = parts.write(chunk, i, cwrows, 0, row)
             ctx = fluid.layers.chunk_latent_attention(
                 fluid.layers.squeeze(q, [0]), arena, crows, cspan,
-                param_attrs=weights, sm_scale=sm_scale, **latent)
+                param_attrs=weights, sm_scale=sm_scale, block_size=BS,
+                **latent)
             return fluid.layers.unsqueeze(ctx, [0])
 
         chu_logits, _ = stack(

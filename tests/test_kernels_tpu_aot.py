@@ -131,6 +131,36 @@ def test_paged_kernel_serves_one_latent_arena(v5e_devices):
     assert "latent_paged_attention" in text
 
 
+@pytest.mark.parametrize("chunk", [1024, 512])
+def test_latent_chunk_kernel_serves_the_cells_chunk(chunk, v5e_devices):
+    """mistral_small_4_119b's chunk of 1,024 queries, and a prompt's short
+    last launch of 512 (32 heads of 64 + 64 | 128 over a latent of 256, a
+    slot of 33,280 positions in blocks of 16 rows of 384 lanes), compiles
+    as ONE call named ``latent_chunk_attention`` (8 heads a call) in ONE
+    loop over the four groups of heads, counted a lowered call and no
+    fallback, and makes nothing of a chunk's expanded size in HBM:
+    beside its arguments and its output the executable keeps the queries
+    and the output turned (8 MB each at 1,024 queries) and little else (a
+    slot's expanded keys and values would be 537 MB)."""
+    from paddle_tpu.kernels import attention as A
+    from paddle_tpu.kernels import registry
+
+    (case,) = [c for c in kernels.get("latent_chunk_attention").tpu_cases()
+               if c[2][0][0][0] == chunk]
+    _label, fn, arg_specs = case
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    served, fell = registry.latent_chunk_counter(), kernels.fallback_counter()
+    before = served.value, fell.value
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert (served.value - before[0], fell.value - before[1]) == (1, 0)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert A.LATENT_CHUNK_KERNEL in text and text.count(" while(") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 24 << 20
+
+
 #: the flash cases' geometries (``_tpu_cases_flash``'s labels): B*H, S and
 #: the (batch, head) pairs a grid step of the forward, dk/dv and dq kernels
 #: serves there — the largest divisor of B*H a 4 MiB set of blocks holds

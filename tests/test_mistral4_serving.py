@@ -16,6 +16,7 @@ row, and ``_choose_token`` is where each delivered row passes.
 
 import math
 import os
+import re
 import sys
 
 import numpy as np
@@ -166,6 +167,31 @@ def test_float32_build_gives_the_references_logits(exact):
     # product's rule: every real token for every held expert
     assert stats["moe_grouped_rows"] == sum(PROMPT_LENS) * layers * 4
     assert 0 < stats["moe_grouped_experts"] <= stats["chunk_runs"] * layers * 4
+
+
+def test_float32_build_served_by_the_kernels_gives_the_references_logits():
+    """The same prompts with the kernels serving (interpreted): every chunk
+    through ``latent_chunk_attention`` (a lowered call a layer of the ONE
+    chunk program, no fallback among them), every step through the paged
+    kernel handed one arena; the rows stay inside the exact band."""
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import registry
+
+    served, fell = registry.latent_chunk_counter(), kernels.fallback_counter()
+    before = served.value, fell.value
+    with kernels.scoped_mode("interpret"):
+        engine, entry = _engine(_model(name="mistral4_kernels"))
+        try:
+            rows = _record_rows(entry)
+            prompts = _prompts()
+            sampled = _serve_sampled(engine, prompts)
+        finally:
+            engine.shutdown()
+    assert served.value - before[0] == CONFIG["num_hidden_layers"]
+    assert fell.value == before[1]
+    errors = _row_errors(entry, prompts, sampled, rows, offset=4)
+    assert errors.max() < EXACT_BAND, errors.max()
+    assert entry.stats()["prefills"] == 0
 
 
 def test_bfloat16_build_is_inside_its_band_and_outside_the_exact_one():
@@ -380,35 +406,40 @@ def _latent_case(rng, heads, nope, rope, value, latent, L, bs, queries):
     return q, w_uk, w_uv, jnp.asarray(arena), rows
 
 
-def test_the_chunk_op_is_the_expanded_form_dense_or_by_tiles():
+def test_the_chunk_op_is_the_expanded_form_dense_kernel_or_loops():
     """``chunk_latent_attention`` has ONE form: its definition is the dense
-    expanded composite, and where kernels serve the program the same form
-    by tiles of queries and of rows (XLA's loops: the chunk program holds
-    no custom call for it)."""
+    expanded composite; where kernels serve the program the same form
+    through the ``latent_chunk_attention`` kernel (counted a lowered call),
+    and through its fallback, XLA's loops by tiles of queries and of rows
+    (counted a fallback), for an op that names no block size."""
     from paddle_tpu import kernels
+    from paddle_tpu.kernels import registry
 
     rng = np.random.RandomState(6)
     q, w_uk, w_uv, arena, rows = _latent_case(rng, 4, 8, 8, 16, 32, 320, 16,
                                               32)
     op = OpRegistry.get("chunk_latent_attention")
+    served, fell = registry.latent_chunk_counter(), kernels.fallback_counter()
     for start, real in ((0, 32), (224, 31)):
         ins = {"Q": [q], "WUK": [w_uk], "WUV": [w_uv], "Arena": [arena],
                "Rows": [rows], "Span": [np.array([start, real], "int32")]}
-        attrs = {"sm_scale": 0.2, "rope": 8}
+        attrs = {"sm_scale": 0.2, "rope": 8, "block_size": 16}
         want = np.asarray(attention.latent_chunk_expanded(
             q, w_uk, w_uv, arena, rows, ins["Span"][0], 0.2, 8))
         np.testing.assert_array_equal(op.lower(ins, attrs)["Out"][0], want)
         with kernels.scoped_mode("off"):
             off = op.lowering(True)(ins, attrs)["Out"][0]
         np.testing.assert_array_equal(off, want)
-        with kernels.scoped_mode("interpret"):
-            text = jax.jit(lambda *a: op.lowering(True)(
-                dict(ins, Q=[a[0]]), attrs)["Out"][0]).lower(q).as_text()
-            tiled = np.asarray(op.lowering(True)(ins, attrs)["Out"][0])
-        assert "while" in text and "custom_call" not in text
-        np.testing.assert_allclose(tiled[:real], want[:real], rtol=2e-5,
-                                   atol=2e-5)
-        assert not tiled[real:].any()
+        for attrs, counted in ((attrs, served),
+                               ({"sm_scale": 0.2, "rope": 8}, fell)):
+            before = served.value, fell.value
+            with kernels.scoped_mode("interpret"):
+                got = np.asarray(op.lowering(True)(ins, attrs)["Out"][0])
+            assert (served.value - before[0], fell.value - before[1]) == (
+                (1, 0) if counted is served else (0, 1))
+            np.testing.assert_allclose(got[:real], want[:real], rtol=2e-5,
+                                       atol=2e-5)
+            assert not got[real:].any()
     assert attention._EXPAND_QUERY_TILE == attention._EXPAND_TILE_ROWS == 512
 
 
@@ -441,37 +472,86 @@ def test_expanded_by_tiles_of_queries_is_the_dense_expanded():
 
 def test_every_loop_of_the_chunk_program_is_its_expanded_attention(
         monkeypatch):
-    """What ``latent_attention_device_share`` and
-    ``latent_chunk_attention_roofline`` rest on: their readers match device
-    events by NAME (``^%?while``: no scope reaches an event's name, and a
-    loop's carried shapes would tie the metric to a tile size), so every
-    ``while`` of the chunk program has to be the expanded attention's, one
-    a tile of queries a layer, and the step program may hold none. With
-    the composites everywhere the step program holds no loop and the chunk
-    program one a layer; the chunk op alone lowers to exactly its query
-    tiles' loops (one, dense); what the grouped
-    product does outside its kernel (the pairs' sort, gather and scatter)
-    to none. A loop added to either program turns this red before it is
-    counted as attention."""
+    """What the latent attention's readings rest on: their readers match
+    device events by NAME. Where the kernel serves (the programs lowered for
+    the chip, at widths Mosaic tiles) the step program holds no ``while``
+    and the chunk program ONE a layer, the loop over its groups of heads,
+    whose body holds the one call named ``latent_chunk_attention``, counted
+    in ``latent_chunk_kernel_lowerings_total`` and no fallback:
+    ``latent_chunk_kernel_roofline`` and ``..._device_share`` find the
+    calls by that name, ``latent_chunk_attention_roofline`` and
+    ``latent_attention_device_share`` (``^%?while``) the loops around them.
+    With the composites everywhere (``off``) the step program holds no loop
+    and the chunk program one a layer, the dense definition; the fallback's
+    loops are one a tile of queries; what the grouped product does outside
+    its kernel (the pairs' sort, gather and scatter) holds none. A loop
+    added to either program turns this red before it is counted as
+    attention."""
     import paddle_tpu as fluid
     from paddle_tpu import kernels
+    from paddle_tpu.core import lowering
+    from paddle_tpu.kernels import registry
     from paddle_tpu.utils import hlo
 
     loops = lambda text: text.count("stablehlo.while")  # noqa: E731
+
+    def lowered(m, scope, platform=None):
+        """The step and the chunk program's StableHLO (for ``platform``: no
+        compiler of the chip's is needed to LOWER for it)."""
+        for program, sig, fetch in (
+                (m.decode_program, m.decode_feed_sig(), m.counts_fetch),
+                (m.chunk_program, m.chunk_feed_sig(), m.chunk_logits_fetch)):
+            entry, _src = lowering.lower_step(
+                program, scope, tuple((n, shape, str(np.dtype(dtype)))
+                                      for n, shape, dtype in sorted(sig)),
+                [fetch], donate=True, use_cache=False, persist=False,
+                label="hlo")
+            shapes = lambda names: tuple(  # noqa: E731
+                hlo._sds_of(scope.find_var(n)) for n in names)
+            yield entry.fn.trace(
+                tuple(jax.ShapeDtypeStruct(shape, np.dtype(dtype))
+                      for _n, shape, dtype in sorted(sig)),
+                shapes(entry.donated), shapes(entry.readonly),
+                hlo._sds_of(jax.random.PRNGKey(0))).lower(
+                    lowering_platforms=platform and (platform,)).as_text()
+
     m = _model(name="loops")
     scope = fluid.Scope()
     with fluid.scope_guard(scope), kernels.scoped_mode("off"):
         fluid.Executor(fluid.CPUPlace()).run(m.startup_program)
-        for program, sig, fetch in (
-                (m.decode_program, m.decode_feed_sig(), m.counts_fetch),
-                (m.chunk_program, m.chunk_feed_sig(), m.chunk_logits_fetch)):
-            feed = {n: np.zeros(shape, dtype) for n, shape, dtype in sig}
-            # (the dense definition is the same loop at one trip: one a
-            # layer, and the op alone accounts for it, below)
-            assert loops(hlo.lower_program_step(
-                program, feed, [fetch], scope=scope).as_text()) == (
-                    CONFIG["num_hidden_layers"]
-                    if program is m.chunk_program else 0)
+        # (the dense definition is the fallback's loop at one trip: one a
+        # layer, and the op alone accounts for it, below)
+        step, chunk = lowered(m, scope)
+        assert (loops(step), loops(chunk)) == (
+            0, CONFIG["num_hidden_layers"])
+    # widths Mosaic tiles: 16 heads (two groups of 8) of 64 + 64 | 128
+    # over a latent of 128, blocks of 16 rows, a chunk of 128 (a sub-tile's
+    # queries are lanes)
+    wide = dict(CONFIG, hidden_size=128, num_attention_heads=16,
+                kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+                v_head_dim=128, moe_intermediate_size=128)
+    m = build_latent_moe_model(
+        **wide, router_experts=ROUTER, slots=4, max_len=256, block_size=16,
+        chunk_tokens=128, num_blocks=70, dtype="bfloat16", name="served",
+        initializer_range=0.3)
+    scope = fluid.Scope()
+    served, fell = registry.latent_chunk_counter(), kernels.fallback_counter()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(m.startup_program)
+        monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+        programs = lowered(m, scope, "tpu")
+        step = next(programs)
+        before = served.value, fell.value
+        chunk = next(programs)
+        monkeypatch.undo()
+    layers = wide["num_hidden_layers"]
+    assert (loops(step), loops(chunk)) == (0, layers)
+    names = lambda text: re.findall(  # noqa: E731
+        r'kernel_name = "(\w+)"', text)
+    assert names(step) == [attention.LATENT_STEP_KERNEL] * layers
+    assert names(chunk) == [attention.LATENT_CHUNK_KERNEL] * layers
+    assert attention.LATENT_CHUNK_KERNEL == "latent_chunk_attention"
+    assert (served.value - before[0], fell.value - before[1]) == (layers, 0)
     rng = np.random.RandomState(8)
     q, w_uk, w_uv, arena, rows = _latent_case(rng, 4, 8, 8, 16, 32, 320, 16,
                                               32)
@@ -496,13 +576,38 @@ def test_every_loop_of_the_chunk_program_is_its_expanded_attention(
         idx, w, mask, np.zeros((n, 16), "float32")).as_text()) == 0
 
 
+@pytest.mark.parametrize("family", [
+    "nemotron_h", "lfm2", "ouro", "sdar", "granite_hybrid"])
+def test_no_other_familys_program_holds_a_latent_op(family):
+    """Who else runs the chunk's kernel: nobody. The latent ops (the one
+    whose written form PR 57 changed: ``chunk_latent_attention`` names its
+    ``block_size``; and the step's) are written by ``_hybrid_model(latent=)``
+    alone, so the other five serving families' programs, at their tests'
+    sizes, hold neither and are what they were (their ``to_bytes`` equal
+    the parent commit's: PERF.md section 6, PR 57, compared by hand)."""
+    import importlib
+
+    m = importlib.import_module(f"test_{family}_serving")._model()
+    for program in (m.decode_program, m.chunk_program, m.startup_program):
+        types = {op.type for block in program.blocks for op in block.ops}
+        assert not types & {"chunk_latent_attention",
+                            "paged_latent_attention"}
+    mine = _model(name="census")
+    ops = [op for op in mine.chunk_program.global_block().ops
+           if op.type == "chunk_latent_attention"]
+    assert len(ops) == CONFIG["num_hidden_layers"]
+    assert all(op.attrs["block_size"] == GEOMETRY["block_size"]
+               for op in ops)
+
+
 @pytest.mark.parametrize("start,real", [(0, 32), (0, 7), (224, 31),
                                         (288, 32)])
 def test_absorbed_is_expanded(start, real):
     """The same numbers both ways: a chunk's queries EXPANDED (the chunk
-    op's form) and the same queries as decode slots of one sequence,
-    ABSORBED (the step op's form), through the composite and through the
-    kernel handed one arena (interpreted)."""
+    op's form: the dense definition, and the ``latent_chunk_attention``
+    kernel that serves, interpreted) and the same queries as decode slots
+    of one sequence, ABSORBED (the step op's form), through the composite
+    and through the kernel handed one arena (interpreted)."""
     from paddle_tpu import kernels
 
     rng = np.random.RandomState(3)
@@ -526,8 +631,17 @@ def test_absorbed_is_expanded(start, real):
     with kernels.scoped_mode("interpret"):
         kernel = step.lowering(True)(ins, attrs)["Out"][0]
     assert kernels.fallback_counter().value == before
+    chunk = OpRegistry.get("chunk_latent_attention")
+    with kernels.scoped_mode("interpret"):
+        served = chunk.lowering(True)(
+            {"Q": [q], "WUK": [w_uk], "WUV": [w_uv], "Arena": [arena],
+             "Rows": [rows], "Span": [span]},
+            {"sm_scale": 0.2, "rope": rope, "block_size": bs})["Out"][0]
+    assert kernels.fallback_counter().value == before
+    np.testing.assert_allclose(served[:real], want, rtol=2e-5, atol=2e-5)
     for got in (step.lower(ins, attrs)["Out"][0], kernel):
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(got, served[:real], rtol=2e-5, atol=2e-5)
 
 
 # -- the grouped expert product ----------------------------------------------

@@ -4,7 +4,7 @@ chip-free compile gives (PERF.md section 6, PR 56).
 
     python3 tools/check_latent_attention.py [--seed <n>] [--repeats 8]
         [--parts chunk,step,grouped] [--grouped-tokens 256,384,512]
-        [--rehearse-cpu]
+        [--kernel-tiles 512x512,256x256] [--rehearse-cpu]
 
 At ``mistral_small_4_119b``'s geometry (32 heads of 64 + 64 | 128 over a
 latent of 256, rows of 384 lanes, block 16, slots of 33,280 positions, 16
@@ -12,11 +12,13 @@ held gated experts of width 2,048 at hidden 4,096, 4 of 128 chosen),
 bfloat16, under a shuffled block table:
 
 * *chunk*: a prompt chunk of 512, 1,024 and 2,048 queries behind 0, 8,192
-  and 30,720 rows, EXPANDED by the loops over live rows, 512 queries at a
-  time (``kernels/attention.py latent_chunk_expanded``), a call's device
-  time beside what its operations take at the chip's peak: what
-  ``_EXPAND_QUERY_TILE`` is held to (the absorbed form through the chunk
-  kernel was read beside it once, 1.75 x slower, and is gone: PERF.md);
+  and 30,720 rows, EXPANDED by the ``latent_chunk_attention`` kernel (what
+  serves) and by the loops over live rows, 512 queries at a time (its
+  fallback, ``kernels/attention.py latent_chunk_expanded``): a call's
+  device time each, beside what its operations take at the chip's peak,
+  and the two's largest difference over the loops' largest value (the
+  absorbed form through the chunk kernel was read beside the loops once,
+  1.75 x slower, and is gone: PERF.md);
 * *step*: 16 slots at 8,192 and at 33,280 positions through the step
   kernel handed one arena, a call's time beside the time its rows' 640 B a
   token take at the chip's bytes/s;
@@ -101,18 +103,39 @@ def _chunk(args, report, geometry, timed):
     w_uk, w_uv = (draw(latent ** -0.5, heads, nope, latent),
                   draw(latent ** -0.5, heads, latent, value))
     out = []
+    TILES = (A._LATENT_CHUNK_QUERY_ROWS, A._LATENT_CHUNK_TILE_ROWS)
     chunks = (32,) if args.rehearse_cpu else (512, 1024, 2048)
     starts = ((0, 224) if args.rehearse_cpu else (8192,) if args.quick
               else (0, 8192, 30720))
     tile = 16 if args.rehearse_cpu else A._EXPAND_TILE_ROWS
+    interpret = True if args.rehearse_cpu else False
+    sweep = [tuple(int(n) for n in pair.split("x"))
+             for pair in args.kernel_tiles.split(",") if pair]
     for C in chunks:
         q = draw(2.0, C, heads * (nope + rope))
         fn = lambda q, span: A.latent_chunk_expanded(  # noqa: E731
             q, w_uk, w_uv, arena, rows, span, SCALE, rope, tile_rows=tile)
+        kernel = lambda q, span: A.latent_chunk_attention(  # noqa: E731
+            q, w_uk, w_uv, arena, rows, span, bs, SCALE, rope,
+            interpret=interpret)
         timer = _timer(fn, args.repeats, timed)
-        for start in starts:
+        kernel_timer = _timer(kernel, args.repeats, timed)
+        # the kernel at other (queries a sub-tile) x (rows a tile): each
+        # traced under its own sizes, which the body reads at lowering
+        swept = {}
+        for qs, tr in sweep:
+            A._LATENT_CHUNK_QUERY_ROWS, A._LATENT_CHUNK_TILE_ROWS = qs, tr
+            try:
+                at = _timer(kernel, args.repeats, timed)
+                swept[f"{qs}x{tr}"] = [
+                    at(q, jnp.asarray([start, C], jnp.int32))
+                    for start in starts]
+            finally:
+                A._LATENT_CHUNK_QUERY_ROWS, A._LATENT_CHUNK_TILE_ROWS = TILES
+        for n, start in enumerate(starts):
             span = jnp.asarray([start, C], jnp.int32)
             got = np.asarray(jax.jit(fn)(q, span), np.float32)
+            served = np.asarray(jax.jit(kernel)(q, span), np.float32)
             dense = np.asarray(A.latent_chunk_expanded(
                 q, w_uk, w_uv, arena, rows, span, SCALE, rope),
                 np.float32) if args.rehearse_cpu else None
@@ -121,8 +144,12 @@ def _chunk(args, report, geometry, timed):
                 value, latent, 2)
             out.append({
                 "chunk": C, "start": start, "expanded_ms": timer(q, span),
+                "kernel_ms": kernel_timer(q, span),
+                "kernel_ms_at": {k: v[n] for k, v in swept.items()},
                 "expanded_gflop": ops / 1e9,
                 "ms_at_197_TFLOPs": 1e3 * ops / 197e12,
+                "kernel_from_expanded": float(
+                    np.abs(served - got).max() / np.abs(got).max()),
                 "from_dense": None if dense is None else float(
                     np.abs(got - dense).max() / np.abs(dense).max())})
     report["chunk"] = out
@@ -219,6 +246,9 @@ def main(argv=None):
     ap.add_argument("--parts", default="chunk,step,grouped")
     ap.add_argument("--grouped-tokens", default="", help="the grouped "
                     "product at these token counts, comma-separated")
+    ap.add_argument("--kernel-tiles", default="", help="the chunk kernel "
+                    "also at these (queries a sub-tile)x(rows a tile), e.g. "
+                    "512x512,256x256: a tuning sweep")
     ap.add_argument("--rehearse-cpu", action="store_true")
     args = ap.parse_args(argv)
 
