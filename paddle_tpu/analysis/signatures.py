@@ -83,6 +83,8 @@ _SIGNATURES = {
         ranks={"Packed": 2, "Token": 2},
         dtype_family={"Packed": "int", "Token": "int"},
     ),
+    "paged_window_feeds": OpSignature(
+        ranks={"Packed": 2}, dtype_family={"Packed": "int"}),
     "paged_block_feeds": OpSignature(
         ranks={"Packed": 2, "State": 2},
         dtype_family={"Packed": "int", "State": "int"},

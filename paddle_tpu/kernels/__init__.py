@@ -408,7 +408,7 @@ def _chunk_case(rng, C, L, bs, G, per, D, dtype="float32"):
 
 
 @functools.lru_cache(maxsize=None)
-def _chunk_both(bs, G, D, block_len):
+def _chunk_both(bs, G, D, block_len, window=0):
     """(kernel through the interpreter, composite under the rule's bias),
     jitted once a geometry: the chunk's span is an argument."""
     import jax
@@ -417,15 +417,16 @@ def _chunk_both(bs, G, D, block_len):
 
     sm = 1.0 / float(np.sqrt(D))
     return (jax.jit(lambda *a: A.chunk_attention(
-                *a, bs, sm, G, block_len=block_len, interpret=True)),
+                *a, bs, sm, G, block_len=block_len, interpret=True,
+                window=window)),
             jax.jit(lambda *a: A.chunk_attention_by_span(
-                *a, sm, G, block_len)))
+                *a, sm, G, block_len, window)))
 
 
-def _assert_chunk_parity(args, start, real, bs, G, D, block_len=1):
+def _assert_chunk_parity(args, start, real, bs, G, D, block_len=1, window=0):
     """The real queries within 2e-5 both ways of the composite under the
     rule's bias; a query past them reads nothing and gives zeros."""
-    kernel, composite = _chunk_both(bs, G, D, block_len)
+    kernel, composite = _chunk_both(bs, G, D, block_len, window)
     span = np.array([start, real], "int32")
     got = np.asarray(kernel(*args, span))
     ref = np.asarray(composite(*args, span))
@@ -447,6 +448,12 @@ def _parity_chunk_attention(rng):
     args = _chunk_case(rng, 32, 320, 16, 4, 8, 128)
     for start, real in ((0, 28), (32, 32), (252, 8)):
         _assert_chunk_parity(args, start, real, 16, 4, 128, block_len=4)
+    # under a window (trinity_large_preview's 8 x 6 x 128): the edge inside
+    # the chunk, a block and a copy tile behind it, and past everything
+    args = _chunk_case(rng, 32, 320, 16, 8, 6, 128)
+    for start, real, window in ((0, 32, 20), (224, 31, 48), (288, 32, 272),
+                                (288, 17, 1000)):
+        _assert_chunk_parity(args, start, real, 16, 8, 128, window=window)
 
 
 def _tpu_cases_chunk_attention():
@@ -458,21 +465,27 @@ def _tpu_cases_chunk_attention():
     block mask)."""
     from paddle_tpu.kernels import attention as A
 
-    def case(C, L, bs, G, per, D, block_len=1):
+    def case(C, L, bs, G, per, D, block_len=1, window=0):
         R = 4 * L
 
         def fwd(q, k, v, rows, span):
             return A.chunk_attention(q, k, v, rows, span, bs,
                                      1.0 / float(np.sqrt(D)), G,
-                                     block_len=block_len)
+                                     block_len=block_len, window=window)
 
-        return (f"c{C}_l{L}_b{bs}_g{G}x{per}x{D}_m{block_len}_bf16", fwd, [
+        return (f"c{C}_l{L}_b{bs}_g{G}x{per}x{D}_m{block_len}"
+                + (f"_w{window}" if window else "") + "_bf16", fwd, [
             ((C, G * per * D), "bfloat16"), ((R, G * D), "bfloat16"),
             ((R, G * D), "bfloat16"), ((L,), "int32"), ((2,), "int32")])
 
+    # trinity_large_preview (8 x 6 x 128, a chunk of 1,024): the full
+    # layer over 33,792 rows, a sliding one over the 321 blocks its window
+    # group's row map holds, under the window
     return [case(512, 16896, 16, 8, 4, 64), case(512, 4096, 16, 2, 16, 128),
             case(512, 4096, 16, 16, 1, 128),
-            case(512, 4096, 16, 4, 8, 128, block_len=4)]
+            case(512, 4096, 16, 4, 8, 128, block_len=4),
+            case(1024, 33792, 16, 8, 6, 128),
+            case(1024, 5136, 16, 8, 6, 128, window=4096)]
 
 
 def _latent_chunk_case(rng, heads, nope, rope, value, latent, L, bs, C,
@@ -549,8 +562,9 @@ def _tpu_cases_latent_chunk():
 
 
 def _parity_moe_experts(rng):
-    """Held experts some of which no token chose, a masked token, and the
-    step in which none is touched."""
+    """Held experts some of which no token chose, a masked token, the
+    step in which none is touched, and a step whose tokens are no whole
+    sublane tiles (padded inside)."""
     import jax
     import jax.numpy as jnp
 
@@ -575,6 +589,11 @@ def _parity_moe_experts(rng):
             1e-5, 1e-5)
     assert untouched, "no case left a held expert untouched"
     assert not np.asarray(run(x, jnp.zeros((T, E)), w_up, w_down)).any()
+    odd = run(x[:11], c[:11], w_up, w_down)
+    assert odd.shape == (11, H)
+    _assert_close_both_ways(
+        odd, moe.experts_composite(x[:11], c[:11], w_up, w_down),
+        "moe_experts (11 tokens)", 1e-5, 1e-5)
     # gated experts: a third matrix and its own accumulator
     H2 = 256
     x2 = jnp.asarray(rng.randn(T, H2).astype("float32"))
@@ -590,9 +609,11 @@ def _tpu_cases_moe_experts():
     """The hybrid serving cells' expert layers in bfloat16:
     nemotron3_nano_30b_a3b (a step's 32 tokens, 16 held relu2 experts of
     width 1,856 at hidden 2,688), lfm2_24b_a2b (128 tokens, 8 held gated
-    experts of width 1,536 at hidden 2,048) and sdar_30b_a3b (a block
+    experts of width 1,536 at hidden 2,048), sdar_30b_a3b (a block
     pass's 32 x 4 tokens, 16 held gated experts of width 768 at hidden
-    2,048)."""
+    2,048) and trinity_large_preview (24 tokens, no whole sublane tiles:
+    padded to 32 inside the kernel's wrapper; 32 held gated experts of
+    width 3,072 at hidden 3,072)."""
     from paddle_tpu.kernels import moe
 
     def case(T, H, F, E, matrices):
@@ -601,7 +622,7 @@ def _tpu_cases_moe_experts():
                 + [((E, F, H), "bfloat16")] * matrices)
 
     return [case(32, 2688, 1856, 16, 2), case(128, 2048, 1536, 8, 3),
-            case(128, 2048, 768, 16, 3)]
+            case(128, 2048, 768, 16, 3), case(24, 3072, 3072, 32, 3)]
 
 
 def _parity_moe_grouped(rng):

@@ -97,7 +97,8 @@ __all__ = [
     "cached_attention_composite", "paged_attention_composite",
     "chunk_attention_composite", "decode_attention", "paged_attention",
     "chunk_attention", "chunk_attention_by_span", "chunk_horizon",
-    "chunk_mask_bias", "fits_vmem", "grouped_layout", "paged_copy_unit",
+    "chunk_mask_bias", "chunk_floor", "fits_vmem", "grouped_layout",
+    "paged_copy_unit",
     "absorb_queries", "project_values", "latent_chunk_expanded",
     "latent_chunk_attention",
 ]
@@ -200,12 +201,23 @@ def chunk_horizon(span, chunk, length, block_len=1):
     return jnp.where(c < real, bound, 0)
 
 
-def chunk_mask_bias(span, chunk, length, block_len=1):
-    """``chunk_horizon`` as the additive float32 ``[1, C, L]`` bias a
-    composite adds to its scores: 0.0 where a query sees, ``-1e9`` where
-    not."""
-    sees = (jnp.arange(int(length), dtype=jnp.int32)[None, :]
-            < chunk_horizon(span, chunk, length, block_len)[:, None])
+def chunk_floor(span, chunk, window):
+    """The LOWER edge of the chunk program's mask for layers that see the
+    last ``window`` positions, the query's own among them: query ``c`` at
+    ``start + c`` sees nothing below ``start + c - window + 1``. int32
+    ``[C]``, 0 where the window reaches the sequence's start."""
+    c = jnp.arange(int(chunk), dtype=jnp.int32)
+    return jnp.maximum(span[0].astype(jnp.int32) + c - int(window) + 1, 0)
+
+
+def chunk_mask_bias(span, chunk, length, block_len=1, window=0):
+    """``chunk_horizon`` (and, with ``window``, ``chunk_floor``) as the
+    additive float32 ``[1, C, L]`` bias a composite adds to its scores: 0.0
+    where a query sees, ``-1e9`` where not."""
+    at = jnp.arange(int(length), dtype=jnp.int32)[None, :]
+    sees = at < chunk_horizon(span, chunk, length, block_len)[:, None]
+    if window:
+        sees &= at >= chunk_floor(span, chunk, window)[:, None]
     return jnp.where(sees, 0.0, -1e9).astype(jnp.float32)[None]
 
 
@@ -234,12 +246,12 @@ def chunk_attention_composite(q, k_arena, v_arena, rows, bias, sm_scale,
 
 
 def chunk_attention_by_span(q, k_arena, v_arena, rows, span, sm_scale,
-                            kv_heads, block_len=1):
+                            kv_heads, block_len=1, window=0):
     """``chunk_attention_composite`` under the bias the rule makes of the
     chunk's ``span``: THE definition of the ``chunk_paged_attention`` op."""
     return chunk_attention_composite(
         q, k_arena, v_arena, rows,
-        chunk_mask_bias(span, q.shape[0], rows.shape[0], block_len),
+        chunk_mask_bias(span, q.shape[0], rows.shape[0], block_len, window),
         sm_scale, kv_heads)
 
 
@@ -734,12 +746,16 @@ CHUNK_KERNEL_MIN_WORK = 1 << 20
 
 def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, sm_scale, block,
-                tile, per_slot):
+                tile, per_slot, ft_ref=None, lo_ref=None):
     """One tile of a chunk's queries (``q_ref`` ``[pairs, rows, lanes]``:
     ``grouped_layout``'s packing, a position's query rows together) against
     the slot's live rows, a copy tile of ``tile`` blocks at a time, the
     next one in flight while this one is reduced: an online softmax whose
-    mask is ``key position < hz_ref`` (``chunk_horizon``, a row each)."""
+    mask is ``key position < hz_ref`` (``chunk_horizon``, a row each).
+    Under a window (``_windowed_chunk_body`` hands ``ft_ref`` and
+    ``lo_ref``) the query tile starts at copy tile ``ft_ref[i]``, the one
+    that holds its first query's lower edge, and the mask is ``lo_ref <=
+    key position < hz_ref``: no tile wholly under that edge is copied."""
     i = pl.program_id(0)
     pairs, rows, lanes = q_ref.shape
     trows = tile * block
@@ -747,6 +763,7 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
     prec = (jax.lax.Precision.HIGHEST if kbuf.dtype == f32
             else jax.lax.Precision.DEFAULT)
     nk = nt_ref[i]
+    t0 = 0 if ft_ref is None else ft_ref[i]
 
     @pl.when(i == 0)
     def _():
@@ -764,12 +781,12 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
                       tile_no, half, block=block, unit=tile,
                       per_slot=per_slot)
 
-    @pl.when(nk > 0)
+    @pl.when(nk > t0)
     def _():
-        start(0, 0)
+        start(t0, 0)
 
     def step(t, c):
-        half = t % 2
+        half = t % 2 if ft_ref is None else (t - t0) % 2
 
         @pl.when(t + 1 < nk)
         def _():
@@ -779,6 +796,8 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
                      unit=tile)
         at = t * trows + jax.lax.broadcasted_iota(jnp.int32, (1, trows), 1)
         sees = at < hz_ref[...]                              # [rows, trows]
+        if lo_ref is not None:
+            sees &= at >= lo_ref[...]
         for g in range(pairs):
             k = kbuf[half, :, g * lanes:(g + 1) * lanes]    # [trows, lanes]
             v = vbuf[half, :, g * lanes:(g + 1) * lanes]
@@ -799,7 +818,7 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
             m_ref[g] = m_new
         return c
 
-    jax.lax.fori_loop(0, nk, step, 0)
+    jax.lax.fori_loop(t0, nk, step, 0)
     # a query that sees nothing (past the real ones) gives zeros
     real = hz_ref[...] > 0
     for g in range(pairs):
@@ -807,8 +826,23 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
         o_ref[g] = jnp.where(real, acc_ref[g] / l, 0.0).astype(o_ref.dtype)
 
 
+def _windowed_chunk_body(bt_ref, len_ref, nt_ref, ft_ref, q_ref, hz_ref,
+                         lo_ref, *refs, **static):
+    """``_chunk_body`` under a window: a fourth prefetched vector, each
+    query tile's first copy tile, and each query row's lower edge beside
+    its horizon."""
+    _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, *refs, ft_ref=ft_ref,
+                lo_ref=lo_ref, **static)
+
+
+#: the chunk kernel's name under a window: another executable, and a device
+#: trace tells its events from the unwindowed calls' by name (which does
+#: NOT contain that kernel's name: a pattern for one misses the other)
+WINDOWED_CHUNK_KERNEL = "windowed_chunk_attn"
+
+
 def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
-                    kv_heads, block_len=1, interpret=False):
+                    kv_heads, block_len=1, interpret=False, window=0):
     """``chunk_attention_composite`` under ``chunk_mask_bias(span)``
     computed from the slot's LIVE blocks alone: a prompt chunk's ``C``
     queries ``[C, heads * D]`` over the rows that ``rows`` ``[L]`` (block
@@ -823,13 +857,21 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
     garbage there). Falls back to the composite, counted, where Mosaic
     cannot tile the geometry or inside a manual region; a geometry whose
     ``C x L`` is under ``CHUNK_KERNEL_MIN_WORK`` takes the composite by
-    choice (it is the faster one there, as measured), uncounted."""
+    choice (it is the faster one there, as measured), uncounted.
+
+    With ``window`` W (a static size; 0: none, and the kernel is what it
+    was) query ``c`` sees ``[max(0, start + c - W + 1), start + c]``
+    (``chunk_floor``): a tile of queries starts at the copy tile that holds
+    ITS first query's lower edge, so it copies at most ``W + queries - 1``
+    rows rounded out to copy tiles, whatever lies behind; the executable is
+    another (``WINDOWED_CHUNK_KERNEL``)."""
     C, L, bs, G = q.shape[0], rows.shape[0], int(block_size), int(kv_heads)
     H = k_arena.shape[-1]
+    W = int(window)
 
     def composite():
         return chunk_attention_by_span(q, k_arena, v_arena, rows, span,
-                                       sm_scale, G, block_len)
+                                       sm_scale, G, block_len, W)
 
     if not interpret and C * L < CHUNK_KERNEL_MIN_WORK:
         return composite()
@@ -859,15 +901,23 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
         k_arena.dtype)                              # [C, pairs, rpp, lanes]
     q_in = jnp.swapaxes(q_in, 0, 1).reshape(pairs, C * rpp, lanes)
     spec = pl.BlockSpec((pairs, qt * rpp, lanes), lambda i, *_: (0, i, 0))
+    edge = pl.BlockSpec((qt * rpp, 1), lambda i, *_: (i, 0))
+    prefetch, operands = (table, live, ntiles.astype(jnp.int32)), (q_in, hz)
+    if W:
+        floor = chunk_floor(span, C, W)
+        # a query tile's first copy tile: its first query's edge is its
+        # lowest (a query past the real ones sees nothing either way)
+        prefetch += ((floor.reshape(C // qt, qt)[:, 0] // trows).astype(
+            jnp.int32),)
+        operands += (jnp.repeat(floor, rpp).reshape(C * rpp, 1),)
     out = pl.pallas_call(
-        functools.partial(_chunk_body, sm_scale=sm_scale, block=bs,
+        functools.partial(_windowed_chunk_body if W else _chunk_body,
+                          sm_scale=sm_scale, block=bs,
                           tile=tile, per_slot=per_slot),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(C // qt,),
-            in_specs=[
-                spec,
-                pl.BlockSpec((qt * rpp, 1), lambda i, *_: (i, 0)),
+            in_specs=[spec] + [edge] * (len(operands) - 1) + [
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -886,8 +936,8 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
         interpret=interpret,
-        name="chunk_attention",
-    )(table, live, ntiles.astype(jnp.int32), q_in, hz, k_arena, v_arena)
+        name=WINDOWED_CHUNK_KERNEL if W else "chunk_attention",
+    )(*prefetch, *operands, k_arena, v_arena)
     out = jnp.swapaxes(out.reshape(pairs, C, rpp, lanes), 0, 1)
     return _unpack_heads(out, pack, per).reshape(q.shape)
 

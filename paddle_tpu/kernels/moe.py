@@ -48,6 +48,9 @@ _HI = jax.lax.Precision.HIGHEST
 #: matrix: what is left of Mosaic's 16 MiB is the tokens, the output and
 #: the two ``[T, F]`` scratches
 _WEIGHT_BLOCKS = 8 * 1024 * 1024
+#: tokens in one sublane tile of bfloat16: Mosaic takes the kernel's token
+#: blocks in whole ones
+_TOKEN_TILE = 16
 
 
 #: how a router turns its products into an expert's score
@@ -136,8 +139,9 @@ def hidden_tile(tokens, hidden, ffn, dtype, matrices, interpret=False):
     expert: the widest whole number of 128-lane tiles that divides
     ``hidden`` and keeps both buffers of every matrix's block within
     ``_WEIGHT_BLOCKS``. 0 says that Mosaic cannot take the geometry
-    (bfloat16, tokens in whole sublane tiles of it, a hidden size in whole
-    lane tiles) and the composite runs: THE eligibility test, by geometry.
+    (bfloat16, tokens in whole sublane tiles of it, which ``moe_experts``
+    pads a step's tokens up to, a hidden size in whole lane tiles) and the
+    composite runs: THE eligibility test, by geometry.
     ``interpret`` has no Mosaic to please: any dtype and token count, and
     a hidden size that no lane tile divides is one tile."""
     size = jnp.dtype(dtype).itemsize
@@ -146,7 +150,7 @@ def hidden_tile(tokens, hidden, ffn, dtype, matrices, interpret=False):
             if hidden % t == 0 and t <= room]
     if interpret:
         return max(fits, default=hidden)
-    if jnp.dtype(dtype) != jnp.bfloat16 or tokens % 16:
+    if jnp.dtype(dtype) != jnp.bfloat16 or tokens % _TOKEN_TILE:
         return 0
     return max(fits, default=0)
 
@@ -208,15 +212,22 @@ def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
     where there is a gate) over tiles of the hidden size into ``[T, F]``
     scratches, then ``tiles`` steps write the down product's columns, tile
     by tile, into the resident output. Past the last touched expert every
-    block index stays where it was: no copy."""
-    t, hidden = x.shape
+    block index stays where it was: no copy. A token count that is no
+    whole number of sublane tiles (24 slots) is padded up to one with
+    tokens that choose no expert; their rows are cut off the result."""
+    real, hidden = x.shape
     held, ffn, _ = w_up.shape
     firsts = [w_up] if w_gate is None else [w_up, w_gate]
+    pad = -real % _TOKEN_TILE
+    t = real + pad
     tile = hidden_tile(t, hidden, ffn, w_up.dtype, len(firsts) + 1,
                        interpret)
     if vma_names(x) or not tile:
         fallback_counter().inc()
         return experts_composite(x, c, w_up, w_down, w_gate)
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        c = jnp.pad(c, ((0, pad), (0, 0)))
     tiles = hidden // tile
     touched = jnp.any(c != 0.0, axis=0)                        # [E]
     order = jnp.argsort(jnp.logical_not(touched),
@@ -259,7 +270,8 @@ def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
         interpret=interpret,
         name="moe_experts",
     )(eid, count.reshape(1), xs, cols, *firsts, w_down)
-    return jnp.swapaxes(out, 0, 1).reshape(t, hidden)
+    out = jnp.swapaxes(out, 0, 1).reshape(t, hidden)
+    return out[:real] if pad else out
 
 
 # ---------------------------------------------------------------------------

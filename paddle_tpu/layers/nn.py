@@ -34,6 +34,7 @@ __all__ = [
     "chunk_mask_bias",
     "paged_step_feeds",
     "paged_block_feeds",
+    "paged_window_feeds",
     "block_fill_decide",
     "rms_norm",
     "rotary_embedding",
@@ -771,6 +772,26 @@ def paged_step_feeds(packed, token, length, block_size, name=None):
     return tuple(outs.values())
 
 
+def paged_window_feeds(packed, column, blocks, block_size, name=None):
+    """A paged decode step's inputs for ONE window group of attention
+    layers (ops/nn.py ``paged_window_feeds``), from the columns of
+    ``packed`` (``paged_step_feeds``'s array) that start at ``column``: a
+    slot's ``length, low, write_row`` and a table of ``blocks`` block ids
+    from its first live block. Returns ``(bias [S, 1, blocks * block_size]
+    float32, rows [S * blocks * block_size], write_rows [S])``."""
+    helper = LayerHelper("paged_window_feeds", name=name)
+    outs = {slot: helper.create_variable_for_type_inference(
+        "float32" if slot == "Bias" else packed.dtype, stop_gradient=True)
+        for slot in ("Bias", "Rows", "WriteRows")}
+    helper.append_op(
+        "paged_window_feeds", {"Packed": [packed.name]},
+        {slot: [v.name] for slot, v in outs.items()},
+        {"column": int(column), "blocks": int(blocks),
+         "block_size": int(block_size)},
+    )
+    return tuple(outs.values())
+
+
 def paged_block_feeds(packed, state, length, block_size, block_len,
                       mask_token, name=None):
     """``paged_step_feeds`` for a step that runs ``block_len`` positions a
@@ -830,13 +851,16 @@ def chunk_mask_bias(span, chunk, length, block_len=1, name=None):
 
 
 def chunk_paged_attention(q, k_arena, v_arena, rows, span, kv_heads,
-                          block_size, sm_scale=1.0, block_len=1, name=None):
+                          block_size, sm_scale=1.0, block_len=1, window=0,
+                          name=None):
     """A prompt chunk's queries ``[C, heads * D]`` over ONE sequence's
     ``[L]`` rows of the paged arenas, grouped-query (``kv_heads`` K/V heads
     a row), under the mask the device makes of ``span`` (the chunk's first
     position and its count of real positions) and ``block_len``: the chunk
     program's form of ``paged_attention``, from the live blocks alone where
-    the kernel serves it (kernels/attention.py ``chunk_attention``)."""
+    the kernel serves it (kernels/attention.py ``chunk_attention``). With
+    ``window`` W a query sees the last W positions, its own among them, and
+    nothing older (``chunk_floor``)."""
     helper = LayerHelper("chunk_paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     helper.append_op(
@@ -845,7 +869,10 @@ def chunk_paged_attention(q, k_arena, v_arena, rows, span, kv_heads,
          "Rows": [rows.name], "Span": [span.name]},
         {"Out": [out.name]},
         {"sm_scale": float(sm_scale), "kv_heads": int(kv_heads),
-         "block_size": int(block_size), "block_len": int(block_len)},
+         "block_size": int(block_size), "block_len": int(block_len),
+         # written only where there is one: a program without a window
+         # keeps the bytes it had (the compile cache's key)
+         **({"window": int(window)} if window else {})},
     )
     return out
 

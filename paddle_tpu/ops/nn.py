@@ -641,6 +641,35 @@ def _paged_step_feeds(ins, attrs):
     }
 
 
+@register_op("paged_window_feeds", nondiff_inputs=("Packed",))
+def _paged_window_feeds(ins, attrs):
+    """What a paged decode step reads of its slots in ONE window group
+    (serving/decode/model.py ``KVGroup``), from the columns of ``Packed``
+    that ``paged_step_feeds`` leaves alone: from ``column`` on a slot's
+    ``length, low, write_row`` and a table of ``blocks`` block ids that
+    starts at the slot's first LIVE block. Counted from that block's first
+    position the step's query sees rows ``[low, length)``: ``low`` masks the
+    rows of the oldest block that have left the window, a ``length`` of 0
+    everything (the slot does not step). Gives the additive ``[S, 1, blocks
+    * block_size]`` bias, the row map over those rows and the ``[S]`` write
+    rows: what ``paged_attention`` takes for a layer of the group."""
+    packed = first(ins, "Packed")
+    at, n, bs = (int(attrs["column"]), int(attrs["blocks"]),
+                 int(attrs["block_size"]))
+    S = packed.shape[0]
+    head = packed[:, at:at + 3]
+    table = packed[:, at + 3:at + 3 + n]
+    within = jnp.arange(bs, dtype=packed.dtype)
+    rows = (table[:, :, None] * bs + within).reshape(S, -1)
+    j = jnp.arange(n * bs, dtype=packed.dtype)
+    open_ = (j >= head[:, 1:2]) & (j < head[:, 0:1])            # [S, n * bs]
+    return {
+        "Bias": [jnp.where(open_, 0.0, -1e9).astype(jnp.float32)[:, None]],
+        "Rows": [rows.reshape(-1)],
+        "WriteRows": [head[:, 2]],
+    }
+
+
 @register_op("paged_block_feeds", nondiff_inputs=("Packed", "State"))
 def _paged_block_feeds(ins, attrs):
     """``paged_step_feeds`` for a step that runs a BLOCK of ``B`` positions
@@ -731,7 +760,8 @@ def _chunk_paged_attention_reference(ins, attrs):
     return {"Out": [fused.chunk_attention_by_span(
         first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
         first(ins, "Rows"), first(ins, "Span"), attrs.get("sm_scale", 1.0),
-        attrs["kv_heads"], attrs.get("block_len", 1))]}
+        attrs["kv_heads"], attrs.get("block_len", 1),
+        attrs.get("window", 0))]}
 
 
 def _chunk_paged_attention_pallas(ins, attrs):
@@ -745,7 +775,8 @@ def _chunk_paged_attention_pallas(ins, attrs):
         first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
         first(ins, "Rows"), first(ins, "Span"), attrs["block_size"],
         attrs.get("sm_scale", 1.0), attrs["kv_heads"],
-        block_len=attrs.get("block_len", 1), interpret=sel.interpret)]}
+        block_len=attrs.get("block_len", 1), interpret=sel.interpret,
+        window=attrs.get("window", 0))]}
 
 
 # a prompt chunk's queries ``[C, heads * D]`` over one sequence's rows of

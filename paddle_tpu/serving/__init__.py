@@ -41,6 +41,7 @@ from paddle_tpu.serving.batcher import BucketLattice, DynamicBatcher
 from paddle_tpu.serving.decode import (
     DecodeModel,
     GenerationEngine,
+    build_afmoe_model,
     build_decoder_model,
     build_granite_hybrid_model,
     build_latent_moe_model,
@@ -77,6 +78,7 @@ __all__ = [
     "GenerationEngine",
     "LocalReplica",
     "SubprocessReplica",
+    "build_afmoe_model",
     "build_decoder_model",
     "build_nemotron_h_model",
     "build_granite_hybrid_model",

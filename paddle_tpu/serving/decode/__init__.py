@@ -67,7 +67,7 @@ from paddle_tpu.serving.decode.generate import (
 )
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.hybrid import (
-    build_granite_hybrid_model, build_latent_moe_model, build_lfm2_model,
+    build_afmoe_model, build_granite_hybrid_model, build_latent_moe_model, build_lfm2_model,
     build_nemotron_h_model, build_ouro_model, build_sdar_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
@@ -91,6 +91,7 @@ __all__ = [
     "SamplingParams",
     "SlotPool",
     "block_hashes",
+    "build_afmoe_model",
     "build_decoder_model",
     "build_nemotron_h_model",
     "build_granite_hybrid_model",

@@ -711,7 +711,7 @@ class _ModelEntry:
         if self._thread is not None:
             return False
         self._scope.erase(
-            [n for kv in self._model.state_names for n in kv]
+            [n for kv in self._model.all_state_names for n in kv]
             + [n for n, _shape, _dtype in self._model.slot_states])
         return True
 
@@ -1542,6 +1542,8 @@ class _ModelEntry:
         toks[0, :real] = req.prompt[start:stop]
         pos = np.zeros((1, C), "int64")
         pos[0, :real] = np.arange(start, stop)
+        if st.kv.windows:
+            self._window_chunk(st.kv, start, stop)
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::chunk") as ev:
@@ -1562,6 +1564,9 @@ class _ModelEntry:
                     DecodeModel.CHU_WRITE_ROWS: st.kv.chunk_write_rows(
                         start, stop, C),
                 }
+                if st.kv.windows:
+                    feeds.update(m.window_chunk_feeds(start, real,
+                                                      st.kv.windows))
                 if m.recurrent:
                     # the chunk at position 0 resets the slot's state
                     # (``decode::state_reset``: whatever a retired or
@@ -1593,6 +1598,21 @@ class _ModelEntry:
         if req.beam is not None or not (ahead or self._any_stepping()):
             self._land_chunks(deferred=False)
         return 1
+
+    def _window_chunk(self, kv, start, stop):
+        """Before the chunk ``[start, stop)`` of a model with window
+        groups is launched: what lies behind the window of its first query
+        is given back (`KVStore.release_behind`), its own blocks are
+        opened there, and what its window layers will read is counted
+        beside what their context holds."""
+        with _span("decode::window_release") as sp:
+            given = self.kv.release_behind(kv, start)
+            if sp is not None:
+                sp.set(blocks=given, chunk=True)
+        self.kv.open_windows(kv, stop)
+        self._metrics.observe_window_chunk(
+            start, stop, self._model.block_size, kv.windows,
+            [g.window for g in self._model.window_groups], given)
 
     def _land_chunks(self, deferred):
         """Fetch every last chunk's logits row that is still on the
@@ -2353,6 +2373,8 @@ class _ModelEntry:
         groups = []     # beam groups with a live slot this step
         live_blocks = copy_units = 0
         launched = self._launched
+        if m.window_groups:
+            self._window_step()
         for s in range(S):
             st = self._slots[s]
             if st is None or st.mode not in ("decode", "beam"):
@@ -2420,6 +2442,10 @@ class _ModelEntry:
                 m.fill_step(step, s, st.cursor, st.kv.table,
                             st.kv.row_of(st.cursor),
                             -1 if st.ahead else st.last_token)
+                if st.kv.windows:
+                    m.fill_windows(step, s, st.cursor, st.kv.windows)
+                    self._metrics.observe_window_rows(
+                        st.cursor + 1, m.block_size, st.kv.windows)
             reads = (st.cursor + m.block_len - 1) // m.block_size + 1
             live_blocks += reads
             if self.kv.copy_unit:
@@ -2438,6 +2464,21 @@ class _ModelEntry:
         if dmask is not None:
             feeds[DecodeModel.DEC_MASK] = dmask
         return feeds, active, groups
+
+    def _window_step(self):
+        """Before a step's feeds are built for a model with window groups:
+        every decoding slot gives back what lies behind the window of its
+        NEXT token (its cursor; a step in flight stands before it and was
+        launched already), under ONE span a step, and each group's pool
+        is observed."""
+        with _span("decode::window_release") as sp:
+            given = sum(self.kv.release_behind(st.kv, st.cursor)
+                        for st in self._slots
+                        if st is not None and st.mode == "decode")
+            if sp is not None:
+                sp.set(blocks=given, chunk=False)
+        self._metrics.observe_pools(self.kv.pool, self.kv.window_pools,
+                                    given)
 
     def _sample(self, fetched, active, groups, now, tokens_only):
         """The half of a decode step's host work that needs what it

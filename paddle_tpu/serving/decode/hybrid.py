@@ -1,6 +1,7 @@
 """Hybrid decoders as paged DecodeModels: per-slot recurrent state beside
 paged grouped-query K/V rows, routed experts of which this chip holds a
-share, a stack of layers run several times a token. Five families, one
+share, a stack of layers run several times a token, attention layers that
+keep different rows. Seven families, one
 set of parts (``_Parts``: the named seeded parameters, projections, norms,
 arenas and their write; ``_hybrid_model``: the two programs around a
 family's ``stack`` and the DecodeModel).
@@ -129,16 +130,42 @@ the same width; a final RMSNorm and an untied head; no bias anywhere. The
 chunk program sums, on the device, what its routed layers multiplied
 (``GROUPED_COUNTS``), and the next step hands the sums to the host in its
 one fetch.
+
+**``afmoe``** (Arcee Trinity: ``build_afmoe_model``). ``layer_types`` names
+one ATTENTION a layer: ``sliding_attention``, whose query at position i sees
+the keys ``j`` with ``0 <= i - j < sliding_window`` and whose q and k are
+rotated (whole head, rotate-half), or ``full_attention``, which sees every
+``j <= i`` and carries no position encoding. Both are grouped-query with an
+RMSNorm over each head of q and k, and both are GATED: ``attn = (softmax(q k
+/ sqrt(D)) v * sigmoid(u Wg)) Wo``, the gate as wide as the query and read
+from the same normed input ``u``. The sliding layers are a window group of
+their own (model.py ``KVGroup``; kvstate.py, "Layer groups"): their arenas
+hold a sequence's last ``sliding_window`` rows and what a chunk adds, the
+blocks behind them go back to the group's pool, the step reads them through
+the group's own table and a bias with a LOWER edge, a chunk under
+``chunk_paged_attention``'s ``window``. A layer is sandwich-normed, four
+RMSNorms: ``a = x + N2(attn(N1(x)))``, ``x' = a + N4(ffn(N3(a)))``; the
+embedding is scaled by ``sqrt(hidden_size)`` (``mup_enabled``). The
+feed-forward is a dense SwiGLU in the ``num_dense_layers`` leading layers;
+in the others ``shared(h) + route_scale * sum_e w_e expert_e(h)``: sigmoid
+scores over all the router's experts in float32, a bias on the choice and
+not on the weight, top k, the chosen scores over their sum (``route_norm``;
+guarded by 1e-20), gated SwiGLU experts of which this chip holds a share
+and a shared expert of the same width. A final RMSNorm and an untied head;
+no bias anywhere. The chunk program sums its routed layers' counts on the
+device as ``mistral4``'s does (``GROUPED_COUNTS``).
 """
 
 import math
 
-from paddle_tpu.serving.decode.model import DecodeModel, _state_var
+from paddle_tpu.serving.decode.model import (
+    DecodeModel, KVGroup, _state_var, window_chunk_blocks,
+    window_table_blocks)
 
 __all__ = ["build_nemotron_h_model", "build_lfm2_model", "build_ouro_model",
            "build_sdar_model", "build_granite_hybrid_model",
-           "build_latent_moe_model", "yarn_frequencies", "MOE_COUNTS",
-           "GROUPED_COUNTS", "LOOP_COUNTS"]
+           "build_latent_moe_model", "build_afmoe_model", "yarn_frequencies",
+           "MOE_COUNTS", "GROUPED_COUNTS", "LOOP_COUNTS"]
 
 #: what the decode step's ``Counts`` hold, in order: the engine adds them
 #: to the counters of these names when the step's tokens come back
@@ -228,10 +255,16 @@ class _Parts:
     arenas of the attention layers ``a_layers`` with their scatter write,
     and the per-slot states. An attention layer is named by its index, or
     by ``(pass, layer)`` where a stack runs several times and every pass
-    keeps rows of its own."""
+    keeps rows of its own. ``windows`` lists further groups of attention
+    layers, ``(name, layers, num_blocks, window)`` each: ``window_groups``
+    holds them as model.py's ``KVGroup`` (their arenas have the group's
+    ``num_blocks x block_size`` rows), ``window_layers`` each group's
+    layers, and ``group_of`` says which group a layer writes to and
+    reads."""
 
     def __init__(self, prefix, dtype, eps, std, back, rows, kv_width,
-                 a_layers, slot_states, latent=False):
+                 a_layers, slot_states, latent=False, windows=(),
+                 block_size=0):
         import paddle_tpu as fluid
         from paddle_tpu.core.ir import Program
 
@@ -249,7 +282,19 @@ class _Parts:
             else (f"{prefix}.kcache{tag}", f"{prefix}.vcache{tag}")
             for tag in tags]
         self.slot_states = slot_states
+        self.block_size = int(block_size)
+        self.window_layers = [list(layers) for _n, layers, _b, _w in windows]
+        self.window_groups = [
+            KVGroup(name, [(f"{prefix}.kcache{i}", f"{prefix}.vcache{i}")
+                           for i in layers], blocks, window)
+            for name, layers, blocks, window in windows]
         self.startup = Program()
+
+    def group_of(self, i):
+        """The index in ``window_groups`` of attention layer ``i``'s group,
+        or None for a layer of the first group."""
+        return next((g for g, layers in enumerate(self.window_layers)
+                     if i in layers), None)
 
     def attr(self, suffix, init):
         return self.fluid.ParamAttr(name=f"{self.prefix}.{suffix}",
@@ -278,11 +323,15 @@ class _Parts:
 
     def normed_rotated_heads(self, t, n, positions, suffix, theta):
         """QK-norm (an RMSNorm over a head, weight ``suffix``), then the
-        rotation at ``positions``, over each of ``t``'s ``n`` heads."""
+        rotation at ``positions`` (``theta`` None: no rotation), over each
+        of ``t``'s ``n`` heads."""
         fluid = self.fluid
         lead, d = [int(x) for x in t.shape[:2]], int(t.shape[-1]) // n
-        t = self.norm(fluid.layers.reshape(t, lead + [n, d]), suffix,
-                      out_dtype="float32")
+        t = fluid.layers.reshape(t, lead + [n, d])
+        if theta is None:
+            return fluid.layers.reshape(
+                self.norm(t, suffix), lead + [n * d])
+        t = self.norm(t, suffix, out_dtype="float32")
         return fluid.layers.reshape(fluid.layers.rotary_embedding(
             t, positions, theta=float(theta), out_dtype=self.dtype),
             lead + [n * d])
@@ -292,10 +341,16 @@ class _Parts:
         return _state_var(program, self.startup, name, shape, dtype=dtype)
 
     def arenas(self, program, i):
-        shape = [self.rows, self.kv_width]
+        g = self.group_of(i)
+        if g is None:
+            rows, names = self.rows, self.state_names[self.a_layers.index(i)]
+        else:
+            group = self.window_groups[g]
+            names = group.state_names[self.window_layers[g].index(i)]
+            rows = group.num_blocks * self.block_size
         return tuple(
-            _state_var(program, self.startup, n, shape, dtype=self.dtype)
-            for n in self.state_names[self.a_layers.index(i)])
+            _state_var(program, self.startup, n, [rows, self.kv_width],
+                       dtype=self.dtype) for n in names)
 
     def write(self, program, i, wrows, axis, *rows):
         """Scatter the new rows, one ``[.., kv_width]`` array an arena of
@@ -330,13 +385,24 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
     ``heads``, ``nope``, ``rope``, ``value``, ``latent`` of a latent
     attention) a layer keeps ONE arena and ``attend(i, q, row, weights)``
     writes the token's ``row`` and attends over the arena as it lies
-    (``weights``: the ``w_uk`` and ``w_uv`` parameter attributes)."""
+    (``weights``: the ``w_uk`` and ``w_uv`` parameter attributes). Where
+    ``parts`` has window groups, ``attend`` writes and reads layer ``i``
+    through ITS group's row map, bias and write rows (model.py, "Layer
+    groups"), a chunk under the group's window; the ``wrows`` the stack is
+    handed are the first group's, which marks every real token."""
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
     from paddle_tpu.utils import unique_name
 
     S, L, BS, C = slots, max_len, block_size, chunk_tokens
     startup = parts.startup
+    per_slot = -(-L // BS)
+    groups = parts.window_groups
+    # a window group's table in ``dec_step`` and its chunk row map, blocks
+    step_blocks = [window_table_blocks(g.window, BS, per_slot)
+                   for g in groups]
+    chunk_blocks = [window_chunk_blocks(g.window, C, BS, per_slot)
+                    for g in groups]
 
     # -- decode step: one token per slot at [S, 1], or a block's B --------
     B = int(block_len)
@@ -344,7 +410,8 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
     with unique_name.guard(), program_guard(decode, startup):
         packed = fluid.data(
             DecodeModel.DEC_STEP,
-            [S, DecodeModel.STEP_TABLE + (B if B > 1 else 0) + -(-L // BS)],
+            [S, DecodeModel.STEP_TABLE + (B if B > 1 else 0) + per_slot
+             + sum(3 + n for n in step_blocks)],
             dtype="int32")
         if B > 1:
             state = fluid.data(DecodeModel.DEC_TOKEN, [S, 2 * B],
@@ -358,11 +425,21 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                 fluid.data(DecodeModel.DEC_TOKEN, [S, 1], dtype="int64"),
                 L, BS)
 
+        # a window group's (bias, rows, write rows, rows a slot)
+        column, step_groups = DecodeModel.STEP_TABLE + per_slot, []
+        for n in step_blocks:
+            step_groups.append(fluid.layers.paged_window_feeds(
+                packed, column, n, BS) + (n * BS,))
+            column += 3 + n
+
         def attend_step(i, q, k, v):
-            nk, nv = parts.write(decode, i, wrows, 1, k, v)
+            g = parts.group_of(i)
+            gbias, grows, gwrows, length = (
+                (bias, rows, wrows, L) if g is None else step_groups[g])
+            nk, nv = parts.write(decode, i, gwrows, 1, k, v)
             ctx = fluid.layers.paged_attention(
-                fluid.layers.squeeze(q, [1]), nk, nv, rows, bias, S, L,
-                sm_scale=sm_scale, block_size=BS, kv_heads=kv_heads)
+                fluid.layers.squeeze(q, [1]), nk, nv, grows, gbias, S,
+                length, sm_scale=sm_scale, block_size=BS, kv_heads=kv_heads)
             return fluid.layers.unsqueeze(ctx, [1])
 
         def attend_block(i, q, k, v):
@@ -429,11 +506,22 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         cslot = (fluid.data(DecodeModel.CHU_SLOT, [1], dtype="int64")
                  if parts.slot_states else None)
 
+        chunk_groups = []
+        for g, n in enumerate(chunk_blocks):
+            span, grows, gwrows = DecodeModel.chunk_group_feeds(g)
+            chunk_groups.append((
+                fluid.data(span, [2], dtype="int32"),
+                fluid.data(grows, [n * BS], dtype="int64"),
+                fluid.data(gwrows, [C], dtype="int64"), groups[g].window))
+
         def attend_chunk(i, q, k, v):
-            nk, nv = parts.write(chunk, i, cwrows, 0, k, v)
+            g = parts.group_of(i)
+            gspan, grows, gwrows, window = (
+                (cspan, crows, cwrows, 0) if g is None else chunk_groups[g])
+            nk, nv = parts.write(chunk, i, gwrows, 0, k, v)
             ctx = fluid.layers.chunk_paged_attention(
-                fluid.layers.squeeze(q, [0]), nk, nv, crows, cspan, kv_heads,
-                BS, sm_scale=sm_scale, block_len=B)
+                fluid.layers.squeeze(q, [0]), nk, nv, grows, gspan, kv_heads,
+                BS, sm_scale=sm_scale, block_len=B, window=window)
             return fluid.layers.unsqueeze(ctx, [0])
 
         def attend_chunk_latent(i, q, row, weights):
@@ -458,7 +546,7 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         logits_fetch=dec_logits.name, token_fetch=next_token.name,
         counts_fetch=token_counts.name if counts or B > 1 else None,
         count_names=count_names if counts else (), passes=passes,
-        block_len=B, mask_token=mask_token,
+        block_len=B, mask_token=mask_token, window_groups=groups,
         prefill_logits_fetch=None, chunk_logits_fetch=chu_logits.name,
         prefill_kv_fetches=[], inject_kv_feeds=[],
         eos_id=eos_id, name=name, version=version, builder=rebuild)
@@ -1297,3 +1385,148 @@ def build_latent_moe_model(
         count_names=MOE_COUNTS + GROUPED_COUNTS,
         latent={"heads": NH, "nope": DN, "rope": DR, "value": DV,
                 "latent": KVR})
+
+
+def build_afmoe_model(
+        vocab_size, hidden_size, layer_types, *, num_attention_heads,
+        num_key_value_heads, head_dim, intermediate_size, num_dense_layers,
+        num_experts, router_experts, num_experts_per_tok,
+        moe_intermediate_size, sliding_window, num_shared_experts=1,
+        route_scale=1.0, route_norm=True, mup_enabled=True,
+        rms_norm_eps=1e-5, rope_theta=10000.0, initializer_range=0.02,
+        expert_rank=0, dtype="bfloat16", slots=4,
+        max_len=64, block_size=16, num_blocks=None, window_num_blocks=None,
+        chunk_tokens=16, eos_id=None, name="afmoe", version="1"):
+    """Build the ``afmoe`` decoder as a paged DecodeModel (module
+    docstring). The sizes are the published ``config.json``'s keys under
+    their own names; ``num_experts`` is how many experts are HELD here and
+    ``router_experts`` how many the router scores (the published count);
+    ``vocab_size`` the rows of the vocabulary held here. ``num_blocks`` is
+    the FULL layers' pool and ``window_num_blocks`` the sliding layers'
+    (default: every slot's ``max_len``, and what a slot can hold at once
+    in its window: nothing can then run out); either may be smaller, and
+    the engine admits a request against what it needs in both.
+    ``initializer_range`` as ``build_lfm2_model``'s."""
+    kwargs = dict(locals())
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import NormalInitializer
+
+    V, H = int(vocab_size), int(hidden_size)
+    kinds = [str(kind) for kind in layer_types]
+    if set(kinds) - {"sliding_attention", "full_attention"}:
+        raise ValueError(f"layer_types {kinds}: a layer's attention is "
+                         "sliding_attention or full_attention")
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    NQ, NKV, D = (int(num_attention_heads), int(num_key_value_heads),
+                  int(head_dim))
+    W, dense = int(sliding_window), int(num_dense_layers)
+    F, FS = (int(moe_intermediate_size),
+             int(moe_intermediate_size) * int(num_shared_experts))
+    held, router = int(num_experts), int(router_experts)
+    offset = _held(expert_rank, held, router)
+    sliding = [i for i, kind in enumerate(kinds)
+               if kind == "sliding_attention"]
+    WNB = int(window_num_blocks) if window_num_blocks else (
+        S * window_chunk_blocks(W, C, BS, -(-L // BS)))
+    prefix = f"{name}_v{version}"
+    # two sub-layers a layer write into the residual
+    std = float(initializer_range)
+    parts = _Parts(
+        prefix, dtype, float(rms_norm_eps), std,
+        std / math.sqrt(2 * len(kinds)), R, NKV * D,
+        [i for i, kind in enumerate(kinds) if kind == "full_attention"], [],
+        windows=[("sliding", sliding, WNB, W)] if sliding else [],
+        block_size=BS)
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+    grouped_name = f"{prefix}.grouped_counts"
+
+    def swiglu(x, width, gate, up, down):
+        gated = fluid.layers.elementwise_mul(
+            proj(x, width, gate, act="silu", out_dtype="float32"),
+            proj(x, width, up, out_dtype="float32"))
+        return proj(fluid.layers.cast(gated, dtype), H, down, residual=True,
+                    out_dtype="float32")
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The layers over ``toks``: gated attention, then the
+        feed-forward, each between two norms."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        if mup_enabled:
+            h = fluid.layers.scale(h, scale=math.sqrt(H))
+        counts, pairs = [], []
+        for i, kind in enumerate(kinds):
+            x = norm(h, f"l{i}.input_layernorm")
+            theta = rope_theta if kind == "sliding_attention" else None
+            ctx = attend(
+                i,
+                parts.normed_rotated_heads(
+                    proj(x, NQ * D, f"l{i}.q", out_dtype="float32"), NQ,
+                    positions, f"l{i}.q_norm", theta),
+                parts.normed_rotated_heads(
+                    proj(x, NKV * D, f"l{i}.k", out_dtype="float32"), NKV,
+                    positions, f"l{i}.k_norm", theta),
+                proj(x, NKV * D, f"l{i}.v"))
+            gated = fluid.layers.elementwise_mul(
+                fluid.layers.cast(ctx, "float32"),
+                proj(x, NQ * D, f"l{i}.gate_proj", act="sigmoid",
+                     out_dtype="float32"))
+            out = proj(fluid.layers.cast(gated, dtype), H, f"l{i}.o",
+                       residual=True, out_dtype="float32")
+            h = fluid.layers.elementwise_add(
+                h, norm(out, f"l{i}.post_attention_layernorm",
+                        out_dtype="float32"))
+            x = norm(h, f"l{i}.pre_mlp_layernorm")
+            if i < dense:
+                out = swiglu(x, int(intermediate_size), f"l{i}.gate",
+                             f"l{i}.up", f"l{i}.down")
+            else:
+                routed, n, g = fluid.layers.moe_routed_experts(
+                    x, wrows, R, router, held, F, int(num_experts_per_tok),
+                    {"gate": matrix(f"l{i}.router"),
+                     "select_bias": attr(f"l{i}.expert_bias",
+                                         NormalInitializer(0.0, 0.05)),
+                     "w_gate": matrix(f"l{i}.w1"),
+                     "w_up": matrix(f"l{i}.w3"),
+                     "w_down": matrix(f"l{i}.w2", residual=True)},
+                    expert_offset=offset, score_scale=float(route_scale),
+                    normalize=bool(route_norm), kernel=mode == "step",
+                    group_counts=True)
+                counts.append(n)
+                pairs.append(g)
+                out = fluid.layers.elementwise_add(routed, swiglu(
+                    x, FS, f"l{i}.shared_gate", f"l{i}.shared_up",
+                    f"l{i}.shared_down"))
+            h = fluid.layers.elementwise_add(
+                h, norm(out, f"l{i}.post_mlp_layernorm",
+                        out_dtype="float32"))
+        logits = proj(norm(h, "norm"), V, "head", out_dtype="float32")
+        if not counts:
+            return logits, []
+        # the chunks' routed counts are summed on the device and handed
+        # over, and zeroed, by the next step (``build_latent_moe_model``)
+        total = _state_var(program, parts.startup, grouped_name,
+                           [len(GROUPED_COUNTS)], dtype="int32")
+        if mode == "chunk":
+            fluid.layers.assign(fluid.layers.sums([total] + pairs),
+                                output=total)
+            return logits, []
+        both = fluid.layers.concat(
+            [fluid.layers.sums(counts) if len(counts) > 1 else counts[0],
+             total], axis=0)
+        fluid.layers.assign(
+            fluid.layers.fill_constant([len(GROUPED_COUNTS)], "int32", 0),
+            output=total)
+        return logits, [both]
+
+    return _hybrid_model(
+        parts, stack, lambda: build_afmoe_model(**kwargs), vocab=V, hidden=H,
+        slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
+        kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
+        version=version,
+        count_names=(MOE_COUNTS + GROUPED_COUNTS
+                     if len(kinds) > dense else ()))

@@ -34,6 +34,27 @@ and the request waits in the queue (counted once). The sequence opens its
 blocks out of the promise (`open_block`) and `release` hands back the rest.
 Chosen by the pool's size and the tier's absence alone.
 
+**Layer groups.** A model may keep some attention layers' rows in groups of
+their own (model.py ``KVGroup``): layers that see the last ``window``
+positions and nothing older. Each such group has a `BlockPool` of its own
+(``KVStore.window_pools``) and a sequence a footing in it (`WindowKV`) that
+holds only the blocks with a position inside the window of the sequence's
+NEXT query: `KVStore.release_behind` gives the others back. When: the
+scheduler calls it for position ``p`` right before it builds the feeds of the
+launch whose first query stands at ``p`` (the step of the token at ``p``,
+the chunk that starts at ``p``). Every launch that read the blocks given
+back was made before that, and whoever is handed them next writes them in a
+launch made after it; the device runs launches in the order they were made
+(a step launched ahead included: PR 42's launch-ahead keeps ONE order of
+launches, it only fetches later), so a row is overwritten only after its
+last reader ran. Nothing approximates: a row inside a query's window is in
+a live block, a row outside it is masked (the oldest live block's) or
+absent. Admission promises a request, a group, what it can ever hold there
+at once, and a block given back renews the promise for the blocks still to
+be opened. Such a store always reserves, shares no block and carries
+neither a prefix cache nor a tier: a block that was given back can be
+neither shared nor restored.
+
 **What a store refuses to carry.** The tier and the prefix cache key on K/V
 rows; a per-slot recurrent state is no function of them, and a model
 without an inject program (``chunks_only``) could never take rows back. So
@@ -60,13 +81,29 @@ from paddle_tpu.serving.decode.tier import HostKVTier
 
 # SlotPool is the scheduler's (which batch slot is free); it is handed on
 # so that the scheduler imports this module alone
-__all__ = ["ArenaInvalidError", "KVStore", "SeqKV", "SlotPool"]
+__all__ = ["ArenaInvalidError", "KVStore", "SeqKV", "SlotPool", "WindowKV"]
 
 
 class ArenaInvalidError(RuntimeError):
     """A DONATED arena update (inject) failed mid-execution: the old
     buffers were consumed and the new ones never materialized, so the
     whole KV pool — not just the sequence written — is undefined."""
+
+
+def _block_rows(blocks, block_size):
+    """The arena rows of ``blocks``' positions, in the chain's order."""
+    row0 = np.fromiter((b.row0 for b in blocks), "int64", len(blocks))
+    return (row0[:, None] + np.arange(block_size)).reshape(-1)
+
+
+def _chunk_rows(row_map, base, lo, start, stop, width, nowhere):
+    """A chunk program's ``[width]`` write rows for positions
+    ``[start:stop)`` out of a ``row_map`` that starts at position ``base``:
+    the positions under ``lo`` and the padding write ``nowhere``."""
+    rows = np.full((width,), nowhere, dtype="int64")
+    if lo < stop:
+        rows[lo - start:stop - start] = row_map[lo - base:stop - base]
+    return rows
 
 
 class SeqKV:
@@ -77,13 +114,16 @@ class SeqKV:
     radix-shared blocks already hold, never to be rewritten; ``reserve``
     what is left of its reservation: blocks promised and not yet opened."""
 
-    __slots__ = ("blocks", "row_map", "table", "reserve", "shared_len", "_m")
+    __slots__ = ("blocks", "row_map", "table", "reserve", "shared_len",
+                 "windows", "_m")
 
-    def __init__(self, model, blocks, shared_len=0, reserve=0):
+    def __init__(self, model, blocks, shared_len=0, reserve=0, windows=()):
         self._m = model
         self.blocks = blocks
         self.shared_len = shared_len
         self.reserve = reserve
+        # its footing in each of the model's window groups (`WindowKV`)
+        self.windows = windows
         self.row_map = np.zeros(model.max_len, dtype="int64")
         self.remap()
 
@@ -91,11 +131,8 @@ class SeqKV:
         """``row_map`` and ``table`` after ``blocks`` changed. What lies
         past the blocks is left as it was, and is never read."""
         m = self._m
-        bs = m.block_size
-        for i, b in enumerate(self.blocks):
-            lo = i * bs
-            hi = min(lo + bs, m.max_len)
-            self.row_map[lo:hi] = b.row0 + np.arange(hi - lo)
+        rows = _block_rows(self.blocks, m.block_size)[:m.max_len]
+        self.row_map[:len(rows)] = rows
         self.table = m.block_table(self.blocks)
 
     def row_of(self, p):
@@ -105,11 +142,50 @@ class SeqKV:
     def chunk_write_rows(self, start, stop, width):
         """The chunk program's ``[width]`` write rows for positions
         ``[start:stop)``: a shared position and the padding write nowhere."""
-        rows = np.full((width,), self._m.rows, dtype="int64")
-        lo = max(start, self.shared_len)
-        if lo < stop:
-            rows[lo - start:stop - start] = self.row_map[lo:stop]
-        return rows
+        return _chunk_rows(self.row_map, 0, max(start, self.shared_len),
+                           start, stop, width, self._m.rows)
+
+
+class WindowKV:
+    """One sequence's footing in ONE window group (model.py ``KVGroup``).
+    ``blocks`` is the chain's LIVE part and ``first`` the index, in the
+    whole chain, of ``blocks[0]``; ``row_map`` and ``table`` (what the
+    chunk program and the decode step are fed) start at that block, so
+    position ``p`` lies at ``row_map[p - first * block_size]``. ``reserve``
+    is what the pool has promised the sequence and it has not opened,
+    ``left`` the blocks it has yet to open over its life, ``limit`` the most
+    it holds at once: ``reserve == min(limit - len(blocks), left)``."""
+
+    __slots__ = ("blocks", "first", "reserve", "left", "limit", "row_map",
+                 "table", "_m", "_rows")
+
+    def __init__(self, model, group, life_blocks):
+        self._m = model
+        self._rows = group.num_blocks * model.block_size
+        self.blocks, self.first = [], 0
+        self.left = life_blocks
+        most = model.window_chunk_blocks(group)
+        self.limit = self.reserve = min(life_blocks, most)
+        self.row_map = np.zeros(most * model.block_size, "int64")
+        self.table = np.zeros(model.window_table_blocks(group), "int32")
+
+    def remap(self):
+        """``row_map`` and ``table`` after ``blocks`` or ``first`` changed;
+        what lies past the blocks is never read."""
+        bs = self._m.block_size
+        rows = _block_rows(self.blocks, bs)
+        self.row_map[:len(rows)] = rows
+        n = min(len(self.blocks), len(self.table))
+        self.table[:n] = rows[:n * bs:bs] // bs
+
+    def row_of(self, p):
+        return self.row_map[p - self.first * self._m.block_size]
+
+    def chunk_write_rows(self, start, stop, width):
+        """The chunk program's ``[width]`` write rows in this group for
+        positions ``[start:stop)``; the padding writes nowhere."""
+        return _chunk_rows(self.row_map, self.first * self._m.block_size,
+                           start, start, stop, width, self._rows)
 
 
 class KVStore:
@@ -138,8 +214,13 @@ class KVStore:
         self.tier = HostKVTier(capacity_bytes=tier_bytes)
         if tier_bytes:
             self.pool.attach_tier(self.tier, read_rows=self._writeback)
-        self.reserves = (not tier_bytes and model.num_blocks
-                         < model.slots * model.blocks_per_slot)
+        # a window group's pool, by the group's place in the model's list;
+        # what happens in them is counted here and not by the pools
+        self.window_pools = [BlockPool(g.num_blocks, model.block_size)
+                             for g in model.window_groups]
+        self.reserves = (not tier_bytes and (
+            model.num_blocks < model.slots * model.blocks_per_slot
+            or bool(self.window_pools)))
         # blocks the paged-attention kernel copies as one unit at this
         # geometry (0: no kernel serves it), to count a step's units
         self.copy_unit = paged_copy_unit(
@@ -172,6 +253,16 @@ class KVStore:
                 "engine with prefix_cache_size=0 and host_tier_mb=0 (got "
                 f"prefix_cache_size={prefix_cache_size}, "
                 f"host_tier_mb={tier_bytes >> 20})")
+        if model.window_groups and (prefix_cache_size or tier_bytes):
+            raise EnforceError(
+                f"model {model.label} keeps attention layers in window "
+                "groups, which give back the blocks behind a sequence's "
+                "window: a block that was given back can be neither shared "
+                "by a later prompt nor restored from the host KV tier, and "
+                "the prefix cache and the tier hold whole prefixes. Host "
+                "it on an engine with prefix_cache_size=0 and "
+                f"host_tier_mb=0 (got prefix_cache_size={prefix_cache_size}"
+                f", host_tier_mb={tier_bytes >> 20})")
         if model.chunks_only and tier_bytes:
             raise EnforceError(
                 f"model {model.label} has no inject program: what the host "
@@ -211,11 +302,21 @@ class KVStore:
         that this round's picks will take; if not it is held back. A chain
         that can NEVER fit goes on, to fail loudly at its admission."""
         need = self.chain(req)
-        if (need <= self.free_blocks - taken
-                or need > self._model.num_blocks):
+        if ((need <= self.free_blocks - taken
+             or need > self._model.num_blocks)
+                and all(n <= pool.free_count or n > pool.num_blocks
+                        for n, pool in zip(self._window_needs(need),
+                                           self.window_pools))):
             return True
         self.hold_back(req)
         return False
+
+    def _window_needs(self, chain):
+        """What a request whose whole sequence takes ``chain`` blocks is
+        promised in each window group: what it can hold there at once."""
+        m = self._model
+        return [min(chain, m.window_chunk_blocks(g))
+                for g in m.window_groups]
 
     def hold_back(self, req):
         """The pool cannot cover ``req``'s chain yet: counted once a
@@ -237,8 +338,19 @@ class KVStore:
                 f"the request's chain of {chain} blocks (prompt and answer)"
                 f" can never fit a pool of {m.num_blocks}; shorten it or "
                 "host the model with more blocks")
+        for need, pool in zip(self._window_needs(chain), self.window_pools):
+            if need > pool.num_blocks:
+                self._never_fits(
+                    f"the {need} blocks the request can hold at once in a "
+                    f"window group can never fit its pool of "
+                    f"{pool.num_blocks}; host the model with more blocks")
         if chain:
             if not self.pool.reserve(chain):
+                self.hold_back(req)
+                return None
+            windows = self._promise_windows(chain)
+            if windows is None:
+                self.pool.release((), chain)
                 self.hold_back(req)
                 return None
             held = self.pool.reserved
@@ -249,7 +361,7 @@ class KVStore:
             # less what the prompt's blocks used up of the promise
             # (reserved moves on this thread alone)
             return SeqKV(m, blocks, shared_len,
-                         chain - (held - self.pool.reserved))
+                         chain - (held - self.pool.reserved), windows)
         blocks, shared_len = self.pool.acquire_for_prompt(req.prompt)
         if blocks is not None:
             return SeqKV(m, blocks, shared_len)
@@ -259,6 +371,64 @@ class KVStore:
                 f" free of {m.num_blocks}) and the prompt alone can never "
                 "fit; shorten the prompt or host the model with more blocks")
         return None
+
+    def _promise_windows(self, chain):
+        """A `WindowKV` a window group for a sequence of ``chain`` blocks,
+        each promised what it can hold at once, or None, and nothing
+        promised, where a group's pool cannot cover that now."""
+        m = self._model
+        windows = tuple(WindowKV(m, g, chain) for g in m.window_groups)
+        for i, (w, pool) in enumerate(zip(windows, self.window_pools)):
+            if not pool.reserve(w.reserve):
+                for v, given in zip(windows[:i], self.window_pools):
+                    given.release((), v.reserve)
+                return None
+            self._metrics.incr("kv_blocks_reserved_window", w.reserve)
+        return windows
+
+    def release_behind(self, kv, position):
+        """Give back, in every window group, the blocks of ``kv`` that lie
+        wholly behind the window of a query at ``position`` (the next one
+        the sequence launches: the module docstring says why that is
+        safe), and renew the promise for as many of them as the sequence
+        has yet to open. Returns the blocks given back."""
+        m = self._model
+        bs, given = m.block_size, 0
+        for g, w, pool in zip(m.window_groups, kv.windows,
+                              self.window_pools):
+            first = max(position - g.window + 1, 0) // bs
+            n = min(first - w.first, len(w.blocks))
+            if n <= 0:
+                continue
+            dead, w.blocks = w.blocks[:n], w.blocks[n:]
+            w.first += n
+            keep = min(w.limit - len(w.blocks), w.left) - w.reserve
+            pool.recycle(dead, keep)
+            w.reserve += keep
+            w.remap()
+            given += n
+        if given:
+            self._metrics.incr("kv_window_blocks_released", given)
+        return given
+
+    def open_windows(self, kv, stop):
+        """Make every position below ``stop`` writable in every window
+        group, out of the sequence's promise there (which `acquire` sized
+        so that it cannot run out once `release_behind` has run for the
+        launch's first position)."""
+        bs = self._model.block_size
+        for w, pool in zip(kv.windows, self.window_pools):
+            need = -(-stop // bs) - w.first - len(w.blocks)
+            if need <= 0:
+                continue
+            if need > w.reserve:
+                raise RuntimeError(
+                    f"a window group's promise of {w.limit} blocks does "
+                    f"not cover {need} more beside {len(w.blocks)} live")
+            w.blocks += pool.open_promised(need)
+            w.reserve -= need
+            w.left -= need
+            w.remap()
 
     def _never_fits(self, why):
         self._metrics.incr("blocks_exhausted")
@@ -280,6 +450,8 @@ class KVStore:
         is empty (nothing changed; the scheduler parks, drains or
         rejects). RuntimeError on a pool invariant violation,
         `ArenaInvalidError` where the copy-on-write's inject failed."""
+        if kv.windows:
+            self.open_windows(kv, cursor + 1)
         blocks, nb, cow = self.pool.ensure_appendable(
             kv.blocks, cursor, promised=kv.reserve > 0)
         if blocks is None:
@@ -315,8 +487,13 @@ class KVStore:
     def release(self, kv):
         """Give back a sequence's blocks and the unopened part of its
         reservation. Registered blocks stay cached for the next prompt."""
-        if kv is not None and kv.blocks:
+        if kv is None:
+            return
+        if kv.blocks:
             self.pool.release(kv.blocks, kv.reserve)
+        for w, pool in zip(kv.windows, self.window_pools):
+            pool.release(w.blocks, w.reserve)
+            w.blocks, w.reserve = [], 0
 
     # -- rows: the one write and the one read -------------------------------
     def write_rows(self, target, lo, hi, source, span, fault=None, **attrs):
@@ -464,8 +641,10 @@ class KVStore:
         """Index a prompt's freshly written blocks so later prompts share
         them; its partial tail too where ``live`` (the one-shot prefill's
         host copy) can back a copy-on-write. A recurrent model's blocks
-        are never shared: its state is no function of them."""
-        if self._model.recurrent:
+        are never shared: its state is no function of them. Nor are those
+        of a model with window groups: the rows a later prompt would need
+        there may have been given back."""
+        if self._model.recurrent or self._model.window_groups:
             return
         host_rows = None
         if live is not None:
@@ -495,14 +674,22 @@ class KVStore:
 
         m = self._model
         scope = self._scope()
-        for n in (n for pair in m.state_names for n in pair):
-            scope.set(n, jax.device_put(
-                jnp.zeros((m.rows, m.kv_width), m.kv_dtype), self._device))
+        sized = [(m.state_names, m.rows)] + [
+            (g.state_names, g.num_blocks * m.block_size)
+            for g in m.window_groups]
+        for names, rows in sized:
+            for n in (n for pair in names for n in pair):
+                scope.set(n, jax.device_put(
+                    jnp.zeros((rows, m.kv_width), m.kv_dtype), self._device))
         self.pool.reset()
+        for pool in self.window_pools:
+            pool.reset()
 
     def stats(self):
         pool = self.pool.stats()
         return {
+            **({"window_pools": [p.stats() for p in self.window_pools]}
+               if self.window_pools else {}),
             "block_pool": pool,
             "block_dedup_ratio": pool["dedup_ratio"],
             "prefix_cache_entries": len(self.prefix),

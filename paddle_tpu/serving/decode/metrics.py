@@ -255,6 +255,24 @@ class DecodeMetrics(ServingMetrics):
         # whose commit pass was delivered; the slots' passes by kind are
         # serving_block_passes_total{kind=fill|commit}
         "block_tokens_decided", "blocks_committed",
+        # a model with window groups (model.py KVGroup; none of these moves
+        # for any other): blocks its sequences gave back from behind their
+        # windows, blocks promised to admissions there; per decode step and
+        # per chunk the rows the window layers' attention reads (from the
+        # first live block to the last position, a slot and group) beside
+        # the rows their context holds; and, summed over the decode steps,
+        # the blocks live and promised in the first (full) group's pool and
+        # in the window groups' beside those pools' sizes
+        "kv_window_blocks_released", "kv_blocks_reserved_window",
+        "attention_rows_read_step", "attention_rows_in_context_step",
+        "attention_rows_read_chunk", "attention_rows_in_context_chunk",
+        # what a chunk's window layers REQUIRE, a group: the rows from its
+        # first query's lower edge to its last position, and the (query,
+        # row) pairs the window's mask opens
+        "attention_window_rows_chunk", "attention_window_pairs_chunk",
+        "kv_blocks_live_full", "kv_blocks_live_window",
+        "kv_blocks_promised_full", "kv_blocks_promised_window",
+        "kv_pool_blocks_full", "kv_pool_blocks_window",
         # the device lane (lane.py), float seconds, moved only while the
         # tracer's lanes are on: the four parts of the wall time between the
         # watcher's stamps
@@ -283,6 +301,7 @@ class DecodeMetrics(ServingMetrics):
             "real prompt positions of one chunk launch", labels=labels,
             buckets=CHUNK_TOKEN_BUCKETS,
         )
+        self._released = None       # `_observe_release` makes it
         self._first_token = self._registry.histogram(
             "serving_decode_first_token_seconds",
             "submit to first token", labels=labels, buckets=TOKEN_BUCKETS,
@@ -423,6 +442,59 @@ class DecodeMetrics(ServingMetrics):
         self.incr("decode_block_slots", slots)
         self.incr("paged_copy_units", copy_units)
         self.incr("paged_copy_units_ahead", max(copy_units - 1, 0))
+
+    def observe_window_rows(self, length, block_size, windows):
+        """One stepping slot of a model with window groups: its window
+        layers read, a group, the rows from its first live block to its
+        cursor, of the ``length`` their context holds."""
+        self.incr("attention_rows_read_step", sum(
+            length - w.first * block_size for w in windows))
+        self.incr("attention_rows_in_context_step", length * len(windows))
+
+    def observe_window_chunk(self, start, stop, block_size, windows,
+                             sizes, given):
+        """One chunk ``[start, stop)`` of a model with window groups, whose
+        sequence gave ``given`` blocks back before it: a group's layers
+        read the rows from its first live block to ``stop`` of the ``stop``
+        their context holds, and are REQUIRED to read those from the first
+        query's lower edge on, over the pairs its window opens."""
+        self.incr("attention_rows_read_chunk", sum(
+            stop - w.first * block_size for w in windows))
+        self.incr("attention_rows_in_context_chunk", stop * len(windows))
+        for size in sizes:
+            self.incr("attention_window_rows_chunk",
+                      stop - max(start - size + 1, 0))
+            # sum over p in [start, stop) of min(size, p + 1)
+            ramp = min(max(size - 1, start), stop)
+            self.incr("attention_window_pairs_chunk",
+                      (ramp - start) * (start + ramp + 1) // 2
+                      + (stop - ramp) * size)
+        self._observe_release(given)
+
+    def _observe_release(self, blocks):
+        """Blocks one release gave back (its sum over a window is the
+        blocks released there); made when a model first releases."""
+        if self._released is None:
+            self._released = self._registry.histogram(
+                "serving_window_release_blocks",
+                "blocks one release behind the windows gave back",
+                labels={"engine": self.engine_label},
+                buckets=CHUNK_TOKEN_BUCKETS)
+        self._released.observe(blocks)
+
+    def observe_pools(self, full, windows, given):
+        """One decode step of a model with window groups, before which its
+        slots gave ``given`` blocks back: the blocks live and promised in
+        the first group's pool and in the window groups', beside the pools'
+        sizes."""
+        self._observe_release(given)
+        for name, pools in (("full", (full,)), ("window", windows)):
+            self.incr("kv_blocks_live_" + name,
+                      sum(p.live_count for p in pools))
+            self.incr("kv_blocks_promised_" + name,
+                      sum(p.reserved for p in pools))
+            self.incr("kv_pool_blocks_" + name,
+                      sum(p.num_blocks for p in pools))
 
     def observe_tokens(self, request):
         """At retirement: the request's time to first token and the mean

@@ -71,11 +71,48 @@ PR 10 slotted arena.
 
 import numpy as np
 
-__all__ = ["DecodeModel", "build_decoder_model"]
+__all__ = ["DecodeModel", "KVGroup", "build_decoder_model"]
 
 # additive-mask value: exp(-1e9) underflows to exactly 0.0 (the repo-wide
 # padding contract), so masked cache positions are bit-invisible
 NEG_INF = -1e9
+
+
+def window_table_blocks(window, block_size, blocks_per_slot):
+    """Blocks a slot can hold live in a window group when it STEPS at
+    ``p``: from the block of ``p - window + 1`` to the block of ``p``."""
+    return min(blocks_per_slot, (window + block_size - 2) // block_size + 1)
+
+
+def window_chunk_blocks(window, chunk_tokens, block_size, blocks_per_slot):
+    """Blocks a slot can hold live in a window group while a CHUNK runs:
+    from the block of ``start - window + 1`` to the block of the chunk's
+    last position."""
+    return min(blocks_per_slot,
+               (window + chunk_tokens + block_size - 3) // block_size + 1)
+
+
+class KVGroup:
+    """A FURTHER group of a model's attention layers: layers whose queries
+    see the last ``window`` positions (their own among them) and nothing
+    older, with arenas of their own (``state_names``, as
+    ``DecodeModel.state_names``: ``[num_blocks * block_size, kv_width]``
+    each) and a block pool of their own, ``num_blocks`` blocks. A sequence
+    holds in such a group only the blocks with a position inside the window
+    of its next query (kvstate.py ``WindowKV``); the programs read them
+    through a table and a row map that start at the sequence's first LIVE
+    block (``DecodeModel.fill_windows``, ``window_chunk_feeds``)."""
+
+    __slots__ = ("name", "state_names", "num_blocks", "window")
+
+    def __init__(self, name, state_names, num_blocks, window):
+        self.name = str(name)
+        self.state_names = [tuple(names) for names in state_names]
+        self.num_blocks = int(num_blocks)
+        self.window = int(window)
+        if self.window < 1 or self.num_blocks < 1:
+            raise ValueError(f"group {name}: window {window} and num_blocks "
+                             f"{num_blocks} have to be positive")
 
 
 class DecodeModel:
@@ -138,7 +175,23 @@ class DecodeModel:
     ``DEC_TOKEN`` ``[S, 2 B]`` the block state the pass before left on the
     device (``token_fetch``: tokens, decided bits); ``counts_fetch`` opens
     with ``2 S`` integers, each slot's decided position (-1: a commit
-    pass) and its token."""
+    pass) and its token.
+
+    **Layer groups.** A model's attention layers may fall into groups that
+    keep different rows. ``state_names``, ``num_blocks`` and the block
+    table of ``dec_step`` are the FIRST group's, whose layers see the whole
+    context: every model has it, and a model with no other is fed and run
+    as it ever was. ``window_groups`` lists the others (`KVGroup`). For
+    each, a slot's row of ``dec_step`` carries, after the first group's
+    table, ``length, low, write_row`` and a table of `window_table_blocks`
+    block ids that starts at the slot's first LIVE block: the step's
+    queries see rows ``[low, length)`` of what that table names
+    (`fill_windows`; ops/nn.py ``paged_window_feeds`` makes the bias and
+    the row map of them). The chunk program takes, a group, a span, a row
+    map and write rows of its own under the names `chunk_group_feeds`
+    gives, the row map ``[window_chunk_blocks * block_size]`` from the
+    first live block and the span's start counted from that block's first
+    position (`window_chunk_feeds`)."""
 
     # feed-name contract (fixed; the engine builds these arrays)
     DEC_TOKEN = "dec_token"
@@ -167,7 +220,7 @@ class DecodeModel:
                  version="1", builder=None, logits_mask=False,
                  token_fetch=None, kv_width=None, kv_dtype="float32",
                  slot_states=(), counts_fetch=None, count_names=(),
-                 passes=1, block_len=1, mask_token=None):
+                 passes=1, block_len=1, mask_token=None, window_groups=()):
         self.decode_program = decode_program
         self.prefill_program = prefill_program
         self.inject_program = inject_program
@@ -201,6 +254,13 @@ class DecodeModel:
         self.passes = int(passes)
         self.block_len = int(block_len)
         self.mask_token = mask_token
+        self.window_groups = list(window_groups)
+        if self.window_groups and (self.block_len > 1
+                                   or not self.chunks_only):
+            raise ValueError(
+                "a model with window groups is served by chunks alone, a "
+                "token a step: nothing re-injects rows that a group has "
+                "given back")
         if self.block_len > 1 and (self.block_size % self.block_len
                                    or self.max_len % self.block_len
                                    or self.chunk_tokens % self.block_len):
@@ -243,6 +303,36 @@ class DecodeModel:
         return sum(len(names) for names in self.state_names)
 
     @property
+    def all_state_names(self):
+        """``state_names`` of every group, the first group's first."""
+        return self.state_names + [
+            names for g in self.window_groups for names in g.state_names]
+
+    def window_table_blocks(self, group):
+        """Blocks a slot can hold live in ``group`` when it STEPS: from the
+        block of position ``p - window + 1`` to the block of ``p``, the
+        width of the group's table in ``dec_step``."""
+        return window_table_blocks(group.window, self.block_size,
+                                   self.blocks_per_slot)
+
+    def window_chunk_blocks(self, group):
+        """Blocks a slot can hold live in ``group`` while a CHUNK runs:
+        from the block of ``start - window + 1`` to the block of the
+        chunk's last position; what a request's admission reserves there
+        at most, and the blocks of the chunk program's row map."""
+        return window_chunk_blocks(group.window, self.chunk_tokens,
+                                   self.block_size, self.blocks_per_slot)
+
+    @property
+    def step_width(self):
+        """Columns of ``dec_step``: the head, a block pass's tokens, the
+        first group's table, then ``length, low, write_row`` and a table a
+        window group."""
+        return (self.step_table + self.blocks_per_slot
+                + sum(3 + self.window_table_blocks(g)
+                      for g in self.window_groups))
+
+    @property
     def blocks_per_slot(self):
         """Blocks a slot at ``max_len`` holds: its block table's width."""
         return -(-self.max_len // self.block_size)
@@ -269,10 +359,13 @@ class DecodeModel:
         every write row ``R``, every token left to ``dec_token``): the
         engine fills the rows of the slots that step (`fill_step`,
         `fill_block`)."""
-        feed = np.zeros((self.slots, self.step_table + self.blocks_per_slot),
-                        "int32")
+        feed = np.zeros((self.slots, self.step_width), "int32")
         feed[:, self.STEP_TOKEN] = -1
         feed[:, self.STEP_WRITE_ROW] = self.rows
+        at = self.step_table + self.blocks_per_slot
+        for g in self.window_groups:
+            feed[:, at + 2] = g.num_blocks * self.block_size
+            at += 3 + self.window_table_blocks(g)
         return feed
 
     def fill_step(self, feed, slot, position, table, write_row, token=-1):
@@ -283,7 +376,23 @@ class DecodeModel:
         ``dec_token`` holds it on the device."""
         feed[slot, :self.STEP_TABLE] = (token, position, position + 1,
                                         write_row)
-        feed[slot, self.STEP_TABLE:] = table
+        feed[slot, self.STEP_TABLE:self.STEP_TABLE + len(table)] = table
+
+    def fill_windows(self, feed, slot, position, windows):
+        """The window groups' part of slot ``slot``'s row, stepping at
+        ``position``: ``windows`` is the sequence's footing in each
+        (kvstate.py ``WindowKV``: ``first``, the index of its first live
+        block; ``table``; ``row_of``). Counted from that block's first
+        position, the step's queries see rows ``[low, length)``: ``low``
+        masks the rows of the oldest block that have left the window."""
+        at = self.step_table + self.blocks_per_slot
+        for g, w in zip(self.window_groups, windows):
+            base = w.first * self.block_size
+            feed[slot, at:at + 3] = (
+                position + 1 - base,
+                max(position - g.window + 1 - base, 0), w.row_of(position))
+            feed[slot, at + 3:at + 3 + len(w.table)] = w.table
+            at += 3 + self.window_table_blocks(g)
 
     def fill_block(self, feed, slot, start, table, write_row, tokens=None):
         """Slot ``slot`` runs a pass over the block at positions ``[start,
@@ -319,6 +428,28 @@ class DecodeModel:
             np.float32(0.0), np.float32(NEG_INF))
         return bias
 
+    @staticmethod
+    def chunk_group_feeds(index):
+        """The names of window group ``index``'s chunk feeds: its span,
+        row map and write rows."""
+        return tuple(f"{name}.g{index + 1}" for name in (
+            DecodeModel.CHU_SPAN, DecodeModel.CHU_ROWS,
+            DecodeModel.CHU_WRITE_ROWS))
+
+    def window_chunk_feeds(self, start, real, windows):
+        """The window groups' chunk feeds for ``real`` prompt positions
+        from ``start``: a group's span counts ``start`` from its first
+        live block's first position, as its row map does."""
+        feeds = {}
+        for i, w in enumerate(windows):
+            span, rows, wrows = self.chunk_group_feeds(i)
+            feeds[span] = np.array(
+                [start - w.first * self.block_size, real], "int32")
+            feeds[rows] = w.row_map
+            feeds[wrows] = w.chunk_write_rows(start, start + real,
+                                              self.chunk_tokens)
+        return feeds
+
     def block_table(self, blocks):
         """The ``[blocks_per_slot]`` block ids of a slot's block list (its
         row of ``dec_step``'s table; 0 past the last block)."""
@@ -334,10 +465,13 @@ class DecodeModel:
         persistent state and what the HBM budget gate reasons about.
         The slotted design's ``S * max_len`` rows become
         ``num_blocks * block_size``, sized to USED tokens."""
-        per = self.rows * self.kv_width * _itemsize(self.kv_dtype)
+        row = self.kv_width * _itemsize(self.kv_dtype)
         slot = sum(int(np.prod(shape)) * _itemsize(dt)
                    for _n, shape, dt in self.slot_states)
-        return per * self.arenas + slot
+        groups = sum(g.num_blocks * self.block_size * row
+                     * sum(len(names) for names in g.state_names)
+                     for g in self.window_groups)
+        return self.rows * row * self.arenas + groups + slot
 
     def slotted_equivalent_bytes(self):
         """What the PR 10 dense design would reserve for the same
@@ -352,8 +486,7 @@ class DecodeModel:
         s = self.slots
         sig = [
             (self.DEC_TOKEN, (s, self.step_state_width), "int64"),
-            (self.DEC_STEP, (s, self.step_table + self.blocks_per_slot),
-             "int32"),
+            (self.DEC_STEP, (s, self.step_width), "int32"),
         ]
         if self.logits_mask:
             # grammar-constrained decode: per-step [S, 1, V] additive
@@ -390,6 +523,12 @@ class DecodeModel:
         if self.recurrent:
             # whose rows of the per-slot state arrays the chunk advances
             sig.append((self.CHU_SLOT, (1,), "int64"))
+        for i, g in enumerate(self.window_groups):
+            span, rows, wrows = self.chunk_group_feeds(i)
+            sig += [(span, (2,), "int32"),
+                    (rows, (self.window_chunk_blocks(g) * self.block_size,),
+                     "int64"),
+                    (wrows, (c,), "int64")]
         return tuple(sig)
 
 

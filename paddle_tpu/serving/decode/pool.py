@@ -363,6 +363,11 @@ class BlockPool:
         return self._room_locked() if self._tier is None else (
             len(self._free) - self.reserved)
 
+    @property
+    def live_count(self):
+        """Blocks some owner holds: neither free nor cached."""
+        return self.num_blocks - len(self._free) - len(self._cached)
+
     def _room_locked(self):
         """Blocks an owner without a reservation may still be given."""
         return len(self._free) + len(self._cached) - self.reserved
@@ -634,6 +639,22 @@ class BlockPool:
                 else:
                     b.reset()
                     self._free.append(b.id)
+
+    def open_promised(self, n):
+        """``n`` fresh private blocks out of their owner's reservation,
+        each to be written whole."""
+        with self._lock:
+            out = [self._alloc_locked(promised=True) for _ in range(n)]
+        for b in out:
+            b.size_used = self.block_size
+        return out
+
+    def recycle(self, blocks, keep):
+        """`release` for an owner that goes on: ``blocks`` (private, read
+        by no launch still to be made) go back to the free list and
+        ``keep`` of them, at most all, are promised to the same owner
+        again, who opens as many later."""
+        self.release(blocks, -int(keep))
 
     def reset(self):
         """Arena wiped (relaunch path): every block returns to the free

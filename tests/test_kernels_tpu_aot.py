@@ -199,6 +199,25 @@ def test_flash_kernels_group_heads_at_every_geometry(label, v5e_devices):
                 == bh // g * (seq // 128))
 
 
+def test_moe_experts_takes_a_step_of_no_whole_sublane_tiles(v5e_devices):
+    """A step of 24 slots (trinity_large_preview) is no whole number of
+    bfloat16 sublane tiles: ``moe_experts`` pads it to 32 tokens that choose
+    no expert and Mosaic takes it, ONE custom call and no fallback to the
+    dense product over every held expert; the result has the step's rows."""
+    (case,) = [c for c in kernels.get("moe_experts").tpu_cases()
+               if c[0].startswith("t24_")]
+    _label, fn, arg_specs = case
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.fallback_counter().value
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert lowered.out_info.shape == arg_specs[0][0]
+
+
 #: ``_tpu_cases_ssm_update``'s labels and the heads of one slot a grid step
 #: of ``ssm_update`` carries there: the hybrid serving cells' layer (both
 #: publish 64 heads x 64 x 128: a slot's whole 2 MB state a grid step) and

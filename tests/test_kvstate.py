@@ -442,7 +442,8 @@ def test_reset_zeroes_the_arenas_and_empties_the_pool():
 def test_a_recurrent_model_is_refused_what_cannot_carry_its_state(
         tier_bytes, prefix):
     model = types.SimpleNamespace(recurrent=True, chunks_only=True,
-                                  fills_blocks=False, label="r@1")
+                                  fills_blocks=False, window_groups=[],
+                                  label="r@1")
     with pytest.raises(EnforceError, match="per-slot recurrent state"):
         KVStore.check_carries(model, tier_bytes, prefix)
     KVStore.check_carries(model, 0, 0)
@@ -450,7 +451,8 @@ def test_a_recurrent_model_is_refused_what_cannot_carry_its_state(
 
 def test_a_model_without_an_inject_program_is_refused_a_tier():
     model = types.SimpleNamespace(recurrent=False, chunks_only=True,
-                                  fills_blocks=False, label="c@1")
+                                  fills_blocks=False, window_groups=[],
+                                  label="c@1")
     with pytest.raises(EnforceError, match="no inject program"):
         KVStore.check_carries(model, 1 << 20, 0)
     KVStore.check_carries(model, 0, 4)

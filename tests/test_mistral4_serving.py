@@ -548,7 +548,10 @@ def test_every_loop_of_the_chunk_program_is_its_expanded_attention(
     assert (loops(step), loops(chunk)) == (0, layers)
     names = lambda text: re.findall(  # noqa: E731
         r'kernel_name = "(\w+)"', text)
-    assert names(step) == [attention.LATENT_STEP_KERNEL] * layers
+    # (beside the step's attention its routed experts' kernel: a step of
+    # 4 tokens is padded to a whole sublane tile inside `moe_experts`)
+    assert [n for n in names(step) if n != "moe_experts"] == [
+        attention.LATENT_STEP_KERNEL] * layers
     assert names(chunk) == [attention.LATENT_CHUNK_KERNEL] * layers
     assert attention.LATENT_CHUNK_KERNEL == "latent_chunk_attention"
     assert (served.value - before[0], fell.value - before[1]) == (layers, 0)

@@ -1,7 +1,7 @@
 """A served hybrid configuration (``nemotron3_nano_30b_a3b``,
 ``lfm2_24b_a2b``, ``ouro_2_6b``, ``sdar_30b_a3b``, ``granite_4_0_h_micro``,
-``mistral_small_4_119b``:
-the six families of ``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
+``mistral_small_4_119b``, ``trinity_large_preview``:
+the seven families of ``serving/decode/hybrid.py``, any whose file names a ``builder`` and a
 ``reference``) against its plain reference, outside any timed window, and
 the readings the cell's limits are set from (its traffic file; PERF.md
 section 2).
@@ -10,7 +10,7 @@ section 2).
         [--config nemotron3_nano_30b_a3b] [--traffic reasoning_steady]
         [--requests 32] [--steps 192]
         [--faults ssm,conv,kv,kv_all,positions,chunk_ssm,chunk_kv,chunks_kv,
-                  again]
+                  again,window_early,window_early_block]
         [--references float8_e4m3fn,operands:bfloat16,attention_multiplier=0.125]
         [--state-dtype bfloat16] [--kernels off] [--pattern MEM*E]
         [--passes 3] [--share-passes] [--stale-arena 1,7]
@@ -42,6 +42,12 @@ the slot dropped there, or the first attention layer's K arena left a chunk
 stale (``_chunk_fault``); ``chunks_kv`` puts that arena back after EVERY
 chunk launch, as ``kv`` does after every decode step (no prompt's K rows
 land in it: a chunk's queries find their own chunk's keys alone);
+``window_early`` and ``window_early_block`` are a model's with WINDOW
+GROUPS (``--config trinity_large_preview``): a sequence past its window
+gives the oldest block of its window group back one STEP early (whenever
+one of that block's rows is still inside the window) or one BLOCK early
+(in every step), and the block is reused: its rows inside the window read
+as another block's (the step's table names the next block in its place);
 ``again`` plants nothing and serves the prompts a second time AS THE POOL
 HOLDS THEM (a model without per-slot state meets its prompts' full blocks
 in place and prefills the rest alone): a served token may not depend on
@@ -121,7 +127,11 @@ def _stale(entry, fault):
         names = [n for n in k_arenas if n.endswith(tag)]
     else:
         names = [n for n, _s, _d in m.slot_states if "." + fault in n][:1]
-    if fault not in ("positions", "skip_commit") and not names:
+    early = {"window_early": 1, "window_early_block": m.block_size}.get(fault)
+    if early and not m.window_groups:
+        raise ValueError(f"{fault!r} is a model's with window groups")
+    if (fault not in ("positions", "skip_commit") and not early
+            and not names):
         raise ValueError(f"no state of the model answers to {fault!r}")
     launch = entry._run
 
@@ -140,6 +150,16 @@ def _stale(entry, fault):
         if fault == "positions":
             step = np.array(feeds[m.DEC_STEP])
             step[:, 1] += 1
+            feeds = dict(feeds, **{m.DEC_STEP: step})
+        if early:
+            # a stepping slot whose oldest live block of the window group
+            # has left the window but for its last ``early`` rows at most:
+            # given back early and reused, its rows are another block's
+            step = np.array(feeds[m.DEC_STEP])
+            at = m.step_table + m.blocks_per_slot
+            length, low = step[:, at], step[:, at + 1]
+            gone = (length > 0) & (low > 0) & (m.block_size - low <= early)
+            step[gone, at + 3] = step[gone, at + 4]
             feeds = dict(feeds, **{m.DEC_STEP: step})
         kept = [jnp.array(entry._scope.find_var(n), copy=True)
                 for n in names]
