@@ -42,7 +42,7 @@ __all__ = ["LoweredStep", "lower_step", "jit_compile", "verify_for_lowering",
 #: step closure here EMITS for an unchanged Program: the persistent tier
 #: would otherwise load the executable of the old lowering. A Pallas
 #: kernel's body has its own ``KernelSpec(version=)``.
-LOWERING_VERSION = 3
+LOWERING_VERSION = 4
 
 _JITS = obs_metrics.registry().counter(
     "lowering_jit_total", "jax.jit computations created via the chokepoint"

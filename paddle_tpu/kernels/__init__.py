@@ -668,10 +668,12 @@ def _parity_moe_grouped(rng):
 
 
 def _tpu_cases_moe_grouped():
-    """mistral_small_4_119b's chunk (512 and 2,048 tokens choosing 4 of
-    128, 16 held gated experts of width 2,048 at hidden 4,096) in
-    bfloat16, and a squared-relu geometry (nemotron3_nano_30b_a3b's
-    experts at a chunk of 1,024)."""
+    """mistral_small_4_119b's chunk (512, the cell's 1,024 and 2,048 tokens
+    choosing 4 of 128, 16 held gated experts of width 2,048 at hidden
+    4,096) and trinity_large_preview's (1,024 tokens choosing 4 of 256, 32
+    held gated experts of width 3,072 at hidden 3,072) in bfloat16, and a
+    squared-relu geometry (nemotron3_nano_30b_a3b's experts at a chunk of
+    1,024)."""
     import jax.numpy as jnp
 
     from paddle_tpu.kernels import moe
@@ -687,7 +689,8 @@ def _tpu_cases_moe_grouped():
                  ((T, k), "float32"), ((T,), "bool")]
                 + [((E, F, H), "bfloat16")] * matrices)
 
-    return [case(512, 4096, 2048, 16, 4, 3), case(2048, 4096, 2048, 16, 4, 3),
+    return [case(512, 4096, 2048, 16, 4, 3), case(1024, 4096, 2048, 16, 4, 3),
+            case(2048, 4096, 2048, 16, 4, 3), case(1024, 3072, 3072, 32, 4, 3),
             case(1024, 2688, 1856, 16, 6, 2)]
 
 
@@ -863,12 +866,13 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "moe_grouped", ("moe_routed_experts",), "tolerance", _parity_moe_grouped,
-    tpu_cases=_tpu_cases_moe_grouped,
+    tpu_cases=_tpu_cases_moe_grouped, version=2,
     doc="a prompt chunk's (token, held expert) pairs sorted by expert, each "
         "expert's rows in whole row tiles, one product a row tile over that "
-        "expert's matrices; dropless; taken where the rows it multiplies "
-        "are a quarter of the dense product's or fewer (kernels/moe.py "
-        "takes_grouped)",
+        "expert's matrices; the tokens' rows and their float32 sums stay in "
+        "VMEM, a row tile picks its rows and adds its pairs itself; "
+        "dropless; taken where the rows it multiplies are a quarter of the "
+        "dense product's or fewer (kernels/moe.py takes_grouped)",
 ))
 register(KernelSpec(
     "ssm_update", ("mamba2_mixer",), "tolerance", _parity_ssm_update,

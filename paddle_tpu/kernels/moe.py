@@ -18,7 +18,10 @@ handed none, ``relu(W_up,e . x)^2`` (two).
 (token, held expert) pairs sorted by expert, each expert's rows padded to
 whole row tiles, and one product a row tile over that expert's matrices:
 the operations follow the pairs the routing made, not ``tokens x held``.
-``takes_grouped`` is THE rule of when it runs.
+The sorted rows exist in VMEM alone, a row tile at a time: the kernel keeps
+the call's tokens and their float32 sums resident, picks a tile's rows out
+of the one and adds its pairs into the other. ``takes_grouped`` is THE rule
+of when it runs.
 
 ``experts_composite`` computes that part densely (every held expert over
 every token, weighted by ``c``): the reference lowering, the CPU path, the
@@ -155,23 +158,19 @@ def hidden_tile(tokens, hidden, ffn, dtype, matrices, interpret=False):
     return max(fits, default=0)
 
 
-def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated,
-                  summed=True):
+def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated):
     """``refs``: the up (and, ``gated``, the gate) matrix's tile, the down
     matrix's, the output, then the scratches: the up product (and the
     gate's) ``[T, F]`` float32 and the activation in the weights' dtype.
-    ``summed``: every grid row (an expert) adds into the ONE resident
-    output; without it a grid row (a row tile of one expert) writes the
-    output block of its own."""
+    Every grid row (an expert) adds into the ONE resident output."""
     ins, (o_ref, *acc, a_ref) = refs[:2 + gated], refs[2 + gated:]
     firsts, down_ref = ins[:-1], ins[-1]
     g, j = pl.program_id(0), pl.program_id(1)
     prec = _kernel_precision(down_ref.dtype)
 
-    if summed:
-        @pl.when((g == 0) & (j == 0))
-        def _():
-            o_ref[...] = jnp.zeros_like(o_ref)
+    @pl.when((g == 0) & (j == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(g < n_ref[0])
     def _():
@@ -199,10 +198,7 @@ def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated,
             jj = jnp.maximum(j - tiles, 0)
             out = jnp.dot(a_ref[...], down_ref[...], precision=prec,
                           preferred_element_type=jnp.float32)
-            if summed:
-                o_ref[jj] += out
-            else:
-                o_ref[jj] = out
+            o_ref[jj] += out
 
 
 def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
@@ -282,17 +278,21 @@ def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
 #: expert's pairs are padded to whole tiles of this many
 ROW_TILE = 128
 
-#: the grouped kernel's scoped-VMEM limit: a row tile's two ``[rows, F]``
-#: float32 products, its activation, the token and output blocks and both
-#: buffers of three weight blocks pass Mosaic's 16 MiB default at an expert
-#: width of 2,048 and a hidden size of 4,096. (Weight tiles of 1,024 lanes
-#: in place of ``hidden_tile``'s 256 read the same time on the chip: 2.11
-#: ms for 2.07 at 512 tokens, PR 56.)
+#: the grouped kernel's scoped-VMEM limit: beside a row tile's two ``[rows,
+#: F]`` float32 products, its activation and both buffers of three weight
+#: blocks (past Mosaic's 16 MiB default at an expert width of 2,048 and a
+#: hidden size of 4,096) the call keeps its tokens and their float32 sums
+#: RESIDENT (``_grouped_resident``: 24 MiB at 1,024 tokens of 4,096).
+#: (Weight tiles of 1,024 lanes in place of ``hidden_tile``'s 256 read the
+#: same time on the chip: 2.11 ms for 2.07 at 512 tokens, PR 56.)
 _GROUPED_VMEM_LIMIT = 64 * 1024 * 1024
+#: what of that limit is left to Mosaic's own scratch
+_GROUPED_VMEM_SPARE = 4 * 1024 * 1024
 
 
 def grouped_rows(tokens, k, held, row_tile=ROW_TILE):
-    """Rows of the grouped product's sorted buffer: every pair the routing
+    """Rows of the grouped product's layout (pairs sorted by expert: row
+    INDICES, no buffer of rows is made of them): every pair the routing
     can make (``tokens x min(k, held)``: dropless whatever the imbalance)
     in whole row tiles, and a ragged last tile for each held expert."""
     pairs = tokens * min(k, held)
@@ -338,11 +338,12 @@ def group_pairs(idx, w, mask, offset, held, rows, row_tile=ROW_TILE):
     (``offset .. offset + held - 1``) with ``mask[t]`` true. Pairs are
     grouped by expert, in token order within one, and expert ``e``'s group
     starts at a multiple of ``row_tile``. Returns ``(dest [T, k]: a pair's
-    row in the sorted buffer, ``rows`` where there is no pair; token
+    row in the layout, ``rows`` where there is no pair; token
     [rows]: the token each row reads (0 for padding); weight [rows]: its
     routing weight (0.0 for padding); tile_expert [rows / row_tile]: the
-    expert whose matrices a row tile multiplies; tiles: how many row tiles
-    hold a pair)``."""
+    expert whose matrices a row tile multiplies; tile_pairs [rows /
+    row_tile]: the pairs a row tile holds, its first rows (0 past the last
+    used tile); tiles: how many row tiles hold a pair)``."""
     T, k = idx.shape
     valid, hit = _held_pairs(idx, mask, offset, held)
     flat = hit.reshape(T * k, held).astype(jnp.int32)
@@ -366,8 +367,13 @@ def group_pairs(idx, w, mask, offset, held, rows, row_tile=ROW_TILE):
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends, at, side="right", method="compare_all"),
         held - 1)
+    first_row = jnp.arange(rows // row_tile) * row_tile
+    tile_pairs = jnp.where(
+        first_row < tiles * row_tile,
+        jnp.clip((start + counts)[tile_expert] - first_row, 0, row_tile), 0)
     return (dest.reshape(T, k), token, weight,
-            tile_expert.astype(jnp.int32), tiles.astype(jnp.int32))
+            tile_expert.astype(jnp.int32), tile_pairs.astype(jnp.int32),
+            tiles.astype(jnp.int32))
 
 
 def grouped_counts(idx, mask, offset, held, row_tile=ROW_TILE):
@@ -382,12 +388,103 @@ def grouped_counts(idx, mask, offset, held, row_tile=ROW_TILE):
                       jnp.sum((counts > 0).astype(jnp.int32))])
 
 
-def _gather_pairs(ys, dest):
-    """Token ``t``'s result: the sum of its pairs' rows of ``ys`` (``dest``
-    ``[T, k]``; a ``dest`` past the last row is no pair and adds 0)."""
-    picked = jnp.take(ys, dest.reshape(-1), axis=0, mode="fill",
-                      fill_value=0.0)
-    return jnp.sum(picked.reshape(dest.shape + (-1,)), axis=1)
+def _grouped_body(eid_ref, n_ref, pairs_ref, token_ref, x_hbm, tok_ref,
+                  c_ref, *refs, tiles, gated):
+    """``refs``: the up (and, ``gated``, the gate) matrix's tile, the down
+    matrix's, the output ``[T, H]`` in HBM, then the scratches: the tokens
+    and their float32 sums, both ``[tiles, T, tile]`` and RESIDENT for the
+    whole call; the row tile's pick of its tokens ``[rows, T]`` (one 1.0 a
+    row), the up product (and the gate's) ``[rows, F]`` float32, the
+    activation in the weights' dtype, the down product ``[tiles, rows,
+    tile]`` float32; the copies' semaphores. Nothing of ``rows`` rows is in
+    HBM: a row tile picks its tokens' rows out of the resident ones on the
+    MXU (exact: a row of the pick is one 1.0, the sum float32) and adds its
+    down product's first ``pairs_ref[m]`` rows, the pairs, each into its
+    token's sum."""
+    ins, o_hbm = refs[:2 + gated], refs[2 + gated]
+    xs_ref, sum_ref, pick_ref, *acc, a_ref, y_ref, sem = refs[3 + gated:]
+    firsts, down_ref = ins[:-1], ins[-1]
+    m, j = pl.program_id(0), pl.program_id(1)
+    last = (m == pl.num_programs(0) - 1) & (j == 2 * tiles - 1)
+    prec = _kernel_precision(down_ref.dtype)
+    rows, tile = y_ref.shape[1:]
+
+    def copies(inward):
+        """A hidden tile's columns of the tokens in, or of the sums out:
+        the ``[T, H]`` array is turned by the copy engine, not by XLA."""
+        for n in range(tiles):
+            columns = (x_hbm if inward else o_hbm).at[:, pl.ds(n * tile, tile)]
+            yield pltpu.make_async_copy(
+                *((columns, xs_ref.at[n]) if inward
+                  else (sum_ref.at[n], columns)), sem.at[n])
+
+    @pl.when((m == 0) & (j == 0))
+    def _():
+        for copy in copies(True):
+            copy.start()
+        sum_ref[...] = jnp.zeros_like(sum_ref)
+        for copy in copies(True):
+            copy.wait()
+
+    @pl.when(m < n_ref[0])
+    def _():
+        @pl.when(j == 0)
+        def _():
+            for h_ref in acc:
+                h_ref[...] = jnp.zeros_like(h_ref)
+            at = jax.lax.broadcasted_iota(jnp.int32, pick_ref.shape, 1)
+            pick_ref[...] = (at == tok_ref[...]).astype(pick_ref.dtype)
+
+        @pl.when(j < tiles)
+        def _():
+            xt = jnp.dot(pick_ref[...], xs_ref[jnp.minimum(j, tiles - 1)],
+                         precision=prec, preferred_element_type=jnp.float32
+                         ).astype(pick_ref.dtype)
+            for w_ref, h_ref in zip(firsts, acc):
+                h_ref[...] += jax.lax.dot_general(
+                    xt, w_ref[...], (((1,), (1,)), ((), ())), precision=prec,
+                    preferred_element_type=jnp.float32)
+
+        @pl.when(j == tiles)
+        def _():
+            a_ref[...] = _act(
+                acc[0][...], acc[1][...] if gated else None,
+                c_ref[...]).astype(a_ref.dtype)
+
+        @pl.when(j >= tiles)
+        def _():
+            y_ref[jnp.maximum(j - tiles, 0)] = jnp.dot(
+                a_ref[...], down_ref[...], precision=prec,
+                preferred_element_type=jnp.float32)
+
+        @pl.when(j == 2 * tiles - 1)
+        def _():
+            def add(r, carry):
+                to = pl.ds(token_ref[m * rows + r], 1)
+                sum_ref[:, to, :] += y_ref[:, pl.ds(r, 1), :]
+                return carry
+
+            jax.lax.fori_loop(0, pairs_ref[m], add, 0)
+
+    @pl.when(last)
+    def _():
+        for copy in copies(False):
+            copy.start()
+        for copy in copies(False):
+            copy.wait()
+
+
+def _grouped_resident(hidden, ffn, tile, dtype, matrices, row_tile):
+    """Tokens one call of the grouped kernel can keep resident (their rows
+    in the weights' dtype, their float32 sums and a column of every row
+    tile's pick) beside what a row tile takes (its down product, its up
+    products and activation, both buffers of the weight blocks) under
+    ``_GROUPED_VMEM_LIMIT``, in whole lane tiles of the pick; 0: none."""
+    size = jnp.dtype(dtype).itemsize
+    a_tile = (row_tile * (4 * hidden + ffn * (4 * (matrices - 1) + size))
+              + 2 * matrices * ffn * tile * size)
+    room = _GROUPED_VMEM_LIMIT - _GROUPED_VMEM_SPARE - a_tile
+    return max(room // (hidden * (size + 4) + row_tile * size), 0) // 128 * 128
 
 
 def moe_grouped(x, idx, w, mask, offset, w_up, w_down, w_gate=None,
@@ -398,28 +495,41 @@ def moe_grouped(x, idx, w, mask, offset, w_up, w_down, w_gate=None,
     tiles, 2 * hidden tiles)``: a row tile's ``x . W_up`` (and ``x .
     W_gate``) accumulate over tiles of the hidden size into ``[rows, F]``
     scratches, then the down product's columns leave tile by tile; a row
-    tile past the last used one does nothing and copies nothing. Dropless:
-    the sorted buffer holds every pair the routing can make
+    tile past the last used one does nothing and copies nothing. The
+    tokens' rows and their sums stay in VMEM for the whole call
+    (``_grouped_body``): only ``[T, H]`` arrays enter and leave, whatever
+    the rows. Dropless: the layout holds every pair the routing can make
     (``grouped_rows``), so all tokens on one expert, or an expert with
-    none, are the same program. Falls back to the dense composite, counted,
-    where Mosaic cannot take the geometry (``hidden_tile``) or inside a
-    manual region."""
+    none, are the same program. More tokens than a call can keep resident
+    (``_grouped_resident``) go in as many calls as it takes, each over its
+    own tokens' pairs. Falls back to the dense composite, counted, where
+    Mosaic cannot take the geometry (``hidden_tile``) or inside a manual
+    region."""
     t, hidden = x.shape
     held, ffn, _ = w_up.shape
     firsts = [w_up] if w_gate is None else [w_up, w_gate]
     tile = hidden_tile(row_tile, hidden, ffn, w_up.dtype, len(firsts) + 1,
                        interpret)
-    if vma_names(x) or not tile:
+    resident = tile and _grouped_resident(hidden, ffn, tile, w_up.dtype,
+                                          len(firsts) + 1, row_tile)
+    if vma_names(x) or not resident:
         fallback_counter().inc()
         return experts_composite(x, held_weights(idx, w, mask, offset, held),
                                  w_up, w_down, w_gate)
+    if t > resident:
+        calls = -(-t // resident)
+        each = -(-t // calls)
+        return jnp.concatenate([moe_grouped(
+            x[at:at + each], idx[at:at + each], w[at:at + each],
+            mask[at:at + each], offset, w_up, w_down, w_gate, interpret,
+            row_tile) for at in range(0, t, each)])
+    pad = -t % 128
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
     rows = grouped_rows(t, idx.shape[1], held, row_tile)
-    dest, token, weight, tile_expert, used = group_pairs(
+    _dest, token, weight, tile_expert, tile_pairs, used = group_pairs(
         idx, w, mask, offset, held, rows, row_tile)
     tiles, M = hidden // tile, rows // row_tile
-    xs = jnp.take(x.astype(w_up.dtype), token, axis=0)         # [rows, H]
-    xs = jnp.swapaxes(xs.reshape(rows, tiles, tile), 0, 1)
-    cols = weight.reshape(rows, 1)
 
     def tile_of(m, n_ref):
         return jnp.minimum(m, jnp.maximum(n_ref[0] - 1, 0))
@@ -427,37 +537,40 @@ def moe_grouped(x, idx, w, mask, offset, w_up, w_down, w_gate=None,
     def step(m, j, n_ref):
         return jnp.where(m < n_ref[0], j, 2 * tiles - 1)
 
+    a_row = pl.BlockSpec((row_tile, 1),
+                         lambda m, j, e, n, *_: (tile_of(m, n), 0))
     first = pl.BlockSpec(
-        (None, ffn, tile), lambda m, j, e, n: (
+        (None, ffn, tile), lambda m, j, e, n, *_: (
             e[m], 0, jnp.minimum(step(m, j, n), tiles - 1)))
     out = pl.pallas_call(
-        functools.partial(_experts_body, tiles=tiles,
-                          gated=w_gate is not None, summed=False),
+        functools.partial(_grouped_body, tiles=tiles,
+                          gated=w_gate is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=4,
             grid=(M, 2 * tiles),
             in_specs=[
-                pl.BlockSpec((tiles, row_tile, tile),
-                             lambda m, j, e, n: (0, tile_of(m, n), 0)),
-                pl.BlockSpec((row_tile, 1),
-                             lambda m, j, e, n: (tile_of(m, n), 0)),
+                pl.BlockSpec(memory_space=pl.ANY), a_row, a_row,
                 *[first for _ in firsts],
                 pl.BlockSpec(
-                    (None, ffn, tile), lambda m, j, e, n: (
+                    (None, ffn, tile), lambda m, j, e, n, *_: (
                         e[m], 0, jnp.maximum(step(m, j, n) - tiles, 0))),
             ],
-            out_specs=pl.BlockSpec((tiles, row_tile, tile),
-                                   lambda m, j, e, n: (0, tile_of(m, n), 0)),
-            scratch_shapes=[pltpu.VMEM((row_tile, ffn), jnp.float32)
-                            for _ in firsts]
-            + [pltpu.VMEM((row_tile, ffn), w_down.dtype)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((tiles, t + pad, tile), w_up.dtype),
+                            pltpu.VMEM((tiles, t + pad, tile), jnp.float32),
+                            pltpu.VMEM((row_tile, t + pad), w_up.dtype)]
+            + [pltpu.VMEM((row_tile, ffn), jnp.float32) for _ in firsts]
+            + [pltpu.VMEM((row_tile, ffn), w_down.dtype),
+               pltpu.VMEM((tiles, row_tile, tile), jnp.float32),
+               pltpu.SemaphoreType.DMA((tiles,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((tiles, rows, tile), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((t + pad, hidden), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_GROUPED_VMEM_LIMIT),
         interpret=interpret,
         name="moe_grouped",
-    )(tile_expert, used.reshape(1), xs, cols, *firsts, w_down)
-    ys = jnp.swapaxes(out, 0, 1).reshape(rows, hidden)
-    return _gather_pairs(ys, dest)
+    )(tile_expert, used.reshape(1), tile_pairs, token,
+      x.astype(w_up.dtype), token.reshape(rows, 1), weight.reshape(rows, 1),
+      *firsts, w_down)
+    return out[:t] if pad else out

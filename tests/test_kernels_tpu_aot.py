@@ -218,6 +218,156 @@ def test_moe_experts_takes_a_step_of_no_whole_sublane_tiles(v5e_devices):
     assert lowered.out_info.shape == arg_specs[0][0]
 
 
+#: ``moe_experts``' traced program (the call's grid, block maps, scratches
+#: and body: ``str(jax.make_jaxpr(...))``, which names no file and no line)
+#: at the step geometries of the five routed serving cells, tokens x hidden
+#: x width x held x matrices, as PR 60's PARENT traced it: sha256. The
+#: grouped product got a body of its own there and this kernel's lost an
+#: argument; the five cells' step must not have moved with it
+EXPERTS_DIGESTS = {
+    "nemotron3_nano_30b_a3b": ((32, 2688, 1856, 16, 2), "a51f5c97cdae16a0"),
+    "lfm2_24b_a2b": ((128, 2048, 1536, 8, 3), "26c19befe4b6345d"),
+    "sdar_30b_a3b": ((128, 2048, 768, 16, 3), "44f8cc6ccb8c0f2e"),
+    "mistral_small_4_119b": ((16, 4096, 2048, 16, 3), "0b5bf87580e762dc"),
+    "trinity_large_preview": ((24, 3072, 3072, 32, 3), "3d28d24cef18a061"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERTS_DIGESTS))
+def test_moe_experts_lowers_to_the_module_it_did(cell):
+    import hashlib
+
+    from paddle_tpu.kernels import moe
+
+    (T, H, F, E, matrices), digest = EXPERTS_DIGESTS[cell]
+    args = [jax.ShapeDtypeStruct((T, H), np.dtype("bfloat16")),
+            jax.ShapeDtypeStruct((T, E), np.dtype("float32"))] + [
+        jax.ShapeDtypeStruct((E, F, H), np.dtype("bfloat16"))] * matrices
+    text = str(jax.make_jaxpr(moe.moe_experts)(*args))
+    assert "moe.py" not in text and "name=moe_experts" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def _arrays_of_rows(text, rows):
+    """Shapes in a compiled program's text with a dimension of ``rows``
+    and more than a number a row: ``bf16[6144,4096]``, ``f32[6144,16,256]``
+    (the layout's own vectors, ``s32[6144]`` and ``f32[6144,1]``, are a
+    number a row)."""
+    shapes = set(re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", text))
+    return sorted(s for s in shapes if str(rows) in s.split(",")
+                  and np.prod([int(n) for n in s.split(",")]) > rows)
+
+
+#: the two cells whose chunks take the grouped product: the chunk's tokens,
+#: hidden size, expert width, held experts, choices (``_tpu_cases_moe_
+#: grouped`` labels a case by them)
+GROUPED_CELLS = {
+    "mistral_small_4_119b": (1024, 4096, 2048, 16, 4),
+    "trinity_large_preview": (1024, 3072, 3072, 32, 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_moe_grouped_serves_both_cells_chunk(cell, v5e_devices):
+    """At each cell's geometry the grouped product lowers through Mosaic,
+    ONE custom call and no fallback, and the compiled function holds no
+    array of ``grouped_rows`` rows but the layout's own vectors: what XLA
+    carried to the kernel and from it (the sorted rows made, turned,
+    written in float32, turned back, gathered) is gone."""
+    from paddle_tpu.kernels import moe
+
+    T, H, F, held, k = GROUPED_CELLS[cell]
+    (case,) = [c for c in kernels.get("moe_grouped").tpu_cases()
+               if c[0] == f"t{T}_h{H}_f{F}_e{held}_k{k}_m3_bf16"]
+    _label, fn, arg_specs = case
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.fallback_counter().value
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    rows = moe.grouped_rows(T, k, held)
+    assert rows in (6144, 8192) and f"s32[{rows}]" in text
+    assert _arrays_of_rows(text, rows) == []
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_no_chunk_program_holds_the_grouped_products_rows(cell, v5e_devices,
+                                                          monkeypatch):
+    """The chunk program of each of the two configurations, built at the
+    cell's size from a scope of SHAPES (no weights) and compiled for the
+    described chip: a ``moe_grouped`` call a routed layer, no fallback, and
+    no array of ``grouped_rows`` rows (``[6144, 4096]``, ``[8192, 3072]``,
+    in any dtype or tiling) outside them. PR 57 found a chunk launch 12 ms
+    slower when XLA moved such a 100 MB array out of VMEM; there is none to
+    place now."""
+    import importlib
+
+    from benchmark import manifest
+    from paddle_tpu.core import lowering
+    from paddle_tpu.kernels import moe, registry
+    from paddle_tpu import serving
+
+    bench = manifest.load_manifest()
+    cfg = manifest.load_config(bench, cell)
+    builder = importlib.import_module("benchmark.builders." + cfg["builder"])
+    keys = manifest.published(cfg, False)
+    settings = manifest.sizes(cfg["settings"], False)
+    model = manifest.model_sizes(cfg, False)
+    build = {"mistral4_engine": serving.build_latent_moe_model,
+             "afmoe_engine": serving.build_afmoe_model}[cfg["builder"]]
+    m = build(name=cell, version="1", dtype=settings["dtype"],
+              expert_rank=settings["expert_rank"],
+              initializer_range=settings["initializer_range"],
+              **{k: keys[k] for k in builder._BUILDER_KEYS}, **model)
+    sharding = SingleDeviceSharding(v5e_devices[0])
+
+    class Shapes:
+        """A scope of shapes: the programs' persistables, no values."""
+
+        def __init__(self):
+            self.vars = {
+                name: jax.ShapeDtypeStruct(tuple(v.shape), np.dtype(v.dtype),
+                                           sharding=sharding)
+                for program in (m.startup_program, m.chunk_program)
+                for block in program.blocks
+                for name, v in block.vars.items() if v.persistable}
+
+        def has_var(self, name):
+            return name in self.vars
+
+        def find_var(self, name):
+            return self.vars.get(name)
+
+    scope = Shapes()
+    monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+    sig = sorted(m.chunk_feed_sig())
+    before = kernels.fallback_counter().value
+    entry, _src = lowering.lower_step(
+        m.chunk_program, scope,
+        tuple((n, shape, str(np.dtype(dt))) for n, shape, dt in sig),
+        [m.chunk_logits_fetch], donate=True, use_cache=False, persist=False,
+        label="hlo")
+    text = entry.fn.lower(
+        tuple(jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+              for _n, shape, dt in sig),
+        tuple(scope.find_var(n) for n in entry.donated),
+        tuple(scope.find_var(n) for n in entry.readonly),
+        jax.ShapeDtypeStruct((2,), np.uint32, sharding=sharding),
+    ).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    routed = sum(op.type == "moe_routed_experts"
+                 for op in m.chunk_program.global_block().ops)
+    assert routed and len(re.findall(
+        r"= [^=]*custom-call\([^\n]*moe_grouped", text)) == routed
+    T, H, _F, held, k = GROUPED_CELLS[cell]
+    assert (T, H) == (model["chunk_tokens"], keys["hidden_size"])
+    rows = moe.grouped_rows(T, k, held)
+    assert rows in (6144, 8192) and f"s32[{rows}]" in text
+    assert _arrays_of_rows(text, rows) == []
+
+
 #: ``_tpu_cases_ssm_update``'s labels and the heads of one slot a grid step
 #: of ``ssm_update`` carries there: the hybrid serving cells' layer (both
 #: publish 64 heads x 64 x 128: a slot's whole 2 MB state a grid step) and

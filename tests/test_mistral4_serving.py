@@ -570,13 +570,12 @@ def test_every_loop_of_the_chunk_program_is_its_expanded_attention(
     mask = jnp.asarray(rng.rand(T) > 0.2)
     n = moe.grouped_rows(T, k, E, 8)
 
-    def outside_the_kernel(idx, w, mask, ys):
-        dest, *layout = moe.group_pairs(idx, w, mask, 4, E, n, 8)
-        return (moe._gather_pairs(ys, dest), layout,
+    def outside_the_kernel(idx, w, mask):
+        return (moe.group_pairs(idx, w, mask, 4, E, n, 8),
                 moe.grouped_counts(idx, mask, 4, E, 8))
 
     assert loops(jax.jit(outside_the_kernel).lower(
-        idx, w, mask, np.zeros((n, 16), "float32")).as_text()) == 0
+        idx, w, mask).as_text()) == 0
 
 
 @pytest.mark.parametrize("family", [
@@ -670,11 +669,53 @@ def test_the_rule_of_the_grouped_product():
     assert moe.grouped_rows(512, 4, 16) == (16 + 16) * 128
 
 
-@pytest.mark.parametrize("select", ["balanced", "all_on_one", "none_held"])
+#: a routing's selection bias over the router's 16 experts (held: 0-3 or
+#: 4-7), or the routing written out
+_SELECT = {
+    "balanced": np.zeros(16),
+    "all_on_one": np.where(np.arange(16) == 5, 100.0, 0.0),
+    "none_held": np.where(np.arange(16) < 8, -100.0, 0.0),
+    # experts 1 and 6 are chosen by nobody: a held expert with no pair
+    # between experts that have some
+    "an_expert_with_none": np.where(np.isin(np.arange(16), (1, 6)),
+                                    -100.0, 0.0),
+    # (as balanced: what differs is the tokens, and the mask)
+    "ragged_tokens": np.zeros(16),
+    "masked_but_three": np.zeros(16),
+}
+
+
+def _routing(select, rng, x, gate, mask, k):
+    """``(idx, w, mask)``: the router's under a selection bias, or written
+    out: a token with two of its four choices among experts 0-3 and two
+    among 4-7 (a rank that holds either four has two pairs a token, two
+    elsewhere); every token masked but three."""
+    T = x.shape[0]
+    if select == "two_held_two_elsewhere":
+        idx = np.stack([rng.randint(0, 2, T), rng.randint(2, 4, T),
+                        rng.randint(4, 6, T), rng.randint(6, 8, T)], 1)
+        w = rng.rand(T, 4).astype("float32") + 0.1
+        return jnp.asarray(idx), jnp.asarray(w / w.sum(1, keepdims=True)), mask
+    if select == "masked_but_three":
+        mask = jnp.asarray(np.isin(np.arange(T), (0, T // 2, T - 1)))
+    idx, w = moe.route(x, gate, jnp.asarray(_SELECT[select].astype(
+        "float32")), k, 1.0, True, score="softmax")
+    return idx, w, mask
+
+
+@pytest.mark.parametrize("select", [
+    "balanced", "all_on_one", "none_held", "an_expert_with_none",
+    "two_held_two_elsewhere", "masked_but_three", "ragged_tokens"])
 @pytest.mark.parametrize("gated", [True, False])
 def test_grouped_product_is_the_dense_one_under_imbalance(select, gated):
+    """The grouped product (interpreted) against the dense composite over
+    the same routing, whatever the imbalance: the kernel picks its tokens'
+    rows and sums a token's pairs itself, so every case goes through its
+    pick and its adds. ``ragged_tokens``: 50 tokens, no multiple of either
+    row tile nor of a lane tile (padded inside)."""
     rng = np.random.RandomState(5)
-    T, H, F, E, EA, k = 64, 256, 40, 4, 16, 3
+    T, H, F, E, EA = 50 if select == "ragged_tokens" else 64, 256, 40, 4, 16
+    k = 4 if select == "two_held_two_elsewhere" else 3
     x = jnp.asarray(rng.randn(T, H).astype("float32"))
     gate = jnp.asarray(rng.randn(EA, H).astype("float32"))
     w_gate, w_up, w_down = (
@@ -682,11 +723,7 @@ def test_grouped_product_is_the_dense_one_under_imbalance(select, gated):
         for _ in range(3))
     w_gate = w_gate if gated else None
     mask = jnp.asarray(rng.rand(T) > 0.2)
-    bias = {"balanced": np.zeros(EA),
-            "all_on_one": np.where(np.arange(EA) == 5, 100.0, 0.0),
-            "none_held": np.where(np.arange(EA) < 8, -100.0, 0.0)}[select]
-    idx, w = moe.route(x, gate, jnp.asarray(bias.astype("float32")), k, 1.0,
-                       True, score="softmax")
+    idx, w, mask = _routing(select, rng, x, gate, mask, k)
     for offset in (0, 4):
         c = moe.held_weights(idx, w, mask, offset, E)
         want = moe.experts_composite(x, c, w_up, w_down, w_gate)
@@ -699,12 +736,19 @@ def test_grouped_product_is_the_dense_one_under_imbalance(select, gated):
             # the layout it multiplied: every pair a row of its expert's
             # tiles, each expert's rows from a multiple of the tile on
             rows = moe.grouped_rows(T, k, E, tile)
-            dest, token, weight, tile_expert, used = (
+            dest, token, weight, tile_expert, tile_pairs, used = (
                 np.asarray(a) for a in moe.group_pairs(
                     idx, w, mask, offset, E, rows, tile))
             at = dest[dest < rows]
             assert len(set(at)) == len(at) == per_expert.sum()
             assert used == (-(-per_expert // tile)).sum()
+            # a tile's pairs are its first rows: its expert's, less the
+            # whole tiles before it
+            assert tile_pairs.sum() == per_expert.sum()
+            assert (tile_pairs[used:] == 0).all()
+            for i in range(used):
+                assert (weight[i * tile:i * tile + tile_pairs[i]] != 0).all()
+                assert not weight[i * tile + tile_pairs[i]:(i + 1) * tile].any()
             chosen = np.asarray(idx) - offset
             for t_, j in zip(*np.nonzero(dest < rows)):
                 assert token[dest[t_, j]] == t_
@@ -719,6 +763,60 @@ def test_grouped_product_is_the_dense_one_under_imbalance(select, gated):
             assert per_expert.max() == int(np.asarray(mask).sum())
         if select == "none_held" and offset == 0:
             assert not per_expert.any() and not np.asarray(want).any()
+        if select == "an_expert_with_none":
+            assert (per_expert == 0).sum() == 1 and per_expert[-1]
+        if select == "two_held_two_elsewhere":
+            held = np.asarray((c != 0).sum(1))
+            assert (held[np.asarray(mask)] == 2).all() and not held[
+                ~np.asarray(mask)].any()
+        if select == "masked_but_three":
+            assert per_expert.sum() <= 3 * k
+
+
+def test_the_grouped_product_keeps_resident_what_its_vmem_takes(monkeypatch):
+    """The kernel keeps a call's tokens and their float32 sums in VMEM, so
+    the wrapper reckons them against the limit it asks for, by geometry:
+    both cells' chunk of 1,024 tokens (and 2,048 at Mistral's widths) is
+    one call; under a limit that takes 128 tokens, 300 tokens go in three
+    calls of 100, each over its own tokens' pairs, and still equal the
+    composite; under one that takes none the composite runs, counted."""
+    from paddle_tpu import kernels
+
+    assert moe._grouped_resident(4096, 2048, 256, jnp.bfloat16, 3, 128) == 2048
+    assert moe._grouped_resident(3072, 3072, 128, jnp.bfloat16, 3, 128) >= 2048
+    rng = np.random.RandomState(13)
+    T, H, F, E, EA, k, tile = 300, 256, 40, 4, 16, 3, 16
+    x = jnp.asarray(rng.randn(T, H).astype("float32"))
+    gate = jnp.asarray(rng.randn(EA, H).astype("float32"))
+    w_gate, w_up, w_down = (
+        jnp.asarray(0.1 * rng.randn(E, F, H).astype("float32"))
+        for _ in range(3))
+    mask = jnp.asarray(rng.rand(T) > 0.2)
+    idx, w = moe.route(x, gate, jnp.zeros((EA,), jnp.float32), k, 1.0, True,
+                       score="softmax")
+    want = moe.experts_composite(x, moe.held_weights(idx, w, mask, 4, E),
+                                 w_up, w_down, w_gate)
+    # (a function of its own a trace: the limit is read while tracing)
+    run = lambda: lambda *a: moe.moe_grouped(  # noqa: E731
+        *a, 4, w_up, w_down, w_gate, interpret=True, row_tile=tile)
+    calls = lambda: str(jax.make_jaxpr(run())(x, idx, w, mask)).count(  # noqa: E731
+        "name=moe_grouped")
+    fell = kernels.fallback_counter()
+    assert calls() == 1
+    # float32 here: a token's row and its sums, a column of the pick; a row
+    # tile's down product, two up products, activation and weight blocks
+    a_token = H * (4 + 4) + tile * 4
+    a_tile = tile * (4 * H + F * (4 * 2 + 4)) + 2 * 3 * F * H * 4
+    monkeypatch.setattr(moe, "_GROUPED_VMEM_SPARE", 0)
+    for tokens, expect in ((128, 3), (0, 0)):
+        monkeypatch.setattr(moe, "_GROUPED_VMEM_LIMIT",
+                            a_tile + (tokens + 100) * a_token)
+        assert moe._grouped_resident(H, F, H, jnp.float32, 3, tile) == tokens
+        before = fell.value
+        assert calls() == expect
+        np.testing.assert_allclose(jax.jit(run())(x, idx, w, mask), want,
+                                   rtol=1e-5, atol=1e-5)
+        assert fell.value - before == 2 * (not tokens)
 
 
 def test_the_op_takes_the_grouped_product_by_its_rule(monkeypatch):

@@ -4,6 +4,7 @@ chip-free compile gives (PERF.md section 6, PR 56).
 
     python3 tools/check_latent_attention.py [--seed <n>] [--repeats 8]
         [--parts chunk,step,grouped] [--grouped-tokens 256,384,512]
+        [--grouped-geometry 4096x2048x16x128,3072x3072x32x256]
         [--kernel-tiles 512x512,256x256] [--rehearse-cpu]
 
 At ``mistral_small_4_119b``'s geometry (32 heads of 64 + 64 | 128 over a
@@ -25,10 +26,16 @@ bfloat16, under a shuffled block table:
 * *grouped*: the grouped product beside the dense composite at 512, 1,024
   and 2,048 tokens (``--grouped-tokens``: others), and at 512 with every
   token on one expert: what ``kernels/moe.py takes_grouped`` is held to.
+  At each of ``--grouped-geometry`` (16 held gated experts of width 2,048
+  at hidden 4,096 of 128, and 32 of width 3,072 at hidden 3,072 of 256),
+  choosing 4. The function ALONE in a program under the profiler:
+  ``grouped_kernel_ms`` is the ``moe_grouped`` events' device time a
+  launch, ``grouped_ms`` and ``dense_ms`` the whole module's (the layout,
+  and whatever XLA carries to the kernel and from it).
 
-Times are ``--repeats`` dependent calls inside one program, the best of
-three runs. A JSON line. ``--rehearse-cpu``: the same code at a toy size
-through the interpreter, no times."""
+The chunk's and the step's times are ``--repeats`` dependent calls inside
+one program, the best of three runs. A JSON line. ``--rehearse-cpu``: the
+same code at a toy size through the interpreter, no times."""
 
 import argparse
 import json
@@ -189,51 +196,96 @@ def _step(args, report, geometry, timed):
     report["step"] = out
 
 
+def _device_ms(fn, operands, repeats):
+    """``(kernel_ms, whole_ms)`` a launch of ``fn(*operands)`` ALONE in a
+    program: ONE profiler session over ``repeats`` launches one after the
+    other; the ``moe_grouped`` events' device time a launch (None where the
+    program holds none: the dense composite) and the whole module's."""
+    import glob
+    import shutil
+    import tempfile
+
+    import jax
+
+    from benchmark import trace as tr
+
+    run = jax.jit(fn)
+    operands = [jax.device_put(v) for v in operands]
+    jax.block_until_ready(run(*operands))
+    directory = tempfile.mkdtemp(prefix="check_grouped_")
+    try:
+        jax.profiler.start_trace(directory)
+        for _ in range(repeats):
+            out = run(*operands)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(
+            directory, "plugins", "profile", "*", "*.xplane.pb"))
+        device = tr.load_xplane(path)["devices"]["0"]
+    finally:
+        shutil.rmtree(directory, ignore_errors=True)
+    kernel = [e[2] for e in device["ops"] if "moe_grouped" in e[0]]
+    return (1e3 * sum(kernel) / repeats if kernel else None,
+            1e3 * sum(e[2] for e in device["modules"]) / repeats)
+
+
 def _grouped(args, report, geometry, timed):
+    import jax
     import jax.numpy as jnp
 
     from paddle_tpu.kernels import moe
 
-    H, F, E, EA, k = (256, 128, 4, 32, 4) if args.rehearse_cpu else (
-        4096, 2048, 16, 128, 4)
+    k = 4
+    geometries = [(256, 128, 4, 32)] if args.rehearse_cpu else [
+        tuple(int(n) for n in g.split("x"))
+        for g in args.grouped_geometry.split(",")]
     rng = np.random.default_rng(args.seed + 2)
     draw = lambda std, *shape: jnp.asarray(  # noqa: E731
         std * rng.standard_normal(shape, dtype=np.float32), jnp.bfloat16)
-    ws = [draw(0.02, E, F, H) for _ in range(3)]
-    gate = jnp.asarray(rng.standard_normal((EA, H), dtype=np.float32))
     interpret = True if args.rehearse_cpu else False
-    out = []
     tokens = ([int(t) for t in args.grouped_tokens.split(",")]
               if args.grouped_tokens else (64,) if args.rehearse_cpu
               else (512, 2048) if args.quick else (512, 1024, 2048))
-    for T, select in [(t, None) for t in tokens] + [
-            (64 if args.rehearse_cpu else 512, 3)]:
-        x = draw(1.0, T, H)
-        bias = np.zeros(EA, np.float32)
-        if select is not None:
-            bias[select] = 100.0
-        idx, w = moe.route(x, gate, jnp.asarray(bias), k, 1.0, True,
-                           score="softmax")
-        mask = jnp.ones((T,), bool)
-        c = moe.held_weights(idx, w, mask, 0, E)
-        grouped = _timer(lambda x: moe.moe_grouped(
-            x, idx, w, mask, 0, ws[1], ws[2], ws[0], interpret=interpret),
-            args.repeats, timed)
-        dense = _timer(lambda x: moe.experts_composite(
-            x, c, ws[1], ws[2], ws[0]), args.repeats, timed)
-        got = np.asarray(moe.moe_grouped(x, idx, w, mask, 0, ws[1], ws[2],
-                                         ws[0], interpret=interpret))
-        want = np.asarray(moe.experts_composite(x, c, ws[1], ws[2], ws[0]))
-        pairs, rows, touched = (int(n) for n in np.asarray(
-            moe.grouped_counts(idx, mask, 0, E)))
-        out.append({"tokens": T, "one_expert": select is not None,
-                    "pairs": pairs, "rows": rows, "touched": touched,
-                    "takes_grouped": bool(moe.takes_grouped(T, k, E, EA)),
-                    "grouped_ms": grouped(x), "dense_ms": dense(x),
-                    "differ": float(np.abs(got - want).max()
-                                    / max(float(np.abs(want).max()), 1e-9)),
-                    "weights_ms_at_819_GBps":
-                        1e3 * touched * 3 * H * F * 2 / 819e9})
+    out = []
+    for H, F, E, EA in geometries:
+        ws = [draw(0.02, E, F, H) for _ in range(3)]
+        gate = jnp.asarray(rng.standard_normal((EA, H), dtype=np.float32))
+        # (the matrices are operands: closed over they would be constants
+        # of every program, 0.8 and 1.8 GB each on the host)
+        grouped = lambda x, idx, w, mask, wg, wu, wd: (  # noqa: E731
+            moe.moe_grouped(x, idx, w, mask, 0, wu, wd, wg,
+                            interpret=interpret))
+        dense = lambda x, c, wg, wu, wd: moe.experts_composite(  # noqa: E731
+            x, c, wu, wd, wg)
+        for T, select in [(t, None) for t in tokens] + [
+                (64 if args.rehearse_cpu else 512, 3)]:
+            x = draw(1.0, T, H)
+            bias = np.zeros(EA, np.float32)
+            if select is not None:
+                bias[select] = 100.0
+            idx, w = moe.route(x, gate, jnp.asarray(bias), k, 1.0, True,
+                               score="softmax")
+            mask = jnp.ones((T,), bool)
+            c = moe.held_weights(idx, w, mask, 0, E)
+            got = np.asarray(jax.jit(grouped)(x, idx, w, mask, *ws))
+            want = np.asarray(jax.jit(dense)(x, c, *ws))
+            pairs, rows, touched = (int(n) for n in np.asarray(
+                moe.grouped_counts(idx, mask, 0, E)))
+            kernel_ms, whole_ms = _device_ms(
+                grouped, (x, idx, w, mask, *ws), args.repeats) if timed else (
+                    None, None)
+            out.append({
+                "hidden": H, "width": F, "held": E, "of": EA, "tokens": T,
+                "one_expert": select is not None, "pairs": pairs,
+                "rows": rows, "touched": touched,
+                "takes_grouped": bool(moe.takes_grouped(T, k, E, EA)),
+                "grouped_kernel_ms": kernel_ms, "grouped_ms": whole_ms,
+                "dense_ms": _device_ms(dense, (x, c, *ws), args.repeats)[1]
+                if timed else None,
+                "differ": float(np.abs(got - want).max()
+                                / max(float(np.abs(want).max()), 1e-9)),
+                "weights_ms_at_819_GBps":
+                    1e3 * touched * 3 * H * F * 2 / 819e9})
     report["grouped"] = out
 
 
@@ -246,6 +298,10 @@ def main(argv=None):
     ap.add_argument("--parts", default="chunk,step,grouped")
     ap.add_argument("--grouped-tokens", default="", help="the grouped "
                     "product at these token counts, comma-separated")
+    ap.add_argument("--grouped-geometry", default="4096x2048x16x128,"
+                    "3072x3072x32x256", help="the grouped product's experts "
+                    "as hidden x width x held x (the router's experts): "
+                    "mistral_small_4_119b's and trinity_large_preview's")
     ap.add_argument("--kernel-tiles", default="", help="the chunk kernel "
                     "also at these (queries a sub-tile)x(rows a tile), e.g. "
                     "512x512,256x256: a tuning sweep")
