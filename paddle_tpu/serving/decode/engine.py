@@ -50,15 +50,15 @@ alone):
 
 State of a second kind: a model may keep **per-slot recurrent state**
 (``DecodeModel.slot_states``), or K/V rows per (pass, layer) of a looped
-stack; either has no prefill and inject programs (``chunks_only``). The
-scheduler names the slot to the chunk program, sends EVERY prompt of such
-a model through chunked prefill from its first token (the chunk at
-position 0 starts the slot from zero: ``decode::state_reset``), parks no
-session of it, and refuses beam search and speculation at ``submit``
-(what the store refuses it: `kvstate.py`). Launch-ahead stays safe: the
-one step an ``eos_id`` wastes dirties only a state the next admission
-resets. A step's integer counts (``counts_fetch``) come back in the same
-fetch as its tokens.
+stack; either has no prefill and inject programs. The scheduler names the
+slot to the chunk program, sends EVERY prompt of a model without a prefill
+program through chunked prefill from its first token (the chunk at
+position 0 starts the slot from zero: ``decode::state_reset``), and asks
+the store what such a state lets it do (`KVStore.check_carries`): it parks
+no session of it and refuses beam search and speculation at ``submit``.
+Launch-ahead stays safe: the one step an ``eos_id`` wastes dirties only a
+state the next admission resets. A step's integer counts
+(``counts_fetch``) come back in the same fetch as its tokens.
 
 **Admission by reservation** (`KVStore.acquire`): where the store promises
 a request its whole block chain or nothing, a tenant's head request whose
@@ -985,7 +985,8 @@ class _ModelEntry:
         it was ever registered, so nothing is shared)."""
         m = self._model
         return bool(m.chunk_tokens and "chunk" in self._entries
-                    and (len(req.prompt) > m.chunk_tokens or m.chunks_only))
+                    and (len(req.prompt) > m.chunk_tokens
+                         or "prefill" not in self._entries))
 
     def _admit_picked(self, picked):
         for req in picked:
@@ -1101,11 +1102,10 @@ class _ModelEntry:
         the whole pool)."""
         st = self._slots[s]
         if (st is None or st.mode not in ("decode", "spec") or st.ahead
-                or self._model.chunks_only):
+                or not self.kv.restores):
             # a session whose last token is still on the device cannot
             # be spilled: its rows are known, its tokens are not. Nor can
-            # one of a model that nothing re-injects into: the tier keeps
-            # K/V rows, and a recurrent state is no function of them
+            # one whose rows the store could never put back
             return False
         req = st.request
         m = self._model
@@ -1542,8 +1542,7 @@ class _ModelEntry:
         toks[0, :real] = req.prompt[start:stop]
         pos = np.zeros((1, C), "int64")
         pos[0, :real] = np.arange(start, stop)
-        if st.kv.windows:
-            self._window_chunk(st.kv, start, stop)
+        self._window_chunk(st.kv, start, stop)
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::chunk") as ev:
@@ -1555,18 +1554,13 @@ class _ModelEntry:
                                 last=last, passes=m.passes,
                                 block_mask=m.fills_blocks, context=start,
                                 live_blocks=-(-stop // m.block_size))
+                # a group's span, row map and write rows (which never
+                # rewrite radix-shared rows)
                 feeds = {
                     DecodeModel.CHU_TOKENS: toks,
                     DecodeModel.CHU_POSITIONS: pos,
-                    DecodeModel.CHU_SPAN: m.chunk_span(start, real),
-                    DecodeModel.CHU_ROWS: st.kv.row_map,
-                    # never rewrite radix-shared rows
-                    DecodeModel.CHU_WRITE_ROWS: st.kv.chunk_write_rows(
-                        start, stop, C),
+                    **m.chunk_feeds(start, real, st.kv.groups),
                 }
-                if st.kv.windows:
-                    feeds.update(m.window_chunk_feeds(start, real,
-                                                      st.kv.windows))
                 if m.recurrent:
                     # the chunk at position 0 resets the slot's state
                     # (``decode::state_reset``: whatever a retired or
@@ -1600,19 +1594,19 @@ class _ModelEntry:
         return 1
 
     def _window_chunk(self, kv, start, stop):
-        """Before the chunk ``[start, stop)`` of a model with window
-        groups is launched: what lies behind the window of its first query
-        is given back (`KVStore.release_behind`), its own blocks are
-        opened there, and what its window layers will read is counted
-        beside what their context holds."""
-        with _span("decode::window_release") as sp:
-            given = self.kv.release_behind(kv, start)
-            if sp is not None:
-                sp.set(blocks=given, chunk=True)
+        """Before the chunk ``[start, stop)`` is launched: in every group
+        that has a window, what lies behind the window of the chunk's
+        first query is given back (`KVStore.release_behind`) and what the
+        group's layers will read is counted beside what their context
+        holds; then the chunk's own blocks are opened there."""
+        for g in self.kv.windowed:
+            with _span("decode::window_release") as sp:
+                given = self.kv.release_behind(kv, start, g)
+                if sp is not None:
+                    sp.set(blocks=given, chunk=True)
+            self._metrics.observe_window_chunk(start, stop, kv.groups[g],
+                                               given)
         self.kv.open_windows(kv, stop)
-        self._metrics.observe_window_chunk(
-            start, stop, self._model.block_size, kv.windows,
-            [g.window for g in self._model.window_groups], given)
 
     def _land_chunks(self, deferred):
         """Fetch every last chunk's logits row that is still on the
@@ -1897,8 +1891,7 @@ class _ModelEntry:
         row is right already). Returns the ``[V]`` logits row."""
         m = self._model
         step = m.step_feed()
-        m.fill_step(step, slot, p, kv.table,
-                    kv.row_of(p) if write else m.rows, int(token))
+        m.fill_step(step, slot, p, kv.groups, int(token), write)
         feeds = {DecodeModel.DEC_STEP: step,
                  DecodeModel.DEC_TOKEN: self._no_tokens}
         if m.logits_mask:
@@ -2373,8 +2366,7 @@ class _ModelEntry:
         groups = []     # beam groups with a live slot this step
         live_blocks = copy_units = 0
         launched = self._launched
-        if m.window_groups:
-            self._window_step()
+        self._window_step()
         for s in range(S):
             st = self._slots[s]
             if st is None or st.mode not in ("decode", "beam"):
@@ -2439,13 +2431,11 @@ class _ModelEntry:
                              st.kv.row_of(st.cursor),
                              None if st.ahead else st.block)
             else:
-                m.fill_step(step, s, st.cursor, st.kv.table,
-                            st.kv.row_of(st.cursor),
+                m.fill_step(step, s, st.cursor, st.kv.groups,
                             -1 if st.ahead else st.last_token)
-                if st.kv.windows:
-                    m.fill_windows(step, s, st.cursor, st.kv.windows)
-                    self._metrics.observe_window_rows(
-                        st.cursor + 1, m.block_size, st.kv.windows)
+                for g in self.kv.windowed:
+                    self._metrics.observe_window_rows(st.cursor + 1,
+                                                      st.kv.groups[g])
             reads = (st.cursor + m.block_len - 1) // m.block_size + 1
             live_blocks += reads
             if self.kv.copy_unit:
@@ -2466,19 +2456,19 @@ class _ModelEntry:
         return feeds, active, groups
 
     def _window_step(self):
-        """Before a step's feeds are built for a model with window groups:
-        every decoding slot gives back what lies behind the window of its
-        NEXT token (its cursor; a step in flight stands before it and was
-        launched already), under ONE span a step, and each group's pool
-        is observed."""
-        with _span("decode::window_release") as sp:
-            given = sum(self.kv.release_behind(st.kv, st.cursor)
-                        for st in self._slots
-                        if st is not None and st.mode == "decode")
-            if sp is not None:
-                sp.set(blocks=given, chunk=False)
-        self._metrics.observe_pools(self.kv.pool, self.kv.window_pools,
-                                    given)
+        """Before a step's feeds are built: in every group that has a
+        window, every decoding slot gives back what lies behind the window
+        of its NEXT token (its cursor; a step in flight stands before it
+        and was launched already), under ONE span a step and group, and
+        the group's pool is observed."""
+        for g in self.kv.windowed:
+            with _span("decode::window_release") as sp:
+                given = sum(self.kv.release_behind(st.kv, st.cursor, g)
+                            for st in self._slots
+                            if st is not None and st.mode == "decode")
+                if sp is not None:
+                    sp.set(blocks=given, chunk=False)
+            self._metrics.observe_pools(self.kv.pools, g, given)
 
     def _sample(self, fetched, active, groups, now, tokens_only):
         """The half of a decode step's host work that needs what it
@@ -3074,11 +3064,10 @@ class GenerationEngine:
                              "committed: sampling, beam search, grammars "
                              "and speculative decoding are not served "
                              "for it")
-        if m.chunks_only and (beam_width is not None
-                              or draft_model is not None):
+        if not entry.kv.restores and (beam_width is not None
+                                      or draft_model is not None):
             # a fork re-injects copied K/V rows and a verify re-derives
-            # them in a one-shot prefill; a model served by chunks alone
-            # has neither program (and a fork carries no recurrent state)
+            # them in a one-shot prefill: the store can put none back
             self._bad(entry, f"model {m.label} keeps per-slot recurrent "
                              "state or has no prefill and inject programs: "
                              "beam search and speculative decoding "

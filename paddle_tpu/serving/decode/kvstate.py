@@ -34,34 +34,38 @@ and the request waits in the queue (counted once). The sequence opens its
 blocks out of the promise (`open_block`) and `release` hands back the rest.
 Chosen by the pool's size and the tier's absence alone.
 
-**Layer groups.** A model may keep some attention layers' rows in groups of
-their own (model.py ``KVGroup``): layers that see the last ``window``
-positions and nothing older. Each such group has a `BlockPool` of its own
-(``KVStore.window_pools``) and a sequence a footing in it (`WindowKV`) that
-holds only the blocks with a position inside the window of the sequence's
-NEXT query: `KVStore.release_behind` gives the others back. When: the
-scheduler calls it for position ``p`` right before it builds the feeds of the
-launch whose first query stands at ``p`` (the step of the token at ``p``,
-the chunk that starts at ``p``). Every launch that read the blocks given
-back was made before that, and whoever is handed them next writes them in a
-launch made after it; the device runs launches in the order they were made
-(a step launched ahead included: PR 42's launch-ahead keeps ONE order of
-launches, it only fetches later), so a row is overwritten only after its
+**Layer groups.** A model's attention layers fall into groups that keep
+different rows (model.py ``KVGroup``), each with arenas and a `BlockPool` of
+its own (``KVStore.pools``, in the model's order), and a sequence has a
+footing in each (``SeqKV.groups``). A group whose queries see the whole
+context takes a prompt's blocks at `acquire`, may share them and holds them
+to the end. A group with a ``window`` opens its blocks as the launches reach
+them and holds only those with a position inside the window of the
+sequence's NEXT query: `KVStore.release_behind` gives the others back. When:
+the scheduler calls it for position ``p`` right before it builds the feeds
+of the launch whose first query stands at ``p`` (the step of the token at
+``p``, the chunk that starts at ``p``). Every launch that read the blocks
+given back was made before that, and whoever is handed them next writes them
+in a launch made after it; the device runs launches in the order they were
+made (a step launched ahead included: PR 42's launch-ahead keeps ONE order
+of launches, it only fetches later), so a row is overwritten only after its
 last reader ran. Nothing approximates: a row inside a query's window is in
 a live block, a row outside it is masked (the oldest live block's) or
 absent. Admission promises a request, a group, what it can ever hold there
 at once, and a block given back renews the promise for the blocks still to
-be opened. Such a store always reserves, shares no block and carries
-neither a prefix cache nor a tier: a block that was given back can be
-neither shared nor restored.
+be opened.
 
-**What a store refuses to carry.** The tier and the prefix cache key on K/V
-rows; a per-slot recurrent state is no function of them, and a model
-without an inject program (``chunks_only``) could never take rows back. So
-`GenerationEngine.register_model` refuses such a model a prefix cache or a
-tier, its blocks are never registered for sharing and its sessions never
-spill. A model that fills its answer a block at a time is refused both as
-well: a block's rows are rewritten by every pass until it is committed.
+**What a store refuses to carry** is said once, by `KVStore.check_carries`,
+from three facts the model states of its state and one its groups do. Rows
+that are rewritten until a block is committed (``fills_blocks``) are no
+function of the token prefix yet; a per-slot ``recurrent`` state is no
+function of rows at all; rows that a group with a window gave back are
+gone: none of these may sit in the prefix cache or the host tier, which key
+on K/V rows and hold whole prefixes, and the latter two are never
+registered for sharing (``shares``). A model without an inject program
+(``chunks_only``) could never take rows back: it gets no tier, its sessions
+never spill, and nothing forks or re-derives its rows (``restores``). A
+store with a windowed group always reserves.
 """
 
 import numpy as np
@@ -81,7 +85,7 @@ from paddle_tpu.serving.decode.tier import HostKVTier
 
 # SlotPool is the scheduler's (which batch slot is free); it is handed on
 # so that the scheduler imports this module alone
-__all__ = ["ArenaInvalidError", "KVStore", "SeqKV", "SlotPool", "WindowKV"]
+__all__ = ["ArenaInvalidError", "KVStore", "SeqKV", "SlotPool"]
 
 
 class ArenaInvalidError(RuntimeError):
@@ -107,85 +111,69 @@ def _chunk_rows(row_map, base, lo, start, stop, width, nowhere):
 
 
 class SeqKV:
-    """The storage state of ONE sequence on ONE entry. ``blocks`` is its
-    block chain; ``row_map[p]`` the arena row of position ``p`` (what the
-    chunk and inject programs are fed) and ``table`` the blocks' ids (its
-    row of the decode step's one feed); ``shared_len`` the positions that
-    radix-shared blocks already hold, never to be rewritten; ``reserve``
-    what is left of its reservation: blocks promised and not yet opened."""
+    """The storage state of ONE sequence on ONE entry: its footing in the
+    model's first layer group, and through ``groups`` in every group (this
+    one first). ``blocks`` is the LIVE part of the footing's block chain
+    and ``first`` the index, in the whole chain, of ``blocks[0]`` (0 unless
+    the group has a ``window``); ``row_map`` and ``table`` (what the chunk
+    and inject programs and the decode step are fed) start at that block,
+    so position ``p`` lies at ``row_map[p - base]``.
+    ``shared_len`` is the positions that radix-shared blocks already hold,
+    never to be rewritten; ``reserve`` what is left of the footing's
+    reservation, blocks promised and not yet opened; ``life`` the blocks of
+    the whole sequence where they were promised and ``limit`` the most of
+    them the footing holds at once."""
 
-    __slots__ = ("blocks", "row_map", "table", "reserve", "shared_len",
-                 "windows", "_m")
+    __slots__ = ("blocks", "first", "row_map", "table", "reserve", "life",
+                 "limit", "shared_len", "window", "_more", "_bs", "_rows")
 
-    def __init__(self, model, blocks, shared_len=0, reserve=0, windows=()):
-        self._m = model
-        self.blocks = blocks
+    def __init__(self, model, group, life=0, blocks=None, shared_len=0):
+        self._bs = model.block_size
+        self._rows = group.num_blocks * model.block_size
+        self.window = group.window
+        self.blocks, self.first = blocks or [], 0
         self.shared_len = shared_len
-        self.reserve = reserve
-        # its footing in each of the model's window groups (`WindowKV`)
-        self.windows = windows
-        self.row_map = np.zeros(model.max_len, dtype="int64")
+        self.life = life
+        self.limit = self.reserve = min(life, model.window_chunk_blocks(group))
+        self.row_map = np.zeros(model.chunk_rows(group), "int64")
+        self.table = np.zeros(model.window_table_blocks(group), "int32")
+        self._more = ()
         self.remap()
 
-    def remap(self):
-        """``row_map`` and ``table`` after ``blocks`` changed. What lies
-        past the blocks is left as it was, and is never read."""
-        m = self._m
-        rows = _block_rows(self.blocks, m.block_size)[:m.max_len]
-        self.row_map[:len(rows)] = rows
-        self.table = m.block_table(self.blocks)
+    @property
+    def groups(self):
+        """The sequence's footing in every layer group, this one first."""
+        return (self, *self._more)
 
-    def row_of(self, p):
-        bs = self._m.block_size
-        return self.blocks[p // bs].row0 + p % bs
+    @property
+    def base(self):
+        """The position of ``row_map[0]``: the first live block's first."""
+        return self.first * self._bs
 
-    def chunk_write_rows(self, start, stop, width):
-        """The chunk program's ``[width]`` write rows for positions
-        ``[start:stop)``: a shared position and the padding write nowhere."""
-        return _chunk_rows(self.row_map, 0, max(start, self.shared_len),
-                           start, stop, width, self._m.rows)
-
-
-class WindowKV:
-    """One sequence's footing in ONE window group (model.py ``KVGroup``).
-    ``blocks`` is the chain's LIVE part and ``first`` the index, in the
-    whole chain, of ``blocks[0]``; ``row_map`` and ``table`` (what the
-    chunk program and the decode step are fed) start at that block, so
-    position ``p`` lies at ``row_map[p - first * block_size]``. ``reserve``
-    is what the pool has promised the sequence and it has not opened,
-    ``left`` the blocks it has yet to open over its life, ``limit`` the most
-    it holds at once: ``reserve == min(limit - len(blocks), left)``."""
-
-    __slots__ = ("blocks", "first", "reserve", "left", "limit", "row_map",
-                 "table", "_m", "_rows")
-
-    def __init__(self, model, group, life_blocks):
-        self._m = model
-        self._rows = group.num_blocks * model.block_size
-        self.blocks, self.first = [], 0
-        self.left = life_blocks
-        most = model.window_chunk_blocks(group)
-        self.limit = self.reserve = min(life_blocks, most)
-        self.row_map = np.zeros(most * model.block_size, "int64")
-        self.table = np.zeros(model.window_table_blocks(group), "int32")
+    @property
+    def left(self):
+        """Blocks of its ``life`` the footing has yet to open."""
+        return self.life - self.first - len(self.blocks)
 
     def remap(self):
-        """``row_map`` and ``table`` after ``blocks`` or ``first`` changed;
-        what lies past the blocks is never read."""
-        bs = self._m.block_size
-        rows = _block_rows(self.blocks, bs)
+        """``row_map`` and ``table`` after ``blocks`` or ``first`` changed.
+        What lies past the blocks is left as it was, and is never read."""
+        rows = _block_rows(self.blocks, self._bs)[:len(self.row_map)]
         self.row_map[:len(rows)] = rows
         n = min(len(self.blocks), len(self.table))
-        self.table[:n] = rows[:n * bs:bs] // bs
+        self.table[:n] = rows[:n * self._bs:self._bs] // self._bs
 
     def row_of(self, p):
-        return self.row_map[p - self.first * self._m.block_size]
+        bs = self._bs
+        return self.blocks[p // bs - self.first].row0 + p % bs
 
     def chunk_write_rows(self, start, stop, width):
         """The chunk program's ``[width]`` write rows in this group for
-        positions ``[start:stop)``; the padding writes nowhere."""
-        return _chunk_rows(self.row_map, self.first * self._m.block_size,
-                           start, start, stop, width, self._rows)
+        positions ``[start:stop)``: a shared position and the padding write
+        nowhere."""
+        return _chunk_rows(self.row_map, self.base,
+                           max(start, self.shared_len), start, stop, width,
+                           self._rows)
 
 
 class KVStore:
@@ -206,69 +194,85 @@ class KVStore:
         self._fetch = fetch
         self._scope = scope
         self._device = device
-        self.pool = BlockPool(model.num_blocks, model.block_size,
-                              count=metrics.incr)
+        # a pool a layer group, in the model's order; what happens in a
+        # windowed group's is counted here and not by the pool
+        self.pools = [
+            BlockPool(g.num_blocks, model.block_size,
+                      count=metrics.incr if g.window is None else None)
+            for g in model.groups]
+        # the groups that give back what lies behind a window, by index
+        self.windowed = [i for i, g in enumerate(model.groups)
+                         if g.window is not None]
         self.prefix = PrefixCache(prefix_cache_size)
         # the pool writes registered blocks back to the tier at LRU
         # eviction (decode.blocks -> decode.tier), through `_writeback`
         self.tier = HostKVTier(capacity_bytes=tier_bytes)
         if tier_bytes:
             self.pool.attach_tier(self.tier, read_rows=self._writeback)
-        # a window group's pool, by the group's place in the model's list;
-        # what happens in them is counted here and not by the pools
-        self.window_pools = [BlockPool(g.num_blocks, model.block_size)
-                             for g in model.window_groups]
+        self.shares, self.restores = self.check_carries(
+            model, tier_bytes, prefix_cache_size)
         self.reserves = (not tier_bytes and (
             model.num_blocks < model.slots * model.blocks_per_slot
-            or bool(self.window_pools)))
+            or bool(self.windowed)))
         # blocks the paged-attention kernel copies as one unit at this
         # geometry (0: no kernel serves it), to count a step's units
         self.copy_unit = paged_copy_unit(
             model.block_size, model.blocks_per_slot, model.kv_width,
             model.kv_dtype, model.arenas // len(model.state_names))
 
+    @property
+    def pool(self):
+        """The first group's pool: the one that shares and spills."""
+        return self.pools[0]
+
+    @property
+    def window_pools(self):
+        """The pools of the groups after the first."""
+        return self.pools[1:]
+
     @staticmethod
-    def check_carries(model, tier_bytes, prefix_cache_size):
-        """Refuse, before anything is built, a model whose state a store
-        of these sizes could not carry."""
+    def check_carries(model, tier_bytes=0, prefix_cache_size=0):
+        """What the model's state lets a store do, as ``(shares,
+        restores)``: whether a prompt's blocks may be registered for later
+        prompts to share, and whether rows can be put back (a session
+        spills, a beam forks, a verify re-derives them). Raises, before
+        anything is built, where a store of these sizes would have to
+        carry what such a state cannot give it."""
         from paddle_tpu.serving.request import ServingError
         from paddle_tpu.utils.enforce import EnforceError
 
+        sizes = (f"prefix_cache_size={prefix_cache_size}, "
+                 f"host_tier_mb={tier_bytes >> 20})")
+        both = ("Host it on an engine with prefix_cache_size=0 and "
+                "host_tier_mb=0 (got " + sizes)
         if model.fills_blocks and (prefix_cache_size or tier_bytes):
             raise ServingError(
                 f"model {model.label} fills its answer a block of "
                 f"{model.block_len} positions at a time: a block's K/V "
                 "rows are rewritten by every pass and final only once it "
                 "is committed, so neither the prefix cache nor the host KV "
-                "tier may hold them. Host it on an engine with "
-                "prefix_cache_size=0 and host_tier_mb=0 (got "
-                f"prefix_cache_size={prefix_cache_size}, "
-                f"host_tier_mb={tier_bytes >> 20})")
+                "tier may hold them. " + both)
         if model.recurrent and (prefix_cache_size or tier_bytes):
             raise EnforceError(
                 f"model {model.label} keeps per-slot recurrent state, "
                 "which the prefix cache and the host KV tier cannot carry: "
                 "both key on K/V rows, a function of the token prefix "
-                "alone, and hold no snapshot of a state. Host it on an "
-                "engine with prefix_cache_size=0 and host_tier_mb=0 (got "
-                f"prefix_cache_size={prefix_cache_size}, "
-                f"host_tier_mb={tier_bytes >> 20})")
+                "alone, and hold no snapshot of a state. " + both)
         if model.window_groups and (prefix_cache_size or tier_bytes):
             raise EnforceError(
                 f"model {model.label} keeps attention layers in window "
                 "groups, which give back the blocks behind a sequence's "
                 "window: a block that was given back can be neither shared "
                 "by a later prompt nor restored from the host KV tier, and "
-                "the prefix cache and the tier hold whole prefixes. Host "
-                "it on an engine with prefix_cache_size=0 and "
-                f"host_tier_mb=0 (got prefix_cache_size={prefix_cache_size}"
-                f", host_tier_mb={tier_bytes >> 20})")
+                "the prefix cache and the tier hold whole prefixes. " + both)
         if model.chunks_only and tier_bytes:
             raise EnforceError(
                 f"model {model.label} has no inject program: what the host "
                 "KV tier keeps (an evicted block's rows, a parked "
                 "session's) could never be put back. Host it on an engine "
                 f"with host_tier_mb=0 (got {tier_bytes >> 20})")
+        return (not (model.recurrent or model.window_groups),
+                not model.chunks_only)
 
     # -- blocks: a chain taken, grown, forked and given back ---------------
     @property
@@ -298,25 +302,25 @@ class KVStore:
         return self.chain(req) or (len(req.prompt) + bs - 1) // bs
 
     def covers(self, req, taken):
-        """Whether ``req``'s chain can be promised beside ``taken`` blocks
-        that this round's picks will take; if not it is held back. A chain
-        that can NEVER fit goes on, to fail loudly at its admission."""
-        need = self.chain(req)
-        if ((need <= self.free_blocks - taken
-             or need > self._model.num_blocks)
-                and all(n <= pool.free_count or n > pool.num_blocks
-                        for n, pool in zip(self._window_needs(need),
-                                           self.window_pools))):
+        """Whether ``req``'s chain can be promised, in every group, beside
+        ``taken`` blocks of whole chains that this round's picks will take
+        (of a group that holds whole chains); if not it is held back. A
+        chain that can NEVER fit goes on, to fail loudly at its
+        admission."""
+        if all(n <= pool.free_count - (taken if g.window is None else 0)
+               or n > pool.num_blocks
+               for g, n, pool in zip(self._model.groups,
+                                     self._needs(self.chain(req)),
+                                     self.pools)):
             return True
         self.hold_back(req)
         return False
 
-    def _window_needs(self, chain):
+    def _needs(self, chain):
         """What a request whose whole sequence takes ``chain`` blocks is
-        promised in each window group: what it can hold there at once."""
+        promised in each group: what it can hold there at once."""
         m = self._model
-        return [min(chain, m.window_chunk_blocks(g))
-                for g in m.window_groups]
+        return [min(chain, m.window_chunk_blocks(g)) for g in m.groups]
 
     def hold_back(self, req):
         """The pool cannot cover ``req``'s chain yet: counted once a
@@ -326,98 +330,93 @@ class KVStore:
             self._metrics.incr("admissions_deferred")
 
     def acquire(self, req):
-        """The prompt's block chain as a `SeqKV`, or None when the pool
-        cannot give it now: under reservation the request is held back
-        (its whole chain is promised or nothing is), else the pool is
-        exhausted and the scheduler decides whom to park before it asks
-        again. Raises only for what no pool of this size could hold."""
+        """The prompt's footing in every group as a `SeqKV`, or None when
+        a pool cannot give it now: under reservation the request is held
+        back (its whole chain is promised in every group or nothing is),
+        else the pool is exhausted and the scheduler decides whom to park
+        before it asks again. Raises only for what no pool of this size
+        could hold."""
         m = self._model
         chain = self.chain(req)
-        if chain > m.num_blocks:
-            self._never_fits(
-                f"the request's chain of {chain} blocks (prompt and answer)"
-                f" can never fit a pool of {m.num_blocks}; shorten it or "
-                "host the model with more blocks")
-        for need, pool in zip(self._window_needs(chain), self.window_pools):
+        needs = self._needs(chain)
+        for g, need, pool in zip(m.groups, needs, self.pools):
             if need > pool.num_blocks:
                 self._never_fits(
-                    f"the {need} blocks the request can hold at once in a "
-                    f"window group can never fit its pool of "
-                    f"{pool.num_blocks}; host the model with more blocks")
-        if chain:
-            if not self.pool.reserve(chain):
-                self.hold_back(req)
-                return None
-            windows = self._promise_windows(chain)
-            if windows is None:
-                self.pool.release((), chain)
-                self.hold_back(req)
-                return None
-            held = self.pool.reserved
-            blocks, shared_len = self.pool.acquire_for_prompt(
-                req.prompt, promised=chain)
-            self._metrics.incr("reserved_admissions")
-            self._metrics.incr("blocks_reserved", chain)
-            # less what the prompt's blocks used up of the promise
-            # (reserved moves on this thread alone)
-            return SeqKV(m, blocks, shared_len,
-                         chain - (held - self.pool.reserved), windows)
-        blocks, shared_len = self.pool.acquire_for_prompt(req.prompt)
-        if blocks is not None:
-            return SeqKV(m, blocks, shared_len)
-        if -(-len(req.prompt) // m.block_size) > m.num_blocks:
-            self._never_fits(
-                f"block pool exhausted ({self.pool.stats()['blocks_free']}"
-                f" free of {m.num_blocks}) and the prompt alone can never "
-                "fit; shorten the prompt or host the model with more blocks")
-        return None
+                    f"the {need} blocks the request (prompt and answer) "
+                    f"holds at once in layer group {g.name!r} can never "
+                    f"fit its pool of {pool.num_blocks}; shorten it or "
+                    "host the model with more blocks")
+        if chain and not self._promise(needs):
+            self.hold_back(req)
+            return None
+        held = self.pool.reserved
+        blocks, shared_len = self.pool.acquire_for_prompt(
+            req.prompt, promised=chain)
+        if blocks is None:
+            if -(-len(req.prompt) // m.block_size) > m.num_blocks:
+                self._never_fits(
+                    "block pool exhausted "
+                    f"({self.pool.stats()['blocks_free']} free of "
+                    f"{m.num_blocks}) and the prompt alone can never fit; "
+                    "shorten the prompt or host the model with more blocks")
+            return None
+        kv = self._seq(blocks, shared_len, chain)
+        # less what the prompt's blocks used up of the promise (reserved
+        # moves on this thread alone)
+        kv.reserve -= held - self.pool.reserved
+        return kv
 
-    def _promise_windows(self, chain):
-        """A `WindowKV` a window group for a sequence of ``chain`` blocks,
-        each promised what it can hold at once, or None, and nothing
+    def _promise(self, needs):
+        """Promise a sequence ``needs`` blocks, a group; False, and nothing
         promised, where a group's pool cannot cover that now."""
-        m = self._model
-        windows = tuple(WindowKV(m, g, chain) for g in m.window_groups)
-        for i, (w, pool) in enumerate(zip(windows, self.window_pools)):
-            if not pool.reserve(w.reserve):
-                for v, given in zip(windows[:i], self.window_pools):
-                    given.release((), v.reserve)
-                return None
-            self._metrics.incr("kv_blocks_reserved_window", w.reserve)
-        return windows
+        for i, (need, pool) in enumerate(zip(needs, self.pools)):
+            if not pool.reserve(need):
+                for given, back in zip(self.pools[:i], needs):
+                    given.release((), back)
+                return False
+        self._metrics.incr("reserved_admissions")
+        self._metrics.incr("blocks_reserved", needs[0])
+        for i in self.windowed:
+            self._metrics.incr("kv_blocks_reserved_window", needs[i])
+        return True
 
-    def release_behind(self, kv, position):
-        """Give back, in every window group, the blocks of ``kv`` that lie
-        wholly behind the window of a query at ``position`` (the next one
-        the sequence launches: the module docstring says why that is
-        safe), and renew the promise for as many of them as the sequence
-        has yet to open. Returns the blocks given back."""
+    def _seq(self, blocks, shared_len=0, chain=0):
+        """A sequence's footing in every group, ``blocks`` its chain in the
+        first, each promised what `_needs` says of a ``chain``."""
         m = self._model
-        bs, given = m.block_size, 0
-        for g, w, pool in zip(m.window_groups, kv.windows,
-                              self.window_pools):
-            first = max(position - g.window + 1, 0) // bs
-            n = min(first - w.first, len(w.blocks))
-            if n <= 0:
-                continue
-            dead, w.blocks = w.blocks[:n], w.blocks[n:]
-            w.first += n
-            keep = min(w.limit - len(w.blocks), w.left) - w.reserve
-            pool.recycle(dead, keep)
-            w.reserve += keep
-            w.remap()
-            given += n
-        if given:
-            self._metrics.incr("kv_window_blocks_released", given)
-        return given
+        kv = SeqKV(m, m.groups[0], chain, blocks, shared_len)
+        kv._more = tuple(SeqKV(m, g, chain) for g in m.groups[1:])
+        return kv
+
+    def release_behind(self, kv, position, group):
+        """Give back, in windowed group ``group`` (an index of
+        ``windowed``), the blocks of ``kv`` that lie wholly behind the
+        window of a query at ``position`` (the next one the sequence
+        launches: the module docstring says why that is safe), and renew
+        the promise for as many of them as the sequence has yet to open.
+        Returns the blocks given back."""
+        w = kv.groups[group]
+        first = max(position - w.window + 1, 0) // self._model.block_size
+        n = min(first - w.first, len(w.blocks))
+        if n <= 0:
+            return 0
+        dead, w.blocks = w.blocks[:n], w.blocks[n:]
+        w.first += n
+        keep = min(w.limit - len(w.blocks), w.left) - w.reserve
+        self.pools[group].recycle(dead, keep)
+        w.reserve += keep
+        w.remap()
+        self._metrics.incr("kv_window_blocks_released", n)
+        return n
 
     def open_windows(self, kv, stop):
-        """Make every position below ``stop`` writable in every window
+        """Make every position below ``stop`` writable in every windowed
         group, out of the sequence's promise there (which `acquire` sized
         so that it cannot run out once `release_behind` has run for the
         launch's first position)."""
         bs = self._model.block_size
-        for w, pool in zip(kv.windows, self.window_pools):
+        for i in self.windowed:
+            w = kv.groups[i]
             need = -(-stop // bs) - w.first - len(w.blocks)
             if need <= 0:
                 continue
@@ -425,9 +424,8 @@ class KVStore:
                 raise RuntimeError(
                     f"a window group's promise of {w.limit} blocks does "
                     f"not cover {need} more beside {len(w.blocks)} live")
-            w.blocks += pool.open_promised(need)
+            w.blocks += self.pools[i].open_promised(need)
             w.reserve -= need
-            w.left -= need
             w.remap()
 
     def _never_fits(self, why):
@@ -439,7 +437,7 @@ class KVStore:
         """Fresh private blocks for rows ``[0:n)`` that the caller puts
         back (`restore`), or None when the pool cannot cover them."""
         blocks = self.pool.acquire_rows(n)
-        return None if blocks is None else SeqKV(self._model, blocks)
+        return None if blocks is None else self._seq(blocks)
 
     def open_block(self, kv, cursor):
         """Make position ``cursor`` writable: a fresh block where it opens
@@ -450,8 +448,7 @@ class KVStore:
         is empty (nothing changed; the scheduler parks, drains or
         rejects). RuntimeError on a pool invariant violation,
         `ArenaInvalidError` where the copy-on-write's inject failed."""
-        if kv.windows:
-            self.open_windows(kv, cursor + 1)
+        self.open_windows(kv, cursor + 1)
         blocks, nb, cow = self.pool.ensure_appendable(
             kv.blocks, cursor, promised=kv.reserve > 0)
         if blocks is None:
@@ -482,18 +479,18 @@ class KVStore:
             rows, _ = self.read_block(src, nb.size_used)
             self.write_rows(nb, 0, nb.size_used, rows,
                             "decode::beam_fork_inject")
-        return SeqKV(self._model, blocks, parent.shared_len)
+        return self._seq(blocks, parent.shared_len)
 
     def release(self, kv):
         """Give back a sequence's blocks and the unopened part of its
         reservation. Registered blocks stay cached for the next prompt."""
         if kv is None:
             return
-        if kv.blocks:
-            self.pool.release(kv.blocks, kv.reserve)
-        for w, pool in zip(kv.windows, self.window_pools):
+        for w, pool in zip(kv.groups, self.pools):
             pool.release(w.blocks, w.reserve)
-            w.blocks, w.reserve = [], 0
+            if w.window is not None:
+                # private blocks, freed at once: given back but once
+                w.blocks, w.reserve = [], 0
 
     # -- rows: the one write and the one read -------------------------------
     def write_rows(self, target, lo, hi, source, span, fault=None, **attrs):
@@ -640,11 +637,9 @@ class KVStore:
     def register(self, kv, prompt, live=None):
         """Index a prompt's freshly written blocks so later prompts share
         them; its partial tail too where ``live`` (the one-shot prefill's
-        host copy) can back a copy-on-write. A recurrent model's blocks
-        are never shared: its state is no function of them. Nor are those
-        of a model with window groups: the rows a later prompt would need
-        there may have been given back."""
-        if self._model.recurrent or self._model.window_groups:
+        host copy) can back a copy-on-write. Not where the state lets
+        nothing be shared (`check_carries`)."""
+        if not self.shares:
             return
         host_rows = None
         if live is not None:
@@ -674,15 +669,11 @@ class KVStore:
 
         m = self._model
         scope = self._scope()
-        sized = [(m.state_names, m.rows)] + [
-            (g.state_names, g.num_blocks * m.block_size)
-            for g in m.window_groups]
-        for names, rows in sized:
-            for n in (n for pair in names for n in pair):
+        for g, pool in zip(m.groups, self.pools):
+            for n in (n for pair in g.state_names for n in pair):
                 scope.set(n, jax.device_put(
-                    jnp.zeros((rows, m.kv_width), m.kv_dtype), self._device))
-        self.pool.reset()
-        for pool in self.window_pools:
+                    jnp.zeros((pool.rows, m.kv_width), m.kv_dtype),
+                    self._device))
             pool.reset()
 
     def stats(self):

@@ -443,32 +443,29 @@ class DecodeMetrics(ServingMetrics):
         self.incr("paged_copy_units", copy_units)
         self.incr("paged_copy_units_ahead", max(copy_units - 1, 0))
 
-    def observe_window_rows(self, length, block_size, windows):
-        """One stepping slot of a model with window groups: its window
-        layers read, a group, the rows from its first live block to its
+    def observe_window_rows(self, length, w):
+        """One stepping slot's footing ``w`` in a windowed group: the
+        group's layers read the rows from its first live block to the
         cursor, of the ``length`` their context holds."""
-        self.incr("attention_rows_read_step", sum(
-            length - w.first * block_size for w in windows))
-        self.incr("attention_rows_in_context_step", length * len(windows))
+        self.incr("attention_rows_read_step", length - w.base)
+        self.incr("attention_rows_in_context_step", length)
 
-    def observe_window_chunk(self, start, stop, block_size, windows,
-                             sizes, given):
-        """One chunk ``[start, stop)`` of a model with window groups, whose
-        sequence gave ``given`` blocks back before it: a group's layers
-        read the rows from its first live block to ``stop`` of the ``stop``
-        their context holds, and are REQUIRED to read those from the first
-        query's lower edge on, over the pairs its window opens."""
-        self.incr("attention_rows_read_chunk", sum(
-            stop - w.first * block_size for w in windows))
-        self.incr("attention_rows_in_context_chunk", stop * len(windows))
-        for size in sizes:
-            self.incr("attention_window_rows_chunk",
-                      stop - max(start - size + 1, 0))
-            # sum over p in [start, stop) of min(size, p + 1)
-            ramp = min(max(size - 1, start), stop)
-            self.incr("attention_window_pairs_chunk",
-                      (ramp - start) * (start + ramp + 1) // 2
-                      + (stop - ramp) * size)
+    def observe_window_chunk(self, start, stop, w, given):
+        """One chunk ``[start, stop)`` over footing ``w`` in a windowed
+        group, which gave ``given`` blocks back before it: the group's
+        layers read the rows from its first live block to ``stop`` of the
+        ``stop`` their context holds, and are REQUIRED to read those from
+        the first query's lower edge on, over the pairs its window opens."""
+        size = w.window
+        self.incr("attention_rows_read_chunk", stop - w.base)
+        self.incr("attention_rows_in_context_chunk", stop)
+        self.incr("attention_window_rows_chunk",
+                  stop - max(start - size + 1, 0))
+        # sum over p in [start, stop) of min(size, p + 1)
+        ramp = min(max(size - 1, start), stop)
+        self.incr("attention_window_pairs_chunk",
+                  (ramp - start) * (start + ramp + 1) // 2
+                  + (stop - ramp) * size)
         self._observe_release(given)
 
     def _observe_release(self, blocks):
@@ -482,19 +479,16 @@ class DecodeMetrics(ServingMetrics):
                 buckets=CHUNK_TOKEN_BUCKETS)
         self._released.observe(blocks)
 
-    def observe_pools(self, full, windows, given):
-        """One decode step of a model with window groups, before which its
-        slots gave ``given`` blocks back: the blocks live and promised in
-        the first group's pool and in the window groups', beside the pools'
-        sizes."""
+    def observe_pools(self, pools, group, given):
+        """One decode step's release in windowed group ``group`` of a
+        store's ``pools``, which gave ``given`` blocks back: the blocks
+        live and promised in that group's pool, beside those in the first
+        group's, and the pools' sizes."""
         self._observe_release(given)
-        for name, pools in (("full", (full,)), ("window", windows)):
-            self.incr("kv_blocks_live_" + name,
-                      sum(p.live_count for p in pools))
-            self.incr("kv_blocks_promised_" + name,
-                      sum(p.reserved for p in pools))
-            self.incr("kv_pool_blocks_" + name,
-                      sum(p.num_blocks for p in pools))
+        for name, pool in (("full", pools[0]), ("window", pools[group])):
+            self.incr("kv_blocks_live_" + name, pool.live_count)
+            self.incr("kv_blocks_promised_" + name, pool.reserved)
+            self.incr("kv_pool_blocks_" + name, pool.num_blocks)
 
     def observe_tokens(self, request):
         """At retirement: the request's time to first token and the mean

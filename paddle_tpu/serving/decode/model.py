@@ -93,15 +93,15 @@ def window_chunk_blocks(window, chunk_tokens, block_size, blocks_per_slot):
 
 
 class KVGroup:
-    """A FURTHER group of a model's attention layers: layers whose queries
-    see the last ``window`` positions (their own among them) and nothing
-    older, with arenas of their own (``state_names``, as
-    ``DecodeModel.state_names``: ``[num_blocks * block_size, kv_width]``
-    each) and a block pool of their own, ``num_blocks`` blocks. A sequence
-    holds in such a group only the blocks with a position inside the window
-    of its next query (kvstate.py ``WindowKV``); the programs read them
-    through a table and a row map that start at the sequence's first LIVE
-    block (``DecodeModel.fill_windows``, ``window_chunk_feeds``)."""
+    """One group of a model's attention layers: its arenas (``state_names``,
+    as ``DecodeModel.state_names``: ``[num_blocks * block_size, kv_width]``
+    each) and the ``num_blocks`` of its block pool. ``window`` is None where
+    the group's queries see the whole context, else the positions they see
+    (their own among them): a sequence then holds in the group only the
+    blocks with a position inside the window of its next query, and the
+    programs read them through a table and a row map that start at the
+    sequence's first LIVE block (kvstate.py ``SeqKV``;
+    ``DecodeModel.fill_step``, ``chunk_feeds``)."""
 
     __slots__ = ("name", "state_names", "num_blocks", "window")
 
@@ -109,8 +109,8 @@ class KVGroup:
         self.name = str(name)
         self.state_names = [tuple(names) for names in state_names]
         self.num_blocks = int(num_blocks)
-        self.window = int(window)
-        if self.window < 1 or self.num_blocks < 1:
+        self.window = None if window is None else int(window)
+        if self.num_blocks < 1 or (window is not None and self.window < 1):
             raise ValueError(f"group {name}: window {window} and num_blocks "
                              f"{num_blocks} have to be positive")
 
@@ -177,21 +177,20 @@ class DecodeModel:
     with ``2 S`` integers, each slot's decided position (-1: a commit
     pass) and its token.
 
-    **Layer groups.** A model's attention layers may fall into groups that
-    keep different rows. ``state_names``, ``num_blocks`` and the block
-    table of ``dec_step`` are the FIRST group's, whose layers see the whole
-    context: every model has it, and a model with no other is fed and run
-    as it ever was. ``window_groups`` lists the others (`KVGroup`). For
-    each, a slot's row of ``dec_step`` carries, after the first group's
-    table, ``length, low, write_row`` and a table of `window_table_blocks`
-    block ids that starts at the slot's first LIVE block: the step's
-    queries see rows ``[low, length)`` of what that table names
-    (`fill_windows`; ops/nn.py ``paged_window_feeds`` makes the bias and
-    the row map of them). The chunk program takes, a group, a span, a row
-    map and write rows of its own under the names `chunk_group_feeds`
-    gives, the row map ``[window_chunk_blocks * block_size]`` from the
-    first live block and the span's start counted from that block's first
-    position (`window_chunk_feeds`)."""
+    **Layer groups.** ``groups`` lists the model's attention layers by the
+    rows they keep (`KVGroup`), each with arenas and a block pool of its
+    own. The first is made of ``state_names`` and ``num_blocks`` and sees
+    the whole context; ``window_groups`` are the rest, each of a window. A
+    slot's row of ``dec_step`` carries the first group's head and table,
+    then for every further group ``length, low, write_row`` and a table of
+    `window_table_blocks` block ids that starts at the slot's first LIVE
+    block: the step's queries see rows ``[low, length)`` of what that table
+    names (`fill_step`; ops/nn.py ``paged_window_feeds`` makes the bias and
+    the row map of them). The chunk program takes a span, a row map and
+    write rows a group (`chunk_feeds`; a further group's under the names
+    `chunk_group_feeds` gives): the row map `chunk_rows` long from the
+    group's first live block, the span's start counted from that block's
+    first position. A model with one group is fed and run as it ever was."""
 
     # feed-name contract (fixed; the engine builds these arrays)
     DEC_TOKEN = "dec_token"
@@ -255,6 +254,8 @@ class DecodeModel:
         self.block_len = int(block_len)
         self.mask_token = mask_token
         self.window_groups = list(window_groups)
+        self.groups = [KVGroup("full", self.state_names, self.num_blocks,
+                               None)] + self.window_groups
         if self.window_groups and (self.block_len > 1
                                    or not self.chunks_only):
             raise ValueError(
@@ -311,7 +312,10 @@ class DecodeModel:
     def window_table_blocks(self, group):
         """Blocks a slot can hold live in ``group`` when it STEPS: from the
         block of position ``p - window + 1`` to the block of ``p``, the
-        width of the group's table in ``dec_step``."""
+        width of the group's table in ``dec_step``. A whole slot's where
+        the group sees the whole context, here and in a chunk."""
+        if group.window is None:
+            return self.blocks_per_slot
         return window_table_blocks(group.window, self.block_size,
                                    self.blocks_per_slot)
 
@@ -319,9 +323,16 @@ class DecodeModel:
         """Blocks a slot can hold live in ``group`` while a CHUNK runs:
         from the block of ``start - window + 1`` to the block of the
         chunk's last position; what a request's admission reserves there
-        at most, and the blocks of the chunk program's row map."""
+        at most."""
+        if group.window is None:
+            return self.blocks_per_slot
         return window_chunk_blocks(group.window, self.chunk_tokens,
                                    self.block_size, self.blocks_per_slot)
+
+    def chunk_rows(self, group):
+        """The length of ``group``'s row map in the chunk program."""
+        return (self.max_len if group.window is None
+                else self.window_chunk_blocks(group) * self.block_size)
 
     @property
     def step_width(self):
@@ -368,29 +379,27 @@ class DecodeModel:
             at += 3 + self.window_table_blocks(g)
         return feed
 
-    def fill_step(self, feed, slot, position, table, write_row, token=-1):
-        """Slot ``slot`` steps at ``position`` over the blocks ``table``
-        names (an int array of `blocks_per_slot` block ids), attending to
-        positions ``<= position``. Its new K/V row lands at ``write_row``
-        (``rows``: nowhere); ``token`` is fed from the host, or -1 where
-        ``dec_token`` holds it on the device."""
-        feed[slot, :self.STEP_TABLE] = (token, position, position + 1,
-                                        write_row)
-        feed[slot, self.STEP_TABLE:self.STEP_TABLE + len(table)] = table
-
-    def fill_windows(self, feed, slot, position, windows):
-        """The window groups' part of slot ``slot``'s row, stepping at
-        ``position``: ``windows`` is the sequence's footing in each
-        (kvstate.py ``WindowKV``: ``first``, the index of its first live
-        block; ``table``; ``row_of``). Counted from that block's first
-        position, the step's queries see rows ``[low, length)``: ``low``
-        masks the rows of the oldest block that have left the window."""
+    def fill_step(self, feed, slot, position, groups, token=-1, write=True):
+        """Slot ``slot`` steps at ``position``, attending to positions ``<=
+        position``: ``groups`` is its sequence's footing in each layer
+        group (kvstate.py ``SeqKV``: ``base``, the position of its first live
+        block; ``table``; ``row_of``). Its new K/V rows land where the
+        footings say, or nowhere without ``write``; ``token`` is fed from
+        the host, or -1 where ``dec_token`` holds it on the device. Counted
+        from a further group's first live block, the step's queries see
+        rows ``[low, length)``: ``low`` masks the rows of the oldest block
+        that have left the window."""
+        kv = groups[0]
+        feed[slot, :self.STEP_TABLE] = (
+            token, position, position + 1,
+            kv.row_of(position) if write else self.rows)
+        feed[slot, self.STEP_TABLE:self.STEP_TABLE + len(kv.table)] = kv.table
         at = self.step_table + self.blocks_per_slot
-        for g, w in zip(self.window_groups, windows):
-            base = w.first * self.block_size
+        for i, g in enumerate(self.window_groups, 1):
+            w = groups[i]
             feed[slot, at:at + 3] = (
-                position + 1 - base,
-                max(position - g.window + 1 - base, 0), w.row_of(position))
+                position + 1 - w.base,
+                max(position - g.window + 1 - w.base, 0), w.row_of(position))
             feed[slot, at + 3:at + 3 + len(w.table)] = w.table
             at += 3 + self.window_table_blocks(g)
 
@@ -430,21 +439,22 @@ class DecodeModel:
 
     @staticmethod
     def chunk_group_feeds(index):
-        """The names of window group ``index``'s chunk feeds: its span,
-        row map and write rows."""
-        return tuple(f"{name}.g{index + 1}" for name in (
+        """The names of window group ``index``'s chunk feeds (-1: the first
+        group's): its span, row map and write rows."""
+        suffix = f".g{index + 1}" if index >= 0 else ""
+        return tuple(name + suffix for name in (
             DecodeModel.CHU_SPAN, DecodeModel.CHU_ROWS,
             DecodeModel.CHU_WRITE_ROWS))
 
-    def window_chunk_feeds(self, start, real, windows):
-        """The window groups' chunk feeds for ``real`` prompt positions
-        from ``start``: a group's span counts ``start`` from its first
-        live block's first position, as its row map does."""
+    def chunk_feeds(self, start, real, groups):
+        """What the chunk program is fed of a sequence's footing in each
+        layer group (``groups``) for ``real`` prompt positions from
+        ``start``: a group's span counts ``start`` from its first live
+        block's first position, as its row map does."""
         feeds = {}
-        for i, w in enumerate(windows):
-            span, rows, wrows = self.chunk_group_feeds(i)
-            feeds[span] = np.array(
-                [start - w.first * self.block_size, real], "int32")
+        for i, w in enumerate(groups):
+            span, rows, wrows = self.chunk_group_feeds(i - 1)
+            feeds[span] = self.chunk_span(start - w.base, real)
             feeds[rows] = w.row_map
             feeds[wrows] = w.chunk_write_rows(start, start + real,
                                               self.chunk_tokens)
@@ -526,8 +536,7 @@ class DecodeModel:
         for i, g in enumerate(self.window_groups):
             span, rows, wrows = self.chunk_group_feeds(i)
             sig += [(span, (2,), "int32"),
-                    (rows, (self.window_chunk_blocks(g) * self.block_size,),
-                     "int64"),
+                    (rows, (self.chunk_rows(g),), "int64"),
                     (wrows, (c,), "int64")]
         return tuple(sig)
 

@@ -116,8 +116,7 @@ def served(request):
     try:
         out.sound = serve()
         out.pools_after_sound = [
-            p.check_conservation() for p in
-            [entry.kv.pool] + entry.kv.window_pools]
+            p.check_conservation() for p in entry.kv.pools]
         out.faulted = {f: serve(f)
                        for f in ("window_early", "window_early_block")}
     finally:
@@ -226,9 +225,9 @@ def test_a_launch_reads_no_more_than_its_window(served):
 
 
 def test_both_pools_are_whole_when_the_requests_are_over(served):
-    for counts, pool in zip(served.pools_after_sound,
-                            [served.entry.kv.pool]
-                            + served.entry.kv.window_pools):
+    kv = served.entry.kv
+    assert kv.pools == [kv.pool] + kv.window_pools and kv.windowed == [1]
+    for counts, pool in zip(served.pools_after_sound, kv.pools):
         assert counts["blocks_live"] == 0 and pool.reserved == 0
         assert pool.check_conservation()["blocks_free"] == pool.num_blocks
     assert "window_pools" in served.entry.kv.stats()

@@ -34,7 +34,9 @@ from paddle_tpu.serving.decode import (
     build_decoder_model,
     build_nemotron_h_model,
 )
+from paddle_tpu.serving.decode.kvstate import SeqKV
 from paddle_tpu.serving.decode.model import NEG_INF, DecodeModel
+from paddle_tpu.serving.decode.pool import Block
 
 OUTPUTS = ("TokenOut", "Position", "Bias", "Rows", "WriteRows")
 
@@ -217,14 +219,12 @@ def test_the_expansion_equals_the_arrays_the_parent_built(program, case):
     assert step.shape == (m.slots, 4 + -(-m.max_len // bs))
     stepping, covered = {}, {}
 
-    class Block:
-        def __init__(self, bid):
-            self.row0 = bid * bs
-
     for s, (token, p, blocks, write) in states.items():
         row = blocks[p // bs] * bs + p % bs if write else m.rows
-        m.fill_step(step, s, p, m.block_table([Block(b) for b in blocks]),
-                    row, token)
+        kv = SeqKV(m, m.groups[0],
+                   blocks=[Block(b, b * bs) for b in blocks])
+        assert kv.table.tolist() == m.block_table(kv.blocks).tolist()
+        m.fill_step(step, s, p, kv.groups, token, write)
         stepping[s] = (token, p, _parent_row_map(m, blocks), row)
         covered[s] = min(len(blocks) * bs, m.max_len)
     got = run(step, np.zeros((m.slots, 1), "int64"))
@@ -240,9 +240,10 @@ def test_a_token_of_minus_one_takes_the_device_feeds(program):
     that carry -1 read ``dec_token``, the others their own column."""
     m, run = program
     step = m.step_feed()
-    table = np.zeros(m.blocks_per_slot, "int32")
-    m.fill_step(step, 0, 3, table, 3)                   # token left at -1
-    m.fill_step(step, 1, 5, table, 5, token=17)
+    groups = SeqKV(m, m.groups[0], blocks=[
+        Block(b, b * m.block_size) for b in (0, 1)]).groups
+    m.fill_step(step, 0, 3, groups)                     # token left at -1
+    m.fill_step(step, 1, 5, groups, token=17)
     device = np.arange(10, 10 + m.slots, dtype="int64").reshape(-1, 1)
     tok = np.asarray(run(step, device)[0])
     assert tok[:, 0].tolist() == [10, 17] + list(range(12, 10 + m.slots))

@@ -51,11 +51,13 @@ def _walk(store, kv, plen, new):
     m = store._model
     for start in range(0, plen, m.chunk_tokens):
         stop = min(start + m.chunk_tokens, plen)
-        store.release_behind(kv, start)
+        for g in store.windowed:
+            store.release_behind(kv, start, g)
         store.open_windows(kv, stop)
         yield "chunk", start, stop
     for p in range(plen, plen + new - 1):
-        store.release_behind(kv, p)
+        for g in store.windowed:
+            store.release_behind(kv, p, g)
         assert store.open_block(kv, p)
         yield "step", p, p + 1
 
@@ -84,8 +86,9 @@ def test_a_window_chain_holds_its_window_and_nothing_behind_it(plen, new):
     m = _model()
     store, metrics = _store(m)
     kv = store.acquire(_Req(plen, new))
-    (w,) = kv.windows
-    (pool,) = store.window_pools
+    _full, w = kv.groups
+    _shared, pool = store.pools
+    assert store.windowed == [1] and w.window == W and kv.window is None
     for kind, first, stop in _walk(store, kv, plen, new):
         held = range(w.first, w.first + len(w.blocks))
         # never a block wholly behind the first query's window ...
@@ -113,18 +116,19 @@ def test_a_window_chain_holds_its_window_and_nothing_behind_it(plen, new):
 def test_a_released_block_is_the_next_owners():
     m = _model(window_num_blocks=7)
     store, _metrics = _store(m)
-    (pool,) = store.window_pools
+    pool = store.pools[1]
     a = store.acquire(_Req(30, 2))
-    assert a.windows[0].limit == 5 and pool.free_count == 2
+    w = a.groups[1]
+    assert w.limit == 5 and pool.free_count == 2
     for _ in _walk(store, a, 30, 2):
         pass
     # 8 blocks were opened over the prompt's life out of a promise of 5
-    assert pool.allocs == 8 and len(a.windows[0].blocks) <= 3
+    assert pool.allocs == 8 and len(w.blocks) <= 3
     # with nothing left to open, what it gave back is anyone's
-    assert a.windows[0].reserve == a.windows[0].left == 0
+    assert w.reserve == w.left == 0
     b = store.acquire(_Req(5, 3))
     assert b is not None
-    assert pool.free_count == 7 - len(a.windows[0].blocks) - 2
+    assert pool.free_count == 7 - len(w.blocks) - 2
     store.release(a)
     store.release(b)
     assert pool.check_conservation()["blocks_free"] == 7
@@ -140,7 +144,7 @@ def test_admission_waits_for_both_promises(full, window, admitted):
     there = store.acquire(_Req(20, 5))
     assert there is not None
     req = _Req(20, 5)
-    assert store.chain(req) == 7 and store._window_needs(7) == [5]
+    assert store.chain(req) == 7 and store._needs(7) == [7, 5]
     assert store.covers(req, 0) is admitted
     kv = store.acquire(req)
     assert (kv is not None) is admitted
@@ -149,31 +153,81 @@ def test_admission_waits_for_both_promises(full, window, admitted):
     # a request held back leaves nothing promised in either pool
     assert store.pool.reserved + len(there.blocks) == (
         7 + (7 - len(kv.blocks) if admitted else 0))
-    assert store.window_pools[0].reserved == (10 if admitted else 5)
+    assert store.pools[1].reserved == (10 if admitted else 5)
     store.release(there)
     # what the first gave back is what the second waited for
     assert store.covers(req, 0)
     store.release(kv or store.acquire(req))
-    for p in [store.pool] + store.window_pools:
+    for p in store.pools:
         assert p.reserved == 0 and p.check_conservation()["blocks_live"] == 0
 
 
 def test_a_request_no_window_pool_could_hold_fails_loudly():
     m = _model(window_num_blocks=3)
     store, _metrics = _store(m)
-    with pytest.raises(RuntimeError, match="window group"):
+    with pytest.raises(RuntimeError, match="layer group 'sliding' can never"):
         store.acquire(_Req(20, 5))
 
 
-@pytest.mark.parametrize("sizes", [{"prefix_cache_size": 4},
-                                   {"tier_bytes": 1 << 20}])
-def test_a_store_refuses_to_share_or_park_what_a_group_gives_back(sizes):
-    from paddle_tpu.utils.enforce import EnforceError
+_BOTH = ("Host it on an engine with prefix_cache_size=0 and host_tier_mb=0 "
+         "(got prefix_cache_size={prefix}, host_tier_mb={tier})")
+# a fact of the state -> who refuses it, in what words, and for which sizes
+REFUSALS = {
+    "block_filling": (
+        dict(fills_blocks=True), "ServingError", ("prefix", "tier"),
+        "model m@1 fills its answer a block of 4 positions at a time: a "
+        "block's K/V rows are rewritten by every pass and final only once "
+        "it is committed, so neither the prefix cache nor the host KV tier "
+        "may hold them. " + _BOTH),
+    "recurrent": (
+        dict(recurrent=True, chunks_only=True), "EnforceError",
+        ("prefix", "tier"),
+        "model m@1 keeps per-slot recurrent state, which the prefix cache "
+        "and the host KV tier cannot carry: both key on K/V rows, a "
+        "function of the token prefix alone, and hold no snapshot of a "
+        "state. " + _BOTH),
+    "window_group": (
+        dict(window_groups=["g"], chunks_only=True), "EnforceError",
+        ("prefix", "tier"),
+        "model m@1 keeps attention layers in window groups, which give back "
+        "the blocks behind a sequence's window: a block that was given back "
+        "can be neither shared by a later prompt nor restored from the host "
+        "KV tier, and the prefix cache and the tier hold whole prefixes. "
+        + _BOTH),
+    "no_inject_program": (
+        dict(chunks_only=True), "EnforceError", ("tier",),
+        "model m@1 has no inject program: what the host KV tier keeps (an "
+        "evicted block's rows, a parked session's) could never be put back. "
+        "Host it on an engine with host_tier_mb=0 (got {tier})"),
+}
 
-    with pytest.raises(EnforceError, match="given back"):
-        KVStore.check_carries(_model(), sizes.get("tier_bytes", 0),
-                              sizes.get("prefix_cache_size", 0))
-    KVStore.check_carries(_model(), 0, 0)
+
+@pytest.mark.parametrize("size", ["prefix", "tier"])
+@pytest.mark.parametrize("fact", sorted(REFUSALS))
+def test_a_store_refuses_what_the_state_cannot_give_it(fact, size):
+    import types
+
+    facts, error, refused, words = REFUSALS[fact]
+    model = types.SimpleNamespace(**dict(
+        dict(label="m@1", block_len=4, fills_blocks=False, recurrent=False,
+             chunks_only=False, window_groups=[]), **facts))
+    tier, prefix = ((3 << 20, 0) if size == "tier" else (0, 4))
+    if size in refused:
+        with pytest.raises(RuntimeError) as caught:
+            KVStore.check_carries(model, tier, prefix)
+        assert type(caught.value).__name__ == error
+        assert str(caught.value) == words.format(prefix=prefix,
+                                                 tier=tier >> 20)
+    else:
+        KVStore.check_carries(model, tier, prefix)
+    # with nothing to carry every state is hosted, and the store knows what
+    # it may still do: share a prompt's blocks, put rows back
+    assert KVStore.check_carries(model, 0, 0) == (
+        fact in ("block_filling", "no_inject_program"),
+        fact == "block_filling")
+    real = _model()
+    assert KVStore.check_carries(real) == (False, False)
+    assert _store(real)[0].reserves
 
 
 def test_the_steps_feed_names_the_live_blocks_and_masks_what_left():
@@ -182,7 +236,7 @@ def test_the_steps_feed_names_the_live_blocks_and_masks_what_left():
     kv = store.acquire(_Req(21, 4))
     for _kind, p, _stop in _walk(store, kv, 21, 4):
         pass
-    (w,) = kv.windows
+    w = kv.groups[1]
     assert p == 23 and w.first == 4       # position 16 is the window's first
     step = m.step_feed()
     assert step.shape == (S, m.step_width) == (S, 4 + 16 + 3 + 3)
@@ -190,15 +244,16 @@ def test_the_steps_feed_names_the_live_blocks_and_masks_what_left():
     # a slot that does not step sees nothing and writes nowhere
     assert (step[:, at] == 0).all()
     assert (step[:, at + 2] == m.window_groups[0].num_blocks * BS).all()
-    m.fill_step(step, 2, p, kv.table, kv.row_of(p))
-    m.fill_windows(step, 2, p, kv.windows)
+    m.fill_step(step, 2, p, kv.groups)
     length, low, wrow = step[2, at:at + 3]
     assert (length, low) == (p + 1 - 16, 0) and wrow == w.row_of(p)
     assert list(step[2, at + 3:at + 3 + len(w.blocks)]) == [
         b.row0 // BS for b in w.blocks]
     # one position on the oldest block's first row has left the window
-    w.first, low_at = 4, 24
-    m.fill_windows(step, 2, low_at, kv.windows)
+    low_at = 24
+    assert store.release_behind(kv, low_at, 1) == 0 and w.first == 4
+    assert store.open_block(kv, low_at)
+    m.fill_step(step, 2, low_at, kv.groups)
     assert step[2, at + 1] == low_at - W + 1 - 16 == 1
 
 
@@ -226,7 +281,6 @@ def test_the_chunks_feeds_count_from_the_first_live_block():
     store, _metrics = _store(m)
     kv = store.acquire(_Req(30, 2))
     launches = list(_walk(store, kv, 30, 2))
-    (w,) = kv.windows
     sig = dict((name, (shape, dtype)) for name, shape, dtype in
                m.chunk_feed_sig())
     span, rows, wrows = DecodeModel.chunk_group_feeds(0)
@@ -238,8 +292,8 @@ def test_the_chunks_feeds_count_from_the_first_live_block():
     for kind, start, stop in _walk(store, kv2, 30, 2):
         if (kind, start) == ("chunk", 24):
             break
-    feeds = m.window_chunk_feeds(24, 6, kv2.windows)
-    (w2,) = kv2.windows
+    feeds = m.chunk_feeds(24, 6, kv2.groups)
+    w2 = kv2.groups[1]
     assert w2.first == (24 - W + 1) // BS == 4
     assert feeds[span].tolist() == [24 - 16, 6]
     assert feeds[rows] is w2.row_map
@@ -280,12 +334,13 @@ def test_a_model_with_one_group_is_fed_what_it_ever_was(kind, model):
                                           else [])
     store, metrics = _store(m)
     assert store.window_pools == [] and not store.reserves
+    assert store.pools == [store.pool] and store.windowed == []
     kv = store.acquire(_Req(6, 3))
-    assert isinstance(kv, SeqKV) and kv.windows == ()
-    m.fill_step(step, 1, 6, kv.table, kv.row_of(6) if len(kv.blocks) > 1
-                else m.rows)
+    assert isinstance(kv, SeqKV) and kv.groups == (kv,)
+    m.fill_step(step, 1, 6, kv.groups, write=len(kv.blocks) > 1)
     assert step[1, 4:].tolist() == kv.table.tolist()
-    assert store.release_behind(kv, 6) == 0
+    assert list(m.chunk_feeds(4, 2, kv.groups)) == names[2:5]
+    store.open_windows(kv, 9)
     store.release(kv)
     assert store.pool.check_conservation()["blocks_live"] == 0
     for name in ("kv_window_blocks_released", "attention_rows_read_step"):
@@ -295,3 +350,72 @@ def test_a_model_with_one_group_is_fed_what_it_ever_was(kind, model):
         ops = program.global_block().ops
         assert "paged_window_feeds" not in [op.type for op in ops]
         assert not any("window" in op.attrs for op in ops)
+
+
+def _by_hand(groups):
+    """What the scheduler feeds of a sequence with the footings ``groups``
+    in each case below, written out."""
+    if groups == 2:
+        # 21 prompt positions in three chunks, then the step at 21. The
+        # first group holds blocks 0..5 of its pool of 40; the window group
+        # opened 0, 1 then 2, 3 of its pool of 24, gave 0 and 1 back before
+        # the chunk at 16 and took them again (1 first), gave 2 back before
+        # the step
+        nowhere = np.int64(24 * BS)
+        chunk = {
+            "chu_span": np.array([16, 5], "int32"),
+            "chu_rows": np.array(list(range(24)) + [0] * 40, "int64"),
+            "chu_write_rows": np.array(
+                [16, 17, 18, 19, 20] + [40 * BS] * 3, "int64"),
+            "chu_span.g1": np.array([8, 5], "int32"),
+            "chu_rows.g1": np.array(
+                list(range(8, 16)) + [4, 5, 6, 7, 0, 1, 2, 3] + [0] * 4,
+                "int64"),
+            "chu_write_rows.g1": np.array([4, 5, 6, 7, 0] + [nowhere] * 3,
+                                          "int64"),
+        }
+        idle = [-1, 0, 0, 40 * BS] + [0] * 16 + [0, 0, nowhere, 0, 0, 0]
+        step = np.array([idle, idle, [
+            7, 21, 22, 21, 0, 1, 2, 3, 4, 5] + [0] * 10 + [
+            # ten rows from position 12, two of them behind the window
+            10, 2, 1, 3, 1, 0], idle], "int32")
+        return chunk, step
+    chunk = {
+        "chu_span": np.array([4, 2], "int32"),
+        "chu_rows": np.array(list(range(8)) + [0] * 8, "int64"),
+        "chu_write_rows": np.array([4, 5, 32, 32], "int64"),
+    }
+    step = np.array([[-1, 0, 0, 32, 0, 0, 0, 0],
+                     [-1, 6, 7, 6, 0, 1, 0, 0]], "int32")
+    return chunk, step
+
+
+@pytest.mark.parametrize("groups", [2, 1])
+def test_the_folded_fillers_feed_the_arrays_written_out_by_hand(groups):
+    if groups == 2:
+        m, (plen, new), (slot, token) = _model(), (21, 4), (2, 7)
+    else:
+        (_kind, m), _ = _one_group_models()
+        (plen, new), (slot, token) = (6, 3), (1, -1)
+    store, _metrics = _store(m)
+    kv = store.acquire(_Req(plen, new))
+    assert len(kv.groups) == groups == len(store.pools)
+    want_chunk, want_step = _by_hand(groups)
+    got = {}
+    for kind, first, stop in _walk(store, kv, plen, new):
+        if kind == "chunk" and stop == plen:
+            got = {name: np.array(a) for name, a in
+                   m.chunk_feeds(first, stop - first, kv.groups).items()}
+        if (kind, first) == ("step", plen):
+            break
+    step = m.step_feed()
+    m.fill_step(step, slot, plen, kv.groups, token)
+    sig = {name: (shape, dtype) for name, shape, dtype in m.chunk_feed_sig()}
+    assert list(got) == list(want_chunk)
+    for name, want in want_chunk.items():
+        assert (got[name].shape, str(got[name].dtype)) == sig[name], name
+        assert got[name].dtype == want.dtype, name
+        assert got[name].tolist() == want.tolist(), name
+    assert step.dtype == want_step.dtype == np.int32
+    assert step.shape == m.decode_feed_sig()[1][1]
+    assert step.tolist() == want_step.tolist()

@@ -17,7 +17,6 @@ from paddle_tpu.serving.decode.kvstate import (
 )
 from paddle_tpu.serving.decode.model import DecodeModel
 from paddle_tpu.serving.decode.pool import block_hashes
-from paddle_tpu.utils.enforce import EnforceError
 
 GEOM = dict(vocab_size=32, hidden=8, num_layers=2, slots=4, max_len=32,
             block_size=4, version="1")
@@ -436,23 +435,3 @@ def test_reset_zeroes_the_arenas_and_empties_the_pool():
     h.store.reset()
     assert not any(np.asarray(a).any() for a in h.scope.arenas.values())
     assert h.store.free_blocks == h.model.num_blocks
-
-
-@pytest.mark.parametrize("tier_bytes,prefix", [(1 << 20, 0), (0, 4)])
-def test_a_recurrent_model_is_refused_what_cannot_carry_its_state(
-        tier_bytes, prefix):
-    model = types.SimpleNamespace(recurrent=True, chunks_only=True,
-                                  fills_blocks=False, window_groups=[],
-                                  label="r@1")
-    with pytest.raises(EnforceError, match="per-slot recurrent state"):
-        KVStore.check_carries(model, tier_bytes, prefix)
-    KVStore.check_carries(model, 0, 0)
-
-
-def test_a_model_without_an_inject_program_is_refused_a_tier():
-    model = types.SimpleNamespace(recurrent=False, chunks_only=True,
-                                  fills_blocks=False, window_groups=[],
-                                  label="c@1")
-    with pytest.raises(EnforceError, match="no inject program"):
-        KVStore.check_carries(model, 1 << 20, 0)
-    KVStore.check_carries(model, 0, 4)
