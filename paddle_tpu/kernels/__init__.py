@@ -564,7 +564,11 @@ def _tpu_cases_latent_chunk():
 def _parity_moe_experts(rng):
     """Held experts some of which no token chose, a masked token, the
     step in which none is touched, and a step whose tokens are no whole
-    sublane tiles (padded inside)."""
+    sublane tiles (padded inside); then, with the gate and without: ONE
+    expert touched (the first, one in the middle, the last), all of them,
+    all but the first, and touched experts that are not the leading ids
+    (the grid stops at the last touched one: the order the wrapper gives
+    it decides what it reads)."""
     import jax
     import jax.numpy as jnp
 
@@ -595,25 +599,52 @@ def _parity_moe_experts(rng):
         odd, moe.experts_composite(x[:11], c[:11], w_up, w_down),
         "moe_experts (11 tokens)", 1e-5, 1e-5)
     # gated experts: a third matrix and its own accumulator
-    H2 = 256
+    H2, E2 = 256, 8
     x2 = jnp.asarray(rng.randn(T, H2).astype("float32"))
-    three = [jnp.asarray(0.1 * rng.randn(E, F, H2).astype("float32"))
+    three = [jnp.asarray(0.1 * rng.randn(E2, F, H2).astype("float32"))
              for _ in range(3)]
-    c = moe.held_weights(idx, w, mask, 0, E)
+    c = moe.held_weights(idx, w, mask, 0, E2)
     _assert_close_both_ways(
         run(x2, c, *three), moe.experts_composite(x2, c, *three),
         "moe_experts (gated)", 1e-5, 1e-5)
+    for name, ids in (("one touched", [5]), ("the first alone", [0]),
+                      ("the last alone", [E2 - 1]),
+                      ("all touched", range(E2)),
+                      ("all but the first", range(1, E2)),
+                      ("not the leading ids", [2, 5, 7])):
+        c = np.zeros((T, E2), "float32")
+        for e in ids:
+            rows = rng.permutation(T)[:1 + e % 3]
+            c[rows, e] = 0.1 + rng.rand(len(rows))
+        c = jnp.asarray(c)
+        for matrices in (three, three[:2]):
+            _assert_close_both_ways(
+                run(x2, c, *matrices),
+                moe.experts_composite(x2, c, *matrices),
+                f"moe_experts ({name}, {len(matrices)} matrices)",
+                1e-5, 1e-5)
+
+
+#: the decode step's expert layer of each routed serving cell, by its
+#: configuration: tokens, hidden size, expert width, held experts, matrices
+#: an expert (3: gated, 2: relu2). nemotron3_nano_30b_a3b steps 32 slots,
+#: lfm2_24b_a2b 128, sdar_30b_a3b a block pass's 32 x 4 tokens,
+#: trinity_large_preview 24 (no whole sublane tiles: padded to 32 inside the
+#: kernel's wrapper), mistral_small_4_119b its 16 slots. The ONE statement
+#: of them: the chip-free compile, the traced program's digests and
+#: ``tools/check_moe_experts.py`` read it
+MOE_EXPERTS_STEPS = {
+    "nemotron3_nano_30b_a3b": (32, 2688, 1856, 16, 2),
+    "lfm2_24b_a2b": (128, 2048, 1536, 8, 3),
+    "sdar_30b_a3b": (128, 2048, 768, 16, 3),
+    "trinity_large_preview": (24, 3072, 3072, 32, 3),
+    "mistral_small_4_119b": (16, 4096, 2048, 16, 3),
+}
 
 
 def _tpu_cases_moe_experts():
-    """The hybrid serving cells' expert layers in bfloat16:
-    nemotron3_nano_30b_a3b (a step's 32 tokens, 16 held relu2 experts of
-    width 1,856 at hidden 2,688), lfm2_24b_a2b (128 tokens, 8 held gated
-    experts of width 1,536 at hidden 2,048), sdar_30b_a3b (a block
-    pass's 32 x 4 tokens, 16 held gated experts of width 768 at hidden
-    2,048) and trinity_large_preview (24 tokens, no whole sublane tiles:
-    padded to 32 inside the kernel's wrapper; 32 held gated experts of
-    width 3,072 at hidden 3,072)."""
+    """``MOE_EXPERTS_STEPS`` in bfloat16: the step of every routed serving
+    cell (mistral_small_4_119b's since PR 62)."""
     from paddle_tpu.kernels import moe
 
     def case(T, H, F, E, matrices):
@@ -621,8 +652,7 @@ def _tpu_cases_moe_experts():
                 [((T, H), "bfloat16"), ((T, E), "float32")]
                 + [((E, F, H), "bfloat16")] * matrices)
 
-    return [case(32, 2688, 1856, 16, 2), case(128, 2048, 1536, 8, 3),
-            case(128, 2048, 768, 16, 3), case(24, 3072, 3072, 32, 3)]
+    return [case(*step) for step in MOE_EXPERTS_STEPS.values()]
 
 
 def _parity_moe_grouped(rng):
@@ -860,9 +890,10 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "moe_experts", ("moe_routed_experts",), "tolerance", _parity_moe_experts,
-    tpu_cases=_tpu_cases_moe_experts,
-    doc="a decode step's held experts: the touched ones' weights streamed "
-        "once, the others never read (kernels/moe.py)",
+    tpu_cases=_tpu_cases_moe_experts, version=2,
+    doc="a decode step's held experts: a grid row for each touched one, "
+        "its weights streamed once; the others are never read and cost no "
+        "grid step (kernels/moe.py)",
 ))
 register(KernelSpec(
     "moe_grouped", ("moe_routed_experts",), "tolerance", _parity_moe_grouped,

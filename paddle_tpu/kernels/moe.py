@@ -26,10 +26,12 @@ of when it runs.
 ``experts_composite`` computes that part densely (every held expert over
 every token, weighted by ``c``): the reference lowering, the CPU path, the
 ``off`` path, and the prompt chunk's path. ``moe_experts`` is the decode
-step's Pallas kernel: the grid runs over the held experts that some token
-chose, touched ones first (scalar prefetch), and streams each one's
-matrices through VMEM in tiles of the hidden size (``hidden_tile``: the
-hidden size's own); an expert no token chose is never read.
+step's Pallas kernel: the grid's expert axis is as long as the step's
+work, a row for each held expert that some token chose (their ids and
+their count are scalar prefetches, the count the grid's traced extent)
+and no row for any other, and a row streams its expert's matrices through
+VMEM in tiles of the hidden size (``hidden_tile``: the hidden size's
+own); an expert no token chose is never read, and costs no grid step.
 """
 
 import functools
@@ -162,7 +164,8 @@ def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated):
     """``refs``: the up (and, ``gated``, the gate) matrix's tile, the down
     matrix's, the output, then the scratches: the up product (and the
     gate's) ``[T, F]`` float32 and the activation in the weights' dtype.
-    Every grid row (an expert) adds into the ONE resident output."""
+    Every grid row (a touched expert) adds into the ONE resident output;
+    the one row of a step that touched none adds nothing."""
     ins, (o_ref, *acc, a_ref) = refs[:2 + gated], refs[2 + gated:]
     firsts, down_ref = ins[:-1], ins[-1]
     g, j = pl.program_id(0), pl.program_id(1)
@@ -172,7 +175,7 @@ def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated):
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(g < n_ref[0])
+    @pl.when(n_ref[0] > 0)
     def _():
         @pl.when(j == 0)
         def _():
@@ -203,14 +206,18 @@ def _experts_body(eid_ref, n_ref, x_ref, c_ref, *refs, tiles, gated):
 
 def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
     """``experts_composite`` reading the weights of the held experts that
-    some token chose, and no others. Grid ``(experts, 2 * tiles)``: for one
-    expert, ``tiles`` steps accumulate ``x . W_up`` (and ``x . W_gate``,
-    where there is a gate) over tiles of the hidden size into ``[T, F]``
-    scratches, then ``tiles`` steps write the down product's columns, tile
-    by tile, into the resident output. Past the last touched expert every
-    block index stays where it was: no copy. A token count that is no
-    whole number of sublane tiles (24 slots) is padded up to one with
-    tokens that choose no expert; their rows are cut off the result."""
+    some token chose, and no others. Grid ``(touched, 2 * tiles)``, its
+    first extent TRACED: the count of held experts with a token, so the
+    walk ends at the last of them (two of 32 held: 2 rows, not 32). For
+    one expert, ``tiles`` steps accumulate ``x . W_up`` (and ``x .
+    W_gate``, where there is a gate) over tiles of the hidden size into
+    ``[T, F]`` scratches, then ``tiles`` steps write the down product's
+    columns, tile by tile, into the resident output. A step that touched
+    no held expert still walks ONE row (the output is zeroed at the first
+    grid step): its block indices stay at one tile and its body adds
+    nothing. A token count that is no whole number of sublane tiles (24
+    slots) is padded up to one with tokens that choose no expert; their
+    rows are cut off the result."""
     real, hidden = x.shape
     held, ffn, _ = w_up.shape
     firsts = [w_up] if w_gate is None else [w_up, w_gate]
@@ -229,30 +236,28 @@ def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
     order = jnp.argsort(jnp.logical_not(touched),
                         stable=True).astype(jnp.int32)
     count = jnp.sum(touched.astype(jnp.int32))
-    last = jnp.maximum(count - 1, 0)
-    eid = jnp.where(jnp.arange(held) < count, order, order[last])
     xs = jnp.swapaxes(x.astype(w_up.dtype).reshape(t, tiles, tile), 0, 1)
     cols = c.astype(jnp.float32).T[:, :, None]                 # [E, T, 1]
 
-    def step(g, j, n_ref):
-        return jnp.where(g < n_ref[0], j, 2 * tiles - 1)
+    def step(j, n_ref):
+        return jnp.where(n_ref[0] > 0, j, 2 * tiles - 1)
 
     first = pl.BlockSpec(
         (None, ffn, tile), lambda g, j, e, n: (
-            e[g], 0, jnp.minimum(step(g, j, n), tiles - 1)))
+            e[g], 0, jnp.minimum(step(j, n), tiles - 1)))
     out = pl.pallas_call(
         functools.partial(_experts_body, tiles=tiles,
                           gated=w_gate is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(held, 2 * tiles),
+            grid=(jnp.maximum(count, 1), 2 * tiles),
             in_specs=[
                 pl.BlockSpec((tiles, t, tile), lambda g, j, e, n: (0, 0, 0)),
                 pl.BlockSpec((None, t, 1), lambda g, j, e, n: (e[g], 0, 0)),
                 *[first for _ in firsts],
                 pl.BlockSpec(
                     (None, ffn, tile), lambda g, j, e, n: (
-                        e[g], 0, jnp.maximum(step(g, j, n) - tiles, 0))),
+                        e[g], 0, jnp.maximum(step(j, n) - tiles, 0))),
             ],
             out_specs=pl.BlockSpec((tiles, t, tile),
                                    lambda g, j, e, n: (0, 0, 0)),
@@ -265,7 +270,7 @@ def moe_experts(x, c, w_up, w_down, w_gate=None, interpret=False):
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="moe_experts",
-    )(eid, count.reshape(1), xs, cols, *firsts, w_down)
+    )(order, count.reshape(1), xs, cols, *firsts, w_down)
     out = jnp.swapaxes(out, 0, 1).reshape(t, hidden)
     return out[:real] if pad else out
 

@@ -220,16 +220,18 @@ def test_moe_experts_takes_a_step_of_no_whole_sublane_tiles(v5e_devices):
 
 #: ``moe_experts``' traced program (the call's grid, block maps, scratches
 #: and body: ``str(jax.make_jaxpr(...))``, which names no file and no line)
-#: at the step geometries of the five routed serving cells, tokens x hidden
-#: x width x held x matrices, as PR 60's PARENT traced it: sha256. The
-#: grouped product got a body of its own there and this kernel's lost an
-#: argument; the five cells' step must not have moved with it
+#: at the step geometries of the five routed serving cells
+#: (``kernels.MOE_EXPERTS_STEPS``), as PR 62 left it (the grid's expert axis
+#: a traced bound): sha256. An edit beside the kernel (the grouped
+#: product's body, PR 60) must not move the five cells' step; a deliberate
+#: edit to ``moe_experts`` recomputes these AND bumps its ``KernelSpec``
+#: version
 EXPERTS_DIGESTS = {
-    "nemotron3_nano_30b_a3b": ((32, 2688, 1856, 16, 2), "a51f5c97cdae16a0"),
-    "lfm2_24b_a2b": ((128, 2048, 1536, 8, 3), "26c19befe4b6345d"),
-    "sdar_30b_a3b": ((128, 2048, 768, 16, 3), "44f8cc6ccb8c0f2e"),
-    "mistral_small_4_119b": ((16, 4096, 2048, 16, 3), "0b5bf87580e762dc"),
-    "trinity_large_preview": ((24, 3072, 3072, 32, 3), "3d28d24cef18a061"),
+    "nemotron3_nano_30b_a3b": "9af8962f4d132952",
+    "lfm2_24b_a2b": "b1199ed721335b3d",
+    "sdar_30b_a3b": "021d63bb1cebc6d9",
+    "mistral_small_4_119b": "2475faf3bb8613f2",
+    "trinity_large_preview": "65720a41c3457090",
 }
 
 
@@ -239,13 +241,15 @@ def test_moe_experts_lowers_to_the_module_it_did(cell):
 
     from paddle_tpu.kernels import moe
 
-    (T, H, F, E, matrices), digest = EXPERTS_DIGESTS[cell]
+    assert set(EXPERTS_DIGESTS) == set(kernels.MOE_EXPERTS_STEPS)
+    T, H, F, E, matrices = kernels.MOE_EXPERTS_STEPS[cell]
     args = [jax.ShapeDtypeStruct((T, H), np.dtype("bfloat16")),
             jax.ShapeDtypeStruct((T, E), np.dtype("float32"))] + [
         jax.ShapeDtypeStruct((E, F, H), np.dtype("bfloat16"))] * matrices
     text = str(jax.make_jaxpr(moe.moe_experts)(*args))
     assert "moe.py" not in text and "name=moe_experts" in text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        EXPERTS_DIGESTS[cell])
 
 
 def _arrays_of_rows(text, rows):
