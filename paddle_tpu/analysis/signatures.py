@@ -99,6 +99,13 @@ _SIGNATURES = {
         ranks={"Q": 2, "KArena": 2, "VArena": 2, "Rows": 1, "Span": 1},
         dtype_family={"Q": "float", "Span": "int", "Rows": "int"},
     ),
+    # an indexer's choice of rows: a step's bias or a chunk's span
+    "sparse_index_select": OpSignature(
+        same_dtype=[("Q", "Arena")],
+        ranks={"Q": 2, "W": 2, "Arena": 2, "Rows": 1, "Bias": 3, "Span": 1},
+        dtype_family={"Q": "float", "W": "float", "Rows": "int",
+                      "Bias": "float", "Span": "int"},
+    ),
     # one latent arena: a row is its token's key and, its first lanes, value
     "paged_latent_attention": OpSignature(
         same_dtype=[("Q", "Arena", "WUK", "WUV")],

@@ -12,6 +12,8 @@ cached_attention     cached_attention (decode [S,1]) bit       mode
 paged_attention      paged_attention, paged_latent_attention (decode [S,1]; one latent arena or K and V) tolerance mode
 chunk_paged_attention chunk_paged_attention ([C] of one slot) tolerance mode
 latent_chunk_attention chunk_latent_attention ([C] of one slot over ONE latent arena, expanded; fallback: the same form by XLA's loops) tolerance mode
+index_select         sparse_index_select (an indexer's scores and exact top-k rows, a step's slots or a chunk's queries) tolerance mode
+masked_chunk_attention chunk_paged_attention with a Mask ([C] of one slot under the selection) tolerance mode
 moe_experts          moe_routed_experts (decode)     tolerance mode
 moe_grouped          moe_routed_experts (a prompt chunk's pairs, by expert) tolerance mode
 ssm_update           mamba2_mixer (decode [S,1]; a grid step a stepping slot's heads, as many as VMEM takes) tolerance mode
@@ -488,6 +490,158 @@ def _tpu_cases_chunk_attention():
             case(1024, 5136, 16, 8, 6, 128, window=4096)]
 
 
+def _sparse_case(rng, G, Q, L, bs, heads, width, dtype="float32"):
+    """``G`` sequences' index arena under shuffled block tables and ``G x
+    Q`` index queries with their weights: ``(q, w, arena, rows)``."""
+    per_slot = -(-L // bs)
+    pool = G * per_slot + 3
+    ids = rng.permutation(pool)[:G * per_slot].reshape(G, per_slot)
+    rows = (ids[:, :, None] * bs + np.arange(bs)).reshape(G, -1)[:, :L]
+    draw = lambda *shape: rng.randn(*shape).astype(dtype)
+    return (draw(G * Q, heads * width), draw(G * Q, heads),
+            draw(pool * bs, width), rows.reshape(-1).astype("int64"))
+
+
+def _parity_index_select(rng):
+    """The selection's set is ``lax.top_k``'s, ties and exact zeros
+    included, whatever the horizon (none, fewer than ``topk``, more); the
+    scores kernel is within 2e-5 of its composite for a step's slots and
+    for a chunk's queries, at a horizon one under, at and one over a block
+    and a copy tile."""
+    import jax
+
+    from paddle_tpu.kernels import sparse as S
+
+    for n, l, topk in ((16, 2048, 64), (32, 1024, 200), (8, 1024, 1)):
+        scores = rng.randn(n, l).astype("float32")
+        # ties: whole runs of one value, and the relu's exact zeros (of
+        # both signs) across the threshold
+        scores[:, ::3] = np.round(scores[:, ::3])
+        scores[:, 1::5] = np.where(rng.rand(n, len(scores[0, 1::5])) < .5,
+                                   0.0, -0.0)
+        horizon = rng.randint(0, l + 1, n).astype("int32")
+        horizon[:4] = (0, 1, topk, l)
+        want = _top_k_mask(scores, horizon, topk)
+        # (top_k orders -0.0 under 0.0; the selection takes them as equal)
+        _vals, idx = jax.lax.top_k(
+            _valid_scores(scores + np.float32(0.0), horizon), topk)
+        for r in range(n):
+            k = min(topk, int(horizon[r]))
+            assert sorted(np.asarray(idx[r][:k])) == sorted(
+                np.flatnonzero(want[r])), "the test's own set is top_k's"
+        got = np.asarray(jax.jit(lambda s, h: S.index_select(
+            s, h, topk, interpret=True))(scores, horizon))
+        ref = np.asarray(jax.jit(lambda s, h: S.index_select_composite(
+            s, h, topk))(scores, horizon))
+        assert (got != 0).tolist() == want.tolist(), f"kernel set {n}x{l}"
+        assert ref.tolist() == want.tolist(), f"composite set {n}x{l}"
+    for G, Q, horizons in ((4, 1, (0, 15, 528, 1040)),
+                           (1, 16, tuple(range(505, 521)))):
+        args = _sparse_case(rng, G, Q, 1040, 16, 4, 128)
+        hz = np.array(horizons, "int32")
+        got = np.asarray(jax.jit(lambda *a: S.index_scores(
+            *a, G, 16, hz, interpret=True))(*args))
+        ref = np.asarray(jax.jit(lambda *a: S.index_scores_composite(
+            *a, G))(*args))
+        for r, h in enumerate(horizons):
+            _assert_close_both_ways(got[r, :h], ref[r, :h],
+                                    f"index_scores {G}x{Q} row {r}", 2e-5,
+                                    2e-5)
+
+
+def _top_k_mask(scores, horizon, topk):
+    """``lax.top_k``'s set as a bool mask, in numpy, for the tests: of the
+    positions under a row's horizon the ``min(topk, horizon)`` of largest
+    score, a tie to the lower position."""
+    scores = np.asarray(scores, np.float32)
+    out = np.zeros(scores.shape, bool)
+    for r, hz in enumerate(np.asarray(horizon)):
+        hz = int(hz)
+        order = np.lexsort((np.arange(hz), -scores[r, :hz]))
+        out[r, order[:min(int(topk), hz)]] = True
+    return out
+
+
+def _valid_scores(scores, horizon):
+    return np.where(np.arange(scores.shape[1])[None] < horizon[:, None],
+                    scores, -np.inf).astype("float32")
+
+
+def _tpu_cases_index_select():
+    """keye_vl_2_0_30b_a3b's step (16 slots of 32,768 positions, 16 index
+    heads over one 128-lane key, block 16, bfloat16) and chunk (1,024
+    queries): the scores, then the top 2,048."""
+    from paddle_tpu.kernels import sparse as S
+
+    def scores(G, Q, L, bs=16, heads=16, width=128):
+        def fwd(q, w, arena, rows, hz):
+            return S.index_scores(q, w, arena, rows, G, bs, hz)
+
+        return (f"scores_g{G}_q{Q}_l{L}_h{heads}x{width}_bf16", fwd, [
+            ((G * Q, heads * width), "bfloat16"), ((G * Q, heads), "float32"),
+            ((4 * L, width), "bfloat16"), ((G * L,), "int32"),
+            ((G * Q,), "int32")])
+
+    def select(N, L, topk=2048):
+        def fwd(s, hz):
+            return S.index_select(s, hz, topk)
+
+        return (f"select_n{N}_l{L}_k{topk}", fwd,
+                [((N, L), "float32"), ((N,), "int32")])
+
+    return [scores(16, 1, 32768), scores(1, 1024, 32768),
+            select(16, 32768), select(1024, 32768)]
+
+
+def _parity_masked_chunk(rng):
+    """The masked chunk kernel within 2e-5 both ways of the composite under
+    the mask's bias, the chunk at the prompt's start and behind rows, under
+    random masks inside the causal horizon; a query that keeps nothing
+    gives zeros."""
+    import jax
+
+    from paddle_tpu.kernels import attention as A
+    from paddle_tpu.kernels import sparse as S
+
+    G, per, D, bs, C, L = 4, 8, 128, 16, 32, 320
+    args = _chunk_case(rng, C, L, bs, G, per, D)
+    sm = 1.0 / float(np.sqrt(D))
+    kernel = jax.jit(lambda q, k, v, rows, span, mask: S.masked_chunk_attention(
+        q, k, v, rows, span, mask, bs, sm, G, interpret=True))
+    composite = jax.jit(lambda q, k, v, rows, mask: S.masked_chunk_composite(
+        q, k, v, rows, mask, sm, G))
+    for start, real in ((0, 32), (0, 7), (224, 31), (240, 17), (288, 32)):
+        span = np.array([start, real], "int32")
+        horizon = np.asarray(A.chunk_horizon(span, C, L))
+        mask = ((rng.rand(C, L) < 0.3)
+                & (np.arange(L)[None] < horizon[:, None])).astype("int8")
+        mask[: real, 0] = 1          # every real query keeps a row
+        got = np.asarray(kernel(*args, span, mask))
+        ref = np.asarray(composite(*args, mask))
+        _assert_close_both_ways(got[:real], ref[:real],
+                                f"masked_chunk {start}+{real}", 2e-5, 2e-5)
+        assert not got[real:].any()
+
+
+def _tpu_cases_masked_chunk():
+    """keye_vl_2_0_30b_a3b's chunk: 1,024 queries of 32 heads of 128 over
+    up to 32,768 rows of 4 K/V heads, block 16, bfloat16, under the
+    selection's int8 mask."""
+    from paddle_tpu.kernels import sparse as S
+
+    def case(C, L, bs, G, per, D):
+        def fwd(q, k, v, rows, span, mask):
+            return S.masked_chunk_attention(q, k, v, rows, span, mask, bs,
+                                            1.0 / float(np.sqrt(D)), G)
+
+        return (f"c{C}_l{L}_b{bs}_g{G}x{per}x{D}_bf16", fwd, [
+            ((C, G * per * D), "bfloat16"), ((4 * L, G * D), "bfloat16"),
+            ((4 * L, G * D), "bfloat16"), ((L,), "int32"), ((2,), "int32"),
+            ((C, L), "int8")])
+
+    return [case(1024, 32768, 16, 4, 8, 128)]
+
+
 def _latent_chunk_case(rng, heads, nope, rope, value, latent, L, bs, C,
                        dtype="float32"):
     """One slot's latent arena (rows ``[c | k^R | zeros]`` of 128 lanes)
@@ -877,6 +1031,22 @@ register(KernelSpec(
         "mask made on the device from the chunk's span, online softmax "
         "over double-buffered copy tiles (kernels/attention.py "
         "chunk_attention)",
+))
+register(KernelSpec(
+    "index_select", ("sparse_index_select",), "tolerance",
+    _parity_index_select, tpu_cases=_tpu_cases_index_select,
+    doc="an indexer's scores over a sequence's live index blocks "
+        "(index_scores) and the exact top-k rows a query keeps, found by "
+        "bisection over the scores' ordered bits, no sort (index_select): "
+        "the selection as a mask (kernels/sparse.py)",
+))
+register(KernelSpec(
+    "masked_chunk_attention", ("chunk_paged_attention",), "tolerance",
+    _parity_masked_chunk, tpu_cases=_tpu_cases_masked_chunk,
+    doc="a prompt chunk's queries over the live blocks of its slot under a "
+        "[C, L] mask (the indexer's selection), the mask's tile copied "
+        "beside K and V, a query head a product (kernels/sparse.py "
+        "masked_chunk_attention)",
 ))
 register(KernelSpec(
     "latent_chunk_attention", ("chunk_latent_attention",), "tolerance",

@@ -31,6 +31,7 @@ __all__ = [
     "cached_attention",
     "paged_attention",
     "chunk_paged_attention",
+    "sparse_index_select",
     "chunk_mask_bias",
     "paged_step_feeds",
     "paged_block_feeds",
@@ -850,9 +851,36 @@ def chunk_mask_bias(span, chunk, length, block_len=1, name=None):
     return out
 
 
+def sparse_index_select(q, w, arena, rows, topk, block_size, bias=None,
+                        span=None, name=None):
+    """The rows an indexer lets each query attend to (ops/nn.py
+    ``sparse_index_select``; kernels/sparse.py): ``q`` ``[N, heads *
+    width]`` index queries and ``w`` ``[N, heads]`` their float32 weights
+    against the index keys ``arena`` ``[R, width]`` under the row map
+    ``rows``; a query keeps the ``topk`` positions of largest ``sum_j w_j
+    relu(q_j . key)`` among those it sees, a tie to the lower position, all
+    of them where it sees fewer. A decode step hands its additive ``bias``
+    ``[S, 1, L]`` and gets it back with the positions not kept closed (what
+    ``paged_attention`` takes); a prompt chunk hands its ``span`` and gets
+    the int8 ``[C, >= L]`` mask that ``chunk_paged_attention`` takes."""
+    helper = LayerHelper("sparse_index_select", name=name)
+    step = bias is not None
+    out = helper.create_variable_for_type_inference(
+        bias.dtype if step else "int8")
+    helper.append_op(
+        "sparse_index_select",
+        {"Q": [q.name], "W": [w.name], "Arena": [arena.name],
+         "Rows": [rows.name],
+         **({"Bias": [bias.name]} if step else {"Span": [span.name]})},
+        {"Out": [out.name]},
+        {"topk": int(topk), "block_size": int(block_size)},
+    )
+    return out
+
+
 def chunk_paged_attention(q, k_arena, v_arena, rows, span, kv_heads,
                           block_size, sm_scale=1.0, block_len=1, window=0,
-                          name=None):
+                          mask=None, name=None):
     """A prompt chunk's queries ``[C, heads * D]`` over ONE sequence's
     ``[L]`` rows of the paged arenas, grouped-query (``kv_heads`` K/V heads
     a row), under the mask the device makes of ``span`` (the chunk's first
@@ -860,13 +888,16 @@ def chunk_paged_attention(q, k_arena, v_arena, rows, span, kv_heads,
     program's form of ``paged_attention``, from the live blocks alone where
     the kernel serves it (kernels/attention.py ``chunk_attention``). With
     ``window`` W a query sees the last W positions, its own among them, and
-    nothing older (``chunk_floor``)."""
+    nothing older (``chunk_floor``). With ``mask`` (``sparse_index_select``'s
+    int8 ``[C, >= L]``) a query sees the rows its mask keeps, which lie
+    under the span's horizon, and nothing else."""
     helper = LayerHelper("chunk_paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     helper.append_op(
         "chunk_paged_attention",
         {"Q": [q.name], "KArena": [k_arena.name], "VArena": [v_arena.name],
-         "Rows": [rows.name], "Span": [span.name]},
+         "Rows": [rows.name], "Span": [span.name],
+         **({"Mask": [mask.name]} if mask is not None else {})},
         {"Out": [out.name]},
         {"sm_scale": float(sm_scale), "kv_heads": int(kv_heads),
          "block_size": int(block_size), "block_len": int(block_len),

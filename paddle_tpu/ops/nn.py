@@ -754,9 +754,73 @@ def _chunk_mask_bias(ins, attrs):
         attrs.get("block_len", 1))]}
 
 
+def _index_select(ins, attrs, sel):
+    """The rows an indexer lets each query attend to (kernels/sparse.py):
+    ``Q`` ``[N, heads * width]`` index queries and ``W`` ``[N, heads]``
+    their float32 weights against the index keys ``Arena`` ``[R, width]``
+    under the row map ``Rows``. A decode step hands its additive ``Bias``
+    ``[S, 1, L]`` (a query a slot) and gets it back with every position
+    outside the query's ``topk`` closed; a prompt chunk hands its ``Span``
+    (one sequence's ``Rows`` ``[L]``) and gets the int8 mask ``[C, >= L]``
+    that ``chunk_paged_attention`` takes as ``Mask``. ``sel`` says whether
+    the kernels serve it."""
+    from paddle_tpu.kernels import sparse
+
+    q, w, arena = first(ins, "Q"), first(ins, "W"), first(ins, "Arena")
+    rows, bias = first(ins, "Rows"), maybe(ins, "Bias")
+    topk = int(attrs["topk"])
+    if bias is not None:
+        seqs, horizon = bias.shape[0], sparse.step_lengths(bias)
+    else:
+        from paddle_tpu.kernels.attention import chunk_horizon
+
+        seqs = 1
+        horizon = chunk_horizon(first(ins, "Span"), q.shape[0],
+                                rows.shape[0])
+    if sel is not None and attrs.get("block_size"):
+        scores = sparse.index_scores(q, w, arena, rows, seqs,
+                                     attrs["block_size"], horizon,
+                                     interpret=sel.interpret)
+        keep = sparse.index_select(scores, horizon, topk,
+                                   interpret=sel.interpret)
+    else:
+        keep = sparse.index_select_composite(
+            sparse.index_scores_composite(q, w, arena, rows, seqs), horizon,
+            topk).astype(jnp.int8)
+    if bias is None:
+        return {"Out": [keep]}
+    length = bias.shape[-1]
+    return {"Out": [jnp.where(keep[:, :length] != 0, bias.reshape(
+        seqs, length), -1e9).astype(bias.dtype)[:, None]]}
+
+
+def _index_select_pallas(ins, attrs):
+    from paddle_tpu import kernels
+
+    return _index_select(ins, attrs, kernels.selected("index_select"))
+
+
+OpRegistry.register(
+    OpDef(
+        "sparse_index_select",
+        lambda ins, attrs: _index_select(ins, attrs, None),
+        pallas=_index_select_pallas,
+        nondiff_inputs=("Q", "W", "Arena", "Rows", "Bias", "Span"),
+    )
+)
+
+
 def _chunk_paged_attention_reference(ins, attrs):
     from paddle_tpu.kernels import attention as fused
 
+    if maybe(ins, "Mask") is not None:
+        from paddle_tpu.kernels import sparse
+
+        rows = first(ins, "Rows")
+        return {"Out": [sparse.masked_chunk_composite(
+            first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
+            rows, first(ins, "Mask")[:, :rows.shape[0]],
+            attrs.get("sm_scale", 1.0), attrs["kv_heads"])]}
     return {"Out": [fused.chunk_attention_by_span(
         first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
         first(ins, "Rows"), first(ins, "Span"), attrs.get("sm_scale", 1.0),
@@ -771,6 +835,14 @@ def _chunk_paged_attention_pallas(ins, attrs):
     sel = kernels.selected_for("chunk_paged_attention", attrs)
     if sel is None:
         return _chunk_paged_attention_reference(ins, attrs)
+    if maybe(ins, "Mask") is not None:
+        from paddle_tpu.kernels import sparse
+
+        return {"Out": [sparse.masked_chunk_attention(
+            first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
+            first(ins, "Rows"), first(ins, "Span"), first(ins, "Mask"),
+            attrs["block_size"], attrs.get("sm_scale", 1.0),
+            attrs["kv_heads"], interpret=sel.interpret)]}
     return {"Out": [fused.chunk_attention(
         first(ins, "Q"), first(ins, "KArena"), first(ins, "VArena"),
         first(ins, "Rows"), first(ins, "Span"), attrs["block_size"],
@@ -787,7 +859,7 @@ OpRegistry.register(
         "chunk_paged_attention",
         _chunk_paged_attention_reference,
         pallas=_chunk_paged_attention_pallas,
-        nondiff_inputs=("Rows", "Span"),
+        nondiff_inputs=("Rows", "Span", "Mask"),
     )
 )
 

@@ -48,6 +48,7 @@ from paddle_tpu.serving.decode import (
     build_lfm2_model,
     build_nemotron_h_model,
     build_ouro_model,
+    build_keye_vl_model,
     build_sdar_model,
 )
 from paddle_tpu.serving.engine import ServingEngine
@@ -85,6 +86,7 @@ __all__ = [
     "build_latent_moe_model",
     "build_lfm2_model",
     "build_ouro_model",
+    "build_keye_vl_model",
     "build_sdar_model",
     "Priority",
     "RejectedError",

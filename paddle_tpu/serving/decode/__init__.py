@@ -68,7 +68,8 @@ from paddle_tpu.serving.decode.generate import (
 from paddle_tpu.serving.decode.metrics import DecodeMetrics
 from paddle_tpu.serving.decode.hybrid import (
     build_afmoe_model, build_granite_hybrid_model, build_latent_moe_model, build_lfm2_model,
-    build_nemotron_h_model, build_ouro_model, build_sdar_model)
+    build_keye_vl_model, build_nemotron_h_model, build_ouro_model,
+    build_sdar_model)
 from paddle_tpu.serving.decode.model import DecodeModel, build_decoder_model
 from paddle_tpu.serving.decode.pool import (
     BlockPool,
@@ -98,6 +99,7 @@ __all__ = [
     "build_latent_moe_model",
     "build_lfm2_model",
     "build_ouro_model",
+    "build_keye_vl_model",
     "build_sdar_model",
     "prompt_key",
 ]

@@ -1543,6 +1543,9 @@ class _ModelEntry:
         pos = np.zeros((1, C), "int64")
         pos[0, :real] = np.arange(start, stop)
         self._window_chunk(st.kv, start, stop)
+        if m.index_names:
+            self._metrics.observe_sparse_chunk(start, stop, m.index_topk,
+                                               len(m.index_names))
         t0 = time.perf_counter()
         try:
             with profiler.RecordEvent("decode::chunk") as ev:
@@ -2436,6 +2439,9 @@ class _ModelEntry:
                 for g in self.kv.windowed:
                     self._metrics.observe_window_rows(st.cursor + 1,
                                                       st.kv.groups[g])
+                if m.index_names:
+                    self._metrics.observe_sparse_step(
+                        st.cursor + 1, m.index_topk, len(m.index_names))
             reads = (st.cursor + m.block_len - 1) // m.block_size + 1
             live_blocks += reads
             if self.kv.copy_unit:

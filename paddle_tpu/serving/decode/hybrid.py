@@ -1,7 +1,7 @@
 """Hybrid decoders as paged DecodeModels: per-slot recurrent state beside
 paged grouped-query K/V rows, routed experts of which this chip holds a
 share, a stack of layers run several times a token, attention layers that
-keep different rows. Seven families, one
+keep different rows or read a chosen subset of them. Eight families, one
 set of parts (``_Parts``: the named seeded parameters, projections, norms,
 arenas and their write; ``_hybrid_model``: the two programs around a
 family's ``stack`` and the DecodeModel).
@@ -89,6 +89,25 @@ together, all under the slot's one bias row), whose experts see ``S x
 block_len`` tokens, and whose last op decides one position a slot
 (``block_fill_decide``).
 
+**``keye_vl``** (Kwai Keye-VL-2.0's language model: ``build_keye_vl_model``).
+``sdar_moe``'s layer (the Qwen3-MoE decoder's: the two share one stack,
+``_qk_normed_moe_stack``) under a causal mask, a token a step, and an INDEXER
+a layer (DeepSeek-Sparse-Attention's): from the layer's normed input ``x``,
+``qI = rot(W_qI x)`` in ``indexer_num_heads`` heads of ``indexer_head_dim``,
+ONE index key a token ``kI = rot(LN(W_kI x))`` (LayerNorm with weight and
+bias; the whole head rotated, rotate-half) and weights ``w = W_w x heads^-1/2
+dim^-1/2``; position t scores ``I(t, s) = sum_j w_j relu(qI_j . kI_s)`` for
+``s <= t`` and attends to its ``topk`` positions of largest score alone, a
+tie to the lower one, all of them where it sees fewer: exactly
+(kernels/sparse.py). The index keys lie in a THIRD paged arena a layer
+(``index_names``: ``[R, 128]``, the key's 64 lanes and zeros, under the
+layer's block table), written with K and V; a step hands ``paged_attention``
+its bias with the positions not kept closed, a chunk hands
+``chunk_paged_attention`` the selection's ``[C, L]`` mask. The chunk
+program sums its routed layers' counts on the device as ``mistral4``'s does.
+The vision tower is not built: image and video tokens are ids like any other
+and carry the text's one position.
+
 **``granitemoehybrid``** (IBM Granite 4.0-H, dense: ``build_granite_hybrid_model``).
 ``layer_types`` names one MIXER a layer, ``mamba`` (``nemotron_h``'s
 Mamba-2 mixer with ONE group: every head reads the same B and C) or
@@ -163,7 +182,8 @@ from paddle_tpu.serving.decode.model import (
     window_table_blocks)
 
 __all__ = ["build_nemotron_h_model", "build_lfm2_model", "build_ouro_model",
-           "build_sdar_model", "build_granite_hybrid_model",
+           "build_sdar_model", "build_keye_vl_model",
+           "build_granite_hybrid_model",
            "build_latent_moe_model", "build_afmoe_model", "yarn_frequencies",
            "MOE_COUNTS", "GROUPED_COUNTS", "LOOP_COUNTS"]
 
@@ -264,7 +284,7 @@ class _Parts:
 
     def __init__(self, prefix, dtype, eps, std, back, rows, kv_width,
                  a_layers, slot_states, latent=False, windows=(),
-                 block_size=0):
+                 block_size=0, index_width=0):
         import paddle_tpu as fluid
         from paddle_tpu.core.ir import Program
 
@@ -281,6 +301,11 @@ class _Parts:
             (f"{prefix}.lcache{tag}",) if latent
             else (f"{prefix}.kcache{tag}", f"{prefix}.vcache{tag}")
             for tag in tags]
+        # an indexer's keys: a THIRD arena a layer, ``index_width`` lanes a
+        # token, under the layer's block table
+        self.index_width = int(index_width)
+        self.index_names = ([f"{prefix}.icache{tag}" for tag in tags]
+                            if index_width else [])
         self.slot_states = slot_states
         self.block_size = int(block_size)
         self.window_layers = [list(layers) for _n, layers, _b, _w in windows]
@@ -352,6 +377,18 @@ class _Parts:
             _state_var(program, self.startup, n, [rows, self.kv_width],
                        dtype=self.dtype) for n in names)
 
+    def write_index(self, program, i, wrows, axis, row):
+        """``write`` for layer ``i``'s index arena: the tokens' index keys
+        ``[.., index_width]`` at the rows K and V go to."""
+        fluid = self.fluid
+        arena = _state_var(
+            program, self.startup, self.index_names[self.a_layers.index(i)],
+            [self.rows, self.index_width], dtype=self.dtype)
+        written = fluid.layers.block_scatter_write(
+            arena, wrows, fluid.layers.squeeze(row, [axis]))
+        fluid.layers.assign(written, output=arena)
+        return written
+
     def write(self, program, i, wrows, axis, *rows):
         """Scatter the new rows, one ``[.., kv_width]`` array an arena of
         layer ``i`` (K and V, or a latent cache's one; ``axis``: the one to
@@ -372,7 +409,7 @@ class _Parts:
 def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                   block_size, num_blocks, chunk_tokens, kv_heads, sm_scale,
                   eos_id, name, version, count_names=MOE_COUNTS, passes=1,
-                  block_len=1, mask_token=None, latent=None):
+                  block_len=1, mask_token=None, latent=None, index_topk=0):
     """The hybrid family's two programs around ``stack(program, toks,
     positions, wrows, mode, attend, slot)`` (the layers over ``toks`` ``[S,
     1]`` or ``[1, C]``; ``attend(i, q, k, v)`` is the program's own
@@ -389,7 +426,11 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
     ``parts`` has window groups, ``attend`` writes and reads layer ``i``
     through ITS group's row map, bias and write rows (model.py, "Layer
     groups"), a chunk under the group's window; the ``wrows`` the stack is
-    handed are the first group's, which marks every real token."""
+    handed are the first group's, which marks every real token. A layer
+    that hands ``attend`` an indexer's ``index`` (the tokens' index queries,
+    index keys and weights) keeps the keys in a third arena and attends to
+    the ``index_topk`` rows the indexer chooses (kernels/sparse.py): a step
+    under a bias with the others closed, a chunk under a mask."""
     import paddle_tpu as fluid
     from paddle_tpu.core.ir import Program, program_guard
     from paddle_tpu.utils import unique_name
@@ -432,11 +473,18 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                 packed, column, n, BS) + (n * BS,))
             column += 3 + n
 
-        def attend_step(i, q, k, v):
+        def attend_step(i, q, k, v, index=None):
             g = parts.group_of(i)
             gbias, grows, gwrows, length = (
                 (bias, rows, wrows, L) if g is None else step_groups[g])
             nk, nv = parts.write(decode, i, gwrows, 1, k, v)
+            if index is not None:
+                qi, ki, w = index
+                gbias = fluid.layers.sparse_index_select(
+                    fluid.layers.squeeze(qi, [1]),
+                    fluid.layers.squeeze(w, [1]),
+                    parts.write_index(decode, i, gwrows, 1, ki), grows,
+                    index_topk, BS, bias=gbias)
             ctx = fluid.layers.paged_attention(
                 fluid.layers.squeeze(q, [1]), nk, nv, grows, gbias, S,
                 length, sm_scale=sm_scale, block_size=BS, kv_heads=kv_heads)
@@ -514,14 +562,22 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
                 fluid.data(grows, [n * BS], dtype="int64"),
                 fluid.data(gwrows, [C], dtype="int64"), groups[g].window))
 
-        def attend_chunk(i, q, k, v):
+        def attend_chunk(i, q, k, v, index=None):
             g = parts.group_of(i)
             gspan, grows, gwrows, window = (
                 (cspan, crows, cwrows, 0) if g is None else chunk_groups[g])
             nk, nv = parts.write(chunk, i, gwrows, 0, k, v)
+            mask = None
+            if index is not None:
+                qi, ki, w = index
+                mask = fluid.layers.sparse_index_select(
+                    fluid.layers.squeeze(qi, [0]),
+                    fluid.layers.squeeze(w, [0]),
+                    parts.write_index(chunk, i, gwrows, 0, ki), grows,
+                    index_topk, BS, span=gspan)
             ctx = fluid.layers.chunk_paged_attention(
                 fluid.layers.squeeze(q, [0]), nk, nv, grows, gspan, kv_heads,
-                BS, sm_scale=sm_scale, block_len=B, window=window)
+                BS, sm_scale=sm_scale, block_len=B, window=window, mask=mask)
             return fluid.layers.unsqueeze(ctx, [0])
 
         def attend_chunk_latent(i, q, row, weights):
@@ -547,7 +603,8 @@ def _hybrid_model(parts, stack, rebuild, *, vocab, hidden, slots, max_len,
         counts_fetch=token_counts.name if counts or B > 1 else None,
         count_names=count_names if counts else (), passes=passes,
         block_len=B, mask_token=mask_token, window_groups=groups,
-        prefill_logits_fetch=None, chunk_logits_fetch=chu_logits.name,
+        index_names=parts.index_names, index_width=parts.index_width,
+        index_topk=index_topk, prefill_logits_fetch=None, chunk_logits_fetch=chu_logits.name,
         prefill_kv_fetches=[], inject_kv_feeds=[],
         eos_id=eos_id, name=name, version=version, builder=rebuild)
 
@@ -963,6 +1020,84 @@ def build_ouro_model(
         version=version, count_names=LOOP_COUNTS, passes=T)
 
 
+def _qk_normed_moe_stack(parts, V, H, NL, NQ, NKV, D, rope_theta, router,
+                         held, offset, ffn, top_k, normalize, indexer=None,
+                         grouped_name=None):
+    """The stack ``sdar_moe`` and ``keye_vl`` share (the Qwen3-MoE
+    decoder's): ``NL`` layers of grouped-query attention with an RMSNorm
+    over each head of q and k and rotary positions, then softmax-routed
+    SwiGLU experts (top ``top_k`` of ``router``, ``held`` of them here),
+    a final RMSNorm and an untied head. ``indexer(i, x, positions)``, where
+    a family has one, gives layer ``i``'s index queries, keys and weights
+    from the layer's normed input: they ride to ``attend`` as its
+    ``index``. With ``grouped_name`` (a state's name) the chunk program
+    sums what its routed layers multiplied on the device and the next step
+    hands the sums over and zeroes them, as ``mistral4``'s does
+    (``GROUPED_COUNTS``)."""
+    fluid = parts.fluid
+    from paddle_tpu.initializer import ConstantInitializer
+
+    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
+                                parts.norm)
+    dtype, R = parts.dtype, parts.rows
+
+    def heads(t, n, positions, suffix):
+        return parts.normed_rotated_heads(t, n, positions, suffix,
+                                          rope_theta)
+
+    def stack(program, toks, positions, wrows, mode, attend, slot=None):
+        """The ``NL`` layers over ``toks``: attention, then the experts."""
+        h = fluid.layers.cast(fluid.layers.embedding(
+            toks, size=(V, H), dtype=dtype,
+            param_attr=matrix("embed")), "float32")
+        counts, pairs = [], []
+        for i in range(NL):
+            x = norm(h, f"l{i}.input_layernorm")
+            ctx = attend(
+                i,
+                heads(proj(x, NQ * D, f"l{i}.q", out_dtype="float32"),
+                      NQ, positions, f"l{i}.q_norm"),
+                heads(proj(x, NKV * D, f"l{i}.k", out_dtype="float32"),
+                      NKV, positions, f"l{i}.k_norm"),
+                proj(x, NKV * D, f"l{i}.v"),
+                **({"index": indexer(i, x, positions)} if indexer else {}))
+            h = fluid.layers.elementwise_add(h, proj(
+                ctx, H, f"l{i}.o", residual=True, out_dtype="float32"))
+            out, n, *g = fluid.layers.moe_routed_experts(
+                norm(h, f"l{i}.post_attention_layernorm"), wrows, R, router,
+                held, ffn, top_k,
+                {"gate": matrix(f"l{i}.gate"),
+                 # this router's choice is its scores' own
+                 "select_bias": attr(f"l{i}.select_bias",
+                                     ConstantInitializer(0.0)),
+                 "w_gate": matrix(f"l{i}.w1"),
+                 "w_up": matrix(f"l{i}.w3"),
+                 "w_down": matrix(f"l{i}.w2", residual=True)},
+                expert_offset=offset, normalize=normalize,
+                kernel=mode == "step", score="softmax",
+                **({"group_counts": True} if grouped_name else {}))
+            counts.append(n)
+            pairs.extend(g)
+            h = fluid.layers.elementwise_add(h, out)
+        logits = proj(norm(h, "norm"), V, "head", out_dtype="float32")
+        if not grouped_name:
+            return logits, counts
+        total = _state_var(program, parts.startup, grouped_name,
+                           [len(GROUPED_COUNTS)], dtype="int32")
+        if mode == "chunk":
+            fluid.layers.assign(fluid.layers.sums([total] + pairs),
+                                output=total)
+            return logits, []
+        both = fluid.layers.concat([fluid.layers.sums(counts), total],
+                                   axis=0)
+        fluid.layers.assign(
+            fluid.layers.fill_constant([len(GROUPED_COUNTS)], "int32", 0),
+            output=total)
+        return logits, [both]
+
+    return stack
+
+
 def build_sdar_model(
         vocab_size, hidden_size, num_hidden_layers, *, num_attention_heads,
         num_key_value_heads, head_dim, num_experts, router_experts,
@@ -984,9 +1119,6 @@ def build_sdar_model(
     an undecided position holds and what no answer may hold (default: the
     vocabulary's last id). ``initializer_range`` as ``build_lfm2_model``'s."""
     kwargs = dict(locals())
-    import paddle_tpu as fluid
-    from paddle_tpu.initializer import ConstantInitializer
-
     V, H, NL = int(vocab_size), int(hidden_size), int(num_hidden_layers)
     B = int(block_len)
     if B < 2 or int(denoising_steps) != B:
@@ -1008,52 +1140,107 @@ def build_sdar_model(
     std = float(initializer_range)
     parts = _Parts(prefix, dtype, float(rms_norm_eps), std,
                    std / math.sqrt(2 * NL), R, NKV * D, list(range(NL)), [])
-    attr, matrix, proj, norm = (parts.attr, parts.matrix, parts.proj,
-                                parts.norm)
-
-    def heads(t, n, positions, suffix):
-        return parts.normed_rotated_heads(t, n, positions, suffix,
-                                          rope_theta)
-
-    def stack(program, toks, positions, wrows, mode, attend, slot=None):
-        """The ``NL`` layers over ``toks``: attention, then the experts."""
-        h = fluid.layers.cast(fluid.layers.embedding(
-            toks, size=(V, H), dtype=dtype,
-            param_attr=matrix("embed")), "float32")
-        counts = []
-        for i in range(NL):
-            x = norm(h, f"l{i}.input_layernorm")
-            ctx = attend(
-                i,
-                heads(proj(x, NQ * D, f"l{i}.q", out_dtype="float32"),
-                      NQ, positions, f"l{i}.q_norm"),
-                heads(proj(x, NKV * D, f"l{i}.k", out_dtype="float32"),
-                      NKV, positions, f"l{i}.k_norm"),
-                proj(x, NKV * D, f"l{i}.v"))
-            h = fluid.layers.elementwise_add(h, proj(
-                ctx, H, f"l{i}.o", residual=True, out_dtype="float32"))
-            out, n = fluid.layers.moe_routed_experts(
-                norm(h, f"l{i}.post_attention_layernorm"), wrows, R, router,
-                held, int(moe_intermediate_size), int(num_experts_per_tok),
-                {"gate": matrix(f"l{i}.gate"),
-                 # this router's choice is its scores' own
-                 "select_bias": attr(f"l{i}.select_bias",
-                                     ConstantInitializer(0.0)),
-                 "w_gate": matrix(f"l{i}.w1"),
-                 "w_up": matrix(f"l{i}.w3"),
-                 "w_down": matrix(f"l{i}.w2", residual=True)},
-                expert_offset=offset, normalize=bool(norm_topk_prob),
-                kernel=mode == "step", score="softmax")
-            counts.append(n)
-            h = fluid.layers.elementwise_add(h, out)
-        logits = proj(norm(h, "norm"), V, "head", out_dtype="float32")
-        return logits, counts
+    stack = _qk_normed_moe_stack(
+        parts, V, H, NL, NQ, NKV, D, rope_theta, router, held, offset,
+        int(moe_intermediate_size), int(num_experts_per_tok),
+        bool(norm_topk_prob))
 
     return _hybrid_model(
         parts, stack, lambda: build_sdar_model(**kwargs), vocab=V, hidden=H,
         slots=S, max_len=L, block_size=BS, num_blocks=NB, chunk_tokens=C,
         kv_heads=NKV, sm_scale=1.0 / math.sqrt(D), eos_id=eos_id, name=name,
         version=version, block_len=B, mask_token=mask)
+
+
+def build_keye_vl_model(
+        vocab_size, hidden_size, num_hidden_layers, *, num_attention_heads,
+        num_key_value_heads, head_dim, num_experts, router_experts,
+        num_experts_per_tok, moe_intermediate_size, sa_config,
+        norm_topk_prob=True, rms_norm_eps=1e-6, rope_theta=10000000.0,
+        initializer_range=0.02, expert_rank=0, dtype="bfloat16", slots=4,
+        max_len=64, block_size=16, num_blocks=None, chunk_tokens=16,
+        eos_id=None, name="keye_vl", version="1"):
+    """Build the ``keye_vl`` language model as a paged DecodeModel (module
+    docstring): ``sdar_moe``'s stack a token a step, and an indexer a
+    layer. The sizes are the published ``config.json``'s keys under their
+    own names; ``sa_config`` is the published group whole
+    (``indexer_num_heads``, ``indexer_head_dim``, ``topk``; ONE index key
+    head: ``indexer_num_kv_heads`` 1; its chunk sizes tile the source's
+    score computation and are not read); ``num_experts`` is how many
+    experts are HELD here and ``router_experts`` how many the router
+    scores. ``chunk_tokens`` has to be a whole number of blocks."""
+    kwargs = dict(locals())
+    from paddle_tpu.initializer import ConstantInitializer
+
+    V, H, NL = int(vocab_size), int(hidden_size), int(num_hidden_layers)
+    S, L, BS, NB, C = _geometry(slots, max_len, block_size, num_blocks,
+                                chunk_tokens)
+    R = NB * BS
+    NQ, NKV, D = (int(num_attention_heads), int(num_key_value_heads),
+                  int(head_dim))
+    held, router = int(num_experts), int(router_experts)
+    offset = _held(expert_rank, held, router)
+    IH, ID, topk = (int(sa_config["indexer_num_heads"]),
+                    int(sa_config["indexer_head_dim"]),
+                    int(sa_config["topk"]))
+    if int(sa_config.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError("sa_config.indexer_num_kv_heads "
+                         f"{sa_config['indexer_num_kv_heads']}: the indexer "
+                         "keeps ONE key a token, which all its heads read")
+    if topk < 1 or ID % 2:
+        raise ValueError(f"sa_config: topk {topk} has to be positive and "
+                         f"indexer_head_dim {ID} even (it is rotated)")
+    if C % BS:
+        raise ValueError(f"chunk_tokens {C} has to be whole blocks of {BS}")
+    # a token's key in whole 128-lane tiles, zeros behind it: an array's
+    # minor dimension is tiled by 128 on the chip whatever its declared
+    # width, so a 64-lane row takes the same bytes
+    IW = -(-ID // 128) * 128
+    prefix = f"{name}_v{version}"
+    std = float(initializer_range)
+    parts = _Parts(prefix, dtype, float(rms_norm_eps), std,
+                   std / math.sqrt(2 * NL), R, NKV * D, list(range(NL)), [],
+                   index_width=IW)
+    fluid, proj, attr = parts.fluid, parts.proj, parts.attr
+
+    def rotated(t, lead, n, positions):
+        """``t`` ``[.., n * ID]`` rotated over each of its ``n`` heads (the
+        whole head, rotate-half), each padded to ``IW`` lanes."""
+        t = fluid.layers.rotary_embedding(
+            fluid.layers.reshape(t, lead + [n, ID]), positions,
+            theta=float(rope_theta), out_dtype=dtype)
+        if IW > ID:
+            t = fluid.layers.pad(t, [0, 0] * (len(lead) + 1) + [0, IW - ID])
+        return fluid.layers.reshape(t, lead + [n * IW])
+
+    def indexer(i, x, positions):
+        """Layer ``i``'s ``(qI, kI, w)`` from its normed input ``x``: the
+        index queries rotated, the one key LayerNormed (weight and bias)
+        and rotated, the weights scaled by ``heads^-1/2 width^-1/2``."""
+        lead = [int(d) for d in x.shape[:2]]
+        qi = rotated(proj(x, IH * ID, f"l{i}.index_q", out_dtype="float32"),
+                     lead, IH, positions)
+        ki = fluid.layers.layer_norm(
+            proj(x, ID, f"l{i}.index_k", out_dtype="float32"),
+            begin_norm_axis=2, epsilon=1e-6,
+            param_attr=attr(f"l{i}.index_k_norm", ConstantInitializer(1.0)),
+            bias_attr=attr(f"l{i}.index_k_norm_b", ConstantInitializer(0.0)))
+        w = fluid.layers.scale(
+            proj(x, IH, f"l{i}.index_w", out_dtype="float32"),
+            scale=1.0 / math.sqrt(IH * ID))
+        return qi, rotated(ki, lead, 1, positions), w
+
+    stack = _qk_normed_moe_stack(
+        parts, V, H, NL, NQ, NKV, D, rope_theta, router, held, offset,
+        int(moe_intermediate_size), int(num_experts_per_tok),
+        bool(norm_topk_prob), indexer=indexer,
+        grouped_name=f"{prefix}.grouped_counts")
+    return _hybrid_model(
+        parts, stack, lambda: build_keye_vl_model(**kwargs), vocab=V,
+        hidden=H, slots=S, max_len=L, block_size=BS, num_blocks=NB,
+        chunk_tokens=C, kv_heads=NKV, sm_scale=1.0 / math.sqrt(D),
+        eos_id=eos_id, name=name, version=version, index_topk=topk,
+        count_names=MOE_COUNTS + GROUPED_COUNTS)
 
 
 def build_granite_hybrid_model(

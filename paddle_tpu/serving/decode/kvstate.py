@@ -56,16 +56,17 @@ at once, and a block given back renews the promise for the blocks still to
 be opened.
 
 **What a store refuses to carry** is said once, by `KVStore.check_carries`,
-from three facts the model states of its state and one its groups do. Rows
+from four facts the model states of its state and one its groups do. Rows
 that are rewritten until a block is committed (``fills_blocks``) are no
 function of the token prefix yet; a per-slot ``recurrent`` state is no
 function of rows at all; rows that a group with a window gave back are
-gone: none of these may sit in the prefix cache or the host tier, which key
-on K/V rows and hold whole prefixes, and the latter two are never
-registered for sharing (``shares``). A model without an inject program
-(``chunks_only``) could never take rows back: it gets no tier, its sessions
-never spill, and nothing forks or re-derives its rows (``restores``). A
-store with a windowed group always reserves.
+gone; an indexer's keys (``index_names``) lie in an arena that neither the
+cache nor the tier reads: none of these may sit in the prefix cache or the
+host tier, which key on K/V rows and hold whole prefixes, and the latter
+three are never registered for sharing (``shares``). A model without an
+inject program (``chunks_only``) could never take rows back: it gets no
+tier, its sessions never spill, and nothing forks or re-derives its rows
+(``restores``). A store with a windowed group always reserves.
 """
 
 import numpy as np
@@ -265,13 +266,21 @@ class KVStore:
                 "window: a block that was given back can be neither shared "
                 "by a later prompt nor restored from the host KV tier, and "
                 "the prefix cache and the tier hold whole prefixes. " + both)
+        if model.index_names and (prefix_cache_size or tier_bytes):
+            raise EnforceError(
+                f"model {model.label} keeps an indexer's keys in an arena "
+                "of their own beside K and V: the prefix cache and the host "
+                "KV tier hold K and V rows alone, so a prefix taken from "
+                "either would come back without the keys its rows are "
+                "chosen by. " + both)
         if model.chunks_only and tier_bytes:
             raise EnforceError(
                 f"model {model.label} has no inject program: what the host "
                 "KV tier keeps (an evicted block's rows, a parked "
                 "session's) could never be put back. Host it on an engine "
                 f"with host_tier_mb=0 (got {tier_bytes >> 20})")
-        return (not (model.recurrent or model.window_groups),
+        return (not (model.recurrent or model.window_groups
+                     or model.index_names),
                 not model.chunks_only)
 
     # -- blocks: a chain taken, grown, forked and given back ---------------
@@ -675,6 +684,10 @@ class KVStore:
                     jnp.zeros((pool.rows, m.kv_width), m.kv_dtype),
                     self._device))
             pool.reset()
+        for n in m.index_names:
+            scope.set(n, jax.device_put(
+                jnp.zeros((self.pool.rows, m.index_width), m.kv_dtype),
+                self._device))
 
     def stats(self):
         pool = self.pool.stats()

@@ -148,6 +148,14 @@ LAUNCH_BUCKETS = (
 CHUNK_TOKEN_BUCKETS = tuple(float(1 << i) for i in range(1, 14))
 
 
+def _capped_pairs(start, stop, cap):
+    """The sum over ``p`` in ``[start, stop)`` of ``min(cap, p + 1)``: the
+    (query, row) pairs a chunk's queries see where each sees at most
+    ``cap`` rows (a window, an indexer's top-k)."""
+    ramp = min(max(cap - 1, start), stop)
+    return (ramp - start) * (start + ramp + 1) // 2 + (stop - ramp) * cap
+
+
 class DecodeMetrics(ServingMetrics):
     COUNTERS = ServingMetrics.COUNTERS + (
         # iteration-level scheduler ("generated_tokens" counts tokens a
@@ -270,6 +278,16 @@ class DecodeMetrics(ServingMetrics):
         # first query's lower edge to its last position, and the (query,
         # row) pairs the window's mask opens
         "attention_window_rows_chunk", "attention_window_pairs_chunk",
+        # a model whose layers keep an indexer's arena (model.py, "An
+        # indexer's arena"; none of these moves for any other), summed over
+        # its layers: per decode step the rows a stepping slot's query is
+        # let attend to, min(index_topk, rows in its context), beside the
+        # index keys it scores (all of them; the rows in context are
+        # "attention_rows_in_context_step", a slot and layer here); per
+        # chunk the (query, row) pairs the selection opens and the pairs
+        # it scores
+        "sparse_rows_selected_step", "index_rows_scanned_step",
+        "sparse_rows_selected_chunk", "index_rows_scanned_chunk",
         "kv_blocks_live_full", "kv_blocks_live_window",
         "kv_blocks_promised_full", "kv_blocks_promised_window",
         "kv_pool_blocks_full", "kv_pool_blocks_window",
@@ -450,6 +468,24 @@ class DecodeMetrics(ServingMetrics):
         self.incr("attention_rows_read_step", length - w.base)
         self.incr("attention_rows_in_context_step", length)
 
+    def observe_sparse_step(self, length, topk, layers):
+        """One stepping slot of a model with an indexer, ``length`` rows in
+        its context: each of the ``layers`` scores every row's index key
+        and lets the query attend to ``min(topk, length)`` of them."""
+        self.incr("sparse_rows_selected_step", min(topk, length) * layers)
+        self.incr("index_rows_scanned_step", length * layers)
+        self.incr("attention_rows_in_context_step", length * layers)
+
+    def observe_sparse_chunk(self, start, stop, topk, layers):
+        """One chunk ``[start, stop)`` of such a model: the query at ``p``
+        scores ``p + 1`` index keys and keeps ``min(topk, p + 1)`` rows, a
+        layer."""
+        scanned = layers * _capped_pairs(start, stop, stop)
+        self.incr("sparse_rows_selected_chunk",
+                  layers * _capped_pairs(start, stop, topk))
+        self.incr("index_rows_scanned_chunk", scanned)
+        self.incr("attention_rows_in_context_chunk", scanned)
+
     def observe_window_chunk(self, start, stop, w, given):
         """One chunk ``[start, stop)`` over footing ``w`` in a windowed
         group, which gave ``given`` blocks back before it: the group's
@@ -461,11 +497,8 @@ class DecodeMetrics(ServingMetrics):
         self.incr("attention_rows_in_context_chunk", stop)
         self.incr("attention_window_rows_chunk",
                   stop - max(start - size + 1, 0))
-        # sum over p in [start, stop) of min(size, p + 1)
-        ramp = min(max(size - 1, start), stop)
         self.incr("attention_window_pairs_chunk",
-                  (ramp - start) * (start + ramp + 1) // 2
-                  + (stop - ramp) * size)
+                  _capped_pairs(start, stop, size))
         self._observe_release(given)
 
     def _observe_release(self, blocks):

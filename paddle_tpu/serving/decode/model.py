@@ -190,7 +190,15 @@ class DecodeModel:
     write rows a group (`chunk_feeds`; a further group's under the names
     `chunk_group_feeds` gives): the row map `chunk_rows` long from the
     group's first live block, the span's start counted from that block's
-    first position. A model with one group is fed and run as it ever was."""
+    first position. A model with one group is fed and run as it ever was.
+
+    **An indexer's arena.** A layer whose queries attend to the
+    ``index_topk`` rows an indexer chooses keeps a THIRD arena beside K and
+    V (``index_names``, one a layer of ``state_names``: ``[R, index_width]``
+    of ``kv_dtype``, a token's one index key), written at the rows K and V
+    are and read through the same block table and row maps: the programs
+    need no feed for it. The store zeroes it with the others and carries it
+    nowhere (kvstate.py ``KVStore.check_carries``)."""
 
     # feed-name contract (fixed; the engine builds these arrays)
     DEC_TOKEN = "dec_token"
@@ -219,7 +227,8 @@ class DecodeModel:
                  version="1", builder=None, logits_mask=False,
                  token_fetch=None, kv_width=None, kv_dtype="float32",
                  slot_states=(), counts_fetch=None, count_names=(),
-                 passes=1, block_len=1, mask_token=None, window_groups=()):
+                 passes=1, block_len=1, mask_token=None, window_groups=(),
+                 index_names=(), index_width=0, index_topk=0):
         self.decode_program = decode_program
         self.prefill_program = prefill_program
         self.inject_program = inject_program
@@ -254,6 +263,10 @@ class DecodeModel:
         self.block_len = int(block_len)
         self.mask_token = mask_token
         self.window_groups = list(window_groups)
+        # an indexer's keys (class docstring, "An indexer's arena")
+        self.index_names = list(index_names)
+        self.index_width = int(index_width)
+        self.index_topk = int(index_topk)
         self.groups = [KVGroup("full", self.state_names, self.num_blocks,
                                None)] + self.window_groups
         if self.window_groups and (self.block_len > 1
@@ -305,9 +318,11 @@ class DecodeModel:
 
     @property
     def all_state_names(self):
-        """``state_names`` of every group, the first group's first."""
+        """``state_names`` of every group, the first group's first, then
+        the indexer's arenas, each alone."""
         return self.state_names + [
-            names for g in self.window_groups for names in g.state_names]
+            names for g in self.window_groups for names in g.state_names
+        ] + [(name,) for name in self.index_names]
 
     def window_table_blocks(self, group):
         """Blocks a slot can hold live in ``group`` when it STEPS: from the
@@ -481,7 +496,9 @@ class DecodeModel:
         groups = sum(g.num_blocks * self.block_size * row
                      * sum(len(names) for names in g.state_names)
                      for g in self.window_groups)
-        return self.rows * row * self.arenas + groups + slot
+        index = (len(self.index_names) * self.rows * self.index_width
+                 * _itemsize(self.kv_dtype))
+        return self.rows * row * self.arenas + groups + slot + index
 
     def slotted_equivalent_bytes(self):
         """What the PR 10 dense design would reserve for the same

@@ -571,3 +571,82 @@ def test_dropout_whose_rows_the_mesh_does_not_divide_draws_globally(
     shapes = _dropout_step_rng_shapes(v5e_devices, ("data",), (4,),
                                       (64, 126, 256), batch_first=False)
     assert set(shapes) == {(126, 64, 256)}, shapes
+
+
+@pytest.mark.parametrize("kind", ["step", "chunk"])
+def test_the_sparse_cells_programs_lower_with_no_fallback(kind, v5e_devices,
+                                                          monkeypatch):
+    """``keye_vl_2_0_30b_a3b``'s decode step and chunk programs, built at
+    the cell's size from a scope of SHAPES (no weights) and lowered for the
+    described chip: ``kernel_fallbacks_total`` does not move, and every
+    layer's selection is the kernels' (``index_scores`` then
+    ``index_select``), a step's attention ``paged_attention`` under the
+    selection's bias and a chunk's ``masked_chunk_attn`` under its mask."""
+    import importlib
+
+    from benchmark import manifest
+    from paddle_tpu.core import lowering
+    from paddle_tpu.kernels import registry
+    from paddle_tpu import serving
+
+    cell = "keye_vl_2_0_30b_a3b"
+    bench = manifest.load_manifest()
+    cfg = manifest.load_config(bench, cell)
+    builder = importlib.import_module("benchmark.builders." + cfg["builder"])
+    keys = manifest.published(cfg, False)
+    settings = manifest.sizes(cfg["settings"], False)
+    model = manifest.model_sizes(cfg, False)
+    m = serving.build_keye_vl_model(
+        name=cell, version="1", dtype=settings["dtype"],
+        expert_rank=settings["expert_rank"],
+        initializer_range=settings["initializer_range"],
+        **{k: keys[k] for k in builder._BUILDER_KEYS}, **model)
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    program, sig, fetch = {
+        "step": (m.decode_program, m.decode_feed_sig(), m.counts_fetch),
+        "chunk": (m.chunk_program, m.chunk_feed_sig(), m.chunk_logits_fetch),
+    }[kind]
+
+    class Shapes:
+        """A scope of shapes: the programs' persistables, no values."""
+
+        def __init__(self):
+            self.vars = {
+                name: jax.ShapeDtypeStruct(tuple(v.shape), np.dtype(v.dtype),
+                                           sharding=sharding)
+                for prog in (m.startup_program, program)
+                for block in prog.blocks
+                for name, v in block.vars.items() if v.persistable}
+
+        def has_var(self, name):
+            return name in self.vars
+
+        def find_var(self, name):
+            return self.vars.get(name)
+
+    scope = Shapes()
+    monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+    sig = sorted(sig)
+    before = kernels.fallback_counter().value
+    entry, _src = lowering.lower_step(
+        program, scope,
+        tuple((n, shape, str(np.dtype(dt))) for n, shape, dt in sig),
+        [fetch], donate=True, use_cache=False, persist=False, label="hlo")
+    text = entry.fn.lower(
+        tuple(jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+              for _n, shape, dt in sig),
+        tuple(scope.find_var(n) for n in entry.donated),
+        tuple(scope.find_var(n) for n in entry.readonly),
+        jax.ShapeDtypeStruct((2,), np.uint32, sharding=sharding),
+    ).as_text()
+    assert kernels.fallback_counter().value == before
+    layers = keys["num_hidden_layers"]
+    assert len(m.index_names) == layers == 12
+
+    def calls(name):
+        return len(re.findall(r'kernel_name\s*=\s*"%s"' % name, text))
+
+    assert calls("index_scores") == calls("index_select") == layers
+    attends = "paged_attention" if kind == "step" else "masked_chunk_attn"
+    assert calls(attends) == layers
+    assert calls("chunk_attention") == 0

@@ -194,6 +194,13 @@ REFUSALS = {
         "can be neither shared by a later prompt nor restored from the host "
         "KV tier, and the prefix cache and the tier hold whole prefixes. "
         + _BOTH),
+    "indexer": (
+        dict(index_names=["i"], chunks_only=True), "EnforceError",
+        ("prefix", "tier"),
+        "model m@1 keeps an indexer's keys in an arena of their own beside "
+        "K and V: the prefix cache and the host KV tier hold K and V rows "
+        "alone, so a prefix taken from either would come back without the "
+        "keys its rows are chosen by. " + _BOTH),
     "no_inject_program": (
         dict(chunks_only=True), "EnforceError", ("tier",),
         "model m@1 has no inject program: what the host KV tier keeps (an "
@@ -210,7 +217,8 @@ def test_a_store_refuses_what_the_state_cannot_give_it(fact, size):
     facts, error, refused, words = REFUSALS[fact]
     model = types.SimpleNamespace(**dict(
         dict(label="m@1", block_len=4, fills_blocks=False, recurrent=False,
-             chunks_only=False, window_groups=[]), **facts))
+             chunks_only=False, window_groups=[], index_names=[]),
+        **facts))
     tier, prefix = ((3 << 20, 0) if size == "tier" else (0, 4))
     if size in refused:
         with pytest.raises(RuntimeError) as caught:

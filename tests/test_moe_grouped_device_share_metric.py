@@ -72,7 +72,9 @@ def test_the_manifest_lists_it_for_the_cell(cell):
     entry, spec = mine[NAME], manifest.load_metric(NAME)
     for key in ("unit", "better", "source", "layer", "moves"):
         assert spec[key] == entry[key], key
-    assert entry["workloads"] == list(CELLS) and "workloads" not in spec
+    # (IN the list, in their order: a later cell may have joined it)
+    assert entry["workloads"][:len(CELLS)] == list(CELLS)
+    assert "workloads" not in spec
     assert (entry["layer"], entry["moves"], entry["source"]) == (
         "kernels", "serve_token_latency_p50", "device_trace")
     assert spec["args"] == {"pattern": "moe_grouped"}
