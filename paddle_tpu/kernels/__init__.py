@@ -1019,14 +1019,16 @@ register(KernelSpec(
 register(KernelSpec(
     "paged_attention", ("paged_attention", "paged_latent_attention"),
     "tolerance", _parity_paged,
-    tpu_cases=_tpu_cases_paged, version=3,
+    tpu_cases=_tpu_cases_paged, version=4,
     doc="blocked [S,1] decode attention over the live blocks of a paged "
-        "arena, online softmax, the next live copy unit always in flight "
+        "arena, online softmax, the next live copy unit always in flight, "
+        "a run of 8 neighbouring blocks one copy descriptor "
         "(kernels/attention.py)",
 ))
 register(KernelSpec(
     "chunk_paged_attention", ("chunk_paged_attention",), "tolerance",
     _parity_chunk_attention, tpu_cases=_tpu_cases_chunk_attention,
+    version=2,
     doc="a prompt chunk's queries over the live blocks of its slot, the "
         "mask made on the device from the chunk's span, online softmax "
         "over double-buffered copy tiles (kernels/attention.py "
@@ -1034,7 +1036,7 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "index_select", ("sparse_index_select",), "tolerance",
-    _parity_index_select, tpu_cases=_tpu_cases_index_select,
+    _parity_index_select, tpu_cases=_tpu_cases_index_select, version=2,
     doc="an indexer's scores over a sequence's live index blocks "
         "(index_scores) and the exact top-k rows a query keeps, found by "
         "bisection over the scores' ordered bits, no sort (index_select): "
@@ -1042,7 +1044,7 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "masked_chunk_attention", ("chunk_paged_attention",), "tolerance",
-    _parity_masked_chunk, tpu_cases=_tpu_cases_masked_chunk,
+    _parity_masked_chunk, tpu_cases=_tpu_cases_masked_chunk, version=2,
     doc="a prompt chunk's queries over the live blocks of its slot under a "
         "[C, L] mask (the indexer's selection), the mask's tile copied "
         "beside K and V, a query head a product (kernels/sparse.py "
@@ -1050,7 +1052,7 @@ register(KernelSpec(
 ))
 register(KernelSpec(
     "latent_chunk_attention", ("chunk_latent_attention",), "tolerance",
-    _parity_latent_chunk, tpu_cases=_tpu_cases_latent_chunk, version=3,
+    _parity_latent_chunk, tpu_cases=_tpu_cases_latent_chunk, version=4,
     doc="a prompt chunk's latent attention, EXPANDED: a group of heads a "
         "grid step, the slot's live rows walked once in double-buffered "
         "copy tiles, each up-projected once for all the chunk's queries; "

@@ -27,13 +27,17 @@ blocks, about a megabyte of K plus V from the block size, the row width and
 the dtype (one 128-row tile at 2,048 bf16 or 1,024 float32 lanes, four at
 512 bf16, eight at 256), never more than a slot holds, and small enough that
 two halves of K and of V (the double-buffered VMEM scratch) fit the budget
-below. A unit's live blocks are started with one ``make_async_copy`` a block
-and arena, all on its half's semaphore, and waited for together: a DMA
-semaphore counts bytes, so ONE wait an arena of the unit's size serves a
-full unit (a short one takes a wait per power of two). The grid runs over
-the slots, ``_STEP_SLOTS`` to a grid step (a grid step costs the same
-whether its slots are live or free); the block table, the lengths and each
-slot's NEXT LIVE slot ride in scalar prefetch. What is in flight when: the
+below. A unit's live blocks are started a RUN at a time: an aligned group of
+``_RUN_BLOCKS`` table entries that the arena holds side by side, ascending,
+and that are all live goes in ONE ``make_async_copy`` an arena (the
+wrapper flags such groups from the block table on the device, exactly:
+``_copy_runs``), every other block in one of its own; all on the half's
+semaphore, and waited for together: a DMA semaphore counts bytes, so ONE
+wait an arena of the unit's size serves a full unit (a short one takes a
+wait per power of two). The grid runs over the slots, ``_STEP_SLOTS`` to a
+grid step (a grid step costs the same whether its slots are live or free);
+the block table, its run flags, the lengths and each slot's NEXT LIVE slot
+ride in scalar prefetch. What is in flight when: the
 next live unit, always. The first grid step zeroes the scratch and starts the
 first live slot's first unit; from then on, when a unit's reduce begins, the
 unit after it is started into the other half — the slot's own next unit or,
@@ -98,7 +102,7 @@ __all__ = [
     "chunk_attention_composite", "decode_attention", "paged_attention",
     "chunk_attention", "chunk_attention_by_span", "chunk_horizon",
     "chunk_mask_bias", "chunk_floor", "fits_vmem", "grouped_layout",
-    "paged_copy_unit",
+    "paged_copy_unit", "paged_run_blocks",
     "absorb_queries", "project_values", "latent_chunk_expanded",
     "latent_chunk_attention",
 ]
@@ -363,24 +367,100 @@ def _mosaic_tiles(block_size, hidden, dtype):
     return block_size % sublanes == 0 and hidden % 128 == 0
 
 
-def _start_copies(bt_ref, len_ref, arenas, bufs, sem, slot, unit_no, half, *,
-                  block, unit, per_slot):
+#: blocks ONE copy descriptor brings in where the block table names them
+#: side by side in the arena, ascending (a RUN). Issuing a descriptor costs
+#: the scalar core ~20 ns whatever it moves, NOT hidden under the reduce: a
+#: 16-row block at a time that was a third of a call at 1 KB rows and four
+#: fifths of ``index_scores``' at 256 B ones. READ ON THE CHIP
+#: (tools/check_paged_copies.py; PERF.md section 6, PR 64; us a call of 16
+#: slots at 14k over an ascending | a shuffled table, the parent's 1,467):
+#: 4 blocks 1,089 | 1,654, 8 blocks 992 | 1,551, 16 blocks 944 | 1,499, 32
+#: blocks 919 | 1,473 with a loop a group that is no run, and 8 blocks
+#: 1,005 | 1,378 with the group straight-line, as it is now. 8 is the most
+#: that divides every serving cell's copy unit (decoder_1024x24's is 8)
+_RUN_BLOCKS = 8
+
+
+def paged_run_blocks(unit, arena_blocks):
+    """Blocks ONE descriptor of a paged kernel brings in where its block
+    table names them side by side, at copy units of ``unit`` blocks over an
+    arena of ``arena_blocks``: ``_RUN_BLOCKS``, or 0 (every block alone)
+    where a unit is not whole groups of that many or the arena holds
+    fewer. What the kernels run and what the engine counts a step's run
+    blocks by."""
+    run = _RUN_BLOCKS
+    return run if run > 1 and unit % run == 0 and arena_blocks >= run else 0
+
+
+def _copy_runs(table, unit, arena, block):
+    """``(run, flags)`` of a block table ``[.., per_slot]`` read in copy
+    units of ``unit`` blocks: ``run`` is ``paged_run_blocks``' (0: every
+    block goes alone and ``flags`` is a placeholder); ``flags`` int32
+    ``[slots * groups]`` says, an aligned group of ``run`` table entries
+    each, that every entry is its neighbour's plus one. Exact for ANY
+    table; a slot's last, padded group is never wholly live and never
+    read."""
+    run = paged_run_blocks(unit, arena.shape[0] // block)
+    if not run:
+        return 0, jnp.zeros((1,), jnp.int32)
+    per_slot = table.shape[-1]
+    groups = -(-per_slot // run)
+    t = jnp.pad(table.reshape(-1, per_slot),
+                ((0, 0), (0, groups * run - per_slot)))
+    t = t.reshape(-1, groups, run)
+    return run, jnp.all(t[..., 1:] - t[..., :-1] == 1,
+                        axis=-1).astype(jnp.int32).reshape(-1)
+
+
+def _start_copies(bt_ref, run_ref, len_ref, arenas, bufs, sem, slot, unit_no,
+                  half, *, block, unit, per_slot, run):
     """Start the copies of the live blocks of ``slot``'s copy unit
-    ``unit_no``, K and V, into ``half`` of the scratch: one descriptor a
-    block and arena, all of a half's on one semaphore an arena."""
+    ``unit_no``, K and V, into ``half`` of the scratch, all of a half's on
+    one semaphore an arena: an aligned group of ``run`` blocks that is
+    wholly live and flagged a run (``run_ref``: ``_copy_runs``) goes in ONE
+    descriptor an arena, every other block in one of its own (``run`` 0:
+    all of them)."""
     live = pl.cdiv(len_ref[slot], block)
     first = unit_no * unit
+    count = jnp.minimum(live - first, unit)
 
-    def one(j, c):
+    def copy(j, blocks):
         row0 = pl.multiple_of(
             bt_ref[slot * per_slot + first + j] * block, block)
-        dst = (half, pl.ds(pl.multiple_of(j * block, block), block))
+        dst = (half, pl.ds(pl.multiple_of(j * block, block), blocks * block))
         for n, (arena, buf) in enumerate(zip(arenas, bufs)):
-            pltpu.make_async_copy(arena.at[pl.ds(row0, block)], buf.at[dst],
-                                  sem.at[n, half]).start()
+            pltpu.make_async_copy(
+                arena.at[pl.ds(row0, blocks * block)], buf.at[dst],
+                sem.at[n, half]).start()
+
+    def one(j, c):
+        copy(j, 1)
         return c
 
-    jax.lax.fori_loop(0, jnp.minimum(live - first, unit), one, 0)
+    if not run:
+        jax.lax.fori_loop(0, count, one, 0)
+        return
+    flag0 = slot * (-(-per_slot // run)) + first // run
+
+    def group(g, c):
+        is_run = run_ref[flag0 + g] != 0
+
+        @pl.when(is_run)
+        def _():
+            copy(g * run, run)
+
+        @pl.when(jnp.logical_not(is_run))
+        def _():
+            # (straight-line: a loop a group cost a shuffled table's call
+            # 6-27 % on the chip, PERF.md section 6, PR 64)
+            for j in range(run):
+                copy(g * run + j, 1)
+
+        return c
+
+    whole = jnp.maximum(count, 0) // run
+    jax.lax.fori_loop(0, whole, group, 0)
+    jax.lax.fori_loop(whole * run, count, one, 0)
 
 
 def _wait_copies(len_ref, bufs, sem, slot, unit_no, half, *, block, unit):
@@ -401,9 +481,9 @@ def _wait_copies(len_ref, bufs, sem, slot, unit_no, half, *, block, unit):
         run >>= 1
 
 
-def _paged_pipeline(bt_ref, len_ref, nxt_ref, arenas, bufs, sem, half_ref,
-                    init, reduce_tile, finish, *, block, tile, unit,
-                    per_slot, step_slots):
+def _paged_pipeline(bt_ref, run_ref, len_ref, nxt_ref, arenas, bufs, sem,
+                    half_ref, init, reduce_tile, finish, *, block, tile,
+                    unit, per_slot, step_slots, run):
     """The copy pipeline both bodies share. A grid step serves
     ``step_slots`` slots, one after the other: ``carry = init(i)``, then
     the slot's live reduce tiles (``tile`` blocks each) go through
@@ -423,8 +503,9 @@ def _paged_pipeline(bt_ref, len_ref, nxt_ref, arenas, bufs, sem, half_ref,
     per_unit = unit // tile
 
     def start(slot, unit_no, half):
-        _start_copies(bt_ref, len_ref, arenas, bufs, sem, slot, unit_no,
-                      half, block=block, unit=unit, per_slot=per_slot)
+        _start_copies(bt_ref, run_ref, len_ref, arenas, bufs, sem, slot,
+                      unit_no, half, block=block, unit=unit,
+                      per_slot=per_slot, run=run)
 
     @pl.when(step0 == 0)
     def _():
@@ -477,8 +558,8 @@ def _paged_pipeline(bt_ref, len_ref, nxt_ref, arenas, bufs, sem, half_ref,
     jax.lax.fori_loop(0, step_slots, one_slot, 0)
 
 
-def _paged_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
-                kbuf, vbuf, sem, half_ref, *, sm_scale, **geometry):
+def _paged_body(bt_ref, run_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm,
+                o_ref, kbuf, vbuf, sem, half_ref, *, sm_scale, **geometry):
     f32 = jnp.float32
     trows = geometry["tile"] * geometry["block"]
 
@@ -506,12 +587,13 @@ def _paged_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
         _m, l, acc = carry
         o_ref[i] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
 
-    _paged_pipeline(bt_ref, len_ref, nxt_ref, (k_hbm, v_hbm), (kbuf, vbuf),
-                    sem, half_ref, init, reduce_tile, finish, **geometry)
+    _paged_pipeline(bt_ref, run_ref, len_ref, nxt_ref, (k_hbm, v_hbm),
+                    (kbuf, vbuf), sem, half_ref, init, reduce_tile, finish,
+                    **geometry)
 
 
-def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, *refs,
-                        sm_scale, kv_heads, arenas, **geometry):
+def _paged_grouped_body(bt_ref, run_ref, len_ref, nxt_ref, q_ref, b_ref,
+                        *refs, sm_scale, kv_heads, arenas, **geometry):
     """``_paged_body`` with a head axis: the rows hold ``kv_heads`` K (V)
     heads of ``D`` side by side, a slot's ``q_ref[i]`` is ``[kv_heads, per,
     D]``, and a tile of rows is reduced once per K/V head on the MXU,
@@ -563,8 +645,8 @@ def _paged_grouped_body(bt_ref, len_ref, nxt_ref, q_ref, b_ref, *refs,
         for g, (_m, l, acc) in enumerate(carry):
             o_ref[i, g] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
 
-    _paged_pipeline(bt_ref, len_ref, nxt_ref, hbm, bufs, sem, half_ref,
-                    init, reduce_tile, finish, **geometry)
+    _paged_pipeline(bt_ref, run_ref, len_ref, nxt_ref, hbm, bufs, sem,
+                    half_ref, init, reduce_tile, finish, **geometry)
 
 
 def grouped_layout(width, kv_heads, q_width, dtype, interpret=False):
@@ -656,10 +738,11 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     ntiles = -(-per_slot // tile)
     step_slots = max(n for n in range(1, _STEP_SLOTS + 1) if S % n == 0)
     # what every call over one ``rows`` and ``bias`` derives alike (XLA
-    # computes it once a program: tests/test_hlo.py): the block table, the
-    # lengths, each slot's next live slot and the bias in tiles
+    # computes it once a program: tests/test_hlo.py): the block table, its
+    # runs, the lengths, each slot's next live slot and the bias in tiles
     with jax.named_scope(TABLES_SCOPE):
         table = (rows.reshape(S, L)[:, ::bs] // bs).astype(jnp.int32)
+        run, runs = _copy_runs(table, unit, k_arena, bs)
         bias2 = bias.reshape(S, L)
         lengths = jnp.max(jnp.where(
             bias2 > _CLOSED, jnp.arange(1, L + 1, dtype=jnp.int32), 0),
@@ -689,9 +772,9 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
     out = pl.pallas_call(
         functools.partial(body, sm_scale=sm_scale, block=bs, tile=tile,
                           unit=unit, per_slot=per_slot,
-                          step_slots=step_slots),
+                          step_slots=step_slots, run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(S // step_slots,),
             in_specs=[
                 row,
@@ -710,7 +793,7 @@ def paged_attention(q, k_arena, v_arena, rows, bias, seqs, length,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention" if not latent else LATENT_STEP_KERNEL,
-    )(table.reshape(-1), lengths, nxt, q_in, tiles, *arenas)
+    )(table.reshape(-1), runs, lengths, nxt, q_in, tiles, *arenas)
     if G:
         out = _unpack_heads(out, pack, q.shape[-1] // H)
     return out.reshape(S, -1)
@@ -744,9 +827,9 @@ _CHUNK_VMEM_LIMIT = 48 * 1024 * 1024
 CHUNK_KERNEL_MIN_WORK = 1 << 20
 
 
-def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
-                kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, sm_scale, block,
-                tile, per_slot, ft_ref=None, lo_ref=None):
+def _chunk_body(bt_ref, run_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm,
+                o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, sm_scale,
+                block, tile, per_slot, run, ft_ref=None, lo_ref=None):
     """One tile of a chunk's queries (``q_ref`` ``[pairs, rows, lanes]``:
     ``grouped_layout``'s packing, a position's query rows together) against
     the slot's live rows, a copy tile of ``tile`` blocks at a time, the
@@ -777,9 +860,9 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
     acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
     def start(tile_no, half):
-        _start_copies(bt_ref, len_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, 0,
-                      tile_no, half, block=block, unit=tile,
-                      per_slot=per_slot)
+        _start_copies(bt_ref, run_ref, len_ref, (k_hbm, v_hbm), (kbuf, vbuf),
+                      sem, 0, tile_no, half, block=block, unit=tile,
+                      per_slot=per_slot, run=run)
 
     @pl.when(nk > t0)
     def _():
@@ -826,13 +909,13 @@ def _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, k_hbm, v_hbm, o_ref,
         o_ref[g] = jnp.where(real, acc_ref[g] / l, 0.0).astype(o_ref.dtype)
 
 
-def _windowed_chunk_body(bt_ref, len_ref, nt_ref, ft_ref, q_ref, hz_ref,
-                         lo_ref, *refs, **static):
+def _windowed_chunk_body(bt_ref, run_ref, len_ref, nt_ref, ft_ref, q_ref,
+                         hz_ref, lo_ref, *refs, **static):
     """``_chunk_body`` under a window: a fourth prefetched vector, each
     query tile's first copy tile, and each query row's lower edge beside
     its horizon."""
-    _chunk_body(bt_ref, len_ref, nt_ref, q_ref, hz_ref, *refs, ft_ref=ft_ref,
-                lo_ref=lo_ref, **static)
+    _chunk_body(bt_ref, run_ref, len_ref, nt_ref, q_ref, hz_ref, *refs,
+                ft_ref=ft_ref, lo_ref=lo_ref, **static)
 
 
 #: the chunk kernel's name under a window: another executable, and a device
@@ -892,6 +975,7 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
     lanes = pack * (H // G)
     pairs = G // pack
     table = (rows[::bs] // bs).astype(jnp.int32)
+    run, runs = _copy_runs(table, tile, k_arena, bs)
     horizon = chunk_horizon(span, C, L, block_len)
     # rows the copies may touch: up to the chunk's last horizon
     live = jnp.max(horizon).reshape(1)
@@ -902,7 +986,8 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
     q_in = jnp.swapaxes(q_in, 0, 1).reshape(pairs, C * rpp, lanes)
     spec = pl.BlockSpec((pairs, qt * rpp, lanes), lambda i, *_: (0, i, 0))
     edge = pl.BlockSpec((qt * rpp, 1), lambda i, *_: (i, 0))
-    prefetch, operands = (table, live, ntiles.astype(jnp.int32)), (q_in, hz)
+    prefetch = (table, runs, live, ntiles.astype(jnp.int32))
+    operands = (q_in, hz)
     if W:
         floor = chunk_floor(span, C, W)
         # a query tile's first copy tile: its first query's edge is its
@@ -913,7 +998,7 @@ def chunk_attention(q, k_arena, v_arena, rows, span, block_size, sm_scale,
     out = pl.pallas_call(
         functools.partial(_windowed_chunk_body if W else _chunk_body,
                           sm_scale=sm_scale, block=bs,
-                          tile=tile, per_slot=per_slot),
+                          tile=tile, per_slot=per_slot, run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(C // qt,),
@@ -1102,10 +1187,10 @@ _LATENT_CHUNK_TILE_ROWS = 512
 _LATENT_CHUNK_VMEM = 40 * 1024 * 1024
 
 
-def _latent_chunk_body(bt_ref, live_ref, first_ref, last_ref, q_ref, hz_ref,
-                       wk_ref, wv_ref, arena, o_ref, rowbuf, sem, key_ref,
-                       val_ref, m_ref, l_ref, acc_ref, *, sm_scale, block,
-                       tile, per_slot):
+def _latent_chunk_body(bt_ref, run_ref, live_ref, first_ref, last_ref, q_ref,
+                       hz_ref, wk_ref, wv_ref, arena, o_ref, rowbuf, sem,
+                       key_ref, val_ref, m_ref, l_ref, acc_ref, *, sm_scale,
+                       block, tile, per_slot, run):
     """One group of heads against the slot's live rows, a copy tile of
     ``tile`` blocks at a time, the next one in flight while this one is
     up-projected and reduced. A tile's rows ``[c | k^R | zeros]`` become,
@@ -1137,8 +1222,9 @@ def _latent_chunk_body(bt_ref, live_ref, first_ref, last_ref, q_ref, hz_ref,
     acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
     def start(tile_no, half):
-        _start_copies(bt_ref, live_ref, (arena,), (rowbuf,), sem, 0, tile_no,
-                      half, block=block, unit=tile, per_slot=per_slot)
+        _start_copies(bt_ref, run_ref, live_ref, (arena,), (rowbuf,), sem, 0,
+                      tile_no, half, block=block, unit=tile,
+                      per_slot=per_slot, run=run)
 
     @pl.when(nk > 0)
     def _():
@@ -1277,16 +1363,17 @@ def latent_chunk_attention(q, w_uk, w_uv, arena, rows, span, block_size,
     latent_chunk_counter().inc()
     subtiles, groups = C // qs, heads // group
     table = (rows[::bs] // bs).astype(jnp.int32)
+    run, runs = _copy_runs(table, tile, arena, bs)
     horizon = chunk_horizon(span, C, L).reshape(subtiles, qs)
-    scalars = (table, jnp.max(horizon).reshape(1), horizon[:, 0],
+    scalars = (table, runs, jnp.max(horizon).reshape(1), horizon[:, 0],
                jnp.max(horizon, axis=-1))
     whole = lambda *shape: pl.BlockSpec(                     # noqa: E731
         shape, lambda g, *_: (0,) * len(shape))
     call = pl.pallas_call(
         functools.partial(_latent_chunk_body, sm_scale=sm_scale, block=bs,
-                          tile=tile, per_slot=per_slot),
+                          tile=tile, per_slot=per_slot, run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(1,),
             in_specs=[
                 whole(group, subtiles, width, qs),

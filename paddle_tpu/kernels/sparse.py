@@ -28,9 +28,12 @@ whose unselected positions are closed (``-1e9``): the kernel reads a slot's
 live blocks as it ever did and the selection only masks. Gathering the 2,048
 rows themselves would move 1/8 of the bytes at 16k of context, a row (1 KB
 of K, 1 KB of V at 4 K/V heads of 128 in bfloat16) a copy descriptor; the
-paged kernel moves a 16-row block a descriptor and reads half its roofline
-so (PERF.md section 6, PR 63), which puts the row form's crossing past this
-repo's contexts. A prompt chunk attends under the ``[C, L]`` mask
+paged kernel moves a RUN of 8 neighbouring 16-row blocks a descriptor where
+the block table names them side by side in the arena, and a block a
+descriptor elsewhere (``kernels/attention.py _start_copies``: 0.99 against
+1.47 ms a layer at 16 slots x 14k over an ascending table, PERF.md section
+6, PR 64), which puts the row form's crossing further past this repo's
+contexts than PR 63 read it. A prompt chunk attends under the ``[C, L]`` mask
 (``masked_chunk_attention``): per (query, row) the mask form costs
 operations where the gather form costs bytes, and under ~61k of context the
 operations are the cheaper (PERF.md section 4).
@@ -58,7 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.kernels.attention import (
-    _CLOSED, _mosaic_tiles, _start_copies, _wait_copies,
+    _CLOSED, _copy_runs, _mosaic_tiles, _start_copies, _wait_copies,
     chunk_attention_composite, chunk_horizon,
 )
 from paddle_tpu.kernels.registry import fallback_counter
@@ -213,8 +216,8 @@ def masked_chunk_composite(q, k_arena, v_arena, rows, mask, sm_scale,
 # index_scores
 # ---------------------------------------------------------------------------
 
-def _scores_body(bt_ref, len_ref, nt_ref, q_ref, w_ref, arena, o_ref, kbuf,
-                 sem, *, block, tile, per_slot, tiles_a_seq):
+def _scores_body(bt_ref, run_ref, len_ref, nt_ref, q_ref, w_ref, arena, o_ref,
+                 kbuf, sem, *, block, tile, per_slot, tiles_a_seq, run):
     """The scores of one grid step's queries (a step: ``q_ref`` ``[1,
     heads, width]``, the slot's one query; a chunk: ``[heads, tq, width]``)
     against their sequence's index rows, ``tile`` blocks a product, the
@@ -230,8 +233,9 @@ def _scores_body(bt_ref, len_ref, nt_ref, q_ref, w_ref, arena, o_ref, kbuf,
     step = q_ref.shape[0] == 1
 
     def start(t, half):
-        _start_copies(bt_ref, len_ref, (arena,), (kbuf,), sem, g, t, half,
-                      block=block, unit=tile, per_slot=per_slot)
+        _start_copies(bt_ref, run_ref, len_ref, (arena,), (kbuf,), sem, g, t,
+                      half, block=block, unit=tile, per_slot=per_slot,
+                      run=run)
 
     @pl.when(nk > 0)
     def _():
@@ -295,6 +299,7 @@ def index_scores(q, w, arena, rows, seqs, block_size, horizon,
         return index_scores_composite(q, w, arena, rows, G)
     i32 = jnp.int32
     table = (rows.reshape(G, L)[:, ::bs] // bs).astype(i32)
+    run, runs = _copy_runs(table, tile, arena, bs)
     hz = horizon.astype(i32).reshape(G, Q // tq, tq)
     bound = jnp.max(hz, axis=-1)                          # [G, Q / tq]
     live = jnp.max(bound, axis=-1)                        # [G]
@@ -313,9 +318,9 @@ def index_scores(q, w, arena, rows, seqs, block_size, horizon,
         w_spec = pl.BlockSpec((heads, tq, 1), lambda g, i, *_: (0, i, 0))
     out = pl.pallas_call(
         functools.partial(_scores_body, block=bs, tile=tile,
-                          per_slot=per_slot, tiles_a_seq=Q // tq),
+                          per_slot=per_slot, tiles_a_seq=Q // tq, run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(G, Q // tq),
             in_specs=[q_spec, w_spec, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, tq, Lp), lambda g, i, *_: (g, i, 0)),
@@ -330,7 +335,8 @@ def index_scores(q, w, arena, rows, seqs, block_size, horizon,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name=INDEX_SCORES_KERNEL,
-    )(table.reshape(-1), live, nt.reshape(-1).astype(i32), q_in, w_in, arena)
+    )(table.reshape(-1), runs, live, nt.reshape(-1).astype(i32), q_in, w_in,
+      arena)
     return out.reshape(N, Lp)
 
 
@@ -420,9 +426,9 @@ def index_select(scores, horizon, topk, interpret=False):
 # masked chunk attention
 # ---------------------------------------------------------------------------
 
-def _masked_chunk_body(bt_ref, len_ref, nt_ref, q_ref, mask, k_hbm, v_hbm,
-                       o_ref, kbuf, vbuf, mbuf, sem, m_ref, l_ref, acc_ref,
-                       *, sm_scale, block, tile, per_slot):
+def _masked_chunk_body(bt_ref, run_ref, len_ref, nt_ref, q_ref, mask, k_hbm,
+                       v_hbm, o_ref, kbuf, vbuf, mbuf, sem, m_ref, l_ref,
+                       acc_ref, *, sm_scale, block, tile, per_slot, run):
     """One tile of a chunk's queries (``q_ref`` ``[G, per, qt, D]``: a K/V
     head's ``per`` query heads, each head's ``qt`` positions together)
     against the slot's live rows, a copy tile at a time with the mask's
@@ -453,8 +459,9 @@ def _masked_chunk_body(bt_ref, len_ref, nt_ref, q_ref, mask, k_hbm, v_hbm,
             mbuf.at[half], sem.at[2, half])
 
     def start(t, half):
-        _start_copies(bt_ref, len_ref, (k_hbm, v_hbm), (kbuf, vbuf), sem, 0,
-                      t, half, block=block, unit=tile, per_slot=per_slot)
+        _start_copies(bt_ref, run_ref, len_ref, (k_hbm, v_hbm), (kbuf, vbuf),
+                      sem, 0, t, half, block=block, unit=tile,
+                      per_slot=per_slot, run=run)
         mask_copy(t, half).start()
 
     @pl.when(nk > 0)
@@ -531,6 +538,7 @@ def masked_chunk_attention(q, k_arena, v_arena, rows, span, mask, block_size,
     if mask.shape[1] < Lp:
         mask = jnp.pad(mask, ((0, 0), (0, Lp - mask.shape[1])))
     table = (rows[::bs] // bs).astype(jnp.int32)
+    run, runs = _copy_runs(table, tile, k_arena, bs)
     horizon = chunk_horizon(span, C, L)
     live = jnp.max(horizon).reshape(1)
     ntiles = -(-jnp.max(horizon.reshape(C // qt, qt), axis=-1) // trows)
@@ -541,9 +549,9 @@ def masked_chunk_attention(q, k_arena, v_arena, rows, span, mask, block_size,
     any_ = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(_masked_chunk_body, sm_scale=sm_scale, block=bs,
-                          tile=tile, per_slot=per_slot),
+                          tile=tile, per_slot=per_slot, run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(C // qt,),
             in_specs=[spec, any_, any_, any_],
             out_specs=spec,
@@ -563,5 +571,6 @@ def masked_chunk_attention(q, k_arena, v_arena, rows, span, mask, block_size,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name=MASKED_CHUNK_KERNEL,
-    )(table, live, ntiles.astype(jnp.int32), q_in, mask, k_arena, v_arena)
+    )(table, runs, live, ntiles.astype(jnp.int32), q_in, mask, k_arena,
+      v_arena)
     return jnp.transpose(out, (2, 0, 1, 3)).reshape(q.shape)

@@ -2367,7 +2367,7 @@ class _ModelEntry:
                  if m.logits_mask else None)
         active = []
         groups = []     # beam groups with a live slot this step
-        live_blocks = copy_units = 0
+        live_blocks = copy_units = copy_blocks = run_blocks = 0
         launched = self._launched
         self._window_step()
         for s in range(S):
@@ -2446,6 +2446,8 @@ class _ModelEntry:
             live_blocks += reads
             if self.kv.copy_unit:
                 copy_units += -(-reads // self.kv.copy_unit)
+                copy_blocks += reads
+                run_blocks += st.kv.run_blocks(reads)
             if dmask is not None and st.grammar is not None:
                 # the grammar's next-token constraint rides in as DATA —
                 # same compiled program for every request, zero retraces
@@ -2453,7 +2455,7 @@ class _ModelEntry:
         if not active and not groups:
             return None
         self._metrics.observe_blocks(live_blocks, S * m.blocks_per_slot,
-                                     copy_units)
+                                     copy_units, copy_blocks, run_blocks)
         feeds = {DecodeModel.DEC_STEP: step,
                  DecodeModel.DEC_TOKEN: (self._no_tokens if launched is None
                                          else launched.fetches[1])}

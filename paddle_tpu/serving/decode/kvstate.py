@@ -123,14 +123,22 @@ class SeqKV:
     never to be rewritten; ``reserve`` what is left of the footing's
     reservation, blocks promised and not yet opened; ``life`` the blocks of
     the whole sequence where they were promised and ``limit`` the most of
-    them the footing holds at once."""
+    them the footing holds at once. With ``run`` (the blocks a paged kernel
+    copies in ONE descriptor where the table names them side by side:
+    ``kernels.attention.paged_run_blocks``) ``runs[g]`` is how many blocks
+    of the table's first ``g`` aligned groups of ``run`` entries lie in such
+    runs, by the kernel's own rule."""
 
     __slots__ = ("blocks", "first", "row_map", "table", "reserve", "life",
-                 "limit", "shared_len", "window", "_more", "_bs", "_rows")
+                 "limit", "shared_len", "window", "runs", "_more", "_bs",
+                 "_rows", "_run", "_runs_first")
 
-    def __init__(self, model, group, life=0, blocks=None, shared_len=0):
+    def __init__(self, model, group, life=0, blocks=None, shared_len=0,
+                 run=0):
         self._bs = model.block_size
         self._rows = group.num_blocks * model.block_size
+        self._run = run
+        self.runs, self._runs_first = [0], 0
         self.window = group.window
         self.blocks, self.first = blocks or [], 0
         self.shared_len = shared_len
@@ -163,6 +171,32 @@ class SeqKV:
         self.row_map[:len(rows)] = rows
         n = min(len(self.blocks), len(self.table))
         self.table[:n] = rows[:n * self._bs:self._bs] // self._bs
+        if self._run:
+            self._count_runs(n // self._run)
+
+    def _count_runs(self, whole):
+        """``runs`` for the table's first ``whole`` groups. A chain changes
+        at its tail alone (a block opened, the tail copied on write) unless
+        its first live block moved, so the groups counted before the last
+        one stand: a step that opens a block pays for one group, not for
+        the chain."""
+        run, runs, blocks = self._run, self.runs, self.blocks
+        keep = (max(min(len(runs) - 2, whole), 0)
+                if self._runs_first == self.first else 0)
+        self._runs_first = self.first
+        del runs[keep + 1:]
+        for g in range(keep, whole):
+            ids = [b.id for b in blocks[g * run:(g + 1) * run]]
+            runs.append(runs[-1] + run * (
+                ids == list(range(ids[0], ids[0] + run))))
+
+    def run_blocks(self, live):
+        """Of the footing's first ``live`` blocks, those a paged kernel
+        copies as part of a run: the blocks of the aligned groups that are
+        wholly among them and flagged (`remap`)."""
+        runs = self.runs
+        g = live // self._run if self._run else 0
+        return runs[g] if g < len(runs) else runs[-1]
 
     def row_of(self, p):
         bs = self._bs
@@ -187,7 +221,9 @@ class KVStore:
 
     def __init__(self, model, tier_bytes, prefix_cache_size, metrics, run,
                  fetch, scope, device):
-        from paddle_tpu.kernels.attention import paged_copy_unit
+        from paddle_tpu.kernels.attention import (
+            paged_copy_unit, paged_run_blocks,
+        )
 
         self._model = model
         self._metrics = metrics
@@ -220,6 +256,10 @@ class KVStore:
         self.copy_unit = paged_copy_unit(
             model.block_size, model.blocks_per_slot, model.kv_width,
             model.kv_dtype, model.arenas // len(model.state_names))
+        # blocks of a copy unit that go in ONE descriptor where a slot's
+        # table names them side by side (0: every block alone), to count
+        # a step's run blocks
+        self.run_blocks = paged_run_blocks(self.copy_unit, model.num_blocks)
 
     @property
     def pool(self):
@@ -393,7 +433,8 @@ class KVStore:
         """A sequence's footing in every group, ``blocks`` its chain in the
         first, each promised what `_needs` says of a ``chain``."""
         m = self._model
-        kv = SeqKV(m, m.groups[0], chain, blocks, shared_len)
+        kv = SeqKV(m, m.groups[0], chain, blocks, shared_len,
+                   self.run_blocks)
         kv._more = tuple(SeqKV(m, g, chain) for g in m.groups[1:])
         return kv
 

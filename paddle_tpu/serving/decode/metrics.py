@@ -23,7 +23,10 @@ step's attention has to read: ``serving_decode_live_blocks_total`` over
 ``serving_decode_block_slots_total``; and in how many copy units the
 kernel brings it in, ``serving_paged_copy_units_total``, of which
 ``serving_paged_copy_units_ahead_total`` are in flight before their reduce
-is due.
+is due; the blocks it copies, ``serving_paged_copy_blocks_total`` (the
+live blocks where a kernel serves the geometry), and those of them that lie
+in a run of the slot's block table and go in ONE descriptor,
+``serving_paged_run_blocks_total``.
 
 The order of a decode step's phases. A step is feeds, launch
 (``decode::step``), ONE fetch (``decode::step_fetch``) and the host half
@@ -223,6 +226,12 @@ class DecodeMetrics(ServingMetrics):
         # copy is started before their reduce is due (all but a step's
         # first: a slot's first unit rides under the slot before it)
         "paged_copy_units", "paged_copy_units_ahead",
+        # the blocks those units hold (the live blocks, where a kernel
+        # serves the geometry), and those of them in an aligned group of
+        # kernels/attention.py paged_run_blocks table entries that lie
+        # side by side in the arena and are all live: the kernel copies
+        # such a group in one descriptor an arena, by the same rule
+        "paged_copy_blocks", "paged_run_blocks",
         # a model with routed experts of which this chip holds a share,
         # per decode step as the device ran it (wasted slots included):
         # tokens x k over the expert layers, those that landed on a held
@@ -450,13 +459,17 @@ class DecodeMetrics(ServingMetrics):
             self.incr("device_idle_host_seconds", parts.idle_host_ns * 1e-9)
         self.incr("device_unqueued_seconds", parts.unqueued_ns * 1e-9)
 
-    def observe_blocks(self, live, slots, copy_units):
+    def observe_blocks(self, live, slots, copy_units, copy_blocks,
+                       run_blocks):
         """One decode step's feeds: ``live`` blocks hold its stepping
         slots' positions up to their cursors, of ``slots`` block slots
         (S x blocks per slot) in the step's row map; the kernel brings
         them in as ``copy_units`` units, all but the first in flight
-        before their reduce is due."""
+        before their reduce is due: ``copy_blocks`` blocks, ``run_blocks``
+        of them in runs of one descriptor."""
         self.incr("decode_live_blocks", live)
+        self.incr("paged_copy_blocks", copy_blocks)
+        self.incr("paged_run_blocks", run_blocks)
         self.incr("decode_block_slots", slots)
         self.incr("paged_copy_units", copy_units)
         self.incr("paged_copy_units_ahead", max(copy_units - 1, 0))

@@ -626,9 +626,14 @@ class BlockPool:
     def release(self, blocks, reserved=0):
         """Drop one owner's references, and the ``reserved`` blocks it was
         promised and never opened. Registered refcount-0 blocks stay
-        cached (LRU) for future prefix hits; private ones free."""
+        cached (LRU) for future prefix hits; private ones free, the chain's
+        LAST block deepest: the free list hands out from its end, so what
+        a chain gives back is handed out again in the chain's order, and
+        blocks that lay side by side in the arena come back side by side
+        (the paged kernels copy such a run in one descriptor)."""
         with self._lock:
             self.reserved -= int(reserved)
+            freed = []
             for b in blocks:
                 b.refcount -= 1
                 if b.refcount > 0:
@@ -638,7 +643,8 @@ class BlockPool:
                     self._cached.move_to_end(b.id)
                 else:
                     b.reset()
-                    self._free.append(b.id)
+                    freed.append(b.id)
+            self._free.extend(reversed(freed))
 
     def open_promised(self, n):
         """``n`` fresh private blocks out of their owner's reservation,

@@ -118,10 +118,11 @@ def test_a_query_tile_starts_at_the_tile_of_its_first_edge(monkeypatch):
     started = []
     own = A._start_copies
 
-    def spy(bt_ref, len_ref, arenas, bufs, sem, slot, unit_no, half, **kw):
+    def spy(bt_ref, run_ref, len_ref, arenas, bufs, sem, slot, unit_no, half,
+            **kw):
         jax.debug.callback(lambda t: started.append(int(t)), unit_no)
-        return own(bt_ref, len_ref, arenas, bufs, sem, slot, unit_no, half,
-                   **kw)
+        return own(bt_ref, run_ref, len_ref, arenas, bufs, sem, slot,
+                   unit_no, half, **kw)
 
     monkeypatch.setattr(A, "_start_copies", spy)
     start, window = 64, 20

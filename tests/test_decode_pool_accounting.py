@@ -188,3 +188,101 @@ def test_a_parked_sessions_spill_says_what_it_read():
     assert st["arena_read_bytes"] == read > 0
     assert st["fetched_bytes"] - before == read + sum(
         s["args"]["bytes"] for s in spans if s["name"] in FETCH_SPANS[:3])
+
+
+# -- the order chains come back in (ISSUE 64) ------------------------------
+
+def _ids(blocks):
+    return [b.id for b in blocks]
+
+
+def _is_one_run(ids):
+    return all(b - a == 1 for a, b in zip(ids, ids[1:]))
+
+
+def test_a_chain_released_and_opened_again_comes_back_ascending():
+    """`release` pushes a chain's private blocks so that the free list
+    hands them out in the chain's order again: blocks that lay side by
+    side in the arena come back side by side (the paged kernels copy such
+    a run in one descriptor), not reversed."""
+    pool = BlockPool(96, 4)
+    first = pool.acquire_rows(4 * 20)
+    second = pool.acquire_rows(4 * 30)
+    assert _ids(first) == list(range(20))
+    assert _ids(second) == list(range(20, 50))
+    pool.release(first)
+    again = pool.acquire_rows(4 * 20)
+    assert _ids(again) == list(range(20))
+    # the later chain released first, then the earlier one: each is a run
+    pool.release(second)
+    pool.release(again)
+    a, b = pool.acquire_rows(4 * 20), pool.acquire_rows(4 * 30)
+    assert _ids(a) == list(range(20)) and _ids(b) == list(range(20, 50))
+    pool.check_conservation()
+
+
+@pytest.mark.parametrize("churn", [0, 3, 9])
+def test_a_chunks_open_promised_off_a_recycled_pool_is_one_run(churn):
+    """A chunk of 1,024 tokens opens 64 blocks in one call: off a pool
+    whose chains of 64 and more came and went ``churn`` times it is ONE
+    ascending run, as off a fresh one."""
+    pool = BlockPool(512, 16)
+    rng = np.random.RandomState(churn)
+    held = []
+    for _ in range(churn):
+        assert pool.reserve(128)
+        held.append(pool.open_promised(64) + pool.open_promised(64))
+        if len(held) > 2:
+            pool.release(held.pop(int(rng.randint(len(held)))))
+    for chain in held:
+        pool.release(chain)
+    assert pool.reserve(64)
+    chunk = pool.open_promised(64)
+    assert all(b.size_used == 16 for b in chunk)
+    assert _is_one_run(_ids(chunk)), _ids(chunk)
+    assert pool.reserved == 0
+    pool.check_conservation()
+
+
+def test_a_windows_recycled_blocks_come_back_in_their_order():
+    """`recycle` (what lies behind a window) is `release` for an owner
+    that goes on: the blocks it gives back are the next it opens, in the
+    order it held them."""
+    pool = BlockPool(40, 4)
+    assert pool.reserve(12)
+    chain = pool.open_promised(12)
+    pool.recycle(chain[:8], keep=8)
+    assert pool.reserved == 8
+    assert _ids(pool.open_promised(8)) == _ids(chain[:8])
+
+
+def test_the_pools_order_leaves_eviction_reservations_and_stats_alone():
+    """What `release` changed is the ORDER of the free list alone: the LRU
+    still evicts a retired chain's registered blocks head first, a
+    reservation is still what `reserve` promised less what was opened, and
+    `stats` counts what it counted."""
+    pool = BlockPool(12, 2)
+    prompts = [[1, 2, 3, 4, 5, 6], [7, 8, 9, 10, 11, 12]]
+    chains = []
+    for toks in prompts:
+        blocks, shared = pool.acquire_for_prompt(toks)
+        assert shared == 0
+        pool.register_prompt_blocks(blocks, toks)
+        chains.append(blocks)
+    private = pool.acquire_rows(8)
+    assert _ids(chains[0] + chains[1] + private) == list(range(10))
+    for chain in chains:
+        pool.release(chain)
+    pool.release(private)
+    st = pool.stats()
+    assert (st["blocks_free"], st["blocks_cached"], st["blocks_live"]) == (
+        6, 6, 0)
+    assert st["allocs"] == 10 and st["evictions"] == 0
+    # the free list first (the private chain in its order, then the
+    # blocks never handed out), then the LRU: the first chain's head first
+    assert pool.reserve(12) and not pool.reserve(1)
+    opened = _ids(pool.open_promised(12))
+    assert opened == [6, 7, 8, 9, 10, 11, 0, 1, 2, 3, 4, 5]
+    assert pool.reserved == 0 and pool.evictions == 6
+    assert pool.stats()["radix_entries"] == 0
+    pool.check_conservation()

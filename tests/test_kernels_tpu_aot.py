@@ -101,6 +101,43 @@ def test_paged_kernel_serves_every_cells_geometry(label, v5e_devices):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("label", sorted(PAGED_UNITS))
+def test_every_cells_copy_unit_is_whole_runs(label):
+    """A copy unit of every serving cell's geometry is whole groups of
+    ``_RUN_BLOCKS`` blocks, so the compile above lowered the run
+    descriptors (ISSUE 64: a flagged, wholly live group goes in ONE copy
+    of 8 x 16 rows), not the static block-by-block path of a tiny arena."""
+    from paddle_tpu.kernels import attention as A
+
+    unit = PAGED_UNITS[label]
+    assert A._RUN_BLOCKS == 8 and unit % 8 == 0
+    assert A.paged_run_blocks(unit, 20480) == 8
+    assert A.paged_run_blocks(unit, 7) == 0
+    assert A.paged_run_blocks(12, 20480) == 0
+
+
+@pytest.mark.parametrize("run", [0, 4, 16])
+def test_the_paged_kernel_lowers_at_other_runs_and_at_none(
+        run, v5e_devices, monkeypatch):
+    """The copy pipeline lowers for the chip whatever ``_RUN_BLOCKS`` is
+    (what ``tools/check_paged_copies.py --run-blocks`` sweeps), and with
+    no runs at all (the path of an arena smaller than a run): at the
+    widest two-arena geometry, granite's 1,056 blocks a slot."""
+    from paddle_tpu.kernels import attention as A
+
+    monkeypatch.setattr(A, "_RUN_BLOCKS", run)
+    (case,) = [c for c in kernels.get("paged_attention").tpu_cases()
+               if c[0] == "s32_l16896_b16_g8x4x64_bf16"]
+    _label, fn, arg_specs = case
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=sharding)
+            for shape, dt in arg_specs]
+    before = kernels.fallback_counter().value
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernels.fallback_counter().value == before
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def test_paged_kernel_serves_one_latent_arena(v5e_devices):
     """Handed ONE arena (mistral_small_4_119b: 16 slots of 33,280 positions,
     rows of 384 lanes, 32 absorbed query heads, values the first 256 lanes)

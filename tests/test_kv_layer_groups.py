@@ -367,8 +367,8 @@ def _by_hand(groups):
         # 21 prompt positions in three chunks, then the step at 21. The
         # first group holds blocks 0..5 of its pool of 40; the window group
         # opened 0, 1 then 2, 3 of its pool of 24, gave 0 and 1 back before
-        # the chunk at 16 and took them again (1 first), gave 2 back before
-        # the step
+        # the chunk at 16 and took them again (in their order, since PR 64:
+        # `BlockPool.release`), gave 2 back before the step
         nowhere = np.int64(24 * BS)
         chunk = {
             "chu_span": np.array([16, 5], "int32"),
@@ -377,16 +377,16 @@ def _by_hand(groups):
                 [16, 17, 18, 19, 20] + [40 * BS] * 3, "int64"),
             "chu_span.g1": np.array([8, 5], "int32"),
             "chu_rows.g1": np.array(
-                list(range(8, 16)) + [4, 5, 6, 7, 0, 1, 2, 3] + [0] * 4,
+                list(range(8, 16)) + [0, 1, 2, 3, 4, 5, 6, 7] + [0] * 4,
                 "int64"),
-            "chu_write_rows.g1": np.array([4, 5, 6, 7, 0] + [nowhere] * 3,
+            "chu_write_rows.g1": np.array([0, 1, 2, 3, 4] + [nowhere] * 3,
                                           "int64"),
         }
         idle = [-1, 0, 0, 40 * BS] + [0] * 16 + [0, 0, nowhere, 0, 0, 0]
         step = np.array([idle, idle, [
             7, 21, 22, 21, 0, 1, 2, 3, 4, 5] + [0] * 10 + [
             # ten rows from position 12, two of them behind the window
-            10, 2, 1, 3, 1, 0], idle], "int32")
+            10, 2, 5, 3, 0, 1], idle], "int32")
         return chunk, step
     chunk = {
         "chu_span": np.array([4, 2], "int32"),
